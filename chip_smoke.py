@@ -1,0 +1,670 @@
+#!/usr/bin/env python3
+"""chip_smoke.py — the quickest proof that dllama-tpu still starts on the chip.
+
+Drives the main path once, through the entry points a user calls, at the
+full width (and by default the full depth) of Llama-2-7B with seeded random
+Q40 weights:
+
+  kernels  each main-path Pallas kernel against its plain reference on the
+           chip, at the real shapes (Q40 matmul, fused paged attention)
+  cli      ``python -m dllama_tpu inference`` on a synthesized .m/.t pair
+  server   ``python -m dllama_tpu.server.api`` with a paged slot scheduler:
+           concurrent completions, a streamed chat, /metrics, SIGTERM drain
+
+``--chips 4`` runs, instead, only the tensor-parallel path and what it is
+compared with: the same files decoded greedily at tp=4 and tp=1.
+
+This parent never imports JAX.  Each phase is one child process that holds
+the chip alone, checks ``jax.devices()[0].platform == "tpu"`` first, and is
+reaped before the next starts.  Children's stdout is captured and re-printed
+here on earlier lines (one JSON object per line); diagnostics go to stderr.
+The LAST stdout line of a passing run is exactly
+
+    {"ok": true, "device": {"platform": "tpu", "kind": "...", "count": N}}
+
+and nothing prints after it.  Any failed phase, or no TPU, exits non-zero
+without a line containing ``"ok": true``.
+"""
+
+from __future__ import annotations
+
+import argparse
+import json
+import os
+import shutil
+import signal
+import subprocess
+import sys
+import tempfile
+import threading
+import time
+import urllib.request
+
+HERE = os.path.dirname(os.path.abspath(__file__))
+MODEL = "llama2-7b"
+BUDGET_S = 1150            # the driver allows 1200 s, compilation included
+Q40_TOL = 1e-2             # max |pallas - xla| / max |xla|
+ATTN_TOL = 2e-2            # max |fused - gather| / max |gather| (bf16 out)
+TP_LOGIT_TOL = 5e-2        # max |tp4 - tp1| / max |tp1| on first-step logits
+CHILD_MARK = "CHIP_SMOKE "  # prefix of the result lines a child prints
+
+
+class PhaseFailed(Exception):
+    pass
+
+
+def log(msg: str) -> None:
+    print(f"chip_smoke: {msg}", file=sys.stderr, flush=True)
+
+
+def emit(obj: dict) -> None:
+    """An EARLIER stdout line (never the last): one JSON object."""
+    print(json.dumps(obj), flush=True)
+
+
+def last_line(device: dict) -> str:
+    """The one line the driver reads: exactly ``ok`` and ``device``, and in
+    ``device`` exactly ``platform``, ``kind``, ``count``."""
+    return json.dumps({"ok": True, "device": {
+        "platform": str(device["platform"]), "kind": str(device["kind"]),
+        "count": int(device["count"])}})
+
+
+def require(cond, msg: str) -> None:
+    if not cond:
+        raise PhaseFailed(msg)
+
+
+# ---------------------------------------------------------------------------
+# Parent side: spawn, reap, check
+# ---------------------------------------------------------------------------
+
+def _child_env() -> dict:
+    env = dict(os.environ)
+    env["PYTHONPATH"] = HERE + os.pathsep + env.get("PYTHONPATH", "")
+    env["PYTHONUNBUFFERED"] = "1"
+    return env
+
+
+def _child_cmd(phase: str, *args: str, rehearse: bool = False) -> list[str]:
+    """``rehearse`` (tests only, never set by ``main``): the child may run
+    off-TPU at a toy size, so a phase's control flow is checked on the CPU."""
+    return [sys.executable, os.path.abspath(__file__), "--phase", phase,
+            *(["--rehearse"] if rehearse else []), *args]
+
+
+def _results(stdout: str, phase: str) -> tuple[list[dict], dict]:
+    """The result rows a child printed, and apart from them its compile
+    row (re-printed here; every phase returns it for the closing line)."""
+    rows = [json.loads(ln[len(CHILD_MARK):]) for ln in stdout.splitlines()
+            if ln.startswith(CHILD_MARK)]
+    comp = next((r for r in rows if r.get("what") == "compile"), {})
+    if comp:
+        emit(dict(comp, phase=phase))
+    return [r for r in rows if r is not comp], comp
+
+
+def run_child(phase: str, args: list[str], timeout: float,
+              rehearse: bool = False) -> tuple[int, str]:
+    """Run one child to its end with stdout captured (never inherited);
+    a child past its timeout is killed and reaped."""
+    log(f"phase {phase}: starting (timeout {timeout:.0f}s)")
+    p = subprocess.Popen(_child_cmd(phase, *args, rehearse=rehearse),
+                         stdout=subprocess.PIPE, text=True, env=_child_env(),
+                         cwd=HERE)
+    try:
+        out, _ = p.communicate(timeout=max(timeout, 1))
+    except subprocess.TimeoutExpired:
+        p.kill()
+        out, _ = p.communicate()
+        raise PhaseFailed(f"{phase}: no result within {timeout:.0f}s (killed)")
+    return p.returncode, out
+
+
+def cache_entries(path: str) -> int:
+    try:
+        return sum(1 for n in os.listdir(path) if not n.startswith("."))
+    except OSError:
+        return 0
+
+
+def phase_kernels(timeout: float, rehearse: bool = False) -> dict:
+    """Child: kernels vs references on the device.  Returns the device the
+    child held; every kernel's error is re-printed on an earlier line."""
+    rc, out = run_child("kernels", [], timeout, rehearse)
+    rows, comp = _results(out, "kernels")
+    for r in rows:
+        emit(dict(r, phase="kernels"))
+    require(rc == 0, f"kernels: child exited {rc}")
+    dev = next((r for r in rows if r.get("what") == "device"), None)
+    require(dev is not None, "kernels: child reported no device")
+    errs = [r for r in rows if "rel_err" in r]
+    require(errs, "kernels: no kernel was compared")
+    bad = [r for r in errs if not r["rel_err"] <= r["tol"]]
+    require(not bad, f"kernels: above tolerance: {bad}")
+    if not rehearse:
+        require(dev["platform"] == "tpu", f"kernels: ran on {dev['platform']}")
+        require(dev["peaks_source"] == "table",
+                f"kernels: peaks source {dev['peaks_source']!r}, not the table")
+    return dict(dev, compile=comp)
+
+
+def phase_cli(mpath: str, tpath: str, timeout: float, steps: int = 64,
+              rehearse: bool = False) -> dict:
+    """Child: ``python -m dllama_tpu inference`` (the module is run as
+    ``__main__`` after the platform check)."""
+    args = ["inference", "--model", mpath, "--tokenizer", tpath, "--prompt",
+            "hello hello hello", "--steps", str(steps), "--warmup", str(steps),
+            "--workers", "tpu:1", "--temperature", "0", "--seed", "0"]
+    rc, out = run_child("cli", args, timeout, rehearse)
+    _, comp = _results(out, "cli")
+    require(rc == 0, f"cli: exited {rc}; tail: {out[-600:]!r}")
+    lines = out.splitlines()
+
+    def grab(prefix):
+        hit = [ln for ln in lines if ln.startswith(prefix)]
+        require(hit, f"cli: no {prefix!r} line")
+        return hit[-1][len(prefix):].strip()
+
+    n_tokens = int(grab("Generated tokens:"))
+    tok_s = float(grab("Avg tokens / second:"))
+    ledger = next((ln for ln in lines if "kernel dispatch:" in ln), "")
+    warm = next((ln for ln in lines if "warmup:" in ln), "")
+    res = {"phase": "cli", "generated_tokens": n_tokens,
+           "ledger": ledger.strip()}
+    if not rehearse:  # a CPU timing is never printed as a reading
+        res.update(warmup_incl_compile=warm.split("warmup:")[-1].strip(),
+                   smoke_tok_s=tok_s, smoke_tok_s_note="a smoke reading "
+                   "after warm-up, not a benchmark")
+    emit(res)
+    require(n_tokens == steps, f"cli: {n_tokens} tokens, wanted {steps}")
+    require(ledger, "cli: no dispatch ledger line")
+    require("DEGRADED" not in ledger, f"cli: degraded run: {ledger}")
+    if not rehearse:
+        require("q40/pallas-fused" in ledger, f"cli: no pallas-fused: {ledger}")
+        require("q40/xla-dequant" not in ledger,
+                f"cli: xla-dequant at decode width: {ledger}")
+    return dict(res, compile=comp)
+
+
+def _http(method: str, url: str, body: dict | None = None,
+          timeout: float = 300):
+    data = json.dumps(body).encode() if body is not None else None
+    req = urllib.request.Request(url, data=data, method=method, headers={
+        "Content-Type": "application/json"})
+    with urllib.request.urlopen(req, timeout=timeout) as r:
+        return r.status, r.read()
+
+
+def _free_port() -> int:
+    import socket
+    with socket.socket() as s:
+        s.bind(("127.0.0.1", 0))
+        return s.getsockname()[1]
+
+
+def phase_server(mpath: str, tpath: str, timeout: float, tmp: str,
+                 slots: int = 4, ctx: int = 512, page: int = 16,
+                 max_tokens: int = 32, rehearse: bool = False) -> dict:
+    """Child: ``python -m dllama_tpu.server.api`` on the same files with a
+    paged slot scheduler.  Everything the parent starts here (the child,
+    the request threads) is joined before this returns."""
+    port = _free_port()
+    base = f"http://127.0.0.1:{port}"
+    kv_pages = slots * (-(-ctx // page)) + 8
+    args = ["--model", mpath, "--tokenizer", tpath, "--port", str(port),
+            "--host", "127.0.0.1", "--workers", "tpu:1",
+            "--batch-slots", str(slots), "--kv-pages", str(kv_pages),
+            "--kv-page-size", str(page), "--temperature", "0"]
+    out_path = os.path.join(tmp, "server.stdout")
+    deadline = time.monotonic() + timeout
+    log(f"phase server: starting on port {port} (timeout {timeout:.0f}s)")
+    with open(out_path, "w") as out_f:
+        p = subprocess.Popen(_child_cmd("server", *args, rehearse=rehearse),
+                             stdout=out_f, env=_child_env(), cwd=HERE)
+    try:
+        health = None
+        while time.monotonic() < deadline:
+            require(p.poll() is None, f"server: exited {p.returncode} at boot")
+            try:
+                st, body = _http("GET", base + "/health", timeout=5)
+                if st == 200 and json.loads(body).get("ready", True):
+                    health = json.loads(body)
+                    break
+            except OSError:
+                pass
+            time.sleep(1.0)
+        require(health is not None, "server: /health never came up")
+        t_ready = time.monotonic()
+
+        def left():
+            return max(deadline - time.monotonic(), 1)
+
+        greedy = [{"prompt": f"hello {'hi ' * (i + 1)}", "max_tokens":
+                   max_tokens, "temperature": 0} for i in range(slots)]
+        results: list = [None] * (slots + 1)
+
+        def post(i, path, body):
+            try:
+                results[i] = _http("POST", base + path, body, timeout=left())
+            except Exception as e:  # noqa: BLE001 — reported by the checks
+                results[i] = (0, repr(e).encode())
+
+        # the streamed request is sampled (no seed): it is what puts
+        # sample/sample-dev in the ledger; the greedy ones compile argmax
+        chat = {"messages": [{"role": "user", "content": "hi"}],
+                "max_tokens": 24, "temperature": 0.7, "top_p": 0.9,
+                "stream": True}
+        ths = [threading.Thread(target=post, args=(i, "/v1/completions", b))
+               for i, b in enumerate(greedy)]
+        ths.append(threading.Thread(
+            target=post, args=(slots, "/v1/chat/completions", chat)))
+        for th in ths:
+            th.start()
+        for th in ths:
+            th.join()
+        texts = []
+        for i in range(slots):
+            st, body = results[i]
+            require(st == 200, f"server: completion {i} → {st} {body[:200]!r}")
+            texts.append(json.loads(body)["choices"][0]["text"])
+            require(texts[-1], f"server: completion {i} returned empty text")
+        st, body = results[slots]
+        require(st == 200, f"server: streamed chat → {st} {body[:200]!r}")
+        deltas = [json.loads(ln[6:]) for ln in body.decode().splitlines()
+                  if ln.startswith("data: {")]
+        require(not any("error" in d for d in deltas),
+                f"server: streamed chat carried an error: {deltas[-1]}")
+        streamed = "".join(d["choices"][0].get("delta", {}).get("content") or ""
+                           for d in deltas)
+        require(streamed, "server: streamed chat produced no text")
+        require(b"data: [DONE]" in body, "server: stream did not finish")
+        # the same greedy request twice more, one at a time: same bytes
+        t_solo = time.monotonic()
+        again = [json.loads(_http("POST", base + "/v1/completions", greedy[0],
+                                  timeout=left())[1])["choices"][0]["text"]
+                 for _ in range(2)]
+        solo_s = (time.monotonic() - t_solo) / 2  # programs compiled by now
+        require(again[0] == again[1] == texts[0],
+                f"server: greedy replay differs: {texts[0]!r} {again!r}")
+        st, body = _http("GET", base + "/metrics", timeout=30)
+        metrics = json.loads(body)
+        st, body = _http("GET", base + "/health", timeout=30)
+        health2 = json.loads(body)
+        t_served = time.monotonic()
+        p.send_signal(signal.SIGTERM)
+        rc = p.wait(timeout=left())
+    finally:
+        if p.poll() is None:
+            p.kill()
+            p.wait()
+    paths = metrics.get("matmul_dispatch", {})
+    degrades = {k: metrics.get(k) or {} for k in ("q40_degrade", "attn_degrade")}
+    peaks = (health2.get("perf") or {}).get("peaks") or {}
+    res = {"phase": "server", "backend": health.get("backend"),
+           "completions": slots, "streamed_chars": len(streamed),
+           "greedy_replay_identical": True, "dispatch": paths,
+           "degrades": degrades, "peaks_source": peaks.get("source"),
+           "serve_seconds": round(t_served - t_ready, 1), "drain_rc": rc}
+    if not rehearse:  # a CPU timing is never printed as a reading
+        res.update(smoke_solo_request_seconds=round(solo_s, 3),
+                   smoke_solo_tok_s=round(max_tokens / solo_s, 2),
+                   smoke_note="one greedy request alone on the scheduler, "
+                   "a smoke reading, not a benchmark")
+    emit(res)
+    with open(out_path) as f:
+        _, comp = _results(f.read(), "server")
+    require(rc == 0, f"server: exit code {rc} after SIGTERM")
+    require(not any(degrades.values()), f"server: degrades {degrades}")
+    if not rehearse:
+        require(health.get("backend") == "tpu",
+                f"server: backend {health.get('backend')!r}")
+        for fam in ("q40/pallas-fused", "kv_dense/paged-fused",
+                    "sample/sample-dev"):
+            require(paths.get(fam, 0) > 0, f"server: {fam} absent: {paths}")
+        require(peaks.get("source") == "table",
+                f"server: peaks source {peaks.get('source')!r}")
+    return dict(res, compile=comp)
+
+
+def phase_tp(mpath: str, tpath: str, timeout: float, tp: int = 4,
+             rehearse: bool = False) -> dict:
+    """Child (one process owning all chips): greedy decode at tp=N and at
+    tp=1 on the same files; agreement is judged here."""
+    rc, out = run_child("tp", ["--model", mpath, "--tokenizer", tpath,
+                               "--tp", str(tp)], timeout, rehearse)
+    rows, comp = _results(out, "tp")
+    for r in rows:
+        emit(dict(r, phase="tp"))
+    require(rc == 0, f"tp: child exited {rc}; tail: {out[-600:]!r}")
+    dev = next((r for r in rows if r.get("what") == "device"), None)
+    cmp_ = next((r for r in rows if r.get("what") == "compare"), None)
+    require(dev and cmp_, "tp: child reported no device or no comparison")
+    require(cmp_["logits_rel_err"] <= TP_LOGIT_TOL,
+            f"tp: first-step logits differ by {cmp_['logits_rel_err']}")
+    div = cmp_["first_divergence"]
+    require(div is None or cmp_["divergence_within_tol"],
+            f"tp: greedy streams diverge at {div} beyond tolerance: {cmp_}")
+    require(cmp_["weight_devices"] == tp and cmp_["cache_devices"] == tp,
+            f"tp: state not spread over {tp} devices: {cmp_}")
+    if not rehearse:  # off-TPU the psum reduce is a recorded degrade
+        require("DEGRADED" not in cmp_["ledger_tp"],
+                f"tp: degraded: {cmp_['ledger_tp']}")
+        require(dev["platform"] == "tpu", f"tp: ran on {dev['platform']}")
+        require(dev["count"] >= tp, f"tp: only {dev['count']} devices")
+        require("q40/pallas-fused" in cmp_["ledger_tp"],
+                f"tp: no pallas-fused at tp={tp}: {cmp_['ledger_tp']}")
+        require(cmp_["reduce"] in ("tp_fused_reduce", "tp_psum"),
+                f"tp: no reduce path recorded: {cmp_}")
+    return dict(dev, compile=comp)
+
+
+# ---------------------------------------------------------------------------
+# Child side: each runs in its own process and owns the chip
+# ---------------------------------------------------------------------------
+
+def _say(obj: dict) -> None:
+    print(CHILD_MARK + json.dumps(obj), flush=True)
+
+
+def _report_compiles_at_exit() -> None:
+    """Sum the seconds this process spends in XLA's backend compile (a
+    persistent-cache hit costs only its read) and say so when it exits."""
+    import atexit
+
+    from jax import monitoring
+    tot = {"what": "compile", "backend_compile_seconds": 0.0, "programs": 0,
+           "persistent_cache_hits": 0}
+
+    def on_duration(event, secs, **_):
+        if event == "/jax/core/compile/backend_compile_duration":
+            tot["backend_compile_seconds"] += secs
+            tot["programs"] += 1
+
+    def on_event(event, **_):
+        if event == "/jax/compilation_cache/cache_hits":
+            tot["persistent_cache_hits"] += 1
+
+    monitoring.register_event_duration_secs_listener(on_duration)
+    monitoring.register_event_listener(on_event)
+    atexit.register(lambda: _say(dict(
+        tot, backend_compile_seconds=round(tot["backend_compile_seconds"], 2))))
+
+
+def _claim_device(rehearse: bool) -> dict:
+    """A child's first act: the platform check."""
+    from dllama_tpu.hostenv import configure_compile_cache
+    cache = configure_compile_cache()
+    import jax
+    d = jax.devices()[0]
+    if d.platform != "tpu" and not rehearse:
+        raise SystemExit(f"chip_smoke child: JAX found no TPU "
+                         f"(platform {d.platform!r})")
+    _report_compiles_at_exit()
+    return {"what": "device", "platform": d.platform, "kind": d.device_kind,
+            "count": len(jax.devices()), "compile_cache": cache}
+
+
+def child_kernels(rehearse: bool) -> None:
+    dev = _claim_device(rehearse)
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from dllama_tpu import native
+    from dllama_tpu.obs import cost, dispatch as obs_dispatch
+    from dllama_tpu.ops import attention as att, q40
+    from dllama_tpu.synth import model_cfg
+
+    cost.set_backend(dev["kind"], dev["platform"])
+    _say(dict(dev, peaks_source=cost.peaks()["source"],
+              native_loader=native.have_native()))
+    cfg = model_cfg("cpu-tiny" if rehearse else MODEL)
+    pallas = "pallas_interpret" if rehearse else "pallas"
+    D, H, V = cfg.dim, cfg.hidden_dim, cfg.vocab_size
+    qkv = (cfg.n_heads + 2 * cfg.n_kv_heads) * cfg.head_size
+    # (name, n, d, stacked) — as the single-chip loader lays the model out
+    shapes = [("wqkv", D, qkv, True), ("wo", D, D, True),
+              ("w13", D, 2 * H, True), ("w2", H, D, True),
+              ("wcls", D, V, False)]
+    key = jax.random.PRNGKey(0)
+
+    def rel_err(a_, b_):
+        a_, b_ = np.asarray(a_, np.float32), np.asarray(b_, np.float32)
+        require(np.isfinite(a_).all() and np.isfinite(b_).all(),
+                "non-finite kernel output")
+        return float(np.abs(a_ - b_).max() / max(np.abs(b_).max(), 1e-9))
+
+    for name, n, d, stacked in shapes:
+        np_ = q40.padded_n(n)
+        lead = (2,) if stacked else ()
+        key, k1, k2, k3 = jax.random.split(key, 4)
+        qp = jax.random.bits(k1, (*lead, np_ // 2, d), jnp.uint8)
+        sc = (0.004 + 0.008 * jax.random.uniform(k2, (*lead, np_ // 32, d))
+              ).astype(jnp.float16)
+        sc = sc * (jnp.arange(np_ // 32)[:, None] < n // 32)  # zero pad rows
+        qt = q40.QTensor(qp, jax.lax.bitcast_convert_type(sc, jnp.uint16),
+                         (n, d))
+        w = q40.QLayerView(qt, jnp.int32(1)) if stacked else qt
+        for rows in (1, 8):
+            x = jax.random.normal(jax.random.fold_in(k3, rows), (rows, n),
+                                  jnp.bfloat16)
+            t0 = time.perf_counter()
+            got = q40.matmul(x, w, impl=pallas, out_dtype=jnp.float32)
+            ref = q40.matmul(x, w, impl="xla", out_dtype=jnp.float32)
+            _say({"kernel": f"q40.{name}", "shape": [n, d], "rows": rows,
+                  "stacked": stacked, "rel_err": rel_err(got, ref),
+                  "tol": Q40_TOL,
+                  "seconds": round(time.perf_counter() - t0, 2)})
+
+    # the auto choice inside a jit trace must be the Pallas kernel on a TPU
+    # (w, x: the last pair of the loop above — wcls, 8 rows)
+    obs_dispatch.reset()
+    jax.block_until_ready(jax.jit(
+        lambda v: q40.matmul(v, w, impl="auto"))(x))
+    _say({"what": "auto_in_jit", "ledger": obs_dispatch.summary_line()})
+    if not rehearse:
+        require("q40/pallas-fused" in obs_dispatch.summary_line()
+                and "DEGRADED" not in obs_dispatch.summary_line(),
+                f"auto in jit: {obs_dispatch.summary_line()}")
+
+    hq, hkv, dh, ps = cfg.n_heads, cfg.n_kv_heads, cfg.head_size, 16
+    b, maxp = 4, cfg.seq_len // ps
+    n_pages = 1 + b * maxp
+    rng = np.random.RandomState(0)
+    table = jnp.asarray(rng.permutation(np.arange(1, n_pages)).reshape(
+        b, maxp).astype(np.int32))
+    pos = jnp.asarray([maxp * ps - 1, 2 * ps + 5, (maxp // 2) * ps, 0],
+                      jnp.int32)  # ragged rows, incl. a full and a 1-token row
+    key, kq, kk, kv = jax.random.split(key, 4)
+    q = (jax.random.normal(kq, (b, hq, 1, dh)) * 0.5).astype(cfg.dtype)
+    pool_shape = (2, n_pages, hkv, ps, dh)
+    pk = jax.random.normal(kk, pool_shape, jnp.float32) * 0.5
+    pv = jax.random.normal(kv, pool_shape, jnp.float32) * 0.5
+    layer = jnp.int32(1)
+    for quantized in (False, True):
+        if quantized:
+            (k_, sk), (v_, sv) = att.quantize_kv(pk), att.quantize_kv(pv)
+            scales = (sk, sv)
+        else:
+            k_, v_, scales = pk.astype(cfg.dtype), pv.astype(cfg.dtype), None
+        t0 = time.perf_counter()
+        got = att.fused_paged_attention(q, k_, v_, layer, table, pos,
+                                        scales=scales, interpret=rehearse)
+        ks, vs = scales if quantized else (None, None)
+        ref = att._rows_ceiling_attention(
+            q, att.paged_gather_layer(k_, layer, table, scale_pool=ks),
+            att.paged_gather_layer(v_, layer, table, scale_pool=vs), pos)
+        _say({"kernel": "fused_paged_attention",
+              "kv": "int8" if quantized else "dense",
+              "geometry": {"hq": hq, "hkv": hkv, "dh": dh, "page": ps,
+                           "rows": b, "max_pages": maxp},
+              "rel_err": rel_err(got, ref), "tol": ATTN_TOL,
+              "seconds": round(time.perf_counter() - t0, 2)})
+
+
+def child_module(module: str, argv: list[str], rehearse: bool) -> None:
+    """Platform check, then the user's entry point as ``__main__``."""
+    _claim_device(rehearse)
+    import runpy
+    sys.argv = [module, *argv]
+    runpy.run_module(module, run_name="__main__", alter_sys=True)
+
+
+def child_tp(argv: list[str], rehearse: bool) -> None:
+    ap = argparse.ArgumentParser()
+    ap.add_argument("--model", required=True)
+    ap.add_argument("--tokenizer", required=True)
+    ap.add_argument("--tp", type=int, default=4)
+    a = ap.parse_args(argv)
+    dev = _claim_device(rehearse)
+    _say(dev)
+    import numpy as np
+
+    from dllama_tpu import cli
+    from dllama_tpu.obs import dispatch as obs_dispatch
+
+    steps = 16
+
+    def run(tp: int):
+        obs_dispatch.reset()
+        args = cli.build_parser().parse_args(
+            ["inference", "--model", a.model, "--tokenizer", a.tokenizer,
+             "--workers", f"tpu:{tp}", "--temperature", "0"])
+        engine, tok = cli.load_stack(args)
+        ids = tok.encode("hello hello hello", add_bos=engine.cfg.add_bos)
+        logits, _ = engine.prefill(ids)
+        for _ in range(2):  # the second pass is timed with programs compiled
+            engine.reset()
+            t0 = time.perf_counter()
+            toks = [t for t, _ in engine.generate_stream(
+                ids, len(ids) + steps, temperature=0.0, topp=0.9, seed=0,
+                chunk=steps)][len(ids):]
+            secs = time.perf_counter() - t0
+        wname = "wq" if "wq" in engine.params else "wqkv"
+        leaf = engine.params[wname].qpacked
+        spread = (len(leaf.sharding.device_set),
+                  len(engine.cache.k.sharding.device_set),
+                  [int(s) for s in leaf.addressable_shards[0].data.shape])
+        return engine, ids, np.asarray(logits, np.float32)[0], toks, spread, \
+            secs, obs_dispatch.summary_line()
+
+    eng, ids, lg_tp, toks_tp, spread, secs_tp, ledger_tp = run(a.tp)
+    reduce = ("tp_fused_reduce" if "tp_fused_reduce" in ledger_tp else
+              "tp_psum" if "tp_psum" in ledger_tp else None)
+    del eng
+    eng1, _, lg_1, toks_1, _, secs_1, ledger_1 = run(1)
+    rel = float(np.abs(lg_tp - lg_1).max() / max(np.abs(lg_1).max(), 1e-9))
+    div = next((i for i, (x, y) in enumerate(zip(toks_tp, toks_1)) if x != y),
+               None)
+    margin = within = None
+    if div is not None:
+        # the logit margin at the step where the streams part: a split
+        # inside the numeric noise between the two reduction orders is a
+        # tie-break, not a fault
+        eng1.reset()
+        lg, _ = eng1.prefill(list(ids) + toks_1[:div])
+        lg = np.asarray(lg, np.float32)[0]
+        margin = float(abs(lg[toks_1[div]] - lg[toks_tp[div]])
+                       / max(np.abs(lg).max(), 1e-9))
+        within = margin <= TP_LOGIT_TOL
+    _say({"what": "compare", "tp": a.tp, "logits_rel_err": rel,
+          "logits_tol": TP_LOGIT_TOL, "tokens_tp": toks_tp, "tokens_tp1": toks_1,
+          "first_divergence": div, "divergence_margin": margin,
+          "divergence_within_tol": within, "weight_devices": spread[0],
+          "cache_devices": spread[1], "weight_shard_shape": spread[2],
+          "reduce": reduce, "ledger_tp": ledger_tp, "ledger_tp1": ledger_1,
+          "smoke_decode_seconds_tp": round(secs_tp, 3),
+          "smoke_decode_seconds_tp1": round(secs_1, 3), "decode_tokens": steps})
+
+
+# ---------------------------------------------------------------------------
+
+def main(argv: list[str] | None = None) -> int:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--chips", type=int, choices=(1, 4), default=1,
+                    help="4: only the tp=4 path and the tp=1 run it is "
+                         "compared with (needs four chips)")
+    a = ap.parse_args(argv)
+    t_start = time.monotonic()
+
+    def left(reserve: float = 20) -> float:
+        return BUDGET_S - (time.monotonic() - t_start) - reserve
+
+    if not os.path.isdir(os.path.join(HERE, "dllama_tpu")):
+        log("the dllama_tpu package is not next to this script")
+        return 2
+    sys.path.insert(0, HERE)
+    from dllama_tpu.hostenv import compile_cache_dir  # no JAX in there
+    from dllama_tpu.synth import synth_model_files
+
+    cache = compile_cache_dir()
+    n_before = cache_entries(cache)
+    emit({"what": "setup", "model": MODEL, "chips": a.chips,
+          "depth": "full (32 layers), no cut", "compile_cache": cache,
+          "cache_entries_before": n_before,
+          "cache": "warm" if n_before else "cold"})
+    # model files live outside the checkout (a 4 GB file in the tree can
+    # make it too large to copy) and are removed at the end
+    tmp = tempfile.mkdtemp(prefix="dllama_smoke_")
+    device = None
+    compiles = []  # one row per child that held the chip
+
+    def timed(name, fn, *args):
+        t0 = time.monotonic()
+        out = fn(*args)
+        if isinstance(out, dict) and out.get("compile"):
+            compiles.append(out["compile"])
+        emit({"what": "phase_done", "phase": name,
+              "seconds": round(time.monotonic() - t0, 1)})
+        return out
+
+    try:
+        if a.chips == 1:
+            device = timed("kernels", phase_kernels, min(420, left()))
+        mpath, tpath = timed("synth", synth_model_files, MODEL, tmp)
+        emit({"what": "model", "bytes": os.path.getsize(mpath)})
+        require("jax" not in sys.modules, "the parent imported JAX")
+        if a.chips == 1:
+            timed("cli", phase_cli, mpath, tpath, min(600, left()))
+            timed("server", phase_server, mpath, tpath, min(600, left()), tmp)
+        else:
+            device = timed("tp", phase_tp, mpath, tpath, min(420, left()), 4)
+        require(device["platform"] == "tpu", "no TPU held the run")
+        require(device["count"] >= a.chips,
+                f"{device['count']} chips, wanted {a.chips}")
+        device = dict(device, count=a.chips)
+    except PhaseFailed as e:
+        log(f"FAILED: {e}")
+        emit({"what": "failed", "error": str(e)})
+        return 1
+    finally:
+        shutil.rmtree(tmp, ignore_errors=True)
+    emit({"what": "done", "cache_entries_after": cache_entries(cache),
+          "cache_entries_before": n_before,
+          "backend_compile_seconds": round(sum(
+              c["backend_compile_seconds"] for c in compiles), 2),
+          "persistent_cache_hits": sum(
+              c["persistent_cache_hits"] for c in compiles),
+          "wall_seconds": round(time.monotonic() - t_start, 1)})
+    print(last_line(device), flush=True)
+    return 0
+
+
+if __name__ == "__main__":
+    if len(sys.argv) > 2 and sys.argv[1] == "--phase":
+        phase, rest = sys.argv[2], sys.argv[3:]
+        rehearse = rest[:1] == ["--rehearse"]
+        rest = rest[1:] if rehearse else rest
+        if phase == "kernels":
+            child_kernels(rehearse)
+        elif phase == "cli":
+            child_module("dllama_tpu", rest, rehearse)
+        elif phase == "server":
+            child_module("dllama_tpu.server.api", rest, rehearse)
+        elif phase == "tp":
+            child_tp(rest, rehearse)
+        else:
+            raise SystemExit(f"unknown phase {phase!r}")
+    else:
+        sys.exit(main())

@@ -443,13 +443,13 @@ def model_from_engine(engine) -> CostModel | None:
         if engine.paged:
             try:
                 # ask the attention ladder what the decode trace will
-                # actually pick for this geometry (probe is cached), so
-                # cost families track the ledger path
+                # actually pick for this geometry, so cost families
+                # track the ledger path
                 from ..ops import attention as _attn
-                fused, _ = _attn._fused_choice(
-                    1, cfg.n_heads, cfg.n_kv_heads,
-                    int(getattr(engine, "kv_page_size", 0) or 0),
-                    cfg.dim // cfg.n_heads, kv_codec == "kv_int8")
+                from ..parallel.mesh import active_mesh
+                with active_mesh(engine.mesh):
+                    fused, _ = _attn._fused_choice(
+                        1, cfg.n_heads, cfg.n_kv_heads)
             except Exception:
                 fused = False
         return CostModel(

@@ -133,7 +133,7 @@ class Gauge:
 
 class LabeledCounter:
     """A counter family: one Prometheus metric name, one sample per label
-    value (``dllama_q40_degrade_total{reason="probe_failed"} 2``).  The
+    value (``dllama_q40_degrade_total{reason="unshardable"} 2``).  The
     JSON exposition is a dict keyed by the label value (multi-label
     children join their values with ``/``).  Children are created on
     first increment — a scrape between registration and the first event

@@ -14,7 +14,6 @@ and is the axis sharded across the tensor-parallel mesh.
 
 from __future__ import annotations
 
-import functools
 import math
 import os
 
@@ -22,6 +21,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..parallel.mesh import get_active_mesh
 from .kernels import softmax_f32
 
 
@@ -300,9 +300,9 @@ def paged_decode_attention(q: jax.Array, pool_k: jax.Array, pool_v: jax.Array,
 # int8 pools (the per-position scale plane rides as a second prefetched
 # block and the cast*scale happens in-register), and the online-softmax
 # accumulators live in VMEM scratch across the page walk.  Gating mirrors
-# the q40 matmul ladder: ``DLLAMA_FUSED_ATTN`` auto/on/off/interp, a
-# cached hardware probe guards auto, and every forced-path fallback goes
-# through the warn-once degrade ledger (obs/dispatch.py).
+# the q40 matmul ladder: ``DLLAMA_FUSED_ATTN`` auto/on/off/interp, auto is
+# a static choice (platform, mesh, shape), and every forced-path fallback
+# goes through the warn-once degrade ledger (obs/dispatch.py).
 
 
 _FUSED_ENV = "DLLAMA_FUSED_ATTN"
@@ -310,7 +310,7 @@ _FUSED_ENV = "DLLAMA_FUSED_ATTN"
 
 def fused_mode() -> str:
     """The fused paged-attention gate, read lazily so tests and the
-    bench A/B can flip it per engine: ``auto`` (TPU + probe, silent CPU
+    bench A/B can flip it per engine: ``auto`` (single TPU device, silent CPU
     fallback), ``on`` (degrade loudly if unusable), ``off``, ``interp``
     (force the kernel in Pallas interpret mode — CPU parity tests and
     the ``-fused4`` A/B)."""
@@ -405,8 +405,6 @@ def fused_paged_attention(q: jax.Array, pool_k: jax.Array, pool_v: jax.Array,
     from jax.experimental import pallas as plx
     from jax.experimental.pallas import tpu as pltpu
 
-    from . import pallas_compat
-
     b, hq, t, dh = q.shape
     if t != 1:
         raise ValueError("fused paged attention is decode-only (T must be 1)")
@@ -433,7 +431,7 @@ def fused_paged_attention(q: jax.Array, pool_k: jax.Array, pool_v: jax.Array,
     kernel = _make_fused_kernel(hq, hkv, dh, ps, maxp, quantized, q.dtype)
     out = plx.pallas_call(
         kernel,
-        grid_spec=pallas_compat.prefetch_grid_spec(
+        grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
             grid=(b, maxp),
             in_specs=in_specs,
@@ -442,7 +440,7 @@ def fused_paged_attention(q: jax.Array, pool_k: jax.Array, pool_v: jax.Array,
                             pltpu.VMEM((hq, 128), jnp.float32),
                             pltpu.VMEM((hq, dh), jnp.float32)]),
         out_shape=jax.ShapeDtypeStruct((b, hq, dh), q.dtype),
-        compiler_params=pallas_compat.compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")),
         interpret=interpret,
     )(jnp.atleast_1d(layer).astype(jnp.int32),
@@ -450,79 +448,35 @@ def fused_paged_attention(q: jax.Array, pool_k: jax.Array, pool_v: jax.Array,
     return out[:, :, None, :]
 
 
-@functools.cache
-def _fused_ok(hkv: int, g: int, ps: int, dh: int, quantized: bool) -> bool:
-    """Hardware probe: can Mosaic lower + run the fused paged kernel at
-    this (head, page) geometry?  Guards the ``auto``/``on`` ladder so a
-    lowering failure (tiny page lane widths, odd head dims) degrades to
-    the gather+score path with a warn-once ledger entry instead of
-    crashing decode.  The fixture is RANDOM (fixed seed) with ragged row
-    positions, so a walk-order or mask bug fails the value check rather
-    than shipping wrong numerics (same contract as q40._pallas_ok)."""
-    try:
-        b, maxp = 2, 3
-        npages = 1 + b * maxp
-        rng = np.random.RandomState(0)
-        table = np.arange(1, npages).reshape(b, maxp).astype(np.int32)
-        pos_rows = jnp.asarray([maxp * ps - 1, ps + ps // 2], jnp.int32)
-        q = jnp.asarray(rng.randn(b, hkv * g, 1, dh) * 0.3, jnp.float32)
-        if quantized:
-            # quantize_kv reduces over the last axis, so it quantizes the
-            # pool layout (1, P, Hkv, ps, Dh) directly → scale (…, ps, 1)
-            pk, sk = quantize_kv(jnp.asarray(
-                rng.randn(1, npages, hkv, ps, dh), jnp.float32))
-            pv, sv = quantize_kv(jnp.asarray(
-                rng.randn(1, npages, hkv, ps, dh), jnp.float32))
-            ref_scales = (sk, sv)
-        else:
-            pk = jnp.asarray(rng.randn(1, npages, hkv, ps, dh) * 0.3,
-                             jnp.bfloat16)
-            pv = jnp.asarray(rng.randn(1, npages, hkv, ps, dh) * 0.3,
-                             jnp.bfloat16)
-            ref_scales = None
-        layer = jnp.int32(0)
-        tbl = jnp.asarray(table)
-        out = fused_paged_attention(
-            q, pk, pv, layer, tbl, pos_rows,
-            scales=(sk, sv) if quantized else None)
-        ksc, vsc = (ref_scales if quantized else (None, None))
-        k_l = paged_gather_layer(pk, layer, tbl, scale_pool=ksc)
-        v_l = paged_gather_layer(pv, layer, tbl, scale_pool=vsc)
-        ref = _rows_ceiling_attention(q, k_l, v_l, pos_rows)
-        tol = 1e-2 * max(float(np.abs(np.asarray(ref)).max()), 1e-3)
-        if not np.allclose(np.asarray(out), np.asarray(ref), atol=tol):
-            raise AssertionError("fused attention probe result mismatch")
-        return True
-    except Exception as e:  # Mosaic lowering/runtime failure
-        from ..obs import dispatch as obs_dispatch
-        obs_dispatch.record_degrade(
-            "attn", "probe_failed", warn_key=(hkv, g, ps, dh, quantized),
-            hkv=hkv, g=g, page_size=ps, dh=dh, quantized=quantized,
-            error=f"{type(e).__name__}: {str(e)[:120]}")
-        return False
-
-
-def _fused_choice(t: int, hq: int, hkv: int, ps: int, dh: int,
-                  quantized: bool) -> tuple[bool, bool]:
-    """Resolve the fused-vs-fallback decision for one trace-time call
-    site.  Returns ``(use_fused, interpret)``.  Mirrors the q40 ladder:
-    ``auto`` off-TPU falls back silently (the clean-run ledger contract);
-    ``on`` off-TPU and any probe failure degrade loudly (warn-once)."""
+def _fused_choice(t: int, hq: int, hkv: int) -> tuple[bool, bool]:
+    """Resolve the fused-vs-fallback decision for one call site from
+    static facts only (mode, platform, mesh, head counts), so it is the same
+    inside and outside a jit trace.  Returns ``(use_fused, interpret)``.
+    On a single TPU device ``auto``/``on`` mean the fused kernel; nothing
+    is executed to decide, so a Mosaic lowering or runtime error
+    propagates and fails the run (values are checked on the chip by
+    chip_smoke.py).  Every KV/q/scale block spans the full last two dims
+    of its array, so no page size, head size or codec is tile-illegal.  A
+    ``pallas_call`` is not partitioned by GSPMD, so on a multi-device
+    mesh the TPU path stays the gather form.  ``auto`` off-TPU falls
+    back silently (the clean-run ledger contract); ``on`` where the
+    kernel cannot run degrades loudly (warn-once)."""
     mode = fused_mode()
     if mode == "off" or t != 1 or hq % hkv != 0:
         return False, False
     if mode == "interp":
         return True, True
-    on_tpu = jax.default_backend() == "tpu"
-    if mode == "on" and not on_tpu:
+    backend = jax.default_backend()
+    mesh = get_active_mesh()
+    n_dev = mesh.size if mesh is not None else 1
+    if backend == "tpu" and n_dev == 1:
+        return True, False
+    if mode == "on":
         from ..obs import dispatch as obs_dispatch
         obs_dispatch.record_degrade(
-            "attn", "fused_needs_tpu", warn_key=jax.default_backend(),
-            backend=jax.default_backend())
-        return False, False
-    if not on_tpu:  # auto on CPU: silent XLA fallback, same as q40
-        return False, False
-    return _fused_ok(hkv, hq // hkv, ps, dh, quantized), False
+            "attn", "fused_needs_tpu", warn_key=(backend, n_dev),
+            backend=backend, mesh_size=n_dev)
+    return False, False
 
 
 def paged_gqa_attention_at(q: jax.Array, pool_k: jax.Array, pool_v: jax.Array,
@@ -554,8 +508,7 @@ def paged_gqa_attention_at(q: jax.Array, pool_k: jax.Array, pool_v: jax.Array,
     ps = pool_k.shape[3]
     s = page_table.shape[1] * ps
     codec = "kv_int8" if scales is not None else "kv_dense"
-    use_fused, interp = _fused_choice(t, q.shape[1], pool_k.shape[2], ps,
-                                      pool_k.shape[4], scales is not None)
+    use_fused, interp = _fused_choice(t, q.shape[1], pool_k.shape[2])
     if use_fused:
         obs_dispatch.record_dispatch(codec, "paged-fused", t=t, s=s,
                                      page_size=ps, interpret=interp)

@@ -39,10 +39,9 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from . import pallas_compat
 from .. import quants
 from .q40 import (PALLAS_MAX_ROWS, QLayerView, _f16_bits_to_f32, _pad_x,
-                  _smap_mesh, _tiles, padded_n)
+                  _smap_mesh, _tile_n_legal, _tiles, padded_n)
 
 # Width-rule VMEM ceiling for THIS codec: the q8 kernel carries an f32
 # accumulator intermediate of tn*td*4 B on top of the int8 value tile, so
@@ -222,13 +221,12 @@ def _stacked_q8_kernel(lidx_ref, x_ref, qv_ref, s_ref, o_ref, acc_ref, *, nsteps
     _q8_kernel(x_ref, qv_ref, s_ref, o_ref, acc_ref, nsteps=nsteps)
 
 
-@functools.partial(jax.jit, static_argnames=("interpret", "tiles"))
+@functools.partial(jax.jit, static_argnames=("interpret",))
 def _pallas_matmul(x: jax.Array, qv: jax.Array, s: jax.Array,
-                   interpret: bool = False,
-                   tiles: tuple[int, int] | None = None) -> jax.Array:
+                   interpret: bool = False) -> jax.Array:
     t, n = x.shape
     d = qv.shape[-1]
-    tile_n, tile_d = tiles or _tiles(n, d, cap_elems=Q8_TILE_CAP)
+    tile_n, tile_d = _tiles(n, d, cap_elems=Q8_TILE_CAP)
     grid = (pl.cdiv(d, tile_d), n // tile_n)
     return pl.pallas_call(
         functools.partial(_q8_kernel, nsteps=grid[1]),
@@ -243,7 +241,7 @@ def _pallas_matmul(x: jax.Array, qv: jax.Array, s: jax.Array,
                                memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct((t, d), jnp.float32),
         scratch_shapes=[pltpu.VMEM((t, tile_d), jnp.float32)],
-        compiler_params=pallas_compat.compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(x.astype(jnp.bfloat16), qv, s)
@@ -272,34 +270,10 @@ def _pallas_matmul_stacked(x: jax.Array, qv: jax.Array, s: jax.Array,
             scratch_shapes=[pltpu.VMEM((t, tile_d), jnp.float32)],
         ),
         out_shape=jax.ShapeDtypeStruct((t, d), jnp.float32),
-        compiler_params=pallas_compat.compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(layer.reshape(1).astype(jnp.int32), x.astype(jnp.bfloat16), qv, s)
-
-
-@functools.cache
-def _pallas_ok(tile_n: int, tile_d: int, t: int) -> bool:
-    """Hardware probe for the Q80 kernel (random fixture — q40._pallas_ok
-    rationale applies: layout bugs must not hide behind constant blocks)."""
-    try:
-        n = 2 * tile_n
-        rng = np.random.RandomState(0)
-        qt = quantize((rng.randn(n, tile_d) * 0.1).astype(np.float32))
-        x = jnp.asarray(rng.randn(t, n).astype(np.float32), jnp.bfloat16)
-        out = _pallas_matmul(x, qt.qpacked, qt.scales, tiles=(tile_n, tile_d))
-        ref = x @ dequantize(qt, jnp.bfloat16)
-        if not np.allclose(np.asarray(out), np.asarray(ref),
-                           atol=1e-2 * float(np.abs(np.asarray(ref)).max())):
-            raise AssertionError("q8 pallas probe result mismatch")
-        return True
-    except Exception as e:
-        from ..obs import dispatch as obs_dispatch
-        obs_dispatch.record_degrade(
-            "q8", "probe_failed", warn_key=(tile_n, tile_d, t),
-            tile_n=tile_n, tile_d=tile_d, t=t,
-            error=f"{type(e).__name__}: {str(e)[:120]}")
-        return False
 
 
 # ---------------------------------------------------------------------------
@@ -310,7 +284,7 @@ def matmul(x: jax.Array, qt: Q8Tensor | QLayerView, impl: str = "auto",
            out_dtype=None, kind: str | None = None) -> jax.Array:
     """``x @ dequantize(qt)`` with f32 accumulation (Q80 weights).
 
-    Single-device: fused Pallas kernel (probe-guarded).  On a multi-device
+    Single-device: fused Pallas kernel.  On a multi-device
     mesh or off-TPU: the GSPMD-partitionable XLA emulation (see module
     docstring) — ``kind`` is accepted for call-site symmetry with q40.mm
     but only the XLA path runs there, so it is unused.
@@ -325,11 +299,12 @@ def matmul(x: jax.Array, qt: Q8Tensor | QLayerView, impl: str = "auto",
     if impl == "auto":
         on_tpu = jax.default_backend() == "tpu"
         np_ = (qt.qt if is_view else qt).qpacked.shape[-2]
-        tile_n, tile_d = _tiles(np_, d, cap_elems=Q8_TILE_CAP)
+        # static rule only (q40._auto_pallas rationale): a lowering or
+        # runtime failure of the chosen kernel raises
         impl = "pallas" if (on_tpu and rows <= PALLAS_MAX_ROWS
                             and _smap_mesh() is None
-                            and _pallas_ok(tile_n, tile_d,
-                                           1 if rows == 1 else PALLAS_MAX_ROWS)) \
+                            and _tile_n_legal(
+                                np_, _tiles(np_, d, cap_elems=Q8_TILE_CAP)[0])) \
             else "xla"
 
     from ..obs import dispatch as obs_dispatch
@@ -357,7 +332,7 @@ def matmul(x: jax.Array, qt: Q8Tensor | QLayerView, impl: str = "auto",
         obs_dispatch.record_degrade(
             "q8", "mesh_xla", warn_key=qt.logical_nd,
             shape=qt.logical_nd, impl=impl)
-    # XLA path (meshes, CPU, probe failure)
+    # XLA path (meshes, CPU, prefill row counts)
     obs_dispatch.record_dispatch("q8", "xla-dequant", rows=rows)
     base = qt.sliced() if is_view else qt
     w = dequantize(base, dtype=jnp.bfloat16)

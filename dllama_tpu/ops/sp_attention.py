@@ -32,7 +32,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from ..parallel.mesh import shard_map
 from .attention import _use_blocked_decode, blocked_live_fold
 
 NEG_BIG = -1e30  # stand-in for -inf that keeps exp() NaN-free on empty shards
@@ -81,7 +80,7 @@ def sp_update_kv_cache_at(k_cache: jax.Array, v_cache: jax.Array,
 
         return write(kc, kn), write(vc, vn)
 
-    return shard_map(
+    return jax.shard_map(
         shard_fn, mesh=mesh,
         in_specs=(kv_spec, kv_spec, new_spec, new_spec),
         out_specs=(kv_spec, kv_spec))(k_cache, v_cache, k_new, v_new)
@@ -89,12 +88,8 @@ def sp_update_kv_cache_at(k_cache: jax.Array, v_cache: jax.Array,
 
 def _varying(x):
     """Mark a freshly-created accumulator as device-varying over the mesh
-    (shard_map branch/carry types must match the computed side).  Older
-    jax has no varying-manual-axes typing (and no ``jax.lax.pcast``), so
-    there the value is already fine as-is."""
-    if hasattr(jax.lax, "pcast"):
-        return jax.lax.pcast(x, ("dp", "sp", "tp"), to="varying")
-    return x
+    (shard_map branch/carry types must match the computed side)."""
+    return jax.lax.pcast(x, ("dp", "sp", "tp"), to="varying")
 
 
 def _empty_partials(shape, dh):
@@ -228,7 +223,7 @@ def ring_attention(q: jax.Array, k: jax.Array, v: jax.Array, mesh,
         out = out / jnp.maximum(lsum[..., None], 1e-38)
         return out.reshape(q.shape[0], hq_l, t_local, dh).astype(q.dtype)
 
-    return shard_map(
+    return jax.shard_map(
         shard_fn, mesh=mesh, in_specs=(q_spec, kv_spec, kv_spec),
         out_specs=q_spec)(q, k, v)
 
@@ -295,7 +290,7 @@ def sp_gqa_attention(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
         out = out / jnp.maximum(denom[..., None], 1e-38)
         return out.reshape(q.shape[0], hq_l, t, dh).astype(q.dtype)
 
-    return shard_map(
+    return jax.shard_map(
         shard_fn, mesh=mesh,
         in_specs=(q_spec, kv_spec, kv_spec),
         out_specs=q_spec,

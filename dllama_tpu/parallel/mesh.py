@@ -48,25 +48,6 @@ def get_active_mesh() -> Mesh | None:
     return _ACTIVE[-1] if _ACTIVE else None
 
 
-def shard_map(f, *, mesh: Mesh, in_specs, out_specs, check_vma: bool = True):
-    """``jax.shard_map`` across jax versions.
-
-    New jax exports shard_map at top level with a ``check_vma`` kwarg;
-    older jax only has ``jax.experimental.shard_map`` where the same
-    knob is spelled ``check_rep``.  Every shard_map in this package goes
-    through here so kernels don't carry per-call-site version checks.
-    """
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    # check_rep (the old spelling) has no replication rule for while/cond
-    # bodies our attention kernels use, so the old branch always runs
-    # unchecked — the new-jax path keeps the check where it works.
-    return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, check_rep=False)
-
-
 def make_mesh(tp: int | None = None, sp: int = 1, dp: int = 1, ep: int = 1,
               devices=None) -> Mesh:
     """Build a (dp, sp, ep, tp) mesh; tp defaults to all remaining devices.

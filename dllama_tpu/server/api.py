@@ -2602,6 +2602,8 @@ def main(argv=None):
     import sys
 
     from ..cli import build_parser, load_draft_engine, load_stack
+    from ..hostenv import configure_compile_cache
+    configure_compile_cache()
     argv = list(sys.argv[1:] if argv is None else argv)
     # reuse the dllama flag surface; the server has no positional mode
     args = build_parser().parse_args(["inference", *argv])

@@ -11,17 +11,19 @@ import sys
 import numpy as np
 
 from dllama_tpu import quants
-from dllama_tpu.io import mfile, tfile
+from dllama_tpu.io import mfile
+from dllama_tpu.synth import write_synth_tokenizer as write_tiny_tokenizer  # noqa: F401
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
-CHATML_JINJA = "{% for message in messages %}<|im_start|>...jinja...{% endfor %}"
 
 
 def write_tiny_model(path, *, arch=mfile.ARCH_LLAMA, ftype=quants.Q80,
-                     vocab_size=300, n_experts=0, seq_len=128, seed=0) -> mfile.ModelSpec:
+                     vocab_size=300, n_experts=0, seq_len=128, seed=0,
+                     dim=64, hidden_dim=96, n_kv_heads=2) -> mfile.ModelSpec:
     spec = mfile.ModelSpec(
-        arch=arch, dim=64, hidden_dim=96, n_layers=2, n_heads=4, n_kv_heads=2,
+        arch=arch, dim=dim, hidden_dim=hidden_dim, n_layers=2, n_heads=4,
+        n_kv_heads=n_kv_heads,
         n_experts=n_experts, n_active_experts=2 if n_experts else 0,
         vocab_size=vocab_size, seq_len=seq_len, hidden_act=mfile.ACT_SILU,
         rope_theta=10000.0, weights_ftype=ftype)
@@ -30,29 +32,6 @@ def write_tiny_model(path, *, arch=mfile.ARCH_LLAMA, ftype=quants.Q80,
         for t in w.plan:
             w.write_tensor(t.name, (rng.randn(*t.shape) * 0.05).astype(np.float32))
     return spec
-
-
-def write_tiny_tokenizer(path, vocab_size=300) -> tfile.TokenizerData:
-    """Vocab: 3 specials (+ 256 byte tokens when it fits) + a few words;
-    chatml template.  Small vocab sizes skip the byte-fallback pieces."""
-    vocab = [b"<unk>", b"<s>", b"</s>"]
-    words = [b" ", b"a", b"b", b"e", b"h", b"i", b"l", b"o", b"he", b"ll",
-             b"hell", b"hello", b"hi", b" hi", b" hello",
-             b"<|im_end|>", b"<|im_start|>"]
-    if vocab_size >= 3 + 256 + len(words):
-        vocab += [f"<0x{i:02X}>".encode() for i in range(256)]
-    vocab += words
-    if len(vocab) > vocab_size:
-        raise ValueError(f"vocab_size {vocab_size} too small for fixture")
-    while len(vocab) < vocab_size:
-        vocab.append(f"<extra_{len(vocab)}>".encode())
-    scores = [float(len(v)) if v in words else 0.0 for v in vocab]
-    t = tfile.TokenizerData(
-        vocab=vocab, scores=scores, bos_id=1, eos_id=2,
-        chat_eos_id=vocab.index(b"<|im_end|>"),
-        chat_template=CHATML_JINJA, chat_stop=None)
-    tfile.write_tfile(path, t)
-    return t
 
 
 def free_port() -> int:

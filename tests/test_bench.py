@@ -1,35 +1,27 @@
-"""The bench orchestrator's output contract (bench.py).
+"""The bench stages' output contract (bench.py).
 
-The driver records bench.py's LAST stdout line as the round's JSON; every
-failure branch was manually validated against dead/half-up/killed relay
-states — these tests pin the pieces that must never regress: the
-single-line emit contract, the extras merge, the relay TCP gate, and the
-SIGTERM last-resort line.
+``python bench.py --attempt <stage>`` prints one JSON line; a chip stage
+that finds no TPU fails instead of printing a CPU number under a device
+name; the compile cache is placed by the shared helper.
 """
 
 from __future__ import annotations
 
 import json
 import os
-import select
-import signal
-import socket
 import subprocess
 import sys
-import time
 
 import pytest
 
-from fixtures import REPO, free_port
+from fixtures import REPO, cpu_env
 
 sys.path.insert(0, REPO)
 import bench  # noqa: E402
 
 
 def test_emit_contract(capfd):
-    """One parseable line; backend stripped; extras riding along (plus
-    the perf-sentinel verdict when a previous banked round exists next
-    to bench.py — evidence, never a gate)."""
+    """One parseable line; backend stripped; extras riding along."""
     bench._emit({"metric": "m", "value": 1.5, "unit": "tok/s",
                  "vs_baseline": None, "backend": "tpu"},
                 {"llama3-8b_toks": 88.0})
@@ -38,97 +30,76 @@ def test_emit_contract(capfd):
     assert len(lines) == 1
     obj = json.loads(lines[0])
     assert obj["value"] == 1.5 and "backend" not in obj
-    extras = obj["extras"]
-    assert extras["llama3-8b_toks"] == 88.0
-    sentinel = extras.pop("perf_sentinel", None)
-    assert extras == {"llama3-8b_toks": 88.0}
-    if sentinel is not None:  # this checkout has banked rounds
-        assert sentinel["verdict"] in ("ok", "regression")
-        assert sentinel["vs"].startswith("BENCH_r")
+    assert obj["extras"] == {"llama3-8b_toks": 88.0}
 
 
-def test_relay_listening_gate(monkeypatch):
-    port = free_port()
-    monkeypatch.setattr(bench, "RELAY_PORT", port)
-    monkeypatch.setattr(bench, "RELAY_HOST", "127.0.0.1")
-    assert bench._relay_listening(1.0) is False  # nothing bound
-    srv = socket.socket()
-    srv.setsockopt(socket.SOL_SOCKET, socket.SO_REUSEADDR, 1)
-    srv.bind(("127.0.0.1", port))
-    srv.listen(1)
-    try:
-        assert bench._relay_listening(1.0) is True
-    finally:
-        srv.close()
+CHIP_STAGES = [
+    "llama2-7b", "llama2-7b-b8", "llama2-7b-q8kv", "llama2-7b-q8w",
+    "llama2-7b-profile", "llama2-7b-c64", "llama2-7b-long", "llama3-8b",
+    "llama2-13b", "tinyllama-1.1b", "llama2-7b-cli", "llama2-7b-prefill",
+    "llama2-7b-sched4", "llama2-7b-prefix4", "llama2-7b-pressure4",
+    "llama2-7b-overlap4", "llama2-7b-fused4", "llama2-7b-spec4",
+    "llama2-7b-tp4sched4"]
 
 
-def test_sigterm_emits_last_resort_line():
-    """A killed bench must still leave one parseable JSON line (the r03
-    failure mode: a dead round with nothing for BENCH_r{N}.json)."""
-    env = dict(os.environ)
-    env["BENCH_BUDGET_S"] = "3000"
-    env["BENCH_RELAY_PORT"] = str(free_port())  # guaranteed-dead relay
-    p = subprocess.Popen([sys.executable, os.path.join(REPO, "bench.py")],
-                         stdout=subprocess.PIPE, stderr=subprocess.PIPE,
-                         env=env, cwd=REPO)
-    # Wait for the poll-loop stderr marker before killing: it prints after
-    # the term handler is installed, so the SIGTERM provably races nothing.
-    # (A fixed sleep flaked when a parallel TPU bench starved this child's
-    # interpreter startup past the margin.)  select() bounds the wait even
-    # if the child goes silent before the marker.
-    deadline = time.time() + 120
-    buf = b""
-    while b"polling for tunnel" not in buf and time.time() < deadline:
-        r, _, _ = select.select([p.stderr], [], [],
-                                max(0.0, deadline - time.time()))
-        if not r:
-            break
-        chunk = os.read(p.stderr.fileno(), 4096)
-        if not chunk:
-            break
-        buf += chunk
-    p.stderr.close()
-    p.send_signal(signal.SIGTERM)
-    out, _ = p.communicate(timeout=30)
-    assert p.returncode == 1
-    lines = [l for l in out.decode().splitlines() if l.strip()]
-    assert len(lines) == 1, lines
-    obj = json.loads(lines[0])
-    assert obj["unit"] == "tok/s" and "interrupted" in obj["metric"]
+@pytest.mark.parametrize("stage", CHIP_STAGES)
+def test_chip_stage_refuses_before_any_work(stage, monkeypatch, capfd):
+    """Every real-config stage, ``-prefill`` and ``-cli`` included, stops
+    at the one guard on top of ``_attempt_body``: nothing is synthesized,
+    compiled or printed on the CPU backend."""
+    def no_work(*a, **k):
+        raise AssertionError("a chip stage started work without a TPU")
+    for fn in ("_model_cfg", "_synth_model_files", "_run_cli_bench",
+               "_bench_decode", "_bench_prefill", "_bench_sched"):
+        monkeypatch.setattr(bench, fn, no_work)
+    with pytest.raises(SystemExit, match="chip stage and JAX found no TPU"):
+        bench._attempt_body(stage)
+    assert capfd.readouterr().out == ""
 
 
-def test_dead_relay_emits_insession_capture():
-    """With the relay dead but a committed in-session TPU capture present,
-    the round-end bench must surface that hardware evidence (provenance-
-    tagged) as its one line — not only a degraded CPU number (r05: the
-    relay was alive mid-session and dead at round end in 3 of 4 rounds)."""
-    art_path = os.path.join(REPO, "BENCH_insession.json")
-    if not os.path.exists(art_path):
-        pytest.skip("no in-session artifact in this checkout")
-    art = json.loads(open(art_path).read().strip())
-    if not art.get("value") or "DEGRADED" in art.get("metric", ""):
-        pytest.skip("in-session artifact is not hardware evidence")
-    # mirror bench's freshness gate exactly: round stamp first, 14 h
-    # timestamp fallback — same parser bench uses
-    cur_round = bench.current_round()
-    if art.get("round") is not None and cur_round is not None:
-        fresh = int(art["round"]) == cur_round
-    else:
-        fresh = time.time() - float(art.get("captured_unix") or 0) < 14 * 3600
-    if not fresh:
-        pytest.skip("in-session artifact is stale; bench correctly "
-                    "prefers the degraded path")
-    env = dict(os.environ)
-    env["BENCH_BUDGET_S"] = "200"
-    env["BENCH_RELAY_PORT"] = str(free_port())  # guaranteed-dead relay
+@pytest.mark.parametrize("stage", ["llama2-7b", "llama2-7b-sched4",
+                                   "llama2-7b-prefill", "llama2-7b-cli"])
+def test_chip_stage_fails_without_a_tpu(stage):
+    """No CPU number is ever printed under a device name: a real-config
+    stage on the CPU backend exits non-zero with no result line."""
+    r = subprocess.run([sys.executable, os.path.join(REPO, "bench.py"),
+                        "--attempt", stage], env=cpu_env(1),
+                       capture_output=True, text=True, timeout=300, cwd=REPO)
+    assert r.returncode != 0
+    assert r.stdout.strip() == ""
+    assert "chip stage" in r.stderr
+
+
+def test_no_orchestrator_left():
     r = subprocess.run([sys.executable, os.path.join(REPO, "bench.py")],
-                       stdout=subprocess.PIPE, stderr=subprocess.DEVNULL,
-                       env=env, cwd=REPO, timeout=600)
-    lines = [l for l in r.stdout.decode().splitlines() if l.strip()]
-    assert len(lines) == 1, lines
-    obj = json.loads(lines[0])
-    assert "in-session capture" in obj["metric"]
-    assert obj["value"] == art["value"]
+                       env=cpu_env(1), capture_output=True, text=True,
+                       timeout=120, cwd=REPO)
+    assert r.returncode != 0 and r.stdout == ""
+    assert "--attempt" in r.stderr
+
+
+class TestCompileCache:
+    def test_env_dir_is_honoured_and_nothing_else_set(self, monkeypatch):
+        import jax
+        from dllama_tpu import hostenv
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/some/dir")
+        calls = []
+        monkeypatch.setattr(jax.config, "update",
+                            lambda *a: calls.append(a))
+        assert hostenv.configure_compile_cache() == "/some/dir"
+        assert calls == []  # JAX reads the variable itself
+
+    def test_default_is_the_fixed_checkout_path(self, monkeypatch):
+        import jax
+        from dllama_tpu import hostenv
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        calls = []
+        monkeypatch.setattr(jax.config, "update",
+                            lambda *a: calls.append(a))
+        want = os.path.join(REPO, "build", "xla_cache")
+        assert hostenv.compile_cache_dir() == want
+        assert hostenv.configure_compile_cache() == want
+        assert calls == [("jax_compilation_cache_dir", want)]
 
 
 def test_maybe_blocked_applies_to_q40_only(monkeypatch):

@@ -1,0 +1,101 @@
+"""chip_smoke.py: the last-line contract, refusal without a TPU, and a CPU
+rehearsal of every phase's control flow at a toy size (the real run is on
+the chip; nothing here is a device result)."""
+
+from __future__ import annotations
+
+import json
+import os
+import subprocess
+import sys
+
+import pytest
+
+from fixtures import REPO, cpu_env, write_tiny_model, write_tiny_tokenizer
+
+sys.path.insert(0, REPO)
+import chip_smoke  # noqa: E402
+
+from dllama_tpu import quants  # noqa: E402
+
+
+def test_last_line_is_exactly_the_contract():
+    line = chip_smoke.last_line({"platform": "tpu", "kind": "TPU v5 lite",
+                                 "count": 1, "compile_cache": "/x",
+                                 "what": "device"})
+    assert "\n" not in line
+    obj = json.loads(line)
+    assert set(obj) == {"ok", "device"} and obj["ok"] is True
+    assert set(obj["device"]) == {"platform", "kind", "count"}
+    assert obj["device"] == {"platform": "tpu", "kind": "TPU v5 lite",
+                             "count": 1}
+    assert json.dumps(obj) == line  # round-trips byte for byte
+
+
+def test_refuses_without_a_tpu():
+    """On the CPU the script exits non-zero and never prints ok:true (the
+    first child's platform check fails before any model is synthesized)."""
+    r = subprocess.run([sys.executable, os.path.join(REPO, "chip_smoke.py")],
+                       env=cpu_env(1), capture_output=True, text=True,
+                       timeout=300, cwd=REPO)
+    assert r.returncode != 0
+    assert '"ok": true' not in r.stdout
+    assert "no TPU" in r.stderr
+
+
+def test_parent_side_never_imports_jax(tmp_path):
+    """What the parent runs itself (cache directory, model synthesis) stays
+    JAX-free: a parent that has touched JAX can take the chip from the
+    children.  ``main`` holds itself to the same after its synth phase."""
+    code = (
+        "import sys; sys.path.insert(0, sys.argv[1]); import chip_smoke\n"
+        "from dllama_tpu.hostenv import compile_cache_dir\n"
+        "from dllama_tpu.synth import synth_model_files\n"
+        "compile_cache_dir(); synth_model_files('cpu-tiny', sys.argv[2])\n"
+        "assert 'jax' not in sys.modules, 'jax was imported'\n")
+    r = subprocess.run([sys.executable, "-c", code, REPO, str(tmp_path)],
+                       env=cpu_env(1), capture_output=True, text=True,
+                       timeout=300, cwd=REPO)
+    assert r.returncode == 0, r.stderr[-600:]
+
+
+@pytest.fixture
+def rehearsal_env(monkeypatch, tmp_path):
+    for k, v in cpu_env(4).items():
+        monkeypatch.setenv(k, v)
+    m, t = str(tmp_path / "toy.m"), str(tmp_path / "toy.t")
+    # shapes that allow tp=4 (n_kv_heads=4) with whole tiles per shard
+    write_tiny_model(m, ftype=quants.Q40, dim=256, hidden_dim=512,
+                     n_kv_heads=4, seq_len=256)
+    write_tiny_tokenizer(t)
+    return m, t, str(tmp_path)
+
+
+def test_rehearse_kernels_phase(rehearsal_env, capfd):
+    dev = chip_smoke.phase_kernels(600, rehearse=True)
+    assert dev["platform"] == "cpu"
+    rows = [json.loads(ln) for ln in capfd.readouterr().out.splitlines()]
+    errs = [r for r in rows if "rel_err" in r]
+    assert len(errs) == 12  # five shapes × rows {1, 8} + dense/int8 attention
+    assert all(r["rel_err"] <= r["tol"] for r in errs)
+
+
+def test_rehearse_cli_and_server_phases(rehearsal_env, capfd):
+    m, t, tmp = rehearsal_env
+    res = chip_smoke.phase_cli(m, t, 600, steps=16, rehearse=True)
+    assert res["generated_tokens"] == 16
+    res = chip_smoke.phase_server(m, t, 600, tmp, slots=2, ctx=64, page=4,
+                                  max_tokens=8, rehearse=True)
+    assert res["drain_rc"] == 0 and res["greedy_replay_identical"]
+    assert res["dispatch"].get("sample/sample-dev", 0) > 0
+    assert '"ok": true' not in capfd.readouterr().out
+
+
+def test_rehearse_tp_phase_on_virtual_devices(rehearsal_env, capfd):
+    m, t, _ = rehearsal_env
+    dev = chip_smoke.phase_tp(m, t, 600, tp=4, rehearse=True)
+    assert dev["count"] >= 4
+    rows = [json.loads(ln) for ln in capfd.readouterr().out.splitlines()]
+    cmp_ = next(r for r in rows if r.get("what") == "compare")
+    assert cmp_["weight_devices"] == 4 and cmp_["cache_devices"] == 4
+    assert cmp_["tokens_tp"] == cmp_["tokens_tp1"]

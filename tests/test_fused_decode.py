@@ -106,6 +106,57 @@ def test_fused_kernel_matches_gather_reference(quantized):
                                np.asarray(ref, np.float32), atol=tol)
 
 
+@pytest.mark.parametrize("mode", ["auto", "on"])
+def test_fused_choice_is_static_and_raises_on_tpu(monkeypatch, mode):
+    """Platform patched to read ``tpu``: the attention ladder picks the
+    fused kernel outside and inside ``jax.jit`` alike, and its lowering
+    failure (this backend is really the CPU) propagates — no probe, no
+    degrade to the gather path."""
+    from dllama_tpu.obs import dispatch as obs_dispatch
+    from dllama_tpu.ops import attention as att
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setenv("DLLAMA_FUSED_ATTN", mode)
+    q, pk, pv, table, pos_rows, _ = _pool_fixture(False)
+    assert att._fused_choice(1, 4, 2) == (True, False)
+    assert att._fused_choice(2, 4, 2) == (False, False)  # t > 1
+    assert att._fused_choice(1, 3, 2) == (False, False)  # hq % hkv
+
+    def read(qv):
+        return att.paged_gqa_attention_at(qv, pk, pv, jnp.int32(0), table,
+                                          pos_rows)
+
+    for call in (lambda: read(q), lambda: jax.jit(read)(q)):
+        obs_dispatch.reset()
+        try:
+            with pytest.raises(Exception):  # noqa: B017 — any lowering error
+                jax.block_until_ready(call())
+            assert obs_dispatch.dispatches() == {"kv_dense/paged-fused": 1}
+            assert obs_dispatch.degraded() is False
+        finally:
+            obs_dispatch.reset()
+
+
+def test_fused_choice_on_a_mesh_stays_gather(monkeypatch):
+    """A pallas_call is not partitioned by GSPMD: on a multi-device mesh
+    the TPU ladder keeps the gather form (a static choice, no degrade
+    under ``auto``; ``on`` says so loudly)."""
+    from dllama_tpu.obs import dispatch as obs_dispatch
+    from dllama_tpu.ops import attention as att
+    from dllama_tpu.parallel.mesh import active_mesh
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    obs_dispatch.reset()
+    try:
+        with active_mesh(make_mesh(tp=2, devices=jax.devices()[:2])):
+            monkeypatch.setenv("DLLAMA_FUSED_ATTN", "auto")
+            assert att._fused_choice(1, 4, 2) == (False, False)
+            assert obs_dispatch.degraded() is False
+            monkeypatch.setenv("DLLAMA_FUSED_ATTN", "on")
+            assert att._fused_choice(1, 4, 2) == (False, False)
+            assert obs_dispatch.reasons() == {"attn:fused_needs_tpu": 1}
+    finally:
+        obs_dispatch.reset()
+
+
 def test_fused_kernel_under_jit():
     """The kernel composes with jit (the engine always calls it inside a
     compiled step) and stays deterministic across calls."""

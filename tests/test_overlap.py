@@ -283,6 +283,11 @@ def test_handoff_export_flushes_pipeline(paged_solo_ref):
         assert t.rid in records
         assert inflight_seen and all(n == 0 for n in inflight_seen), \
             inflight_seen
+        # the cancel of t_bg lands on the scheduler thread: wait (bounded)
+        # for it to go quiet instead of racing its last dispatch
+        deadline = time.monotonic() + 20.0
+        while (sa._inflight_n or sa._depth) and time.monotonic() < deadline:
+            time.sleep(0.005)
         assert sa._inflight_n == 0 and sa._depth == 0
 
         meta, _ = snapfmt.loads_request(records[t.rid])
@@ -312,11 +317,19 @@ def test_flush_discards_inflight_dispatch():
     try:
         with injected("engine.device_step=delay:0.05x100000"):
             t = sched.submit(P2, 50)
-            time.sleep(0.3)  # steady decode: pipeline nearly always full
+
+            def wait_inflight(timeout=20.0):
+                """Bounded wait for a pipelined dispatch on the device —
+                the condition a discard needs; a fixed sleep missed it
+                under loaded xdist workers."""
+                deadline = time.monotonic() + timeout
+                while sched._inflight_n == 0 and time.monotonic() < deadline:
+                    time.sleep(0.002)
+
             for _ in range(5):
+                wait_inflight()
                 sched.flush()
                 assert sched._inflight_n == 0
-                time.sleep(0.1)
             t.cancel("aborted")
             list(t.tokens())
     finally:

@@ -1,0 +1,127 @@
+"""Compile the main-path kernels for a described (not attached) TPU v5e.
+
+The TPU compiler is installed here and compiles for a chip that is
+described, not attached, so a Mosaic lowering failure at the real
+Llama-2-7B widths costs no chip time.  Nothing runs: these tests say
+nothing about values or speed (chip_smoke.py checks values on the chip).
+
+The topology is described inside a module-scoped fixture, never at import:
+only one process may load the TPU library, and every xdist worker imports
+every test file.  All TPU compiles live in this one file for the same
+reason, and run in the test's own process.
+"""
+
+from __future__ import annotations
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, NamedSharding, SingleDeviceSharding
+from jax.sharding import PartitionSpec as P
+
+from dllama_tpu.ops import attention as att
+from dllama_tpu.ops import q40
+
+# Llama-2-7B: (name, input dim n, output dim d, layer-stacked)
+SHAPES_7B = [("wqkv", 4096, 12288, True), ("wo", 4096, 4096, True),
+             ("w13", 4096, 22016, True), ("w2", 11008, 4096, True),
+             ("wcls", 4096, 32000, False)]
+
+
+@pytest.fixture(scope="module")
+def topo():
+    import os
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no TPU compiler in this install
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(autouse=True)
+def _no_compile_cache():
+    """A compile for a described chip can be written to the persistent
+    cache but not read back without the chip; keep these out of it."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", prev)
+    cc.reset_cache()
+
+
+def _q40_shapes(n, d, rows, stacked, sharding):
+    np_ = q40.padded_n(n)
+    lead = (2,) if stacked else ()
+    s = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=sharding)  # noqa: E731
+    return (s((rows, np_), jnp.bfloat16), s((*lead, np_ // 2, d), jnp.uint8),
+            s((*lead, np_ // 32, d), jnp.uint16))
+
+
+@pytest.mark.parametrize("rows", [1, 8])
+@pytest.mark.parametrize("name,n,d,stacked", SHAPES_7B,
+                         ids=[s[0] for s in SHAPES_7B])
+def test_q40_matmul_compiles_at_7b_shapes(one_chip, name, n, d, stacked, rows):
+    x, qp, sc = _q40_shapes(n, d, rows, stacked, one_chip)
+    assert q40._tile_n_legal(x.shape[1], q40._tiles(x.shape[1], d)[0])
+    if stacked:
+        layer = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+        compiled = jax.jit(q40._pallas_matmul_stacked).lower(
+            x, qp, sc, layer).compile()
+    else:
+        compiled = jax.jit(q40._pallas_matmul).lower(x, qp, sc).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("quantized", [False, True], ids=["dense", "int8"])
+def test_fused_paged_attention_compiles_at_7b_geometry(one_chip, quantized):
+    b, hq, hkv, dh, ps, maxp = 4, 32, 32, 128, 16, 64
+    n_pages = 1 + b * maxp
+    s = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)  # noqa: E731
+    pool = s((2, n_pages, hkv, ps, dh), jnp.int8 if quantized else jnp.bfloat16)
+    scale = s((2, n_pages, hkv, ps, 1), jnp.float32)
+
+    def f(q, k, v, layer, table, pos, *sc):
+        return att.fused_paged_attention(q, k, v, layer, table, pos,
+                                         scales=sc or None)
+
+    args = [s((b, hq, 1, dh), jnp.bfloat16), pool, pool, s((), jnp.int32),
+            s((b, maxp), jnp.int32), s((b,), jnp.int32)]
+    if quantized:
+        args += [scale, scale]
+    compiled = jax.jit(f).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("name,n,d", [("wo", 4096, 4096), ("w2", 11008, 4096)])
+def test_tp4_col_matmul_compiles_on_described_mesh(topo, monkeypatch, name,
+                                                   n, d):
+    """tp=4 col-sharded matmul + its reduce on a mesh of the described
+    devices: the per-shard kernel must survive shard_map partitioning."""
+    mesh = Mesh(np.asarray(topo.devices[:4]).reshape(1, 1, 1, 4),
+                ("dp", "sp", "ep", "tp"))
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    np_ = q40.padded_n(n)
+    sh = lambda spec: NamedSharding(mesh, spec)  # noqa: E731
+    x = jax.ShapeDtypeStruct((1, np_), jnp.bfloat16, sharding=sh(P(None, "tp")))
+    qp = jax.ShapeDtypeStruct((2, np_ // 2, d), jnp.uint8,
+                              sharding=sh(P(None, "tp", None)))
+    sc = jax.ShapeDtypeStruct((2, np_ // 32, d), jnp.uint16,
+                              sharding=sh(P(None, "tp", None)))
+    layer = jax.ShapeDtypeStruct((), jnp.int32, sharding=sh(P()))
+    assert q40._tile_n_legal(np_ // 4, q40._tiles(np_ // 4, d)[0])
+
+    def f(x, qp, sc, layer):
+        return q40._sharded_matmul(x, qp, sc, layer, "col", mesh, False)
+
+    compiled = jax.jit(f).lower(x, qp, sc, layer).compile()
+    assert "tpu_custom_call" in compiled.as_text()
