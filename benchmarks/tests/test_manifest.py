@@ -1,0 +1,82 @@
+"""BENCHMARK.json against the contract's rules that can be checked here, and
+against the data files it names."""
+
+import json
+import os
+import re
+
+import pytest
+
+from conftest import BENCH, ROOT
+
+NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
+UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
+SOURCES = {"device_trace", "program_span", "program_counter", "host_clock"}
+
+
+@pytest.fixture(scope="module")
+def manifest():
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        return json.load(f)
+
+
+def test_keys_and_limits(manifest):
+    assert set(manifest) == {"command", "paths", "run_seconds", "configs",
+                             "workloads", "end_to_end", "per_layer"}
+    assert 1 <= manifest["run_seconds"] <= 51
+    assert os.path.getsize(os.path.join(ROOT, "BENCHMARK.json")) <= 64 * 1024
+    assert 2 <= len(manifest["workloads"]) <= 24
+    four = sum(w["chips"] == 4 for w in manifest["workloads"])
+    assert four <= max(1, len(manifest["workloads"]) // 4)
+
+
+def test_names_and_units(manifest):
+    names = []
+    for group in ("configs", "workloads", "end_to_end", "per_layer"):
+        for e in manifest[group]:
+            assert NAME.match(e["name"]), e["name"]
+            names.append((group in ("end_to_end", "per_layer"), e["name"]))
+    assert len(names) == len(set(names))
+    for m in manifest["end_to_end"] + manifest["per_layer"]:
+        assert UNIT.match(m["unit"]), m["unit"]
+        assert m["better"] in ("lower", "higher")
+        assert m["source"] in SOURCES
+    for m in manifest["end_to_end"]:
+        assert set(m) <= {"name", "unit", "better", "bound", "source", "workloads"}
+        assert 0.01 <= m["bound"] <= 0.1
+        assert m["source"] in ("host_clock", "device_trace")
+    assert any(m["name"] == "setup_s" for m in manifest["end_to_end"])
+    e2e = {m["name"] for m in manifest["end_to_end"]}
+    for m in manifest["per_layer"]:
+        assert set(m) <= {"name", "unit", "better", "source", "layer", "moves",
+                          "workloads"}
+        assert m["moves"] in e2e
+        assert "\n" not in m["layer"] and 1 <= len(m["layer"]) <= 200
+
+
+def test_every_cell_has_its_files(manifest):
+    configs = {c["name"]: c for c in manifest["configs"]}
+    cells = {w["name"] for w in manifest["workloads"]}
+    assert {w["config"] for w in manifest["workloads"]} == set(configs)
+    for c in configs.values():
+        assert c["file"].startswith("benchmarks/")
+        with open(os.path.join(ROOT, c["file"])) as f:
+            cfg = json.load(f)
+        assert cfg["reduced"] == c["reduced"]
+        assert len(c["why"]) <= 200 and c["source"].startswith("https://")
+    for w in manifest["workloads"]:
+        assert NAME.match(w["traffic"]) and len(w["why"]) <= 200
+        with open(os.path.join(BENCH, "cells", w["name"] + ".json")) as f:
+            cell = json.load(f)
+        assert (cell["config"], cell["traffic"], cell["chips"]) == \
+            (w["config"], w["traffic"], w["chips"])
+        assert cell["chips"] in (1, 4) and isinstance(cell["argv"], list)
+        assert os.path.exists(os.path.join(BENCH, "traffic", w["traffic"] + ".json"))
+    for m in manifest["per_layer"]:
+        assert os.path.exists(os.path.join(BENCH, "layer_metrics", m["name"] + ".py"))
+    for m in manifest["end_to_end"] + manifest["per_layer"]:
+        assert set(m.get("workloads", [])) <= cells
+    for w in cells:  # each cell: setup_s, another end-to-end metric, a per-layer one
+        e = [m["name"] for m in manifest["end_to_end"] if w in m.get("workloads", [w])]
+        assert "setup_s" in e and len(e) >= 2
+        assert any(w in m.get("workloads", [w]) for m in manifest["per_layer"])
