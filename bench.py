@@ -329,10 +329,9 @@ def _bench_sched(cfg, slots=4, max_new=96, tp=1):
     tok/s (completion tokens only — prefill is inside the window, as it is
     for a real request).
 
-    ``tp`` > 1 runs the same workload on a tensor-parallel mesh (PR-12):
-    the scheduler's step loop samples the mesh's all-reduce latency into
-    ``engine_collective_ms`` as it serves, and the dispatch ledger
-    records whether decode collectives took the fused ring or psum."""
+    ``tp`` > 1 runs the same workload on a tensor-parallel mesh (PR-12);
+    the dispatch ledger records whether decode collectives took the fused
+    ring or psum (their time is read from a device trace, not here)."""
     import threading
 
     import jax
@@ -878,8 +877,6 @@ def _attempt_body(name):
                   f"{len(jax.devices())}", file=sys.stderr)
             raise SystemExit(3)
         toks = _bench_sched(cfg.with_(quant_impl=impl), tp=4)
-        from dllama_tpu.obs import metrics as obs_metrics
-        coll = obs_metrics.ENGINE_COLLECTIVE_MS
         print(json.dumps({
             "metric": f"{base} q40 tensor-parallel tp=4 continuous-batching "
                       f"slots=4 aggregate decode tok/s "
@@ -887,8 +884,6 @@ def _attempt_body(name):
             "value": round(toks, 2), "unit": "tok/s",
             "vs_baseline": _vs_baseline(
                 toks, BASELINE_7B_TOKS if base == "llama2-7b" else None),
-            "collective_ms_avg": round(coll.sum / coll.count, 3)
-            if coll.count else None,
             "backend": jax.default_backend()}))
         return
 

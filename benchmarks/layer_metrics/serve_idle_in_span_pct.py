@@ -1,0 +1,4 @@
+"""`idle_in_span_pct` in a served cell: the same reader under a name that moves
+`serve_itl_p50_ms` (a per-layer metric is reported where the metric it moves is)."""
+
+from idle_in_span_pct import read  # noqa: F401

@@ -35,6 +35,7 @@ from ..ops.attention import (gqa_attention_at, paged_gqa_attention_at,
                              quantize_kv, slot_gqa_attention_at,
                              update_kv_cache_at, update_kv_cache_rows)
 from ..ops.kernels import ACTIVATIONS, apply_rope, rmsnorm, rope_angles, softmax_f32
+from ..ops.scopes import scope
 from ..ops.sp_attention import ring_attention, sp_gqa_attention, sp_update_kv_cache_at
 from ..parallel.mesh import get_active_mesh
 from .config import ModelConfig
@@ -127,18 +128,20 @@ def update_cache_at(cache: KVCache, k_new, v_new, layer, pos) -> KVCache:
     """Write one layer's step KV window into the stacked cache at
     ``(layer, pos)`` — quantizing to int8 + per-position scales first when
     the cache is quantized (see init_kv_cache)."""
-    if not cache.quantized:
-        ck, cv = update_kv_cache_at(cache.k, cache.v, k_new, v_new, layer, pos)
-        return KVCache(ck, cv)
-    qk, sk = quantize_kv(k_new)
-    qv, sv = quantize_kv(v_new)
-    zero = jnp.zeros((), layer.dtype)
-    idx = (layer, zero, zero, pos.astype(layer.dtype), zero)
-    return KVCache(
-        jax.lax.dynamic_update_slice(cache.k, qk[None], idx),
-        jax.lax.dynamic_update_slice(cache.v, qv[None], idx),
-        jax.lax.dynamic_update_slice(cache.k_scale, sk[None], idx),
-        jax.lax.dynamic_update_slice(cache.v_scale, sv[None], idx))
+    with scope("kv_write"):
+        if not cache.quantized:
+            ck, cv = update_kv_cache_at(cache.k, cache.v, k_new, v_new,
+                                        layer, pos)
+            return KVCache(ck, cv)
+        qk, sk = quantize_kv(k_new)
+        qv, sv = quantize_kv(v_new)
+        zero = jnp.zeros((), layer.dtype)
+        idx = (layer, zero, zero, pos.astype(layer.dtype), zero)
+        return KVCache(
+            jax.lax.dynamic_update_slice(cache.k, qk[None], idx),
+            jax.lax.dynamic_update_slice(cache.v, qv[None], idx),
+            jax.lax.dynamic_update_slice(cache.k_scale, sk[None], idx),
+            jax.lax.dynamic_update_slice(cache.v_scale, sv[None], idx))
 
 
 def _attention_block(x, lp, cfg: ModelConfig, cache: KVCache, cos, sin, pos,
@@ -151,24 +154,27 @@ def _attention_block(x, lp, cfg: ModelConfig, cache: KVCache, cos, sin, pos,
     b, t, d = x.shape
     hq, hkv, dh = cfg.n_heads, cfg.n_kv_heads, cfg.head_size
 
-    xb = rmsnorm(x, lp["rms_att"])
-    if "wqkv" in lp:  # fused projection (quantized load): one kernel launch
-        qkv = _mm(xb, lp["wqkv"], cfg)
-        q, k, v = jnp.split(qkv, [hq * dh, (hq + hkv) * dh], axis=-1)
-    else:
-        q = _mm(xb, lp["wq"], cfg, kind="row")
-        k = _mm(xb, lp["wk"], cfg, kind="row")
-        v = _mm(xb, lp["wv"], cfg, kind="row")
-    q = q.reshape(b, t, hq, dh)
-    k = k.reshape(b, t, hkv, dh)
-    v = v.reshape(b, t, hkv, dh)
+    with scope("norm"):
+        xb = rmsnorm(x, lp["rms_att"])
+    with scope("qkv"):
+        if "wqkv" in lp:  # fused projection (quantized load): one kernel launch
+            qkv = _mm(xb, lp["wqkv"], cfg)
+            q, k, v = jnp.split(qkv, [hq * dh, (hq + hkv) * dh], axis=-1)
+        else:
+            q = _mm(xb, lp["wq"], cfg, kind="row")
+            k = _mm(xb, lp["wk"], cfg, kind="row")
+            v = _mm(xb, lp["wv"], cfg, kind="row")
+        q = q.reshape(b, t, hq, dh)
+        k = k.reshape(b, t, hkv, dh)
+        v = v.reshape(b, t, hkv, dh)
 
-    q = apply_rope(q, cos, sin, interleaved=cfg.rope_interleaved)
-    k = apply_rope(k, cos, sin, interleaved=cfg.rope_interleaved)
+    with scope("rope"):
+        q = apply_rope(q, cos, sin, interleaved=cfg.rope_interleaved)
+        k = apply_rope(k, cos, sin, interleaved=cfg.rope_interleaved)
 
-    q = q.transpose(0, 2, 1, 3)  # (B, Hq, T, Dh)
-    k = k.transpose(0, 2, 1, 3)
-    v = v.transpose(0, 2, 1, 3)
+        q = q.transpose(0, 2, 1, 3)  # (B, Hq, T, Dh)
+        k = k.transpose(0, 2, 1, 3)
+        v = v.transpose(0, 2, 1, 3)
     mesh = get_active_mesh()
     sp_on = mesh is not None and mesh.shape.get("sp", 1) > 1
     ring = sp_on and cfg.ring_prefill and t > 1
@@ -185,37 +191,61 @@ def _attention_block(x, lp, cfg: ModelConfig, cache: KVCache, cos, sin, pos,
                 # int8 pages: quantize the step window once, scatter
                 # values and per-position scales through the same write
                 # indices, and let attention dequantize on read
-                qk, sk = quantize_kv(k)
-                qv, sv = quantize_kv(v)
-                ck, cv = paged_update_kv_rows(cache.k, cache.v, qk, qv,
-                                              layer, pidx, oidx)
-                csk, csv = paged_update_kv_rows(cache.k_scale, cache.v_scale,
-                                                sk, sv, layer, pidx, oidx)
-                cache = KVCache(ck, cv, csk, csv)
-                att = paged_gqa_attention_at(
-                    q, cache.k, cache.v, layer, page_table, pos_rows,
-                    scales=(cache.k_scale, cache.v_scale))
+                with scope("kv_write"):
+                    qk, sk = quantize_kv(k)
+                    qv, sv = quantize_kv(v)
+                    ck, cv = paged_update_kv_rows(cache.k, cache.v, qk, qv,
+                                                  layer, pidx, oidx)
+                    csk, csv = paged_update_kv_rows(
+                        cache.k_scale, cache.v_scale, sk, sv, layer, pidx,
+                        oidx)
+                    cache = KVCache(ck, cv, csk, csv)
+                with scope("attn"):
+                    att = paged_gqa_attention_at(
+                        q, cache.k, cache.v, layer, page_table, pos_rows,
+                        scales=(cache.k_scale, cache.v_scale))
             else:
-                ck, cv = paged_update_kv_rows(cache.k, cache.v, k, v, layer,
-                                              pidx, oidx)
-                cache = KVCache(ck, cv)
-                att = paged_gqa_attention_at(q, cache.k, cache.v, layer,
-                                             page_table, pos_rows)
+                with scope("kv_write"):
+                    ck, cv = paged_update_kv_rows(cache.k, cache.v, k, v,
+                                                  layer, pidx, oidx)
+                    cache = KVCache(ck, cv)
+                with scope("attn"):
+                    att = paged_gqa_attention_at(q, cache.k, cache.v, layer,
+                                                 page_table, pos_rows)
         else:
-            ck, cv = update_kv_cache_rows(cache.k, cache.v, k, v, layer,
-                                          pos_rows)
-            cache = KVCache(ck, cv)
-            att = slot_gqa_attention_at(q, cache.k, cache.v, layer, pos_rows)
-        att = att.transpose(0, 2, 1, 3).reshape(b, t, hq * dh)
-        return _mm(att, lp["wo"], cfg, kind="col"), cache
+            with scope("kv_write"):
+                ck, cv = update_kv_cache_rows(cache.k, cache.v, k, v, layer,
+                                              pos_rows)
+                cache = KVCache(ck, cv)
+            with scope("attn"):
+                att = slot_gqa_attention_at(q, cache.k, cache.v, layer,
+                                            pos_rows)
+        with scope("attn"):
+            att = att.transpose(0, 2, 1, 3).reshape(b, t, hq * dh)
+        with scope("wo"):
+            return _mm(att, lp["wo"], cfg, kind="col"), cache
     if t == 1 and sp_on:
         # seq-sharded cache: explicit shard-local write (no GSPMD-chosen
         # gather/scatter per decode step); quantized caches are gated off
         # sp meshes at the engine boundary
-        ck, cv = sp_update_kv_cache_at(cache.k, cache.v, k, v, layer, pos, mesh)
-        cache = KVCache(ck, cv)
+        with scope("kv_write"):
+            ck, cv = sp_update_kv_cache_at(cache.k, cache.v, k, v, layer, pos,
+                                           mesh)
+            cache = KVCache(ck, cv)
     else:
         cache = update_cache_at(cache, k, v, layer, pos)
+    with scope("attn"):
+        att = _attend(q, k, v, cache, cfg, pos, t, layer, offsets, mesh,
+                      sp_on, ring)
+        att = att.transpose(0, 2, 1, 3).reshape(b, t, hq * dh)
+    with scope("wo"):
+        # col-sharded: partial sums all-reduced here
+        return _mm(att, lp["wo"], cfg, kind="col"), cache
+
+
+def _attend(q, k, v, cache: KVCache, cfg: ModelConfig, pos, t, layer, offsets,
+            mesh, sp_on: bool, ring: bool):
+    """The contiguous-cache attention forms of :func:`_attention_block`."""
     if sp_on:
         # ragged batches are gated off sp meshes at the engine boundary
         # (Engine.generate_batch raises), so offsets is always None here
@@ -223,30 +253,31 @@ def _attention_block(x, lp, cfg: ModelConfig, cache: KVCache, cos, sin, pos,
             # from-scratch prefill: the fresh block IS the whole history
             # (engine gates this on pos==0), so attend blockwise over the
             # sequence-sharded q/k/v ring — no cache read, O(T/sp) memory
-            att = ring_attention(q, k, v, mesh, pos0=pos)
-        else:
-            # sequence-parallel decode / continuation: seq-sharded cache,
-            # one-round distributed softmax combine; the layer is sliced
-            # inside the shard body (see sp_gqa_attention)
-            att = sp_gqa_attention(q, cache.k, cache.v, pos, t, mesh, layer=layer)
-    else:
-        att = gqa_attention_at(
-            q, cache.k, cache.v, layer, pos, t, start=offsets,
-            scales=((cache.k_scale, cache.v_scale) if cache.quantized else None))
-    att = att.transpose(0, 2, 1, 3).reshape(b, t, hq * dh)
-    out = _mm(att, lp["wo"], cfg, kind="col")  # col-sharded: partial sums all-reduced here
-    return out, cache
+            return ring_attention(q, k, v, mesh, pos0=pos)
+        # sequence-parallel decode / continuation: seq-sharded cache,
+        # one-round distributed softmax combine; the layer is sliced
+        # inside the shard body (see sp_gqa_attention)
+        return sp_gqa_attention(q, cache.k, cache.v, pos, t, mesh,
+                                layer=layer)
+    return gqa_attention_at(
+        q, cache.k, cache.v, layer, pos, t, start=offsets,
+        scales=((cache.k_scale, cache.v_scale) if cache.quantized else None))
 
 
 def _dense_ffn(xb, lp, cfg: ModelConfig):
     act = ACTIVATIONS[cfg.hidden_act]
     if "w13" in lp:  # fused gate+up (quantized load)
-        h13 = _mm(xb, lp["w13"], cfg)
-        h1, h3 = jnp.split(h13, 2, axis=-1)
-        h = act(h1) * h3
+        with scope("w13"):
+            h13 = _mm(xb, lp["w13"], cfg)
+            h1, h3 = jnp.split(h13, 2, axis=-1)
+            h = act(h1) * h3
     else:
-        h = act(_mm(xb, lp["w1"], cfg, kind="row")) * _mm(xb, lp["w3"], cfg, kind="row")
-    return _mm(h, lp["w2"], cfg, kind="col")
+        with scope("w1"):
+            h1 = act(_mm(xb, lp["w1"], cfg, kind="row"))
+        with scope("w3"):
+            h = h1 * _mm(xb, lp["w3"], cfg, kind="row")
+    with scope("w2"):
+        return _mm(h, lp["w2"], cfg, kind="col")
 
 
 def moe_ffn(xb2d: jax.Array, lp, cfg: ModelConfig) -> jax.Array:
@@ -372,21 +403,24 @@ def run_blocks(params: Params, cfg: ModelConfig, tokens: jax.Array,
     decoding alone, so batched greedy output matches the single-stream
     run token for token."""
     b, t = tokens.shape
-    x = jnp.take(params["embedding"], tokens, axis=0).astype(cfg.dtype)
-    if cfg.embedding_scale != 1.0:
-        x = x * jnp.asarray(cfg.embedding_scale, cfg.dtype)
+    with scope("embed"):
+        x = jnp.take(params["embedding"], tokens, axis=0).astype(cfg.dtype)
+        if cfg.embedding_scale != 1.0:
+            x = x * jnp.asarray(cfg.embedding_scale, cfg.dtype)
 
-    positions = pos + jnp.arange(t)
-    if pos_rows is not None:
-        # continuous-batching slots: every row has its own clock, and slot
-        # requests always start at cache position 0, so cache position ==
-        # logical RoPE position (no offset subtraction)
-        positions = pos_rows[:, None] + jnp.arange(t)[None, :]
-    elif offsets is not None:
-        # per-row logical positions; pad slots clamp to 0 (their k/q values
-        # are garbage either way and masked out of every live row's view)
-        positions = jnp.maximum(positions[None, :] - offsets[:, None], 0)
-    cos, sin = rope_angles(positions, cfg.head_size, cfg.rope_theta)  # (T, Dh/2)
+    with scope("rope"):
+        positions = pos + jnp.arange(t)
+        if pos_rows is not None:
+            # continuous-batching slots: every row has its own clock, and
+            # slot requests always start at cache position 0, so cache
+            # position == logical RoPE position (no offset subtraction)
+            positions = pos_rows[:, None] + jnp.arange(t)[None, :]
+        elif offsets is not None:
+            # per-row logical positions; pad slots clamp to 0 (their k/q
+            # values are garbage either way and masked out of every live
+            # row's view)
+            positions = jnp.maximum(positions[None, :] - offsets[:, None], 0)
+        cos, sin = rope_angles(positions, cfg.head_size, cfg.rope_theta)  # (T, Dh/2)
 
     layer_keys = [k for k in params if k not in ("embedding", "rms_final", "wcls")]
     # Packed-Q40 weights stay out of the scan's xs: the scan would slice a
@@ -408,19 +442,29 @@ def run_blocks(params: Params, cfg: ModelConfig, tokens: jax.Array,
                                         idx, offsets=offsets,
                                         pos_rows=pos_rows, paged=paged)
         if cfg.post_block_norms:
-            att_out = rmsnorm(att_out, lp["rms_ffn"])  # grokRmfFfnNorm
-        x = x + att_out
+            with scope("norm"):
+                att_out = rmsnorm(att_out, lp["rms_ffn"])  # grokRmfFfnNorm
+        with scope("wo"):
+            x = x + att_out
 
         if cfg.is_moe:
             pre = lp["rms_moe"] if cfg.post_block_norms else lp["rms_ffn"]
-            xb = rmsnorm(x, pre)
-            ff = moe_ffn(xb.reshape(b * t, cfg.dim), lp, cfg).reshape(b, t, cfg.dim)
+            with scope("norm"):
+                xb = rmsnorm(x, pre)
+            with scope("moe"):
+                ff = moe_ffn(xb.reshape(b * t, cfg.dim), lp,
+                             cfg).reshape(b, t, cfg.dim)
             if cfg.post_block_norms:
-                ff = rmsnorm(ff, lp["rms_ffn2"])  # grokMoeRmsNormFinal
+                with scope("norm"):
+                    ff = rmsnorm(ff, lp["rms_ffn2"])  # grokMoeRmsNormFinal
+            with scope("moe"):
+                x = x + ff
         else:
-            xb = rmsnorm(x, lp["rms_ffn"])
+            with scope("norm"):
+                xb = rmsnorm(x, lp["rms_ffn"])
             ff = _dense_ffn(xb, lp, cfg)
-        x = x + ff
+            with scope("w2"):
+                x = x + ff
         return (x, kvc), None
 
     # The stacked caches are scan *carries*, not xs/ys: each layer touches
@@ -434,13 +478,15 @@ def run_blocks(params: Params, cfg: ModelConfig, tokens: jax.Array,
 
 
 def _head(params: Params, cfg: ModelConfig, x: jax.Array) -> jax.Array:
-    x = rmsnorm(x, params["rms_final"])
-    # out_dtype=f32 keeps the matmul's f32 accumulation for the sampler
-    # instead of a round trip through the bf16 activation dtype
-    logits = q40.mm(x, params["wcls"], impl=cfg.quant_impl, out_dtype=jnp.float32,
-                    kind="row")
-    if cfg.logit_scale != 1.0:
-        logits = logits * cfg.logit_scale
+    with scope("norm"):
+        x = rmsnorm(x, params["rms_final"])
+    with scope("head"):
+        # out_dtype=f32 keeps the matmul's f32 accumulation for the sampler
+        # instead of a round trip through the bf16 activation dtype
+        logits = q40.mm(x, params["wcls"], impl=cfg.quant_impl,
+                        out_dtype=jnp.float32, kind="row")
+        if cfg.logit_scale != 1.0:
+            logits = logits * cfg.logit_scale
     return logits
 
 
@@ -466,7 +512,8 @@ def forward_last(params: Params, cfg: ModelConfig, tokens: jax.Array,
     the same final index, so the shared ``last_index`` needs no per-row
     variant."""
     x, cache = run_blocks(params, cfg, tokens, cache, pos, offsets=offsets)
-    x_last = jax.lax.dynamic_slice_in_dim(x, last_index, 1, axis=1)[:, 0]  # (B, D)
+    with scope("head"):
+        x_last = jax.lax.dynamic_slice_in_dim(x, last_index, 1, axis=1)[:, 0]  # (B, D)
     return _head(params, cfg, x_last), cache
 
 
@@ -497,18 +544,30 @@ def forward_slots(params: Params, cfg: ModelConfig, tokens: jax.Array,
     scratch page instead of landing above the ceiling.
     """
     t = tokens.shape[1]
+    x, cache = _run_slot_blocks(params, cfg, tokens, cache, pos_rows, n_valid,
+                                page_table)
+    with scope("head"):
+        idx = jnp.clip(n_valid - 1, 0, t - 1)
+        x_last = jax.vmap(
+            lambda row, i: jax.lax.dynamic_index_in_dim(row, i, 0,
+                                                        keepdims=False)
+        )(x, idx)  # (B, D): per-row last-valid gather
+    return _head(params, cfg, x_last), cache
+
+
+def _run_slot_blocks(params: Params, cfg: ModelConfig, tokens, cache: KVCache,
+                     pos_rows, n_valid, page_table):
+    """:func:`run_blocks` for slot rows; on a paged pool the write indices
+    are computed once here (identical for every layer)."""
     paged = None
     if page_table is not None:
-        ps = cache.k.shape[3]
-        pidx, oidx = paged_write_indices(page_table, pos_rows, n_valid, t, ps)
+        with scope("page_idx"):
+            pidx, oidx = paged_write_indices(page_table, pos_rows, n_valid,
+                                             tokens.shape[1],
+                                             cache.k.shape[3])
         paged = (page_table, pidx, oidx)
-    x, cache = run_blocks(params, cfg, tokens, cache, jnp.int32(0),
-                          pos_rows=pos_rows, paged=paged)
-    idx = jnp.clip(n_valid - 1, 0, t - 1)
-    x_last = jax.vmap(
-        lambda row, i: jax.lax.dynamic_index_in_dim(row, i, 0, keepdims=False)
-    )(x, idx)  # (B, D): per-row last-valid gather
-    return _head(params, cfg, x_last), cache
+    return run_blocks(params, cfg, tokens, cache, jnp.int32(0),
+                      pos_rows=pos_rows, paged=paged)
 
 
 def forward_slots_all(params: Params, cfg: ModelConfig, tokens: jax.Array,
@@ -524,12 +583,6 @@ def forward_slots_all(params: Params, cfg: ModelConfig, tokens: jax.Array,
     KV write/mask semantics — including stale writes above a row's
     ``n_valid`` landing beyond its causal ceiling (or in the scratch
     page when paged) — are identical to :func:`forward_slots`."""
-    t = tokens.shape[1]
-    paged = None
-    if page_table is not None:
-        ps = cache.k.shape[3]
-        pidx, oidx = paged_write_indices(page_table, pos_rows, n_valid, t, ps)
-        paged = (page_table, pidx, oidx)
-    x, cache = run_blocks(params, cfg, tokens, cache, jnp.int32(0),
-                          pos_rows=pos_rows, paged=paged)
+    x, cache = _run_slot_blocks(params, cfg, tokens, cache, pos_rows, n_valid,
+                                page_table)
     return _head(params, cfg, x), cache

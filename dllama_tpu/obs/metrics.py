@@ -512,10 +512,6 @@ ENGINE_INFERENCE_MS = REGISTRY.histogram(
 ENGINE_TRANSFER_MS = REGISTRY.histogram(
     "engine_transfer_ms", STEP_MS_BUCKETS,
     "Per-token host<->device boundary time (T), milliseconds.")
-ENGINE_COLLECTIVE_MS = REGISTRY.histogram(
-    "engine_collective_ms", STEP_MS_BUCKETS,
-    "Measured tp all-reduce latency of a decode-width partial sum "
-    "across the engine's mesh (Engine.probe_collective), milliseconds.")
 HOST_DEVICE_SENT_BYTES = REGISTRY.histogram(
     "host_device_sent_bytes", BYTES_BUCKETS,
     "Host->device bytes per engine dispatch (tokens + scalars).")
@@ -679,6 +675,16 @@ SCHED_STEP_TIME_MS = REGISTRY.labeled_counter(
     "sched_step_time_ms", ("component",),
     "Scheduler wall-time decomposition in milliseconds, by component "
     "(prefill|decode|pad|host_gap|idle).")
+SCHED_STEPS = REGISTRY.labeled_counter(
+    "sched_steps", ("kind",),
+    "Dispatches landed by the slot scheduler, by kind: decode (no row "
+    "mid-prefill), mixed (at least one prefill row), verify (a "
+    "speculative verify window).")
+SCHED_STEP_WALL_MS = REGISTRY.labeled_counter(
+    "sched_step_wall_ms", ("kind",),
+    "Wall milliseconds of the dispatches counted by sched_steps, same "
+    "kinds; over all kinds it equals sched_step_time_ms's prefill + "
+    "decode + pad.")
 SCHED_GOODPUT_RATIO = REGISTRY.gauge(
     "sched_goodput_ratio",
     "Fraction of scheduler wall time spent on live rows "

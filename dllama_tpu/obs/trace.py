@@ -9,16 +9,25 @@ JSON for ``chrome://tracing`` / Perfetto.  When a span points at a phase
 worth dissecting, ``--profile-split`` (runtime/profiling.py) remains the
 heavyweight XLA-level tool.
 
-Timestamps are ``time.perf_counter()`` seconds (converted to µs in the
-export); they order and measure correctly within one process but are not
-wall-clock.  The ``raw()`` export therefore samples ``(perf_now,
-wall_now)`` at serve time so a cross-process stitcher (the router's
-``/debug/trace?scope=fleet``) can compute a per-replica offset and shift
-every ring onto one wall-clock axis.  Capacity comes from
-``--trace-buffer`` / ``DLLAMA_TRACE_BUFFER`` (legacy alias
-``DLLAMA_TRACE_CAPACITY``; default 8192 spans ≈ a few hundred requests);
-a malformed value warns once and falls back, mirroring the
-``DLLAMA_Q40_BLOCK_TILES`` contract.
+Every timestamp in the ring is ``time.perf_counter()`` seconds (converted
+to µs in the export); they order and measure correctly within one process
+but are not wall-clock.  The ``raw()`` export therefore samples
+``(perf_now, wall_now)`` at serve time so a cross-process stitcher (the
+router's ``/debug/trace?scope=fleet``) can compute a per-replica offset
+and shift every ring onto one wall-clock axis.  Capacity comes from
+``--trace-buffer`` / ``DLLAMA_TRACE_BUFFER`` (default 8192 spans ≈ a few
+hundred requests); a malformed value warns once and falls back, mirroring
+the ``DLLAMA_Q40_BLOCK_TILES`` contract.
+
+``span(name, **args)`` is the one entry point for a block of code: besides
+the ring record it enters ``jax.profiler.TraceAnnotation(name, **args)``,
+so a ``jax.profiler`` trace shows the same spans, with the same arguments,
+on the profiler's clock beside the device's ops (a flag test when no
+profiler session is active; skipped in a process that never loaded JAX).
+``record(name, t0, t1)`` stays for spans that are not one block of code
+(a pipelined dispatch is enqueued in one scheduler round and lands in the
+next); those are in the ring only.  The names are listed in
+docs/OBSERVABILITY.md ("Host spans").
 
 Fleet trace context: ``X-Dllama-Trace`` carries one id for a request's
 whole life across router hops and DLREQ01 migrations.  The id rides a
@@ -33,6 +42,7 @@ from __future__ import annotations
 import contextvars
 import os
 import re
+import sys
 import threading
 import time
 import uuid
@@ -97,14 +107,12 @@ def trace_of(rid: str | None) -> str | None:
         return _rid_trace.get(rid)
 
 
-def parse_buffer_env(var: str, default: int, legacy: str | None = None) -> int:
-    """Ring capacity from ``var`` (falling back to ``legacy``); a value
-    that is not a positive integer logs one warning per distinct spec and
-    falls back to ``default`` — never raises (the buffer size must not be
-    able to take the server down)."""
+def parse_buffer_env(var: str, default: int) -> int:
+    """Ring capacity from ``var``; a value that is not a positive integer
+    logs one warning per distinct spec and falls back to ``default`` —
+    never raises (the buffer size must not be able to take the server
+    down)."""
     spec = os.environ.get(var)
-    if spec is None and legacy is not None:
-        spec = os.environ.get(legacy)
     if spec is None or spec == "":
         return default
     try:
@@ -122,8 +130,37 @@ def parse_buffer_env(var: str, default: int, legacy: str | None = None) -> int:
 
 
 def _capacity() -> int:
-    return parse_buffer_env("DLLAMA_TRACE_BUFFER", DEFAULT_CAPACITY,
-                            legacy="DLLAMA_TRACE_CAPACITY")
+    return parse_buffer_env("DLLAMA_TRACE_BUFFER", DEFAULT_CAPACITY)
+
+
+def _profiler_value(v):
+    """A span argument as the profiler keeps it: numbers and strings as
+    they are, a list joined with ``;`` (the annotation's encoding ends a
+    value at a comma)."""
+    if isinstance(v, (list, tuple, set)):
+        return ";".join(str(x) for x in v)
+    return v if isinstance(v, (int, float, str)) else str(v)
+
+
+def _profiler_args(args: dict, skip=()) -> dict:
+    return {k: _profiler_value(v) for k, v in args.items()
+            if v is not None and k not in skip}
+
+
+def _annotation(name: str, rid, args: dict):
+    """An entered ``jax.profiler.TraceAnnotation`` for a span, or None
+    when no profiler session is active (one flag test) or the process has
+    not loaded JAX (the router, the smoke test's parent): a span never
+    imports it."""
+    jax = sys.modules.get("jax")
+    if jax is None or not jax.profiler.TraceAnnotation.is_enabled():
+        return None
+    kw = _profiler_args(args)
+    if rid is not None:
+        kw["rid"] = str(rid)
+    ann = jax.profiler.TraceAnnotation(name, **kw)
+    ann.__enter__()
+    return ann
 
 
 class Tracer:
@@ -162,12 +199,24 @@ class Tracer:
         return self._spans.maxlen or 0
 
     @contextmanager
-    def span(self, name: str, **args):
+    def span(self, name: str, rid=None, **args):
+        """Time the enclosed block into the ring and, under an active
+        ``jax.profiler`` session, into the profiler's host plane.  Yields
+        the argument dict: what the block adds to it (a shape decided
+        half-way) reaches both."""
+        ann = _annotation(name, rid, args)
+        known = tuple(args) if ann is not None else ()
         t0 = time.perf_counter()
         try:
-            yield
+            yield args
         finally:
-            self.record(name, t0, time.perf_counter(), **args)
+            t1 = time.perf_counter()
+            if ann is not None:
+                late = _profiler_args(args, skip=known)
+                if late:
+                    ann.set_metadata(**late)
+                ann.__exit__(None, None, None)
+            self.record(name, t0, t1, rid=rid, **args)
 
     def snapshot(self) -> list[dict]:
         with self._lock:
@@ -240,14 +289,22 @@ def record(name: str, t0: float, t1: float, rid=None, **args) -> None:
     TRACER.record(name, t0, t1, rid=rid, **args)
 
 
+def record_ending_now(name: str, dur_s: float, rid=None, **args) -> None:
+    """Record a span of ``dur_s`` seconds that ends now, on the ring's
+    clock: for a caller whose start instant is on another clock (a
+    ticket's ``time.monotonic()`` deadline arithmetic)."""
+    t1 = time.perf_counter()
+    TRACER.record(name, t1 - max(dur_s, 0.0), t1, rid=rid, **args)
+
+
 def configure(capacity: int | None = None) -> None:
     """Apply a CLI-chosen capacity (``--trace-buffer``) after import."""
     if capacity is not None:
         TRACER.resize(capacity)
 
 
-def span(name: str, **args):
-    return TRACER.span(name, **args)
+def span(name: str, rid=None, **args):
+    return TRACER.span(name, rid=rid, **args)
 
 
 def trace_json(last_requests: int | None = None) -> dict:

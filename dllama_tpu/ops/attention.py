@@ -443,6 +443,7 @@ def fused_paged_attention(q: jax.Array, pool_k: jax.Array, pool_v: jax.Array,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")),
         interpret=interpret,
+        name="paged_attn_fused",
     )(jnp.atleast_1d(layer).astype(jnp.int32),
       page_table.astype(jnp.int32), pos_rows.astype(jnp.int32), *operands)
     return out[:, :, None, :]

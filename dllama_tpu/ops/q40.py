@@ -570,6 +570,7 @@ def _pallas_matmul(x: jax.Array, qpacked: jax.Array, scales: jax.Array,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
+        name="q40_mm",
     )(x_lo, x_hi, bsum, qpacked, scales)
 
 
@@ -618,6 +619,7 @@ def _pallas_matmul_stacked(x: jax.Array, qpacked: jax.Array, scales: jax.Array,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
+        name="q40_mm_stacked",
     )(layer.reshape(1).astype(jnp.int32), x_lo, x_hi, bsum, qpacked, scales)
     return out
 
@@ -871,6 +873,7 @@ def _pallas_matmul_blocked(x: jax.Array, qb: jax.Array, sb: jax.Array,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
+        name="q40_mm_blocked",
     )(layer.reshape(1).astype(jnp.int32), x_lo, x_hi, bsum, qb, sb)
 
 
@@ -997,6 +1000,7 @@ def _tp_ring_allreduce(x: jax.Array, tp: int) -> jax.Array:
         ],
         compiler_params=pltpu.CompilerParams(
             has_side_effects=True, collective_id=0),
+        name="q40_ring",
     )(x)
 
 

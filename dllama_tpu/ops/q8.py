@@ -244,6 +244,7 @@ def _pallas_matmul(x: jax.Array, qv: jax.Array, s: jax.Array,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
+        name="q8_mm",
     )(x.astype(jnp.bfloat16), qv, s)
 
 
@@ -273,6 +274,7 @@ def _pallas_matmul_stacked(x: jax.Array, qv: jax.Array, s: jax.Array,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
+        name="q8_mm_stacked",
     )(layer.reshape(1).astype(jnp.int32), x.astype(jnp.bfloat16), qv, s)
 
 

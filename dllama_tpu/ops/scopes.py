@@ -1,0 +1,45 @@
+"""The stage names of the forward pass as the device trace shows them.
+
+Every path (contiguous, slot, paged, tp) wraps the work of a stage in
+``scope(<name>)``; XLA keeps the name in each op's ``op_name`` metadata and
+the profiler in the op's ``tf_op`` stat, so a trace can be split by stage
+whatever instruction numbers the compile gave (docs/OBSERVABILITY.md,
+"Device scopes").  A scope changes metadata only: no executable, cache key
+or number moves.  Which weight a Pallas matmul read is told by the scope;
+its family by the kernel's ``name=`` (``q40_mm``, ``q40_mm_stacked``,
+``q40_mm_blocked``, ``q40_ring``, ``q8_mm``, ``q8_mm_stacked``,
+``paged_attn_fused``).
+
+The tuple is an interface: the benchmark's readers
+(``benchmarks/layer_metrics/_scopes.py``) hold a copy and a test compares
+the two.  An op whose name path carries none of these is ``unscoped``.
+"""
+
+from __future__ import annotations
+
+import jax
+
+SCOPES = (
+    "embed",     # token embedding lookup and its scale
+    "norm",      # every rmsnorm
+    "qkv",       # wqkv (or wq/wk/wv) projection and its split
+    "rope",      # angles, rotation, the head-major transposes
+    "kv_write",  # cache / pool update, the int8 quantize that feeds it
+    "page_idx",  # paged write indices, once per slot step
+    "attn",      # scores, softmax, values; gather or fused paged kernel
+    "wo",        # output projection and the residual add it feeds
+    "w13",       # fused gate+up projection and the activation product
+    "w1",        # gate projection where not fused
+    "w3",        # up projection where not fused, and the product
+    "w2",        # down projection and the residual add it feeds
+    "moe",       # router, expert matmuls, combine
+    "head",      # last-position gather, output matmul, logit scale
+    "sample",    # device sampler, key split, token feedback of a burst
+)
+
+
+def scope(name: str):
+    """``jax.named_scope(name)`` for a name of :data:`SCOPES`."""
+    if name not in SCOPES:
+        raise ValueError(f"{name!r} is not a scope of {SCOPES}")
+    return jax.named_scope(name)

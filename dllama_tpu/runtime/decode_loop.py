@@ -26,6 +26,7 @@ import jax.numpy as jnp
 from ..models.config import ModelConfig
 from ..models.transformer import (KVCache, forward_last, forward_slots,
                                   forward_slots_all)
+from ..ops.scopes import scope
 from ..sampling import sample_on_device
 
 
@@ -49,19 +50,20 @@ def device_sample(logits: jax.Array, key: jax.Array, temperature: float,
     static so each mode compiles to its own minimal program; ``mask`` is
     the optional vocab keep-mask (grammar seam, identity today).
     """
-    if temperature == 0.0:
-        if mask is not None:
-            logits = jnp.where(jnp.asarray(mask).astype(bool), logits,
-                               -jnp.inf)
-        return jnp.argmax(logits, axis=-1).astype(jnp.int32)
-    b = logits.shape[0]
-    _record_sample_dev(b)
-    coins = jax.random.uniform(key, (b,), jnp.float32)
-    return sample_on_device(
-        logits, coins,
-        jnp.full((b,), temperature, jnp.float32),
-        jnp.full((b,), topp, jnp.float32),
-        jnp.full((b,), topk, jnp.int32), mask=mask)
+    with scope("sample"):
+        if temperature == 0.0:
+            if mask is not None:
+                logits = jnp.where(jnp.asarray(mask).astype(bool), logits,
+                                   -jnp.inf)
+            return jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        b = logits.shape[0]
+        _record_sample_dev(b)
+        coins = jax.random.uniform(key, (b,), jnp.float32)
+        return sample_on_device(
+            logits, coins,
+            jnp.full((b,), temperature, jnp.float32),
+            jnp.full((b,), topp, jnp.float32),
+            jnp.full((b,), topk, jnp.int32), mask=mask)
 
 
 def decode_chunk(params, cfg: ModelConfig, cache: KVCache, token: jax.Array,
@@ -82,9 +84,10 @@ def decode_chunk(params, cfg: ModelConfig, cache: KVCache, token: jax.Array,
         cache, token, pos, key = carry
         logits, cache = forward_last(params, cfg, token[:, None], cache, pos,
                                      jnp.int32(0), offsets=offsets)
-        key, sub = jax.random.split(key)
-        nxt = device_sample(logits, sub, temperature, topp)
-        return (cache, nxt, pos + 1, key), nxt
+        with scope("sample"):
+            key, sub = jax.random.split(key)
+            nxt = device_sample(logits, sub, temperature, topp)
+            return (cache, nxt, pos + 1, key), nxt
 
     (cache, last, pos, key), toks = jax.lax.scan(
         body, (cache, token, pos, key), None, length=steps)
@@ -107,17 +110,18 @@ def device_sample_rows(logits: jax.Array, key: jax.Array, temps: jax.Array,
     host reference, one uniform coin per row from ``key``.  ``mask`` is
     the optional vocab keep-mask (grammar seam, identity today).
     """
-    if greedy:
-        if mask is not None:
-            logits = jnp.where(jnp.asarray(mask).astype(bool), logits,
-                               -jnp.inf)
-        return jnp.argmax(logits, axis=-1).astype(jnp.int32)
-    b = logits.shape[0]
-    _record_sample_dev(b)
-    coins = jax.random.uniform(key, (b,), jnp.float32)
-    if topks is None:
-        topks = jnp.zeros((b,), jnp.int32)
-    return sample_on_device(logits, coins, temps, topps, topks, mask=mask)
+    with scope("sample"):
+        if greedy:
+            if mask is not None:
+                logits = jnp.where(jnp.asarray(mask).astype(bool), logits,
+                                   -jnp.inf)
+            return jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        b = logits.shape[0]
+        _record_sample_dev(b)
+        coins = jax.random.uniform(key, (b,), jnp.float32)
+        if topks is None:
+            topks = jnp.zeros((b,), jnp.int32)
+        return sample_on_device(logits, coins, temps, topps, topks, mask=mask)
 
 
 def slot_chunk(params, cfg: ModelConfig, cache: KVCache, tokens: jax.Array,
@@ -156,31 +160,34 @@ def slot_chunk(params, cfg: ModelConfig, cache: KVCache, tokens: jax.Array,
     """
     logits, cache = forward_slots(params, cfg, tokens, cache, pos_rows,
                                   n_valid, page_table=page_table)
-    if not greedy:
-        key, sub = jax.random.split(key)
-    else:
-        sub = key
-    first = device_sample_rows(logits, sub, temps, topps, greedy, topks,
-                               vocab_mask)
-    pos_rows = pos_rows + n_valid
+    with scope("sample"):
+        if not greedy:
+            key, sub = jax.random.split(key)
+        else:
+            sub = key
+        first = device_sample_rows(logits, sub, temps, topps, greedy, topks,
+                                   vocab_mask)
+        pos_rows = pos_rows + n_valid
 
     def body(carry, _):
         cache, tok, pos_rows, key = carry
         logits, cache = forward_slots(params, cfg, tok[:, None], cache,
                                       pos_rows, jnp.ones_like(pos_rows),
                                       page_table=page_table)
-        if not greedy:
-            key, sub = jax.random.split(key)
-        else:
-            sub = key
-        nxt = device_sample_rows(logits, sub, temps, topps, greedy, topks,
-                                 vocab_mask)
-        return (cache, nxt, pos_rows + 1, key), nxt
+        with scope("sample"):
+            if not greedy:
+                key, sub = jax.random.split(key)
+            else:
+                sub = key
+            nxt = device_sample_rows(logits, sub, temps, topps, greedy,
+                                     topks, vocab_mask)
+            return (cache, nxt, pos_rows + 1, key), nxt
 
     if steps > 1:
         (cache, last, _, key), rest = jax.lax.scan(
             body, (cache, first, pos_rows, key), None, length=steps - 1)
-        toks = jnp.concatenate([first[None], rest], axis=0)
+        with scope("sample"):
+            toks = jnp.concatenate([first[None], rest], axis=0)
     else:
         toks, last = first[None], first
     return toks, cache, last, key
@@ -223,19 +230,21 @@ def slot_verify_chunk(params, cfg: ModelConfig, cache: KVCache,
     """
     logits, cache = forward_slots_all(params, cfg, tokens, cache, pos_rows,
                                       n_valid, page_table=page_table)
-    preds = jnp.argmax(logits, axis=-1).astype(jnp.int32)  # (B, T)
-    if not greedy:
-        key, sub = jax.random.split(key)
-        first = device_sample_rows(logits[:, 0], sub, temps, topps, greedy,
-                                   topks, vocab_mask)
-        preds = preds.at[:, 0].set(first)
-    t = tokens.shape[1]
-    # leading-match count: draft j (fed at column j+1) is accepted iff it
-    # equals the model's prediction at column j and every earlier draft
-    # was accepted too — cumprod turns the match mask into leading-ones
-    ok = (tokens[:, 1:] == preds[:, :-1]) \
-        & (jnp.arange(t - 1)[None, :] < (n_valid - 1)[:, None])
-    accepted = jnp.sum(jnp.cumprod(ok.astype(jnp.int32), axis=1), axis=1)
-    accepted = accepted.astype(jnp.int32)  # (B,)
-    last = jnp.take_along_axis(preds, accepted[:, None], axis=1)[:, 0]
+    with scope("sample"):
+        preds = jnp.argmax(logits, axis=-1).astype(jnp.int32)  # (B, T)
+        if not greedy:
+            key, sub = jax.random.split(key)
+            first = device_sample_rows(logits[:, 0], sub, temps, topps,
+                                       greedy, topks, vocab_mask)
+            preds = preds.at[:, 0].set(first)
+        t = tokens.shape[1]
+        # leading-match count: draft j (fed at column j+1) is accepted iff
+        # it equals the model's prediction at column j and every earlier
+        # draft was accepted too — cumprod turns the match mask into
+        # leading-ones
+        ok = (tokens[:, 1:] == preds[:, :-1]) \
+            & (jnp.arange(t - 1)[None, :] < (n_valid - 1)[:, None])
+        accepted = jnp.sum(jnp.cumprod(ok.astype(jnp.int32), axis=1), axis=1)
+        accepted = accepted.astype(jnp.int32)  # (B,)
+        last = jnp.take_along_axis(preds, accepted[:, None], axis=1)[:, 0]
     return preds, cache, accepted, last, key

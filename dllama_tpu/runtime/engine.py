@@ -388,10 +388,6 @@ class Engine:
         # a replica whose stream would diverge
         self.sampling_path = os.environ.get(
             "DLLAMA_SAMPLING_PATH", "device").strip().lower() or "device"
-        # collective-latency probe (probe_collective): compiled lazily on
-        # first use, rate-limited host-side
-        self._collective_fn = None
-        self._collective_probe_t = 0.0
         self._offsets: jax.Array | None = None  # ragged-batch left padding
 
     # ------------------------------------------------------------------
@@ -586,42 +582,6 @@ class Engine:
             self._chunk_counter += 1
         return self._dev_key
 
-    def probe_collective(self, min_interval_s: float = 0.5) -> float | None:
-        """Time one tp all-reduce of a decode-width (1, dim) partial sum
-        across this engine's mesh and feed ``engine_collective_ms``.
-
-        The in-step collective (the fused ring or its psum fallback) is
-        fused inside a compiled program, so its latency is not separable
-        host-side; this probe dispatches the same-shape reduce as its own
-        program — real devices, real ICI path — which is the per-step
-        collective cost the fused-reduce work targets.  Rate-limited
-        (callers may invoke per burst), no-op on tp==1 meshes; the first
-        call compiles outside the timed window.  Returns the measured
-        milliseconds, or None when skipped."""
-        tp = self.mesh.shape.get("tp", 1)
-        if tp <= 1:
-            return None
-        now = time.monotonic()
-        if now - self._collective_probe_t < min_interval_s:
-            return None
-        if self._collective_fn is None:
-            fn = jax.jit(jax.shard_map(
-                lambda v: jax.lax.psum(v, "tp"), mesh=self.mesh,
-                in_specs=P(None, "tp"), out_specs=P(None, None),
-                check_vma=False))
-            x = jax.device_put(
-                jnp.zeros((1, self.cfg.dim), jnp.float32),
-                NamedSharding(self.mesh, P(None, "tp")))
-            jax.block_until_ready(fn(x))  # compile, uncounted
-            self._collective_fn = (fn, x)
-        fn, x = self._collective_fn
-        t0 = time.perf_counter()
-        jax.block_until_ready(fn(x))
-        ms = (time.perf_counter() - t0) * 1e3
-        self._collective_probe_t = now
-        obs_metrics.ENGINE_COLLECTIVE_MS.observe(ms)
-        return ms
-
     def read_pool_pages(self, pages) -> dict[str, np.ndarray]:
         """Copy the given physical pages out of the paged pool to host
         numpy, all layers at once: shape ``(L, n, Hkv, ps, Dh)`` (plus the
@@ -774,6 +734,14 @@ class Engine:
             raise ValueError("paged engine is slot-only: the pool has no "
                              "contiguous per-row addressing; drive it via "
                              "slot_step / the slot scheduler")
+        k = int(tokens_np.shape[1])
+        with obs_trace.span("engine.prefill" if k > 1 else
+                            "engine.decode_step", pos=self.pos, k=k):
+            return self._run_step(tokens_np, last_index, offsets)
+
+    def _run_step(self, tokens_np: np.ndarray, last_index: int,
+                  offsets: jax.Array | None) -> tuple[np.ndarray, StepStats]:
+        """:meth:`_run` inside its span: enqueue, wait, fetch the logits."""
         stats = StepStats()
         t0 = time.perf_counter()
         # from-scratch prefill on an sp mesh → blockwise ring attention with
@@ -817,9 +785,6 @@ class Engine:
         stats.generation_ms = (t2 - t0) * 1000
         stats.sent_bytes = tokens_np.nbytes + 8  # token ids + pos/last scalars
         stats.recv_bytes = host_logits.nbytes
-        phase = "prefill" if tokens_np.shape[1] > 1 else "decode_step"
-        obs_trace.record(phase, t0, t2, pos=self.pos,
-                         n_tokens=int(tokens_np.shape[1]))
         obs_metrics.ENGINE_GENERATION_MS.observe(stats.generation_ms)
         obs_metrics.ENGINE_INFERENCE_MS.observe(stats.inference_ms)
         obs_metrics.ENGINE_TRANSFER_MS.observe(stats.transfer_ms)
@@ -998,7 +963,8 @@ class Engine:
             sent = 12 + (in_tok_dev.nbytes
                          if isinstance(in_tok_dev, np.ndarray) else 0)
             t0 = time.perf_counter()
-            with active_mesh(self.mesh):
+            with obs_trace.span("engine.chunk_enqueue", pos=p0, k=k), \
+                    active_mesh(self.mesh):
                 toks_dev, self.cache, last_dev, _pos, _key = fn(
                     self.params, self.cache, jnp.asarray(in_tok_dev),
                     jnp.int32(p0), sub)
@@ -1021,9 +987,10 @@ class Engine:
                 expected += k
                 pending = dispatch(last_dev, expected) \
                     if expected < steps and self.pos < self.seq_len else None
-                self._sync(toks_dev, f"decode chunk at pos {p0}")
-                t1 = time.perf_counter()
-                toks = np.asarray(toks_dev)[:, 0]  # (k,)
+                with obs_trace.span("engine.chunk_fetch", pos=p0, k=k):
+                    self._sync(toks_dev, f"decode chunk at pos {p0}")
+                    t1 = time.perf_counter()
+                    toks = np.asarray(toks_dev)[:, 0]  # (k,)
                 t2 = time.perf_counter()
                 # steady-state chunk wall = boundary to boundary (this
                 # chunk was dispatched before the PREVIOUS fetch returned)
@@ -1038,7 +1005,6 @@ class Engine:
                     transfer_ms=t_ms,
                     sent_bytes=sent / k,
                     recv_bytes=toks.nbytes / k)
-                obs_trace.record("decode_chunk", g0, t2, pos=p0, k=k)
                 obs_metrics.ENGINE_GENERATION_MS.observe(per.generation_ms)
                 obs_metrics.ENGINE_INFERENCE_MS.observe(per.inference_ms)
                 obs_metrics.ENGINE_TRANSFER_MS.observe(per.transfer_ms)
@@ -1305,24 +1271,25 @@ class Engine:
         fn = self._chunk_fns[key]
         sub = self._next_dev_key()
         t0 = time.perf_counter()
-        if feed_dev is not None:
-            tok_arr = jnp.asarray(feed_dev, jnp.int32)[:, None]  # on device
-        else:
-            tok_arr = jnp.asarray(tokens_np, jnp.int32)
-        if topks_np is None:
-            topks_np = np.zeros(len(pos_rows_np), np.int32)
-        args = (self.params, self.cache, tok_arr,
-                jnp.asarray(pos_rows_np, jnp.int32),
-                jnp.asarray(n_valid_np, jnp.int32), sub,
-                jnp.asarray(temps_np, jnp.float32),
-                jnp.asarray(topps_np, jnp.float32),
-                jnp.asarray(topks_np, jnp.int32))
-        if self.paged:
-            args = args + (jnp.asarray(page_tables_np, jnp.int32),)
-        if has_mask:
-            args = args + (jnp.asarray(vocab_mask_np, bool),)
-        with active_mesh(self.mesh):
-            toks_dev, self.cache, last_dev, self._dev_key = fn(*args)
+        with obs_trace.span("engine.slot_enqueue", t=t, steps=steps):
+            if feed_dev is not None:
+                tok_arr = jnp.asarray(feed_dev, jnp.int32)[:, None]  # on device
+            else:
+                tok_arr = jnp.asarray(tokens_np, jnp.int32)
+            if topks_np is None:
+                topks_np = np.zeros(len(pos_rows_np), np.int32)
+            args = (self.params, self.cache, tok_arr,
+                    jnp.asarray(pos_rows_np, jnp.int32),
+                    jnp.asarray(n_valid_np, jnp.int32), sub,
+                    jnp.asarray(temps_np, jnp.float32),
+                    jnp.asarray(topps_np, jnp.float32),
+                    jnp.asarray(topks_np, jnp.int32))
+            if self.paged:
+                args = args + (jnp.asarray(page_tables_np, jnp.int32),)
+            if has_mask:
+                args = args + (jnp.asarray(vocab_mask_np, bool),)
+            with active_mesh(self.mesh):
+                toks_dev, self.cache, last_dev, self._dev_key = fn(*args)
         return SlotDispatch(self, toks_dev, last_dev, t=t, steps=steps,
                             fresh=fresh, enqueued_at=t0)
 
@@ -1425,21 +1392,23 @@ class Engine:
         fn = self._chunk_fns[key]
         sub = self._next_dev_key()
         t0 = time.perf_counter()
-        if topks_np is None:
-            topks_np = np.zeros(len(pos_rows_np), np.int32)
-        args = (self.params, self.cache, jnp.asarray(tokens_np, jnp.int32),
-                jnp.asarray(pos_rows_np, jnp.int32),
-                jnp.asarray(n_valid_np, jnp.int32), sub,
-                jnp.asarray(temps_np, jnp.float32),
-                jnp.asarray(topps_np, jnp.float32),
-                jnp.asarray(topks_np, jnp.int32))
-        if self.paged:
-            args = args + (jnp.asarray(page_tables_np, jnp.int32),)
-        if has_mask:
-            args = args + (jnp.asarray(vocab_mask_np, bool),)
-        with active_mesh(self.mesh):
-            preds_dev, self.cache, accepted_dev, last_dev, self._dev_key = \
-                fn(*args)
+        with obs_trace.span("engine.slot_enqueue", t=t, steps=1, verify=True):
+            if topks_np is None:
+                topks_np = np.zeros(len(pos_rows_np), np.int32)
+            args = (self.params, self.cache,
+                    jnp.asarray(tokens_np, jnp.int32),
+                    jnp.asarray(pos_rows_np, jnp.int32),
+                    jnp.asarray(n_valid_np, jnp.int32), sub,
+                    jnp.asarray(temps_np, jnp.float32),
+                    jnp.asarray(topps_np, jnp.float32),
+                    jnp.asarray(topks_np, jnp.int32))
+            if self.paged:
+                args = args + (jnp.asarray(page_tables_np, jnp.int32),)
+            if has_mask:
+                args = args + (jnp.asarray(vocab_mask_np, bool),)
+            with active_mesh(self.mesh):
+                preds_dev, self.cache, accepted_dev, last_dev, \
+                    self._dev_key = fn(*args)
         return SlotVerifyDispatch(self, preds_dev, accepted_dev, last_dev,
                                   t=t, fresh=fresh, enqueued_at=t0)
 

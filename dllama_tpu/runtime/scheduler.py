@@ -224,7 +224,7 @@ class _Pending:
     __slots__ = ("handle", "error", "active", "tickets", "steps",
                  "t_width", "n_valid", "temps", "topps", "topks", "prefset",
                  "rid_by_slot", "fed_by_slot", "pos_rows", "enq_tp",
-                 "t0_mono", "host_gap_ms", "idle_ms", "overlapped",
+                 "seq", "host_gap_ms", "idle_ms", "overlapped",
                  "queued", "verify", "proposed_by_slot")
 
     def __init__(self, **kw):
@@ -346,6 +346,12 @@ class SlotScheduler:
         self._spec_accepted = 0
         self._n_dispatched = 0
         self._n_overlapped = 0
+        # dispatches enqueued so far: the ``seq`` that ties one dispatch's
+        # sched.enqueue, sched.land_wait and sched.fanout spans together
+        self._n_enqueued = 0
+        # an idle scheduler wakes twice a second; only the first wait
+        # after work is recorded as a span (see _span)
+        self._quiet = False
         self._park_wakeups = 0   # parked-wait iterations (idle test hook)
         # goodput accounting: every ms between the first and the latest
         # dispatch lands in exactly one component (see obs/metrics.py)
@@ -926,9 +932,9 @@ class SlotScheduler:
             self._page_tables[slot_idx][:] = 0
             obs_metrics.KV_PAGES_IN_USE.set(self.pool.in_use)
         obs_metrics.SCHED_SLOT_RETIRES.inc(slot_idx, reason)
-        now = time.monotonic()
-        obs_trace.record("sched_retire", now, now, rid=t.rid, slot=slot_idx,
-                         reason=reason, produced=s.produced)
+        obs_trace.record_ending_now("sched_retire", 0.0, rid=t.rid,
+                                    slot=slot_idx, reason=reason,
+                                    produced=s.produced)
         # the log record factory stamps the contextvar, so bind the
         # ticket's ID around the call (this thread serves many requests)
         ctx = request_id_var.set(t.rid)
@@ -1109,12 +1115,11 @@ class SlotScheduler:
             t.slot = free
             queued_ms = round((now - t.submitted_at) * 1e3, 3)
             obs_metrics.SCHED_SLOT_JOINS.inc(free)
-            obs_trace.record("sched_admit", t.submitted_at, now, rid=t.rid,
-                             slot=free, queued_ms=queued_ms,
-                             n_prompt=len(t.prompt),
-                             prefix_reused=s.prefix_tokens,
-                             priority=PRIORITY_NAMES.get(t.priority,
-                                                         t.priority))
+            obs_trace.record_ending_now(
+                "sched_admit", now - t.submitted_at, rid=t.rid, slot=free,
+                queued_ms=queued_ms, n_prompt=len(t.prompt),
+                prefix_reused=s.prefix_tokens,
+                priority=PRIORITY_NAMES.get(t.priority, t.priority))
             ctx = request_id_var.set(t.rid)
             try:
                 _log.info("slot join", extra={
@@ -1169,9 +1174,10 @@ class SlotScheduler:
         if self.spec is not None:
             self.spec.reset(slot_idx)
         obs_metrics.SCHED_PREEMPTIONS.inc(reason)
-        obs_trace.record("sched_preempt", now, time.monotonic(), rid=t.rid,
-                         slot=slot_idx, reason=reason, produced=s.produced,
-                         priority=PRIORITY_NAMES.get(t.priority, t.priority))
+        obs_trace.record_ending_now(
+            "sched_preempt", time.monotonic() - now, rid=t.rid,
+            slot=slot_idx, reason=reason, produced=s.produced,
+            priority=PRIORITY_NAMES.get(t.priority, t.priority))
         if t.preempt_count >= self.preempt_cap \
                 or len(self._parked) >= self.parked_max:
             self._retire(slot_idx, "preempted")
@@ -1300,9 +1306,10 @@ class SlotScheduler:
         self._drop_parked_locked(entry)
         obs_metrics.KV_PAGES_IN_USE.set(self.pool.in_use)
         obs_metrics.SCHED_SLOT_JOINS.inc(slot_idx)
-        obs_trace.record("sched_resume", entry.parked_at, now, rid=t.rid,
-                         slot=slot_idx, parked_ms=parked_ms, pos=pos,
-                         priority=PRIORITY_NAMES.get(t.priority, t.priority))
+        obs_trace.record_ending_now(
+            "sched_resume", now - entry.parked_at, rid=t.rid, slot=slot_idx,
+            parked_ms=parked_ms, pos=pos,
+            priority=PRIORITY_NAMES.get(t.priority, t.priority))
         ctx = request_id_var.set(t.rid)
         try:
             _log.info("slot resume", extra={
@@ -1561,42 +1568,58 @@ class SlotScheduler:
             return None
         return self._first_dispatch_at, self._last_dispatch_end
 
+    def _span(self, name: str, **args):
+        """``obs_trace.span`` for the loop's own rounds, silent while the
+        scheduler idles with an empty queue: the ring keeps the requests'
+        spans instead of two records a second of nothing."""
+        if self._quiet:
+            return contextlib.nullcontext()
+        return obs_trace.span(name, **args)
+
+    def _round_head_locked(self, now: float) -> list[int]:
+        """The head of a round under ``_cond``: cancels and deadlines,
+        parked sweep, page-in, admission, the tier round.  Returns the
+        slots that hold a ticket."""
+        # honor cancels/deadlines first so their slots free up
+        for i in self._active():
+            t = self.slots[i].ticket
+            if t._cancel is not None:
+                self._retire(i, t._cancel)
+            elif t.deadline is not None and now >= t.deadline:
+                self._retire(i, "timeout")
+        for t in [q for q in self._queue
+                  if q._cancel is not None
+                  or (q.deadline is not None and now >= q.deadline)]:
+            self._queue.remove(t)
+            self._fail_ticket(t, t._cancel or "timeout")
+        self._sweep_parked_locked(now)
+        if self.paged and self._spilled:
+            # spilled slots rejoin before fresh admissions: they hold
+            # live tickets whose consumers are stalled, so freed pages go
+            # to them first
+            self._try_page_in_locked()
+        if not self._paused:
+            self._admit_locked(now)
+        if self.paged and self.optimistic:
+            self._tier_round_locked(now)
+        return self._active()
+
     def _run(self) -> None:
         try:
             while True:
                 with self._cond:
-                    now = time.monotonic()
-                    # honor cancels/deadlines first so their slots free up
-                    for i in self._active():
-                        t = self.slots[i].ticket
-                        if t._cancel is not None:
-                            self._retire(i, t._cancel)
-                        elif t.deadline is not None and now >= t.deadline:
-                            self._retire(i, "timeout")
-                    for t in [q for q in self._queue
-                              if q._cancel is not None
-                              or (q.deadline is not None and now >= q.deadline)]:
-                        self._queue.remove(t)
-                        self._fail_ticket(t, t._cancel or "timeout")
-                    self._sweep_parked_locked(now)
-                    if self.paged and self._spilled:
-                        # spilled slots rejoin before fresh admissions:
-                        # they hold live tickets whose consumers are
-                        # stalled, so freed pages go to them first
-                        self._try_page_in_locked()
-                    if not self._paused:
-                        self._admit_locked(now)
-                    if self.paged and self.optimistic:
-                        self._tier_round_locked(now)
-                    real_active = self._active()
-                    # a spilled slot holds a ticket but no pages — it
-                    # must sit out the dispatch (its page-table row is
-                    # all scratch) until _try_page_in_locked restores it
-                    active = [i for i in real_active
-                              if not self.slots[i].spilled]
-                    queued = len(self._queue)
-                    obs_metrics.SCHED_SLOTS_OCCUPIED.set(len(active))
-                    obs_metrics.SCHED_QUEUE_DEPTH.set(queued)
+                    with self._span("sched.admit"):
+                        now = time.monotonic()
+                        real_active = self._round_head_locked(now)
+                        # a spilled slot holds a ticket but no pages — it
+                        # must sit out the dispatch (its page-table row is
+                        # all scratch) until _try_page_in_locked restores
+                        # it
+                        active = [i for i in real_active
+                                  if not self.slots[i].spilled]
+                        queued = len(self._queue)
+                        obs_metrics.SCHED_SLOTS_OCCUPIED.set(len(active))
+                        obs_metrics.SCHED_QUEUE_DEPTH.set(queued)
                     if self._stop:
                         return
                     if not active:
@@ -1620,10 +1643,13 @@ class SlotScheduler:
                             timeout = min(timeout,
                                           max(min(dls) - now, 0.0))
                         w0 = time.perf_counter()
-                        self._cond.wait(timeout)
+                        with self._span("sched.idle", timeout=timeout):
+                            self._cond.wait(timeout)
                         self._park_wakeups += 1
                         self._idle_accum += time.perf_counter() - w0
+                        self._quiet = not self._queue
                         continue
+                self._quiet = False
                 self._dispatch(active, queued)
         except BaseException as e:  # loop must not die silently
             _log.error("scheduler loop failed", extra={"error": repr(e)})
@@ -1656,7 +1682,8 @@ class SlotScheduler:
                 if nxt is not None:
                     self._abandon(nxt)
                 return
-            survivors = self._pipeline_verdict(nxt)
+            with obs_trace.span("sched.verdict", seq=nxt.seq):
+                survivors = self._pipeline_verdict(nxt)
             if survivors is None:
                 self._abandon(nxt)
                 return
@@ -1666,6 +1693,15 @@ class SlotScheduler:
         """Build and enqueue the round's first (host-fed) dispatch.
         Does not block on the device — the returned handle's tokens are
         still in flight."""
+        self._n_enqueued += 1
+        with obs_trace.span("sched.enqueue", seq=self._n_enqueued,
+                            overlapped=False) as sp:
+            return self._build_and_enqueue(active, queued, sp)
+
+    def _build_and_enqueue(self, active: list[int], queued: int,
+                           sp: dict) -> _Pending:
+        """:meth:`_enqueue_first` inside its span; ``sp`` takes the
+        dispatch's shape once it is decided."""
         eng = self.engine
         b = eng.batch
         slots = self.slots
@@ -1754,6 +1790,9 @@ class SlotScheduler:
         obs_metrics.SCHED_BATCH_EFFICIENCY.set(len(active) / b)
         prefset = set(prefilling)
         rid_by_slot = {i: slots[i].ticket.rid for i in active}
+        sp.update(t=t_width, steps=steps, rows=len(active),
+                  prefill_rows=len(prefilling), verify=bool(props),
+                  rids=sorted(rid_by_slot.values()))
         fed_by_slot = {i: int(n_valid[i]) for i in prefilling}
         tickets = {i: slots[i].ticket for i in active}
 
@@ -1799,7 +1838,7 @@ class SlotScheduler:
                         topks=topks,
                         prefset=prefset, rid_by_slot=rid_by_slot,
                         fed_by_slot=fed_by_slot, pos_rows=pos_rows,
-                        enq_tp=tp0, t0_mono=time.monotonic(),
+                        enq_tp=tp0, seq=self._n_enqueued,
                         host_gap_ms=host_gap_ms, idle_ms=idle_ms,
                         overlapped=False, queued=queued,
                         verify=bool(props),
@@ -1886,15 +1925,21 @@ class SlotScheduler:
             # concurrent _flushed() waiter sees this dispatch coming
             self._inflight_n += 1
         handle, err = None, None
-        try:
-            with self._engine_lock:
-                handle = eng.slot_step_async(
-                    None, pos2, np.ones((b,), np.int32),
-                    temps_np=cur.temps, topps_np=cur.topps,
-                    topks_np=cur.topks, steps=steps2,
-                    page_tables_np=ptab, feed_dev=cur.handle.last_dev)
-        except Exception as e:
-            err = e
+        self._n_enqueued += 1
+        with obs_trace.span("sched.enqueue", seq=self._n_enqueued,
+                            overlapped=True, t=1, steps=steps2,
+                            rows=len(cur.active), prefill_rows=0,
+                            verify=False,
+                            rids=sorted(cur.rid_by_slot.values())):
+            try:
+                with self._engine_lock:
+                    handle = eng.slot_step_async(
+                        None, pos2, np.ones((b,), np.int32),
+                        temps_np=cur.temps, topps_np=cur.topps,
+                        topks_np=cur.topks, steps=steps2,
+                        page_tables_np=ptab, feed_dev=cur.handle.last_dev)
+            except Exception as e:
+                err = e
         if err is not None:
             with self._cond:
                 self._inflight_n -= 1
@@ -1912,7 +1957,7 @@ class SlotScheduler:
                         prefset=set(),
                         rid_by_slot=dict(cur.rid_by_slot), fed_by_slot={},
                         pos_rows=pos2, enq_tp=time.perf_counter(),
-                        t0_mono=time.monotonic(), host_gap_ms=0.0,
+                        seq=self._n_enqueued, host_gap_ms=0.0,
                         idle_ms=0.0, overlapped=True, queued=0)
 
     def _attribute_cost(self, cur: _Pending, wall_ms: float) -> None:
@@ -1968,16 +2013,25 @@ class SlotScheduler:
         and fan the tokens out to their tickets.  Returns False when the
         dispatch errored (every active slot retires with the error and
         the pipeline round ends)."""
-        eng = self.engine
-        b = eng.batch
         tw = time.perf_counter()
         error, out = cur.error, None
         if error is None:
-            try:
-                out = cur.handle.wait()
-            except Exception as e:
-                error = e
+            with obs_trace.span("sched.land_wait", seq=cur.seq):
+                try:
+                    out = cur.handle.wait()
+                except Exception as e:
+                    error = e
         tp1 = time.perf_counter()
+        with obs_trace.span("sched.fanout", seq=cur.seq,
+                            rids=sorted(cur.rid_by_slot.values())):
+            return self._account_and_fanout(cur, out, error, tw, tp1)
+
+    def _account_and_fanout(self, cur: _Pending, out, error, tw: float,
+                            tp1: float) -> bool:
+        """:meth:`_land_and_fanout` after the wait (``tw`` to ``tp1``):
+        the goodput clock, the step counters, the tokens' fan-out."""
+        eng = self.engine
+        b = eng.batch
         prev_end = self._last_dispatch_end
         self._last_dispatch_end = tp1
         if cur.handle is not None:
@@ -2029,6 +2083,9 @@ class SlotScheduler:
         self._account("prefill", wall_ms * n_pref / b)
         self._account("decode", wall_ms * (n_act - n_pref) / b)
         self._account("pad", wall_ms * (b - n_act) / b)
+        kind = "verify" if cur.verify else "mixed" if n_pref else "decode"
+        obs_metrics.SCHED_STEPS.inc(kind)
+        obs_metrics.SCHED_STEP_WALL_MS.inc(kind, n=wall_ms)
         busy = self._comp["prefill"] + self._comp["decode"]
         total = sum(self._comp.values())
         if total > 0:
@@ -2051,24 +2108,20 @@ class SlotScheduler:
                     self._retire(i, "error", error=error)
             return False
         self._note_step_time(wall_ms, cur.steps, cur.handle.fresh)
-        if self.engine.mesh.shape.get("tp", 1) > 1:
-            # sample the mesh's all-reduce latency alongside real decode
-            # traffic (rate-limited inside probe_collective) so the
-            # engine_collective_ms histogram reflects the serving mesh
-            # under load, not an idle microbenchmark
-            with self._engine_lock:
-                self.engine.probe_collective()
+        # enqueue to fan-out is not one block of code (a pipelined
+        # dispatch is enqueued a round before it lands), so these two are
+        # ring records; a profile shows the parts, tied by ``seq``
         if cur.verify:
             preds, accepted = out
             n_prop = sum(cur.proposed_by_slot.values())
             n_acc = sum(int(accepted[i]) for i in cur.proposed_by_slot)
-            obs_trace.record("sched_verify", cur.t0_mono, time.monotonic(),
-                             active=n_act, queued=cur.queued,
+            obs_trace.record("sched_verify", cur.enq_tp, time.perf_counter(),
+                             seq=cur.seq, active=n_act, queued=cur.queued,
                              t=cur.t_width, proposed=n_prop, accepted=n_acc,
                              rids=sorted(cur.rid_by_slot.values()))
         else:
-            obs_trace.record("sched_step", cur.t0_mono, time.monotonic(),
-                             active=n_act, queued=cur.queued,
+            obs_trace.record("sched_step", cur.enq_tp, time.perf_counter(),
+                             seq=cur.seq, active=n_act, queued=cur.queued,
                              t=cur.t_width, steps=cur.steps,
                              overlapped=cur.overlapped,
                              rids=sorted(cur.rid_by_slot.values()))
@@ -2170,13 +2223,15 @@ class SlotScheduler:
         reuse.  The sampler RNG tick it consumed is not rewound: sampled
         draws are co-scheduling-dependent by contract (module
         docstring); greedy rows never touch the stream."""
-        try:
-            nxt.handle.wait()
-        except Exception as e:
-            # the discarded dispatch owns its own failure — nothing was
-            # emitted from it; the next live dispatch re-probes the device
-            _log.error("discarded in-flight dispatch failed", extra={
-                "error": repr(e)})
+        with obs_trace.span("sched.land_wait", seq=nxt.seq, discarded=True):
+            try:
+                nxt.handle.wait()
+            except Exception as e:
+                # the discarded dispatch owns its own failure — nothing
+                # was emitted from it; the next live dispatch re-probes
+                # the device
+                _log.error("discarded in-flight dispatch failed", extra={
+                    "error": repr(e)})
         tp1 = time.perf_counter()
         prev_end = self._last_dispatch_end
         self._last_dispatch_end = tp1
@@ -2190,8 +2245,11 @@ class SlotScheduler:
             self._inflight_n -= 1
             self._cond.notify_all()
         wall_ms = max(tp1 - prev_end, 0.0) * 1e3
-        # burned device capacity, not goodput
+        # burned device capacity, not goodput; still a landed decode
+        # dispatch, so the step counters keep summing to the clock
         self._account("pad", wall_ms)
+        obs_metrics.SCHED_STEPS.inc("decode")
+        obs_metrics.SCHED_STEP_WALL_MS.inc("decode", n=wall_ms)
         obs_metrics.SCHED_OVERLAP_DISCARDS.inc()
         obs_flight.TIMELINE.record_step(
             ts=prev_end, wall_ms=wall_ms, steps=nxt.steps, t_width=1,

@@ -116,10 +116,13 @@ def sample_on_device(logits, coins, temps, topps, topks, mask=None):
     import jax
     import jax.numpy as jnp
 
-    lf = logits.astype(jnp.float32)
-    v = lf.shape[-1]
-    if mask is not None:
-        lf = jnp.where(jnp.asarray(mask).astype(bool), lf, -jnp.inf)
+    from .ops.scopes import scope
+
+    with scope("sample"):
+        lf = logits.astype(jnp.float32)
+        v = lf.shape[-1]
+        if mask is not None:
+            lf = jnp.where(jnp.asarray(mask).astype(bool), lf, -jnp.inf)
 
     def row(lr, coin, temp, topp, topk):
         # top-k: k-th largest value as threshold, ties at the bar survive
@@ -147,7 +150,9 @@ def sample_on_device(logits, coins, temps, topps, topks, mask=None):
         sampled = jnp.where(use_topp, topp_tok, mult_tok)
         return jnp.where(temp == 0.0, greedy_tok, sampled)
 
-    return jax.vmap(row)(lf, coins, temps, topps, topks.astype(jnp.int32))
+    with scope("sample"):
+        return jax.vmap(row)(lf, coins, temps, topps,
+                             topks.astype(jnp.int32))
 
 
 class Sampler:

@@ -1577,18 +1577,16 @@ def make_handler(state: ApiState):
                     if aborted[0]:
                         return
                     try:
-                        e0 = time.perf_counter()
-                        FAULTS.fire("server.emit_delta")
-                        chunk = {"id": cid, "object": "text_completion",
-                                 "created": created, "model": state.model_name,
-                                 "choices": [{"text": delta, "index": idx,
-                                              "finish_reason": finish,
-                                              "logprobs": None}]}
-                        self.wfile.write(
-                            f"data: {json.dumps(chunk)}\n\n".encode())
-                        self.wfile.flush()
-                        obs_trace.record("emit", e0, time.perf_counter(),
-                                         idx=idx)
+                        with obs_trace.span("api.emit", idx=idx):
+                            FAULTS.fire("server.emit_delta")
+                            chunk = {"id": cid, "object": "text_completion",
+                                     "created": created, "model": state.model_name,
+                                     "choices": [{"text": delta, "index": idx,
+                                                  "finish_reason": finish,
+                                                  "logprobs": None}]}
+                            self.wfile.write(
+                                f"data: {json.dumps(chunk)}\n\n".encode())
+                            self.wfile.flush()
                         if timer is not None:
                             timer.tick()
                         if finish == "timeout":
@@ -1948,18 +1946,17 @@ def make_handler(state: ApiState):
                     if aborted[0]:
                         return
                     try:
-                        e0 = time.perf_counter()
-                        FAULTS.fire("server.emit_delta")
-                        chunk = {"id": cid, "object": "text_completion",
-                                 "created": created,
-                                 "model": state.model_name,
-                                 "choices": [{"text": delta, "index": 0,
-                                              "finish_reason": finish,
-                                              "logprobs": None}]}
-                        self.wfile.write(
-                            f"data: {json.dumps(chunk)}\n\n".encode())
-                        self.wfile.flush()
-                        obs_trace.record("emit", e0, time.perf_counter())
+                        with obs_trace.span("api.emit"):
+                            FAULTS.fire("server.emit_delta")
+                            chunk = {"id": cid, "object": "text_completion",
+                                     "created": created,
+                                     "model": state.model_name,
+                                     "choices": [{"text": delta, "index": 0,
+                                                  "finish_reason": finish,
+                                                  "logprobs": None}]}
+                            self.wfile.write(
+                                f"data: {json.dumps(chunk)}\n\n".encode())
+                            self.wfile.flush()
                         if timer is not None:
                             timer.tick()
                         if finish == "timeout":
@@ -2047,19 +2044,18 @@ def make_handler(state: ApiState):
                     if aborted[0] or not delta:
                         return
                     try:
-                        e0 = time.perf_counter()
-                        FAULTS.fire("server.emit_delta")
-                        chunk = {"id": cid,
-                                 "object": "chat.completion.chunk",
-                                 "created": created,
-                                 "model": state.model_name,
-                                 "choices": [{"index": 0,
-                                              "delta": {"content": delta},
-                                              "finish_reason": None}]}
-                        self.wfile.write(
-                            f"data: {json.dumps(chunk)}\n\n".encode())
-                        self.wfile.flush()
-                        obs_trace.record("emit", e0, time.perf_counter())
+                        with obs_trace.span("api.emit"):
+                            FAULTS.fire("server.emit_delta")
+                            chunk = {"id": cid,
+                                     "object": "chat.completion.chunk",
+                                     "created": created,
+                                     "model": state.model_name,
+                                     "choices": [{"index": 0,
+                                                  "delta": {"content": delta},
+                                                  "finish_reason": None}]}
+                            self.wfile.write(
+                                f"data: {json.dumps(chunk)}\n\n".encode())
+                            self.wfile.flush()
                         if timer is not None:
                             timer.tick()
                     except OSError:
@@ -2283,7 +2279,6 @@ def make_handler(state: ApiState):
                                state.retry_after_hint())})
                 return
             t0 = time.monotonic()
-            tp0 = time.perf_counter()
             deadline = state.request_deadline(body)
             # stream timer starts at admission: queue wait counts into TTFT
             timer = _StreamTimer(rid=self._rid)
@@ -2297,6 +2292,10 @@ def make_handler(state: ApiState):
                 obs_flight.submit(self._rid, path=self.path,
                                   priority=prio_name)
             ok = False
+            # entered here and left in the finally below: the handler's
+            # whole try block is the span
+            req_span = obs_trace.span("api.request", path=self.path)
+            req_span.__enter__()
             try:
                 locked = False
                 use_sched = False
@@ -2336,11 +2335,11 @@ def make_handler(state: ApiState):
                     # cache; the wait here IS the admission queue
                     # try_enter bounded
                     q0 = time.perf_counter()
-                    if not locked:
-                        state.engine_lock.acquire()
+                    with obs_trace.span("api.lock_wait"):
+                        if not locked:
+                            state.engine_lock.acquire()
                     q1 = time.perf_counter()
                     obs_metrics.QUEUE_WAIT.observe(q1 - q0)
-                    obs_trace.record("queue_wait", q0, q1)
                     obs_flight.admit(self._rid, queued_ms=(q1 - q0) * 1e3)
                     _log.info("queue", extra={"wait_s": round(q1 - q0, 6)})
                     try:
@@ -2389,8 +2388,7 @@ def make_handler(state: ApiState):
                 raise  # surface in the server log — a 500 is a bug to fix
             finally:
                 state.leave(time.monotonic() - t0)
-                obs_trace.record("request", tp0, time.perf_counter(),
-                                 path=self.path)
+                req_span.__exit__(None, None, None)
                 # fallback close for any path that didn't retire with a
                 # specific finish (no-op when one already did)
                 obs_flight.retire(self._rid, "served" if ok else "error")
@@ -2455,17 +2453,16 @@ def make_handler(state: ApiState):
                     if aborted[0]:
                         return
                     try:
-                        e0 = time.perf_counter()
-                        FAULTS.fire("server.emit_delta")
-                        chunk = {"id": cid, "object": "chat.completion.chunk",
-                                 "created": created, "model": state.model_name,
-                                 "choices": [{"index": 0,
-                                              "delta": {"content": delta},
-                                              "finish_reason": None}]}
-                        self.wfile.write(
-                            f"data: {json.dumps(chunk)}\n\n".encode())
-                        self.wfile.flush()
-                        obs_trace.record("emit", e0, time.perf_counter())
+                        with obs_trace.span("api.emit"):
+                            FAULTS.fire("server.emit_delta")
+                            chunk = {"id": cid, "object": "chat.completion.chunk",
+                                     "created": created, "model": state.model_name,
+                                     "choices": [{"index": 0,
+                                                  "delta": {"content": delta},
+                                                  "finish_reason": None}]}
+                            self.wfile.write(
+                                f"data: {json.dumps(chunk)}\n\n".encode())
+                            self.wfile.flush()
                         if timer is not None:
                             timer.tick()
                     except OSError:

@@ -129,16 +129,14 @@ def test_buffer_env_malformed_warns_once(monkeypatch):
         logger.removeHandler(h)
 
 
-def test_buffer_env_legacy_alias(monkeypatch):
+def test_buffer_env_has_one_name(monkeypatch):
+    """``DLLAMA_TRACE_BUFFER`` is the ring's one variable: the old
+    ``DLLAMA_TRACE_CAPACITY`` alias is gone and is not read."""
     monkeypatch.delenv("DLLAMA_TRACE_BUFFER", raising=False)
     monkeypatch.setenv("DLLAMA_TRACE_CAPACITY", "123")
-    assert obs_trace.parse_buffer_env(
-        "DLLAMA_TRACE_BUFFER", obs_trace.DEFAULT_CAPACITY,
-        legacy="DLLAMA_TRACE_CAPACITY") == 123
-    monkeypatch.setenv("DLLAMA_TRACE_BUFFER", "456")  # new name wins
-    assert obs_trace.parse_buffer_env(
-        "DLLAMA_TRACE_BUFFER", obs_trace.DEFAULT_CAPACITY,
-        legacy="DLLAMA_TRACE_CAPACITY") == 456
+    assert obs_trace._capacity() == obs_trace.DEFAULT_CAPACITY
+    monkeypatch.setenv("DLLAMA_TRACE_BUFFER", "456")
+    assert obs_trace._capacity() == 456
 
 
 # --- SLO engine unit tests (no server, no jax) ----------------------------

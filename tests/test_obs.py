@@ -472,8 +472,9 @@ def test_debug_trace_endpoint(api):
         doc = json.loads(r.read())
     xs = [e for e in doc["traceEvents"] if e.get("ph") == "X"]
     names = {e["name"] for e in xs}
-    assert {"request", "queue_wait", "prefill"} <= names, names
-    assert "decode_chunk" in names or "decode_step" in names, names
+    assert {"api.request", "api.lock_wait", "engine.prefill"} <= names, names
+    assert "engine.chunk_fetch" in names or "engine.decode_step" in names, \
+        names
     for e in xs:  # chrome trace_event essentials
         assert e["ph"] == "X" and "ts" in e and "dur" in e
         assert e["pid"] == 1 and isinstance(e["tid"], int)
@@ -492,7 +493,7 @@ def test_trace_dump_cli(api, tmp_path, capsys):
     assert tool.main([base, "-o", str(out), "-n", "5"]) == 0
     doc = json.loads(out.read_text())
     names = {e["name"] for e in doc["traceEvents"] if e.get("ph") == "X"}
-    assert {"request", "queue_wait"} <= names
+    assert {"api.request", "api.lock_wait"} <= names
     printed = capsys.readouterr().out
     assert "spans across" in printed
     # unreachable server → clean failure, not a traceback

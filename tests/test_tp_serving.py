@@ -21,10 +21,7 @@ mode:
   page leaks (``pool.check()``);
 * **ledger hygiene** — building a tp>1 engine on a non-TPU backend
   records the ``tp_psum`` degrade (the fused collective-matmul ring is
-  TPU-only), same treatment as ``blocked_ignored_mesh``;
-* **collective probe** — ``Engine.probe_collective`` lands a sample in
-  the ``engine_collective_ms`` histogram on tp>1 and stays silent on
-  tp=1.
+  TPU-only), same treatment as ``blocked_ignored_mesh``.
 
 Config note: the suite's usual ``tiny_config`` only shards to tp=2
 (n_kv_heads=2); this file widens it to n_kv_heads=4 / hidden_dim=128 so
@@ -239,20 +236,6 @@ def test_tp_engine_on_cpu_records_psum_degrade():
         assert obs_dispatch.reasons().get("q40:tp_psum", 0) == 0
     finally:
         obs_dispatch.reset()
-
-
-def test_probe_collective_feeds_histogram():
-    eng = make_engine(2)
-    before = obs_metrics.ENGINE_COLLECTIVE_MS.count
-    ms = eng.probe_collective()
-    assert ms is not None and ms >= 0.0
-    assert obs_metrics.ENGINE_COLLECTIVE_MS.count == before + 1
-    # rate limit: an immediate second probe declines
-    assert eng.probe_collective() is None
-    # tp=1: nothing to measure
-    e1 = make_engine(1)
-    assert e1.probe_collective() is None
-    assert obs_metrics.ENGINE_COLLECTIVE_MS.count == before + 1
 
 
 def test_constraint_error_names_valid_degrees():

@@ -79,7 +79,10 @@ def test_q40_matmul_compiles_at_7b_shapes(one_chip, name, n, d, stacked, rows):
             x, qp, sc, layer).compile()
     else:
         compiled = jax.jit(q40._pallas_matmul).lower(x, qp, sc).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    # the kernel's family is its name in the trace (docs/OBSERVABILITY.md)
+    assert ("q40_mm_stacked" if stacked else "q40_mm") in text
 
 
 @pytest.mark.parametrize("quantized", [False, True], ids=["dense", "int8"])
@@ -98,8 +101,57 @@ def test_fused_paged_attention_compiles_at_7b_geometry(one_chip, quantized):
             s((b, maxp), jnp.int32), s((b,), jnp.int32)]
     if quantized:
         args += [scale, scale]
-    compiled = jax.jit(f).lower(*args).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    text = jax.jit(f).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text and "paged_attn_fused" in text
+
+
+def test_paged_slot_step_names_its_pool_copy(one_chip, monkeypatch):
+    """A 2-layer paged slot step (dense toy weights, 128-wide heads so the
+    fused attention kernel is chosen) compiled for the described chip: its
+    ops carry the program's scopes, and the whole-pool ``copy`` that XLA puts
+    beside the kernel (ROADMAP S3) is one of them, under ``kv_write``."""
+    import re
+    import time
+
+    from dllama_tpu.models import transformer as tf
+    from dllama_tpu.models.config import tiny_config
+    from dllama_tpu.models.params import param_shapes
+    from dllama_tpu.ops.scopes import SCOPES
+    from dllama_tpu.runtime.decode_loop import slot_chunk
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(jax, "device_count", lambda: 1)
+    cfg = tiny_config(dim=512, hidden_dim=1024, n_layers=2, n_heads=4,
+                      n_kv_heads=4, vocab_size=1024, seq_len=256,
+                      dtype=jnp.bfloat16)
+    s = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)  # noqa: E731
+    params = {k: s(shape, jnp.float32 if k.startswith("rms") else jnp.bfloat16)
+              for k, shape in param_shapes(cfg).items()}
+    b, n_pages, ps = 4, 65, 16
+    pool = tf.KVCache(*(s((2, n_pages, 4, ps, 128), jnp.bfloat16),) * 2)
+    vec = lambda dt: s((b,), dt)  # noqa: E731
+    t0 = time.monotonic()
+    text = jax.jit(
+        lambda p, c, tok, pr, nv, k, tm, tp, tk, pt: slot_chunk(
+            p, cfg, c, tok, pr, nv, k, tm, tp, tk, steps=1, greedy=True,
+            page_table=pt), donate_argnums=(1,)).lower(
+        params, pool, s((b, 1), jnp.int32), vec(jnp.int32), vec(jnp.int32),
+        s((2,), jnp.uint32), vec(jnp.float32), vec(jnp.float32),
+        vec(jnp.int32), s((b, 16), jnp.int32)).compile().as_text()
+    assert time.monotonic() - t0 < 30, "too slow for tier-1: drop this test"
+    assert "paged_attn_fused" in text
+    ops = re.findall(r"^\s*(?:ROOT )?%?([\w.\-]+) = (\S+) ([\w\-]+)\(.*?"
+                     r"op_name=\"([^\"]+)\"", text, re.M)
+    scope_of = lambda path: ([c for c in path.split("/") if c in SCOPES]  # noqa: E731
+                             or [None])[-1]
+    assert {"qkv", "kv_write", "attn", "wo", "w2", "head"} <= \
+        {scope_of(path) for _, _, _, path in ops}
+    pool_copies = [(name, path) for name, shape, op, path in ops
+                   if op == "copy" and f"[2,{n_pages},4,{ps},128]" in shape]
+    # the copy is a layout change between the scatter's pool layout and the
+    # kernel's; the PR that takes it out (S3) turns this into "no such copy"
+    assert pool_copies and all(scope_of(path) == "kv_write"
+                               for _, path in pool_copies), pool_copies
 
 
 @pytest.mark.parametrize("name,n,d", [("wo", 4096, 4096), ("w2", 11008, 4096)])
@@ -123,5 +175,6 @@ def test_tp4_col_matmul_compiles_on_described_mesh(topo, monkeypatch, name,
     def f(x, qp, sc, layer):
         return q40._sharded_matmul(x, qp, sc, layer, "col", mesh, False)
 
-    compiled = jax.jit(f).lower(x, qp, sc, layer).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    text = jax.jit(f).lower(x, qp, sc, layer).compile().as_text()
+    assert "tpu_custom_call" in text
+    assert "q40_mm_stacked" in text and "q40_ring" in text

@@ -4,7 +4,8 @@
 Fetches ``GET /debug/trace?last=N`` (dllama_tpu/obs/trace.py) and writes
 the Chrome ``trace_event`` JSON to a file loadable in ``chrome://tracing``
 or https://ui.perfetto.dev — the cheap first-line latency attribution for
-a live server (queue_wait / prefill / decode_chunk / emit / request spans
+a live server (api.request / api.lock_wait / engine.prefill /
+engine.chunk_fetch / api.emit / sched.* spans
 per request ID), no restart and no ``--profile-split`` XLA tracer needed.
 
 With ``--slots`` it also fetches ``GET /debug/timeline`` (the scheduler's
