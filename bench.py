@@ -854,8 +854,8 @@ def _attempt_body(name):
         # prompt-evaluation throughput (the reference's "evaluation" stat,
         # dllama.cpp:45-93; no published number to compare): one bucketed
         # forward over T tokens through the REAL dispatch (quant_impl
-        # "auto": prefill rows beyond PALLAS_MAX_ROWS take the XLA dequant
-        # path, which pipelines the unpack into the MXU dots)
+        # "auto": on one chip, rows beyond PALLAS_MAX_ROWS take the fused
+        # kernel over row blocks, q40._row_block)
         ms = _bench_prefill(_model_cfg("llama2-7b"))
         print(json.dumps({
             "metric": "llama2-7b q40 prefill tok/s (1 TPU chip, T=512)",

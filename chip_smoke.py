@@ -446,7 +446,7 @@ def child_kernels(rehearse: bool) -> None:
         qt = q40.QTensor(qp, jax.lax.bitcast_convert_type(sc, jnp.uint16),
                          (n, d))
         w = q40.QLayerView(qt, jnp.int32(1)) if stacked else qt
-        for rows in (1, 8):
+        for rows in (1, 8, 256):  # 256: the row-blocked form
             x = jax.random.normal(jax.random.fold_in(k3, rows), (rows, n),
                                   jnp.bfloat16)
             t0 = time.perf_counter()

@@ -26,7 +26,11 @@ block):
 
 Two matmul implementations:
 
-* ``pallas`` — the fused kernel.  A `pallas_call` is not auto-partitioned
+* ``pallas`` — the fused kernel, at every row count on a single device: up
+  to ``PALLAS_MAX_ROWS`` rows ride in one block (decode), more rows (the
+  served mixed step, prefill buckets) in row blocks of up to
+  ``ROW_BLOCK_MAX`` (:func:`_row_block`), each weight tile unpacked once
+  per block.  A `pallas_call` is not auto-partitioned
   by GSPMD, so on a multi-device mesh it runs **per shard under
   ``jax.shard_map``** (see :func:`_sharded_matmul`): the caller declares the
   weight's TP slicing ``kind`` — ``"row"`` (output dim sharded, the
@@ -38,9 +42,10 @@ Two matmul implementations:
   quantization block on either axis.
 * ``xla``   — plain-jnp emulation (unpack → scale → dot).  Partitionable
   under GSPMD (reshapes split the sharded axis at block granularity), used
-  for prefill (compute-bound anyway), CPU tests, and as the fallback when
-  shapes don't divide the mesh evenly.  XLA materializes the dequantized
-  operand, so it is not the fast path for decode.
+  off the TPU (CPU tests), on a mesh above ``PALLAS_MAX_ROWS`` rows, and as
+  the fallback when shapes don't divide the mesh evenly.  XLA materializes
+  the dequantized operand in HBM — measured on the v5e at 3× the kernel's
+  time for a 256-row step (PERF.md §6, PR 25) — so it is no fast path.
 
 Activations stay bf16 — the TPU analogue of the reference's Q80 activation
 quantization (whose purpose is wire compression, tasks.cpp:124-163; on a
@@ -70,9 +75,19 @@ from ..parallel.mesh import get_active_mesh
 # tools/sweep_q40.py can explore the tile space on hardware without edits.
 TILE_N = int(os.environ.get("DLLAMA_Q40_TILE_N", "1024"))
 TILE_D = int(os.environ.get("DLLAMA_Q40_TILE_D", "1024"))
-# Decode uses the Pallas kernel; past this many rows the matmul is MXU-bound
-# and the XLA path (which can pipeline the dequant) is preferable.
+# Up to this many rows the fused kernel holds every activation row in one
+# block (all decode programs).  Above it, on a single device, the same kernel
+# runs over row blocks (_row_block); a device mesh, the blocked layout and
+# Q80 still send more rows to the XLA path, untimed (PERF.md §7).  The chip
+# says XLA does not pipeline the dequant: it writes each layer's weight to
+# HBM as bf16 and reads it back for one multiply (PERF.md §6, PR 25).
 PALLAS_MAX_ROWS = 128
+# Row-blocked form: the most rows one pass over the weights serves, the VMEM
+# its row-sized buffers may take, and the kernel's scoped-VMEM limit (the
+# default scope is 16 MiB of the v5e's 128).
+ROW_BLOCK_MAX = 1024
+ROW_BLOCK_VMEM = 32 * 1024 * 1024
+ROW_VMEM_LIMIT = 64 * 1024 * 1024
 # Kernel dequant variant (see _q40_kernel): classic | fma | folded | exact.
 KERNEL_VARIANT = os.environ.get("DLLAMA_Q40_VARIANT", "classic")
 
@@ -327,8 +342,11 @@ def dequantize(qt: QTensor, dtype=jnp.float32) -> jax.Array:
 # ---------------------------------------------------------------------------
 
 def _q40_kernel(xlo_ref, xhi_ref, bsum_ref, qp_ref, s_ref, o_ref, acc_ref, *,
-                nsteps, variant):
-    """One (tile_n × tile_d) fused dequant-matmul step.
+                nsteps, variant, n_axis=1):
+    """One (tile_n × tile_d) fused dequant-matmul step: the weight tile is
+    unpacked once and contracted against every activation row of the block
+    (all rows, or one row block of the row-blocked form, whose reduction
+    axis is grid axis ``n_axis`` = 2).
 
     The lo/hi nibble planes are contracted by two separate dots against the
     matching halves of x (prepared outside the kernel, where XLA fuses the
@@ -367,7 +385,7 @@ def _q40_kernel(xlo_ref, xhi_ref, bsum_ref, qp_ref, s_ref, o_ref, acc_ref, *,
     activation sums with two tiny MXU dots instead of a streamed ``xs``
     operand.
     """
-    i = pl.program_id(1)
+    i = pl.program_id(n_axis)
     qp = qp_ref[...]                                      # (tn/2, td) uint8
     tn2, td = qp.shape[-2:]
     qp = qp.reshape(tn2, td)
@@ -446,10 +464,9 @@ def _q40_kernel(xlo_ref, xhi_ref, bsum_ref, qp_ref, s_ref, o_ref, acc_ref, *,
 
 
 def _stacked_q40_kernel(lidx_ref, xlo_ref, xhi_ref, bsum_ref, qp_ref, s_ref,
-                        o_ref, acc_ref, *, nsteps, variant):
+                        o_ref, acc_ref, **kw):
     del lidx_ref  # consumed by the index_maps
-    _q40_kernel(xlo_ref, xhi_ref, bsum_ref, qp_ref, s_ref, o_ref, acc_ref,
-                nsteps=nsteps, variant=variant)
+    _q40_kernel(xlo_ref, xhi_ref, bsum_ref, qp_ref, s_ref, o_ref, acc_ref, **kw)
 
 
 def _x_parts(x: jax.Array) -> tuple[jax.Array, jax.Array]:
@@ -530,54 +547,117 @@ def _tiles(n: int, d: int, cap_elems: int = 4 * 1024 * 1024) -> tuple[int, int]:
     return tile_n, tile_d
 
 
-@functools.partial(jax.jit, static_argnames=("interpret", "variant", "tiles"))
-def _pallas_matmul(x: jax.Array, qpacked: jax.Array, scales: jax.Array,
-                   interpret: bool = False, variant: str | None = None,
-                   tiles: tuple[int, int] | None = None) -> jax.Array:
-    """x (t, n_padded) @ packed (n_padded/2, d) → (t, d) f32.
+def _row_block(t: int, tile_n: int, tile_d: int, variant: str) -> int | None:
+    """Rows per block of the row-blocked form; None up to PALLAS_MAX_ROWS,
+    where one block holds every row and the program is the one it always was.
 
-    ``tiles`` forces a (tile_n, tile_d) choice (tile sweeps and tests)."""
-    t, n = x.shape
-    d = qpacked.shape[-1]
-    tile_n, tile_d = tiles or _tiles(n, d)
-    grid = (pl.cdiv(d, tile_d), n // tile_n)
-    variant = _check_variant(variant)
-    x_lo, x_hi = _x_parts(x.astype(jnp.bfloat16))
-    bsum = jnp.asarray(_bsum_mat(tile_n))
+    A block is as large as ROW_BLOCK_MAX and the VMEM budget allow, so the
+    served mixed step (16 slots x 16 tokens) and the 256-token prefill bucket
+    are one pass over the weights with one dequant per tile; more rows split
+    into equal blocks, each re-streaming the weights (at 256 rows and up a
+    block is MXU-bound, so the re-read is hidden).  Blocks are sublane-aligned
+    (lane-aligned for ``exact``, whose activations arrive transposed); the
+    ragged last block is masked on store like the ragged ``d`` edge."""
+    if t <= PALLAS_MAX_ROWS:
+        return None
+    # per row: both activation halves and the output tile, double-buffered,
+    # the f32 accumulator and the two dots' f32 results
+    per_row = 2 * 2 * (tile_n // 2) * 2 + 5 * tile_d * 4
+    cap = min(ROW_BLOCK_MAX, max(256, ROW_BLOCK_VMEM // per_row // 128 * 128))
+    align = 128 if variant == "exact" else 16
+    return -(-pl.cdiv(t, pl.cdiv(t, cap)) // align) * align
+
+
+def _mm_call(t: int, n: int, d: int, tile_n: int, tile_d: int, variant: str,
+             stacked: bool, row_block: int | None, **ms):
+    """What the flat and the stacked kernel share of their ``pallas_call``:
+    grid and specs (as keywords), compiler parameters, and the kernel's own
+    keywords.  The grid is ``(d tiles, n steps)`` with every row in the block
+    up to PALLAS_MAX_ROWS, else ``(row blocks, d tiles, n steps)``."""
+    tr = row_block or _row_block(t, tile_n, tile_d, variant)
+    nd, nn = pl.cdiv(d, tile_d), n // tile_n
+    if tr is None:
+        grid, tb = (nd, nn), t
+        at = lambda f: lambda j, i, *l: f(0, j, i, *l)  # noqa: E731
+        params = pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"))
+    else:
+        grid, tb = (pl.cdiv(t, tr), nd, nn), tr
+        at = lambda f: f  # noqa: E731
+        params = pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=ROW_VMEM_LIMIT)
+    lead = (1,) if stacked else ()
+    layer = lambda l: tuple(ref[0] for ref in l)  # noqa: E731 — prefetched index
+    w_at = at(lambda r, j, i, *l: layer(l) + (i, j))
+    nb = tile_n // 32
     if variant == "exact":
         # transposed activation planes + transposed summing matrix: lets
         # the kernel's per-block reshapes regroup sublanes only (the lane
         # regroup of the original form does not lower under Mosaic)
-        x_lo, x_hi, bsum = x_lo.T, x_hi.T, bsum.T
-        xspec = pl.BlockSpec((tile_n // 2, t), lambda j, i: (i, 0),
-                             memory_space=pltpu.VMEM)
+        xspec = pl.BlockSpec((tile_n // 2, tb), at(lambda r, j, i, *l: (i, r)), **ms)
+        bshape = (nb, tile_n // 2)
     else:
-        xspec = pl.BlockSpec((t, tile_n // 2), lambda j, i: (0, i),
-                             memory_space=pltpu.VMEM)
-    return pl.pallas_call(
-        functools.partial(_q40_kernel, nsteps=grid[1], variant=variant),
+        xspec = pl.BlockSpec((tb, tile_n // 2), at(lambda r, j, i, *l: (r, i)), **ms)
+        bshape = (tile_n // 2, nb)
+    grid_kw = dict(
         grid=grid,
         in_specs=[
             xspec,
             xspec,
-            pl.BlockSpec(bsum.shape, lambda j, i: (0, 0), memory_space=pltpu.VMEM),
-            pl.BlockSpec((tile_n // 2, tile_d), lambda j, i: (i, j), memory_space=pltpu.VMEM),
-            pl.BlockSpec((tile_n // 32, tile_d), lambda j, i: (i, j), memory_space=pltpu.VMEM),
+            pl.BlockSpec(bshape, at(lambda r, j, i, *l: (0, 0)), **ms),
+            pl.BlockSpec(lead + (tile_n // 2, tile_d), w_at, **ms),
+            pl.BlockSpec(lead + (nb, tile_d), w_at, **ms),
         ],
-        out_specs=pl.BlockSpec((t, tile_d), lambda j, i: (0, j), memory_space=pltpu.VMEM),
+        out_specs=pl.BlockSpec((tb, tile_d), at(lambda r, j, i, *l: (r, j)), **ms),
+        scratch_shapes=[pltpu.VMEM((tb, tile_d), jnp.float32)])
+    return grid_kw, params, dict(nsteps=nn, variant=variant,
+                                 n_axis=len(grid) - 1)
+
+
+def _mm_operands(x: jax.Array, tile_n: int, variant: str):
+    """The kernel's activation halves and block-summing matrix (transposed
+    for ``exact``)."""
+    x_lo, x_hi = _x_parts(x.astype(jnp.bfloat16))
+    bsum = jnp.asarray(_bsum_mat(tile_n))
+    if variant == "exact":
+        return x_lo.T, x_hi.T, bsum.T
+    return x_lo, x_hi, bsum
+
+
+@functools.partial(jax.jit, static_argnames=("interpret", "variant", "tiles",
+                                             "row_block"))
+def _pallas_matmul(x: jax.Array, qpacked: jax.Array, scales: jax.Array,
+                   interpret: bool = False, variant: str | None = None,
+                   tiles: tuple[int, int] | None = None,
+                   row_block: int | None = None) -> jax.Array:
+    """x (t, n_padded) @ packed (n_padded/2, d) → (t, d) f32.
+
+    ``tiles`` forces a (tile_n, tile_d) choice and ``row_block`` the rows of
+    a block of the row-blocked form (sweeps and tests)."""
+    t, n = x.shape
+    d = qpacked.shape[-1]
+    tile_n, tile_d = tiles or _tiles(n, d)
+    variant = _check_variant(variant)
+    grid_kw, params, kernel_kw = _mm_call(
+        t, n, d, tile_n, tile_d, variant, False, row_block,
+        memory_space=pltpu.VMEM)
+    return pl.pallas_call(
+        functools.partial(_q40_kernel, **kernel_kw),
+        **grid_kw,
         out_shape=jax.ShapeDtypeStruct((t, d), jnp.float32),
-        scratch_shapes=[pltpu.VMEM((t, tile_d), jnp.float32)],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
+        compiler_params=params,
         interpret=interpret,
         name="q40_mm",
-    )(x_lo, x_hi, bsum, qpacked, scales)
+    )(*_mm_operands(x, tile_n, variant), qpacked, scales)
 
 
-@functools.partial(jax.jit, static_argnames=("interpret", "variant"))
+@functools.partial(jax.jit, static_argnames=("interpret", "variant",
+                                             "row_block"))
 def _pallas_matmul_stacked(x: jax.Array, qpacked: jax.Array, scales: jax.Array,
                            layer: jax.Array, interpret: bool = False,
-                           variant: str | None = None) -> jax.Array:
+                           variant: str | None = None,
+                           row_block: int | None = None) -> jax.Array:
     """Layer-indexed matmul over layer-stacked packed weights.
 
     The layer index rides as a scalar-prefetch argument into the block
@@ -590,38 +670,19 @@ def _pallas_matmul_stacked(x: jax.Array, qpacked: jax.Array, scales: jax.Array,
     t, n = x.shape
     d = qpacked.shape[-1]
     tile_n, tile_d = _tiles(n, d)
-    grid = (pl.cdiv(d, tile_d), n // tile_n)
     variant = _check_variant(variant)
-    x_lo, x_hi = _x_parts(x.astype(jnp.bfloat16))
-    bsum = jnp.asarray(_bsum_mat(tile_n))
-    if variant == "exact":  # transposed operands — see _pallas_matmul
-        x_lo, x_hi, bsum = x_lo.T, x_hi.T, bsum.T
-        xspec = pl.BlockSpec((tile_n // 2, t), lambda j, i, l: (i, 0))
-    else:
-        xspec = pl.BlockSpec((t, tile_n // 2), lambda j, i, l: (0, i))
-    out = pl.pallas_call(
-        functools.partial(_stacked_q40_kernel, nsteps=grid[1],
-                          variant=variant),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=grid,
-            in_specs=[
-                xspec,
-                xspec,
-                pl.BlockSpec(bsum.shape, lambda j, i, l: (0, 0)),
-                pl.BlockSpec((1, tile_n // 2, tile_d), lambda j, i, l: (l[0], i, j)),
-                pl.BlockSpec((1, tile_n // 32, tile_d), lambda j, i, l: (l[0], i, j)),
-            ],
-            out_specs=pl.BlockSpec((t, tile_d), lambda j, i, l: (0, j)),
-            scratch_shapes=[pltpu.VMEM((t, tile_d), jnp.float32)],
-        ),
+    grid_kw, params, kernel_kw = _mm_call(
+        t, n, d, tile_n, tile_d, variant, True, row_block)
+    operands = _mm_operands(x, tile_n, variant)
+    return pl.pallas_call(
+        functools.partial(_stacked_q40_kernel, **kernel_kw),
+        grid_spec=pltpu.PrefetchScalarGridSpec(num_scalar_prefetch=1,
+                                               **grid_kw),
         out_shape=jax.ShapeDtypeStruct((t, d), jnp.float32),
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
+        compiler_params=params,
         interpret=interpret,
         name="q40_mm_stacked",
-    )(layer.reshape(1).astype(jnp.int32), x_lo, x_hi, bsum, qpacked, scales)
-    return out
+    )(layer.reshape(1).astype(jnp.int32), *operands, qpacked, scales)
 
 
 @dataclass(frozen=True)
@@ -1160,9 +1221,12 @@ def _auto_pallas(np_: int, d: int, rows: int, kind: str | None) -> bool:
     executed: a Mosaic lowering or runtime error in the chosen kernel
     propagates and fails the run; values are checked on the chip by
     chip_smoke.py."""
-    if rows > PALLAS_MAX_ROWS:
-        return False
     mesh = _smap_mesh()
+    if rows > PALLAS_MAX_ROWS and mesh is not None:
+        # a mesh keeps the cap: the per-shard call would hand the ring reduce
+        # a partial of more rows than its comm scratch has ever held, and no
+        # cell can time it yet (PERF.md §7); one device takes row blocks
+        return False
     tp = mesh.shape.get("tp", 1) if mesh is not None else 1
     local_n, local_d = np_, d
     if mesh is not None:

@@ -288,6 +288,70 @@ class TestScaleValidation:
             q40.pack_file_groups([[(bad.reshape(d, -1), d, n)]], stacked=False)
 
 
+class TestRowBlocks:
+    """Over PALLAS_MAX_ROWS rows the fused kernel runs over row blocks:
+    same rounding as the XLA path (bf16 dequant, f32 accumulation), another
+    summation order."""
+
+    @staticmethod
+    def _case(form, n, d, rows):
+        lead = {"plain": (), "stacked": (3,), "experts": (2, 3)}[form]
+        qt = q40.quantize(_rand((*lead, n, d), seed=11))
+        x = jnp.asarray(_rand((rows, n), seed=rows, scale=1.0), jnp.bfloat16)
+        if form == "plain":
+            return x, qt, qt
+        view = q40.QLayerView(qt, jnp.int32(1))
+        if form == "experts":  # (L, E, n/2, d): layer 1, expert 2
+            view = view.select(jnp.int32(2), 3)
+        return x, qt, view
+
+    @pytest.mark.parametrize("rows", [129, 256, 272, 1024])
+    @pytest.mark.parametrize("form,n", [("plain", 1024), ("plain", 1056),
+                                        ("stacked", 1056), ("experts", 1024)])
+    def test_matches_xla(self, form, n, rows):
+        """n=1056 is stored padded to 2048 rows of zero scales."""
+        x, _, w = self._case(form, n, 384, rows)
+        got = np.asarray(q40.matmul(x, w, impl="pallas_interpret",
+                                    out_dtype=jnp.float32))
+        ref = np.asarray(q40.matmul(x, w, impl="xla", out_dtype=jnp.float32))
+        np.testing.assert_allclose(got, ref, rtol=0,
+                                   atol=1e-4 * np.abs(ref).max())
+
+    @pytest.mark.parametrize("form", ["plain", "stacked"])
+    def test_ragged_last_block_is_masked(self, form):
+        """272 rows in blocks of 256: the last block holds 16 rows and 240
+        of padding that must not reach the output."""
+        x, qt, w = self._case(form, 1056, 384, 272)
+        xp = q40._pad_x(x, 1056, 2048)
+        if form == "plain":
+            got = q40._pallas_matmul(xp, qt.qpacked, qt.scales,
+                                     interpret=True, row_block=256)
+        else:
+            got = q40._pallas_matmul_stacked(xp, qt.qpacked, qt.scales,
+                                             jnp.int32(1), interpret=True,
+                                             row_block=256)
+        ref = np.asarray(q40.matmul(x, w, impl="xla", out_dtype=jnp.float32))
+        assert got.shape == ref.shape and np.isfinite(np.asarray(got)).all()
+        np.testing.assert_allclose(np.asarray(got), ref, rtol=0,
+                                   atol=1e-4 * np.abs(ref).max())
+
+    @pytest.mark.parametrize("rows,variant,want", [
+        (1, "classic", None), (128, "classic", None),   # today's programs
+        (129, "classic", 144), (256, "classic", 256), (272, "classic", 272),
+        (600, "classic", 608), (1024, "classic", 1024),  # one block
+        (2048, "classic", 1024), (2500, "classic", 848),  # equal blocks
+        (129, "exact", 256),                            # rows on the lanes
+    ])
+    def test_row_block_rule(self, rows, variant, want):
+        assert q40._row_block(rows, 1024, 1024, variant) == want
+
+    def test_row_block_shrinks_to_the_vmem_budget(self):
+        """A 4096-wide output tile leaves room for fewer rows than
+        ROW_BLOCK_MAX, never fewer than 256."""
+        tr = q40._row_block(2048, 256, 4096, "classic")
+        assert 256 <= tr < q40.ROW_BLOCK_MAX and tr % 16 == 0
+
+
 class TestAutoChoice:
     """``impl="auto"`` is a static choice (platform, rows, mesh, tile
     legality): the same inside and outside a jit trace, and a kernel that
@@ -306,7 +370,7 @@ class TestAutoChoice:
     @pytest.mark.parametrize("np_,d,rows,kind,want", [
         (4096, 4096, 1, None, True),
         (4096, 4096, 128, None, True),
-        (4096, 4096, 129, None, False),    # prefill width: MXU-bound, XLA
+        (4096, 4096, 129, None, True),     # over 128 rows: row blocks
         (1408, 4096, 1, None, False),      # ladder lands on an illegal tile
     ])
     def test_auto_rule_single_device(self, np_, d, rows, kind, want):
@@ -320,6 +384,15 @@ class TestAutoChoice:
             assert q40._auto_pallas(4096, 32000, 1, "row") is True
             assert q40._auto_pallas(4096, 4096, 1, None) is False  # no kind
             assert q40._auto_pallas(96, 4096, 1, "col") is False   # splits a block
+
+    @pytest.mark.parametrize("rows,want", [(128, True), (256, False)])
+    def test_auto_rule_keeps_the_row_cap_on_a_mesh(self, rows, want):
+        """The row-blocked form is single-device: on a tp mesh more than
+        PALLAS_MAX_ROWS rows still take the GSPMD XLA path."""
+        from dllama_tpu.parallel.mesh import active_mesh, make_mesh
+        with active_mesh(make_mesh(tp=4, devices=jax.devices()[:4])):
+            assert q40._auto_pallas(4096, 4096, rows, "col") is want
+            assert q40._auto_pallas(4096, 32000, rows, "row") is want
 
     @pytest.mark.parametrize("codec", ["q40", "q8"])
     def test_auto_on_tpu_is_pallas_in_and_out_of_jit_and_raises(

@@ -67,7 +67,8 @@ def _q40_shapes(n, d, rows, stacked, sharding):
             s((*lead, np_ // 32, d), jnp.uint16))
 
 
-@pytest.mark.parametrize("rows", [1, 8])
+# 256 and 2048: the row-blocked form (one block; two of 1024)
+@pytest.mark.parametrize("rows", [1, 8, 256, 2048])
 @pytest.mark.parametrize("name,n,d,stacked", SHAPES_7B,
                          ids=[s[0] for s in SHAPES_7B])
 def test_q40_matmul_compiles_at_7b_shapes(one_chip, name, n, d, stacked, rows):
@@ -105,6 +106,31 @@ def test_fused_paged_attention_compiles_at_7b_geometry(one_chip, quantized):
     assert "tpu_custom_call" in text and "paged_attn_fused" in text
 
 
+def _slot_step_text(one_chip, cfg, params, b, t, n_pages, max_pages, ps=16):
+    """Compiled text of one paged slot step of (b, t) tokens for the
+    described chip."""
+    from dllama_tpu.models import transformer as tf
+    from dllama_tpu.runtime.decode_loop import slot_chunk
+
+    s = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)  # noqa: E731
+    pool = tf.KVCache(*(s((2, n_pages, 4, ps, 128), jnp.bfloat16),) * 2)
+    vec = lambda dt: s((b,), dt)  # noqa: E731
+    return jax.jit(
+        lambda p, c, tok, pr, nv, k, tm, tp, tk, pt: slot_chunk(
+            p, cfg, c, tok, pr, nv, k, tm, tp, tk, steps=1, greedy=True,
+            page_table=pt), donate_argnums=(1,)).lower(
+        params, pool, s((b, t), jnp.int32), vec(jnp.int32), vec(jnp.int32),
+        s((2,), jnp.uint32), vec(jnp.float32), vec(jnp.float32),
+        vec(jnp.int32), s((b, max_pages), jnp.int32)).compile().as_text()
+
+
+def _toy_cfg():
+    from dllama_tpu.models.config import tiny_config
+    return tiny_config(dim=512, hidden_dim=1024, n_layers=2, n_heads=4,
+                       n_kv_heads=4, vocab_size=1024, seq_len=256,
+                       dtype=jnp.bfloat16)
+
+
 def test_paged_slot_step_names_its_pool_copy(one_chip, monkeypatch):
     """A 2-layer paged slot step (dense toy weights, 128-wide heads so the
     fused attention kernel is chosen) compiled for the described chip: its
@@ -113,31 +139,18 @@ def test_paged_slot_step_names_its_pool_copy(one_chip, monkeypatch):
     import re
     import time
 
-    from dllama_tpu.models import transformer as tf
-    from dllama_tpu.models.config import tiny_config
     from dllama_tpu.models.params import param_shapes
     from dllama_tpu.ops.scopes import SCOPES
-    from dllama_tpu.runtime.decode_loop import slot_chunk
 
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     monkeypatch.setattr(jax, "device_count", lambda: 1)
-    cfg = tiny_config(dim=512, hidden_dim=1024, n_layers=2, n_heads=4,
-                      n_kv_heads=4, vocab_size=1024, seq_len=256,
-                      dtype=jnp.bfloat16)
+    cfg = _toy_cfg()
     s = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)  # noqa: E731
     params = {k: s(shape, jnp.float32 if k.startswith("rms") else jnp.bfloat16)
               for k, shape in param_shapes(cfg).items()}
-    b, n_pages, ps = 4, 65, 16
-    pool = tf.KVCache(*(s((2, n_pages, 4, ps, 128), jnp.bfloat16),) * 2)
-    vec = lambda dt: s((b,), dt)  # noqa: E731
+    n_pages, ps = 65, 16
     t0 = time.monotonic()
-    text = jax.jit(
-        lambda p, c, tok, pr, nv, k, tm, tp, tk, pt: slot_chunk(
-            p, cfg, c, tok, pr, nv, k, tm, tp, tk, steps=1, greedy=True,
-            page_table=pt), donate_argnums=(1,)).lower(
-        params, pool, s((b, 1), jnp.int32), vec(jnp.int32), vec(jnp.int32),
-        s((2,), jnp.uint32), vec(jnp.float32), vec(jnp.float32),
-        vec(jnp.int32), s((b, 16), jnp.int32)).compile().as_text()
+    text = _slot_step_text(one_chip, cfg, params, 4, 1, n_pages, 16)
     assert time.monotonic() - t0 < 30, "too slow for tier-1: drop this test"
     assert "paged_attn_fused" in text
     ops = re.findall(r"^\s*(?:ROOT )?%?([\w.\-]+) = (\S+) ([\w\-]+)\(.*?"
@@ -152,6 +165,51 @@ def test_paged_slot_step_names_its_pool_copy(one_chip, monkeypatch):
     # kernel's; the PR that takes it out (S3) turns this into "no such copy"
     assert pool_copies and all(scope_of(path) == "kv_write"
                                for _, path in pool_copies), pool_copies
+
+
+def test_mixed_slot_step_keeps_q40_on_the_fused_kernel(one_chip, monkeypatch):
+    """The served mixed step, 16 slots x a 16-token chunk = 256 rows, over a
+    2-layer toy model with Q40 weights, compiled for the described chip:
+    every Q40 site is the fused kernel (row-blocked above 128 rows), none the
+    XLA path, so no weight is written to HBM as bf16 (a ``convert``-rooted
+    fusion under ``w13``/``w2``, 2.67 of 4.62 device seconds before PR 25)."""
+    import re
+
+    from dllama_tpu.models.params import param_shapes
+    from dllama_tpu.obs import dispatch as obs_dispatch
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(jax, "device_count", lambda: 1)
+    cfg = _toy_cfg()
+    s = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)  # noqa: E731
+
+    def packed(*shapes):  # fused along the output dim, as load_params does
+        *lead, n, _ = shapes[0]
+        d = sum(sh[-1] for sh in shapes)
+        return q40.QTensor(s((*lead, n // 2, d), jnp.uint8),
+                           s((*lead, n // 32, d), jnp.uint16), (n, d))
+
+    sh = param_shapes(cfg)
+    params = {k: s(sh[k], jnp.float32 if k.startswith("rms") else jnp.bfloat16)
+              for k in ("embedding", "rms_att", "rms_ffn", "rms_final")}
+    params.update(wqkv=packed(sh["wq"], sh["wk"], sh["wv"]), wo=packed(sh["wo"]),
+                  w13=packed(sh["w1"], sh["w3"]), w2=packed(sh["w2"]),
+                  wcls=packed(sh["wcls"]))
+    obs_dispatch.reset()
+    try:
+        text = _slot_step_text(one_chip, cfg, params, 16, 16, 129, 8)
+        sites = obs_dispatch.dispatches()
+    finally:
+        obs_dispatch.reset()
+    assert sites.get("q40/pallas-fused", 0) >= 5, sites
+    assert "q40/xla-dequant" not in sites, sites
+    assert "q40_mm_stacked" in text and "q40_mm" in text
+    # fusions whose root converts, by the scope of the root's op_name
+    roots = re.findall(r"^\s*ROOT [^\n]*? (convert)\([^\n]*?op_name=\"([^\"]+)\"",
+                       text, re.M)
+    dequant = [path for _, path in roots
+               if {"w13", "w1", "w3", "w2"} & set(path.split("/"))]
+    assert not dequant, dequant
 
 
 @pytest.mark.parametrize("name,n,d", [("wo", 4096, 4096), ("w2", 11008, 4096)])

@@ -14,6 +14,7 @@ timing is stable to a few percent and matches the xplane per-op numbers.
 
 Usage: python tools/sweep_q40.py            # sweep and rank
        python tools/sweep_q40.py --one folded 1024 2048   # single config
+       python tools/sweep_q40.py --rows [head,w13]  # rows x row block, Mistral-7B shapes
 """
 
 from __future__ import annotations
@@ -167,7 +168,92 @@ def measure_one(variant: str, reps: int = 32, only: set | None = None) -> dict:
     return out
 
 
+# Mistral-7B's five matmuls: (name, n_in, d_out, stacked)
+ROWS_SHAPES = [("qkv", 4096, 6144, True), ("wo", 4096, 4096, True),
+               ("w13", 4096, 28672, True), ("w2", 14336, 4096, True),
+               ("head", 4096, 32768, False)]
+# (rows, row block): None is the code's own choice (one block of every row
+# up to 128, q40._row_block above), "xla" the dequantize-then-dot path
+ROWS_CONFIGS = [(64, None), (128, None), (64, 64), (128, 128), (256, 256),
+                (256, "xla"), (512, 256), (512, 512), (1024, 256),
+                (1024, 512), (1024, 1024), (2048, None)]
+
+
+def measure_rows(only: set | None = None, reps: int = 16,
+                 layers: int = 4) -> list[dict]:
+    """Time the fused kernel by row count and row block on one chip, inside
+    a jitted scan over the layer index as the model runs it.  One JSON line
+    per (shape, rows, row block); all of them to chiprun_out/sweep_rows.json."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    sys.path.insert(0, HERE)
+    from dllama_tpu.ops import q40
+
+    if jax.default_backend() != "tpu":
+        print(json.dumps({"error": "no TPU"}))
+        sys.exit(1)
+    rng = np.random.RandomState(0)
+    results = []
+    for name, n, d, stacked in ROWS_SHAPES:
+        if only and name not in only:
+            continue
+        L = layers if stacked else 1
+        qp = jnp.asarray(rng.randint(0, 256, (L, n // 2, d), dtype=np.uint8))
+        sc = jnp.asarray((rng.rand(L, n // 32, d).astype(np.float16)
+                          * 0.01).view(np.uint16))
+        qt = q40.QTensor(qp, sc, (n, d))
+        for rows, block in ROWS_CONFIGS:
+            x = jnp.asarray(rng.randn(rows, n).astype(np.float32), jnp.bfloat16)
+
+            def one(x, qp, sc, i, block=block):
+                if not stacked:
+                    # no layer index to vary: vary x, or XLA hoists the one
+                    # call out of the scan
+                    x = x + (i % 2).astype(x.dtype)
+                if block == "xla":
+                    w = q40.QLayerView(q40.QTensor(qp, sc, (n, d)), i % L)
+                    return q40.matmul(x, w, impl="xla", out_dtype=jnp.float32)
+                if stacked:
+                    return q40._pallas_matmul_stacked(x, qp, sc, i % L,
+                                                      row_block=block)
+                return q40._pallas_matmul(x, qp[0], sc[0], row_block=block)
+
+            @jax.jit
+            def run(x, qp, sc):
+                def body(acc, i):
+                    o = one(x, qp, sc, i)
+                    # a kernel is opaque and runs whole whatever is read of
+                    # it; XLA would push a slice into its dot, so read all
+                    return acc + (o if block == "xla" else o[:8, :128]).sum(), None
+                return jax.lax.scan(body, jnp.float32(0), jnp.arange(reps))[0]
+
+            rec = {"shape": name, "n": n, "d": d, "rows": rows, "block": block}
+            try:
+                float(run(x, qt.qpacked, qt.scales))  # compile + warm-up
+                best = float("inf")
+                for _ in range(3):
+                    t0 = time.perf_counter()
+                    float(run(x, qt.qpacked, qt.scales))
+                    best = min(best, (time.perf_counter() - t0) * 1000 / reps)
+                rec.update(ms=round(best, 4),
+                           tflops=round(2 * rows * n * d / best / 1e9, 1),
+                           us_per_row=round(best * 1000 / rows, 3))
+            except Exception as e:  # noqa: BLE001 — a form Mosaic refuses is a result
+                rec["error"] = f"{type(e).__name__}: {str(e)[:300]}"
+            print(json.dumps(rec), flush=True)
+            results.append(rec)
+    os.makedirs(os.path.join(HERE, "chiprun_out"), exist_ok=True)
+    with open(os.path.join(HERE, "chiprun_out", "sweep_rows.json"), "w") as f:
+        json.dump(results, f, indent=1)
+    return results
+
+
 def main():
+    if len(sys.argv) > 1 and sys.argv[1] == "--rows":
+        measure_rows(set(sys.argv[2].split(",")) if len(sys.argv) > 2 else None)
+        return
     # a deployed width-rule table would silently override the tiles under
     # test (every swept config would measure the rule's tiles and the sweep
     # could never contradict the current rules) — the sweep measures the
