@@ -354,8 +354,16 @@ def phase_tp(mpath: str, tpath: str, timeout: float, tp: int = 4,
         require(dev["count"] >= tp, f"tp: only {dev['count']} devices")
         require("q40/pallas-fused" in cmp_["ledger_tp"],
                 f"tp: no pallas-fused at tp={tp}: {cmp_['ledger_tp']}")
-        require(cmp_["reduce"] in ("tp_fused_reduce", "tp_psum"),
-                f"tp: no reduce path recorded: {cmp_}")
+        # 4096 % 256 == 0, so the rule (ops/q40.py _fused_reduce_ok) picks
+        # the ring for both column matmuls: it is the only reduce path
+        require(cmp_["reduce"] == "tp_fused_reduce"
+                and "tp_psum" not in cmp_["ledger_tp"],
+                f"tp: reduce path is not the fused ring alone: {cmp_}")
+        peaks = cmp_["load_peak_bytes"]
+        rest = max(peaks[1:])
+        require(rest > 0 and abs(peaks[0] - rest) <= 0.1 * rest,
+                f"tp: device 0 peaked at {peaks[0]} B after the load, the "
+                f"others at most {rest} B: a weight was staged whole")
     return dict(dev, compile=comp)
 
 
@@ -532,6 +540,10 @@ def child_tp(argv: list[str], rehearse: bool) -> None:
             ["inference", "--model", a.model, "--tokenizer", a.tokenizer,
              "--workers", f"tpu:{tp}", "--temperature", "0"])
         engine, tok = cli.load_stack(args)
+        # per-device peak right after the load: a loader that stages a
+        # whole stack on device 0 before sharding it shows here
+        load_peaks = [int((d.memory_stats() or {}).get("peak_bytes_in_use", 0))
+                      for d in engine.mesh.devices.flat]
         ids = tok.encode("hello hello hello", add_bos=engine.cfg.add_bos)
         logits, _ = engine.prefill(ids)
         for _ in range(2):  # the second pass is timed with programs compiled
@@ -545,7 +557,8 @@ def child_tp(argv: list[str], rehearse: bool) -> None:
         leaf = engine.params[wname].qpacked
         spread = (len(leaf.sharding.device_set),
                   len(engine.cache.k.sharding.device_set),
-                  [int(s) for s in leaf.addressable_shards[0].data.shape])
+                  [int(s) for s in leaf.addressable_shards[0].data.shape],
+                  load_peaks)
         return engine, ids, np.asarray(logits, np.float32)[0], toks, spread, \
             secs, obs_dispatch.summary_line()
 
@@ -573,6 +586,7 @@ def child_tp(argv: list[str], rehearse: bool) -> None:
           "first_divergence": div, "divergence_margin": margin,
           "divergence_within_tol": within, "weight_devices": spread[0],
           "cache_devices": spread[1], "weight_shard_shape": spread[2],
+          "load_peak_bytes": spread[3],
           "reduce": reduce, "ledger_tp": ledger_tp, "ledger_tp1": ledger_1,
           "smoke_decode_seconds_tp": round(secs_tp, 3),
           "smoke_decode_seconds_tp1": round(secs_1, 3), "decode_tokens": steps})

@@ -14,15 +14,15 @@ which replaces the reference's ``splitWeights`` + socket streaming
 
 from __future__ import annotations
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 
 from ..io import mfile
+from ..obs import trace as obs_trace
 from ..ops import q40, q8
 from .config import ModelConfig
 
-Params = dict  # pytree: str -> jnp.ndarray | q40.QTensor
+Params = dict  # pytree: str -> array (numpy until placed) | q40.QTensor
 
 
 def param_shapes(cfg: ModelConfig) -> dict[str, tuple[int, ...]]:
@@ -157,7 +157,7 @@ def _stack_q_experts(mf: mfile.MFile, cfg: ModelConfig, fname: str, codec=q40):
     if not np.isfinite(sc).all():  # same loud-failure rule as pack_file_groups
         raise ValueError(f"{fname}: expert scale plane contains inf/NaN f16 "
                          "scales — corrupt or overflowed .m tensor")
-    return cls(jnp.asarray(qp), jnp.asarray(sc.view(np.uint16)), (n, d))
+    return cls(qp, sc.view(np.uint16), (n, d))
 
 
 def load_params(mf: mfile.MFile, cfg: ModelConfig | None = None,
@@ -166,9 +166,14 @@ def load_params(mf: mfile.MFile, cfg: ModelConfig | None = None,
     """Load a `.m` file into the runtime layout.
 
     Mirrors ``Transformer::loadRoot`` (transformer.cpp:428-487) but instead
-    of streaming slices to workers, produces host arrays that the engine
-    places onto the mesh with shardings (upload happens once, sliced by
-    XLA, riding PCIe/ICI instead of the reference's TCP star).
+    of streaming slices to workers, produces **host (numpy) arrays** — the
+    leaves of every ``QTensor``/``Q8Tensor`` and the dense tensors alike —
+    that the engine places onto the mesh with shardings
+    (``parallel/sharding.py place_params``): nothing is committed to a
+    device here, so the upload happens once and each chip receives only
+    its own shard, straight from host memory over PCIe instead of the
+    reference's TCP star.  The host holds the file's stacks (about the
+    packed model size) until the engine has placed them.
 
     ``keep_quantized=True`` keeps Q40/Q80 matmul weights packed for their
     fused dequant-matmuls (ops/q40.py, ops/q8.py — the reference likewise
@@ -184,6 +189,12 @@ def load_params(mf: mfile.MFile, cfg: ModelConfig | None = None,
     """
     if cfg is None:
         cfg = ModelConfig.from_spec(mf.spec)
+    with obs_trace.span("engine.load_read", layers=cfg.n_layers):
+        return cfg, _read_params(mf, cfg, dtype, keep_quantized, fuse)
+
+
+def _read_params(mf: mfile.MFile, cfg: ModelConfig, dtype,
+                 keep_quantized: bool, fuse: bool) -> Params:
     if dtype is None:
         dtype = cfg.dtype
     np_dtype = np.dtype(jnp.dtype(dtype).name) if dtype != jnp.bfloat16 else jnp.bfloat16
@@ -240,5 +251,4 @@ def load_params(mf: mfile.MFile, cfg: ModelConfig | None = None,
             stacked=False)
     else:
         p["wcls"] = np.ascontiguousarray(mf.tensor("wcls").T).astype(np_dtype)
-    return cfg, {k: v if isinstance(v, (q40.QTensor, q8.Q8Tensor)) else jnp.asarray(v)
-                 for k, v in p.items()}
+    return p

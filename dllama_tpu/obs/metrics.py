@@ -662,6 +662,12 @@ HBM_BYTES_IN_USE = REGISTRY.labeled_gauge(
 HBM_BYTES_PEAK = REGISTRY.labeled_gauge(
     "hbm_bytes_peak", "device",
     "Per-device peak HBM bytes allocated since process start.")
+# set by runtime/engine.py once place_params has run: what each device holds
+# of the model itself, so a lopsided placement (a whole stack staged on
+# device 0) shows on every backend, the CPU mesh of the tests included
+PARAM_BYTES_RESIDENT = REGISTRY.labeled_gauge(
+    "param_bytes_resident", "device",
+    "Per-device bytes of placed model parameters (addressable shards).")
 
 # scheduler goodput accounting (runtime/scheduler.py + obs/flight.py):
 # every millisecond between the scheduler's first and last dispatch lands

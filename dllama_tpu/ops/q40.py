@@ -243,6 +243,8 @@ def pack_file_groups(groups: list[list[tuple[np.ndarray, int, int]]],
     q40_planes → concat → transpose → repack pipeline with one repack per
     tensor into a preallocated stack (native csrc/q40pack.cpp when built).
     ``stacked=False`` with a single group returns the 2-D QTensor (wcls).
+    The leaves are **host numpy arrays**: the loader commits nothing to a
+    device, ``parallel/sharding.py place_params`` uploads each chip's shard.
     """
     n = groups[0][0][2]
     d_total = sum(g[1] for g in groups[0])
@@ -271,8 +273,8 @@ def pack_file_groups(groups: list[list[tuple[np.ndarray, int, int]]],
     if not stacked:
         if L != 1:
             raise ValueError("stacked=False needs exactly one group")
-        return QTensor(jnp.asarray(qp[0]), jnp.asarray(scu[0]), (n, d_total))
-    return QTensor(jnp.asarray(qp), jnp.asarray(scu), (n, d_total))
+        return QTensor(qp[0], scu[0], (n, d_total))
+    return QTensor(qp, scu, (n, d_total))
 
 
 def split_d(qt: QTensor, sizes: list[int]) -> list[QTensor]:
@@ -993,6 +995,16 @@ def _ring_reduce_kernel(x_ref, o_ref, comm_ref, send_sem, recv_sem, *,
     RDMA, which is the "reduce fused into the dispatch" this kernel
     exists for (the psum it replaces serializes transfer after the
     matmul).
+
+    Flow control: every step has a comm slot of its own (``tp`` slots a
+    direction: slot 0 is seeded locally, step ``s`` sends slot ``s`` into
+    the neighbor's slot ``s + 1``), so within one call no slot is written
+    twice and a sender can never overwrite a chunk its neighbor is still
+    forwarding or folding (with two alternating slots a chip one step
+    ahead could: the send-side gap left open since PR 22; no wrong sum
+    was ever seen from it, PERF.md PR 26).  Across calls the entry
+    barrier is enough: a neighbor that has entered the next call has
+    waited out all its transfers and folded its last slot.
     """
     t, d = x_ref.shape
     dh = d // 2
@@ -1019,29 +1031,27 @@ def _ring_reduce_kernel(x_ref, o_ref, comm_ref, send_sem, recv_sem, *,
     pltpu.semaphore_wait(barrier, 2)
 
     for step in range(tp - 1):
-        snd, rcv = step % 2, (step + 1) % 2
         copies = []
         for dirn, nb in ((0, right), (1, left)):
             rdma = pltpu.make_async_remote_copy(
-                src_ref=comm_ref.at[dirn, snd],
-                dst_ref=comm_ref.at[dirn, rcv],
-                send_sem=send_sem.at[dirn, snd],
-                recv_sem=recv_sem.at[dirn, rcv],
+                src_ref=comm_ref.at[dirn, step],
+                dst_ref=comm_ref.at[dirn, step + 1],
+                send_sem=send_sem.at[dirn, step],
+                recv_sem=recv_sem.at[dirn, step + 1],
                 device_id=base + (nb,),
                 device_id_type=pltpu.DeviceIdType.MESH)
             rdma.start()
             copies.append(rdma)
         if step > 0:
-            # overlap: fold the chunk received last step (slot ``snd`` —
+            # overlap: fold the chunk received last step (slot ``step`` —
             # also this step's outgoing payload; both are reads) into the
             # accumulator while the transfer is in flight
-            o_ref[:, :dh] += comm_ref[0, snd]
-            o_ref[:, dh:] += comm_ref[1, snd]
+            o_ref[:, :dh] += comm_ref[0, step]
+            o_ref[:, dh:] += comm_ref[1, step]
         for rdma in copies:
             rdma.wait()
-    last = (tp - 1) % 2
-    o_ref[:, :dh] += comm_ref[0, last]
-    o_ref[:, dh:] += comm_ref[1, last]
+    o_ref[:, :dh] += comm_ref[0, tp - 1]
+    o_ref[:, dh:] += comm_ref[1, tp - 1]
 
 
 def _tp_ring_allreduce(x: jax.Array, tp: int) -> jax.Array:
@@ -1055,12 +1065,15 @@ def _tp_ring_allreduce(x: jax.Array, tp: int) -> jax.Array:
         out_specs=pl.BlockSpec(memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct((t, d), jnp.float32),
         scratch_shapes=[
-            pltpu.VMEM((2, 2, t, d // 2), jnp.float32),  # [dir, slot, ...]
-            pltpu.SemaphoreType.DMA((2, 2)),
-            pltpu.SemaphoreType.DMA((2, 2)),
+            pltpu.VMEM((2, tp, t, d // 2), jnp.float32),  # [dir, step slot, ...]
+            pltpu.SemaphoreType.DMA((2, tp)),
+            pltpu.SemaphoreType.DMA((2, tp)),
         ],
+        # tp slots of (t, d/2) f32 a direction, beside x and the output: at
+        # 128 rows of Yi-34B's 7168 that is 22 MB, over the 16 MiB default
         compiler_params=pltpu.CompilerParams(
-            has_side_effects=True, collective_id=0),
+            has_side_effects=True, collective_id=0,
+            vmem_limit_bytes=ROW_VMEM_LIMIT),
         name="q40_ring",
     )(x)
 

@@ -142,7 +142,8 @@ def repack_file_bytes_into(raw: np.ndarray, d: int, n: int,
 def pack_file_groups(groups: list[list[tuple[np.ndarray, int, int]]],
                      stacked: bool = True) -> Q8Tensor:
     """Layer-stacked Q8Tensor straight from `.m` file bytes (the Q80 twin
-    of q40.pack_file_groups; same fused-group and inf/NaN-scale rules)."""
+    of q40.pack_file_groups; same fused-group and inf/NaN-scale rules, and
+    like it the leaves stay host numpy arrays until ``place_params``)."""
     n = groups[0][0][2]
     d_total = sum(g[1] for g in groups[0])
     L = len(groups)
@@ -164,8 +165,8 @@ def pack_file_groups(groups: list[list[tuple[np.ndarray, int, int]]],
     if not stacked:
         if L != 1:
             raise ValueError("stacked=False needs exactly one group")
-        return Q8Tensor(jnp.asarray(qv[0]), jnp.asarray(scu[0]), (n, d_total))
-    return Q8Tensor(jnp.asarray(qv), jnp.asarray(scu), (n, d_total))
+        return Q8Tensor(qv[0], scu[0], (n, d_total))
+    return Q8Tensor(qv, scu, (n, d_total))
 
 
 # ---------------------------------------------------------------------------

@@ -36,6 +36,7 @@ from ..models.transformer import forward_last, init_kv_cache
 from ..obs import dispatch as obs_dispatch, metrics as obs_metrics, \
     trace as obs_trace
 from ..obs.log import get_logger
+from ..ops import q40, q8
 from ..parallel import sharding
 from ..parallel.mesh import active_mesh, make_mesh
 from ..sampling import Sampler
@@ -68,6 +69,24 @@ obs_metrics.HBM_BYTES_IN_USE.fn = _hbm_reader("bytes_in_use")
 obs_metrics.HBM_BYTES_PEAK.fn = _hbm_reader("peak_bytes_in_use")
 
 
+def _resident_param_bytes(params: Params) -> dict[str, int]:
+    """``{device_id: bytes}`` of the placed parameters' addressable shards."""
+    out: dict[str, int] = {}
+    for leaf in jax.tree.leaves(params):
+        for shard in leaf.addressable_shards:
+            key = str(shard.device.id)
+            out[key] = out.get(key, 0) + shard.data.nbytes
+    return out
+
+
+def _zeros_on_mesh(make, sharding: NamedSharding):
+    """``make()`` (a pytree of zeros: the KV cache or pool) built sharded, each
+    device filling only its own part: made eagerly it would sit whole on
+    device 0 first (8 GB for Yi-34B's 32k cache) before ``device_put`` spread
+    it."""
+    return jax.jit(make, out_shardings=sharding)()
+
+
 def _next_bucket(n: int, minimum: int = 16) -> int:
     b = minimum
     while b < n:
@@ -79,8 +98,6 @@ def _unfuse(params: Params, cfg: ModelConfig) -> Params:
     """Split fused ``wqkv``/``w13`` tensors into per-projection weights for
     tensor-parallel placement (the fused layout is a single-chip launch
     optimization; its concat axis does not align with TP shard boundaries)."""
-    from ..ops import q40, q8
-
     def split(w, sizes):
         if isinstance(w, (q40.QTensor, q8.Q8Tensor)):
             return q40.split_d(w, sizes)
@@ -258,7 +275,6 @@ class Engine:
                 # becomes one sequential HBM read — single-device decode
                 # only; on a mesh the row-major layout keeps its
                 # splitWeights-compatible sharding semantics
-                from ..ops import q40
                 params = q40.blocked_params(params)
             else:
                 # requested layout silently kept row-major — that is a
@@ -286,7 +302,10 @@ class Engine:
                 tp=self.mesh.shape.get("tp", 1),
                 hint="fused collective-matmul decode is TPU-only; tp "
                      "collectives run as plain psum all-reduce")
-        self.params = sharding.place_params(params, cfg, self.mesh)
+        with obs_trace.span("engine.load_place", devices=self.mesh.size):
+            self.params = sharding.place_params(params, cfg, self.mesh)
+        for dev, nbytes in _resident_param_bytes(self.params).items():
+            obs_metrics.PARAM_BYTES_RESIDENT.set(dev, nbytes)
         # kv_dtype "q8" (or int8) selects the quantized cache: int8 values
         # + per-position f32 scales — ~2× less cache HBM traffic and
         # residency than bf16, so max context per chip nearly doubles
@@ -333,18 +352,18 @@ class Engine:
             # pages); paged attention dequantizes after the int8-sized
             # page read, so cache HBM traffic and residency halve again
             # on top of paging
-            self.cache = jax.device_put(
-                init_kv_pool(cfg, self.kv_pages, self.kv_page_size,
-                             dtype=None if kv_quant else kv_dtype,
-                             quant=kv_quant),
+            self.cache = _zeros_on_mesh(
+                lambda: init_kv_pool(cfg, self.kv_pages, self.kv_page_size,
+                                     dtype=None if kv_quant else kv_dtype,
+                                     quant=kv_quant),
                 self._cache_sh)
             obs_metrics.KV_PAGE_CODEC.set(
                 "int8" if kv_quant else str(self.cache.k.dtype), 1)
         else:
-            self.cache = jax.device_put(
-                init_kv_cache(cfg, batch, self.seq_len,
-                              dtype=None if kv_quant else kv_dtype,
-                              quant=kv_quant),
+            self.cache = _zeros_on_mesh(
+                lambda: init_kv_cache(cfg, batch, self.seq_len,
+                                      dtype=None if kv_quant else kv_dtype,
+                                      quant=kv_quant),
                 self._cache_sh)
         self.pos = 0
 

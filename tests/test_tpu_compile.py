@@ -212,9 +212,13 @@ def test_mixed_slot_step_keeps_q40_on_the_fused_kernel(one_chip, monkeypatch):
     assert not dequant, dequant
 
 
-@pytest.mark.parametrize("name,n,d", [("wo", 4096, 4096), ("w2", 11008, 4096)])
+@pytest.mark.parametrize("name,n,d,reduce", [
+    ("wo", 4096, 4096, "q40_ring"), ("w2", 11008, 4096, "q40_ring"),
+    # Yi-34B: 1792 = 7 x 256 contracted columns a chip; 7168 % 256 == 0, so
+    # the rule (q40._fused_reduce_ok) takes the ring here too
+    ("wo-yi", 7168, 7168, "q40_ring"), ("w2-yi", 20480, 7168, "q40_ring")])
 def test_tp4_col_matmul_compiles_on_described_mesh(topo, monkeypatch, name,
-                                                   n, d):
+                                                   n, d, reduce):
     """tp=4 col-sharded matmul + its reduce on a mesh of the described
     devices: the per-shard kernel must survive shard_map partitioning."""
     mesh = Mesh(np.asarray(topo.devices[:4]).reshape(1, 1, 1, 4),
@@ -233,6 +237,25 @@ def test_tp4_col_matmul_compiles_on_described_mesh(topo, monkeypatch, name,
     def f(x, qp, sc, layer):
         return q40._sharded_matmul(x, qp, sc, layer, "col", mesh, False)
 
-    text = jax.jit(f).lower(x, qp, sc, layer).compile().as_text()
+    from dllama_tpu.obs import dispatch as obs_dispatch
+    obs_dispatch.reset()
+    try:
+        text = jax.jit(f).lower(x, qp, sc, layer).compile().as_text()
+        degraded = obs_dispatch.reasons()
+    finally:
+        obs_dispatch.reset()
     assert "tpu_custom_call" in text
-    assert "q40_mm_stacked" in text and "q40_ring" in text
+    assert "q40_mm_stacked" in text and reduce in text
+    assert ("q40_ring" in text) == (reduce == "q40_ring")
+    assert not degraded, degraded  # either reduce is the rule's own choice
+
+
+def test_ring_reduce_compiles_at_prefill_rows(topo, monkeypatch):
+    """The ring at 128 rows of 4096: ``tp`` comm slots a direction."""
+    mesh = Mesh(np.asarray(topo.devices[:4]).reshape(1, 1, 1, 4),
+                ("dp", "sp", "ep", "tp"))
+    x = jax.ShapeDtypeStruct((128, 4096), jnp.float32,
+                             sharding=NamedSharding(mesh, P()))
+    f = jax.shard_map(lambda x: q40._tp_ring_allreduce(x, 4), mesh=mesh,
+                      in_specs=P(), out_specs=P(), check_vma=False)
+    assert "q40_ring" in jax.jit(f).lower(x).compile().as_text()
