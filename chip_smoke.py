@@ -486,7 +486,7 @@ def child_kernels(rehearse: bool) -> None:
                       jnp.int32)  # ragged rows, incl. a full and a 1-token row
     key, kq, kk, kv = jax.random.split(key, 4)
     q = (jax.random.normal(kq, (b, hq, 1, dh)) * 0.5).astype(cfg.dtype)
-    pool_shape = (2, n_pages, hkv, ps, dh)
+    pool_shape = (2, n_pages, ps, hkv, dh)  # a page is token-major
     pk = jax.random.normal(kk, pool_shape, jnp.float32) * 0.5
     pv = jax.random.normal(kv, pool_shape, jnp.float32) * 0.5
     layer = jnp.int32(1)

@@ -49,7 +49,9 @@ MOE_PREFILL_UNROLL_MAX = 8
 
 
 class KVCache(NamedTuple):
-    k: jax.Array  # (L, B, Hkv, S, Dh) — cfg dtype, or int8 when quantized
+    # (L, B, Hkv, S, Dh) — cfg dtype, or int8 when quantized; a paged pool
+    # (init_kv_pool) is (L, P, ps, Hkv, Dh), its scale planes (L, P, ps, Hkv, 1)
+    k: jax.Array
     v: jax.Array
     # per-(layer, row, head, position) dequant scales, (L, B, Hkv, S, 1)
     # f32 — present only for the quantized cache.  Kept 5-D (trailing 1)
@@ -88,24 +90,31 @@ def init_kv_cache(cfg: ModelConfig, batch: int, seq_len: int | None = None,
     return KVCache(jnp.zeros(shape, dt), jnp.zeros(shape, dt))
 
 
+# axis order inside one pool page, by name: part of the snapshot and
+# DLREQ01 fingerprints (runtime/engine.py), so a file written with another
+# order is refused even where the sizes coincide
+PAGE_AXES = "ps,Hkv,Dh"
+
+
 def init_kv_pool(cfg: ModelConfig, n_pages: int, page_size: int,
                  dtype=None, quant: bool = False) -> KVCache:
-    """Paged KV pool: the stacked layout with the batch axis generalized
-    to physical pages and the sequence axis shrunk to one page —
-    ``(L, n_pages, Hkv, page_size, Dh)``.  Axis-for-axis compatible with
-    the contiguous cache's sharding spec (pages ride the batch axis, the
-    page interior rides the sequence axis).  Page 0 is the reserved
-    scratch page (see ops.attention paged section); slots address the
-    pool through per-slot page tables, so pool memory is bounded by live
-    *tokens*, not slots × max-seq.
+    """Paged KV pool ``(L, n_pages, page_size, Hkv, Dh)``: physical pages
+    in place of the batch axis, and each page token-major, so a token's
+    (Hkv, Dh) slab is contiguous and the per-token KV write's layout is
+    the pool's own (the contiguous cache keeps (L, B, Hkv, S, Dh): its
+    writes are windows along S).  The pool has a sharding spec of its own
+    (``parallel/sharding.py kv_pool_sharding``: kv heads on axis 3).  Page 0
+    is the reserved scratch page (see ops.attention paged section); slots
+    address the pool through per-slot page tables, so pool memory is
+    bounded by live *tokens*, not slots × max-seq.
 
     ``quant=True`` (``--kv-quant int8``) stores int8 values plus a
-    per-(page, head, position) f32 scale plane ``(L, P, Hkv, ps, 1)`` —
+    per-(page, position, head) f32 scale plane ``(L, P, ps, Hkv, 1)`` —
     the page-granular mirror of the contiguous quantized cache's codec
     (same quantize_kv absmax math, same ~2× HBM saving), so a pool page
     is self-describing: values and scales always travel together through
     spills, snapshots and DLREQ01 hand-offs."""
-    shape = (cfg.n_layers, n_pages, cfg.n_kv_heads, page_size, cfg.head_size)
+    shape = (cfg.n_layers, n_pages, page_size, cfg.n_kv_heads, cfg.head_size)
     if quant:
         sshape = shape[:-1] + (1,)
         return KVCache(jnp.zeros(shape, jnp.int8), jnp.zeros(shape, jnp.int8),
@@ -564,7 +573,7 @@ def _run_slot_blocks(params: Params, cfg: ModelConfig, tokens, cache: KVCache,
         with scope("page_idx"):
             pidx, oidx = paged_write_indices(page_table, pos_rows, n_valid,
                                              tokens.shape[1],
-                                             cache.k.shape[3])
+                                             cache.k.shape[2])
         paged = (page_table, pidx, oidx)
     return run_blocks(params, cfg, tokens, cache, jnp.int32(0),
                       pos_rows=pos_rows, paged=paged)

@@ -161,12 +161,18 @@ def slot_gqa_attention_at(q: jax.Array, ck: jax.Array, cv: jax.Array,
 # ---------------------------------------------------------------------------
 # Paged KV: a global page pool + per-slot block tables (PagedAttention).
 #
-# The pool is ``(L, n_pages, Hkv, page_size, Dh)`` — the contiguous stacked
-# layout with the batch axis generalized to physical pages and the sequence
-# axis shrunk to one page.  A slot's logical cache is described by one
-# (max_pages,) int32 row of the page table, shared across layers: logical
-# position ``p`` of slot ``r`` lives at ``pool[:, table[r, p // ps], :,
-# p % ps]``.  Physical page 0 is reserved as a scratch page: table entries
+# The pool is ``(L, n_pages, page_size, Hkv, Dh)``: a page is stored
+# token-major, so one token's ``(Hkv, Dh)`` slab is contiguous and the KV
+# write's scatter has the pool's resident layout.  The page offset must not
+# sit between the two axes a token's slab spans: the scatter then wants
+# another physical order than the program boundary, the layer loop's carry
+# and the fused kernel hold, and XLA bridges them with copies of the whole
+# pool (PERF.md §6, PR 27).  Readers bring a page to the head-major
+# ``(Hkv, ps, Dh)`` the score math takes.  A slot's logical cache is
+# described by one (max_pages,) int32 row of the page table, shared across
+# layers: logical position ``p`` of slot ``r`` lives at
+# ``pool[:, table[r, p // ps], p % ps]``.
+# Physical page 0 is reserved as a scratch page: table entries
 # past a slot's reserved pages point at it, and every *invalid* token write
 # (decode padding, tokens past ``n_valid``, burst overshoot past a retired
 # row's budget) is redirected there — so shared prefix pages are immutable
@@ -198,20 +204,21 @@ def paged_update_kv_rows(pool_k: jax.Array, pool_v: jax.Array,
                          layer: jax.Array, pidx: jax.Array, oidx: jax.Array
                          ) -> tuple[jax.Array, jax.Array]:
     """Write one layer's step KV (B, Hkv, T, Dh) into the paged pools
-    (L, P, Hkv, ps, Dh) at per-token physical ``(page, offset)`` indices
+    (L, P, ps, Hkv, Dh) at per-token physical ``(page, offset)`` indices
     (B, T) from :func:`paged_write_indices`.
 
-    One advanced-indexing scatter per pool: the (B, T) page/offset arrays
-    are non-adjacent advanced indices (the Hkv slice sits between), so the
-    update operand is (B, T, Hkv, Dh) — the step KV with its token axis
-    moved ahead of the head axis.  Invalid tokens all target scratch page
-    0; colliding scratch writes are unordered, which is fine — nothing
-    reads that page unmasked."""
+    One advanced-indexing scatter per pool: layer, page and offset are
+    adjacent advanced indices, each token's (Hkv, Dh) slab is one
+    contiguous window of the pool, and the update operand is
+    (B, T, Hkv, Dh) — the step KV with its token axis moved ahead of the
+    head axis.  Invalid tokens all target scratch page 0; colliding
+    scratch writes are unordered, which is fine — nothing reads that page
+    unmasked."""
     kbt = k_new.transpose(0, 2, 1, 3).astype(pool_k.dtype)  # (B, T, Hkv, Dh)
     vbt = v_new.transpose(0, 2, 1, 3).astype(pool_v.dtype)
     li = layer.astype(jnp.int32)
-    pool_k = pool_k.at[li, pidx, :, oidx].set(kbt)
-    pool_v = pool_v.at[li, pidx, :, oidx].set(vbt)
+    pool_k = pool_k.at[li, pidx, oidx].set(kbt)
+    pool_v = pool_v.at[li, pidx, oidx].set(vbt)
     return pool_k, pool_v
 
 
@@ -219,24 +226,25 @@ def paged_gather_layer(pool: jax.Array, layer: jax.Array,
                        page_table: jax.Array,
                        scale_pool: jax.Array | None = None) -> jax.Array:
     """Materialize one layer's logical KV view (B, Hkv, maxp·ps, Dh) by
-    gathering each slot's pages from the pool (L, P, Hkv, ps, Dh).  The
-    gather is the paged twin of the contiguous layer slice: XLA fuses it
-    into the score dot for the short-cache one-shot path, and the
-    long-cache decode path avoids it entirely (page-walk fold).
+    gathering each slot's pages from the pool (L, P, ps, Hkv, Dh) and
+    moving the head axis ahead of the tokens.  The gather is the paged
+    twin of the contiguous layer slice: XLA fuses it into the score dot
+    for the short-cache one-shot path, and the long-cache decode path
+    avoids it entirely (page-walk fold).
 
     ``scale_pool``: the int8 pool's per-position scale planes
-    (L, P, Hkv, ps, 1) — the gather stays int8-sized and the dequant
+    (L, P, ps, Hkv, 1) — the gather stays int8-sized and the dequant
     multiply fuses into the downstream dot like the plain cast."""
-    pl = jax.lax.dynamic_index_in_dim(pool, layer, 0, keepdims=False)
-    view = pl[page_table]  # (B, maxp, Hkv, ps, Dh)
-    b, maxp, hkv, ps, dh = view.shape
-    out = view.transpose(0, 2, 1, 3, 4).reshape(b, hkv, maxp * ps, dh)
+
+    def view(p):
+        pl = jax.lax.dynamic_index_in_dim(p, layer, 0, keepdims=False)
+        pages = pl[page_table]  # (B, maxp, ps, Hkv, Dh | 1)
+        b, maxp, ps, hkv, last = pages.shape
+        return pages.transpose(0, 3, 1, 2, 4).reshape(b, hkv, maxp * ps, last)
+
     if scale_pool is None:
-        return out
-    sl = jax.lax.dynamic_index_in_dim(scale_pool, layer, 0, keepdims=False)
-    sview = sl[page_table]  # (B, maxp, Hkv, ps, 1)
-    sc = sview.transpose(0, 2, 1, 3, 4).reshape(b, hkv, maxp * ps, 1)
-    return dequant_kv(out, sc)
+        return view(pool)
+    return dequant_kv(view(pool), view(scale_pool))
 
 
 def paged_decode_attention(q: jax.Array, pool_k: jax.Array, pool_v: jax.Array,
@@ -252,13 +260,12 @@ def paged_decode_attention(q: jax.Array, pool_k: jax.Array, pool_v: jax.Array,
     runs out before the longest neighbor read scratch page 0, fully
     masked.
 
-    ``scales``: the int8 pool's (k, v) scale planes (L, P, Hkv, ps, 1) —
+    ``scales``: the int8 pool's (k, v) scale planes (L, P, ps, Hkv, 1) —
     each fold step gathers the value page AND its scale page and
     dequantizes after the int8-sized HBM read (the point of the
     quantized pool)."""
     b, hq, t, dh = q.shape
-    hkv = pool_k.shape[2]
-    ps = pool_k.shape[3]
+    ps, hkv = pool_k.shape[2], pool_k.shape[3]
     maxp = page_table.shape[1]
     g = hq // hkv
     qf = q.astype(jnp.float32).reshape(b, hkv, g, t, dh)
@@ -266,9 +273,10 @@ def paged_decode_attention(q: jax.Array, pool_k: jax.Array, pool_v: jax.Array,
     def slice_page(pool, start, length):
         pid = jax.lax.dynamic_index_in_dim(page_table, start // ps, 1,
                                            keepdims=False)  # (B,)
-        # advanced (scalar layer, (B,) page) indexing: one (B, Hkv, ps, Dh)
-        # page gather per fold step — never the whole layer slab
-        return pool[layer.astype(jnp.int32), pid]
+        # advanced (scalar layer, (B,) page) indexing: one (B, ps, Hkv, Dh)
+        # page gather per fold step — never the whole layer slab — brought
+        # to the fold's head-major (B, Hkv, ps, Dh) block
+        return pool[layer.astype(jnp.int32), pid].transpose(0, 2, 1, 3)
 
     if scales is None:
         kc_arg, vc_arg = pool_k, pool_v
@@ -351,7 +359,7 @@ def _make_fused_kernel(hq: int, hkv: int, dh: int, ps: int, maxp: int,
         # prefetch pipeline issues no new DMA for them)
         @plx.when(p <= pos // ps)
         def _fold():
-            k = k_ref[0, 0]  # (Hkv, ps, Dh)
+            k = k_ref[0, 0]  # (ps, Hkv, Dh): the pool's token-major page
             v = v_ref[0, 0]
             if quantized:
                 # in-register dequant: int8 page block × per-position
@@ -359,6 +367,9 @@ def _make_fused_kernel(hq: int, hkv: int, dh: int, ps: int, maxp: int,
                 # without the materialized intermediate)
                 k = (k.astype(jnp.float32) * ks_ref[0, 0]).astype(jnp.bfloat16)
                 v = (v.astype(jnp.float32) * vs_ref[0, 0]).astype(jnp.bfloat16)
+            # head-major in VMEM: the dots below batch over the kv-head axis
+            k = jnp.swapaxes(k, 0, 1)  # (Hkv, ps, Dh)
+            v = jnp.swapaxes(v, 0, 1)
             qb = q_ref[0].reshape(hkv, g, dh).astype(k.dtype)
             # (Hkv, G, ps): score dot batched over the kv-head axis, f32
             # accumulation like _online_fold
@@ -408,7 +419,7 @@ def fused_paged_attention(q: jax.Array, pool_k: jax.Array, pool_v: jax.Array,
     b, hq, t, dh = q.shape
     if t != 1:
         raise ValueError("fused paged attention is decode-only (T must be 1)")
-    hkv, ps = pool_k.shape[2], pool_k.shape[3]
+    ps, hkv = pool_k.shape[2], pool_k.shape[3]
     maxp = page_table.shape[1]
     quantized = scales is not None
 
@@ -421,11 +432,11 @@ def fused_paged_attention(q: jax.Array, pool_k: jax.Array, pool_v: jax.Array,
     def row_map(bi, pi, *_):
         return (bi, 0, 0)
 
-    kv_spec = plx.BlockSpec((1, 1, hkv, ps, dh), walk_map)
+    kv_spec = plx.BlockSpec((1, 1, ps, hkv, dh), walk_map)
     in_specs = [plx.BlockSpec((1, hq, dh), row_map), kv_spec, kv_spec]
     operands = [q[:, :, 0, :], pool_k, pool_v]
     if quantized:
-        sc_spec = plx.BlockSpec((1, 1, hkv, ps, 1), walk_map)
+        sc_spec = plx.BlockSpec((1, 1, ps, hkv, 1), walk_map)
         in_specs += [sc_spec, sc_spec]
         operands += [scales[0], scales[1]]
     kernel = _make_fused_kernel(hq, hkv, dh, ps, maxp, quantized, q.dtype)
@@ -502,14 +513,14 @@ def paged_gqa_attention_at(q: jax.Array, pool_k: jax.Array, pool_v: jax.Array,
     plus the score/softmax pass (``attn-score``), plus a ``dequant``
     record for int8 pools whose scale multiply rides the gathered view.
 
-    ``scales``: the int8-pool (k, v) scale planes (L, P, Hkv, ps, 1);
+    ``scales``: the int8-pool (k, v) scale planes (L, P, ps, Hkv, 1);
     every unfused arm dequantizes after the int8-sized page read."""
     from ..obs import dispatch as obs_dispatch
     t = q.shape[2]
-    ps = pool_k.shape[3]
+    ps, hkv = pool_k.shape[2], pool_k.shape[3]
     s = page_table.shape[1] * ps
     codec = "kv_int8" if scales is not None else "kv_dense"
-    use_fused, interp = _fused_choice(t, q.shape[1], pool_k.shape[2])
+    use_fused, interp = _fused_choice(t, q.shape[1], hkv)
     if use_fused:
         obs_dispatch.record_dispatch(codec, "paged-fused", t=t, s=s,
                                      page_size=ps, interpret=interp)

@@ -119,6 +119,13 @@ def kv_cache_sharding(mesh: Mesh, seq_axis: str | None = None) -> NamedSharding:
     return NamedSharding(mesh, kv_cache_spec(seq_axis))
 
 
+def kv_pool_sharding(mesh: Mesh) -> NamedSharding:
+    """Paged pool (L, P, ps, Hkv, Dh): pages where the contiguous cache has
+    its batch, kv heads on ``tp`` at axis 3 (a page is token-major,
+    models.transformer.init_kv_pool)."""
+    return NamedSharding(mesh, P(None, "dp", None, "tp", None))
+
+
 def place_params(params: dict, cfg: ModelConfig, mesh: Mesh) -> dict:
     """Upload host params onto the mesh with their TP shardings.
 

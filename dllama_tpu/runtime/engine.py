@@ -32,7 +32,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..models.config import ModelConfig
 from ..models.params import Params
-from ..models.transformer import forward_last, init_kv_cache
+from ..models.transformer import PAGE_AXES, forward_last, init_kv_cache
 from ..obs import dispatch as obs_dispatch, metrics as obs_metrics, \
     trace as obs_trace
 from ..obs.log import get_logger
@@ -344,9 +344,10 @@ class Engine:
             # per-slot table width: enough logical pages to cover seq_len
             self.max_pages_per_slot = -(-self.seq_len // self.kv_page_size)
             from ..models.transformer import init_kv_pool
-            # pool layout (L, P, Hkv, ps, Dh) is axis-compatible with the
-            # contiguous cache spec: pages ride the batch ("dp") axis, the
-            # page interior rides the sequence axis
+            # pool layout (L, P, ps, Hkv, Dh): pages ride the batch ("dp")
+            # axis, a page is token-major, so the kv-head axis that tp
+            # shards is axis 3 — a spec of its own
+            self._cache_sh = sharding.kv_pool_sharding(self.mesh)
             # --kv-quant int8: pool pages hold int8 values + per-position
             # f32 scale planes (the Q80 weight codec's trick applied to
             # pages); paged attention dequantizes after the int8-sized
@@ -437,7 +438,9 @@ class Engine:
                       for n, a in self._cache_arrays().items()],
             # pool geometry: a paged snapshot only means something in an
             # engine with the same page count/size (page ids are physical)
-            "paged": [self.kv_pages, self.kv_page_size] if self.paged else None,
+            # and the same axis order inside a page
+            "paged": [self.kv_pages, self.kv_page_size, PAGE_AXES]
+            if self.paged else None,
         }
         return snapfmt.fingerprint(fields)
 
@@ -572,10 +575,13 @@ class Engine:
             "n_active_experts": c.n_active_experts,
             "vocab_size": c.vocab_size, "hidden_act": c.hidden_act,
             "rope_theta": c.rope_theta, "seq_len": self.seq_len,
-            # page shape (Hkv, ps, Dh) + dtype, not pool page count; the
-            # codec is explicit so int8-paged vs dense records reject
-            # cleanly even where the raw dtype string would coincide
+            # page shape (ps, Hkv, Dh) + dtype, not pool page count, with
+            # the axis order by name: a record written head-major (before
+            # PR 27) is refused even where Hkv == ps; the codec is
+            # explicit so int8-paged vs dense records reject cleanly even
+            # where the raw dtype string would coincide
             "page": [str(k.dtype), list(k.shape[2:])],
+            "page_axes": PAGE_AXES,
             "codec": "int8" if self.cache.quantized else "dense",
             "handoff": 1,
         }
@@ -603,8 +609,8 @@ class Engine:
 
     def read_pool_pages(self, pages) -> dict[str, np.ndarray]:
         """Copy the given physical pages out of the paged pool to host
-        numpy, all layers at once: shape ``(L, n, Hkv, ps, Dh)`` (plus the
-        ``(L, n, Hkv, ps, 1)`` scale planes for an int8 pool).  Used by
+        numpy, all layers at once: shape ``(L, n, ps, Hkv, Dh)`` (plus the
+        ``(L, n, ps, Hkv, 1)`` scale planes for an int8 pool).  Used by
         the scheduler's drain-time export and the spill path."""
         return {k: h.wait() for k, h in
                 self.read_pool_pages_async(pages).items()}

@@ -16,6 +16,27 @@ from dllama_tpu.synth import write_synth_tokenizer as write_tiny_tokenizer  # no
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
+# (Hkv, page size): one geometry where the two differ and one where they
+# coincide, so a paged reader or writer with the page's axes swapped cannot
+# pass by symmetry
+PAGE_GEOMETRIES = [(2, 8), (4, 4)]
+PAGE_GEOMETRY_IDS = ["hkv2-ps8", "hkv4-ps4"]
+
+
+def pool_from_logical(kv, table, n_pages: int, ps: int) -> np.ndarray:
+    """Place logical head-major KV ``(L, B, Hkv, S, last)`` into a paged pool
+    ``(L, n_pages, ps, Hkv, last)`` through ``table`` (B, S // ps): position
+    ``p`` of row ``r`` goes to ``pool[:, table[r, p // ps], p % ps]``.  This
+    is what the pool's axis order *means*, written with none of the program's
+    readers or writers, so a test that compares against it pins the order
+    itself (rows' pages must be distinct)."""
+    kv, table = np.asarray(kv), np.asarray(table)
+    nl, b, hkv, s, last = kv.shape
+    pool = np.zeros((nl, n_pages, ps, hkv, last), kv.dtype)
+    for r in range(b):
+        for p in range(s):
+            pool[:, table[r, p // ps], p % ps] = kv[:, r, :, p]
+    return pool
 
 
 def write_tiny_model(path, *, arch=mfile.ARCH_LLAMA, ftype=quants.Q80,

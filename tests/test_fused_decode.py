@@ -36,7 +36,7 @@ import jax.numpy as jnp
 
 from dllama_tpu.models.config import tiny_config
 from dllama_tpu.models.params import init_params
-from dllama_tpu.ops.attention import (_rows_ceiling_attention,
+from dllama_tpu.ops.attention import (_rows_ceiling_attention, dequant_kv,
                                       fused_paged_attention,
                                       paged_gather_layer, quantize_kv)
 from dllama_tpu.parallel.mesh import make_mesh
@@ -45,6 +45,7 @@ from dllama_tpu.runtime.engine import Engine
 from dllama_tpu.runtime.faults import injected
 from dllama_tpu.runtime.scheduler import SlotScheduler
 from dllama_tpu.sampling import sample_on_device, sample_with_coin
+from fixtures import PAGE_GEOMETRIES, PAGE_GEOMETRY_IDS, pool_from_logical
 
 CFG = tiny_config(seq_len=64)
 PAGE = 8
@@ -62,43 +63,56 @@ def make_paged_engine(batch=4, page=PAGE, **kw):
 
 # -- kernel vs gather reference --------------------------------------------
 
-def _pool_fixture(quantized, b=3, maxp=3, hkv=2, g=2, ps=8, dh=16,
-                  nlayers=2):
+def _pool_fixture(quantized, hkv=2, ps=8, b=3, maxp=3, g=2, dh=16, nlayers=2):
+    """A ragged paged read: the pool is laid out from logical head-major KV
+    by :func:`fixtures.pool_from_logical`, and ``logical`` is that KV
+    (dequantized for an int8 pool) for a reference that never touches the
+    pool."""
     npages = 1 + b * maxp
     rng = np.random.RandomState(3)
-    table = jnp.asarray(np.arange(1, 1 + b * maxp).reshape(b, maxp),
+    table = jnp.asarray(rng.permutation(np.arange(1, npages)).reshape(b, maxp),
                         jnp.int32)
     # ragged: one full row, one mid-page, one inside the first page
-    pos_rows = jnp.asarray([maxp * ps - 1, ps + ps // 2, 3], jnp.int32)
+    pos_rows = jnp.asarray([maxp * ps - 1, ps + ps // 2, min(3, ps - 1)],
+                           jnp.int32)
     q = jnp.asarray(rng.randn(b, hkv * g, 1, dh) * 0.3, jnp.float32)
+    shape = (nlayers, b, hkv, maxp * ps, dh)
+    place = lambda a: jnp.asarray(pool_from_logical(a, table, npages, ps))  # noqa: E731
     if quantized:
-        pk, sk = quantize_kv(jnp.asarray(
-            rng.randn(nlayers, npages, hkv, ps, dh), jnp.float32))
-        pv, sv = quantize_kv(jnp.asarray(
-            rng.randn(nlayers, npages, hkv, ps, dh), jnp.float32))
-        scales = (sk, sv)
+        (qk, sk), (qv, sv) = (quantize_kv(jnp.asarray(rng.randn(*shape),
+                                                      jnp.float32))
+                              for _ in range(2))
+        pk, pv, scales = place(qk), place(qv), (place(sk), place(sv))
+        logical = (dequant_kv(qk, sk), dequant_kv(qv, sv))
     else:
-        pk = jnp.asarray(rng.randn(nlayers, npages, hkv, ps, dh) * 0.3,
-                         jnp.bfloat16)
-        pv = jnp.asarray(rng.randn(nlayers, npages, hkv, ps, dh) * 0.3,
-                         jnp.bfloat16)
-        scales = None
-    return q, pk, pv, table, pos_rows, scales
+        k, v = (jnp.asarray(rng.randn(*shape) * 0.3, jnp.bfloat16)
+                for _ in range(2))
+        pk, pv, scales, logical = place(k), place(v), None, (k, v)
+    return q, pk, pv, table, pos_rows, scales, logical
 
 
+@pytest.mark.parametrize("hkv,ps", PAGE_GEOMETRIES, ids=PAGE_GEOMETRY_IDS)
 @pytest.mark.parametrize("quantized", [False, True],
                          ids=["dense", "kv_int8"])
-def test_fused_kernel_matches_gather_reference(quantized):
+def test_fused_kernel_matches_gather_reference(quantized, hkv, ps):
     """The page-walk kernel and the materialized-gather path compute the
     same attention read — ragged rows, layer 1 of 2 (the layer index
-    rides scalar prefetch), dead pages fully masked."""
-    q, pk, pv, table, pos_rows, scales = _pool_fixture(quantized)
+    rides scalar prefetch), dead pages fully masked — and the gather view
+    IS the logical KV the pool was laid out from, exactly: the token-major
+    page order (L, P, ps, Hkv, Dh) read back head-major."""
+    q, pk, pv, table, pos_rows, scales, (k_log, v_log) = _pool_fixture(
+        quantized, hkv, ps)
+    assert pk.shape[2:4] == (ps, hkv)
     layer = jnp.int32(1)
     out = fused_paged_attention(q, pk, pv, layer, table, pos_rows,
                                 scales=scales, interpret=True)
     ks, vs = scales if scales is not None else (None, None)
     k_l = paged_gather_layer(pk, layer, table, scale_pool=ks)
     v_l = paged_gather_layer(pv, layer, table, scale_pool=vs)
+    np.testing.assert_array_equal(np.asarray(k_l, np.float32),
+                                  np.asarray(k_log[1], np.float32))
+    np.testing.assert_array_equal(np.asarray(v_l, np.float32),
+                                  np.asarray(v_log[1], np.float32))
     ref = _rows_ceiling_attention(q, k_l, v_l, pos_rows)
     assert out.shape == ref.shape == q.shape
     tol = 1e-2 * max(float(np.abs(np.asarray(ref, np.float32)).max()), 1e-3)
@@ -116,7 +130,7 @@ def test_fused_choice_is_static_and_raises_on_tpu(monkeypatch, mode):
     from dllama_tpu.ops import attention as att
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     monkeypatch.setenv("DLLAMA_FUSED_ATTN", mode)
-    q, pk, pv, table, pos_rows, _ = _pool_fixture(False)
+    q, pk, pv, table, pos_rows, _, _ = _pool_fixture(False)
     assert att._fused_choice(1, 4, 2) == (True, False)
     assert att._fused_choice(2, 4, 2) == (False, False)  # t > 1
     assert att._fused_choice(1, 3, 2) == (False, False)  # hq % hkv
@@ -160,7 +174,7 @@ def test_fused_choice_on_a_mesh_stays_gather(monkeypatch):
 def test_fused_kernel_under_jit():
     """The kernel composes with jit (the engine always calls it inside a
     compiled step) and stays deterministic across calls."""
-    q, pk, pv, table, pos_rows, scales = _pool_fixture(False)
+    q, pk, pv, table, pos_rows, scales, _ = _pool_fixture(False)
 
     @jax.jit
     def step(q):

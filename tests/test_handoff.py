@@ -175,6 +175,45 @@ def test_import_rejects_incompatible_geometry(stack):
         sa.import_request(blob)
 
 
+def test_record_of_another_page_axis_order_is_refused(monkeypatch, tmp_path):
+    """The hand-off and snapshot fingerprints name the axis order inside a
+    page (``transformer.PAGE_AXES``), so a record or snapshot written
+    head-major — as every file from before PR 27 was — is refused by name,
+    even at Hkv == ps where the page's shape is the same either way.  (A
+    file from before PR 27 lacks the key altogether, which changes the
+    digest just the same.)"""
+    from dllama_tpu.runtime import engine as engine_mod
+
+    cfg = tiny_config(n_kv_heads=4, seq_len=32)  # Hkv == ps == 4
+
+    def paged():
+        return Engine(cfg, init_params(cfg, seed=4),
+                      mesh=make_mesh(tp=1, devices=jax.devices()[:1]),
+                      batch=2, kv_pages=17, kv_page_size=PAGE)
+
+    now = paged()
+    assert now.cache.k.shape[2] == now.cache.k.shape[3] == 4
+    monkeypatch.setattr(engine_mod, "PAGE_AXES", "Hkv,ps,Dh")
+    old = paged()  # same shapes, the page's axes named the old way round
+    old_fp = old.handoff_fingerprint()
+    snap = old.snapshot(str(tmp_path / "old.snap"))
+    monkeypatch.undo()
+    assert now.handoff_fingerprint() != old_fp
+    blob = snapfmt.dumps_request(
+        fingerprint=old_fp, pos=4, chunk_counter=0,
+        arrays={n: np.zeros((cfg.n_layers, 1, 4, 4, cfg.head_size), np.float32)
+                for n in ("pages.k", "pages.v")},
+        extra={"rid": "old-order", "prompt": [1, 2], "max_new": 4})
+    sched = SlotScheduler(now, prefill_chunk=4, max_wait_ms=20.0)
+    try:
+        with pytest.raises(SnapshotMismatch, match="geometry"):
+            sched.import_request(blob)
+    finally:
+        sched.close()
+    with pytest.raises(SnapshotMismatch, match="fingerprint"):
+        now.restore(snap)
+
+
 def test_import_rejects_inconsistent_pages(stack):
     """Right fingerprint, but the page payload disagrees with the record
     position (a torn or doctored export) — refused before any state is

@@ -37,12 +37,15 @@ from dllama_tpu.models.params import init_params
 from dllama_tpu.obs import flight as obs_flight, metrics as obs_metrics
 from dllama_tpu.ops.attention import (_rows_ceiling_attention,
                                       paged_decode_attention,
-                                      paged_gather_layer)
+                                      paged_gather_layer,
+                                      paged_update_kv_rows,
+                                      paged_write_indices, quantize_kv)
 from dllama_tpu.parallel.mesh import make_mesh
 from dllama_tpu.runtime.engine import ContextOverflow, Engine
 from dllama_tpu.runtime.pagepool import (PagePool, PagePoolExhausted,
                                          RadixTree)
 from dllama_tpu.runtime.scheduler import SlotScheduler
+from fixtures import PAGE_GEOMETRIES, PAGE_GEOMETRY_IDS, pool_from_logical
 
 CFG = tiny_config(seq_len=64)
 PAGE = 8
@@ -146,28 +149,76 @@ def test_radix_export_restore_roundtrip():
 
 # -- device-side paged attention ------------------------------------------
 
-def test_paged_decode_matches_gather_attention():
+@pytest.mark.parametrize("hkv,ps,maxp", [(2, 128, 32), (8, 8, 6)],
+                         ids=["hkv2-ps128", "hkv8-ps8"])
+def test_paged_decode_matches_gather_attention(hkv, ps, maxp):
     """The page-walking decode fold must equal the one-shot gather-view
     attention on the same pool — they are the same logical computation, so
-    any divergence is a fold-masking bug.  Geometry chosen to clear the
-    blocked-decode dispatch threshold (s >= 4096)."""
+    any divergence is a fold-masking bug — and both must equal the same
+    attention over the logical head-major KV the pool was laid out from
+    (the pool's token-major order, pinned without the pool's readers).  The
+    first geometry clears the blocked-decode dispatch threshold
+    (s >= 4096); the second has Hkv == ps, where a swapped axis would keep
+    every shape."""
     rng = np.random.RandomState(3)
-    L, n_pages, hkv, ps, dh, b, hq = 1, 40, 2, 128, 8, 3, 4
-    maxp = 32  # s = 4096
-    pool_k = jnp.asarray(rng.randn(L, n_pages, hkv, ps, dh), jnp.float32)
-    pool_v = jnp.asarray(rng.randn(L, n_pages, hkv, ps, dh), jnp.float32)
-    # arbitrary (even repeating) physical pages: the logical view is
-    # whatever the table says it is
-    table = jnp.asarray(rng.randint(0, n_pages, (b, maxp)), jnp.int32)
+    L, dh, b, hq = 1, 8, 3, 2 * hkv
+    n_pages = 1 + b * maxp
+    s = maxp * ps
+    k_log = rng.randn(L, b, hkv, s, dh).astype(np.float32)
+    v_log = rng.randn(L, b, hkv, s, dh).astype(np.float32)
+    table = jnp.asarray(rng.permutation(np.arange(1, n_pages)).reshape(
+        b, maxp), jnp.int32)
+    pool_k = jnp.asarray(pool_from_logical(k_log, table, n_pages, ps))
+    pool_v = jnp.asarray(pool_from_logical(v_log, table, n_pages, ps))
     q = jnp.asarray(rng.randn(b, hq, 1, dh), jnp.float32)
-    pos_rows = jnp.asarray([130, 4095, 700], jnp.int32)
+    pos_rows = jnp.asarray([ps + 2, s - 1, 5 * ps + ps // 2], jnp.int32)
     layer = jnp.int32(0)
     got = paged_decode_attention(q, pool_k, pool_v, layer, table, pos_rows)
     k_l = paged_gather_layer(pool_k, layer, table)
     v_l = paged_gather_layer(pool_v, layer, table)
+    np.testing.assert_array_equal(np.asarray(k_l), k_log[0])
+    np.testing.assert_array_equal(np.asarray(v_l), v_log[0])
     want = _rows_ceiling_attention(q, k_l, v_l, pos_rows)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize("hkv,ps", PAGE_GEOMETRIES, ids=PAGE_GEOMETRY_IDS)
+@pytest.mark.parametrize("codec", ["bf16", "int8"])
+def test_paged_write_lands_token_major(codec, hkv, ps):
+    """One layer's step KV written through the page table lands at
+    ``pool[layer, page, offset, head]`` — the token-major page — for a bf16
+    pool and for an int8 pool's values and scale planes alike; tokens past
+    ``n_valid`` go to scratch page 0 and the other layer stays untouched."""
+    rng = np.random.RandomState(5)
+    L, dh, b, t, maxp = 2, 16, 3, 5, 4
+    n_pages = 1 + b * maxp
+    table = rng.permutation(np.arange(1, n_pages)).reshape(b, maxp)
+    pos_rows = np.asarray([0, ps - 2, 2 * ps + 1])  # row 1 straddles pages
+    n_valid = np.asarray([t, t, 2])
+    k_new = jnp.asarray(rng.randn(b, hkv, t, dh), jnp.float32)
+    pidx, oidx = paged_write_indices(jnp.asarray(table, jnp.int32),
+                                     jnp.asarray(pos_rows, jnp.int32),
+                                     jnp.asarray(n_valid, jnp.int32), t, ps)
+    if codec == "int8":
+        vals, scale = quantize_kv(k_new)
+        planes = [(vals, jnp.int8), (scale, jnp.float32)]
+    else:
+        planes = [(k_new, jnp.bfloat16)]
+    for new, dt in planes:
+        last = new.shape[-1]
+        pool = jnp.zeros((L, n_pages, ps, hkv, last), dt)
+        got, _ = paged_update_kv_rows(pool, pool, new, new, jnp.int32(1),
+                                      pidx, oidx)
+        got = np.array(got.astype(jnp.float32))
+        want = np.zeros(got.shape, np.float32)
+        new_np = np.asarray(new.astype(dt).astype(jnp.float32))
+        for r in range(b):
+            for j in range(int(n_valid[r])):
+                p = int(pos_rows[r]) + j
+                want[1, table[r, p // ps], p % ps] = new_np[r, :, j]
+        got[1, 0] = 0.0  # scratch page: invalid tokens' writes, unordered
+        np.testing.assert_array_equal(got, want)
 
 
 # -- scheduler over the paged engine --------------------------------------
@@ -240,6 +291,71 @@ def test_paged_greedy_parity_ragged_traffic(solo_refs, paged_stack):
         want = solo_refs[tuple(p)][:len(outs[tuple(p)])]
         assert outs[tuple(p)] == want, f"prompt {p} diverged"
         assert len(outs[tuple(p)]) > 0
+
+
+@pytest.mark.parametrize("hkv,ps", PAGE_GEOMETRIES, ids=PAGE_GEOMETRY_IDS)
+@pytest.mark.parametrize("kv_dtype", [None, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+def test_paged_greedy_parity_by_page_geometry(kv_dtype, hkv, ps):
+    """Paged greedy output is byte-identical to the contiguous engine's at
+    a page geometry where Hkv != ps and at one where Hkv == ps (a swapped
+    page axis keeps every shape there), with the cache in f32 and bf16."""
+    cfg = tiny_config(n_kv_heads=hkv, seq_len=64)
+    params = init_params(cfg, seed=4)
+    mesh = make_mesh(tp=1, devices=jax.devices()[:1])
+    solo = Engine(cfg, params, mesh=mesh, batch=1, kv_dtype=kv_dtype)
+    eng = Engine(cfg, params, mesh=mesh, batch=2, kv_dtype=kv_dtype,
+                 kv_pages=2 * (cfg.seq_len // ps) + 1, kv_page_size=ps)
+    assert eng.cache.k.shape == (cfg.n_layers, eng.kv_pages, ps, hkv,
+                                 cfg.head_size)
+    sched = SlotScheduler(eng, prefill_chunk=4, max_wait_ms=20.0,
+                          decode_burst=4)
+    try:
+        tickets = [sched.submit(p, 20, temperature=0.0) for p in (P1, P2)]
+        outs = [list(t.tokens()) for t in tickets]
+    finally:
+        sched.close()
+    for p, out in zip((P1, P2), outs):
+        solo.reset()
+        want = [t for t, _ in solo.generate_stream(
+            p, len(p) + 20, temperature=0.0, chunk=5)][len(p):]
+        assert out == want, f"prompt {p} diverged at Hkv={hkv}, ps={ps}"
+
+
+@pytest.mark.parametrize("hkv,ps", PAGE_GEOMETRIES, ids=PAGE_GEOMETRY_IDS)
+@pytest.mark.parametrize("kv_dtype", [jnp.bfloat16, "q8"],
+                         ids=["bf16", "int8"])
+def test_pool_pages_export_import_round_trip(kv_dtype, hkv, ps):
+    """``read_pool_pages`` hands out whole token-major pages
+    ``(L, n, ps, Hkv, Dh)`` (an int8 pool's scale planes beside them) and
+    ``write_pool_pages`` puts them back at other page ids of another
+    engine, value for value; pages nobody wrote stay zero."""
+    cfg = tiny_config(n_kv_heads=hkv, seq_len=32)
+    params = init_params(cfg, seed=4)
+    mesh = make_mesh(tp=1, devices=jax.devices()[:1])
+    a, b = (Engine(cfg, params, mesh=mesh, batch=2, kv_dtype=kv_dtype,
+                   kv_pages=9, kv_page_size=ps) for _ in range(2))
+    rng = np.random.RandomState(11)
+    fill = lambda x: jnp.asarray(  # noqa: E731
+        rng.randint(-100, 100, x.shape), x.dtype)
+    a.cache = jax.tree.map(fill, a.cache)
+    src, dst = [3, 1, 7], [5, 6, 2]
+    pages = a.read_pool_pages(src)
+    names = {"pages.k": "k", "pages.v": "v"}
+    if kv_dtype == "q8":
+        names.update({"pages.k_scale": "k_scale", "pages.v_scale": "v_scale"})
+    assert set(pages) == set(names)
+    for name, attr in names.items():
+        last = 1 if name.endswith("_scale") else cfg.head_size
+        assert pages[name].shape == (cfg.n_layers, 3, ps, hkv, last)
+        np.testing.assert_array_equal(
+            pages[name], np.asarray(getattr(a.cache, attr))[:, src])
+    b.write_pool_pages(dst, pages)
+    back = b.read_pool_pages(dst)
+    rest = [i for i in range(9) if i not in dst]
+    for name, attr in names.items():
+        np.testing.assert_array_equal(back[name], pages[name])
+        assert not np.asarray(getattr(b.cache, attr))[:, rest].any()
 
 
 def test_page_recycling_no_stale_kv(solo_refs, paged_stack):

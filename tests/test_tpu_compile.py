@@ -91,8 +91,8 @@ def test_fused_paged_attention_compiles_at_7b_geometry(one_chip, quantized):
     b, hq, hkv, dh, ps, maxp = 4, 32, 32, 128, 16, 64
     n_pages = 1 + b * maxp
     s = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)  # noqa: E731
-    pool = s((2, n_pages, hkv, ps, dh), jnp.int8 if quantized else jnp.bfloat16)
-    scale = s((2, n_pages, hkv, ps, 1), jnp.float32)
+    pool = s((2, n_pages, ps, hkv, dh), jnp.int8 if quantized else jnp.bfloat16)
+    scale = s((2, n_pages, ps, hkv, 1), jnp.float32)
 
     def f(q, k, v, layer, table, pos, *sc):
         return att.fused_paged_attention(q, k, v, layer, table, pos,
@@ -113,7 +113,8 @@ def _slot_step_text(one_chip, cfg, params, b, t, n_pages, max_pages, ps=16):
     from dllama_tpu.runtime.decode_loop import slot_chunk
 
     s = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)  # noqa: E731
-    pool = tf.KVCache(*(s((2, n_pages, 4, ps, 128), jnp.bfloat16),) * 2)
+    shape = jax.eval_shape(lambda: tf.init_kv_pool(cfg, n_pages, ps)).k.shape
+    pool = tf.KVCache(*(s(shape, jnp.bfloat16),) * 2)
     vec = lambda dt: s((b,), dt)  # noqa: E731
     return jax.jit(
         lambda p, c, tok, pr, nv, k, tm, tp, tk, pt: slot_chunk(
@@ -131,40 +132,86 @@ def _toy_cfg():
                        dtype=jnp.bfloat16)
 
 
-def test_paged_slot_step_names_its_pool_copy(one_chip, monkeypatch):
-    """A 2-layer paged slot step (dense toy weights, 128-wide heads so the
-    fused attention kernel is chosen) compiled for the described chip: its
-    ops carry the program's scopes, and the whole-pool ``copy`` that XLA puts
-    beside the kernel (ROADMAP S3) is one of them, under ``kv_write``."""
+# pages of the toy pools below: 2 layers x 4097 pages x (16, Hkv, 128) bf16 is
+# over the chip's 128 MiB of VMEM, as every served pool is; a pool that fits
+# is prefetched there whole (copy-start/copy-done), which is not the subject
+POOL_PAGES = 4097
+
+
+def _assert_pool_is_only_scattered(text, cfg, n_pages, ps):
+    """Of every instruction of the compiled text whose result has the paged
+    pool's full shape, the only ones that make a pool are the KV write's
+    in-place scatter and the fusion that wraps it: no ``copy``."""
+    import re
+
+    shape = f"[{cfg.n_layers},{n_pages},{ps},{cfg.n_kv_heads},{cfg.head_size}]"
+    ops = re.findall(r"^\s*(?:ROOT )?%?([\w.\-]+) = (\S+) ([\w\-]+)\((.*)$",
+                     text, re.M)
+    made = []
+    for name, result, op, rest in ops:
+        if shape in result and not result.startswith("(") and op not in (
+                "parameter", "get-tuple-element", "bitcast"):
+            path = re.search(r'op_name="([^"]+)"', rest)
+            made.append((name, op, path.group(1) if path else ""))
+    assert any(op == "scatter" for _, op, _ in made), made
+    assert all(op in ("scatter", "fusion")
+               and path.endswith("/kv_write/scatter")
+               for _, op, path in made), made
+
+
+def _dense_toy_params(cfg, one_chip):
+    from dllama_tpu.models.params import param_shapes
+
+    return {k: jax.ShapeDtypeStruct(
+        shape, jnp.float32 if k.startswith("rms") else jnp.bfloat16,
+        sharding=one_chip) for k, shape in param_shapes(cfg).items()}
+
+
+# Hkv 4 (the toy's) and 8 (Mistral's: the served cell's page is (16, 8, 128))
+@pytest.mark.parametrize("hkv", [4, 8])
+def test_paged_slot_step_has_no_pool_copy(one_chip, monkeypatch, hkv):
+    """A 2-layer paged slot step of 4 slots x 1 token (dense toy weights,
+    128-wide heads so the fused attention kernel is chosen) compiled for the
+    described chip: its ops carry the program's scopes, the kernel is there,
+    and nothing of the pool's full shape is made but the in-place scatter of
+    the KV write.  A page is token-major (L, P, ps, Hkv, Dh), so the
+    scatter's layout is the pool's resident one; with the offset between the
+    head axes XLA copied the whole pool per layer here, and in and out of
+    the program (PERF.md §6, PR 27)."""
     import re
     import time
 
-    from dllama_tpu.models.params import param_shapes
     from dllama_tpu.ops.scopes import SCOPES
 
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     monkeypatch.setattr(jax, "device_count", lambda: 1)
-    cfg = _toy_cfg()
-    s = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)  # noqa: E731
-    params = {k: s(shape, jnp.float32 if k.startswith("rms") else jnp.bfloat16)
-              for k, shape in param_shapes(cfg).items()}
-    n_pages, ps = 65, 16
+    cfg = _toy_cfg().with_(n_heads=hkv, n_kv_heads=hkv, dim=128 * hkv)
+    n_pages, ps = POOL_PAGES, 16
     t0 = time.monotonic()
-    text = _slot_step_text(one_chip, cfg, params, 4, 1, n_pages, 16)
+    text = _slot_step_text(one_chip, cfg, _dense_toy_params(cfg, one_chip), 4,
+                           1, n_pages, 16)
     assert time.monotonic() - t0 < 30, "too slow for tier-1: drop this test"
     assert "paged_attn_fused" in text
-    ops = re.findall(r"^\s*(?:ROOT )?%?([\w.\-]+) = (\S+) ([\w\-]+)\(.*?"
-                     r"op_name=\"([^\"]+)\"", text, re.M)
+    paths = re.findall(r"op_name=\"([^\"]+)\"", text)
     scope_of = lambda path: ([c for c in path.split("/") if c in SCOPES]  # noqa: E731
                              or [None])[-1]
     assert {"qkv", "kv_write", "attn", "wo", "w2", "head"} <= \
-        {scope_of(path) for _, _, _, path in ops}
-    pool_copies = [(name, path) for name, shape, op, path in ops
-                   if op == "copy" and f"[2,{n_pages},4,{ps},128]" in shape]
-    # the copy is a layout change between the scatter's pool layout and the
-    # kernel's; the PR that takes it out (S3) turns this into "no such copy"
-    assert pool_copies and all(scope_of(path) == "kv_write"
-                               for _, path in pool_copies), pool_copies
+        {scope_of(path) for path in paths}
+    _assert_pool_is_only_scattered(text, cfg, n_pages, ps)
+
+
+def test_mixed_paged_slot_step_has_no_pool_copy(one_chip, monkeypatch):
+    """The twin for the served mixed step's form, 16 slots x a 16-token chunk
+    (gather attention), at Mistral's 8 KV heads: no ``copy`` of the pool's
+    full shape on the way in or out, nor inside the layer loop."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(jax, "device_count", lambda: 1)
+    cfg = _toy_cfg().with_(n_heads=8, n_kv_heads=8, dim=1024)
+    n_pages, ps = POOL_PAGES, 16
+    text = _slot_step_text(one_chip, cfg, _dense_toy_params(cfg, one_chip), 16,
+                           16, n_pages, 8)
+    assert "paged_attn_fused" not in text  # t > 1: the gather form
+    _assert_pool_is_only_scattered(text, cfg, n_pages, ps)
 
 
 def test_mixed_slot_step_keeps_q40_on_the_fused_kernel(one_chip, monkeypatch):

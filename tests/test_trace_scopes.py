@@ -286,7 +286,12 @@ def test_an_idle_scheduler_goes_quiet():
     try:
         list(sched.submit([5, 9, 2], 2).tokens())
         sched.flush()
-        time.sleep(0.2)
+        # the first wait of the idle spell is the one that is recorded, when
+        # it ends: let it end before the ring is cleared
+        wake0 = sched._park_wakeups
+        deadline = time.monotonic() + 5
+        while sched._park_wakeups == wake0 and time.monotonic() < deadline:
+            time.sleep(0.05)
         obs_trace.clear()
         wake0 = sched._park_wakeups
         deadline = time.monotonic() + 5
