@@ -202,7 +202,7 @@ def _bench_decode(cfg, chunk=32, n_chunks=10, profile=False, start_pos=0,
     from dllama_tpu.models.transformer import init_kv_cache
     from dllama_tpu.runtime.decode_loop import decode_chunk
 
-    params = maybe_blocked(_zero_q40_params(cfg, codec), codec)
+    params = _zero_q40_params(cfg, codec)
     cache = init_kv_cache(cfg, batch=batch, quant=kv_quant)
 
     fn = jax.jit(
@@ -280,18 +280,6 @@ def _bench_decode(cfg, chunk=32, n_chunks=10, profile=False, start_pos=0,
     return float(np.mean(times))
 
 
-def maybe_blocked(params, codec="q40"):
-    """Apply the tile-contiguous layout lever when the env asks for it —
-    the ONE shared recipe (bench decode/prefill, tools/profile_decode.py).
-    Q40 only: blocked_params is a no-op on Q8 planes, and claiming the
-    layout for a q80 run would mislabel the measurement."""
-    if os.environ.get("DLLAMA_Q40_LAYOUT", "") == "blocked" and codec == "q40":
-        from dllama_tpu.ops import q40 as _q40
-        params = _q40.blocked_params(params)
-        print("bench: blocked (tile-contiguous) Q40 layout", file=sys.stderr)
-    return params
-
-
 def _bench_prefill(cfg, T=512, reps=6):
     """Avg ms/token over ``reps`` bucketed prefill forwards (compile +
     warmup excluded).  The cache is NOT donated — each rep rewrites the
@@ -302,7 +290,7 @@ def _bench_prefill(cfg, T=512, reps=6):
     import numpy as np
     from dllama_tpu.models.transformer import forward_last, init_kv_cache
 
-    params = maybe_blocked(_zero_q40_params(cfg))
+    params = _zero_q40_params(cfg)
     cache = init_kv_cache(cfg, batch=1)
     fn = jax.jit(lambda p, c, t: forward_last(p, cfg, t, c, jnp.int32(0),
                                               jnp.int32(T - 1)))
@@ -340,7 +328,7 @@ def _bench_sched(cfg, slots=4, max_new=96, tp=1):
     from dllama_tpu.runtime.engine import Engine
     from dllama_tpu.runtime.scheduler import SlotScheduler
 
-    params = maybe_blocked(_zero_q40_params(cfg))
+    params = _zero_q40_params(cfg)
     eng = Engine(cfg, params,
                  mesh=make_mesh(tp=tp, devices=jax.devices()[:tp]),
                  batch=slots)
@@ -418,7 +406,7 @@ def _bench_sched_prefix(cfg, slots=4, max_new=96):
     from dllama_tpu.runtime.engine import Engine
     from dllama_tpu.runtime.scheduler import SlotScheduler
 
-    params = maybe_blocked(_zero_q40_params(cfg))
+    params = _zero_q40_params(cfg)
     page_size = 16
     eng = Engine(cfg, params,
                  mesh=make_mesh(tp=1, devices=jax.devices()[:1]),
@@ -483,7 +471,7 @@ def _bench_sched_pressure(cfg, slots=4, max_new=96):
     from dllama_tpu.runtime.engine import Engine
     from dllama_tpu.runtime.scheduler import SlotScheduler
 
-    params = maybe_blocked(_zero_q40_params(cfg))
+    params = _zero_q40_params(cfg)
     page_size = 16
     rng = np.random.RandomState(7)
     prompts = [[int(t) for t in rng.randint(1, cfg.vocab_size, 8 + 4 * i)]
@@ -560,7 +548,7 @@ def _bench_sched_overlap(cfg, slots=4, max_new=96):
     from dllama_tpu.runtime.engine import Engine
     from dllama_tpu.runtime.scheduler import SlotScheduler
 
-    params = maybe_blocked(_zero_q40_params(cfg))
+    params = _zero_q40_params(cfg)
     rng = np.random.RandomState(7)
     prompts = [[int(t) for t in rng.randint(1, cfg.vocab_size, 8)]
                for _ in range(slots)]
@@ -642,7 +630,7 @@ def _bench_sched_fused(cfg, slots=4, max_new=96):
     from dllama_tpu.runtime.engine import Engine
     from dllama_tpu.runtime.scheduler import SlotScheduler
 
-    params = maybe_blocked(_zero_q40_params(cfg))
+    params = _zero_q40_params(cfg)
     page_size = 16
     rng = np.random.RandomState(7)
     prompts = [[int(t) for t in rng.randint(1, cfg.vocab_size, 8)]
@@ -743,7 +731,7 @@ def _bench_sched_spec(cfg, slots=4, max_new=96, spec_k=4):
     from dllama_tpu.runtime.scheduler import SlotScheduler
     from dllama_tpu.runtime.spec import PromptLookupProposer
 
-    params = maybe_blocked(_zero_q40_params(cfg))
+    params = _zero_q40_params(cfg)
     rng = np.random.RandomState(7)
     prompts = [[int(t) for t in rng.randint(1, cfg.vocab_size, 8 + 4 * i)]
                for i in range(slots)]

@@ -437,8 +437,7 @@ def run_blocks(params: Params, cfg: ModelConfig, tokens: jax.Array,
     # gets a QLayerView and the fused kernel indexes the stacked buffer
     # directly (scalar-prefetch index_map, ops/q40.py).
     qt_keys = [k for k in layer_keys
-               if isinstance(params[k], (q40.QTensor, q40.BlockedQTensor,
-                                         q8.Q8Tensor))]
+               if isinstance(params[k], (q40.QTensor, q8.Q8Tensor))]
     stacked = {k: params[k] for k in layer_keys if k not in qt_keys}
 
     def block(carry, layer):

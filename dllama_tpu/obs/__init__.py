@@ -15,8 +15,7 @@ Three stdlib-only building blocks, threaded through every layer:
   + ``tools/trace_dump.py``); the cheap first-line latency attribution
   next to the heavyweight XLA tracer (``runtime/profiling.py``).
 * :mod:`.dispatch` — the kernel-dispatch ledger: which matmul path every
-  weight actually took (pallas-fused / pallas-blocked / xla-dequant /
-  dense), labeled degrade counters replacing the old warn-once prints,
+  weight actually took (pallas-fused / xla-dequant / dense), labeled degrade counters replacing the old warn-once prints,
   and the process-wide ``degraded`` flag that ``/health`` and the
   end-of-run CLI summary surface.
 * :mod:`.cost` — the analytic roofline cost model: FLOPs/bytes-moved

@@ -523,7 +523,7 @@ HOST_DEVICE_RECV_BYTES = REGISTRY.histogram(
 MATMUL_DISPATCH = REGISTRY.labeled_counter(
     "matmul_dispatch", ("codec", "path"),
     "Matmul dispatch decisions by codec (q40/q8/dense) and executed path "
-    "(pallas-fused, pallas-blocked, xla-dequant, dense).  Counted at "
+    "(pallas-fused, xla-dequant, dense).  Counted at "
     "trace time: one bump per compiled call site, not per decode step.")
 Q40_DEGRADE = REGISTRY.labeled_counter(
     "q40_degrade", "reason",

@@ -16,8 +16,7 @@ but are not wall-clock.  The ``raw()`` export therefore samples
 router's ``/debug/trace?scope=fleet``) can compute a per-replica offset
 and shift every ring onto one wall-clock axis.  Capacity comes from
 ``--trace-buffer`` / ``DLLAMA_TRACE_BUFFER`` (default 8192 spans ≈ a few
-hundred requests); a malformed value warns once and falls back, mirroring
-the ``DLLAMA_Q40_BLOCK_TILES`` contract.
+hundred requests); a malformed value warns once and falls back.
 
 ``span(name, **args)`` is the one entry point for a block of code: besides
 the ring record it enters ``jax.profiler.TraceAnnotation(name, **args)``,

@@ -70,17 +70,17 @@ from .. import quants
 from ..obs import dispatch as obs_dispatch
 from ..parallel.mesh import get_active_mesh
 
-# Sweet spot measured on v5e (HBM-roofline for the 4096×11008 matvec);
-# shrunk automatically when N or D is smaller.  Env-overridable so
-# tools/sweep_q40.py can explore the tile space on hardware without edits.
-TILE_N = int(os.environ.get("DLLAMA_Q40_TILE_N", "1024"))
-TILE_D = int(os.environ.get("DLLAMA_Q40_TILE_D", "1024"))
+# The one tile pair every cell has run (PERF.md §6, PR 28: the sweep that
+# retired the others); _tiles shrinks it when N or D is smaller.  TILE_N
+# also sets the pack-time padding of the input dim (padded_n).
+TILE_N = 1024
+TILE_D = 1024
 # Up to this many rows the fused kernel holds every activation row in one
 # block (all decode programs).  Above it, on a single device, the same kernel
-# runs over row blocks (_row_block); a device mesh, the blocked layout and
-# Q80 still send more rows to the XLA path, untimed (PERF.md §7).  The chip
-# says XLA does not pipeline the dequant: it writes each layer's weight to
-# HBM as bf16 and reads it back for one multiply (PERF.md §6, PR 25).
+# runs over row blocks (_row_block); a device mesh (_auto_pallas) and Q80
+# (ops/q8.py) still send more rows to the XLA path, untimed (PERF.md §7).
+# The chip says XLA does not pipeline the dequant: it writes each layer's
+# weight to HBM as bf16 and reads it back for one multiply (PERF.md §6, PR 25).
 PALLAS_MAX_ROWS = 128
 # Row-blocked form: the most rows one pass over the weights serves, the VMEM
 # its row-sized buffers may take, and the kernel's scoped-VMEM limit (the
@@ -88,8 +88,6 @@ PALLAS_MAX_ROWS = 128
 ROW_BLOCK_MAX = 1024
 ROW_BLOCK_VMEM = 32 * 1024 * 1024
 ROW_VMEM_LIMIT = 64 * 1024 * 1024
-# Kernel dequant variant (see _q40_kernel): classic | fma | folded | exact.
-KERNEL_VARIANT = os.environ.get("DLLAMA_Q40_VARIANT", "classic")
 
 
 def padded_n(n: int) -> int:
@@ -323,8 +321,6 @@ def _f16_bits_to_f32(u: jax.Array) -> jax.Array:
 
 def dequantize(qt: QTensor, dtype=jnp.float32) -> jax.Array:
     """Reconstruct the dense array (tests / the XLA matmul path)."""
-    if isinstance(qt, BlockedQTensor):
-        qt = unblock(qt)
     *lead, n2, d = qt.qpacked.shape
     nb = n2 // 16
     v = qt.qpacked.astype(jnp.int32).reshape(*lead, nb, 16, d)
@@ -343,49 +339,20 @@ def dequantize(qt: QTensor, dtype=jnp.float32) -> jax.Array:
 # Pallas fused kernel
 # ---------------------------------------------------------------------------
 
-def _q40_kernel(xlo_ref, xhi_ref, bsum_ref, qp_ref, s_ref, o_ref, acc_ref, *,
-                nsteps, variant, n_axis=1):
+def _q40_kernel(xlo_ref, xhi_ref, qp_ref, s_ref, o_ref, acc_ref, *,
+                nsteps, n_axis=1):
     """One (tile_n × tile_d) fused dequant-matmul step: the weight tile is
     unpacked once and contracted against every activation row of the block
     (all rows, or one row block of the row-blocked form, whose reduction
     axis is grid axis ``n_axis`` = 2).
 
-    The lo/hi nibble planes are contracted by two separate dots against the
-    matching halves of x (prepared outside the kernel, where XLA fuses the
-    splits), which avoids a concat-to-logical-order relayout.  VPU unpack
-    work is the decode bottleneck after DMA, so four ``variant`` trade-offs
-    exist between per-weight VPU ops and rounding:
-
-    * ``classic`` — ``bf16(f32(v−8)·s)`` per weight: the reference's
-      dequantization rounding (one bf16 round of the exact product,
-      funcs.cpp:330-335 semantics); ~5.5 VPU ops/weight.
-    * ``fma``     — same f32 math regrouped as ``v·s + (−8·s)`` with the
-      per-block ``−8·s`` computed once per (block, column): saves the
-      per-weight subtract if the backend emits a fused multiply-add
-      (~4.5 VPU ops/weight); identical result up to one f32 rounding
-      regrouping, same single bf16 round as classic.
-    * ``folded``  — the −8 bias never touches the weights: with
-      ``w=(v−8)·s``, ``x·w = x·(v·s) − 8·(Σ_block x)·s``, so the kernel
-      feeds the MXU ``bf16(v)·bf16(s)`` and corrects with a per-block dot
-      against block sums of x; ~3.5 VPU ops/weight, rounding
-      ~2× classic (still an order below the codec's ±s/2).
-    * ``exact``   — per-block batched dots of the *raw* nibbles (integers
-      ≤15, exact in bf16), scales applied per (block, column) in f32
-      afterwards; ~2.5 VPU ops/weight and *less* rounding than classic —
-      but its (nb, 16, t)×(nb, 16, td) batched dots stress the MXU with
-      K=16 passes, so its win is hardware-dependent.  For this variant
-      the activation refs hold TRANSPOSED (tn/2, t) planes and
-      ``bsum_ref`` the transposed (nb, tn/2) matrix, so every in-kernel
-      reshape regroups sublanes only (the original (t, tn/2) form needed
-      a lane-dim regroup — an unsupported Mosaic shape cast, which kept
-      this variant interpret-only through r03).
-
-    ``bsum_ref`` is a constant (tn/2, nb) 0/1 matrix ((nb, tn/2) for
-    ``exact``; full-array block either way, so its narrow lane dim is
-    legal under Mosaic's block-shape rules, which a (t, tile_n/32)
-    streamed input is not); ``folded``/``exact`` recover the per-block
-    activation sums with two tiny MXU dots instead of a streamed ``xs``
-    operand.
+    Dequantization is ``bf16(f32(v−8)·s)`` per weight: the reference's
+    rounding (one bf16 round of the exact product, funcs.cpp:330-335
+    semantics), the same on every tp shard and in the XLA path.  The lo/hi
+    nibble planes are contracted by two separate dots against the matching
+    halves of x (prepared outside the kernel, where XLA fuses the splits),
+    which avoids a concat-to-logical-order relayout of the unpacked tile.
+    VPU unpack work (~5.5 ops/weight) is the decode bottleneck after DMA.
     """
     i = pl.program_id(n_axis)
     qp = qp_ref[...]                                      # (tn/2, td) uint8
@@ -395,62 +362,12 @@ def _q40_kernel(xlo_ref, xhi_ref, bsum_ref, qp_ref, s_ref, o_ref, acc_ref, *,
     sbits = s_ref[...].reshape(nb, td)                    # uint16 f16 bits
     s32 = _f16_bits_to_f32(sbits)                         # (nb, td) f32, exact
     vi = qp.astype(jnp.int32)
-
-    def block_sums():
-        """Per-block sums of this tile's activations: (t, nb) f32 — the
-        whole block's sum is the sum over its lo and hi halves."""
-        b = bsum_ref[:]
-        return (jnp.dot(xlo_ref[:], b, preferred_element_type=jnp.float32)
-                + jnp.dot(xhi_ref[:], b, preferred_element_type=jnp.float32))
-
-    if variant == "exact":
-        # Mosaic-legal form (r04 rework; the original regrouped the LANE
-        # dim of (t, tn/2) activations, an unsupported shape cast — see
-        # mosaic-v5e notes): the activation operands arrive TRANSPOSED
-        # (tn/2, t) from _pallas_matmul, so every reshape below splits the
-        # SUBLANE dim only, and ``bsum_ref`` holds the transposed (nb,
-        # tn/2) summing matrix.  The batched dot emits (nb, t, td)
-        # directly — no in-kernel transpose anywhere.
-        lo = (vi & 0xF).astype(jnp.bfloat16).reshape(nb, 16, td)
-        hi = (vi >> 4).astype(jnp.bfloat16).reshape(nb, 16, td)
-        xloT = xlo_ref[:]                                 # (tn/2, t) bf16
-        xhiT = xhi_ref[:]
-        dot = functools.partial(
-            jax.lax.dot_general,
-            dimension_numbers=(((1,), (1,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32)
-        tt = xloT.shape[-1]
-        p = (dot(xloT.reshape(nb, 16, tt), lo)
-             + dot(xhiT.reshape(nb, 16, tt), hi))         # (nb, t, td)
-        bs = (jnp.dot(bsum_ref[:], xloT, preferred_element_type=jnp.float32)
-              + jnp.dot(bsum_ref[:], xhiT, preferred_element_type=jnp.float32))
-        corr = p - 8.0 * bs[:, :, None]                   # bs: (nb, t)
-        part = jnp.sum(corr * s32[:, None, :], axis=0)    # (t, td)
-    else:
-        if variant == "classic":
-            lo = ((vi & 0xF).astype(jnp.float32) - 8.0).reshape(nb, 16, td)
-            hi = ((vi >> 4).astype(jnp.float32) - 8.0).reshape(nb, 16, td)
-            lo = (lo * s32[:, None, :]).astype(jnp.bfloat16).reshape(tn2, td)
-            hi = (hi * s32[:, None, :]).astype(jnp.bfloat16).reshape(tn2, td)
-            bias = 0.0
-        elif variant == "fma":
-            m32 = -8.0 * s32                              # (nb, td), amortized /16
-            lo = (vi & 0xF).astype(jnp.float32).reshape(nb, 16, td)
-            hi = (vi >> 4).astype(jnp.float32).reshape(nb, 16, td)
-            lo = (lo * s32[:, None, :] + m32[:, None, :]).astype(jnp.bfloat16).reshape(tn2, td)
-            hi = (hi * s32[:, None, :] + m32[:, None, :]).astype(jnp.bfloat16).reshape(tn2, td)
-            bias = 0.0
-        else:  # folded
-            sb = s32.astype(jnp.bfloat16)
-            lo = (vi & 0xF).astype(jnp.bfloat16).reshape(nb, 16, td)
-            hi = (vi >> 4).astype(jnp.bfloat16).reshape(nb, 16, td)
-            lo = (lo * sb[:, None, :]).reshape(tn2, td)
-            hi = (hi * sb[:, None, :]).reshape(tn2, td)
-            bias = 8.0 * jnp.dot(block_sums().astype(jnp.bfloat16), sb,
-                                 preferred_element_type=jnp.float32)
-        part = (jnp.dot(xlo_ref[:], lo, preferred_element_type=jnp.float32)
-                + jnp.dot(xhi_ref[:], hi, preferred_element_type=jnp.float32)
-                - bias)
+    lo = ((vi & 0xF).astype(jnp.float32) - 8.0).reshape(nb, 16, td)
+    hi = ((vi >> 4).astype(jnp.float32) - 8.0).reshape(nb, 16, td)
+    lo = (lo * s32[:, None, :]).astype(jnp.bfloat16).reshape(tn2, td)
+    hi = (hi * s32[:, None, :]).astype(jnp.bfloat16).reshape(tn2, td)
+    part = (jnp.dot(xlo_ref[:], lo, preferred_element_type=jnp.float32)
+            + jnp.dot(xhi_ref[:], hi, preferred_element_type=jnp.float32))
 
     @pl.when(i == 0)
     def _():
@@ -465,10 +382,10 @@ def _q40_kernel(xlo_ref, xhi_ref, bsum_ref, qp_ref, s_ref, o_ref, acc_ref, *,
         o_ref[:] = acc_ref[:]
 
 
-def _stacked_q40_kernel(lidx_ref, xlo_ref, xhi_ref, bsum_ref, qp_ref, s_ref,
+def _stacked_q40_kernel(lidx_ref, xlo_ref, xhi_ref, qp_ref, s_ref,
                         o_ref, acc_ref, **kw):
     del lidx_ref  # consumed by the index_maps
-    _q40_kernel(xlo_ref, xhi_ref, bsum_ref, qp_ref, s_ref, o_ref, acc_ref, **kw)
+    _q40_kernel(xlo_ref, xhi_ref, qp_ref, s_ref, o_ref, acc_ref, **kw)
 
 
 def _x_parts(x: jax.Array) -> tuple[jax.Array, jax.Array]:
@@ -483,63 +400,15 @@ def _x_parts(x: jax.Array) -> tuple[jax.Array, jax.Array]:
     return x_lo, x_hi
 
 
-@functools.cache
-def _bsum_mat(tile_n: int) -> np.ndarray:
-    """Constant (tile_n/2, tile_n/32) block-summing matrix: column b is the
-    indicator of packed rows [16b, 16b+16) — one half of quantization block
-    b — so ``x_half @ B`` yields that half's per-block sums."""
-    nb = tile_n // 32
-    return np.kron(np.eye(nb, dtype=np.float32),
-                   np.ones((16, 1), np.float32)).astype(jnp.bfloat16)
-
-
-def _check_variant(variant: str | None) -> str:
-    v = variant or KERNEL_VARIANT
-    if v not in ("classic", "fma", "folded", "exact"):
-        raise ValueError(f"unknown q40 kernel variant {v!r} "
-                         "(expected classic | fma | folded | exact)")
-    return v
-
-
-def _tile_rules() -> list[tuple[int, int, int]]:
-    """Width-aware tile overrides, highest d_min first: ``(d_min, tn, td)``
-    applies to weights with output width ≥ d_min.
-
-    Motivation (docs/PERF.md lever #1): a (tn/2, td) tile of the row-major
-    packed plane is td contiguous bytes per row, so td sets the HBM burst
-    length — and measured per-shape kernel bandwidth falls with d (wo at
-    d=4096 streams ~632 GB/s, w13 at 22016 only ~354).  The rule table is
-    data-driven (env ``DLLAMA_Q40_TILES_JSON``, e.g. ``[[8192,512,2048]]``)
-    so the hardware sweep (tools/sweep_q40.py) can flip defaults without a
-    code edit; empty until a driver-verified measurement lands."""
-    s = os.environ.get("DLLAMA_Q40_TILES_JSON", "")
-    if not s:
-        return []
-    import json
-    return sorted(((int(a), int(b), int(c)) for a, b, c in json.loads(s)),
-                  reverse=True)
-
-
-def _tiles(n: int, d: int, cap_elems: int = 4 * 1024 * 1024) -> tuple[int, int]:
-    """Pick reduction/output tile sizes; the ragged last D tile is masked
-    on store.  Pack-time padding makes n a TILE_N multiple for whole
-    tensors; a TP shard's local n may be a smaller power-of-two multiple
-    (padded_n/tp), so fall down the divisor ladder rather than taking the
-    whole axis as one tile (which would blow VMEM at 7B shapes).
-
-    ``cap_elems`` bounds tn·td so the working set fits VMEM and is
-    codec-specific: q40's packed tile + bf16 dequant temporaries stay
-    ~12 MB at the 4 Mi default, but the q8 kernel also carries an f32
-    intermediate of tn·td·4 B (16 MB alone at 4 Mi), so its dispatch
-    passes a 2 Mi cap — one shared ladder, two ceilings (ADVICE r04 #2)."""
-    for d_min, tn, td in _tile_rules():
-        # tn ≥ 256 keeps the scales operand's sublane count ≥ 8 (Mosaic);
-        # td must be a positive lane-dim multiple; tn·td is capped per the
-        # calling codec (see above).  Malformed rules are skipped, not
-        # applied.
-        if d >= d_min and tn >= 256 and tn % 32 == 0 and n % tn == 0 \
-                and td >= 128 and td % 128 == 0 and tn * td <= cap_elems:
-            return tn, td
+def _tiles(n: int, d: int) -> tuple[int, int]:
+    """Pick reduction/output tile sizes (the Q40 and the Q80 kernel share
+    the rule); the ragged last D tile is masked on store.  Pack-time padding
+    makes n a TILE_N multiple for whole tensors; a TP shard's local n may be
+    a smaller power-of-two multiple (padded_n/tp), so fall down the divisor
+    ladder rather than taking the whole axis as one tile (which would blow
+    VMEM at 7B shapes).  At most TILE_N·TILE_D = 1 Mi elements a tile: the
+    packed tile and its bf16 dequant temporaries (Q80: an f32 intermediate
+    of tn·td·4 B) stay well inside VMEM."""
     tile_n = n
     for tn in (TILE_N, TILE_N // 2, TILE_N // 4, TILE_N // 8, TILE_N // 16, 32):
         if n % tn == 0:
@@ -549,7 +418,7 @@ def _tiles(n: int, d: int, cap_elems: int = 4 * 1024 * 1024) -> tuple[int, int]:
     return tile_n, tile_d
 
 
-def _row_block(t: int, tile_n: int, tile_d: int, variant: str) -> int | None:
+def _row_block(t: int, tile_n: int, tile_d: int) -> int | None:
     """Rows per block of the row-blocked form; None up to PALLAS_MAX_ROWS,
     where one block holds every row and the program is the one it always was.
 
@@ -558,25 +427,24 @@ def _row_block(t: int, tile_n: int, tile_d: int, variant: str) -> int | None:
     are one pass over the weights with one dequant per tile; more rows split
     into equal blocks, each re-streaming the weights (at 256 rows and up a
     block is MXU-bound, so the re-read is hidden).  Blocks are sublane-aligned
-    (lane-aligned for ``exact``, whose activations arrive transposed); the
-    ragged last block is masked on store like the ragged ``d`` edge."""
+    (16 rows of bf16); the ragged last block is masked on store like the
+    ragged ``d`` edge."""
     if t <= PALLAS_MAX_ROWS:
         return None
     # per row: both activation halves and the output tile, double-buffered,
     # the f32 accumulator and the two dots' f32 results
     per_row = 2 * 2 * (tile_n // 2) * 2 + 5 * tile_d * 4
     cap = min(ROW_BLOCK_MAX, max(256, ROW_BLOCK_VMEM // per_row // 128 * 128))
-    align = 128 if variant == "exact" else 16
-    return -(-pl.cdiv(t, pl.cdiv(t, cap)) // align) * align
+    return -(-pl.cdiv(t, pl.cdiv(t, cap)) // 16) * 16
 
 
-def _mm_call(t: int, n: int, d: int, tile_n: int, tile_d: int, variant: str,
+def _mm_call(t: int, n: int, d: int, tile_n: int, tile_d: int,
              stacked: bool, row_block: int | None, **ms):
     """What the flat and the stacked kernel share of their ``pallas_call``:
     grid and specs (as keywords), compiler parameters, and the kernel's own
     keywords.  The grid is ``(d tiles, n steps)`` with every row in the block
     up to PALLAS_MAX_ROWS, else ``(row blocks, d tiles, n steps)``."""
-    tr = row_block or _row_block(t, tile_n, tile_d, variant)
+    tr = row_block or _row_block(t, tile_n, tile_d)
     nd, nn = pl.cdiv(d, tile_d), n // tile_n
     if tr is None:
         grid, tb = (nd, nn), t
@@ -592,45 +460,23 @@ def _mm_call(t: int, n: int, d: int, tile_n: int, tile_d: int, variant: str,
     lead = (1,) if stacked else ()
     layer = lambda l: tuple(ref[0] for ref in l)  # noqa: E731 — prefetched index
     w_at = at(lambda r, j, i, *l: layer(l) + (i, j))
-    nb = tile_n // 32
-    if variant == "exact":
-        # transposed activation planes + transposed summing matrix: lets
-        # the kernel's per-block reshapes regroup sublanes only (the lane
-        # regroup of the original form does not lower under Mosaic)
-        xspec = pl.BlockSpec((tile_n // 2, tb), at(lambda r, j, i, *l: (i, r)), **ms)
-        bshape = (nb, tile_n // 2)
-    else:
-        xspec = pl.BlockSpec((tb, tile_n // 2), at(lambda r, j, i, *l: (r, i)), **ms)
-        bshape = (tile_n // 2, nb)
+    xspec = pl.BlockSpec((tb, tile_n // 2), at(lambda r, j, i, *l: (r, i)), **ms)
     grid_kw = dict(
         grid=grid,
         in_specs=[
             xspec,
             xspec,
-            pl.BlockSpec(bshape, at(lambda r, j, i, *l: (0, 0)), **ms),
             pl.BlockSpec(lead + (tile_n // 2, tile_d), w_at, **ms),
-            pl.BlockSpec(lead + (nb, tile_d), w_at, **ms),
+            pl.BlockSpec(lead + (tile_n // 32, tile_d), w_at, **ms),
         ],
         out_specs=pl.BlockSpec((tb, tile_d), at(lambda r, j, i, *l: (r, j)), **ms),
         scratch_shapes=[pltpu.VMEM((tb, tile_d), jnp.float32)])
-    return grid_kw, params, dict(nsteps=nn, variant=variant,
-                                 n_axis=len(grid) - 1)
+    return grid_kw, params, dict(nsteps=nn, n_axis=len(grid) - 1)
 
 
-def _mm_operands(x: jax.Array, tile_n: int, variant: str):
-    """The kernel's activation halves and block-summing matrix (transposed
-    for ``exact``)."""
-    x_lo, x_hi = _x_parts(x.astype(jnp.bfloat16))
-    bsum = jnp.asarray(_bsum_mat(tile_n))
-    if variant == "exact":
-        return x_lo.T, x_hi.T, bsum.T
-    return x_lo, x_hi, bsum
-
-
-@functools.partial(jax.jit, static_argnames=("interpret", "variant", "tiles",
-                                             "row_block"))
+@functools.partial(jax.jit, static_argnames=("interpret", "tiles", "row_block"))
 def _pallas_matmul(x: jax.Array, qpacked: jax.Array, scales: jax.Array,
-                   interpret: bool = False, variant: str | None = None,
+                   interpret: bool = False,
                    tiles: tuple[int, int] | None = None,
                    row_block: int | None = None) -> jax.Array:
     """x (t, n_padded) @ packed (n_padded/2, d) → (t, d) f32.
@@ -640,10 +486,8 @@ def _pallas_matmul(x: jax.Array, qpacked: jax.Array, scales: jax.Array,
     t, n = x.shape
     d = qpacked.shape[-1]
     tile_n, tile_d = tiles or _tiles(n, d)
-    variant = _check_variant(variant)
     grid_kw, params, kernel_kw = _mm_call(
-        t, n, d, tile_n, tile_d, variant, False, row_block,
-        memory_space=pltpu.VMEM)
+        t, n, d, tile_n, tile_d, False, row_block, memory_space=pltpu.VMEM)
     return pl.pallas_call(
         functools.partial(_q40_kernel, **kernel_kw),
         **grid_kw,
@@ -651,16 +495,16 @@ def _pallas_matmul(x: jax.Array, qpacked: jax.Array, scales: jax.Array,
         compiler_params=params,
         interpret=interpret,
         name="q40_mm",
-    )(*_mm_operands(x, tile_n, variant), qpacked, scales)
+    )(*_x_parts(x.astype(jnp.bfloat16)), qpacked, scales)
 
 
-@functools.partial(jax.jit, static_argnames=("interpret", "variant",
-                                             "row_block"))
+@functools.partial(jax.jit, static_argnames=("interpret", "tiles", "row_block"))
 def _pallas_matmul_stacked(x: jax.Array, qpacked: jax.Array, scales: jax.Array,
                            layer: jax.Array, interpret: bool = False,
-                           variant: str | None = None,
+                           tiles: tuple[int, int] | None = None,
                            row_block: int | None = None) -> jax.Array:
-    """Layer-indexed matmul over layer-stacked packed weights.
+    """Layer-indexed matmul over layer-stacked packed weights (``tiles`` and
+    ``row_block`` as in :func:`_pallas_matmul`).
 
     The layer index rides as a scalar-prefetch argument into the block
     index_maps, so the kernel DMAs tiles of layer ``layer`` straight out of
@@ -671,11 +515,9 @@ def _pallas_matmul_stacked(x: jax.Array, qpacked: jax.Array, scales: jax.Array,
     """
     t, n = x.shape
     d = qpacked.shape[-1]
-    tile_n, tile_d = _tiles(n, d)
-    variant = _check_variant(variant)
+    tile_n, tile_d = tiles or _tiles(n, d)
     grid_kw, params, kernel_kw = _mm_call(
-        t, n, d, tile_n, tile_d, variant, True, row_block)
-    operands = _mm_operands(x, tile_n, variant)
+        t, n, d, tile_n, tile_d, True, row_block)
     return pl.pallas_call(
         functools.partial(_stacked_q40_kernel, **kernel_kw),
         grid_spec=pltpu.PrefetchScalarGridSpec(num_scalar_prefetch=1,
@@ -684,7 +526,8 @@ def _pallas_matmul_stacked(x: jax.Array, qpacked: jax.Array, scales: jax.Array,
         compiler_params=params,
         interpret=interpret,
         name="q40_mm_stacked",
-    )(layer.reshape(1).astype(jnp.int32), *operands, qpacked, scales)
+    )(layer.reshape(1).astype(jnp.int32), *_x_parts(x.astype(jnp.bfloat16)),
+      qpacked, scales)
 
 
 @dataclass(frozen=True)
@@ -736,208 +579,6 @@ def _pad_x(x2: jax.Array, n: int, np_: int) -> jax.Array:
     if np_ == n:
         return x2
     return jnp.pad(x2, ((0, 0), (0, np_ - n)))  # zeros meet zero pad scales
-
-
-# ---------------------------------------------------------------------------
-# Tile-contiguous ("blocked") storage — docs/PERF.md lever #1b
-# ---------------------------------------------------------------------------
-
-@jax.tree_util.register_dataclass
-@dataclass(frozen=True)
-class BlockedQTensor:
-    """Layer-stacked Q40 storage re-blocked so each kernel tile is ONE
-    fully-sequential HBM read.
-
-    The row-major layout streams a (tn/2, td) tile as tn/2 separate
-    td-byte bursts with a d-byte stride; the r05 xplane showed per-shape
-    kernel bandwidth falling with output width d (w13 at d=22016 ~317
-    GB/s vs wo at d=4096 ~632), pointing at burst length.  Here the
-    packed plane lives as ``(L, n2/bn, dp/td, bn, td)`` (``bn = tn/2``,
-    ``dp`` = d padded to a td multiple) so the tile DMA is ``bn·td``
-    contiguous bytes.  Scales are blocked the same way.  Created from a
-    row-major :class:`QTensor` at load time (:func:`to_blocked`, env
-    ``DLLAMA_Q40_LAYOUT=blocked``); single-device decode only — on a
-    multi-device mesh the loader keeps row-major storage, whose sharding
-    semantics match the reference's splitWeights (commands.cpp:19-36).
-    """
-
-    qpacked: jax.Array          # uint8  (L, n2/bn, dp/td, bn, td)
-    scales: jax.Array          # uint16 (L, n2/bn, dp/td, bn/16, td)
-    logical_nd: tuple[int, int] = field(metadata=dict(static=True))
-    tiles: tuple[int, int] = field(metadata=dict(static=True))  # (tn, td)
-    # True when built from a 2-D (n/2, d) tensor (wcls — the widest d and
-    # the worst strided-burst penalty): storage carries L=1 and unblock
-    # squeezes it back out
-    lead_2d: bool = field(default=False, metadata=dict(static=True))
-
-    @property
-    def shape(self) -> tuple[int, ...]:
-        if self.lead_2d:
-            return self.logical_nd
-        return (self.qpacked.shape[0],) + self.logical_nd
-
-    @property
-    def dtype(self):
-        return jnp.bfloat16
-
-
-# default blocked tiles: tn=512 keeps bn·td at 512 KB per DMA with td=2048
-# (well under the VMEM cap; wide td = the long sequential burst being
-# probed).  Overridable until a hardware sweep bakes a measured choice.
-DEFAULT_BLOCKED_TILES = (512, 2048)
-
-
-def blocked_tiles_env() -> tuple[int, int]:
-    """The ``DLLAMA_Q40_BLOCK_TILES`` override, parsed LAZILY at each
-    :func:`to_blocked` call (an import-time parse would crash the process
-    on a typo and ignore post-import env changes).  The value must be
-    exactly two positive ints; anything else warns once through the
-    dispatch ledger and falls back to :data:`DEFAULT_BLOCKED_TILES`."""
-    spec = os.environ.get("DLLAMA_Q40_BLOCK_TILES", "")
-    if not spec:
-        return DEFAULT_BLOCKED_TILES
-    try:
-        parts = tuple(int(v) for v in spec.split(","))
-        if len(parts) != 2 or parts[0] <= 0 or parts[1] <= 0:
-            raise ValueError(spec)
-        return parts
-    except ValueError:
-        obs_dispatch.record_degrade(
-            "q40", "bad_block_tiles_env", warn_key=spec, spec=spec,
-            fallback=DEFAULT_BLOCKED_TILES)
-        return DEFAULT_BLOCKED_TILES
-
-
-def to_blocked(qt: QTensor, tn: int | None = None,
-               td: int | None = None) -> "BlockedQTensor":
-    """Re-block a layer-stacked row-major QTensor (qpacked (L, n2, d)).
-
-    d pads up to a td multiple with ZERO scales, so pad output columns are
-    exactly 0 and callers slice ``[..., :d]``.  One-time load-cost
-    transform (device-side reshape/transpose)."""
-    env_tn, env_td = blocked_tiles_env()
-    tn = tn or env_tn
-    td = td or env_td
-    lead_2d = qt.qpacked.ndim == 2
-    qp0 = qt.qpacked[None] if lead_2d else qt.qpacked
-    sc0 = qt.scales[None] if lead_2d else qt.scales
-    if qp0.ndim != 3:
-        raise ValueError("to_blocked expects a (n/2, d) or layer-stacked "
-                         f"(L, n/2, d) QTensor, got {qt.qpacked.shape}")
-    L, n2, d = qp0.shape
-    # clamp tiles to the tensor: tn falls down the divisor ladder (tiny
-    # test models; production shapes take the requested tn — note the
-    # hardware kernel needs tn ≥ 256 for the scales operand's sublane
-    # count, which every real model satisfies), td shrinks toward d so a
-    # narrow weight doesn't pad 20× (d pads to the next td multiple)
-    while tn > 32 and n2 % (tn // 2):
-        tn //= 2
-    td = min(td, -(-d // 128) * 128)
-    bn, bnb = tn // 2, tn // 32
-    if n2 % bn or tn % 32:
-        raise ValueError(f"packed rows {n2} not divisible by tn/2={bn}")
-    dp = -(-d // td) * td
-    qp = jnp.pad(qp0, ((0, 0), (0, 0), (0, dp - d)))
-    sc = jnp.pad(sc0, ((0, 0), (0, 0), (0, dp - d)))
-    qb = qp.reshape(L, n2 // bn, bn, dp // td, td).transpose(0, 1, 3, 2, 4)
-    sb = sc.reshape(L, n2 // bn, bnb, dp // td, td).transpose(0, 1, 3, 2, 4)
-    return BlockedQTensor(qb, sb, qt.logical_nd, (tn, td), lead_2d)
-
-
-def unblock(bqt: BlockedQTensor) -> QTensor:
-    """Inverse of :func:`to_blocked` (drops the d padding) — the XLA/CPU
-    dequant fallback path."""
-    L, nI, nJ, bn, td = bqt.qpacked.shape
-    d = bqt.logical_nd[1]
-    qp = bqt.qpacked.transpose(0, 1, 3, 2, 4).reshape(L, nI * bn, nJ * td)
-    bnb = bqt.scales.shape[3]
-    sc = bqt.scales.transpose(0, 1, 3, 2, 4).reshape(L, nI * bnb, nJ * td)
-    if bqt.lead_2d:
-        qp, sc = qp[0], sc[0]
-    return QTensor(qp[..., :d], sc[..., :d], bqt.logical_nd)
-
-
-def _unblock_layer(bqt: "BlockedQTensor", layer: jax.Array) -> QTensor:
-    """Un-transpose ONE layer of a blocked stack to row-major (the XLA
-    fallback for per-layer calls — prefill rows past PALLAS_MAX_ROWS)."""
-    qp = jax.lax.dynamic_index_in_dim(bqt.qpacked, layer, 0, keepdims=False)
-    sc = jax.lax.dynamic_index_in_dim(bqt.scales, layer, 0, keepdims=False)
-    nI, nJ, bn, td = qp.shape
-    d = bqt.logical_nd[1]
-    qp = qp.transpose(0, 2, 1, 3).reshape(nI * bn, nJ * td)[:, :d]
-    bnb = sc.shape[2]
-    sc = sc.transpose(0, 2, 1, 3).reshape(nI * bnb, nJ * td)[:, :d]
-    return QTensor(qp, sc, bqt.logical_nd)
-
-
-def _blocked_tiles_ok(bqt: "BlockedQTensor") -> bool:
-    """STATIC legality of a blocked tensor's pack-time tiles: the scales
-    operand needs tn/32 ≥ 8 sublanes (tn ≥ 256), td must be a lane-dim
-    multiple, and the packed block must respect the VMEM cap.  Failing
-    tiles degrade dispatch to the XLA path (tiny test shapes; bad env
-    overrides).  This predicate cannot prove Mosaic lowerability at real
-    shapes; a genuine lowering failure raises."""
-    tn, td = bqt.tiles
-    return tn >= 256 and tn % 32 == 0 and td % 128 == 0 \
-        and tn * td <= 4 * 1024 * 1024
-
-
-def blocked_params(params: dict) -> dict:
-    """Convert every dense Q40 weight in a params pytree to the
-    tile-contiguous layout (DLLAMA_Q40_LAYOUT=blocked): layer-stacked
-    3-D weights and 2-D wcls (the widest d — the worst strided-burst
-    penalty).  4-D MoE expert stacks keep row-major storage (the
-    expert-select kernel path, _sharded_matmul_ep)."""
-    def conv(v):
-        if isinstance(v, QTensor) and v.qpacked.ndim in (2, 3):
-            return to_blocked(v)
-        return v
-    return jax.tree.map(conv, params,
-                        is_leaf=lambda v: isinstance(v, QTensor))
-
-
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def _pallas_matmul_blocked(x: jax.Array, qb: jax.Array, sb: jax.Array,
-                           layer: jax.Array,
-                           interpret: bool = False) -> jax.Array:
-    """Layer-indexed fused matmul over tile-contiguous packed storage.
-
-    Identical math to ``_pallas_matmul_stacked`` (classic variant); only
-    the HBM layout of the weight operands differs — each grid step DMAs
-    one contiguous (1,1,1,bn,td) block (the kernel's leading-singleton
-    squeeze handles the rank).  Returns (t, dp); callers slice ``[:, :d]``.
-    """
-    t = x.shape[0]
-    L, nI, nJ, bn, td = qb.shape
-    tn = bn * 2
-    grid = (nJ, nI)
-    x_lo, x_hi = _x_parts(x.astype(jnp.bfloat16))
-    bsum = jnp.asarray(_bsum_mat(tn))
-    xspec = pl.BlockSpec((t, bn), lambda j, i, l: (0, i))
-    return pl.pallas_call(
-        functools.partial(_stacked_q40_kernel, nsteps=grid[1],
-                          variant="classic"),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=grid,
-            in_specs=[
-                xspec,
-                xspec,
-                pl.BlockSpec(bsum.shape, lambda j, i, l: (0, 0)),
-                pl.BlockSpec((1, 1, 1, bn, td),
-                             lambda j, i, l: (l[0], i, j, 0, 0)),
-                pl.BlockSpec((1, 1, 1, bn // 16, td),
-                             lambda j, i, l: (l[0], i, j, 0, 0)),
-            ],
-            out_specs=pl.BlockSpec((t, td), lambda j, i, l: (0, j)),
-            scratch_shapes=[pltpu.VMEM((t, td), jnp.float32)],
-        ),
-        out_shape=jax.ShapeDtypeStruct((t, nJ * td), jnp.float32),
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
-        interpret=interpret,
-        name="q40_mm_blocked",
-    )(layer.reshape(1).astype(jnp.int32), x_lo, x_hi, bsum, qb, sb)
 
 
 # ---------------------------------------------------------------------------
@@ -1124,8 +765,8 @@ def _sharded_matmul(x2: jax.Array, qp: jax.Array, s: jax.Array,
         if not fused and not interp \
                 and os.environ.get("DLLAMA_TP_REDUCE", "") != "psum":
             # falling off the fused collective is a degrade off the fast
-            # path, same funnel as blocked_ignored_mesh (warn-once per
-            # backend + width; the counter keeps the true count)
+            # path (warn-once per backend + width; the counter keeps the
+            # true count)
             obs_dispatch.record_degrade(
                 "q40", "tp_psum",
                 warn_key=(jax.default_backend(), d_out),
@@ -1167,7 +808,7 @@ def _sharded_matmul_ep(x2: jax.Array, qp4: jax.Array, s4: jax.Array,
       into (layer, expert), and ONLY the owner runs the kernel on its
       local sub-stack — non-owners take the zero branch of a ``lax.cond``
       and perform **no packed-tile DMA at all** (VERDICT r04 Weak #2: the
-      earlier mask-the-input variant still streamed a clamped expert's
+      earlier mask-the-input form still streamed a clamped expert's
       tiles on every shard, making per-step expert-weight HBM traffic
       ~ep× the useful bytes);
     * a psum over ``ep`` (and ``tp`` for col-sharded weights) then
@@ -1259,8 +900,11 @@ def matmul(x: jax.Array, qt: QTensor | QLayerView, impl: str = "auto",
     x: (..., n); qt logical (n, d) — a 2-D QTensor or a QLayerView of a
     stacked one.  Returns (..., d).
 
-    ``kind`` declares the weight's TP slicing on a multi-device mesh
-    ("row" = output dim on ``tp``, "col" = input dim on ``tp``) so the
+    ``impl``: ``auto`` (on a TPU the fused kernel wherever
+    :func:`_auto_pallas` allows it, else ``xla``), ``pallas``,
+    ``pallas_interpret`` (how the CPU tests run the kernel) or ``xla`` (the
+    reference).  ``kind`` declares the weight's TP slicing on a multi-device
+    mesh ("row" = output dim on ``tp``, "col" = input dim on ``tp``) so the
     pallas path can run per shard; without it (or when shapes don't divide
     the mesh evenly) a multi-device pallas request falls back to the
     GSPMD-partitionable XLA emulation.
@@ -1269,117 +913,56 @@ def matmul(x: jax.Array, qt: QTensor | QLayerView, impl: str = "auto",
     lead = x.shape[:-1]
     rows = int(np.prod(lead)) if lead else 1
     out_dtype = out_dtype or x.dtype
-
-    raw_qt = qt.qt if isinstance(qt, QLayerView) else qt
-    blocked = isinstance(raw_qt, BlockedQTensor)
+    view = isinstance(qt, QLayerView)
+    np_ = (qt.qt if view else qt).qpacked.shape[-2] * 2
 
     if impl == "auto":
-        on_tpu = jax.default_backend() == "tpu"
-        if blocked:
-            # blocked tiles are fixed at pack time; Mosaic-illegal tiles
-            # (clamped-down tn < 256 on tiny shapes, or a bad env
-            # override) degrade to the XLA path like the row-major ladder
-            impl = "pallas" if (on_tpu and rows <= PALLAS_MAX_ROWS
-                                and _blocked_tiles_ok(raw_qt)) else "xla"
-        else:
-            impl = "pallas" if on_tpu and _auto_pallas(
-                raw_qt.qpacked.shape[-2] * 2, d, rows, kind) else "xla"
+        impl = "pallas" if jax.default_backend() == "tpu" and _auto_pallas(
+            np_, d, rows, kind) else "xla"
+    if impl not in ("pallas", "pallas_interpret", "xla"):
+        raise ValueError(f"unknown q40 matmul impl {impl!r}")
 
-    if blocked and impl == "pallas":
-        # forced-pallas callers (cfg.quant_impl) get the same degrades as
-        # auto dispatch — never a Mosaic compile error mid-forward
-        if not _blocked_tiles_ok(raw_qt):
-            obs_dispatch.record_degrade(
-                "q40", "blocked_tiles_illegal", warn_key=raw_qt.tiles,
-                tiles=raw_qt.tiles,
-                hint="need tn >= 256, td % 128 == 0, within the VMEM cap")
-            impl = "xla"
-        elif rows > PALLAS_MAX_ROWS:
-            # the blocked kernel's grid is sized for decode-width row
-            # counts; a forced-pallas prefill mirrors the auto-dispatch
-            # rows cap instead of hitting a lowering failure mid-forward
-            obs_dispatch.record_degrade(
-                "q40", "rows_exceed_pallas_max",
-                warn_key=("blocked", raw_qt.tiles), rows=rows,
-                max_rows=PALLAS_MAX_ROWS, tiles=raw_qt.tiles)
-            impl = "xla"
-    if blocked and impl in ("pallas", "pallas_interpret"):
-        if _smap_mesh() is not None:
-            # blocked storage is single-device by construction (to_blocked
-            # is only applied on 1-device meshes); a mesh here means a
-            # programming error upstream
-            raise ValueError("BlockedQTensor cannot run under a multi-"
-                             "device mesh; load with row-major storage")
-        layer = qt.layer if isinstance(qt, QLayerView) else jnp.int32(0)
-        np_ = raw_qt.qpacked.shape[1] * raw_qt.tiles[0]
-        x2 = _pad_x(x.reshape(rows, n), n, np_)
-        obs_dispatch.record_dispatch("q40", "pallas-blocked", rows=rows,
-                                     tiles=raw_qt.tiles, layout="blocked")
-        out = _pallas_matmul_blocked(x2, raw_qt.qpacked, raw_qt.scales,
-                                     layer, interpret=impl == "pallas_interpret")
-        return out[:, :d].reshape(*lead, d).astype(out_dtype)
-    if blocked:  # xla / CPU fallback: undo the layout, then the dense path
-        if isinstance(qt, QLayerView):
-            # slice the ONE layer first, then un-transpose it: unblocking
-            # the whole (L, ...) stack inside a traced per-layer call
-            # would relayout every layer's bytes L times per forward
-            qt = _unblock_layer(raw_qt, qt.layer)
-        else:
-            qt = unblock(raw_qt)
-
-    if impl in ("pallas", "pallas_interpret"):
+    if impl != "xla":
         interp = impl == "pallas_interpret"
-        if isinstance(qt, QLayerView):
-            qp3, s3 = qt.flat_planes()
-            layer = qt.layer
-        else:
-            if len(qt.qpacked.shape) != 2:
-                raise ValueError(f"matmul needs a 2-D QTensor, got {qt.shape}")
-            qp3, s3, layer = qt.qpacked, qt.scales, None
-        np_ = qp3.shape[-2] * 2
+        if not view and qt.qpacked.ndim != 2:
+            raise ValueError(f"matmul needs a 2-D QTensor, got {qt.shape}")
         mesh = _smap_mesh()
-        if mesh is not None:
-            tp = mesh.shape.get("tp", 1)
-            ep = mesh.shape.get("ep", 1)
-            if _tp_shardable(np_, d, kind, tp):
-                x2 = _pad_x(x.reshape(rows, n), n, np_)
-                raw = qt.qt if isinstance(qt, QLayerView) else None
-                if (ep > 1 and raw is not None and raw.qpacked.ndim == 4
-                        and raw.qpacked.shape[1] % ep == 0
-                        and kind in ("row", "col")):
-                    # (L, E, n/2, d) expert stack on an ep mesh: the stack
-                    # is expert-sharded in HBM (place_params) — decode the
-                    # flat index per shard and psum the owner's product
-                    out = _sharded_matmul_ep(x2, raw.qpacked, raw.scales,
-                                             layer, kind, mesh, interp)
-                else:
-                    out = _sharded_matmul(x2, qp3, s3, layer, kind, mesh, interp)
-                obs_dispatch.record_dispatch(
-                    "q40", "pallas-fused", rows=rows, kind=kind,
-                    tp=mesh.shape.get("tp", 1), layout="row-major")
-                return out.reshape(*lead, d).astype(out_dtype)
+        tp, ep = (1, 1) if mesh is None else (mesh.shape.get("tp", 1),
+                                               mesh.shape.get("ep", 1))
+        if mesh is not None and not _tp_shardable(np_, d, kind, tp):
             obs_dispatch.record_degrade(
                 "q40", "unshardable", warn_key=(kind, np_, d, tp),
                 shape=(np_, d), kind=kind, tp=tp)
-            impl = "xla"
         else:
             x2 = _pad_x(x.reshape(rows, n), n, np_)
             obs_dispatch.record_dispatch("q40", "pallas-fused", rows=rows,
-                                         kind=kind, layout="row-major")
-            if layer is not None:
-                out = _pallas_matmul_stacked(x2, qp3, s3, layer, interpret=interp)
+                                         kind=kind, tp=tp)
+            if view:
+                (qp, s), layer = qt.flat_planes(), qt.layer
             else:
-                out = _pallas_matmul(x2, qp3, s3, interpret=interp)
+                qp, s, layer = qt.qpacked, qt.scales, None
+            if mesh is None:
+                if view:
+                    out = _pallas_matmul_stacked(x2, qp, s, layer,
+                                                 interpret=interp)
+                else:
+                    out = _pallas_matmul(x2, qp, s, interpret=interp)
+            elif (ep > 1 and view and qt.qt.qpacked.ndim == 4
+                    and qt.qt.qpacked.shape[1] % ep == 0
+                    and kind in ("row", "col")):
+                # (L, E, n/2, d) expert stack on an ep mesh: the stack is
+                # expert-sharded in HBM (place_params) — decode the flat
+                # index per shard and psum the owner's product
+                out = _sharded_matmul_ep(x2, qt.qt.qpacked, qt.qt.scales,
+                                         layer, kind, mesh, interp)
+            else:
+                out = _sharded_matmul(x2, qp, s, layer, kind, mesh, interp)
             return out.reshape(*lead, d).astype(out_dtype)
-    if impl == "xla":
-        if isinstance(qt, QLayerView):
-            qt = qt.sliced()
-        obs_dispatch.record_dispatch("q40", "xla-dequant", rows=rows,
-                                     kind=kind)
-        w = dequantize(qt, dtype=jnp.bfloat16)
-        return jnp.dot(x.astype(jnp.bfloat16), w,
-                       preferred_element_type=jnp.float32).astype(out_dtype)
-    raise ValueError(f"unknown q40 matmul impl {impl!r}")
+
+    obs_dispatch.record_dispatch("q40", "xla-dequant", rows=rows, kind=kind)
+    w = dequantize(qt.sliced() if view else qt, dtype=jnp.bfloat16)
+    return jnp.dot(x.astype(jnp.bfloat16), w,
+                   preferred_element_type=jnp.float32).astype(out_dtype)
 
 
 def mm(x: jax.Array, w, impl: str = "auto", out_dtype=None,
@@ -1391,7 +974,7 @@ def mm(x: jax.Array, w, impl: str = "auto", out_dtype=None,
         base = w.qt if isinstance(w, QLayerView) else w
         if isinstance(base, q8.Q8Tensor):
             return q8.matmul(x, w, impl=impl, out_dtype=out_dtype, kind=kind)
-        if isinstance(base, (QTensor, BlockedQTensor)):
+        if isinstance(base, QTensor):
             return matmul(x, w, impl=impl, out_dtype=out_dtype, kind=kind)
         raise TypeError(f"mm: unsupported weight type {type(w).__name__}")
     obs_dispatch.record_dispatch("dense", "dense",

@@ -13,12 +13,12 @@ This module closes that gap the TPU way, mirroring ops/q40.py:
 * a Pallas kernel that widens int8 → f32, applies the per-block scale
   (the file codec's math, quants.py:162-171), rounds the product to bf16
   for the MXU — one more round than the codec's f32 dequant, the same
-  policy as the q40 classic variant — and accumulates reduction tiles in
+  policy as the q40 kernel — and accumulates reduction tiles in
   VMEM; q8.dequantize applies the identical round so kernel and XLA
   emulation agree bit-for-bit;
-* a layer-stacked variant with the layer index as scalar prefetch, so
+* a layer-stacked form with the layer index as scalar prefetch, so
   the ``lax.scan`` over layers DMAs tiles straight from the stacked HBM
-  buffer (no per-layer slice materialization — see q40.py:494-506);
+  buffer (no per-layer slice materialization — see q40._pallas_matmul_stacked);
 * XLA-emulation fallback (`impl="xla"`): bit-identical dequant + dot,
   GSPMD-partitionable — the path multi-device meshes take (Q80 is not
   the production format; its mesh story is correctness, not the custom
@@ -42,13 +42,6 @@ from jax.experimental.pallas import tpu as pltpu
 from .. import quants
 from .q40 import (PALLAS_MAX_ROWS, QLayerView, _f16_bits_to_f32, _pad_x,
                   _smap_mesh, _tile_n_legal, _tiles, padded_n)
-
-# Width-rule VMEM ceiling for THIS codec: the q8 kernel carries an f32
-# accumulator intermediate of tn*td*4 B on top of the int8 value tile, so
-# a rule legal for q40 (4 Mi elements) can blow VMEM here; 2 Mi keeps the
-# worst case ~8 MB f32 + 2 MB int8 against ~16 MB VMEM (ADVICE r04 #2).
-Q8_TILE_CAP = 2 * 1024 * 1024
-
 
 @jax.tree_util.register_dataclass
 @dataclass(frozen=True)
@@ -227,7 +220,7 @@ def _pallas_matmul(x: jax.Array, qv: jax.Array, s: jax.Array,
                    interpret: bool = False) -> jax.Array:
     t, n = x.shape
     d = qv.shape[-1]
-    tile_n, tile_d = _tiles(n, d, cap_elems=Q8_TILE_CAP)
+    tile_n, tile_d = _tiles(n, d)
     grid = (pl.cdiv(d, tile_d), n // tile_n)
     return pl.pallas_call(
         functools.partial(_q8_kernel, nsteps=grid[1]),
@@ -256,7 +249,7 @@ def _pallas_matmul_stacked(x: jax.Array, qv: jax.Array, s: jax.Array,
     into the (L, n, d) HBM buffer — see q40._pallas_matmul_stacked)."""
     t, n = x.shape
     d = qv.shape[-1]
-    tile_n, tile_d = _tiles(n, d, cap_elems=Q8_TILE_CAP)
+    tile_n, tile_d = _tiles(n, d)
     grid = (pl.cdiv(d, tile_d), n // tile_n)
     return pl.pallas_call(
         functools.partial(_stacked_q8_kernel, nsteps=grid[1]),
@@ -307,7 +300,7 @@ def matmul(x: jax.Array, qt: Q8Tensor | QLayerView, impl: str = "auto",
         impl = "pallas" if (on_tpu and rows <= PALLAS_MAX_ROWS
                             and _smap_mesh() is None
                             and _tile_n_legal(
-                                np_, _tiles(np_, d, cap_elems=Q8_TILE_CAP)[0])) \
+                                np_, _tiles(np_, d)[0])) \
             else "xla"
 
     from ..obs import dispatch as obs_dispatch

@@ -268,34 +268,16 @@ class Engine:
                 raise ValueError(
                     f"n_experts {cfg.n_experts} not divisible by ep={ep}")
         self.cfg = cfg
-        if os.environ.get("DLLAMA_Q40_LAYOUT", "") == "blocked":
-            if self.mesh.size == 1:
-                # tile-contiguous packed storage (ops/q40.py
-                # BlockedQTensor): every dense Q40 weight's kernel tile
-                # becomes one sequential HBM read — single-device decode
-                # only; on a mesh the row-major layout keeps its
-                # splitWeights-compatible sharding semantics
-                params = q40.blocked_params(params)
-            else:
-                # requested layout silently kept row-major — that is a
-                # degrade off the *requested* path, so it goes through the
-                # ledger (warn-once structured log + labeled counter +
-                # degraded flag), not scrollback
-                obs_dispatch.record_degrade(
-                    "q40", "blocked_ignored_mesh", warn_key=self.mesh.size,
-                    mesh_size=self.mesh.size,
-                    hint="blocked storage is single-device only; "
-                         "row-major keeps sharding semantics")
         if self.mesh.shape.get("tp", 1) > 1 \
                 and jax.default_backend() != "tpu" \
                 and os.environ.get("DLLAMA_TP_REDUCE", "") != "psum":
             # tp serving off-TPU cannot take the fused collective-matmul
             # decode path (ops/q40.py _tp_ring_allreduce is built on
             # inter-chip RDMA): decode collectives degrade to plain
-            # psum/GSPMD all-reduce.  Same ledger treatment as
-            # blocked_ignored_mesh — the run still serves, but a bench
-            # number from this configuration must not read as the fused
-            # number
+            # psum/GSPMD all-reduce.  Through the degrade ledger (warn-once
+            # record, labeled counter, degraded flag): the run still
+            # serves, but a bench number from this configuration must not
+            # read as the fused number
             obs_dispatch.record_degrade(
                 "q40", "tp_psum", warn_key=jax.default_backend(),
                 backend=jax.default_backend(),

@@ -75,7 +75,7 @@ def cpu_env(n_devices: int = 1) -> dict:
 
 def run_cli(args: list[str], *, input_text: str | None = None, n_devices: int = 1,
             timeout: int = 240, env: dict | None = None) -> subprocess.CompletedProcess:
-    """``env`` overlays extra variables (e.g. DLLAMA_Q40_LAYOUT) on the
+    """``env`` overlays extra variables (e.g. DLLAMA_FAULTS) on the
     forced-CPU base environment."""
     full_env = cpu_env(n_devices)
     if env:

@@ -102,29 +102,6 @@ class TestCompileCache:
         assert calls == [("jax_compilation_cache_dir", want)]
 
 
-def test_maybe_blocked_applies_to_q40_only(monkeypatch):
-    """The blocked-layout lever converts Q40 params and refuses to claim
-    the layout for q80 runs (blocked_params is a no-op on Q8 planes; the
-    banner would mislabel the measurement)."""
-    import numpy as np
-    import jax.numpy as jnp
-    from dllama_tpu.ops import q40
-    from dllama_tpu.ops.q8 import Q8Tensor
-
-    monkeypatch.setenv("DLLAMA_Q40_LAYOUT", "blocked")
-    qt = q40.quantize(
-        (np.random.RandomState(0).randn(2, 64, 32) * 0.1).astype(np.float32))
-    out = bench.maybe_blocked({"a": qt})
-    assert isinstance(out["a"], q40.BlockedQTensor)
-    q8t = Q8Tensor(jnp.zeros((2, 64, 32), jnp.int8),
-                   jnp.zeros((2, 2, 32), jnp.uint16), (64, 32))
-    out2 = bench.maybe_blocked({"b": q8t}, codec="q80")
-    assert out2["b"] is q8t
-    monkeypatch.delenv("DLLAMA_Q40_LAYOUT")
-    out3 = bench.maybe_blocked({"a": qt})
-    assert out3["a"] is qt  # lever off → untouched
-
-
 def test_bench_decode_pipelined_schedule_runs():
     """_bench_decode's depth-1 pipelined loop (dispatch chunk i+1 on the
     device-carried token before fetching chunk i) must keep the position
@@ -133,19 +110,3 @@ def test_bench_decode_pipelined_schedule_runs():
     cfg = bench._model_cfg("cpu-tiny").with_(quant_impl="xla")
     ms = bench._bench_decode(cfg, chunk=8, n_chunks=3)
     assert 0 < ms < 10_000
-
-
-def test_memory_plan_models_blocked_padding(monkeypatch):
-    """The planner's blocked-layout estimate pads the output axis with
-    to_blocked's exact clamp (narrow planes pad to 128 multiples, not the
-    full tile)."""
-    import importlib.util
-    spec = importlib.util.spec_from_file_location(
-        "memory_plan", os.path.join(REPO, "tools", "memory_plan.py"))
-    mp = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mp)
-    cfg = mp._cfg("llama2-7b")
-    base = mp.plan(cfg)["weights_sharded"]
-    monkeypatch.setenv("DLLAMA_Q40_LAYOUT", "blocked")
-    blocked = mp.plan(cfg)["weights_sharded"]
-    assert base < blocked < base * 1.12  # padding exists but is bounded

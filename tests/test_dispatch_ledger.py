@@ -2,7 +2,7 @@
 (docs/OBSERVABILITY.md; obs/dispatch.py).
 
 The acceptance contract this file pins: NO silent degrade path remains —
-every Pallas/blocked/shard fallback in q40/q8 lands in a labeled registry
+every Pallas/shard/reduce fallback in q40/q8 lands in a labeled registry
 counter and a structured log record, and an injected degrade is visible
 in ``/metrics`` (JSON and Prometheus), ``/health``, and the end-of-run
 CLI summary in the SAME test.  Plus: recompiles vs executable-cache hits
@@ -83,28 +83,9 @@ def test_labeled_gauge_fn_and_graceful_absence():
     assert "widget_bytes{" not in rendered()
 
 
-# --- satellite: DLLAMA_Q40_BLOCK_TILES lazy validated parse ---------------
+# --- the degrade funnel ----------------------------------------------------
 
-def test_block_tiles_env_valid_and_default(monkeypatch):
-    monkeypatch.delenv("DLLAMA_Q40_BLOCK_TILES", raising=False)
-    assert q40.blocked_tiles_env() == q40.DEFAULT_BLOCKED_TILES
-    monkeypatch.setenv("DLLAMA_Q40_BLOCK_TILES", "256,1024")
-    assert q40.blocked_tiles_env() == (256, 1024)
-    assert obs_dispatch.degraded() is False
-
-
-@pytest.mark.parametrize("bad", ["banana", "512", "0,2048", "512,-1",
-                                 "512,2048,64"])
-def test_block_tiles_env_malformed_falls_back(monkeypatch, bad):
-    monkeypatch.setenv("DLLAMA_Q40_BLOCK_TILES", bad)
-    before = obs_metrics.Q40_DEGRADE.get("bad_block_tiles_env")
-    assert q40.blocked_tiles_env() == q40.DEFAULT_BLOCKED_TILES
-    assert obs_metrics.Q40_DEGRADE.get("bad_block_tiles_env") == before + 1
-    assert obs_dispatch.degraded() is True
-    assert "q40:bad_block_tiles_env" in obs_dispatch.reasons()
-
-
-def test_degrade_logs_once_but_counts_every_occurrence(monkeypatch):
+def test_degrade_logs_once_but_counts_every_occurrence():
     records = []
     h = logging.Handler()
     h.emit = lambda r: records.append(r)
@@ -112,57 +93,48 @@ def test_degrade_logs_once_but_counts_every_occurrence(monkeypatch):
     lg.addHandler(h)
     old = lg.level
     lg.setLevel(logging.DEBUG)
+    before = obs_metrics.Q40_DEGRADE.get("unshardable")
     try:
-        monkeypatch.setenv("DLLAMA_Q40_BLOCK_TILES", "nope")
         for _ in range(3):
-            q40.blocked_tiles_env()
+            obs_dispatch.record_degrade(
+                "q40", "unshardable", warn_key=("col", 96, 64, 4),
+                shape=(96, 64), kind="col", tp=4)
     finally:
         lg.removeHandler(h)
         lg.setLevel(old)
     warned = [r for r in records if r.getMessage() == "kernel_degrade"]
     assert len(warned) == 1, "warn-once per (codec, reason, warn_key)"
-    assert obs_metrics.Q40_DEGRADE.get("bad_block_tiles_env") == 3
+    assert obs_metrics.Q40_DEGRADE.get("unshardable") == before + 3
+    assert obs_dispatch.reasons() == {"q40:unshardable": 3}
 
 
-# --- tentpole: forced-pallas blocked guards (real degrades) ---------------
-
-def _blocked_fixture(n, d, seed=0):
+def _q40_fixture(n, d, seed=0):
     rng = np.random.RandomState(seed)
-    qt = q40.quantize((rng.randn(n, d) * 0.05).astype(np.float32))
-    return qt, q40.to_blocked(qt)
+    return q40.quantize((rng.randn(n, d) * 0.05).astype(np.float32))
 
 
-def test_forced_pallas_illegal_tiles_degrades_correctly():
-    """tn clamps below 256 on a tiny shape → Mosaic-illegal; forced pallas
-    must degrade through the ledger and still return the right numbers."""
+def _unshardable_degrade():
+    """One real degrade: a forced-pallas matmul on a tp mesh whose caller
+    declared no slicing ``kind`` cannot run per shard and falls back to the
+    XLA path through the ledger.  Returns (got, reference)."""
+    import jax
     import jax.numpy as jnp
-    qt, bqt = _blocked_fixture(128, 256)
-    assert bqt.tiles[0] < 256  # the premise: clamped-down, kernel-illegal
+    from dllama_tpu.parallel.mesh import active_mesh, make_mesh
+    qt = _q40_fixture(128, 256)
     x = jnp.asarray(np.random.RandomState(1).randn(2, 128), jnp.float32)
-    before = obs_metrics.Q40_DEGRADE.get("blocked_tiles_illegal")
-    out = q40.matmul(x, bqt, impl="pallas")
-    assert obs_metrics.Q40_DEGRADE.get("blocked_tiles_illegal") == before + 1
+    with active_mesh(make_mesh(tp=2, devices=jax.devices()[:2])):
+        out = q40.matmul(x, qt, impl="pallas_interpret")
+    return out, x.astype(jnp.bfloat16) @ q40.dequantize(qt, jnp.bfloat16)
+
+
+def test_unshardable_on_a_mesh_degrades_correctly():
+    """The degrade is counted, flags the process, and still returns the
+    right numbers."""
+    before = obs_metrics.Q40_DEGRADE.get("unshardable")
+    out, ref = _unshardable_degrade()
+    assert obs_metrics.Q40_DEGRADE.get("unshardable") == before + 1
     assert obs_dispatch.degraded() is True
-    ref = x.astype(jnp.bfloat16) @ q40.dequantize(qt, jnp.bfloat16)
-    np.testing.assert_allclose(np.asarray(out, np.float32),
-                               np.asarray(ref, np.float32),
-                               rtol=2e-2, atol=2e-2)
-
-
-def test_forced_pallas_blocked_rows_over_cap_degrades():
-    """Satellite: legal blocked tiles but rows > PALLAS_MAX_ROWS (a
-    forced-pallas prefill) must mirror the auto-dispatch rows cap instead
-    of a Mosaic lowering failure mid-forward."""
-    import jax.numpy as jnp
-    qt, bqt = _blocked_fixture(512, 256)
-    assert q40._blocked_tiles_ok(bqt)  # the premise: tiles are legal
-    rows = q40.PALLAS_MAX_ROWS + 1
-    x = jnp.asarray(np.random.RandomState(2).randn(rows, 512), jnp.float32)
-    before = obs_metrics.Q40_DEGRADE.get("rows_exceed_pallas_max")
-    out = q40.matmul(x, bqt, impl="pallas")
-    assert obs_metrics.Q40_DEGRADE.get("rows_exceed_pallas_max") == before + 1
-    assert out.shape == (rows, 256)
-    ref = x.astype(jnp.bfloat16) @ q40.dequantize(qt, jnp.bfloat16)
+    assert obs_dispatch.dispatches() == {"q40/xla-dequant": 1}
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(ref, np.float32),
                                rtol=2e-2, atol=2e-2)
@@ -172,7 +144,7 @@ def test_dispatch_paths_recorded():
     """Every resolved dispatch lands in the labeled matmul_dispatch family
     (auto on CPU resolves to xla-dequant)."""
     import jax.numpy as jnp
-    qt, _ = _blocked_fixture(128, 256)
+    qt = _q40_fixture(128, 256)
     x = jnp.ones((1, 128), jnp.float32)
     before = obs_metrics.MATMUL_DISPATCH.get("q40", "xla-dequant")
     q40.matmul(x, qt)  # impl="auto"; CPU → xla-dequant
@@ -182,12 +154,11 @@ def test_dispatch_paths_recorded():
     assert "q40/xla-dequant" in obs_dispatch.summary_line()
 
 
-def test_engine_init_degrades_share_ledger_treatment(monkeypatch):
-    """The two engine-construction degrades — blocked layout silently
-    kept row-major on a mesh (``blocked_ignored_mesh``), and off-TPU tp
-    collectives falling back to plain psum (``tp_psum``) — take the
-    identical ledger path: labeled counter + degraded flag + warn-once
-    structured record, never scrollback."""
+def test_engine_init_degrade_takes_the_ledger_path():
+    """The engine-construction degrade — off-TPU tp collectives falling
+    back to plain psum (``tp_psum``) — takes the ledger path: labeled
+    counter + degraded flag + warn-once structured record, never
+    scrollback."""
     import jax
     from dllama_tpu.models.config import tiny_config
     from dllama_tpu.models.params import init_params
@@ -203,9 +174,7 @@ def test_engine_init_degrades_share_ledger_treatment(monkeypatch):
     old = lg.level
     lg.setLevel(logging.DEBUG)
     try:
-        monkeypatch.setenv("DLLAMA_Q40_LAYOUT", "blocked")
-        # one tp=2 engine on CPU trips both: blocked storage is ignored
-        # on any mesh, and tp collectives have no RDMA ring off-TPU
+        # tp collectives have no RDMA ring off-TPU
         for _ in range(2):
             Engine(cfg, init_params(cfg, seed=4),
                    mesh=make_mesh(tp=2, devices=jax.devices()[:2]))
@@ -213,12 +182,11 @@ def test_engine_init_degrades_share_ledger_treatment(monkeypatch):
         lg.removeHandler(h)
         lg.setLevel(old)
     assert obs_dispatch.degraded() is True
-    for reason in ("blocked_ignored_mesh", "tp_psum"):
-        assert obs_metrics.Q40_DEGRADE.get(reason) == 2, reason
-        assert obs_dispatch.reasons().get(f"q40:{reason}") == 2, reason
+    assert obs_metrics.Q40_DEGRADE.get("tp_psum") == 2
+    assert obs_dispatch.reasons().get("q40:tp_psum") == 2
     warned = [r.__dict__["reason"] for r in records
               if r.getMessage() == "kernel_degrade"]
-    assert sorted(warned) == ["blocked_ignored_mesh", "tp_psum"], \
+    assert warned == ["tp_psum"], \
         "one structured record per degrade site, not per engine"
 
 
@@ -313,25 +281,23 @@ def _get(base, path, accept=None):
 
 
 def test_degrade_visible_in_metrics_health_and_summary(api):
-    """THE acceptance test: one real injected degrade (forced-pallas on
-    Mosaic-illegal blocked tiles) must show up in /metrics JSON, /metrics
-    Prometheus, /health, and the end-of-run CLI summary line — in this
-    one test."""
-    import jax.numpy as jnp
+    """THE acceptance test: one real degrade (a forced-pallas matmul that
+    cannot shard over the active mesh) must show up in /metrics JSON,
+    /metrics Prometheus, /health, and the end-of-run CLI summary line — in
+    this one test."""
     _, base = api
-    _, bqt = _blocked_fixture(128, 256)
-    q40.matmul(jnp.ones((1, 128), jnp.float32), bqt, impl="pallas")
+    _unshardable_degrade()
 
     code, raw = _get(base, "/metrics")
     j = json.loads(raw)
     assert code == 200
-    assert j["q40_degrade"].get("blocked_tiles_illegal", 0) >= 1
+    assert j["q40_degrade"].get("unshardable", 0) >= 1
     assert any(k.startswith("q40/") for k in j["matmul_dispatch"])
 
     code, raw = _get(base, "/metrics?format=prometheus")
     text = raw.decode()
-    m = re.search(r'dllama_q40_degrade_total\{reason="blocked_tiles_'
-                  r'illegal"\} (\d+)', text)
+    m = re.search(r'dllama_q40_degrade_total\{reason="unshardable"\} (\d+)',
+                  text)
     assert m and int(m.group(1)) >= 1
     assert "# TYPE dllama_q40_degrade_total counter" in text
     assert re.search(r'dllama_matmul_dispatch_total\{codec="q40",'
@@ -340,16 +306,16 @@ def test_degrade_visible_in_metrics_health_and_summary(api):
     code, raw = _get(base, "/health")
     h = json.loads(raw)
     assert code == 200 and h["degraded"] is True
-    assert h["degrade_reasons"].get("q40:blocked_tiles_illegal", 0) >= 1
+    assert h["degrade_reasons"].get("q40:unshardable", 0) >= 1
 
     line = obs_dispatch.summary_line()   # what cmd_inference prints last
-    assert "DEGRADED" in line and "q40:blocked_tiles_illegal" in line
+    assert "DEGRADED" in line and "q40:unshardable" in line
 
 
 def test_clean_run_reads_clean(api):
     import jax.numpy as jnp
     _, base = api
-    qt, _ = _blocked_fixture(128, 256)
+    qt = _q40_fixture(128, 256)
     q40.matmul(jnp.ones((1, 128), jnp.float32), qt)  # auto → xla, no degrade
     _, raw = _get(base, "/health")
     h = json.loads(raw)
@@ -414,20 +380,19 @@ def test_debug_profile_restores_engine_position(api):
 # --- CLI: end-of-run summary (subprocess, real degrade) -------------------
 
 def test_cli_inference_prints_degraded_summary(tmp_path):
-    """`dllama inference` over a Q40 model with a malformed
-    DLLAMA_Q40_BLOCK_TILES must run to completion on the fallback tiles
-    AND say DEGRADED in its end-of-run dispatch summary."""
+    """`dllama inference` over a Q40 model on a tp=2 mesh off the TPU must
+    run to completion on plain psum AND say DEGRADED in its end-of-run
+    dispatch summary."""
     m = str(tmp_path / "m.m")
     t = str(tmp_path / "m.t")
     write_tiny_model(m, ftype=quants.Q40)
     write_tiny_tokenizer(t)
     r = run_cli(["inference", "--model", m, "--tokenizer", t,
-                 "--prompt", "hello", "--steps", "4", "--max-seq-len", "64"],
-                env={"DLLAMA_Q40_LAYOUT": "blocked",
-                     "DLLAMA_Q40_BLOCK_TILES": "banana"})
+                 "--prompt", "hello", "--steps", "4", "--max-seq-len", "64",
+                 "--workers", "tpu:2"], n_devices=2)
     assert r.returncode == 0, r.stderr[-2000:]
     assert "kernel dispatch: DEGRADED" in r.stdout
-    assert "q40:bad_block_tiles_env" in r.stdout
+    assert "q40:tp_psum" in r.stdout
 
 
 @pytest.mark.slow

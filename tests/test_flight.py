@@ -104,8 +104,7 @@ class _Capture(logging.Handler):
 
 def test_buffer_env_malformed_warns_once(monkeypatch):
     """Satellite: a malformed DLLAMA_FLIGHT_BUFFER/DLLAMA_TRACE_BUFFER
-    warns ONCE per distinct spec and falls back to the default, mirroring
-    the DLLAMA_Q40_BLOCK_TILES contract."""
+    warns ONCE per distinct spec and falls back to the default."""
     h = _Capture()
     logger = logging.getLogger("dllama.obs.trace")
     logger.addHandler(h)

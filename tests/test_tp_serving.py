@@ -21,7 +21,7 @@ mode:
   page leaks (``pool.check()``);
 * **ledger hygiene** — building a tp>1 engine on a non-TPU backend
   records the ``tp_psum`` degrade (the fused collective-matmul ring is
-  TPU-only), same treatment as ``blocked_ignored_mesh``.
+  TPU-only).
 
 Config note: the suite's usual ``tiny_config`` only shards to tp=2
 (n_kv_heads=2); this file widens it to n_kv_heads=4 / hidden_dim=128 so
@@ -222,8 +222,7 @@ def test_tp4_preempt_park_resume_parity(solo_refs):
 
 def test_tp_engine_on_cpu_records_psum_degrade():
     """Satellite contract: a tp>1 engine off TPU records the
-    ``tp_psum`` degrade exactly like ``blocked_ignored_mesh`` — counter
-    + degraded flag + warn-once — so a CPU/GPU run can never pass off a
+    ``tp_psum`` degrade — counter + degraded flag + warn-once — so a CPU/GPU run can never pass off a
     plain-psum decode as the fused collective number."""
     obs_dispatch.reset()
     try:

@@ -279,25 +279,30 @@ def test_one_stream_spans_carry_their_position(tmp_path):
 
 
 def test_an_idle_scheduler_goes_quiet():
-    """Two wake-ups a second of nothing must not push the requests' spans
-    out of the ring: only the first wait of an idle spell is recorded."""
+    """Wake-ups of nothing must not push the requests' spans out of the
+    ring: only the first wait of an idle spell is recorded.  The test wakes
+    the parked loop itself and counts its wake-ups; the clock only bounds a
+    hang."""
     eng = make_engine(2)
     sched = SlotScheduler(eng, prefill_chunk=4)
+
+    def wake():
+        seen, deadline = sched._park_wakeups, time.monotonic() + 60
+        while sched._park_wakeups == seen:
+            assert time.monotonic() < deadline, "the parked loop never woke"
+            with sched._cond:
+                sched._cond.notify_all()
+            time.sleep(0.01)
+
     try:
         list(sched.submit([5, 9, 2], 2).tokens())
         sched.flush()
         # the first wait of the idle spell is the one that is recorded, when
         # it ends: let it end before the ring is cleared
-        wake0 = sched._park_wakeups
-        deadline = time.monotonic() + 5
-        while sched._park_wakeups == wake0 and time.monotonic() < deadline:
-            time.sleep(0.05)
+        wake()
         obs_trace.clear()
-        wake0 = sched._park_wakeups
-        deadline = time.monotonic() + 5
-        while sched._park_wakeups < wake0 + 2 and time.monotonic() < deadline:
-            time.sleep(0.1)
-        assert sched._park_wakeups >= wake0 + 2
+        wake()
+        wake()
         assert not [s for s in obs_trace.TRACER.snapshot()
                     if s["name"] in ("sched.idle", "sched.admit")]
     finally:

@@ -108,20 +108,11 @@ def plan(cfg, tp=1, sp=1, dp=1, ep=1, seq_len=None, batch=1,
                 # granularity (q40.padded_n; up to +9% on odd hidden dims,
                 # e.g. TinyLlama's 5632→6144) — estimate what HBM actually
                 # holds, not the logical element count (ADVICE r03)
-                from dllama_tpu.ops.q40 import blocked_tiles_env, padded_n
+                from dllama_tpu.ops.q40 import padded_n
                 *lead, nin, dout = shp
                 n = 1
                 for x in lead:
                     n *= x
-                if os.environ.get("DLLAMA_Q40_LAYOUT", "") == "blocked" \
-                        and not is_expert:
-                    # tile-contiguous storage also pads the OUTPUT axis to
-                    # a tile_d multiple (q40.to_blocked; ~1% on the 7B
-                    # shapes at the 2048 default)
-                    # mirror to_blocked's clamp: planes narrower than the
-                    # tile pad only to a 128 multiple
-                    td = min(blocked_tiles_env()[1], -(-dout // 128) * 128)
-                    dout = -(-dout // td) * td
                 n *= padded_n(nin) * dout
             w_sharded += n * per_w / div
             if is_expert:

@@ -30,14 +30,14 @@ def main():
     import jax.numpy as jnp
     import numpy as np
 
-    from bench import _model_cfg, _zero_q40_params, maybe_blocked
+    from bench import _model_cfg, _zero_q40_params
     from dllama_tpu.models.transformer import init_kv_cache
     from dllama_tpu.runtime.decode_loop import decode_chunk
 
     print(f"backend: {jax.default_backend()} {jax.devices()}", file=sys.stderr)
     impl = "pallas" if jax.default_backend() == "tpu" else "xla"
     cfg = _model_cfg(args.model).with_(quant_impl=impl)
-    params = maybe_blocked(_zero_q40_params(cfg))  # same lever as the bench
+    params = _zero_q40_params(cfg)
     cache = init_kv_cache(cfg, batch=1)
     chunk = args.chunk
 
