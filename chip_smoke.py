@@ -510,6 +510,36 @@ def child_kernels(rehearse: bool) -> None:
               "rel_err": rel_err(got, ref), "tol": ATTN_TOL,
               "seconds": round(time.perf_counter() - t0, 2)})
 
+    # the live walk at prefill rows against one-shot attention over the whole
+    # contiguous cache, at the one-stream cells' 32k context: a prompt at
+    # position 0 (one block) and deep in the cache (many)
+    s_len, t = (cfg.seq_len, 16) if rehearse else (32768, 256)
+    key, kq, kk, kv = jax.random.split(key, 4)
+    q = (jax.random.normal(kq, (1, hq, t, dh)) * 0.5).astype(cfg.dtype)
+    ck = jax.random.normal(kk, (2, 1, hkv, s_len, dh), jnp.float32) * 0.5
+    cv = jax.random.normal(kv, (2, 1, hkv, s_len, dh), jnp.float32) * 0.5
+    for quantized in (False, True):
+        if quantized:
+            (k_, sk), (v_, sv) = att.quantize_kv(ck), att.quantize_kv(cv)
+            scales = (sk, sv)
+            k_ref, v_ref = att.dequant_kv(k_[1], sk[1]), att.dequant_kv(v_[1], sv[1])
+        else:
+            k_, v_, scales = ck.astype(cfg.dtype), cv.astype(cfg.dtype), None
+            k_ref, v_ref = k_[1], v_[1]
+        for p in (0, 20000 * s_len // 32768):
+            t0 = time.perf_counter()
+            got = att.live_gqa_attention(q, k_, v_, jnp.int32(p), layer=layer,
+                                         scales=scales)
+            ref = att._rows_ceiling_attention(q, k_ref, v_ref,
+                                              jnp.asarray([p], jnp.int32))
+            _say({"kernel": "live_gqa_attention",
+                  "kv": "int8" if quantized else "dense",
+                  "geometry": {"hq": hq, "hkv": hkv, "dh": dh, "s": s_len,
+                               "t": t, "pos": p,
+                               "block": att._kv_chunk(s_len)},
+                  "rel_err": rel_err(got, ref), "tol": ATTN_TOL,
+                  "seconds": round(time.perf_counter() - t0, 2)})
+
 
 def child_module(module: str, argv: list[str], rehearse: bool) -> None:
     """Platform check, then the user's entry point as ``__main__``."""

@@ -527,7 +527,7 @@ def paged_gqa_attention_at(q: jax.Array, pool_k: jax.Array, pool_v: jax.Array,
         return fused_paged_attention(q, pool_k, pool_v, layer, page_table,
                                      pos_rows, scales=scales,
                                      interpret=interp)
-    if _use_blocked_decode(t, s):
+    if t == 1 and _use_live_walk(q.shape[1] // hkv, t, s):
         obs_dispatch.record_dispatch(codec, "paged-decode", t=t, s=s,
                                      page_size=ps)
         return paged_decode_attention(q, pool_k, pool_v, layer, page_table,
@@ -544,28 +544,48 @@ def paged_gqa_attention_at(q: jax.Array, pool_k: jax.Array, pool_v: jax.Array,
     return _rows_ceiling_attention(q, k_l, v_l, pos_rows)
 
 
-# Above this many score elements per kv-head group, prefill switches to the
-# blocked online-softmax path: the one-shot path materializes the full
-# (B, Hkv, G, T, S) f32 score tensor, which becomes the HBM wall at long
-# context (VERDICT r01 weak #5).
+# Above this many score elements per kv-head group the one-shot path's full
+# (B, Hkv, G, T, S) f32 score tensor is the HBM wall at long context
+# (VERDICT r01 weak #5): such a call walks KV blocks with an online softmax
+# instead (see _use_live_walk).
 _BLOCKED_THRESHOLD = 1 << 21
+# Over caches at least this long every call (any T) walks only the live
+# prefix of the cache instead of reading the whole preallocated buffer;
+# below it, one-shot attention is cheaper than the loop overhead.
+_WALK_MIN_S = 4096
 # numpy (not jnp): a module-level device constant would initialize the XLA
 # backend at import time, breaking jax.distributed.initialize ordering
 _NEG = np.float32(-1e30)  # finite -inf stand-in: keeps the running max
 
 
 def _kv_chunk(s: int) -> int:
+    """KV block width of the live walk over ``s`` positions, one for every
+    ``T``: against 1024, a 256-key block saves under 1 ms of a 256-token
+    prompt at position 0 and costs 8 ms of one at 16k (7B shapes on the
+    chip, PERF.md §6, PR 29)."""
     for c in (1024, 512, 256, 128):
         if s % c == 0:
             return c
     return s
 
 
+def _use_live_walk(g: int, t: int, s: int) -> bool:
+    """The one dispatch rule of the contiguous-cache attention, made from
+    shapes only so the stacked-cache, per-layer and sequence-parallel entry
+    points can never diverge on which algorithm serves the same shapes:
+    walk live blocks when the cache is blockable and either long (any
+    ``t``) or the one-shot score tensor would pass ``_BLOCKED_THRESHOLD``.
+    ``_kv_chunk(s) == s`` would be one loop step over the whole cache: all
+    the loop overhead, none of the O(pos) traffic win."""
+    return _kv_chunk(s) < s and (s >= _WALK_MIN_S
+                                 or g * t * s > _BLOCKED_THRESHOLD)
+
+
 def _online_fold(qf, kb, vb, mask, m, l, acc, scale):
-    """One flash-softmax block fold shared by the blocked prefill scan and
-    the length-aware decode loop: fold block scores masked by ``mask``
-    (``(T, S)`` broadcast over (B, Hkv, G), or ``(B, T, S)`` for per-row
-    ragged-batch masks) into the running (max, denom, numerator).
+    """One flash-softmax block fold of the live walk: fold block scores
+    masked by ``mask`` (``(T, S)`` broadcast over (B, Hkv, G), or
+    ``(B, T, S)`` for per-row ragged-batch masks) into the running (max,
+    denom, numerator).
 
     Dots keep the cache's dtype as operand type with f32 *accumulation*
     (bf16 in, f32 out on the MXU): widening a bf16 cache to f32 first makes
@@ -593,90 +613,40 @@ def _fold_init(b, hkv, g, t, dh):
             jnp.zeros((b, hkv, g, t, dh), jnp.float32))
 
 
-def blocked_gqa_attention(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
-                          pos: jax.Array, q_len: int,
-                          start: jax.Array | None = None) -> jax.Array:
-    """Flash-style causal GQA: ``lax.scan`` over KV chunks with an online
-    (running max/sum) softmax, so peak memory is O(T·chunk) instead of
-    O(T·S).  Numerically equivalent to the one-shot path (same f32
-    accumulation; association differs only within the rescale chain).
-
-    ``start`` (B,) masks key positions below a per-row floor — the
-    left-padding region of a ragged batch (see gqa_attention).
-    """
-    b, hq, t, dh = q.shape
-    hkv = k_cache.shape[1]
-    s = k_cache.shape[2]
-    g = hq // hkv
-    c = _kv_chunk(s)
-    nc = s // c
-    scale = 1.0 / jnp.sqrt(jnp.float32(dh))
-
-    qf = q.astype(jnp.float32).reshape(b, hkv, g, t, dh)
-    # chunk-major scan inputs: (nc, B, Hkv, c, Dh)
-    kc = k_cache.reshape(b, hkv, nc, c, dh).transpose(2, 0, 1, 3, 4)
-    vc = v_cache.reshape(b, hkv, nc, c, dh).transpose(2, 0, 1, 3, 4)
-    t_idx = pos + jnp.arange(t)[:, None]  # (T, 1)
-
-    def body(carry, inp):
-        kb, vb, base = inp
-        s_idx = base + jnp.arange(c)[None, :]
-        mask = s_idx <= t_idx  # (T, c)
-        if start is not None:
-            mask = mask[None] & (s_idx[None] >= start[:, None, None])  # (B, T, c)
-        return _online_fold(qf, kb, vb, mask, *carry, scale), None
-
-    bases = jnp.arange(nc) * c
-    (m, l, acc), _ = jax.lax.scan(body, _fold_init(b, hkv, g, t, dh),
-                                  (kc, vc, bases))
-    out = acc / jnp.maximum(l, 1e-38)[..., None]
-    return out.reshape(b, hq, t, dh).astype(q.dtype)
-
-
-# Decode (t==1) over caches at least this long walks only the live
-# prefix of the cache (length-aware while_loop) instead of reading the
-# whole preallocated buffer; below it, one-shot attention is cheaper than
-# the loop overhead.
-_DECODE_BLOCKED_MIN_S = 4096
-
-
-def _use_blocked_decode(t: int, s: int) -> bool:
-    """Shared dispatch predicate for the length-aware decode path, so the
-    stacked-cache, per-layer, and sequence-parallel entry points can never
-    diverge on which attention algorithm serves the same shapes.
-    ``_kv_chunk(s) == s`` would be one loop step over the whole cache: all
-    the loop overhead, none of the O(pos) traffic win."""
-    return t == 1 and s >= _DECODE_BLOCKED_MIN_S and _kv_chunk(s) < s
-
-
 def blocked_live_fold(qf, slice_block, k_cache, v_cache, pos, base, c,
                       wrap=lambda x: x, row_start: jax.Array | None = None,
                       row_pos: jax.Array | None = None,
                       block: int | None = None):
     """The length-aware online-softmax core: walk only the KV blocks of a
     chunk of length ``c`` (global position offset ``base``) that cover
-    live positions ≤ ``pos``, folding each into the running (max, denom,
-    numerator).  Shared by :func:`decode_gqa_attention` (base 0, whole
-    cache), the sequence-parallel per-shard partials (base = the shard's
-    chunk start), and the paged decode walk (block = one KV page) so the
-    block walk cannot drift between them.
+    live positions — those up to the last query's, ``pos + T - 1`` —
+    folding each into the running (max, denom, numerator); query row ``i``
+    masks at its own ceiling ``pos + i``.  Shared by
+    :func:`live_gqa_attention` (base 0, whole cache), the
+    sequence-parallel per-shard partials (base = the shard's chunk start),
+    and the paged decode walk (block = one KV page) so the block walk
+    cannot drift between them.
 
     ``slice_block(cache, start, length)`` cuts one (B, Hkv, length, Dh)
     block; ``wrap`` marks fresh accumulators (shard_map bodies pass a
-    device-varying cast).  ``row_pos`` (B,) replaces the scalar causal
+    device-varying cast).  ``row_start`` (B,) is the ragged batch's
+    per-row key floor.  ``row_pos`` (B,) replaces the scalar causal
     ceiling with a per-row one (T must be 1): ``pos`` then only bounds
     the walk — pass its row max — while each row masks at its own
-    ceiling.  ``block`` overrides the auto-tuned chunk width when the
-    storage layout fixes the granularity (paged pools walk page-sized
-    blocks).  Returns raw ``(m, l, acc)`` — callers gated on a non-empty
-    live region fold at least one block, so ``m`` is a real max.  The
-    caller normalizes (``acc / l``) or combines partials."""
+    ceiling.  ``block`` overrides :func:`_kv_chunk` when the storage
+    layout fixes the granularity (paged pools walk page-sized blocks).
+    Returns raw ``(m, l, acc)`` — callers gated on a non-empty live region
+    fold at least one block, so ``m`` is a real max.  The caller
+    normalizes (``acc / l``) or combines partials."""
     b, hkv, g, t, dh = qf.shape
+    if row_pos is not None and t != 1:
+        raise ValueError("per-row ceilings (row_pos) need T == 1")
     if block is None:
         block = _kv_chunk(c)
     scale = 1.0 / jnp.sqrt(jnp.float32(dh))
-    local_last = jnp.clip(pos - base, 0, c - 1)
+    local_last = jnp.clip(pos + (t - 1) - base, 0, c - 1)
     n_live = local_last // block + 1
+    t_idx = pos + jnp.arange(t)[:, None]  # (T, 1): each row's causal ceiling
 
     def cond(carry):
         return carry[0] < n_live
@@ -690,7 +660,7 @@ def blocked_live_fold(qf, slice_block, k_cache, v_cache, pos, base, c,
         if row_pos is not None:  # slot batch: per-row causal ceiling
             mask = s_idx[None, None, :] <= row_pos[:, None, None]  # (B, 1, blk)
         else:
-            mask = (s_idx <= pos)[None, :]
+            mask = s_idx[None, :] <= t_idx  # (T, blk)
         if row_start is not None:  # ragged batch: per-row key floor
             floor = s_idx[None, None] >= row_start[:, None, None]
             mask = (mask if mask.ndim == 3 else mask[None]) & floor
@@ -703,35 +673,44 @@ def blocked_live_fold(qf, slice_block, k_cache, v_cache, pos, base, c,
     return m, l, acc
 
 
-def decode_gqa_attention(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
-                         pos: jax.Array,
-                         layer: jax.Array | None = None,
-                         start: jax.Array | None = None,
-                         scales: tuple[jax.Array, jax.Array] | None = None
-                         ) -> jax.Array:
-    """Single-token causal GQA that reads only blocks covering positions
-    ``0..pos``.
+def live_gqa_attention(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
+                       pos: jax.Array,
+                       layer: jax.Array | None = None,
+                       start: jax.Array | None = None,
+                       scales: tuple[jax.Array, jax.Array] | None = None,
+                       block: int | None = None) -> jax.Array:
+    """Causal GQA for ``T`` query rows at ``pos..pos+T-1`` that reads only
+    the KV blocks covering positions ``0..pos+T-1``: the flash-style
+    online softmax (peak memory O(T·block), numerically the one-shot path:
+    same f32 accumulation, association differs only within the rescale
+    chain) over the live length of the cache, not its capacity.
 
     A static-shape einsum over the full cache costs O(S) HBM traffic per
-    token no matter where in the sequence decoding stands — at 64k
-    context that is ~32 GB/token for 7B shapes, dwarfing the weights.
+    call no matter where in the sequence it stands — at 64k context that
+    is ~32 GB/token for 7B shapes, dwarfing the weights, and a 256-token
+    prompt at position 0 of a 32k cache scores 32× the keys it can see.
     The reference's attention loop is O(pos) (llama2-tasks.cpp:68-92);
     this restores that bound under XLA's static shapes with a
-    ``lax.while_loop`` whose trip count is ``pos//block + 1``: each step
-    dynamic-slices one KV block and folds it into the online-softmax
+    ``lax.while_loop`` whose trip count is ``(pos+T-1)//block + 1``: each
+    step dynamic-slices one KV block and folds it into the online-softmax
     accumulator, so traffic is proportional to the live prefix.
 
     With ``layer`` the caches are the *stacked* (L, B, Hkv, S, Dh) buffers
     and each block is sliced at ``(layer, ..., start, ...)`` directly —
     slicing out the layer first would materialize the whole layer slab
     (O(S) again, e.g. 128 MB per layer-step at 16k) before the loop reads
-    its first block.
+    its first block.  ``start`` (B,) is the ragged batch's key floor;
+    ``scales`` the int8 cache's (k, v) dequant planes, sliced block-wise
+    beside the values; ``block`` overrides the fold's width
+    (tools/sweep_attn.py times the candidates through it).
     """
+    from ..obs import dispatch as obs_dispatch
     b, hq, t, dh = q.shape
     seq_ax = 2 if layer is None else 3
     hkv = k_cache.shape[seq_ax - 1]
     s = k_cache.shape[seq_ax]
     g = hq // hkv
+    obs_dispatch.record_dispatch("attn", "live-walk", t=t, s=s)
     qf = q.astype(jnp.float32).reshape(b, hkv, g, t, dh)
 
     def slice_block(cache, start, length):
@@ -761,7 +740,7 @@ def decode_gqa_attention(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
         kc_arg, vc_arg = (k_cache, ks), (v_cache, vs)
 
     _, l, acc = blocked_live_fold(qf, sl, kc_arg, vc_arg, pos,
-                                  jnp.int32(0), s, row_start=start)
+                                  jnp.int32(0), s, row_start=start, block=block)
     out = acc / jnp.maximum(l, 1e-38)[..., None]
     return out.reshape(b, hq, t, dh).astype(q.dtype)
 
@@ -774,22 +753,21 @@ def gqa_attention_at(q: jax.Array, ck: jax.Array, cv: jax.Array,
     """:func:`gqa_attention` over the *stacked* (L, B, Hkv, S, Dh) caches
     at ``layer``.
 
-    The long-cache decode path slices its KV blocks straight out of the
-    stacked buffer (O(pos) traffic end to end); the short-cache and
-    prefill paths read the layer slice, which XLA fuses into the score
-    dot rather than materializing (observed in the 7B decode xplane).
+    The live walk (decode and prefill alike, see :func:`_use_live_walk`)
+    slices its KV blocks straight out of the stacked buffer, O(pos + T)
+    traffic end to end; only the short-cache one-shot path reads the
+    layer slice, which XLA fuses into the score dot rather than
+    materializing (observed in the 7B decode xplane).
 
     ``scales``: the int8-cache dequant planes (Lk, Lv stacked,
-    (L, B, Hkv, S, 1) f32).  The decode path dequantizes block-wise (the
-    HBM read stays int8-sized — the point of the quantized cache); the
-    short/prefill paths dequantize the layer slice, which XLA fuses into
-    the dot like the plain cast.
+    (L, B, Hkv, S, 1) f32).  The walk dequantizes block-wise (the HBM
+    read stays int8-sized — the point of the quantized cache); the
+    one-shot path dequantizes the layer slice, which XLA fuses into the
+    dot like the plain cast.
     """
-    t = q.shape[2]
-    s = ck.shape[3]
-    if _use_blocked_decode(t, s):
-        return decode_gqa_attention(q, ck, cv, pos, layer=layer, start=start,
-                                    scales=scales)
+    if _use_live_walk(q.shape[1] // ck.shape[2], q.shape[2], ck.shape[3]):
+        return live_gqa_attention(q, ck, cv, pos, layer=layer, start=start,
+                                  scales=scales)
     k_l = jax.lax.dynamic_index_in_dim(ck, layer, 0, keepdims=False)
     v_l = jax.lax.dynamic_index_in_dim(cv, layer, 0, keepdims=False)
     if scales is not None:
@@ -816,10 +794,12 @@ def gqa_attention(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
     ``kvMul = nHeads/nKvHeads`` (llama2-tasks.cpp:58) becomes a reshape to
     (B, Hkv, G, T, Dh) so each kv head serves G query heads in one einsum.
 
-    Long prefills (score tensor past ``_BLOCKED_THRESHOLD`` elements per
-    batch×kv-head) dispatch to :func:`blocked_gqa_attention`; decode over
-    a long cache dispatches to the length-aware
-    :func:`decode_gqa_attention`.
+    A long cache (any ``T``) and a score tensor past
+    ``_BLOCKED_THRESHOLD`` elements per batch×kv-head dispatch to the
+    length-aware :func:`live_gqa_attention` (:func:`_use_live_walk`); the
+    rest is the one-shot form below.  Either way the call site records
+    its family in the dispatch ledger at trace time: ``attn/live-walk``
+    or ``attn/one-shot``.
 
     ``start`` (B,) is the ragged-batch key floor: row ``b`` may only see
     key positions ``>= start[b]`` (its left-padding slots hold other
@@ -830,15 +810,15 @@ def gqa_attention(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
     live rows ``exp(_NEG - m)`` underflows to exactly 0.0, so the result
     is bit-identical to the -inf fill.
     """
+    from ..obs import dispatch as obs_dispatch
     b, hq, t, dh = q.shape
     hkv = k_cache.shape[1]
     s = k_cache.shape[2]
     g = hq // hkv
 
-    if t > 1 and g * t * s > _BLOCKED_THRESHOLD:
-        return blocked_gqa_attention(q, k_cache, v_cache, pos, q_len, start=start)
-    if _use_blocked_decode(t, s):
-        return decode_gqa_attention(q, k_cache, v_cache, pos, start=start)
+    if _use_live_walk(g, t, s):
+        return live_gqa_attention(q, k_cache, v_cache, pos, start=start)
+    obs_dispatch.record_dispatch("attn", "one-shot", t=t, s=s)
 
     # operands in cache dtype, f32 accumulation — see _online_fold for why
     qc = q.reshape(b, hkv, g, t, dh).astype(k_cache.dtype)

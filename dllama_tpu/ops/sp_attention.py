@@ -32,7 +32,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from .attention import _use_blocked_decode, blocked_live_fold
+from .attention import _use_live_walk, blocked_live_fold
 
 NEG_BIG = -1e30  # stand-in for -inf that keeps exp() NaN-free on empty shards
 
@@ -129,7 +129,7 @@ def _local_partials(q, k, v, pos, q_len, chunk_start):
 def _local_partials_blocked(q, k, v, pos, chunk_start):
     """Decode-step (T==1) per-shard partials that read only the KV blocks
     covering this shard's *live* positions — the within-shard analogue of
-    ops.attention.decode_gqa_attention (same shared block-walk core), so
+    ops.attention.live_gqa_attention (same shared block-walk core), so
     sp long-context decode is O(live prefix) per shard instead of
     O(chunk): at 128k context over sp=8, a shard whose live region is 4k
     reads 4k positions, not its whole 16k chunk.  Produces the same
@@ -268,7 +268,7 @@ def sp_gqa_attention(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
         def compute(_):
             # decode over a long local chunk: walk only the blocks covering
             # this shard's live positions (O(live) per shard, not O(chunk))
-            if _use_blocked_decode(q_len, chunk):
+            if q_len == 1 and _use_live_walk(g, q_len, chunk):
                 return _local_partials_blocked(qf, k, v, pos, chunk_start)
             return _local_partials(qf, k, v, pos, q_len, chunk_start)
 

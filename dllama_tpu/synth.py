@@ -32,7 +32,7 @@ MODEL_SHAPES = {
     # long-context variant: a 16k cache (2×4.3 GB bf16) next to the ~4 GB
     # packed weights — decode stays fast only because attention reads the
     # live prefix, not the whole cache (ops/attention.py
-    # decode_gqa_attention)
+    # live_gqa_attention)
     "llama2-7b-long": dict(dim=4096, hidden_dim=11008, n_layers=32,
                            n_heads=32, n_kv_heads=32, vocab_size=32000,
                            seq_len=16384, dtype="bfloat16"),

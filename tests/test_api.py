@@ -74,16 +74,21 @@ def server(tmp_path_factory):
     write_tiny_model(m)
     write_tiny_tokenizer(t)
     port = free_port()
+    # the server's output goes to a file: nobody drains a pipe while the
+    # module's tests run, and a full pipe (64 KB: the log lines of a few
+    # dozen requests, or of a warm compile cache's loads) blocks the server
+    log = open(d / "server.log", "w+")
     proc = subprocess.Popen(
         [sys.executable, "-m", "dllama_tpu.server.api", "--model", m,
          "--tokenizer", t, "--port", str(port), "--temperature", "0",
          "--max-seq-len", "128", "--batch-slots", "3"],
-        cwd=REPO, env=cpu_env(), stdout=subprocess.PIPE, stderr=subprocess.STDOUT,
+        cwd=REPO, env=cpu_env(), stdout=log, stderr=subprocess.STDOUT,
         text=True)
     base = f"http://127.0.0.1:{port}"
     for _ in range(600):
         if proc.poll() is not None:
-            raise RuntimeError(f"server died:\n{proc.stdout.read()}")
+            log.seek(0)
+            raise RuntimeError(f"server died:\n{log.read()}")
         try:
             urllib.request.urlopen(base + "/health", timeout=1)
             break
@@ -95,6 +100,7 @@ def server(tmp_path_factory):
     yield base
     proc.kill()
     proc.wait()
+    log.close()
 
 
 def post(base, path, body, timeout=240):

@@ -76,7 +76,9 @@ def test_rehearse_kernels_phase(rehearsal_env, capfd):
     assert dev["platform"] == "cpu"
     rows = [json.loads(ln) for ln in capfd.readouterr().out.splitlines()]
     errs = [r for r in rows if "rel_err" in r]
-    assert len(errs) == 17  # five shapes × rows {1, 8, 256} + dense/int8 attention
+    # five shapes × rows {1, 8, 256} + dense/int8 fused attention + the
+    # live walk at prefill rows, dense/int8 × two positions
+    assert len(errs) == 21
     assert all(r["rel_err"] <= r["tol"] for r in errs)
 
 

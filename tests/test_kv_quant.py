@@ -12,8 +12,8 @@ import pytest
 from dllama_tpu.models.config import tiny_config
 from dllama_tpu.models.params import init_params
 from dllama_tpu.models.transformer import KVCache, init_kv_cache, update_cache_at
-from dllama_tpu.ops.attention import (decode_gqa_attention, dequant_kv,
-                                      gqa_attention, quantize_kv)
+from dllama_tpu.ops.attention import (dequant_kv, gqa_attention,
+                                      live_gqa_attention, quantize_kv)
 from dllama_tpu.parallel.mesh import make_mesh
 from dllama_tpu.runtime.engine import Engine
 
@@ -71,7 +71,7 @@ def test_blocked_decode_matches_dequant_oneshot():
     kq, ks = quantize_kv(jnp.asarray(rng.randn(b, hkv, s, dh), jnp.float32))
     vq, vs = quantize_kv(jnp.asarray(rng.randn(b, hkv, s, dh), jnp.float32))
     q = jnp.asarray(rng.randn(b, hkv * g, 1, dh), jnp.float32)
-    out_blocked = decode_gqa_attention(q, kq, vq, jnp.int32(pos),
+    out_blocked = live_gqa_attention(q, kq, vq, jnp.int32(pos),
                                        scales=(ks, vs))
     out_ref = gqa_attention(q, dequant_kv(kq, ks), dequant_kv(vq, vs),
                             jnp.int32(pos), 1)
@@ -89,7 +89,7 @@ def test_blocked_decode_layer_indexed_quantized():
     kq, ks = quantize_kv(jnp.asarray(rng.randn(L, b, hkv, s, dh), jnp.float32))
     vq, vs = quantize_kv(jnp.asarray(rng.randn(L, b, hkv, s, dh), jnp.float32))
     q = jnp.asarray(rng.randn(b, hkv * g, 1, dh), jnp.float32)
-    out = decode_gqa_attention(q, kq, vq, jnp.int32(pos),
+    out = live_gqa_attention(q, kq, vq, jnp.int32(pos),
                                layer=jnp.int32(layer), scales=(ks, vs))
     out_ref = gqa_attention(q, dequant_kv(kq[layer], ks[layer]),
                             dequant_kv(vq[layer], vs[layer]),

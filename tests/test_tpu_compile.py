@@ -200,6 +200,46 @@ def test_paged_slot_step_has_no_pool_copy(one_chip, monkeypatch, hkv):
     _assert_pool_is_only_scattered(text, cfg, n_pages, ps)
 
 
+def test_one_stream_prefill_walks_live_blocks_without_a_slab(one_chip):
+    """The one-stream prefill program at the one-stream cells' attention
+    shapes (a 32k contiguous cache, a 256-token bucket, 32 query and 8 KV
+    heads of 128; 3 toy-width layers, so each stacked cache is over VMEM's
+    128 MiB) compiled for the described chip: under ``attn`` it holds the
+    live walk's ``while`` and nothing makes an array of a layer slab's
+    extent (``[…,32768,128]``) — no chunk-major ``transpose``/``copy`` of
+    the layer's K and V, which with the scan over all 32 chunks was 47.8 of
+    the 74.8 ms program (PERF.md §6, PR 29)."""
+    import re
+
+    from dllama_tpu.models import transformer as tf
+
+    cfg = _toy_cfg().with_(n_layers=3, n_heads=32, n_kv_heads=8, dim=4096,
+                           seq_len=32768)
+    s = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)  # noqa: E731
+    shape = jax.eval_shape(lambda: tf.init_kv_cache(cfg, 1)).k.shape
+    assert shape == (3, 1, 8, 32768, 128)
+    cache = tf.KVCache(*(s(shape, jnp.bfloat16),) * 2)
+    text = jax.jit(
+        lambda p, c, tok, pos, last: tf.forward_last(p, cfg, tok, c, pos, last),
+        donate_argnums=(1,)).lower(
+        _dense_toy_params(cfg, one_chip), cache, s((1, 256), jnp.int32),
+        s((), jnp.int32), s((), jnp.int32)).compile().as_text()
+    slab = 8 * 32768 * 128  # one layer's K or V
+    whiles, made = 0, []
+    for line in text.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%?([\w.\-]+) = (\(.*?\)|\S+) ([\w\-]+)\(", line)
+        if not m or not re.search(r'op_name="[^"]*/attn/', line):
+            continue
+        name, result, op = m.groups()
+        whiles += op == "while"
+        dims = re.match(r"\w+\[([\d,]*)\]", result)  # None for a tuple
+        if dims and op not in ("get-tuple-element", "bitcast", "parameter") \
+                and np.prod([int(d) for d in dims.group(1).split(",") if d]) >= slab:
+            made.append((name, result, op))
+    assert whiles, "no while under attn: the live walk is not in the program"
+    assert not made, made
+
+
 def test_mixed_paged_slot_step_has_no_pool_copy(one_chip, monkeypatch):
     """The twin for the served mixed step's form, 16 slots x a 16-token chunk
     (gather attention), at Mistral's 8 KV heads: no ``copy`` of the pool's
