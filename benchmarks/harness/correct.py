@@ -1,8 +1,9 @@
 """What decides ``correct``.  Three checks, all outside the measured window:
 
-(a) the served path against the float32 reference (``reference.py``) on seeded
-    prompts: the first greedy token the server returns for each prompt must be
-    one the reference also rates (nearly) highest;
+(a) the served path against the float32 reference (the ``last_logits`` of the
+    configuration's ``models/<name>.py``) on seeded prompts: the first greedy
+    token the server returns for each prompt must be one the reference also
+    rates (nearly) highest;
 (b) the same greedy request sent alone before and after the window returns the
     same bytes;
 (c) the degrade counters (``q40_degrade``, ``attn_degrade``) did not move, and

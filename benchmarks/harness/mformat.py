@@ -1,7 +1,9 @@
 """The distributed-llama ``.m`` model file as the benchmark writes and reads
 it: the benchmark's own copy of the format (header of (key, value) i32 pairs,
 then tensors in a fixed order), so that neither the synthesizer nor the plain
-reference goes through the loader under test.  Dense llama-style models only.
+reference goes through the loader under test.  What is here is what every
+architecture shares; which header values and which tensors a file has is its
+architecture's own ``header`` and ``plan`` (``models/<name>.py``).
 
 Q40: blocks of 32 values = one f16 scale + 16 bytes; value ``i`` of a block is
 the low nibble of byte ``i``, value ``i + 16`` the high nibble, and a value is
@@ -20,7 +22,6 @@ from concurrent.futures import ThreadPoolExecutor
 import numpy as np
 
 MAGIC = 0xA00ABCD
-ARCH_LLAMA = 0xABCD00
 F32, Q40 = 0, 2
 Q40_BLOCK = 18
 HEADER_KEYS = ("version", "arch", "dim", "hidden_dim", "n_layers", "n_heads",
@@ -28,29 +29,18 @@ HEADER_KEYS = ("version", "arch", "dim", "hidden_dim", "n_layers", "n_heads",
                "seq_len", "hidden_act", "rope_theta", "weights_ftype")
 
 
-def header(shape: dict) -> bytes:
-    vals = dict(shape, version=1, arch=ARCH_LLAMA, n_experts=0,
-                n_active_experts=0, hidden_act=1, weights_ftype=Q40,
-                rope_theta=int(shape["rope_theta"]))
+def pack_header(vals: dict) -> bytes:
+    """The header's bytes from a value for every key of ``HEADER_KEYS``."""
     data = b"".join(struct.pack("<ii", k, int(vals[name]))
                     for k, name in enumerate(HEADER_KEYS))
     return struct.pack("<ii", MAGIC, 8 + len(data)) + data
 
 
-def plan(shape: dict) -> list[tuple[str, tuple, int, int, int]]:
-    """(name, shape, ftype, offset, nbytes) of every tensor, in file order."""
-    dim, hid, voc = shape["dim"], shape["hidden_dim"], shape["vocab_size"]
-    kv = dim // shape["n_heads"] * shape["n_kv_heads"]
-    names = [("token_embedding", (voc, dim), F32)]
-    for i in range(shape["n_layers"]):
-        p = f"layers.{i}."
-        names += [(p + "wq", (dim, dim), Q40), (p + "wk", (kv, dim), Q40),
-                  (p + "wv", (kv, dim), Q40), (p + "wo", (dim, dim), Q40),
-                  (p + "w1", (hid, dim), Q40), (p + "w2", (dim, hid), Q40),
-                  (p + "w3", (hid, dim), Q40), (p + "rms_att", (dim,), F32),
-                  (p + "rms_ffn", (dim,), F32)]
-    names += [("rms_final", (dim,), F32), ("wcls", (voc, dim), Q40)]
-    out, pos = [], len(header(shape))
+def lay_out(names: list[tuple[str, tuple, int]],
+            start: int) -> list[tuple[str, tuple, int, int, int]]:
+    """(name, shape, ftype, offset, nbytes) of every (name, shape, ftype), in
+    file order from ``start`` (the header's length)."""
+    out, pos = [], start
     for name, shp, ft in names:
         n = int(np.prod(shp))
         nbytes = 4 * n if ft == F32 else n // 32 * Q40_BLOCK
@@ -93,15 +83,16 @@ def _tensor_bytes(seed: int, index: int, shp: tuple, ftype: int,
     return arr.reshape(-1)
 
 
-def synthesize(path: str, shape: dict, seed: int, workers: int = 8) -> None:
-    """Write the model at packed size, tensors made in parallel (numpy's
-    generators release the GIL) and written at their offsets."""
+def synthesize(path: str, model, shape: dict, seed: int, workers: int = 8) -> None:
+    """Write the model that ``model`` (the architecture's module) lays out for
+    ``shape`` at packed size, tensors made in parallel (numpy's generators
+    release the GIL) and written at their offsets."""
     t0 = time.time()
-    tensors = plan(shape)
+    tensors = model.plan(shape)
     part = path + ".part"
     fd = os.open(part, os.O_CREAT | os.O_WRONLY | os.O_TRUNC, 0o644)
     try:
-        os.pwrite(fd, header(shape), 0)
+        os.pwrite(fd, model.header(shape), 0)
 
         def one(i: int) -> None:
             name, shp, ft, off, nbytes = tensors[i]

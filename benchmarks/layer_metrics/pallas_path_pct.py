@@ -1,9 +1,12 @@
 """kernel choice: share of the dispatch ledger's matmul and attention call
 sites (``matmul_dispatch``, recorded once per compiled call site) that resolved
-to a Pallas kernel.  Prefill sites above 128 rows take XLA by rule, so the
-figure is below 100 on a clean run; it drops when a decode site degrades."""
+to a Pallas kernel.  One device takes the Q40 kernel at any row count, so a
+clean one-chip run reads 100; a mesh still sends more than 128 rows to XLA
+(``q40/xla-dequant``), as does the gather attention of a served chunk step, so
+those cells read below 100 on a clean run.  It drops when a decode site
+degrades."""
 
-PALLAS = ("pallas-fused", "pallas-blocked", "paged-fused", "tp_fused_reduce")
+PALLAS = ("pallas-fused", "paged-fused", "tp_fused_reduce")
 
 
 def read(ctx):

@@ -2,12 +2,13 @@
 """One cell, one run: ``python3 benchmarks/run.py --workload <cell> --seed <n>
 --seconds <s> --trace <0|1>``.
 
-Loads the cell's data (``cells/``, ``configs/``, ``traffic/``), makes the model
-files from the configuration's seed if this checkout has none yet, starts the
-server through its normal entry point on a thread of this process, warms up
-every shape the cell's traffic uses, drives the measured window from a JAX-free
-child (``harness/loadgen.py``), checks the outputs, and prints one JSON object
-as the last line of its standard output.  Needs a TPU; ``--rehearse`` runs the
+Loads the cell's data (``cells/``, ``configs/``, ``traffic/``) and the
+configuration's architecture (``models/<name>.py``, found by
+``harness/models.py``), makes the model files from the configuration's seed if
+this checkout has none yet, starts the server through its normal entry point on
+a thread of this process, warms up every shape the cell's traffic uses, drives
+the measured window from a JAX-free child (``harness/loadgen.py``), checks the
+outputs, and prints one JSON object as the last line of its standard output.  Needs a TPU; ``--rehearse`` runs the
 same control flow on the CPU at toy widths and reports no device metric.
 """
 
@@ -32,7 +33,7 @@ ROOT = os.path.dirname(HERE)
 sys.path.insert(0, HERE)
 sys.path.insert(0, ROOT)
 
-from harness import correct, e2e, loadgen, mformat, tokens  # noqa: E402
+from harness import correct, e2e, loadgen, mformat, models, tokens  # noqa: E402
 from harness.server import Server, counter_total  # noqa: E402
 
 CACHE = os.path.join(HERE, ".cache")
@@ -40,9 +41,6 @@ OUT = os.path.join(HERE, "out")
 TRACE_S = 5.0        # length of the traced sub-window, mid-run
 SAMPLE_HZ = 5.0      # /metrics gauges are sampled at this rate in a traced run
 N_CHECK, CHECK_LEN = 8, 32
-# toy widths for --rehearse (the CPU cannot hold or time the published ones)
-REHEARSE_SHAPE = dict(dim=256, hidden_dim=512, n_layers=2, n_heads=8,
-                      n_kv_heads=4, vocab_size=2048)
 
 
 def log(msg: str) -> None:
@@ -73,21 +71,14 @@ def load_cell(name: str) -> dict:
                           if name in m.get("workloads", [name])]}
 
 
-def model_shape(config: dict, rehearse: bool) -> dict:
-    """The ``.m`` header's sizes from the configuration file's (published) keys."""
-    shape = dict(dim=config["hidden_size"], hidden_dim=config["intermediate_size"],
-                 n_layers=config["num_hidden_layers"],
-                 n_heads=config["num_attention_heads"],
-                 n_kv_heads=config["num_key_value_heads"],
-                 vocab_size=config["vocab_size"],
-                 seq_len=config["max_position_embeddings"],
-                 rope_theta=config["rope_theta"])
-    if shape["dim"] // shape["n_heads"] != config["head_dim"]:
-        raise SystemExit("head_dim is not hidden_size / num_attention_heads")
-    return dict(shape, **REHEARSE_SHAPE) if rehearse else shape
+def model_shape(model, config: dict, rehearse: bool) -> dict:
+    """The ``.m`` header's sizes as the configuration's architecture reads them
+    from the file's (published) keys; a rehearsal gets its toy widths."""
+    shape = model.shape(config)
+    return dict(shape, **model.REHEARSE) if rehearse else shape
 
 
-def ensure_files(name: str, shape: dict, seed: int) -> tuple[str, str]:
+def ensure_files(name: str, model, shape: dict, seed: int) -> tuple[str, str]:
     """The model and tokenizer of this checkout, made once from the
     configuration's seed and reused by every later run (weights are not made
     from ``--seed``: a 4-21 GB file per run would be most of every run)."""
@@ -97,21 +88,20 @@ def ensure_files(name: str, shape: dict, seed: int) -> tuple[str, str]:
         tokens.write_tokenizer(stem + ".t", shape["vocab_size"])
     if not os.path.exists(stem + ".m"):
         log(f"synthesizing {stem}.m")
-        mformat.synthesize(stem + ".m", shape, seed)
+        mformat.synthesize(stem + ".m", model, shape, seed)
     return stem + ".m", stem + ".t"
 
 
-def reference_logits(mpath: str, prompts: list[list[int]], endpoint: str):
+def reference_logits(model, mpath: str, prompts: list[list[int]], endpoint: str):
     """The float32 reference's logits for the check prompts, computed once per
     checkout and kept beside the model file."""
     import numpy as np
     path = f"{mpath[:-2]}.ref-{endpoint}-{len(prompts)}x{len(prompts[0])}.npy"
     if os.path.exists(path):
         return np.load(path)
-    from harness import reference
     log("running the float32 reference (first run in this checkout)")
     full = [tokens.encode_text(tokens.text_of(p), endpoint) for p in prompts]
-    logits = reference.last_logits(mpath, full)
+    logits = model.last_logits(mpath, full)
     np.save(path + ".part.npy", logits)
     os.replace(path + ".part.npy", path)
     return logits
@@ -182,7 +172,8 @@ def main(argv=None) -> int:
     c = load_cell(a.workload)
     cfg, mix, chips = c["config"], c["mix"], c["chips"]
     endpoint = mix["endpoint"]
-    shape = model_shape(cfg, a.rehearse)
+    model = models.for_config(cfg)
+    shape = model_shape(model, cfg, a.rehearse)
 
     if a.rehearse:
         os.environ.setdefault("JAX_PLATFORMS", "cpu")
@@ -207,11 +198,11 @@ def main(argv=None) -> int:
     peak = None if a.rehearse else peaks_mod.peaks(devs[0].device_kind)
 
     mpath, tpath = ensure_files(
-        c["config_name"] + ("-rehearse" if a.rehearse else ""), shape,
+        c["config_name"] + ("-rehearse" if a.rehearse else ""), model, shape,
         int(cfg["weights_seed"]))
     vocab = shape["vocab_size"]
     checks = correct.check_prompts(int(cfg["weights_seed"]), N_CHECK, CHECK_LEN, vocab)
-    ref = reference_logits(mpath, checks, endpoint)
+    ref = reference_logits(model, mpath, checks, endpoint)
 
     srv = Server(["--model", mpath, "--tokenizer", tpath, "--temperature", "0",
                   *c["cell"]["argv"]])

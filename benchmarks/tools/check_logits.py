@@ -14,10 +14,11 @@ greedy continuation collapses onto one repeated token, identical tokens have
 near-identical keys, attention over scores this peaked is a hard choice, and a
 near-tie that bfloat16 flips against float32 swaps the whole output: seen at 4
 layers, engine on the chip and on the CPU's XLA path alike, PERF.md PR 26.)  The reference
-(``harness/reference.py``: float32, ``highest`` matmul precision, no cache,
-one tensor at a time) then runs its full forward over the same tokens, once
-per sequence length (it returns the last position's logits), and the two are
-compared position by position.
+(the ``last_logits`` of the configuration's architecture, ``models/<name>.py``:
+float32, ``highest`` matmul precision, no cache, one tensor at a time) then
+runs its full forward over the same tokens, once per sequence length (it
+returns the last position's logits), and the two are compared position by
+position.
 
 Reported per position and overall, in sigmas (the standard deviation of the
 reference's logits over the vocabulary at that position): the largest
@@ -128,15 +129,16 @@ def main(argv=None) -> int:
     import numpy as np
 
     import run as bench_run
-    from harness import correct, reference
+    from harness import correct, models
 
     cfg = bench_run.load_json(a.config)
-    shape = bench_run.model_shape(cfg, a.cpu)
+    model = models.for_config(cfg)
+    shape = bench_run.model_shape(model, cfg, a.cpu)
     if a.layers:
         shape["n_layers"] = a.layers
     name = os.path.splitext(os.path.basename(a.config))[0]
     mpath, tpath = bench_run.ensure_files(name + ("-rehearse" if a.cpu else ""),
-                                          shape, int(cfg["weights_seed"]))
+                                          model, shape, int(cfg["weights_seed"]))
     import jax
     if not a.cpu and jax.devices()[0].platform != "tpu":
         raise SystemExit("check_logits needs a TPU (or --cpu for the control flow)")
@@ -163,8 +165,8 @@ def main(argv=None) -> int:
     rows, ref_s = [], []
     for k in range(got.shape[1]):
         t0 = time.time()
-        ref = reference.last_logits(mpath, [list(map(int, t[:n0 + k]))
-                                            for t in toks])
+        ref = model.last_logits(mpath, [list(map(int, t[:n0 + k]))
+                                        for t in toks])
         ref_s.append(time.time() - t0)
         sigma = ref.std(axis=1)
         diff = got[:, k] - ref
