@@ -57,8 +57,7 @@ def _zero_q40_params(cfg, codec="q40"):
     "q80"), built as zero device buffers
     (no host-side f32 materialization).  Matches the quantized loader's
     single-chip layout (load_params fuse=True): fused wqkv everywhere,
-    fused w13 for dense FFNs, packed expert stacks for MoE — shared by
-    the bench and tools/moe_hw_check.py."""
+    fused w13 for dense FFNs, packed expert stacks for MoE."""
     import jax.numpy as jnp
     from dllama_tpu.models.params import param_shapes
     from dllama_tpu.ops.q40 import QTensor, padded_n

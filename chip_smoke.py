@@ -10,6 +10,10 @@ Q40 weights:
   cli      ``python -m dllama_tpu inference`` on a synthesized .m/.t pair
   server   ``python -m dllama_tpu.server.api`` with a paged slot scheduler:
            concurrent completions, a streamed chat, /metrics, SIGTERM drain
+  moe      the mixture-of-experts path at OLMoE-1B-7B's widths and 2 layers:
+           a seeded .m through the loader, ``moe_ffn``'s select strategy at 1
+           row and its scan over the 64 packed experts at 16 rows against the
+           XLA-dequantized matmul, then the same paged server on that file
 
 ``--chips 4`` runs, instead, only the tensor-parallel path and what it is
 compared with: the same files decoded greedily at tp=4 and tp=1.
@@ -42,6 +46,8 @@ import urllib.request
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 MODEL = "llama2-7b"
+MOE_MODEL, MOE_LAYERS = "olmoe-1b-7b", 2
+MOE_TOL = 2e-2             # max |pallas - xla| / max |xla| of a layer's moe_ffn
 BUDGET_S = 1150            # the driver allows 1200 s, compilation included
 Q40_TOL = 1e-2             # max |pallas - xla| / max |xla|
 ATTN_TOL = 2e-2            # max |fused - gather| / max |gather| (bf16 out)
@@ -147,6 +153,27 @@ def phase_kernels(timeout: float, rehearse: bool = False) -> dict:
         require(dev["peaks_source"] == "table",
                 f"kernels: peaks source {dev['peaks_source']!r}, not the table")
     return dict(dev, compile=comp)
+
+
+def phase_moe(mpath: str, timeout: float, rehearse: bool = False) -> dict:
+    """Child: ``moe_ffn`` on a loaded file, kernel path against the XLA
+    path, at one row (select) and sixteen (scan)."""
+    rc, out = run_child("moe", ["--model", mpath], timeout, rehearse)
+    rows, comp = _results(out, "moe")
+    for r in rows:
+        emit(dict(r, phase="moe"))
+    require(rc == 0, f"moe: child exited {rc}")
+    errs = {r["strategy"]: r for r in rows if "rel_err" in r}
+    require(set(errs) == {"select", "scan"}, f"moe: compared {sorted(errs)}")
+    bad = [r for r in errs.values() if not r["rel_err"] <= r["tol"]]
+    require(not bad, f"moe: above tolerance: {bad}")
+    ledger = next(r for r in rows if r.get("what") == "ledger")["ledger"]
+    require("moe/select" in ledger and "moe/scan" in ledger,
+            f"moe: strategies absent from the ledger: {ledger}")
+    if not rehearse:
+        require("q40/pallas-fused" in ledger and "DEGRADED" not in ledger,
+                f"moe: {ledger}")
+    return {"phase": "moe", "compile": comp}
 
 
 def phase_cli(mpath: str, tpath: str, timeout: float, steps: int = 64,
@@ -497,13 +524,18 @@ def child_kernels(rehearse: bool) -> None:
         else:
             k_, v_, scales = pk.astype(cfg.dtype), pv.astype(cfg.dtype), None
         t0 = time.perf_counter()
-        got = att.fused_paged_attention(q, k_, v_, layer, table, pos,
-                                        scales=scales, interpret=rehearse)
+        # a dense pool is the fused kernel's; an int8 pool's read is the
+        # XLA live walk (its scale plane cannot be copied by the page)
+        got = att.paged_decode_attention(
+            q, k_, v_, layer, table, pos, scales=scales) if quantized else \
+            att.fused_paged_attention(q, k_, v_, layer, table, pos,
+                                      interpret=rehearse)
         ks, vs = scales if quantized else (None, None)
         ref = att._rows_ceiling_attention(
             q, att.paged_gather_layer(k_, layer, table, scale_pool=ks),
             att.paged_gather_layer(v_, layer, table, scale_pool=vs), pos)
-        _say({"kernel": "fused_paged_attention",
+        _say({"kernel": "paged_decode_attention" if quantized
+              else "fused_paged_attention",
               "kv": "int8" if quantized else "dense",
               "geometry": {"hq": hq, "hkv": hkv, "dh": dh, "page": ps,
                            "rows": b, "max_pages": maxp},
@@ -539,6 +571,48 @@ def child_kernels(rehearse: bool) -> None:
                                "block": att._kv_chunk(s_len)},
                   "rel_err": rel_err(got, ref), "tol": ATTN_TOL,
                   "seconds": round(time.perf_counter() - t0, 2)})
+
+
+def child_moe(argv: list[str], rehearse: bool) -> None:
+    ap = argparse.ArgumentParser()
+    ap.add_argument("--model", required=True)
+    a = ap.parse_args(argv)
+    _say(_claim_device(rehearse))
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from dllama_tpu.io import mfile
+    from dllama_tpu.models.config import ModelConfig
+    from dllama_tpu.models.params import load_params
+    from dllama_tpu.models.transformer import moe_ffn
+    from dllama_tpu.obs import dispatch as obs_dispatch
+    from dllama_tpu.ops import q40
+
+    mf = mfile.MFile(a.model)
+    dtype = jnp.float32 if rehearse else jnp.bfloat16
+    cfg, params = load_params(mf, ModelConfig.from_spec(mf.spec, dtype=dtype),
+                              dtype=dtype, keep_quantized=True)
+    params = jax.device_put({k: params[k] for k in ("router", "up", "gate", "down")})
+    lp = {k: (q40.QLayerView(v, jnp.int32(1)) if isinstance(v, q40.QTensor)
+              else v[1]) for k, v in params.items()}
+    _say({"what": "moe_model", "arch": mf.spec.arch_name,
+          "experts": cfg.n_experts, "active": cfg.n_active_experts,
+          "dim": cfg.dim, "expert_width": cfg.hidden_dim})
+    obs_dispatch.reset()
+    for rows, strategy in ((1, "select"), (16, "scan")):
+        x = jax.random.normal(jax.random.PRNGKey(rows), (rows, cfg.dim), dtype)
+        t0 = time.perf_counter()
+        got = jax.jit(lambda v: moe_ffn(v, lp, cfg.with_(
+            quant_impl="pallas_interpret" if rehearse else "auto")))(x)
+        ref = jax.jit(lambda v: moe_ffn(v, lp, cfg.with_(quant_impl="xla")))(x)
+        got, ref = np.asarray(got, np.float32), np.asarray(ref, np.float32)
+        require(np.isfinite(got).all() and np.isfinite(ref).all(),
+                "non-finite moe_ffn output")
+        _say({"kernel": "moe_ffn", "strategy": strategy, "rows": rows,
+              "rel_err": float(np.abs(got - ref).max() / max(np.abs(ref).max(), 1e-9)),
+              "tol": MOE_TOL, "seconds": round(time.perf_counter() - t0, 2)})
+    _say({"what": "ledger", "ledger": obs_dispatch.summary_line()})
 
 
 def child_module(module: str, argv: list[str], rehearse: bool) -> None:
@@ -672,6 +746,12 @@ def main(argv: list[str] | None = None) -> int:
         if a.chips == 1:
             timed("cli", phase_cli, mpath, tpath, min(600, left()))
             timed("server", phase_server, mpath, tpath, min(600, left()), tmp)
+            os.remove(mpath)  # room for the next file
+            mpath, tpath = timed("synth_moe", synth_model_files, MOE_MODEL,
+                                 tmp, MOE_LAYERS)
+            timed("moe", phase_moe, mpath, min(420, left()))
+            timed("moe_server", lambda: phase_server(
+                mpath, tpath, min(420, left()), tmp, max_tokens=16))
         else:
             device = timed("tp", phase_tp, mpath, tpath, min(420, left()), 4)
         require(device["platform"] == "tpu", "no TPU held the run")
@@ -702,6 +782,8 @@ if __name__ == "__main__":
         rest = rest[1:] if rehearse else rest
         if phase == "kernels":
             child_kernels(rehearse)
+        elif phase == "moe":
+            child_moe(rest, rehearse)
         elif phase == "cli":
             child_module("dllama_tpu", rest, rehearse)
         elif phase == "server":

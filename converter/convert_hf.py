@@ -2,13 +2,17 @@
 
 Re-implements `/root/reference/converter/convert-hf.py`: llama / mistral /
 mixtral folders with ``config.json`` + ``*.safetensors`` become a `.m` file
-in the canonical tensor order.  Key semantics preserved:
+in the canonical tensor order; beyond the reference, olmoe folders
+(``ARCH_OLMOE``).  Key semantics preserved:
 
 * q/k head permutation (convert-hf.py:12-15): HF stores RoPE in rotate-half
   layout; the `.m` format expects the interleaved-pair layout, so q and k
   rows are permuted ``(h, 2, hs/2) → (h, hs/2, 2)``.  The reference applies
   this to every arch (including Mixtral, whose runtime then rotates
   neox-style — a reference quirk preserved for file-format parity).
+  OLMoE rows are NOT permuted: its runtime rotates halves as HF does, and a
+  permuted q or k could not share the order of its ``q_norm``/``k_norm``
+  weight, which spans the whole projection.
 * dense FFN file order gate/down/up = w1/w2/w3 (convert-hf.py:77-83);
   MoE per-expert order up(w3)/gate(w1)/down(w2) (convert-hf.py:68-75).
 
@@ -32,6 +36,7 @@ ARCH_BY_MODEL_TYPE = {
     "llama": mfile.ARCH_LLAMA,
     "mistral": mfile.ARCH_LLAMA,
     "mixtral": mfile.ARCH_MIXTRAL,
+    "olmoe": mfile.ARCH_OLMOE,
 }
 HIDDEN_ACT = {"gelu": mfile.ACT_GELU, "silu": mfile.ACT_SILU}
 
@@ -44,13 +49,34 @@ def permute(t: np.ndarray, n_heads: int, n_kv_heads: int) -> np.ndarray:
              .swapaxes(1, 2).reshape(t.shape))
 
 
+def _refuse_olmoe_variants(config: dict) -> None:
+    """``ARCH_OLMOE`` is one block: the settings the published OLMoE-1B-7B
+    has.  A config that departs from them would convert to a file the
+    runtime computes silently wrong, so it is refused here."""
+    if config.get("norm_topk_prob", False):
+        raise SystemExit("olmoe: norm_topk_prob is true; the runtime uses the "
+                         "top-k router probabilities unnormalised for this arch")
+    if config.get("clip_qkv") is not None:
+        raise SystemExit(f"olmoe: clip_qkv is {config['clip_qkv']}; the runtime "
+                         "does not clip q, k or v")
+    if config.get("attention_bias", False):
+        raise SystemExit("olmoe: attention_bias is true; the .m format has no "
+                         "bias tensors")
+    if config.get("rope_scaling") is not None:
+        raise SystemExit(f"olmoe: rope_scaling is {config['rope_scaling']}; the "
+                         "runtime's RoPE is unscaled")
+
+
 def load_spec(folder: str, weights_ftype: int) -> mfile.ModelSpec:
     with open(os.path.join(folder, "config.json")) as f:
         config = json.load(f)
     arch = ARCH_BY_MODEL_TYPE.get(config["model_type"])
     if arch is None:
         raise SystemExit(f"Unsupported arch type: {config['model_type']}")
-    n_experts = config.get("num_local_experts") or 0
+    if arch == mfile.ARCH_OLMOE:
+        _refuse_olmoe_variants(config)
+    # Mixtral's key, then OLMoE's
+    n_experts = config.get("num_local_experts") or config.get("num_experts") or 0
     n_active = (config.get("num_active_local_experts")
                 or config.get("num_experts_per_tok") or 0)
     return mfile.ModelSpec(
@@ -109,10 +135,13 @@ def hf_source_name(our_name: str, spec: mfile.ModelSpec) -> tuple[str, bool]:
     li = parts[1]
     leaf = parts[-1]
     base = f"model.layers.{li}"
+    olmoe = spec.arch == mfile.ARCH_OLMOE
     if leaf == "wq":
-        return f"{base}.self_attn.q_proj.weight", True
+        return f"{base}.self_attn.q_proj.weight", not olmoe
     if leaf == "wk":
-        return f"{base}.self_attn.k_proj.weight", True
+        return f"{base}.self_attn.k_proj.weight", not olmoe
+    if leaf in ("q_norm", "k_norm"):
+        return f"{base}.self_attn.{leaf}.weight", False
     if leaf == "wv":
         return f"{base}.self_attn.v_proj.weight", False
     if leaf == "wo":
@@ -130,10 +159,13 @@ def hf_source_name(our_name: str, spec: mfile.ModelSpec) -> tuple[str, bool]:
         return f"{base}.mlp.up_proj.weight", False
     if parts[2] == "experts":
         e = parts[3]
+        if olmoe:
+            return f"{base}.mlp.experts.{e}.{leaf}_proj.weight", False
         hf_leaf = {"up": "w3", "gate": "w1", "down": "w2"}[leaf]
         return f"{base}.block_sparse_moe.experts.{e}.{hf_leaf}.weight", False
     if leaf == "moe_router":
-        return f"{base}.block_sparse_moe.gate.weight", False
+        return (f"{base}.mlp.gate.weight" if olmoe
+                else f"{base}.block_sparse_moe.gate.weight"), False
     raise SystemExit(f"no HF mapping for {our_name}")
 
 

@@ -10,8 +10,9 @@ the reference converters load directly:
   Legacy magics ``0xABCD00``/``0xABCD01`` carry a fixed 9-int struct
   (transformer.cpp:27-42).
 * tensor walk mirrors ``Transformer::loadRoot`` (transformer.cpp:428-487):
-  embedding, then per layer q/k/v/wo, (router + per-expert up/gate/down |
-  w1/w2/w3), rms_att, rms_ffn, (grok: rms_moe, rms_ffn2), then rms_final
+  embedding, then per layer q/k/v/wo, (olmoe: q_norm, k_norm), (router +
+  per-expert up/gate/down | w1/w2/w3), rms_att, rms_ffn, (grok: rms_moe,
+  rms_ffn2), then rms_final
   and wcls.  Matmul weights are stored row-major ``(d_out, n_in)`` in the
   model's weight float type; norm weights and the embedding are F32
   (transformer.cpp:213-218, 266-278).
@@ -40,7 +41,12 @@ LEGACY_MAGICS = (0xABCD00, 0xABCD01)
 ARCH_LLAMA = 0xABCD00
 ARCH_GROK1 = 0xABCD01
 ARCH_MIXTRAL = 0xABCD02
-ARCH_NAMES = {ARCH_LLAMA: "llama", ARCH_GROK1: "grok1", ARCH_MIXTRAL: "mixtral"}
+# beyond the reference's three: OLMoE (q/k RMSNorm over the whole projection,
+# top-k router probabilities used unnormalised).  An arch id and not a header
+# key: the per-arch flags are derived from it (models/config.py)
+ARCH_OLMOE = 0xABCD03
+ARCH_NAMES = {ARCH_LLAMA: "llama", ARCH_GROK1: "grok1", ARCH_MIXTRAL: "mixtral",
+              ARCH_OLMOE: "olmoe"}
 
 # TransformerHiddenAct (transformer.hpp:45-48)
 ACT_GELU = 0
@@ -132,6 +138,9 @@ def tensor_plan(spec: ModelSpec) -> list[TensorInfo]:
         add(f"layers.{i}.wk", (spec.kv_dim, spec.dim), w)
         add(f"layers.{i}.wv", (spec.kv_dim, spec.dim), w)
         add(f"layers.{i}.wo", (spec.dim, spec.dim), w)
+        if spec.arch == ARCH_OLMOE:
+            add(f"layers.{i}.q_norm", (spec.dim,), quants.F32)
+            add(f"layers.{i}.k_norm", (spec.kv_dim,), quants.F32)
         if spec.n_experts > 0:
             add(f"layers.{i}.moe_router", (spec.n_experts, spec.dim), w)
             for e in range(spec.n_experts):
@@ -228,6 +237,10 @@ def validate_spec(spec: ModelSpec, path) -> ModelSpec:
                             "more active experts than experts",
                             expected=f"<= {spec.n_experts}",
                             got=spec.n_active_experts)
+    if spec.arch == ARCH_OLMOE and not spec.n_active_experts:
+        raise ArtifactError(path, "header field n_active_experts",
+                            "an olmoe file has experts and a top-k",
+                            expected=">= 1", got=spec.n_active_experts)
     return spec
 
 

@@ -4,7 +4,8 @@ Bridges the on-disk ``ModelSpec`` (`.m` header, transformer.cpp:12-125) to
 the runtime: adds compute dtype and derives the per-arch structural flags
 that the reference encodes as three separate hand-built task lists
 (`buildLlamaArch` llama2-tasks.cpp:241-298, `buildGrok1Arch`
-grok1-tasks.cpp:275-354, `buildMixtralArch` mixtral-tasks.cpp:5-78).
+grok1-tasks.cpp:275-354, `buildMixtralArch` mixtral-tasks.cpp:5-78), and
+those of OLMoE (`ARCH_OLMOE`, beyond the reference).
 """
 
 from __future__ import annotations
@@ -85,6 +86,20 @@ class ModelConfig:
         add (grokRmfFfnNorm / grokMoeRmsNormFinal, grok1-tasks.cpp:16-41,
         :245-263); Llama/Mixtral add raw outputs to the residual."""
         return self.arch == mfile.ARCH_GROK1
+
+    @property
+    def qk_norm(self) -> bool:
+        """OLMoE RMS-normalises the whole q and the whole k projection (one
+        weight vector each, ``layers.{i}.q_norm`` / ``k_norm``) before the
+        split into heads and before RoPE; no other arch has the vectors."""
+        return self.arch == mfile.ARCH_OLMOE
+
+    @property
+    def norm_topk_prob(self) -> bool:
+        """Mixtral and Grok-1 renormalise the chosen experts' probabilities
+        to sum to 1 (grok1-tasks.cpp:60-114); OLMoE (``norm_topk_prob:
+        false``) uses them as the softmax over all experts gave them."""
+        return self.arch != mfile.ARCH_OLMOE
 
     @classmethod
     def from_spec(cls, spec: mfile.ModelSpec, dtype=jnp.float32) -> "ModelConfig":

@@ -41,6 +41,8 @@ def param_shapes(cfg: ModelConfig) -> dict[str, tuple[int, ...]]:
         "rms_final": (D,),
         "wcls": (D, V),
     }
+    if cfg.qk_norm:
+        shapes.update({"q_norm": (L, Hq), "k_norm": (L, Hkv)})
     if cfg.is_moe:
         shapes.update({
             "router": (L, D, E),
@@ -61,11 +63,12 @@ def init_params(cfg: ModelConfig, seed: int = 0, scale: float = 0.02) -> Params:
     rng = np.random.RandomState(seed)
     params: Params = {}
     for name, shape in param_shapes(cfg).items():
-        if name.startswith("rms"):
+        norm = name.startswith("rms") or name in ("q_norm", "k_norm")
+        if norm:
             x = np.ones(shape, dtype=np.float32)
         else:
             x = (rng.standard_normal(shape) * scale).astype(np.float32)
-        params[name] = jnp.asarray(x, dtype=jnp.float32 if name.startswith("rms") else cfg.dtype)
+        params[name] = jnp.asarray(x, dtype=jnp.float32 if norm else cfg.dtype)
     return params
 
 
@@ -217,6 +220,9 @@ def _read_params(mf: mfile.MFile, cfg: ModelConfig, dtype,
             p[key] = _stack(mf, [f"layers.{i}.{key}" for i in range(L)], True, np_dtype)
     p["rms_att"] = _stack(mf, [f"layers.{i}.rms_att" for i in range(L)], False, np.float32)
     p["rms_ffn"] = _stack(mf, [f"layers.{i}.rms_ffn" for i in range(L)], False, np.float32)
+    if cfg.qk_norm:
+        for key in ("q_norm", "k_norm"):
+            p[key] = _stack(mf, [f"layers.{i}.{key}" for i in range(L)], False, np.float32)
     if cfg.is_moe:
         p["router"] = _stack(mf, [f"layers.{i}.moe_router" for i in range(L)], True, np_dtype)
         if quant:

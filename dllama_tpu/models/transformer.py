@@ -29,13 +29,14 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
+from ..obs import dispatch as obs_dispatch
 from ..ops import q40, q8
 from ..ops.attention import (gqa_attention_at, paged_gqa_attention_at,
                              paged_update_kv_rows, paged_write_indices,
                              quantize_kv, slot_gqa_attention_at,
                              update_kv_cache_at, update_kv_cache_rows)
 from ..ops.kernels import ACTIVATIONS, apply_rope, rmsnorm, rope_angles, softmax_f32
-from ..ops.scopes import scope
+from ..ops.scopes import part, scope
 from ..ops.sp_attention import ring_attention, sp_gqa_attention, sp_update_kv_cache_at
 from ..parallel.mesh import get_active_mesh
 from .config import ModelConfig
@@ -173,6 +174,12 @@ def _attention_block(x, lp, cfg: ModelConfig, cache: KVCache, cos, sin, pos,
             q = _mm(xb, lp["wq"], cfg, kind="row")
             k = _mm(xb, lp["wk"], cfg, kind="row")
             v = _mm(xb, lp["wv"], cfg, kind="row")
+        if cfg.qk_norm:
+            # over the whole projection, before the head split and RoPE; on
+            # a tp mesh q and k are sharded on this axis and the mean is
+            # GSPMD's all-reduce (tests/test_olmoe.py, 4-device CPU mesh)
+            q = rmsnorm(q, lp["q_norm"])
+            k = rmsnorm(k, lp["k_norm"])
         q = q.reshape(b, t, hq, dh)
         k = k.reshape(b, t, hkv, dh)
         v = v.reshape(b, t, hkv, dh)
@@ -294,7 +301,14 @@ def moe_ffn(xb2d: jax.Array, lp, cfg: ModelConfig) -> jax.Array:
 
     Routing: softmax over *all* expert logits, top-k, renormalize the
     selected probabilities (grokMoeRouterSoftmax/Topk/NormWeights,
-    grok1-tasks.cpp:60-114).
+    grok1-tasks.cpp:60-114); OLMoE (``not cfg.norm_topk_prob``) uses the
+    selected probabilities as they are.
+
+    Sub-scopes inside ``moe``: ``router`` (logits, softmax, top-k),
+    ``experts`` (the expert matmuls and, on the scan, its bookkeeping),
+    ``combine`` (the weighted sum and the cast to the activation dtype).  Each
+    compiled call site records its strategy in the dispatch ledger as
+    ``{codec="moe", path="select"|"scan"|"unrolled"|"dense"}``.
 
     Two execution strategies, chosen statically by token count:
     * decode (few tokens): compute only the k selected experts — with
@@ -318,43 +332,54 @@ def moe_ffn(xb2d: jax.Array, lp, cfg: ModelConfig) -> jax.Array:
     e, k = cfg.n_experts, cfg.n_active_experts
     act = ACTIVATIONS[cfg.hidden_act]
 
-    router = lp["router"]
-    router_logits = xb2d.astype(jnp.float32) @ router.astype(jnp.float32)  # (N, E)
-    probs = softmax_f32(router_logits)
-    top_vals, top_idx = jax.lax.top_k(probs, k)  # (N, k)
-    weights = top_vals / jnp.sum(top_vals, axis=-1, keepdims=True)
+    with part("router"):
+        router = lp["router"]
+        router_logits = xb2d.astype(jnp.float32) @ router.astype(jnp.float32)  # (N, E)
+        probs = softmax_f32(router_logits)
+        top_vals, top_idx = jax.lax.top_k(probs, k)  # (N, k)
+        weights = top_vals
+        if cfg.norm_topk_prob:
+            weights = top_vals / jnp.sum(top_vals, axis=-1, keepdims=True)
 
     quant = isinstance(lp["up"], (q40.QTensor, q40.QLayerView))
 
     if n <= 4 and quant:
         # decode, packed experts: per-(token, slot) fused matmuls on the
         # selected expert's packed planes
+        obs_dispatch.record_dispatch("moe", "select", rows=n, experts=e)
         outs = []
         for i in range(n):
             xi = xb2d[i:i + 1]
             acc = jnp.zeros((1, d), jnp.float32)
             for j in range(k):
-                sel = top_idx[i, j]
-                up = lp["up"].select(sel, e)
-                gate = lp["gate"].select(sel, e)
-                down = lp["down"].select(sel, e)
-                h = act(_mm(xi, gate, cfg, kind="row")) * _mm(xi, up, cfg, kind="row")
-                o = q40.mm(h, down, impl=cfg.quant_impl, kind="col",
-                           out_dtype=jnp.float32)
-                acc = acc + weights[i, j] * o
+                with part("experts"):
+                    sel = top_idx[i, j]
+                    up = lp["up"].select(sel, e)
+                    gate = lp["gate"].select(sel, e)
+                    down = lp["down"].select(sel, e)
+                    h = act(_mm(xi, gate, cfg, kind="row")) * _mm(xi, up, cfg, kind="row")
+                    o = q40.mm(h, down, impl=cfg.quant_impl, kind="col",
+                               out_dtype=jnp.float32)
+                with part("combine"):
+                    acc = acc + weights[i, j] * o
             outs.append(acc)
-        return jnp.concatenate(outs, 0).astype(cfg.dtype)
+        with part("combine"):
+            return jnp.concatenate(outs, 0).astype(cfg.dtype)
 
     if n <= 4 and not quant:  # decode path: gather selected experts' weights
-        up_w = jnp.take(lp["up"], top_idx, axis=0)      # (N, k, D, F)
-        gate_w = jnp.take(lp["gate"], top_idx, axis=0)  # (N, k, D, F)
-        down_w = jnp.take(lp["down"], top_idx, axis=0)  # (N, k, F, D)
-        h = act(jnp.einsum("nd,nkdf->nkf", xb2d, gate_w)) * jnp.einsum("nd,nkdf->nkf", xb2d, up_w)
-        out = jnp.einsum("nkf,nkfd->nkd", h, down_w)
-        return jnp.einsum("nk,nkd->nd", weights.astype(out.dtype), out)
+        obs_dispatch.record_dispatch("moe", "select", rows=n, experts=e)
+        with part("experts"):
+            up_w = jnp.take(lp["up"], top_idx, axis=0)      # (N, k, D, F)
+            gate_w = jnp.take(lp["gate"], top_idx, axis=0)  # (N, k, D, F)
+            down_w = jnp.take(lp["down"], top_idx, axis=0)  # (N, k, F, D)
+            h = act(jnp.einsum("nd,nkdf->nkf", xb2d, gate_w)) * jnp.einsum("nd,nkdf->nkf", xb2d, up_w)
+            out = jnp.einsum("nkf,nkfd->nkd", h, down_w)
+        with part("combine"):
+            return jnp.einsum("nk,nkd->nd", weights.astype(out.dtype), out)
 
-    dense_w = jnp.zeros((n, e), weights.dtype)
-    dense_w = jnp.put_along_axis(dense_w, top_idx, weights, axis=-1, inplace=False)
+    with part("router"):
+        dense_w = jnp.zeros((n, e), weights.dtype)
+        dense_w = jnp.put_along_axis(dense_w, top_idx, weights, axis=-1, inplace=False)
 
     if quant:
         # prefill, packed experts: one expert dequantized at a time with a
@@ -368,31 +393,45 @@ def moe_ffn(xb2d: jax.Array, lp, cfg: ModelConfig) -> jax.Array:
         # math; the scan's QLayerView.select simply gets a traced index —
         # exactly how the decode path already selects experts.
         def one_expert(ei):
-            up = lp["up"].select(ei, e)
-            gate = lp["gate"].select(ei, e)
-            down = lp["down"].select(ei, e)
-            h = act(_mm(xb2d, gate, cfg, kind="row")) * _mm(xb2d, up, cfg, kind="row")
-            return q40.mm(h, down, impl=cfg.quant_impl, kind="col",
-                          out_dtype=jnp.float32)
+            with part("experts"):
+                up = lp["up"].select(ei, e)
+                gate = lp["gate"].select(ei, e)
+                down = lp["down"].select(ei, e)
+                h = act(_mm(xb2d, gate, cfg, kind="row")) * _mm(xb2d, up, cfg, kind="row")
+                return q40.mm(h, down, impl=cfg.quant_impl, kind="col",
+                              out_dtype=jnp.float32)
 
-        if e <= MOE_PREFILL_UNROLL_MAX:
+        unrolled = e <= MOE_PREFILL_UNROLL_MAX
+        obs_dispatch.record_dispatch("moe", "unrolled" if unrolled else "scan",
+                                     rows=n, experts=e)
+        if unrolled:
             out = jnp.zeros((n, d), jnp.float32)
             for ei in range(e):
                 oe = one_expert(jnp.int32(ei))
-                out = out + dense_w[:, ei:ei + 1].astype(jnp.float32) * oe
+                with part("combine"):
+                    out = out + dense_w[:, ei:ei + 1].astype(jnp.float32) * oe
         else:
             def body(acc, ei):
-                w_e = jax.lax.dynamic_slice_in_dim(dense_w, ei, 1, axis=1)
-                return acc + w_e.astype(jnp.float32) * one_expert(ei), None
+                with part("combine"):
+                    w_e = jax.lax.dynamic_slice_in_dim(dense_w, ei, 1, axis=1)
+                oe = one_expert(ei)
+                with part("combine"):
+                    return acc + w_e.astype(jnp.float32) * oe, None
 
-            out, _ = jax.lax.scan(body, jnp.zeros((n, d), jnp.float32),
-                                  jnp.arange(e, dtype=jnp.int32))
-        return out.astype(cfg.dtype)
+            # the loop itself (its counter, its carries) is the experts' too
+            with part("experts"):
+                out, _ = jax.lax.scan(body, jnp.zeros((n, d), jnp.float32),
+                                      jnp.arange(e, dtype=jnp.int32))
+        with part("combine"):
+            return out.astype(cfg.dtype)
 
     # prefill path: dense dispatch over all experts
-    h = act(jnp.einsum("nd,edf->nef", xb2d, lp["gate"])) * jnp.einsum("nd,edf->nef", xb2d, lp["up"])
-    outs = jnp.einsum("nef,efd->ned", h, lp["down"])
-    return jnp.einsum("ne,ned->nd", dense_w.astype(outs.dtype), outs)
+    obs_dispatch.record_dispatch("moe", "dense", rows=n, experts=e)
+    with part("experts"):
+        h = act(jnp.einsum("nd,edf->nef", xb2d, lp["gate"])) * jnp.einsum("nd,edf->nef", xb2d, lp["up"])
+        outs = jnp.einsum("nef,efd->ned", h, lp["down"])
+    with part("combine"):
+        return jnp.einsum("ne,ned->nd", dense_w.astype(outs.dtype), outs)
 
 
 def run_blocks(params: Params, cfg: ModelConfig, tokens: jax.Array,

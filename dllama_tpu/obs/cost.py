@@ -449,7 +449,8 @@ def model_from_engine(engine) -> CostModel | None:
                 from ..parallel.mesh import active_mesh
                 with active_mesh(engine.mesh):
                     fused, _ = _attn._fused_choice(
-                        1, cfg.n_heads, cfg.n_kv_heads)
+                        1, cfg.n_heads, cfg.n_kv_heads, cfg.head_size,
+                        bool(engine.cache.quantized))
             except Exception:
                 fused = False
         return CostModel(

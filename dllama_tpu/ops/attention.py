@@ -301,19 +301,30 @@ def paged_decode_attention(q: jax.Array, pool_k: jax.Array, pool_v: jax.Array,
 # ---------------------------------------------------------------------------
 # Fused paged-attention megakernel (one-dispatch decode, ROADMAP item 2).
 #
-# One Pallas program per decode step walks the page table directly via
-# scalar prefetch: grid (B, max_pages), each step's KV block is DMA'd
-# straight out of the pool at ``pool[layer, table[b, p]]`` — no
-# materialized (B, Hkv, maxp·ps, Dh) gather, no separate dequant pass for
-# int8 pools (the per-position scale plane rides as a second prefetched
-# block and the cast*scale happens in-register), and the online-softmax
-# accumulators live in VMEM scratch across the page walk.  Gating mirrors
-# the q40 matmul ladder: ``DLLAMA_FUSED_ATTN`` auto/on/off/interp, auto is
-# a static choice (platform, mesh, shape), and every forced-path fallback
-# goes through the warn-once degrade ledger (obs/dispatch.py).
+# One Pallas program per decode step walks the page table directly: grid
+# (B,), one slot a step, and inside it a loop over the slot's LIVE pages
+# in chunks of ``_WALK_PAGES``.  The pool stays in HBM; a chunk's pages are
+# copied straight out of ``pool[layer, table[b, p]]`` into one of two VMEM
+# buffers, all of a chunk's copies in flight together and the next chunk's
+# behind the fold of this one — no materialized (B, Hkv, maxp·ps, Dh)
+# gather, and the online-softmax state is the loop's carry.  Dead pages
+# cost nothing: the loop's trip count is the slot's own.  Dense pools
+# only: an int8 pool's scale plane (L, P, ps, Hkv, 1) has one lane of 128
+# a row, no page of it can be sliced for a copy, and such a pool reads
+# through the XLA forms below.  The copies are the kernel's own and not a
+# BlockSpec's: a BlockSpec keeps two buffers, so one page's copy in
+# flight, and takes a grid step for every page of the table, live or not
+# (0.46 us a live page and 0.16 us a dead one on a v5e, PERF.md §6 PR 31).
+# Gating mirrors the q40 matmul ladder: ``DLLAMA_FUSED_ATTN``
+# auto/on/off/interp, auto is a static choice (platform, mesh, shape), and
+# every forced-path fallback goes through the warn-once degrade ledger
+# (obs/dispatch.py).
 
 
 _FUSED_ENV = "DLLAMA_FUSED_ATTN"
+# pages of one chunk of the walk: 8 pages of 16 tokens at 16 kv heads are
+# 16 copies of 64 KB in flight and 2 MB of VMEM for the two buffers
+_WALK_PAGES = 8
 
 
 def fused_mode() -> str:
@@ -325,79 +336,94 @@ def fused_mode() -> str:
     return os.environ.get(_FUSED_ENV, "auto").strip().lower() or "auto"
 
 
-def _make_fused_kernel(hq: int, hkv: int, dh: int, ps: int, maxp: int,
-                       quantized: bool, out_dtype):
-    """Build the fused decode kernel body for one (head/page) geometry.
+def _make_fused_kernel(hq: int, hkv: int, dh: int, ps: int, cp: int,
+                       out_dtype):
+    """Build the fused decode kernel body for one (head/page) geometry;
+    ``cp`` is the walk's chunk in pages.
 
     Ref order: 3 scalar-prefetch refs (layer (1,), page table (B, maxp),
-    per-row positions (B,)), then the q block and the page-walk KV blocks
-    (+ scale blocks when quantized), the output block, and the VMEM
-    scratch accumulators (running max, denom, numerator) that persist
-    across the page axis of the grid."""
+    per-row positions (B,)), then the q block, the K and V pools left in
+    HBM, the output block, and the scratch: two chunk buffers per pool and
+    one DMA semaphore per buffer."""
     g = hq // hkv
     inv_sqrt = np.float32(1.0 / math.sqrt(dh))
+    n_keys = cp * ps * hkv
 
-    def kernel(layer_ref, ptab_ref, pos_ref, q_ref, k_ref, v_ref, *rest):
-        del layer_ref, ptab_ref  # consumed by the BlockSpec index maps
-        if quantized:
-            ks_ref, vs_ref, o_ref, m_ref, l_ref, acc_ref = rest
-        else:
-            o_ref, m_ref, l_ref, acc_ref = rest
+    def kernel(layer_ref, ptab_ref, pos_ref, q_ref, *rest):
         from jax.experimental import pallas as plx
+        from jax.experimental.pallas import tpu as pltpu
+        pools, o_ref, bufs, sem = rest[:2], rest[2], rest[3:5], rest[5]
         b = plx.program_id(0)
-        p = plx.program_id(1)
         pos = pos_ref[b]
+        layer = layer_ref[0]
+        last = pos // ps                 # the row's last live page
+        n_chunks = last // cp + 1
 
-        @plx.when(p == 0)
-        def _init():
-            m_ref[...] = jnp.full(m_ref.shape, _NEG, jnp.float32)
-            l_ref[...] = jnp.zeros(l_ref.shape, jnp.float32)
-            acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
+        def start(c, slot):
+            # the last chunk's pages past ``last`` read the last live page
+            # again: their keys are masked, and a buffer never holds bytes
+            # that were not a page's
+            for i in range(cp):
+                page = ptab_ref[b, jnp.minimum(c * cp + i, last)]
+                for pool, buf in zip(pools, bufs):
+                    pltpu.make_async_copy(pool.at[layer, page],
+                                          buf.at[slot, i],
+                                          sem.at[slot]).start()
 
-        # pages past the row's live prefix are skipped entirely (their
-        # BlockSpec index map also clamps to the last live page, so the
-        # prefetch pipeline issues no new DMA for them)
-        @plx.when(p <= pos // ps)
-        def _fold():
-            k = k_ref[0, 0]  # (ps, Hkv, Dh): the pool's token-major page
-            v = v_ref[0, 0]
-            if quantized:
-                # in-register dequant: int8 page block × per-position
-                # scale column → bf16 dot operands (dequant_kv semantics,
-                # without the materialized intermediate)
-                k = (k.astype(jnp.float32) * ks_ref[0, 0]).astype(jnp.bfloat16)
-                v = (v.astype(jnp.float32) * vs_ref[0, 0]).astype(jnp.bfloat16)
-            # head-major in VMEM: the dots below batch over the kv-head axis
-            k = jnp.swapaxes(k, 0, 1)  # (Hkv, ps, Dh)
-            v = jnp.swapaxes(v, 0, 1)
-            qb = q_ref[0].reshape(hkv, g, dh).astype(k.dtype)
-            # (Hkv, G, ps): score dot batched over the kv-head axis, f32
-            # accumulation like _online_fold
-            scores = jax.lax.dot_general(
-                qb, k, (((2,), (2,)), ((0,), (0,))),
+        def wait(slot):
+            for i in range(cp):
+                for pool, buf in zip(pools, bufs):
+                    pltpu.make_async_copy(pool.at[0, 0], buf.at[slot, i],
+                                          sem.at[slot]).wait()
+
+        qb = q_ref[0]                                   # (Hq, Dh)
+        row = jax.lax.broadcasted_iota(jnp.int32, (hq, n_keys), 0)
+        col = jax.lax.broadcasted_iota(jnp.int32, (hq, n_keys), 1)
+        own = jax.lax.rem(col, hkv) == jax.lax.div(row, g)
+        tok = jax.lax.div(col, hkv)      # a key's token, within its chunk
+
+        def fold(c, carry):
+            m_prev, l_prev, acc = carry
+            slot = jax.lax.rem(c, 2)
+
+            @plx.when(c + 1 < n_chunks)
+            def _next():
+                start(c + 1, 1 - slot)
+
+            wait(slot)
+            k = bufs[0][slot]    # (cp, ps, Hkv, Dh): token-major pages
+            v = bufs[1][slot]
+            # the pages stay token-major: a chunk's (cp, ps, Hkv) rows are
+            # one operand of n_keys keys, every query head is scored
+            # against all of them in ONE dot, and a head keeps only the
+            # columns of its own kv head.  The masked columns weigh
+            # exactly 0 in the second dot, so the result is the per-head
+            # read; the MXU does Hkv times the useful work, which at one
+            # query row is nothing beside a relayout of every page to
+            # head-major and Hkv dots of one row each
+            kf = k.reshape(n_keys, dh)
+            vf = v.reshape(n_keys, dh)
+            sc = jax.lax.dot_general(
+                qb.astype(kf.dtype), kf, (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32) * inv_sqrt
-            s_idx = p * ps + jax.lax.broadcasted_iota(
-                jnp.int32, scores.shape, 2)
-            scores = jnp.where(s_idx <= pos, scores, _NEG)
-            sc = scores.reshape(hq, ps)
-            m_prev = m_ref[:, 0:1]                      # (Hq, 1)
-            l_prev = l_ref[:, 0:1]
+            keep = own & (c * (cp * ps) + tok <= pos)
+            sc = jnp.where(keep, sc, _NEG)              # (Hq, n_keys)
             m_new = jnp.maximum(m_prev, jnp.max(sc, axis=1, keepdims=True))
             alpha = jnp.exp(m_prev - m_new)
-            pexp = jnp.exp(sc - m_new)                  # (Hq, ps)
+            pexp = jnp.where(keep, jnp.exp(sc - m_new), 0.0)
             l_new = alpha * l_prev + jnp.sum(pexp, axis=1, keepdims=True)
             pv = jax.lax.dot_general(
-                pexp.reshape(hkv, g, ps).astype(v.dtype), v,
-                (((2,), (1,)), ((0,), (0,))),
-                preferred_element_type=jnp.float32)     # (Hkv, G, Dh)
-            acc_ref[...] = alpha * acc_ref[...] + pv.reshape(hq, dh)
-            m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
-            l_ref[...] = jnp.broadcast_to(l_new, l_ref.shape)
+                pexp.astype(vf.dtype), vf, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)     # (Hq, Dh)
+            return m_new, l_new, alpha * acc + pv
 
-        @plx.when(p == maxp - 1)
-        def _emit():
-            l = jnp.maximum(l_ref[:, 0:1], 1e-38)
-            o_ref[0] = (acc_ref[...] / l).astype(out_dtype)
+        start(0, 0)
+        _, l, acc = jax.lax.fori_loop(
+            0, n_chunks, fold,
+            (jnp.full((hq, 1), _NEG, jnp.float32),
+             jnp.zeros((hq, 1), jnp.float32),
+             jnp.zeros((hq, dh), jnp.float32)))
+        o_ref[0] = (acc / jnp.maximum(l, 1e-38)).astype(out_dtype)
 
     return kernel
 
@@ -405,13 +431,11 @@ def _make_fused_kernel(hq: int, hkv: int, dh: int, ps: int, maxp: int,
 def fused_paged_attention(q: jax.Array, pool_k: jax.Array, pool_v: jax.Array,
                           layer: jax.Array, page_table: jax.Array,
                           pos_rows: jax.Array,
-                          scales: tuple[jax.Array, jax.Array] | None = None,
                           *, interpret: bool = False) -> jax.Array:
-    """Single-token paged GQA as ONE kernel: page-table walk, (optional)
-    in-register int8 dequant, and online-softmax fold in a single
-    pallas_call.  Numerics mirror :func:`paged_decode_attention`'s fold
-    (same operand dtypes, f32 accumulation, ``_NEG`` mask fill); rows
-    whose table runs out read their last live page again, fully masked.
+    """Single-token paged GQA over a dense pool as ONE kernel: page-table
+    walk and online-softmax fold in a single pallas_call.  Numerics mirror :func:`paged_decode_attention`'s fold
+    (same operand dtypes, f32 accumulation, ``_NEG`` mask fill); traffic
+    is the live pages of each row, rounded up to the walk's chunk.
     """
     from jax.experimental import pallas as plx
     from jax.experimental.pallas import tpu as pltpu
@@ -420,61 +444,53 @@ def fused_paged_attention(q: jax.Array, pool_k: jax.Array, pool_v: jax.Array,
     if t != 1:
         raise ValueError("fused paged attention is decode-only (T must be 1)")
     ps, hkv = pool_k.shape[2], pool_k.shape[3]
-    maxp = page_table.shape[1]
-    quantized = scales is not None
+    cp = min(_WALK_PAGES, page_table.shape[1])
+    pools = [pool_k, pool_v]
 
-    def walk_map(bi, pi, layer_r, ptab_r, pos_r):
-        # dead pages revisit the row's last live page: consecutive equal
-        # block indices skip the DMA, so traffic stays O(live pages)
-        pp = jnp.minimum(pi, pos_r[bi] // ps)
-        return (layer_r[0], ptab_r[bi, pp], 0, 0, 0)
-
-    def row_map(bi, pi, *_):
+    def row_map(bi, *_):
         return (bi, 0, 0)
 
-    kv_spec = plx.BlockSpec((1, 1, ps, hkv, dh), walk_map)
-    in_specs = [plx.BlockSpec((1, hq, dh), row_map), kv_spec, kv_spec]
-    operands = [q[:, :, 0, :], pool_k, pool_v]
-    if quantized:
-        sc_spec = plx.BlockSpec((1, 1, ps, hkv, 1), walk_map)
-        in_specs += [sc_spec, sc_spec]
-        operands += [scales[0], scales[1]]
-    kernel = _make_fused_kernel(hq, hkv, dh, ps, maxp, quantized, q.dtype)
+    hbm = plx.BlockSpec(memory_space=plx.ANY)
+    kernel = _make_fused_kernel(hq, hkv, dh, ps, cp, q.dtype)
     out = plx.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
-            grid=(b, maxp),
-            in_specs=in_specs,
+            grid=(b,),
+            in_specs=[plx.BlockSpec((1, hq, dh), row_map)]
+            + [hbm] * len(pools),
             out_specs=plx.BlockSpec((1, hq, dh), row_map),
-            scratch_shapes=[pltpu.VMEM((hq, 128), jnp.float32),
-                            pltpu.VMEM((hq, 128), jnp.float32),
-                            pltpu.VMEM((hq, dh), jnp.float32)]),
+            scratch_shapes=[pltpu.VMEM((2, cp, *pool.shape[2:]), pool.dtype)
+                            for pool in pools]
+            + [pltpu.SemaphoreType.DMA((2,))]),
         out_shape=jax.ShapeDtypeStruct((b, hq, dh), q.dtype),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary", "arbitrary")),
+            dimension_semantics=("arbitrary",)),
         interpret=interpret,
         name="paged_attn_fused",
     )(jnp.atleast_1d(layer).astype(jnp.int32),
-      page_table.astype(jnp.int32), pos_rows.astype(jnp.int32), *operands)
+      page_table.astype(jnp.int32), pos_rows.astype(jnp.int32),
+      q[:, :, 0, :], *pools)
     return out[:, :, None, :]
 
 
-def _fused_choice(t: int, hq: int, hkv: int) -> tuple[bool, bool]:
+def _fused_choice(t: int, hq: int, hkv: int, dh: int = 128,
+                  quantized: bool = False) -> tuple[bool, bool]:
     """Resolve the fused-vs-fallback decision for one call site from
-    static facts only (mode, platform, mesh, head counts), so it is the same
-    inside and outside a jit trace.  Returns ``(use_fused, interpret)``.
-    On a single TPU device ``auto``/``on`` mean the fused kernel; nothing
-    is executed to decide, so a Mosaic lowering or runtime error
-    propagates and fails the run (values are checked on the chip by
-    chip_smoke.py).  Every KV/q/scale block spans the full last two dims
-    of its array, so no page size, head size or codec is tile-illegal.  A
-    ``pallas_call`` is not partitioned by GSPMD, so on a multi-device
-    mesh the TPU path stays the gather form.  ``auto`` off-TPU falls
+    static facts only (mode, platform, mesh, head counts and size, the
+    pool's codec), so it is the same inside and outside a jit trace.
+    Returns ``(use_fused, interpret)``.  On a single TPU device
+    ``auto``/``on`` mean the fused kernel for a dense pool whose heads
+    fill whole lanes (a page is copied as it lies, and a copy of part of
+    a 128-lane row is refused); nothing is executed to decide, so a
+    Mosaic lowering or runtime error propagates and fails the run (values
+    are checked on the chip by chip_smoke.py).  A ``pallas_call`` is not
+    partitioned by GSPMD, so on a multi-device mesh the TPU path stays
+    the gather form.  ``auto`` off-TPU falls
     back silently (the clean-run ledger contract); ``on`` where the
     kernel cannot run degrades loudly (warn-once)."""
     mode = fused_mode()
-    if mode == "off" or t != 1 or hq % hkv != 0:
+    if mode == "off" or t != 1 or hq % hkv != 0 or quantized:
         return False, False
     if mode == "interp":
         return True, True
@@ -482,7 +498,7 @@ def _fused_choice(t: int, hq: int, hkv: int) -> tuple[bool, bool]:
     mesh = get_active_mesh()
     n_dev = mesh.size if mesh is not None else 1
     if backend == "tpu" and n_dev == 1:
-        return True, False
+        return dh % 128 == 0, False
     if mode == "on":
         from ..obs import dispatch as obs_dispatch
         obs_dispatch.record_degrade(
@@ -498,8 +514,8 @@ def paged_gqa_attention_at(q: jax.Array, pool_k: jax.Array, pool_v: jax.Array,
                            ) -> jax.Array:
     """Causal GQA read through the page-table indirection at ``layer``,
     with the slot path's per-row causal ceiling.  Single-token decode
-    prefers the fused page-walk megakernel (:func:`fused_paged_attention`
-    — one dispatch, no materialized gather, in-register int8 dequant)
+    over a dense pool prefers the fused page-walk megakernel
+    (:func:`fused_paged_attention` — one dispatch, no materialized gather)
     when the ``DLLAMA_FUSED_ATTN`` ladder resolves to it; otherwise
     dispatch mirrors the contiguous path: long-cache single-token decode
     walks live pages (:func:`paged_decode_attention`, O(max pos)
@@ -520,13 +536,13 @@ def paged_gqa_attention_at(q: jax.Array, pool_k: jax.Array, pool_v: jax.Array,
     ps, hkv = pool_k.shape[2], pool_k.shape[3]
     s = page_table.shape[1] * ps
     codec = "kv_int8" if scales is not None else "kv_dense"
-    use_fused, interp = _fused_choice(t, q.shape[1], hkv)
+    use_fused, interp = _fused_choice(t, q.shape[1], hkv, q.shape[3],
+                                      scales is not None)
     if use_fused:
         obs_dispatch.record_dispatch(codec, "paged-fused", t=t, s=s,
                                      page_size=ps, interpret=interp)
         return fused_paged_attention(q, pool_k, pool_v, layer, page_table,
-                                     pos_rows, scales=scales,
-                                     interpret=interp)
+                                     pos_rows, interpret=interp)
     if t == 1 and _use_live_walk(q.shape[1] // hkv, t, s):
         obs_dispatch.record_dispatch(codec, "paged-decode", t=t, s=s,
                                      page_size=ps)

@@ -12,6 +12,15 @@ its family by the kernel's ``name=`` (``q40_mm``, ``q40_mm_stacked``,
 The tuple is an interface: the benchmark's readers
 (``benchmarks/layer_metrics/_scopes.py``) hold a copy and a test compares
 the two.  An op whose name path carries none of these is ``unscoped``.
+
+Inside ``moe`` the work is split further by :func:`part` into ``router``,
+``experts`` and ``combine`` (``PARTS``): plain sub-names, not scopes.  An
+op's path then ends ``.../moe/experts/...`` and a reader that knows only
+``SCOPES`` still files it under ``moe``; ``by-scope.json``'s op table
+carries the whole path for the finer split.  Which strategy a compiled call
+site of ``moe_ffn`` took is in the dispatch ledger, not in a name:
+``dllama_matmul_dispatch_total{codec="moe", path="select"|"scan"|
+"unrolled"|"dense"}`` (docs/OBSERVABILITY.md).
 """
 
 from __future__ import annotations
@@ -35,6 +44,21 @@ SCOPES = (
     "head",      # last-position gather, output matmul, logit scale
     "sample",    # device sampler, key split, token feedback of a burst
 )
+
+
+PARTS = (
+    "router",    # router logits, softmax, top-k, the dense weight table
+    "experts",   # the expert matmuls of every strategy, a scan's bookkeeping
+    "combine",   # the weighted sum and the cast back to the activation dtype
+)
+
+
+def part(name: str):
+    """``jax.named_scope(name)`` for a sub-name of :data:`PARTS` (inside
+    scope ``moe``)."""
+    if name not in PARTS:
+        raise ValueError(f"{name!r} is not a part of {PARTS}")
+    return jax.named_scope(name)
 
 
 def scope(name: str):

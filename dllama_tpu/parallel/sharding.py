@@ -82,6 +82,10 @@ def param_specs(cfg: ModelConfig) -> dict[str, P]:
         "rms_final": REPL,
         "wcls": P(None, "tp"),               # vocab-sharded logits; gathered on host fetch
     }
+    if cfg.qk_norm:
+        # whole-projection norms: replicated, like every norm vector; the
+        # mean over a tp-sharded q or k is GSPMD's all-reduce
+        specs.update({"q_norm": REPL, "k_norm": REPL})
     if cfg.is_moe:
         specs.update({
             "router": REPL,                  # root-computed in the reference (grok1-tasks.cpp:59)

@@ -30,8 +30,12 @@ Mechanics per dispatch:
   pipeline: while dispatch N's tokens land and fan out host-side,
   dispatch N+1 is already enqueued on device, fed by N's on-device
   last-token row (``Engine.slot_step_async``'s ``feed_dev`` — no
-  device→host→device round trip).  Every *flush point* — a queued
-  ticket awaiting admission, slot retire, cancel/deadline,
+  device→host→device round trip).  A queued ticket stops the pipeline
+  only when it can be served at the next boundary (a free slot, a row
+  whose budget runs out in the dispatch in flight, an eviction it may
+  ask for, or its own cancel or deadline): behind a full house it
+  waits for a slot either way, and the steps it waits through stay
+  pipelined.  Every *flush point* — slot retire, cancel/deadline,
   ``exclusive()`` parking, hand-off export/import, drain — falls back
   to synchronous dispatch: the pipelined dispatch is landed and
   discarded, its KV writes sit above every surviving row's position
@@ -1845,13 +1849,36 @@ class SlotScheduler:
                         proposed_by_slot={i: len(d)
                                           for i, d in props.items()})
 
+    def _queue_must_wait_locked(self, cur: _Pending, now: float) -> bool:
+        """True when nothing queued can be served at ``cur``'s boundary,
+        so the queue is no reason to stop pipelining: every slot holds a
+        ticket of ``cur`` (a budget that runs out in ``cur`` is the
+        caller's check; an EOS frees its slot one dispatch late, and the
+        in-flight dispatch is kept, not discarded), no queued ticket is
+        cancelled or past its deadline (the round head owes it its
+        error), and none may evict a running one (``_preempt_for_locked``'s
+        rule).  Caller holds ``_cond``."""
+        running = [s.ticket for s in self.slots]
+        if any(t is None or t is not cur.tickets.get(j)
+               for j, t in enumerate(running)):
+            return False
+        for q in self._queue:
+            if q._cancel is not None or (q.deadline is not None
+                                         and now >= q.deadline):
+                return False
+            if self.preempt and self.pool is not None and any(
+                    t.priority > q.priority for t in running):
+                return False
+        return True
+
     def _maybe_pipeline(self, cur: _Pending) -> _Pending | None:
         """While ``cur`` is still in flight, speculate on the next burst:
         enqueue the next pure-decode dispatch fed by ``cur``'s on-device
         last-token row.  ("Speculate" here is dispatch pipelining — a
         guess that no flush point interrupts the round — not token
         speculation; that is the ``spec`` proposer's job.)  Returns None
-        at any pipeline flush point — queued admission pending, drain /
+        at any pipeline flush point — a queued ticket that the next
+        boundary can serve (:meth:`_queue_must_wait_locked`), drain /
         pause / flush request, cancel or expired deadline, a row still
         mid-prefill after ``cur``, a hand-off import, no context room —
         and the round then completes synchronously."""
@@ -1867,9 +1894,12 @@ class SlotScheduler:
         b = eng.batch
         with self._cond:
             if (self._stop or self._draining or self._paused
-                    or self._flush_req or self._queue or self._parked):
+                    or self._flush_req or self._parked):
                 return None
             now = time.monotonic()
+            queued = len(self._queue)
+            if queued and not self._queue_must_wait_locked(cur, now):
+                return None
             pos2 = np.zeros((b,), np.int32)
             budget = 0
             for j in range(b):
@@ -1889,7 +1919,10 @@ class SlotScheduler:
                     return None       # still mid-prefill after cur
                 pos2[j] = s.pos + nv + (cur.steps - 1)
                 made = 1 if j in cur.prefset else cur.steps
-                budget = max(budget, t.max_new - (s.produced + made))
+                left = t.max_new - (s.produced + made)
+                if queued and left < 1:
+                    return None       # its slot frees when cur lands
+                budget = max(budget, left)
             if budget < 1:
                 # every row hits its token budget during ``cur``: unlike
                 # the sync path (which only learns a row retired after
@@ -1906,6 +1939,12 @@ class SlotScheduler:
             # overlap on/off A/B compares dispatch pipelining alone
             steps2 = max(1, min(self.decode_burst, room))
             steps2 = 1 << (steps2.bit_length() - 1)
+            if queued:
+                # a burst amortizes the host gap, and a pipelined
+                # dispatch has none: single steps keep a stream's tokens
+                # evenly spaced and the first slot to free one step from
+                # its admission boundary
+                steps2 = 1
             if self.paged and self.optimistic:
                 # pipelined chains are unbounded per round (cur = nxt
                 # loops), so the round-start grow cannot cover them:
@@ -1958,7 +1997,7 @@ class SlotScheduler:
                         rid_by_slot=dict(cur.rid_by_slot), fed_by_slot={},
                         pos_rows=pos2, enq_tp=time.perf_counter(),
                         seq=self._n_enqueued, host_gap_ms=0.0,
-                        idle_ms=0.0, overlapped=True, queued=0)
+                        idle_ms=0.0, overlapped=True, queued=queued)
 
     def _attribute_cost(self, cur: _Pending, wall_ms: float) -> None:
         """Analytic roofline attribution for one landed dispatch
@@ -2186,8 +2225,12 @@ class SlotScheduler:
         cheaper than flushing the whole pipeline."""
         slots = self.slots
         with self._cond:
+            # a queued ticket is not among these: ``nxt`` is on the device
+            # either way, admission waits for it to land whether its
+            # tokens are kept or not, and _maybe_pipeline enqueues nothing
+            # further once the queue can be served
             if (self._stop or self._draining or self._paused
-                    or self._flush_req or self._queue or self._parked):
+                    or self._flush_req or self._parked):
                 return None
             now = time.monotonic()
             survivors = []

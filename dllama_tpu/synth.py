@@ -51,6 +51,17 @@ MODEL_SHAPES = {
     "tinyllama-1.1b": dict(dim=2048, hidden_dim=5632, n_layers=22,
                            n_heads=32, n_kv_heads=4, vocab_size=32000,
                            seq_len=2048, dtype="bfloat16"),
+    # the first mixture-of-experts model run on the chip (benchmarks/configs/
+    # olmoe-1b-7b.json has the published keys): 64 experts of 1024, 8 a
+    # token, MHA 16 x 128; "arch" is a name of mfile.ARCH_NAMES
+    "olmoe-1b-7b": dict(arch="olmoe", dim=2048, hidden_dim=1024, n_layers=16,
+                        n_heads=16, n_kv_heads=16, n_experts=64,
+                        n_active_experts=8, vocab_size=50304, seq_len=4096,
+                        dtype="bfloat16"),
+    "cpu-tiny-olmoe": dict(arch="olmoe", dim=256, hidden_dim=128, n_layers=2,
+                           n_heads=8, n_kv_heads=8, n_experts=64,
+                           n_active_experts=8, vocab_size=4096, seq_len=256,
+                           dtype="float32"),
     "cpu-tiny": dict(dim=512, hidden_dim=1408, n_layers=4, n_heads=8,
                      n_kv_heads=8, vocab_size=4096, seq_len=256,
                      dtype="float32"),
@@ -63,13 +74,18 @@ def model_shape(name: str) -> dict:
     return dict(MODEL_SHAPES[name])
 
 
+def _arch_id(shape: dict) -> int:
+    return {v: k for k, v in mfile.ARCH_NAMES.items()}[shape.get("arch", "llama")]
+
+
 def model_cfg(name: str):
     """The runtime's ``ModelConfig`` for a named shape (imports JAX)."""
     import jax.numpy as jnp
 
     from .models.config import tiny_config
     shape = model_shape(name)
-    return tiny_config(**dict(shape, dtype=getattr(jnp, shape["dtype"])))
+    return tiny_config(**dict(shape, dtype=getattr(jnp, shape["dtype"]),
+                              arch=_arch_id(shape)))
 
 
 def write_synth_tokenizer(path, vocab_size=300) -> tfile.TokenizerData:
@@ -106,9 +122,10 @@ def synth_model_files(name: str, dirpath: str, n_layers: int | None = None,
     if n_layers is not None:
         shape["n_layers"] = n_layers
     spec = mfile.ModelSpec(
-        arch=mfile.ARCH_LLAMA, dim=shape["dim"], hidden_dim=shape["hidden_dim"],
+        arch=_arch_id(shape), dim=shape["dim"], hidden_dim=shape["hidden_dim"],
         n_layers=shape["n_layers"], n_heads=shape["n_heads"],
-        n_kv_heads=shape["n_kv_heads"], n_experts=0, n_active_experts=0,
+        n_kv_heads=shape["n_kv_heads"], n_experts=shape.get("n_experts", 0),
+        n_active_experts=shape.get("n_active_experts", 0),
         vocab_size=shape["vocab_size"], seq_len=shape["seq_len"],
         hidden_act=mfile.ACT_SILU,
         rope_theta=shape.get("rope_theta", 10000.0),

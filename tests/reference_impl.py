@@ -54,15 +54,18 @@ def rope_rotate(x, pos, theta, interleaved):
     return out.astype(np.float32)
 
 
-def moe(xb, router, up, gate, down, n_active, act):
+def moe(xb, router, up, gate, down, n_active, act, norm_topk_prob=True):
     """xb: (T, D). Reference routing: softmax over all experts, top-k,
-    renormalize (grok1-tasks.cpp:60-114)."""
+    renormalize (grok1-tasks.cpp:60-114); OLMoE (``norm_topk_prob`` false)
+    uses the chosen probabilities as they are."""
     t, d = xb.shape
     probs = softmax(xb @ router)  # (T, E)
     out = np.zeros_like(xb)
     for i in range(t):
         idx = np.argsort(-probs[i], kind="stable")[:n_active]
-        w = probs[i, idx] / probs[i, idx].sum()
+        w = probs[i, idx]
+        if norm_topk_prob:
+            w = w / w.sum()
         for j, e in enumerate(idx):
             h = act(xb[i] @ gate[e]) * (xb[i] @ up[e])
             out[i] += w[j] * (h @ down[e])
@@ -84,8 +87,11 @@ def np_forward(params, cfg, tokens):
         lp = {k: np.asarray(v[li]) for k, v in params.items()
               if k not in ("embedding", "rms_final", "wcls")}
         xb = rmsnorm(x, lp["rms_att"])
-        q = (xb @ lp["wq"]).reshape(t, hq, dh)
-        k = (xb @ lp["wk"]).reshape(t, hkv, dh)
+        q, k = xb @ lp["wq"], xb @ lp["wk"]
+        if cfg.qk_norm:  # OLMoE: over the whole projection, before the heads
+            q, k = rmsnorm(q, lp["q_norm"]), rmsnorm(k, lp["k_norm"])
+        q = q.reshape(t, hq, dh)
+        k = k.reshape(t, hkv, dh)
         v = (xb @ lp["wv"]).reshape(t, hkv, dh)
         q = rope_rotate(q, pos, cfg.rope_theta, cfg.rope_interleaved)
         k = rope_rotate(k, pos, cfg.rope_theta, cfg.rope_interleaved)
@@ -108,7 +114,7 @@ def np_forward(params, cfg, tokens):
             pre = lp["rms_moe"] if cfg.post_block_norms else lp["rms_ffn"]
             xb = rmsnorm(x, pre)
             ff = moe(xb, lp["router"], lp["up"], lp["gate"], lp["down"],
-                     cfg.n_active_experts, act)
+                     cfg.n_active_experts, act, cfg.norm_topk_prob)
             if cfg.post_block_norms:
                 ff = rmsnorm(ff, lp["rms_ffn2"])
         else:

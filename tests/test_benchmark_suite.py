@@ -3,7 +3,10 @@
 ``pytest tests/`` never collected that directory, so a broken reader, trace
 reducer or load generator was invisible to tier-1.  This module loads those
 test modules and re-exports their test functions and fixtures, so that they
-are collected, run and counted here.  They are JAX-free and take seconds.
+are collected, run and counted here.  Most are JAX-free and take seconds;
+``test_models_program`` and ``test_models_olmoe`` import JAX and
+``dllama_tpu`` (the architecture modules against the program's engine, at toy
+widths) and take about a minute together.
 
 The benchmark's ``conftest.py`` puts ``benchmarks/`` and
 ``benchmarks/layer_metrics/`` on ``sys.path``, and its test modules import
@@ -20,7 +23,8 @@ from _pytest.fixtures import FixtureFunctionDefinition
 
 _BENCH_TESTS = os.path.join(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))), "benchmarks", "tests")
-MODULES = ("test_loadgen", "test_manifest", "test_tp_readers", "test_traffic",
+MODULES = ("test_loadgen", "test_manifest", "test_models", "test_models_olmoe",
+           "test_models_program", "test_tp_readers", "test_traffic",
            "test_xmeta", "test_xplane")
 
 

@@ -101,3 +101,26 @@ def test_rehearse_tp_phase_on_virtual_devices(rehearsal_env, capfd):
     cmp_ = next(r for r in rows if r.get("what") == "compare")
     assert cmp_["weight_devices"] == 4 and cmp_["cache_devices"] == 4
     assert cmp_["tokens_tp"] == cmp_["tokens_tp1"]
+
+
+def test_rehearse_moe_phases(rehearsal_env, capfd):
+    """The mixture-of-experts pass: a seeded OLMoE-shaped file (64 experts, 8
+    a token, toy widths) through the loader, ``moe_ffn``'s select and scan
+    strategies against the XLA path, then the paged server on that file."""
+    from dllama_tpu.io import mfile
+    from dllama_tpu.synth import synth_model_files
+
+    _, _, tmp = rehearsal_env
+    m, t = synth_model_files("cpu-tiny-olmoe", tmp)
+    spec = mfile.MFile(m).spec
+    assert (spec.arch, spec.n_experts, spec.n_active_experts) == \
+        (mfile.ARCH_OLMOE, 64, 8)
+    chip_smoke.phase_moe(m, 600, rehearse=True)
+    rows = [json.loads(ln) for ln in capfd.readouterr().out.splitlines()]
+    errs = {r["strategy"]: r for r in rows if "rel_err" in r}
+    assert set(errs) == {"select", "scan"}
+    assert all(r["rel_err"] <= r["tol"] for r in errs.values())
+    res = chip_smoke.phase_server(m, t, 600, tmp, slots=2, ctx=64, page=4,
+                                  max_tokens=8, rehearse=True)
+    assert res["drain_rc"] == 0 and res["greedy_replay_identical"]
+    assert res["dispatch"].get("moe/scan", 0) > 0

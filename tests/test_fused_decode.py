@@ -37,6 +37,7 @@ import jax.numpy as jnp
 from dllama_tpu.models.config import tiny_config
 from dllama_tpu.models.params import init_params
 from dllama_tpu.ops.attention import (_rows_ceiling_attention, dequant_kv,
+                                      paged_decode_attention,
                                       fused_paged_attention,
                                       paged_gather_layer, quantize_kv)
 from dllama_tpu.parallel.mesh import make_mesh
@@ -104,8 +105,12 @@ def test_fused_kernel_matches_gather_reference(quantized, hkv, ps):
         quantized, hkv, ps)
     assert pk.shape[2:4] == (ps, hkv)
     layer = jnp.int32(1)
-    out = fused_paged_attention(q, pk, pv, layer, table, pos_rows,
-                                scales=scales, interpret=True)
+    # an int8 pool is not the kernel's (its scale plane cannot be copied by
+    # the page): its read is the XLA live walk
+    out = paged_decode_attention(q, pk, pv, layer, table, pos_rows,
+                                 scales=scales) if quantized else \
+        fused_paged_attention(q, pk, pv, layer, table, pos_rows,
+                              interpret=True)
     ks, vs = scales if scales is not None else (None, None)
     k_l = paged_gather_layer(pk, layer, table, scale_pool=ks)
     v_l = paged_gather_layer(pv, layer, table, scale_pool=vs)
@@ -130,10 +135,12 @@ def test_fused_choice_is_static_and_raises_on_tpu(monkeypatch, mode):
     from dllama_tpu.ops import attention as att
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     monkeypatch.setenv("DLLAMA_FUSED_ATTN", mode)
-    q, pk, pv, table, pos_rows, _, _ = _pool_fixture(False)
+    q, pk, pv, table, pos_rows, _, _ = _pool_fixture(False, dh=128)
     assert att._fused_choice(1, 4, 2) == (True, False)
     assert att._fused_choice(2, 4, 2) == (False, False)  # t > 1
     assert att._fused_choice(1, 3, 2) == (False, False)  # hq % hkv
+    assert att._fused_choice(1, 4, 2, 64) == (False, False)  # half a lane row
+    assert att._fused_choice(1, 4, 2, 128, True) == (False, False)  # int8 pool
 
     def read(qv):
         return att.paged_gqa_attention_at(qv, pk, pv, jnp.int32(0), table,

@@ -33,6 +33,7 @@ CFGS = {
     "mixtral": tiny_config(arch=mfile.ARCH_MIXTRAL, n_experts=4, n_active_experts=2),
     "grok1": tiny_config(arch=mfile.ARCH_GROK1, n_experts=4, n_active_experts=2,
                          hidden_act=mfile.ACT_GELU),
+    "olmoe": tiny_config(arch=mfile.ARCH_OLMOE, n_experts=8, n_active_experts=3),
 }
 
 
@@ -46,7 +47,7 @@ def test_forward_matches_numpy_oracle(name):
     np.testing.assert_allclose(got, want, atol=2e-4, rtol=1e-3)
 
 
-@pytest.mark.parametrize("name", ["llama", "mixtral", "grok1"])
+@pytest.mark.parametrize("name", ["llama", "mixtral", "grok1", "olmoe"])
 def test_decode_matches_prefill(name):
     """Token-at-a-time decode through the KV cache must reproduce the
     full-sequence forward — the autoregression-correctness property."""

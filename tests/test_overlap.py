@@ -445,3 +445,72 @@ def test_parked_wakeups_bounded_and_deadline_accurate():
             assert time.monotonic() - t0 < 0.6
     finally:
         sched.close()
+
+
+# -- pipelining behind a queue that cannot be served -------------------------
+
+def test_pipeline_runs_behind_a_full_house(solo_refs):
+    """Two slots, four requests: while both slots decode and two tickets
+    wait, no boundary can admit anyone, so decode stays pipelined, in
+    single steps; the queued tickets are admitted when a budget runs out,
+    and every output is the solo run's whatever the overlap setting."""
+    outs, seen = {}, []
+    for overlap in (False, True):
+        sched = SlotScheduler(make_engine(2), prefill_chunk=4,
+                              max_wait_ms=50.0, decode_burst=6,
+                              overlap=overlap)
+        inner = sched._maybe_pipeline
+
+        def spy(cur, inner=inner, sched=sched):
+            queued = len(sched._queue)
+            nxt = inner(cur)
+            seen.append((queued, nxt is not None and nxt.queued > 0,
+                         nxt.steps if nxt is not None else 0))
+            return nxt
+
+        sched._maybe_pipeline = spy
+        try:
+            with injected("engine.device_step=delay:0.01x100000"):
+                tickets = [sched.submit(p, n) for p, n in
+                           zip(PROMPTS, (14, 9, 12, 10))]
+                outs[overlap] = [(list(t.tokens()), t.finish)
+                                 for t in tickets]
+            sched.flush()
+            assert sched._inflight_n == 0 and sched._depth == 0
+        finally:
+            sched.close()
+    assert outs[True] == outs[False]
+    for p, n, (got, finish) in zip(PROMPTS, (14, 9, 12, 10), outs[True]):
+        assert got == solo_refs[tuple(p)][:n], p
+        assert finish == "length"
+    behind = [s for s in seen if s[1]]
+    assert behind, "decode never pipelined behind the waiting tickets"
+    assert {s[2] for s in behind} == {1}, "a queued pipeline step is single"
+
+
+@pytest.mark.parametrize("case,expect", [
+    ("full", True), ("free_slot", False), ("queued_cancelled", False),
+    ("queued_expired", False), ("may_evict", False), ("evict_off", True)])
+def test_queue_must_wait_rule(case, expect):
+    """The queue stops the pipeline exactly when the next boundary could
+    serve it: a free slot, a queued ticket owed its cancel or timeout, or
+    one that may evict a running ticket of a lower class."""
+    from types import SimpleNamespace
+    from dllama_tpu.runtime.scheduler import Ticket
+    sched = SlotScheduler(make_paged_engine(), prefill_chunk=4,
+                          preempt=case != "evict_off")
+    sched.close()
+    mk = lambda prio=1, deadline=None: Ticket(  # noqa: E731
+        [1, 2], 8, 0.0, 0.9, (), deadline, priority=prio)
+    running = [mk(2 if case in ("may_evict", "evict_off") else 1), mk()]
+    for s, t in zip(sched.slots, running):
+        s.ticket = t
+    if case == "free_slot":
+        sched.slots[1].ticket = None
+    q = mk(1, time.monotonic() - 1 if case == "queued_expired" else None)
+    if case == "queued_cancelled":
+        q._cancel = "aborted"
+    sched._queue.append(q)
+    cur = SimpleNamespace(tickets=dict(enumerate(running)))
+    with sched._cond:
+        assert sched._queue_must_wait_locked(cur, time.monotonic()) is expect

@@ -86,23 +86,20 @@ def test_q40_matmul_compiles_at_7b_shapes(one_chip, name, n, d, stacked, rows):
     assert ("q40_mm_stacked" if stacked else "q40_mm") in text
 
 
-@pytest.mark.parametrize("quantized", [False, True], ids=["dense", "int8"])
-def test_fused_paged_attention_compiles_at_7b_geometry(one_chip, quantized):
-    b, hq, hkv, dh, ps, maxp = 4, 32, 32, 128, 16, 64
+# (rows, query heads, kv heads, pages a slot): Llama-2-7B, and the two
+# served cells (Mistral-7B's GQA at 64 pages, OLMoE's MHA at 128)
+@pytest.mark.parametrize("b,hq,hkv,maxp", [(4, 32, 32, 64), (16, 32, 8, 64),
+                                           (16, 16, 16, 128)],
+                         ids=["7b", "mistral-7b", "olmoe-1b-7b"])
+def test_fused_paged_attention_compiles_at_served_geometry(one_chip, b, hq,
+                                                           hkv, maxp):
+    dh, ps = 128, 16
     n_pages = 1 + b * maxp
     s = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)  # noqa: E731
-    pool = s((2, n_pages, ps, hkv, dh), jnp.int8 if quantized else jnp.bfloat16)
-    scale = s((2, n_pages, ps, hkv, 1), jnp.float32)
-
-    def f(q, k, v, layer, table, pos, *sc):
-        return att.fused_paged_attention(q, k, v, layer, table, pos,
-                                         scales=sc or None)
-
-    args = [s((b, hq, 1, dh), jnp.bfloat16), pool, pool, s((), jnp.int32),
-            s((b, maxp), jnp.int32), s((b,), jnp.int32)]
-    if quantized:
-        args += [scale, scale]
-    text = jax.jit(f).lower(*args).compile().as_text()
+    pool = s((2, n_pages, ps, hkv, dh), jnp.bfloat16)
+    text = jax.jit(att.fused_paged_attention).lower(
+        s((b, hq, 1, dh), jnp.bfloat16), pool, pool, s((), jnp.int32),
+        s((b, maxp), jnp.int32), s((b,), jnp.int32)).compile().as_text()
     assert "tpu_custom_call" in text and "paged_attn_fused" in text
 
 
