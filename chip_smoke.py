@@ -12,8 +12,9 @@ Q40 weights:
            concurrent completions, a streamed chat, /metrics, SIGTERM drain
   moe      the mixture-of-experts path at OLMoE-1B-7B's widths and 2 layers:
            a seeded .m through the loader, ``moe_ffn``'s select strategy at 1
-           row and its scan over the 64 packed experts at 16 rows against the
-           XLA-dequantized matmul, then the same paged server on that file
+           row and its all-experts launches (``q40_mm_experts``, 64 packed
+           experts) at 16 and 256 rows against the XLA-dequantized scan, then
+           the same paged server on that file
 
 ``--chips 4`` runs, instead, only the tensor-parallel path and what it is
 compared with: the same files decoded greedily at tp=4 and tp=1.
@@ -157,18 +158,20 @@ def phase_kernels(timeout: float, rehearse: bool = False) -> dict:
 
 def phase_moe(mpath: str, timeout: float, rehearse: bool = False) -> dict:
     """Child: ``moe_ffn`` on a loaded file, kernel path against the XLA
-    path, at one row (select) and sixteen (scan)."""
+    path, at one row (select) and at 16 and 256 (all-experts; the XLA path's
+    form of it is the scan)."""
     rc, out = run_child("moe", ["--model", mpath], timeout, rehearse)
     rows, comp = _results(out, "moe")
     for r in rows:
         emit(dict(r, phase="moe"))
     require(rc == 0, f"moe: child exited {rc}")
-    errs = {r["strategy"]: r for r in rows if "rel_err" in r}
-    require(set(errs) == {"select", "scan"}, f"moe: compared {sorted(errs)}")
+    errs = {(r["strategy"], r["rows"]): r for r in rows if "rel_err" in r}
+    require(set(errs) == {("select", 1), ("all-experts", 16), ("all-experts", 256)},
+            f"moe: compared {sorted(errs)}")
     bad = [r for r in errs.values() if not r["rel_err"] <= r["tol"]]
     require(not bad, f"moe: above tolerance: {bad}")
     ledger = next(r for r in rows if r.get("what") == "ledger")["ledger"]
-    require("moe/select" in ledger and "moe/scan" in ledger,
+    require(all(f"moe/{p}" in ledger for p in ("select", "all-experts", "scan")),
             f"moe: strategies absent from the ledger: {ledger}")
     if not rehearse:
         require("q40/pallas-fused" in ledger and "DEGRADED" not in ledger,
@@ -600,7 +603,7 @@ def child_moe(argv: list[str], rehearse: bool) -> None:
           "experts": cfg.n_experts, "active": cfg.n_active_experts,
           "dim": cfg.dim, "expert_width": cfg.hidden_dim})
     obs_dispatch.reset()
-    for rows, strategy in ((1, "select"), (16, "scan")):
+    for rows, strategy in ((1, "select"), (16, "all-experts"), (256, "all-experts")):
         x = jax.random.normal(jax.random.PRNGKey(rows), (rows, cfg.dim), dtype)
         t0 = time.perf_counter()
         got = jax.jit(lambda v: moe_ffn(v, lp, cfg.with_(

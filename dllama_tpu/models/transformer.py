@@ -308,18 +308,25 @@ def moe_ffn(xb2d: jax.Array, lp, cfg: ModelConfig) -> jax.Array:
     ``experts`` (the expert matmuls and, on the scan, its bookkeeping),
     ``combine`` (the weighted sum and the cast to the activation dtype).  Each
     compiled call site records its strategy in the dispatch ledger as
-    ``{codec="moe", path="select"|"scan"|"unrolled"|"dense"}``.
+    ``{codec="moe", path="select"|"all-experts"|"scan"|"unrolled"|"dense"}``.
 
-    Two execution strategies, chosen statically by token count:
-    * decode (few tokens): compute only the k selected experts — with
+    Execution strategies, chosen statically (token count, packed or not,
+    mesh, kernel path; no flag):
+    * up to 4 tokens (``select``): compute only the k selected experts — with
       packed-Q40 experts each (token, k) pair runs the fused dequant-
       matmul on a ``QLayerView`` whose flat index selects the expert, so
       HBM reads are bounded by the k active experts' *packed* bytes
       (the reference likewise keeps MoE Q40 end-to-end,
       transformer.cpp:299-317); dense experts use a gather + einsum.
-    * prefill (many tokens): run every expert and mask — regular shapes
-      on the MXU; quantized experts unroll a static expert loop so only
-      one expert's weights are dequantized at a time.
+    * more tokens: run every expert and mask — regular shapes on the MXU.
+      Packed Q40 experts on one device with the fused kernel chosen
+      (``q40.all_experts_impl``; every served step and prefill on a TPU) take
+      ``all-experts``: gate, up and down are one launch each of
+      ``q40_mm_experts`` over all E experts, then one weighted sum over E.
+      With ``quant_impl="xla"``, on any mesh, and with Q80 experts, the loop
+      over experts stays: a static unroll up to MOE_PREFILL_UNROLL_MAX
+      (``unrolled``), a ``lax.scan`` past it (``scan``), one expert's weights
+      dequantized at a time.  Dense experts: one einsum (``dense``).
 
     Experts are TP-sliced like the reference (all experts on all shards,
     hidden dim sharded — transformer.cpp:299-317).  Under an ``ep`` mesh
@@ -380,6 +387,22 @@ def moe_ffn(xb2d: jax.Array, lp, cfg: ModelConfig) -> jax.Array:
     with part("router"):
         dense_w = jnp.zeros((n, e), weights.dtype)
         dense_w = jnp.put_along_axis(dense_w, top_idx, weights, axis=-1, inplace=False)
+
+    kernel = quant and q40.all_experts_impl(
+        (lp["gate"], lp["up"], lp["down"]), n, cfg.quant_impl)
+    if kernel:
+        # packed experts on the fused kernel, one device: the expert index is
+        # a grid axis, three launches a layer whatever E; every expert is
+        # read, as the masked loops below read them
+        obs_dispatch.record_dispatch("moe", "all-experts", rows=n, experts=e)
+        with part("experts"):
+            g = q40.matmul_experts(xb2d, lp["gate"], e, kernel)
+            u = q40.matmul_experts(xb2d, lp["up"], e, kernel)
+            o = q40.matmul_experts(act(g) * u, lp["down"], e, kernel,
+                                   out_dtype=jnp.float32)          # (E, N, D)
+        with part("combine"):
+            w_e = dense_w.T.astype(jnp.float32)[:, :, None]
+            return (w_e * o).sum(0).astype(cfg.dtype)
 
     if quant:
         # prefill, packed experts: one expert dequantized at a time with a

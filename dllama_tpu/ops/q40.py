@@ -30,7 +30,9 @@ Two matmul implementations:
   to ``PALLAS_MAX_ROWS`` rows ride in one block (decode), more rows (the
   served mixed step, prefill buckets) in row blocks of up to
   ``ROW_BLOCK_MAX`` (:func:`_row_block`), each weight tile unpacked once
-  per block.  A `pallas_call` is not auto-partitioned
+  per block.  A mixture-of-experts layer's E experts are one launch a
+  matmul (:func:`matmul_experts`, ``q40_mm_experts``): the expert index is
+  a grid axis of the same kernel.  A `pallas_call` is not auto-partitioned
   by GSPMD, so on a multi-device mesh it runs **per shard under
   ``jax.shard_map``** (see :func:`_sharded_matmul`): the caller declares the
   weight's TP slicing ``kind`` — ``"row"`` (output dim sharded, the
@@ -389,14 +391,13 @@ def _stacked_q40_kernel(lidx_ref, xlo_ref, xhi_ref, qp_ref, s_ref,
 
 
 def _x_parts(x: jax.Array) -> tuple[jax.Array, jax.Array]:
-    """Split activations (t, n) into the packed-row-order halves the kernel
-    contracts against: ``x_lo``/``x_hi`` (t, n/2) matching the low/high
-    nibble planes."""
-    t, n = x.shape
-    nb = n // 32
-    xr = x.reshape(t, nb, 32)
-    x_lo = xr[:, :, :16].reshape(t, n // 2)
-    x_hi = xr[:, :, 16:].reshape(t, n // 2)
+    """Split activations (..., t, n) into the packed-row-order halves the
+    kernel contracts against: ``x_lo``/``x_hi`` (..., t, n/2) matching the
+    low/high nibble planes."""
+    *lead, n = x.shape
+    xr = x.reshape(*lead, n // 32, 32)
+    x_lo = xr[..., :16].reshape(*lead, n // 2)
+    x_hi = xr[..., 16:].reshape(*lead, n // 2)
     return x_lo, x_hi
 
 
@@ -439,28 +440,46 @@ def _row_block(t: int, tile_n: int, tile_d: int) -> int | None:
 
 
 def _mm_call(t: int, n: int, d: int, tile_n: int, tile_d: int,
-             stacked: bool, row_block: int | None, **ms):
-    """What the flat and the stacked kernel share of their ``pallas_call``:
-    grid and specs (as keywords), compiler parameters, and the kernel's own
-    keywords.  The grid is ``(d tiles, n steps)`` with every row in the block
-    up to PALLAS_MAX_ROWS, else ``(row blocks, d tiles, n steps)``."""
+             stacked: bool, row_block: int | None, experts: int = 0,
+             x_per_expert: bool = False, **ms):
+    """What the three kernels share of their ``pallas_call``: grid and specs
+    (as keywords), compiler parameters, and the kernel's own keywords.  The
+    grid is ``(d tiles, n steps)`` with every row in the block up to
+    PALLAS_MAX_ROWS, else ``(row blocks, d tiles, n steps)``.  With
+    ``experts`` the expert index is one more parallel axis in front of the d
+    tiles: the weight's plane is ``layer * experts + e`` of the flat stack,
+    the output is ``(experts, t, d)``, and the activations are one
+    ``(t, n/2)`` pair for every expert (its index map ignores ``e``) or, with
+    ``x_per_expert``, ``(experts, t, n/2)``."""
     tr = row_block or _row_block(t, tile_n, tile_d)
     nd, nn = pl.cdiv(d, tile_d), n // tile_n
-    if tr is None:
-        grid, tb = (nd, nn), t
-        at = lambda f: lambda j, i, *l: f(0, j, i, *l)  # noqa: E731
-        params = pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary"))
-    else:
-        grid, tb = (pl.cdiv(t, tr), nd, nn), tr
-        at = lambda f: f  # noqa: E731
-        params = pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
-            vmem_limit_bytes=ROW_VMEM_LIMIT)
+    tb = t if tr is None else tr
+    grid = ((() if tr is None else (pl.cdiv(t, tr),))
+            + ((experts,) if experts else ()) + (nd, nn))
+
+    def at(f):
+        """``f(row block, expert, d tile, n step, *prefetched)`` as this
+        grid's index map: 0 for an axis the grid lacks."""
+        def index_map(*g):
+            g = list(g)
+            r = 0 if tr is None else g.pop(0)
+            e = g.pop(0) if experts else 0
+            return f(r, e, *g)
+        return index_map
+
+    def plane(e, l):  # the stack's leading index, from the prefetched layer
+        return (l[0][0] * experts + e,) if experts else tuple(ref[0] for ref in l)
+
+    # an expert axis of a block is squeezed (None): the kernel sees 2-D refs
+    ex = lambda on, e: (e,) if on else ()  # noqa: E731
+    params = pltpu.CompilerParams(
+        dimension_semantics=("parallel",) * (len(grid) - 1) + ("arbitrary",),
+        vmem_limit_bytes=None if tr is None else ROW_VMEM_LIMIT)
     lead = (1,) if stacked else ()
-    layer = lambda l: tuple(ref[0] for ref in l)  # noqa: E731 — prefetched index
-    w_at = at(lambda r, j, i, *l: layer(l) + (i, j))
-    xspec = pl.BlockSpec((tb, tile_n // 2), at(lambda r, j, i, *l: (r, i)), **ms)
+    w_at = at(lambda r, e, j, i, *l: plane(e, l) + (i, j))
+    xspec = pl.BlockSpec(
+        ex(x_per_expert, None) + (tb, tile_n // 2),
+        at(lambda r, e, j, i, *l: ex(x_per_expert, e) + (r, i)), **ms)
     grid_kw = dict(
         grid=grid,
         in_specs=[
@@ -469,7 +488,9 @@ def _mm_call(t: int, n: int, d: int, tile_n: int, tile_d: int,
             pl.BlockSpec(lead + (tile_n // 2, tile_d), w_at, **ms),
             pl.BlockSpec(lead + (tile_n // 32, tile_d), w_at, **ms),
         ],
-        out_specs=pl.BlockSpec((tb, tile_d), at(lambda r, j, i, *l: (r, j)), **ms),
+        out_specs=pl.BlockSpec(
+            ex(experts, None) + (tb, tile_d),
+            at(lambda r, e, j, i, *l: ex(experts, e) + (r, j)), **ms),
         scratch_shapes=[pltpu.VMEM((tb, tile_d), jnp.float32)])
     return grid_kw, params, dict(nsteps=nn, n_axis=len(grid) - 1)
 
@@ -530,6 +551,41 @@ def _pallas_matmul_stacked(x: jax.Array, qpacked: jax.Array, scales: jax.Array,
       qpacked, scales)
 
 
+@functools.partial(jax.jit, static_argnames=("experts", "interpret", "tiles",
+                                             "row_block"))
+def _pallas_matmul_experts(x: jax.Array, qpacked: jax.Array, scales: jax.Array,
+                           layer: jax.Array, experts: int,
+                           interpret: bool = False,
+                           tiles: tuple[int, int] | None = None,
+                           row_block: int | None = None) -> jax.Array:
+    """Every expert of one layer in one launch: ``x`` against planes
+    ``layer * experts + e`` of the flat ``(L * experts, n/2, d)`` stack, for
+    ``e`` in ``range(experts)`` → ``(experts, t, d)`` f32.
+
+    ``x`` is ``(t, n)``, shared by all experts (gate, up), or
+    ``(experts, t, n)``, one activation block an expert (down).  The expert
+    index is a grid axis (:func:`_mm_call`), so what was ``experts`` launches
+    of :func:`_pallas_matmul_stacked` from a traced loop is one, with the
+    same tile math, and ``_x_parts`` runs once on the whole activation.  All
+    ``experts`` planes are read whatever the router chose."""
+    t, n = x.shape[-2:]
+    d = qpacked.shape[-1]
+    tile_n, tile_d = tiles or _tiles(n, d)
+    grid_kw, params, kernel_kw = _mm_call(
+        t, n, d, tile_n, tile_d, True, row_block, experts=experts,
+        x_per_expert=x.ndim == 3)
+    return pl.pallas_call(
+        functools.partial(_stacked_q40_kernel, **kernel_kw),
+        grid_spec=pltpu.PrefetchScalarGridSpec(num_scalar_prefetch=1,
+                                               **grid_kw),
+        out_shape=jax.ShapeDtypeStruct((experts, t, d), jnp.float32),
+        compiler_params=params,
+        interpret=interpret,
+        name="q40_mm_experts",
+    )(layer.reshape(1).astype(jnp.int32), *_x_parts(x.astype(jnp.bfloat16)),
+      qpacked, scales)
+
+
 @dataclass(frozen=True)
 class QLayerView:
     """A traced view of one 2-D slice of a stacked QTensor.
@@ -578,7 +634,8 @@ class QLayerView:
 def _pad_x(x2: jax.Array, n: int, np_: int) -> jax.Array:
     if np_ == n:
         return x2
-    return jnp.pad(x2, ((0, 0), (0, np_ - n)))  # zeros meet zero pad scales
+    # zeros meet zero pad scales
+    return jnp.pad(x2, ((0, 0),) * (x2.ndim - 1) + ((0, np_ - n),))
 
 
 # ---------------------------------------------------------------------------
@@ -893,6 +950,49 @@ def _auto_pallas(np_: int, d: int, rows: int, kind: str | None) -> bool:
     return _tile_n_legal(local_n, _tiles(local_n, local_d)[0])
 
 
+def _resolve_impl(impl: str, np_: int, d: int, rows: int,
+                  kind: str | None) -> str:
+    """``auto`` resolved by the static rule; the other names checked."""
+    if impl == "auto":
+        impl = "pallas" if jax.default_backend() == "tpu" and _auto_pallas(
+            np_, d, rows, kind) else "xla"
+    if impl not in ("pallas", "pallas_interpret", "xla"):
+        raise ValueError(f"unknown q40 matmul impl {impl!r}")
+    return impl
+
+
+def all_experts_impl(views, rows: int, impl: str) -> str | None:
+    """The kernel ``impl`` with which :func:`matmul_experts` serves these
+    expert stacks at ``rows`` rows, or None where the caller keeps its loop
+    of :func:`matmul` calls: a mesh (its expert path is per shard and per
+    expert, ``_sharded_matmul`` / ``_sharded_matmul_ep``), Q80 experts, or
+    the XLA path by the same static rule ``matmul`` applies to one expert."""
+    if _smap_mesh() is not None:
+        return None
+    if not all(isinstance(v, QLayerView) and isinstance(v.qt, QTensor)
+               for v in views):
+        return None
+    impls = {_resolve_impl(impl, v.qt.qpacked.shape[-2] * 2, v.logical_nd[1],
+                           rows, None) for v in views}
+    return None if "xla" in impls else impls.pop()
+
+
+def matmul_experts(x: jax.Array, qt: QLayerView, experts: int, impl: str,
+                   out_dtype=None) -> jax.Array:
+    """``x @ dequantize(expert e of the view's layer)`` for every ``e``, in
+    one launch of the fused kernel on one device (``impl`` from
+    :func:`all_experts_impl`): ``x`` ``(t, n)`` shared or ``(experts, t, n)``
+    → ``(experts, t, d)``.  The view's ``layer`` indexes the lead dims in
+    front of the expert axis."""
+    x = _pad_x(x, qt.logical_nd[0], qt.qt.qpacked.shape[-2] * 2)
+    obs_dispatch.record_dispatch("q40", "pallas-fused", rows=x.shape[-2],
+                                 kind=None, tp=1, experts=experts)
+    out = _pallas_matmul_experts(x, *qt.flat_planes(), qt.layer,
+                                 experts=experts,
+                                 interpret=impl == "pallas_interpret")
+    return out.astype(out_dtype or x.dtype)
+
+
 def matmul(x: jax.Array, qt: QTensor | QLayerView, impl: str = "auto",
            out_dtype=None, kind: str | None = None) -> jax.Array:
     """``x @ dequantize(qt)`` with f32 accumulation.
@@ -916,12 +1016,7 @@ def matmul(x: jax.Array, qt: QTensor | QLayerView, impl: str = "auto",
     view = isinstance(qt, QLayerView)
     np_ = (qt.qt if view else qt).qpacked.shape[-2] * 2
 
-    if impl == "auto":
-        impl = "pallas" if jax.default_backend() == "tpu" and _auto_pallas(
-            np_, d, rows, kind) else "xla"
-    if impl not in ("pallas", "pallas_interpret", "xla"):
-        raise ValueError(f"unknown q40 matmul impl {impl!r}")
-
+    impl = _resolve_impl(impl, np_, d, rows, kind)
     if impl != "xla":
         interp = impl == "pallas_interpret"
         if not view and qt.qpacked.ndim != 2:

@@ -105,8 +105,9 @@ def test_rehearse_tp_phase_on_virtual_devices(rehearsal_env, capfd):
 
 def test_rehearse_moe_phases(rehearsal_env, capfd):
     """The mixture-of-experts pass: a seeded OLMoE-shaped file (64 experts, 8
-    a token, toy widths) through the loader, ``moe_ffn``'s select and scan
-    strategies against the XLA path, then the paged server on that file."""
+    a token, toy widths) through the loader, ``moe_ffn``'s select and
+    all-experts strategies against the XLA path (whose many-row form is the
+    scan), then the paged server on that file (on the CPU: the XLA path)."""
     from dllama_tpu.io import mfile
     from dllama_tpu.synth import synth_model_files
 
@@ -117,8 +118,8 @@ def test_rehearse_moe_phases(rehearsal_env, capfd):
         (mfile.ARCH_OLMOE, 64, 8)
     chip_smoke.phase_moe(m, 600, rehearse=True)
     rows = [json.loads(ln) for ln in capfd.readouterr().out.splitlines()]
-    errs = {r["strategy"]: r for r in rows if "rel_err" in r}
-    assert set(errs) == {"select", "scan"}
+    errs = {(r["strategy"], r["rows"]): r for r in rows if "rel_err" in r}
+    assert set(errs) == {("select", 1), ("all-experts", 16), ("all-experts", 256)}
     assert all(r["rel_err"] <= r["tol"] for r in errs.values())
     res = chip_smoke.phase_server(m, t, 600, tmp, slots=2, ctx=64, page=4,
                                   max_tokens=8, rehearse=True)

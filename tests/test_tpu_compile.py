@@ -86,6 +86,27 @@ def test_q40_matmul_compiles_at_7b_shapes(one_chip, name, n, d, stacked, rows):
     assert ("q40_mm_stacked" if stacked else "q40_mm") in text
 
 
+# OLMoE-1B-7B's expert matmuls (hidden 2048, expert width 1024, 64 experts of
+# 16 layers): gate / up from the shared activation, down from one an expert
+@pytest.mark.parametrize("rows", [16, 256])
+@pytest.mark.parametrize("name,n,d,per_expert", [
+    ("gate", 2048, 1024, False), ("up", 2048, 1024, False),
+    ("down", 1024, 2048, True)], ids=["gate", "up", "down"])
+def test_q40_experts_matmul_compiles_at_olmoe_shapes(one_chip, name, n, d,
+                                                     per_expert, rows):
+    L, E = 16, 64
+    s = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)  # noqa: E731
+    x = s(((E,) if per_expert else ()) + (rows, n), jnp.bfloat16)
+    compiled = jax.jit(
+        lambda x, qp, sc, layer: q40._pallas_matmul_experts(
+            x, qp, sc, layer, experts=E)).lower(
+        x, s((L * E, n // 2, d), jnp.uint8), s((L * E, n // 32, d), jnp.uint16),
+        s((), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "q40_mm_experts" in text
+    assert f"f32[{E},{rows},{d}]" in text
+
+
 # (rows, query heads, kv heads, pages a slot): Llama-2-7B, and the two
 # served cells (Mistral-7B's GQA at 64 pages, OLMoE's MHA at 128)
 @pytest.mark.parametrize("b,hq,hkv,maxp", [(4, 32, 32, 64), (16, 32, 8, 64),
@@ -294,6 +315,57 @@ def test_mixed_slot_step_keeps_q40_on_the_fused_kernel(one_chip, monkeypatch):
     dequant = [path for _, path in roots
                if {"w13", "w1", "w3", "w2"} & set(path.split("/"))]
     assert not dequant, dequant
+
+
+def test_olmoe_decode_slot_step_runs_the_experts_in_three_launches_a_layer(
+        one_chip, monkeypatch):
+    """The served pure-decode step, 16 slots x 1 token, over a 2-layer model
+    with OLMoE's block (64 packed experts, 8 a token; narrower widths),
+    compiled for the described chip: the experts are ``q40_mm_experts``, three
+    call sites in the layer loop's body, the strategy recorded is
+    ``all-experts``, and no ``while`` (the scan over experts) or
+    ``q40_mm_stacked`` launch is left under ``moe``."""
+    import re
+
+    from dllama_tpu.io import mfile
+    from dllama_tpu.models.config import tiny_config
+    from dllama_tpu.models.params import param_shapes
+    from dllama_tpu.obs import dispatch as obs_dispatch
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(jax, "device_count", lambda: 1)
+    cfg = tiny_config(arch=mfile.ARCH_OLMOE, dim=512, hidden_dim=256,
+                      n_layers=2, n_heads=4, n_kv_heads=4, n_experts=64,
+                      n_active_experts=8, vocab_size=1024, seq_len=256,
+                      dtype=jnp.bfloat16)
+    s = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)  # noqa: E731
+
+    def packed(shape):
+        *lead, n, d = shape
+        return q40.QTensor(s((*lead, n // 2, d), jnp.uint8),
+                           s((*lead, n // 32, d), jnp.uint16), (n, d))
+
+    sh = param_shapes(cfg)
+    dense = ("embedding", "router", "rms_att", "rms_ffn", "rms_final",
+             "q_norm", "k_norm")
+    params = {k: s(sh[k], jnp.bfloat16 if k in ("embedding", "router")
+                   else jnp.float32) for k in dense}
+    params.update({k: packed(sh[k]) for k in sh if k not in dense})
+    obs_dispatch.reset()
+    try:
+        text = _slot_step_text(one_chip, cfg, params, 16, 1, 129, 8)
+        sites = obs_dispatch.dispatches()
+    finally:
+        obs_dispatch.reset()
+    assert sites.get("moe/all-experts") == 1 and "moe/scan" not in sites, sites
+    assert "q40/xla-dequant" not in sites, sites
+    ops = re.findall(r"^\s*(?:ROOT )?%?[\w.\-]+ = \S+ ([\w\-]+)\(.*?"
+                     r"op_name=\"([^\"]+)\"", text, re.M)
+    under_moe = [(op, path) for op, path in ops if "/moe/" in path]
+    assert under_moe and not [o for o in under_moe if o[0] == "while"], under_moe
+    calls = [path for op, path in under_moe
+             if op == "custom-call" and "pallas_call" in path]
+    assert len(calls) == 3 and all("q40_mm_experts" in c for c in calls), calls
 
 
 @pytest.mark.parametrize("name,n,d,reduce", [
