@@ -2,7 +2,8 @@
 
 Re-implements `/root/reference/converter/convert-hf.py`: llama / mistral /
 mixtral folders with ``config.json`` + ``*.safetensors`` become a `.m` file
-in the canonical tensor order; beyond the reference, olmoe folders
+in the canonical tensor order; beyond the reference, deepseek_v2 folders
+(MLA, ``kv_b_proj`` kept whole, header keys 14..31) and olmoe folders
 (``ARCH_OLMOE``).  Key semantics preserved:
 
 * q/k head permutation (convert-hf.py:12-15): HF stores RoPE in rotate-half
@@ -37,6 +38,7 @@ ARCH_BY_MODEL_TYPE = {
     "mistral": mfile.ARCH_LLAMA,
     "mixtral": mfile.ARCH_MIXTRAL,
     "olmoe": mfile.ARCH_OLMOE,
+    "deepseek_v2": mfile.ARCH_DEEPSEEK2,
 }
 HIDDEN_ACT = {"gelu": mfile.ACT_GELU, "silu": mfile.ACT_SILU}
 
@@ -67,6 +69,65 @@ def _refuse_olmoe_variants(config: dict) -> None:
                          "runtime's RoPE is unscaled")
 
 
+def _refuse_deepseek2_variants(config: dict) -> None:
+    """``ARCH_DEEPSEEK2`` is DeepSeek-V2's block (softmax router scores, experts
+    chosen greedily or by group, the chosen probabilities scaled and not
+    renormalised, an expert FFN in every layer past the dense ones, YaRN or
+    plain RoPE, no biases, q through its latent).  What the runtime does not
+    compute is refused by name (V3's sigmoid scores and correction bias, V2-
+    Lite's direct q projection)."""
+    def no(why):
+        raise SystemExit(f"deepseek_v2: {why}")
+
+    if config.get("scoring_func") not in (None, "softmax"):
+        no(f"scoring_func is {config['scoring_func']!r}; the runtime scores "
+           "experts by a softmax")
+    if config.get("topk_method", "greedy") not in ("greedy", "group_limited_greedy"):
+        no(f"topk_method is {config['topk_method']!r}; the runtime chooses "
+           "experts by greedy or group_limited_greedy (no correction bias)")
+    if config.get("norm_topk_prob", False):
+        no("norm_topk_prob is true; the runtime scales the chosen "
+           "probabilities by routed_scaling_factor and does not renormalise them")
+    if config.get("moe_layer_freq") not in (None, 1):
+        no(f"moe_layer_freq is {config['moe_layer_freq']}; the runtime has an "
+           "expert FFN in every layer past first_k_dense_replace")
+    if config.get("attention_bias", False):
+        no("attention_bias is true; the .m format has no bias tensors")
+    if config.get("q_lora_rank") is None:
+        no("q_lora_rank is null; the runtime projects q through its latent "
+           "(wq_a, q_a_norm, wq_b)")
+    scaling = config.get("rope_scaling")
+    if scaling is not None and scaling.get("type", scaling.get("rope_type")) != "yarn":
+        no(f"rope_scaling type is {scaling.get('type', scaling.get('rope_type'))!r}; "
+           "the runtime computes yarn or unscaled RoPE")
+
+
+def _deepseek2_fields(config: dict) -> dict:
+    """The header's keys 14..31 from a ``deepseek_v2`` config.json."""
+    _refuse_deepseek2_variants(config)
+    scaling = config.get("rope_scaling") or {}
+    grouped = config.get("topk_method", "greedy") == "group_limited_greedy"
+    return dict(
+        q_lora_rank=config["q_lora_rank"], kv_lora_rank=config["kv_lora_rank"],
+        qk_nope_head_dim=config["qk_nope_head_dim"],
+        qk_rope_head_dim=config["qk_rope_head_dim"],
+        v_head_dim=config["v_head_dim"],
+        moe_hidden_dim=config["moe_intermediate_size"],
+        n_shared_experts=config.get("n_shared_experts") or 0,
+        n_groups=config["n_group"] if grouped else 1,
+        topk_groups=config["topk_group"] if grouped else 1,
+        n_dense_layers=config.get("first_k_dense_replace", 0),
+        routed_scale=float(config.get("routed_scaling_factor", 1.0)),
+        rope_factor=float(scaling.get("factor", 1.0)),
+        rope_orig_seq_len=int(scaling.get(
+            "original_max_position_embeddings", 0)),
+        rope_beta_fast=float(scaling.get("beta_fast", 32)),
+        rope_beta_slow=float(scaling.get("beta_slow", 1)),
+        rope_mscale=float(scaling.get("mscale", 1.0)),
+        rope_mscale_all_dim=float(scaling.get("mscale_all_dim", 0.0)),
+        norm_eps=float(config.get("rms_norm_eps", 1e-6)))
+
+
 def load_spec(folder: str, weights_ftype: int) -> mfile.ModelSpec:
     with open(os.path.join(folder, "config.json")) as f:
         config = json.load(f)
@@ -75,8 +136,10 @@ def load_spec(folder: str, weights_ftype: int) -> mfile.ModelSpec:
         raise SystemExit(f"Unsupported arch type: {config['model_type']}")
     if arch == mfile.ARCH_OLMOE:
         _refuse_olmoe_variants(config)
-    # Mixtral's key, then OLMoE's
-    n_experts = config.get("num_local_experts") or config.get("num_experts") or 0
+    ext = _deepseek2_fields(config) if arch == mfile.ARCH_DEEPSEEK2 else {}
+    # Mixtral's key, then OLMoE's, then DeepSeek-V2's
+    n_experts = (config.get("num_local_experts") or config.get("num_experts")
+                 or config.get("n_routed_experts") or 0)
     n_active = (config.get("num_active_local_experts")
                 or config.get("num_experts_per_tok") or 0)
     return mfile.ModelSpec(
@@ -92,7 +155,7 @@ def load_spec(folder: str, weights_ftype: int) -> mfile.ModelSpec:
         seq_len=config["max_position_embeddings"],
         hidden_act=HIDDEN_ACT[config.get("hidden_act", "silu")],
         rope_theta=float(config.get("rope_theta", 10000.0)),
-        weights_ftype=weights_ftype)
+        weights_ftype=weights_ftype, **ext)
 
 
 class SafetensorsStore:
@@ -112,6 +175,9 @@ class SafetensorsStore:
         if not self._handles:
             raise SystemExit("Not found any model file")
 
+    def has(self, suffix: str) -> bool:
+        return any(k.endswith(suffix) for k in self._index)
+
     def get(self, key: str) -> np.ndarray:
         path = self._index.get(key)
         if path is None:
@@ -121,6 +187,16 @@ class SafetensorsStore:
             import jax.numpy as jnp
             t = np.asarray(jnp.asarray(t.view(jnp.bfloat16), jnp.float32))
         return np.asarray(t, dtype=np.float32)
+
+
+_DEEPSEEK2_LEAVES = {
+    "wq_a": "self_attn.q_a_proj", "q_a_norm": "self_attn.q_a_layernorm",
+    "wq_b": "self_attn.q_b_proj", "wkv_a": "self_attn.kv_a_proj_with_mqa",
+    "kv_a_norm": "self_attn.kv_a_layernorm", "wkv_b": "self_attn.kv_b_proj",
+    "shared_w1": "mlp.shared_experts.gate_proj",
+    "shared_w2": "mlp.shared_experts.down_proj",
+    "shared_w3": "mlp.shared_experts.up_proj",
+}
 
 
 def hf_source_name(our_name: str, spec: mfile.ModelSpec) -> tuple[str, bool]:
@@ -136,6 +212,10 @@ def hf_source_name(our_name: str, spec: mfile.ModelSpec) -> tuple[str, bool]:
     leaf = parts[-1]
     base = f"model.layers.{li}"
     olmoe = spec.arch == mfile.ARCH_OLMOE
+    # the two arch ids whose HF experts are mlp.experts.N.{gate,up,down}_proj
+    mlp_experts = spec.arch in (mfile.ARCH_OLMOE, mfile.ARCH_DEEPSEEK2)
+    if leaf in _DEEPSEEK2_LEAVES:  # kv_b_proj whole, rows as published
+        return f"{base}.{_DEEPSEEK2_LEAVES[leaf]}.weight", False
     if leaf == "wq":
         return f"{base}.self_attn.q_proj.weight", not olmoe
     if leaf == "wk":
@@ -159,12 +239,12 @@ def hf_source_name(our_name: str, spec: mfile.ModelSpec) -> tuple[str, bool]:
         return f"{base}.mlp.up_proj.weight", False
     if parts[2] == "experts":
         e = parts[3]
-        if olmoe:
+        if mlp_experts:
             return f"{base}.mlp.experts.{e}.{leaf}_proj.weight", False
         hf_leaf = {"up": "w3", "gate": "w1", "down": "w2"}[leaf]
         return f"{base}.block_sparse_moe.experts.{e}.{hf_leaf}.weight", False
     if leaf == "moe_router":
-        return (f"{base}.mlp.gate.weight" if olmoe
+        return (f"{base}.mlp.gate.weight" if mlp_experts
                 else f"{base}.block_sparse_moe.gate.weight"), False
     raise SystemExit(f"no HF mapping for {our_name}")
 
@@ -172,6 +252,9 @@ def hf_source_name(our_name: str, spec: mfile.ModelSpec) -> tuple[str, bool]:
 def convert(folder: str, weights_ftype: int, out_path: str) -> None:
     spec = load_spec(folder, weights_ftype)
     store = SafetensorsStore(folder)
+    if spec.arch == mfile.ARCH_DEEPSEEK2 and store.has("e_score_correction_bias"):
+        raise SystemExit("deepseek_v2: the checkpoint has e_score_correction_bias "
+                         "(V3's router); the runtime has no correction bias")
     with mfile.MFileWriter(out_path, spec) as w:
         for item in w.plan:
             key, do_permute = hf_source_name(item.name, spec)

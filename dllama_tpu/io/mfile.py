@@ -13,7 +13,12 @@ the reference converters load directly:
   embedding, then per layer q/k/v/wo, (olmoe: q_norm, k_norm), (router +
   per-expert up/gate/down | w1/w2/w3), rms_att, rms_ffn, (grok: rms_moe,
   rms_ffn2), then rms_final
-  and wcls.  Matmul weights are stored row-major ``(d_out, n_in)`` in the
+  and wcls.  A DeepSeek-V2 file (``ARCH_DEEPSEEK2``) has its own attention
+  tensors (``wq_a``, ``q_a_norm``, ``wq_b``, ``wkv_a``, ``kv_a_norm``,
+  ``wkv_b`` kept whole, ``wo``), a dense FFN in its first
+  ``n_dense_layers`` layers and router + experts + one shared expert
+  (``shared_w1/w2/w3``) in the rest, and header keys 14..31 for the sizes no
+  older arch has (floats as their IEEE-754 f32 bits).  Matmul weights are stored row-major ``(d_out, n_in)`` in the
   model's weight float type; norm weights and the embedding are F32
   (transformer.cpp:213-218, 266-278).
 
@@ -45,8 +50,12 @@ ARCH_MIXTRAL = 0xABCD02
 # top-k router probabilities used unnormalised).  An arch id and not a header
 # key: the per-arch flags are derived from it (models/config.py)
 ARCH_OLMOE = 0xABCD03
+# DeepSeek-V2: latent attention (MLA), experts chosen by group, shared
+# experts, leading dense layers.  Its sizes are header keys (14..31): eleven
+# of them cannot be derived from an id
+ARCH_DEEPSEEK2 = 0xABCD04
 ARCH_NAMES = {ARCH_LLAMA: "llama", ARCH_GROK1: "grok1", ARCH_MIXTRAL: "mixtral",
-              ARCH_OLMOE: "olmoe"}
+              ARCH_OLMOE: "olmoe", ARCH_DEEPSEEK2: "deepseek2"}
 
 # TransformerHiddenAct (transformer.hpp:45-48)
 ACT_GELU = 0
@@ -67,6 +76,38 @@ KEY_SEQ_LEN = 10
 KEY_HIDDEN_ACT = 11
 KEY_ROPE_THETA = 12
 KEY_WEIGHTS_FLOAT_TYPE = 13
+# beyond the reference's fourteen (ARCH_DEEPSEEK2 only).  ``(key, field,
+# is_float)``: a float travels as the bits of its IEEE-754 f32 in the i32
+EXT_KEYS = (
+    (14, "q_lora_rank", False),
+    (15, "kv_lora_rank", False),
+    (16, "qk_nope_head_dim", False),
+    (17, "qk_rope_head_dim", False),
+    (18, "v_head_dim", False),
+    (19, "moe_hidden_dim", False),      # one routed expert's width
+    (20, "n_shared_experts", False),    # the shared expert is this many wide
+    (21, "n_groups", False),
+    (22, "topk_groups", False),
+    (23, "n_dense_layers", False),      # leading layers with a dense FFN
+    (24, "routed_scale", True),
+    (25, "rope_factor", True),          # YaRN; 1.0 = plain RoPE
+    (26, "rope_orig_seq_len", False),
+    (27, "rope_beta_fast", True),
+    (28, "rope_beta_slow", True),
+    (29, "rope_mscale", True),
+    (30, "rope_mscale_all_dim", True),
+    (31, "norm_eps", True),
+)
+_EXT_BY_KEY = {k: (name, is_f) for k, name, is_f in EXT_KEYS}
+KEY_MAX = EXT_KEYS[-1][0]
+
+
+def _f32_bits(x: float) -> int:
+    return struct.unpack("<i", struct.pack("<f", float(x)))[0]
+
+
+def _bits_f32(v: int) -> float:
+    return struct.unpack("<f", struct.pack("<i", int(v)))[0]
 
 
 @dataclass
@@ -88,6 +129,25 @@ class ModelSpec:
     weights_ftype: int = quants.F32
     version: int = 1
     header_size: int = 0
+    # ARCH_DEEPSEEK2's sizes (EXT_KEYS); 0 / 1.0 / 1e-5 where the arch has none
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    moe_hidden_dim: int = 0
+    n_shared_experts: int = 0
+    n_groups: int = 0
+    topk_groups: int = 0
+    n_dense_layers: int = 0
+    routed_scale: float = 1.0
+    rope_factor: float = 1.0
+    rope_orig_seq_len: int = 0
+    rope_beta_fast: float = 32.0
+    rope_beta_slow: float = 1.0
+    rope_mscale: float = 1.0
+    rope_mscale_all_dim: float = 0.0
+    norm_eps: float = 1e-5
 
     @property
     def head_size(self) -> int:
@@ -96,6 +156,10 @@ class ModelSpec:
     @property
     def kv_dim(self) -> int:
         return (self.dim * self.n_kv_heads) // self.n_heads
+
+    @property
+    def is_mla(self) -> bool:
+        return self.kv_lora_rank > 0
 
     @property
     def arch_name(self) -> str:
@@ -133,7 +197,9 @@ def tensor_plan(spec: ModelSpec) -> list[TensorInfo]:
         pos += nbytes
 
     add("token_embedding", (spec.vocab_size, spec.dim), quants.F32)
-    for i in range(spec.n_layers):
+    if spec.arch == ARCH_DEEPSEEK2:
+        _deepseek2_layers(spec, add)
+    for i in range(0 if spec.arch == ARCH_DEEPSEEK2 else spec.n_layers):
         add(f"layers.{i}.wq", (spec.dim, spec.dim), w)
         add(f"layers.{i}.wk", (spec.kv_dim, spec.dim), w)
         add(f"layers.{i}.wv", (spec.kv_dim, spec.dim), w)
@@ -159,6 +225,42 @@ def tensor_plan(spec: ModelSpec) -> list[TensorInfo]:
     add("rms_final", (spec.dim,), quants.F32)
     add("wcls", (spec.vocab_size, spec.dim), w)
     return plan
+
+
+def _deepseek2_layers(spec: ModelSpec, add) -> None:
+    """A DeepSeek-V2 layer: the MLA projections (``wkv_b`` whole, its rows
+    head by head ``k_nope`` then ``v``, as published), the dense FFN in the
+    first ``n_dense_layers`` layers and router, experts and the shared expert
+    in the others, then the two block norms."""
+    w, d, h = spec.weights_ftype, spec.dim, spec.n_heads
+    qk = spec.qk_nope_head_dim + spec.qk_rope_head_dim
+    for i in range(spec.n_layers):
+        p = f"layers.{i}."
+        add(p + "wq_a", (spec.q_lora_rank, d), w)
+        add(p + "q_a_norm", (spec.q_lora_rank,), quants.F32)
+        add(p + "wq_b", (h * qk, spec.q_lora_rank), w)
+        add(p + "wkv_a", (spec.kv_lora_rank + spec.qk_rope_head_dim, d), w)
+        add(p + "kv_a_norm", (spec.kv_lora_rank,), quants.F32)
+        add(p + "wkv_b", (h * (spec.qk_nope_head_dim + spec.v_head_dim),
+                          spec.kv_lora_rank), w)
+        add(p + "wo", (d, h * spec.v_head_dim), w)
+        if i < spec.n_dense_layers:
+            add(p + "w1", (spec.hidden_dim, d), w)
+            add(p + "w2", (d, spec.hidden_dim), w)
+            add(p + "w3", (spec.hidden_dim, d), w)
+        else:
+            f = spec.moe_hidden_dim
+            add(p + "moe_router", (spec.n_experts, d), w)
+            for e in range(spec.n_experts):
+                add(f"{p}experts.{e}.up", (f, d), w)
+                add(f"{p}experts.{e}.gate", (f, d), w)
+                add(f"{p}experts.{e}.down", (d, f), w)
+            fs = f * spec.n_shared_experts
+            add(p + "shared_w1", (fs, d), w)
+            add(p + "shared_w2", (d, fs), w)
+            add(p + "shared_w3", (fs, d), w)
+        add(p + "rms_att", (d,), quants.F32)
+        add(p + "rms_ffn", (d,), quants.F32)
 
 
 def _read_exact(f, n: int, path, field: str) -> tuple[bytes, int]:
@@ -237,11 +339,70 @@ def validate_spec(spec: ModelSpec, path) -> ModelSpec:
                             "more active experts than experts",
                             expected=f"<= {spec.n_experts}",
                             got=spec.n_active_experts)
+    if spec.arch == ARCH_DEEPSEEK2:
+        _validate_deepseek2(spec, path)
+    elif spec.is_mla or spec.n_dense_layers or spec.n_shared_experts \
+            or spec.n_groups or spec.moe_hidden_dim:
+        raise ArtifactError(path, "header key",
+                            "keys 14..30 describe a deepseek2 file",
+                            expected=hex(ARCH_DEEPSEEK2), got=hex(spec.arch))
+    if not 0 < spec.norm_eps < 1e-2:
+        raise ArtifactError(path, "header field norm_eps",
+                            "value out of range — corrupt header",
+                            expected="0..1e-2", got=spec.norm_eps)
     if spec.arch == ARCH_OLMOE and not spec.n_active_experts:
         raise ArtifactError(path, "header field n_active_experts",
                             "an olmoe file has experts and a top-k",
                             expected=">= 1", got=spec.n_active_experts)
     return spec
+
+
+def _validate_deepseek2(spec: ModelSpec, path) -> None:
+    """The cross-field rules of an ``ARCH_DEEPSEEK2`` header."""
+    def bad(field, why, expected, got):
+        raise ArtifactError(path, f"header field {field}", why,
+                            expected=expected, got=got)
+
+    for field, hi in (("q_lora_rank", 1 << 16), ("kv_lora_rank", 1 << 16),
+                      ("qk_nope_head_dim", 4096), ("qk_rope_head_dim", 4096),
+                      ("v_head_dim", 4096), ("moe_hidden_dim", 1 << 24),
+                      ("n_groups", 512), ("topk_groups", 512)):
+        v = getattr(spec, field)
+        if not 1 <= v <= hi:
+            bad(field, "a deepseek2 file states this size", f"1..{hi}", v)
+    if spec.qk_rope_head_dim % 2:
+        bad("qk_rope_head_dim", "RoPE rotates pairs", "even",
+            spec.qk_rope_head_dim)
+    if not 0 <= spec.n_shared_experts <= 64:
+        bad("n_shared_experts", "value out of range — corrupt header",
+            "0..64", spec.n_shared_experts)
+    if not 0 <= spec.n_dense_layers <= spec.n_layers:
+        bad("n_dense_layers", "more dense layers than layers",
+            f"0..{spec.n_layers}", spec.n_dense_layers)
+    if spec.n_dense_layers < spec.n_layers:
+        if not spec.n_experts or not spec.n_active_experts:
+            bad("n_experts", "the layers past the dense ones have experts "
+                "and a top-k", ">= 1", spec.n_experts)
+        if spec.n_experts % spec.n_groups:
+            bad("n_groups", "experts not divisible into groups",
+                f"divisor of n_experts={spec.n_experts}", spec.n_groups)
+        if spec.topk_groups > spec.n_groups:
+            bad("topk_groups", "more groups kept than groups",
+                f"<= {spec.n_groups}", spec.topk_groups)
+        if spec.n_active_experts > spec.topk_groups * (spec.n_experts
+                                                       // spec.n_groups):
+            bad("n_active_experts", "more experts a token than the kept "
+                "groups hold", f"<= {spec.topk_groups} groups of "
+                f"{spec.n_experts // spec.n_groups}", spec.n_active_experts)
+    if spec.n_kv_heads != spec.n_heads:
+        bad("n_kv_heads", "latent attention has one latent for all heads; "
+            "the header repeats n_heads", spec.n_heads, spec.n_kv_heads)
+    if not (spec.routed_scale > 0 and spec.rope_factor >= 1.0):
+        bad("routed_scale", "routed_scale must be positive and rope_factor "
+            ">= 1", "> 0, >= 1", (spec.routed_scale, spec.rope_factor))
+    if spec.rope_factor > 1.0 and spec.rope_orig_seq_len < 1:
+        bad("rope_orig_seq_len", "YaRN needs the original context length",
+            ">= 1", spec.rope_orig_seq_len)
 
 
 def read_spec(path: str | os.PathLike, weights_ftype: int | None = None) -> ModelSpec:
@@ -319,11 +480,14 @@ def read_spec(path: str | os.PathLike, weights_ftype: int | None = None) -> Mode
                 elif k == KEY_WEIGHTS_FLOAT_TYPE:
                     spec.weights_ftype = v
                     found_wft = True
+                elif k in _EXT_BY_KEY:
+                    name, is_f = _EXT_BY_KEY[k]
+                    setattr(spec, name, _bits_f32(v) if is_f else v)
                 else:
                     raise ArtifactError(path, "header key",
                                         "unsupported .m header key",
                                         offset=pair_off,
-                                        expected=f"0..{KEY_WEIGHTS_FLOAT_TYPE}",
+                                        expected=f"0..{KEY_MAX}",
                                         got=k)
         else:
             raise ArtifactError(path, "magic",
@@ -493,6 +657,10 @@ def write_header(f, spec: ModelSpec) -> int:
         (KEY_ROPE_THETA, int(spec.rope_theta)),
         (KEY_WEIGHTS_FLOAT_TYPE, spec.weights_ftype),
     ]
+    if spec.arch == ARCH_DEEPSEEK2:
+        # the older archs keep the reference's fourteen keys, byte for byte
+        pairs += [(k, _f32_bits(getattr(spec, name)) if is_f
+                   else getattr(spec, name)) for k, name, is_f in EXT_KEYS]
     data = b"".join(struct.pack("<ii", k, v) for k, v in pairs)
     f.write(struct.pack("<ii", MAGIC_V2, 8 + len(data)))
     f.write(data)

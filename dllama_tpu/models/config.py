@@ -5,11 +5,14 @@ the runtime: adds compute dtype and derives the per-arch structural flags
 that the reference encodes as three separate hand-built task lists
 (`buildLlamaArch` llama2-tasks.cpp:241-298, `buildGrok1Arch`
 grok1-tasks.cpp:275-354, `buildMixtralArch` mixtral-tasks.cpp:5-78), and
-those of OLMoE (`ARCH_OLMOE`, beyond the reference).
+those of OLMoE (`ARCH_OLMOE`, beyond the reference).  DeepSeek-V2
+(`ARCH_DEEPSEEK2`) states its sizes in the header (`io/mfile.py EXT_KEYS`) and
+they are fields here, not properties of the id.
 """
 
 from __future__ import annotations
 
+import math
 from dataclasses import dataclass, replace
 
 import jax.numpy as jnp
@@ -47,10 +50,66 @@ class ModelConfig:
     # sequence-sharded q/k/v (ops/sp_attention.py) instead of the
     # cache-reading one-round combine — O(T/sp) activation memory
     ring_prefill: bool = False
+    # ---- ARCH_DEEPSEEK2 (header keys 14..31); 0 / 1.0 = the arch has none
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0           # > 0: latent attention (MLA)
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    moe_hidden_dim: int = 0         # one routed expert's width (else hidden_dim)
+    n_shared_experts: int = 0       # the shared expert is this many experts wide
+    n_groups: int = 0               # > 1: experts chosen by group
+    topk_groups: int = 0
+    n_dense_layers: int = 0         # leading layers with a dense FFN
+    routed_scale: float = 1.0
+    rope_factor: float = 1.0        # > 1: YaRN frequencies
+    rope_orig_seq_len: int = 0
+    rope_beta_fast: float = 32.0
+    rope_beta_slow: float = 1.0
+    rope_mscale: float = 1.0
+    rope_mscale_all_dim: float = 0.0
+    norm_eps: float = 1e-5
 
     @property
     def head_size(self) -> int:
         return self.dim // self.n_heads
+
+    @property
+    def is_mla(self) -> bool:
+        return self.kv_lora_rank > 0
+
+    @property
+    def latent_dim(self) -> int:
+        """What MLA caches of a token in a layer: the normed latent and the
+        one rotated key all heads share (512 + 64 for DeepSeek-V2)."""
+        return self.kv_lora_rank + self.qk_rope_head_dim
+
+    @property
+    def qk_head_dim(self) -> int:
+        return self.qk_nope_head_dim + self.qk_rope_head_dim
+
+    @property
+    def expert_dim(self) -> int:
+        return self.moe_hidden_dim or self.hidden_dim
+
+    @property
+    def n_moe_layers(self) -> int:
+        return self.n_layers - self.n_dense_layers if self.is_moe else 0
+
+    @property
+    def kv_values_per_token(self) -> int:
+        """Values one cached token occupies in one layer."""
+        return self.latent_dim if self.is_mla else 2 * self.kv_dim
+
+    @property
+    def attn_scale(self) -> float:
+        """MLA's softmax scale: ``qk_head_dim^-1/2 * mscale^2`` with YaRN's
+        ``mscale = 0.1 * mscale_all_dim * ln(factor) + 1``."""
+        scale = self.qk_head_dim ** -0.5
+        if self.rope_factor > 1.0 and self.rope_mscale_all_dim:
+            m = 0.1 * self.rope_mscale_all_dim * math.log(self.rope_factor) + 1.0
+            scale *= m * m
+        return scale
 
     @property
     def kv_dim(self) -> int:
@@ -63,8 +122,9 @@ class ModelConfig:
     @property
     def rope_interleaved(self) -> bool:
         """Llama uses adjacent-pair RoPE; Grok-1/Mixtral use the rotate-half
-        ("Falcon") convention (transformer.cpp:227-231)."""
-        return self.arch == mfile.ARCH_LLAMA
+        ("Falcon") convention (transformer.cpp:227-231); a DeepSeek-V2 file
+        keeps the published rows, whose rotated part is in adjacent pairs."""
+        return self.arch in (mfile.ARCH_LLAMA, mfile.ARCH_DEEPSEEK2)
 
     @property
     def add_bos(self) -> bool:
@@ -98,8 +158,9 @@ class ModelConfig:
     def norm_topk_prob(self) -> bool:
         """Mixtral and Grok-1 renormalise the chosen experts' probabilities
         to sum to 1 (grok1-tasks.cpp:60-114); OLMoE (``norm_topk_prob:
-        false``) uses them as the softmax over all experts gave them."""
-        return self.arch != mfile.ARCH_OLMOE
+        false``) uses them as the softmax over all experts gave them, and so
+        does DeepSeek-V2, times ``routed_scale``."""
+        return self.arch not in (mfile.ARCH_OLMOE, mfile.ARCH_DEEPSEEK2)
 
     @classmethod
     def from_spec(cls, spec: mfile.ModelSpec, dtype=jnp.float32) -> "ModelConfig":
@@ -109,7 +170,8 @@ class ModelConfig:
             n_kv_heads=spec.n_kv_heads, n_experts=spec.n_experts,
             n_active_experts=spec.n_active_experts, vocab_size=spec.vocab_size,
             seq_len=spec.seq_len, hidden_act=spec.hidden_act,
-            rope_theta=spec.rope_theta, dtype=dtype)
+            rope_theta=spec.rope_theta, dtype=dtype,
+            **{name: getattr(spec, name) for _, name, _ in mfile.EXT_KEYS})
 
     def with_(self, **kw) -> "ModelConfig":
         return replace(self, **kw)
@@ -118,11 +180,29 @@ class ModelConfig:
 def tiny_config(arch=mfile.ARCH_LLAMA, *, dim=64, hidden_dim=96, n_layers=2,
                 n_heads=4, n_kv_heads=2, n_experts=0, n_active_experts=0,
                 vocab_size=128, seq_len=64, hidden_act=mfile.ACT_SILU,
-                rope_theta=10000.0, dtype=jnp.float32) -> ModelConfig:
+                rope_theta=10000.0, dtype=jnp.float32, **ext) -> ModelConfig:
     """Small config for tests — the analogue of the reference's hand-sized
     test fixtures (llama2-tasks-test.cpp:528-554)."""
     return ModelConfig(arch=arch, dim=dim, hidden_dim=hidden_dim,
                        n_layers=n_layers, n_heads=n_heads, n_kv_heads=n_kv_heads,
                        n_experts=n_experts, n_active_experts=n_active_experts,
                        vocab_size=vocab_size, seq_len=seq_len,
-                       hidden_act=hidden_act, rope_theta=rope_theta, dtype=dtype)
+                       hidden_act=hidden_act, rope_theta=rope_theta, dtype=dtype,
+                       **ext)
+
+
+def tiny_deepseek2(**kw) -> ModelConfig:
+    """DeepSeek-V2 at a toy size that keeps every ratio: a dense prefix and
+    expert layers, 8 groups of which 3 are kept, top-6, two shared experts, a
+    rotated part narrower than the head, YaRN past its original length."""
+    base = dict(arch=mfile.ARCH_DEEPSEEK2, dim=64, hidden_dim=96, n_layers=3,
+                n_heads=4, n_kv_heads=4, n_experts=32, n_active_experts=6,
+                vocab_size=128, seq_len=64, q_lora_rank=64, kv_lora_rank=32,
+                qk_nope_head_dim=16, qk_rope_head_dim=8, v_head_dim=16,
+                moe_hidden_dim=32, n_shared_experts=2, n_groups=8,
+                topk_groups=3, n_dense_layers=1, routed_scale=16.0,
+                rope_factor=40.0, rope_orig_seq_len=16, rope_beta_fast=32.0,
+                rope_beta_slow=1.0, rope_mscale=0.707,
+                rope_mscale_all_dim=0.707, norm_eps=1e-6)
+    base.update(kw)
+    return tiny_config(**base)

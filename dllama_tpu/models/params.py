@@ -25,7 +25,49 @@ from .config import ModelConfig
 Params = dict  # pytree: str -> array (numpy until placed) | q40.QTensor
 
 
+# DeepSeek-V2's stacks by the layers they cover: attention and block norms
+# all L, the dense FFN the leading ``n_dense_layers``, router / experts /
+# shared expert the rest.  A stack's leading index is a layer's index WITHIN
+# its segment (models/transformer.py run_blocks).
+MLA_ATT_KEYS = ("wq_a", "wkv_a", "wqkv_a", "q_a_norm", "wq_b", "kv_a_norm",
+                "wkv_b", "wo", "rms_att", "rms_ffn")
+DENSE_FFN_KEYS = ("w1", "w2", "w3", "w13")
+MOE_FFN_KEYS = ("router", "up", "gate", "down", "shared_w1", "shared_w2",
+                "shared_w3", "shared_w13")
+
+
+def _mla_param_shapes(cfg: ModelConfig) -> dict[str, tuple[int, ...]]:
+    L, D, V, H = cfg.n_layers, cfg.dim, cfg.vocab_size, cfg.n_heads
+    Ld, Le = cfg.n_dense_layers, cfg.n_moe_layers
+    r, ql = cfg.kv_lora_rank, cfg.q_lora_rank
+    shapes = {
+        "embedding": (V, D),
+        "wq_a": (L, D, ql), "q_a_norm": (L, ql),
+        "wq_b": (L, ql, H * cfg.qk_head_dim),
+        "wkv_a": (L, D, cfg.latent_dim), "kv_a_norm": (L, r),
+        # kept whole: a head's columns are its k_nope then its v
+        "wkv_b": (L, r, H * (cfg.qk_nope_head_dim + cfg.v_head_dim)),
+        "wo": (L, H * cfg.v_head_dim, D),
+        "rms_att": (L, D), "rms_ffn": (L, D),
+        "rms_final": (D,), "wcls": (D, V),
+    }
+    if Ld:
+        F = cfg.hidden_dim
+        shapes.update({"w1": (Ld, D, F), "w2": (Ld, F, D), "w3": (Ld, D, F)})
+    if Le:
+        E, F = cfg.n_experts, cfg.expert_dim
+        shapes.update({"router": (Le, D, E), "up": (Le, E, D, F),
+                       "gate": (Le, E, D, F), "down": (Le, E, F, D)})
+        if cfg.n_shared_experts:
+            Fs = F * cfg.n_shared_experts
+            shapes.update({"shared_w1": (Le, D, Fs), "shared_w2": (Le, Fs, D),
+                           "shared_w3": (Le, D, Fs)})
+    return shapes
+
+
 def param_shapes(cfg: ModelConfig) -> dict[str, tuple[int, ...]]:
+    if cfg.is_mla:
+        return _mla_param_shapes(cfg)
     L, D, F, V = cfg.n_layers, cfg.dim, cfg.hidden_dim, cfg.vocab_size
     Hq = cfg.n_heads * cfg.head_size       # == D
     Hkv = cfg.n_kv_heads * cfg.head_size   # == kv_dim
@@ -63,7 +105,7 @@ def init_params(cfg: ModelConfig, seed: int = 0, scale: float = 0.02) -> Params:
     rng = np.random.RandomState(seed)
     params: Params = {}
     for name, shape in param_shapes(cfg).items():
-        norm = name.startswith("rms") or name in ("q_norm", "k_norm")
+        norm = name.startswith("rms") or name.endswith("_norm")
         if norm:
             x = np.ones(shape, dtype=np.float32)
         else:
@@ -117,6 +159,8 @@ def quantize_matmuls(params: Params, cfg: ModelConfig,
     ``fuse=True`` additionally concatenates q/k/v (and w1/w3) output dims
     into single ``wqkv``/``w13`` tensors — see load_params."""
     out = dict(params)
+    if cfg.is_mla:
+        return _quantize_mla(out, fuse)
     if fuse:
         out["wqkv"] = q40.quantize(np.concatenate(
             [np.asarray(params[k], np.float32) for k in ("wq", "wk", "wv")], axis=-1))
@@ -138,25 +182,48 @@ def quantize_matmuls(params: Params, cfg: ModelConfig,
     return out
 
 
-def _stack_q_experts(mf: mfile.MFile, cfg: ModelConfig, fname: str, codec=q40):
+def _quantize_mla(out: Params, fuse: bool) -> Params:
+    """:func:`quantize_matmuls` for a DeepSeek-V2 pytree: every matrix packs
+    but ``wkv_b`` (the absorbed form multiplies it head by head, so it stays
+    dense) and the router; ``fuse`` joins the two down-projections from x
+    (``wqkv_a``) and each SwiGLU's gate and up."""
+    def f32(k):
+        return np.asarray(out.pop(k), np.float32)
+
+    if fuse:
+        out["wqkv_a"] = q40.quantize(np.concatenate([f32("wq_a"), f32("wkv_a")], -1))
+        for pre in ("w", "shared_w"):
+            if pre + "1" in out:
+                out[pre + "13"] = q40.quantize(
+                    np.concatenate([f32(pre + "1"), f32(pre + "3")], -1))
+    for k in ("wq_a", "wkv_a", "wq_b", "wo", "wcls", "w1", "w2", "w3", "up",
+              "gate", "down", "shared_w1", "shared_w2", "shared_w3"):
+        if k in out:
+            out[k] = q40.quantize(np.asarray(out[k], np.float32))
+    return out
+
+
+def _stack_q_experts(mf: mfile.MFile, cfg: ModelConfig, fname: str, codec=q40,
+                     layers: range | None = None):
     """Layer×expert-stacked packed expert weights (Q40 or Q80 ``codec``),
     filled tensor by tensor into preallocated host arrays — no f32
     materialization and no transient double-buffering, so host RAM transit
     is bounded by the packed size (~0.69 B/weight for Q40).  Replaces the
     dense f32 expert loading that made Mixtral-8x7B (~90 GB f32 transit)
     unloadable (VERDICT r01)."""
-    L, E = cfg.n_layers, cfg.n_experts
-    t0 = mf.info(f"layers.0.experts.0.{fname}")
+    layers = range(cfg.n_layers) if layers is None else layers
+    L, E = len(layers), cfg.n_experts
+    t0 = mf.info(f"layers.{layers[0]}.experts.0.{fname}")
     d = int(np.prod(t0.shape[:-1]))
     n = t0.shape[-1]
     np_ = codec.padded_n(n)
     qp = codec.alloc_value_plane((L, E), np_, d)
     cls = codec.Tensor
     sc = np.zeros((L, E, np_ // 32, d), np.float16)
-    for l in range(L):
+    for l, layer in enumerate(layers):
         for e in range(E):
             codec.repack_file_bytes_into(
-                mf.raw(f"layers.{l}.experts.{e}.{fname}"), d, n, qp[l, e], sc[l, e])
+                mf.raw(f"layers.{layer}.experts.{e}.{fname}"), d, n, qp[l, e], sc[l, e])
     if not np.isfinite(sc).all():  # same loud-failure rule as pack_file_groups
         raise ValueError(f"{fname}: expert scale plane contains inf/NaN f16 "
                          "scales — corrupt or overflowed .m tensor")
@@ -207,6 +274,9 @@ def _read_params(mf: mfile.MFile, cfg: ModelConfig, dtype,
     L = cfg.n_layers
     p: Params = {}
     p["embedding"] = mf.tensor("token_embedding").astype(np_dtype)
+    if cfg.is_mla:
+        _read_mla_layers(mf, cfg, p, np_dtype, codec if quant else None, fuse)
+        return _read_tail(mf, p, np_dtype, codec if quant else None)
     if quant and fuse:
         p["wqkv"] = _stack_q(
             mf, [[f"layers.{i}.wq", f"layers.{i}.wk", f"layers.{i}.wv"]
@@ -249,6 +319,69 @@ def _read_params(mf: mfile.MFile, cfg: ModelConfig, dtype,
     else:
         for key in ("w1", "w2", "w3"):
             p[key] = _stack(mf, [f"layers.{i}.{key}" for i in range(L)], True, np_dtype)
+    return _read_tail(mf, p, np_dtype, codec if quant else None)
+
+
+def _read_mla_layers(mf: mfile.MFile, cfg: ModelConfig, p: Params, np_dtype,
+                     codec, fuse: bool) -> None:
+    """A DeepSeek-V2 file's layer stacks into ``p`` (``codec`` None: dense).
+    ``wkv_b`` is dequantized once, here, whatever the file's type: the
+    absorbed form multiplies it head by head, which no packed kernel does."""
+    att = range(cfg.n_layers)
+    dense = range(cfg.n_dense_layers)
+    moe = range(cfg.n_dense_layers, cfg.n_layers)
+
+    def mats(keys, layers):
+        for key in keys:
+            fnames = [f"layers.{i}.{key}" for i in layers]
+            p[key] = (_stack_q(mf, fnames, codec) if codec
+                      else _stack(mf, fnames, True, np_dtype))
+
+    def fused(key, a, b, layers):
+        p[key] = _stack_q(mf, [[f"layers.{i}.{a}", f"layers.{i}.{b}"]
+                               for i in layers], codec)
+
+    def vecs(keys, layers):
+        for key in keys:
+            p[key] = _stack(mf, [f"layers.{i}.{key}" for i in layers], False,
+                            np.float32)
+
+    join = codec is not None and fuse
+    if join:
+        fused("wqkv_a", "wq_a", "wkv_a", att)
+    else:
+        mats(("wq_a", "wkv_a"), att)
+    mats(("wq_b", "wo"), att)
+    p["wkv_b"] = _stack(mf, [f"layers.{i}.wkv_b" for i in att], True, np_dtype)
+    vecs(("q_a_norm", "kv_a_norm", "rms_att", "rms_ffn"), att)
+    if len(dense):
+        if join:
+            fused("w13", "w1", "w3", dense)
+            mats(("w2",), dense)
+        else:
+            mats(("w1", "w2", "w3"), dense)
+    if not len(moe):
+        return
+    p["router"] = _stack(mf, [f"layers.{i}.moe_router" for i in moe], True,
+                         np_dtype)
+    for key in ("up", "gate", "down"):
+        if codec:
+            p[key] = _stack_q_experts(mf, cfg, key, codec, layers=moe)
+        else:
+            p[key] = np.stack([np.stack([
+                np.ascontiguousarray(mf.tensor(f"layers.{i}.experts.{e}.{key}").T)
+                for e in range(cfg.n_experts)]) for i in moe]).astype(np_dtype)
+    if cfg.n_shared_experts:
+        if join:
+            fused("shared_w13", "shared_w1", "shared_w3", moe)
+            mats(("shared_w2",), moe)
+        else:
+            mats(("shared_w1", "shared_w2", "shared_w3"), moe)
+
+
+def _read_tail(mf: mfile.MFile, p: Params, np_dtype, codec) -> Params:
+    """The final norm and the head, which every arch ends with."""
+    quant = codec is not None
     p["rms_final"] = mf.tensor("rms_final").astype(np.float32)
     if quant:
         tw = mf.info("wcls")

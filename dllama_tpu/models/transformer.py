@@ -1,4 +1,5 @@
-"""Unified transformer forward pass: Llama / Mixtral / Grok-1.
+"""Unified transformer forward pass: Llama / Mixtral / Grok-1 / OLMoE /
+DeepSeek-V2.
 
 One function serves prefill (T > 1) and decode (T == 1): tokens enter as
 ``(B, T)``, the KV cache as ``(L, B, Hkv, S, Dh)`` pairs, and ``pos`` is a
@@ -30,7 +31,7 @@ import jax
 import jax.numpy as jnp
 
 from ..obs import dispatch as obs_dispatch
-from ..ops import q40, q8
+from ..ops import mla, q40, q8
 from ..ops.attention import (gqa_attention_at, paged_gqa_attention_at,
                              paged_update_kv_rows, paged_write_indices,
                              quantize_kv, slot_gqa_attention_at,
@@ -40,7 +41,7 @@ from ..ops.scopes import part, scope
 from ..ops.sp_attention import ring_attention, sp_gqa_attention, sp_update_kv_cache_at
 from ..parallel.mesh import get_active_mesh
 from .config import ModelConfig
-from .params import Params
+from .params import DENSE_FFN_KEYS, MLA_ATT_KEYS, MOE_FFN_KEYS, Params
 
 
 # Quantized-MoE prefill unrolls the per-expert loop statically up to this
@@ -65,6 +66,15 @@ class KVCache(NamedTuple):
     def quantized(self) -> bool:
         return self.k_scale is not None
 
+    @property
+    def latent(self) -> bool:
+        return self.k.ndim == 4
+
+    def planes(self) -> dict[str, jax.Array]:
+        """The arrays this cache has, by field name: what a snapshot, a spill
+        or a hand-off record carries, whatever the cache's kind."""
+        return {n: a for n, a in self._asdict().items() if a is not None}
+
 
 def init_kv_cache(cfg: ModelConfig, batch: int, seq_len: int | None = None,
                   dtype=None, quant: bool = False) -> KVCache:
@@ -81,6 +91,8 @@ def init_kv_cache(cfg: ModelConfig, batch: int, seq_len: int | None = None,
     the HBM read stays int8-sized).
     """
     s = seq_len or cfg.seq_len
+    if cfg.is_mla:
+        return _init_latent((cfg.n_layers, batch, s), cfg, dtype, quant)
     shape = (cfg.n_layers, batch, cfg.n_kv_heads, s, cfg.head_size)
     if quant:
         sshape = shape[:-1] + (1,)
@@ -95,6 +107,20 @@ def init_kv_cache(cfg: ModelConfig, batch: int, seq_len: int | None = None,
 # DLREQ01 fingerprints (runtime/engine.py), so a file written with another
 # order is refused even where the sizes coincide
 PAGE_AXES = "ps,Hkv,Dh"
+LATENT_PAGE_AXES = "ps,r|ps,rope"  # a latent (MLA) pool's page, plane by plane
+
+
+def _init_latent(lead, cfg: ModelConfig, dtype, quant: bool) -> KVCache:
+    """MLA's cache in either form, ``lead`` = (L, B, S) or (L, P, ps):
+    ``kv_lora_rank + qk_rope_head_dim`` values a token a layer in two planes
+    (ops/mla.py has why two), nothing per head."""
+    if quant:
+        raise ValueError("a latent (MLA) cache has no int8 form yet: the "
+                         "latent and the rotated key want a scale each "
+                         "(--kv-quant int8 is refused for this architecture)")
+    dt = dtype or cfg.dtype
+    return KVCache(jnp.zeros(lead + (cfg.kv_lora_rank,), dt),
+                   jnp.zeros(lead + (cfg.qk_rope_head_dim,), dt))
 
 
 def init_kv_pool(cfg: ModelConfig, n_pages: int, page_size: int,
@@ -115,6 +141,9 @@ def init_kv_pool(cfg: ModelConfig, n_pages: int, page_size: int,
     (same quantize_kv absmax math, same ~2× HBM saving), so a pool page
     is self-describing: values and scales always travel together through
     spills, snapshots and DLREQ01 hand-offs."""
+    if cfg.is_mla:
+        # (L, P, ps, ·): the same token-major page, one row a token a plane
+        return _init_latent((cfg.n_layers, n_pages, page_size), cfg, dtype, quant)
     shape = (cfg.n_layers, n_pages, page_size, cfg.n_kv_heads, cfg.head_size)
     if quant:
         sshape = shape[:-1] + (1,)
@@ -280,6 +309,68 @@ def _attend(q, k, v, cache: KVCache, cfg: ModelConfig, pos, t, layer, offsets,
         scales=((cache.k_scale, cache.v_scale) if cache.quantized else None))
 
 
+def _mla_attention_block(x, lp, cfg: ModelConfig, cache: KVCache, cos, sin,
+                         pos, layer, offsets=None, pos_rows=None, paged=None):
+    """DeepSeek-V2's attention sub-block (ops/mla.py has the two forms).  The
+    layer writes its tokens' latent rows into the stacked cache in place and
+    reads the live part back; per-head keys and values exist only inside the
+    attention call."""
+    b, t, _ = x.shape
+    h, r, dn = cfg.n_heads, cfg.kv_lora_rank, cfg.qk_nope_head_dim
+    eps = cfg.norm_eps
+    with scope("norm"):
+        xb = rmsnorm(x, lp["rms_att"], eps)
+    with scope("qkv"):
+        with part("kv_lora"):
+            if "wqkv_a" in lp:  # both down-projections from x: one launch
+                c_q, ckv = jnp.split(_mm(xb, lp["wqkv_a"], cfg),
+                                     [cfg.q_lora_rank], axis=-1)
+            else:
+                ckv = _mm(xb, lp["wkv_a"], cfg)
+            c_kv = rmsnorm(ckv[..., :r], lp["kv_a_norm"], eps)
+        with part("q_lora"):
+            if "wqkv_a" not in lp:
+                c_q = _mm(xb, lp["wq_a"], cfg)
+            q = _mm(rmsnorm(c_q, lp["q_a_norm"], eps), lp["wq_b"], cfg)
+            q = q.reshape(b, t, h, cfg.qk_head_dim)
+    with scope("rope"):
+        # adjacent pairs, as the published rows have them; one key for all heads
+        q = jnp.concatenate(
+            [q[..., :dn], apply_rope(q[..., dn:], cos, sin, interleaved=True)],
+            -1)
+        k_pe = apply_rope(ckv[..., None, r:], cos, sin, interleaved=True)[..., 0, :]
+    page_table = paged[0] if paged is not None else None
+
+    def write(plane, rows):
+        if paged is not None:
+            return mla.write_paged(plane, rows, layer, *paged[1:])
+        if pos_rows is not None:
+            return mla.write_rows(plane, rows, layer, pos_rows)
+        return mla.write_at(plane, rows, layer, pos)
+
+    with scope("kv_write"):
+        cache = KVCache(write(cache.k, c_kv), write(cache.v, k_pe))
+    with scope("attn"):
+        w_kvb = lp["wkv_b"].reshape(r, h, dn + cfg.v_head_dim)
+        att = mla.attention(q, cache.k, cache.v, w_kvb, cfg, layer, pos=pos,
+                            pos_rows=pos_rows, page_table=page_table,
+                            floor=offsets)
+    with scope("wo"):
+        return _mm(att, lp["wo"], cfg, kind="col"), cache
+
+
+def _swiglu(xb, lp, cfg: ModelConfig, pre: str):
+    """``W2 (act(W1 x) * W3 x)`` of the weights ``pre + "1"`` ... (or the
+    fused ``pre + "13"``), under the caller's scope."""
+    act = ACTIVATIONS[cfg.hidden_act]
+    if pre + "13" in lp:
+        h1, h3 = jnp.split(_mm(xb, lp[pre + "13"], cfg), 2, axis=-1)
+    else:
+        h1 = _mm(xb, lp[pre + "1"], cfg, kind="row")
+        h3 = _mm(xb, lp[pre + "3"], cfg, kind="row")
+    return _mm(act(h1) * h3, lp[pre + "2"], cfg, kind="col")
+
+
 def _dense_ffn(xb, lp, cfg: ModelConfig):
     act = ACTIVATIONS[cfg.hidden_act]
     if "w13" in lp:  # fused gate+up (quantized load)
@@ -297,12 +388,27 @@ def _dense_ffn(xb, lp, cfg: ModelConfig):
 
 
 def moe_ffn(xb2d: jax.Array, lp, cfg: ModelConfig) -> jax.Array:
+    """The routed experts (:func:`_routed_experts`, every strategy) plus,
+    where the layer has one (DeepSeek-V2), the shared expert every row takes:
+    sub-scope ``shared`` inside ``moe``."""
+    out = _routed_experts(xb2d, lp, cfg)
+    if "shared_w2" in lp:
+        with part("shared"):
+            out = out + _swiglu(xb2d, lp, cfg, "shared_w")
+    return out
+
+
+def _routed_experts(xb2d: jax.Array, lp, cfg: ModelConfig) -> jax.Array:
     """Mixture-of-experts FFN (grok1-tasks.cpp:56-228 semantics).
 
     Routing: softmax over *all* expert logits, top-k, renormalize the
     selected probabilities (grokMoeRouterSoftmax/Topk/NormWeights,
     grok1-tasks.cpp:60-114); OLMoE (``not cfg.norm_topk_prob``) uses the
-    selected probabilities as they are.
+    selected probabilities as they are.  DeepSeek-V2 (``cfg.n_groups > 1``)
+    chooses in two stages, ``topk_groups`` groups by their best expert and
+    then the top-k of the experts in them, and scales the chosen
+    probabilities by ``cfg.routed_scale``; every strategy below takes its
+    experts and weights from this one choice.
 
     Sub-scopes inside ``moe``: ``router`` (logits, softmax, top-k),
     ``experts`` (the expert matmuls and, on the scan, its bookkeeping),
@@ -343,10 +449,21 @@ def moe_ffn(xb2d: jax.Array, lp, cfg: ModelConfig) -> jax.Array:
         router = lp["router"]
         router_logits = xb2d.astype(jnp.float32) @ router.astype(jnp.float32)  # (N, E)
         probs = softmax_f32(router_logits)
+        if cfg.n_groups > 1:
+            # a group's score is its best expert's; experts outside the
+            # topk_groups best groups are out of the top-k (their p set to 0)
+            best = probs.reshape(n, cfg.n_groups, -1).max(-1)
+            _, gidx = jax.lax.top_k(best, cfg.topk_groups)
+            kept = jnp.put_along_axis(jnp.zeros(best.shape, bool), gidx, True,
+                                      axis=-1, inplace=False)
+            probs = jnp.where(jnp.repeat(kept, e // cfg.n_groups, axis=-1),
+                              probs, 0.0)
         top_vals, top_idx = jax.lax.top_k(probs, k)  # (N, k)
         weights = top_vals
         if cfg.norm_topk_prob:
             weights = top_vals / jnp.sum(top_vals, axis=-1, keepdims=True)
+        if cfg.routed_scale != 1.0:
+            weights = weights * jnp.float32(cfg.routed_scale)
 
     quant = isinstance(lp["up"], (q40.QTensor, q40.QLayerView))
 
@@ -491,7 +608,14 @@ def run_blocks(params: Params, cfg: ModelConfig, tokens: jax.Array,
             # values are garbage either way and masked out of every live
             # row's view)
             positions = jnp.maximum(positions[None, :] - offsets[:, None], 0)
-        cos, sin = rope_angles(positions, cfg.head_size, cfg.rope_theta)  # (T, Dh/2)
+        if cfg.is_mla:
+            cos, sin = mla.rope_angles(positions, cfg)
+        else:
+            cos, sin = rope_angles(positions, cfg.head_size, cfg.rope_theta)  # (T, Dh/2)
+
+    if cfg.is_mla:
+        return _run_segments(params, cfg, x, cache, cos, sin, pos, offsets,
+                             pos_rows, paged)
 
     layer_keys = [k for k in params if k not in ("embedding", "rms_final", "wcls")]
     # Packed-Q40 weights stay out of the scan's xs: the scan would slice a
@@ -547,9 +671,59 @@ def run_blocks(params: Params, cfg: ModelConfig, tokens: jax.Array,
     return x, cache
 
 
+def _run_segments(params: Params, cfg: ModelConfig, x, cache: KVCache, cos,
+                  sin, pos, offsets, pos_rows, paged):
+    """DeepSeek-V2's layers: a dense prefix and an expert segment, each one
+    ``lax.scan`` over its own FFN stack, sharing the attention stacks (indexed
+    by the running layer), the cache and the residual stream.  No stack rides
+    a scan's xs: a layer's slice is indexed where it is used (packed weights
+    through a ``QLayerView``, as in :func:`run_blocks`)."""
+    b, t, _ = x.shape
+
+    def at(w, i):
+        if isinstance(w, (q40.QTensor, q8.Q8Tensor)):
+            return q40.QLayerView(w, i)
+        return jax.lax.dynamic_index_in_dim(w, i, 0, keepdims=False)
+
+    def segment(carry, first: int, count: int, ffn_keys, ffn):
+        def block(carry, i):
+            x, kvc = carry
+            layer = i + first
+            lp = {k: at(params[k], layer) for k in MLA_ATT_KEYS if k in params}
+            lp.update({k: at(params[k], i) for k in ffn_keys if k in params})
+            att_out, kvc = _mla_attention_block(
+                x, lp, cfg, kvc, cos, sin, pos, layer, offsets=offsets,
+                pos_rows=pos_rows, paged=paged)
+            with scope("wo"):
+                x = x + att_out
+            with scope("norm"):
+                xb = rmsnorm(x, lp["rms_ffn"], cfg.norm_eps)
+            return (ffn(x, xb, lp), kvc), None
+
+        return jax.lax.scan(block, carry, jnp.arange(count, dtype=jnp.int32))[0]
+
+    def dense(x, xb, lp):
+        ff = _dense_ffn(xb, lp, cfg)
+        with scope("w2"):
+            return x + ff
+
+    def experts(x, xb, lp):
+        with scope("moe"):
+            return x + moe_ffn(xb.reshape(b * t, cfg.dim), lp,
+                               cfg).reshape(b, t, cfg.dim)
+
+    carry = (x, cache)
+    if cfg.n_dense_layers:
+        carry = segment(carry, 0, cfg.n_dense_layers, DENSE_FFN_KEYS, dense)
+    if cfg.n_moe_layers:
+        carry = segment(carry, cfg.n_dense_layers, cfg.n_moe_layers,
+                        MOE_FFN_KEYS, experts)
+    return carry
+
+
 def _head(params: Params, cfg: ModelConfig, x: jax.Array) -> jax.Array:
     with scope("norm"):
-        x = rmsnorm(x, params["rms_final"])
+        x = rmsnorm(x, params["rms_final"], cfg.norm_eps)
     with scope("head"):
         # out_dtype=f32 keeps the matmul's f32 accumulation for the sampler
         # instead of a round trip through the bf16 activation dtype

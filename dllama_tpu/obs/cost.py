@@ -239,7 +239,9 @@ class CostModel:
                  kv_codec: str = "kv_f32", kv_el_bytes: int = 4,
                  tp: int = 1, paged: bool = False, page_size: int = 0,
                  n_experts: int = 0, n_active_experts: int = 0,
-                 fused: bool = False):
+                 fused: bool = False, n_dense_layers: int = 0,
+                 moe_hidden_dim: int = 0, n_shared_experts: int = 0,
+                 mla: dict | None = None):
         self.dim = dim
         self.hidden_dim = hidden_dim
         self.n_layers = n_layers
@@ -266,8 +268,34 @@ class CostModel:
         if self.moe:
             ffn *= n_active_experts
         attn = 2 * dim * dim + 2 * dim * self.kv_dim  # wq+wo, wk+wv
+        #: values one cached position holds in one layer, and FLOPs of one
+        #: (query, context) pair in one layer (QK^T + weighted V sum)
+        self.kv_values = 2 * self.kv_dim
+        self.pair_flops = 4 * dim
+        #: latent attention (MLA): ``mla`` holds q_lora_rank, kv_lora_rank,
+        #: qk_nope_head_dim, qk_rope_head_dim, v_head_dim.  A cached position
+        #: is one latent and one rotated key for all heads, and a pair costs
+        #: the absorbed form's two products over them (W_kvb's absorb is in
+        #: the per-token matmuls)
+        self.mla = mla
+        if mla:
+            r, dr = mla["kv_lora_rank"], mla["qk_rope_head_dim"]
+            dn, dv, ql = (mla["qk_nope_head_dim"], mla["v_head_dim"],
+                          mla["q_lora_rank"])
+            attn = (dim * ql + ql * n_heads * (dn + dr) + dim * (r + dr)
+                    + r * n_heads * (dn + dv) + n_heads * dv * dim)
+            self.kv_values = r + dr
+            self.pair_flops = 2 * n_heads * (2 * r + dr)
         #: matmul weights touched per token (logits head separate)
         self.params_per_token = n_layers * (attn + ffn)
+        if n_dense_layers and self.moe:
+            # two layer kinds: a dense FFN in the leading layers, and in the
+            # rest the token's routed experts plus the shared expert
+            fe = moe_hidden_dim or hidden_dim
+            moe_ffn = 3 * dim * fe * (n_active_experts + n_shared_experts)
+            self.params_per_token = (
+                n_layers * attn + n_dense_layers * 3 * dim * hidden_dim
+                + (n_layers - n_dense_layers) * moe_ffn)
 
     # --- building blocks (all return ints) -------------------------------
 
@@ -300,14 +328,14 @@ class CostModel:
     def attn_flops(self, pos: int, n_new: int) -> int:
         """QK^T + weighted V sum: 4 * dim MACs -> FLOPs per (query,
         context) pair, per layer."""
-        return 4 * self.dim * self.n_layers * self._ctx_sum(pos, n_new)
+        return self.pair_flops * self.n_layers * self._ctx_sum(pos, n_new)
 
     def kv_pos_bytes(self) -> int:
         """Bytes one (k, v) position occupies in one layer."""
         if self.kv_codec == "kv_int8":
             # 1 B values + per-(head, position) f32 scale planes
             return 2 * (self.kv_dim + 4 * self.n_kv_heads)
-        return 2 * self.kv_dim * self.kv_el_bytes
+        return self.kv_values * self.kv_el_bytes
 
     def kv_write_bytes(self, n_new: int) -> int:
         return n_new * self.n_layers * self.kv_pos_bytes()
@@ -352,6 +380,8 @@ class CostModel:
                 "ring_bytes": self.ring_bytes(n_new)}
 
     def attn_path(self, phase: str) -> str:
+        if self.mla:
+            return "mla-absorbed"
         if not self.paged:
             return "attention"
         if phase == "decode":
@@ -440,7 +470,7 @@ def model_from_engine(engine) -> CostModel | None:
         except Exception:
             pass
         fused = False
-        if engine.paged:
+        if engine.paged and not cfg.is_mla:
             try:
                 # ask the attention ladder what the decode trace will
                 # actually pick for this geometry, so cost families
@@ -462,7 +492,14 @@ def model_from_engine(engine) -> CostModel | None:
             page_size=getattr(engine, "kv_page_size", 0) or 0,
             n_experts=getattr(cfg, "n_experts", 0) or 0,
             n_active_experts=getattr(cfg, "n_active_experts", 0) or 0,
-            fused=fused)
+            fused=fused, n_dense_layers=cfg.n_dense_layers,
+            moe_hidden_dim=cfg.moe_hidden_dim,
+            n_shared_experts=cfg.n_shared_experts,
+            mla=dict(q_lora_rank=cfg.q_lora_rank,
+                     kv_lora_rank=cfg.kv_lora_rank,
+                     qk_nope_head_dim=cfg.qk_nope_head_dim,
+                     qk_rope_head_dim=cfg.qk_rope_head_dim,
+                     v_head_dim=cfg.v_head_dim) if cfg.is_mla else None)
     except Exception:
         return None
 

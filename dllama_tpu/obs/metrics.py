@@ -668,6 +668,14 @@ HBM_BYTES_PEAK = REGISTRY.labeled_gauge(
 PARAM_BYTES_RESIDENT = REGISTRY.labeled_gauge(
     "param_bytes_resident", "device",
     "Per-device bytes of placed model parameters (addressable shards).")
+# set by runtime/engine.py once the cache is built, from the cache's own
+# arrays: 2 x kv heads x head size x element size x layers for a GQA cache
+# (plus scale planes for int8), layers x (kv_lora_rank + qk_rope_head_dim) x
+# element size for a latent (MLA) cache
+KV_BYTES_PER_TOKEN = REGISTRY.gauge(
+    "kv_bytes_per_token",
+    "Bytes one cached token occupies over all layers, in the contiguous "
+    "cache or the paged pool.")
 
 # scheduler goodput accounting (runtime/scheduler.py + obs/flight.py):
 # every millisecond between the scheduler's first and last dispatch lands

@@ -15,7 +15,9 @@ The tuple is an interface: the benchmark's readers
 the two.  An op whose name path carries none of these is ``unscoped``.
 
 Inside ``moe`` the work is split further by :func:`part` into ``router``,
-``experts`` and ``combine`` (``PARTS``): plain sub-names, not scopes.  An
+``experts``, ``combine`` and (DeepSeek-V2) ``shared``; inside ``qkv`` MLA's
+``q_lora`` / ``kv_lora``; inside ``attn`` MLA's ``absorb`` / ``latent`` /
+``expand`` (``PARTS``, by scope): plain sub-names, not scopes.  An
 op's path then ends ``.../moe/experts/...`` and a reader that knows only
 ``SCOPES`` still files it under ``moe``; ``by-scope.json``'s op table
 carries the whole path for the finer split.  Which strategy a compiled call
@@ -47,17 +49,31 @@ SCOPES = (
 )
 
 
-PARTS = (
-    "router",    # router logits, softmax, top-k, the dense weight table
-    "experts",   # the expert matmuls of every strategy, a scan's bookkeeping
-    "combine",   # the weighted sum over experts, the cast to the activation dtype
-)
+# sub-names by the scope they split; an arch that lacks the work lacks the name
+# (no dense-attention program has ``q_lora``, OLMoE's ``moe`` has no ``shared``)
+PARTS = {
+    "qkv": (
+        "q_lora",    # MLA: q's latent norm and the up-projection to the heads
+        "kv_lora",   # MLA: the down-projection(s) from x, the latent's norm
+    ),
+    "attn": (
+        "absorb",    # MLA absorbed form: W_uk into the query, W_uv out of the result
+        "latent",    # MLA absorbed form: the walk over latent rows
+        "expand",    # MLA expanded form: a block's rows through W_kvb, in the walk
+    ),
+    "moe": (
+        "router",    # router logits, softmax, (groups,) top-k, the dense weight table
+        "experts",   # the expert matmuls of every strategy, a scan's bookkeeping
+        "combine",   # the weighted sum over experts, the cast to the activation dtype
+        "shared",    # the shared expert every row takes, and its add
+    ),
+}
+_ALL_PARTS = frozenset(n for names in PARTS.values() for n in names)
 
 
 def part(name: str):
-    """``jax.named_scope(name)`` for a sub-name of :data:`PARTS` (inside
-    scope ``moe``)."""
-    if name not in PARTS:
+    """``jax.named_scope(name)`` for a sub-name of :data:`PARTS`."""
+    if name not in _ALL_PARTS:
         raise ValueError(f"{name!r} is not a part of {PARTS}")
     return jax.named_scope(name)
 

@@ -30,6 +30,7 @@ import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..models.config import ModelConfig
+from ..models.params import DENSE_FFN_KEYS, MLA_ATT_KEYS, MOE_FFN_KEYS
 
 REPL = P()
 
@@ -66,6 +67,11 @@ def check_tp_constraint(cfg: ModelConfig, tp: int) -> None:
 
 def param_specs(cfg: ModelConfig) -> dict[str, P]:
     """PartitionSpec per parameter (layer-stacked layouts from params.py)."""
+    if cfg.is_mla:
+        # one device (the engine refuses a tp / sp / ep mesh for this arch):
+        # every stack whole, whatever its fused or unfused name
+        return dict.fromkeys(("embedding", "rms_final", "wcls") + MLA_ATT_KEYS
+                             + DENSE_FFN_KEYS + MOE_FFN_KEYS, REPL)
     specs = {
         "embedding": REPL,                   # root-owned in the reference; replicated here
         "wq": P(None, None, "tp"),           # RowMatmulSlice: out dim = heads
@@ -119,14 +125,19 @@ def kv_cache_spec(seq_axis: str | None = None) -> P:
     return P(None, "dp", "tp", seq_axis, None)
 
 
-def kv_cache_sharding(mesh: Mesh, seq_axis: str | None = None) -> NamedSharding:
+def kv_cache_sharding(mesh: Mesh, seq_axis: str | None = None,
+                      latent: bool = False) -> NamedSharding:
+    if latent:  # two planes (L, B, S, ·): no head axis to put on tp
+        return NamedSharding(mesh, P(None, "dp", None, None))
     return NamedSharding(mesh, kv_cache_spec(seq_axis))
 
 
-def kv_pool_sharding(mesh: Mesh) -> NamedSharding:
+def kv_pool_sharding(mesh: Mesh, latent: bool = False) -> NamedSharding:
     """Paged pool (L, P, ps, Hkv, Dh): pages where the contiguous cache has
     its batch, kv heads on ``tp`` at axis 3 (a page is token-major,
-    models.transformer.init_kv_pool)."""
+    models.transformer.init_kv_pool).  A latent pool's planes are (L, P, ps, ·)."""
+    if latent:
+        return NamedSharding(mesh, P(None, "dp", None, None))
     return NamedSharding(mesh, P(None, "dp", None, "tp", None))
 
 

@@ -32,7 +32,8 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..models.config import ModelConfig
 from ..models.params import Params
-from ..models.transformer import PAGE_AXES, forward_last, init_kv_cache
+from ..models.transformer import (LATENT_PAGE_AXES, PAGE_AXES, forward_last,
+                                  init_kv_cache)
 from ..obs import dispatch as obs_dispatch, metrics as obs_metrics, \
     trace as obs_trace
 from ..obs.log import get_logger
@@ -222,6 +223,28 @@ class RunStats:
         return 1000.0 / g if g > 0 else 0.0
 
 
+def page_axes(cache) -> str:
+    """The axis order inside one pool page, by name, learned from the cache:
+    part of the snapshot and DLREQ01 fingerprints."""
+    return LATENT_PAGE_AXES if cache.latent else PAGE_AXES
+
+
+def _refuse_mla_unsupported(mesh, kv_dtype) -> None:
+    """What a latent-attention (MLA, DeepSeek-V2) model cannot do yet, refused
+    here by name and not discovered in a trace."""
+    for ax in ("tp", "sp", "ep"):
+        if mesh.shape.get(ax, 1) > 1:
+            raise ValueError(
+                f"latent attention (MLA) runs on one device: a {ax}={mesh.shape[ax]} "
+                "mesh is not supported for this architecture (the latent cache "
+                "would be replicated and the heads sharded; not wired)")
+    if kv_dtype == "q8" or (kv_dtype is not None
+                            and jnp.dtype(kv_dtype) == jnp.int8):
+        raise ValueError(
+            "--kv-quant int8 is not supported with latent attention (MLA): "
+            "the latent cache has no int8 form")
+
+
 class Engine:
     """Owns placed params, the KV cache, and the compiled step functions."""
 
@@ -268,6 +291,8 @@ class Engine:
                 raise ValueError(
                     f"n_experts {cfg.n_experts} not divisible by ep={ep}")
         self.cfg = cfg
+        if cfg.is_mla:
+            _refuse_mla_unsupported(self.mesh, kv_dtype)
         if self.mesh.shape.get("tp", 1) > 1 \
                 and jax.default_backend() != "tpu" \
                 and os.environ.get("DLLAMA_TP_REDUCE", "") != "psum":
@@ -303,7 +328,7 @@ class Engine:
         # the same sharding is pinned as jit out_shardings below so cache
         # placement and step outputs can never silently diverge
         self._cache_sh = sharding.kv_cache_sharding(
-            self.mesh, "sp" if self.sp > 1 else None)
+            self.mesh, "sp" if self.sp > 1 else None, latent=cfg.is_mla)
         # kv_pages > 0 replaces the per-slot contiguous cache with a paged
         # pool + per-slot page tables (ops/attention.py paged section):
         # memory is bounded by live tokens, not batch × seq_len, and the
@@ -329,7 +354,8 @@ class Engine:
             # pool layout (L, P, ps, Hkv, Dh): pages ride the batch ("dp")
             # axis, a page is token-major, so the kv-head axis that tp
             # shards is axis 3 — a spec of its own
-            self._cache_sh = sharding.kv_pool_sharding(self.mesh)
+            self._cache_sh = sharding.kv_pool_sharding(self.mesh,
+                                                       latent=cfg.is_mla)
             # --kv-quant int8: pool pages hold int8 values + per-position
             # f32 scale planes (the Q80 weight codec's trick applied to
             # pages); paged attention dequantizes after the int8-sized
@@ -348,6 +374,13 @@ class Engine:
                                       dtype=None if kv_quant else kv_dtype,
                                       quant=kv_quant),
                 self._cache_sh)
+        # what one cached token occupies over all layers, learned from the
+        # cache itself (a latent cache: layers x C x element size)
+        tokens = (self.kv_pages * self.kv_page_size if self.paged
+                  else batch * self.seq_len)
+        self.kv_bytes_per_token = sum(
+            int(a.nbytes) for a in self.cache.planes().values()) // tokens
+        obs_metrics.KV_BYTES_PER_TOKEN.set(self.kv_bytes_per_token)
         self.pos = 0
 
         def step(params, cache, tokens, pos, last_index, offsets=None):
@@ -421,17 +454,13 @@ class Engine:
             # pool geometry: a paged snapshot only means something in an
             # engine with the same page count/size (page ids are physical)
             # and the same axis order inside a page
-            "paged": [self.kv_pages, self.kv_page_size, PAGE_AXES]
+            "paged": [self.kv_pages, self.kv_page_size, page_axes(self.cache)]
             if self.paged else None,
         }
         return snapfmt.fingerprint(fields)
 
     def _cache_arrays(self) -> dict:
-        out = {"cache.k": self.cache.k, "cache.v": self.cache.v}
-        if self.cache.quantized:
-            out["cache.k_scale"] = self.cache.k_scale
-            out["cache.v_scale"] = self.cache.v_scale
-        return out
+        return {f"cache.{n}": a for n, a in self.cache.planes().items()}
 
     def snapshot(self, path: str | os.PathLike,
                  extra: dict | None = None,
@@ -474,7 +503,6 @@ class Engine:
         stream is token-identical to never having restarted
         (tests/test_snapshot.py); returns the snapshot's ``extra`` dict."""
         from ..io.integrity import bump_counter
-        from ..models.transformer import KVCache
         from . import snapshot as snapfmt
         meta, arrays = snapfmt.load(path)
         want_fp = self.config_fingerprint()
@@ -511,11 +539,8 @@ class Engine:
                 path, "sampling_path",
                 "snapshot sampled on a different sampling path",
                 expected=self.sampling_path, got=snap_sp)
-        if self.cache.quantized:
-            cache = KVCache(cache_np["cache.k"], cache_np["cache.v"],
-                            cache_np["cache.k_scale"], cache_np["cache.v_scale"])
-        else:
-            cache = KVCache(cache_np["cache.k"], cache_np["cache.v"])
+        cache = self.cache._replace(
+            **{n: cache_np[f"cache.{n}"] for n in self.cache.planes()})
         self.cache = jax.device_put(cache, self._cache_sh)
         self.pos = pos
         self._chunk_counter = int(meta["chunk_counter"])
@@ -557,13 +582,13 @@ class Engine:
             "n_active_experts": c.n_active_experts,
             "vocab_size": c.vocab_size, "hidden_act": c.hidden_act,
             "rope_theta": c.rope_theta, "seq_len": self.seq_len,
-            # page shape (ps, Hkv, Dh) + dtype, not pool page count, with
+            # page shape (ps, Hkv, Dh | ps, C) + dtype, not pool page count, with
             # the axis order by name: a record written head-major (before
             # PR 27) is refused even where Hkv == ps; the codec is
             # explicit so int8-paged vs dense records reject cleanly even
             # where the raw dtype string would coincide
             "page": [str(k.dtype), list(k.shape[2:])],
-            "page_axes": PAGE_AXES,
+            "page_axes": page_axes(self.cache),
             "codec": "int8" if self.cache.quantized else "dense",
             "handoff": 1,
         }
@@ -592,7 +617,8 @@ class Engine:
     def read_pool_pages(self, pages) -> dict[str, np.ndarray]:
         """Copy the given physical pages out of the paged pool to host
         numpy, all layers at once: shape ``(L, n, ps, Hkv, Dh)`` (plus the
-        ``(L, n, ps, Hkv, 1)`` scale planes for an int8 pool).  Used by
+        ``(L, n, ps, Hkv, 1)`` scale planes for an int8 pool; a latent pool
+        is ``pages.k`` alone, ``(L, n, ps, C)``).  Used by
         the scheduler's drain-time export and the spill path."""
         return {k: h.wait() for k, h in
                 self.read_pool_pages_async(pages).items()}
@@ -619,34 +645,18 @@ class Engine:
             def wait(self):
                 return np.asarray(self._dev)
 
-        out = {"pages.k": _Handle(self.cache.k[:, idx]),
-               "pages.v": _Handle(self.cache.v[:, idx])}
-        if self.cache.quantized:
-            out["pages.k_scale"] = _Handle(self.cache.k_scale[:, idx])
-            out["pages.v_scale"] = _Handle(self.cache.v_scale[:, idx])
-        return out
+        return {f"pages.{n}": _Handle(a[:, idx])
+                for n, a in self.cache.planes().items()}
 
     def write_pool_pages(self, pages, arrays: dict[str, np.ndarray]) -> None:
         """Write exported page slices (from :meth:`read_pool_pages` on a
         peer) into this engine's pool at the given physical page ids.
         One transient pool copy — acceptable at hand-off import time,
         which is off the steady-state decode path."""
-        from ..models.transformer import KVCache
         idx = jnp.asarray(np.asarray(pages, np.int32))
-        new_k = self.cache.k.at[:, idx].set(
-            jnp.asarray(arrays["pages.k"], self.cache.k.dtype))
-        new_v = self.cache.v.at[:, idx].set(
-            jnp.asarray(arrays["pages.v"], self.cache.v.dtype))
-        if self.cache.quantized:
-            new_ks = self.cache.k_scale.at[:, idx].set(
-                jnp.asarray(arrays["pages.k_scale"],
-                            self.cache.k_scale.dtype))
-            new_vs = self.cache.v_scale.at[:, idx].set(
-                jnp.asarray(arrays["pages.v_scale"],
-                            self.cache.v_scale.dtype))
-            cache = KVCache(new_k, new_v, new_ks, new_vs)
-        else:
-            cache = KVCache(new_k, new_v)
+        cache = self.cache._replace(**{
+            n: a.at[:, idx].set(jnp.asarray(arrays[f"pages.{n}"], a.dtype))
+            for n, a in self.cache.planes().items()})
         self.cache = jax.device_put(cache, self._cache_sh)
 
     def _sync(self, arrays, what: str) -> list[str]:

@@ -297,11 +297,9 @@ class SlotScheduler:
             self.host_pool = HostPagePool(
                 int(float(host_pool_mb) * (1 << 20)))
             cache = engine.cache
-            planes = (cache.k, cache.v) + (
-                (cache.k_scale, cache.v_scale) if cache.quantized else ())
             self._page_nbytes = sum(
                 int(np.prod(a.shape[:1] + a.shape[2:])) * a.dtype.itemsize
-                for a in planes)
+                for a in cache.planes().values())
             obs_metrics.KV_PAGES_TOTAL.set(self.pool.capacity)
             obs_metrics.KV_PAGES_IN_USE.set(0)
         self._queue: deque[Ticket] = deque()
@@ -793,12 +791,11 @@ class SlotScheduler:
         ps = self.pool.page_size
         n_data = -(-pos // ps)
         # the record must carry exactly this pool's page planes: values
-        # always, per-position scale planes iff the pool is int8 — an
+        # always (a latent pool's are ``pages.k`` alone), per-position scale
+        # planes iff the pool is int8 — an
         # int8 record into a dense pool (or vice versa) already failed
         # the fingerprint above, this validates shape against position
-        page_names = ["pages.k", "pages.v"]
-        if eng.cache.quantized:
-            page_names += ["pages.k_scale", "pages.v_scale"]
+        page_names = [f"pages.{n}" for n in eng.cache.planes()]
         page_arrays: dict = {}
         for name in page_names:
             ref = getattr(eng.cache, name.split(".", 1)[1])
@@ -1283,9 +1280,9 @@ class SlotScheduler:
         others = any(s.ticket is not None for s in self.slots)
         with self._engine_lock:
             if n_data:
-                eng.write_pool_pages(pages[:n_data],
-                                     {"pages.k": arrays["pages.k"],
-                                      "pages.v": arrays["pages.v"]})
+                eng.write_pool_pages(
+                    pages[:n_data],
+                    {n: arrays[n] for n in arrays if n.startswith("pages.")})
             if not others and not self._queue and "rng_key" in arrays:
                 eng.set_rng(arrays["rng_key"], int(meta["chunk_counter"]),
                             dev_key_np=arrays.get("rng_dev_key"))

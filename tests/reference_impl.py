@@ -125,3 +125,138 @@ def np_forward(params, cfg, tokens):
     x = rmsnorm(x, np.asarray(params["rms_final"]))
     logits = (x @ params["wcls"]).astype(np.float32) * cfg.logit_scale
     return logits
+
+
+# ---- DeepSeek-V2 (ARCH_DEEPSEEK2) -----------------------------------------
+# Written from the published equations (HF modeling_deepseek.py's
+# DeepseekV2Attention / MoEGate / DeepseekV2MoE), in the expanded form only:
+# per-head keys and values from the latent, full-sequence causal attention, no
+# cache, experts one at a time.  Shares nothing with dllama_tpu.ops.mla.
+
+def yarn_inv_freq(dim, theta, factor, orig_len, beta_fast, beta_slow):
+    """``inv = inter * (1 - m) + extra * m``, ``m = 1 - ramp(low, high)``."""
+    i = np.arange(dim // 2, dtype=np.float64)
+    extra = theta ** (-2.0 * i / dim)
+    if factor <= 1.0:
+        return extra
+    inter = extra / factor
+
+    def c(r):
+        return dim * np.log(orig_len / (2 * np.pi * r)) / (2 * np.log(theta))
+
+    low = max(int(np.floor(c(beta_fast))), 0)
+    high = min(int(np.ceil(c(beta_slow))), dim - 1)
+    if low == high:
+        high = high + 0.001
+    m = 1.0 - np.clip((i - low) / (high - low), 0.0, 1.0)
+    return inter * (1.0 - m) + extra * m
+
+
+def yarn_mscale(factor, m):
+    return 1.0 if factor <= 1.0 else 0.1 * m * np.log(factor) + 1.0
+
+
+def rope_pairs(x, pos, inv_freq, amp=1.0):
+    """Adjacent-pair rotation of x (T, H, D) at per-pair frequencies."""
+    ang = np.asarray(pos, np.float64)[:, None] * inv_freq
+    cos, sin = np.cos(ang)[:, None] * amp, np.sin(ang)[:, None] * amp
+    out = np.empty_like(x, dtype=np.float64)
+    x0, x1 = x[..., 0::2], x[..., 1::2]
+    out[..., 0::2] = x0 * cos - x1 * sin
+    out[..., 1::2] = x0 * sin + x1 * cos
+    return out.astype(np.float32)
+
+
+def rmsnorm_eps(x, w, eps):
+    ms = np.mean(x.astype(np.float64) ** 2, axis=-1, keepdims=True)
+    return (w * (x / np.sqrt(ms + eps))).astype(np.float32)
+
+
+def grouped_choice(probs, n_groups, topk_groups, k):
+    """One row's experts and their probabilities: the ``topk_groups`` groups
+    with the largest best-expert probability, then the top-k within them."""
+    per = probs.reshape(n_groups, -1)
+    groups = np.argsort(-per.max(-1), kind="stable")[:topk_groups]
+    masked = np.zeros_like(probs)
+    for g in groups:
+        lo = g * per.shape[1]
+        masked[lo:lo + per.shape[1]] = probs[lo:lo + per.shape[1]]
+    idx = np.argsort(-masked, kind="stable")[:k]
+    return idx, masked[idx]
+
+
+def deepseek2_moe(xb, lp, cfg, act, *, groups=True, scale=True, shared=True):
+    """xb (T, D).  The keyword switches leave a piece of the mathematics out:
+    the tests use them to show that leaving it out is seen."""
+    probs = softmax(xb.astype(np.float64) @ lp["router"].astype(np.float64))
+    out = np.zeros_like(xb)
+    for i in range(xb.shape[0]):
+        if groups:
+            idx, w = grouped_choice(probs[i], cfg.n_groups, cfg.topk_groups,
+                                    cfg.n_active_experts)
+        else:
+            idx = np.argsort(-probs[i], kind="stable")[:cfg.n_active_experts]
+            w = probs[i, idx]
+        if scale:
+            w = w * cfg.routed_scale
+        for wj, e in zip(w, idx):
+            h = act(xb[i] @ lp["gate"][e]) * (xb[i] @ lp["up"][e])
+            out[i] += wj * (h @ lp["down"][e])
+    if shared and "shared_w2" in lp:
+        out += (act(xb @ lp["shared_w1"]) * (xb @ lp["shared_w3"])) @ lp["shared_w2"]
+    return out
+
+
+def np_forward_deepseek2(params, cfg, tokens, *, mscale=True, yarn=True, **moe_kw):
+    """Full-sequence forward of DeepSeek-V2.  ``params``: numpy dict in the
+    runtime layout, unfused and dense (attention stacks over all layers, the
+    dense FFN's over the leading ``n_dense_layers``, the experts' over the
+    rest).  Returns (T, V) logits."""
+    act = {0: gelu_tanh, 1: silu}[cfg.hidden_act]
+    t = len(tokens)
+    h, r = cfg.n_heads, cfg.kv_lora_rank
+    dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+    eps = cfg.norm_eps
+    pos = np.arange(t)
+    factor = cfg.rope_factor if yarn else 1.0
+    inv = yarn_inv_freq(dr, cfg.rope_theta, factor, cfg.rope_orig_seq_len,
+                        cfg.rope_beta_fast, cfg.rope_beta_slow)
+    amp = yarn_mscale(factor, cfg.rope_mscale) / yarn_mscale(
+        factor, cfg.rope_mscale_all_dim)
+    scale = (dn + dr) ** -0.5
+    if mscale:
+        scale *= yarn_mscale(cfg.rope_factor, cfg.rope_mscale_all_dim) ** 2
+    mask = np.tril(np.ones((t, t), bool))
+
+    def layer_params(keys, i):
+        return {k: np.asarray(params[k][i], np.float32) for k in keys
+                if k in params}
+
+    x = params["embedding"][tokens].astype(np.float32)
+    for li in range(cfg.n_layers):
+        lp = layer_params(("wq_a", "q_a_norm", "wq_b", "wkv_a", "kv_a_norm",
+                           "wkv_b", "wo", "rms_att", "rms_ffn"), li)
+        xb = rmsnorm_eps(x, lp["rms_att"], eps)
+        q = rmsnorm_eps(xb @ lp["wq_a"], lp["q_a_norm"], eps) @ lp["wq_b"]
+        q = q.reshape(t, h, dn + dr)
+        ckv = xb @ lp["wkv_a"]
+        c_kv = rmsnorm_eps(ckv[:, :r], lp["kv_a_norm"], eps)
+        k_pe = rope_pairs(ckv[:, None, r:], pos, inv, amp)        # (T, 1, dr)
+        q_pe = rope_pairs(q[..., dn:], pos, inv, amp)
+        kv = (c_kv @ lp["wkv_b"]).reshape(t, h, dn + dv)
+        att = np.zeros((t, h, dv), np.float32)
+        for hh in range(h):
+            s = (q[:, hh, :dn] @ kv[:, hh, :dn].T + q_pe[:, hh] @ k_pe[:, 0].T)
+            s = np.where(mask, s.astype(np.float64) * scale, -np.inf)
+            att[:, hh] = softmax(s) @ kv[:, hh, dn:]
+        x = x + att.reshape(t, h * dv) @ lp["wo"]
+        xb = rmsnorm_eps(x, lp["rms_ffn"], eps)
+        if li < cfg.n_dense_layers:
+            fp = layer_params(("w1", "w2", "w3"), li)
+            x = x + (act(xb @ fp["w1"]) * (xb @ fp["w3"])) @ fp["w2"]
+        else:
+            fp = layer_params(("router", "up", "gate", "down", "shared_w1",
+                               "shared_w2", "shared_w3"), li - cfg.n_dense_layers)
+            x = x + deepseek2_moe(xb, fp, cfg, act, **moe_kw)
+    x = rmsnorm_eps(x, np.asarray(params["rms_final"]), eps)
+    return (x @ np.asarray(params["wcls"], np.float32)).astype(np.float32)

@@ -195,3 +195,117 @@ def test_launch_lists_reference_zoo():
     import launch
     assert set(launch.MODELS) == {"tinyllama_1_1b_3t_q40", "llama3_8b_q40",
                                   "llama3_8b_instruct_q40"}
+
+
+# ---- deepseek_v2 -------------------------------------------------------------
+
+DS2_HF = dict(
+    hidden_size=64, intermediate_size=96, moe_intermediate_size=32,
+    num_hidden_layers=3, num_attention_heads=4, num_key_value_heads=4,
+    vocab_size=128, max_position_embeddings=64, q_lora_rank=64, kv_lora_rank=32,
+    qk_nope_head_dim=16, qk_rope_head_dim=8, v_head_dim=16, head_dim=8,
+    n_routed_experts=32, num_experts_per_tok=6, n_shared_experts=2, n_group=8,
+    topk_group=3, topk_method="group_limited_greedy", first_k_dense_replace=1,
+    routed_scaling_factor=16.0, norm_topk_prob=False, rope_theta=10000.0,
+    tie_word_embeddings=False, rms_norm_eps=1e-6)
+
+
+@pytest.fixture(scope="module")
+def hf_deepseek2_dir(tmp_path_factory):
+    import torch
+    from transformers import DeepseekV2Config, DeepseekV2ForCausalLM
+    torch.manual_seed(0)
+    model = DeepseekV2ForCausalLM(DeepseekV2Config(**DS2_HF)).eval()
+    with torch.no_grad():  # the gate's weight is allocated, not initialised
+        for name, p in model.named_parameters():
+            if name.endswith("mlp.gate.weight"):
+                p.normal_(0, 0.5)
+    d = tmp_path_factory.mktemp("hf_deepseek2")
+    model.save_pretrained(d, safe_serialization=True)
+    return str(d), model
+
+
+def test_convert_deepseek_v2_logits_match_huggingfaces_own(hf_deepseek2_dir, tmp_path):
+    """``model_type: deepseek_v2`` through the converter, the loader and
+    ``forward`` against transformers' ``DeepseekV2ForCausalLM``: the names,
+    ``kv_b_proj`` kept whole (a head's rows k_nope then v), RoPE on adjacent
+    pairs with no permutation, the grouped choice, the x16 and the shared
+    experts.  (transformers' port applies no ``mscale`` to the softmax scale,
+    so the fixture has no ``rope_scaling``; YaRN is held to the published
+    formula in ``tests/test_deepseek_v2.py``.)  float32 on both sides: 2e-5."""
+    import jax
+    import jax.numpy as jnp
+    import torch
+
+    import convert_hf
+    from dllama_tpu.models.transformer import forward, init_kv_cache
+
+    folder, torch_model = hf_deepseek2_dir
+    out = str(tmp_path / "ds2.m")
+    convert_hf.convert(folder, quants.F32, out)
+    mf = mfile.MFile(out)
+    assert mf.spec.arch == mfile.ARCH_DEEPSEEK2
+    assert (mf.spec.n_groups, mf.spec.topk_groups, mf.spec.n_dense_layers,
+            mf.spec.n_shared_experts, mf.spec.moe_hidden_dim) == (8, 3, 1, 2, 32)
+    assert mf.spec.rope_factor == 1.0 and mf.spec.norm_eps == np.float32(1e-6)
+    assert mf.info("layers.1.wkv_b").shape == (4 * 32, 32)
+    cfg, params = load_params(mf)
+    cfg = cfg.with_(dtype=jnp.float32)
+    tokens = [[3, 17, 42, 99, 7, 64, 5, 23, 81, 11]]
+    with torch.no_grad():
+        want = torch_model(torch.tensor(tokens)).logits.numpy()[0]
+    with jax.default_matmul_precision("highest"):
+        logits, _ = forward(params, cfg, jnp.asarray(tokens),
+                            init_kv_cache(cfg, 1), jnp.int32(0))
+    np.testing.assert_allclose(np.asarray(logits)[0], want, atol=2e-5, rtol=1e-4)
+
+
+@pytest.mark.parametrize("key,value,says", [
+    ("scoring_func", "sigmoid", "scoring_func is 'sigmoid'"),
+    ("topk_method", "noaux_tc", "topk_method is 'noaux_tc'"),
+    ("norm_topk_prob", True, "norm_topk_prob is true"),
+    ("moe_layer_freq", 2, "moe_layer_freq is 2"),
+    ("attention_bias", True, "attention_bias is true"),
+    ("rope_scaling", {"type": "linear", "factor": 4.0}, "rope_scaling type is 'linear'"),
+    ("q_lora_rank", None, "q_lora_rank is null"),
+])
+def test_convert_hf_refuses_deepseek_v2_variants_by_name(tmp_path, key, value, says):
+    import convert_hf
+
+    config = dict(DS2_HF, model_type="deepseek_v2", hidden_act="silu",
+                  scoring_func="softmax", moe_layer_freq=1, attention_bias=False)
+    config[key] = value
+    (tmp_path / "config.json").write_text(json.dumps(config))
+    with pytest.raises(SystemExit, match=says):
+        convert_hf.load_spec(str(tmp_path), quants.F32)
+
+
+def test_convert_hf_reads_yarn_and_greedy_into_the_header(tmp_path):
+    import convert_hf
+
+    config = dict(DS2_HF, model_type="deepseek_v2", topk_method="greedy",
+                  rope_scaling={"type": "yarn", "factor": 40, "beta_fast": 32,
+                                "beta_slow": 1, "mscale": 0.707,
+                                "mscale_all_dim": 0.707,
+                                "original_max_position_embeddings": 4096})
+    (tmp_path / "config.json").write_text(json.dumps(config))
+    spec = convert_hf.load_spec(str(tmp_path), quants.Q40)
+    assert (spec.n_groups, spec.topk_groups) == (1, 1)  # greedy: one group
+    assert (spec.rope_factor, spec.rope_orig_seq_len, spec.rope_mscale,
+            spec.rope_mscale_all_dim) == (40.0, 4096, 0.707, 0.707)
+    mfile.validate_spec(spec, "x.m")
+
+
+def test_convert_hf_refuses_a_checkpoint_with_a_correction_bias(tmp_path):
+    """V3's router adds ``e_score_correction_bias`` to the scores; the runtime
+    has none, and a config.json alone does not say the checkpoint has one."""
+    from safetensors.numpy import save_file
+
+    import convert_hf
+
+    config = dict(DS2_HF, model_type="deepseek_v2")
+    (tmp_path / "config.json").write_text(json.dumps(config))
+    save_file({"model.layers.1.mlp.gate.e_score_correction_bias":
+               np.zeros(32, np.float32)}, str(tmp_path / "model.safetensors"))
+    with pytest.raises(SystemExit, match="e_score_correction_bias"):
+        convert_hf.convert(str(tmp_path), quants.F32, str(tmp_path / "x.m"))

@@ -73,3 +73,31 @@ def test_unrealizable_mesh_rejected():
     cfg = _cfg("llama3-8b")  # 8 kv heads
     with pytest.raises(ValueError, match="nKvHeads"):
         plan(cfg, tp=32)
+
+
+def test_deepseek_v2_two_layer_kinds_and_a_latent_cache():
+    """DeepSeek-V2's published widths at the benchmark's 5 layers: the plan
+    sums the MLA stacks of every layer, the dense FFN of the first and the
+    experts of the other four (``down``'s 1536 input columns stored padded to
+    2048), and charges the cache 576 values a token a layer, not 2 x 128
+    heads x 40."""
+    from dllama_tpu.io import mfile
+    from dllama_tpu.models.config import tiny_config
+
+    cfg = tiny_config(
+        arch=mfile.ARCH_DEEPSEEK2, dim=5120, hidden_dim=12288, n_layers=5,
+        n_heads=128, n_kv_heads=128, n_experts=160, n_active_experts=6,
+        vocab_size=102400, seq_len=2048, q_lora_rank=1536, kv_lora_rank=512,
+        qk_nope_head_dim=128, qk_rope_head_dim=64, v_head_dim=128,
+        moe_hidden_dim=1536, n_shared_experts=2, n_groups=8, topk_groups=3,
+        n_dense_layers=1)
+    p = plan(cfg, batch=16)
+    assert p["kv_cache"] == 5 * 16 * 2048 * 576 * 2
+    q = 18 / 32
+    experts = 4 * 160 * (2 * 5120 * 1536 + 2048 * 5120) * q
+    assert experts < p["weights_sharded"] < experts + 1.0e9
+    # the embedding in bf16, and wkv_b dequantized at load (5 x 512 x 32768 x 2)
+    assert p["weights_replicated"] > 102400 * 5120 * 2 + 5 * 512 * 32768 * 2
+    assert p["fits_v5e"] and 11e9 < p["per_chip"] < 14e9
+    # a decode step streams 6 of 160 experts a layer, not all of them
+    assert p["decode_read_per_step"] < 0.2 * p["weights_sharded"]

@@ -53,7 +53,8 @@ def test_arch_id_and_the_flags_derived_from_it():
                     tiny_config(arch=arch).rope_interleaved)
              for arch, name in mfile.ARCH_NAMES.items()}
     assert flags == {"llama": (False, True, True), "grok1": (False, True, False),
-                     "mixtral": (False, True, False), "olmoe": (True, False, False)}
+                     "mixtral": (False, True, False), "olmoe": (True, False, False),
+                     "deepseek2": (False, False, True)}
     shapes = param_shapes(tiny_config(arch=mfile.ARCH_OLMOE, n_experts=4,
                                       n_active_experts=2))
     assert (shapes["q_norm"], shapes["k_norm"]) == ((2, 64), (2, 32))
@@ -92,7 +93,7 @@ def test_validate_spec_knows_the_arch_and_wants_its_experts(tmp_path):
     with pytest.raises(ArtifactError, match="n_active_experts"):
         mfile.validate_spec(_spec(mfile.ARCH_OLMOE, n_experts=0), "x.m")
     bad = _spec(mfile.ARCH_OLMOE)
-    bad.arch = 0xABCD04
+    bad.arch = 0xABCD05
     with pytest.raises(ArtifactError, match="unknown architecture"):
         mfile.validate_spec(bad, "x.m")
 
@@ -323,10 +324,12 @@ def test_moe_parts_are_named_and_the_ledger_records_the_strategy(rows, experts,
     names = _OP_NAME.findall(text)
     under_moe = [n for n in names if "/moe/" in n]
     seen = {c for n in under_moe for c in n.split("/moe/", 1)[1].split("/")
-            if c in PARTS}
+            if c in PARTS["moe"]}
     # a fusion carries its root's name: the dense strategies' small combine
-    # may fuse into an op of the experts
-    assert seen == set(PARTS) or (not packed and seen == {"router", "experts"}), \
+    # may fuse into an op of the experts.  OLMoE has no shared expert: its
+    # `moe` has the three parts it had, and no other
+    olmoe_parts = {"router", "experts", "combine"}
+    assert seen == olmoe_parts or (not packed and seen == {"router", "experts"}), \
         sorted(seen)
     for n in under_moe:  # the last component that is a scope is still `moe`
         assert [c for c in n.split("/") if c in SCOPES][-1] == "moe", n

@@ -368,6 +368,123 @@ def test_olmoe_decode_slot_step_runs_the_experts_in_three_launches_a_layer(
     assert len(calls) == 3 and all("q40_mm_experts" in c for c in calls), calls
 
 
+# DeepSeek-V2's expert matmuls (hidden 5120, expert width 1536, 160 experts of
+# 4 expert layers): gate / up have a ragged second d tile (1536 = 1024 + 512),
+# down's 1536 input columns are stored padded to 2048 (q40.padded_n)
+@pytest.mark.parametrize("rows", [16, 256])
+@pytest.mark.parametrize("name,n,d,per_expert", [
+    ("gate", 5120, 1536, False), ("down", 1536, 5120, True)], ids=["gate", "down"])
+def test_q40_experts_matmul_compiles_at_deepseek_v2_shapes(one_chip, name, n, d,
+                                                           per_expert, rows):
+    L, E = 4, 160
+    np_ = q40.padded_n(n)
+    s = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)  # noqa: E731
+    x = s(((E,) if per_expert else ()) + (rows, np_), jnp.bfloat16)
+    compiled = jax.jit(
+        lambda x, qp, sc, layer: q40._pallas_matmul_experts(
+            x, qp, sc, layer, experts=E)).lower(
+        x, s((L * E, np_ // 2, d), jnp.uint8), s((L * E, np_ // 32, d), jnp.uint16),
+        s((), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "q40_mm_experts" in text
+    assert f"f32[{E},{rows},{d}]" in text
+
+
+# DeepSeek-V2's MLA projections: the fused down-projections from x (1536 + 576
+# = 2112 outputs: a ragged last d tile of 64), q's up-projection (1536 inputs,
+# padded to 2048), wo (16384 inputs), the shared expert's down (3072 inputs)
+@pytest.mark.parametrize("name,n,d", [
+    ("wqkv_a", 5120, 2112), ("wq_b", 1536, 24576), ("wo", 16384, 5120),
+    ("shared_w2", 3072, 5120)], ids=lambda v: str(v))
+def test_q40_matmul_compiles_at_deepseek_v2_shapes(one_chip, name, n, d):
+    x, qp, sc = _q40_shapes(n, d, 16, True, one_chip)
+    assert q40._tile_n_legal(x.shape[1], q40._tiles(x.shape[1], d)[0])
+    text = jax.jit(q40._pallas_matmul_stacked).lower(
+        x, qp, sc, jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+    ).compile().as_text()
+    assert "tpu_custom_call" in text and "q40_mm_stacked" in text
+
+
+@pytest.mark.parametrize("t", [1, 16], ids=["pure-decode", "mixed"])
+def test_deepseek_v2_slot_steps_compile_over_a_latent_pool(one_chip, monkeypatch, t):
+    """The served steps of DeepSeek-V2's block (16 slots x 1 token and x a
+    16-token chunk) over a 3-layer model (a dense layer and two expert layers,
+    160 packed experts in 8 groups, narrower widths, the published 512 + 64
+    latent row) and a latent pool of 2056 pages, compiled for the described
+    chip: the attention is the absorbed form, the experts are three
+    ``q40_mm_experts`` launches in the expert segment's loop body, no Q40 site
+    takes the XLA path, and the only pool in the program is the two latent
+    planes ``(L, P, 16, 512)`` and ``(L, P, 16, 64)``: nothing per head."""
+    import re
+
+    from dllama_tpu.models import transformer as tf
+    from dllama_tpu.models.config import tiny_deepseek2
+    from dllama_tpu.models.params import param_shapes
+    from dllama_tpu.obs import dispatch as obs_dispatch
+    from dllama_tpu.runtime.decode_loop import slot_chunk
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(jax, "device_count", lambda: 1)
+    cfg = tiny_deepseek2(
+        dim=512, hidden_dim=1024, n_heads=8, n_kv_heads=8, n_experts=160,
+        vocab_size=1024, seq_len=2048, q_lora_rank=256, kv_lora_rank=512,
+        qk_nope_head_dim=128, qk_rope_head_dim=64, v_head_dim=128,
+        moe_hidden_dim=256, rope_orig_seq_len=4096, dtype=jnp.bfloat16)
+    s = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)  # noqa: E731
+
+    def packed(*shapes):
+        *lead, n, _ = shapes[0]
+        d, np_ = sum(sh[-1] for sh in shapes), q40.padded_n(n)
+        return q40.QTensor(s((*lead, np_ // 2, d), jnp.uint8),
+                           s((*lead, np_ // 32, d), jnp.uint16), (n, d))
+
+    sh = param_shapes(cfg)
+    params = {k: s(sh[k], jnp.float32) for k in sh
+              if k.startswith("rms") or k.endswith("_norm")}
+    params.update({k: s(sh[k], jnp.bfloat16) for k in ("embedding", "router", "wkv_b")})
+    params.update(
+        wqkv_a=packed(sh["wq_a"], sh["wkv_a"]), w13=packed(sh["w1"], sh["w3"]),
+        shared_w13=packed(sh["shared_w1"], sh["shared_w3"]),
+        **{k: packed(sh[k]) for k in ("wq_b", "wo", "w2", "up", "gate", "down",
+                                      "shared_w2", "wcls")})
+    b, n_pages, ps, maxp = 16, 2056, 16, 128
+    pool = tf.KVCache(s((cfg.n_layers, n_pages, ps, 512), jnp.bfloat16),
+                      s((cfg.n_layers, n_pages, ps, 64), jnp.bfloat16))
+    vec = lambda dt: s((b,), dt)  # noqa: E731
+    obs_dispatch.reset()
+    try:
+        text = jax.jit(
+            lambda p, c, tok, pr, nv, k, tm, tp, tk, pt: slot_chunk(
+                p, cfg, c, tok, pr, nv, k, tm, tp, tk, steps=1, greedy=True,
+                page_table=pt), donate_argnums=(1,)).lower(
+            params, pool, s((b, t), jnp.int32), vec(jnp.int32), vec(jnp.int32),
+            s((2,), jnp.uint32), vec(jnp.float32), vec(jnp.float32),
+            vec(jnp.int32), s((b, maxp), jnp.int32)).compile().as_text()
+        sites = obs_dispatch.dispatches()
+    finally:
+        obs_dispatch.reset()
+    assert sites.get("moe/all-experts") == 1 and "moe/scan" not in sites, sites
+    assert sites.get("attn/mla-absorbed") == 2 and "attn/mla-expanded" not in sites, sites
+    assert "q40/xla-dequant" not in sites, sites
+    ops = re.findall(r"^\s*(?:ROOT )?%?[\w.\-]+ = \S+ ([\w\-]+)\(.*?"
+                     r"op_name=\"([^\"]+)\"", text, re.M)
+    calls = [path for op, path in ops if op == "custom-call"
+             and "pallas_call" in path and "/moe/experts/" in path]
+    assert len(calls) == 3 and all("q40_mm_experts" in c for c in calls), calls
+    assert any("/attn/latent/" in path for _, path in ops)
+    assert any("/attn/absorb/" in path for _, path in ops)
+    assert any("/moe/shared/" in path for _, path in ops)
+    # the latent plane is never copied whole (a 576-wide plane was, in and out
+    # of every step: PERF.md §6, PR 33); the 64-wide plane of the rotated key,
+    # a ninth of the bytes, still is
+    latent = f"bf16[{cfg.n_layers},{n_pages},{ps},512]"
+    assert latent in text
+    assert not re.search(r"= " + re.escape(latent) + r"\S* copy\(", text)
+    # nothing per head is kept: no array with the heads' expanded K or V over
+    # the pool's tokens
+    assert not re.search(rf"\[\d+,{n_pages},{ps},8,\d+\]", text)
+
+
 @pytest.mark.parametrize("name,n,d,reduce", [
     ("wo", 4096, 4096, "q40_ring"), ("w2", 11008, 4096, "q40_ring"),
     # Yi-34B: 1792 = 7 x 256 contracted columns a chip; 7168 % 256 == 0, so

@@ -93,10 +93,12 @@ def plan(cfg, tp=1, sp=1, dp=1, ep=1, seq_len=None, batch=1,
         if k in ("embedding",):
             w_repl += n * 2
             decode_read += cfg.dim * 2  # one row gathered per token
-        elif k.startswith("rms"):
+        elif k.startswith("rms") or k.endswith("_a_norm"):
             w_repl += n * 4
             decode_read += n * 4
-        elif k == "router":
+        elif k in ("router", "wkv_b"):
+            # wkv_b (MLA): dequantized at load, the absorbed form multiplies
+            # it head by head
             w_repl += n * 2
             decode_read += n * 2
         else:
@@ -121,8 +123,10 @@ def plan(cfg, tp=1, sp=1, dp=1, ep=1, seq_len=None, batch=1,
                 decode_read += n * per_w * cfg.n_active_experts / cfg.n_experts
             else:
                 decode_read += n * per_w
-    cache = 2 * cfg.n_layers * batch * cfg.n_kv_heads * s * cfg.head_size * kv_bytes
-    cache /= tp * sp * max(dp, 1)  # kv heads /tp, seq /sp, batch /dp
+    # a GQA cache holds 2 x kv heads x head size a token a layer; a latent
+    # (MLA) cache one latent and one rotated key, and has no head axis for tp
+    cache = cfg.n_layers * batch * s * cfg.kv_values_per_token * kv_bytes
+    cache /= (1 if cfg.is_mla else tp) * sp * max(dp, 1)  # kv heads /tp, seq /sp, batch /dp
     per_chip = w_sharded + w_repl + cache + OVERHEAD
     return {
         "weights_sharded": w_sharded, "weights_replicated": w_repl,
