@@ -45,8 +45,9 @@ def record_dispatch(codec: str, path: str, **ctx) -> None:
 
     ``codec`` is the weight storage ("q40", "q8", "dense"); ``path`` the
     executed implementation ("pallas-fused", "xla-dequant", "dense").
-    Extra keyword context (rows, kind, tp) rides on the debug log record
-    only.
+    Extra keyword context (rows, kind, tp; from the Q40 kernel also the
+    tile pair it got and the stored input dim) rides on the debug log
+    record only.
     """
     obs_metrics.MATMUL_DISPATCH.inc(codec, path)
     with _lock:

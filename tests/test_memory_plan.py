@@ -4,6 +4,8 @@ import os
 import subprocess
 import sys
 
+import numpy as np
+
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
 from tools.memory_plan import PRESETS, _cfg, find_fit, plan  # noqa: E402
@@ -78,9 +80,8 @@ def test_unrealizable_mesh_rejected():
 def test_deepseek_v2_two_layer_kinds_and_a_latent_cache():
     """DeepSeek-V2's published widths at the benchmark's 5 layers: the plan
     sums the MLA stacks of every layer, the dense FFN of the first and the
-    experts of the other four (``down``'s 1536 input columns stored padded to
-    2048), and charges the cache 576 values a token a layer, not 2 x 128
-    heads x 40."""
+    experts of the other four, and charges the cache 576 values a token a
+    layer, not 2 x 128 heads x 40."""
     from dllama_tpu.io import mfile
     from dllama_tpu.models.config import tiny_config
 
@@ -94,10 +95,40 @@ def test_deepseek_v2_two_layer_kinds_and_a_latent_cache():
     p = plan(cfg, batch=16)
     assert p["kv_cache"] == 5 * 16 * 2048 * 576 * 2
     q = 18 / 32
-    experts = 4 * 160 * (2 * 5120 * 1536 + 2048 * 5120) * q
+    experts = 4 * 160 * 3 * 5120 * 1536 * q
     assert experts < p["weights_sharded"] < experts + 1.0e9
     # the embedding in bf16, and wkv_b dequantized at load (5 x 512 x 32768 x 2)
     assert p["weights_replicated"] > 102400 * 5120 * 2 + 5 * 512 * 32768 * 2
     assert p["fits_v5e"] and 11e9 < p["per_chip"] < 14e9
     # a decode step streams 6 of 160 experts a layer, not all of them
     assert p["decode_read_per_step"] < 0.2 * p["weights_sharded"]
+
+
+def test_deepseek_v2_plan_counts_no_padded_column():
+    """``down`` and ``wq_b`` have 1536 input columns, which the tile rule
+    cuts whole (``q40.padded_n(1536) == 1536``): the plan holds every Q40
+    matrix of DeepSeek-V2 at its logical size, 0.5625 B a weight, where the
+    rule of PRs 28-33 stored those two with 2048 columns (0.94 GB of zeros at
+    the benchmark's 5 layers)."""
+    from dllama_tpu.io import mfile
+    from dllama_tpu.models.config import tiny_config
+    from dllama_tpu.models.params import param_shapes
+    from dllama_tpu.ops.q40 import padded_n
+
+    cfg = tiny_config(
+        arch=mfile.ARCH_DEEPSEEK2, dim=5120, hidden_dim=12288, n_layers=5,
+        n_heads=128, n_kv_heads=128, n_experts=160, n_active_experts=6,
+        vocab_size=102400, seq_len=2048, q_lora_rank=1536, kv_lora_rank=512,
+        qk_nope_head_dim=128, qk_rope_head_dim=64, v_head_dim=128,
+        moe_hidden_dim=1536, n_shared_experts=2, n_groups=8, topk_groups=3,
+        n_dense_layers=1)
+    shapes = param_shapes(cfg)
+    assert shapes["down"][-2:] == (1536, 5120) and padded_n(1536) == 1536
+    dense = ("embedding", "router", "wkv_b")
+    logical = sum(int(np.prod(shp)) for k, shp in shapes.items()
+                  if k not in dense and not k.startswith("rms")
+                  and not k.endswith("_a_norm"))
+    p = plan(cfg, batch=16)
+    assert p["weights_sharded"] == logical * 18 / 32
+    padded = 4 * 160 * 512 * 5120 * 18 / 32   # what 2048 columns for down added
+    assert padded > 0.9e9 and p["weights_sharded"] + padded > 10.3e9

@@ -238,14 +238,104 @@ class TestShardMap:
         assert t1 == t8
 
 
+def _parent_tiles(n, d):
+    """The tile rule of PRs 28-33, kept to show which shapes it still
+    decides: 1024 x 1024, the reduction tile shrunk down a power-of-two ladder
+    to the first that divides, the output tile one of 1024 whatever d."""
+    tile_n = n
+    for tn in (1024, 512, 256, 128, 64, 32):
+        if n % tn == 0:
+            tile_n = tn
+            break
+    return tile_n, (min(1024, d) if d % 128 == 0 else 1024)
+
+
+def _parent_padded_n(n):
+    return n if n <= 1024 else -(-n // 1024) * 1024
+
+
+# Every Q40 matmul of the benchmark's five cells: (configuration, matrix,
+# input dim n, output dim d, tp slicing, the tile pair, the stored n).  n and d
+# are the whole tensor's; Yi-34B runs at tp=4, where the kernel cuts one
+# shard (q40._shard_nd).  Mistral's two cells share a configuration.
+CELL_SHAPES = [
+    ("mistral-7b", "wqkv", 4096, 6144, None, (1024, 1024), 4096),
+    ("mistral-7b", "wo", 4096, 4096, None, (1024, 1024), 4096),
+    ("mistral-7b", "w13", 4096, 28672, None, (1024, 1024), 4096),
+    ("mistral-7b", "w2", 14336, 4096, None, (1024, 1024), 14336),
+    ("mistral-7b", "wcls", 4096, 32768, None, (1024, 1024), 4096),
+    ("olmoe-1b-7b", "wqkv", 2048, 6144, None, (1024, 1024), 2048),
+    ("olmoe-1b-7b", "wo", 2048, 2048, None, (1024, 1024), 2048),
+    ("olmoe-1b-7b", "gate/up", 2048, 1024, None, (1024, 1024), 2048),
+    ("olmoe-1b-7b", "down", 1024, 2048, None, (1024, 1024), 1024),
+    ("olmoe-1b-7b", "wcls", 2048, 50304, None, (1024, 1024), 2048),
+    ("deepseek-v2", "wqkv_a", 5120, 2112, None, (1280, 768), 5120),
+    ("deepseek-v2", "wq_b", 1536, 24576, None, (1536, 640), 1536),
+    ("deepseek-v2", "wo", 16384, 5120, None, (1024, 1024), 16384),
+    ("deepseek-v2", "w13", 5120, 24576, None, (1024, 1024), 5120),
+    ("deepseek-v2", "w2", 12288, 5120, None, (1024, 1024), 12288),
+    ("deepseek-v2", "gate/up", 5120, 1536, None, (1280, 768), 5120),
+    ("deepseek-v2", "down", 1536, 5120, None, (1536, 640), 1536),
+    ("deepseek-v2", "shared_w13", 5120, 6144, None, (1024, 1024), 5120),
+    ("deepseek-v2", "shared_w2", 3072, 5120, None, (1024, 1024), 3072),
+    ("deepseek-v2", "wcls", 5120, 102400, None, (1024, 1024), 5120),
+    ("yi-34b", "wq", 7168, 7168, "row", (1024, 896), 7168),
+    ("yi-34b", "wk/wv", 7168, 1024, "row", (1792, 256), 7168),
+    ("yi-34b", "wo", 7168, 7168, "col", (1792, 512), 7168),
+    ("yi-34b", "w1/w3", 7168, 20480, "row", (1024, 1024), 7168),
+    ("yi-34b", "w2", 20480, 7168, "col", (1024, 1024), 20480),
+    ("yi-34b", "wcls", 7168, 64000, "row", (1024, 1024), 7168),
+]
+# the cells that bypass the rule's new part: every shape divides by 1024
+PARENTS_OWN = ("mistral-7b", "olmoe-1b-7b")
+
+
 class TestTiles:
     def test_ladder(self):
-        """One rule: 1024 × 1024, shrunk to what divides a shard's rows."""
+        """One rule: the pair that divides the matrix with the largest tiles,
+        1024 x 1024 where the sides divide by 1024."""
         assert q40._tiles(4096, 28672) == (1024, 1024)   # Mistral w13
         assert q40._tiles(14336, 4096) == (1024, 1024)   # w2
-        assert q40._tiles(3584, 4096) == (512, 1024)     # w2 per tp=4 shard
-        assert q40._tiles(1792, 20480) == (256, 1024)    # Yi hidden / 4
+        assert q40._tiles(3584, 4096) == (1792, 512)     # w2 per tp=4 shard
+        assert q40._tiles(1792, 20480) == (1792, 512)    # Yi hidden / 4
         assert q40._tiles(64, 192) == (64, 1024)         # toy: the whole axis
+        assert q40._tiles(11264 // 4, 4096) == (256, 1024)  # Llama-2 w2 / 4
+        assert q40._tiles(7168, 256) == (1792, 256)      # not over MAX_TILE_N
+        assert q40._tiles(8224, 4096)[0] == 32           # no legal tile: XLA
+
+    @pytest.mark.parametrize("n,stored", [
+        (1536, 1536), (1792, 1792), (3584, 3584), (1056, 1056),  # as they are
+        (11008, 11264), (5632, 6144), (2752, 3072),   # no healthy tile: padded
+        (96, 96), (1024, 1024), (4096, 4096), (14336, 14336)])
+    def test_padded_n(self, n, stored):
+        assert q40.padded_n(n) == stored
+        # what is stored can be cut into legal tiles
+        assert q40._tile_n_legal(stored, q40._tiles(stored, 4096)[0])
+
+    @pytest.mark.parametrize("config,name,n,d,kind,tiles,stored", CELL_SHAPES,
+                             ids=[f"{c[0]}-{c[1]}" for c in CELL_SHAPES])
+    def test_cells_tile_table(self, config, name, n, d, kind, tiles, stored):
+        """The tile pair and the stored input dim of every matmul the five
+        cells run.  Mistral's and OLMoE's are what the parent's rule gave
+        them: nothing of this rule reaches the cells it was not written for."""
+        tp = 4 if kind else 1
+        assert q40.padded_n(n) == stored
+        local = q40._shard_nd(stored, d, kind, tp)
+        got = q40._tiles(*local)
+        assert got == tiles
+        tile_n, tile_d = got
+        assert local[0] % tile_n == 0 and q40._tile_n_legal(local[0], tile_n)
+        assert tile_d % 128 == 0 and tile_n * tile_d <= q40.TILE_ELEMS
+        # the last output tile is ragged by less than a tile
+        assert -(-local[1] // tile_d) * tile_d - local[1] < tile_d
+        if config in PARENTS_OWN:
+            assert got == _parent_tiles(*local)
+            assert stored == _parent_padded_n(n)
+        else:
+            # what the rule changed is never more tile work than the parent's
+            work = lambda t, nd: -(-nd[0] // t[0]) * t[0] * -(-nd[1] // t[1]) * t[1]  # noqa: E731
+            plocal = q40._shard_nd(_parent_padded_n(n), d, kind, tp)
+            assert work(got, local) <= work(_parent_tiles(*plocal), plocal)
 
     @pytest.mark.parametrize("form", ["flat", "stacked"])
     def test_kernel_correct_at_rule_tiles(self, form):
@@ -265,6 +355,64 @@ class TestTiles:
         ref = np.asarray(x @ q40.dequantize(qt, jnp.bfloat16)[1])
         np.testing.assert_allclose(np.asarray(out), ref, rtol=0,
                                    atol=1e-2 * np.abs(ref).max())
+
+
+# (n, d) whose tile pair the parent's rule did not give: DeepSeek-V2's gate
+# (1280 x 768), down at an unpadded 1536 (1536 x 640), wqkv_a (a ragged third
+# tile of 768), Yi-34B's shards of wo (the whole 1792 against 512), q
+# (1024 x 896) and k / v (1792 x 256)
+NEW_TILE_SHAPES = [(5120, 1536), (1536, 5120), (5120, 2112), (1792, 7168),
+                   (7168, 1792), (7168, 256)]
+
+
+@pytest.mark.parametrize("form", ["flat", "stacked"])
+@pytest.mark.parametrize("n,d", NEW_TILE_SHAPES, ids=lambda v: str(v))
+def test_kernel_matches_xla_at_the_rules_new_tiles(form, n, d):
+    """Interpret-mode numerics at the tile pairs this rule brought, chosen by
+    the rule itself, against the XLA reference: another tile_n only moves
+    where the f32 accumulator's partial sums are cut."""
+    assert q40._tiles(n, d) != _parent_tiles(_parent_padded_n(n), d)
+    lead = (2,) if form == "stacked" else ()
+    qt = q40.quantize(_rand((*lead, n, d), seed=n % 97))
+    assert qt.qpacked.shape[-2] * 2 == n  # stored as it is
+    x = jnp.asarray(_rand((5, n), seed=d % 89, scale=1.0), jnp.bfloat16)
+    w = q40.QLayerView(qt, jnp.int32(1)) if form == "stacked" else qt
+    got = np.asarray(q40.matmul(x, w, impl="pallas_interpret", out_dtype=jnp.float32))
+    ref = np.asarray(q40.matmul(x, w, impl="xla", out_dtype=jnp.float32))
+    np.testing.assert_allclose(got, ref, rtol=0, atol=1e-4 * np.abs(ref).max())
+
+
+# First line of every function of ops/q40.py that is a frame while a Q40
+# kernel is traced, as at PR 33 (commit fe8bd20).
+KERNEL_PATH_LINES = {
+    "_q40_kernel": 344, "_stacked_q40_kernel": 387, "_x_parts": 393,
+    "_mm_call": 442, "_pallas_matmul": 498, "_pallas_matmul_stacked": 522,
+    "_pallas_matmul_experts": 554, "_pad_x": 634, "_sharded_matmul": 779,
+    "_sharded_matmul_ep": 852, "matmul_experts": 980, "matmul": 996, "mm": 1063}
+
+
+def test_the_kernels_trace_path_kept_its_lines():
+    """A Mosaic kernel is serialized with the file and line of every frame
+    that traced it, and the persistent compile cache keys on those bytes: one
+    line added above ``mm`` gives every Q40 program of every model a new key,
+    and the driver's check then compares a change that compiles against a
+    parent that does not (seconds of ``setup_s``: PERF.md §7, PR 35).  So new
+    code that the kernels' call path does not run goes below ``mm``.  A PR
+    that has to move these lines re-pins them here, and knows what it costs."""
+    import inspect
+    got = {name: inspect.unwrap(getattr(q40, name)).__code__.co_firstlineno
+           for name in KERNEL_PATH_LINES}
+    assert got == KERNEL_PATH_LINES
+
+
+def test_dispatch_record_carries_the_tile_pair_and_the_stored_n(caplog):
+    import logging
+    qt = q40.quantize(_rand((1536, 256), seed=5))
+    x = jnp.asarray(_rand((2, 1536), seed=6, scale=1.0), jnp.bfloat16)
+    with caplog.at_level(logging.DEBUG, logger="dllama"):
+        q40.matmul(x, qt, impl="pallas_interpret")
+    recs = [r for r in caplog.records if getattr(r, "path", "") == "pallas-fused"]
+    assert recs and recs[-1].tiles == (1536, 256) and recs[-1].stored_n == 1536
 
 
 class TestScaleValidation:
@@ -303,9 +451,11 @@ class TestRowBlocks:
 
     @pytest.mark.parametrize("rows", [129, 256, 272, 1024])
     @pytest.mark.parametrize("form,n", [("plain", 1024), ("plain", 1056),
-                                        ("stacked", 1056), ("experts", 1024)])
+                                        ("plain", 2752), ("stacked", 2752),
+                                        ("experts", 1024)])
     def test_matches_xla(self, form, n, rows):
-        """n=1056 is stored padded to 2048 rows of zero scales."""
+        """n=1056 is stored as it is (one reduction step of 1056); n=2752 is
+        stored padded to 3072 rows of zero scales."""
         x, _, w = self._case(form, n, 384, rows)
         got = np.asarray(q40.matmul(x, w, impl="pallas_interpret",
                                     out_dtype=jnp.float32))
@@ -317,8 +467,8 @@ class TestRowBlocks:
     def test_ragged_last_block_is_masked(self, form):
         """272 rows in blocks of 256: the last block holds 16 rows and 240
         of padding that must not reach the output."""
-        x, qt, w = self._case(form, 1056, 384, 272)
-        xp = q40._pad_x(x, 1056, 2048)
+        x, qt, w = self._case(form, 2752, 384, 272)
+        xp = q40._pad_x(x, 2752, 3072)
         if form == "plain":
             got = q40._pallas_matmul(xp, qt.qpacked, qt.scales,
                                      interpret=True, row_block=256)
@@ -365,7 +515,8 @@ class TestAutoChoice:
         (4096, 4096, 1, None, True),
         (4096, 4096, 128, None, True),
         (4096, 4096, 129, None, True),     # over 128 rows: row blocks
-        (1408, 4096, 1, None, False),      # ladder lands on an illegal tile
+        (1408, 4096, 1, None, True),       # the whole axis against 640
+        (8224, 4096, 1, None, False),      # no legal tile: 257 blocks of 32
     ])
     def test_auto_rule_single_device(self, np_, d, rows, kind, want):
         assert q40._auto_pallas(np_, d, rows, kind) is want
@@ -451,18 +602,20 @@ class TestModel:
         np.testing.assert_allclose(np.asarray(lq), np.asarray(ld),
                                    rtol=0, atol=5e-2 + 2e-2 * np.abs(np.asarray(ld)).max())
 
-    def test_quantized_forward_padded_hidden(self):
-        """Hidden dim ≥ TILE_N but not a multiple (TinyLlama's 5632 shape
-        class): the w2 input axis gets pack-time padding rows whose zero
-        scales must contribute nothing — checked through a full forward,
-        both matmul implementations."""
+    @pytest.mark.parametrize("hidden,padded", [(2752, True), (1408, False)])
+    def test_quantized_forward_padded_hidden(self, hidden, padded):
+        """Hidden dims over TILE_N that are no multiple of it.  2752 has no
+        healthy tile: the w2 input axis gets pack-time padding rows whose zero
+        scales must contribute nothing.  1408 (TinyLlama's 5632 shape class at
+        a quarter) is stored as it is and reduced in one step.  Checked
+        through a full forward, both matmul implementations."""
         from dllama_tpu.models.config import tiny_config
         from dllama_tpu.models.params import init_params, quantize_matmuls
         from dllama_tpu.models.transformer import forward, init_kv_cache
 
-        cfg = tiny_config(dim=64, hidden_dim=q40.TILE_N + 384, n_layers=2,
+        cfg = tiny_config(dim=64, hidden_dim=hidden, n_layers=2,
                           n_heads=4, n_kv_heads=2, vocab_size=128, seq_len=32)
-        assert q40.padded_n(cfg.hidden_dim) != cfg.hidden_dim  # padding active
+        assert (q40.padded_n(hidden) != hidden) is padded
         params = init_params(cfg, seed=2)
         qparams = quantize_matmuls(params, cfg)
         dparams = {k: (q40.dequantize(v, jnp.float32) if isinstance(v, q40.QTensor) else v)

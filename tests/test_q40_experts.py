@@ -77,11 +77,13 @@ def test_ragged_last_row_block_is_masked():
 
 
 def test_matmul_experts_pads_the_input_dim_and_records_its_site():
-    """An input dim over TILE_N that is no multiple of it is padded at pack
-    time (zero scales); the activation is padded to match, once a call."""
-    n, d, experts = 1056, 128, 4
+    """An input dim that the tile rule cannot cut into healthy tiles (2752 =
+    86 blocks of 32: no divisor is a multiple of 256, and the whole axis
+    leaves a 256-wide output tile) is padded at pack time (zero scales); the
+    activation is padded to match, once a call."""
+    n, d, experts = 2752, 128, 4
     qt, rng = _stack(experts, n, d, seed=3)
-    assert qt.qpacked.shape[-2] * 2 == q40.padded_n(n) == 2048
+    assert qt.qpacked.shape[-2] * 2 == q40.padded_n(n) == 3072
     view = q40.QLayerView(qt, jnp.int32(LAYER))
     x = jnp.asarray(rng.standard_normal((6, n)), jnp.bfloat16)
     before = obs_dispatch.dispatches().get("q40/pallas-fused", 0)
@@ -91,6 +93,32 @@ def test_matmul_experts_pads_the_input_dim_and_records_its_site():
         ref = q40.matmul(x, view.select(jnp.int32(e), experts), impl="pallas_interpret",
                          out_dtype=jnp.float32)
         np.testing.assert_array_equal(np.asarray(out[e]), np.asarray(ref))
+
+
+# DeepSeek-V2's expert shapes at a few experts: gate / up (1280 x 768 tiles,
+# four reduction steps) from the shared activation, down from one an expert at
+# an unpadded 1536 (one step of 1536 x 640, eight output tiles)
+@pytest.mark.parametrize("rows", [5, 16])
+@pytest.mark.parametrize("n,d,per_expert,tiles", [
+    (5120, 1536, False, (1280, 768)), (1536, 5120, True, (1536, 640))],
+    ids=["gate", "down"])
+def test_all_experts_launch_matches_xla_at_the_rules_new_tiles(n, d, per_expert,
+                                                                tiles, rows):
+    experts = 3
+    rng = np.random.default_rng(n + rows)
+    qt = q40.quantize(rng.standard_normal((2, experts, n, d)).astype(np.float32) * 0.1)
+    assert qt.qpacked.shape[-2] * 2 == n == q40.padded_n(n)
+    assert q40._tiles(n, d) == tiles
+    view = q40.QLayerView(qt, jnp.int32(1))
+    x = jnp.asarray(rng.standard_normal(((experts,) if per_expert else ()) + (rows, n)),
+                    jnp.bfloat16)
+    out = np.asarray(q40.matmul_experts(x, view, experts, "pallas_interpret",
+                                        out_dtype=jnp.float32))
+    for e in range(experts):
+        ref = np.asarray(q40.matmul(x[e] if per_expert else x,
+                                    view.select(jnp.int32(e), experts), impl="xla",
+                                    out_dtype=jnp.float32))
+        np.testing.assert_allclose(out[e], ref, rtol=0, atol=1e-4 * np.abs(ref).max())
 
 
 def _views(experts=4, n=64, d=96):

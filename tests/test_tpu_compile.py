@@ -369,15 +369,18 @@ def test_olmoe_decode_slot_step_runs_the_experts_in_three_launches_a_layer(
 
 
 # DeepSeek-V2's expert matmuls (hidden 5120, expert width 1536, 160 experts of
-# 4 expert layers): gate / up have a ragged second d tile (1536 = 1024 + 512),
-# down's 1536 input columns are stored padded to 2048 (q40.padded_n)
+# 4 expert layers): gate / up are two d tiles of 768 under reduction tiles of
+# 1280, down's 1536 input columns are stored as 1536 and reduced in one step
+# against d tiles of 640 (q40._tiles, q40.padded_n)
 @pytest.mark.parametrize("rows", [16, 256])
 @pytest.mark.parametrize("name,n,d,per_expert", [
-    ("gate", 5120, 1536, False), ("down", 1536, 5120, True)], ids=["gate", "down"])
+    ("gate", 5120, 1536, False), ("up", 5120, 1536, False),
+    ("down", 1536, 5120, True)], ids=["gate", "up", "down"])
 def test_q40_experts_matmul_compiles_at_deepseek_v2_shapes(one_chip, name, n, d,
                                                            per_expert, rows):
     L, E = 4, 160
     np_ = q40.padded_n(n)
+    assert np_ == n and q40._tile_n_legal(np_, q40._tiles(np_, d)[0])
     s = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)  # noqa: E731
     x = s(((E,) if per_expert else ()) + (rows, np_), jnp.bfloat16)
     compiled = jax.jit(
@@ -391,8 +394,9 @@ def test_q40_experts_matmul_compiles_at_deepseek_v2_shapes(one_chip, name, n, d,
 
 
 # DeepSeek-V2's MLA projections: the fused down-projections from x (1536 + 576
-# = 2112 outputs: a ragged last d tile of 64), q's up-projection (1536 inputs,
-# padded to 2048), wo (16384 inputs), the shared expert's down (3072 inputs)
+# = 2112 outputs: three d tiles of 768, the last ragged), q's up-projection
+# (1536 inputs, stored as 1536), wo (16384 inputs), the shared expert's down
+# (3072 inputs)
 @pytest.mark.parametrize("name,n,d", [
     ("wqkv_a", 5120, 2112), ("wq_b", 1536, 24576), ("wo", 16384, 5120),
     ("shared_w2", 3072, 5120)], ids=lambda v: str(v))
@@ -402,6 +406,23 @@ def test_q40_matmul_compiles_at_deepseek_v2_shapes(one_chip, name, n, d):
     text = jax.jit(q40._pallas_matmul_stacked).lower(
         x, qp, sc, jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
     ).compile().as_text()
+    assert "tpu_custom_call" in text and "q40_mm_stacked" in text
+
+
+# One tp=4 shard of Yi-34B's attention projections, as the kernel under
+# shard_map sees them: q (7168 x 1792: d tiles of 896), k / v (7168 x 256: four
+# reduction steps of 1792), wo (1792 x 7168: the whole axis in one step
+# against d tiles of 512, not seven steps of 256).  128 rows: the mesh's cap
+@pytest.mark.parametrize("rows", [1, 128])
+@pytest.mark.parametrize("name,n,d,tiles", [
+    ("wq", 7168, 1792, (1024, 896)), ("wkv", 7168, 256, (1792, 256)),
+    ("wo", 1792, 7168, (1792, 512))], ids=["wq", "wkv", "wo"])
+def test_q40_matmul_compiles_at_yi_34b_shard_shapes(one_chip, name, n, d, tiles, rows):
+    assert q40._tiles(n, d) == tiles and q40._tile_n_legal(n, tiles[0])
+    s = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)  # noqa: E731
+    text = jax.jit(q40._pallas_matmul_stacked).lower(
+        s((rows, n), jnp.bfloat16), s((2, n // 2, d), jnp.uint8),
+        s((2, n // 32, d), jnp.uint16), s((), jnp.int32)).compile().as_text()
     assert "tpu_custom_call" in text and "q40_mm_stacked" in text
 
 
@@ -487,8 +508,8 @@ def test_deepseek_v2_slot_steps_compile_over_a_latent_pool(one_chip, monkeypatch
 
 @pytest.mark.parametrize("name,n,d,reduce", [
     ("wo", 4096, 4096, "q40_ring"), ("w2", 11008, 4096, "q40_ring"),
-    # Yi-34B: 1792 = 7 x 256 contracted columns a chip; 7168 % 256 == 0, so
-    # the rule (q40._fused_reduce_ok) takes the ring here too
+    # Yi-34B: 1792 contracted columns a chip, one reduction step (q40._tiles);
+    # 7168 % 256 == 0, so the rule (q40._fused_reduce_ok) takes the ring here too
     ("wo-yi", 7168, 7168, "q40_ring"), ("w2-yi", 20480, 7168, "q40_ring")])
 def test_tp4_col_matmul_compiles_on_described_mesh(topo, monkeypatch, name,
                                                    n, d, reduce):

@@ -106,10 +106,11 @@ def plan(cfg, tp=1, sp=1, dp=1, ep=1, seq_len=None, batch=1,
             is_expert = k in ("up", "gate", "down")
             div = tp * (ep if is_expert else 1)
             if quant:
-                # packed planes pad the input axis to the kernel's block
-                # granularity (q40.padded_n; up to +9% on odd hidden dims,
-                # e.g. TinyLlama's 5632→6144) — estimate what HBM actually
-                # holds, not the logical element count (ADVICE r03)
+                # packed planes pad an input axis that the tile rule cannot
+                # cut into healthy tiles (q40.padded_n; up to +9%, e.g.
+                # TinyLlama's 5632→6144; DeepSeek-V2's 1536 is stored as it
+                # is) — estimate what HBM actually holds, not the logical
+                # element count (ADVICE r03)
                 from dllama_tpu.ops.q40 import padded_n
                 *lead, nin, dout = shp
                 n = 1

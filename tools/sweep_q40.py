@@ -1,15 +1,18 @@
 """Hardware sweep of the fused Q40 kernel: tile pairs, and rows x row block.
 
-Times the kernel on Mistral-7B's five matmul shapes on one chip.  Every
-measurement happens *inside one jitted ``lax.scan``* cycling the layer
-index, exactly like the decode loop runs the kernel: a host-side dispatch
-loop measures host dispatch latency, not kernel time.  Tile pairs go through
-the kernels' ``tiles=`` keyword and row blocks through ``row_block=``, so one
-process times them all; the program's own choices are ``q40._tiles`` and
-``q40._row_block``.
+Times the kernel on one chip at the matmul shapes of the benchmark's four
+configurations (Mistral-7B, OLMoE-1B-7B, DeepSeek-V2, and a tp=4 shard of
+Yi-34B): the flat and stacked forms, and the experts form
+(``q40_mm_experts``) at each model's expert count.  Every measurement
+happens *inside one jitted ``lax.scan``* cycling the layer index, exactly
+like the decode loop runs the kernel: a host-side dispatch loop measures
+host dispatch latency, not kernel time.  Tile pairs go through the kernels'
+``tiles=`` keyword and row blocks through ``row_block=``, so one process
+times them all; the program's own choices are ``q40._tiles`` (marked
+``"rule": true`` in the records) and ``q40._row_block``.
 
-Usage: python tools/sweep_q40.py --tiles [head,w13]  # tile pairs at one row
-       python tools/sweep_q40.py --rows [head,w13]   # rows x row block
+Usage: python tools/sweep_q40.py --tiles [ds_gate,yi_wo]  # tile pairs at 1, 16, 256 rows
+       python tools/sweep_q40.py --rows [head,w13]        # rows x row block
 """
 
 from __future__ import annotations
@@ -18,20 +21,56 @@ import json
 import os
 import sys
 import time
+from typing import NamedTuple
 
 HERE = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
-# Mistral-7B's five matmuls: (name, n_in, d_out, stacked)
-SHAPES = [("qkv", 4096, 6144, True), ("wo", 4096, 4096, True),
-          ("w13", 4096, 28672, True), ("w2", 14336, 4096, True),
-          ("head", 4096, 32768, False)]
+
+class Shape(NamedTuple):
+    name: str
+    n: int                  # input dim as stored (a tp shard's local rows)
+    d: int
+    layers: int             # 0: a flat 2-D weight (the head)
+    experts: int = 0        # > 0: the experts form, this many a layer
+    x_per_expert: bool = False   # one activation block an expert (down)
+    tiles: tuple = ()       # (tile_n, tile_d) pairs to time beside the rule's
+
+
+# Mistral-7B's five matmuls: the shapes of --rows, and the first of --tiles
+MISTRAL = [Shape("qkv", 4096, 6144, 32), Shape("wo", 4096, 4096, 32),
+           Shape("w13", 4096, 28672, 32), Shape("w2", 14336, 4096, 32),
+           Shape("head", 4096, 32768, 0)]
 LAYERS = 32
-# (tile_n, tile_d); the first is the program's own (q40.TILE_N, q40.TILE_D).
-# A (tn/2, td) tile of a row-major (n/2, d) plane is td contiguous bytes per
-# row, so td sets the HBM burst length.  tile_n below 256 is illegal (the
-# scales block needs tn/32 >= 8 sublanes).
-TILE_CONFIGS = [(1024, 1024), (512, 2048), (256, 4096), (512, 4096),
-                (256, 2048), (1024, 2048), (512, 1024)]
+# PR 28's pairs, kept for Mistral.  A (tn/2, td) tile of a row-major (n/2, d)
+# plane is td contiguous bytes per row, so td sets the HBM burst length.  A
+# partial-axis tile_n below 256 is illegal (q40._tile_n_legal).
+WIDE = ((512, 2048), (1024, 2048))
+SHAPES = [s._replace(tiles=WIDE) for s in MISTRAL] + [
+    # OLMoE-1B-7B: 64 experts of 1024, hidden 2048
+    Shape("olmoe_gate", 2048, 1024, 4, 64, tiles=((2048, 512),)),
+    Shape("olmoe_down", 1024, 2048, 4, 64, True, tiles=((1024, 512),)),
+    Shape("olmoe_head", 2048, 50304, 0),
+    # DeepSeek-V2: 160 experts of 1536, hidden 5120; the fused down-projections
+    # from x (1536 + 576), q's up-projection.  *_pad: what the 1024 ladder of
+    # PRs 28-33 ran (down and wq_b stored with 2048 input columns)
+    Shape("ds_gate", 5120, 1536, 2, 160,
+          tiles=((1024, 1024), (1024, 768), (2560, 384), (512, 1536))),
+    Shape("ds_down", 1536, 5120, 2, 160, True,
+          tiles=((768, 1024), (768, 1280), (1536, 512), (512, 1024), (256, 1024))),
+    Shape("ds_down_pad", 2048, 5120, 2, 160, True),
+    Shape("ds_wqkv_a", 5120, 2112, 4, tiles=((1024, 1024), (1024, 768))),
+    Shape("ds_wq_b", 1536, 24576, 4, tiles=((768, 1024), (1536, 512))),
+    Shape("ds_wq_b_pad", 2048, 24576, 4),
+    # Yi-34B a tp=4 shard: q and k / v (row), wo (col: 7168 / 4 = 7 x 256
+    # rows), and Mistral's w2 at tp=4 (3584 = 7 x 512, no cell)
+    Shape("yi_q", 7168, 1792, 8, tiles=((1024, 1024), (1792, 512))),
+    Shape("yi_kv", 7168, 256, 8, tiles=((1024, 256), (3584, 256))),
+    Shape("yi_wo", 1792, 7168, 8, tiles=((256, 1024), (1792, 256), (256, 4096))),
+    Shape("yi_w13", 7168, 5120, 8),
+    Shape("yi_w2", 5120, 7168, 8),
+    Shape("w2_tp4", 3584, 4096, 8, tiles=((512, 1024), (3584, 256))),
+]
+TILE_ROWS = (1, 16, 256)
 # (rows, row block): None is the code's own choice (one block of every row
 # up to 128, q40._row_block above), "xla" the dequantize-then-dot path
 ROWS_CONFIGS = [(64, None), (128, None), (64, 64), (128, 128), (256, 256),
@@ -39,43 +78,57 @@ ROWS_CONFIGS = [(64, None), (128, None), (64, 64), (128, 128), (256, 256),
                 (1024, 512), (1024, 1024), (2048, None)]
 
 
-def _sweep(configs, only: set | None, reps: int, layers: int, out_name: str):
-    """Time each shape under each config inside a jitted scan over the layer
-    index, as the model runs it.  A config is ``(tag, rows, kw)``: ``tag``
-    names it in the record, ``kw`` holds the kernel's keywords (None: the
-    dequantize-then-dot XLA path).
+def _q40():
+    sys.path.insert(0, HERE)
+    from dllama_tpu.ops import q40
+    return q40
+
+
+def _sweep(shapes, configs_of, only: set | None, reps: int, out_name: str):
+    """Time each shape under each of ``configs_of(shape)`` inside a jitted
+    scan over the layer index, as the model runs it.  A config is ``(tag,
+    rows, kw)``: ``tag`` names it in the record, ``kw`` holds the kernel's
+    keywords (None: the dequantize-then-dot XLA path).
     One JSON line per measurement; all of them to ``chiprun_out/<out_name>``."""
     import jax
     import jax.numpy as jnp
-    import numpy as np
 
-    sys.path.insert(0, HERE)
-    from dllama_tpu.ops import q40
-
+    q40 = _q40()
     if jax.default_backend() != "tpu":
         print(json.dumps({"error": "no TPU"}))
         sys.exit(1)
-    rng = np.random.RandomState(0)
+    key = jax.random.key(0)
     results = []
-    for name, n, d, stacked in SHAPES:
-        if only and name not in only:
+    for sh in shapes:
+        if only and sh.name not in only:
             continue
-        L = layers if stacked else 1
-        qp = jnp.asarray(rng.randint(0, 256, (L, n // 2, d), dtype=np.uint8))
-        sc = jnp.asarray((rng.rand(L, n // 32, d).astype(np.float16)
-                          * 0.01).view(np.uint16))
-        for tag, rows, kw in configs:
-            x = jnp.asarray(rng.randn(rows, n).astype(np.float32), jnp.bfloat16)
+        n, d, E = sh.n, sh.d, sh.experts
+        L = max(sh.layers, 1)
+        # one plane's random bits, repeated: what a call reads lies at its own
+        # addresses, and the generator's 32-bit counters for a whole stack
+        # (0.8 G elements at 160 experts) do not fit in HBM
+        planes = L * max(E, 1)
+        qp = jnp.tile(jax.random.bits(key, (1, n // 2, d), jnp.uint8),
+                      (planes, 1, 1))
+        sc = jnp.tile(jax.lax.bitcast_convert_type(
+            jax.random.uniform(key, (1, n // 32, d), jnp.float16) * 0.01,
+            jnp.uint16), (planes, 1, 1))
+        for tag, rows, kw in configs_of(sh):
+            xshape = ((E,) if sh.x_per_expert else ()) + (rows, n)
+            x = jax.random.normal(key, xshape, jnp.bfloat16)
 
             def one(x, qp, sc, i):
-                if not stacked:
+                if not sh.layers:
                     # no layer index to vary: vary x, or XLA hoists the one
                     # call out of the scan
                     x = x + (i % 2).astype(x.dtype)
                 if kw is None:
                     w = q40.QLayerView(q40.QTensor(qp, sc, (n, d)), i % L)
                     return q40.matmul(x, w, impl="xla", out_dtype=jnp.float32)
-                if stacked:
+                if E:
+                    return q40._pallas_matmul_experts(x, qp, sc, i % L,
+                                                      experts=E, **kw)
+                if sh.layers:
                     return q40._pallas_matmul_stacked(x, qp, sc, i % L, **kw)
                 return q40._pallas_matmul(x, qp[0], sc[0], **kw)
 
@@ -85,10 +138,12 @@ def _sweep(configs, only: set | None, reps: int, layers: int, out_name: str):
                     o = one(x, qp, sc, i)
                     # a kernel is opaque and runs whole whatever is read of
                     # it; XLA would push a slice into its dot, so read all
-                    return acc + (o if kw is None else o[:8, :128]).sum(), None
+                    return acc + (o if kw is None else o[..., :8, :128]).sum(), None
                 return jax.lax.scan(body, jnp.float32(0), jnp.arange(reps))[0]
 
-            rec = {"shape": name, "n": n, "d": d, "rows": rows, **tag}
+            rec = {"shape": sh.name, "n": n, "d": d, "rows": rows, **tag}
+            if E:
+                rec["experts"] = E
             try:
                 float(run(x, qp, sc))  # compile + warm-up
                 best = float("inf")
@@ -96,15 +151,24 @@ def _sweep(configs, only: set | None, reps: int, layers: int, out_name: str):
                     t0 = time.perf_counter()
                     float(run(x, qp, sc))
                     best = min(best, (time.perf_counter() - t0) * 1000 / reps)
-                nbytes = (n // 2) * d + (n // 32) * d * 2  # packed + scales
+                # packed + scales, of every plane a call reads
+                nbytes = max(E, 1) * ((n // 2) * d + (n // 32) * d * 2)
                 rec.update(ms=round(best, 4),
                            GBps=round(nbytes / best / 1e6, 1),
-                           tflops=round(2 * rows * n * d / best / 1e9, 1),
+                           tflops=round(2 * max(E, 1) * rows * n * d / best / 1e9, 1),
                            us_per_row=round(best * 1000 / rows, 3))
             except Exception as e:  # noqa: BLE001 — a form Mosaic refuses is a result
                 rec["error"] = f"{type(e).__name__}: {str(e)[:300]}"
             print(json.dumps(rec), flush=True)
             results.append(rec)
+            # a loaded executable keeps its temporaries (0.8 GB at 160 experts
+            # x 256 rows): without this the sweep runs out of HBM
+            del run
+            jax.clear_caches()
+        del qp, sc, x
+        stats = jax.devices()[0].memory_stats() or {}
+        print(f"{sh.name}: {stats.get('bytes_in_use', 0) / 1e9:.2f} GB in use",
+              file=sys.stderr)
     os.makedirs(os.path.join(HERE, "chiprun_out"), exist_ok=True)
     with open(os.path.join(HERE, "chiprun_out", out_name), "w") as f:
         json.dump(results, f, indent=1)
@@ -112,15 +176,25 @@ def _sweep(configs, only: set | None, reps: int, layers: int, out_name: str):
 
 
 def measure_tiles(only: set | None = None, reps: int = 32) -> list[dict]:
-    """Tile pairs at one row (the decode shape), through ``tiles=``; per tile
-    pair, the five matmuls summed into a decode token's matmul time."""
-    results = _sweep([({"tiles": list(t)}, 1, {"tiles": t})
-                      for t in TILE_CONFIGS],
-                     only, reps, LAYERS, "sweep_tiles.json")
+    """Tile pairs at 1, 16 and 256 rows (the decode shape, the served
+    pure-decode step, the mixed step), through ``tiles=``: the rule's own pair
+    first, then the shape's candidates.  For Mistral, per tile pair, the five
+    matmuls at one row summed into a decode token's matmul time."""
+    q40 = _q40()
+
+    def configs_of(sh):
+        rule = q40._tiles(sh.n, sh.d)
+        pairs = [rule] + [t for t in sh.tiles if t != rule]
+        return [({"tiles": list(t), "rule": t == rule}, rows, {"tiles": t})
+                for rows in TILE_ROWS for t in pairs]
+
+    results = _sweep(SHAPES, configs_of, only, reps, "sweep_tiles.json")
     if not only:
-        for t in TILE_CONFIGS:
-            ms = [r.get("ms") for r in results if r["tiles"] == list(t)]
-            if None not in ms:  # SHAPES order: four a layer, then the head
+        names = [s.name for s in MISTRAL]
+        for t in [q40._tiles(4096, 4096), *WIDE]:
+            ms = [r.get("ms") for r in results if r["tiles"] == list(t)
+                  and r["rows"] == 1 and r["shape"] in names]
+            if None not in ms:  # MISTRAL order: four a layer, then the head
                 print(json.dumps({"tiles": list(t), "matmul_ms_per_token":
                                   round(sum(ms[:-1]) * LAYERS + ms[-1], 3)}))
     return results
@@ -129,10 +203,11 @@ def measure_tiles(only: set | None = None, reps: int = 32) -> list[dict]:
 def measure_rows(only: set | None = None, reps: int = 16,
                  layers: int = 4) -> list[dict]:
     """Rows x row block, through ``row_block=``, against the XLA path."""
-    return _sweep([({"block": block}, rows,
-                    None if block == "xla" else {"row_block": block})
-                   for rows, block in ROWS_CONFIGS],
-                  only, reps, layers, "sweep_rows.json")
+    configs = [({"block": block}, rows,
+                None if block == "xla" else {"row_block": block})
+               for rows, block in ROWS_CONFIGS]
+    return _sweep([s._replace(layers=min(s.layers, layers)) for s in MISTRAL],
+                  lambda sh: configs, only, reps, "sweep_rows.json")
 
 
 def main():
