@@ -18,7 +18,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..io import mfile
-from ..obs import trace as obs_trace
+from ..obs import metrics as obs_metrics, trace as obs_trace
 from ..ops import q40, q8
 from .config import ModelConfig
 
@@ -259,7 +259,8 @@ def load_params(mf: mfile.MFile, cfg: ModelConfig | None = None,
     """
     if cfg is None:
         cfg = ModelConfig.from_spec(mf.spec)
-    with obs_trace.span("engine.load_read", layers=cfg.n_layers):
+    with obs_trace.span("engine.load_read", layers=cfg.n_layers,
+                        total=obs_metrics.load_seconds("read")):
         return cfg, _read_params(mf, cfg, dtype, keep_quantized, fuse)
 
 

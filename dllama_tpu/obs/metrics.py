@@ -30,6 +30,7 @@ and stdlib-only.  See docs/OBSERVABILITY.md for the metric catalog.
 from __future__ import annotations
 
 import bisect
+import sys
 import threading
 import time
 
@@ -131,6 +132,31 @@ class Gauge:
         lines.append(f"{self.name} {_fmt(self.value)}")
 
 
+class Cell:
+    """One child of a :class:`LabeledCounter`, bound once so a hot call
+    site pays no label handling: ``add(x)`` adds ``x * scale`` (a span
+    hands over seconds, ``sched_host_ms`` keeps milliseconds) and
+    ``received`` is what it took in so far, in the caller's unit.  This is
+    what ``obs.trace.span(total=..., less=...)`` takes."""
+
+    __slots__ = ("_family", "_key", "_scale")
+
+    def __init__(self, family: "LabeledCounter", key: tuple, scale: float):
+        self._family, self._key, self._scale = family, key, scale
+
+    def add(self, x: float) -> None:
+        fam = self._family
+        with fam._lock:
+            fam._children[self._key] = fam._children.get(self._key, 0) \
+                + x * self._scale
+
+    @property
+    def received(self) -> float:
+        fam = self._family
+        with fam._lock:
+            return fam._children.get(self._key, 0) / self._scale
+
+
 class LabeledCounter:
     """A counter family: one Prometheus metric name, one sample per label
     value (``dllama_q40_degrade_total{reason="unshardable"} 2``).  The
@@ -148,6 +174,19 @@ class LabeledCounter:
         self.labels = (labels,) if isinstance(labels, str) else tuple(labels)
         self._lock = threading.Lock()
         self._children: dict[tuple, float] = {}
+        self._cells: dict[tuple, Cell] = {}
+
+    def cell(self, *values, scale: float = 1.0) -> Cell:
+        """The bound child for ``values`` (made once, then looked up)."""
+        cell = self._cells.get((values, scale))
+        if cell is None:
+            if len(values) != len(self.labels):
+                raise ValueError(f"{self.name} takes {len(self.labels)} "
+                                 f"label value(s) {self.labels}, got "
+                                 f"{values!r}")
+            cell = self._cells[(values, scale)] = Cell(
+                self, tuple(str(v) for v in values), scale)
+        return cell
 
     def inc(self, *values, n: float = 1) -> None:
         if len(values) != len(self.labels):
@@ -583,6 +622,39 @@ ENGINE_COMPILE_S = REGISTRY.histogram(
 ENGINE_LIVE_EXECUTABLES = REGISTRY.gauge(
     "engine_live_executables",
     "Compiled executables the live engines currently hold.")
+# what JAX itself reports of every compile in the process, the persistent
+# cache's side included (watch_compiles() below feeds them from
+# jax.monitoring): the part of a start-up that the cache's history decides
+COMPILE_CACHE_REQUESTS = REGISTRY.counter(
+    "compile_cache_requests",
+    "Compiles that looked their program up in the persistent compile "
+    "cache (0 while the cache is off).")
+COMPILE_CACHE_HITS = REGISTRY.counter(
+    "compile_cache_hits",
+    "Of those, programs loaded from the persistent compile cache; "
+    "requests - hits were compiled by the backend.")
+COMPILE_CACHE_WRITES = REGISTRY.counter(
+    "compile_cache_writes",
+    "Compiled programs written to the persistent compile cache (a "
+    "program under JAX's minimum compile time is never written, and "
+    "misses again in the next process).")
+BACKEND_COMPILE_SECONDS = REGISTRY.counter(
+    "backend_compile_seconds",
+    "Seconds inside the backend's compile-or-load call, cache hits' "
+    "loads included.")
+COMPILE_CACHE_RETRIEVAL_SECONDS = REGISTRY.counter(
+    "compile_cache_retrieval_seconds",
+    "Seconds spent reading hit programs from the persistent compile "
+    "cache.")
+JAXPR_TRACE_SECONDS = REGISTRY.counter(
+    "jaxpr_trace_seconds",
+    "Seconds spent tracing Python functions to jaxprs.")
+# set where the spans engine.load_read / engine.load_place close: the
+# ring forgets the spans within one served window, the gauge does not
+ENGINE_LOAD_SECONDS = REGISTRY.labeled_gauge(
+    "engine_load_seconds", "phase",
+    "Seconds of the last model load, by phase: read (file to host "
+    "stacks) and place (each chip's shard uploaded).")
 
 # continuous-batching scheduler (runtime/scheduler.py).  Efficiency is
 # set per dispatch: live rows / slots — pad/free rows ride the lockstep
@@ -728,6 +800,17 @@ SCHED_HOST_GAP_HIDDEN_MS = REGISTRY.counter(
     "Host-side dispatch-gap milliseconds hidden behind device execution "
     "by the overlapped pipeline (reported separately, never double-"
     "counted into sched_step_time_ms components).")
+# the host's part of a step by phase, whether the device hid it or not: the
+# split of sched_step_time_ms{component="host_gap"} + sched_host_gap_hidden_ms
+# (those two say how much of it the device waited for).  Each cell receives
+# the duration of the span of the same name (sched.admit's less the
+# sched.evict inside it), so /debug/trace and this family cannot disagree.
+SCHED_HOST_MS = REGISTRY.labeled_counter(
+    "sched_host_ms", ("phase", "kind"),
+    "Host milliseconds of the scheduler loop by phase (admit, evict, "
+    "build, h2d, launch, fanout, verdict: work; land_wait: the host "
+    "waiting for the device, its slack) and by the step's kind as in "
+    "sched_steps (round: before the step's shape is decided).")
 SCHED_OVERLAP_DISCARDS = REGISTRY.counter(
     "sched_overlap_discards",
     "Pipelined dispatches landed and thrown away at a pipeline flush "
@@ -894,3 +977,71 @@ FLEET_SCRAPE_SECONDS = REGISTRY.histogram(
     "fleet_scrape_seconds", (0.005, 0.02, 0.05, 0.1, 0.25, 1.0, 5.0),
     "Wall time of one whole federated /metrics fan-out (all replicas "
     "scraped concurrently, slowest replica dominates).")
+
+
+class GaugeCell:
+    """One child of a :class:`LabeledGauge` as a span's ``total=``: the
+    duration it is handed is set, not summed (the last load's seconds)."""
+
+    __slots__ = ("_family", "_values")
+
+    def __init__(self, family: LabeledGauge, *values):
+        self._family, self._values = family, values
+
+    def add(self, x: float) -> None:
+        self._family.set(*self._values, x)
+
+
+def load_seconds(phase: str) -> GaugeCell:
+    """``engine_load_seconds{phase}`` for ``obs.trace.span(total=...)``."""
+    return GaugeCell(ENGINE_LOAD_SECONDS, phase)
+
+
+def host_ms(phase: str, kind: str) -> Cell:
+    """The ``sched_host_ms`` cell of one phase and step kind, for
+    ``obs.trace.span(total=...)``: it takes seconds, keeps milliseconds."""
+    return SCHED_HOST_MS.cell(phase, kind, scale=1e3)
+
+
+#: jax.monitoring event -> the counter it feeds (one per occurrence)
+_COMPILE_EVENTS = {
+    "/jax/compilation_cache/compile_requests_use_cache":
+        COMPILE_CACHE_REQUESTS,
+    "/jax/compilation_cache/cache_hits": COMPILE_CACHE_HITS,
+    # fired where an entry is written, not where a look-up fails
+    "/jax/compilation_cache/cache_misses": COMPILE_CACHE_WRITES,
+}
+#: jax.monitoring duration event -> the counter that sums its seconds
+_COMPILE_DURATIONS = {
+    "/jax/core/compile/backend_compile_duration": BACKEND_COMPILE_SECONDS,
+    "/jax/compilation_cache/cache_retrieval_time_sec":
+        COMPILE_CACHE_RETRIEVAL_SECONDS,
+    "/jax/core/compile/jaxpr_trace_duration": JAXPR_TRACE_SECONDS,
+}
+_watching_compiles = False
+
+
+def watch_compiles() -> bool:
+    """Feed the six compile counters from ``jax.monitoring``, once a
+    process: called where the engine is built.  A process that has not
+    loaded JAX (the router, a supervisor) gets no listener and no import;
+    returns whether the listeners are in place."""
+    global _watching_compiles
+    jax = sys.modules.get("jax")
+    if jax is None or _watching_compiles:
+        return _watching_compiles
+
+    def on_event(event: str, **_kw) -> None:
+        counter = _COMPILE_EVENTS.get(event)
+        if counter is not None:
+            counter.inc()
+
+    def on_duration(event: str, duration: float, **_kw) -> None:
+        counter = _COMPILE_DURATIONS.get(event)
+        if counter is not None:
+            counter.inc(duration)
+
+    jax.monitoring.register_event_listener(on_event)
+    jax.monitoring.register_event_duration_secs_listener(on_duration)
+    _watching_compiles = True
+    return True

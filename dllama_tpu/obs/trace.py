@@ -23,6 +23,11 @@ the ring record it enters ``jax.profiler.TraceAnnotation(name, **args)``,
 so a ``jax.profiler`` trace shows the same spans, with the same arguments,
 on the profiler's clock beside the device's ops (a flag test when no
 profiler session is active; skipped in a process that never loaded JAX).
+A call site may also name a counter cell (``total=``, e.g.
+``obs.metrics.host_ms("h2d", "decode")``): it receives the same
+``t1 - t0`` the ring record holds, less what a second cell (``less=``)
+received meanwhile, so a parent's counter can keep its self time.  One
+measurement feeds ring, profiler and counter; they cannot disagree.
 ``record(name, t0, t1)`` stays for spans that are not one block of code
 (a pipelined dispatch is enqueued in one scheduler round and lands in the
 next); those are in the ring only.  The names are listed in
@@ -46,7 +51,6 @@ import threading
 import time
 import uuid
 from collections import OrderedDict, deque
-from contextlib import contextmanager
 
 from .log import get_logger, request_id_var
 
@@ -162,6 +166,59 @@ def _annotation(name: str, rid, args: dict):
     return ann
 
 
+class SpanArgs(dict):
+    """A span's arguments as its block sees them.  What the block adds (a
+    shape decided half-way) reaches the ring and the profiler; ``total``
+    is the counter cell the duration goes to, which the block may also
+    name late (a step's kind is decided while it is built)."""
+
+    __slots__ = ("total",)
+
+
+class Span:
+    """One timed block: ``with span(name, **args) as args``.  On exit the
+    one ``t1 - t0`` goes to the ring, to the profiler's annotation (entered
+    first, so it encloses the same block) and, where the call site named
+    one, to a counter cell: anything with ``add(seconds)``.  ``less`` is a
+    cell with ``received`` (seconds so far): what it took in while the
+    block ran is left out of ``total``, which then holds the block's self
+    time (``sched.admit`` less the ``sched.evict`` inside it)."""
+
+    __slots__ = ("_tracer", "_name", "_rid", "_args", "_less", "_less0",
+                 "_ann", "_known", "_t0")
+
+    def __init__(self, tracer, name: str, rid, total, less, args: dict):
+        self._tracer, self._name, self._rid = tracer, name, rid
+        self._args = SpanArgs(args)
+        self._args.total = total
+        self._less = less
+
+    def __enter__(self) -> SpanArgs:
+        args = self._args
+        self._ann = _annotation(self._name, self._rid, args)
+        self._known = tuple(args) if self._ann is not None else ()
+        if self._less is not None:
+            self._less0 = self._less.received
+        self._t0 = time.perf_counter()
+        return args
+
+    def __exit__(self, *exc) -> bool:
+        t1 = time.perf_counter()
+        args, ann = self._args, self._ann
+        if ann is not None:
+            late = _profiler_args(args, skip=self._known)
+            if late:
+                ann.set_metadata(**late)
+            ann.__exit__(None, None, None)
+        dur = self._tracer.record(self._name, self._t0, t1, rid=self._rid,
+                                  **args)
+        if args.total is not None:
+            if self._less is not None:
+                dur = max(dur - (self._less.received - self._less0), 0.0)
+            args.total.add(dur)
+        return False
+
+
 class Tracer:
     """Lock + ring buffer of completed spans (dicts)."""
 
@@ -171,8 +228,9 @@ class Tracer:
         self._seq = 0
 
     def record(self, name: str, t0: float, t1: float, rid=None,
-               **args) -> None:
+               **args) -> float:
         """Record a completed span; ``t0``/``t1`` are perf_counter secs.
+        Returns the duration it stored.
         ``rid`` overrides the ambient contextvar request ID — threads that
         work on behalf of another request (the scheduler loop) stamp the
         ticket's ID explicitly.  The span's fleet trace id resolves from
@@ -180,13 +238,15 @@ class Tracer:
         th = threading.current_thread()
         rid = rid if rid is not None else request_id_var.get()
         trace = trace_of(rid) or trace_id_var.get()
-        span = {"name": name, "ts": t0, "dur": max(t1 - t0, 0.0),
+        dur = max(t1 - t0, 0.0)
+        span = {"name": name, "ts": t0, "dur": dur,
                 "tid": th.ident or 0, "thread": th.name,
                 "rid": rid, "trace": trace, "args": args}
         with self._lock:
             self._seq += 1
             span["seq"] = self._seq
             self._spans.append(span)
+        return dur
 
     def resize(self, capacity: int) -> None:
         """Re-bound the ring, keeping the most recent spans that fit."""
@@ -197,25 +257,11 @@ class Tracer:
     def capacity(self) -> int:
         return self._spans.maxlen or 0
 
-    @contextmanager
-    def span(self, name: str, rid=None, **args):
+    def span(self, name: str, rid=None, total=None, less=None, **args):
         """Time the enclosed block into the ring and, under an active
-        ``jax.profiler`` session, into the profiler's host plane.  Yields
-        the argument dict: what the block adds to it (a shape decided
-        half-way) reaches both."""
-        ann = _annotation(name, rid, args)
-        known = tuple(args) if ann is not None else ()
-        t0 = time.perf_counter()
-        try:
-            yield args
-        finally:
-            t1 = time.perf_counter()
-            if ann is not None:
-                late = _profiler_args(args, skip=known)
-                if late:
-                    ann.set_metadata(**late)
-                ann.__exit__(None, None, None)
-            self.record(name, t0, t1, rid=rid, **args)
+        ``jax.profiler`` session, into the profiler's host plane; see
+        :class:`Span`."""
+        return Span(self, name, rid, total, less, args)
 
     def snapshot(self) -> list[dict]:
         with self._lock:
@@ -302,8 +348,8 @@ def configure(capacity: int | None = None) -> None:
         TRACER.resize(capacity)
 
 
-def span(name: str, rid=None, **args):
-    return TRACER.span(name, rid=rid, **args)
+def span(name: str, rid=None, total=None, less=None, **args):
+    return TRACER.span(name, rid=rid, total=total, less=less, **args)
 
 
 def trace_json(last_requests: int | None = None) -> dict:

@@ -30,6 +30,7 @@ import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..models.config import ModelConfig
+from ..obs import metrics as obs_metrics, trace as obs_trace
 from ..models.params import DENSE_FFN_KEYS, MLA_ATT_KEYS, MOE_FFN_KEYS
 
 REPL = P()
@@ -155,7 +156,16 @@ def place_params(params: dict, cfg: ModelConfig, mesh: Mesh) -> dict:
     quantized bytes (commands.cpp:19-36).  ``jax.device_put`` applies the
     sharding to both pytree leaves (qpacked + scales, whose row counts are
     N/2 and N/32 — both divisible at block granularity).
+
+    The span ``engine.load_place`` and ``engine_load_seconds{phase="place"}``
+    (the gauge outlives the span ring).
     """
+    with obs_trace.span("engine.load_place", devices=mesh.size,
+                        total=obs_metrics.load_seconds("place")):
+        return _place_params(params, cfg, mesh)
+
+
+def _place_params(params: dict, cfg: ModelConfig, mesh: Mesh) -> dict:
     specs = param_specs(cfg)
     out = {}
     for k, v in params.items():

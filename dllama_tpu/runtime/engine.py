@@ -21,6 +21,7 @@ only remaining boundary: fetching logits for the host-side sampler.
 
 from __future__ import annotations
 
+import contextlib
 import os
 import time
 from dataclasses import dataclass, field
@@ -116,6 +117,56 @@ def _unfuse(params: Params, cfg: ModelConfig) -> Params:
     if "w13" in p:
         p["w1"], p["w3"] = split(p.pop("w13"), [cfg.hidden_dim, cfg.hidden_dim])
     return p
+
+
+def _compile_totals() -> tuple[int, int, float, float]:
+    return (obs_metrics.COMPILE_CACHE_REQUESTS.value,
+            obs_metrics.COMPILE_CACHE_HITS.value,
+            obs_metrics.BACKEND_COMPILE_SECONDS.value,
+            obs_metrics.COMPILE_CACHE_RETRIEVAL_SECONDS.value)
+
+
+@contextlib.contextmanager
+def _compile_span(key: tuple):
+    before = _compile_totals()
+    with obs_trace.span("engine.compile", key=repr(key)) as sp:
+        try:
+            yield
+        finally:
+            req, hits, secs, load = (a - b for a, b in zip(_compile_totals(),
+                                                           before))
+            sp.update(cache_requests=req, cache_hits=hits,
+                      backend_s=round(secs, 6),
+                      compiled_s=round(max(secs - load, 0.0), 6))
+
+
+def _compiling(fresh: bool, *key):
+    """The span ``engine.compile`` around a fresh program's first call, which
+    blocks through trace and compile (no span once the program has run):
+    ``key`` as the ``compile`` log line has it, and what JAX counted
+    meanwhile (``obs_metrics.watch_compiles``): programs looked up in the
+    persistent cache, programs found there, the seconds inside the backend's
+    compile-or-load (``backend_s``) and those of them not spent loading a
+    hit (``compiled_s``)."""
+    return _compile_span(key) if fresh else contextlib.nullcontext()
+
+
+def _compile_clock() -> float:
+    """Seconds JAX has spent tracing and compiling in this process: it moves
+    over a call that compiled, whether the engine knew the program or not
+    (``jax.jit`` traces a known program again for operands placed anew: the
+    first pipelined step, whose tokens are on the device)."""
+    return obs_metrics.JAXPR_TRACE_SECONDS.value \
+        + obs_metrics.BACKEND_COMPILE_SECONDS.value
+
+
+def _launched(span_args, kind: str, clock0: float) -> None:
+    """Close of an ``engine.launch`` block: a launch that traced or compiled
+    is a compile, not the host's part of a step, and its duration goes to
+    ``sched_host_ms{phase="compile"}`` (which no reader counts)."""
+    if _compile_clock() != clock0:
+        span_args.update(compiled=True)
+        span_args.total = obs_metrics.host_ms("compile", kind)
 
 
 class ContextOverflow(ValueError):
@@ -309,8 +360,8 @@ class Engine:
                 tp=self.mesh.shape.get("tp", 1),
                 hint="fused collective-matmul decode is TPU-only; tp "
                      "collectives run as plain psum all-reduce")
-        with obs_trace.span("engine.load_place", devices=self.mesh.size):
-            self.params = sharding.place_params(params, cfg, self.mesh)
+        obs_metrics.watch_compiles()  # before this engine's first program
+        self.params = sharding.place_params(params, cfg, self.mesh)
         for dev, nbytes in _resident_param_bytes(self.params).items():
             obs_metrics.PARAM_BYTES_RESIDENT.set(dev, nbytes)
         # kv_dtype "q8" (or int8) selects the quantized cache: int8 values
@@ -773,7 +824,7 @@ class Engine:
         step_key = ("ring" if use_ring else "step",
                     tokens_np.shape, offsets is not None)
         fresh_exec = step_key not in self._compiled_steps
-        with active_mesh(self.mesh):  # read at trace time (first call)
+        with _compiling(fresh_exec, *step_key), active_mesh(self.mesh):
             if use_ring:
                 toks = jax.device_put(
                     tokens_np, NamedSharding(self.mesh, P("dp", "sp")))
@@ -968,8 +1019,9 @@ class Engine:
             # necessarily fetched) so a speculative chunk never overshoots
             # the requested steps
             k = min(chunk, steps - done, self.seq_len - self.pos)
-            fresh = (k, float(temperature), float(topp)) not in self._chunk_fns
-            fn = self._chunk_fn(k, temperature, topp)
+            key = (k, float(temperature), float(topp))
+            fresh = key not in self._chunk_fns
+            fn = self._chunk_fn(*key)
             sub = jax.random.fold_in(self._key, self._chunk_counter)
             self._chunk_counter += 1
             p0 = self.pos
@@ -981,7 +1033,7 @@ class Engine:
                          if isinstance(in_tok_dev, np.ndarray) else 0)
             t0 = time.perf_counter()
             with obs_trace.span("engine.chunk_enqueue", pos=p0, k=k), \
-                    active_mesh(self.mesh):
+                    _compiling(fresh, "chunk", *key), active_mesh(self.mesh):
                 toks_dev, self.cache, last_dev, _pos, _key = fn(
                     self.params, self.cache, jnp.asarray(in_tok_dev),
                     jnp.int32(p0), sub)
@@ -1137,12 +1189,13 @@ class Engine:
             # ``done`` = steps already covered by prior dispatches, so a
             # speculative chunk never runs past the consumer's budget
             k = min(chunk, steps - done, self.seq_len - self.pos)
-            fresh = (k, float(temperature), float(topp)) not in self._chunk_fns
-            fn = self._chunk_fn(k, temperature, topp)
+            key = (k, float(temperature), float(topp))
+            fresh = key not in self._chunk_fns
+            fn = self._chunk_fn(*key)
             sub = jax.random.fold_in(self._key, self._chunk_counter)
             self._chunk_counter += 1
             tc = time.perf_counter()
-            with active_mesh(self.mesh):
+            with _compiling(fresh, "chunk", *key), active_mesh(self.mesh):
                 toks_dev, self.cache, last_dev, _pos, _key = fn(
                     self.params, self.cache, jnp.asarray(in_tok, jnp.int32),
                     jnp.int32(self.pos), sub, self._offsets)
@@ -1173,6 +1226,33 @@ class Engine:
                 self._chunk_counter -= 1
 
     # ------------------------------------------------------------------
+    def _slot_operands(self, kind: str, tokens_np, feed_dev, pos_rows_np,
+                       n_valid_np, sub, temps_np, topps_np, topks_np,
+                       page_tables_np, vocab_mask_np) -> tuple:
+        """The operands of a slot program in its argument order, the host
+        arrays uploaded: the span ``engine.h2d`` (``arrays`` and ``bytes``
+        that crossed; ``feed_dev``: the tokens were on the device already)
+        and ``sched_host_ms{phase="h2d"}`` under the step's ``kind``."""
+        with obs_trace.span("engine.h2d", feed_dev=feed_dev is not None,
+                            total=obs_metrics.host_ms("h2d", kind)) as sp:
+            if topks_np is None:
+                topks_np = np.zeros(len(pos_rows_np), np.int32)
+            host = [] if feed_dev is not None else [(tokens_np, jnp.int32)]
+            host += [(pos_rows_np, jnp.int32), (n_valid_np, jnp.int32),
+                     (temps_np, jnp.float32), (topps_np, jnp.float32),
+                     (topks_np, jnp.int32)]
+            if self.paged:
+                host.append((page_tables_np, jnp.int32))
+            if vocab_mask_np is not None:
+                host.append((vocab_mask_np, bool))
+            dev = [jnp.asarray(a, dt) for a, dt in host]
+            tok = dev.pop(0) if feed_dev is None \
+                else jnp.asarray(feed_dev, jnp.int32)[:, None]  # on device
+            pos, n_valid, *rest = dev
+            sp.update(arrays=len(host),
+                      bytes=sum(np.asarray(a).nbytes for a, _ in host))
+            return (self.params, self.cache, tok, pos, n_valid, sub, *rest)
+
     def slot_step_async(self, tokens_np: np.ndarray | None,
                         pos_rows_np: np.ndarray, n_valid_np: np.ndarray, *,
                         temps_np: np.ndarray, topps_np: np.ndarray,
@@ -1287,26 +1367,24 @@ class Engine:
         self._note_executable(fresh, key=key)
         fn = self._chunk_fns[key]
         sub = self._next_dev_key()
+        kind = "mixed" if t > 1 else "decode"  # the step's, as given here
         t0 = time.perf_counter()
+        # the host's part of the enqueue, in two phases that each feed a
+        # cell of ``sched_host_ms``: the operand uploads (``engine.h2d``)
+        # and the jitted call (``engine.launch``: executable look-up,
+        # argument handling, PJRT enqueue and any wait inside it; a call
+        # that compiled goes to the ``compile`` cell instead)
         with obs_trace.span("engine.slot_enqueue", t=t, steps=steps):
-            if feed_dev is not None:
-                tok_arr = jnp.asarray(feed_dev, jnp.int32)[:, None]  # on device
-            else:
-                tok_arr = jnp.asarray(tokens_np, jnp.int32)
-            if topks_np is None:
-                topks_np = np.zeros(len(pos_rows_np), np.int32)
-            args = (self.params, self.cache, tok_arr,
-                    jnp.asarray(pos_rows_np, jnp.int32),
-                    jnp.asarray(n_valid_np, jnp.int32), sub,
-                    jnp.asarray(temps_np, jnp.float32),
-                    jnp.asarray(topps_np, jnp.float32),
-                    jnp.asarray(topks_np, jnp.int32))
-            if self.paged:
-                args = args + (jnp.asarray(page_tables_np, jnp.int32),)
-            if has_mask:
-                args = args + (jnp.asarray(vocab_mask_np, bool),)
-            with active_mesh(self.mesh):
+            args = self._slot_operands(
+                kind, tokens_np, feed_dev, pos_rows_np, n_valid_np, sub,
+                temps_np, topps_np, topks_np, page_tables_np, vocab_mask_np)
+            clock0 = _compile_clock()
+            with obs_trace.span(
+                    "engine.launch", fresh=fresh,
+                    total=obs_metrics.host_ms("launch", kind)) as launch, \
+                    _compiling(fresh, *key), active_mesh(self.mesh):
                 toks_dev, self.cache, last_dev, self._dev_key = fn(*args)
+                _launched(launch, kind, clock0)
         return SlotDispatch(self, toks_dev, last_dev, t=t, steps=steps,
                             fresh=fresh, enqueued_at=t0)
 
@@ -1410,22 +1488,17 @@ class Engine:
         sub = self._next_dev_key()
         t0 = time.perf_counter()
         with obs_trace.span("engine.slot_enqueue", t=t, steps=1, verify=True):
-            if topks_np is None:
-                topks_np = np.zeros(len(pos_rows_np), np.int32)
-            args = (self.params, self.cache,
-                    jnp.asarray(tokens_np, jnp.int32),
-                    jnp.asarray(pos_rows_np, jnp.int32),
-                    jnp.asarray(n_valid_np, jnp.int32), sub,
-                    jnp.asarray(temps_np, jnp.float32),
-                    jnp.asarray(topps_np, jnp.float32),
-                    jnp.asarray(topks_np, jnp.int32))
-            if self.paged:
-                args = args + (jnp.asarray(page_tables_np, jnp.int32),)
-            if has_mask:
-                args = args + (jnp.asarray(vocab_mask_np, bool),)
-            with active_mesh(self.mesh):
+            args = self._slot_operands(
+                "verify", tokens_np, None, pos_rows_np, n_valid_np, sub,
+                temps_np, topps_np, topks_np, page_tables_np, vocab_mask_np)
+            clock0 = _compile_clock()
+            with obs_trace.span(
+                    "engine.launch", fresh=fresh,
+                    total=obs_metrics.host_ms("launch", "verify")) as launch, \
+                    _compiling(fresh, *key), active_mesh(self.mesh):
                 preds_dev, self.cache, accepted_dev, last_dev, \
                     self._dev_key = fn(*args)
+                _launched(launch, "verify", clock0)
         return SlotVerifyDispatch(self, preds_dev, accepted_dev, last_dev,
                                   t=t, fresh=fresh, enqueued_at=t0)
 
@@ -1493,7 +1566,7 @@ class Engine:
             # many array outputs the top_k variant returns
             self._chunk_fns[key] = jax.jit(score, out_shardings=self._rep)
         tc = time.perf_counter()
-        with active_mesh(self.mesh):
+        with _compiling(fresh_score, *key), active_mesh(self.mesh):
             cache = init_kv_cache(self.cfg, self.batch, bucket,
                                   dtype=self.cache.k.dtype
                                   if not self.cache.quantized else None)
@@ -1738,8 +1811,6 @@ class SlotDispatch:
         # (obs/flight.py); for an overlapped dispatch it includes the
         # predecessor still executing, so it bounds device time from above
         eng.last_slot_dispatch_ms = (t1 - self.enqueued_at) * 1e3
-        obs_trace.record("slot_step", self.enqueued_at, t1,
-                         t=self.t, steps=self.steps)
         self._out = np.asarray(self.tokens_dev)  # (steps, B)
         return self._out
 
@@ -1785,7 +1856,6 @@ class SlotVerifyDispatch:
         if self.fresh:  # first call blocked through trace + compile
             obs_metrics.ENGINE_COMPILE_S.observe(t1 - self.enqueued_at)
         eng.last_slot_dispatch_ms = (t1 - self.enqueued_at) * 1e3
-        obs_trace.record("slot_verify", self.enqueued_at, t1, t=self.t)
         self._out = (np.asarray(self.preds_dev),
                      np.asarray(self.accepted_dev))
         return self._out

@@ -30,6 +30,8 @@ pages are never written after insertion).
 
 from __future__ import annotations
 
+from ..obs import metrics as obs_metrics, trace as obs_trace
+
 
 class PagePoolExhausted(RuntimeError):
     """No free pages for an allocation; the caller defers admission."""
@@ -210,11 +212,23 @@ class RadixTree:
         """Free at least ``n_pages`` pages by dropping LRU *leaf* nodes
         whose pages only the tree references (live requests are never
         robbed).  Returns the number actually freed (may be less when
-        everything else is shared or interior)."""
-        freed = 0
+        everything else is shared or interior).  One whole walk of the
+        tree a page: the span ``sched.evict`` says what that costs
+        (``visited``: nodes the walks touched) and feeds
+        ``sched_host_ms{phase="evict"}``; it lies inside the scheduler
+        span that asked (``sched.admit``, ``sched.build``)."""
+        with obs_trace.span("sched.evict", asked=n_pages,
+                            total=obs_metrics.host_ms("evict", "round")) as sp:
+            freed, visited = self._evict(n_pages)
+            sp.update(freed=freed, visited=visited)
+        return freed
+
+    def _evict(self, n_pages: int) -> tuple[int, int]:
+        freed = visited = 0
         while freed < n_pages:
             victim_parent = victim_key = victim = None
             stack = [(self._children, k, nd) for k, nd in self._children.items()]
+            visited += self._n_nodes  # a walk pops every node once
             while stack:
                 parent, key, nd = stack.pop()
                 if nd.children:
@@ -231,7 +245,7 @@ class RadixTree:
             self._n_nodes -= 1
             self.pool.decref([victim.page])
             freed += 1
-        return freed
+        return freed, visited
 
     def drop_all(self) -> int:
         """Release every retained page (scheduler close/reset)."""

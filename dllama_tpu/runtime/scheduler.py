@@ -236,6 +236,19 @@ class _Pending:
             setattr(self, k, kw.get(k))
 
 
+def _kind(cur: _Pending) -> str:
+    """A dispatch's kind as ``sched_steps`` counts it."""
+    return "verify" if cur.verify else "mixed" if cur.prefset else "decode"
+
+
+# cells of ``sched_host_ms`` whose kind is known beforehand (``obs_trace.span``
+# ``total=`` / ``less=``)
+_ADMIT = obs_metrics.host_ms("admit", "round")
+_EVICT = obs_metrics.host_ms("evict", "round")
+_BUILD_DECLINED = obs_metrics.host_ms("build", "round")
+_VERDICT = obs_metrics.host_ms("verdict", "decode")  # of a pipelined step
+
+
 class SlotScheduler:
     """Owns the batch engine; see the module docstring.  ``max_queue``
     bounds requests waiting for a slot (beyond it submit() raises
@@ -1609,7 +1622,8 @@ class SlotScheduler:
         try:
             while True:
                 with self._cond:
-                    with self._span("sched.admit"):
+                    with self._span("sched.admit", seq=self._n_enqueued + 1,
+                                    total=_ADMIT, less=_EVICT):
                         now = time.monotonic()
                         real_active = self._round_head_locked(now)
                         # a spilled slot holds a ticket but no pages — it
@@ -1683,7 +1697,7 @@ class SlotScheduler:
                 if nxt is not None:
                     self._abandon(nxt)
                 return
-            with obs_trace.span("sched.verdict", seq=nxt.seq):
+            with obs_trace.span("sched.verdict", seq=nxt.seq, total=_VERDICT):
                 survivors = self._pipeline_verdict(nxt)
             if survivors is None:
                 self._abandon(nxt)
@@ -1702,116 +1716,125 @@ class SlotScheduler:
     def _build_and_enqueue(self, active: list[int], queued: int,
                            sp: dict) -> _Pending:
         """:meth:`_enqueue_first` inside its span; ``sp`` takes the
-        dispatch's shape once it is decided."""
-        eng = self.engine
-        b = eng.batch
-        slots = self.slots
-        prefilling = [i for i in active
-                      if slots[i].fed < len(slots[i].ticket.prompt)]
-        room = min(eng.seq_len - slots[i].pos for i in active)
-        # consume the slots' pending draft proposals (runtime/spec.py).
-        # Proposals are valid for exactly the next dispatch after the
-        # burst that produced them — decode rows advance every dispatch —
-        # so they are popped unconditionally here and re-validated:
-        # identity-checked against the slot's *current* ticket (retire /
-        # park / import all rebind), dropped whole when a prefilling row
-        # joins (the mixed step has no verify shape) or the context edge
-        # is closer than a full verify window (flush, not truncate: the
-        # proposer re-drafts next round from exact state either way)
-        props: dict[int, list[int]] = {}
-        if self.spec is not None:
-            with self._cond:
-                pend, self._proposals = self._proposals, {}
-            if not prefilling and room >= self.spec_k + 1:
-                for i, (tk, d) in pend.items():
-                    if i in active and slots[i].ticket is tk and d:
-                        props[i] = d
-        # both dispatch dimensions ride the compile key (engine.slot_step
-        # caches per (T, steps, greedy)), so each is rounded down to a
-        # power of two: transient values — a neighbor 3 tokens from its
-        # prompt end, a row 2 tokens from its budget — would otherwise
-        # mint one-off executables (PR-4 compile telemetry made that
-        # visible).  O(log chunk × log burst) shapes total, each reusable.
-        if props:
-            # ragged verify burst: a fixed T = spec_k + 1 window (one
-            # compile key per spec_k), rows with proposals feed
-            # [last, d_1..d_k] and rows without ride along as plain
-            # single-token decode (n_valid 1) — one slot speculating
-            # never stalls a neighbor that has nothing to propose
-            t_width = self.spec_k + 1
-            steps = 1
-        elif prefilling:
-            # mixed step: prefill chunks ride along with the decode rows'
-            # single tokens; steps=1 keeps every row's clock advancing by
-            # its own n_valid
-            t_width = min(self.prefill_chunk, room,
-                          max(len(slots[i].ticket.prompt) - slots[i].fed
-                              for i in prefilling))
-            t_width = 1 << (t_width.bit_length() - 1)
-            steps = 1
-        else:
-            # pure decode: burst on device, clamped so (a) no row outruns
-            # the context edge and (b) queued work waits at most
-            # ~max_wait_ms for the next admission boundary.  A row that
-            # hits its token budget mid-burst retires and the fanout
-            # discards its overrun — cheaper than letting per-row budget
-            # minima pick the burst size (lockstep rows share the cost of
-            # the longest-running neighbor either way)
-            t_width = 1
-            steps = min(self.decode_burst, room)
-            if queued and self._step_ms_ema:
-                steps = min(steps, max(
-                    1, int(self.max_wait_ms / self._step_ms_ema)))
-            steps = max(1, steps)
-            steps = 1 << (steps.bit_length() - 1)
-
-        tokens = np.zeros((b, t_width), np.int32)
-        n_valid = np.ones((b,), np.int32)
-        pos_rows = np.zeros((b,), np.int32)
-        temps = np.zeros((b,), np.float32)
-        topps = np.full((b,), 0.9, np.float32)
-        topks = np.zeros((b,), np.int32)
-        for i in active:
-            s = slots[i]
-            pos_rows[i] = s.pos
-            temps[i] = s.ticket.temperature
-            topps[i] = s.ticket.top_p
-            topks[i] = s.ticket.top_k
-            if s.fed < len(s.ticket.prompt):
-                c = min(t_width, len(s.ticket.prompt) - s.fed)
-                tokens[i, :c] = s.ticket.prompt[s.fed:s.fed + c]
-                n_valid[i] = c
+        dispatch's shape once it is decided.  The host's part before the
+        engine call (shape choice, numpy operands, gap accounting) is the
+        span ``sched.build`` and ``sched_host_ms{phase="build"}``, under the
+        kind the shape turns out to have; the engine's ``engine.h2d`` and
+        ``engine.launch`` follow inside ``engine.slot_enqueue``."""
+        with obs_trace.span("sched.build", seq=self._n_enqueued,
+                            overlapped=False) as bp:
+            eng = self.engine
+            b = eng.batch
+            slots = self.slots
+            prefilling = [i for i in active
+                          if slots[i].fed < len(slots[i].ticket.prompt)]
+            room = min(eng.seq_len - slots[i].pos for i in active)
+            # consume the slots' pending draft proposals (runtime/spec.py).
+            # Proposals are valid for exactly the next dispatch after the
+            # burst that produced them — decode rows advance every
+            # dispatch — so they are popped unconditionally and re-validated:
+            # identity-checked against the slot's *current* ticket (retire /
+            # park / import all rebind), dropped whole when a prefilling row
+            # joins (the mixed step has no verify shape) or the context edge
+            # is closer than a full verify window (flush, not truncate: the
+            # proposer re-drafts next round from exact state either way)
+            props: dict[int, list[int]] = {}
+            if self.spec is not None:
+                with self._cond:
+                    pend, self._proposals = self._proposals, {}
+                if not prefilling and room >= self.spec_k + 1:
+                    for i, (tk, d) in pend.items():
+                        if i in active and slots[i].ticket is tk and d:
+                            props[i] = d
+            # both dispatch dimensions ride the compile key (engine.slot_step
+            # caches per (T, steps, greedy)), so each is rounded down to a
+            # power of two: transient values — a neighbor 3 tokens from its
+            # prompt end, a row 2 tokens from its budget — would otherwise
+            # mint one-off executables (PR-4 compile telemetry made that
+            # visible).  O(log chunk × log burst) shapes total, each reusable.
+            if props:
+                # ragged verify burst: a fixed T = spec_k + 1 window (one
+                # compile key per spec_k), rows with proposals feed
+                # [last, d_1..d_k] and rows without ride along as plain
+                # single-token decode (n_valid 1) — one slot speculating
+                # never stalls a neighbor that has nothing to propose
+                t_width = self.spec_k + 1
+                steps = 1
+            elif prefilling:
+                # mixed step: prefill chunks ride along with the decode rows'
+                # single tokens; steps=1 keeps every row's clock advancing by
+                # its own n_valid
+                t_width = min(self.prefill_chunk, room,
+                              max(len(slots[i].ticket.prompt) - slots[i].fed
+                                  for i in prefilling))
+                t_width = 1 << (t_width.bit_length() - 1)
+                steps = 1
             else:
-                tokens[i, 0] = s.last
-                d = props.get(i)
-                if d is not None:
-                    tokens[i, 1:1 + len(d)] = d
-                    n_valid[i] = 1 + len(d)
+                # pure decode: burst on device, clamped so (a) no row outruns
+                # the context edge and (b) queued work waits at most
+                # ~max_wait_ms for the next admission boundary.  A row that
+                # hits its token budget mid-burst retires and the fanout
+                # discards its overrun — cheaper than letting per-row budget
+                # minima pick the burst size (lockstep rows share the cost of
+                # the longest-running neighbor either way)
+                t_width = 1
+                steps = min(self.decode_burst, room)
+                if queued and self._step_ms_ema:
+                    steps = min(steps, max(
+                        1, int(self.max_wait_ms / self._step_ms_ema)))
+                steps = max(1, steps)
+                steps = 1 << (steps.bit_length() - 1)
 
-        obs_metrics.SCHED_BATCH_EFFICIENCY.set(len(active) / b)
-        prefset = set(prefilling)
-        rid_by_slot = {i: slots[i].ticket.rid for i in active}
-        sp.update(t=t_width, steps=steps, rows=len(active),
-                  prefill_rows=len(prefilling), verify=bool(props),
-                  rids=sorted(rid_by_slot.values()))
-        fed_by_slot = {i: int(n_valid[i]) for i in prefilling}
-        tickets = {i: slots[i].ticket for i in active}
+            tokens = np.zeros((b, t_width), np.int32)
+            n_valid = np.ones((b,), np.int32)
+            pos_rows = np.zeros((b,), np.int32)
+            temps = np.zeros((b,), np.float32)
+            topps = np.full((b,), 0.9, np.float32)
+            topks = np.zeros((b,), np.int32)
+            for i in active:
+                s = slots[i]
+                pos_rows[i] = s.pos
+                temps[i] = s.ticket.temperature
+                topps[i] = s.ticket.top_p
+                topks[i] = s.ticket.top_k
+                if s.fed < len(s.ticket.prompt):
+                    c = min(t_width, len(s.ticket.prompt) - s.fed)
+                    tokens[i, :c] = s.ticket.prompt[s.fed:s.fed + c]
+                    n_valid[i] = c
+                else:
+                    tokens[i, 0] = s.last
+                    d = props.get(i)
+                    if d is not None:
+                        tokens[i, 1:1 + len(d)] = d
+                        n_valid[i] = 1 + len(d)
 
-        # inter-dispatch gap: idle (slept waiting for work) vs host_gap
-        # (token fanout, admission, array prep — the overhead the
-        # overlapped pipeline exists to hide)
-        tp0 = time.perf_counter()
-        host_gap_ms = idle_ms = 0.0
-        if self._last_dispatch_end is None:
-            self._first_dispatch_at = tp0
-        else:
-            gap_ms = max(tp0 - self._last_dispatch_end, 0.0) * 1e3
-            idle_ms = min(self._idle_accum * 1e3, gap_ms)
-            host_gap_ms = gap_ms - idle_ms
-            self._account("idle", idle_ms)
-            self._account("host_gap", host_gap_ms)
-            obs_metrics.SCHED_HOST_GAP_MS.observe(host_gap_ms)
-        self._idle_accum = 0.0
+            obs_metrics.SCHED_BATCH_EFFICIENCY.set(len(active) / b)
+            prefset = set(prefilling)
+            rid_by_slot = {i: slots[i].ticket.rid for i in active}
+            sp.update(t=t_width, steps=steps, rows=len(active),
+                      prefill_rows=len(prefilling), verify=bool(props),
+                      rids=sorted(rid_by_slot.values()))
+            fed_by_slot = {i: int(n_valid[i]) for i in prefilling}
+            tickets = {i: slots[i].ticket for i in active}
+
+            # inter-dispatch gap: idle (slept waiting for work) vs host_gap
+            # (token fanout, admission, array prep — the overhead the
+            # overlapped pipeline exists to hide)
+            tp0 = time.perf_counter()
+            host_gap_ms = idle_ms = 0.0
+            if self._last_dispatch_end is None:
+                self._first_dispatch_at = tp0
+            else:
+                gap_ms = max(tp0 - self._last_dispatch_end, 0.0) * 1e3
+                idle_ms = min(self._idle_accum * 1e3, gap_ms)
+                host_gap_ms = gap_ms - idle_ms
+                self._account("idle", idle_ms)
+                self._account("host_gap", host_gap_ms)
+                obs_metrics.SCHED_HOST_GAP_MS.observe(host_gap_ms)
+            self._idle_accum = 0.0
+            bp.update(t=t_width, steps=steps, rows=len(active))
+            kind = "verify" if props else "mixed" if prefilling else "decode"
+            bp.total = obs_metrics.host_ms("build", kind)
 
         handle, error = None, None
         try:
@@ -1886,80 +1909,86 @@ class SlotScheduler:
             # cannot be built while ``cur`` is in flight.  The verify
             # burst's multi-token yield amortizes the host gap instead.
             return None
-        eng = self.engine
-        slots = self.slots
-        b = eng.batch
-        with self._cond:
-            if (self._stop or self._draining or self._paused
-                    or self._flush_req or self._parked):
-                return None
-            now = time.monotonic()
-            queued = len(self._queue)
-            if queued and not self._queue_must_wait_locked(cur, now):
-                return None
-            pos2 = np.zeros((b,), np.int32)
-            budget = 0
-            for j in range(b):
-                s = slots[j]
-                t = s.ticket
-                if j not in cur.tickets:
-                    if t is not None:
-                        return None   # hand-off import mid-round
-                    continue
-                if t is None or t is not cur.tickets[j]:
-                    return None       # slot re-bound under us
-                if t._cancel is not None or (t.deadline is not None
-                                             and now >= t.deadline):
+        # a plan that declines keeps no ``seq`` and counts as ``build/round``
+        with obs_trace.span("sched.build", overlapped=True,
+                            total=_BUILD_DECLINED, less=_EVICT) as bp:
+            eng = self.engine
+            slots = self.slots
+            b = eng.batch
+            with self._cond:
+                if (self._stop or self._draining or self._paused
+                        or self._flush_req or self._parked):
                     return None
-                nv = int(cur.n_valid[j])
-                if s.fed < len(t.prompt) and s.fed + nv < len(t.prompt):
-                    return None       # still mid-prefill after cur
-                pos2[j] = s.pos + nv + (cur.steps - 1)
-                made = 1 if j in cur.prefset else cur.steps
-                left = t.max_new - (s.produced + made)
-                if queued and left < 1:
-                    return None       # its slot frees when cur lands
-                budget = max(budget, left)
-            if budget < 1:
-                # every row hits its token budget during ``cur``: unlike
-                # the sync path (which only learns a row retired after
-                # the burst lands), the pipelined dispatch knows its
-                # predecessor's yield up front, so the all-overrun burst
-                # is avoidable waste, not a shape-count trade
-                return None
-            room = min(int(eng.seq_len) - int(pos2[i])
-                       for i in cur.active)
-            if room < 1:
-                return None
-            # sized exactly like the sync burst (mid-burst retirement
-            # overrun stays cheaper than minting tail shapes), so the
-            # overlap on/off A/B compares dispatch pipelining alone
-            steps2 = max(1, min(self.decode_burst, room))
-            steps2 = 1 << (steps2.bit_length() - 1)
-            if queued:
-                # a burst amortizes the host gap, and a pipelined
-                # dispatch has none: single steps keep a stream's tokens
-                # evenly spaced and the first slot to free one step from
-                # its admission boundary
-                steps2 = 1
-            if self.paged and self.optimistic:
-                # pipelined chains are unbounded per round (cur = nxt
-                # loops), so the round-start grow cannot cover them:
-                # each burst grows its rows here.  No spill rung — a
-                # D2H page read would order behind the in-flight
-                # dispatch; radix eviction stays safe mid-flight (it
-                # only frees pages no slot row references)
-                for j in cur.active:
-                    if not self._grow_slot_locked(
-                            j, int(pos2[j]) + steps2, allow_spill=False):
+                now = time.monotonic()
+                queued = len(self._queue)
+                if queued and not self._queue_must_wait_locked(cur, now):
+                    return None
+                pos2 = np.zeros((b,), np.int32)
+                budget = 0
+                for j in range(b):
+                    s = slots[j]
+                    t = s.ticket
+                    if j not in cur.tickets:
+                        if t is not None:
+                            return None   # hand-off import mid-round
+                        continue
+                    if t is None or t is not cur.tickets[j]:
+                        return None       # slot re-bound under us
+                    if t._cancel is not None or (t.deadline is not None
+                                                 and now >= t.deadline):
                         return None
-            # the import path rewrites _page_tables under _cond; freeze
-            # a copy so the enqueue below (outside the lock) cannot
-            # observe a half-written row
-            ptab = self._page_tables.copy() if self.paged else None
-            # reserve the in-flight count before releasing the lock so a
-            # concurrent _flushed() waiter sees this dispatch coming
-            self._inflight_n += 1
+                    nv = int(cur.n_valid[j])
+                    if s.fed < len(t.prompt) and s.fed + nv < len(t.prompt):
+                        return None       # still mid-prefill after cur
+                    pos2[j] = s.pos + nv + (cur.steps - 1)
+                    made = 1 if j in cur.prefset else cur.steps
+                    left = t.max_new - (s.produced + made)
+                    if queued and left < 1:
+                        return None       # its slot frees when cur lands
+                    budget = max(budget, left)
+                if budget < 1:
+                    # every row hits its token budget during ``cur``: unlike
+                    # the sync path (which only learns a row retired after
+                    # the burst lands), the pipelined dispatch knows its
+                    # predecessor's yield up front, so the all-overrun burst
+                    # is avoidable waste, not a shape-count trade
+                    return None
+                room = min(int(eng.seq_len) - int(pos2[i])
+                           for i in cur.active)
+                if room < 1:
+                    return None
+                # sized exactly like the sync burst (mid-burst retirement
+                # overrun stays cheaper than minting tail shapes), so the
+                # overlap on/off A/B compares dispatch pipelining alone
+                steps2 = max(1, min(self.decode_burst, room))
+                steps2 = 1 << (steps2.bit_length() - 1)
+                if queued:
+                    # a burst amortizes the host gap, and a pipelined
+                    # dispatch has none: single steps keep a stream's tokens
+                    # evenly spaced and the first slot to free one step from
+                    # its admission boundary
+                    steps2 = 1
+                if self.paged and self.optimistic:
+                    # pipelined chains are unbounded per round (cur = nxt
+                    # loops), so the round-start grow cannot cover them:
+                    # each burst grows its rows here.  No spill rung — a
+                    # D2H page read would order behind the in-flight
+                    # dispatch; radix eviction stays safe mid-flight (it
+                    # only frees pages no slot row references)
+                    for j in cur.active:
+                        if not self._grow_slot_locked(
+                                j, int(pos2[j]) + steps2, allow_spill=False):
+                            return None
+                # the import path rewrites _page_tables under _cond; freeze
+                # a copy so the enqueue below (outside the lock) cannot
+                # observe a half-written row
+                ptab = self._page_tables.copy() if self.paged else None
+                # reserve the in-flight count before releasing the lock so a
+                # concurrent _flushed() waiter sees this dispatch coming
+                self._inflight_n += 1
+            bp.update(seq=self._n_enqueued + 1, t=1, steps=steps2,
+                      rows=len(cur.active))
+            bp.total = obs_metrics.host_ms("build", "decode")
         handle, err = None, None
         self._n_enqueued += 1
         with obs_trace.span("sched.enqueue", seq=self._n_enqueued,
@@ -2050,16 +2079,18 @@ class SlotScheduler:
         dispatch errored (every active slot retires with the error and
         the pipeline round ends)."""
         tw = time.perf_counter()
-        error, out = cur.error, None
+        error, out, kind = cur.error, None, _kind(cur)
         if error is None:
-            with obs_trace.span("sched.land_wait", seq=cur.seq):
+            with obs_trace.span("sched.land_wait", seq=cur.seq,
+                                total=obs_metrics.host_ms("land_wait", kind)):
                 try:
                     out = cur.handle.wait()
                 except Exception as e:
                     error = e
         tp1 = time.perf_counter()
         with obs_trace.span("sched.fanout", seq=cur.seq,
-                            rids=sorted(cur.rid_by_slot.values())):
+                            rids=sorted(cur.rid_by_slot.values()),
+                            total=obs_metrics.host_ms("fanout", kind)):
             return self._account_and_fanout(cur, out, error, tw, tp1)
 
     def _account_and_fanout(self, cur: _Pending, out, error, tw: float,
@@ -2119,7 +2150,7 @@ class SlotScheduler:
         self._account("prefill", wall_ms * n_pref / b)
         self._account("decode", wall_ms * (n_act - n_pref) / b)
         self._account("pad", wall_ms * (b - n_act) / b)
-        kind = "verify" if cur.verify else "mixed" if n_pref else "decode"
+        kind = _kind(cur)
         obs_metrics.SCHED_STEPS.inc(kind)
         obs_metrics.SCHED_STEP_WALL_MS.inc(kind, n=wall_ms)
         busy = self._comp["prefill"] + self._comp["decode"]
@@ -2263,7 +2294,8 @@ class SlotScheduler:
         reuse.  The sampler RNG tick it consumed is not rewound: sampled
         draws are co-scheduling-dependent by contract (module
         docstring); greedy rows never touch the stream."""
-        with obs_trace.span("sched.land_wait", seq=nxt.seq, discarded=True):
+        with obs_trace.span("sched.land_wait", seq=nxt.seq, discarded=True,
+                            total=obs_metrics.host_ms("land_wait", "decode")):
             try:
                 nxt.handle.wait()
             except Exception as e:

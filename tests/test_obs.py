@@ -467,10 +467,18 @@ def test_debug_trace_endpoint(api):
     state, base = api()
     obs_trace.clear()
     with post(base, CHAT, BODY) as r:
+        rid = r.headers["X-Request-Id"]
         json.loads(r.read())
-    with get(base, "/debug/trace?last=5") as r:
-        doc = json.loads(r.read())
-    xs = [e for e in doc["traceEvents"] if e.get("ph") == "X"]
+    # the handler closes ``api.request`` after it has written the response,
+    # so this GET can overtake it: ask until the request's own span is there
+    for _ in range(500):
+        with get(base, "/debug/trace?last=5") as r:
+            doc = json.loads(r.read())
+        xs = [e for e in doc["traceEvents"] if e.get("ph") == "X"]
+        if any(e["name"] == "api.request"
+               and e["args"].get("request_id") == rid for e in xs):
+            break
+        time.sleep(0.01)
     names = {e["name"] for e in xs}
     assert {"api.request", "api.lock_wait", "engine.prefill"} <= names, names
     assert "engine.chunk_fetch" in names or "engine.decode_step" in names, \
