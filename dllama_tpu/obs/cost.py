@@ -257,9 +257,10 @@ class CostModel:
         self.tp = max(1, int(tp))
         self.paged = paged
         self.page_size = int(page_size or 0)
-        #: decode attention runs the fused page-walk Pallas kernel (one
-        #: attention-family dispatch; same FLOPs/bytes, different family
-        #: so MFU/MBU attribution matches the ledger path)
+        #: paged attention runs the fused page-walk Pallas kernel (one
+        #: attention-family dispatch at every step width; same
+        #: FLOPs/bytes, different family so MFU/MBU attribution matches
+        #: the ledger path)
         self.fused = bool(fused)
         self.moe = n_experts > 0
         self.n_active_experts = n_active_experts
@@ -384,9 +385,9 @@ class CostModel:
             return "mla-absorbed"
         if not self.paged:
             return "attention"
-        if phase == "decode":
-            return "paged-fused" if self.fused else "paged-decode"
-        return "paged-gather"
+        if self.fused:
+            return "paged-fused"
+        return "paged-decode" if phase == "decode" else "paged-gather"
 
     def dispatch_cost(self, rows, steps: int = 1) -> dict:
         """Cost of one landed dispatch.
@@ -480,7 +481,8 @@ def model_from_engine(engine) -> CostModel | None:
                 with active_mesh(engine.mesh):
                     fused, _ = _attn._fused_choice(
                         1, cfg.n_heads, cfg.n_kv_heads, cfg.head_size,
-                        bool(engine.cache.quantized))
+                        bool(engine.cache.quantized), engine.kv_page_size,
+                        engine.max_pages_per_slot)
             except Exception:
                 fused = False
         return CostModel(

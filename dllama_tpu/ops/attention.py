@@ -301,7 +301,8 @@ def paged_decode_attention(q: jax.Array, pool_k: jax.Array, pool_v: jax.Array,
 # ---------------------------------------------------------------------------
 # Fused paged-attention megakernel (one-dispatch decode, ROADMAP item 2).
 #
-# One Pallas program per decode step walks the page table directly: grid
+# One Pallas program per paged step (pure-decode, mixed or verify: one or
+# more query tokens a slot) walks the page table directly: grid
 # (B,), one slot a step, and inside it a loop over the slot's LIVE pages
 # in chunks of ``_WALK_PAGES``.  The pool stays in HBM; a chunk's pages are
 # copied straight out of ``pool[layer, table[b, p]]`` into one of two VMEM
@@ -325,6 +326,13 @@ _FUSED_ENV = "DLLAMA_FUSED_ATTN"
 # pages of one chunk of the walk: 8 pages of 16 tokens at 16 kv heads are
 # 16 copies of 64 KB in flight and 2 MB of VMEM for the two buffers
 _WALK_PAGES = 8
+# the most score elements one fold of the walk may hold: ``Hq * T`` query
+# rows by a chunk's ``tokens * Hkv`` keys, in f32 beside its mask and its
+# exponentials.  1 Mi of them compile into a v5e's 16 MiB of scoped VMEM
+# (Mistral's 32/8 heads up to T = 32, Llama-2-7B's 32/32 up to T = 8, at
+# page 16), 2 Mi do not (tests/test_tpu_compile.py); a wider block of rows
+# keeps the gather form
+_SCORE_TILE_MAX = 1 << 20
 
 
 def fused_mode() -> str:
@@ -337,15 +345,17 @@ def fused_mode() -> str:
 
 
 def _make_fused_kernel(hq: int, hkv: int, dh: int, ps: int, cp: int,
-                       out_dtype):
-    """Build the fused decode kernel body for one (head/page) geometry;
-    ``cp`` is the walk's chunk in pages.
+                       t: int, maxp: int, out_dtype):
+    """Build the fused page-walk kernel body for one (head/page/row)
+    geometry; ``cp`` is the walk's chunk in pages, ``t`` the query tokens a
+    slot, ``maxp`` the table's width.
 
     Ref order: 3 scalar-prefetch refs (layer (1,), page table (B, maxp),
     per-row positions (B,)), then the q block, the K and V pools left in
     HBM, the output block, and the scratch: two chunk buffers per pool and
     one DMA semaphore per buffer."""
     g = hq // hkv
+    rows = hq * t
     inv_sqrt = np.float32(1.0 / math.sqrt(dh))
     n_keys = cp * ps * hkv
 
@@ -356,7 +366,10 @@ def _make_fused_kernel(hq: int, hkv: int, dh: int, ps: int, cp: int,
         b = plx.program_id(0)
         pos = pos_ref[b]
         layer = layer_ref[0]
-        last = pos // ps                 # the row's last live page
+        # the row's last live page: its last query token's (the step's own
+        # keys are in the pool before the read), inside the table
+        last = pos // ps if t == 1 else \
+            jnp.minimum((pos + (t - 1)) // ps, maxp - 1)
         n_chunks = last // cp + 1
 
         def start(c, slot):
@@ -376,11 +389,32 @@ def _make_fused_kernel(hq: int, hkv: int, dh: int, ps: int, cp: int,
                     pltpu.make_async_copy(pool.at[0, 0], buf.at[slot, i],
                                           sem.at[slot]).wait()
 
-        qb = q_ref[0]                                   # (Hq, Dh)
-        row = jax.lax.broadcasted_iota(jnp.int32, (hq, n_keys), 0)
-        col = jax.lax.broadcasted_iota(jnp.int32, (hq, n_keys), 1)
-        own = jax.lax.rem(col, hkv) == jax.lax.div(row, g)
-        tok = jax.lax.div(col, hkv)      # a key's token, within its chunk
+        # (Hq*T, Dh): head-major rows, a head's T tokens together.  Key
+        # column ``k`` of a chunk is token ``k // Hkv`` of kv head ``k %
+        # Hkv``; a row keeps its own kv head's keys up to its ceiling
+        qb = q_ref[0]
+        if t == 1:
+            row = jax.lax.broadcasted_iota(jnp.int32, (hq, n_keys), 0)
+            col = jax.lax.broadcasted_iota(jnp.int32, (hq, n_keys), 1)
+            own = jax.lax.rem(col, hkv) == jax.lax.div(row, g)
+            tok = jax.lax.div(col, hkv)  # a key's token, within its chunk
+
+            def live(c):
+                return own & (c * (cp * ps) + tok <= pos)
+        else:
+            # token ``j`` of the block sees key positions <= pos + j.  At
+            # hundreds of rows the mask is the loop's VPU work, so what does
+            # not depend on the chunk is folded into one threshold outside
+            # it: a key is live from chunk ``c`` on iff ``c * (cp * ps) <=
+            # pos + j - its token``, another head's key never
+            row = jax.lax.broadcasted_iota(jnp.int32, (rows, 1), 0)
+            col = jax.lax.broadcasted_iota(jnp.int32, (1, n_keys), 1)
+            own = jax.lax.rem(col, hkv) == jax.lax.div(jax.lax.div(row, t), g)
+            thr = jnp.where(own, pos + jax.lax.rem(row, t)
+                            - jax.lax.div(col, hkv), -1)
+
+            def live(c):
+                return thr >= c * (cp * ps)
 
         def fold(c, carry):
             m_prev, l_prev, acc = carry
@@ -394,35 +428,37 @@ def _make_fused_kernel(hq: int, hkv: int, dh: int, ps: int, cp: int,
             k = bufs[0][slot]    # (cp, ps, Hkv, Dh): token-major pages
             v = bufs[1][slot]
             # the pages stay token-major: a chunk's (cp, ps, Hkv) rows are
-            # one operand of n_keys keys, every query head is scored
-            # against all of them in ONE dot, and a head keeps only the
+            # one operand of n_keys keys, every query row is scored
+            # against all of them in ONE dot, and a row keeps only the
             # columns of its own kv head.  The masked columns weigh
             # exactly 0 in the second dot, so the result is the per-head
             # read; the MXU does Hkv times the useful work, which at one
             # query row is nothing beside a relayout of every page to
-            # head-major and Hkv dots of one row each
+            # head-major and Hkv dots of one row each, and at a chunk's
+            # rows is still the faster form at every width but one
+            # (tools/sweep_attn.py --paged; PERF.md §6, PR 37)
             kf = k.reshape(n_keys, dh)
             vf = v.reshape(n_keys, dh)
             sc = jax.lax.dot_general(
                 qb.astype(kf.dtype), kf, (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32) * inv_sqrt
-            keep = own & (c * (cp * ps) + tok <= pos)
-            sc = jnp.where(keep, sc, _NEG)              # (Hq, n_keys)
+            keep = live(c)
+            sc = jnp.where(keep, sc, _NEG)              # (Hq*T, n_keys)
             m_new = jnp.maximum(m_prev, jnp.max(sc, axis=1, keepdims=True))
             alpha = jnp.exp(m_prev - m_new)
             pexp = jnp.where(keep, jnp.exp(sc - m_new), 0.0)
             l_new = alpha * l_prev + jnp.sum(pexp, axis=1, keepdims=True)
             pv = jax.lax.dot_general(
                 pexp.astype(vf.dtype), vf, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)     # (Hq, Dh)
+                preferred_element_type=jnp.float32)     # (Hq*T, Dh)
             return m_new, l_new, alpha * acc + pv
 
         start(0, 0)
         _, l, acc = jax.lax.fori_loop(
             0, n_chunks, fold,
-            (jnp.full((hq, 1), _NEG, jnp.float32),
-             jnp.zeros((hq, 1), jnp.float32),
-             jnp.zeros((hq, dh), jnp.float32)))
+            (jnp.full((rows, 1), _NEG, jnp.float32),
+             jnp.zeros((rows, 1), jnp.float32),
+             jnp.zeros((rows, dh), jnp.float32)))
         o_ref[0] = (acc / jnp.maximum(l, 1e-38)).astype(out_dtype)
 
     return kernel
@@ -432,65 +468,76 @@ def fused_paged_attention(q: jax.Array, pool_k: jax.Array, pool_v: jax.Array,
                           layer: jax.Array, page_table: jax.Array,
                           pos_rows: jax.Array,
                           *, interpret: bool = False) -> jax.Array:
-    """Single-token paged GQA over a dense pool as ONE kernel: page-table
-    walk and online-softmax fold in a single pallas_call.  Numerics mirror :func:`paged_decode_attention`'s fold
-    (same operand dtypes, f32 accumulation, ``_NEG`` mask fill); traffic
-    is the live pages of each row, rounded up to the walk's chunk.
+    """Paged GQA of ``T >= 1`` query tokens a slot over a dense pool as ONE
+    kernel: page-table walk and online-softmax fold in a single
+    pallas_call, under :func:`_rows_ceiling_attention`'s per-row causal
+    ceiling (token ``j`` of row ``r`` sees key positions ``<= pos_rows[r]
+    + j``).  Numerics mirror :func:`paged_decode_attention`'s fold (same
+    operand dtypes, f32 accumulation, ``_NEG`` mask fill); traffic is the
+    live pages of each row up to its last query token's, rounded up to the
+    walk's chunk.  Tokens past a row's ``n_valid`` read what the gather
+    form reads for them (their pages past the row's reservation are
+    scratch page 0) and the caller drops them.
     """
     from jax.experimental import pallas as plx
     from jax.experimental.pallas import tpu as pltpu
 
     b, hq, t, dh = q.shape
-    if t != 1:
-        raise ValueError("fused paged attention is decode-only (T must be 1)")
     ps, hkv = pool_k.shape[2], pool_k.shape[3]
-    cp = min(_WALK_PAGES, page_table.shape[1])
+    maxp = page_table.shape[1]
+    cp = min(_WALK_PAGES, maxp)
     pools = [pool_k, pool_v]
 
     def row_map(bi, *_):
         return (bi, 0, 0)
 
     hbm = plx.BlockSpec(memory_space=plx.ANY)
-    kernel = _make_fused_kernel(hq, hkv, dh, ps, cp, q.dtype)
+    kernel = _make_fused_kernel(hq, hkv, dh, ps, cp, t, maxp, q.dtype)
     out = plx.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
             grid=(b,),
-            in_specs=[plx.BlockSpec((1, hq, dh), row_map)]
+            in_specs=[plx.BlockSpec((1, hq * t, dh), row_map)]
             + [hbm] * len(pools),
-            out_specs=plx.BlockSpec((1, hq, dh), row_map),
+            out_specs=plx.BlockSpec((1, hq * t, dh), row_map),
             scratch_shapes=[pltpu.VMEM((2, cp, *pool.shape[2:]), pool.dtype)
                             for pool in pools]
             + [pltpu.SemaphoreType.DMA((2,))]),
-        out_shape=jax.ShapeDtypeStruct((b, hq, dh), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, hq * t, dh), q.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
         name="paged_attn_fused",
     )(jnp.atleast_1d(layer).astype(jnp.int32),
       page_table.astype(jnp.int32), pos_rows.astype(jnp.int32),
-      q[:, :, 0, :], *pools)
-    return out[:, :, None, :]
+      q.reshape(b, hq * t, dh), *pools)
+    return out.reshape(b, hq, t, dh)
 
 
 def _fused_choice(t: int, hq: int, hkv: int, dh: int = 128,
-                  quantized: bool = False) -> tuple[bool, bool]:
+                  quantized: bool = False, ps: int = 16,
+                  maxp: int = _WALK_PAGES) -> tuple[bool, bool]:
     """Resolve the fused-vs-fallback decision for one call site from
-    static facts only (mode, platform, mesh, head counts and size, the
-    pool's codec), so it is the same inside and outside a jit trace.
-    Returns ``(use_fused, interpret)``.  On a single TPU device
-    ``auto``/``on`` mean the fused kernel for a dense pool whose heads
-    fill whole lanes (a page is copied as it lies, and a copy of part of
-    a 128-lane row is refused); nothing is executed to decide, so a
-    Mosaic lowering or runtime error propagates and fails the run (values
-    are checked on the chip by chip_smoke.py).  A ``pallas_call`` is not
+    static facts only (mode, platform, mesh, the block's query tokens
+    ``t``, head counts and size, the pool's codec, the page size ``ps``
+    and the table's width ``maxp``, which give the walk's chunk), so it is
+    the same inside and outside a jit trace.  Returns ``(use_fused,
+    interpret)``.  On a single TPU device ``auto``/``on`` mean the fused
+    kernel at every ``t`` (the pure-decode step's one token, a mixed
+    step's chunk, a verify step's ``spec_k + 1``) for a dense pool whose
+    heads fill whole lanes (a page is copied as it lies, and a copy of
+    part of a 128-lane row is refused) and whose score tile fits
+    (``_SCORE_TILE_MAX``); nothing is executed to decide, so a Mosaic
+    lowering or runtime error propagates and fails the run (values are
+    checked on the chip by chip_smoke.py).  A ``pallas_call`` is not
     partitioned by GSPMD, so on a multi-device mesh the TPU path stays
     the gather form.  ``auto`` off-TPU falls
     back silently (the clean-run ledger contract); ``on`` where the
     kernel cannot run degrades loudly (warn-once)."""
     mode = fused_mode()
-    if mode == "off" or t != 1 or hq % hkv != 0 or quantized:
+    tile = hq * t * min(_WALK_PAGES, maxp) * ps * hkv
+    if mode == "off" or hq % hkv != 0 or quantized or tile > _SCORE_TILE_MAX:
         return False, False
     if mode == "interp":
         return True, True
@@ -513,8 +560,8 @@ def paged_gqa_attention_at(q: jax.Array, pool_k: jax.Array, pool_v: jax.Array,
                            scales: tuple[jax.Array, jax.Array] | None = None
                            ) -> jax.Array:
     """Causal GQA read through the page-table indirection at ``layer``,
-    with the slot path's per-row causal ceiling.  Single-token decode
-    over a dense pool prefers the fused page-walk megakernel
+    with the slot path's per-row causal ceiling.  A dense pool prefers the
+    fused page-walk megakernel at every ``T``
     (:func:`fused_paged_attention` — one dispatch, no materialized gather)
     when the ``DLLAMA_FUSED_ATTN`` ladder resolves to it; otherwise
     dispatch mirrors the contiguous path: long-cache single-token decode
@@ -537,7 +584,8 @@ def paged_gqa_attention_at(q: jax.Array, pool_k: jax.Array, pool_v: jax.Array,
     s = page_table.shape[1] * ps
     codec = "kv_int8" if scales is not None else "kv_dense"
     use_fused, interp = _fused_choice(t, q.shape[1], hkv, q.shape[3],
-                                      scales is not None)
+                                      scales is not None, ps,
+                                      page_table.shape[1])
     if use_fused:
         obs_dispatch.record_dispatch(codec, "paged-fused", t=t, s=s,
                                      page_size=ps, interpret=interp)

@@ -76,9 +76,12 @@ def test_rehearse_kernels_phase(rehearsal_env, capfd):
     assert dev["platform"] == "cpu"
     rows = [json.loads(ln) for ln in capfd.readouterr().out.splitlines()]
     errs = [r for r in rows if "rel_err" in r]
-    # five shapes × rows {1, 8, 256} + dense/int8 fused attention + the
-    # live walk at prefill rows, dense/int8 × two positions
-    assert len(errs) == 21
+    # five shapes × rows {1, 8, 256} + dense/int8 fused attention + the fused
+    # walk at a chunk's 16 tokens a slot, two head geometries + the live walk
+    # at prefill rows, dense/int8 × two positions
+    assert len(errs) == 23
+    assert [r["geometry"]["heads"] for r in errs if r.get("t") == 16] == \
+        ["mistral-7b", "olmoe-1b-7b"]
     assert all(r["rel_err"] <= r["tol"] for r in errs)
 
 

@@ -469,6 +469,44 @@ def test_steady_decode_dispatch_families(monkeypatch):
     assert obs_dispatch.degraded() is False  # interp is a mode, not a degrade
 
 
+def test_mixed_step_dispatch_families(monkeypatch):
+    """A mixed step (a prefill chunk beside decode rows, ``t`` tokens a
+    slot) reads the pool through the same one attention family as the
+    pure-decode step: ``kv_dense/paged-fused``, recorded with its ``t``; the
+    gather form's two families are what the fallback records."""
+    import jax
+    from dllama_tpu.models.config import tiny_config
+    from dllama_tpu.models.params import init_params
+    from dllama_tpu.parallel.mesh import make_mesh
+    from dllama_tpu.runtime.engine import Engine
+
+    cfg = tiny_config(seq_len=64)
+    eng = Engine(cfg, init_params(cfg, seed=4),
+                 mesh=make_mesh(tp=1, devices=jax.devices()[:1]),
+                 batch=2, kv_pages=17, kv_page_size=8)
+    ptab = np.asarray([[1, 2], [3, 4]], np.int32)
+
+    def trace_families(mode, t):
+        monkeypatch.setenv("DLLAMA_FUSED_ATTN", mode)
+        obs_dispatch.reset()
+        # row 0 feeds a chunk of t prompt tokens, row 1 decodes one
+        eng.slot_step(np.ones((2, t), np.int32), np.asarray([0, 5], np.int32),
+                      np.asarray([t, 1], np.int32),
+                      temps_np=np.zeros(2, np.float32),
+                      topps_np=np.full(2, 0.9, np.float32),
+                      page_tables_np=ptab)
+        return {k for k in obs_dispatch.dispatches() if k.startswith("kv_")}
+
+    try:
+        for t in (4, 8):
+            assert trace_families("interp", t) == {"kv_dense/paged-fused"}
+            assert trace_families("off", t) == {"kv_dense/paged-gather",
+                                                "kv_dense/attn-score"}
+        assert obs_dispatch.degraded() is False
+    finally:
+        obs_dispatch.reset()
+
+
 # --- satellite: fast tier keeps its non-trivial core ----------------------
 
 def test_fast_tier_collects_core_suites():

@@ -8,8 +8,9 @@ Contracts pinned here on CPU — the kernel runs in Pallas interpret mode
 (``DLLAMA_FUSED_ATTN=interp``: same kernel logic, no TPU needed):
 
 * **kernel parity** — the fused kernel matches the gather +
-  rows-ceiling reference on a random ragged fixture, dense and int8
-  pools, at a non-zero layer (tolerance scaled to the reference
+  rows-ceiling reference on a random ragged fixture at every step width
+  (one token, a mixed step's chunk, a verify block), dense pools and the
+  int8 pool's walk, at a non-zero layer (tolerance scaled to the reference
   magnitude: the two implementations associate the bf16 online-softmax
   folds differently, so 2e-5 elementwise is the wrong bar);
 * **byte parity** — greedy decode through the paged scheduler is
@@ -64,19 +65,25 @@ def make_paged_engine(batch=4, page=PAGE, **kw):
 
 # -- kernel vs gather reference --------------------------------------------
 
-def _pool_fixture(quantized, hkv=2, ps=8, b=3, maxp=3, g=2, dh=16, nlayers=2):
-    """A ragged paged read: the pool is laid out from logical head-major KV
-    by :func:`fixtures.pool_from_logical`, and ``logical`` is that KV
+def _pool_fixture(quantized, hkv=2, ps=8, t=1, g=2, dh=16, nlayers=2):
+    """A ragged paged read of ``t`` query tokens a row over a table wider
+    than one chunk of the walk: the pool is laid out from logical head-major
+    KV by :func:`fixtures.pool_from_logical`, and ``logical`` is that KV
     (dequantized for an int8 pool) for a reference that never touches the
-    pool."""
+    pool.  Rows, by what their block of ``t`` tokens does: starts at
+    position 0; crosses a page boundary; crosses a boundary of the walk's
+    chunks (``_WALK_PAGES`` pages); ends on the table's last position; and
+    one whose table past its first token's page is scratch page 0, as a
+    decode row's is in a mixed step (its tokens past the first see page 0's
+    keys in both forms)."""
+    from dllama_tpu.ops.attention import _WALK_PAGES
+    b, maxp = 5, _WALK_PAGES + 2
     npages = 1 + b * maxp
     rng = np.random.RandomState(3)
-    table = jnp.asarray(rng.permutation(np.arange(1, npages)).reshape(b, maxp),
-                        jnp.int32)
-    # ragged: one full row, one mid-page, one inside the first page
-    pos_rows = jnp.asarray([maxp * ps - 1, ps + ps // 2, min(3, ps - 1)],
-                           jnp.int32)
-    q = jnp.asarray(rng.randn(b, hkv * g, 1, dh) * 0.3, jnp.float32)
+    table = rng.permutation(np.arange(1, npages)).reshape(b, maxp)
+    pos_rows = jnp.asarray([0, ps - 1, _WALK_PAGES * ps - max(1, t // 2),
+                            maxp * ps - t, ps + 1], jnp.int32)
+    q = jnp.asarray(rng.randn(b, hkv * g, t, dh) * 0.3, jnp.float32)
     shape = (nlayers, b, hkv, maxp * ps, dh)
     place = lambda a: jnp.asarray(pool_from_logical(a, table, npages, ps))  # noqa: E731
     if quantized:
@@ -89,20 +96,28 @@ def _pool_fixture(quantized, hkv=2, ps=8, b=3, maxp=3, g=2, dh=16, nlayers=2):
         k, v = (jnp.asarray(rng.randn(*shape) * 0.3, jnp.bfloat16)
                 for _ in range(2))
         pk, pv, scales, logical = place(k), place(v), None, (k, v)
-    return q, pk, pv, table, pos_rows, scales, logical
+        # scratch page 0 holds what the last invalid write left there
+        page0 = jnp.asarray(rng.randn(nlayers, ps, hkv, dh) * 0.3, pk.dtype)
+        pk, pv = pk.at[:, 0].set(page0), pv.at[:, 0].set(-page0)
+    table[-1, int(pos_rows[-1]) // ps + 1:] = 0
+    return q, pk, pv, jnp.asarray(table, jnp.int32), pos_rows, scales, logical
 
 
+# the scheduler's mixed-step widths, one odd verify width (spec_k + 1), and
+# the int8 pool's read at the one width its live walk takes
 @pytest.mark.parametrize("hkv,ps", PAGE_GEOMETRIES, ids=PAGE_GEOMETRY_IDS)
-@pytest.mark.parametrize("quantized", [False, True],
-                         ids=["dense", "kv_int8"])
-def test_fused_kernel_matches_gather_reference(quantized, hkv, ps):
+@pytest.mark.parametrize(
+    "quantized,t", [(False, t) for t in (1, 2, 4, 5, 8, 16)] + [(True, 1)],
+    ids=[f"dense-t{t}" for t in (1, 2, 4, 5, 8, 16)] + ["kv_int8"])
+def test_fused_kernel_matches_gather_reference(quantized, t, hkv, ps):
     """The page-walk kernel and the materialized-gather path compute the
-    same attention read — ragged rows, layer 1 of 2 (the layer index
-    rides scalar prefetch), dead pages fully masked — and the gather view
-    IS the logical KV the pool was laid out from, exactly: the token-major
-    page order (L, P, ps, Hkv, Dh) read back head-major."""
+    same attention read — ragged rows of ``t`` tokens under the per-row
+    causal ceiling, layer 1 of 2 (the layer index rides scalar prefetch),
+    dead pages fully masked — and the gather view IS the logical KV the pool
+    was laid out from, exactly: the token-major page order
+    (L, P, ps, Hkv, Dh) read back head-major."""
     q, pk, pv, table, pos_rows, scales, (k_log, v_log) = _pool_fixture(
-        quantized, hkv, ps)
+        quantized, hkv, ps, t)
     assert pk.shape[2:4] == (ps, hkv)
     layer = jnp.int32(1)
     # an int8 pool is not the kernel's (its scale plane cannot be copied by
@@ -114,10 +129,11 @@ def test_fused_kernel_matches_gather_reference(quantized, hkv, ps):
     ks, vs = scales if scales is not None else (None, None)
     k_l = paged_gather_layer(pk, layer, table, scale_pool=ks)
     v_l = paged_gather_layer(pv, layer, table, scale_pool=vs)
-    np.testing.assert_array_equal(np.asarray(k_l, np.float32),
-                                  np.asarray(k_log[1], np.float32))
-    np.testing.assert_array_equal(np.asarray(v_l, np.float32),
-                                  np.asarray(v_log[1], np.float32))
+    # every row but the last has its whole table
+    np.testing.assert_array_equal(np.asarray(k_l[:-1], np.float32),
+                                  np.asarray(k_log[1][:-1], np.float32))
+    np.testing.assert_array_equal(np.asarray(v_l[:-1], np.float32),
+                                  np.asarray(v_log[1][:-1], np.float32))
     ref = _rows_ceiling_attention(q, k_l, v_l, pos_rows)
     assert out.shape == ref.shape == q.shape
     tol = 1e-2 * max(float(np.abs(np.asarray(ref, np.float32)).max()), 1e-3)
@@ -137,7 +153,6 @@ def test_fused_choice_is_static_and_raises_on_tpu(monkeypatch, mode):
     monkeypatch.setenv("DLLAMA_FUSED_ATTN", mode)
     q, pk, pv, table, pos_rows, _, _ = _pool_fixture(False, dh=128)
     assert att._fused_choice(1, 4, 2) == (True, False)
-    assert att._fused_choice(2, 4, 2) == (False, False)  # t > 1
     assert att._fused_choice(1, 3, 2) == (False, False)  # hq % hkv
     assert att._fused_choice(1, 4, 2, 64) == (False, False)  # half a lane row
     assert att._fused_choice(1, 4, 2, 128, True) == (False, False)  # int8 pool
@@ -155,6 +170,53 @@ def test_fused_choice_is_static_and_raises_on_tpu(monkeypatch, mode):
             assert obs_dispatch.degraded() is False
         finally:
             obs_dispatch.reset()
+
+
+# a mixed step's widths (a power of two up to --sched-prefill-chunk), a verify
+# step's spec_k + 1, at the served cells' heads (query, kv) and Llama-2-7B's
+@pytest.mark.parametrize("t", [1, 2, 4, 5, 8, 16])
+def test_fused_choice_takes_every_step_width_on_one_tpu_device(monkeypatch, t):
+    """On one TPU device the fused walk serves the pure-decode step's one
+    token, every chunk width of a mixed step and a verify step's block, by
+    a rule of shapes alone; an int8 pool and a mesh keep the gather form at
+    the same widths, and so does a block whose score tile
+    (``Hq * T`` rows by a chunk's ``tokens * Hkv`` keys) passes
+    ``_SCORE_TILE_MAX``."""
+    from dllama_tpu.ops import attention as att
+    from dllama_tpu.parallel.mesh import active_mesh
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setenv("DLLAMA_FUSED_ATTN", "auto")
+    for hq, hkv in ((32, 8), (16, 16)):
+        assert att._fused_choice(t, hq, hkv) == (True, False)
+        assert att._fused_choice(t, hq, hkv, 128, True) == (False, False)
+        with active_mesh(make_mesh(tp=2, devices=jax.devices()[:2])):
+            assert att._fused_choice(t, hq, hkv) == (False, False)
+    # 32 x 32 heads: 8 pages of 16 tokens are 4096 keys a chunk, so 1 Mi
+    # score elements at 8 tokens a slot; a page of 64 tokens quarters that
+    assert att._fused_choice(t, 32, 32) == (t <= 8, False)
+    assert att._fused_choice(t, 32, 32, ps=64) == (t <= 2, False)
+    assert att._fused_choice(64, 32, 8) == (False, False)
+
+
+def test_fused_choice_records_the_path_of_a_chunk(monkeypatch):
+    """The read of a chunk step records ``paged-fused`` with its ``t`` where
+    the rule takes it and the gather form's two families where the rule
+    names a reason (here: the score tile), under the same mode."""
+    from dllama_tpu.obs import dispatch as obs_dispatch
+    from dllama_tpu.ops import attention as att
+    monkeypatch.setenv("DLLAMA_FUSED_ATTN", "interp")
+    q, pk, pv, table, pos_rows, _, _ = _pool_fixture(False, t=4)
+    obs_dispatch.reset()
+    try:
+        att.paged_gqa_attention_at(q, pk, pv, jnp.int32(0), table, pos_rows)
+        assert obs_dispatch.dispatches() == {"kv_dense/paged-fused": 1}
+        obs_dispatch.reset()
+        monkeypatch.setattr(att, "_SCORE_TILE_MAX", 4 * 4 * 8 * 8 * 2 - 1)
+        att.paged_gqa_attention_at(q, pk, pv, jnp.int32(0), table, pos_rows)
+        assert obs_dispatch.dispatches() == {"kv_dense/paged-gather": 1,
+                                             "kv_dense/attn-score": 1}
+    finally:
+        obs_dispatch.reset()
 
 
 def test_fused_choice_on_a_mesh_stays_gather(monkeypatch):

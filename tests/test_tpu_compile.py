@@ -108,18 +108,32 @@ def test_q40_experts_matmul_compiles_at_olmoe_shapes(one_chip, name, n, d,
 
 
 # (rows, query heads, kv heads, pages a slot): Llama-2-7B, and the two
-# served cells (Mistral-7B's GQA at 64 pages, OLMoE's MHA at 128)
+# served cells (Mistral-7B's GQA at 64 pages, OLMoE's MHA at 128).  Tokens a
+# slot: the pure-decode step's one, a verify block of spec_k + 1 = 5, the
+# mixed step's chunk of 16, and the widest block the rule takes (32 for the
+# served cells; 8 for Llama-2-7B, whose 32 kv heads make a chunk 4096 keys)
+@pytest.mark.parametrize("t", [1, 5, 16, "widest"])
 @pytest.mark.parametrize("b,hq,hkv,maxp", [(4, 32, 32, 64), (16, 32, 8, 64),
                                            (16, 16, 16, 128)],
                          ids=["7b", "mistral-7b", "olmoe-1b-7b"])
-def test_fused_paged_attention_compiles_at_served_geometry(one_chip, b, hq,
-                                                           hkv, maxp):
+def test_fused_paged_attention_compiles_at_served_geometry(one_chip, monkeypatch,
+                                                           b, hq, hkv, maxp, t):
     dh, ps = 128, 16
     n_pages = 1 + b * maxp
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    widest = att._SCORE_TILE_MAX // (hq * att._WALK_PAGES * ps * hkv)
+    if t == "widest":
+        t = widest
+        assert att._fused_choice(t, hq, hkv, dh, False, ps, maxp)[0]
+        assert not att._fused_choice(t + 1, hq, hkv, dh, False, ps, maxp)[0]
+    elif t > widest:
+        # the rule keeps this width on the gather form here
+        assert not att._fused_choice(t, hq, hkv, dh, False, ps, maxp)[0]
+        return
     s = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)  # noqa: E731
     pool = s((2, n_pages, ps, hkv, dh), jnp.bfloat16)
     text = jax.jit(att.fused_paged_attention).lower(
-        s((b, hq, 1, dh), jnp.bfloat16), pool, pool, s((), jnp.int32),
+        s((b, hq, t, dh), jnp.bfloat16), pool, pool, s((), jnp.int32),
         s((b, maxp), jnp.int32), s((b,), jnp.int32)).compile().as_text()
     assert "tpu_custom_call" in text and "paged_attn_fused" in text
 
@@ -260,15 +274,18 @@ def test_one_stream_prefill_walks_live_blocks_without_a_slab(one_chip):
 
 def test_mixed_paged_slot_step_has_no_pool_copy(one_chip, monkeypatch):
     """The twin for the served mixed step's form, 16 slots x a 16-token chunk
-    (gather attention), at Mistral's 8 KV heads: no ``copy`` of the pool's
-    full shape on the way in or out, nor inside the layer loop."""
+    (the fused walk at 16 tokens a slot), at Mistral's 8 KV heads: no ``copy``
+    of the pool's full shape on the way in or out, nor inside the layer loop,
+    and no gathered view of a slot's whole table either."""
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     monkeypatch.setattr(jax, "device_count", lambda: 1)
     cfg = _toy_cfg().with_(n_heads=8, n_kv_heads=8, dim=1024)
     n_pages, ps = POOL_PAGES, 16
     text = _slot_step_text(one_chip, cfg, _dense_toy_params(cfg, one_chip), 16,
                            16, n_pages, 8)
-    assert "paged_attn_fused" not in text  # t > 1: the gather form
+    assert "paged_attn_fused" in text
+    # the gather form's (B, Hkv, maxp * ps, Dh) view of K or V is gone
+    assert f"[16,{cfg.n_kv_heads},{8 * ps},{cfg.head_size}]" not in text
     _assert_pool_is_only_scattered(text, cfg, n_pages, ps)
 
 

@@ -1,5 +1,6 @@
-"""Hardware sweep of the contiguous-cache attention: KV block width of the
-live walk at prefill rows.
+"""Hardware sweep of the attention reads: KV block width of the contiguous
+cache's live walk at prefill rows, and (``--paged``) the fused page walk
+against the gather form over a paged pool.
 
 Times ``ops.attention`` on one chip at Mistral-7B's attention shapes (32
 query and 8 KV heads of 128, 32 layers, a 32k bf16 cache) for a few
@@ -12,12 +13,26 @@ without the walk: ``--repo <parent checkout>``); the widths go through
 ``attention._kv_chunk(s)`` for every ``t`` (PERF.md §6, PR 29 has the table
 this tool gave); judge a candidate in the cell, not here.
 
+``--paged`` times the served steps' read, ``paged_gqa_attention_at``'s two
+dense-pool arms, at the two served geometries (Mistral-7B: 32 query and 8 KV
+heads, 32 layers; OLMoE-1B-7B: 16 and 16, 16 layers; heads of 128, 16 slots,
+a 64-page table of 16-token pages out of the cell's pool): ``fused``
+(``fused_paged_attention``, kernel ``paged_attn_fused``) and ``gather``
+(``paged_gather_layer`` + ``_rows_ceiling_attention``) at ``t`` 1 and 16 and
+a live context of 256 and 1024 tokens a slot (``--ts`` for other widths),
+inside one jitted loop over the layers, each checked against the gather form
+(``rel_err``).  ``auto`` is the program's own choice, with the ledger path it
+recorded.  PERF.md §6, PR 37 has the table, and the two per-kv-head reads of
+the chunk buffer that were timed against the kept body and not kept.
+
 Usage: python tools/sweep_attn.py [--repo DIR] [--blocks 256,512,1024]
+       python tools/sweep_attn.py --paged [--repo DIR]
 """
 
 from __future__ import annotations
 
 import argparse
+import functools
 import json
 import os
 import sys
@@ -28,9 +43,98 @@ HQ, HKV, DH, LAYERS, S = 32, 8, 128, 32, 32768
 POINTS = [(256, 0), (128, 0), (64, 0), (256, 16384), (16, 300), (1, 300)]
 
 
+# (name, query heads, kv heads, layers, pool pages): the two served cells
+PAGED_GEOMETRIES = [("mistral-7b", 32, 8, 32, 1032),
+                    ("olmoe-1b-7b", 16, 16, 16, 2056)]
+PAGED_SLOTS, PAGED_TABLE, PAGE = 16, 64, 16
+
+
+def _median_ms(run, args, reps):
+    import jax
+    jax.block_until_ready(run(*args))
+    times = []
+    for _ in range(reps):
+        t0 = time.perf_counter()
+        jax.block_until_ready(run(*args))
+        times.append(time.perf_counter() - t0)
+    return (round(1e3 * sorted(times)[len(times) // 2], 3),
+            round(1e3 * min(times), 3))
+
+
+def sweep_paged(a, att) -> list[dict]:
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from dllama_tpu.obs import dispatch as obs_dispatch
+
+    def gather(q, pk, pv, layer, table, pos):
+        return att._rows_ceiling_attention(
+            q, att.paged_gather_layer(pk, layer, table),
+            att.paged_gather_layer(pv, layer, table), pos)
+
+    forms = {"auto": att.paged_gqa_attention_at, "gather": gather,
+             "fused": att.fused_paged_attention}
+    b, maxp, ps = (2, 8, PAGE) if a.rehearse else (PAGED_SLOTS, PAGED_TABLE, PAGE)
+    results = []
+    for geo, hq, hkv, layers, n_pages in PAGED_GEOMETRIES:
+        if a.rehearse:
+            layers, n_pages = 2, 1 + b * maxp
+        kk, kv, kq = jax.random.split(jax.random.PRNGKey(0), 3)
+        shape = (layers, n_pages, ps, hkv, DH)
+        pk = jax.random.normal(kk, shape, jnp.bfloat16)
+        pv = jax.random.normal(kv, shape, jnp.bfloat16)
+        table = jnp.asarray(np.random.RandomState(0).permutation(
+            np.arange(1, n_pages))[:b * maxp].reshape(b, maxp), jnp.int32)
+        for t in (int(x) for x in a.ts.split(",")):
+            q = jax.random.normal(kq, (b, hq, t, DH), jnp.bfloat16)
+            for ctx in ((32, 64) if a.rehearse else (256, 1024)):
+                # every slot's last query token is the context's last
+                pos = jnp.full((b,), ctx - t, jnp.int32)
+                ref = None
+                for name, fn in forms.items():
+                    if a.rehearse and name == "fused":
+                        fn = functools.partial(fn, interpret=True)
+
+                    @jax.jit
+                    def run(q_, pk_, pv_, table_, pos_, fn=fn):
+                        # each layer's queries depend on the last layer's
+                        # output, as in the model
+                        return jax.lax.fori_loop(
+                            0, layers, lambda i, acc: fn(
+                                q_ + (acc * 0).astype(q_.dtype), pk_, pv_, i,
+                                table_, pos_).astype(jnp.float32),
+                            jnp.zeros(q_.shape, jnp.float32))
+
+                    obs_dispatch.reset()
+                    args = (q, pk, pv, table, pos)
+                    med, low = _median_ms(run, args, a.reps)
+                    path = sorted(k for k in obs_dispatch.dispatches()
+                                  if k.startswith("kv_"))
+                    obs_dispatch.reset()
+                    one = jax.jit(fn)(q, pk, pv, jnp.int32(layers - 1), table,
+                                      pos).astype(jnp.float32)
+                    if name == "gather":
+                        ref = one
+                    rec = {"geometry": geo, "t": t, "live": ctx, "form": name,
+                           "ms_all_layers": med, "min_ms": low,
+                           "layers": layers, "repo": a.repo}
+                    if name == "auto":
+                        rec["ledger"] = path
+                    if ref is not None and name != "gather":
+                        rec["rel_err"] = float(jnp.abs(one - ref).max()
+                                               / jnp.abs(ref).max())
+                    results.append(rec)
+                    print(json.dumps(rec), flush=True)
+    return results
+
+
 def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--repo", default=HERE)
+    ap.add_argument("--paged", action="store_true",
+                    help="the paged pool's reads: fused page walk vs gather")
+    ap.add_argument("--ts", default="1,16", help="--paged: query tokens a slot")
     ap.add_argument("--blocks", default="256,512,1024")
     ap.add_argument("--reps", type=int, default=5)
     ap.add_argument("--rehearse", action="store_true",
@@ -45,6 +149,9 @@ def main() -> None:
     if not a.rehearse and jax.default_backend() != "tpu":
         print(json.dumps({"error": "no TPU"}))
         sys.exit(1)
+    if a.paged:
+        _write(sweep_paged(a, att), "sweep_attn_paged.jsonl")
+        return
     layers = 2 if a.rehearse else LAYERS
     widths = [None] + ([int(b) for b in a.blocks.split(",") if b]
                        if hasattr(att, "live_gqa_attention") else [])
@@ -73,21 +180,18 @@ def main() -> None:
                         i, q_ + (acc * 0).astype(q_.dtype), pos_, ck_, cv_)
                     .astype(jnp.float32), jnp.zeros(q_.shape, jnp.float32))
 
-            p = jnp.int32(pos)
-            jax.block_until_ready(run(q, p, ck, cv))
-            times = []
-            for _ in range(a.reps):
-                t0 = time.perf_counter()
-                jax.block_until_ready(run(q, p, ck, cv))
-                times.append(time.perf_counter() - t0)
+            med, low = _median_ms(run, (q, jnp.int32(pos), ck, cv), a.reps)
             rec = {"t": t, "pos": pos, "block": block or "auto",
-                   "ms_32_layers": round(1e3 * sorted(times)[len(times) // 2], 3),
-                   "min_ms": round(1e3 * min(times), 3), "repo": a.repo}
+                   "ms_32_layers": med, "min_ms": low, "repo": a.repo}
             results.append(rec)
             print(json.dumps(rec), flush=True)
+    _write(results, "sweep_attn.jsonl")
+
+
+def _write(results: list[dict], name: str) -> None:
     out = os.path.join(HERE, "chiprun_out")
     os.makedirs(out, exist_ok=True)
-    with open(os.path.join(out, "sweep_attn.jsonl"), "a") as f:
+    with open(os.path.join(out, name), "a") as f:
         f.writelines(json.dumps(r) + "\n" for r in results)
 
 
