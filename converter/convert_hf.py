@@ -3,8 +3,9 @@
 Re-implements `/root/reference/converter/convert-hf.py`: llama / mistral /
 mixtral folders with ``config.json`` + ``*.safetensors`` become a `.m` file
 in the canonical tensor order; beyond the reference, deepseek_v2 folders
-(MLA, ``kv_b_proj`` kept whole, header keys 14..31) and olmoe folders
-(``ARCH_OLMOE``).  Key semantics preserved:
+(MLA, ``kv_b_proj`` kept whole, header keys 14..31), olmoe folders
+(``ARCH_OLMOE``) and smallthinker folders (``ARCH_SMALLTHINKER``: header keys
+31..34, rows not permuted).  Key semantics preserved:
 
 * q/k head permutation (convert-hf.py:12-15): HF stores RoPE in rotate-half
   layout; the `.m` format expects the interleaved-pair layout, so q and k
@@ -39,8 +40,10 @@ ARCH_BY_MODEL_TYPE = {
     "mixtral": mfile.ARCH_MIXTRAL,
     "olmoe": mfile.ARCH_OLMOE,
     "deepseek_v2": mfile.ARCH_DEEPSEEK2,
+    "smallthinker": mfile.ARCH_SMALLTHINKER,
 }
-HIDDEN_ACT = {"gelu": mfile.ACT_GELU, "silu": mfile.ACT_SILU}
+HIDDEN_ACT = {"gelu": mfile.ACT_GELU, "silu": mfile.ACT_SILU,
+              "relu": mfile.ACT_RELU}
 
 
 def permute(t: np.ndarray, n_heads: int, n_kv_heads: int) -> np.ndarray:
@@ -128,6 +131,42 @@ def _deepseek2_fields(config: dict) -> dict:
         norm_eps=float(config.get("rms_norm_eps", 1e-6)))
 
 
+def _smallthinker_fields(config: dict) -> dict:
+    """The header's keys 31..34 from a ``smallthinker`` config.json.
+    ``ARCH_SMALLTHINKER`` is one block: a softmax over the chosen primary
+    experts' logits, whole periods of one full (unrotated) layer and then
+    window layers that are exactly the rotated ones, ReLU, unscaled RoPE.  What
+    the runtime does not compute is refused by name."""
+    def no(why):
+        raise SystemExit(f"smallthinker: {why}")
+
+    if not config.get("moe_primary_router_apply_softmax", False):
+        no("moe_primary_router_apply_softmax is false (a sigmoid router); the "
+           "runtime softmaxes the chosen experts' logits")
+    if not config.get("norm_topk_prob", True):
+        no("norm_topk_prob is false; the runtime's chosen weights sum to 1")
+    if config.get("moe_enable_secondary_experts") or config.get(
+            "moe_num_secondary_experts") or config.get("moe_secondary_ffn_hidden_size"):
+        no("secondary experts are configured; the runtime has primary experts only")
+    if config.get("rope_scaling") is not None:
+        no(f"rope_scaling is {config['rope_scaling']}; the runtime's RoPE is unscaled")
+    if config.get("tie_word_embeddings", False):
+        no("tie_word_embeddings is true; the .m format has a head of its own")
+    layers = config["num_hidden_layers"]
+    layout = [int(v) for v in config["sliding_window_layout"]]
+    if [int(v) for v in config.get("rope_layout", layout)] != layout:
+        no("rope_layout differs from sliding_window_layout; the runtime rotates "
+           "exactly its window layers")
+    period = layout[1:].index(0) + 1 if 0 in layout[1:] else 0
+    if period < 2 or layers % period or layout != ([0] + [1] * (period - 1)) * (
+            layers // period):
+        no(f"sliding_window_layout {layout} is not whole periods of one full "
+           "layer and then window layers")
+    return dict(norm_eps=float(config.get("rms_norm_eps", 1e-6)),
+                head_dim=int(config["head_dim"]),
+                window=int(config["sliding_window_size"]), window_period=period)
+
+
 def load_spec(folder: str, weights_ftype: int) -> mfile.ModelSpec:
     with open(os.path.join(folder, "config.json")) as f:
         config = json.load(f)
@@ -137,11 +176,17 @@ def load_spec(folder: str, weights_ftype: int) -> mfile.ModelSpec:
     if arch == mfile.ARCH_OLMOE:
         _refuse_olmoe_variants(config)
     ext = _deepseek2_fields(config) if arch == mfile.ARCH_DEEPSEEK2 else {}
-    # Mixtral's key, then OLMoE's, then DeepSeek-V2's
+    if arch == mfile.ARCH_SMALLTHINKER:
+        ext = _smallthinker_fields(config)
+        config = dict(config, intermediate_size=config["moe_ffn_hidden_size"],
+                      hidden_act="relu")
+    # Mixtral's key, then OLMoE's, then DeepSeek-V2's, then SmallThinker's
     n_experts = (config.get("num_local_experts") or config.get("num_experts")
-                 or config.get("n_routed_experts") or 0)
+                 or config.get("n_routed_experts")
+                 or config.get("moe_num_primary_experts") or 0)
     n_active = (config.get("num_active_local_experts")
-                or config.get("num_experts_per_tok") or 0)
+                or config.get("num_experts_per_tok")
+                or config.get("moe_num_active_primary_experts") or 0)
     return mfile.ModelSpec(
         arch=arch,
         dim=config["hidden_size"],
@@ -211,7 +256,8 @@ def hf_source_name(our_name: str, spec: mfile.ModelSpec) -> tuple[str, bool]:
     li = parts[1]
     leaf = parts[-1]
     base = f"model.layers.{li}"
-    olmoe = spec.arch == mfile.ARCH_OLMOE
+    # rows as published: these runtimes rotate halves, as HF does
+    olmoe = spec.arch in (mfile.ARCH_OLMOE, mfile.ARCH_SMALLTHINKER)
     # the two arch ids whose HF experts are mlp.experts.N.{gate,up,down}_proj
     mlp_experts = spec.arch in (mfile.ARCH_OLMOE, mfile.ARCH_DEEPSEEK2)
     if leaf in _DEEPSEEK2_LEAVES:  # kv_b_proj whole, rows as published
@@ -237,6 +283,10 @@ def hf_source_name(our_name: str, spec: mfile.ModelSpec) -> tuple[str, bool]:
         return f"{base}.mlp.down_proj.weight", False
     if leaf == "w3":
         return f"{base}.mlp.up_proj.weight", False
+    if spec.arch == mfile.ARCH_SMALLTHINKER:
+        moe = f"{base}.block_sparse_moe"
+        return (f"{moe}.primary_router.weight" if leaf == "moe_router"
+                else f"{moe}.experts.{parts[3]}.{leaf}.weight"), False
     if parts[2] == "experts":
         e = parts[3]
         if mlp_experts:

@@ -18,7 +18,10 @@ the reference converters load directly:
   ``wkv_b`` kept whole, ``wo``), a dense FFN in its first
   ``n_dense_layers`` layers and router + experts + one shared expert
   (``shared_w1/w2/w3``) in the rest, and header keys 14..31 for the sizes no
-  older arch has (floats as their IEEE-754 f32 bits).  Matmul weights are stored row-major ``(d_out, n_in)`` in the
+  older arch has (floats as their IEEE-754 f32 bits).  A SmallThinker file
+  (``ARCH_SMALLTHINKER``) has Mixtral's tensors at a query width of
+  ``n_heads * head_dim`` (key 32) and keys 31, 33, 34 for the norm's epsilon,
+  the sliding window and the layer period.  Matmul weights are stored row-major ``(d_out, n_in)`` in the
   model's weight float type; norm weights and the embedding are F32
   (transformer.cpp:213-218, 266-278).
 
@@ -54,12 +57,19 @@ ARCH_OLMOE = 0xABCD03
 # experts, leading dense layers.  Its sizes are header keys (14..31): eleven
 # of them cannot be derived from an id
 ARCH_DEEPSEEK2 = 0xABCD04
+# SmallThinker: Mixtral's tensors with a head size of its own (key 32), periods
+# of one full layer without rotation and ``window_period - 1`` sliding-window
+# layers with RoPE (keys 33, 34), a router that reads the layer's input as it
+# arrives, ReLU experts
+ARCH_SMALLTHINKER = 0xABCD05
 ARCH_NAMES = {ARCH_LLAMA: "llama", ARCH_GROK1: "grok1", ARCH_MIXTRAL: "mixtral",
-              ARCH_OLMOE: "olmoe", ARCH_DEEPSEEK2: "deepseek2"}
+              ARCH_OLMOE: "olmoe", ARCH_DEEPSEEK2: "deepseek2",
+              ARCH_SMALLTHINKER: "smallthinker"}
 
-# TransformerHiddenAct (transformer.hpp:45-48)
+# TransformerHiddenAct (transformer.hpp:45-48), and beyond it ReLU
 ACT_GELU = 0
 ACT_SILU = 1
+ACT_RELU = 2
 
 # TransformerHeaderKey (transformer.hpp:10-25)
 KEY_VERSION = 0
@@ -76,8 +86,10 @@ KEY_SEQ_LEN = 10
 KEY_HIDDEN_ACT = 11
 KEY_ROPE_THETA = 12
 KEY_WEIGHTS_FLOAT_TYPE = 13
-# beyond the reference's fourteen (ARCH_DEEPSEEK2 only).  ``(key, field,
-# is_float)``: a float travels as the bits of its IEEE-754 f32 in the i32
+# beyond the reference's fourteen: DeepSeek-V2's (``EXT_KEYS``, 14..31) and
+# SmallThinker's own (``WINDOW_KEYS``, 32..34; its file also carries key 31).
+# ``(key, field, is_float)``: a float travels as the bits of its IEEE-754 f32
+# in the i32
 EXT_KEYS = (
     (14, "q_lora_rank", False),
     (15, "kv_lora_rank", False),
@@ -98,8 +110,16 @@ EXT_KEYS = (
     (30, "rope_mscale_all_dim", True),
     (31, "norm_eps", True),
 )
-_EXT_BY_KEY = {k: (name, is_f) for k, name, is_f in EXT_KEYS}
-KEY_MAX = EXT_KEYS[-1][0]
+WINDOW_KEYS = (
+    (32, "head_dim", False),            # a head's size where it is not dim / n_heads
+    (33, "window", False),              # keys a sliding-window layer sees, the query's own included
+    (34, "window_period", False),       # layer l is full (and unrotated) iff l % period == 0
+)
+# the keys a file of an arch carries past the fourteen
+ARCH_EXT_KEYS = {ARCH_DEEPSEEK2: tuple(range(14, 32)),
+                 ARCH_SMALLTHINKER: (31, 32, 33, 34)}
+_EXT_BY_KEY = {k: (name, is_f) for k, name, is_f in EXT_KEYS + WINDOW_KEYS}
+KEY_MAX = WINDOW_KEYS[-1][0]
 
 
 def _f32_bits(x: float) -> int:
@@ -148,14 +168,24 @@ class ModelSpec:
     rope_mscale: float = 1.0
     rope_mscale_all_dim: float = 0.0
     norm_eps: float = 1e-5
+    # ARCH_SMALLTHINKER's; 0 where the arch has none
+    head_dim: int = 0
+    window: int = 0
+    window_period: int = 0
 
     @property
     def head_size(self) -> int:
-        return self.dim // self.n_heads
+        return self.head_dim or self.dim // self.n_heads
+
+    @property
+    def q_dim(self) -> int:
+        """Width of the query projection (``dim`` unless the header states a
+        head size)."""
+        return self.head_size * self.n_heads
 
     @property
     def kv_dim(self) -> int:
-        return (self.dim * self.n_kv_heads) // self.n_heads
+        return self.head_size * self.n_kv_heads
 
     @property
     def is_mla(self) -> bool:
@@ -200,10 +230,10 @@ def tensor_plan(spec: ModelSpec) -> list[TensorInfo]:
     if spec.arch == ARCH_DEEPSEEK2:
         _deepseek2_layers(spec, add)
     for i in range(0 if spec.arch == ARCH_DEEPSEEK2 else spec.n_layers):
-        add(f"layers.{i}.wq", (spec.dim, spec.dim), w)
+        add(f"layers.{i}.wq", (spec.q_dim, spec.dim), w)
         add(f"layers.{i}.wk", (spec.kv_dim, spec.dim), w)
         add(f"layers.{i}.wv", (spec.kv_dim, spec.dim), w)
-        add(f"layers.{i}.wo", (spec.dim, spec.dim), w)
+        add(f"layers.{i}.wo", (spec.dim, spec.q_dim), w)
         if spec.arch == ARCH_OLMOE:
             add(f"layers.{i}.q_norm", (spec.dim,), quants.F32)
             add(f"layers.{i}.k_norm", (spec.kv_dim,), quants.F32)
@@ -308,9 +338,9 @@ def validate_spec(spec: ModelSpec, path) -> ModelSpec:
                             "unknown architecture id",
                             expected=sorted(hex(a) for a in ARCH_NAMES),
                             got=hex(spec.arch))
-    if spec.hidden_act not in (ACT_GELU, ACT_SILU):
+    if spec.hidden_act not in (ACT_GELU, ACT_SILU, ACT_RELU):
         raise ArtifactError(path, "header field hidden_act",
-                            "unknown activation id", expected="0|1",
+                            "unknown activation id", expected="0|1|2",
                             got=spec.hidden_act)
     if spec.weights_ftype not in quants.FLOAT_TYPE_NAMES:
         raise ArtifactError(path, "header field weights_ftype",
@@ -324,7 +354,7 @@ def validate_spec(spec: ModelSpec, path) -> ModelSpec:
         raise ArtifactError(path, "header field n_kv_heads",
                             "more KV heads than attention heads",
                             expected=f"<= {spec.n_heads}", got=spec.n_kv_heads)
-    if spec.dim % spec.n_heads:
+    if spec.dim % spec.n_heads and not spec.head_dim:
         raise ArtifactError(path, "header field n_heads",
                             "dim not divisible by n_heads",
                             expected=f"divisor of dim={spec.dim}",
@@ -339,6 +369,12 @@ def validate_spec(spec: ModelSpec, path) -> ModelSpec:
                             "more active experts than experts",
                             expected=f"<= {spec.n_experts}",
                             got=spec.n_active_experts)
+    if spec.arch == ARCH_SMALLTHINKER:
+        _validate_smallthinker(spec, path)
+    elif spec.head_dim or spec.window or spec.window_period:
+        raise ArtifactError(path, "header key",
+                            "keys 32..34 describe a smallthinker file",
+                            expected=hex(ARCH_SMALLTHINKER), got=hex(spec.arch))
     if spec.arch == ARCH_DEEPSEEK2:
         _validate_deepseek2(spec, path)
     elif spec.is_mla or spec.n_dense_layers or spec.n_shared_experts \
@@ -355,6 +391,27 @@ def validate_spec(spec: ModelSpec, path) -> ModelSpec:
                             "an olmoe file has experts and a top-k",
                             expected=">= 1", got=spec.n_active_experts)
     return spec
+
+
+def _validate_smallthinker(spec: ModelSpec, path) -> None:
+    """The cross-field rules of an ``ARCH_SMALLTHINKER`` header."""
+    def bad(field, why, expected, got):
+        raise ArtifactError(path, f"header field {field}", why,
+                            expected=expected, got=got)
+
+    if not 2 <= spec.head_dim <= 4096 or spec.head_dim % 2:
+        bad("head_dim", "a smallthinker file states its head size, and RoPE "
+            "rotates halves of it", "even, 2..4096", spec.head_dim)
+    if not 1 <= spec.window <= 1 << 24:
+        bad("window", "a smallthinker file states its sliding window",
+            "1..2^24", spec.window)
+    if spec.window_period < 2 or spec.n_layers % spec.window_period:
+        bad("window_period", "the layers are whole periods of one full layer "
+            "and window_period - 1 window layers",
+            f">= 2, a divisor of n_layers={spec.n_layers}", spec.window_period)
+    if not spec.n_experts or not spec.n_active_experts:
+        bad("n_experts", "every smallthinker layer has experts and a top-k",
+            ">= 1", spec.n_experts)
 
 
 def _validate_deepseek2(spec: ModelSpec, path) -> None:
@@ -657,10 +714,11 @@ def write_header(f, spec: ModelSpec) -> int:
         (KEY_ROPE_THETA, int(spec.rope_theta)),
         (KEY_WEIGHTS_FLOAT_TYPE, spec.weights_ftype),
     ]
-    if spec.arch == ARCH_DEEPSEEK2:
-        # the older archs keep the reference's fourteen keys, byte for byte
-        pairs += [(k, _f32_bits(getattr(spec, name)) if is_f
-                   else getattr(spec, name)) for k, name, is_f in EXT_KEYS]
+    # the older archs keep the reference's fourteen keys, byte for byte
+    own = ARCH_EXT_KEYS.get(spec.arch, ())
+    pairs += [(k, _f32_bits(getattr(spec, name)) if is_f
+               else getattr(spec, name))
+              for k, name, is_f in EXT_KEYS + WINDOW_KEYS if k in own]
     data = b"".join(struct.pack("<ii", k, v) for k, v in pairs)
     f.write(struct.pack("<ii", MAGIC_V2, 8 + len(data)))
     f.write(data)

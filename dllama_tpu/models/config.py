@@ -7,7 +7,8 @@ that the reference encodes as three separate hand-built task lists
 grok1-tasks.cpp:275-354, `buildMixtralArch` mixtral-tasks.cpp:5-78), and
 those of OLMoE (`ARCH_OLMOE`, beyond the reference).  DeepSeek-V2
 (`ARCH_DEEPSEEK2`) states its sizes in the header (`io/mfile.py EXT_KEYS`) and
-they are fields here, not properties of the id.
+they are fields here, not properties of the id; so are SmallThinker's
+(`ARCH_SMALLTHINKER`) head size, sliding window and layer period.
 """
 
 from __future__ import annotations
@@ -22,6 +23,9 @@ from ..io import mfile
 # Grok-1 scaling constants (grok1-tasks.cpp:13, :272)
 GROK_EMBEDDING_SCALE = 78.38367176906169
 GROK_LOGIT_SCALE = 0.5773502691896257
+# the float32 product of all experts over a prefill call's rows may take this
+# much (``ModelConfig.prefill_chunk``)
+PREFILL_PRODUCT_BYTES = 512 << 20
 
 
 @dataclass(frozen=True)
@@ -69,10 +73,41 @@ class ModelConfig:
     rope_mscale: float = 1.0
     rope_mscale_all_dim: float = 0.0
     norm_eps: float = 1e-5
+    # ---- ARCH_SMALLTHINKER (header keys 32..34); 0 = the arch has none
+    head_dim: int = 0               # a head's size where it is not dim / n_heads
+    window: int = 0                 # > 0: sliding-window layers see this many keys
+    window_period: int = 0          # layer l is full and unrotated iff l % period == 0
 
     @property
     def head_size(self) -> int:
-        return self.dim // self.n_heads
+        return self.head_dim or self.dim // self.n_heads
+
+    @property
+    def q_dim(self) -> int:
+        return self.head_size * self.n_heads
+
+    @property
+    def n_full_layers(self) -> int:
+        """Layers that cache every position (all of them without a window)."""
+        return self.n_layers // self.window_period if self.window else self.n_layers
+
+    @property
+    def n_window_layers(self) -> int:
+        return self.n_layers - self.n_full_layers
+
+    def prefill_chunk(self) -> int:
+        """Rows of one prefill call: the largest power of two whose float32
+        ``(experts, rows, dim)`` product (``moe_ffn``'s all-experts strategy;
+        one expert's for a dense model) stays under ``PREFILL_PRODUCT_BYTES``.
+        512 at 64 experts of 2560; a prompt up to one chunk takes one call."""
+        rows = PREFILL_PRODUCT_BYTES // (4 * max(self.n_experts, 1) * self.dim)
+        return max(16, 1 << (max(rows, 1).bit_length() - 1))
+
+    def window_ring(self, seq_len: int) -> int:
+        """Positions a window layer's contiguous cache holds a row: the window
+        plus one prefill chunk (a call's rows are written before they are
+        read), or all of ``seq_len`` where that is no more."""
+        return min(seq_len, self.window + self.prefill_chunk())
 
     @property
     def is_mla(self) -> bool:
@@ -171,7 +206,8 @@ class ModelConfig:
             n_active_experts=spec.n_active_experts, vocab_size=spec.vocab_size,
             seq_len=spec.seq_len, hidden_act=spec.hidden_act,
             rope_theta=spec.rope_theta, dtype=dtype,
-            **{name: getattr(spec, name) for _, name, _ in mfile.EXT_KEYS})
+            **{name: getattr(spec, name)
+               for _, name, _ in mfile.EXT_KEYS + mfile.WINDOW_KEYS})
 
     def with_(self, **kw) -> "ModelConfig":
         return replace(self, **kw)
@@ -189,6 +225,20 @@ def tiny_config(arch=mfile.ARCH_LLAMA, *, dim=64, hidden_dim=96, n_layers=2,
                        vocab_size=vocab_size, seq_len=seq_len,
                        hidden_act=hidden_act, rope_theta=rope_theta, dtype=dtype,
                        **ext)
+
+
+def tiny_smallthinker(**kw) -> ModelConfig:
+    """SmallThinker at a toy size that keeps every ratio: periods of one full
+    and three window layers, a window shorter than the tests' sequences, 7
+    query heads a kv head, a head size that is not dim / n_heads, 64 experts
+    of which 6 a token, ReLU."""
+    base = dict(arch=mfile.ARCH_SMALLTHINKER, dim=96, hidden_dim=32,
+                n_layers=8, n_heads=28, n_kv_heads=4, n_experts=64,
+                n_active_experts=6, vocab_size=128, seq_len=96,
+                hidden_act=mfile.ACT_RELU, rope_theta=1.5e6, norm_eps=1e-6,
+                head_dim=8, window=16, window_period=4)
+    base.update(kw)
+    return tiny_config(**base)
 
 
 def tiny_deepseek2(**kw) -> ModelConfig:

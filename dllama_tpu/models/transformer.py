@@ -40,6 +40,7 @@ from ..ops.kernels import ACTIVATIONS, apply_rope, rmsnorm, rope_angles, softmax
 from ..ops.scopes import part, scope
 from ..ops.sp_attention import ring_attention, sp_gqa_attention, sp_update_kv_cache_at
 from ..parallel.mesh import get_active_mesh
+from . import windowed
 from .config import ModelConfig
 from .params import DENSE_FFN_KEYS, MLA_ATT_KEYS, MOE_FFN_KEYS, Params
 
@@ -61,6 +62,10 @@ class KVCache(NamedTuple):
     # and scales identically.
     k_scale: jax.Array | None = None
     v_scale: jax.Array | None = None
+    # a windowed model (models/windowed.py) only: its window layers' rings
+    # (Lw, B, Hkv, R, Dh); k and v are then its full layers' planes
+    wk: jax.Array | None = None
+    wv: jax.Array | None = None
 
     @property
     def quantized(self) -> bool:
@@ -91,6 +96,8 @@ def init_kv_cache(cfg: ModelConfig, batch: int, seq_len: int | None = None,
     the HBM read stays int8-sized).
     """
     s = seq_len or cfg.seq_len
+    if cfg.window:
+        return windowed.init_cache(cfg, batch, s, dtype, quant)
     if cfg.is_mla:
         return _init_latent((cfg.n_layers, batch, s), cfg, dtype, quant)
     shape = (cfg.n_layers, batch, cfg.n_kv_heads, s, cfg.head_size)
@@ -387,18 +394,20 @@ def _dense_ffn(xb, lp, cfg: ModelConfig):
         return _mm(h, lp["w2"], cfg, kind="col")
 
 
-def moe_ffn(xb2d: jax.Array, lp, cfg: ModelConfig) -> jax.Array:
+def moe_ffn(xb2d: jax.Array, lp, cfg: ModelConfig,
+            router_logits: jax.Array | None = None) -> jax.Array:
     """The routed experts (:func:`_routed_experts`, every strategy) plus,
     where the layer has one (DeepSeek-V2), the shared expert every row takes:
     sub-scope ``shared`` inside ``moe``."""
-    out = _routed_experts(xb2d, lp, cfg)
+    out = _routed_experts(xb2d, lp, cfg, router_logits)
     if "shared_w2" in lp:
         with part("shared"):
             out = out + _swiglu(xb2d, lp, cfg, "shared_w")
     return out
 
 
-def _routed_experts(xb2d: jax.Array, lp, cfg: ModelConfig) -> jax.Array:
+def _routed_experts(xb2d: jax.Array, lp, cfg: ModelConfig,
+                    router_logits: jax.Array | None = None) -> jax.Array:
     """Mixture-of-experts FFN (grok1-tasks.cpp:56-228 semantics).
 
     Routing: softmax over *all* expert logits, top-k, renormalize the
@@ -408,7 +417,10 @@ def _routed_experts(xb2d: jax.Array, lp, cfg: ModelConfig) -> jax.Array:
     chooses in two stages, ``topk_groups`` groups by their best expert and
     then the top-k of the experts in them, and scales the chosen
     probabilities by ``cfg.routed_scale``; every strategy below takes its
-    experts and weights from this one choice.
+    experts and weights from this one choice.  The logits are this layer's
+    FFN input times ``lp["router"]`` unless the caller hands ``router_logits``
+    ``(N, E)`` made elsewhere (SmallThinker's router reads the layer's input
+    before attention, ``models/windowed.py``).
 
     Sub-scopes inside ``moe``: ``router`` (logits, softmax, top-k),
     ``experts`` (the expert matmuls and, on the scan, its bookkeeping),
@@ -446,8 +458,9 @@ def _routed_experts(xb2d: jax.Array, lp, cfg: ModelConfig) -> jax.Array:
     act = ACTIVATIONS[cfg.hidden_act]
 
     with part("router"):
-        router = lp["router"]
-        router_logits = xb2d.astype(jnp.float32) @ router.astype(jnp.float32)  # (N, E)
+        if router_logits is None:
+            router = lp["router"]
+            router_logits = xb2d.astype(jnp.float32) @ router.astype(jnp.float32)  # (N, E)
         probs = softmax_f32(router_logits)
         if cfg.n_groups > 1:
             # a group's score is its best expert's; experts outside the
@@ -616,6 +629,9 @@ def run_blocks(params: Params, cfg: ModelConfig, tokens: jax.Array,
     if cfg.is_mla:
         return _run_segments(params, cfg, x, cache, cos, sin, pos, offsets,
                              pos_rows, paged)
+    if cfg.window:
+        return windowed.run_periods(params, cfg, x, cache, cos, sin, pos,
+                                    offsets, pos_rows, paged)
 
     layer_keys = [k for k in params if k not in ("embedding", "rms_final", "wcls")]
     # Packed-Q40 weights stay out of the scan's xs: the scan would slice a

@@ -241,15 +241,22 @@ class CostModel:
                  n_experts: int = 0, n_active_experts: int = 0,
                  fused: bool = False, n_dense_layers: int = 0,
                  moe_hidden_dim: int = 0, n_shared_experts: int = 0,
-                 mla: dict | None = None):
+                 mla: dict | None = None, head_dim: int = 0,
+                 window: int = 0, window_period: int = 0):
         self.dim = dim
         self.hidden_dim = hidden_dim
         self.n_layers = n_layers
         self.n_heads = n_heads
         self.n_kv_heads = n_kv_heads
         self.vocab_size = vocab_size
-        self.head_size = dim // n_heads
+        self.head_size = head_dim or dim // n_heads
         self.kv_dim = self.head_size * n_kv_heads
+        q_dim = self.head_size * n_heads  # dim unless the head size is stated
+        #: a windowed model (SmallThinker): layer l is full iff l % period == 0,
+        #: the others see the last ``window`` positions
+        self.window = int(window or 0)
+        self.n_window_layers = (n_layers - n_layers // window_period
+                                if self.window else 0)
         self.weight_codec = weight_codec
         self.weight_el_bytes = weight_el_bytes
         self.kv_codec = kv_codec
@@ -268,11 +275,11 @@ class CostModel:
         ffn = 3 * dim * hidden_dim  # w1 + w2 + w3
         if self.moe:
             ffn *= n_active_experts
-        attn = 2 * dim * dim + 2 * dim * self.kv_dim  # wq+wo, wk+wv
+        attn = 2 * dim * q_dim + 2 * dim * self.kv_dim  # wq+wo, wk+wv
         #: values one cached position holds in one layer, and FLOPs of one
         #: (query, context) pair in one layer (QK^T + weighted V sum)
         self.kv_values = 2 * self.kv_dim
-        self.pair_flops = 4 * dim
+        self.pair_flops = 4 * q_dim
         #: latent attention (MLA): ``mla`` holds q_lora_rank, kv_lora_rank,
         #: qk_nope_head_dim, qk_rope_head_dim, v_head_dim.  A cached position
         #: is one latent and one rotated key for all heads, and a pair costs
@@ -329,7 +336,11 @@ class CostModel:
     def attn_flops(self, pos: int, n_new: int) -> int:
         """QK^T + weighted V sum: 4 * dim MACs -> FLOPs per (query,
         context) pair, per layer."""
-        return self.pair_flops * self.n_layers * self._ctx_sum(pos, n_new)
+        full = self.n_layers - self.n_window_layers
+        seen = full * self._ctx_sum(pos, n_new) + self.n_window_layers * sum(
+            min(pos + j + 1, self.window) for j in range(
+                n_new if self.n_window_layers else 0))
+        return self.pair_flops * seen
 
     def kv_pos_bytes(self) -> int:
         """Bytes one (k, v) position occupies in one layer."""
@@ -341,8 +352,11 @@ class CostModel:
     def kv_write_bytes(self, n_new: int) -> int:
         return n_new * self.n_layers * self.kv_pos_bytes()
 
-    def _read_positions(self, pos: int, n_new: int, burst: bool) -> int:
+    def _read_positions(self, pos: int, n_new: int, burst: bool,
+                        window: int = 0) -> int:
         def paged_up(c: int) -> int:
+            if window:  # a window layer reads its window and the block's rows
+                c = min(c, window + (0 if burst else n_new - 1))
             if self.paged and self.page_size:
                 return -(-c // self.page_size) * self.page_size
             return c
@@ -354,8 +368,12 @@ class CostModel:
         return paged_up(pos + n_new)
 
     def kv_read_bytes(self, pos: int, n_new: int, burst: bool) -> int:
-        return (self._read_positions(pos, n_new, burst)
-                * self.n_layers * self.kv_pos_bytes())
+        full = self.n_layers - self.n_window_layers
+        positions = full * self._read_positions(pos, n_new, burst)
+        if self.n_window_layers:
+            positions += self.n_window_layers * self._read_positions(
+                pos, n_new, burst, self.window)
+        return positions * self.kv_pos_bytes()
 
     def ring_bytes(self, tokens: int) -> int:
         """Aggregate TP ring all-reduce hop bytes: two f32 reduces of
@@ -501,7 +519,9 @@ def model_from_engine(engine) -> CostModel | None:
                      kv_lora_rank=cfg.kv_lora_rank,
                      qk_nope_head_dim=cfg.qk_nope_head_dim,
                      qk_rope_head_dim=cfg.qk_rope_head_dim,
-                     v_head_dim=cfg.v_head_dim) if cfg.is_mla else None)
+                     v_head_dim=cfg.v_head_dim) if cfg.is_mla else None,
+            head_dim=cfg.head_dim, window=cfg.window,
+            window_period=cfg.window_period)
     except Exception:
         return None
 

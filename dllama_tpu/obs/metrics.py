@@ -612,6 +612,10 @@ COMPILE_S_BUCKETS = (0.05, 0.1, 0.25, 0.5, 1.0, 2.5, 5.0, 10.0,
 ENGINE_RECOMPILES = REGISTRY.counter(
     "engine_recompiles",
     "XLA executables built by the engine (new step shape or chunk spec).")
+ENGINE_PREFILL_CHUNKS = REGISTRY.counter(
+    "engine_prefill_chunks",
+    "Calls of a chunked prefill (Engine.prefill of a prompt longer than one "
+    "chunk): the whole chunks and the tail.")
 ENGINE_CACHE_HITS = REGISTRY.counter(
     "engine_executable_cache_hits",
     "Engine steps served by an already-compiled executable.")
@@ -748,6 +752,13 @@ KV_BYTES_PER_TOKEN = REGISTRY.gauge(
     "kv_bytes_per_token",
     "Bytes one cached token occupies over all layers, in the contiguous "
     "cache or the paged pool.")
+# beside it, from the same arrays: what the cache holds by kind of plane.
+# "full": planes of every position (all of a model without window layers, the
+# paged pool); "window": a windowed model's rings, bounded by the window plus
+# one prefill chunk whatever --max-seq-len is
+KV_CACHE_BYTES = REGISTRY.labeled_gauge(
+    "kv_cache_bytes", "kind",
+    "Resident bytes of the KV cache by kind of plane (full | window).")
 
 # scheduler goodput accounting (runtime/scheduler.py + obs/flight.py):
 # every millisecond between the scheduler's first and last dispatch lands

@@ -50,7 +50,9 @@ def gelu_tanh(x: jax.Array) -> jax.Array:
     return y.astype(x.dtype)
 
 
-ACTIVATIONS = {0: gelu_tanh, 1: silu}  # TransformerHiddenAct (transformer.hpp:45-48)
+# TransformerHiddenAct (transformer.hpp:45-48); 2 is this format's ReLU
+# (io/mfile.py ACT_RELU: SmallThinker's ReGLU experts)
+ACTIVATIONS = {0: gelu_tanh, 1: silu, 2: jax.nn.relu}
 
 
 def rope_angles(positions: jax.Array, head_size: int, theta: float) -> tuple[jax.Array, jax.Array]:

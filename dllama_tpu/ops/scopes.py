@@ -17,7 +17,8 @@ the two.  An op whose name path carries none of these is ``unscoped``.
 Inside ``moe`` the work is split further by :func:`part` into ``router``,
 ``experts``, ``combine`` and (DeepSeek-V2) ``shared``; inside ``qkv`` MLA's
 ``q_lora`` / ``kv_lora``; inside ``attn`` MLA's ``absorb`` / ``latent`` /
-``expand`` (``PARTS``, by scope): plain sub-names, not scopes.  An
+``expand`` and a windowed model's ``window`` / ``full`` by layer kind
+(``PARTS``, by scope): plain sub-names, not scopes.  An
 op's path then ends ``.../moe/experts/...`` and a reader that knows only
 ``SCOPES`` still files it under ``moe``; ``by-scope.json``'s op table
 carries the whole path for the finer split.  Which strategy a compiled call
@@ -60,6 +61,8 @@ PARTS = {
         "absorb",    # MLA absorbed form: W_uk into the query, W_uv out of the result
         "latent",    # MLA absorbed form: the walk over latent rows
         "expand",    # MLA expanded form: a block's rows through W_kvb, in the walk
+        "window",    # a sliding-window layer's read (ring or bounded page gather)
+        "full",      # a windowed model's full (unrotated) layer's read
     ),
     "moe": (
         "router",    # router logits, softmax, (groups,) top-k, the dense weight table

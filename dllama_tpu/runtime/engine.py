@@ -280,20 +280,37 @@ def page_axes(cache) -> str:
     return LATENT_PAGE_AXES if cache.latent else PAGE_AXES
 
 
-def _refuse_mla_unsupported(mesh, kv_dtype) -> None:
-    """What a latent-attention (MLA, DeepSeek-V2) model cannot do yet, refused
-    here by name and not discovered in a trace."""
+def _refuse_mesh_and_int8(mesh, kv_dtype, what: str, why: str) -> None:
+    """What a model whose cache is not one per-head plane a layer cannot do
+    yet (``what``: latent attention, DeepSeek-V2; window layers, SmallThinker),
+    refused here by name and not discovered in a trace."""
     for ax in ("tp", "sp", "ep"):
         if mesh.shape.get(ax, 1) > 1:
             raise ValueError(
-                f"latent attention (MLA) runs on one device: a {ax}={mesh.shape[ax]} "
-                "mesh is not supported for this architecture (the latent cache "
-                "would be replicated and the heads sharded; not wired)")
+                f"{what} runs on one device: a {ax}={mesh.shape[ax]} mesh is "
+                f"not supported for this architecture ({why}; not wired)")
     if kv_dtype == "q8" or (kv_dtype is not None
                             and jnp.dtype(kv_dtype) == jnp.int8):
-        raise ValueError(
-            "--kv-quant int8 is not supported with latent attention (MLA): "
-            "the latent cache has no int8 form")
+        raise ValueError(f"--kv-quant int8 is not supported with {what}: "
+                         "its cache has no int8 form")
+
+
+def _note_cache_bytes(cache, tokens: int, batch: int) -> int:
+    """Set the cache's gauges from its own arrays and return what one cached
+    token occupies over all layers.  A windowed model's rings hold fewer
+    positions than its full planes, so each plane is counted at its own
+    positions a row: ``kv_cache_bytes{kind="window"}`` is what the bound on
+    the rings saves against ``kind="full"``'s planes per layer."""
+    per_token, by_kind = 0, {"full": 0, "window": 0}
+    for name, a in cache.planes().items():
+        kind = "window" if name in ("wk", "wv") else "full"
+        by_kind[kind] += int(a.nbytes)
+        positions = tokens if kind == "full" else batch * a.shape[3]
+        per_token += int(a.nbytes) // positions
+    for kind, nbytes in by_kind.items():
+        obs_metrics.KV_CACHE_BYTES.set(kind, nbytes)
+    obs_metrics.KV_BYTES_PER_TOKEN.set(per_token)
+    return per_token
 
 
 class Engine:
@@ -343,7 +360,13 @@ class Engine:
                     f"n_experts {cfg.n_experts} not divisible by ep={ep}")
         self.cfg = cfg
         if cfg.is_mla:
-            _refuse_mla_unsupported(self.mesh, kv_dtype)
+            _refuse_mesh_and_int8(
+                self.mesh, kv_dtype, "latent attention (MLA)",
+                "the latent cache would be replicated and the heads sharded")
+        if cfg.window:
+            _refuse_mesh_and_int8(
+                self.mesh, kv_dtype, "a windowed (SmallThinker) model",
+                "its two cache kinds have one placement")
         if self.mesh.shape.get("tp", 1) > 1 \
                 and jax.default_backend() != "tpu" \
                 and os.environ.get("DLLAMA_TP_REDUCE", "") != "psum":
@@ -426,12 +449,11 @@ class Engine:
                                       quant=kv_quant),
                 self._cache_sh)
         # what one cached token occupies over all layers, learned from the
-        # cache itself (a latent cache: layers x C x element size)
+        # cache itself (a latent cache: layers x C x element size; a windowed
+        # model's rings at their own positions)
         tokens = (self.kv_pages * self.kv_page_size if self.paged
                   else batch * self.seq_len)
-        self.kv_bytes_per_token = sum(
-            int(a.nbytes) for a in self.cache.planes().values()) // tokens
-        obs_metrics.KV_BYTES_PER_TOKEN.set(self.kv_bytes_per_token)
+        self.kv_bytes_per_token = _note_cache_bytes(self.cache, tokens, batch)
         self.pos = 0
 
         def step(params, cache, tokens, pos, last_index, offsets=None):
@@ -808,8 +830,11 @@ class Engine:
             return self._run_step(tokens_np, last_index, offsets)
 
     def _run_step(self, tokens_np: np.ndarray, last_index: int,
-                  offsets: jax.Array | None) -> tuple[np.ndarray, StepStats]:
-        """:meth:`_run` inside its span: enqueue, wait, fetch the logits."""
+                  offsets: jax.Array | None, fetch: bool = True
+                  ) -> tuple[np.ndarray | None, StepStats]:
+        """:meth:`_run` inside its span: enqueue, wait, fetch the logits
+        (``fetch=False``: wait only, for a chunk of a prompt that is not its
+        last; the logits stay on the device and ``None`` is returned)."""
         stats = StepStats()
         t0 = time.perf_counter()
         # from-scratch prefill on an sp mesh → blockwise ring attention with
@@ -841,18 +866,20 @@ class Engine:
             self._compiled_steps.add(step_key)
         self._note_executable(fresh_exec, (t1 - t0) if fresh_exec else None,
                               key=step_key)
-        host_logits = np.asarray(logits)  # (B, V)
-        if "nan" in fired:  # injected device fault: poisoned logits
-            host_logits = np.full_like(host_logits, np.nan)
-        host_logits = self._numeric_guard(
-            host_logits, "prefill" if tokens_np.shape[1] > 1 else "decode")
+        host_logits = None
+        if fetch:
+            host_logits = np.asarray(logits)  # (B, V)
+            if "nan" in fired:  # injected device fault: poisoned logits
+                host_logits = np.full_like(host_logits, np.nan)
+            host_logits = self._numeric_guard(
+                host_logits, "prefill" if tokens_np.shape[1] > 1 else "decode")
         t2 = time.perf_counter()
         # block_until_ready (t1) marks end of execution; the rest is fetch
         stats.inference_ms = (t1 - t0) * 1000
         stats.transfer_ms = (t2 - t1) * 1000
         stats.generation_ms = (t2 - t0) * 1000
         stats.sent_bytes = tokens_np.nbytes + 8  # token ids + pos/last scalars
-        stats.recv_bytes = host_logits.nbytes
+        stats.recv_bytes = host_logits.nbytes if fetch else 0
         obs_metrics.ENGINE_GENERATION_MS.observe(stats.generation_ms)
         obs_metrics.ENGINE_INFERENCE_MS.observe(stats.inference_ms)
         obs_metrics.ENGINE_TRANSFER_MS.observe(stats.transfer_ms)
@@ -868,18 +895,58 @@ class Engine:
         if self.pos + n > self.seq_len:
             raise ContextOverflow(
                 f"prompt of {n} exceeds seq_len {self.seq_len} at pos {self.pos}")
-        # the padded bucket must also fit the cache: dynamic_update_slice
-        # clamps out-of-range starts *backwards*, which would silently
-        # overwrite valid KV history near the end of context
-        bucket = max(n, min(_next_bucket(n), self.seq_len - self.pos))
-        toks = np.zeros((self.batch, bucket), np.int32)
-        toks[:, :n] = prompt_tokens
-        logits, stats = self._run(toks, n - 1)
+        if n > self.cfg.prefill_chunk() and self.sp == 1:
+            return self._prefill_chunked(prompt_tokens)
+        logits, stats = self._run(self._bucketed(prompt_tokens), n - 1)
         self.pos += n
         _log.info("prefill", extra={
             "n_tokens": n, "pos": self.pos,
             "generation_ms": round(stats.generation_ms, 3)})
         return logits, stats
+
+    def _bucketed(self, tokens: list[int]) -> np.ndarray:
+        """``tokens`` in every row of a zero array padded to their compile
+        bucket.  The padded bucket must also fit the cache:
+        dynamic_update_slice clamps out-of-range starts *backwards*, which
+        would silently overwrite valid KV history near the end of context."""
+        n = len(tokens)
+        bucket = max(n, min(_next_bucket(n), self.seq_len - self.pos))
+        toks = np.zeros((self.batch, bucket), np.int32)
+        toks[:, :n] = tokens
+        return toks
+
+    def _prefill_chunked(self, prompt_tokens: list[int]
+                         ) -> tuple[np.ndarray, StepStats]:
+        """A prompt longer than one prefill chunk (``ModelConfig.prefill_chunk``,
+        a static rule from the shapes): whole chunks through ONE compiled
+        program (``pos`` is traced), then the tail through its power-of-two
+        bucket, as a short prompt goes.  Each call is waited for, so that its
+        span ``engine.prefill_chunk`` is the chunk's time; only the last
+        call's logits come to the host.  Returns them and the summed stats."""
+        if self.paged:
+            raise ValueError("paged engine is slot-only: drive it via "
+                             "slot_step / the slot scheduler")
+        chunk = self.cfg.prefill_chunk()
+        n = len(prompt_tokens)
+        total = StepStats()
+        with obs_trace.span("engine.prefill", pos=self.pos, k=n,
+                            chunks=-(-n // chunk)):
+            for lo in range(0, n, chunk):
+                piece = prompt_tokens[lo:lo + chunk]
+                toks = self._bucketed(piece)
+                with obs_trace.span("engine.prefill_chunk", pos=self.pos,
+                                    k=len(piece), rows=toks.shape[1]):
+                    logits, stats = self._run_step(
+                        toks, len(piece) - 1, None, fetch=lo + chunk >= n)
+                obs_metrics.ENGINE_PREFILL_CHUNKS.inc()
+                self.pos += len(piece)
+                for f in ("generation_ms", "inference_ms", "transfer_ms",
+                          "sent_bytes", "recv_bytes"):
+                    setattr(total, f, getattr(total, f) + getattr(stats, f))
+        _log.info("prefill", extra={
+            "n_tokens": n, "pos": self.pos, "chunks": -(-n // chunk),
+            "generation_ms": round(total.generation_ms, 3)})
+        return logits, total
 
     def prefill_ragged(self, prompts: list[list[int]]
                        ) -> tuple[np.ndarray, StepStats]:

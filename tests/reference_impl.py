@@ -260,3 +260,68 @@ def np_forward_deepseek2(params, cfg, tokens, *, mscale=True, yarn=True, **moe_k
             x = x + deepseek2_moe(xb, fp, cfg, act, **moe_kw)
     x = rmsnorm_eps(x, np.asarray(params["rms_final"]), eps)
     return (x @ np.asarray(params["wcls"], np.float32)).astype(np.float32)
+
+
+# ---- SmallThinker (ARCH_SMALLTHINKER) --------------------------------------
+
+def relu(x):
+    return np.maximum(x, 0.0)
+
+
+def np_forward_smallthinker(params, cfg, tokens, wrong=None):
+    """SmallThinker's full-sequence forward, (T, V) logits: layer ``l`` is
+    full and unrotated where ``l % window_period == 0`` and otherwise a
+    sliding-window layer (key ``j`` visible to query ``p`` iff ``p - window <
+    j <= p``) with rotate-half RoPE; the router reads ``x_l`` as it enters the
+    layer, before the attention norm; the experts are ReGLU; the six largest
+    logits are chosen and softmaxed among themselves.  No cache, loops over
+    layers, heads and rows.
+
+    ``wrong`` names one deliberate fault, for the tests that prove each is
+    seen: ``rope_on_full``, ``window_plus_one``, ``router_after_norm``,
+    ``silu``, ``softmax_all``."""
+    t = len(tokens)
+    hq, hkv, dh = cfg.n_heads, cfg.n_kv_heads, cfg.head_size
+    pos = np.arange(t)
+    act = silu if wrong == "silu" else relu
+    width = cfg.window + (1 if wrong == "window_plus_one" else 0)
+
+    def norm(x, w):
+        ms = np.mean(x.astype(np.float64) ** 2, axis=-1, keepdims=True)
+        return (w * (x / np.sqrt(ms + cfg.norm_eps))).astype(np.float32)
+
+    x = params["embedding"][tokens].astype(np.float32)
+    for li in range(cfg.n_layers):
+        lp = {k: np.asarray(v[li]) for k, v in params.items()
+              if k not in ("embedding", "rms_final", "wcls")}
+        windowed = li % cfg.window_period != 0
+        xb = norm(x, lp["rms_att"])
+        r_in = xb if wrong == "router_after_norm" else x
+        scores_r = r_in.astype(np.float32) @ lp["router"]            # (T, E)
+        q = (xb @ lp["wq"]).reshape(t, hq, dh)
+        k = (xb @ lp["wk"]).reshape(t, hkv, dh)
+        v = (xb @ lp["wv"]).reshape(t, hkv, dh)
+        if windowed or wrong == "rope_on_full":
+            q = rope_rotate(q, pos, cfg.rope_theta, False)
+            k = rope_rotate(k, pos, cfg.rope_theta, False)
+        mask = pos[None, :] <= pos[:, None]
+        if windowed:
+            mask &= pos[None, :] > pos[:, None] - width
+        att = np.zeros((t, hq, dh), np.float32)
+        for h in range(hq):
+            kh = h // (hq // hkv)
+            sc = np.where(mask, (q[:, h] @ k[:, kh].T) / np.sqrt(dh), -np.inf)
+            att[:, h] = softmax(sc) @ v[:, kh]
+        x = x + att.reshape(t, hq * dh) @ lp["wo"]
+        m = norm(x, lp["rms_ffn"])
+        out = np.zeros_like(x)
+        for i in range(t):
+            idx = np.argsort(-scores_r[i], kind="stable")[:cfg.n_active_experts]
+            w = (softmax(scores_r[i])[idx] if wrong == "softmax_all"
+                 else softmax(scores_r[i, idx]))
+            for wj, e in zip(w, idx):
+                out[i] += wj * ((act(m[i] @ lp["gate"][e]) * (m[i] @ lp["up"][e]))
+                                @ lp["down"][e])
+        x = x + out
+    x = norm(x, np.asarray(params["rms_final"]))
+    return (x @ params["wcls"]).astype(np.float32)

@@ -309,3 +309,89 @@ def test_convert_hf_refuses_a_checkpoint_with_a_correction_bias(tmp_path):
                np.zeros(32, np.float32)}, str(tmp_path / "model.safetensors"))
     with pytest.raises(SystemExit, match="e_score_correction_bias"):
         convert_hf.convert(str(tmp_path), quants.F32, str(tmp_path / "x.m"))
+
+
+# ---- smallthinker --------------------------------------------------------------
+
+ST_HF = dict(
+    model_type="smallthinker", hidden_size=96, moe_ffn_hidden_size=32,
+    num_hidden_layers=8, num_attention_heads=28, num_key_value_heads=4,
+    head_dim=8, vocab_size=128, max_position_embeddings=96,
+    moe_num_primary_experts=64, moe_num_active_primary_experts=6,
+    moe_primary_router_apply_softmax=True, norm_topk_prob=True,
+    rms_norm_eps=1e-6, rope_theta=1500000, rope_scaling=None,
+    rope_layout=[0, 1, 1, 1] * 2, sliding_window_layout=[0, 1, 1, 1] * 2,
+    sliding_window_size=16, tie_word_embeddings=False)
+
+
+def test_convert_smallthinker_names_and_logits(tmp_path):
+    """A toy checkpoint under SmallThinker's published tensor names
+    (``block_sparse_moe.primary_router``, ``experts.N.up|gate|down``) through
+    the converter, the loader and ``forward`` against the plain numpy
+    reference on the same weights: rows are not permuted (the runtime rotates
+    halves as the checkpoint does), the header carries the head size, the
+    window and the period, ReLU is activation 2."""
+    import jax
+    import jax.numpy as jnp
+    from safetensors.numpy import save_file
+
+    import convert_hf
+    import reference_impl as ref
+    from dllama_tpu.models.config import tiny_smallthinker
+    from dllama_tpu.models.params import init_params
+    from dllama_tpu.models.transformer import forward, init_kv_cache
+
+    cfg = tiny_smallthinker()
+    p = {k: np.asarray(v, np.float32)
+         for k, v in init_params(cfg, seed=9, scale=0.08).items()}
+    hf = {"model.embed_tokens.weight": p["embedding"],
+          "model.norm.weight": p["rms_final"], "lm_head.weight": p["wcls"].T}
+    for i in range(cfg.n_layers):
+        base = f"model.layers.{i}."
+        for ours, theirs in (("wq", "q_proj"), ("wk", "k_proj"), ("wv", "v_proj"),
+                             ("wo", "o_proj")):
+            hf[f"{base}self_attn.{theirs}.weight"] = p[ours][i].T
+        hf[base + "input_layernorm.weight"] = p["rms_att"][i]
+        hf[base + "post_attention_layernorm.weight"] = p["rms_ffn"][i]
+        hf[base + "block_sparse_moe.primary_router.weight"] = p["router"][i].T
+        for e in range(cfg.n_experts):
+            for leaf in ("up", "gate", "down"):
+                hf[f"{base}block_sparse_moe.experts.{e}.{leaf}.weight"] = p[leaf][i, e].T
+    (tmp_path / "config.json").write_text(json.dumps(ST_HF))
+    save_file({k: np.ascontiguousarray(v) for k, v in hf.items()},
+              str(tmp_path / "model.safetensors"))
+    out = str(tmp_path / "st.m")
+    convert_hf.convert(str(tmp_path), quants.F32, out)
+    mf = mfile.MFile(out)
+    assert mf.spec.arch == mfile.ARCH_SMALLTHINKER
+    assert (mf.spec.head_dim, mf.spec.window, mf.spec.window_period,
+            mf.spec.hidden_act, mf.spec.hidden_dim) == (8, 16, 4, mfile.ACT_RELU, 32)
+    got_cfg, params = load_params(mf)
+    got_cfg = got_cfg.with_(dtype=jnp.float32)
+    toks = np.random.RandomState(4).randint(3, 128, (40,)).astype(np.int32)
+    want = ref.np_forward_smallthinker(p, cfg, toks)
+    with jax.default_matmul_precision("highest"):
+        logits, _ = forward(params, got_cfg, jnp.asarray(toks)[None],
+                            init_kv_cache(got_cfg, 1), jnp.int32(0))
+    np.testing.assert_allclose(np.asarray(logits)[0], want, atol=2e-5, rtol=1e-4)
+
+
+@pytest.mark.parametrize("key,value,says", [
+    ("moe_primary_router_apply_softmax", False, "a sigmoid router"),
+    ("norm_topk_prob", False, "norm_topk_prob is false"),
+    ("moe_num_secondary_experts", 8, "secondary experts are configured"),
+    ("rope_scaling", {"type": "linear", "factor": 2.0}, "rope_scaling is"),
+    ("rope_layout", [1] * 8, "rope_layout differs from sliding_window_layout"),
+    ("sliding_window_layout", [0, 1, 1, 1, 1, 0, 1, 1], "is not whole periods"),
+    ("tie_word_embeddings", True, "tie_word_embeddings is true"),
+])
+def test_convert_hf_refuses_smallthinker_variants_by_name(tmp_path, key, value, says):
+    import convert_hf
+
+    config = dict(ST_HF)
+    config[key] = value
+    if key == "sliding_window_layout":
+        config["rope_layout"] = value
+    (tmp_path / "config.json").write_text(json.dumps(config))
+    with pytest.raises(SystemExit, match=says):
+        convert_hf.load_spec(str(tmp_path), quants.F32)

@@ -132,3 +132,27 @@ def test_deepseek_v2_plan_counts_no_padded_column():
     assert p["weights_sharded"] == logical * 18 / 32
     padded = 4 * 160 * 512 * 5120 * 18 / 32   # what 2048 columns for down added
     assert padded > 0.9e9 and p["weights_sharded"] + padded > 10.3e9
+
+
+def test_smallthinker_cache_is_bounded_by_the_window():
+    """SmallThinker's published widths, the cell's 16384 positions: 13 full
+    layers hold every position, 39 window layers a ring of 4096 + 512, so the
+    cache is 0.80 GB where 52 full layers would hold 1.74; the whole model
+    fits one chip; a decode step streams 6 of 64 experts a layer."""
+    from dllama_tpu.io import mfile
+    from dllama_tpu.models.config import tiny_config
+
+    cfg = tiny_config(
+        arch=mfile.ARCH_SMALLTHINKER, dim=2560, hidden_dim=768, n_layers=52,
+        n_heads=28, n_kv_heads=4, n_experts=64, n_active_experts=6,
+        vocab_size=151936, seq_len=16384, hidden_act=mfile.ACT_RELU,
+        head_dim=128, window=4096, window_period=4)
+    p = plan(cfg)
+    assert p["kv_cache"] == (13 * 16384 + 39 * 4608) * 2048
+    assert 0.76e9 < p["kv_cache"] <= 0.81e9 < 52 * 16384 * 2048
+    experts = 52 * 64 * 3 * 2560 * 768 * 18 / 32
+    assert experts < p["weights_sharded"] < experts + 1.0e9
+    assert p["fits_v5e"] and 13e9 < p["per_chip"] < 15.5e9
+    assert p["decode_read_per_step"] < 0.2 * p["weights_sharded"]
+    # a short cache is not rounded up to a ring
+    assert plan(cfg, seq_len=2048)["kv_cache"] == 52 * 2048 * 2048

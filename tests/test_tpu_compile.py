@@ -570,3 +570,92 @@ def test_ring_reduce_compiles_at_prefill_rows(topo, monkeypatch):
     f = jax.shard_map(lambda x: q40._tp_ring_allreduce(x, 4), mesh=mesh,
                       in_specs=P(), out_specs=P(), check_vma=False)
     assert "q40_ring" in jax.jit(f).lower(x).compile().as_text()
+
+
+def _smallthinker_programs(one_chip, monkeypatch, n_layers=4):
+    """SmallThinker's published widths (hidden 2560, 28/4 heads of 128, 64
+    experts of 768, vocab 151936, window 4096, a 16384-position cache) at
+    ``n_layers`` layers: the config, abstract packed params and cache."""
+    from dllama_tpu.io import mfile
+    from dllama_tpu.models import transformer as tf
+    from dllama_tpu.models.config import ModelConfig
+    from dllama_tpu.models.params import param_shapes
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(jax, "device_count", lambda: 1)
+    cfg = ModelConfig(
+        arch=mfile.ARCH_SMALLTHINKER, dim=2560, hidden_dim=768,
+        n_layers=n_layers, n_heads=28, n_kv_heads=4, n_experts=64,
+        n_active_experts=6, vocab_size=151936, seq_len=16384,
+        hidden_act=mfile.ACT_RELU, rope_theta=1.5e6, norm_eps=1e-6,
+        head_dim=128, window=4096, window_period=4, dtype=jnp.bfloat16)
+    s = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)  # noqa: E731
+
+    def packed(*shapes):
+        *lead, n, _ = shapes[0]
+        d, np_ = sum(sh[-1] for sh in shapes), q40.padded_n(n)
+        return q40.QTensor(s((*lead, np_ // 2, d), jnp.uint8),
+                           s((*lead, np_ // 32, d), jnp.uint16), (n, d))
+
+    sh = param_shapes(cfg)
+    params = {k: s(sh[k], jnp.float32) for k in sh if k.startswith("rms")}
+    params.update({k: s(sh[k], jnp.bfloat16) for k in ("embedding", "router")})
+    params.update(wqkv=packed(sh["wq"], sh["wk"], sh["wv"]),
+                  **{k: packed(sh[k]) for k in ("wo", "up", "gate", "down", "wcls")})
+    shapes = jax.eval_shape(lambda: tf.init_kv_cache(cfg, 1))
+    cache = tf.KVCache(**{n: s(a.shape, a.dtype)
+                          for n, a in shapes.planes().items()})
+    return cfg, params, cache, s
+
+
+def test_smallthinker_cell_programs_compile_at_published_widths(one_chip, monkeypatch):
+    """The programs of ``smallthinker-21b-a3b.long-stream`` for the described
+    chip, one period of layers: the 512-row prefill chunk (``all-experts``:
+    three ``q40_mm_experts`` launches a layer; the window layers' ring walk,
+    the full layer's live walk) and the 16-step decode chunk (``select``: 6
+    experts x 3 launches a layer).  No Q40 site takes the XLA path, the rings
+    are 4608 positions beside full planes of 16384, and neither kind of plane
+    is copied whole."""
+    import re
+
+    from dllama_tpu.models import transformer as tf
+    from dllama_tpu.obs import dispatch as obs_dispatch
+    from dllama_tpu.runtime.decode_loop import decode_chunk
+
+    cfg, params, cache, s = _smallthinker_programs(one_chip, monkeypatch)
+    assert cfg.prefill_chunk() == 512
+    assert cache.k.shape == (1, 1, 4, 16384, 128) and cache.wk.shape == (3, 1, 4, 4608, 128)
+    obs_dispatch.reset()
+    try:
+        prefill = jax.jit(
+            lambda p, c, tok, pos, last: tf.forward_last(p, cfg, tok, c, pos, last),
+            donate_argnums=(1,)).lower(
+            params, cache, s((1, 512), jnp.int32), s((), jnp.int32),
+            s((), jnp.int32)).compile().as_text()
+        sites_prefill = obs_dispatch.dispatches()
+        obs_dispatch.reset()
+        decode = jax.jit(
+            lambda p, c, tok, pos, k: decode_chunk(
+                p, cfg, c, tok, pos, k, steps=16, temperature=0.0, topp=0.9),
+            donate_argnums=(1,)).lower(
+            params, cache, s((1,), jnp.int32), s((), jnp.int32),
+            s((2,), jnp.uint32)).compile().as_text()
+        sites_decode = obs_dispatch.dispatches()
+    finally:
+        obs_dispatch.reset()
+    assert sites_prefill.get("moe/all-experts") == 4 and "moe/scan" not in sites_prefill
+    assert sites_decode.get("moe/select") == 4, sites_decode
+    for sites in (sites_prefill, sites_decode):
+        assert sites.get("attn/window-walk") == 3 and sites.get("attn/live-walk") == 1
+        assert "q40/xla-dequant" not in sites, sites
+    ops = re.findall(r"^\s*(?:ROOT )?%?[\w.\-]+ = \S+ ([\w\-]+)\(.*?"
+                     r"op_name=\"([^\"]+)\"", decode, re.M)
+    calls = [path for op, path in ops if op == "custom-call"
+             and "pallas_call" in path and "/moe/experts/" in path]
+    assert len(calls) == 4 * 6 * 3, len(calls)
+    for text in (prefill, decode):
+        assert any("/attn/window/" in path for _, path in re.findall(
+            r"(\w+)\(.*?op_name=\"([^\"]+)\"", text))
+        for plane in ("bf16[1,1,4,16384,128]", "bf16[3,1,4,4608,128]"):
+            assert plane in text
+            assert not re.search(r"= " + re.escape(plane) + r"\S* copy\(", text), plane

@@ -126,7 +126,11 @@ def plan(cfg, tp=1, sp=1, dp=1, ep=1, seq_len=None, batch=1,
                 decode_read += n * per_w
     # a GQA cache holds 2 x kv heads x head size a token a layer; a latent
     # (MLA) cache one latent and one rotated key, and has no head axis for tp
-    cache = cfg.n_layers * batch * s * cfg.kv_values_per_token * kv_bytes
+    # and a windowed model's window layers a ring of the window plus one
+    # prefill chunk, whatever ``s`` is (models/windowed.py)
+    positions = cfg.n_full_layers * s + (
+        cfg.n_window_layers * cfg.window_ring(s) if cfg.window else 0)
+    cache = positions * batch * cfg.kv_values_per_token * kv_bytes
     cache /= (1 if cfg.is_mla else tp) * sp * max(dp, 1)  # kv heads /tp, seq /sp, batch /dp
     per_chip = w_sharded + w_repl + cache + OVERHEAD
     return {
