@@ -11,8 +11,9 @@ Q40 weights:
   server   ``python -m dllama_tpu.server.api`` with a paged slot scheduler:
            concurrent completions, a streamed chat, /metrics, SIGTERM drain
   moe      the mixture-of-experts path at OLMoE-1B-7B's widths and 2 layers:
-           a seeded .m through the loader, ``moe_ffn``'s select strategy at 1
-           row and its all-experts launches (``q40_mm_experts``, 64 packed
+           a seeded .m through the loader, ``moe_ffn``'s one launch over the
+           row's chosen experts (``q40_mm_chosen``) at 1 row against the XLA
+           loop, and its all-experts launches (``q40_mm_experts``, 64 packed
            experts) at 16 and 256 rows against the XLA-dequantized scan, then
            the same paged server on that file
 
@@ -158,20 +159,21 @@ def phase_kernels(timeout: float, rehearse: bool = False) -> dict:
 
 def phase_moe(mpath: str, timeout: float, rehearse: bool = False) -> dict:
     """Child: ``moe_ffn`` on a loaded file, kernel path against the XLA
-    path, at one row (select) and at 16 and 256 (all-experts; the XLA path's
-    form of it is the scan)."""
+    path, at one row (select-chosen; the XLA path's form of it is select) and
+    at 16 and 256 (all-experts; the XLA path's form of it is the scan)."""
     rc, out = run_child("moe", ["--model", mpath], timeout, rehearse)
     rows, comp = _results(out, "moe")
     for r in rows:
         emit(dict(r, phase="moe"))
     require(rc == 0, f"moe: child exited {rc}")
     errs = {(r["strategy"], r["rows"]): r for r in rows if "rel_err" in r}
-    require(set(errs) == {("select", 1), ("all-experts", 16), ("all-experts", 256)},
-            f"moe: compared {sorted(errs)}")
+    require(set(errs) == {("select-chosen", 1), ("all-experts", 16),
+                          ("all-experts", 256)}, f"moe: compared {sorted(errs)}")
     bad = [r for r in errs.values() if not r["rel_err"] <= r["tol"]]
     require(not bad, f"moe: above tolerance: {bad}")
     ledger = next(r for r in rows if r.get("what") == "ledger")["ledger"]
-    require(all(f"moe/{p}" in ledger for p in ("select", "all-experts", "scan")),
+    require(all(f"moe/{p}×" in ledger for p in
+                ("select-chosen", "select", "all-experts", "scan")),
             f"moe: strategies absent from the ledger: {ledger}")
     if not rehearse:
         require("q40/pallas-fused" in ledger and "DEGRADED" not in ledger,
@@ -473,16 +475,18 @@ def child_kernels(rehearse: bool) -> None:
                 "non-finite kernel output")
         return float(np.abs(a_ - b_).max() / max(np.abs(b_).max(), 1e-9))
 
-    for name, n, d, stacked in shapes:
+    def random_q40(k1, k2, lead, n, d):
         np_ = q40.padded_n(n)
-        lead = (2,) if stacked else ()
-        key, k1, k2, k3 = jax.random.split(key, 4)
         qp = jax.random.bits(k1, (*lead, np_ // 2, d), jnp.uint8)
         sc = (0.004 + 0.008 * jax.random.uniform(k2, (*lead, np_ // 32, d))
               ).astype(jnp.float16)
         sc = sc * (jnp.arange(np_ // 32)[:, None] < n // 32)  # zero pad rows
-        qt = q40.QTensor(qp, jax.lax.bitcast_convert_type(sc, jnp.uint16),
-                         (n, d))
+        return q40.QTensor(qp, jax.lax.bitcast_convert_type(sc, jnp.uint16),
+                           (n, d))
+
+    for name, n, d, stacked in shapes:
+        key, k1, k2, k3 = jax.random.split(key, 4)
+        qt = random_q40(k1, k2, (2,) if stacked else (), n, d)
         w = q40.QLayerView(qt, jnp.int32(1)) if stacked else qt
         for rows in (1, 8, 256):  # 256: the row-blocked form
             x = jax.random.normal(jax.random.fold_in(k3, rows), (rows, n),
@@ -494,6 +498,25 @@ def child_kernels(rehearse: bool) -> None:
                   "stacked": stacked, "rel_err": rel_err(got, ref),
                   "tol": Q40_TOL,
                   "seconds": round(time.perf_counter() - t0, 2)})
+
+    # a decoded row's chosen experts in one launch (q40_mm_chosen), at
+    # SmallThinker's gate (6 of a layer's 64 experts of 2560 x 768, one row;
+    # the stack's last plane and a repeat among them), against one XLA
+    # matmul an expert
+    n, d, experts = (64, 96, 8) if rehearse else (2560, 768, 64)
+    key, k1, k2, k3 = jax.random.split(key, 4)
+    view = q40.QLayerView(random_q40(k1, k2, (2, experts), n, d), jnp.int32(1))
+    chosen = jnp.asarray([experts - 1, 0, 5, 3, experts - 1, 2], jnp.int32)
+    x1 = jax.random.normal(k3, (1, n), jnp.bfloat16)
+    t0 = time.perf_counter()
+    got = q40.matmul_experts(x1, view, experts, pallas, out_dtype=jnp.float32,
+                             chosen=chosen)
+    ref = jnp.stack([q40.matmul(x1, view.select(e, experts), impl="xla",
+                                out_dtype=jnp.float32) for e in chosen])
+    _say({"kernel": "q40.chosen_experts", "shape": [n, d], "rows": 1,
+          "experts": experts, "chosen": len(chosen),
+          "rel_err": rel_err(got, ref), "tol": Q40_TOL,
+          "seconds": round(time.perf_counter() - t0, 2)})
 
     # the auto choice inside a jit trace must be the Pallas kernel on a TPU
     # (w, x: the last pair of the loop above — wcls, 8 rows)
@@ -629,7 +652,8 @@ def child_moe(argv: list[str], rehearse: bool) -> None:
           "experts": cfg.n_experts, "active": cfg.n_active_experts,
           "dim": cfg.dim, "expert_width": cfg.hidden_dim})
     obs_dispatch.reset()
-    for rows, strategy in ((1, "select"), (16, "all-experts"), (256, "all-experts")):
+    for rows, strategy in ((1, "select-chosen"), (16, "all-experts"),
+                           (256, "all-experts")):
         x = jax.random.normal(jax.random.PRNGKey(rows), (rows, cfg.dim), dtype)
         t0 = time.perf_counter()
         got = jax.jit(lambda v: moe_ffn(v, lp, cfg.with_(

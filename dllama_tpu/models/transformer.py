@@ -426,16 +426,21 @@ def _routed_experts(xb2d: jax.Array, lp, cfg: ModelConfig,
     ``experts`` (the expert matmuls and, on the scan, its bookkeeping),
     ``combine`` (the weighted sum and the cast to the activation dtype).  Each
     compiled call site records its strategy in the dispatch ledger as
-    ``{codec="moe", path="select"|"all-experts"|"scan"|"unrolled"|"dense"}``.
+    ``{codec="moe", path="select"|"select-chosen"|"all-experts"|"scan"|"unrolled"|
+    "dense"}``.
 
     Execution strategies, chosen statically (token count, packed or not,
     mesh, kernel path; no flag):
-    * up to 4 tokens (``select``): compute only the k selected experts — with
-      packed-Q40 experts each (token, k) pair runs the fused dequant-
-      matmul on a ``QLayerView`` whose flat index selects the expert, so
-      HBM reads are bounded by the k active experts' *packed* bytes
-      (the reference likewise keeps MoE Q40 end-to-end,
-      transformer.cpp:299-317); dense experts use a gather + einsum.
+    * up to 4 tokens: compute only the k selected experts, so HBM reads are
+      bounded by the k active experts' *packed* bytes (the reference likewise
+      keeps MoE Q40 end-to-end, transformer.cpp:299-317).  Packed Q40 experts
+      on one device with the fused kernel chosen (``q40.all_experts_impl``;
+      every one-stream decode step on a TPU) take ``select-chosen``: a row's
+      k planes are a grid axis of ``q40_mm_chosen``, gate, up and down one
+      launch each, then one weighted sum over k.  With ``quant_impl="xla"``,
+      on any mesh, and with Q80 experts (``select``) each (token, slot) pair
+      runs the fused dequant-matmul on a ``QLayerView`` whose flat index
+      selects the expert; dense experts use a gather + einsum.
     * more tokens: run every expert and mask — regular shapes on the MXU.
       Packed Q40 experts on one device with the fused kernel chosen
       (``q40.all_experts_impl``; every served step and prefill on a TPU) take
@@ -481,11 +486,27 @@ def _routed_experts(xb2d: jax.Array, lp, cfg: ModelConfig,
     quant = isinstance(lp["up"], (q40.QTensor, q40.QLayerView))
 
     if n <= 4 and quant:
-        # decode, packed experts: per-(token, slot) fused matmuls on the
-        # selected expert's packed planes
-        obs_dispatch.record_dispatch("moe", "select", rows=n, experts=e)
-        outs = []
-        for i in range(n):
+        # decode, packed experts: each row runs its k chosen experts alone
+        views = (lp["gate"], lp["up"], lp["down"])
+        kernel = q40.all_experts_impl(views, 1, cfg.quant_impl)
+        obs_dispatch.record_dispatch(
+            "moe", "select-chosen" if kernel else "select", rows=n, experts=e)
+
+        def chosen_row(i):
+            # one device, the fused kernel: the row's chosen planes are a grid
+            # axis, three launches whatever k
+            xi, idx = xb2d[i:i + 1], top_idx[i]
+            with part("experts"):
+                g, u = (q40.matmul_experts(xi, w, e, kernel, chosen=idx)
+                        for w in views[:2])
+                o = q40.matmul_experts(act(g) * u, views[2], e, kernel,
+                                       out_dtype=jnp.float32, chosen=idx)  # (k, 1, D)
+            with part("combine"):
+                return (weights[i][:, None, None] * o).sum(0)
+
+        def looped_row(i):
+            # a mesh, Q80 experts, the XLA path: per-(token, slot) matmuls on
+            # the selected expert's packed planes
             xi = xb2d[i:i + 1]
             acc = jnp.zeros((1, d), jnp.float32)
             for j in range(k):
@@ -499,7 +520,9 @@ def _routed_experts(xb2d: jax.Array, lp, cfg: ModelConfig,
                                out_dtype=jnp.float32)
                 with part("combine"):
                     acc = acc + weights[i, j] * o
-            outs.append(acc)
+            return acc
+
+        outs = [(chosen_row if kernel else looped_row)(i) for i in range(n)]
         with part("combine"):
             return jnp.concatenate(outs, 0).astype(cfg.dtype)
 

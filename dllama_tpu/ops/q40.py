@@ -30,10 +30,11 @@ Two matmul implementations:
   to ``PALLAS_MAX_ROWS`` rows ride in one block (decode), more rows (the
   served mixed step, prefill buckets) in row blocks of up to
   ``ROW_BLOCK_MAX`` (:func:`_row_block`), each weight tile unpacked once
-  per block.  A mixture-of-experts layer's E experts are one launch a
-  matmul (:func:`matmul_experts`, ``q40_mm_experts``): the expert index is
-  a grid axis of the same kernel.  A `pallas_call` is not auto-partitioned
-  by GSPMD, so on a multi-device mesh it runs **per shard under
+  per block.  A mixture-of-experts layer's E experts, or the k a decoded
+  row chose, are one launch a matmul (:func:`matmul_experts`,
+  ``q40_mm_experts`` / ``q40_mm_chosen``): the expert index is a grid axis
+  of the same kernel.  A `pallas_call` is not auto-partitioned by GSPMD, so
+  on a multi-device mesh it runs **per shard under
   ``jax.shard_map``** (see :func:`_sharded_matmul`): the caller declares the
   weight's TP slicing ``kind`` — ``"row"`` (output dim sharded, the
   reference's RowMatmulSlice, commands.cpp:8-40: no communication) or
@@ -441,15 +442,16 @@ def _row_block(t: int, tile_n: int, tile_d: int) -> int | None:
 
 def _mm_call(t: int, n: int, d: int, tile_n: int, tile_d: int,
              stacked: bool, row_block: int | None, experts: int = 0,
-             x_per_expert: bool = False, **ms):
+             x_per_expert: bool = False, chosen: bool = False, **ms):
     """What the three kernels share of their ``pallas_call``: grid and specs
     (as keywords), compiler parameters, and the kernel's own keywords.  The
     grid is ``(d tiles, n steps)`` with every row in the block up to
     PALLAS_MAX_ROWS, else ``(row blocks, d tiles, n steps)``.  With
     ``experts`` the expert index is one more parallel axis in front of the d
-    tiles: the weight's plane is ``layer * experts + e`` of the flat stack,
-    the output is ``(experts, t, d)``, and the activations are one
-    ``(t, n/2)`` pair for every expert (its index map ignores ``e``) or, with
+    tiles: the weight's plane is ``layer * experts + e`` of the flat stack
+    (with ``chosen``, entry ``e`` of the prefetched vector of planes), the
+    output is ``(experts, t, d)``, and the activations are one ``(t, n/2)``
+    pair for every expert (its index map ignores ``e``) or, with
     ``x_per_expert``, ``(experts, t, n/2)``."""
     tr = row_block or _row_block(t, tile_n, tile_d)
     nd, nn = pl.cdiv(d, tile_d), n // tile_n
@@ -467,7 +469,9 @@ def _mm_call(t: int, n: int, d: int, tile_n: int, tile_d: int,
             return f(r, e, *g)
         return index_map
 
-    def plane(e, l):  # the stack's leading index, from the prefetched layer
+    def plane(e, l):  # the stack's leading index, from what was prefetched
+        if chosen:
+            return (l[0][e],)
         return (l[0][0] * experts + e,) if experts else tuple(ref[0] for ref in l)
 
     # an expert axis of a block is squeezed (None): the kernel sees 2-D refs
@@ -557,32 +561,42 @@ def _pallas_matmul_experts(x: jax.Array, qpacked: jax.Array, scales: jax.Array,
                            layer: jax.Array, experts: int,
                            interpret: bool = False,
                            tiles: tuple[int, int] | None = None,
-                           row_block: int | None = None) -> jax.Array:
-    """Every expert of one layer in one launch: ``x`` against planes
+                           row_block: int | None = None,
+                           chosen: jax.Array | None = None) -> jax.Array:
+    """Experts of one layer in one launch: ``x`` against planes
     ``layer * experts + e`` of the flat ``(L * experts, n/2, d)`` stack, for
-    ``e`` in ``range(experts)`` → ``(experts, t, d)`` f32.
+    every ``e`` in ``range(experts)`` → ``(experts, t, d)`` f32
+    (``q40_mm_experts``), or for the ``k`` traced indices ``chosen`` alone →
+    ``(k, t, d)`` (``q40_mm_chosen``: one row's routed experts; an index may
+    repeat).
 
     ``x`` is ``(t, n)``, shared by all experts (gate, up), or
-    ``(experts, t, n)``, one activation block an expert (down).  The expert
-    index is a grid axis (:func:`_mm_call`), so what was ``experts`` launches
-    of :func:`_pallas_matmul_stacked` from a traced loop is one, with the
-    same tile math, and ``_x_parts`` runs once on the whole activation.  All
-    ``experts`` planes are read whatever the router chose."""
+    ``(experts | k, t, n)``, one activation block an expert (down).  The
+    expert index is a grid axis (:func:`_mm_call`), so what was a launch of
+    :func:`_pallas_matmul_stacked` an expert from a traced loop is one, with
+    the same tile math, and ``_x_parts`` runs once on the whole activation.
+    Only the planes walked are read: all ``experts`` whatever the router
+    chose, or the ``k`` chosen, whose planes ride in as a prefetched vector
+    where the all-experts form needs the layer alone."""
     t, n = x.shape[-2:]
     d = qpacked.shape[-1]
     tile_n, tile_d = tiles or _tiles(n, d)
+    if chosen is None:
+        k, planes, name = experts, layer.reshape(1), "q40_mm_experts"
+    else:
+        k, planes, name = len(chosen), layer * experts + chosen, "q40_mm_chosen"
     grid_kw, params, kernel_kw = _mm_call(
-        t, n, d, tile_n, tile_d, True, row_block, experts=experts,
-        x_per_expert=x.ndim == 3)
+        t, n, d, tile_n, tile_d, True, row_block, experts=k,
+        x_per_expert=x.ndim == 3, chosen=chosen is not None)
     return pl.pallas_call(
         functools.partial(_stacked_q40_kernel, **kernel_kw),
         grid_spec=pltpu.PrefetchScalarGridSpec(num_scalar_prefetch=1,
                                                **grid_kw),
-        out_shape=jax.ShapeDtypeStruct((experts, t, d), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((k, t, d), jnp.float32),
         compiler_params=params,
         interpret=interpret,
-        name="q40_mm_experts",
-    )(layer.reshape(1).astype(jnp.int32), *_x_parts(x.astype(jnp.bfloat16)),
+        name=name,
+    )(planes.astype(jnp.int32), *_x_parts(x.astype(jnp.bfloat16)),
       qpacked, scales)
 
 
@@ -978,17 +992,21 @@ def all_experts_impl(views, rows: int, impl: str) -> str | None:
 
 
 def matmul_experts(x: jax.Array, qt: QLayerView, experts: int, impl: str,
-                   out_dtype=None) -> jax.Array:
-    """``x @ dequantize(expert e of the view's layer)`` for every ``e``, in
-    one launch of the fused kernel on one device (``impl`` from
-    :func:`all_experts_impl`): ``x`` ``(t, n)`` shared or ``(experts, t, n)``
-    → ``(experts, t, d)``.  The view's ``layer`` indexes the lead dims in
-    front of the expert axis."""
+                   out_dtype=None, chosen: jax.Array | None = None) -> jax.Array:
+    """``x @ dequantize(expert e of the view's layer)`` in one launch of the
+    fused kernel on one device (``impl`` from :func:`all_experts_impl`), for
+    every ``e``: ``x`` ``(t, n)`` shared or ``(experts, t, n)`` →
+    ``(experts, t, d)``; or, with ``chosen`` ``(k,)`` traced indices, for those
+    alone: ``x`` shared or ``(k, t, n)`` → ``(k, t, d)``, only their planes
+    read.  The view's ``layer`` indexes the lead dims in front of the expert
+    axis."""
     x = _pad_x(x, qt.logical_nd[0], qt.qt.qpacked.shape[-2] * 2)
-    obs_dispatch.record_dispatch("q40", "pallas-fused", rows=x.shape[-2], experts=experts,
-                                 **_site(x.shape[-1], qt.logical_nd[1], None, 1))
+    obs_dispatch.record_dispatch(
+        "q40", "pallas-fused", rows=x.shape[-2],
+        experts=experts if chosen is None else len(chosen),
+        **_site(x.shape[-1], qt.logical_nd[1], None, 1))
     out = _pallas_matmul_experts(x, *qt.flat_planes(), qt.layer,
-                                 experts=experts,
+                                 experts=experts, chosen=chosen,
                                  interpret=impl == "pallas_interpret")
     return out.astype(out_dtype or x.dtype)
 

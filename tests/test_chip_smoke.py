@@ -76,10 +76,12 @@ def test_rehearse_kernels_phase(rehearsal_env, capfd):
     assert dev["platform"] == "cpu"
     rows = [json.loads(ln) for ln in capfd.readouterr().out.splitlines()]
     errs = [r for r in rows if "rel_err" in r]
-    # five shapes × rows {1, 8, 256} + dense/int8 fused attention + the fused
-    # walk at a chunk's 16 tokens a slot, two head geometries + the live walk
-    # at prefill rows, dense/int8 × two positions
-    assert len(errs) == 23
+    # five shapes × rows {1, 8, 256} + a row's chosen experts in one launch +
+    # dense/int8 fused attention + the fused walk at a chunk's 16 tokens a
+    # slot, two head geometries + the live walk at prefill rows, dense/int8 ×
+    # two positions
+    assert len(errs) == 24
+    assert [r["chosen"] for r in errs if r["kernel"] == "q40.chosen_experts"] == [6]
     assert [r["geometry"]["heads"] for r in errs if r.get("t") == 16] == \
         ["mistral-7b", "olmoe-1b-7b"]
     assert all(r["rel_err"] <= r["tol"] for r in errs)
@@ -108,7 +110,7 @@ def test_rehearse_tp_phase_on_virtual_devices(rehearsal_env, capfd):
 
 def test_rehearse_moe_phases(rehearsal_env, capfd):
     """The mixture-of-experts pass: a seeded OLMoE-shaped file (64 experts, 8
-    a token, toy widths) through the loader, ``moe_ffn``'s select and
+    a token, toy widths) through the loader, ``moe_ffn``'s select-chosen and
     all-experts strategies against the XLA path (whose many-row form is the
     scan), then the paged server on that file (on the CPU: the XLA path)."""
     from dllama_tpu.io import mfile
@@ -122,7 +124,8 @@ def test_rehearse_moe_phases(rehearsal_env, capfd):
     chip_smoke.phase_moe(m, 600, rehearse=True)
     rows = [json.loads(ln) for ln in capfd.readouterr().out.splitlines()]
     errs = {(r["strategy"], r["rows"]): r for r in rows if "rel_err" in r}
-    assert set(errs) == {("select", 1), ("all-experts", 16), ("all-experts", 256)}
+    assert set(errs) == {("select-chosen", 1), ("all-experts", 16),
+                         ("all-experts", 256)}
     assert all(r["rel_err"] <= r["tol"] for r in errs.values())
     res = chip_smoke.phase_server(m, t, 600, tmp, slots=2, ctx=64, page=4,
                                   max_tokens=8, rehearse=True)

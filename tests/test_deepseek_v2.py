@@ -339,6 +339,8 @@ def _moe_case(experts, groups, kept, k, packed, seed=7):
 
 @pytest.mark.parametrize("rows,experts,groups,kept,k,packed,impl,path", [
     (2, 32, 8, 3, 6, True, "xla", "select"),
+    (1, 32, 8, 3, 6, True, "pallas_interpret", "select-chosen"),
+    (4, 32, 8, 3, 6, True, "pallas_interpret", "select-chosen"),
     (2, 32, 8, 3, 6, False, "xla", "select"),
     (6, 32, 8, 3, 6, True, "pallas_interpret", "all-experts"),
     (6, 32, 8, 3, 6, True, "xla", "scan"),

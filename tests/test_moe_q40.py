@@ -202,6 +202,45 @@ def test_ep_non_owner_shards_skip_expert_reads():
                                rtol=0, atol=1e-2 * float(np.abs(ref).max()))
 
 
+@pytest.mark.parametrize("rows", [1, 3])
+def test_the_chosen_launch_reads_the_chosen_experts_alone(rows):
+    """The poison of the test above through ``moe_ffn``'s one-launch form
+    (``select-chosen``): in all three stacks every expert that no row routes
+    to carries NaN scale bits, which the kernel's f16 decode turns into
+    weights of ~1e5.  The output is bit for bit what the clean stacks give,
+    so the k planes a launch walks are the router's and no other."""
+    from dllama_tpu.models.transformer import moe_ffn
+    from dllama_tpu.obs import dispatch as obs_dispatch
+
+    cfg = tiny_config(arch=mfile.ARCH_OLMOE, n_experts=16, n_active_experts=4,
+                      n_layers=3).with_(quant_impl="pallas_interpret")
+    p = quantize_matmuls(init_params(cfg, seed=6, scale=0.2), cfg)
+    layer = 2
+    x = np.random.RandomState(rows).randn(rows, cfg.dim).astype(np.float32)
+    router = np.asarray(p["router"][layer], np.float32)
+    routed = np.unique(np.argsort(-(x @ router), axis=-1)[:, :cfg.n_active_experts])
+    assert 0 < len(routed) < cfg.n_experts
+
+    def lp_of(poison):
+        lp = {"router": jnp.asarray(router)}
+        for key in ("up", "gate", "down"):
+            scales = np.asarray(p[key].scales).copy()
+            if poison:
+                keep = scales[layer, routed].copy()
+                scales[:] = np.uint16(0x7e00)  # f16 NaN bits, every layer
+                scales[layer, routed] = keep
+            lp[key] = q40.QLayerView(q40.QTensor(p[key].qpacked, jnp.asarray(scales),
+                                                 p[key].logical_nd), jnp.int32(layer))
+        return lp
+
+    before = obs_dispatch.dispatches().get("moe/select-chosen", 0)
+    clean = np.asarray(moe_ffn(jnp.asarray(x), lp_of(False), cfg))
+    poisoned = np.asarray(moe_ffn(jnp.asarray(x), lp_of(True), cfg))
+    assert obs_dispatch.dispatches()["moe/select-chosen"] == before + 2
+    assert np.isfinite(poisoned).all() and np.abs(poisoned).max() < 1e3
+    np.testing.assert_array_equal(poisoned, clean)
+
+
 def test_tp8_quantized_moe_matches_tp1():
     """N-shard ≡ 1-shard with packed experts on the pallas-interpret
     shard_map path (shard-clean shapes)."""

@@ -297,7 +297,7 @@ _OP_NAME = re.compile(r"op_name=\"([^\"]+)\"")
 @pytest.mark.parametrize("rows,experts,packed,path", [
     (1, 16, True, "select"), (6, 16, True, "scan"), (6, 4, True, "unrolled"),
     (6, 16, False, "dense"), (1, 16, False, "select"),
-    (6, 16, True, "all-experts")])
+    (6, 16, True, "all-experts"), (1, 16, True, "select-chosen")])
 def test_moe_parts_are_named_and_the_ledger_records_the_strategy(rows, experts,
                                                                  packed, path):
     """Every strategy of ``moe_ffn`` names its work ``moe/router``,
@@ -307,9 +307,9 @@ def test_moe_parts_are_named_and_the_ledger_records_the_strategy(rows, experts,
     from dllama_tpu.obs import dispatch as obs_dispatch
     from dllama_tpu.ops.scopes import PARTS, SCOPES, part
 
-    # the kernel path (interpret mode here) takes every expert in one launch;
-    # the XLA path keeps the loop over experts
-    impl = "pallas_interpret" if path == "all-experts" else "xla"
+    # the kernel path (interpret mode here) takes every expert, or a row's
+    # chosen ones, in one launch; the XLA path keeps the loops over experts
+    impl = "pallas_interpret" if path in ("all-experts", "select-chosen") else "xla"
     cfg = tiny_config(arch=mfile.ARCH_OLMOE, n_experts=experts,
                       n_active_experts=2, n_layers=1).with_(quant_impl=impl)
     params = init_params(cfg, seed=3)

@@ -383,12 +383,13 @@ def test_kernel_matches_xla_at_the_rules_new_tiles(form, n, d):
 
 
 # First line of every function of ops/q40.py that is a frame while a Q40
-# kernel is traced, as at PR 33 (commit fe8bd20).
+# kernel is traced, as at PR 39 (the chosen form of the experts launch grew
+# _mm_call and _pallas_matmul_experts; as at PR 33, commit fe8bd20, before).
 KERNEL_PATH_LINES = {
-    "_q40_kernel": 344, "_stacked_q40_kernel": 387, "_x_parts": 393,
-    "_mm_call": 442, "_pallas_matmul": 498, "_pallas_matmul_stacked": 522,
-    "_pallas_matmul_experts": 554, "_pad_x": 634, "_sharded_matmul": 779,
-    "_sharded_matmul_ep": 852, "matmul_experts": 980, "matmul": 996, "mm": 1063}
+    "_q40_kernel": 345, "_stacked_q40_kernel": 388, "_x_parts": 394,
+    "_mm_call": 443, "_pallas_matmul": 502, "_pallas_matmul_stacked": 526,
+    "_pallas_matmul_experts": 558, "_pad_x": 648, "_sharded_matmul": 793,
+    "_sharded_matmul_ep": 866, "matmul_experts": 994, "matmul": 1014, "mm": 1081}
 
 
 def test_the_kernels_trace_path_kept_its_lines():

@@ -1,11 +1,13 @@
-"""The all-experts launch of the Q40 kernel (``q40_mm_experts``) and its one
-caller, ``moe_ffn``'s ``all-experts`` strategy.
+"""The experts launches of the Q40 kernel, all of a layer (``q40_mm_experts``)
+and a row's chosen ones (``q40_mm_chosen``), and their one caller, ``moe_ffn``'s
+``all-experts`` and ``select-chosen`` strategies.
 
 CPU, ``pallas_interpret``.  The kernel's contract is bit equality with one
 ``q40_mm_stacked`` call an expert: the expert index moved from a traced loop
-into the grid, the tile math did not move.  ``moe_ffn`` on the new path is
+into the grid, the tile math did not move.  ``moe_ffn`` on the new paths is
 compared with ``quant_impl="xla"`` (the scan / unrolled loop) within the
-tolerance test_moe_q40 uses for quantized-against-dense.
+tolerance test_moe_q40 uses for quantized-against-dense, and with a float32
+loop over the chosen experts at the weights the packed tensors hold.
 """
 
 import hashlib
@@ -15,13 +17,15 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import reference_impl as ref
 from dllama_tpu.io import mfile
-from dllama_tpu.models.config import tiny_config
+from dllama_tpu.models.config import tiny_config, tiny_deepseek2, tiny_smallthinker
 from dllama_tpu.models.params import init_params, quantize_matmuls
-from dllama_tpu.models.transformer import forward, init_kv_cache
+from dllama_tpu.models.transformer import forward, init_kv_cache, moe_ffn
 from dllama_tpu.obs import dispatch as obs_dispatch
-from dllama_tpu.ops import q40
+from dllama_tpu.ops import q8, q40
 from dllama_tpu.parallel.mesh import active_mesh, make_mesh
+from dllama_tpu.runtime.engine import Engine
 
 LAYERS, LAYER = 3, 2  # the layer index read is > 0
 
@@ -135,8 +139,6 @@ def test_the_static_rule_that_takes_the_all_experts_path(impl, rows, want):
 
 
 def test_a_mesh_and_q80_experts_keep_the_loop_over_experts():
-    from dllama_tpu.ops import q8
-
     views = _views()
     with active_mesh(make_mesh(tp=2)):
         assert q40.all_experts_impl(views, 16, "pallas_interpret") is None
@@ -157,6 +159,11 @@ MOE = {
 }
 
 
+def _moe_sites(before):
+    after = obs_dispatch.dispatches()
+    return {k for k in after if k.startswith("moe/") and after[k] > before.get(k, 0)}
+
+
 @pytest.mark.parametrize("rows", [5, 16])
 @pytest.mark.parametrize("name", sorted(MOE))
 def test_moe_ffn_all_experts_matches_the_xla_strategies(name, rows):
@@ -170,10 +177,8 @@ def test_moe_ffn_all_experts_matches_the_xla_strategies(name, rows):
         before = obs_dispatch.dispatches()
         out, _ = forward(params, cfg.with_(quant_impl=impl), tokens,
                          init_kv_cache(cfg, 1), jnp.int32(0))
-        after = obs_dispatch.dispatches()
         logits[impl] = np.asarray(out)
-        sites[impl] = {k for k in after if k.startswith("moe/")
-                       and after[k] > before.get(k, 0)}
+        sites[impl] = _moe_sites(before)
     assert sites == {"xla": {"moe/" + xla_path}, "pallas_interpret": {"moe/all-experts"}}
     ref = logits["xla"]
     np.testing.assert_allclose(logits["pallas_interpret"], ref, rtol=0,
@@ -181,17 +186,258 @@ def test_moe_ffn_all_experts_matches_the_xla_strategies(name, rows):
     assert np.abs(logits["pallas_interpret"] - ref).max() < 1e-3 * np.abs(ref).max()
 
 
-def test_four_rows_still_select_and_the_experts_unread_stay_unread():
-    """Up to 4 rows ``select`` runs the k chosen experts only, whatever the
-    kernel path: the all-experts launch starts at 5 rows."""
+def test_four_rows_still_choose_and_the_all_experts_launch_starts_at_five():
+    """Up to 4 rows the k chosen experts alone are run (on the kernel path,
+    one launch a matmul a row: ``select-chosen``); more rows read them all."""
     cfg = tiny_config(arch=mfile.ARCH_OLMOE, n_experts=16, n_active_experts=4,
                       n_layers=1).with_(quant_impl="pallas_interpret")
     params = quantize_matmuls(init_params(cfg, seed=2), cfg)
+    for rows, path in ((4, "select-chosen"), (5, "all-experts")):
+        before = obs_dispatch.dispatches()
+        forward(params, cfg, jnp.zeros((1, rows), jnp.int32), init_kv_cache(cfg, 1),
+                jnp.int32(0))
+        assert _moe_sites(before) == {"moe/" + path}
+
+
+# ---- the chosen launch (q40_mm_chosen): a row's k routed experts ----------
+
+EXPERTS = 8
+# a repeated index, and at the last layer the last plane of the flat stack
+CHOSEN = (EXPERTS - 1, 0, EXPERTS - 1, 3)
+
+
+@pytest.mark.parametrize("per_expert", [False, True], ids=["shared-x", "x-an-expert"])
+@pytest.mark.parametrize("rows", [1, 4])
+@pytest.mark.parametrize("nd", [SMALL, RAGGED_D, TWO_N_STEPS], ids=str)
+def test_chosen_launch_equals_one_launch_an_expert_and_the_xla_reference(
+        nd, rows, per_expert):
+    n, d = nd
+    qt, rng = _stack(EXPERTS, n, d, seed=n + rows)
+    view = q40.QLayerView(qt, jnp.int32(LAYERS - 1))
+    qp, sc = view.flat_planes()
+    x = jnp.asarray(rng.standard_normal(
+        ((len(CHOSEN),) if per_expert else ()) + (rows, n)), jnp.bfloat16)
+    out = q40._pallas_matmul_experts(x, qp, sc, view.layer, experts=EXPERTS,
+                                     interpret=True, chosen=jnp.asarray(CHOSEN))
+    assert out.shape == (len(CHOSEN), rows, d) and out.dtype == jnp.float32
+    for j, e in enumerate(CHOSEN):
+        xe, one = x[j] if per_expert else x, view.select(jnp.int32(e), EXPERTS)
+        ref_k = q40._pallas_matmul_stacked(xe, qp, sc, one.layer, interpret=True)
+        np.testing.assert_array_equal(np.asarray(out[j]), np.asarray(ref_k), err_msg=str(j))
+        ref_x = np.asarray(q40.matmul(xe, one, impl="xla", out_dtype=jnp.float32))
+        np.testing.assert_allclose(np.asarray(out[j]), ref_x, rtol=0,
+                                   atol=1e-4 * np.abs(ref_x).max())
+    assert int(view.select(jnp.int32(CHOSEN[0]), EXPERTS).layer) == qp.shape[0] - 1
+
+
+@pytest.mark.parametrize("per_expert", [False, True], ids=["shared-x", "x-an-expert"])
+def test_chosen_launch_takes_traced_indices_inside_a_scan_over_layers(per_expert):
+    """The model's shape of the call: the layer is the scan's counter, the
+    chosen indices come out of a top-k of traced values."""
+    n, d, k = 64, 96, 3
+    qt, rng = _stack(EXPERTS, n, d, seed=5)
+    x = jnp.asarray(rng.standard_normal(((k,) if per_expert else ()) + (1, n)),
+                    jnp.bfloat16)
+    scores = jnp.asarray(rng.standard_normal((LAYERS, EXPERTS)), jnp.float32)
+
+    def body(_, xs):
+        layer, row = xs
+        idx = jax.lax.top_k(row, k)[1]
+        return None, (idx, q40.matmul_experts(
+            x, q40.QLayerView(qt, layer), EXPERTS, "pallas_interpret",
+            out_dtype=jnp.float32, chosen=idx))
+
+    _, (idx, out) = jax.jit(lambda sc: jax.lax.scan(
+        body, None, (jnp.arange(LAYERS, dtype=jnp.int32), sc)))(scores)
+    assert out.shape == (LAYERS, k, 1, d)
+    np.testing.assert_array_equal(np.asarray(idx), np.argsort(-np.asarray(scores))[:, :k])
+    for layer in range(LAYERS):
+        for j in range(k):
+            one = q40.QLayerView(qt, jnp.int32(layer)).select(idx[layer, j], EXPERTS)
+            want = q40.matmul(x[j] if per_expert else x, one, impl="pallas_interpret",
+                              out_dtype=jnp.float32)
+            np.testing.assert_array_equal(np.asarray(out[layer, j]), np.asarray(want))
+
+
+def test_chosen_launch_pads_the_input_dim_and_records_its_site(caplog):
+    import logging
+    n, d = 2752, 128  # stored as 3072 rows: _pad_x pads the activation
+    qt, rng = _stack(EXPERTS, n, d, seed=3)
+    view = q40.QLayerView(qt, jnp.int32(LAYER))
+    x = jnp.asarray(rng.standard_normal((1, n)), jnp.bfloat16)
+    with caplog.at_level(logging.DEBUG, logger="dllama"):
+        out = q40.matmul_experts(x, view, EXPERTS, "pallas_interpret",
+                                 out_dtype=jnp.float32, chosen=jnp.asarray(CHOSEN))
+    site = [r for r in caplog.records if getattr(r, "path", "") == "pallas-fused"][-1]
+    assert (site.rows, site.experts, site.stored_n) == (1, len(CHOSEN), 3072)
+    for j, e in enumerate(CHOSEN):
+        want = q40.matmul(x, view.select(jnp.int32(e), EXPERTS), impl="pallas_interpret",
+                          out_dtype=jnp.float32)
+        np.testing.assert_array_equal(np.asarray(out[j]), np.asarray(want))
+
+
+def test_chosen_launch_is_named_for_the_trace():
+    view = q40.QLayerView(_stack(EXPERTS, 64, 96, seed=1)[0], jnp.int32(0))
+    x = jnp.zeros((1, 64), jnp.bfloat16)
+    for name, other, chosen in (("q40_mm_chosen", "q40_mm_experts", jnp.asarray(CHOSEN)),
+                                ("q40_mm_experts", "q40_mm_chosen", None)):
+        text = str(jax.make_jaxpr(lambda c: q40.matmul_experts(
+            x, view, EXPERTS, "pallas_interpret", chosen=c))(chosen))
+        assert name in text and other not in text
+
+
+# ---- moe_ffn at 1, 2 and 4 rows on the chosen launch ----------------------
+
+TOYS = {
+    # unnormalised top-k of a softmax over all
+    "olmoe": lambda: tiny_config(arch=mfile.ARCH_OLMOE, n_experts=16,
+                                 n_active_experts=4, n_layers=1),
+    # renormalised top-2
+    "mixtral": lambda: tiny_config(arch=mfile.ARCH_MIXTRAL, n_experts=8,
+                                   n_active_experts=2, n_layers=1),
+    # 3 of 8 groups, top-6, scaled by 16, two shared experts
+    "deepseek2": lambda: tiny_deepseek2(n_layers=1, n_dense_layers=0),
+    # ReLU, 6 of 64 renormalised, the router's logits handed in
+    "smallthinker": lambda: tiny_smallthinker(n_layers=4),
+}
+ACTS = {mfile.ACT_GELU: ref.gelu_tanh, mfile.ACT_SILU: ref.silu,
+        mfile.ACT_RELU: ref.relu}
+MOE_KEYS = ("router", "up", "gate", "down", "shared_w1", "shared_w2", "shared_w3")
+
+
+def _toy_layer(name, codec=q40):
+    """Layer 0 of a toy's expert FFN: the packed views ``moe_ffn`` takes and,
+    for the reference, the float32 weights those tensors hold."""
+    cfg = TOYS[name]()
+    p = init_params(cfg, seed=7, scale=0.2)
+    lp_np = {k: np.asarray(p[k][0], np.float32) for k in MOE_KEYS if k in p}
+    lp = {"router": jnp.asarray(lp_np["router"])}
+    for k in ("up", "gate", "down"):
+        qt = codec.quantize(np.asarray(p[k], np.float32))
+        lp[k] = q40.QLayerView(qt, jnp.int32(0))
+        lp_np[k] = np.asarray(codec.dequantize(qt, jnp.float32))[0]
+    if "shared_w2" in lp_np:
+        qp = quantize_matmuls(p, cfg)
+        for k in ("shared_w13", "shared_w2"):
+            lp[k] = q40.QLayerView(qp[k], jnp.int32(0))
+        w13 = np.asarray(q40.dequantize(qp["shared_w13"]))[0]
+        lp_np["shared_w1"], lp_np["shared_w3"] = np.split(w13, 2, axis=-1)
+        lp_np["shared_w2"] = np.asarray(q40.dequantize(qp["shared_w2"]))[0]
+    return cfg, lp, lp_np
+
+
+def _moe_reference(x, logits, lp, cfg):
+    """A float32 loop over each row's chosen experts."""
+    act, k = ACTS[cfg.hidden_act], cfg.n_active_experts
+    probs = ref.softmax(logits.astype(np.float64))
+    out = np.zeros_like(x)
+    for i in range(len(x)):
+        if cfg.n_groups > 1:
+            idx, w = ref.grouped_choice(probs[i], cfg.n_groups, cfg.topk_groups, k)
+        else:
+            idx = np.argsort(-probs[i], kind="stable")[:k]
+            w = probs[i, idx]
+        if cfg.norm_topk_prob:
+            w = w / w.sum()
+        for wj, e in zip(w * cfg.routed_scale, idx):
+            h = act(x[i] @ lp["gate"][e]) * (x[i] @ lp["up"][e])
+            out[i] += wj * (h @ lp["down"][e])
+    if "shared_w2" in lp:
+        out += (act(x @ lp["shared_w1"]) * (x @ lp["shared_w3"])) @ lp["shared_w2"]
+    return out
+
+
+def _rows_and_logits(name, cfg, lp_np, rows):
+    rng = np.random.RandomState(rows)
+    x = rng.randn(rows, cfg.dim).astype(np.float32)
+    if name != "smallthinker":
+        return x, None, x @ lp_np["router"]
+    # its router reads the layer's input, not the FFN's: other rows
+    logits = rng.randn(rows, cfg.dim).astype(np.float32) @ lp_np["router"]
+    return x, jnp.asarray(logits), logits
+
+
+@pytest.mark.parametrize("rows", [1, 2, 4])
+@pytest.mark.parametrize("name", sorted(TOYS))
+def test_moe_ffn_on_the_chosen_launch_matches_the_float32_reference(name, rows):
+    """Compared at the weights the Q40 tensors hold, so what is left is the
+    bf16 rounding of activations inside the Q40 matmul: held to 3% of the
+    output's spread, as tests/test_deepseek_v2.py holds every strategy; the
+    loop of one launch an expert (``quant_impl="xla"``: ``select``) agrees to
+    a tenth of that."""
+    cfg, lp, lp_np = _toy_layer(name)
+    x, handed, logits = _rows_and_logits(name, cfg, lp_np, rows)
+    wanted = _moe_reference(x, logits, lp_np, cfg)
+    got = {}
+    for impl, path in (("pallas_interpret", "select-chosen"), ("xla", "select")):
+        before = obs_dispatch.dispatches()
+        got[impl] = np.asarray(moe_ffn(jnp.asarray(x), lp, cfg.with_(quant_impl=impl),
+                                       handed))
+        assert _moe_sites(before) == {"moe/" + path}
+    tol = 0.03 * wanted.std()
+    assert np.abs(got["pallas_interpret"] - wanted).max() < tol
+    assert np.abs(got["pallas_interpret"] - got["xla"]).max() < 0.1 * tol
+    wrong = _moe_reference(x, np.roll(logits, 1, axis=-1), lp_np, cfg)
+    assert np.abs(wrong - wanted).max() > 10 * tol  # other experts would be seen
+
+
+def test_q80_experts_keep_the_loop_and_the_ledger_says_select():
+    cfg, lp, lp_np = _toy_layer("olmoe", codec=q8)
+    x, _, logits = _rows_and_logits("olmoe", cfg, lp_np, 2)
     before = obs_dispatch.dispatches()
-    forward(params, cfg, jnp.zeros((1, 4), jnp.int32), init_kv_cache(cfg, 1), jnp.int32(0))
-    after = obs_dispatch.dispatches()
-    assert after.get("moe/select", 0) == before.get("moe/select", 0) + 1
-    assert after.get("moe/all-experts", 0) == before.get("moe/all-experts", 0)
+    got = np.asarray(moe_ffn(jnp.asarray(x), lp, cfg.with_(quant_impl="pallas_interpret")))
+    assert _moe_sites(before) == {"moe/select"}
+    wanted = _moe_reference(x, logits, lp_np, cfg)
+    assert np.abs(got - wanted).max() < 0.03 * wanted.std()
+
+
+def test_a_mesh_keeps_the_loop_and_the_ledger_says_select():
+    if len(jax.devices()) < 2:
+        pytest.skip("needs 2 devices")
+    cfg = tiny_config(arch=mfile.ARCH_MIXTRAL, n_experts=4, n_active_experts=2,
+                      dim=256, hidden_dim=256, n_layers=1, n_heads=8, n_kv_heads=8,
+                      vocab_size=128, seq_len=32).with_(quant_impl="pallas_interpret")
+    qparams = quantize_matmuls(init_params(cfg, seed=4), cfg)
+    logits, sites = {}, {}
+    for tp in (1, 2):
+        before = obs_dispatch.dispatches()
+        engine = Engine(cfg, qparams, mesh=make_mesh(tp=tp, devices=jax.devices()[:tp]))
+        logits[tp], _ = engine.decode_one(7)
+        sites[tp] = _moe_sites(before)
+    assert sites == {1: {"moe/select-chosen"}, 2: {"moe/select"}}
+    np.testing.assert_allclose(logits[1], logits[2], rtol=0,
+                               atol=1e-3 + 1e-3 * np.abs(logits[1]).max())
+
+
+# sha256 of str(jax.make_jaxpr(...)) of the all-experts launch on the parent of
+# the PR that gave the kernel its chosen form: (n, d, experts, layers, an
+# activation block an expert, rows) of OLMoE's, DeepSeek-V2's and
+# SmallThinker's gate and down at their cells' rows
+PARENT_EXPERTS_JAXPRS = {
+    (2048, 1024, 64, 16, False, 16): "7fa11787fb887b88",
+    (1024, 2048, 64, 16, True, 16): "425014c774e69a87",
+    (2048, 1024, 64, 16, False, 256): "3fcf24268c01afaa",
+    (1024, 2048, 64, 16, True, 256): "5af998f2357d095c",
+    (5120, 1536, 160, 4, False, 16): "9dc550a32a365f19",
+    (1536, 5120, 160, 4, True, 16): "3ee64141dca1b94b",
+    (2560, 768, 64, 52, False, 512): "f9e53e8f0110dc55",
+    (768, 2560, 64, 52, True, 512): "c89343558385b1b9",
+}
+
+
+@pytest.mark.parametrize("case", sorted(PARENT_EXPERTS_JAXPRS), ids=str)
+def test_all_experts_kernel_programs_are_the_parents(case):
+    """Grid, index maps, operands and body of ``q40_mm_experts`` as the three
+    cells that run it had them: the chosen form is a keyword beside it."""
+    n, d, experts, layers, per_expert, rows = case
+    s = jax.ShapeDtypeStruct
+    jaxpr = jax.make_jaxpr(lambda x, qp, sc, layer: q40._pallas_matmul_experts(
+        x, qp, sc, layer, experts=experts))(
+        s(((experts,) if per_expert else ()) + (rows, n), jnp.bfloat16),
+        s((layers * experts, n // 2, d), jnp.uint8),
+        s((layers * experts, n // 32, d), jnp.uint16), s((), jnp.int32))
+    assert hashlib.sha256(str(jaxpr).encode()).hexdigest()[:16] == \
+        PARENT_EXPERTS_JAXPRS[case]
 
 
 # ---- the dense cells' kernel programs did not move ------------------------

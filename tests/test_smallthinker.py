@@ -334,6 +334,32 @@ def test_loader_packed_and_dense_agree_with_the_reference(q40_file):
     assert np.median(worst) < 0.05 and (worst > 0.1).sum() <= 6, worst
 
 
+@pytest.mark.parametrize("t", [1, 2, 4])
+def test_a_decoded_block_on_the_chosen_launch_equals_the_loop(q40_file, t):
+    """One stream's step of ``t`` rows past a prompt of 20, every packed matmul
+    on the kernel (interpret mode): each row's six experts are one launch a
+    matrix (``select-chosen``, the router's logits handed in from the layer's
+    input), and the logits are those of the loop of one launch an expert on
+    the XLA path (``select``): the same roundings, another order of float32
+    sums."""
+    cfg, packed = load_params(mfile.MFile(q40_file), dtype=jnp.float32,
+                              keep_quantized=True)
+    toks = jnp.asarray(TOKS)[None]
+    _, cache = forward(packed, cfg.with_(quant_impl="xla"), toks[:, :20],
+                       init_kv_cache(cfg, 1), jnp.int32(0))
+    logits = {}
+    for impl, path in (("pallas_interpret", "select-chosen"), ("xla", "select")):
+        obs_dispatch.reset()
+        lg, _ = forward(packed, cfg.with_(quant_impl=impl), toks[:, 20:20 + t],
+                        cache, jnp.int32(20))
+        assert {k for k in obs_dispatch.dispatches() if k.startswith("moe/")} == \
+            {"moe/" + path}
+        logits[impl] = np.asarray(lg)[0]
+    assert logits["xla"].shape == (t, 128)
+    assert np.abs(logits["pallas_interpret"] - logits["xla"]).max() < \
+        1e-3 * logits["xla"].std()
+
+
 def test_engine_scheduler_contiguous_and_paged_serve_the_same_tokens(
         q40_file, monkeypatch):
     """Greedy tokens through the slot scheduler, on contiguous slots and on the

@@ -410,6 +410,33 @@ def test_q40_experts_matmul_compiles_at_deepseek_v2_shapes(one_chip, name, n, d,
     assert f"f32[{E},{rows},{d}]" in text
 
 
+# One decoded row's chosen experts (``q40_mm_chosen``): SmallThinker's 6 of 64
+# (gate / up two reduction steps of 1280 x 768, down three d tiles of 896) and
+# DeepSeek-V2's 6 of 160, the planes a traced vector
+@pytest.mark.parametrize("model,n,d,experts,layers,per_expert", [
+    ("smallthinker", 2560, 768, 64, 52, False),
+    ("smallthinker", 768, 2560, 64, 52, True),
+    ("deepseek-v2", 5120, 1536, 160, 4, False),
+    ("deepseek-v2", 1536, 5120, 160, 4, True)],
+    ids=["smallthinker-gate", "smallthinker-down", "deepseek-v2-gate",
+         "deepseek-v2-down"])
+def test_q40_chosen_experts_matmul_compiles_at_one_row(one_chip, model, n, d,
+                                                       experts, layers, per_expert):
+    k = 6
+    assert q40.padded_n(n) == n and q40._tile_n_legal(n, q40._tiles(n, d)[0])
+    s = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)  # noqa: E731
+    compiled = jax.jit(
+        lambda x, qp, sc, layer, chosen: q40._pallas_matmul_experts(
+            x, qp, sc, layer, experts=experts, chosen=chosen)).lower(
+        s(((k,) if per_expert else ()) + (1, n), jnp.bfloat16),
+        s((layers * experts, n // 2, d), jnp.uint8),
+        s((layers * experts, n // 32, d), jnp.uint16),
+        s((), jnp.int32), s((k,), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "q40_mm_chosen" in text
+    assert "q40_mm_experts" not in text and f"f32[{k},1,{d}]" in text
+
+
 # DeepSeek-V2's MLA projections: the fused down-projections from x (1536 + 576
 # = 2112 outputs: three d tiles of 768, the last ragged), q's up-projection
 # (1536 inputs, stored as 1536), wo (16384 inputs), the shared expert's down
@@ -612,8 +639,9 @@ def test_smallthinker_cell_programs_compile_at_published_widths(one_chip, monkey
     """The programs of ``smallthinker-21b-a3b.long-stream`` for the described
     chip, one period of layers: the 512-row prefill chunk (``all-experts``:
     three ``q40_mm_experts`` launches a layer; the window layers' ring walk,
-    the full layer's live walk) and the 16-step decode chunk (``select``: 6
-    experts x 3 launches a layer).  No Q40 site takes the XLA path, the rings
+    the full layer's live walk) and the 16-step decode chunk (``select-chosen``:
+    three ``q40_mm_chosen`` launches a layer over the row's 6 experts).  No Q40
+    site takes the XLA path, the rings
     are 4608 positions beside full planes of 16384, and neither kind of plane
     is copied whole."""
     import re
@@ -644,7 +672,8 @@ def test_smallthinker_cell_programs_compile_at_published_widths(one_chip, monkey
     finally:
         obs_dispatch.reset()
     assert sites_prefill.get("moe/all-experts") == 4 and "moe/scan" not in sites_prefill
-    assert sites_decode.get("moe/select") == 4, sites_decode
+    assert sites_decode.get("moe/select-chosen") == 4, sites_decode
+    assert "moe/select" not in sites_decode
     for sites in (sites_prefill, sites_decode):
         assert sites.get("attn/window-walk") == 3 and sites.get("attn/live-walk") == 1
         assert "q40/xla-dequant" not in sites, sites
@@ -652,7 +681,7 @@ def test_smallthinker_cell_programs_compile_at_published_widths(one_chip, monkey
                      r"op_name=\"([^\"]+)\"", decode, re.M)
     calls = [path for op, path in ops if op == "custom-call"
              and "pallas_call" in path and "/moe/experts/" in path]
-    assert len(calls) == 4 * 6 * 3, len(calls)
+    assert len(calls) == 4 * 3 and all("q40_mm_chosen" in c for c in calls), calls
     for text in (prefill, decode):
         assert any("/attn/window/" in path for _, path in re.findall(
             r"(\w+)\(.*?op_name=\"([^\"]+)\"", text))

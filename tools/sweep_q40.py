@@ -11,8 +11,19 @@ host dispatch latency, not kernel time.  Tile pairs go through the kernels'
 times them all; the program's own choices are ``q40._tiles`` (marked
 ``"rule": true`` in the records) and ``q40._row_block``.
 
+``--chosen`` times a decoded row's routed experts at SmallThinker's shapes:
+one launch over the six chosen planes (``q40_mm_chosen``) against six
+launches of ``q40_mm_stacked``, which is what ``moe_ffn`` ran at one row
+before and still runs on a mesh.  An iteration of the scan carries ops of
+its own (the index vector, ``_x_parts``, the slice and sum that keep the
+result alive; for the loop also six index slices and a stack): 27 us read
+here where the cell's trace reads 19-21 a launch, 67 where it reads 6 x 4.3
+(PERF.md §6, PR 39).  The order of the two forms is the tool's to say, a
+launch's time the trace's.
+
 Usage: python tools/sweep_q40.py --tiles [ds_gate,yi_wo]  # tile pairs at 1, 16, 256 rows
        python tools/sweep_q40.py --rows [head,w13]        # rows x row block
+       python tools/sweep_q40.py --chosen [st_gate]       # one launch a row's experts, or one each
 """
 
 from __future__ import annotations
@@ -70,6 +81,10 @@ SHAPES = [s._replace(tiles=WIDE) for s in MISTRAL] + [
     Shape("yi_w2", 5120, 7168, 8),
     Shape("w2_tp4", 3584, 4096, 8, tiles=((512, 1024), (3584, 256))),
 ]
+# SmallThinker-21B-A3B: 64 experts of 768, hidden 2560, 6 a row (--chosen)
+CHOSEN_SHAPES = [Shape("st_gate", 2560, 768, 4, 64),
+                 Shape("st_down", 768, 2560, 4, 64, True)]
+CHOSEN = 6
 TILE_ROWS = (1, 16, 256)
 # (rows, row block): None is the code's own choice (one block of every row
 # up to 128, q40._row_block above), "xla" the dequantize-then-dot path
@@ -88,7 +103,9 @@ def _sweep(shapes, configs_of, only: set | None, reps: int, out_name: str):
     """Time each shape under each of ``configs_of(shape)`` inside a jitted
     scan over the layer index, as the model runs it.  A config is ``(tag,
     rows, kw)``: ``tag`` names it in the record, ``kw`` holds the kernel's
-    keywords (None: the dequantize-then-dot XLA path).
+    keywords (None: the dequantize-then-dot XLA path).  A ``tag`` with a
+    ``form`` reads CHOSEN traced planes of the layer's experts: ``chosen`` in
+    one launch, ``stacked-loop`` in one launch each.
     One JSON line per measurement; all of them to ``chiprun_out/<out_name>``."""
     import jax
     import jax.numpy as jnp
@@ -114,10 +131,20 @@ def _sweep(shapes, configs_of, only: set | None, reps: int, out_name: str):
             jax.random.uniform(key, (1, n // 32, d), jnp.float16) * 0.01,
             jnp.uint16), (planes, 1, 1))
         for tag, rows, kw in configs_of(sh):
-            xshape = ((E,) if sh.x_per_expert else ()) + (rows, n)
+            form = tag.get("form")
+            read = CHOSEN if form else max(E, 1)  # planes a call reads
+            xshape = ((read,) if sh.x_per_expert else ()) + (rows, n)
             x = jax.random.normal(key, xshape, jnp.bfloat16)
 
             def one(x, qp, sc, i):
+                if form:
+                    picks = (i + 11 * jnp.arange(CHOSEN)) % E  # distinct, traced
+                    if form == "chosen":
+                        return q40._pallas_matmul_experts(
+                            x, qp, sc, i % L, experts=E, chosen=picks, **kw)
+                    return jnp.stack([q40._pallas_matmul_stacked(
+                        x[j] if sh.x_per_expert else x, qp, sc,
+                        (i % L) * E + picks[j], **kw) for j in range(CHOSEN)])
                 if not sh.layers:
                     # no layer index to vary: vary x, or XLA hoists the one
                     # call out of the scan
@@ -152,10 +179,10 @@ def _sweep(shapes, configs_of, only: set | None, reps: int, out_name: str):
                     float(run(x, qp, sc))
                     best = min(best, (time.perf_counter() - t0) * 1000 / reps)
                 # packed + scales, of every plane a call reads
-                nbytes = max(E, 1) * ((n // 2) * d + (n // 32) * d * 2)
+                nbytes = read * ((n // 2) * d + (n // 32) * d * 2)
                 rec.update(ms=round(best, 4),
                            GBps=round(nbytes / best / 1e6, 1),
-                           tflops=round(2 * max(E, 1) * rows * n * d / best / 1e9, 1),
+                           tflops=round(2 * read * rows * n * d / best / 1e9, 1),
                            us_per_row=round(best * 1000 / rows, 3))
             except Exception as e:  # noqa: BLE001 — a form Mosaic refuses is a result
                 rec["error"] = f"{type(e).__name__}: {str(e)[:300]}"
@@ -210,8 +237,17 @@ def measure_rows(only: set | None = None, reps: int = 16,
                   lambda sh: configs, only, reps, "sweep_rows.json")
 
 
+def measure_chosen(only: set | None = None, reps: int = 256) -> list[dict]:
+    """One decoded row's CHOSEN routed experts: one launch over their planes
+    against one launch each, at the rule's tiles (``ms`` is all six)."""
+    configs = [({"form": form, "chosen": CHOSEN}, 1, {})
+               for form in ("stacked-loop", "chosen")]
+    return _sweep(CHOSEN_SHAPES, lambda sh: configs, only, reps, "sweep_chosen.json")
+
+
 def main():
-    modes = {"--tiles": measure_tiles, "--rows": measure_rows}
+    modes = {"--tiles": measure_tiles, "--rows": measure_rows,
+             "--chosen": measure_chosen}
     if len(sys.argv) < 2 or sys.argv[1] not in modes:
         sys.exit(__doc__)
     modes[sys.argv[1]](set(sys.argv[2].split(",")) if len(sys.argv) > 2
