@@ -4,8 +4,10 @@ Re-implements `/root/reference/converter/convert-hf.py`: llama / mistral /
 mixtral folders with ``config.json`` + ``*.safetensors`` become a `.m` file
 in the canonical tensor order; beyond the reference, deepseek_v2 folders
 (MLA, ``kv_b_proj`` kept whole, header keys 14..31), olmoe folders
-(``ARCH_OLMOE``) and smallthinker folders (``ARCH_SMALLTHINKER``: header keys
-31..34, rows not permuted).  Key semantics preserved:
+(``ARCH_OLMOE``), smallthinker folders (``ARCH_SMALLTHINKER``: header keys
+31..34, rows not permuted) and exaone_moe folders (``ARCH_EXAONE_MOE``: K-EXAONE;
+``--experts-held N --first-expert I`` write one chip's share of every layer's
+routed experts; ``mtp.*`` tensors are skipped).  Key semantics preserved:
 
 * q/k head permutation (convert-hf.py:12-15): HF stores RoPE in rotate-half
   layout; the `.m` format expects the interleaved-pair layout, so q and k
@@ -41,6 +43,7 @@ ARCH_BY_MODEL_TYPE = {
     "olmoe": mfile.ARCH_OLMOE,
     "deepseek_v2": mfile.ARCH_DEEPSEEK2,
     "smallthinker": mfile.ARCH_SMALLTHINKER,
+    "exaone_moe": mfile.ARCH_EXAONE_MOE,
 }
 HIDDEN_ACT = {"gelu": mfile.ACT_GELU, "silu": mfile.ACT_SILU,
               "relu": mfile.ACT_RELU}
@@ -167,7 +170,68 @@ def _smallthinker_fields(config: dict) -> dict:
                 window=int(config["sliding_window_size"]), window_period=period)
 
 
-def load_spec(folder: str, weights_ftype: int) -> mfile.ModelSpec:
+def _exaone_moe_fields(config: dict, experts_held: int, first_expert: int) -> dict:
+    """The header's keys past the fourteen from an ``exaone_moe`` config.json
+    (K-EXAONE) and the share asked for.  ``ARCH_EXAONE_MOE`` is one block: a
+    sigmoid router over one group with a choice bias, the chosen scores
+    normalised and scaled, whole periods of window layers with one full layer,
+    a dense prefix, unscaled RoPE.  What the runtime does not compute is
+    refused by name."""
+    def no(why):
+        raise SystemExit(f"exaone_moe: {why}")
+
+    if config.get("scoring_func", "sigmoid") != "sigmoid":
+        no(f"scoring_func is {config['scoring_func']!r}; the runtime's router "
+           "for this architecture is a sigmoid")
+    if config.get("n_group", 1) != 1 or config.get("topk_group", 1) != 1:
+        no(f"n_group is {config.get('n_group')} and topk_group "
+           f"{config.get('topk_group')}; the runtime chooses over all experts "
+           "at once (one group)")
+    if not config.get("norm_topk_prob", True):
+        no("norm_topk_prob is false; the runtime normalises the chosen scores")
+    if config.get("tie_word_embeddings", False):
+        no("tie_word_embeddings is true; the .m format has a head of its own")
+    if config.get("hidden_act", "silu") != "silu":
+        no(f"hidden_act is {config['hidden_act']!r}; the runtime's experts are SwiGLU")
+    rope = config.get("rope_parameters") or {}
+    if rope.get("rope_type", "default") != "default":
+        no(f"rope_type is {rope.get('rope_type')!r}; the runtime's RoPE is unscaled")
+    layers = config["num_hidden_layers"]
+    kinds = [t == "full_attention" for t in config["layer_types"]]
+    at = kinds.index(True) if True in kinds else -1
+    period = kinds[at + 1:].index(True) + 1 if True in kinds[at + 1:] else 0
+    if len(kinds) != layers or period < 2 or layers % period or kinds != [
+            j == at for j in range(period)] * (layers // period):
+        no(f"layer_types {config['layer_types']} is not whole periods of "
+           "sliding_attention layers with one full_attention layer")
+    window = int(config["sliding_window"])
+    if "sliding_windows" in config and list(config["sliding_windows"]) != [
+            0 if k else window for k in kinds]:
+        no("sliding_windows is not sliding_window in the window layers and 0 "
+           "in the full ones")
+    dense = int(config.get("first_k_dense_replace", 0))
+    if "mlp_layer_types" in config and [
+            m == "dense" for m in config["mlp_layer_types"]] != [
+            i < dense for i in range(layers)]:
+        no("mlp_layer_types is not first_k_dense_replace dense layers and "
+           "then sparse ones")
+    n = int(config["num_experts"])
+    held = experts_held or n
+    if not (1 <= held <= n and 0 <= first_expert <= n - held):
+        no(f"--experts-held {experts_held} --first-expert {first_expert} is not "
+           f"a run of the {n} experts")
+    return dict(moe_hidden_dim=config["moe_intermediate_size"],
+                n_shared_experts=config.get("num_shared_experts") or 0,
+                n_groups=1, topk_groups=1, n_dense_layers=dense,
+                routed_scale=float(config.get("routed_scaling_factor", 1.0)),
+                norm_eps=float(config.get("rms_norm_eps", 1e-5)),
+                head_dim=int(config["head_dim"]), window=window,
+                window_period=period, window_full_at=at,
+                experts_held=0 if held == n else held, first_expert=first_expert)
+
+
+def load_spec(folder: str, weights_ftype: int, experts_held: int = 0,
+              first_expert: int = 0) -> mfile.ModelSpec:
     with open(os.path.join(folder, "config.json")) as f:
         config = json.load(f)
     arch = ARCH_BY_MODEL_TYPE.get(config["model_type"])
@@ -180,6 +244,14 @@ def load_spec(folder: str, weights_ftype: int) -> mfile.ModelSpec:
         ext = _smallthinker_fields(config)
         config = dict(config, intermediate_size=config["moe_ffn_hidden_size"],
                       hidden_act="relu")
+    if arch == mfile.ARCH_EXAONE_MOE:
+        ext = _exaone_moe_fields(config, experts_held, first_expert)
+        config = dict(config, rope_theta=(config.get("rope_parameters") or {}).get(
+            "rope_theta", config.get("rope_theta", 10000.0)))
+    elif experts_held or first_expert:
+        raise SystemExit("--experts-held / --first-expert write a share of an "
+                         "exaone_moe model's experts; this is "
+                         f"{config['model_type']}")
     # Mixtral's key, then OLMoE's, then DeepSeek-V2's, then SmallThinker's
     n_experts = (config.get("num_local_experts") or config.get("num_experts")
                  or config.get("n_routed_experts")
@@ -257,9 +329,11 @@ def hf_source_name(our_name: str, spec: mfile.ModelSpec) -> tuple[str, bool]:
     leaf = parts[-1]
     base = f"model.layers.{li}"
     # rows as published: these runtimes rotate halves, as HF does
-    olmoe = spec.arch in (mfile.ARCH_OLMOE, mfile.ARCH_SMALLTHINKER)
-    # the two arch ids whose HF experts are mlp.experts.N.{gate,up,down}_proj
-    mlp_experts = spec.arch in (mfile.ARCH_OLMOE, mfile.ARCH_DEEPSEEK2)
+    olmoe = spec.arch in (mfile.ARCH_OLMOE, mfile.ARCH_SMALLTHINKER,
+                          mfile.ARCH_EXAONE_MOE)
+    # the arch ids whose HF experts are mlp.experts.N.{gate,up,down}_proj
+    mlp_experts = spec.arch in (mfile.ARCH_OLMOE, mfile.ARCH_DEEPSEEK2,
+                                mfile.ARCH_EXAONE_MOE)
     if leaf in _DEEPSEEK2_LEAVES:  # kv_b_proj whole, rows as published
         return f"{base}.{_DEEPSEEK2_LEAVES[leaf]}.weight", False
     if leaf == "wq":
@@ -287,8 +361,11 @@ def hf_source_name(our_name: str, spec: mfile.ModelSpec) -> tuple[str, bool]:
         moe = f"{base}.block_sparse_moe"
         return (f"{moe}.primary_router.weight" if leaf == "moe_router"
                 else f"{moe}.experts.{parts[3]}.{leaf}.weight"), False
+    if leaf == "moe_router_bias":
+        return f"{base}.mlp.gate.e_score_correction_bias", False
     if parts[2] == "experts":
-        e = parts[3]
+        # a share's file index e is the router's (and HF's) first_expert + e
+        e = int(parts[3]) + spec.first_expert
         if mlp_experts:
             return f"{base}.mlp.experts.{e}.{leaf}_proj.weight", False
         hf_leaf = {"up": "w3", "gate": "w1", "down": "w2"}[leaf]
@@ -299,9 +376,16 @@ def hf_source_name(our_name: str, spec: mfile.ModelSpec) -> tuple[str, bool]:
     raise SystemExit(f"no HF mapping for {our_name}")
 
 
-def convert(folder: str, weights_ftype: int, out_path: str) -> None:
-    spec = load_spec(folder, weights_ftype)
+def convert(folder: str, weights_ftype: int, out_path: str,
+            experts_held: int = 0, first_expert: int = 0) -> None:
+    spec = load_spec(folder, weights_ftype, experts_held, first_expert)
     store = SafetensorsStore(folder)
+    if spec.arch == mfile.ARCH_EXAONE_MOE:
+        mtp = sorted(k for k in store._index if k.startswith(("mtp.", "model.mtp.")))
+        if mtp:
+            print(f"⏭️  skipping {len(mtp)} mtp.* tensors (the multi-token-"
+                  "prediction block is not computed; next-token logits do not "
+                  "depend on it)")
     if spec.arch == mfile.ARCH_DEEPSEEK2 and store.has("e_score_correction_bias"):
         raise SystemExit("deepseek_v2: the checkpoint has e_score_correction_bias "
                          "(V3's router); the runtime has no correction bias")
@@ -319,13 +403,20 @@ def convert(folder: str, weights_ftype: int, out_path: str) -> None:
 
 def main(argv):
     if len(argv) < 3:
-        print("Usage: python convert_hf.py <sourceFolderPath> <weightsFloatType> <name>")
+        print("Usage: python convert_hf.py <sourceFolderPath> <weightsFloatType> "
+              "<name> [--experts-held N --first-expert I]")
         raise SystemExit(1)
     folder, ftype_name, name = argv[0], argv[1], argv[2]
+    share = {"--experts-held": 0, "--first-expert": 0}
+    rest = argv[3:]
+    if len(rest) % 2 or any(k not in share for k in rest[::2]):
+        raise SystemExit(f"unknown arguments {rest}; the flags are "
+                         "--experts-held N and --first-expert I")
+    share.update({k: int(v) for k, v in zip(rest[::2], rest[1::2])})
     ftype = quants.FLOAT_TYPE_BY_NAME[ftype_name]
     out = f"dllama_model_{name}_{ftype_name}.m"
     print(f"Output file: {out}")
-    convert(folder, ftype, out)
+    convert(folder, ftype, out, share["--experts-held"], share["--first-expert"])
 
 
 if __name__ == "__main__":

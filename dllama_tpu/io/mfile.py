@@ -21,7 +21,12 @@ the reference converters load directly:
   older arch has (floats as their IEEE-754 f32 bits).  A SmallThinker file
   (``ARCH_SMALLTHINKER``) has Mixtral's tensors at a query width of
   ``n_heads * head_dim`` (key 32) and keys 31, 33, 34 for the norm's epsilon,
-  the sliding window and the layer period.  Matmul weights are stored row-major ``(d_out, n_in)`` in the
+  the sliding window and the layer period.  A K-EXAONE file
+  (``ARCH_EXAONE_MOE``) has those keys, DeepSeek-V2's 19..24 (expert width,
+  shared expert, groups, dense prefix, routed scale), and 35..37 for the share
+  of the experts it holds and the full layer's place in a period; per layer a
+  ``q_norm`` / ``k_norm`` of one head's size and, in an expert layer,
+  ``moe_router_bias``.  Matmul weights are stored row-major ``(d_out, n_in)`` in the
   model's weight float type; norm weights and the embedding are F32
   (transformer.cpp:213-218, 266-278).
 
@@ -62,9 +67,17 @@ ARCH_DEEPSEEK2 = 0xABCD04
 # layers with RoPE (keys 33, 34), a router that reads the layer's input as it
 # arrives, ReLU experts
 ARCH_SMALLTHINKER = 0xABCD05
+# K-EXAONE (``exaone_moe``): window (RoPE) and full (unrotated) layers in
+# periods whose full layer stands where key 37 says, a per-head RMSNorm of q
+# and k, a leading dense layer, then expert layers with a sigmoid router (a
+# bias for the choice only, the chosen scores normalised and scaled) and one
+# shared expert.  A file may hold a share of every layer's routed experts
+# (keys 35, 36): one chip's part of an expert-parallel deployment
+ARCH_EXAONE_MOE = 0xABCD06
 ARCH_NAMES = {ARCH_LLAMA: "llama", ARCH_GROK1: "grok1", ARCH_MIXTRAL: "mixtral",
               ARCH_OLMOE: "olmoe", ARCH_DEEPSEEK2: "deepseek2",
-              ARCH_SMALLTHINKER: "smallthinker"}
+              ARCH_SMALLTHINKER: "smallthinker",
+              ARCH_EXAONE_MOE: "exaone_moe"}
 
 # TransformerHiddenAct (transformer.hpp:45-48), and beyond it ReLU
 ACT_GELU = 0
@@ -86,8 +99,9 @@ KEY_SEQ_LEN = 10
 KEY_HIDDEN_ACT = 11
 KEY_ROPE_THETA = 12
 KEY_WEIGHTS_FLOAT_TYPE = 13
-# beyond the reference's fourteen: DeepSeek-V2's (``EXT_KEYS``, 14..31) and
-# SmallThinker's own (``WINDOW_KEYS``, 32..34; its file also carries key 31).
+# beyond the reference's fourteen: DeepSeek-V2's (``EXT_KEYS``, 14..31),
+# SmallThinker's own (``WINDOW_KEYS``, 32..34; its file also carries key 31)
+# and K-EXAONE's (``SHARE_KEYS``, 35..37; its file carries some of each).
 # ``(key, field, is_float)``: a float travels as the bits of its IEEE-754 f32
 # in the i32
 EXT_KEYS = (
@@ -115,11 +129,18 @@ WINDOW_KEYS = (
     (33, "window", False),              # keys a sliding-window layer sees, the query's own included
     (34, "window_period", False),       # layer l is full (and unrotated) iff l % period == 0
 )
+SHARE_KEYS = (
+    (35, "experts_held", False),        # routed experts a layer of this file holds (0: all)
+    (36, "first_expert", False),        # the router's index of the first held one
+    (37, "window_full_at", False),      # layer l is full iff l % period == this
+)
+ALL_EXT_KEYS = EXT_KEYS + WINDOW_KEYS + SHARE_KEYS
 # the keys a file of an arch carries past the fourteen
 ARCH_EXT_KEYS = {ARCH_DEEPSEEK2: tuple(range(14, 32)),
-                 ARCH_SMALLTHINKER: (31, 32, 33, 34)}
-_EXT_BY_KEY = {k: (name, is_f) for k, name, is_f in EXT_KEYS + WINDOW_KEYS}
-KEY_MAX = WINDOW_KEYS[-1][0]
+                 ARCH_SMALLTHINKER: (31, 32, 33, 34),
+                 ARCH_EXAONE_MOE: (19, 20, 21, 22, 23, 24) + tuple(range(31, 38))}
+_EXT_BY_KEY = {k: (name, is_f) for k, name, is_f in ALL_EXT_KEYS}
+KEY_MAX = SHARE_KEYS[-1][0]
 
 
 def _f32_bits(x: float) -> int:
@@ -172,10 +193,20 @@ class ModelSpec:
     head_dim: int = 0
     window: int = 0
     window_period: int = 0
+    # ARCH_EXAONE_MOE's; 0 where the arch has none
+    experts_held: int = 0
+    first_expert: int = 0
+    window_full_at: int = 0
 
     @property
     def head_size(self) -> int:
         return self.head_dim or self.dim // self.n_heads
+
+    @property
+    def n_experts_held(self) -> int:
+        """Routed experts a layer of this file has planes for (the router
+        always has ``n_experts`` outputs)."""
+        return self.experts_held or self.n_experts
 
     @property
     def q_dim(self) -> int:
@@ -229,7 +260,10 @@ def tensor_plan(spec: ModelSpec) -> list[TensorInfo]:
     add("token_embedding", (spec.vocab_size, spec.dim), quants.F32)
     if spec.arch == ARCH_DEEPSEEK2:
         _deepseek2_layers(spec, add)
-    for i in range(0 if spec.arch == ARCH_DEEPSEEK2 else spec.n_layers):
+    if spec.arch == ARCH_EXAONE_MOE:
+        _exaone_moe_layers(spec, add)
+    own_layers = spec.arch in (ARCH_DEEPSEEK2, ARCH_EXAONE_MOE)
+    for i in range(0 if own_layers else spec.n_layers):
         add(f"layers.{i}.wq", (spec.q_dim, spec.dim), w)
         add(f"layers.{i}.wk", (spec.kv_dim, spec.dim), w)
         add(f"layers.{i}.wv", (spec.kv_dim, spec.dim), w)
@@ -282,6 +316,41 @@ def _deepseek2_layers(spec: ModelSpec, add) -> None:
             f = spec.moe_hidden_dim
             add(p + "moe_router", (spec.n_experts, d), w)
             for e in range(spec.n_experts):
+                add(f"{p}experts.{e}.up", (f, d), w)
+                add(f"{p}experts.{e}.gate", (f, d), w)
+                add(f"{p}experts.{e}.down", (d, f), w)
+            fs = f * spec.n_shared_experts
+            add(p + "shared_w1", (fs, d), w)
+            add(p + "shared_w2", (d, fs), w)
+            add(p + "shared_w3", (fs, d), w)
+        add(p + "rms_att", (d,), quants.F32)
+        add(p + "rms_ffn", (d,), quants.F32)
+
+
+def _exaone_moe_layers(spec: ModelSpec, add) -> None:
+    """A K-EXAONE layer: q, k, v, o and one head's ``q_norm`` / ``k_norm``;
+    the dense FFN in the first ``n_dense_layers`` layers and, in the others,
+    the router over all ``n_experts`` with its choice bias, the
+    ``n_experts_held`` experts this file holds (file index ``e`` is the
+    router's ``first_expert + e``) and the shared expert; then the two block
+    norms."""
+    w, d, f = spec.weights_ftype, spec.dim, spec.moe_hidden_dim
+    for i in range(spec.n_layers):
+        p = f"layers.{i}."
+        add(p + "wq", (spec.q_dim, d), w)
+        add(p + "wk", (spec.kv_dim, d), w)
+        add(p + "wv", (spec.kv_dim, d), w)
+        add(p + "wo", (d, spec.q_dim), w)
+        add(p + "q_norm", (spec.head_size,), quants.F32)
+        add(p + "k_norm", (spec.head_size,), quants.F32)
+        if i < spec.n_dense_layers:
+            add(p + "w1", (spec.hidden_dim, d), w)
+            add(p + "w2", (d, spec.hidden_dim), w)
+            add(p + "w3", (spec.hidden_dim, d), w)
+        else:
+            add(p + "moe_router", (spec.n_experts, d), w)
+            add(p + "moe_router_bias", (spec.n_experts,), quants.F32)
+            for e in range(spec.n_experts_held):
                 add(f"{p}experts.{e}.up", (f, d), w)
                 add(f"{p}experts.{e}.gate", (f, d), w)
                 add(f"{p}experts.{e}.down", (d, f), w)
@@ -369,16 +438,23 @@ def validate_spec(spec: ModelSpec, path) -> ModelSpec:
                             "more active experts than experts",
                             expected=f"<= {spec.n_experts}",
                             got=spec.n_active_experts)
-    if spec.arch == ARCH_SMALLTHINKER:
+    if spec.arch in (ARCH_SMALLTHINKER, ARCH_EXAONE_MOE):
         _validate_smallthinker(spec, path)
     elif spec.head_dim or spec.window or spec.window_period:
         raise ArtifactError(path, "header key",
-                            "keys 32..34 describe a smallthinker file",
+                            "keys 32..34 describe a smallthinker file (or an exaone_moe one)",
                             expected=hex(ARCH_SMALLTHINKER), got=hex(spec.arch))
+    if spec.arch == ARCH_EXAONE_MOE:
+        _validate_exaone_moe(spec, path)
+    elif spec.experts_held or spec.first_expert or spec.window_full_at:
+        raise ArtifactError(path, "header key",
+                            "keys 35..37 describe an exaone_moe file",
+                            expected=hex(ARCH_EXAONE_MOE), got=hex(spec.arch))
     if spec.arch == ARCH_DEEPSEEK2:
         _validate_deepseek2(spec, path)
-    elif spec.is_mla or spec.n_dense_layers or spec.n_shared_experts \
-            or spec.n_groups or spec.moe_hidden_dim:
+    elif spec.arch != ARCH_EXAONE_MOE and (  # which carries six of those keys
+            spec.is_mla or spec.n_dense_layers or spec.n_shared_experts
+            or spec.n_groups or spec.moe_hidden_dim):
         raise ArtifactError(path, "header key",
                             "keys 14..30 describe a deepseek2 file",
                             expected=hex(ARCH_DEEPSEEK2), got=hex(spec.arch))
@@ -412,6 +488,41 @@ def _validate_smallthinker(spec: ModelSpec, path) -> None:
     if not spec.n_experts or not spec.n_active_experts:
         bad("n_experts", "every smallthinker layer has experts and a top-k",
             ">= 1", spec.n_experts)
+
+
+def _validate_exaone_moe(spec: ModelSpec, path) -> None:
+    """The cross-field rules of an ``ARCH_EXAONE_MOE`` header past the window
+    keys' (:func:`_validate_smallthinker`)."""
+    def bad(field, why, expected, got):
+        raise ArtifactError(path, f"header field {field}", why,
+                            expected=expected, got=got)
+
+    if not 0 <= spec.window_full_at < spec.window_period:
+        bad("window_full_at", "the full layer's place in a period",
+            f"0..{spec.window_period - 1}", spec.window_full_at)
+    if not 1 <= spec.moe_hidden_dim <= 1 << 24:
+        bad("moe_hidden_dim", "an exaone_moe file states its experts' width",
+            "1..2^24", spec.moe_hidden_dim)
+    if not 0 <= spec.n_shared_experts <= 64:
+        bad("n_shared_experts", "value out of range — corrupt header",
+            "0..64", spec.n_shared_experts)
+    if not 0 <= spec.n_dense_layers < spec.n_layers:
+        bad("n_dense_layers", "the dense layers lead and expert layers follow",
+            f"0..{spec.n_layers - 1}", spec.n_dense_layers)
+    if spec.n_groups != 1 or spec.topk_groups != 1:
+        bad("n_groups", "an exaone_moe router chooses over all experts at "
+            "once (one group)", 1, (spec.n_groups, spec.topk_groups))
+    if not spec.routed_scale > 0:
+        bad("routed_scale", "must be positive", "> 0", spec.routed_scale)
+    held = spec.n_experts_held
+    if not (1 <= held <= spec.n_experts
+            and 0 <= spec.first_expert <= spec.n_experts - held):
+        bad("experts_held", "the held experts are a run of the router's",
+            f"first_expert + experts_held <= n_experts={spec.n_experts}",
+            (spec.first_expert, spec.experts_held))
+    if spec.n_active_experts > spec.n_experts:
+        bad("n_active_experts", "more experts a token than the router has",
+            f"<= {spec.n_experts}", spec.n_active_experts)
 
 
 def _validate_deepseek2(spec: ModelSpec, path) -> None:
@@ -718,7 +829,7 @@ def write_header(f, spec: ModelSpec) -> int:
     own = ARCH_EXT_KEYS.get(spec.arch, ())
     pairs += [(k, _f32_bits(getattr(spec, name)) if is_f
                else getattr(spec, name))
-              for k, name, is_f in EXT_KEYS + WINDOW_KEYS if k in own]
+              for k, name, is_f in ALL_EXT_KEYS if k in own]
     data = b"".join(struct.pack("<ii", k, v) for k, v in pairs)
     f.write(struct.pack("<ii", MAGIC_V2, 8 + len(data)))
     f.write(data)

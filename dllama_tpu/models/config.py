@@ -8,7 +8,9 @@ grok1-tasks.cpp:275-354, `buildMixtralArch` mixtral-tasks.cpp:5-78), and
 those of OLMoE (`ARCH_OLMOE`, beyond the reference).  DeepSeek-V2
 (`ARCH_DEEPSEEK2`) states its sizes in the header (`io/mfile.py EXT_KEYS`) and
 they are fields here, not properties of the id; so are SmallThinker's
-(`ARCH_SMALLTHINKER`) head size, sliding window and layer period.
+(`ARCH_SMALLTHINKER`) head size, sliding window and layer period, and
+K-EXAONE's (`ARCH_EXAONE_MOE`) share of the experts and full layer's place;
+that arch's per-head q/k norm and sigmoid router follow from its id.
 """
 
 from __future__ import annotations
@@ -76,7 +78,11 @@ class ModelConfig:
     # ---- ARCH_SMALLTHINKER (header keys 32..34); 0 = the arch has none
     head_dim: int = 0               # a head's size where it is not dim / n_heads
     window: int = 0                 # > 0: sliding-window layers see this many keys
-    window_period: int = 0          # layer l is full and unrotated iff l % period == 0
+    window_period: int = 0          # layer l is full and unrotated iff l % period == window_full_at
+    # ---- ARCH_EXAONE_MOE (header keys 35..37); 0 = the arch has none
+    experts_held: int = 0           # routed experts a layer holds planes for (0: all)
+    first_expert: int = 0           # the router's index of the first held expert
+    window_full_at: int = 0         # the full layer's place in a period
 
     @property
     def head_size(self) -> int:
@@ -95,12 +101,20 @@ class ModelConfig:
     def n_window_layers(self) -> int:
         return self.n_layers - self.n_full_layers
 
+    @property
+    def n_experts_held(self) -> int:
+        """Routed experts a layer holds planes for: all ``n_experts`` unless
+        the file is one chip's share of an expert-parallel deployment (the
+        router has ``n_experts`` outputs either way)."""
+        return self.experts_held or self.n_experts
+
     def prefill_chunk(self) -> int:
         """Rows of one prefill call: the largest power of two whose float32
-        ``(experts, rows, dim)`` product (``moe_ffn``'s all-experts strategy;
-        one expert's for a dense model) stays under ``PREFILL_PRODUCT_BYTES``.
-        512 at 64 experts of 2560; a prompt up to one chunk takes one call."""
-        rows = PREFILL_PRODUCT_BYTES // (4 * max(self.n_experts, 1) * self.dim)
+        ``(experts held, rows, dim)`` product (``moe_ffn``'s all-experts
+        strategy; one expert's for a dense model) stays under
+        ``PREFILL_PRODUCT_BYTES``.  512 at 64 experts of 2560, 1024 at 16 held
+        of 6144; a prompt up to one chunk takes one call."""
+        rows = PREFILL_PRODUCT_BYTES // (4 * max(self.n_experts_held, 1) * self.dim)
         return max(16, 1 << (max(rows, 1).bit_length() - 1))
 
     def window_ring(self, seq_len: int) -> int:
@@ -183,6 +197,20 @@ class ModelConfig:
         return self.arch == mfile.ARCH_GROK1
 
     @property
+    def qk_head_norm(self) -> bool:
+        """K-EXAONE RMS-normalises each head of q and of k over its own
+        ``head_size`` values, one weight vector of that size each a layer
+        (``q_norm`` / ``k_norm``), before RoPE."""
+        return self.arch == mfile.ARCH_EXAONE_MOE
+
+    @property
+    def router_sigmoid(self) -> bool:
+        """K-EXAONE's router (DeepSeek-V3's): sigmoid scores, a per-expert
+        bias added for the choice only (``router_bias``), the chosen scores
+        normalised to sum to 1 and scaled by ``routed_scale``."""
+        return self.arch == mfile.ARCH_EXAONE_MOE
+
+    @property
     def qk_norm(self) -> bool:
         """OLMoE RMS-normalises the whole q and the whole k projection (one
         weight vector each, ``layers.{i}.q_norm`` / ``k_norm``) before the
@@ -207,7 +235,7 @@ class ModelConfig:
             seq_len=spec.seq_len, hidden_act=spec.hidden_act,
             rope_theta=spec.rope_theta, dtype=dtype,
             **{name: getattr(spec, name)
-               for _, name, _ in mfile.EXT_KEYS + mfile.WINDOW_KEYS})
+               for _, name, _ in mfile.ALL_EXT_KEYS})
 
     def with_(self, **kw) -> "ModelConfig":
         return replace(self, **kw)
@@ -237,6 +265,23 @@ def tiny_smallthinker(**kw) -> ModelConfig:
                 n_active_experts=6, vocab_size=128, seq_len=96,
                 hidden_act=mfile.ACT_RELU, rope_theta=1.5e6, norm_eps=1e-6,
                 head_dim=8, window=16, window_period=4)
+    base.update(kw)
+    return tiny_config(**base)
+
+
+def tiny_exaone_moe(**kw) -> ModelConfig:
+    """K-EXAONE at a toy size that keeps every ratio: periods of three window
+    layers and then a full one, a window shorter than the tests' sequences, 8
+    query heads a kv head, a head size that is not dim / n_heads, a dense first
+    layer, 32 experts of which 8 a token and 4 held here (the second of eight
+    shares), one shared expert, a routed scale."""
+    base = dict(arch=mfile.ARCH_EXAONE_MOE, dim=64, hidden_dim=96, n_layers=8,
+                n_heads=16, n_kv_heads=2, n_experts=32, n_active_experts=8,
+                vocab_size=128, seq_len=96, rope_theta=1e6, norm_eps=1e-5,
+                head_dim=8, window=16, window_period=4, window_full_at=3,
+                moe_hidden_dim=32, n_shared_experts=1, n_groups=1,
+                topk_groups=1, n_dense_layers=1, routed_scale=2.5,
+                experts_held=4, first_expert=4)
     base.update(kw)
     return tiny_config(**base)
 

@@ -32,8 +32,8 @@ Params = dict  # pytree: str -> array (numpy until placed) | q40.QTensor
 MLA_ATT_KEYS = ("wq_a", "wkv_a", "wqkv_a", "q_a_norm", "wq_b", "kv_a_norm",
                 "wkv_b", "wo", "rms_att", "rms_ffn")
 DENSE_FFN_KEYS = ("w1", "w2", "w3", "w13")
-MOE_FFN_KEYS = ("router", "up", "gate", "down", "shared_w1", "shared_w2",
-                "shared_w3", "shared_w13")
+MOE_FFN_KEYS = ("router", "router_bias", "up", "gate", "down", "shared_w1",
+                "shared_w2", "shared_w3", "shared_w13")
 
 
 def _mla_param_shapes(cfg: ModelConfig) -> dict[str, tuple[int, ...]]:
@@ -65,9 +65,40 @@ def _mla_param_shapes(cfg: ModelConfig) -> dict[str, tuple[int, ...]]:
     return shapes
 
 
+def _exaone_param_shapes(cfg: ModelConfig) -> dict[str, tuple[int, ...]]:
+    """K-EXAONE's stacks: attention and norms over all layers, the dense FFN
+    over the leading ``n_dense_layers``, router / held experts / shared expert
+    over the rest (a stack's leading index is a layer's index within its
+    segment, as DeepSeek-V2's).  The router has ``n_experts`` columns; the
+    expert stacks have ``n_experts_held`` planes a layer."""
+    L, D, V = cfg.n_layers, cfg.dim, cfg.vocab_size
+    Ld, Le, Dh = cfg.n_dense_layers, cfg.n_moe_layers, cfg.head_size
+    E, H, F = cfg.n_experts, cfg.n_experts_held, cfg.expert_dim
+    Fs = F * cfg.n_shared_experts
+    shapes = {
+        "embedding": (V, D),
+        "wq": (L, D, cfg.q_dim), "wk": (L, D, cfg.kv_dim),
+        "wv": (L, D, cfg.kv_dim), "wo": (L, cfg.q_dim, D),
+        "q_norm": (L, Dh), "k_norm": (L, Dh),
+        "rms_att": (L, D), "rms_ffn": (L, D),
+        "rms_final": (D,), "wcls": (D, V),
+        "router": (Le, D, E), "router_bias": (Le, E),
+        "up": (Le, H, D, F), "gate": (Le, H, D, F), "down": (Le, H, F, D),
+    }
+    if Ld:
+        Fd = cfg.hidden_dim
+        shapes.update({"w1": (Ld, D, Fd), "w2": (Ld, Fd, D), "w3": (Ld, D, Fd)})
+    if Fs:
+        shapes.update({"shared_w1": (Le, D, Fs), "shared_w2": (Le, Fs, D),
+                       "shared_w3": (Le, D, Fs)})
+    return shapes
+
+
 def param_shapes(cfg: ModelConfig) -> dict[str, tuple[int, ...]]:
     if cfg.is_mla:
         return _mla_param_shapes(cfg)
+    if cfg.arch == mfile.ARCH_EXAONE_MOE:
+        return _exaone_param_shapes(cfg)
     L, D, F, V = cfg.n_layers, cfg.dim, cfg.hidden_dim, cfg.vocab_size
     Hq = cfg.n_heads * cfg.head_size       # == D
     Hkv = cfg.n_kv_heads * cfg.head_size   # == kv_dim
@@ -110,7 +141,8 @@ def init_params(cfg: ModelConfig, seed: int = 0, scale: float = 0.02) -> Params:
             x = np.ones(shape, dtype=np.float32)
         else:
             x = (rng.standard_normal(shape) * scale).astype(np.float32)
-        params[name] = jnp.asarray(x, dtype=jnp.float32 if norm else cfg.dtype)
+        f32 = norm or name == "router_bias"
+        params[name] = jnp.asarray(x, dtype=jnp.float32 if f32 else cfg.dtype)
     return params
 
 
@@ -161,6 +193,17 @@ def quantize_matmuls(params: Params, cfg: ModelConfig,
     out = dict(params)
     if cfg.is_mla:
         return _quantize_mla(out, fuse)
+    if cfg.arch == mfile.ARCH_EXAONE_MOE:
+        # two FFN kinds beside Llama's attention: _quantize_mla's key list
+        # covers them (absent keys are skipped), after the q/k/v join
+        if fuse:
+            out["wqkv"] = q40.quantize(np.concatenate(
+                [np.asarray(out.pop(k), np.float32) for k in ("wq", "wk", "wv")],
+                axis=-1))
+        else:
+            for k in ("wq", "wk", "wv"):
+                out[k] = q40.quantize(np.asarray(out[k], np.float32))
+        return _quantize_mla(out, fuse)
     if fuse:
         out["wqkv"] = q40.quantize(np.concatenate(
             [np.asarray(params[k], np.float32) for k in ("wq", "wk", "wv")], axis=-1))
@@ -191,7 +234,8 @@ def _quantize_mla(out: Params, fuse: bool) -> Params:
         return np.asarray(out.pop(k), np.float32)
 
     if fuse:
-        out["wqkv_a"] = q40.quantize(np.concatenate([f32("wq_a"), f32("wkv_a")], -1))
+        if "wq_a" in out:
+            out["wqkv_a"] = q40.quantize(np.concatenate([f32("wq_a"), f32("wkv_a")], -1))
         for pre in ("w", "shared_w"):
             if pre + "1" in out:
                 out[pre + "13"] = q40.quantize(
@@ -212,7 +256,7 @@ def _stack_q_experts(mf: mfile.MFile, cfg: ModelConfig, fname: str, codec=q40,
     dense f32 expert loading that made Mixtral-8x7B (~90 GB f32 transit)
     unloadable (VERDICT r01)."""
     layers = range(cfg.n_layers) if layers is None else layers
-    L, E = len(layers), cfg.n_experts
+    L, E = len(layers), cfg.n_experts_held
     t0 = mf.info(f"layers.{layers[0]}.experts.0.{fname}")
     d = int(np.prod(t0.shape[:-1]))
     n = t0.shape[-1]
@@ -275,8 +319,9 @@ def _read_params(mf: mfile.MFile, cfg: ModelConfig, dtype,
     L = cfg.n_layers
     p: Params = {}
     p["embedding"] = mf.tensor("token_embedding").astype(np_dtype)
-    if cfg.is_mla:
-        _read_mla_layers(mf, cfg, p, np_dtype, codec if quant else None, fuse)
+    if cfg.is_mla or cfg.arch == mfile.ARCH_EXAONE_MOE:
+        read = _read_mla_layers if cfg.is_mla else _read_exaone_layers
+        read(mf, cfg, p, np_dtype, codec if quant else None, fuse)
         return _read_tail(mf, p, np_dtype, codec if quant else None)
     if quant and fuse:
         p["wqkv"] = _stack_q(
@@ -323,61 +368,98 @@ def _read_params(mf: mfile.MFile, cfg: ModelConfig, dtype,
     return _read_tail(mf, p, np_dtype, codec if quant else None)
 
 
+class _Stacks:
+    """The three ways a segmented file's layer stacks are read into ``p``
+    (``codec`` None: dense): matrices, joined matrices, float32 vectors."""
+
+    def __init__(self, mf, p, np_dtype, codec):
+        self.mf, self.p, self.np_dtype, self.codec = mf, p, np_dtype, codec
+
+    def mats(self, keys, layers):
+        for key in keys:
+            fnames = [f"layers.{i}.{key}" for i in layers]
+            self.p[key] = (_stack_q(self.mf, fnames, self.codec) if self.codec
+                           else _stack(self.mf, fnames, True, self.np_dtype))
+
+    def fused(self, key, parts, layers):
+        self.p[key] = _stack_q(
+            self.mf, [[f"layers.{i}.{a}" for a in parts] for i in layers],
+            self.codec)
+
+    def vecs(self, keys, layers, src=None):
+        for key in keys:
+            self.p[key] = _stack(
+                self.mf, [f"layers.{i}.{src or key}" for i in layers], False,
+                np.float32)
+
+
+def _read_ffn_segments(mf: mfile.MFile, cfg: ModelConfig, p: Params, st: _Stacks,
+                       join: bool) -> None:
+    """The dense prefix's FFN and the expert layers' router, held experts and
+    shared expert of a segmented file (DeepSeek-V2, K-EXAONE)."""
+    dense = range(cfg.n_dense_layers)
+    moe = range(cfg.n_dense_layers, cfg.n_layers)
+    if len(dense):
+        if join:
+            st.fused("w13", ("w1", "w3"), dense)
+            st.mats(("w2",), dense)
+        else:
+            st.mats(("w1", "w2", "w3"), dense)
+    if not len(moe):
+        return
+    p["router"] = _stack(mf, [f"layers.{i}.moe_router" for i in moe], True,
+                         st.np_dtype)
+    for key in ("up", "gate", "down"):
+        if st.codec:
+            p[key] = _stack_q_experts(mf, cfg, key, st.codec, layers=moe)
+        else:
+            p[key] = np.stack([np.stack([
+                np.ascontiguousarray(mf.tensor(f"layers.{i}.experts.{e}.{key}").T)
+                for e in range(cfg.n_experts_held)]) for i in moe]).astype(st.np_dtype)
+    if cfg.n_shared_experts:
+        if join:
+            st.fused("shared_w13", ("shared_w1", "shared_w3"), moe)
+            st.mats(("shared_w2",), moe)
+        else:
+            st.mats(("shared_w1", "shared_w2", "shared_w3"), moe)
+
+
+def _read_exaone_layers(mf: mfile.MFile, cfg: ModelConfig, p: Params, np_dtype,
+                        codec, fuse: bool) -> None:
+    """A K-EXAONE file's layer stacks into ``p`` (``codec`` None: dense): the
+    attention of every layer with its two head norms, then the FFN segments;
+    the router's choice bias stays float32."""
+    att = range(cfg.n_layers)
+    st = _Stacks(mf, p, np_dtype, codec)
+    join = codec is not None and fuse
+    if join:
+        st.fused("wqkv", ("wq", "wk", "wv"), att)
+        st.mats(("wo",), att)
+    else:
+        st.mats(("wq", "wk", "wv", "wo"), att)
+    st.vecs(("q_norm", "k_norm", "rms_att", "rms_ffn"), att)
+    _read_ffn_segments(mf, cfg, p, st, join)
+    st.vecs(("router_bias",), range(cfg.n_dense_layers, cfg.n_layers),
+            src="moe_router_bias")
+
+
 def _read_mla_layers(mf: mfile.MFile, cfg: ModelConfig, p: Params, np_dtype,
                      codec, fuse: bool) -> None:
     """A DeepSeek-V2 file's layer stacks into ``p`` (``codec`` None: dense).
     ``wkv_b`` is dequantized once, here, whatever the file's type: the
     absorbed form multiplies it head by head, which no packed kernel does."""
     att = range(cfg.n_layers)
-    dense = range(cfg.n_dense_layers)
-    moe = range(cfg.n_dense_layers, cfg.n_layers)
-
-    def mats(keys, layers):
-        for key in keys:
-            fnames = [f"layers.{i}.{key}" for i in layers]
-            p[key] = (_stack_q(mf, fnames, codec) if codec
-                      else _stack(mf, fnames, True, np_dtype))
-
-    def fused(key, a, b, layers):
-        p[key] = _stack_q(mf, [[f"layers.{i}.{a}", f"layers.{i}.{b}"]
-                               for i in layers], codec)
-
-    def vecs(keys, layers):
-        for key in keys:
-            p[key] = _stack(mf, [f"layers.{i}.{key}" for i in layers], False,
-                            np.float32)
-
+    st = _Stacks(mf, p, np_dtype, codec)
+    mats, vecs = st.mats, st.vecs
     join = codec is not None and fuse
     if join:
-        fused("wqkv_a", "wq_a", "wkv_a", att)
+        st.fused("wqkv_a", ("wq_a", "wkv_a"), att)
     else:
         mats(("wq_a", "wkv_a"), att)
     mats(("wq_b", "wo"), att)
     p["wkv_b"] = _stack(mf, [f"layers.{i}.wkv_b" for i in att], True, np_dtype)
     vecs(("q_a_norm", "kv_a_norm", "rms_att", "rms_ffn"), att)
-    if len(dense):
-        if join:
-            fused("w13", "w1", "w3", dense)
-            mats(("w2",), dense)
-        else:
-            mats(("w1", "w2", "w3"), dense)
-    if not len(moe):
-        return
-    p["router"] = _stack(mf, [f"layers.{i}.moe_router" for i in moe], True,
-                         np_dtype)
-    for key in ("up", "gate", "down"):
-        if codec:
-            p[key] = _stack_q_experts(mf, cfg, key, codec, layers=moe)
-        else:
-            p[key] = np.stack([np.stack([
-                np.ascontiguousarray(mf.tensor(f"layers.{i}.experts.{e}.{key}").T)
-                for e in range(cfg.n_experts)]) for i in moe]).astype(np_dtype)
-    if cfg.n_shared_experts:
-        if join:
-            fused("shared_w13", "shared_w1", "shared_w3", moe)
-            mats(("shared_w2",), moe)
-        else:
-            mats(("shared_w1", "shared_w2", "shared_w3"), moe)
+    _read_ffn_segments(mf, cfg, p, st, join)
 
 
 def _read_tail(mf: mfile.MFile, p: Params, np_dtype, codec) -> Params:

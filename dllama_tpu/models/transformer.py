@@ -63,7 +63,8 @@ class KVCache(NamedTuple):
     k_scale: jax.Array | None = None
     v_scale: jax.Array | None = None
     # a windowed model (models/windowed.py) only: its window layers' rings
-    # (Lw, B, Hkv, R, Dh); k and v are then its full layers' planes
+    # (Lw, B, Hkv, R, Dh), or beside a paged pool its slots' rings of pages
+    # (Lw, B * ring, ps, Hkv, Dh); k and v are then its full layers' planes
     wk: jax.Array | None = None
     wv: jax.Array | None = None
 
@@ -76,9 +77,15 @@ class KVCache(NamedTuple):
         return self.k.ndim == 4
 
     def planes(self) -> dict[str, jax.Array]:
-        """The arrays this cache has, by field name: what a snapshot, a spill
-        or a hand-off record carries, whatever the cache's kind."""
+        """The arrays this cache has, by field name: what a snapshot carries,
+        whatever the cache's kind."""
         return {n: a for n, a in self._asdict().items() if a is not None}
+
+    def pool_planes(self) -> dict[str, jax.Array]:
+        """The planes of a paged pool that a page id addresses (what a spill
+        or a hand-off record carries page by page): all of them but a windowed
+        model's slot rings, whose pages belong to slots."""
+        return {n: a for n, a in self.planes().items() if n not in ("wk", "wv")}
 
 
 def init_kv_cache(cfg: ModelConfig, batch: int, seq_len: int | None = None,
@@ -131,7 +138,8 @@ def _init_latent(lead, cfg: ModelConfig, dtype, quant: bool) -> KVCache:
 
 
 def init_kv_pool(cfg: ModelConfig, n_pages: int, page_size: int,
-                 dtype=None, quant: bool = False) -> KVCache:
+                 dtype=None, quant: bool = False, slots: int = 0,
+                 max_pages: int | None = None) -> KVCache:
     """Paged KV pool ``(L, n_pages, page_size, Hkv, Dh)``: physical pages
     in place of the batch axis, and each page token-major, so a token's
     (Hkv, Dh) slab is contiguous and the per-token KV write's layout is
@@ -147,7 +155,16 @@ def init_kv_pool(cfg: ModelConfig, n_pages: int, page_size: int,
     the page-granular mirror of the contiguous quantized cache's codec
     (same quantize_kv absmax math, same ~2× HBM saving), so a pool page
     is self-describing: values and scales always travel together through
-    spills, snapshots and DLREQ01 hand-offs."""
+    spills, snapshots and DLREQ01 hand-offs.
+
+    A windowed model (``cfg.window``) has a pool and a table per layer kind:
+    ``k`` / ``v`` are its FULL layers' pool alone, and its window layers get
+    planes of their own, ``wk`` / ``wv``, in which each of ``slots`` slots
+    owns a ring of pages bounded by the window (``models/windowed.py
+    init_pool``; ``max_pages``: a slot's table width, which bounds the ring)."""
+    if cfg.window:
+        return windowed.init_pool(cfg, n_pages, page_size, dtype, quant, slots,
+                                  max_pages or n_pages)
     if cfg.is_mla:
         # (L, P, ps, ·): the same token-major page, one row a token a plane
         return _init_latent((cfg.n_layers, n_pages, page_size), cfg, dtype, quant)
@@ -417,10 +434,23 @@ def _routed_experts(xb2d: jax.Array, lp, cfg: ModelConfig,
     chooses in two stages, ``topk_groups`` groups by their best expert and
     then the top-k of the experts in them, and scales the chosen
     probabilities by ``cfg.routed_scale``; every strategy below takes its
-    experts and weights from this one choice.  The logits are this layer's
+    experts and weights from this one choice.  K-EXAONE
+    (``cfg.router_sigmoid``) scores with a sigmoid, adds ``lp["router_bias"]``
+    for the choice only, and normalises and scales the chosen scores.  The
+    logits are this layer's
     FFN input times ``lp["router"]`` unless the caller hands ``router_logits``
     ``(N, E)`` made elsewhere (SmallThinker's router reads the layer's input
     before attention, ``models/windowed.py``).
+
+    A layer may hold planes for a run of its experts only
+    (``cfg.n_experts_held`` of them from ``cfg.first_expert``: one chip's share
+    of an expert-parallel deployment).  The router then still chooses among all
+    ``E`` and weighs over all ``k`` chosen, and the result is the held experts'
+    part of the sum: a chosen expert that lives elsewhere contributes nothing
+    here (every strategy: weight 0; ``all-experts`` and the loops never visit
+    it, ``select-chosen`` / ``select`` point its grid step at a held plane,
+    which is read in its stead).  The ledger records ``held`` beside
+    ``experts``.
 
     Sub-scopes inside ``moe``: ``router`` (logits, softmax, top-k),
     ``experts`` (the expert matmuls and, on the scan, its bookkeeping),
@@ -460,28 +490,46 @@ def _routed_experts(xb2d: jax.Array, lp, cfg: ModelConfig,
     """
     n, d = xb2d.shape
     e, k = cfg.n_experts, cfg.n_active_experts
+    # the stacks' planes a layer: all e, or this chip's share of them
+    held, first = cfg.n_experts_held, cfg.first_expert
+    share = held != e
+    site = dict(experts=e, held=held) if share else dict(experts=e)
     act = ACTIVATIONS[cfg.hidden_act]
 
     with part("router"):
         if router_logits is None:
             router = lp["router"]
             router_logits = xb2d.astype(jnp.float32) @ router.astype(jnp.float32)  # (N, E)
-        probs = softmax_f32(router_logits)
-        if cfg.n_groups > 1:
-            # a group's score is its best expert's; experts outside the
-            # topk_groups best groups are out of the top-k (their p set to 0)
-            best = probs.reshape(n, cfg.n_groups, -1).max(-1)
-            _, gidx = jax.lax.top_k(best, cfg.topk_groups)
-            kept = jnp.put_along_axis(jnp.zeros(best.shape, bool), gidx, True,
-                                      axis=-1, inplace=False)
-            probs = jnp.where(jnp.repeat(kept, e // cfg.n_groups, axis=-1),
-                              probs, 0.0)
-        top_vals, top_idx = jax.lax.top_k(probs, k)  # (N, k)
+        if cfg.router_sigmoid:
+            probs = jax.nn.sigmoid(router_logits)
+            # the bias moves the choice, never the weights
+            _, top_idx = jax.lax.top_k(probs + lp["router_bias"].astype(jnp.float32), k)
+            top_vals = jnp.take_along_axis(probs, top_idx, axis=-1)
+        else:
+            probs = softmax_f32(router_logits)
+            if cfg.n_groups > 1:
+                # a group's score is its best expert's; experts outside the
+                # topk_groups best groups are out of the top-k (their p set to 0)
+                best = probs.reshape(n, cfg.n_groups, -1).max(-1)
+                _, gidx = jax.lax.top_k(best, cfg.topk_groups)
+                kept = jnp.put_along_axis(jnp.zeros(best.shape, bool), gidx, True,
+                                          axis=-1, inplace=False)
+                probs = jnp.where(jnp.repeat(kept, e // cfg.n_groups, axis=-1),
+                                  probs, 0.0)
+            top_vals, top_idx = jax.lax.top_k(probs, k)  # (N, k)
         weights = top_vals
         if cfg.norm_topk_prob:
             weights = top_vals / jnp.sum(top_vals, axis=-1, keepdims=True)
         if cfg.routed_scale != 1.0:
             weights = weights * jnp.float32(cfg.routed_scale)
+        if share:
+            # the few-row strategies index planes: a chosen expert held
+            # elsewhere gets weight 0 and a held plane to stand on
+            here = (top_idx >= first) & (top_idx < first + held)
+            sel_w = jnp.where(here, weights, 0.0)
+            sel_idx = jnp.where(here, top_idx - first, 0)
+        else:
+            sel_w, sel_idx = weights, top_idx
 
     quant = isinstance(lp["up"], (q40.QTensor, q40.QLayerView))
 
@@ -490,19 +538,19 @@ def _routed_experts(xb2d: jax.Array, lp, cfg: ModelConfig,
         views = (lp["gate"], lp["up"], lp["down"])
         kernel = q40.all_experts_impl(views, 1, cfg.quant_impl)
         obs_dispatch.record_dispatch(
-            "moe", "select-chosen" if kernel else "select", rows=n, experts=e)
+            "moe", "select-chosen" if kernel else "select", rows=n, **site)
 
         def chosen_row(i):
             # one device, the fused kernel: the row's chosen planes are a grid
             # axis, three launches whatever k
-            xi, idx = xb2d[i:i + 1], top_idx[i]
+            xi, idx = xb2d[i:i + 1], sel_idx[i]
             with part("experts"):
-                g, u = (q40.matmul_experts(xi, w, e, kernel, chosen=idx)
+                g, u = (q40.matmul_experts(xi, w, held, kernel, chosen=idx)
                         for w in views[:2])
-                o = q40.matmul_experts(act(g) * u, views[2], e, kernel,
+                o = q40.matmul_experts(act(g) * u, views[2], held, kernel,
                                        out_dtype=jnp.float32, chosen=idx)  # (k, 1, D)
             with part("combine"):
-                return (weights[i][:, None, None] * o).sum(0)
+                return (sel_w[i][:, None, None] * o).sum(0)
 
         def looped_row(i):
             # a mesh, Q80 experts, the XLA path: per-(token, slot) matmuls on
@@ -511,15 +559,15 @@ def _routed_experts(xb2d: jax.Array, lp, cfg: ModelConfig,
             acc = jnp.zeros((1, d), jnp.float32)
             for j in range(k):
                 with part("experts"):
-                    sel = top_idx[i, j]
-                    up = lp["up"].select(sel, e)
-                    gate = lp["gate"].select(sel, e)
-                    down = lp["down"].select(sel, e)
+                    sel = sel_idx[i, j]
+                    up = lp["up"].select(sel, held)
+                    gate = lp["gate"].select(sel, held)
+                    down = lp["down"].select(sel, held)
                     h = act(_mm(xi, gate, cfg, kind="row")) * _mm(xi, up, cfg, kind="row")
                     o = q40.mm(h, down, impl=cfg.quant_impl, kind="col",
                                out_dtype=jnp.float32)
                 with part("combine"):
-                    acc = acc + weights[i, j] * o
+                    acc = acc + sel_w[i, j] * o
             return acc
 
         outs = [(chosen_row if kernel else looped_row)(i) for i in range(n)]
@@ -527,19 +575,21 @@ def _routed_experts(xb2d: jax.Array, lp, cfg: ModelConfig,
             return jnp.concatenate(outs, 0).astype(cfg.dtype)
 
     if n <= 4 and not quant:  # decode path: gather selected experts' weights
-        obs_dispatch.record_dispatch("moe", "select", rows=n, experts=e)
+        obs_dispatch.record_dispatch("moe", "select", rows=n, **site)
         with part("experts"):
-            up_w = jnp.take(lp["up"], top_idx, axis=0)      # (N, k, D, F)
-            gate_w = jnp.take(lp["gate"], top_idx, axis=0)  # (N, k, D, F)
-            down_w = jnp.take(lp["down"], top_idx, axis=0)  # (N, k, F, D)
+            up_w = jnp.take(lp["up"], sel_idx, axis=0)      # (N, k, D, F)
+            gate_w = jnp.take(lp["gate"], sel_idx, axis=0)  # (N, k, D, F)
+            down_w = jnp.take(lp["down"], sel_idx, axis=0)  # (N, k, F, D)
             h = act(jnp.einsum("nd,nkdf->nkf", xb2d, gate_w)) * jnp.einsum("nd,nkdf->nkf", xb2d, up_w)
             out = jnp.einsum("nkf,nkfd->nkd", h, down_w)
         with part("combine"):
-            return jnp.einsum("nk,nkd->nd", weights.astype(out.dtype), out)
+            return jnp.einsum("nk,nkd->nd", sel_w.astype(out.dtype), out)
 
     with part("router"):
         dense_w = jnp.zeros((n, e), weights.dtype)
         dense_w = jnp.put_along_axis(dense_w, top_idx, weights, axis=-1, inplace=False)
+        if share:  # the held experts' columns; from here on ``e`` planes = held
+            dense_w = dense_w[:, first:first + held]
 
     kernel = quant and q40.all_experts_impl(
         (lp["gate"], lp["up"], lp["down"]), n, cfg.quant_impl)
@@ -547,11 +597,11 @@ def _routed_experts(xb2d: jax.Array, lp, cfg: ModelConfig,
         # packed experts on the fused kernel, one device: the expert index is
         # a grid axis, three launches a layer whatever E; every expert is
         # read, as the masked loops below read them
-        obs_dispatch.record_dispatch("moe", "all-experts", rows=n, experts=e)
+        obs_dispatch.record_dispatch("moe", "all-experts", rows=n, **site)
         with part("experts"):
-            g = q40.matmul_experts(xb2d, lp["gate"], e, kernel)
-            u = q40.matmul_experts(xb2d, lp["up"], e, kernel)
-            o = q40.matmul_experts(act(g) * u, lp["down"], e, kernel,
+            g = q40.matmul_experts(xb2d, lp["gate"], held, kernel)
+            u = q40.matmul_experts(xb2d, lp["up"], held, kernel)
+            o = q40.matmul_experts(act(g) * u, lp["down"], held, kernel,
                                    out_dtype=jnp.float32)          # (E, N, D)
         with part("combine"):
             w_e = dense_w.T.astype(jnp.float32)[:, :, None]
@@ -570,19 +620,19 @@ def _routed_experts(xb2d: jax.Array, lp, cfg: ModelConfig,
         # exactly how the decode path already selects experts.
         def one_expert(ei):
             with part("experts"):
-                up = lp["up"].select(ei, e)
-                gate = lp["gate"].select(ei, e)
-                down = lp["down"].select(ei, e)
+                up = lp["up"].select(ei, held)
+                gate = lp["gate"].select(ei, held)
+                down = lp["down"].select(ei, held)
                 h = act(_mm(xb2d, gate, cfg, kind="row")) * _mm(xb2d, up, cfg, kind="row")
                 return q40.mm(h, down, impl=cfg.quant_impl, kind="col",
                               out_dtype=jnp.float32)
 
-        unrolled = e <= MOE_PREFILL_UNROLL_MAX
+        unrolled = held <= MOE_PREFILL_UNROLL_MAX
         obs_dispatch.record_dispatch("moe", "unrolled" if unrolled else "scan",
-                                     rows=n, experts=e)
+                                     rows=n, **site)
         if unrolled:
             out = jnp.zeros((n, d), jnp.float32)
-            for ei in range(e):
+            for ei in range(held):
                 oe = one_expert(jnp.int32(ei))
                 with part("combine"):
                     out = out + dense_w[:, ei:ei + 1].astype(jnp.float32) * oe
@@ -597,12 +647,12 @@ def _routed_experts(xb2d: jax.Array, lp, cfg: ModelConfig,
             # the loop itself (its counter, its carries) is the experts' too
             with part("experts"):
                 out, _ = jax.lax.scan(body, jnp.zeros((n, d), jnp.float32),
-                                      jnp.arange(e, dtype=jnp.int32))
+                                      jnp.arange(held, dtype=jnp.int32))
         with part("combine"):
             return out.astype(cfg.dtype)
 
     # prefill path: dense dispatch over all experts
-    obs_dispatch.record_dispatch("moe", "dense", rows=n, experts=e)
+    obs_dispatch.record_dispatch("moe", "dense", rows=n, **site)
     with part("experts"):
         h = act(jnp.einsum("nd,edf->nef", xb2d, lp["gate"])) * jnp.einsum("nd,edf->nef", xb2d, lp["up"])
         outs = jnp.einsum("nef,efd->ned", h, lp["down"])

@@ -1,24 +1,32 @@
-"""SmallThinker's layers: periods of one full and ``window_period - 1``
-sliding-window layers over a cache with two kinds of plane.
+"""Layers of a model that mixes sliding-window and full attention
+(SmallThinker, K-EXAONE): periods of ``window_period`` layers of which one, at
+``window_full_at``, is full, over a cache with two kinds of plane.
 
-A layer ``l`` with ``l % window_period == 0`` is *full*: no rotation at all
-(NoPE) and a causal mask over every position.  The others are *window* layers:
-rotate-half RoPE and the last ``window`` keys (``ops/window.py``).  Every layer
-is an expert layer whose router reads the layer's input ``x_l`` as it arrives,
-before the attention norm; its logits are handed to ``moe_ffn``, which chooses
-and weighs as for Mixtral (softmax over all, top-k, renormalised: equal to a
-softmax over the chosen logits).
+A layer ``l`` with ``l % window_period == window_full_at`` is *full*: no
+rotation at all (NoPE) and a causal mask over every position.  The others are
+*window* layers: rotate-half RoPE and the last ``window`` keys
+(``ops/window.py``).  What else a layer does follows from the config, not from
+a copy of the loop: K-EXAONE normalises each head of q and k before RoPE
+(``cfg.qk_head_norm``), has a dense FFN in its leading ``n_dense_layers`` and
+routes from the FFN's normed input as every arch but SmallThinker, whose
+router reads the layer's input ``x_l`` as it arrives, before the attention
+norm, and hands the logits to ``moe_ffn``.
 
 The contiguous cache (``init_cache``) keeps the two kinds apart: ``k`` / ``v``
 are the full layers' ``(Lf, B, Hkv, S, Dh)`` and ``wk`` / ``wv`` the window
-layers' rings ``(Lw, B, Hkv, R, Dh)``, ``R = cfg.window_ring(S)``; full layer
-``l`` is plane ``l // period``, window layer ``l`` is ring ``l - l // period -
-1``.  The paged pool is one pool and one table for all layers (``(L, P, ps,
-Hkv, Dh)``, indexed by ``l``), the window a bound on what is read.
+layers' rings ``(Lw, B, Hkv, R, Dh)``, ``R = cfg.window_ring(S)``.  The paged
+engine does the same with pages (``init_pool``): ``k`` / ``v`` are the full
+layers' pool ``(Lf, P, ps, Hkv, Dh)``, what ``--kv-pages`` counts and the
+scheduler's page tables address, and ``wk`` / ``wv`` the window layers' planes
+``(Lw, B * ring, ps, Hkv, Dh)`` in which a slot owns a ring of ``ring`` pages
+(``ops/window.py``).  Layer ``l``'s place in its kind's stack is
+``kind_index``.
 
 The layer loop is a ``lax.scan`` over periods whose body unrolls the period's
-layers, so a layer's kind is static where it is traced; weights stay stacked by
-layer and are indexed where used, as in ``transformer.run_blocks``.
+layers, so a layer's kind is static where it is traced; the periods that hold
+a dense layer are unrolled in front of the scan.  Weights stay stacked by
+layer (by segment for the two FFN kinds) and are indexed where used, as in
+``transformer.run_blocks``.
 """
 
 from __future__ import annotations
@@ -33,6 +41,12 @@ from ..ops.attention import (gqa_attention_at, live_gqa_attention,
 from ..ops.kernels import apply_rope, rmsnorm
 from ..ops.scopes import part, scope
 from .config import ModelConfig
+from .params import DENSE_FFN_KEYS, MOE_FFN_KEYS
+
+# rows of the widest slot step a slot's ring of pages is sized for: the
+# scheduler's default prefill chunk (``--sched-prefill-chunk``), and at least a
+# verify step's ``spec_k + 1``; a wider step is refused by name where traced
+SLOT_ROWS = 16
 
 
 # keys a trip of a full layer's live walk reads for ONE decoded token.  A trip
@@ -59,10 +73,40 @@ def init_cache(cfg: ModelConfig, batch: int, seq_len: int, dtype, quant: bool):
                    wk=jnp.zeros(win, dt), wv=jnp.zeros(win, dt))
 
 
-def _attention(x, lp, cfg: ModelConfig, cache, cos, sin, pos, layer, plane,
+def init_pool(cfg: ModelConfig, n_pages: int, page_size: int, dtype,
+              quant: bool, slots: int, max_pages: int):
+    """The paged engine's cache of a windowed model: the full layers' pool of
+    ``n_pages`` and, for ``slots`` slots, the window layers' rings of
+    ``window_pages(window, SLOT_ROWS, page_size, max_pages)`` pages each."""
+    from .transformer import KVCache
+    if quant:
+        raise ValueError("a cache with window layers has no int8 form yet "
+                         "(--kv-quant int8 is refused for this architecture)")
+    if slots < 1:
+        raise ValueError("a windowed model's pool needs the number of slots: "
+                         "each owns a ring of pages in the window layers' planes")
+    dt = dtype or cfg.dtype
+    page = (page_size, cfg.n_kv_heads, cfg.head_size)
+    ring = window.window_pages(cfg.window, SLOT_ROWS, page_size, max_pages)
+    full = (cfg.n_full_layers, n_pages) + page
+    win = (cfg.n_window_layers, slots * ring) + page
+    return KVCache(jnp.zeros(full, dt), jnp.zeros(full, dt),
+                   wk=jnp.zeros(win, dt), wv=jnp.zeros(win, dt))
+
+
+def kind_index(cfg: ModelConfig, p, j: int):
+    """The place of period ``p``'s ``j``-th layer among the layers of its kind
+    (``p`` may be traced, ``j`` is static)."""
+    if j == cfg.window_full_at:
+        return p
+    return p * (cfg.window_period - 1) + j - (j > cfg.window_full_at)
+
+
+def _attention(x, lp, cfg: ModelConfig, cache, cos, sin, pos, plane,
                windowed: bool, offsets, pos_rows, paged):
-    """One attention sub-block.  ``layer`` indexes the weights (and the paged
-    pool), ``plane`` the contiguous cache's stack of this layer's kind."""
+    """One attention sub-block; ``plane`` indexes the cache's stack of this
+    layer's kind (the contiguous planes or rings, the pool or the slots'
+    rings of pages)."""
     from .transformer import _mm
     b, t, _ = x.shape
     hq, hkv, dh = cfg.n_heads, cfg.n_kv_heads, cfg.head_size
@@ -77,6 +121,10 @@ def _attention(x, lp, cfg: ModelConfig, cache, cos, sin, pos, layer, plane,
         q = q.reshape(b, t, hq, dh)
         k = k.reshape(b, t, hkv, dh)
         v = v.reshape(b, t, hkv, dh)
+        if cfg.qk_head_norm:
+            with part("qk_norm"):
+                q = rmsnorm(q, lp["q_norm"], cfg.norm_eps)
+                k = rmsnorm(k, lp["k_norm"], cfg.norm_eps)
     with scope("rope"):
         if windowed:  # a full layer is not rotated at all
             q = apply_rope(q, cos, sin, interleaved=False)
@@ -85,20 +133,25 @@ def _attention(x, lp, cfg: ModelConfig, cache, cos, sin, pos, layer, plane,
         k = k.transpose(0, 2, 1, 3)
         v = v.transpose(0, 2, 1, 3)
     kind = "window" if windowed else "full"
-    if paged is not None:
-        page_table, pidx, oidx = paged
+    if paged is not None and windowed:
+        # the slot's ring of pages: its table is arithmetic (ops/window.py)
         with scope("kv_write"):
-            ck, cv = paged_update_kv_rows(cache.k, cache.v, k, v, layer, pidx,
+            wk, wv = paged_update_kv_rows(cache.wk, cache.wv, k, v, plane,
+                                          *paged[3])
+            cache = cache._replace(wk=wk, wv=wv)
+        with scope("attn"), part(kind):
+            att = window.paged_window_attention(q, cache.wk, cache.wv, plane,
+                                                pos_rows, cfg.window,
+                                                paged[0].shape[1])
+    elif paged is not None:
+        page_table, pidx, oidx = paged[:3]
+        with scope("kv_write"):
+            ck, cv = paged_update_kv_rows(cache.k, cache.v, k, v, plane, pidx,
                                           oidx)
             cache = cache._replace(k=ck, v=cv)
         with scope("attn"), part(kind):
-            if windowed:
-                att = window.paged_window_attention(
-                    q, cache.k, cache.v, layer, page_table, pos_rows,
-                    cfg.window)
-            else:
-                att = paged_gqa_attention_at(q, cache.k, cache.v, layer,
-                                             page_table, pos_rows)
+            att = paged_gqa_attention_at(q, cache.k, cache.v, plane,
+                                         page_table, pos_rows)
     elif windowed:
         ring = cache.wk.shape[3]
         if ring < cache.k.shape[3] and ring < cfg.window + t - 1:
@@ -145,39 +198,68 @@ def run_periods(params, cfg: ModelConfig, x, cache, cos, sin, pos, offsets,
     """All layers of a windowed model over the residual stream ``x (B, T,
     D)``; returns it and the updated cache (``transformer.run_blocks`` has
     embedded the tokens and made the angles)."""
-    from .transformer import moe_ffn
+    from ..io import mfile
+    from .transformer import _dense_ffn, moe_ffn
     b, t, d = x.shape
-    period = cfg.window_period
+    period, n_dense = cfg.window_period, cfg.n_dense_layers
+    router_first = cfg.arch == mfile.ARCH_SMALLTHINKER
     keys = [k for k in params if k not in ("embedding", "rms_final", "wcls")]
+    # a stack covers all layers, the dense prefix or the expert layers
+    dense_keys = [k for k in keys if n_dense and k in DENSE_FFN_KEYS]
+    moe_keys = [k for k in keys if n_dense and k in MOE_FFN_KEYS]
+    att_keys = [k for k in keys if k not in dense_keys and k not in moe_keys]
+    if paged is not None:
+        with scope("page_idx"):  # the window planes' write places, once
+            paged = paged + (window.paged_ring_indices(
+                pos_rows, t, cache.wk.shape[2], cache.wk.shape[1] // b),)
 
     def at(w, i):
         if isinstance(w, (q40.QTensor, q8.Q8Tensor)):
             return q40.QLayerView(w, i)
         return jax.lax.dynamic_index_in_dim(w, i, 0, keepdims=False)
 
-    def one_layer(x, kvc, layer, plane, windowed: bool):
-        lp = {k: at(params[k], layer) for k in keys}
-        with scope("moe"), part("router"):
-            # x_l as it enters the layer, before any norm
-            router_logits = (x.reshape(b * t, d).astype(jnp.float32)
-                             @ lp["router"].astype(jnp.float32))
-        att_out, kvc = _attention(x, lp, cfg, kvc, cos, sin, pos, layer, plane,
+    def one_layer(x, kvc, layer, j: int, plane, dense: bool):
+        """Layer ``layer`` (traced or static), the ``j``-th of its period."""
+        windowed = j != cfg.window_full_at
+        lp = {k: at(params[k], layer) for k in att_keys}
+        lp.update({k: at(params[k], layer - (0 if dense else n_dense))
+                   for k in (dense_keys if dense else moe_keys)})
+        router_logits = None
+        if router_first:
+            with scope("moe"), part("router"):
+                # x_l as it enters the layer, before any norm
+                router_logits = (x.reshape(b * t, d).astype(jnp.float32)
+                                 @ lp["router"].astype(jnp.float32))
+        att_out, kvc = _attention(x, lp, cfg, kvc, cos, sin, pos, plane,
                                   windowed, offsets, pos_rows, paged)
         with scope("wo"):
             x = x + att_out
         with scope("norm"):
             xb = rmsnorm(x, lp["rms_ffn"], cfg.norm_eps)
+        if dense:
+            ff = _dense_ffn(xb, lp, cfg)
+            with scope("w2"):
+                return x + ff, kvc
         with scope("moe"):
             ff = moe_ffn(xb.reshape(b * t, d), lp, cfg, router_logits)
             return x + ff.reshape(b, t, d), kvc
 
-    def one_period(carry, p):
+    def one_period(carry, p, first_dense: int = 0):
+        """Period ``p`` (traced in the scan, static in front of it); its first
+        ``first_dense`` layers have the dense FFN."""
         x, kvc = carry
-        x, kvc = one_layer(x, kvc, p * period, p, False)
-        for j in range(1, period):
-            x, kvc = one_layer(x, kvc, p * period + j,
-                               p * (period - 1) + (j - 1), True)
+        for j in range(period):
+            x, kvc = one_layer(x, kvc, p * period + j, j, kind_index(cfg, p, j),
+                               dense=j < first_dense)
         return (x, kvc), None
 
-    return jax.lax.scan(one_period, (x, cache),
-                        jnp.arange(cfg.n_layers // period, dtype=jnp.int32))[0]
+    carry = (x, cache)
+    lead = -(-n_dense // period)  # periods with a dense layer: unrolled
+    for p in range(lead):
+        carry, _ = one_period(carry, jnp.int32(p),
+                              min(n_dense - p * period, period))
+    n_periods = cfg.n_layers // period
+    if n_periods > lead:
+        carry, _ = jax.lax.scan(one_period, carry,
+                                jnp.arange(lead, n_periods, dtype=jnp.int32))
+    return carry

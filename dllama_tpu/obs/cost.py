@@ -511,7 +511,11 @@ def model_from_engine(engine) -> CostModel | None:
             tp=engine.mesh.shape.get("tp", 1), paged=bool(engine.paged),
             page_size=getattr(engine, "kv_page_size", 0) or 0,
             n_experts=getattr(cfg, "n_experts", 0) or 0,
-            n_active_experts=getattr(cfg, "n_active_experts", 0) or 0,
+            # of a token's k routed experts, those held here on average (all
+            # k unless the file is a share of an expert-parallel deployment)
+            n_active_experts=max(1, round(
+                cfg.n_active_experts * cfg.n_experts_held / cfg.n_experts))
+            if cfg.is_moe else 0,
             fused=fused, n_dense_layers=cfg.n_dense_layers,
             moe_hidden_dim=cfg.moe_hidden_dim,
             n_shared_experts=cfg.n_shared_experts,

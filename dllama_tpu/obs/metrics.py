@@ -724,6 +724,11 @@ KV_HOST_POOL_BYTES = REGISTRY.gauge(
     "kv_host_pool_bytes",
     "Bytes of spilled KV currently resident in the host-RAM pool "
     "(bounded by --kv-host-pool-mb).")
+KV_WINDOW_PAGES_RECYCLED = REGISTRY.counter(
+    "kv_window_pages_recycled",
+    "Pages of slot rings rewritten in place (a windowed model on the paged "
+    "engine): each is a page behind the window released, per window layer "
+    "kind, not per layer.")
 KV_PAGE_CODEC = REGISTRY.labeled_gauge(
     "kv_page_codec", "codec",
     "Active paged-KV page format (1 for the engine's codec: the pool "

@@ -17,7 +17,8 @@ the two.  An op whose name path carries none of these is ``unscoped``.
 Inside ``moe`` the work is split further by :func:`part` into ``router``,
 ``experts``, ``combine`` and (DeepSeek-V2) ``shared``; inside ``qkv`` MLA's
 ``q_lora`` / ``kv_lora``; inside ``attn`` MLA's ``absorb`` / ``latent`` /
-``expand`` and a windowed model's ``window`` / ``full`` by layer kind
+``expand`` and a windowed model's ``window`` / ``full`` by layer kind;
+inside ``qkv`` also K-EXAONE's ``qk_norm``
 (``PARTS``, by scope): plain sub-names, not scopes.  An
 op's path then ends ``.../moe/experts/...`` and a reader that knows only
 ``SCOPES`` still files it under ``moe``; ``by-scope.json``'s op table
@@ -56,12 +57,13 @@ PARTS = {
     "qkv": (
         "q_lora",    # MLA: q's latent norm and the up-projection to the heads
         "kv_lora",   # MLA: the down-projection(s) from x, the latent's norm
+        "qk_norm",   # K-EXAONE: the RMSNorm of each head of q and of k
     ),
     "attn": (
         "absorb",    # MLA absorbed form: W_uk into the query, W_uv out of the result
         "latent",    # MLA absorbed form: the walk over latent rows
         "expand",    # MLA expanded form: a block's rows through W_kvb, in the walk
-        "window",    # a sliding-window layer's read (ring or bounded page gather)
+        "window",    # a sliding-window layer's read (a row's ring, or a slot's ring of pages)
         "full",      # a windowed model's full (unrotated) layer's read
     ),
     "moe": (

@@ -1,5 +1,5 @@
 """Sliding-window attention: a ring of positions on the contiguous cache, a
-bounded gather on the paged pool.
+slot's ring of pages beside the paged pool.
 
 A window layer's query at position ``p`` sees key ``j`` iff ``p - window < j
 <= p`` (``window`` keys, the query's own included).  So its contiguous cache
@@ -18,14 +18,22 @@ last position alone (``last - ((last - slot) mod R)``; negative: never
 written).  Every row has a clock of its own (``pos`` is ``(B,)``): the
 one-stream engine passes its scalar broadcast, the slot scheduler its rows'.
 
-On the paged pool the pages are the model's one pool and one table for every
-layer; a window layer gathers only the pages its window and the step's rows
-span, ``ceil((window + T - 1) / ps) + 1`` of them from the window's first
-page (runtime/pagepool.py does not release the pages behind it yet).
+On the paged engine the window layers have planes of their own beside the
+full layers' pool (``models/transformer.py init_kv_pool``): ``(Lw, B * ring,
+ps, Hkv, Dh)``, in which slot ``b`` owns pages ``b * ring .. b * ring + ring -
+1`` for its whole life: the window kind's page table is this arithmetic, and
+the scheduler's ``PagePool`` and radix tree manage the full layers' pages
+alone.  ``ring = window_pages(window, rows, ps, table width)`` pages hold the
+window of a step's first query and the step's own rows, so position ``p`` is
+written to ring page ``(p // ps) % ring`` whatever the context's depth: the
+page behind the window is the next one written, which is the release
+(``paged_ring_indices``).  The read takes the slot's whole ring, a contiguous
+slice, with the contiguous ring's rule for the position a slot holds
+(``paged_window_attention``).
 
 Ledger families, one a compiled call site: ``{codec="attn",
-path="window-walk"}`` (the ring) and ``{codec="kv_dense",
-path="window-gather"}`` (the pool).
+path="window-walk"}`` (the contiguous ring) and ``{codec="kv_dense",
+path="window-ring"}`` (a slot's ring of pages).
 """
 
 from __future__ import annotations
@@ -149,34 +157,60 @@ def ring_attention(q: jax.Array, ring_k: jax.Array, ring_v: jax.Array,
 
 
 def window_pages(window: int, t: int, page_size: int, max_pages: int) -> int:
-    """Pages a window layer's step of ``t`` rows reads of a slot's table."""
+    """Pages of a slot's ring for steps of up to ``t`` rows: the window of the
+    step's first query and the step's rows may start and end inside a page,
+    hence one more; never more than the slot's table is wide (a context that
+    fits them never wraps)."""
     return min(max_pages, -(-(window + t - 1) // page_size) + 1)
 
 
-def paged_window_attention(q: jax.Array, pool_k: jax.Array, pool_v: jax.Array,
-                           layer: jax.Array, page_table: jax.Array,
-                           pos_rows: jax.Array, window: int) -> jax.Array:
-    """Sliding-window GQA through the page table: row ``b``'s ``T`` queries at
-    ``pos_rows[b] ..`` over the ``window_pages`` pages of its table from the
-    window's first page on, gathered from the dense pool ``(L, P, ps, Hkv,
-    Dh)`` and scored in one shot.  Pages behind the window are not read."""
+def paged_ring_indices(pos_rows: jax.Array, t: int, page_size: int, ring: int
+                       ) -> tuple[jax.Array, jax.Array]:
+    """``(page, offset)`` index arrays, both ``(B, T)``, of a slot step's writes
+    into the window planes: slot ``b``'s token at position ``p`` goes to page
+    ``b * ring + (p // ps) % ring``, offset ``p % ps``.  Computed once a
+    forward (every window layer writes the same places).  Rows past a slot's
+    ``n_valid`` are written too, as the contiguous ring's: they land ahead of
+    the live position, at most ``t - 1`` rows, and overwrite positions a whole
+    ring behind them, which no later query's window reaches."""
+    tpos = pos_rows[:, None] + jnp.arange(t)[None, :]
+    base = jnp.arange(pos_rows.shape[0], dtype=jnp.int32)[:, None] * ring
+    return ((base + (tpos // page_size) % ring).astype(jnp.int32),
+            (tpos % page_size).astype(jnp.int32))
+
+
+def paged_window_attention(q: jax.Array, ring_k: jax.Array, ring_v: jax.Array,
+                           layer: jax.Array, pos_rows: jax.Array, window: int,
+                           max_pages: int) -> jax.Array:
+    """Sliding-window GQA over the slots' rings of pages: row ``b``'s ``T``
+    queries at ``pos_rows[b] ..`` against its own ``ring`` pages of the window
+    planes ``(Lw, B * ring, ps, Hkv, Dh)`` at window layer ``layer``, which
+    already hold the step's keys, scored in one shot.  The position a ring
+    slot holds follows from the row's last position alone (as
+    :func:`ring_attention`); a slot not yet written, or written by the slot's
+    previous request, comes out negative or above the query and is masked.
+    ``max_pages`` is the slots' table width: a ring that wide never wraps."""
     b, hq, t, dh = q.shape
-    ps, hkv = pool_k.shape[2], pool_k.shape[3]
-    maxp = page_table.shape[1]
+    ps, hkv = ring_k.shape[2], ring_k.shape[3]
+    ring = ring_k.shape[1] // b
+    r = ring * ps
     g = hq // hkv
-    n = window_pages(window, t, ps, maxp)
-    obs_dispatch.record_dispatch("kv_dense", "window-gather", t=t, s=n * ps,
+    if r < window + t - 1 and ring < max_pages:
+        raise ValueError(
+            f"a step of {t} rows does not fit a window layer's ring of {ring} "
+            f"pages of {ps} (window {window}): the engine sized it for fewer "
+            "rows a step")
+    obs_dispatch.record_dispatch("kv_dense", "window-ring", t=t, s=r,
                                  page_size=ps, window=window)
-    first = jnp.clip((pos_rows - window + 1) // ps, 0, maxp - n)      # (B,)
-    pids = jnp.take_along_axis(page_table,
-                               first[:, None] + jnp.arange(n)[None, :], axis=1)
 
-    def view(pool):  # (B, n, ps, Hkv, Dh) -> (B, Hkv, n * ps, Dh)
-        pages = pool[layer.astype(jnp.int32), pids]
-        return pages.transpose(0, 3, 1, 2, 4).reshape(b, hkv, n * ps, dh)
+    def view(planes):  # (B * ring, ps, Hkv, Dh) of the layer -> (B, Hkv, r, Dh)
+        own = jax.lax.dynamic_index_in_dim(planes, layer.astype(jnp.int32), 0,
+                                           keepdims=False)
+        return own.reshape(b, r, hkv, dh).transpose(0, 2, 1, 3)
 
-    k_l, v_l = view(pool_k), view(pool_v)
-    key_pos = first[:, None] * ps + jnp.arange(n * ps)[None, :]
+    k_l, v_l = view(ring_k), view(ring_v)
+    last = pos_rows + (t - 1)                                          # (B,)
+    key_pos = last[:, None] - (last[:, None] - jnp.arange(r)[None, :]) % r
     q_pos = pos_rows[:, None] + jnp.arange(t)[None, :]
     mask = _window_mask(key_pos, q_pos, window)
     qc = q.reshape(b, hkv, g, t, dh).astype(k_l.dtype)

@@ -29,6 +29,7 @@ from __future__ import annotations
 import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from ..io import mfile
 from ..models.config import ModelConfig
 from ..obs import metrics as obs_metrics, trace as obs_trace
 from ..models.params import DENSE_FFN_KEYS, MLA_ATT_KEYS, MOE_FFN_KEYS
@@ -68,10 +69,11 @@ def check_tp_constraint(cfg: ModelConfig, tp: int) -> None:
 
 def param_specs(cfg: ModelConfig) -> dict[str, P]:
     """PartitionSpec per parameter (layer-stacked layouts from params.py)."""
-    if cfg.is_mla:
-        # one device (the engine refuses a tp / sp / ep mesh for this arch):
+    if cfg.is_mla or cfg.arch == mfile.ARCH_EXAONE_MOE:
+        # one device (the engine refuses a tp / sp / ep mesh for these archs):
         # every stack whole, whatever its fused or unfused name
         return dict.fromkeys(("embedding", "rms_final", "wcls") + MLA_ATT_KEYS
+                             + ("wq", "wk", "wv", "wqkv", "q_norm", "k_norm")
                              + DENSE_FFN_KEYS + MOE_FFN_KEYS, REPL)
     specs = {
         "embedding": REPL,                   # root-owned in the reference; replicated here

@@ -31,6 +31,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from ..io import mfile
 from ..models.config import ModelConfig
 from ..models.params import Params
 from ..models.transformer import (LATENT_PAGE_AXES, PAGE_AXES, forward_last,
@@ -295,16 +296,20 @@ def _refuse_mesh_and_int8(mesh, kv_dtype, what: str, why: str) -> None:
                          "its cache has no int8 form")
 
 
-def _note_cache_bytes(cache, tokens: int, batch: int) -> int:
+def _note_cache_bytes(cache, tokens: int, batch: int, paged: bool) -> int:
     """Set the cache's gauges from its own arrays and return what one cached
     token occupies over all layers.  A windowed model's rings hold fewer
     positions than its full planes, so each plane is counted at its own
     positions a row: ``kv_cache_bytes{kind="window"}`` is what the bound on
-    the rings saves against ``kind="full"``'s planes per layer."""
+    the rings saves against ``kind="full"``'s planes per layer.  On a paged
+    engine a token ADDS its bytes in the pool alone (the full layers'): a
+    slot's ring of pages is there whatever the context's depth."""
     per_token, by_kind = 0, {"full": 0, "window": 0}
     for name, a in cache.planes().items():
         kind = "window" if name in ("wk", "wv") else "full"
         by_kind[kind] += int(a.nbytes)
+        if paged and kind == "window":
+            continue
         positions = tokens if kind == "full" else batch * a.shape[3]
         per_token += int(a.nbytes) // positions
     for kind, nbytes in by_kind.items():
@@ -365,7 +370,8 @@ class Engine:
                 "the latent cache would be replicated and the heads sharded")
         if cfg.window:
             _refuse_mesh_and_int8(
-                self.mesh, kv_dtype, "a windowed (SmallThinker) model",
+                self.mesh, kv_dtype,
+                f"a windowed ({mfile.ARCH_NAMES[cfg.arch]}) model",
                 "its two cache kinds have one placement")
         if self.mesh.shape.get("tp", 1) > 1 \
                 and jax.default_backend() != "tpu" \
@@ -438,7 +444,8 @@ class Engine:
             self.cache = _zeros_on_mesh(
                 lambda: init_kv_pool(cfg, self.kv_pages, self.kv_page_size,
                                      dtype=None if kv_quant else kv_dtype,
-                                     quant=kv_quant),
+                                     quant=kv_quant, slots=batch,
+                                     max_pages=self.max_pages_per_slot),
                 self._cache_sh)
             obs_metrics.KV_PAGE_CODEC.set(
                 "int8" if kv_quant else str(self.cache.k.dtype), 1)
@@ -453,7 +460,8 @@ class Engine:
         # model's rings at their own positions)
         tokens = (self.kv_pages * self.kv_page_size if self.paged
                   else batch * self.seq_len)
-        self.kv_bytes_per_token = _note_cache_bytes(self.cache, tokens, batch)
+        self.kv_bytes_per_token = _note_cache_bytes(self.cache, tokens, batch,
+                                                    self.paged)
         self.pos = 0
 
         def step(params, cache, tokens, pos, last_index, offsets=None):
@@ -646,6 +654,7 @@ class Engine:
         if not self.paged:
             raise ValueError("per-request hand-off needs a paged KV cache "
                              "(kv_pages > 0)")
+        self._refuse_slot_rings("per-request hand-off (DLREQ01)")
         c = self.cfg
         k = self.cache.k
         fields = {
@@ -687,6 +696,23 @@ class Engine:
             self._chunk_counter += 1
         return self._dev_key
 
+    @property
+    def ring_pages(self) -> int:
+        """Pages of a slot's ring in a windowed model's window planes (0: the
+        engine has none)."""
+        wk = self.cache.wk if self.paged else None
+        return 0 if wk is None else wk.shape[1] // self.batch
+
+    def _refuse_slot_rings(self, what: str) -> None:
+        """A windowed model's window layers keep a slot's last ``window``
+        positions in the slot's own ring of pages, which no page id addresses:
+        what moves a request's cache page by page is refused by name."""
+        if self.ring_pages:
+            raise ValueError(
+                f"{what} is not supported for a windowed "
+                f"({mfile.ARCH_NAMES[self.cfg.arch]}) model: its window "
+                "layers' slot rings are not carried page by page")
+
     def read_pool_pages(self, pages) -> dict[str, np.ndarray]:
         """Copy the given physical pages out of the paged pool to host
         numpy, all layers at once: shape ``(L, n, ps, Hkv, Dh)`` (plus the
@@ -719,7 +745,7 @@ class Engine:
                 return np.asarray(self._dev)
 
         return {f"pages.{n}": _Handle(a[:, idx])
-                for n, a in self.cache.planes().items()}
+                for n, a in self.cache.pool_planes().items()}
 
     def write_pool_pages(self, pages, arrays: dict[str, np.ndarray]) -> None:
         """Write exported page slices (from :meth:`read_pool_pages` on a
@@ -727,9 +753,10 @@ class Engine:
         One transient pool copy — acceptable at hand-off import time,
         which is off the steady-state decode path."""
         idx = jnp.asarray(np.asarray(pages, np.int32))
+        self._refuse_slot_rings("writing a request's pages into the pool")
         cache = self.cache._replace(**{
             n: a.at[:, idx].set(jnp.asarray(arrays[f"pages.{n}"], a.dtype))
-            for n, a in self.cache.planes().items()})
+            for n, a in self.cache.pool_planes().items()})
         self.cache = jax.device_put(cache, self._cache_sh)
 
     def _sync(self, arrays, what: str) -> list[str]:

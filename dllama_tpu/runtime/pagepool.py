@@ -33,6 +33,20 @@ from __future__ import annotations
 from ..obs import metrics as obs_metrics, trace as obs_trace
 
 
+def ring_pages_recycled(pos0: int, pos1: int, page_size: int, ring: int) -> int:
+    """Ring pages a slot rewrites when its clock moves from ``pos0`` to
+    ``pos1`` (positions ``pos0 .. pos1 - 1`` written): a slot's ring of
+    ``ring`` pages in a windowed model's window planes (ops/window.py) holds
+    logical page ``n`` in ring page ``n % ring``, so each logical page from the
+    ``ring``-th on that the clock enters takes the place of the one ``ring``
+    pages behind it."""
+    if pos1 <= pos0:
+        return 0
+    first = max(-(-pos0 // page_size), ring)   # first logical page entered
+    last = (pos1 - 1) // page_size             # last logical page written
+    return max(0, last - first + 1)
+
+
 class PagePoolExhausted(RuntimeError):
     """No free pages for an allocation; the caller defers admission."""
 

@@ -88,7 +88,8 @@ from ..obs import events as obs_events, flight as obs_flight
 from ..obs import metrics as obs_metrics, trace as obs_trace
 from ..obs.log import get_logger, new_request_id, request_id_var
 from .faults import FAULTS
-from .pagepool import PagePool, PagePoolExhausted, RadixTree
+from .pagepool import (PagePool, PagePoolExhausted, RadixTree,
+                       ring_pages_recycled)
 
 _log = get_logger("runtime.scheduler")
 
@@ -285,6 +286,24 @@ class SlotScheduler:
         # whole request (prompt + budget), so a dispatch can never fail on
         # allocation and exhaustion surfaces as queueing → 429.
         self.paged = bool(getattr(engine, "paged", False))
+        # a windowed model's window layers keep a slot's last ``window``
+        # positions in the slot's own ring of pages (ops/window.py), which no
+        # page id addresses.  So nothing may start a slot past position 0
+        # without having written those positions: the radix tree stays off (a
+        # hit would bind the full layers' prefix pages and leave the rings
+        # empty; ROADMAP M(a)(3)), and so does preemption, whose park and
+        # resume move a request page by page
+        self.ring_pages = int(getattr(engine, "ring_pages", 0))
+        if self.ring_pages:
+            prefix_reuse = preempt = False
+            if kv_reserve == "optimistic":
+                engine._refuse_slot_rings("--kv-reserve optimistic (the spill tier)")
+            from ..models.windowed import SLOT_ROWS
+            if max(int(prefill_chunk), int(spec_k) + 1 if spec else 0) > SLOT_ROWS:
+                raise ValueError(
+                    f"a step of more than {SLOT_ROWS} rows (--sched-prefill-chunk "
+                    f"{prefill_chunk}, --spec-k {spec_k}) does not fit the slot "
+                    "rings of a windowed model's window layers")
         self.pool: PagePool | None = None
         self.prefix_cache: RadixTree | None = None
         # KV tiering (runtime/kvtier.py): under ``optimistic`` reservation
@@ -312,7 +331,7 @@ class SlotScheduler:
             cache = engine.cache
             self._page_nbytes = sum(
                 int(np.prod(a.shape[:1] + a.shape[2:])) * a.dtype.itemsize
-                for a in cache.planes().values())
+                for a in cache.pool_planes().values())
             obs_metrics.KV_PAGES_TOTAL.set(self.pool.capacity)
             obs_metrics.KV_PAGES_IN_USE.set(0)
         self._queue: deque[Ticket] = deque()
@@ -808,7 +827,7 @@ class SlotScheduler:
         # planes iff the pool is int8 — an
         # int8 record into a dense pool (or vice versa) already failed
         # the fingerprint above, this validates shape against position
-        page_names = [f"pages.{n}" for n in eng.cache.planes()]
+        page_names = [f"pages.{n}" for n in eng.cache.pool_planes()]
         page_arrays: dict = {}
         for name in page_names:
             ref = getattr(eng.cache, name.split(".", 1)[1])
@@ -2361,7 +2380,7 @@ class SlotScheduler:
                 tok = int(out[j, i])
                 if j == 0 and s.fed < len(t.prompt):
                     s.fed += int(n_valid[i])
-                    s.pos += int(n_valid[i])
+                    self._advance(s, int(n_valid[i]))
                     if s.fed < len(t.prompt):
                         continue  # mid-prefill: sample not meaningful yet
                     # prefill just completed: this sample IS the first
@@ -2377,7 +2396,7 @@ class SlotScheduler:
                             self.prefix_cache.insert(
                                 t.prompt[:n_full * ps], s.pages[:n_full])
                 else:
-                    s.pos += 1
+                    self._advance(s, 1)
                 s.last = tok
                 if tok in t.eos_ids:
                     with self._cond:
@@ -2390,6 +2409,19 @@ class SlotScheduler:
                 if s.produced >= t.max_new or s.pos >= eng.seq_len:
                     with self._cond:
                         self._retire(i, "length")
+
+    def _advance(self, s: _Slot, n: int) -> None:
+        """Move a slot's clock ``n`` positions on.  Where the engine keeps
+        slot rings (a windowed model), every page the clock enters past the
+        ring's first lap lands on the ring page that held the page ``ring``
+        pages back, in each window layer: the release of the pages behind the
+        window, counted in ``kv_window_pages_recycled``."""
+        if self.ring_pages:
+            recycled = ring_pages_recycled(s.pos, s.pos + n,
+                                           self.pool.page_size, self.ring_pages)
+            if recycled:
+                obs_metrics.KV_WINDOW_PAGES_RECYCLED.inc(recycled)
+        s.pos += n
 
     def _fanout_verify(self, active: list[int], preds, accepted,
                        proposed_by_slot: dict[int, int],
@@ -2424,7 +2456,7 @@ class SlotScheduler:
                 if a:
                     obs_metrics.SCHED_SPEC_ACCEPTED.inc(self.spec.name, n=a)
             for tok in (int(preds[i, j]) for j in range(a + 1)):
-                s.pos += 1
+                self._advance(s, 1)
                 s.last = tok
                 if tok in t.eos_ids:
                     self._retire(i, "stop")

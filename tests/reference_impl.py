@@ -325,3 +325,97 @@ def np_forward_smallthinker(params, cfg, tokens, wrong=None):
         x = x + out
     x = norm(x, np.asarray(params["rms_final"]))
     return (x @ params["wcls"]).astype(np.float32)
+
+
+def exaone_moe_layer(m, lp, cfg, share=None, wrong=None, shared=True):
+    """K-EXAONE's expert FFN over normed rows ``m (T, D)``, float32 loops: a
+    sigmoid router over all ``n_experts``, the top ``k`` of score + bias, the
+    chosen scores normalised over ALL k and scaled; then the routed sum over
+    the experts of ``share = (first, held)`` (``None``: all of them; ``lp``'s
+    expert stacks hold exactly those, file index ``e`` being the router's
+    ``first + e``) and, with ``shared``, the shared expert.
+
+    ``wrong``: ``bias_in_weights``, ``softmax_router``, ``no_scale``,
+    ``norm_over_held`` (the weights normalised over the held chosen only)."""
+    first, held = share or (0, cfg.n_experts)
+    k = cfg.n_active_experts
+    logits = m.astype(np.float32) @ lp["router"]
+    s = softmax(logits) if wrong == "softmax_router" else 1.0 / (1.0 + np.exp(-logits))
+    biased = s + lp["router_bias"]
+    out = np.zeros_like(m)
+    for i in range(len(m)):
+        idx = np.argsort(-biased[i], kind="stable")[:k]
+        w = (biased if wrong == "bias_in_weights" else s)[i, idx]
+        here = (idx >= first) & (idx < first + held)
+        w = w / max(w[here].sum() if wrong == "norm_over_held" else w.sum(), 1e-30)
+        if wrong != "no_scale":
+            w = w * cfg.routed_scale
+        for wj, e in zip(w[here], idx[here] - first):
+            out[i] += wj * ((silu(m[i] @ lp["gate"][e]) * (m[i] @ lp["up"][e]))
+                            @ lp["down"][e])
+    if shared and cfg.n_shared_experts:
+        out += (silu(m @ lp["shared_w1"]) * (m @ lp["shared_w3"])) @ lp["shared_w2"]
+    return out
+
+
+def np_forward_exaone_moe(params, cfg, tokens, wrong=None):
+    """K-EXAONE's full-sequence forward, (T, V) logits, for the share the
+    params hold (``cfg.first_expert``, ``cfg.n_experts_held``): layer ``l`` is
+    full and unrotated where ``l % window_period == window_full_at`` and
+    otherwise a sliding-window layer with rotate-half RoPE; each head of q and
+    k is RMS-normalised before RoPE; the first ``n_dense_layers`` layers have a
+    dense SwiGLU, the others :func:`exaone_moe_layer`.  No cache, loops over
+    layers, heads and rows.
+
+    ``wrong`` names one deliberate fault: ``rope_on_full``, ``full_first``
+    (the full layer at the period's start), ``no_head_norm``, ``window_plus_one``,
+    or one of :func:`exaone_moe_layer`'s."""
+    t = len(tokens)
+    hq, hkv, dh = cfg.n_heads, cfg.n_kv_heads, cfg.head_size
+    pos = np.arange(t)
+    width = cfg.window + (1 if wrong == "window_plus_one" else 0)
+    full_at = 0 if wrong == "full_first" else cfg.window_full_at
+
+    def norm(x, w):
+        ms = np.mean(x.astype(np.float64) ** 2, axis=-1, keepdims=True)
+        return (w * (x / np.sqrt(ms + cfg.norm_eps))).astype(np.float32)
+
+    def stack(key, li):
+        a = params[key]
+        if key in ("w1", "w2", "w3"):
+            return np.asarray(a[li]) if li < cfg.n_dense_layers else None
+        if a.shape[0] == cfg.n_layers - cfg.n_dense_layers != cfg.n_layers:
+            return np.asarray(a[li - cfg.n_dense_layers]) if li >= cfg.n_dense_layers else None
+        return np.asarray(a[li])
+
+    x = params["embedding"][tokens].astype(np.float32)
+    for li in range(cfg.n_layers):
+        lp = {k: stack(k, li) for k in params
+              if k not in ("embedding", "rms_final", "wcls")}
+        windowed = li % cfg.window_period != full_at
+        xb = norm(x, lp["rms_att"])
+        q = (xb @ lp["wq"]).reshape(t, hq, dh)
+        k = (xb @ lp["wk"]).reshape(t, hkv, dh)
+        v = (xb @ lp["wv"]).reshape(t, hkv, dh)
+        if wrong != "no_head_norm":
+            q, k = norm(q, lp["q_norm"]), norm(k, lp["k_norm"])
+        if windowed or wrong == "rope_on_full":
+            q = rope_rotate(q, pos, cfg.rope_theta, False)
+            k = rope_rotate(k, pos, cfg.rope_theta, False)
+        mask = pos[None, :] <= pos[:, None]
+        if windowed:
+            mask &= pos[None, :] > pos[:, None] - width
+        att = np.zeros((t, hq, dh), np.float32)
+        for h in range(hq):
+            kh = h // (hq // hkv)
+            sc = np.where(mask, (q[:, h] @ k[:, kh].T) / np.sqrt(dh), -np.inf)
+            att[:, h] = softmax(sc) @ v[:, kh]
+        x = x + att.reshape(t, hq * dh) @ lp["wo"]
+        m = norm(x, lp["rms_ffn"])
+        if li < cfg.n_dense_layers:
+            x = x + (silu(m @ lp["w1"]) * (m @ lp["w3"])) @ lp["w2"]
+        else:
+            x = x + exaone_moe_layer(
+                m, lp, cfg, (cfg.first_expert, cfg.n_experts_held), wrong)
+    x = norm(x, np.asarray(params["rms_final"]))
+    return (x @ params["wcls"]).astype(np.float32)

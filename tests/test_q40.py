@@ -285,9 +285,19 @@ CELL_SHAPES = [
     ("yi-34b", "w1/w3", 7168, 20480, "row", (1024, 1024), 7168),
     ("yi-34b", "w2", 20480, 7168, "col", (1024, 1024), 20480),
     ("yi-34b", "wcls", 7168, 64000, "row", (1024, 1024), 7168),
+    # K-EXAONE's share (PR 40): every side divides by 1024 but the vocabulary's
+    # 19200 rows, whose last d tile is ragged by 768
+    ("k-exaone-236b-a23b", "wqkv", 6144, 10240, None, (1024, 1024), 6144),
+    ("k-exaone-236b-a23b", "wo", 8192, 6144, None, (1024, 1024), 8192),
+    ("k-exaone-236b-a23b", "gate/up", 6144, 2048, None, (1024, 1024), 6144),
+    ("k-exaone-236b-a23b", "down", 2048, 6144, None, (1024, 1024), 2048),
+    ("k-exaone-236b-a23b", "shared_w13", 6144, 4096, None, (1024, 1024), 6144),
+    ("k-exaone-236b-a23b", "w13", 6144, 36864, None, (1024, 1024), 6144),
+    ("k-exaone-236b-a23b", "w2", 18432, 6144, None, (1024, 1024), 18432),
+    ("k-exaone-236b-a23b", "wcls", 6144, 19200, None, (1024, 1024), 6144),
 ]
 # the cells that bypass the rule's new part: every shape divides by 1024
-PARENTS_OWN = ("mistral-7b", "olmoe-1b-7b")
+PARENTS_OWN = ("mistral-7b", "olmoe-1b-7b", "k-exaone-236b-a23b")
 
 
 class TestTiles:

@@ -218,8 +218,12 @@ def test_slot_path_chunks_mixed_step_pure_decode(params, want, paged, small_chun
     tokens), a mixed step, then pure-decode steps past the window and, on the
     contiguous slots, around the ring.  Pages out of order."""
     if paged:
-        cache = init_kv_pool(CFG, 40, 4)
-        assert cache.k.shape == (8, 40, 4, 4, 8) and cache.wk is None
+        # a pool for the 2 full layers, and for the 6 window layers 2 slots'
+        # rings of 9 pages (window 16 + 16 rows - 1, and one more): 36
+        # positions a slot under sequences of 53 and 57, so the rings wrap
+        cache = init_kv_pool(CFG, 40, 4, slots=2)
+        assert cache.k.shape == (2, 40, 4, 4, 8)
+        assert cache.wk.shape == (6, 2 * 9, 4, 4, 8)
         table = jnp.asarray(np.stack([
             np.random.RandomState(1).permutation(np.arange(1, 20)),
             np.arange(20, 39)]).astype(np.int32))
@@ -253,7 +257,7 @@ def test_slot_path_chunks_mixed_step_pure_decode(params, want, paged, small_chun
 def test_verify_window_keeps_every_position(params, want):
     """``forward_slots_all`` (the speculative verify step) over 5 tokens past
     the window on a pool: every position's logits are the reference's."""
-    pool = init_kv_pool(CFG, 12, 4)
+    pool = init_kv_pool(CFG, 12, 4, slots=1, max_pages=8)
     table = jnp.asarray(np.array([[3, 7, 1, 9, 5, 2, 8, 4]], np.int32))
     _, pool = forward_slots(params, CFG, jnp.asarray(TOKS[None, :24]), pool,
                             jnp.zeros((1,), jnp.int32), jnp.full((1,), 24, jnp.int32),
@@ -370,6 +374,8 @@ def test_engine_scheduler_contiguous_and_paged_serve_the_same_tokens(
     cfg, params = load_params(mf, dtype=jnp.float32, keep_quantized=True)
     solo = Engine(cfg, params, mesh=_mesh(), batch=1)
     per_token = cfg.n_layers * 2 * cfg.kv_dim * 4
+    # what a token adds to a paged pool: the full layers' pages alone
+    pool_token = cfg.n_full_layers * 2 * cfg.kv_dim * 4
     assert solo.kv_bytes_per_token == per_token
     tok = 2 * cfg.kv_dim * 4
     assert obs_metrics.KV_CACHE_BYTES._values == {
@@ -382,7 +388,7 @@ def test_engine_scheduler_contiguous_and_paged_serve_the_same_tokens(
             p, len(p) + 40, temperature=0.0, chunk=5)][len(p):])
     for kw in (dict(), dict(kv_pages=2 * (cfg.seq_len // 4) + 1, kv_page_size=4)):
         eng = Engine(cfg, params, mesh=_mesh(), batch=2, **kw)
-        assert eng.kv_bytes_per_token == per_token
+        assert eng.kv_bytes_per_token == (pool_token if kw else per_token)
         sched = SlotScheduler(eng, prefill_chunk=4, max_wait_ms=20.0, decode_burst=4)
         try:
             tickets = [sched.submit(p, 40, temperature=0.0) for p in (p1, p2)]
@@ -390,10 +396,14 @@ def test_engine_scheduler_contiguous_and_paged_serve_the_same_tokens(
         finally:
             sched.close()
         assert outs == wanted, kw
-    assert eng.cache.wk is None and eng.cache.k.shape == (8, 49, 4, 4, 8)
+    # a pool and a table per layer kind: 2 full layers behind the page
+    # tables, 6 window layers in 2 slots' rings of 9 pages
+    assert eng.cache.k.shape == (2, 49, 4, 4, 8) and eng.ring_pages == 9
+    assert eng.cache.wk.shape == (6, 18, 4, 4, 8)
     pages = eng.read_pool_pages([1, 2])
     assert {k: v.shape for k, v in pages.items()} == {
-        "pages.k": (8, 2, 4, 4, 8), "pages.v": (8, 2, 4, 4, 8)}
+        "pages.k": (2, 2, 4, 4, 8), "pages.v": (2, 2, 4, 4, 8)}
+    assert sched.prefix_cache is None and not sched.preempt
 
 
 def test_prompt_lookup_decoding_matches_greedy(q40_file, monkeypatch):

@@ -688,3 +688,128 @@ def test_smallthinker_cell_programs_compile_at_published_widths(one_chip, monkey
         for plane in ("bf16[1,1,4,16384,128]", "bf16[3,1,4,4608,128]"):
             assert plane in text
             assert not re.search(r"= " + re.escape(plane) + r"\S* copy\(", text), plane
+
+
+# K-EXAONE's share (PR 40): the attention projections at a query width of 8192
+# on a hidden size of 6144, the dense layer's 18432, the 19200-row head (the
+# last d tile ragged), and the 16 HELD experts of a layer in one launch
+@pytest.mark.parametrize("name,n,d", [
+    ("wqkv", 6144, 10240), ("wo", 8192, 6144), ("w13", 6144, 36864),
+    ("w2", 18432, 6144), ("shared_w13", 6144, 4096)], ids=lambda v: str(v))
+def test_q40_matmul_compiles_at_k_exaone_shapes(one_chip, name, n, d):
+    x, qp, sc = _q40_shapes(n, d, 16, True, one_chip)
+    assert x.shape[1] == n and q40._tiles(n, d) == (1024, 1024)
+    text = jax.jit(q40._pallas_matmul_stacked).lower(
+        x, qp, sc, jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+    ).compile().as_text()
+    assert "tpu_custom_call" in text and "q40_mm_stacked" in text
+
+
+def test_q40_head_compiles_at_k_exaones_vocabulary_share(one_chip):
+    x, qp, sc = _q40_shapes(6144, 19200, 16, False, one_chip)
+    text = jax.jit(q40._pallas_matmul).lower(x, qp, sc).compile().as_text()
+    assert "tpu_custom_call" in text and "f32[16,19200]" in text
+
+
+@pytest.mark.parametrize("rows", [16, 256])
+@pytest.mark.parametrize("name,n,d,per_expert", [
+    ("gate", 6144, 2048, False), ("down", 2048, 6144, True)], ids=["gate", "down"])
+def test_q40_experts_matmul_compiles_over_k_exaones_held_experts(
+        one_chip, name, n, d, per_expert, rows):
+    L, held = 23, 16
+    assert q40.padded_n(n) == n and q40._tiles(n, d) == (1024, 1024)
+    s = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)  # noqa: E731
+    x = s(((held,) if per_expert else ()) + (rows, n), jnp.bfloat16)
+    text = jax.jit(
+        lambda x, qp, sc, layer: q40._pallas_matmul_experts(
+            x, qp, sc, layer, experts=held)).lower(
+        x, s((L * held, n // 2, d), jnp.uint8), s((L * held, n // 32, d), jnp.uint16),
+        s((), jnp.int32)).compile().as_text()
+    assert "q40_mm_experts" in text and f"f32[{held},{rows},{d}]" in text
+
+
+def _k_exaone_programs(one_chip, monkeypatch, n_layers=8, slots=16, pages=1025):
+    """K-EXAONE's published widths (hidden 6144, 64/8 heads of 128, a dense
+    layer of 18432, 128 router outputs of which 16 held, experts of 2048, a
+    19200-row vocabulary, window 128) at ``n_layers`` layers on the paged
+    engine's cache: the config, abstract packed params, the pool per kind."""
+    from dllama_tpu.io import mfile
+    from dllama_tpu.models import transformer as tf
+    from dllama_tpu.models.config import ModelConfig
+    from dllama_tpu.models.params import param_shapes
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(jax, "device_count", lambda: 1)
+    cfg = ModelConfig(
+        arch=mfile.ARCH_EXAONE_MOE, dim=6144, hidden_dim=18432,
+        n_layers=n_layers, n_heads=64, n_kv_heads=8, n_experts=128,
+        n_active_experts=8, vocab_size=19200, seq_len=262144,
+        hidden_act=mfile.ACT_SILU, rope_theta=1e6, norm_eps=1e-5, head_dim=128,
+        window=128, window_period=4, window_full_at=3, moe_hidden_dim=2048,
+        n_shared_experts=1, n_groups=1, topk_groups=1, n_dense_layers=1,
+        routed_scale=2.5, experts_held=16, first_expert=0, dtype=jnp.bfloat16)
+    s = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)  # noqa: E731
+
+    def packed(*shapes):
+        *lead, n, _ = shapes[0]
+        d, np_ = sum(sh[-1] for sh in shapes), q40.padded_n(n)
+        return q40.QTensor(s((*lead, np_ // 2, d), jnp.uint8),
+                           s((*lead, np_ // 32, d), jnp.uint16), (n, d))
+
+    sh = param_shapes(cfg)
+    params = {k: s(sh[k], jnp.float32) for k in sh
+              if k.startswith("rms") or k.endswith("_norm") or k == "router_bias"}
+    params.update({k: s(sh[k], jnp.bfloat16) for k in ("embedding", "router")})
+    params.update(
+        wqkv=packed(sh["wq"], sh["wk"], sh["wv"]), w13=packed(sh["w1"], sh["w3"]),
+        shared_w13=packed(sh["shared_w1"], sh["shared_w3"]),
+        **{k: packed(sh[k]) for k in ("wo", "w2", "up", "gate", "down",
+                                      "shared_w2", "wcls")})
+    shapes = jax.eval_shape(lambda: tf.init_kv_pool(
+        cfg, pages, 16, slots=slots, max_pages=6144 // 16))
+    cache = tf.KVCache(**{n: s(a.shape, a.dtype)
+                          for n, a in shapes.planes().items()})
+    return cfg, params, cache, s
+
+
+@pytest.mark.parametrize("t", [1, 16], ids=["pure-decode", "mixed"])
+def test_k_exaone_slot_steps_compile_over_the_pool_per_kind(one_chip, monkeypatch, t):
+    """The two step programs of ``k-exaone-236b-a23b.long-decode`` for the
+    described chip, two periods of layers: the full layers walk their own pool
+    with the fused kernel (at ``t`` = 16 the score tile is exactly
+    ``_SCORE_TILE_MAX``), the window layers read a ring of ten pages a slot,
+    the 16 held experts are three launches a layer, no Q40 site takes the XLA
+    path, and neither the pool nor the window planes are copied whole."""
+    import re
+
+    from dllama_tpu.obs import dispatch as obs_dispatch
+    from dllama_tpu.runtime.decode_loop import slot_chunk
+
+    cfg, params, cache, s = _k_exaone_programs(one_chip, monkeypatch)
+    assert cfg.prefill_chunk() == 1024
+    assert cache.k.shape == (2, 1025, 16, 8, 128)          # 2 full layers' pool
+    assert cache.wk.shape == (6, 16 * 10, 16, 8, 128)      # 6 window layers' rings
+    assert att._fused_choice(t, 64, 8, 128, ps=16, maxp=384) == (True, False)
+    assert 64 * 16 * 8 * 16 * 8 == att._SCORE_TILE_MAX
+    b = 16
+    obs_dispatch.reset()
+    try:
+        text = jax.jit(
+            lambda p, c, tok, pr, nv, k, tm, tp, tk, ptab: slot_chunk(
+                p, cfg, c, tok, pr, nv, k, tm, tp, tk, steps=1, greedy=True,
+                page_table=ptab), donate_argnums=(1,)).lower(
+            params, cache, s((b, t), jnp.int32), s((b,), jnp.int32),
+            s((b,), jnp.int32), s((2,), jnp.uint32), s((b,), jnp.float32),
+            s((b,), jnp.float32), s((b,), jnp.int32),
+            s((b, 384), jnp.int32)).compile().as_text()
+        sites = obs_dispatch.dispatches()
+    finally:
+        obs_dispatch.reset()
+    # the first period is unrolled (its first layer is dense), the second scanned
+    assert sites.get("moe/all-experts") == 3 + 4, sites
+    assert sites.get("kv_dense/paged-fused") == 2 and sites.get("kv_dense/window-ring") == 6
+    assert "q40/xla-dequant" not in sites and "kv_dense/paged-gather" not in sites, sites
+    assert "paged_attn_fused" in text and "q40_mm_experts" in text
+    for plane in ("bf16[2,1025,16,8,128]", "bf16[6,160,16,8,128]"):
+        assert plane in text
+        assert not re.search(r"= " + re.escape(plane) + r"\S* copy\(", text), plane
