@@ -30,10 +30,13 @@ Two matmul implementations:
   to ``PALLAS_MAX_ROWS`` rows ride in one block (decode), more rows (the
   served mixed step, prefill buckets) in row blocks of up to
   ``ROW_BLOCK_MAX`` (:func:`_row_block`), each weight tile unpacked once
-  per block.  A mixture-of-experts layer's E experts, or the k a decoded
-  row chose, are one launch a matmul (:func:`matmul_experts`,
-  ``q40_mm_experts`` / ``q40_mm_chosen``): the expert index is a grid axis
-  of the same kernel.  A `pallas_call` is not auto-partitioned by GSPMD, so
+  per block, to logical row order, and contracted in one dot against the
+  activation block ``(rows, tile_n)`` in the model's own column order (no
+  op stands between the caller's ``x`` and the launch but a cast and, for a
+  padded ``n``, the zero columns; PERF.md §6, PR 41).  A mixture-of-experts
+  layer's E experts, or the k a decoded row chose, are one launch a matmul
+  (:func:`matmul_experts`, ``q40_mm_experts`` / ``q40_mm_chosen``): the
+  expert index is a grid axis of the same kernel.  A `pallas_call` is not auto-partitioned by GSPMD, so
   on a multi-device mesh it runs **per shard under
   ``jax.shard_map``** (see :func:`_sharded_matmul`): the caller declares the
   weight's TP slicing ``kind`` — ``"row"`` (output dim sharded, the
@@ -342,19 +345,20 @@ def dequantize(qt: QTensor, dtype=jnp.float32) -> jax.Array:
 # Pallas fused kernel
 # ---------------------------------------------------------------------------
 
-def _q40_kernel(xlo_ref, xhi_ref, qp_ref, s_ref, o_ref, acc_ref, *,
-                nsteps, n_axis=1):
+def _q40_kernel(x_ref, qp_ref, s_ref, o_ref, acc_ref, *, nsteps, n_axis=1):
     """One (tile_n × tile_d) fused dequant-matmul step: the weight tile is
-    unpacked once and contracted against every activation row of the block
-    (all rows, or one row block of the row-blocked form, whose reduction
-    axis is grid axis ``n_axis`` = 2).
+    unpacked once, to logical row order, and contracted in one dot against
+    every activation row of the block (all rows, or one row block of the
+    row-blocked form, whose reduction axis is grid axis ``n_axis`` = 2).
 
     Dequantization is ``bf16(f32(v−8)·s)`` per weight: the reference's
     rounding (one bf16 round of the exact product, funcs.cpp:330-335
-    semantics), the same on every tp shard and in the XLA path.  The lo/hi
-    nibble planes are contracted by two separate dots against the matching
-    halves of x (prepared outside the kernel, where XLA fuses the splits),
-    which avoids a concat-to-logical-order relayout of the unpacked tile.
+    semantics), the same on every tp shard and in the XLA path.  The
+    activation block is ``(rows, tile_n)`` in the model's own column order:
+    the lo and hi nibble planes of each 32-row quantization block are set
+    one above the other after the bf16 cast, where a piece of 16 rows × 128
+    lanes is exactly one vreg tile: Mosaic emits no op for the placement
+    (the lowered kernel has the op counts of a body with a dot a plane).
     VPU unpack work (~5.5 ops/weight) is the decode bottleneck after DMA.
     """
     i = pl.program_id(n_axis)
@@ -367,10 +371,10 @@ def _q40_kernel(xlo_ref, xhi_ref, qp_ref, s_ref, o_ref, acc_ref, *,
     vi = qp.astype(jnp.int32)
     lo = ((vi & 0xF).astype(jnp.float32) - 8.0).reshape(nb, 16, td)
     hi = ((vi >> 4).astype(jnp.float32) - 8.0).reshape(nb, 16, td)
-    lo = (lo * s32[:, None, :]).astype(jnp.bfloat16).reshape(tn2, td)
-    hi = (hi * s32[:, None, :]).astype(jnp.bfloat16).reshape(tn2, td)
-    part = (jnp.dot(xlo_ref[:], lo, preferred_element_type=jnp.float32)
-            + jnp.dot(xhi_ref[:], hi, preferred_element_type=jnp.float32))
+    lo = (lo * s32[:, None, :]).astype(jnp.bfloat16)
+    hi = (hi * s32[:, None, :]).astype(jnp.bfloat16)
+    w = jnp.concatenate([lo, hi], axis=1).reshape(2 * tn2, td)
+    part = jnp.dot(x_ref[:], w, preferred_element_type=jnp.float32)
 
     @pl.when(i == 0)
     def _():
@@ -385,21 +389,9 @@ def _q40_kernel(xlo_ref, xhi_ref, qp_ref, s_ref, o_ref, acc_ref, *,
         o_ref[:] = acc_ref[:]
 
 
-def _stacked_q40_kernel(lidx_ref, xlo_ref, xhi_ref, qp_ref, s_ref,
-                        o_ref, acc_ref, **kw):
+def _stacked_q40_kernel(lidx_ref, x_ref, qp_ref, s_ref, o_ref, acc_ref, **kw):
     del lidx_ref  # consumed by the index_maps
-    _q40_kernel(xlo_ref, xhi_ref, qp_ref, s_ref, o_ref, acc_ref, **kw)
-
-
-def _x_parts(x: jax.Array) -> tuple[jax.Array, jax.Array]:
-    """Split activations (..., t, n) into the packed-row-order halves the
-    kernel contracts against: ``x_lo``/``x_hi`` (..., t, n/2) matching the
-    low/high nibble planes."""
-    *lead, n = x.shape
-    xr = x.reshape(*lead, n // 32, 32)
-    x_lo = xr[..., :16].reshape(*lead, n // 2)
-    x_hi = xr[..., 16:].reshape(*lead, n // 2)
-    return x_lo, x_hi
+    _q40_kernel(x_ref, qp_ref, s_ref, o_ref, acc_ref, **kw)
 
 
 def _tiles(n: int, d: int) -> tuple[int, int]:
@@ -433,9 +425,9 @@ def _row_block(t: int, tile_n: int, tile_d: int) -> int | None:
     ragged ``d`` edge."""
     if t <= PALLAS_MAX_ROWS:
         return None
-    # per row: both activation halves and the output tile, double-buffered,
-    # the f32 accumulator and the two dots' f32 results
-    per_row = 2 * 2 * (tile_n // 2) * 2 + 5 * tile_d * 4
+    # per row: the activation block and the output tile, double-buffered,
+    # the f32 accumulator and the dot's f32 result
+    per_row = 2 * tile_n * 2 + 4 * tile_d * 4
     cap = min(ROW_BLOCK_MAX, max(256, ROW_BLOCK_VMEM // per_row // 128 * 128))
     return -(-pl.cdiv(t, pl.cdiv(t, cap)) // 16) * 16
 
@@ -450,9 +442,10 @@ def _mm_call(t: int, n: int, d: int, tile_n: int, tile_d: int,
     ``experts`` the expert index is one more parallel axis in front of the d
     tiles: the weight's plane is ``layer * experts + e`` of the flat stack
     (with ``chosen``, entry ``e`` of the prefetched vector of planes), the
-    output is ``(experts, t, d)``, and the activations are one ``(t, n/2)``
-    pair for every expert (its index map ignores ``e``) or, with
-    ``x_per_expert``, ``(experts, t, n/2)``."""
+    output is ``(experts, t, d)``, and the activation is one ``(t, n)`` array
+    for every expert (its index map ignores ``e``) or, with ``x_per_expert``,
+    ``(experts, t, n)``: one ``(rows, tile_n)`` block a grid step, as the
+    caller holds it."""
     tr = row_block or _row_block(t, tile_n, tile_d)
     nd, nn = pl.cdiv(d, tile_d), n // tile_n
     tb = t if tr is None else tr
@@ -481,14 +474,12 @@ def _mm_call(t: int, n: int, d: int, tile_n: int, tile_d: int,
         vmem_limit_bytes=None if tr is None else ROW_VMEM_LIMIT)
     lead = (1,) if stacked else ()
     w_at = at(lambda r, e, j, i, *l: plane(e, l) + (i, j))
-    xspec = pl.BlockSpec(
-        ex(x_per_expert, None) + (tb, tile_n // 2),
-        at(lambda r, e, j, i, *l: ex(x_per_expert, e) + (r, i)), **ms)
     grid_kw = dict(
         grid=grid,
         in_specs=[
-            xspec,
-            xspec,
+            pl.BlockSpec(
+                ex(x_per_expert, None) + (tb, tile_n),
+                at(lambda r, e, j, i, *l: ex(x_per_expert, e) + (r, i)), **ms),
             pl.BlockSpec(lead + (tile_n // 2, tile_d), w_at, **ms),
             pl.BlockSpec(lead + (tile_n // 32, tile_d), w_at, **ms),
         ],
@@ -520,7 +511,7 @@ def _pallas_matmul(x: jax.Array, qpacked: jax.Array, scales: jax.Array,
         compiler_params=params,
         interpret=interpret,
         name="q40_mm",
-    )(*_x_parts(x.astype(jnp.bfloat16)), qpacked, scales)
+    )(x.astype(jnp.bfloat16), qpacked, scales)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret", "tiles", "row_block"))
@@ -551,8 +542,8 @@ def _pallas_matmul_stacked(x: jax.Array, qpacked: jax.Array, scales: jax.Array,
         compiler_params=params,
         interpret=interpret,
         name="q40_mm_stacked",
-    )(layer.reshape(1).astype(jnp.int32), *_x_parts(x.astype(jnp.bfloat16)),
-      qpacked, scales)
+    )(layer.reshape(1).astype(jnp.int32), x.astype(jnp.bfloat16), qpacked,
+      scales)
 
 
 @functools.partial(jax.jit, static_argnames=("experts", "interpret", "tiles",
@@ -574,7 +565,7 @@ def _pallas_matmul_experts(x: jax.Array, qpacked: jax.Array, scales: jax.Array,
     ``(experts | k, t, n)``, one activation block an expert (down).  The
     expert index is a grid axis (:func:`_mm_call`), so what was a launch of
     :func:`_pallas_matmul_stacked` an expert from a traced loop is one, with
-    the same tile math, and ``_x_parts`` runs once on the whole activation.
+    the same tile math, and the activation goes in as the caller holds it.
     Only the planes walked are read: all ``experts`` whatever the router
     chose, or the ``k`` chosen, whose planes ride in as a prefetched vector
     where the all-experts form needs the layer alone."""
@@ -596,8 +587,7 @@ def _pallas_matmul_experts(x: jax.Array, qpacked: jax.Array, scales: jax.Array,
         compiler_params=params,
         interpret=interpret,
         name=name,
-    )(planes.astype(jnp.int32), *_x_parts(x.astype(jnp.bfloat16)),
-      qpacked, scales)
+    )(planes.astype(jnp.int32), x.astype(jnp.bfloat16), qpacked, scales)
 
 
 @dataclass(frozen=True)
@@ -931,8 +921,8 @@ def _sharded_matmul_ep(x2: jax.Array, qp4: jax.Array, s4: jax.Array,
 def _tile_n_legal(n: int, tile_n: int) -> bool:
     """Mosaic's block rule for the reduction tile, shared by the Q40 and
     Q80 kernels: a block's last two dims must be (8, 128)-divisible or
-    span the whole axis.  The scales block is ``(tile_n/32, td)`` and the
-    Q40 activation block ``(t, tile_n/2)``, so a partial-axis tile needs
+    span the whole axis.  The scales block is ``(tile_n/32, td)`` (the Q40
+    activation block ``(t, tile_n)`` asks less), so a partial-axis tile needs
     ``tile_n % 256 == 0``; the output tile's ragged edge is masked by
     Pallas and needs no rule (verified by compiling for v5e,
     tests/test_tpu_compile.py)."""

@@ -393,13 +393,13 @@ def test_kernel_matches_xla_at_the_rules_new_tiles(form, n, d):
 
 
 # First line of every function of ops/q40.py that is a frame while a Q40
-# kernel is traced, as at PR 39 (the chosen form of the experts launch grew
-# _mm_call and _pallas_matmul_experts; as at PR 33, commit fe8bd20, before).
+# kernel is traced, as at PR 41 (the kernel took whole ``x``: ``_x_parts`` went
+# and every line below it moved up; as at PR 39 before).
 KERNEL_PATH_LINES = {
-    "_q40_kernel": 345, "_stacked_q40_kernel": 388, "_x_parts": 394,
-    "_mm_call": 443, "_pallas_matmul": 502, "_pallas_matmul_stacked": 526,
-    "_pallas_matmul_experts": 558, "_pad_x": 648, "_sharded_matmul": 793,
-    "_sharded_matmul_ep": 866, "matmul_experts": 994, "matmul": 1014, "mm": 1081}
+    "_q40_kernel": 348, "_stacked_q40_kernel": 392, "_mm_call": 435,
+    "_pallas_matmul": 493, "_pallas_matmul_stacked": 517,
+    "_pallas_matmul_experts": 549, "_pad_x": 638, "_sharded_matmul": 783,
+    "_sharded_matmul_ep": 856, "matmul_experts": 984, "matmul": 1004, "mm": 1071}
 
 
 def test_the_kernels_trace_path_kept_its_lines():
@@ -446,7 +446,8 @@ class TestScaleValidation:
 class TestRowBlocks:
     """Over PALLAS_MAX_ROWS rows the fused kernel runs over row blocks:
     same rounding as the XLA path (bf16 dequant, f32 accumulation), another
-    summation order."""
+    summation order.  At every row count the activation goes in whole, in
+    the model's column order (1 and 16 rows: the one-block programs)."""
 
     @staticmethod
     def _case(form, n, d, rows):
@@ -460,7 +461,7 @@ class TestRowBlocks:
             view = view.select(jnp.int32(2), 3)
         return x, qt, view
 
-    @pytest.mark.parametrize("rows", [129, 256, 272, 1024])
+    @pytest.mark.parametrize("rows", [1, 16, 129, 256, 272, 300, 1024])
     @pytest.mark.parametrize("form,n", [("plain", 1024), ("plain", 1056),
                                         ("plain", 2752), ("stacked", 2752),
                                         ("experts", 1024)])
@@ -761,3 +762,71 @@ def test_the_q40_knobs_stay_gone():
     for fn in (q40._pallas_matmul, q40._pallas_matmul_stacked):
         names = set(inspect.signature(fn).parameters)
         assert "variant" not in names and "tiles" in names
+
+
+# ---- the kernel takes its activation as the caller holds it (PR 41) -------
+
+@pytest.mark.parametrize("rows", [1, 16, 129, 256, 300])
+@pytest.mark.parametrize("form,per_expert", [
+    ("experts", False), ("experts", True), ("chosen", False), ("chosen", True)],
+    ids=["experts-shared-x", "experts-x-an-expert", "chosen-shared-x",
+         "chosen-x-an-expert"])
+def test_experts_and_chosen_launches_take_whole_x_and_match_xla(form, per_expert,
+                                                                rows):
+    """The experts and the chosen launch at one block of every row (1, 16),
+    in row blocks (129, 256) and with a ragged last row block (300 rows in
+    blocks of 128), two reduction steps and a ragged last ``d`` tile: each
+    plane's product equals the XLA path's."""
+    experts, layer, n, d = 4, 1, 512, 320
+    rng = np.random.default_rng(rows)
+    qt = q40.quantize(rng.standard_normal((2, experts, n, d)).astype(np.float32) * 0.1)
+    view = q40.QLayerView(qt, jnp.int32(layer))
+    picks = (3, 0, 3) if form == "chosen" else tuple(range(experts))
+    x = jnp.asarray(rng.standard_normal(
+        ((len(picks),) if per_expert else ()) + (rows, n)), jnp.bfloat16)
+    out = q40._pallas_matmul_experts(
+        x, *view.flat_planes(), view.layer, experts=experts, interpret=True,
+        tiles=(256, 256), row_block=128 if rows == 300 else None,
+        chosen=jnp.asarray(picks) if form == "chosen" else None)
+    assert out.shape == (len(picks), rows, d)
+    for j, e in enumerate(picks):
+        ref = np.asarray(q40.matmul(x[j] if per_expert else x,
+                                    view.select(jnp.int32(e), experts),
+                                    impl="xla", out_dtype=jnp.float32))
+        np.testing.assert_allclose(np.asarray(out[j]), ref, rtol=0,
+                                   atol=1e-4 * np.abs(ref).max(), err_msg=str(j))
+
+
+def _eqns_outside_kernels(jaxpr):
+    """Every equation of ``jaxpr`` and of the jaxprs nested in it, a
+    ``pallas_call``'s own body left out."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        if eqn.primitive.name == "pallas_call":
+            continue
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _eqns_outside_kernels(sub)
+
+
+@pytest.mark.parametrize("form", ["flat", "stacked"])
+def test_no_split_of_x_stands_in_front_of_the_kernel(form):
+    """At 256 rows (the served mixed step, the prefill bucket) the launch's
+    activation operand is ``x`` itself, cast at most: no ``reshape`` or
+    ``slice`` outside the ``pallas_call``.  The nibble-half split that stood
+    there cost 8.9 ms of Mistral's 49.6 ms mixed step (PERF.md §6, PR 41)."""
+    n, d = 1024, 384
+    qt = q40.quantize(_rand((2, n, d), seed=3))
+    w = q40.QLayerView(qt, jnp.int32(1)) if form == "stacked" else \
+        q40.QTensor(qt.qpacked[0], qt.scales[0], (n, d))
+    x = jax.ShapeDtypeStruct((256, n), jnp.bfloat16)
+    jaxpr = jax.make_jaxpr(
+        lambda x: q40.matmul(x, w, impl="pallas_interpret"))(x).jaxpr
+    eqns = list(_eqns_outside_kernels(jaxpr))
+    # (the stacked form reshapes its layer index: one int32, not ``x``)
+    moved = [e for e in eqns if e.primitive.name in (
+        "reshape", "slice", "dynamic_slice", "gather", "concatenate",
+        "transpose", "copy") and any(v.aval.size >= 256 for v in e.invars)]
+    assert not moved, moved
+    call, = [e for e in eqns if e.primitive.name == "pallas_call"]
+    assert [(256, n)] == [v.aval.shape for v in call.invars
+                          if v.aval.dtype == jnp.bfloat16]

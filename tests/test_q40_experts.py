@@ -409,19 +409,19 @@ def test_a_mesh_keeps_the_loop_and_the_ledger_says_select():
                                atol=1e-3 + 1e-3 * np.abs(logits[1]).max())
 
 
-# sha256 of str(jax.make_jaxpr(...)) of the all-experts launch on the parent of
-# the PR that gave the kernel its chosen form: (n, d, experts, layers, an
+# sha256 of str(jax.make_jaxpr(...)) of the all-experts launch as PR 41 left
+# it (one activation operand, the body's one dot): (n, d, experts, layers, an
 # activation block an expert, rows) of OLMoE's, DeepSeek-V2's and
 # SmallThinker's gate and down at their cells' rows
 PARENT_EXPERTS_JAXPRS = {
-    (2048, 1024, 64, 16, False, 16): "7fa11787fb887b88",
-    (1024, 2048, 64, 16, True, 16): "425014c774e69a87",
-    (2048, 1024, 64, 16, False, 256): "3fcf24268c01afaa",
-    (1024, 2048, 64, 16, True, 256): "5af998f2357d095c",
-    (5120, 1536, 160, 4, False, 16): "9dc550a32a365f19",
-    (1536, 5120, 160, 4, True, 16): "3ee64141dca1b94b",
-    (2560, 768, 64, 52, False, 512): "f9e53e8f0110dc55",
-    (768, 2560, 64, 52, True, 512): "c89343558385b1b9",
+    (2048, 1024, 64, 16, False, 16): "84d73b1c041037c5",
+    (1024, 2048, 64, 16, True, 16): "d39f2394facf8ab1",
+    (2048, 1024, 64, 16, False, 256): "598580865085514f",
+    (1024, 2048, 64, 16, True, 256): "914674feab236910",
+    (5120, 1536, 160, 4, False, 16): "eeb59c20d3403e4a",
+    (1536, 5120, 160, 4, True, 16): "8e45bc4204869734",
+    (2560, 768, 64, 52, False, 512): "f20f86ec89de5502",
+    (768, 2560, 64, 52, True, 512): "d33aeb5b92a78cc8",
 }
 
 
@@ -443,14 +443,15 @@ def test_all_experts_kernel_programs_are_the_parents(case):
 # ---- the dense cells' kernel programs did not move ------------------------
 
 # sha256 of str(jax.make_jaxpr(...)) at Mistral-7B's fused gate+up weight
-# (4096 -> 2 x 14336, 32 layers) on the parent of the PR that added the
-# expert axis to _mm_call: operand lists, grids, block shapes, compiler
-# parameters and kernel bodies of the two older entry points are in that text
-# (PERF.md §6, PR 28: one more operand cost the dense cells 1.3-1.8%).
+# (4096 -> 2 x 14336, 32 layers) as PR 41 left them: operand lists, grids,
+# block shapes, compiler parameters and kernel bodies of the two older entry
+# points are in that text (PERF.md §6, PR 28: one more operand cost the dense
+# cells 1.3-1.8%; PR 41 took one away).  A PR that moves a hash on purpose
+# re-pins it and says what every cell's programs paid.
 PARENT_KERNEL_JAXPRS = {
-    (False, 1): "18d88f23b5d364c9", (False, 16): "ccb1fcd718310986",
-    (False, 256): "14ade32625b455dc", (True, 1): "46beebf4eda2d534",
-    (True, 16): "84fc4ea7ac6207fc", (True, 256): "021db3e6d99976f8",
+    (False, 1): "228e0a67a71105c4", (False, 16): "05cb10f14fd92041",
+    (False, 256): "3967bc32ae344097", (True, 1): "c57d1ea552c71ba2",
+    (True, 16): "0b6ec8b329a75164", (True, 256): "0dce88ed8621a5ef",
 }
 
 

@@ -1,28 +1,38 @@
 """Hardware sweep of the fused Q40 kernel: tile pairs, and rows x row block.
 
-Times the kernel on one chip at the matmul shapes of the benchmark's four
-configurations (Mistral-7B, OLMoE-1B-7B, DeepSeek-V2, and a tp=4 shard of
-Yi-34B): the flat and stacked forms, and the experts form
-(``q40_mm_experts``) at each model's expert count.  Every measurement
-happens *inside one jitted ``lax.scan``* cycling the layer index, exactly
-like the decode loop runs the kernel: a host-side dispatch loop measures
-host dispatch latency, not kernel time.  Tile pairs go through the kernels'
-``tiles=`` keyword and row blocks through ``row_block=``, so one process
-times them all; the program's own choices are ``q40._tiles`` (marked
-``"rule": true`` in the records) and ``q40._row_block``.
+Times the kernel on one chip at the matmul shapes of the benchmark's
+configurations (Mistral-7B, OLMoE-1B-7B, DeepSeek-V2, a tp=4 shard of
+Yi-34B; K-EXAONE's held experts under ``--body``): the flat and stacked
+forms, and the experts form (``q40_mm_experts``) at each model's expert
+count.  Every measurement happens *inside one jitted ``lax.scan``* cycling
+the layer index, exactly like the decode loop runs the kernel: a host-side
+dispatch loop measures host dispatch latency, not kernel time.  Tile pairs
+go through the kernels' ``tiles=`` keyword and row blocks through
+``row_block=``, so one process times them all; the program's own choices are
+``q40._tiles`` (marked ``"rule": true`` in the records) and
+``q40._row_block``.
+
+``--body`` times the kernel at the rule's tiles and the code's own row
+block, 1 to 512 rows, Mistral's matmuls and the experts form of OLMoE,
+DeepSeek-V2 and one chip's share of K-EXAONE.  Run on two checkouts it
+compares two bodies, as PR 41 did (one dot against the activation as the
+caller holds it, for two against its nibble halves): at 64 repetitions a
+one-row figure repeated to 1-2%, at the 128 it takes to 0.5%; an op that XLA
+keeps inside the scan is timed with the launch (PERF.md §6, PR 41).
 
 ``--chosen`` times a decoded row's routed experts at SmallThinker's shapes:
 one launch over the six chosen planes (``q40_mm_chosen``) against six
 launches of ``q40_mm_stacked``, which is what ``moe_ffn`` ran at one row
 before and still runs on a mesh.  An iteration of the scan carries ops of
-its own (the index vector, ``_x_parts``, the slice and sum that keep the
-result alive; for the loop also six index slices and a stack): 27 us read
-here where the cell's trace reads 19-21 a launch, 67 where it reads 6 x 4.3
+its own (the index vector, the slice and sum that keep the result alive; for
+the loop also six index slices and a stack): 27 us read here where the
+cell's trace reads 19-21 a launch, 67 where it reads 6 x 4.3
 (PERF.md §6, PR 39).  The order of the two forms is the tool's to say, a
 launch's time the trace's.
 
 Usage: python tools/sweep_q40.py --tiles [ds_gate,yi_wo]  # tile pairs at 1, 16, 256 rows
        python tools/sweep_q40.py --rows [head,w13]        # rows x row block
+       python tools/sweep_q40.py --body [w2,ds_down]      # the body at the rule's tiles, 1 to 512 rows
        python tools/sweep_q40.py --chosen [st_gate]       # one launch a row's experts, or one each
 """
 
@@ -86,6 +96,10 @@ CHOSEN_SHAPES = [Shape("st_gate", 2560, 768, 4, 64),
                  Shape("st_down", 768, 2560, 4, 64, True)]
 CHOSEN = 6
 TILE_ROWS = (1, 16, 256)
+# K-EXAONE-236B-A23B, one chip's share: 16 held experts of 2048, hidden 6144
+BODY_SHAPES = MISTRAL + [s for s in SHAPES if s.experts and "pad" not in s.name] + [
+    Shape("kx_gate", 6144, 2048, 2, 16), Shape("kx_down", 2048, 6144, 2, 16, True)]
+BODY_ROWS = (1, 16, 128, 256, 512)
 # (rows, row block): None is the code's own choice (one block of every row
 # up to 128, q40._row_block above), "xla" the dequantize-then-dot path
 ROWS_CONFIGS = [(64, None), (128, None), (64, 64), (128, 128), (256, 256),
@@ -237,6 +251,15 @@ def measure_rows(only: set | None = None, reps: int = 16,
                   lambda sh: configs, only, reps, "sweep_rows.json")
 
 
+def measure_body(only: set | None = None, reps: int = 128) -> list[dict]:
+    """The kernel at the rule's tiles and the code's own row block: Mistral's
+    five matmuls and the experts form of the three expert models at 1, 16,
+    128, 256 and 512 rows.  Run on two checkouts, it compares two bodies."""
+    return _sweep([s._replace(layers=min(s.layers, 4)) for s in BODY_SHAPES],
+                  lambda sh: [({}, rows, {}) for rows in BODY_ROWS],
+                  only, reps, "sweep_body.json")
+
+
 def measure_chosen(only: set | None = None, reps: int = 256) -> list[dict]:
     """One decoded row's CHOSEN routed experts: one launch over their planes
     against one launch each, at the rule's tiles (``ms`` is all six)."""
@@ -247,7 +270,7 @@ def measure_chosen(only: set | None = None, reps: int = 256) -> list[dict]:
 
 def main():
     modes = {"--tiles": measure_tiles, "--rows": measure_rows,
-             "--chosen": measure_chosen}
+             "--body": measure_body, "--chosen": measure_chosen}
     if len(sys.argv) < 2 or sys.argv[1] not in modes:
         sys.exit(__doc__)
     modes[sys.argv[1]](set(sys.argv[2].split(",")) if len(sys.argv) > 2
