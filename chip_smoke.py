@@ -10,6 +10,10 @@ Q40 weights:
   cli      ``python -m dllama_tpu inference`` on a synthesized .m/.t pair
   server   ``python -m dllama_tpu.server.api`` with a paged slot scheduler:
            concurrent completions, a streamed chat, /metrics, SIGTERM drain
+  packed   one mixed slot step (16 slots x 16 tokens, 2 prefilling) with its
+           row-local regions over the 46 valid rows packed into 64
+           (models/packing.py) against the step over all 256, same pool:
+           logits at each slot's last valid row within the Q40 tolerance
   moe      the mixture-of-experts path at OLMoE-1B-7B's widths and 2 layers:
            a seeded .m through the loader, ``moe_ffn``'s one launch over the
            row's chosen experts (``q40_mm_chosen``) at 1 row against the XLA
@@ -179,6 +183,26 @@ def phase_moe(mpath: str, timeout: float, rehearse: bool = False) -> dict:
         require("q40/pallas-fused" in ledger and "DEGRADED" not in ledger,
                 f"moe: {ledger}")
     return {"phase": "moe", "compile": comp}
+
+
+def phase_packed(mpath: str, timeout: float, rehearse: bool = False) -> dict:
+    """Child: one mixed slot step (16 slots x 16 tokens, 2 of them
+    prefilling: 46 rows of 256 hold a token) with its row-local regions over
+    the valid rows packed into 64 (models/packing.py) against the same step
+    over every row, from the same pool: the logits at each slot's last valid
+    row."""
+    rc, out = run_child("packed", ["--model", mpath], timeout, rehearse)
+    rows, comp = _results(out, "packed")
+    for r in rows:
+        emit(dict(r, phase="packed"))
+    require(rc == 0, f"packed: child exited {rc}")
+    cmp_ = next((r for r in rows if r.get("what") == "packed_step"), None)
+    require(cmp_ is not None, "packed: nothing was compared")
+    require((cmp_["valid_rows"], cmp_["run_rows"], cmp_["slot_rows"])
+            == (46, 64, 256), f"packed: rows {cmp_}")
+    require(cmp_["rel_err"] <= cmp_["tol"], f"packed: above tolerance: {cmp_}")
+    require(cmp_["greedy_equal"], f"packed: greedy tokens differ: {cmp_}")
+    return {"phase": "packed", "compile": comp}
 
 
 def phase_cli(mpath: str, tpath: str, timeout: float, steps: int = 64,
@@ -668,6 +692,65 @@ def child_moe(argv: list[str], rehearse: bool) -> None:
     _say({"what": "ledger", "ledger": obs_dispatch.summary_line()})
 
 
+def child_packed(argv: list[str], rehearse: bool) -> None:
+    ap = argparse.ArgumentParser()
+    ap.add_argument("--model", required=True)
+    a = ap.parse_args(argv)
+    _say(_claim_device(rehearse))
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from dllama_tpu.io import mfile
+    from dllama_tpu.models import packing
+    from dllama_tpu.models.config import ModelConfig
+    from dllama_tpu.models.params import load_params
+    from dllama_tpu.models.transformer import forward_slots
+    from dllama_tpu.parallel.mesh import make_mesh
+    from dllama_tpu.runtime.engine import Engine
+
+    mf = mfile.MFile(a.model)
+    dtype = jnp.float32 if rehearse else jnp.bfloat16
+    cfg, params = load_params(mf, ModelConfig.from_spec(mf.spec, dtype=dtype),
+                              dtype=dtype, keep_quantized=True)
+    b, t, ps, ctx = 16, 16, 16, 64
+    maxp = ctx // ps
+    eng = Engine(cfg, params, mesh=make_mesh(tp=1, devices=jax.devices()[:1]),
+                 batch=b, seq_len=ctx, kv_pages=b * maxp + 1, kv_page_size=ps)
+    table = jnp.asarray(1 + np.arange(b * maxp, dtype=np.int32).reshape(b, maxp))
+    rng = np.random.RandomState(7)
+    toks = [jnp.asarray(rng.randint(3, cfg.vocab_size, (b, t)), jnp.int32)
+            for _ in range(2)]
+
+    def step(p, c, tok, pos, nv):
+        return forward_slots(p, cfg, tok, c, pos, nv, table)
+
+    packed_fn, every_fn = jax.jit(step), jax.jit(lambda *xs: step(*xs))
+    # every slot holds 16 positions; then slots 3 and 11 feed a chunk of 16
+    # and the other fourteen one token: 14 + 2 x 16 = 46 of 256 rows
+    full = jnp.full((b,), t, jnp.int32)
+    _, pool = packed_fn(eng.params, eng.cache, toks[0], 0 * full, full)
+    nv = np.ones((b,), np.int32)
+    nv[[3, 11]] = t
+    t0 = time.perf_counter()
+    got, _ = packed_fn(eng.params, pool, toks[1], full, jnp.asarray(nv))
+    kept, packing.BUCKETS = packing.BUCKETS, ()  # no bucket: every row
+    try:
+        want, _ = every_fn(eng.params, pool, toks[1], full, jnp.asarray(nv))
+    finally:
+        packing.BUCKETS = kept
+    got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
+    require(np.isfinite(got).all() and np.isfinite(want).all(),
+            "non-finite logits")
+    valid = int(np.minimum(nv, t).sum())
+    _say({"what": "packed_step", "slots": b, "t": t, "valid_rows": valid,
+          "run_rows": packing.run_rows(valid, b, t), "slot_rows": b * t,
+          "rel_err": float(np.abs(got - want).max() / max(np.abs(want).max(), 1e-9)),
+          "tol": Q40_TOL,
+          "greedy_equal": bool((got.argmax(-1) == want.argmax(-1)).all()),
+          "seconds": round(time.perf_counter() - t0, 2)})
+
+
 def child_module(module: str, argv: list[str], rehearse: bool) -> None:
     """Platform check, then the user's entry point as ``__main__``."""
     _claim_device(rehearse)
@@ -799,6 +882,7 @@ def main(argv: list[str] | None = None) -> int:
         if a.chips == 1:
             timed("cli", phase_cli, mpath, tpath, min(600, left()))
             timed("server", phase_server, mpath, tpath, min(600, left()), tmp)
+            timed("packed", phase_packed, mpath, min(300, left()))
             os.remove(mpath)  # room for the next file
             mpath, tpath = timed("synth_moe", synth_model_files, MOE_MODEL,
                                  tmp, MOE_LAYERS)
@@ -837,6 +921,8 @@ if __name__ == "__main__":
             child_kernels(rehearse)
         elif phase == "moe":
             child_moe(rest, rehearse)
+        elif phase == "packed":
+            child_packed(rest, rehearse)
         elif phase == "cli":
             child_module("dllama_tpu", rest, rehearse)
         elif phase == "server":

@@ -40,7 +40,7 @@ from ..ops.kernels import ACTIVATIONS, apply_rope, rmsnorm, rope_angles, softmax
 from ..ops.scopes import part, scope
 from ..ops.sp_attention import ring_attention, sp_gqa_attention, sp_update_kv_cache_at
 from ..parallel.mesh import get_active_mesh
-from . import windowed
+from . import packing, windowed
 from .config import ModelConfig
 from .params import DENSE_FFN_KEYS, MLA_ATT_KEYS, MOE_FFN_KEYS, Params
 
@@ -207,35 +207,48 @@ def update_cache_at(cache: KVCache, k_new, v_new, layer, pos) -> KVCache:
             jax.lax.dynamic_update_slice(cache.v_scale, sv[None], idx))
 
 
+def _project_out(att, lp, cfg: ModelConfig):
+    """An attention sub-block's output projection (row-local; col-sharded on
+    a tp mesh: partial sums all-reduced here)."""
+    with scope("wo"):
+        return _mm(att, lp["wo"], cfg, kind="col")
+
+
 def _attention_block(x, lp, cfg: ModelConfig, cache: KVCache, cos, sin, pos,
-                     layer, offsets=None, pos_rows=None, paged=None):
+                     layer, offsets=None, pos_rows=None, paged=None,
+                     packed=None):
     """One attention sub-block.  ``cache`` holds the *stacked*
     (L, B, Hkv, S, Dh) buffers carried through the layer scan; this layer
     writes its (B, Hkv, T, Dh) step window in place at ``(layer, pos)`` and
     reads back only its own layer slice for attention (see
-    ops.attention.update_kv_cache_at for the cost model)."""
+    ops.attention.update_kv_cache_at for the cost model).  With ``packed``
+    (a slot step at ``t > 1``, models/packing.py) the two projections run over
+    the rows that hold a token; everything between them keeps (B, T)."""
     b, t, d = x.shape
     hq, hkv, dh = cfg.n_heads, cfg.n_kv_heads, cfg.head_size
 
-    with scope("norm"):
-        xb = rmsnorm(x, lp["rms_att"])
-    with scope("qkv"):
-        if "wqkv" in lp:  # fused projection (quantized load): one kernel launch
-            qkv = _mm(xb, lp["wqkv"], cfg)
-            q, k, v = jnp.split(qkv, [hq * dh, (hq + hkv) * dh], axis=-1)
-        else:
-            q = _mm(xb, lp["wq"], cfg, kind="row")
-            k = _mm(xb, lp["wk"], cfg, kind="row")
-            v = _mm(xb, lp["wv"], cfg, kind="row")
-        if cfg.qk_norm:
-            # over the whole projection, before the head split and RoPE; on
-            # a tp mesh q and k are sharded on this axis and the mean is
-            # GSPMD's all-reduce (tests/test_olmoe.py, 4-device CPU mesh)
-            q = rmsnorm(q, lp["q_norm"])
-            k = rmsnorm(k, lp["k_norm"])
-        q = q.reshape(b, t, hq, dh)
-        k = k.reshape(b, t, hkv, dh)
-        v = v.reshape(b, t, hkv, dh)
+    def project(x):  # row-local: any leading axes
+        with scope("norm"):
+            xb = rmsnorm(x, lp["rms_att"])
+        with scope("qkv"):
+            if "wqkv" in lp:  # fused projection (quantized load): one kernel launch
+                qkv = _mm(xb, lp["wqkv"], cfg)
+                q, k, v = jnp.split(qkv, [hq * dh, (hq + hkv) * dh], axis=-1)
+            else:
+                q = _mm(xb, lp["wq"], cfg, kind="row")
+                k = _mm(xb, lp["wk"], cfg, kind="row")
+                v = _mm(xb, lp["wv"], cfg, kind="row")
+            if cfg.qk_norm:
+                # over the whole projection, before the head split and RoPE; on
+                # a tp mesh q and k are sharded on this axis and the mean is
+                # GSPMD's all-reduce (tests/test_olmoe.py, 4-device CPU mesh)
+                q = rmsnorm(q, lp["q_norm"])
+                k = rmsnorm(k, lp["k_norm"])
+            lead = x.shape[:-1]
+            return (q.reshape(*lead, hq, dh), k.reshape(*lead, hkv, dh),
+                    v.reshape(*lead, hkv, dh))
+
+    q, k, v = packing.over(packed, "qkv", project, x)
 
     with scope("rope"):
         q = apply_rope(q, cos, sin, interleaved=cfg.rope_interleaved)
@@ -291,8 +304,8 @@ def _attention_block(x, lp, cfg: ModelConfig, cache: KVCache, cos, sin, pos,
                                             pos_rows)
         with scope("attn"):
             att = att.transpose(0, 2, 1, 3).reshape(b, t, hq * dh)
-        with scope("wo"):
-            return _mm(att, lp["wo"], cfg, kind="col"), cache
+        return packing.over(packed, "wo", _project_out, att, lp=lp,
+                            cfg=cfg), cache
     if t == 1 and sp_on:
         # seq-sharded cache: explicit shard-local write (no GSPMD-chosen
         # gather/scatter per decode step); quantized caches are gated off
@@ -307,9 +320,7 @@ def _attention_block(x, lp, cfg: ModelConfig, cache: KVCache, cos, sin, pos,
         att = _attend(q, k, v, cache, cfg, pos, t, layer, offsets, mesh,
                       sp_on, ring)
         att = att.transpose(0, 2, 1, 3).reshape(b, t, hq * dh)
-    with scope("wo"):
-        # col-sharded: partial sums all-reduced here
-        return _mm(att, lp["wo"], cfg, kind="col"), cache
+    return _project_out(att, lp, cfg), cache
 
 
 def _attend(q, k, v, cache: KVCache, cfg: ModelConfig, pos, t, layer, offsets,
@@ -334,29 +345,33 @@ def _attend(q, k, v, cache: KVCache, cfg: ModelConfig, pos, t, layer, offsets,
 
 
 def _mla_attention_block(x, lp, cfg: ModelConfig, cache: KVCache, cos, sin,
-                         pos, layer, offsets=None, pos_rows=None, paged=None):
+                         pos, layer, offsets=None, pos_rows=None, paged=None,
+                         packed=None):
     """DeepSeek-V2's attention sub-block (ops/mla.py has the two forms).  The
     layer writes its tokens' latent rows into the stacked cache in place and
     reads the live part back; per-head keys and values exist only inside the
-    attention call."""
-    b, t, _ = x.shape
+    attention call.  ``packed``: as :func:`_attention_block`."""
     h, r, dn = cfg.n_heads, cfg.kv_lora_rank, cfg.qk_nope_head_dim
     eps = cfg.norm_eps
-    with scope("norm"):
-        xb = rmsnorm(x, lp["rms_att"], eps)
-    with scope("qkv"):
-        with part("kv_lora"):
-            if "wqkv_a" in lp:  # both down-projections from x: one launch
-                c_q, ckv = jnp.split(_mm(xb, lp["wqkv_a"], cfg),
-                                     [cfg.q_lora_rank], axis=-1)
-            else:
-                ckv = _mm(xb, lp["wkv_a"], cfg)
-            c_kv = rmsnorm(ckv[..., :r], lp["kv_a_norm"], eps)
-        with part("q_lora"):
-            if "wqkv_a" not in lp:
-                c_q = _mm(xb, lp["wq_a"], cfg)
-            q = _mm(rmsnorm(c_q, lp["q_a_norm"], eps), lp["wq_b"], cfg)
-            q = q.reshape(b, t, h, cfg.qk_head_dim)
+
+    def project(x):  # row-local: any leading axes
+        with scope("norm"):
+            xb = rmsnorm(x, lp["rms_att"], eps)
+        with scope("qkv"):
+            with part("kv_lora"):
+                if "wqkv_a" in lp:  # both down-projections from x: one launch
+                    c_q, ckv = jnp.split(_mm(xb, lp["wqkv_a"], cfg),
+                                         [cfg.q_lora_rank], axis=-1)
+                else:
+                    ckv = _mm(xb, lp["wkv_a"], cfg)
+                c_kv = rmsnorm(ckv[..., :r], lp["kv_a_norm"], eps)
+            with part("q_lora"):
+                if "wqkv_a" not in lp:
+                    c_q = _mm(xb, lp["wq_a"], cfg)
+                q = _mm(rmsnorm(c_q, lp["q_a_norm"], eps), lp["wq_b"], cfg)
+                return q.reshape(*x.shape[:-1], h, cfg.qk_head_dim), ckv, c_kv
+
+    q, ckv, c_kv = packing.over(packed, "qkv", project, x)
     with scope("rope"):
         # adjacent pairs, as the published rows have them; one key for all heads
         q = jnp.concatenate(
@@ -379,8 +394,8 @@ def _mla_attention_block(x, lp, cfg: ModelConfig, cache: KVCache, cos, sin,
         att = mla.attention(q, cache.k, cache.v, w_kvb, cfg, layer, pos=pos,
                             pos_rows=pos_rows, page_table=page_table,
                             floor=offsets)
-    with scope("wo"):
-        return _mm(att, lp["wo"], cfg, kind="col"), cache
+    return packing.over(packed, "wo", _project_out, att, lp=lp,
+                        cfg=cfg), cache
 
 
 def _swiglu(xb, lp, cfg: ModelConfig, pre: str):
@@ -664,9 +679,11 @@ def run_blocks(params: Params, cfg: ModelConfig, tokens: jax.Array,
                cache: KVCache, pos: jax.Array,
                offsets: jax.Array | None = None,
                pos_rows: jax.Array | None = None,
-               paged=None) -> tuple[jax.Array, KVCache]:
+               paged=None, packed=None) -> tuple[jax.Array, KVCache]:
     """Embed + all transformer blocks; returns the residual stream (B, T, D)
-    and the updated cache.
+    and the updated cache.  ``packed`` (models/packing.py, a slot step at
+    ``t > 1``): the row-local regions of every layer run over the rows that
+    hold a token.
 
     ``offsets`` (B,) enables ragged batches of *distinct* streams via left
     padding (beyond reference — the reference fixes batch=1,
@@ -701,10 +718,10 @@ def run_blocks(params: Params, cfg: ModelConfig, tokens: jax.Array,
 
     if cfg.is_mla:
         return _run_segments(params, cfg, x, cache, cos, sin, pos, offsets,
-                             pos_rows, paged)
+                             pos_rows, paged, packed)
     if cfg.window:
         return windowed.run_periods(params, cfg, x, cache, cos, sin, pos,
-                                    offsets, pos_rows, paged)
+                                    offsets, pos_rows, paged, packed)
 
     layer_keys = [k for k in params if k not in ("embedding", "rms_final", "wcls")]
     # Packed-Q40 weights stay out of the scan's xs: the scan would slice a
@@ -723,7 +740,8 @@ def run_blocks(params: Params, cfg: ModelConfig, tokens: jax.Array,
             lp[k] = q40.QLayerView(params[k], idx)
         att_out, kvc = _attention_block(x, lp, cfg, kvc, cos, sin, pos,
                                         idx, offsets=offsets,
-                                        pos_rows=pos_rows, paged=paged)
+                                        pos_rows=pos_rows, paged=paged,
+                                        packed=packed)
         if cfg.post_block_norms:
             with scope("norm"):
                 att_out = rmsnorm(att_out, lp["rms_ffn"])  # grokRmfFfnNorm
@@ -731,21 +749,27 @@ def run_blocks(params: Params, cfg: ModelConfig, tokens: jax.Array,
             x = x + att_out
 
         if cfg.is_moe:
-            pre = lp["rms_moe"] if cfg.post_block_norms else lp["rms_ffn"]
-            with scope("norm"):
-                xb = rmsnorm(x, pre)
-            with scope("moe"):
-                ff = moe_ffn(xb.reshape(b * t, cfg.dim), lp,
-                             cfg).reshape(b, t, cfg.dim)
+            def experts(x):  # row-local: any leading axes
+                with scope("norm"):
+                    xb = rmsnorm(x, lp["rms_moe"] if cfg.post_block_norms
+                                 else lp["rms_ffn"])
+                with scope("moe"):
+                    return moe_ffn(xb.reshape(-1, cfg.dim), lp,
+                                   cfg).reshape(x.shape)
+
+            ff = packing.over(packed, "moe", experts, x)
             if cfg.post_block_norms:
                 with scope("norm"):
                     ff = rmsnorm(ff, lp["rms_ffn2"])  # grokMoeRmsNormFinal
             with scope("moe"):
                 x = x + ff
         else:
-            with scope("norm"):
-                xb = rmsnorm(x, lp["rms_ffn"])
-            ff = _dense_ffn(xb, lp, cfg)
+            def dense(x):
+                with scope("norm"):
+                    xb = rmsnorm(x, lp["rms_ffn"])
+                return _dense_ffn(xb, lp, cfg)
+
+            ff = packing.over(packed, "w2", dense, x)
             with scope("w2"):
                 x = x + ff
         return (x, kvc), None
@@ -761,14 +785,12 @@ def run_blocks(params: Params, cfg: ModelConfig, tokens: jax.Array,
 
 
 def _run_segments(params: Params, cfg: ModelConfig, x, cache: KVCache, cos,
-                  sin, pos, offsets, pos_rows, paged):
+                  sin, pos, offsets, pos_rows, paged, packed=None):
     """DeepSeek-V2's layers: a dense prefix and an expert segment, each one
     ``lax.scan`` over its own FFN stack, sharing the attention stacks (indexed
     by the running layer), the cache and the residual stream.  No stack rides
     a scan's xs: a layer's slice is indexed where it is used (packed weights
     through a ``QLayerView``, as in :func:`run_blocks`)."""
-    b, t, _ = x.shape
-
     def at(w, i):
         if isinstance(w, (q40.QTensor, q8.Q8Tensor)):
             return q40.QLayerView(w, i)
@@ -782,24 +804,32 @@ def _run_segments(params: Params, cfg: ModelConfig, x, cache: KVCache, cos,
             lp.update({k: at(params[k], i) for k in ffn_keys if k in params})
             att_out, kvc = _mla_attention_block(
                 x, lp, cfg, kvc, cos, sin, pos, layer, offsets=offsets,
-                pos_rows=pos_rows, paged=paged)
+                pos_rows=pos_rows, paged=paged, packed=packed)
             with scope("wo"):
                 x = x + att_out
-            with scope("norm"):
-                xb = rmsnorm(x, lp["rms_ffn"], cfg.norm_eps)
-            return (ffn(x, xb, lp), kvc), None
+            return (ffn(x, lp), kvc), None
 
         return jax.lax.scan(block, carry, jnp.arange(count, dtype=jnp.int32))[0]
 
-    def dense(x, xb, lp):
-        ff = _dense_ffn(xb, lp, cfg)
+    def normed(x, lp):
+        with scope("norm"):
+            return rmsnorm(x, lp["rms_ffn"], cfg.norm_eps)
+
+    def dense(x, lp):
+        ff = packing.over(packed, "w2",
+                       lambda x: _dense_ffn(normed(x, lp), lp, cfg), x)
         with scope("w2"):
             return x + ff
 
-    def experts(x, xb, lp):
+    def experts(x, lp):
+        def routed(x):  # row-local: any leading axes
+            xb = normed(x, lp)
+            with scope("moe"):
+                return moe_ffn(xb.reshape(-1, cfg.dim), lp, cfg).reshape(x.shape)
+
+        ff = packing.over(packed, "moe", routed, x)
         with scope("moe"):
-            return x + moe_ffn(xb.reshape(b * t, cfg.dim), lp,
-                               cfg).reshape(b, t, cfg.dim)
+            return x + ff
 
     carry = (x, cache)
     if cfg.n_dense_layers:
@@ -891,7 +921,8 @@ def forward_slots(params: Params, cfg: ModelConfig, tokens: jax.Array,
 def _run_slot_blocks(params: Params, cfg: ModelConfig, tokens, cache: KVCache,
                      pos_rows, n_valid, page_table):
     """:func:`run_blocks` for slot rows; on a paged pool the write indices
-    are computed once here (identical for every layer)."""
+    are computed once here (identical for every layer), and at ``t > 1`` on
+    one device the packing of the rows that hold a token (models/packing.py)."""
     paged = None
     if page_table is not None:
         with scope("page_idx"):
@@ -899,8 +930,10 @@ def _run_slot_blocks(params: Params, cfg: ModelConfig, tokens, cache: KVCache,
                                              tokens.shape[1],
                                              cache.k.shape[2])
         paged = (page_table, pidx, oidx)
+    with scope("page_idx"):  # which rows hold a token: once, as the indices
+        packed = packing.plan(n_valid, *tokens.shape)
     return run_blocks(params, cfg, tokens, cache, jnp.int32(0),
-                      pos_rows=pos_rows, paged=paged)
+                      pos_rows=pos_rows, paged=paged, packed=packed)
 
 
 def forward_slots_all(params: Params, cfg: ModelConfig, tokens: jax.Array,

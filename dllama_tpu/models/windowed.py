@@ -40,6 +40,7 @@ from ..ops.attention import (gqa_attention_at, live_gqa_attention,
                              update_kv_cache_at)
 from ..ops.kernels import apply_rope, rmsnorm
 from ..ops.scopes import part, scope
+from . import packing
 from .config import ModelConfig
 from .params import DENSE_FFN_KEYS, MOE_FFN_KEYS
 
@@ -103,28 +104,34 @@ def kind_index(cfg: ModelConfig, p, j: int):
 
 
 def _attention(x, lp, cfg: ModelConfig, cache, cos, sin, pos, plane,
-               windowed: bool, offsets, pos_rows, paged):
+               windowed: bool, offsets, pos_rows, paged, packed):
     """One attention sub-block; ``plane`` indexes the cache's stack of this
     layer's kind (the contiguous planes or rings, the pool or the slots'
-    rings of pages)."""
-    from .transformer import _mm
+    rings of pages).  ``packed``: as ``transformer._attention_block``."""
+    from .transformer import _mm, _project_out
     b, t, _ = x.shape
     hq, hkv, dh = cfg.n_heads, cfg.n_kv_heads, cfg.head_size
-    with scope("norm"):
-        xb = rmsnorm(x, lp["rms_att"], cfg.norm_eps)
-    with scope("qkv"):
-        if "wqkv" in lp:
-            q, k, v = jnp.split(_mm(xb, lp["wqkv"], cfg),
-                                [hq * dh, (hq + hkv) * dh], axis=-1)
-        else:
-            q, k, v = (_mm(xb, lp[w], cfg, kind="row") for w in ("wq", "wk", "wv"))
-        q = q.reshape(b, t, hq, dh)
-        k = k.reshape(b, t, hkv, dh)
-        v = v.reshape(b, t, hkv, dh)
-        if cfg.qk_head_norm:
-            with part("qk_norm"):
-                q = rmsnorm(q, lp["q_norm"], cfg.norm_eps)
-                k = rmsnorm(k, lp["k_norm"], cfg.norm_eps)
+
+    def project(x):  # row-local: any leading axes
+        with scope("norm"):
+            xb = rmsnorm(x, lp["rms_att"], cfg.norm_eps)
+        with scope("qkv"):
+            if "wqkv" in lp:
+                q, k, v = jnp.split(_mm(xb, lp["wqkv"], cfg),
+                                    [hq * dh, (hq + hkv) * dh], axis=-1)
+            else:
+                q, k, v = (_mm(xb, lp[w], cfg, kind="row") for w in ("wq", "wk", "wv"))
+            lead = x.shape[:-1]
+            q = q.reshape(*lead, hq, dh)
+            k = k.reshape(*lead, hkv, dh)
+            v = v.reshape(*lead, hkv, dh)
+            if cfg.qk_head_norm:
+                with part("qk_norm"):
+                    q = rmsnorm(q, lp["q_norm"], cfg.norm_eps)
+                    k = rmsnorm(k, lp["k_norm"], cfg.norm_eps)
+            return q, k, v
+
+    q, k, v = packing.over(packed, "qkv", project, x)
     with scope("rope"):
         if windowed:  # a full layer is not rotated at all
             q = apply_rope(q, cos, sin, interleaved=False)
@@ -189,15 +196,15 @@ def _attention(x, lp, cfg: ModelConfig, cache, cos, sin, pos, plane,
                                        start=offsets)
     with scope("attn"):
         att = att.transpose(0, 2, 1, 3).reshape(b, t, hq * dh)
-    with scope("wo"):
-        return _mm(att, lp["wo"], cfg, kind="col"), cache
+    return packing.over(packed, "wo", _project_out, att, lp=lp,
+                        cfg=cfg), cache
 
 
 def run_periods(params, cfg: ModelConfig, x, cache, cos, sin, pos, offsets,
-                pos_rows, paged):
+                pos_rows, paged, packed=None):
     """All layers of a windowed model over the residual stream ``x (B, T,
     D)``; returns it and the updated cache (``transformer.run_blocks`` has
-    embedded the tokens and made the angles)."""
+    embedded the tokens and made the angles, and planned ``packed``)."""
     from ..io import mfile
     from .transformer import _dense_ffn, moe_ffn
     b, t, d = x.shape
@@ -231,18 +238,33 @@ def run_periods(params, cfg: ModelConfig, x, cache, cos, sin, pos, offsets,
                 router_logits = (x.reshape(b * t, d).astype(jnp.float32)
                                  @ lp["router"].astype(jnp.float32))
         att_out, kvc = _attention(x, lp, cfg, kvc, cos, sin, pos, plane,
-                                  windowed, offsets, pos_rows, paged)
+                                  windowed, offsets, pos_rows, paged, packed)
         with scope("wo"):
             x = x + att_out
-        with scope("norm"):
-            xb = rmsnorm(x, lp["rms_ffn"], cfg.norm_eps)
+
+        def normed(x):
+            with scope("norm"):
+                return rmsnorm(x, lp["rms_ffn"], cfg.norm_eps)
+
         if dense:
-            ff = _dense_ffn(xb, lp, cfg)
+            ff = packing.over(packed, "w2",
+                           lambda x: _dense_ffn(normed(x), lp, cfg), x)
             with scope("w2"):
                 return x + ff, kvc
+
+        def experts(x, *logits):  # row-local: any leading axes
+            xb = normed(x)
+            with scope("moe"):
+                return moe_ffn(xb.reshape(-1, d), lp, cfg,
+                               *(lg.reshape(-1, lg.shape[-1]) for lg in logits)
+                               ).reshape(x.shape)
+
+        if router_logits is not None and packed is not None:
+            router_logits = router_logits.reshape(b, t, -1)  # the rows' own
+        ff = packing.over(packed, "moe", experts, x,
+                       *(() if router_logits is None else (router_logits,)))
         with scope("moe"):
-            ff = moe_ffn(xb.reshape(b * t, d), lp, cfg, router_logits)
-            return x + ff.reshape(b, t, d), kvc
+            return x + ff, kvc
 
     def one_period(carry, p, first_dense: int = 0):
         """Period ``p`` (traced in the scan, static in front of it); its first

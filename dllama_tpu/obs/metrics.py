@@ -787,6 +787,13 @@ SCHED_STEP_WALL_MS = REGISTRY.labeled_counter(
     "Wall milliseconds of the dispatches counted by sched_steps, same "
     "kinds; over all kinds it equals sched_step_time_ms's prefill + "
     "decode + pad.")
+SCHED_STEP_ROWS = REGISTRY.labeled_counter(
+    "sched_step_rows", ("what", "kind"),
+    "Token rows of the dispatches the slot scheduler enqueued, by kind as "
+    "sched_steps: valid (rows that carry a token: the sum of min(n_valid, "
+    "t), times the steps of a decode burst) and run (rows the step's "
+    "matmuls run: the row bucket of a packed step at t > 1, "
+    "models/packing.py, else slots x t).  valid / run is the fill.")
 SCHED_GOODPUT_RATIO = REGISTRY.gauge(
     "sched_goodput_ratio",
     "Fraction of scheduler wall time spent on live rows "

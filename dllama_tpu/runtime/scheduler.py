@@ -83,6 +83,7 @@ from collections import deque
 
 import numpy as np
 
+from ..models import packing
 from ..obs import cost as obs_cost, dispatch as obs_dispatch
 from ..obs import events as obs_events, flight as obs_flight
 from ..obs import metrics as obs_metrics, trace as obs_trace
@@ -235,6 +236,12 @@ class _Pending:
     def __init__(self, **kw):
         for k in self.__slots__:
             setattr(self, k, kw.get(k))
+
+
+def _count_rows(kind: str, valid: int, run: int) -> None:
+    """``sched_step_rows``: the token rows of one enqueued dispatch."""
+    obs_metrics.SCHED_STEP_ROWS.inc("valid", kind, n=valid)
+    obs_metrics.SCHED_STEP_ROWS.inc("run", kind, n=run)
 
 
 def _kind(cur: _Pending) -> str:
@@ -1830,8 +1837,15 @@ class SlotScheduler:
             obs_metrics.SCHED_BATCH_EFFICIENCY.set(len(active) / b)
             prefset = set(prefilling)
             rid_by_slot = {i: slots[i].ticket.rid for i in active}
+            kind = "verify" if props else "mixed" if prefilling else "decode"
+            valid_rows = int(n_valid.sum())  # each at most t_width
+            run_rows = packing.run_rows(valid_rows, b, t_width,
+                                        eng.mesh) * steps
+            valid_rows *= steps
+            _count_rows(kind, valid_rows, run_rows)
             sp.update(t=t_width, steps=steps, rows=len(active),
                       prefill_rows=len(prefilling), verify=bool(props),
+                      valid_rows=valid_rows, run_rows=run_rows,
                       rids=sorted(rid_by_slot.values()))
             fed_by_slot = {i: int(n_valid[i]) for i in prefilling}
             tickets = {i: slots[i].ticket for i in active}
@@ -1852,7 +1866,6 @@ class SlotScheduler:
                 obs_metrics.SCHED_HOST_GAP_MS.observe(host_gap_ms)
             self._idle_accum = 0.0
             bp.update(t=t_width, steps=steps, rows=len(active))
-            kind = "verify" if props else "mixed" if prefilling else "decode"
             bp.total = obs_metrics.host_ms("build", kind)
 
         handle, error = None, None
@@ -2010,10 +2023,12 @@ class SlotScheduler:
             bp.total = obs_metrics.host_ms("build", "decode")
         handle, err = None, None
         self._n_enqueued += 1
+        _count_rows("decode", b * steps2, b * steps2)
         with obs_trace.span("sched.enqueue", seq=self._n_enqueued,
                             overlapped=True, t=1, steps=steps2,
                             rows=len(cur.active), prefill_rows=0,
-                            verify=False,
+                            verify=False, valid_rows=b * steps2,
+                            run_rows=b * steps2,
                             rids=sorted(cur.rid_by_slot.values())):
             try:
                 with self._engine_lock:

@@ -98,6 +98,15 @@ def test_rehearse_cli_and_server_phases(rehearsal_env, capfd):
     assert '"ok": true' not in capfd.readouterr().out
 
 
+def test_rehearse_packed_phase(rehearsal_env, capfd):
+    m, _, _ = rehearsal_env
+    chip_smoke.phase_packed(m, 600, rehearse=True)
+    rows = [json.loads(ln) for ln in capfd.readouterr().out.splitlines()]
+    cmp_ = next(r for r in rows if r.get("what") == "packed_step")
+    assert (cmp_["valid_rows"], cmp_["run_rows"]) == (46, 64)
+    assert cmp_["rel_err"] <= 1e-5 and cmp_["greedy_equal"]
+
+
 def test_rehearse_tp_phase_on_virtual_devices(rehearsal_env, capfd):
     m, t, _ = rehearsal_env
     dev = chip_smoke.phase_tp(m, t, 600, tp=4, rehearse=True)

@@ -20,8 +20,10 @@ import pytest
 from jax.sharding import Mesh, NamedSharding, SingleDeviceSharding
 from jax.sharding import PartitionSpec as P
 
+from dllama_tpu.models import packing
 from dllama_tpu.ops import attention as att
 from dllama_tpu.ops import q40
+from dllama_tpu.ops.scopes import SCOPES
 
 # Llama-2-7B: (name, input dim n, output dim d, layer-stacked)
 SHAPES_7B = [("wqkv", 4096, 12288, True), ("wo", 4096, 4096, True),
@@ -138,6 +140,12 @@ def test_fused_paged_attention_compiles_at_served_geometry(one_chip, monkeypatch
     assert "tpu_custom_call" in text and "paged_attn_fused" in text
 
 
+def scope_of(path):
+    """An op's scope: the innermost scope name of its ``op_name`` path, as the
+    benchmark's readers take it (a packed region nests ``w2/cond/.../norm``)."""
+    return ([c for c in path.split("/") if c in SCOPES] or [None])[-1]
+
+
 def _slot_step_text(one_chip, cfg, params, b, t, n_pages, max_pages, ps=16):
     """Compiled text of one paged slot step of (b, t) tokens for the
     described chip."""
@@ -213,8 +221,6 @@ def test_paged_slot_step_has_no_pool_copy(one_chip, monkeypatch, hkv):
     import re
     import time
 
-    from dllama_tpu.ops.scopes import SCOPES
-
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     monkeypatch.setattr(jax, "device_count", lambda: 1)
     cfg = _toy_cfg().with_(n_heads=hkv, n_kv_heads=hkv, dim=128 * hkv)
@@ -225,8 +231,6 @@ def test_paged_slot_step_has_no_pool_copy(one_chip, monkeypatch, hkv):
     assert time.monotonic() - t0 < 30, "too slow for tier-1: drop this test"
     assert "paged_attn_fused" in text
     paths = re.findall(r"op_name=\"([^\"]+)\"", text)
-    scope_of = lambda path: ([c for c in path.split("/") if c in SCOPES]  # noqa: E731
-                             or [None])[-1]
     assert {"qkv", "kv_write", "attn", "wo", "w2", "head"} <= \
         {scope_of(path) for path in paths}
     _assert_pool_is_only_scattered(text, cfg, n_pages, ps)
@@ -330,7 +334,7 @@ def test_mixed_slot_step_keeps_q40_on_the_fused_kernel(one_chip, monkeypatch):
     roots = re.findall(r"^\s*ROOT [^\n]*? (convert)\([^\n]*?op_name=\"([^\"]+)\"",
                        text, re.M)
     dequant = [path for _, path in roots
-               if {"w13", "w1", "w3", "w2"} & set(path.split("/"))]
+               if scope_of(path) in ("w13", "w1", "w3", "w2")]
     assert not dequant, dequant
 
 
@@ -528,14 +532,17 @@ def test_deepseek_v2_slot_steps_compile_over_a_latent_pool(one_chip, monkeypatch
         sites = obs_dispatch.dispatches()
     finally:
         obs_dispatch.reset()
-    assert sites.get("moe/all-experts") == 1 and "moe/scan" not in sites, sites
+    # the mixed step holds one body of the experts a row bucket (64
+    # and every row: models/packing.py), the pure-decode step the one it had
+    bodies = len(packing.buckets(b * t)) if t > 1 else 1
+    assert sites.get("moe/all-experts") == bodies and "moe/scan" not in sites, sites
     assert sites.get("attn/mla-absorbed") == 2 and "attn/mla-expanded" not in sites, sites
     assert "q40/xla-dequant" not in sites, sites
     ops = re.findall(r"^\s*(?:ROOT )?%?[\w.\-]+ = \S+ ([\w\-]+)\(.*?"
                      r"op_name=\"([^\"]+)\"", text, re.M)
     calls = [path for op, path in ops if op == "custom-call"
              and "pallas_call" in path and "/moe/experts/" in path]
-    assert len(calls) == 3 and all("q40_mm_experts" in c for c in calls), calls
+    assert len(calls) == 3 * bodies and all("q40_mm_experts" in c for c in calls), calls
     assert any("/attn/latent/" in path for _, path in ops)
     assert any("/attn/absorb/" in path for _, path in ops)
     assert any("/moe/shared/" in path for _, path in ops)
@@ -805,8 +812,10 @@ def test_k_exaone_slot_steps_compile_over_the_pool_per_kind(one_chip, monkeypatc
         sites = obs_dispatch.dispatches()
     finally:
         obs_dispatch.reset()
-    # the first period is unrolled (its first layer is dense), the second scanned
-    assert sites.get("moe/all-experts") == 3 + 4, sites
+    # the first period is unrolled (its first layer is dense), the second
+    # scanned; the mixed step holds one body of the experts a row bucket
+    bodies = len(packing.buckets(b * t)) if t > 1 else 1
+    assert sites.get("moe/all-experts") == (3 + 4) * bodies, sites
     assert sites.get("kv_dense/paged-fused") == 2 and sites.get("kv_dense/window-ring") == 6
     assert "q40/xla-dequant" not in sites and "kv_dense/paged-gather" not in sites, sites
     assert "paged_attn_fused" in text and "q40_mm_experts" in text

@@ -32,7 +32,7 @@ launch's time the trace's.
 
 Usage: python tools/sweep_q40.py --tiles [ds_gate,yi_wo]  # tile pairs at 1, 16, 256 rows
        python tools/sweep_q40.py --rows [head,w13]        # rows x row block
-       python tools/sweep_q40.py --body [w2,ds_down]      # the body at the rule's tiles, 1 to 512 rows
+       python tools/sweep_q40.py --body [w2,ds_down [16,32,64]]  # the body at the rule's tiles, 1 to 512 rows or the rows given
        python tools/sweep_q40.py --chosen [st_gate]       # one launch a row's experts, or one each
 """
 
@@ -251,12 +251,13 @@ def measure_rows(only: set | None = None, reps: int = 16,
                   lambda sh: configs, only, reps, "sweep_rows.json")
 
 
-def measure_body(only: set | None = None, reps: int = 128) -> list[dict]:
+def measure_body(only: set | None = None, reps: int = 128,
+                 rows: tuple = BODY_ROWS) -> list[dict]:
     """The kernel at the rule's tiles and the code's own row block: Mistral's
     five matmuls and the experts form of the three expert models at 1, 16,
-    128, 256 and 512 rows.  Run on two checkouts, it compares two bodies."""
+    128, 256 and 512 rows (or at ``rows``: the packed mixed step runs 64, PR 42).  Run on two checkouts, it compares two bodies."""
     return _sweep([s._replace(layers=min(s.layers, 4)) for s in BODY_SHAPES],
-                  lambda sh: [({}, rows, {}) for rows in BODY_ROWS],
+                  lambda sh: [({}, r, {}) for r in rows],
                   only, reps, "sweep_body.json")
 
 
@@ -273,8 +274,11 @@ def main():
              "--body": measure_body, "--chosen": measure_chosen}
     if len(sys.argv) < 2 or sys.argv[1] not in modes:
         sys.exit(__doc__)
+    kw = {}
+    if sys.argv[1] == "--body" and len(sys.argv) > 3:
+        kw["rows"] = tuple(int(r) for r in sys.argv[3].split(","))
     modes[sys.argv[1]](set(sys.argv[2].split(",")) if len(sys.argv) > 2
-                       else None)
+                       else None, **kw)
 
 
 if __name__ == "__main__":
