@@ -7,7 +7,7 @@ accumulates against quantized activations.  Here the weights stay packed in
 HBM and a Pallas kernel fuses nibble-unpack + scale + matmul, so decode —
 which is HBM-bandwidth-bound — streams 0.5625 bytes/weight instead of 2
 (bf16), a ~3.5× roofline advantage over the bf16 matvec.  (Design target;
-driver-captured numbers live in BENCH_r*.json.)
+what the chip reads is in the root PERF.md.)
 
 Device layout (block-local, chosen so any 32-row slice is self-contained
 and therefore tensor-parallel sharding on either axis never splits a
@@ -78,7 +78,7 @@ from ..parallel.mesh import get_active_mesh
 
 # The largest tile sides, and the pair every matrix whose sides divide by 1024
 # gets (PERF.md §6, PR 28: the sweep that retired the others); _tiles cuts any
-# other matrix into tiles that divide it (the rule is at the end of this file).
+# other matrix into tiles that divide it ("The tile rule" below).
 TILE_N = 1024
 TILE_D = 1024
 # Up to this many rows the fused kernel holds every activation row in one
@@ -342,6 +342,113 @@ def dequantize(qt: QTensor, dtype=jnp.float32) -> jax.Array:
 
 
 # ---------------------------------------------------------------------------
+# The tile rule (PR 35): what _tiles and padded_n choose from
+# ---------------------------------------------------------------------------
+# The rule, from ``(n, d)`` alone:
+#
+# * ``tile_n`` is a legal reduction tile (:func:`_tile_n_legal`: the whole axis
+#   or a divisor that is a multiple of 256) of at most MAX_TILE_N rows, so no
+#   reduction step is padded;
+# * ``tile_d`` cuts ``d`` into equal tiles, 128-lane aligned and at most TILE_D
+#   wide: 1536 is 2 x 768, not 1024 and a half-empty 1024; 2112 is 3 x 768;
+# * at most TILE_ELEMS = 1 Mi elements a tile: the packed tile and its bf16
+#   dequant temporaries (Q80: an f32 intermediate of tn·td·4 B) stay well
+#   inside VMEM;
+# * of those pairs the one of the largest area (the fewest grid steps, each
+#   ≈0.3-0.5 us on the v5e), and of equal areas the widest ``tile_d`` (the
+#   longest HBM burst a packed row).
+#
+# Sides that divide by 1024 get 1024 x 1024, as every cell up to PR 33 ran.  A
+# tp shard's local n may have no divisor near 1024 (Yi-34B's 7168 / 4 = 1792 =
+# 7 x 256): it takes the whole axis against a 512-wide output tile, not seven
+# steps of 256.  Measured on the chip at 16 rows (tools/sweep_q40.py --tiles;
+# PERF.md §6, PR 35): 160 experts of 5120 x 1536, 2.73 ms at 1024 x 1024 and
+# 2.04 at 1280 x 768; of 1536 x 5120, 2.74 ms stored as 2048 rows, 1.95 at
+# 1536 x 640.
+TILE_ELEMS = TILE_N * TILE_D
+# padded_n stores an input dim as it is when some legal reduction tile of it
+# leaves a tile of this many elements or more; the kernel's time a stored byte
+# at 768 x 1024 is 9% over 1024 x 1024's, at 512 x 1024 19%, at 256 x 1024 53%
+# (160 experts of 1536 x 5120 at 16 rows), where padding 1536 to 2048 is 33%
+# more bytes and 5632 to 6144 is 9%.
+HEALTHY_TILE_ELEMS = 3 * TILE_ELEMS // 4
+# The longest reduction tile: beside it the budget leaves an output tile of
+# 512.  A longer one showed no gain where the rule would have picked it
+# (Yi-34B's k / v shard, 7168 x 256 at one row: 37.4 us in two steps of 3584
+# and in four of 1792; 40.7 / 36.3 the run before), and leaves a small matrix
+# two grid steps to hide its first tile's DMA behind.
+MAX_TILE_N = 2 * TILE_N
+
+
+def _tile_ns(n: int):
+    """The reduction tiles :func:`_tiles` chooses from for an ``n``-row input
+    axis: the whole axis and every divisor that is a multiple of 256
+    (:func:`_tile_n_legal`), up to MAX_TILE_N rows."""
+    if n <= MAX_TILE_N:
+        yield n
+    for k in range(2, n // 256 + 1):
+        if n % (256 * k) == 0 and n // k <= MAX_TILE_N:
+            yield n // k
+
+
+def _widest_tile_d(tile_n: int) -> int:
+    """The widest output tile the budget leaves beside ``tile_n`` rows: a
+    multiple of the 128 lanes, at most TILE_D (512 at MAX_TILE_N)."""
+    return min(TILE_ELEMS // tile_n, TILE_D) // 128 * 128
+
+
+def _tile_d(tile_n: int, d: int) -> int:
+    """``d`` cut into the fewest equal tiles that fit beside ``tile_n`` rows,
+    the width rounded up to the lanes.  A ``d`` under TILE_D that is no
+    multiple of 128 (toys) keeps one ragged tile of TILE_D where that fits."""
+    widest = _widest_tile_d(tile_n)
+    if d < TILE_D and d % 128 and widest == TILE_D:
+        return TILE_D
+    return -(-pl.cdiv(d, pl.cdiv(d, widest)) // 128) * 128
+
+
+def _healthy_n(n: int) -> bool:
+    """Can the rule cut an ``n``-row input axis, stored as it is, into tiles
+    of HEALTHY_TILE_ELEMS or more (whatever ``d``, if it is wide enough)?"""
+    return any(tn * _widest_tile_d(tn) >= HEALTHY_TILE_ELEMS
+               for tn in _tile_ns(n))
+
+
+def _tiles(n: int, d: int) -> tuple[int, int]:
+    """Pick reduction/output tile sizes from the matrix's ``(n, d)`` alone
+    (the Q40 and the Q80 kernel share the rule; the comment that opens
+    this section states it and what it was measured against): the pair of
+    the largest area (the fewest grid steps) whose ``tile_n`` divides ``n``
+    (:func:`_tile_ns`) and whose ``tile_d`` cuts ``d`` into equal lane-
+    aligned tiles (:func:`_tile_d`: only the last tile's lane rounding is
+    masked on store), at most TILE_ELEMS elements; of equal areas the wider
+    ``tile_d``.  Sides that divide by 1024 get 1024 x 1024."""
+    tile_n = max(_tile_ns(n), default=0,
+                 key=lambda tn: (tn * _tile_d(tn, d), _tile_d(tn, d)))
+    if tile_n:
+        return tile_n, _tile_d(tile_n, d)
+    # no legal tile (an axis over MAX_TILE_N that no multiple of 256 divides):
+    # an illegal partial one, which _auto_pallas sends to XLA
+    return next(tn for tn in (128, 64, 32) if n % tn == 0), TILE_D
+
+
+def _shard_nd(np_: int, d: int, kind: str | None, tp: int) -> tuple[int, int]:
+    """The ``(n, d)`` one tp shard's kernel sees: what :func:`_tiles` cuts."""
+    if tp > 1 and kind == "col":
+        return np_ // tp, d
+    if tp > 1 and kind == "row":
+        return np_, d // tp
+    return np_, d
+
+
+def _site(np_: int, d: int, kind: str | None, tp: int) -> dict:
+    """What a kernel call site adds to its dispatch record: the tp slicing,
+    the tile pair its shard got and the stored input dim."""
+    return dict(kind=kind, tp=tp, stored_n=np_,
+                tiles=_tiles(*_shard_nd(np_, d, kind, tp)))
+
+
+# ---------------------------------------------------------------------------
 # Pallas fused kernel
 # ---------------------------------------------------------------------------
 
@@ -392,24 +499,6 @@ def _q40_kernel(x_ref, qp_ref, s_ref, o_ref, acc_ref, *, nsteps, n_axis=1):
 def _stacked_q40_kernel(lidx_ref, x_ref, qp_ref, s_ref, o_ref, acc_ref, **kw):
     del lidx_ref  # consumed by the index_maps
     _q40_kernel(x_ref, qp_ref, s_ref, o_ref, acc_ref, **kw)
-
-
-def _tiles(n: int, d: int) -> tuple[int, int]:
-    """Pick reduction/output tile sizes from the matrix's ``(n, d)`` alone
-    (the Q40 and the Q80 kernel share the rule; "The tile rule" at the end
-    of this file states it and what it was measured against): the pair of
-    the largest area (the fewest grid steps) whose ``tile_n`` divides ``n``
-    (:func:`_tile_ns`) and whose ``tile_d`` cuts ``d`` into equal lane-
-    aligned tiles (:func:`_tile_d`: only the last tile's lane rounding is
-    masked on store), at most TILE_ELEMS elements; of equal areas the wider
-    ``tile_d``.  Sides that divide by 1024 get 1024 x 1024."""
-    tile_n = max(_tile_ns(n), default=0,
-                 key=lambda tn: (tn * _tile_d(tn, d), _tile_d(tn, d)))
-    if tile_n:
-        return tile_n, _tile_d(tile_n, d)
-    # no legal tile (an axis over MAX_TILE_N that no multiple of 256 divides):
-    # an illegal partial one, which _auto_pallas sends to XLA
-    return next(tn for tn in (128, 64, 32) if n % tn == 0), TILE_D
 
 
 def _row_block(t: int, tile_n: int, tile_d: int) -> int | None:
@@ -1084,99 +1173,3 @@ def mm(x: jax.Array, w, impl: str = "auto", out_dtype=None,
                                  rows=int(np.prod(x.shape[:-1]) or 1))
     out = x @ w
     return out.astype(out_dtype) if out_dtype is not None else out
-
-
-# ---------------------------------------------------------------------------
-# The tile rule (PR 35): what _tiles and padded_n choose from
-# ---------------------------------------------------------------------------
-# It stands at the end of the file on purpose.  A Mosaic kernel is compiled
-# with the file and line of every frame that traced it, and the persistent
-# compile cache keys on those bytes: a line added above ``mm`` gives every Q40
-# program of every model a new key, and the run that follows compiles them all
-# again (seen as seconds of ``setup_s`` in PR 34's check and in PR 35's pairs;
-# PERF.md §7).  Code that the kernels' call path does not run goes below it.
-#
-# The rule, from ``(n, d)`` alone:
-#
-# * ``tile_n`` is a legal reduction tile (:func:`_tile_n_legal`: the whole axis
-#   or a divisor that is a multiple of 256) of at most MAX_TILE_N rows, so no
-#   reduction step is padded;
-# * ``tile_d`` cuts ``d`` into equal tiles, 128-lane aligned and at most TILE_D
-#   wide: 1536 is 2 x 768, not 1024 and a half-empty 1024; 2112 is 3 x 768;
-# * at most TILE_ELEMS = 1 Mi elements a tile: the packed tile and its bf16
-#   dequant temporaries (Q80: an f32 intermediate of tn·td·4 B) stay well
-#   inside VMEM;
-# * of those pairs the one of the largest area (the fewest grid steps, each
-#   ≈0.3-0.5 us on the v5e), and of equal areas the widest ``tile_d`` (the
-#   longest HBM burst a packed row).
-#
-# Sides that divide by 1024 get 1024 x 1024, as every cell up to PR 33 ran.  A
-# tp shard's local n may have no divisor near 1024 (Yi-34B's 7168 / 4 = 1792 =
-# 7 x 256): it takes the whole axis against a 512-wide output tile, not seven
-# steps of 256.  Measured on the chip at 16 rows (tools/sweep_q40.py --tiles;
-# PERF.md §6, PR 35): 160 experts of 5120 x 1536, 2.73 ms at 1024 x 1024 and
-# 2.04 at 1280 x 768; of 1536 x 5120, 2.74 ms stored as 2048 rows, 1.95 at
-# 1536 x 640.
-TILE_ELEMS = TILE_N * TILE_D
-# padded_n stores an input dim as it is when some legal reduction tile of it
-# leaves a tile of this many elements or more; the kernel's time a stored byte
-# at 768 x 1024 is 9% over 1024 x 1024's, at 512 x 1024 19%, at 256 x 1024 53%
-# (160 experts of 1536 x 5120 at 16 rows), where padding 1536 to 2048 is 33%
-# more bytes and 5632 to 6144 is 9%.
-HEALTHY_TILE_ELEMS = 3 * TILE_ELEMS // 4
-# The longest reduction tile: beside it the budget leaves an output tile of
-# 512.  A longer one showed no gain where the rule would have picked it
-# (Yi-34B's k / v shard, 7168 x 256 at one row: 37.4 us in two steps of 3584
-# and in four of 1792; 40.7 / 36.3 the run before), and leaves a small matrix
-# two grid steps to hide its first tile's DMA behind.
-MAX_TILE_N = 2 * TILE_N
-
-
-def _tile_ns(n: int):
-    """The reduction tiles :func:`_tiles` chooses from for an ``n``-row input
-    axis: the whole axis and every divisor that is a multiple of 256
-    (:func:`_tile_n_legal`), up to MAX_TILE_N rows."""
-    if n <= MAX_TILE_N:
-        yield n
-    for k in range(2, n // 256 + 1):
-        if n % (256 * k) == 0 and n // k <= MAX_TILE_N:
-            yield n // k
-
-
-def _widest_tile_d(tile_n: int) -> int:
-    """The widest output tile the budget leaves beside ``tile_n`` rows: a
-    multiple of the 128 lanes, at most TILE_D (512 at MAX_TILE_N)."""
-    return min(TILE_ELEMS // tile_n, TILE_D) // 128 * 128
-
-
-def _tile_d(tile_n: int, d: int) -> int:
-    """``d`` cut into the fewest equal tiles that fit beside ``tile_n`` rows,
-    the width rounded up to the lanes.  A ``d`` under TILE_D that is no
-    multiple of 128 (toys) keeps one ragged tile of TILE_D where that fits."""
-    widest = _widest_tile_d(tile_n)
-    if d < TILE_D and d % 128 and widest == TILE_D:
-        return TILE_D
-    return -(-pl.cdiv(d, pl.cdiv(d, widest)) // 128) * 128
-
-
-def _healthy_n(n: int) -> bool:
-    """Can the rule cut an ``n``-row input axis, stored as it is, into tiles
-    of HEALTHY_TILE_ELEMS or more (whatever ``d``, if it is wide enough)?"""
-    return any(tn * _widest_tile_d(tn) >= HEALTHY_TILE_ELEMS
-               for tn in _tile_ns(n))
-
-
-def _shard_nd(np_: int, d: int, kind: str | None, tp: int) -> tuple[int, int]:
-    """The ``(n, d)`` one tp shard's kernel sees: what :func:`_tiles` cuts."""
-    if tp > 1 and kind == "col":
-        return np_ // tp, d
-    if tp > 1 and kind == "row":
-        return np_, d // tp
-    return np_, d
-
-
-def _site(np_: int, d: int, kind: str | None, tp: int) -> dict:
-    """What a kernel call site adds to its dispatch record: the tp slicing,
-    the tile pair its shard got and the stored input dim."""
-    return dict(kind=kind, tp=tp, stored_n=np_,
-                tiles=_tiles(*_shard_nd(np_, d, kind, tp)))

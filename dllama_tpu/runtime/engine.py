@@ -31,6 +31,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from .. import hostenv
 from ..io import mfile
 from ..models.config import ModelConfig
 from ..models.params import Params
@@ -390,6 +391,7 @@ class Engine:
                 hint="fused collective-matmul decode is TPU-only; tp "
                      "collectives run as plain psum all-reduce")
         obs_metrics.watch_compiles()  # before this engine's first program
+        hostenv.kernels_without_frames()  # for a caller that is no entry point
         self.params = sharding.place_params(params, cfg, self.mesh)
         for dev, nbytes in _resident_param_bytes(self.params).items():
             obs_metrics.PARAM_BYTES_RESIDENT.set(dev, nbytes)

@@ -37,7 +37,7 @@ _HLO_INSTR = re.compile(r"^%([A-Za-z0-9_.\-]+) =")
 def _iter_op_events(path: str):
     """Yield (hlo_op_name, duration_ps) from every device plane of one
     xplane file — the shared walk under both the compute/collective split
-    and per-op attribution (tools/profile_decode.py)."""
+    and per-op attribution."""
     from tensorflow.tsl.profiler.protobuf import xplane_pb2  # lazy, heavy
 
     xs = xplane_pb2.XSpace()
@@ -130,8 +130,8 @@ def traced_op_times(step: Callable[[], None], steps: int = 1) -> dict[str, float
 
 def split_op_times(times: dict[str, float]) -> tuple[float, float]:
     """Classify per-op times into (compute_ms, collective_ms) — the single
-    home of the I/T classification used by both the CLI --profile-split
-    and the bench's profile stage."""
+    home of the I/T classification behind the CLI's --profile-split and the
+    server's /debug/profile."""
     compute = sum(ms for op, ms in times.items() if not _COLLECTIVE.search(op))
     collective = sum(ms for op, ms in times.items() if _COLLECTIVE.search(op))
     return compute, collective
@@ -140,7 +140,7 @@ def split_op_times(times: dict[str, float]) -> tuple[float, float]:
 def summarize_split(times: dict[str, float], steps: int = 1) -> dict:
     """Per-step compute/collective summary of a per-op times dict — the
     single home of the averaging and percentage math (used by
-    :func:`profiled_split`, the CLI's --profile-split, and the bench)."""
+    :func:`profiled_split`, the CLI's --profile-split and /debug/profile)."""
     compute_ms, collective_ms = split_op_times(times)
     compute_ms /= steps
     collective_ms /= steps

@@ -1,5 +1,6 @@
 """Synthetic models at real shapes: named configs plus seeded `.m`/`.t`
-files written at packed size, for the chip smoke test and the bench.
+files written at packed size, for the chip smoke test and the tests' fixtures
+(the benchmark writes its own: `benchmarks/harness/mformat.py`).
 
 No real model download exists in the environments this runs in, and a
 smoke or timing run needs the operator surface (file → loader → Engine),

@@ -55,6 +55,17 @@ def write_tiny_model(path, *, arch=mfile.ARCH_LLAMA, ftype=quants.Q80,
     return spec
 
 
+def kernel_bodies(lowered_text: str) -> list[bytes]:
+    """The serialized Mosaic module of every Pallas kernel in a TPU
+    lowering's StableHLO text (``custom_call_config.body`` of each
+    ``tpu_custom_call``, base64-decoded): the bytes JAX's persistent compile
+    cache hashes for a kernel."""
+    import base64
+    import re
+    return [base64.b64decode(b) for b in re.findall(
+        r'\\22body\\22: \\22([A-Za-z0-9+/=]+)\\22', lowered_text)]
+
+
 def free_port() -> int:
     """An OS-assigned free TCP port (shared by every server-spawning test)."""
     import socket

@@ -1,6 +1,6 @@
-"""Performance-economics plane tests (obs/cost.py + perf sentinel).
+"""Performance-economics plane tests (obs/cost.py).
 
-Three layers of evidence, tier-1 on CPU:
+Two layers of evidence, tier-1 on CPU:
 
 * **hand counts** — the roofline model's FLOPs/bytes for the tiny
   config are recomputed here from first principles as literal
@@ -11,28 +11,19 @@ Three layers of evidence, tier-1 on CPU:
 * **attribution e2e** — a real staggered scheduler run on the tiny
   engine: ledger counters carry exactly what the tracker carried, every
   flight record gains a cost block, and per-request ``chip_ms`` sums to
-  the scheduler's busy (prefill + decode) goodput component within 5%;
-* **sentinel** — ``tools/perf_sentinel.py`` exits nonzero on a canned
-  20% tok/s regression, zero on an equal pair, loads all three snapshot
-  schemas, and its ``--self-check`` passes (the tier-1 CI hook).
+  the scheduler's busy (prefill + decode) goodput component within 5%.
 """
 
-import importlib.util
-import json
-import os
-import sys
 import threading
 
 import pytest
 
+from dllama_tpu.obs import cost as obs_cost
+from dllama_tpu.obs import dispatch as obs_dispatch
+from dllama_tpu.obs import flight as obs_flight
+from dllama_tpu.obs import metrics as obs_metrics
+
 pytestmark = pytest.mark.obs
-
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-
-from dllama_tpu.obs import cost as obs_cost  # noqa: E402
-from dllama_tpu.obs import dispatch as obs_dispatch  # noqa: E402
-from dllama_tpu.obs import flight as obs_flight  # noqa: E402
-from dllama_tpu.obs import metrics as obs_metrics  # noqa: E402
 
 # tiny_config geometry the hand counts below are written against:
 # dim=64, hidden_dim=96, n_layers=2, n_heads=4, n_kv_heads=2, vocab=128
@@ -55,14 +46,6 @@ def tiny_cost_model(**over):
               kv_el_bytes=4)
     kw.update(over)
     return obs_cost.CostModel(**kw)
-
-
-def _load_tool(name):
-    spec = importlib.util.spec_from_file_location(
-        name, os.path.join(REPO, "tools", f"{name}.py"))
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
 
 
 # --- hand-counted unit costs ----------------------------------------------
@@ -329,86 +312,3 @@ def test_model_from_engine_sniffs_codecs(clean_obs):
     assert cm.tp == 1 and not cm.paged
     # an unmodelable engine degrades to None, never raises
     assert obs_cost.model_from_engine(object()) is None
-
-
-# --- perf sentinel --------------------------------------------------------
-
-def _result(value, extras=None):
-    return {"metric": "tiny decode tok/s", "value": value, "unit": "tok/s",
-            "vs_baseline": None, **({"extras": extras} if extras else {})}
-
-
-def test_sentinel_regression_and_clean_pair(tmp_path, capsys):
-    ps = _load_tool("perf_sentinel")
-    base = tmp_path / "base.json"
-    slow = tmp_path / "slow.json"
-    same = tmp_path / "same.json"
-    base.write_text(json.dumps(_result(100.0)))
-    slow.write_text(json.dumps(_result(80.0)))   # 20% tok/s drop
-    same.write_text(json.dumps(_result(100.0)))
-    assert ps.main([str(base), str(slow)]) == 1
-    assert "regression" in capsys.readouterr().out.lower()
-    assert ps.main([str(base), str(same), "--json"]) == 0
-    rep = json.loads(capsys.readouterr().out)
-    assert rep["verdict"] == "ok" and rep["regressions"] == []
-
-
-def test_sentinel_loads_driver_wrapper_and_jsonl(tmp_path):
-    ps = _load_tool("perf_sentinel")
-    # driver wrapper (BENCH_r*.json shape): result rides in "parsed"
-    wrapper = tmp_path / "BENCH_r98.json"
-    wrapper.write_text(json.dumps(
-        {"n": 98, "cmd": "bench", "rc": 0, "tail": "noise",
-         "parsed": _result(50.0, {"cpu_sched4_agg_toks": 40.0})}))
-    flat = ps.load_any(str(wrapper))
-    assert flat == {"value": 50.0, "cpu_sched4_agg_toks": 40.0}
-    # stage-snapshot JSONL: keys are stage:metric, histograms -> _avg
-    jl = tmp_path / "BENCH_metrics.jsonl"
-    jl.write_text(json.dumps(
-        {"stage": "cpu-tiny-sched4", "ts": 1.0, "schema_version": 2,
-         "metrics": {"schema_version": 2, "sched_goodput_ratio": 0.9,
-                     "mfu": 0.25,
-                     "ttft_seconds": {"count": 2, "sum": 0.4, "avg": 0.2,
-                                      "buckets": {}}}}) + "\n")
-    flat = ps.load_any(str(jl))
-    assert flat["cpu-tiny-sched4:sched_goodput_ratio"] == 0.9
-    assert flat["cpu-tiny-sched4:mfu"] == 0.25
-    assert flat["cpu-tiny-sched4:ttft_seconds_avg"] == 0.2
-    # direction map: latency is lower-better, throughput higher-better
-    assert ps.direction_of("x:ttft_seconds_avg") == "lower"
-    assert ps.direction_of("cpu_sched4_agg_toks") == "higher"
-    assert ps.direction_of("mfu") == "higher"
-
-
-def test_sentinel_self_check_fast():
-    """The tier-1 CI hook: --self-check must pass without touching the
-    filesystem or network."""
-    ps = _load_tool("perf_sentinel")
-    assert ps.self_check() == 0
-    assert ps.main(["--self-check"]) == 0
-
-
-def test_bench_stamps_metrics_bank(tmp_path, monkeypatch):
-    """Satellite: every banked stage row carries schema_version, the
-    bench run id, and the git SHA."""
-    bank = tmp_path / "bank.jsonl"
-    monkeypatch.setenv("BENCH_METRICS_BANK", str(bank))
-    monkeypatch.setenv("BENCH_RUN_ID", "testrun-1")
-    monkeypatch.setenv("BENCH_GIT_SHA", "abc1234")
-    sys.path.insert(0, REPO)
-    import bench
-    bench._bank_stage_metrics("unit-stage")
-    row = json.loads(bank.read_text().strip())
-    assert row["stage"] == "unit-stage"
-    assert row["schema_version"] == row["metrics"]["schema_version"]
-    assert row["bench_run_id"] == "testrun-1"
-    assert row["git_sha"] == "abc1234"
-
-
-def test_bench_vs_baseline_helper():
-    sys.path.insert(0, REPO)
-    import bench
-    assert bench._vs_baseline(19.64, 9.82) == 2.0
-    assert bench._vs_baseline(19.64, None) is None
-    assert bench._vs_baseline(None, 9.82) is None
-    assert bench._vs_baseline(5.0, 0) is None

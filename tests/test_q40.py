@@ -392,30 +392,6 @@ def test_kernel_matches_xla_at_the_rules_new_tiles(form, n, d):
     np.testing.assert_allclose(got, ref, rtol=0, atol=1e-4 * np.abs(ref).max())
 
 
-# First line of every function of ops/q40.py that is a frame while a Q40
-# kernel is traced, as at PR 41 (the kernel took whole ``x``: ``_x_parts`` went
-# and every line below it moved up; as at PR 39 before).
-KERNEL_PATH_LINES = {
-    "_q40_kernel": 348, "_stacked_q40_kernel": 392, "_mm_call": 435,
-    "_pallas_matmul": 493, "_pallas_matmul_stacked": 517,
-    "_pallas_matmul_experts": 549, "_pad_x": 638, "_sharded_matmul": 783,
-    "_sharded_matmul_ep": 856, "matmul_experts": 984, "matmul": 1004, "mm": 1071}
-
-
-def test_the_kernels_trace_path_kept_its_lines():
-    """A Mosaic kernel is serialized with the file and line of every frame
-    that traced it, and the persistent compile cache keys on those bytes: one
-    line added above ``mm`` gives every Q40 program of every model a new key,
-    and the driver's check then compares a change that compiles against a
-    parent that does not (seconds of ``setup_s``: PERF.md §7, PR 35).  So new
-    code that the kernels' call path does not run goes below ``mm``.  A PR
-    that has to move these lines re-pins them here, and knows what it costs."""
-    import inspect
-    got = {name: inspect.unwrap(getattr(q40, name)).__code__.co_firstlineno
-           for name in KERNEL_PATH_LINES}
-    assert got == KERNEL_PATH_LINES
-
-
 def test_dispatch_record_carries_the_tile_pair_and_the_stored_n(caplog):
     import logging
     qt = q40.quantize(_rand((1536, 256), seed=5))

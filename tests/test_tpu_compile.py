@@ -822,3 +822,124 @@ def test_k_exaone_slot_steps_compile_over_the_pool_per_kind(one_chip, monkeypatc
     for plane in ("bf16[2,1025,16,8,128]", "bf16[6,160,16,8,128]"):
         assert plane in text
         assert not re.search(r"= " + re.escape(plane) + r"\S* copy\(", text), plane
+
+
+# ---------------------------------------------------------------------------
+# A kernel's cache key is its program (PR 46)
+# ---------------------------------------------------------------------------
+# The persistent compile cache hashes a Mosaic kernel's serialized module as
+# it is.  By default that payload names the file, function, line and column
+# of the ten innermost Python frames that traced the kernel;
+# hostenv.kernels_without_frames() leaves them out, so the bytes below must
+# not move with a line shift, another checkout path or another call stack.
+KERNEL_FAMILIES = ["q40_mm", "q40_mm_stacked", "q40_mm_experts",
+                   "q40_mm_chosen", "q8_mm", "q8_mm_stacked",
+                   "paged_attn_fused-t1", "paged_attn_fused-t16", "q40_ring"]
+KERNEL_FILES = ("q40", "q8", "attention")
+
+
+def _ops_copies(tmp_path, monkeypatch, blank_line):
+    """``ops/q40.py``, ``q8.py`` and ``attention.py`` copied under another
+    absolute path, with a blank line on top of each if asked (every line of
+    every kernel moves down), and loaded as siblings of the real modules."""
+    import importlib.util
+    import sys
+
+    where = tmp_path / "elsewhere" / "dllama_tpu" / "ops"
+    where.mkdir(parents=True)
+    mods = {}
+    for stem in KERNEL_FILES:
+        with open(q40.__file__.replace("q40.py", stem + ".py")) as f:
+            src = f.read()
+        (where / f"{stem}.py").write_text(("\n" if blank_line else "") + src)
+        name = f"dllama_tpu.ops._elsewhere_{stem}"
+        spec = importlib.util.spec_from_file_location(name, where / f"{stem}.py")
+        mods[stem] = importlib.util.module_from_spec(spec)
+        monkeypatch.setitem(sys.modules, name, mods[stem])
+        spec.loader.exec_module(mods[stem])
+    return mods
+
+
+def _family_launch(family, ops, topo):
+    """One launch of ``family`` out of the modules ``ops`` and the shapes it
+    is lowered at, on the described chip (the ring: on a tp=4 mesh of it)."""
+    one = SingleDeviceSharding(topo.devices[0])
+    s = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one)  # noqa: E731
+    n, d, layers, experts, k = 1024, 1024, 2, 4, 2
+    mq, m8, ma = ops["q40"], ops["q8"], ops["attention"]
+    x1, layer = s((1, n), jnp.bfloat16), s((), jnp.int32)
+    planes = lambda lead: (s((*lead, n // 2, d), jnp.uint8),  # noqa: E731
+                           s((*lead, n // 32, d), jnp.uint16))
+    if family == "q40_mm":
+        return mq._pallas_matmul, (x1, *planes(()))
+    if family == "q40_mm_stacked":
+        return mq._pallas_matmul_stacked, (x1, *planes((layers,)), layer)
+    if family == "q40_mm_experts":
+        return (lambda x, qp, sc, l: mq._pallas_matmul_experts(
+            x, qp, sc, l, experts=experts),
+            (s((16, n), jnp.bfloat16), *planes((layers * experts,)), layer))
+    if family == "q40_mm_chosen":
+        return (lambda x, qp, sc, l, c: mq._pallas_matmul_experts(
+            x, qp, sc, l, experts=experts, chosen=c),
+            (x1, *planes((layers * experts,)), layer, s((k,), jnp.int32)))
+    if family == "q8_mm":
+        return m8._pallas_matmul, (x1, s((n, d), jnp.int8),
+                                   s((n // 32, d), jnp.uint16))
+    if family == "q8_mm_stacked":
+        return m8._pallas_matmul_stacked, (
+            x1, s((layers, n, d), jnp.int8), s((layers, n // 32, d), jnp.uint16),
+            layer)
+    if family.startswith("paged_attn_fused"):
+        t = int(family.rsplit("t", 1)[1])
+        b, hq, hkv, dh, ps, maxp = 4, 8, 2, 128, 16, 16
+        pool = s((layers, 1 + b * maxp, ps, hkv, dh), jnp.bfloat16)
+        return ma.fused_paged_attention, (
+            s((b, hq, t, dh), jnp.bfloat16), pool, pool, layer,
+            s((b, maxp), jnp.int32), s((b,), jnp.int32))
+    assert family == "q40_ring"
+    mesh = Mesh(np.asarray(topo.devices[:4]).reshape(1, 1, 1, 4),
+                ("dp", "sp", "ep", "tp"))
+    ring = jax.shard_map(lambda x: mq._tp_ring_allreduce(x, 4), mesh=mesh,
+                         in_specs=P(), out_specs=P(), check_vma=False)
+    return ring, (jax.ShapeDtypeStruct((8, 1024), jnp.float32,
+                                       sharding=NamedSharding(mesh, P())),)
+
+
+def _kernel_body(fn, shapes, frames_between=0) -> bytes:
+    """The serialized Mosaic module of the one kernel ``fn`` launches, as it
+    goes into the custom call's ``backend_config``: what the cache hashes.
+    ``fn`` is wrapped anew on every call (a lowering is cached by its traced
+    function), in ``frames_between`` more Python frames if asked."""
+    from fixtures import kernel_bodies
+
+    def launch(*xs, depth=frames_between):
+        return launch(*xs, depth=depth - 1) if depth else fn(*xs)
+
+    (body,) = kernel_bodies(
+        jax.jit(lambda *xs: launch(*xs)).lower(*shapes).as_text())
+    return body
+
+
+@pytest.mark.parametrize("moved", ["line-shift", "other-path", "other-stack"])
+@pytest.mark.parametrize("family", KERNEL_FAMILIES)
+def test_kernel_key_ignores_where_the_python_stands(topo, tmp_path, monkeypatch,
+                                                    family, moved):
+    from dllama_tpu import hostenv
+    from dllama_tpu.ops import q8
+
+    monkeypatch.delenv("JAX_TRACEBACK_IN_LOCATIONS_LIMIT", raising=False)
+    limit = jax.config.jax_traceback_in_locations_limit
+    hostenv.kernels_without_frames()  # what every entry point and Engine call
+    try:
+        here = {"q40": q40, "q8": q8, "attention": att}
+        want = _kernel_body(*_family_launch(family, here, topo))
+        assert b".py" not in want and len(want) > 1000
+        if moved == "other-stack":
+            got = _kernel_body(*_family_launch(family, here, topo),
+                               frames_between=3)
+        else:
+            there = _ops_copies(tmp_path, monkeypatch, moved == "line-shift")
+            got = _kernel_body(*_family_launch(family, there, topo))
+        assert got == want
+    finally:
+        jax.config.update("jax_traceback_in_locations_limit", limit)
