@@ -7,7 +7,11 @@ in the canonical tensor order; beyond the reference, deepseek_v2 folders
 (``ARCH_OLMOE``), smallthinker folders (``ARCH_SMALLTHINKER``: header keys
 31..34, rows not permuted) and exaone_moe folders (``ARCH_EXAONE_MOE``: K-EXAONE;
 ``--experts-held N --first-expert I`` write one chip's share of every layer's
-routed experts; ``mtp.*`` tensors are skipped).  Key semantics preserved:
+routed experts; ``mtp.*`` tensors are skipped) and lfm2_moe folders
+(``ARCH_LFM2_MOE``: LFM2's gated short-convolution and attention layers,
+header keys 19, 23, 24, 31, 32, 34, 37, 38; ``conv.conv.weight`` (D, 1, L)
+becomes the flat ``conv_taps``; a tied head is written from the embedding's
+rows).  Key semantics preserved:
 
 * q/k head permutation (convert-hf.py:12-15): HF stores RoPE in rotate-half
   layout; the `.m` format expects the interleaved-pair layout, so q and k
@@ -44,6 +48,7 @@ ARCH_BY_MODEL_TYPE = {
     "deepseek_v2": mfile.ARCH_DEEPSEEK2,
     "smallthinker": mfile.ARCH_SMALLTHINKER,
     "exaone_moe": mfile.ARCH_EXAONE_MOE,
+    "lfm2_moe": mfile.ARCH_LFM2_MOE,
 }
 HIDDEN_ACT = {"gelu": mfile.ACT_GELU, "silu": mfile.ACT_SILU,
               "relu": mfile.ACT_RELU}
@@ -230,6 +235,53 @@ def _exaone_moe_fields(config: dict, experts_held: int, first_expert: int) -> di
                 experts_held=0 if held == n else held, first_expert=first_expert)
 
 
+def _lfm2_moe_fields(config: dict) -> dict:
+    """The header's keys past the fourteen from an ``lfm2_moe`` config.json.
+    ``ARCH_LFM2_MOE`` is one block: whole periods of ``conv`` layers with one
+    ``full_attention`` layer, a bias-free convolution, a sigmoid router with a
+    choice bias whose chosen scores are normalised, a dense prefix, unscaled
+    RoPE.  What the runtime does not compute is refused by name."""
+    def no(why):
+        raise SystemExit(f"lfm2_moe: {why}")
+
+    if config.get("conv_bias", False):
+        no("conv_bias is true; the runtime's convolution and its projections "
+           "have no bias")
+    if not config.get("norm_topk_prob", True):
+        no("norm_topk_prob is false; the runtime normalises the chosen scores")
+    if not config.get("use_expert_bias", True):
+        no("use_expert_bias is false; the runtime's router for this "
+           "architecture adds a choice bias (the .m file carries one)")
+    rope = config.get("rope_parameters") or {}
+    if rope.get("rope_type", "default") != "default":
+        no(f"rope_type is {rope.get('rope_type')!r}; the runtime's RoPE is unscaled")
+    layers = config["num_hidden_layers"]
+    types = list(config["layer_types"])
+    kinds = [t == "full_attention" for t in types]
+    at = kinds.index(True) if True in kinds else -1
+    period = kinds[at + 1:].index(True) + 1 if True in kinds[at + 1:] else 0
+    if set(types) - {"conv", "full_attention"} or len(kinds) != layers \
+            or period < 2 or layers % period or kinds != [
+            j == at for j in range(period)] * (layers // period):
+        no(f"layer_types {types} is not whole periods of conv layers with one "
+           "full_attention layer")
+    n, k = int(config["num_experts"]), int(config["num_experts_per_tok"])
+    if not 1 <= k <= n:
+        no(f"num_experts_per_tok {k} is more than num_experts {n}")
+    dense = int(config.get("num_dense_layers", 0))
+    if not 0 <= dense < layers:
+        no(f"num_dense_layers {dense} leaves no expert layer of {layers}")
+    if config["hidden_size"] % config["num_attention_heads"]:
+        no("hidden_size is not a multiple of num_attention_heads")
+    return dict(moe_hidden_dim=config["moe_intermediate_size"],
+                n_dense_layers=dense,
+                routed_scale=float(config.get("routed_scaling_factor", 1.0)),
+                norm_eps=float(config.get("norm_eps", 1e-5)),
+                head_dim=config["hidden_size"] // config["num_attention_heads"],
+                window_period=period, window_full_at=at,
+                conv_taps=int(config["conv_L_cache"]))
+
+
 def load_spec(folder: str, weights_ftype: int, experts_held: int = 0,
               first_expert: int = 0) -> mfile.ModelSpec:
     with open(os.path.join(folder, "config.json")) as f:
@@ -244,10 +296,13 @@ def load_spec(folder: str, weights_ftype: int, experts_held: int = 0,
         ext = _smallthinker_fields(config)
         config = dict(config, intermediate_size=config["moe_ffn_hidden_size"],
                       hidden_act="relu")
-    if arch == mfile.ARCH_EXAONE_MOE:
-        ext = _exaone_moe_fields(config, experts_held, first_expert)
+    if arch == mfile.ARCH_LFM2_MOE and not (experts_held or first_expert):
+        ext = _lfm2_moe_fields(config)
+    if arch in (mfile.ARCH_EXAONE_MOE, mfile.ARCH_LFM2_MOE):
         config = dict(config, rope_theta=(config.get("rope_parameters") or {}).get(
             "rope_theta", config.get("rope_theta", 10000.0)))
+    if arch == mfile.ARCH_EXAONE_MOE:
+        ext = _exaone_moe_fields(config, experts_held, first_expert)
     elif experts_held or first_expert:
         raise SystemExit("--experts-held / --first-expert write a share of an "
                          "exaone_moe model's experts; this is "
@@ -306,6 +361,16 @@ class SafetensorsStore:
         return np.asarray(t, dtype=np.float32)
 
 
+# an lfm2_moe layer's tensors under ``model.layers.N.`` (transformers'
+# Lfm2Moe names; the experts' w1 / w3 / w2 are gate / up / down)
+_LFM2_LEAVES = {
+    "conv_in": "conv.in_proj", "conv_taps": "conv.conv", "conv_out": "conv.out_proj",
+    "wq": "self_attn.q_proj", "wk": "self_attn.k_proj", "wv": "self_attn.v_proj",
+    "wo": "self_attn.out_proj", "q_norm": "self_attn.q_layernorm",
+    "k_norm": "self_attn.k_layernorm", "rms_att": "operator_norm",
+    "rms_ffn": "ffn_norm", "w1": "feed_forward.w1", "w2": "feed_forward.w2",
+    "w3": "feed_forward.w3", "moe_router": "feed_forward.gate",
+}
 _DEEPSEEK2_LEAVES = {
     "wq_a": "self_attn.q_a_proj", "q_a_norm": "self_attn.q_a_layernorm",
     "wq_b": "self_attn.q_b_proj", "wkv_a": "self_attn.kv_a_proj_with_mqa",
@@ -321,13 +386,21 @@ def hf_source_name(our_name: str, spec: mfile.ModelSpec) -> tuple[str, bool]:
     if our_name == "token_embedding":
         return "model.embed_tokens.weight", False
     if our_name == "rms_final":
-        return "model.norm.weight", False
+        return ("model.embedding_norm.weight" if spec.arch == mfile.ARCH_LFM2_MOE
+                else "model.norm.weight"), False
     if our_name == "wcls":
         return "lm_head.weight", False
     parts = our_name.split(".")
     li = parts[1]
     leaf = parts[-1]
     base = f"model.layers.{li}"
+    if spec.arch == mfile.ARCH_LFM2_MOE:  # rows as published: halves rotate
+        if leaf == "moe_router_bias":
+            return f"{base}.feed_forward.expert_bias", False
+        if parts[2] == "experts":
+            hf_leaf = {"up": "w3", "gate": "w1", "down": "w2"}[leaf]
+            return f"{base}.feed_forward.experts.{parts[3]}.{hf_leaf}.weight", False
+        return f"{base}.{_LFM2_LEAVES[leaf]}.weight", False
     # rows as published: these runtimes rotate halves, as HF does
     olmoe = spec.arch in (mfile.ARCH_OLMOE, mfile.ARCH_SMALLTHINKER,
                           mfile.ARCH_EXAONE_MOE)
@@ -392,6 +465,10 @@ def convert(folder: str, weights_ftype: int, out_path: str,
     with mfile.MFileWriter(out_path, spec) as w:
         for item in w.plan:
             key, do_permute = hf_source_name(item.name, spec)
+            if item.name == "wcls" and spec.arch == mfile.ARCH_LFM2_MOE \
+                    and not store.has(key):
+                # the published model ties its head to the embedding
+                key = "model.embed_tokens.weight"
             t = store.get(key)
             if do_permute:
                 heads = spec.n_heads if item.name.endswith("wq") else spec.n_kv_heads

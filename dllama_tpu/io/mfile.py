@@ -26,7 +26,12 @@ the reference converters load directly:
   shared expert, groups, dense prefix, routed scale), and 35..37 for the share
   of the experts it holds and the full layer's place in a period; per layer a
   ``q_norm`` / ``k_norm`` of one head's size and, in an expert layer,
-  ``moe_router_bias``.  Matmul weights are stored row-major ``(d_out, n_in)`` in the
+  ``moe_router_bias``.  An LFM2 file (``ARCH_LFM2_MOE``) has keys 19, 23, 24,
+31, 32, 34, 37 and 38 (the convolution's taps): the layer at a period's place
+``window_full_at`` is K-EXAONE's attention (``wq`` .. ``k_norm``) and every
+other layer a gated short convolution (``conv_in`` (3 dim, dim), ``conv_taps``
+(dim x taps values, f32, channel by channel), ``conv_out`` (dim, dim)); no
+shared expert.  Matmul weights are stored row-major ``(d_out, n_in)`` in the
   model's weight float type; norm weights and the embedding are F32
   (transformer.cpp:213-218, 266-278).
 
@@ -74,10 +79,16 @@ ARCH_SMALLTHINKER = 0xABCD05
 # shared expert.  A file may hold a share of every layer's routed experts
 # (keys 35, 36): one chip's part of an expert-parallel deployment
 ARCH_EXAONE_MOE = 0xABCD06
+# LFM2 (``lfm2_moe``): periods in which one layer is attention (per-head q/k
+# RMSNorm, rotate-half RoPE, at the place key 37 says) and the others are gated
+# short convolutions of ``conv_taps`` taps (key 38) that keep a state, not keys
+# and values; leading dense layers, then a sigmoid router with a choice bias
+# and a ``+ 1e-6`` in the normalisation, no shared expert
+ARCH_LFM2_MOE = 0xABCD07
 ARCH_NAMES = {ARCH_LLAMA: "llama", ARCH_GROK1: "grok1", ARCH_MIXTRAL: "mixtral",
               ARCH_OLMOE: "olmoe", ARCH_DEEPSEEK2: "deepseek2",
               ARCH_SMALLTHINKER: "smallthinker",
-              ARCH_EXAONE_MOE: "exaone_moe"}
+              ARCH_EXAONE_MOE: "exaone_moe", ARCH_LFM2_MOE: "lfm2_moe"}
 
 # TransformerHiddenAct (transformer.hpp:45-48), and beyond it ReLU
 ACT_GELU = 0
@@ -100,8 +111,9 @@ KEY_HIDDEN_ACT = 11
 KEY_ROPE_THETA = 12
 KEY_WEIGHTS_FLOAT_TYPE = 13
 # beyond the reference's fourteen: DeepSeek-V2's (``EXT_KEYS``, 14..31),
-# SmallThinker's own (``WINDOW_KEYS``, 32..34; its file also carries key 31)
-# and K-EXAONE's (``SHARE_KEYS``, 35..37; its file carries some of each).
+# SmallThinker's own (``WINDOW_KEYS``, 32..34; its file also carries key 31),
+# K-EXAONE's (``SHARE_KEYS``, 35..37; its file carries some of each) and
+# LFM2's one (``CONV_KEYS``, 38; its file carries some of each).
 # ``(key, field, is_float)``: a float travels as the bits of its IEEE-754 f32
 # in the i32
 EXT_KEYS = (
@@ -134,13 +146,20 @@ SHARE_KEYS = (
     (36, "first_expert", False),        # the router's index of the first held one
     (37, "window_full_at", False),      # layer l is full iff l % period == this
 )
-ALL_EXT_KEYS = EXT_KEYS + WINDOW_KEYS + SHARE_KEYS
+CONV_KEYS = (
+    (38, "conv_taps", False),           # taps of a short-convolution layer (conv_L_cache)
+)
+ALL_EXT_KEYS = EXT_KEYS + WINDOW_KEYS + SHARE_KEYS + CONV_KEYS
 # the keys a file of an arch carries past the fourteen
 ARCH_EXT_KEYS = {ARCH_DEEPSEEK2: tuple(range(14, 32)),
                  ARCH_SMALLTHINKER: (31, 32, 33, 34),
-                 ARCH_EXAONE_MOE: (19, 20, 21, 22, 23, 24) + tuple(range(31, 38))}
+                 ARCH_EXAONE_MOE: (19, 20, 21, 22, 23, 24) + tuple(range(31, 38)),
+                 # which layer of a period is attention: keys 34 and 37 as
+                 # K-EXAONE's (a period and a place in it; no second pair of
+                 # keys for the same two numbers); no window, so no key 33
+                 ARCH_LFM2_MOE: (19, 23, 24, 31, 32, 34, 37, 38)}
 _EXT_BY_KEY = {k: (name, is_f) for k, name, is_f in ALL_EXT_KEYS}
-KEY_MAX = SHARE_KEYS[-1][0]
+KEY_MAX = CONV_KEYS[-1][0]
 
 
 def _f32_bits(x: float) -> int:
@@ -197,6 +216,8 @@ class ModelSpec:
     experts_held: int = 0
     first_expert: int = 0
     window_full_at: int = 0
+    # ARCH_LFM2_MOE's; 0 where the arch has none
+    conv_taps: int = 0
 
     @property
     def head_size(self) -> int:
@@ -260,9 +281,9 @@ def tensor_plan(spec: ModelSpec) -> list[TensorInfo]:
     add("token_embedding", (spec.vocab_size, spec.dim), quants.F32)
     if spec.arch == ARCH_DEEPSEEK2:
         _deepseek2_layers(spec, add)
-    if spec.arch == ARCH_EXAONE_MOE:
+    if spec.arch in (ARCH_EXAONE_MOE, ARCH_LFM2_MOE):
         _exaone_moe_layers(spec, add)
-    own_layers = spec.arch in (ARCH_DEEPSEEK2, ARCH_EXAONE_MOE)
+    own_layers = spec.arch in (ARCH_DEEPSEEK2, ARCH_EXAONE_MOE, ARCH_LFM2_MOE)
     for i in range(0 if own_layers else spec.n_layers):
         add(f"layers.{i}.wq", (spec.q_dim, spec.dim), w)
         add(f"layers.{i}.wk", (spec.kv_dim, spec.dim), w)
@@ -333,16 +354,25 @@ def _exaone_moe_layers(spec: ModelSpec, add) -> None:
     the router over all ``n_experts`` with its choice bias, the
     ``n_experts_held`` experts this file holds (file index ``e`` is the
     router's ``first_expert + e``) and the shared expert; then the two block
-    norms."""
+    norms.  An LFM2 file is the same walk with, in every layer that is not its
+    period's attention layer, the convolution's three tensors in the attention
+    tensors' place (``conv_taps`` flat, channel by channel: value ``c * taps +
+    j`` weighs ``z[t - (taps - 1) + j]`` in channel ``c``), and no shared
+    expert."""
     w, d, f = spec.weights_ftype, spec.dim, spec.moe_hidden_dim
     for i in range(spec.n_layers):
         p = f"layers.{i}."
-        add(p + "wq", (spec.q_dim, d), w)
-        add(p + "wk", (spec.kv_dim, d), w)
-        add(p + "wv", (spec.kv_dim, d), w)
-        add(p + "wo", (d, spec.q_dim), w)
-        add(p + "q_norm", (spec.head_size,), quants.F32)
-        add(p + "k_norm", (spec.head_size,), quants.F32)
+        if spec.conv_taps and i % spec.window_period != spec.window_full_at:
+            add(p + "conv_in", (3 * d, d), w)
+            add(p + "conv_taps", (d * spec.conv_taps,), quants.F32)
+            add(p + "conv_out", (d, d), w)
+        else:
+            add(p + "wq", (spec.q_dim, d), w)
+            add(p + "wk", (spec.kv_dim, d), w)
+            add(p + "wv", (spec.kv_dim, d), w)
+            add(p + "wo", (d, spec.q_dim), w)
+            add(p + "q_norm", (spec.head_size,), quants.F32)
+            add(p + "k_norm", (spec.head_size,), quants.F32)
         if i < spec.n_dense_layers:
             add(p + "w1", (spec.hidden_dim, d), w)
             add(p + "w2", (d, spec.hidden_dim), w)
@@ -355,9 +385,10 @@ def _exaone_moe_layers(spec: ModelSpec, add) -> None:
                 add(f"{p}experts.{e}.gate", (f, d), w)
                 add(f"{p}experts.{e}.down", (d, f), w)
             fs = f * spec.n_shared_experts
-            add(p + "shared_w1", (fs, d), w)
-            add(p + "shared_w2", (d, fs), w)
-            add(p + "shared_w3", (fs, d), w)
+            if fs:
+                add(p + "shared_w1", (fs, d), w)
+                add(p + "shared_w2", (d, fs), w)
+                add(p + "shared_w3", (fs, d), w)
         add(p + "rms_att", (d,), quants.F32)
         add(p + "rms_ffn", (d,), quants.F32)
 
@@ -440,19 +471,27 @@ def validate_spec(spec: ModelSpec, path) -> ModelSpec:
                             got=spec.n_active_experts)
     if spec.arch in (ARCH_SMALLTHINKER, ARCH_EXAONE_MOE):
         _validate_smallthinker(spec, path)
+    elif spec.arch == ARCH_LFM2_MOE:
+        _validate_lfm2_moe(spec, path)
     elif spec.head_dim or spec.window or spec.window_period:
         raise ArtifactError(path, "header key",
-                            "keys 32..34 describe a smallthinker file (or an exaone_moe one)",
+                            "keys 32..34 describe a smallthinker file (or an "
+                            "exaone_moe or lfm2_moe one)",
                             expected=hex(ARCH_SMALLTHINKER), got=hex(spec.arch))
+    if spec.arch != ARCH_LFM2_MOE and spec.conv_taps:
+        raise ArtifactError(path, "header key",
+                            "key 38 describes an lfm2_moe file",
+                            expected=hex(ARCH_LFM2_MOE), got=hex(spec.arch))
     if spec.arch == ARCH_EXAONE_MOE:
         _validate_exaone_moe(spec, path)
-    elif spec.experts_held or spec.first_expert or spec.window_full_at:
+    elif spec.experts_held or spec.first_expert or (
+            spec.window_full_at and spec.arch != ARCH_LFM2_MOE):
         raise ArtifactError(path, "header key",
                             "keys 35..37 describe an exaone_moe file",
                             expected=hex(ARCH_EXAONE_MOE), got=hex(spec.arch))
     if spec.arch == ARCH_DEEPSEEK2:
         _validate_deepseek2(spec, path)
-    elif spec.arch != ARCH_EXAONE_MOE and (  # which carries six of those keys
+    elif spec.arch not in (ARCH_EXAONE_MOE, ARCH_LFM2_MOE) and (  # which carry some of those keys
             spec.is_mla or spec.n_dense_layers or spec.n_shared_experts
             or spec.n_groups or spec.moe_hidden_dim):
         raise ArtifactError(path, "header key",
@@ -523,6 +562,45 @@ def _validate_exaone_moe(spec: ModelSpec, path) -> None:
     if spec.n_active_experts > spec.n_experts:
         bad("n_active_experts", "more experts a token than the router has",
             f"<= {spec.n_experts}", spec.n_active_experts)
+
+
+def _validate_lfm2_moe(spec: ModelSpec, path) -> None:
+    """The cross-field rules of an ``ARCH_LFM2_MOE`` header."""
+    def bad(field, why, expected, got):
+        raise ArtifactError(path, f"header field {field}", why,
+                            expected=expected, got=got)
+
+    if not 2 <= spec.head_dim <= 4096 or spec.head_dim % 2:
+        bad("head_dim", "an lfm2_moe file states its head size, and RoPE "
+            "rotates halves of it", "even, 2..4096", spec.head_dim)
+    if spec.window:
+        bad("window", "an lfm2_moe file has no sliding window: the layers "
+            "beside a period's attention layer are convolutions", 0, spec.window)
+    if spec.window_period < 2 or spec.n_layers % spec.window_period:
+        bad("window_period", "the layers are whole periods of one attention "
+            "layer and window_period - 1 convolution layers",
+            f">= 2, a divisor of n_layers={spec.n_layers}", spec.window_period)
+    if not 0 <= spec.window_full_at < spec.window_period:
+        bad("window_full_at", "the attention layer's place in a period",
+            f"0..{spec.window_period - 1}", spec.window_full_at)
+    if not 2 <= spec.conv_taps <= 64:
+        bad("conv_taps", "an lfm2_moe file states its convolution's taps "
+            "(conv_L_cache)", "2..64", spec.conv_taps)
+    if not 1 <= spec.moe_hidden_dim <= 1 << 24:
+        bad("moe_hidden_dim", "an lfm2_moe file states its experts' width",
+            "1..2^24", spec.moe_hidden_dim)
+    if not 0 <= spec.n_dense_layers < spec.n_layers:
+        bad("n_dense_layers", "the dense layers lead and expert layers follow",
+            f"0..{spec.n_layers - 1}", spec.n_dense_layers)
+    if not spec.n_experts or not spec.n_active_experts:
+        bad("n_experts", "the layers past the dense ones have experts and a "
+            "top-k", ">= 1", spec.n_experts)
+    if not spec.routed_scale > 0:
+        bad("routed_scale", "must be positive", "> 0", spec.routed_scale)
+    if spec.n_shared_experts or spec.n_groups or spec.topk_groups:
+        bad("n_shared_experts", "an lfm2_moe layer has no shared expert and "
+            "one group of experts (keys 20..22 are not its own)", 0,
+            (spec.n_shared_experts, spec.n_groups, spec.topk_groups))
 
 
 def _validate_deepseek2(spec: ModelSpec, path) -> None:

@@ -83,6 +83,8 @@ class ModelConfig:
     experts_held: int = 0           # routed experts a layer holds planes for (0: all)
     first_expert: int = 0           # the router's index of the first held expert
     window_full_at: int = 0         # the full layer's place in a period
+    # ---- ARCH_LFM2_MOE (header key 38); 0 = the arch has none
+    conv_taps: int = 0              # > 0: a period's other layers are short convolutions
 
     @property
     def head_size(self) -> int:
@@ -93,13 +95,40 @@ class ModelConfig:
         return self.head_size * self.n_heads
 
     @property
+    def periodic(self) -> bool:
+        """The layers come in periods of ``window_period`` of which one, at
+        ``window_full_at``, is full attention (``models/windowed.py``): the
+        others are sliding-window layers (``window``) or gated short
+        convolutions (``conv_taps``)."""
+        return self.window > 0 or self.conv_taps > 0
+
+    @property
     def n_full_layers(self) -> int:
-        """Layers that cache every position (all of them without a window)."""
-        return self.n_layers // self.window_period if self.window else self.n_layers
+        """Layers that cache every position (all of them in a model without
+        periods)."""
+        return self.n_layers // self.window_period if self.periodic else self.n_layers
 
     @property
     def n_window_layers(self) -> int:
-        return self.n_layers - self.n_full_layers
+        return self.n_layers - self.n_full_layers if self.window else 0
+
+    @property
+    def n_conv_layers(self) -> int:
+        """Layers that keep a convolution state and no keys or values."""
+        return self.n_layers - self.n_full_layers if self.conv_taps else 0
+
+    @property
+    def full_rotates(self) -> bool:
+        """A period's full-attention layer carries rotate-half RoPE (LFM2); in
+        a windowed model it is unrotated (NoPE) and the window layers rotate."""
+        return self.conv_taps > 0
+
+    @property
+    def router_norm_eps(self) -> float:
+        """Added to the sum of the chosen scores before a sigmoid router's
+        weights are divided by it: LFM2's ``+ 1e-6``; 0 where the arch has
+        none (K-EXAONE)."""
+        return 1e-6 if self.arch == mfile.ARCH_LFM2_MOE else 0.0
 
     @property
     def n_experts_held(self) -> int:
@@ -200,15 +229,17 @@ class ModelConfig:
     def qk_head_norm(self) -> bool:
         """K-EXAONE RMS-normalises each head of q and of k over its own
         ``head_size`` values, one weight vector of that size each a layer
-        (``q_norm`` / ``k_norm``), before RoPE."""
-        return self.arch == mfile.ARCH_EXAONE_MOE
+        (``q_norm`` / ``k_norm``), before RoPE; so does LFM2 in its attention
+        layers."""
+        return self.arch in (mfile.ARCH_EXAONE_MOE, mfile.ARCH_LFM2_MOE)
 
     @property
     def router_sigmoid(self) -> bool:
         """K-EXAONE's router (DeepSeek-V3's): sigmoid scores, a per-expert
         bias added for the choice only (``router_bias``), the chosen scores
-        normalised to sum to 1 and scaled by ``routed_scale``."""
-        return self.arch == mfile.ARCH_EXAONE_MOE
+        normalised to sum to 1 (LFM2: over their sum ``+ router_norm_eps``)
+        and scaled by ``routed_scale``."""
+        return self.arch in (mfile.ARCH_EXAONE_MOE, mfile.ARCH_LFM2_MOE)
 
     @property
     def qk_norm(self) -> bool:
@@ -282,6 +313,20 @@ def tiny_exaone_moe(**kw) -> ModelConfig:
                 moe_hidden_dim=32, n_shared_experts=1, n_groups=1,
                 topk_groups=1, n_dense_layers=1, routed_scale=2.5,
                 experts_held=4, first_expert=4)
+    base.update(kw)
+    return tiny_config(**base)
+
+
+def tiny_lfm2_moe(**kw) -> ModelConfig:
+    """LFM2 at a toy size that keeps every ratio: periods ``conv, conv,
+    attention, conv``, two leading dense layers of their own width, 3 taps, 4
+    query heads a kv head, a head size that is dim / n_heads stated in the
+    header, 16 experts of which 4 a token, no shared expert, routed scale 1."""
+    base = dict(arch=mfile.ARCH_LFM2_MOE, dim=64, hidden_dim=96, n_layers=8,
+                n_heads=8, n_kv_heads=2, n_experts=16, n_active_experts=4,
+                vocab_size=128, seq_len=128, rope_theta=1e6, norm_eps=1e-5,
+                head_dim=8, window_period=4, window_full_at=2, conv_taps=3,
+                moe_hidden_dim=32, n_dense_layers=2, routed_scale=1.0)
     base.update(kw)
     return tiny_config(**base)
 

@@ -34,6 +34,12 @@ MLA_ATT_KEYS = ("wq_a", "wkv_a", "wqkv_a", "q_a_norm", "wq_b", "kv_a_norm",
 DENSE_FFN_KEYS = ("w1", "w2", "w3", "w13")
 MOE_FFN_KEYS = ("router", "router_bias", "up", "gate", "down", "shared_w1",
                 "shared_w2", "shared_w3", "shared_w13")
+# LFM2's stacks by layer KIND: a convolution layer's three tensors over the
+# convolution layers, the attention tensors over the attention layers alone (a
+# stack's leading index is ``windowed.kind_index``); the block norms stay over
+# all L
+CONV_KEYS = ("conv_in", "conv_taps", "conv_out")
+ATT_KIND_KEYS = ("wq", "wk", "wv", "wqkv", "wo", "q_norm", "k_norm")
 
 
 def _mla_param_shapes(cfg: ModelConfig) -> dict[str, tuple[int, ...]]:
@@ -70,21 +76,27 @@ def _exaone_param_shapes(cfg: ModelConfig) -> dict[str, tuple[int, ...]]:
     over the leading ``n_dense_layers``, router / held experts / shared expert
     over the rest (a stack's leading index is a layer's index within its
     segment, as DeepSeek-V2's).  The router has ``n_experts`` columns; the
-    expert stacks have ``n_experts_held`` planes a layer."""
+    expert stacks have ``n_experts_held`` planes a layer.  LFM2's are the same
+    with the attention stacks over its attention layers alone and the
+    convolution's (``CONV_KEYS``) over the others."""
     L, D, V = cfg.n_layers, cfg.dim, cfg.vocab_size
     Ld, Le, Dh = cfg.n_dense_layers, cfg.n_moe_layers, cfg.head_size
     E, H, F = cfg.n_experts, cfg.n_experts_held, cfg.expert_dim
     Fs = F * cfg.n_shared_experts
+    La, Lc = (cfg.n_full_layers, cfg.n_conv_layers) if cfg.conv_taps else (L, 0)
     shapes = {
         "embedding": (V, D),
-        "wq": (L, D, cfg.q_dim), "wk": (L, D, cfg.kv_dim),
-        "wv": (L, D, cfg.kv_dim), "wo": (L, cfg.q_dim, D),
-        "q_norm": (L, Dh), "k_norm": (L, Dh),
+        "wq": (La, D, cfg.q_dim), "wk": (La, D, cfg.kv_dim),
+        "wv": (La, D, cfg.kv_dim), "wo": (La, cfg.q_dim, D),
+        "q_norm": (La, Dh), "k_norm": (La, Dh),
         "rms_att": (L, D), "rms_ffn": (L, D),
         "rms_final": (D,), "wcls": (D, V),
         "router": (Le, D, E), "router_bias": (Le, E),
         "up": (Le, H, D, F), "gate": (Le, H, D, F), "down": (Le, H, F, D),
     }
+    if Lc:
+        shapes.update({"conv_in": (Lc, D, 3 * D), "conv_taps": (Lc, D, cfg.conv_taps),
+                       "conv_out": (Lc, D, D)})
     if Ld:
         Fd = cfg.hidden_dim
         shapes.update({"w1": (Ld, D, Fd), "w2": (Ld, Fd, D), "w3": (Ld, D, Fd)})
@@ -97,7 +109,7 @@ def _exaone_param_shapes(cfg: ModelConfig) -> dict[str, tuple[int, ...]]:
 def param_shapes(cfg: ModelConfig) -> dict[str, tuple[int, ...]]:
     if cfg.is_mla:
         return _mla_param_shapes(cfg)
-    if cfg.arch == mfile.ARCH_EXAONE_MOE:
+    if cfg.arch in (mfile.ARCH_EXAONE_MOE, mfile.ARCH_LFM2_MOE):
         return _exaone_param_shapes(cfg)
     L, D, F, V = cfg.n_layers, cfg.dim, cfg.hidden_dim, cfg.vocab_size
     Hq = cfg.n_heads * cfg.head_size       # == D
@@ -141,7 +153,9 @@ def init_params(cfg: ModelConfig, seed: int = 0, scale: float = 0.02) -> Params:
             x = np.ones(shape, dtype=np.float32)
         else:
             x = (rng.standard_normal(shape) * scale).astype(np.float32)
-        f32 = norm or name == "router_bias"
+        if name == "conv_taps":  # O(1) taps: the state matters to the logits
+            x = (0.5 + 0.5 * rng.standard_normal(shape)).astype(np.float32)
+        f32 = norm or name in ("router_bias", "conv_taps")
         params[name] = jnp.asarray(x, dtype=jnp.float32 if f32 else cfg.dtype)
     return params
 
@@ -193,7 +207,7 @@ def quantize_matmuls(params: Params, cfg: ModelConfig,
     out = dict(params)
     if cfg.is_mla:
         return _quantize_mla(out, fuse)
-    if cfg.arch == mfile.ARCH_EXAONE_MOE:
+    if cfg.arch in (mfile.ARCH_EXAONE_MOE, mfile.ARCH_LFM2_MOE):
         # two FFN kinds beside Llama's attention: _quantize_mla's key list
         # covers them (absent keys are skipped), after the q/k/v join
         if fuse:
@@ -241,7 +255,8 @@ def _quantize_mla(out: Params, fuse: bool) -> Params:
                 out[pre + "13"] = q40.quantize(
                     np.concatenate([f32(pre + "1"), f32(pre + "3")], -1))
     for k in ("wq_a", "wkv_a", "wq_b", "wo", "wcls", "w1", "w2", "w3", "up",
-              "gate", "down", "shared_w1", "shared_w2", "shared_w3"):
+              "gate", "down", "shared_w1", "shared_w2", "shared_w3", "conv_in",
+              "conv_out"):
         if k in out:
             out[k] = q40.quantize(np.asarray(out[k], np.float32))
     return out
@@ -319,7 +334,7 @@ def _read_params(mf: mfile.MFile, cfg: ModelConfig, dtype,
     L = cfg.n_layers
     p: Params = {}
     p["embedding"] = mf.tensor("token_embedding").astype(np_dtype)
-    if cfg.is_mla or cfg.arch == mfile.ARCH_EXAONE_MOE:
+    if cfg.is_mla or cfg.arch in (mfile.ARCH_EXAONE_MOE, mfile.ARCH_LFM2_MOE):
         read = _read_mla_layers if cfg.is_mla else _read_exaone_layers
         read(mf, cfg, p, np_dtype, codec if quant else None, fuse)
         return _read_tail(mf, p, np_dtype, codec if quant else None)
@@ -428,8 +443,13 @@ def _read_exaone_layers(mf: mfile.MFile, cfg: ModelConfig, p: Params, np_dtype,
                         codec, fuse: bool) -> None:
     """A K-EXAONE file's layer stacks into ``p`` (``codec`` None: dense): the
     attention of every layer with its two head norms, then the FFN segments;
-    the router's choice bias stays float32."""
-    att = range(cfg.n_layers)
+    the router's choice bias stays float32.  An LFM2 file's: the attention of
+    its attention layers, the convolution of the others (the taps float32,
+    ``(Lc, dim, taps)``)."""
+    every = range(cfg.n_layers)
+    att = [i for i in every if not cfg.conv_taps
+           or i % cfg.window_period == cfg.window_full_at]
+    conv = [i for i in every if i not in att]
     st = _Stacks(mf, p, np_dtype, codec)
     join = codec is not None and fuse
     if join:
@@ -437,7 +457,12 @@ def _read_exaone_layers(mf: mfile.MFile, cfg: ModelConfig, p: Params, np_dtype,
         st.mats(("wo",), att)
     else:
         st.mats(("wq", "wk", "wv", "wo"), att)
-    st.vecs(("q_norm", "k_norm", "rms_att", "rms_ffn"), att)
+    st.vecs(("q_norm", "k_norm"), att)
+    st.vecs(("rms_att", "rms_ffn"), every)
+    if conv:
+        st.mats(("conv_in", "conv_out"), conv)
+        st.vecs(("conv_taps",), conv)
+        p["conv_taps"] = p["conv_taps"].reshape(len(conv), cfg.dim, cfg.conv_taps)
     _read_ffn_segments(mf, cfg, p, st, join)
     st.vecs(("router_bias",), range(cfg.n_dense_layers, cfg.n_layers),
             src="moe_router_bias")

@@ -67,6 +67,10 @@ class KVCache(NamedTuple):
     # (Lw, B * ring, ps, Hkv, Dh); k and v are then its full layers' planes
     wk: jax.Array | None = None
     wv: jax.Array | None = None
+    # a model with short-convolution layers only: their state, a ring of the
+    # last positions' ``z`` a row, (Lc, B, 1, R, D) on both engines (a slot
+    # owns its row; ops/conv.py); k and v are then its attention layers' planes
+    cz: jax.Array | None = None
 
     @property
     def quantized(self) -> bool:
@@ -84,8 +88,9 @@ class KVCache(NamedTuple):
     def pool_planes(self) -> dict[str, jax.Array]:
         """The planes of a paged pool that a page id addresses (what a spill
         or a hand-off record carries page by page): all of them but a windowed
-        model's slot rings, whose pages belong to slots."""
-        return {n: a for n, a in self.planes().items() if n not in ("wk", "wv")}
+        model's slot rings and a convolution state, which belong to slots."""
+        return {n: a for n, a in self.planes().items()
+                if n not in ("wk", "wv", "cz")}
 
 
 def init_kv_cache(cfg: ModelConfig, batch: int, seq_len: int | None = None,
@@ -103,7 +108,7 @@ def init_kv_cache(cfg: ModelConfig, batch: int, seq_len: int | None = None,
     the HBM read stays int8-sized).
     """
     s = seq_len or cfg.seq_len
-    if cfg.window:
+    if cfg.periodic:
         return windowed.init_cache(cfg, batch, s, dtype, quant)
     if cfg.is_mla:
         return _init_latent((cfg.n_layers, batch, s), cfg, dtype, quant)
@@ -161,13 +166,21 @@ def init_kv_pool(cfg: ModelConfig, n_pages: int, page_size: int,
     ``k`` / ``v`` are its FULL layers' pool alone, and its window layers get
     planes of their own, ``wk`` / ``wv``, in which each of ``slots`` slots
     owns a ring of pages bounded by the window (``models/windowed.py
-    init_pool``; ``max_pages``: a slot's table width, which bounds the ring)."""
-    if cfg.window:
+    init_pool``; ``max_pages``: a slot's table width, which bounds the ring).
+    A model with convolution layers (``cfg.conv_taps``) likewise: ``k`` / ``v``
+    its attention layers' pool, ``cz`` its slots' convolution state."""
+    if cfg.periodic:
         return windowed.init_pool(cfg, n_pages, page_size, dtype, quant, slots,
                                   max_pages or n_pages)
     if cfg.is_mla:
         # (L, P, ps, ·): the same token-major page, one row a token a plane
         return _init_latent((cfg.n_layers, n_pages, page_size), cfg, dtype, quant)
+    # heads stay one to a row here, whatever their size (``attention.pool_rows``
+    # folds narrow heads for a periodic model's pool): this pool is the one a
+    # mesh shards by kv head on axis 3 (``kv_pool_sharding``) and that
+    # ``--kv-quant int8`` gives a scale a head, both of which a periodic model
+    # refuses by name.  An arch here with heads under 128 lanes (none of the
+    # supported ones on one chip) pays the layout copies ``pool_rows`` names.
     shape = (cfg.n_layers, n_pages, page_size, cfg.n_kv_heads, cfg.head_size)
     if quant:
         sshape = shape[:-1] + (1,)
@@ -451,7 +464,8 @@ def _routed_experts(xb2d: jax.Array, lp, cfg: ModelConfig,
     probabilities by ``cfg.routed_scale``; every strategy below takes its
     experts and weights from this one choice.  K-EXAONE
     (``cfg.router_sigmoid``) scores with a sigmoid, adds ``lp["router_bias"]``
-    for the choice only, and normalises and scales the chosen scores.  The
+    for the choice only, and normalises and scales the chosen scores; LFM2
+    does the same over the sum ``+ cfg.router_norm_eps``.  The
     logits are this layer's
     FFN input times ``lp["router"]`` unless the caller hands ``router_logits``
     ``(N, E)`` made elsewhere (SmallThinker's router reads the layer's input
@@ -534,7 +548,10 @@ def _routed_experts(xb2d: jax.Array, lp, cfg: ModelConfig,
             top_vals, top_idx = jax.lax.top_k(probs, k)  # (N, k)
         weights = top_vals
         if cfg.norm_topk_prob:
-            weights = top_vals / jnp.sum(top_vals, axis=-1, keepdims=True)
+            total = jnp.sum(top_vals, axis=-1, keepdims=True)
+            if cfg.router_norm_eps:  # LFM2's ``+ 1e-6``; K-EXAONE has none
+                total = total + jnp.float32(cfg.router_norm_eps)
+            weights = top_vals / total
         if cfg.routed_scale != 1.0:
             weights = weights * jnp.float32(cfg.routed_scale)
         if share:
@@ -679,11 +696,15 @@ def run_blocks(params: Params, cfg: ModelConfig, tokens: jax.Array,
                cache: KVCache, pos: jax.Array,
                offsets: jax.Array | None = None,
                pos_rows: jax.Array | None = None,
-               paged=None, packed=None) -> tuple[jax.Array, KVCache]:
+               paged=None, packed=None, n_real=None
+               ) -> tuple[jax.Array, KVCache]:
     """Embed + all transformer blocks; returns the residual stream (B, T, D)
     and the updated cache.  ``packed`` (models/packing.py, a slot step at
     ``t > 1``): the row-local regions of every layer run over the rows that
-    hold a token.
+    hold a token.  ``n_real``: how many of the ``T`` rows hold a token, where
+    the caller knows (a bucketed prefill's last index + 1, a slot step's
+    ``n_valid``); only a state that is not addressed row by row asks
+    (``models/windowed.py``).
 
     ``offsets`` (B,) enables ragged batches of *distinct* streams via left
     padding (beyond reference — the reference fixes batch=1,
@@ -719,9 +740,9 @@ def run_blocks(params: Params, cfg: ModelConfig, tokens: jax.Array,
     if cfg.is_mla:
         return _run_segments(params, cfg, x, cache, cos, sin, pos, offsets,
                              pos_rows, paged, packed)
-    if cfg.window:
+    if cfg.periodic:
         return windowed.run_periods(params, cfg, x, cache, cos, sin, pos,
-                                    offsets, pos_rows, paged, packed)
+                                    offsets, pos_rows, paged, packed, n_real)
 
     layer_keys = [k for k in params if k not in ("embedding", "rms_final", "wcls")]
     # Packed-Q40 weights stay out of the scan's xs: the scan would slice a
@@ -874,7 +895,8 @@ def forward_last(params: Params, cfg: ModelConfig, tokens: jax.Array,
     ragged batches (``offsets``) every row's genuine last token sits at
     the same final index, so the shared ``last_index`` needs no per-row
     variant."""
-    x, cache = run_blocks(params, cfg, tokens, cache, pos, offsets=offsets)
+    x, cache = run_blocks(params, cfg, tokens, cache, pos, offsets=offsets,
+                          n_real=last_index + 1 if cfg.conv_taps else None)
     with scope("head"):
         x_last = jax.lax.dynamic_slice_in_dim(x, last_index, 1, axis=1)[:, 0]  # (B, D)
     return _head(params, cfg, x_last), cache
@@ -933,7 +955,8 @@ def _run_slot_blocks(params: Params, cfg: ModelConfig, tokens, cache: KVCache,
     with scope("page_idx"):  # which rows hold a token: once, as the indices
         packed = packing.plan(n_valid, *tokens.shape)
     return run_blocks(params, cfg, tokens, cache, jnp.int32(0),
-                      pos_rows=pos_rows, paged=paged, packed=packed)
+                      pos_rows=pos_rows, paged=paged, packed=packed,
+                      n_real=n_valid)
 
 
 def forward_slots_all(params: Params, cfg: ModelConfig, tokens: jax.Array,

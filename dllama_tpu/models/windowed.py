@@ -1,6 +1,11 @@
-"""Layers of a model that mixes sliding-window and full attention
-(SmallThinker, K-EXAONE): periods of ``window_period`` layers of which one, at
-``window_full_at``, is full, over a cache with two kinds of plane.
+"""Layers of a model whose layers come in periods (SmallThinker, K-EXAONE,
+LFM2): ``window_period`` layers of which one, at ``window_full_at``, is full
+attention, over a cache with a kind of plane per layer kind.  The others are
+sliding-window attention (``cfg.window``) or gated short convolutions
+(``cfg.conv_taps``, ``ops/conv.py``: no keys or values, a ring of the last
+positions' ``z`` in the plane ``cz``); a convolution model's full layers carry
+RoPE (``cfg.full_rotates``) and its weights are stacked by layer kind
+(``params.ATT_KIND_KEYS`` / ``CONV_KEYS``, indexed by ``kind_index``).
 
 A layer ``l`` with ``l % window_period == window_full_at`` is *full*: no
 rotation at all (NoPE) and a causal mask over every position.  The others are
@@ -34,15 +39,16 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from ..ops import q40, q8, window
+from ..ops import conv, q40, q8, window
 from ..ops.attention import (gqa_attention_at, live_gqa_attention,
                              paged_gqa_attention_at, paged_update_kv_rows,
+                             pool_rows,
                              update_kv_cache_at)
 from ..ops.kernels import apply_rope, rmsnorm
 from ..ops.scopes import part, scope
 from . import packing
 from .config import ModelConfig
-from .params import DENSE_FFN_KEYS, MOE_FFN_KEYS
+from .params import ATT_KIND_KEYS, CONV_KEYS, DENSE_FFN_KEYS, MOE_FFN_KEYS
 
 # rows of the widest slot step a slot's ring of pages is sized for: the
 # scheduler's default prefill chunk (``--sched-prefill-chunk``), and at least a
@@ -67,11 +73,23 @@ def init_cache(cfg: ModelConfig, batch: int, seq_len: int, dtype, quant: bool):
                          "(--kv-quant int8 is refused for this architecture)")
     dt = dtype or cfg.dtype
     tail = (cfg.n_kv_heads, seq_len, cfg.head_size)
-    ring = (cfg.n_kv_heads, cfg.window_ring(seq_len), cfg.head_size)
     full = (cfg.n_full_layers, batch) + tail
+    if cfg.conv_taps:
+        return KVCache(jnp.zeros(full, dt), jnp.zeros(full, dt),
+                       cz=_conv_state(cfg, batch, dt))
+    ring = (cfg.n_kv_heads, cfg.window_ring(seq_len), cfg.head_size)
     win = (cfg.n_window_layers, batch) + ring
     return KVCache(jnp.zeros(full, dt), jnp.zeros(full, dt),
                    wk=jnp.zeros(win, dt), wv=jnp.zeros(win, dt))
+
+
+def _conv_state(cfg: ModelConfig, rows: int, dt):
+    """The convolution layers' state ``(Lc, rows, 1, R, D)``: a ring of
+    ``conv.RING`` positions of ``z`` a row (a sequence of the contiguous
+    cache, a slot of a slot engine), laid out as a ring of one head of ``D``
+    so that the window writes of ``ops/window.py`` and the cache's one
+    placement apply."""
+    return jnp.zeros((cfg.n_conv_layers, rows, 1, conv.RING, cfg.dim), dt)
 
 
 def init_pool(cfg: ModelConfig, n_pages: int, page_size: int, dtype,
@@ -88,8 +106,14 @@ def init_pool(cfg: ModelConfig, n_pages: int, page_size: int, dtype,
                          "each owns a ring of pages in the window layers' planes")
     dt = dtype or cfg.dtype
     page = (page_size, cfg.n_kv_heads, cfg.head_size)
+    # the pool's heads under 128 lanes are stored lane-dense (ops/attention.py
+    # pool_rows); the window layers' rings are read as slices, never by page
+    full = (cfg.n_full_layers, n_pages, page_size) + pool_rows(
+        cfg.n_kv_heads, cfg.head_size)
+    if cfg.conv_taps:  # the state is the slots' own, whatever the pages hold
+        return KVCache(jnp.zeros(full, dt), jnp.zeros(full, dt),
+                       cz=_conv_state(cfg, slots, dt))
     ring = window.window_pages(cfg.window, SLOT_ROWS, page_size, max_pages)
-    full = (cfg.n_full_layers, n_pages) + page
     win = (cfg.n_window_layers, slots * ring) + page
     return KVCache(jnp.zeros(full, dt), jnp.zeros(full, dt),
                    wk=jnp.zeros(win, dt), wv=jnp.zeros(win, dt))
@@ -101,6 +125,47 @@ def kind_index(cfg: ModelConfig, p, j: int):
     if j == cfg.window_full_at:
         return p
     return p * (cfg.window_period - 1) + j - (j > cfg.window_full_at)
+
+
+def _short_conv(x, lp, cfg: ModelConfig, cache, pos, plane, offsets, pos_rows,
+                n_real, packed):
+    """One gated short-convolution sub-block (``ops/conv.py`` has the operator
+    and why its state is a ring of positions); ``plane`` indexes ``cache.cz``.
+    The two projections and both gates are row-local and pack; the state's
+    read and write and the taps are a per-row sequence operation and keep
+    ``(B, T)``, as ``rope`` and the KV write do.  Device time under part
+    ``conv`` of the scopes an attention sub-block's stages have."""
+    from .transformer import _mm
+    b, t, d = x.shape
+    taps, ring = cfg.conv_taps, cache.cz.shape[3]
+    if pos_rows is not None and t > ring - (taps - 1):
+        raise ValueError(
+            f"a slot step of {t} rows does not fit a convolution layer's "
+            f"state ring of {ring} positions: feed at most "
+            f"{ring - (taps - 1)} rows a step")
+    conv.record(t, ring, taps)
+
+    def project(x):  # row-local: any leading axes
+        with scope("norm"):
+            u = rmsnorm(x, lp["rms_att"], cfg.norm_eps)
+        with scope("qkv"), part("conv"):
+            gate_b, gate_c, xs = jnp.split(_mm(u, lp["conv_in"], cfg), 3, axis=-1)
+            return gate_b * xs, gate_c
+
+    def project_out(y, lp, cfg):
+        with scope("wo"), part("conv"):
+            return _mm(y, lp["conv_out"], cfg)
+
+    z, gate_c = packing.over(packed, "qkv", project, x)
+    rows = pos_rows if pos_rows is not None else jnp.broadcast_to(pos, (b,))
+    with scope("attn"), part("conv"):
+        carried = conv.state_read(cache.cz, plane, rows, taps, floor=offsets)
+        y = conv.taps_and_gate(z, carried, gate_c, lp["conv_taps"], rows,
+                               floor=offsets)
+    with scope("kv_write"), part("conv"):
+        cache = cache._replace(cz=conv.state_write(cache.cz, z, plane, rows,
+                                                   taps, n_real))
+    return packing.over(packed, "wo", project_out, y, lp=lp, cfg=cfg), cache
 
 
 def _attention(x, lp, cfg: ModelConfig, cache, cos, sin, pos, plane,
@@ -133,7 +198,7 @@ def _attention(x, lp, cfg: ModelConfig, cache, cos, sin, pos, plane,
 
     q, k, v = packing.over(packed, "qkv", project, x)
     with scope("rope"):
-        if windowed:  # a full layer is not rotated at all
+        if windowed or cfg.full_rotates:  # a windowed model's full layer is not rotated at all
             q = apply_rope(q, cos, sin, interleaved=False)
             k = apply_rope(k, cos, sin, interleaved=False)
         q = q.transpose(0, 2, 1, 3)  # (B, Hq, T, Dh)
@@ -201,10 +266,13 @@ def _attention(x, lp, cfg: ModelConfig, cache, cos, sin, pos, plane,
 
 
 def run_periods(params, cfg: ModelConfig, x, cache, cos, sin, pos, offsets,
-                pos_rows, paged, packed=None):
-    """All layers of a windowed model over the residual stream ``x (B, T,
+                pos_rows, paged, packed=None, n_real=None):
+    """All layers of a periodic model over the residual stream ``x (B, T,
     D)``; returns it and the updated cache (``transformer.run_blocks`` has
-    embedded the tokens and made the angles, and planned ``packed``)."""
+    embedded the tokens and made the angles, and planned ``packed``).
+    ``n_real``: how many of the ``T`` rows hold a token (a scalar, or ``(B,)``
+    on a slot step; ``None``: all), which a convolution layer's state write
+    needs of a call wider than its ring."""
     from ..io import mfile
     from .transformer import _dense_ffn, moe_ffn
     b, t, d = x.shape
@@ -215,7 +283,11 @@ def run_periods(params, cfg: ModelConfig, x, cache, cos, sin, pos, offsets,
     dense_keys = [k for k in keys if n_dense and k in DENSE_FFN_KEYS]
     moe_keys = [k for k in keys if n_dense and k in MOE_FFN_KEYS]
     att_keys = [k for k in keys if k not in dense_keys and k not in moe_keys]
-    if paged is not None:
+    if cfg.conv_taps:  # its operators' weights are stacked by layer kind
+        kind_keys = {False: [k for k in att_keys if k in CONV_KEYS],
+                     True: [k for k in att_keys if k in ATT_KIND_KEYS]}
+        att_keys = [k for k in att_keys if k not in CONV_KEYS + ATT_KIND_KEYS]
+    if paged is not None and cache.wk is not None:
         with scope("page_idx"):  # the window planes' write places, once
             paged = paged + (window.paged_ring_indices(
                 pos_rows, t, cache.wk.shape[2], cache.wk.shape[1] // b),)
@@ -227,8 +299,11 @@ def run_periods(params, cfg: ModelConfig, x, cache, cos, sin, pos, offsets,
 
     def one_layer(x, kvc, layer, j: int, plane, dense: bool):
         """Layer ``layer`` (traced or static), the ``j``-th of its period."""
-        windowed = j != cfg.window_full_at
+        full = j == cfg.window_full_at
+        windowed = not full and not cfg.conv_taps
         lp = {k: at(params[k], layer) for k in att_keys}
+        if cfg.conv_taps:
+            lp.update({k: at(params[k], plane) for k in kind_keys[full]})
         lp.update({k: at(params[k], layer - (0 if dense else n_dense))
                    for k in (dense_keys if dense else moe_keys)})
         router_logits = None
@@ -237,8 +312,12 @@ def run_periods(params, cfg: ModelConfig, x, cache, cos, sin, pos, offsets,
                 # x_l as it enters the layer, before any norm
                 router_logits = (x.reshape(b * t, d).astype(jnp.float32)
                                  @ lp["router"].astype(jnp.float32))
-        att_out, kvc = _attention(x, lp, cfg, kvc, cos, sin, pos, plane,
-                                  windowed, offsets, pos_rows, paged, packed)
+        if full or windowed:
+            att_out, kvc = _attention(x, lp, cfg, kvc, cos, sin, pos, plane,
+                                      windowed, offsets, pos_rows, paged, packed)
+        else:
+            att_out, kvc = _short_conv(x, lp, cfg, kvc, pos, plane, offsets,
+                                       pos_rows, n_real, packed)
         with scope("wo"):
             x = x + att_out
 

@@ -760,10 +760,20 @@ KV_BYTES_PER_TOKEN = REGISTRY.gauge(
 # beside it, from the same arrays: what the cache holds by kind of plane.
 # "full": planes of every position (all of a model without window layers, the
 # paged pool); "window": a windowed model's rings, bounded by the window plus
-# one prefill chunk whatever --max-seq-len is
+# one prefill chunk whatever --max-seq-len is; "conv": a convolution model's
+# state rings (ops/conv.py), a fixed size a sequence or a slot
 KV_CACHE_BYTES = REGISTRY.labeled_gauge(
     "kv_cache_bytes", "kind",
-    "Resident bytes of the KV cache by kind of plane (full | window).")
+    "Resident bytes of the KV cache by kind of plane (full | window | conv).")
+# the one-stream engine's account of its convolution state ring (runtime/
+# engine.py Engine._state_enter): a call that starts below the highest position
+# written is a rewind; "in_ring": the rows before it were still held;
+# "reprefill": they were not, the call was refused by name (StateRewindTooDeep)
+# and the caller starts the conversation again from position 0
+CONV_STATE_REWINDS = REGISTRY.labeled_counter(
+    "conv_state_rewinds", "outcome",
+    "Rewinds of the position clock over a convolution state, by outcome "
+    "(in_ring | reprefill).")
 
 # scheduler goodput accounting (runtime/scheduler.py + obs/flight.py):
 # every millisecond between the scheduler's first and last dispatch lands

@@ -179,6 +179,32 @@ def slot_gqa_attention_at(q: jax.Array, ck: jax.Array, cv: jax.Array,
 # by construction and garbage lands where no mask can ever expose it.
 
 
+# lanes of a vector register.  A pool whose rows are narrower is not only
+# stored padded: the TPU's compact layout for ``(L, P, ps, Hkv, 64)`` puts the
+# PAGES minor-most (``{1,4,3,2,0}``), so every step that scatters or gathers
+# by page copies the whole pool to a page-major layout and back
+# (tests/test_tpu_compile.py, LFM2's heads of 64).  ``pool_rows`` keeps a row
+# lane-dense instead, and the pool functions below take a pool of either form.
+_LANES = 128
+
+
+def pool_rows(hkv: int, dh: int) -> tuple[int, int]:
+    """The two minor axes of a dense paged pool ``(L, P, ps, *pool_rows)``:
+    ``(Hkv, Dh)``, but heads narrower than 128 lanes are stored ``128 // Dh``
+    to a row, ``(Hkv * Dh // 128, 128)``, where the heads divide so (the same
+    bytes in the same order: a token's ``(Hkv, Dh)`` slab reshaped)."""
+    f = _LANES // dh if dh < _LANES and _LANES % dh == 0 else 1
+    return (hkv // f, f * dh) if f > 1 and hkv % f == 0 else (hkv, dh)
+
+
+def _heads(pages: jax.Array, dh: int | None) -> jax.Array:
+    """``(..., G, W)`` rows of a pool as ``(..., Hkv, Dh)`` heads (the
+    identity for an unfolded pool and for an int8 pool's scale planes)."""
+    if dh is None or pages.shape[-1] in (dh, 1):
+        return pages
+    return pages.reshape(*pages.shape[:-2], -1, dh)
+
+
 def paged_write_indices(page_table: jax.Array, pos_rows: jax.Array,
                         n_valid: jax.Array, t: int, page_size: int
                         ) -> tuple[jax.Array, jax.Array]:
@@ -216,6 +242,9 @@ def paged_update_kv_rows(pool_k: jax.Array, pool_v: jax.Array,
     unmasked."""
     kbt = k_new.transpose(0, 2, 1, 3).astype(pool_k.dtype)  # (B, T, Hkv, Dh)
     vbt = v_new.transpose(0, 2, 1, 3).astype(pool_v.dtype)
+    # a pool of lane-dense rows (pool_rows) takes the same slab reshaped
+    kbt = kbt.reshape(kbt.shape[:2] + pool_k.shape[3:])
+    vbt = vbt.reshape(vbt.shape[:2] + pool_v.shape[3:])
     li = layer.astype(jnp.int32)
     pool_k = pool_k.at[li, pidx, oidx].set(kbt)
     pool_v = pool_v.at[li, pidx, oidx].set(vbt)
@@ -224,7 +253,8 @@ def paged_update_kv_rows(pool_k: jax.Array, pool_v: jax.Array,
 
 def paged_gather_layer(pool: jax.Array, layer: jax.Array,
                        page_table: jax.Array,
-                       scale_pool: jax.Array | None = None) -> jax.Array:
+                       scale_pool: jax.Array | None = None,
+                       dh: int | None = None) -> jax.Array:
     """Materialize one layer's logical KV view (B, Hkv, maxp·ps, Dh) by
     gathering each slot's pages from the pool (L, P, ps, Hkv, Dh) and
     moving the head axis ahead of the tokens.  The gather is the paged
@@ -234,11 +264,12 @@ def paged_gather_layer(pool: jax.Array, layer: jax.Array,
 
     ``scale_pool``: the int8 pool's per-position scale planes
     (L, P, ps, Hkv, 1) — the gather stays int8-sized and the dequant
-    multiply fuses into the downstream dot like the plain cast."""
+    multiply fuses into the downstream dot like the plain cast.  ``dh``: the
+    head size, for a pool of lane-dense rows (:func:`pool_rows`)."""
 
     def view(p):
         pl = jax.lax.dynamic_index_in_dim(p, layer, 0, keepdims=False)
-        pages = pl[page_table]  # (B, maxp, ps, Hkv, Dh | 1)
+        pages = _heads(pl[page_table], dh)  # (B, maxp, ps, Hkv, Dh | 1)
         b, maxp, ps, hkv, last = pages.shape
         return pages.transpose(0, 3, 1, 2, 4).reshape(b, hkv, maxp * ps, last)
 
@@ -265,7 +296,7 @@ def paged_decode_attention(q: jax.Array, pool_k: jax.Array, pool_v: jax.Array,
     dequantizes after the int8-sized HBM read (the point of the
     quantized pool)."""
     b, hq, t, dh = q.shape
-    ps, hkv = pool_k.shape[2], pool_k.shape[3]
+    ps, hkv = pool_k.shape[2], pool_k.shape[3] * pool_k.shape[4] // dh
     maxp = page_table.shape[1]
     g = hq // hkv
     qf = q.astype(jnp.float32).reshape(b, hkv, g, t, dh)
@@ -276,7 +307,7 @@ def paged_decode_attention(q: jax.Array, pool_k: jax.Array, pool_v: jax.Array,
         # advanced (scalar layer, (B,) page) indexing: one (B, ps, Hkv, Dh)
         # page gather per fold step — never the whole layer slab — brought
         # to the fold's head-major (B, Hkv, ps, Dh) block
-        return pool[layer.astype(jnp.int32), pid].transpose(0, 2, 1, 3)
+        return _heads(pool[layer.astype(jnp.int32), pid], dh).transpose(0, 2, 1, 3)
 
     if scales is None:
         kc_arg, vc_arg = pool_k, pool_v
@@ -579,8 +610,8 @@ def paged_gqa_attention_at(q: jax.Array, pool_k: jax.Array, pool_v: jax.Array,
     ``scales``: the int8-pool (k, v) scale planes (L, P, ps, Hkv, 1);
     every unfused arm dequantizes after the int8-sized page read."""
     from ..obs import dispatch as obs_dispatch
-    t = q.shape[2]
-    ps, hkv = pool_k.shape[2], pool_k.shape[3]
+    t, dh = q.shape[2], q.shape[3]
+    ps, hkv = pool_k.shape[2], pool_k.shape[3] * pool_k.shape[4] // dh
     s = page_table.shape[1] * ps
     codec = "kv_int8" if scales is not None else "kv_dense"
     use_fused, interp = _fused_choice(t, q.shape[1], hkv, q.shape[3],
@@ -603,8 +634,8 @@ def paged_gqa_attention_at(q: jax.Array, pool_k: jax.Array, pool_v: jax.Array,
         obs_dispatch.record_dispatch("kv_int8", "dequant", t=t, s=s,
                                      page_size=ps)
     ks, vs = scales if scales is not None else (None, None)
-    k_l = paged_gather_layer(pool_k, layer, page_table, scale_pool=ks)
-    v_l = paged_gather_layer(pool_v, layer, page_table, scale_pool=vs)
+    k_l = paged_gather_layer(pool_k, layer, page_table, scale_pool=ks, dh=dh)
+    v_l = paged_gather_layer(pool_v, layer, page_table, scale_pool=vs, dh=dh)
     return _rows_ceiling_attention(q, k_l, v_l, pos_rows)
 
 

@@ -18,7 +18,12 @@ Inside ``moe`` the work is split further by :func:`part` into ``router``,
 ``experts``, ``combine`` and (DeepSeek-V2) ``shared``; inside ``qkv`` MLA's
 ``q_lora`` / ``kv_lora``; inside ``attn`` MLA's ``absorb`` / ``latent`` /
 ``expand`` and a windowed model's ``window`` / ``full`` by layer kind;
-inside ``qkv`` also K-EXAONE's ``qk_norm``
+inside ``qkv`` also K-EXAONE's ``qk_norm``; and a gated short-convolution
+layer (LFM2, ``ops/conv.py``) is part ``conv`` of the four scopes it passes
+through, ``qkv`` (``W_in`` and the ``B * X`` gate), ``kv_write`` (the state's
+write), ``attn`` (the state's read, the taps, the ``C`` gate) and ``wo``
+(``W_out``), so that a reader of a scope still reads a whole layer and a
+reader of the part reads the operator alone
 (``PARTS``, by scope): plain sub-names, not scopes.  An
 op's path then ends ``.../moe/experts/...`` and a reader that knows only
 ``SCOPES`` still files it under ``moe``; ``by-scope.json``'s op table
@@ -58,13 +63,21 @@ PARTS = {
         "q_lora",    # MLA: q's latent norm and the up-projection to the heads
         "kv_lora",   # MLA: the down-projection(s) from x, the latent's norm
         "qk_norm",   # K-EXAONE: the RMSNorm of each head of q and of k
+        "conv",      # a short-convolution layer's W_in and its B * X gate
+    ),
+    "kv_write": (
+        "conv",      # a short-convolution layer's state write (the ring of z)
     ),
     "attn": (
         "absorb",    # MLA absorbed form: W_uk into the query, W_uv out of the result
         "latent",    # MLA absorbed form: the walk over latent rows
         "expand",    # MLA expanded form: a block's rows through W_kvb, in the walk
         "window",    # a sliding-window layer's read (a row's ring, or a slot's ring of pages)
-        "full",      # a windowed model's full (unrotated) layer's read
+        "full",      # a periodic model's full layer's read
+        "conv",      # a short-convolution layer's state read, taps and C gate
+    ),
+    "wo": (
+        "conv",      # a short-convolution layer's W_out
     ),
     "moe": (
         "router",    # router logits, softmax, (groups,) top-k, the dense weight table

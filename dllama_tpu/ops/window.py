@@ -101,6 +101,19 @@ def ring_write(ring_k: jax.Array, ring_v: jax.Array, k_new: jax.Array,
     return ring_k, ring_v
 
 
+def ring_write_plane(ring: jax.Array, new: jax.Array, layer: jax.Array,
+                     pos: jax.Array) -> jax.Array:
+    """:func:`ring_write` for one plane: ``new (B, H, T, Dh)`` into the stacked
+    rings ``(L, B, H, R, Dh)`` at ``layer``, row ``b`` at positions ``pos[b] ..
+    pos[b] + T - 1`` modulo ``R`` (``T <= R``).  What a state that is no key and
+    no value is written with (``ops/conv.py``)."""
+    r = ring.shape[3]
+    li = layer.astype(jnp.int32)
+    for b in range(new.shape[0]):
+        ring = _write_row(ring, new[b], li, b, pos[b], r)
+    return ring
+
+
 def _window_mask(key_pos, q_pos, window: int, floor=None):
     """``(B, T, S)``: key position ``key_pos (B, S)`` visible to the query at
     ``q_pos (B, T)``; ``floor (B,)`` is a ragged batch's first real position."""

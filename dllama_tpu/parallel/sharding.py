@@ -32,7 +32,8 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from ..io import mfile
 from ..models.config import ModelConfig
 from ..obs import metrics as obs_metrics, trace as obs_trace
-from ..models.params import DENSE_FFN_KEYS, MLA_ATT_KEYS, MOE_FFN_KEYS
+from ..models.params import (ATT_KIND_KEYS, CONV_KEYS, DENSE_FFN_KEYS,
+                             MLA_ATT_KEYS, MOE_FFN_KEYS)
 
 REPL = P()
 
@@ -69,11 +70,11 @@ def check_tp_constraint(cfg: ModelConfig, tp: int) -> None:
 
 def param_specs(cfg: ModelConfig) -> dict[str, P]:
     """PartitionSpec per parameter (layer-stacked layouts from params.py)."""
-    if cfg.is_mla or cfg.arch == mfile.ARCH_EXAONE_MOE:
+    if cfg.is_mla or cfg.arch in (mfile.ARCH_EXAONE_MOE, mfile.ARCH_LFM2_MOE):
         # one device (the engine refuses a tp / sp / ep mesh for these archs):
         # every stack whole, whatever its fused or unfused name
         return dict.fromkeys(("embedding", "rms_final", "wcls") + MLA_ATT_KEYS
-                             + ("wq", "wk", "wv", "wqkv", "q_norm", "k_norm")
+                             + ATT_KIND_KEYS + CONV_KEYS
                              + DENSE_FFN_KEYS + MOE_FFN_KEYS, REPL)
     specs = {
         "embedding": REPL,                   # root-owned in the reference; replicated here

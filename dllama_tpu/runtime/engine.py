@@ -40,7 +40,7 @@ from ..models.transformer import (LATENT_PAGE_AXES, PAGE_AXES, forward_last,
 from ..obs import dispatch as obs_dispatch, metrics as obs_metrics, \
     trace as obs_trace
 from ..obs.log import get_logger
-from ..ops import q40, q8
+from ..ops import conv, q40, q8
 from ..parallel import sharding
 from ..parallel.mesh import active_mesh, make_mesh
 from ..sampling import Sampler
@@ -297,6 +297,13 @@ def _refuse_mesh_and_int8(mesh, kv_dtype, what: str, why: str) -> None:
                          "its cache has no int8 form")
 
 
+class StateRewindTooDeep(ValueError):
+    """A call starts so far below the highest position a convolution state was
+    written at that the rows before it have left the state's ring
+    (``ops/conv.py``): the caller resets the engine and prefills the
+    conversation again from position 0."""
+
+
 def _note_cache_bytes(cache, tokens: int, batch: int, paged: bool) -> int:
     """Set the cache's gauges from its own arrays and return what one cached
     token occupies over all layers.  A windowed model's rings hold fewer
@@ -304,12 +311,14 @@ def _note_cache_bytes(cache, tokens: int, batch: int, paged: bool) -> int:
     positions a row: ``kv_cache_bytes{kind="window"}`` is what the bound on
     the rings saves against ``kind="full"``'s planes per layer.  On a paged
     engine a token ADDS its bytes in the pool alone (the full layers'): a
-    slot's ring of pages is there whatever the context's depth."""
-    per_token, by_kind = 0, {"full": 0, "window": 0}
+    slot's ring of pages is there whatever the context's depth.  A
+    convolution state (``kind="conv"``) is a fixed size a sequence: a token
+    adds nothing to it on either engine."""
+    per_token, by_kind = 0, {"full": 0, "window": 0, "conv": 0}
     for name, a in cache.planes().items():
-        kind = "window" if name in ("wk", "wv") else "full"
+        kind = {"wk": "window", "wv": "window", "cz": "conv"}.get(name, "full")
         by_kind[kind] += int(a.nbytes)
-        if paged and kind == "window":
+        if kind == "conv" or (paged and kind == "window"):
             continue
         positions = tokens if kind == "full" else batch * a.shape[3]
         per_token += int(a.nbytes) // positions
@@ -369,10 +378,11 @@ class Engine:
             _refuse_mesh_and_int8(
                 self.mesh, kv_dtype, "latent attention (MLA)",
                 "the latent cache would be replicated and the heads sharded")
-        if cfg.window:
+        if cfg.periodic:
             _refuse_mesh_and_int8(
                 self.mesh, kv_dtype,
-                f"a windowed ({mfile.ARCH_NAMES[cfg.arch]}) model",
+                f"a {'convolution' if cfg.conv_taps else 'windowed'} "
+                f"({mfile.ARCH_NAMES[cfg.arch]}) model",
                 "its two cache kinds have one placement")
         if self.mesh.shape.get("tp", 1) > 1 \
                 and jax.default_backend() != "tpu" \
@@ -465,6 +475,9 @@ class Engine:
         self.kv_bytes_per_token = _note_cache_bytes(self.cache, tokens, batch,
                                                     self.paged)
         self.pos = 0
+        # the one-stream account of a convolution state's ring: positions
+        # [lo, hi) are held (_state_enter / _state_wrote)
+        self._state_lo = self._state_hi = 0
 
         def step(params, cache, tokens, pos, last_index, offsets=None):
             return forward_last(params, cfg, tokens, cache, pos, last_index,
@@ -513,6 +526,84 @@ class Engine:
         """Restart the sequence (new conversation); cache memory is reused."""
         self.pos = 0
         self._offsets = None
+        self._state_lo = self._state_hi = 0
+
+    # -- the pos-rewind invariant ---------------------------------------
+    # Callers set ``pos`` back and go on: a decode burst that ran past an
+    # end-of-sequence token (generate_stream), a rejected draft
+    # (generate_pld_stream), a stop string or a cancelled request
+    # (runtime/stream.py, server/api.py).  KEYS AND VALUES survive that
+    # because they are addressed by position: rows above ``pos`` are masked
+    # and the next call overwrites them.  A RECURRENT STATE survives it only
+    # if it is addressed by position too and still holds the rows before the
+    # new ``pos``: a convolution layer's is a ring of ``ops/conv.py RING``
+    # positions (the bound R lives there, with the rewinds it was sized
+    # for), and this engine keeps account of which positions the ring holds.
+    # An arch with another state brings its plane into ``KVCache`` and its
+    # account here; nothing else in the engines changes.
+    def state_holds(self, pos: int) -> bool:
+        """Whether a one-stream call may start at ``pos``: always for keys and
+        values; with a convolution state, only while the ``conv_taps - 1``
+        positions before ``pos`` are in the ring."""
+        if not self.cfg.conv_taps or self.paged or pos == 0:
+            return True
+        need = max(pos - (self.cfg.conv_taps - 1), 0)
+        return self._state_lo <= need and pos <= self._state_hi
+
+    def resume_at(self, pos: int) -> bool:
+        """Set the position clock to ``pos``, a conversation's cached end
+        (``server/api.py NaiveCache``), if everything the cache keeps still
+        holds it: always for keys and values; a convolution state only while
+        the rows before ``pos`` are in its ring.  Otherwise count a
+        ``reprefill``, reset, and return False: the caller prefills the
+        conversation again from position 0."""
+        if self.state_holds(pos):
+            self.pos = pos
+            return True
+        obs_metrics.CONV_STATE_REWINDS.inc("reprefill")
+        self.reset()
+        return False
+
+    def _state_enter(self, pos: int) -> None:
+        """Before a one-stream call at ``pos``: count a rewind, refuse one that
+        left the ring (module-level :class:`StateRewindTooDeep`)."""
+        if not self.cfg.conv_taps or pos == self._state_hi:
+            return
+        if pos > self._state_hi:
+            raise StateRewindTooDeep(
+                f"a call at position {pos} skips positions the convolution "
+                f"state has not seen (written up to {self._state_hi}): "
+                "prefill them first")
+        if self.state_holds(pos):
+            obs_metrics.CONV_STATE_REWINDS.inc("in_ring")
+            return
+        obs_metrics.CONV_STATE_REWINDS.inc("reprefill")
+        raise StateRewindTooDeep(
+            f"position {pos} is more than the convolution state's ring "
+            f"behind the highest position written ({self._state_hi}; the "
+            f"ring holds {self._state_lo}..): reset() and prefill the "
+            "conversation again from position 0")
+
+    def _state_wrote(self, pos: int, n_real: int, rows: int) -> None:
+        """After a one-stream call of ``rows`` rows at ``pos`` of which the
+        first ``n_real`` hold a token: the ring holds what it held, less what
+        the rows written (``ops/conv.py written``) displaced."""
+        if not self.cfg.conv_taps:
+            return
+        first, count = conv.written(int(n_real), rows, conv.RING,
+                                    self.cfg.conv_taps)
+        # rows that start inside the call are not joined to what came before
+        lo = pos + first if first else max(min(self._state_lo, pos),
+                                           pos + count - conv.RING)
+        self._state_lo, self._state_hi = max(lo, 0), pos + n_real
+
+    def _max_burst(self, chunk: int) -> int:
+        """A decode burst of a model with a convolution state is capped so
+        that the deepest rewind (two pipelined bursts less one position)
+        stays in the ring."""
+        if not self.cfg.conv_taps:
+            return chunk
+        return min(chunk, conv.max_burst(conv.RING, self.cfg.conv_taps))
 
     # -- state snapshot/restore (runtime/snapshot.py format) -----------
     def config_fingerprint(self) -> str:
@@ -564,6 +655,8 @@ class Engine:
             arrays["rng_dev_key"] = np.asarray(self._dev_key)
         meta_extra = dict(extra or {})
         meta_extra.setdefault("sampling_path", self.sampling_path)
+        if self.cfg.conv_taps:
+            meta_extra["conv_state"] = [self._state_lo, self._state_hi]
         if self._offsets is not None:
             arrays["offsets"] = np.asarray(self._offsets)
             meta_extra["has_offsets"] = True
@@ -626,6 +719,8 @@ class Engine:
             **{n: cache_np[f"cache.{n}"] for n in self.cache.planes()})
         self.cache = jax.device_put(cache, self._cache_sh)
         self.pos = pos
+        self._state_lo, self._state_hi = meta.get("extra", {}).get(
+            "conv_state", (0, pos))
         self._chunk_counter = int(meta["chunk_counter"])
         self._key = jnp.asarray(arrays["rng_key"]) if "rng_key" in arrays \
             else jax.random.PRNGKey(0)
@@ -656,7 +751,7 @@ class Engine:
         if not self.paged:
             raise ValueError("per-request hand-off needs a paged KV cache "
                              "(kv_pages > 0)")
-        self._refuse_slot_rings("per-request hand-off (DLREQ01)")
+        self._refuse_slot_state("per-request hand-off (DLREQ01)")
         c = self.cfg
         k = self.cache.k
         fields = {
@@ -705,15 +800,29 @@ class Engine:
         wk = self.cache.wk if self.paged else None
         return 0 if wk is None else wk.shape[1] // self.batch
 
-    def _refuse_slot_rings(self, what: str) -> None:
+    @property
+    def slot_state(self) -> str:
+        """What a paged engine's slots own beside the pool, which no page id
+        addresses (empty: nothing): a windowed model's rings of pages, a
+        convolution model's state.  The scheduler keeps everything that moves
+        a request's cache page by page off while this is set."""
+        if not self.paged:
+            return ""
+        if self.cache.cz is not None:
+            return "convolution layers' state"
+        return "window layers' slot rings" if self.ring_pages else ""
+
+    def _refuse_slot_state(self, what: str) -> None:
         """A windowed model's window layers keep a slot's last ``window``
-        positions in the slot's own ring of pages, which no page id addresses:
+        positions in the slot's own ring of pages, and a convolution layer
+        keeps its state in the slot's own row, which no page id addresses:
         what moves a request's cache page by page is refused by name."""
-        if self.ring_pages:
+        if self.slot_state:
+            kind = "convolution" if self.cache.cz is not None else "windowed"
             raise ValueError(
-                f"{what} is not supported for a windowed "
-                f"({mfile.ARCH_NAMES[self.cfg.arch]}) model: its window "
-                "layers' slot rings are not carried page by page")
+                f"{what} is not supported for a {kind} "
+                f"({mfile.ARCH_NAMES[self.cfg.arch]}) model: its "
+                f"{self.slot_state} are not carried page by page")
 
     def read_pool_pages(self, pages) -> dict[str, np.ndarray]:
         """Copy the given physical pages out of the paged pool to host
@@ -755,7 +864,7 @@ class Engine:
         One transient pool copy — acceptable at hand-off import time,
         which is off the steady-state decode path."""
         idx = jnp.asarray(np.asarray(pages, np.int32))
-        self._refuse_slot_rings("writing a request's pages into the pool")
+        self._refuse_slot_state("writing a request's pages into the pool")
         cache = self.cache._replace(**{
             n: a.at[:, idx].set(jnp.asarray(arrays[f"pages.{n}"], a.dtype))
             for n, a in self.cache.pool_planes().items()})
@@ -866,6 +975,7 @@ class Engine:
         last; the logits stay on the device and ``None`` is returned)."""
         stats = StepStats()
         t0 = time.perf_counter()
+        self._state_enter(self.pos)
         # from-scratch prefill on an sp mesh → blockwise ring attention with
         # the tokens (and therefore all activations) sharded on the
         # sequence axis: per-chip activation memory scales 1/sp, which is
@@ -889,6 +999,7 @@ class Engine:
                 logits, self.cache = self._step(
                     self.params, self.cache, jnp.asarray(tokens_np),
                     jnp.int32(self.pos), jnp.int32(last_index), offsets)
+        self._state_wrote(self.pos, last_index + 1, int(tokens_np.shape[1]))
         fired = self._sync(logits, "prefill/decode step")
         t1 = time.perf_counter()
         if fresh_exec:
@@ -1073,6 +1184,7 @@ class Engine:
         dllama.cpp:196-203; VERDICT r04 Weak #6).
         """
         steps = min(steps, self.seq_len - self.pos)
+        chunk = self._max_burst(chunk)
         if seed is not None:
             self._key = jax.random.PRNGKey(seed)
             self._chunk_counter = 0
@@ -1121,6 +1233,7 @@ class Engine:
             sub = jax.random.fold_in(self._key, self._chunk_counter)
             self._chunk_counter += 1
             p0 = self.pos
+            self._state_enter(p0)
             # host→device bytes actually crossing for THIS dispatch: the
             # pos scalar + folded key always; the token array only when it
             # comes from the host (first chunk) — later chunks feed the
@@ -1139,6 +1252,7 @@ class Engine:
                 # compile cost the histogram tracks
                 obs_metrics.ENGINE_COMPILE_S.observe(time.perf_counter() - t0)
             self.pos = p0 + k
+            self._state_wrote(p0, k, k)
             return k, p0, toks_dev, last_dev, t0, sent
 
         if produced >= steps or self.pos >= self.seq_len:
@@ -1184,9 +1298,13 @@ class Engine:
                     produced += 1
                     if token in eos_ids:
                         # rewind past the unconsumed overshoot so a
-                        # following turn prefills at the right position
-                        # (masked rows are never attended and get
-                        # overwritten); the finally below returns the
+                        # following turn prefills at the right position:
+                        # keys and values above it are masked rows, never
+                        # attended and overwritten; a convolution state is
+                        # a ring of positions that still holds the rows
+                        # before it (the pos-rewind invariant above
+                        # ``state_holds``; a burst is capped to fit,
+                        # ``_max_burst``); the finally below returns the
                         # speculative chunk's RNG tick
                         self.pos = p0 + j + 1
                         return
@@ -1267,6 +1385,7 @@ class Engine:
             self._key = jax.random.PRNGKey(seed)
             self._chunk_counter = 0
 
+        chunk = self._max_burst(chunk)
         logits, _ = self.prefill_ragged(prompts)  # validates batch/sp/pos
         sub = jax.random.fold_in(self._key, self._chunk_counter)
         self._chunk_counter += 1
@@ -1291,12 +1410,14 @@ class Engine:
             sub = jax.random.fold_in(self._key, self._chunk_counter)
             self._chunk_counter += 1
             tc = time.perf_counter()
+            self._state_enter(self.pos)
             with _compiling(fresh, "chunk", *key), active_mesh(self.mesh):
                 toks_dev, self.cache, last_dev, _pos, _key = fn(
                     self.params, self.cache, jnp.asarray(in_tok, jnp.int32),
                     jnp.int32(self.pos), sub, self._offsets)
             if fresh:  # first call blocks through trace + compile
                 obs_metrics.ENGINE_COMPILE_S.observe(time.perf_counter() - tc)
+            self._state_wrote(self.pos, k, k)
             self.pos += k
             return k, toks_dev, last_dev
 
@@ -1789,10 +1910,12 @@ class Engine:
             extend_index()
             window = np.asarray([[cur] + propose()], np.int32)  # (1, k+1)
             p0 = self.pos
+            self._state_enter(p0)
             with active_mesh(self.mesh):
                 preds_dev, self.cache = fn(
                     self.params, self.cache, jnp.asarray(window),
                     jnp.int32(p0))
+            self._state_wrote(p0, k + 1, k + 1)
             preds = np.asarray(preds_dev)[0]  # (k+1,) int32
             accepted = 0
             while accepted < k and window[0, accepted + 1] == preds[accepted]:

@@ -294,23 +294,28 @@ class SlotScheduler:
         # allocation and exhaustion surfaces as queueing → 429.
         self.paged = bool(getattr(engine, "paged", False))
         # a windowed model's window layers keep a slot's last ``window``
-        # positions in the slot's own ring of pages (ops/window.py), which no
-        # page id addresses.  So nothing may start a slot past position 0
-        # without having written those positions: the radix tree stays off (a
-        # hit would bind the full layers' prefix pages and leave the rings
-        # empty; ROADMAP M(a)(3)), and so does preemption, whose park and
-        # resume move a request page by page
+        # positions in the slot's own ring of pages (ops/window.py), and a
+        # convolution layer keeps its state in the slot's own row
+        # (ops/conv.py): no page id addresses either.  So nothing may start a
+        # slot past position 0 without having written those positions: the
+        # radix tree stays off (a hit would bind the attention layers' prefix
+        # pages and leave the slot's own state empty; ROADMAP M(a)(3), M(b)),
+        # and so does preemption, whose park and resume move a request page by
+        # page
         self.ring_pages = int(getattr(engine, "ring_pages", 0))
-        if self.ring_pages:
+        self.slot_state = str(getattr(engine, "slot_state", ""))
+        if self.slot_state:
             prefix_reuse = preempt = False
             if kv_reserve == "optimistic":
-                engine._refuse_slot_rings("--kv-reserve optimistic (the spill tier)")
+                engine._refuse_slot_state("--kv-reserve optimistic (the spill tier)")
             from ..models.windowed import SLOT_ROWS
             if max(int(prefill_chunk), int(spec_k) + 1 if spec else 0) > SLOT_ROWS:
                 raise ValueError(
                     f"a step of more than {SLOT_ROWS} rows (--sched-prefill-chunk "
-                    f"{prefill_chunk}, --spec-k {spec_k}) does not fit the slot "
-                    "rings of a windowed model's window layers")
+                    f"{prefill_chunk}, --spec-k {spec_k}) does not fit "
+                    + ("the slot rings of a windowed model's window layers"
+                       if self.ring_pages else
+                       "the state rings of a convolution model's slots"))
         self.pool: PagePool | None = None
         self.prefix_cache: RadixTree | None = None
         # KV tiering (runtime/kvtier.py): under ``optimistic`` reservation

@@ -306,7 +306,15 @@ def _bounded(stream, state: "ApiState", deadline: float | None,
     its normal end-of-stream path — held-back text flushes and
     ``engine.pos`` rewinds exactly as for a budget-exhausted stream, so
     cancellation reuses the one pos-rewind invariant instead of adding a
-    second.  ``flag`` reports why the stream ended early.
+    second.  The invariant (``runtime/engine.py``, above
+    ``Engine.state_holds``): what a sequence leaves in the cache is addressed
+    by position, so rows above ``pos`` are dead and get overwritten.  Keys
+    and values are; a recurrent state has to be made so: a convolution
+    layer's is a ring of ``ops/conv.py RING`` positions, the bound on how far
+    a rewind may reach (a decode burst is capped to fit it, the stop
+    string's hold-back is a few tokens more), and a resume the ring no longer
+    covers (``Engine.resume_at``) prefills the conversation again from 0.
+    ``flag`` reports why the stream ended early.
 
     The deadline arms only after ``n_prompt`` + 1 items: the engine echoes
     the prompt before the first sampled token, and a "timed out" response
@@ -695,6 +703,12 @@ class ApiState:
             return "", 0, 0, "timeout"
 
         start_pos, delta_messages = self.naive_cache.resolve_delta_prompt(params.messages)
+        if start_pos and not engine.resume_at(start_pos):
+            # a recurrent state no longer holds the rows before the cached
+            # turn's end (the pos-rewind invariant, runtime/engine.py): the
+            # whole conversation is prefilled again
+            self.naive_cache.clear()
+            start_pos, delta_messages = 0, params.messages
         if start_pos == 0:
             engine.reset()
         engine.pos = start_pos

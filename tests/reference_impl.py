@@ -419,3 +419,101 @@ def np_forward_exaone_moe(params, cfg, tokens, wrong=None):
                 m, lp, cfg, (cfg.first_expert, cfg.n_experts_held), wrong)
     x = norm(x, np.asarray(params["rms_final"]))
     return (x @ params["wcls"]).astype(np.float32)
+
+
+def lfm2_moe_layer(m, lp, cfg, wrong=None):
+    """LFM2's expert FFN over normed rows ``m (T, D)``, float32 loops: a
+    sigmoid router, the top ``k`` of score + bias, the chosen scores over
+    their sum ``+ 1e-6``, times ``routed_scale``; no shared expert.
+
+    ``wrong``: ``bias_in_weights``, ``softmax_router``, ``no_eps``."""
+    k = cfg.n_active_experts
+    logits = m.astype(np.float32) @ lp["router"]
+    s = softmax(logits) if wrong == "softmax_router" else 1.0 / (1.0 + np.exp(-logits))
+    biased = s + lp["router_bias"]
+    out = np.zeros_like(m)
+    for i in range(len(m)):
+        idx = np.argsort(-biased[i], kind="stable")[:k]
+        w = (biased if wrong == "bias_in_weights" else s)[i, idx]
+        w = w / (w.sum() + (0.0 if wrong == "no_eps" else 1e-6)) * cfg.routed_scale
+        for wj, e in zip(w, idx):
+            out[i] += wj * ((silu(m[i] @ lp["gate"][e]) * (m[i] @ lp["up"][e]))
+                            @ lp["down"][e])
+    return out
+
+
+def np_forward_lfm2_moe(params, cfg, tokens, wrong=None):
+    """LFM2's full-sequence forward, (T, V) logits, no cache and no state:
+    layer ``l`` is attention where ``l % window_period == window_full_at``
+    (per-head q/k RMSNorm, rotate-half RoPE, causal softmax) and otherwise a
+    gated short convolution, computed as a sum of ``conv_taps`` shifted copies
+    of ``z`` over the whole sequence; the first ``n_dense_layers`` layers have
+    a dense SwiGLU, the others :func:`lfm2_moe_layer`.  The stacks are by kind
+    and by segment (``models/params.py``).
+
+    ``wrong`` names one deliberate fault: ``split_order`` (``C, B, X``),
+    ``gate_after`` (``C`` multiplied before the taps), ``taps_reversed``,
+    ``no_rope``, ``no_head_norm``, ``attention_first`` (the attention layer at
+    the period's start), or one of :func:`lfm2_moe_layer`'s."""
+    t = len(tokens)
+    hq, hkv, dh = cfg.n_heads, cfg.n_kv_heads, cfg.head_size
+    taps = cfg.conv_taps
+    pos = np.arange(t)
+    full_at = 0 if wrong == "attention_first" else cfg.window_full_at
+    period = cfg.window_period
+
+    def norm(x, w):
+        ms = np.mean(x.astype(np.float64) ** 2, axis=-1, keepdims=True)
+        return (w * (x / np.sqrt(ms + cfg.norm_eps))).astype(np.float32)
+
+    def seg(key, i):
+        return np.asarray(params[key][i], np.float32)
+
+    x = np.asarray(params["embedding"], np.float32)[tokens]
+    n_att = n_conv = 0
+    for li in range(cfg.n_layers):
+        u = norm(x, seg("rms_att", li))
+        if li % period == full_at:
+            a = n_att
+            n_att += 1
+            q = (u @ seg("wq", a)).reshape(t, hq, dh)
+            k = (u @ seg("wk", a)).reshape(t, hkv, dh)
+            v = (u @ seg("wv", a)).reshape(t, hkv, dh)
+            if wrong != "no_head_norm":
+                q, k = norm(q, seg("q_norm", a)), norm(k, seg("k_norm", a))
+            if wrong != "no_rope":
+                q = rope_rotate(q, pos, cfg.rope_theta, False)
+                k = rope_rotate(k, pos, cfg.rope_theta, False)
+            mask = pos[None, :] <= pos[:, None]
+            att = np.zeros((t, hq, dh), np.float32)
+            for h in range(hq):
+                kh = h // (hq // hkv)
+                sc = np.where(mask, (q[:, h] @ k[:, kh].T) / np.sqrt(dh), -np.inf)
+                att[:, h] = softmax(sc) @ v[:, kh]
+            x = x + att.reshape(t, hq * dh) @ seg("wo", a)
+        else:
+            c = n_conv
+            n_conv += 1
+            parts = np.split(u @ seg("conv_in", c), 3, axis=-1)
+            gb, gc, xs = ((parts[1], parts[0], parts[2])
+                          if wrong == "split_order" else parts)
+            z = gb * xs
+            if wrong == "gate_after":
+                z = z * gc
+            w = seg("conv_taps", c)                       # (D, taps)
+            if wrong == "taps_reversed":
+                w = w[:, ::-1]
+            ext = np.concatenate([np.zeros((taps - 1, z.shape[1]), np.float32), z])
+            y = sum(ext[j:j + t] * w[:, j] for j in range(taps))
+            if wrong != "gate_after":
+                y = gc * y
+            x = x + y @ seg("conv_out", c)
+        m = norm(x, seg("rms_ffn", li))
+        if li < cfg.n_dense_layers:
+            x = x + (silu(m @ seg("w1", li)) * (m @ seg("w3", li))) @ seg("w2", li)
+        else:
+            e = li - cfg.n_dense_layers
+            lp = {k: seg(k, e) for k in ("router", "router_bias", "up", "gate", "down")}
+            x = x + lfm2_moe_layer(m, lp, cfg, wrong)
+    x = norm(x, np.asarray(params["rms_final"]))
+    return (x @ np.asarray(params["wcls"], np.float32)).astype(np.float32)

@@ -132,7 +132,7 @@ def test_older_archs_keep_the_fourteen_keys():
 
 
 @pytest.mark.parametrize("patch,says", [
-    ({38: 1}, "unsupported .m header key"),
+    ({mfile.KEY_MAX + 1: 1}, "unsupported .m header key"),
     ({35: 1}, "keys 35..37 describe an exaone_moe file"),
     ({15: 0}, "a deepseek2 file states this size"),
     ({17: 7}, "RoPE rotates pairs"),
@@ -558,7 +558,8 @@ def test_mla_parts_are_named_under_the_scopes_the_yardstick_knows(
         params, monkeypatch, t, min_t, form):
     from dllama_tpu.ops.scopes import PARTS, SCOPES
     monkeypatch.setattr(mla, "EXPAND_MIN_T", min_t)
-    assert PARTS["qkv"] == ("q_lora", "kv_lora", "qk_norm")  # the last: K-EXAONE's
+    # the last two: K-EXAONE's and a short-convolution layer's (LFM2)
+    assert PARTS["qkv"] == ("q_lora", "kv_lora", "qk_norm", "conv")
     assert PARTS["attn"][:3] == ("absorb", "latent", "expand")
     assert PARTS["moe"] == ("router", "experts", "combine", "shared")
     text = jax.jit(lambda p, tk, c: forward(p, CFG, tk, c, jnp.int32(0))).lower(

@@ -493,7 +493,7 @@ def test_engine_scheduler_contiguous_and_paged_serve_the_same_tokens(
     assert eng.kv_bytes_per_token == cfg.n_full_layers * tok and eng.ring_pages == 9
     assert eng.cache.k.shape == (2, 49, 4, 2, 8) and eng.cache.wk.shape == (6, 18, 4, 2, 8)
     assert obs_metrics.KV_CACHE_BYTES._values == {
-        ("full",): 2 * 49 * 4 * tok, ("window",): 6 * 18 * 4 * tok}
+        ("full",): 2 * 49 * 4 * tok, ("window",): 6 * 18 * 4 * tok, ("conv",): 0}
     assert obs_metrics.KV_BYTES_PER_TOKEN.value == cfg.n_full_layers * tok
     assert sched.prefix_cache is None and not sched.preempt
     # 42 and 60 positions written (the last token out is not fed) on rings of

@@ -56,7 +56,8 @@ def test_arch_id_and_the_flags_derived_from_it():
                      "mixtral": (False, True, False), "olmoe": (True, False, False),
                      "deepseek2": (False, False, True),
                      "smallthinker": (False, True, False),
-                     "exaone_moe": (False, True, False)}
+                     "exaone_moe": (False, True, False),
+                     "lfm2_moe": (False, True, False)}
     shapes = param_shapes(tiny_config(arch=mfile.ARCH_OLMOE, n_experts=4,
                                       n_active_experts=2))
     assert (shapes["q_norm"], shapes["k_norm"]) == ((2, 64), (2, 32))
@@ -95,7 +96,7 @@ def test_validate_spec_knows_the_arch_and_wants_its_experts(tmp_path):
     with pytest.raises(ArtifactError, match="n_active_experts"):
         mfile.validate_spec(_spec(mfile.ARCH_OLMOE, n_experts=0), "x.m")
     bad = _spec(mfile.ARCH_OLMOE)
-    bad.arch = 0xABCD07
+    bad.arch = max(mfile.ARCH_NAMES) + 1   # the first id no arch has
     with pytest.raises(ArtifactError, match="unknown architecture"):
         mfile.validate_spec(bad, "x.m")
 

@@ -379,7 +379,7 @@ def test_engine_scheduler_contiguous_and_paged_serve_the_same_tokens(
     assert solo.kv_bytes_per_token == per_token
     tok = 2 * cfg.kv_dim * 4
     assert obs_metrics.KV_CACHE_BYTES._values == {
-        ("full",): 2 * 96 * tok, ("window",): 6 * 32 * tok}
+        ("full",): 2 * 96 * tok, ("window",): 6 * 32 * tok, ("conv",): 0}
     p1, p2 = [5, 9, 2], [int(t) for t in TOKS[:21]]
     wanted = []
     for p in (p1, p2):
@@ -529,7 +529,7 @@ _OP_NAME = re.compile(r"op_name=\"([^\"]+)\"")
 @pytest.mark.parametrize("t", [1, 6])
 def test_layer_kinds_are_named_under_attn_and_the_router_under_moe(params, t):
     from dllama_tpu.ops.scopes import PARTS, SCOPES
-    assert PARTS["attn"][-2:] == ("window", "full")
+    assert PARTS["attn"][-3:] == ("window", "full", "conv")  # the last: LFM2's
     obs_dispatch.reset()
     hlo = jax.jit(lambda p, tk, c: forward(p, CFG, tk, c, jnp.int32(3))).lower(
         params, jnp.zeros((1, t), jnp.int32), init_kv_cache(CFG, 1)

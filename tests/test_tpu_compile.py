@@ -943,3 +943,208 @@ def test_kernel_key_ignores_where_the_python_stands(topo, tmp_path, monkeypatch,
         assert got == want
     finally:
         jax.config.update("jax_traceback_in_locations_limit", limit)
+
+
+# ---------------------------------------------------------------------------
+# LFM2 (PR 47): hidden 2048, the conv operator's 2048 -> 6144 and 2048 -> 2048,
+# the fused q/k/v of heads of 64 (2048 -> 3072), a dense FFN of 11776, the
+# 65536-row head; 64 experts of 1536 a layer in one launch and 4 chosen of them
+# ---------------------------------------------------------------------------
+@pytest.mark.parametrize("rows", [1, 16, 256])
+@pytest.mark.parametrize("name,n,d", [
+    ("conv_in", 2048, 6144), ("conv_out", 2048, 2048), ("wqkv", 2048, 3072),
+    ("w13", 2048, 23552), ("w2", 11776, 2048)], ids=lambda v: str(v))
+def test_q40_matmul_compiles_at_lfm2_shapes(one_chip, name, n, d, rows):
+    x, qp, sc = _q40_shapes(n, d, rows, True, one_chip)
+    assert x.shape[1] == q40.padded_n(n)
+    text = jax.jit(q40._pallas_matmul_stacked).lower(
+        x, qp, sc, jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+    ).compile().as_text()
+    assert "tpu_custom_call" in text and "q40_mm_stacked" in text
+
+
+def test_q40_head_compiles_at_lfm2s_vocabulary(one_chip):
+    x, qp, sc = _q40_shapes(2048, 65536, 16, False, one_chip)
+    text = jax.jit(q40._pallas_matmul).lower(x, qp, sc).compile().as_text()
+    assert "tpu_custom_call" in text and "f32[16,65536]" in text
+
+
+@pytest.mark.parametrize("rows", [1, 16, 256])
+@pytest.mark.parametrize("name,n,d,per_expert", [
+    ("gate", 2048, 1536, False), ("down", 1536, 2048, True)], ids=["gate", "down"])
+def test_q40_experts_matmuls_compile_at_lfm2_shapes(one_chip, name, n, d,
+                                                    per_expert, rows):
+    """16 and 256 rows: all 64 experts of a layer in one launch
+    (``all-experts``); 1 row: the row's 4 chosen (``select-chosen``)."""
+    L, E, k = 30, 64, 4
+    assert q40.padded_n(n) == n and q40._tile_n_legal(n, q40._tiles(n, d)[0])
+    s = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)  # noqa: E731
+    planes = (s((L * E, n // 2, d), jnp.uint8), s((L * E, n // 32, d), jnp.uint16),
+              s((), jnp.int32))
+    if rows == 1:
+        text = jax.jit(
+            lambda x, qp, sc, layer, chosen: q40._pallas_matmul_experts(
+                x, qp, sc, layer, experts=E, chosen=chosen)).lower(
+            s(((k,) if per_expert else ()) + (1, n), jnp.bfloat16), *planes,
+            s((k,), jnp.int32)).compile().as_text()
+        assert "q40_mm_chosen" in text and f"f32[{k},1,{d}]" in text
+        return
+    text = jax.jit(
+        lambda x, qp, sc, layer: q40._pallas_matmul_experts(
+            x, qp, sc, layer, experts=E)).lower(
+        s(((E,) if per_expert else ()) + (rows, n), jnp.bfloat16), *planes
+    ).compile().as_text()
+    assert "q40_mm_experts" in text and f"f32[{E},{rows},{d}]" in text
+
+
+def _lfm2_programs(one_chip, monkeypatch, paged: bool, n_layers=8, slots=16,
+                   pages=2056):
+    """LFM2-24B-A2B's published widths (hidden 2048, 32/8 heads of 64, two
+    dense layers of 11776, 64 experts of 1536 of which 4 a token, 65536 rows,
+    3 taps, periods conv, conv, attention, conv) at ``n_layers`` layers: the
+    config, abstract packed params, and the served cell's pool with the slots'
+    state (``paged``) or the one-stream cell's cache of 32768 positions."""
+    from dllama_tpu.io import mfile
+    from dllama_tpu.models import transformer as tf
+    from dllama_tpu.models.config import ModelConfig
+    from dllama_tpu.models.params import param_shapes
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(jax, "device_count", lambda: 1)
+    cfg = ModelConfig(
+        arch=mfile.ARCH_LFM2_MOE, dim=2048, hidden_dim=11776, n_layers=n_layers,
+        n_heads=32, n_kv_heads=8, n_experts=64, n_active_experts=4,
+        vocab_size=65536, seq_len=2048 if paged else 32768,
+        hidden_act=mfile.ACT_SILU, rope_theta=1e6, norm_eps=1e-5, head_dim=64,
+        window_period=4, window_full_at=2, conv_taps=3, moe_hidden_dim=1536,
+        n_dense_layers=2, routed_scale=1.0, dtype=jnp.bfloat16)
+    s = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)  # noqa: E731
+
+    def packed(*shapes):
+        *lead, n, _ = shapes[0]
+        d, np_ = sum(sh[-1] for sh in shapes), q40.padded_n(n)
+        return q40.QTensor(s((*lead, np_ // 2, d), jnp.uint8),
+                           s((*lead, np_ // 32, d), jnp.uint16), (n, d))
+
+    sh = param_shapes(cfg)
+    params = {k: s(sh[k], jnp.float32) for k in sh
+              if k.startswith("rms") or k.endswith("_norm")
+              or k in ("router_bias", "conv_taps")}
+    params.update({k: s(sh[k], jnp.bfloat16) for k in ("embedding", "router")})
+    params.update(
+        wqkv=packed(sh["wq"], sh["wk"], sh["wv"]), w13=packed(sh["w1"], sh["w3"]),
+        **{k: packed(sh[k]) for k in ("wo", "w2", "conv_in", "conv_out", "up",
+                                      "gate", "down", "wcls")})
+    shapes = jax.eval_shape(
+        (lambda: tf.init_kv_pool(cfg, pages, 16, slots=slots, max_pages=128))
+        if paged else (lambda: tf.init_kv_cache(cfg, 1)))
+    cache = tf.KVCache(**{n: s(a.shape, a.dtype)
+                          for n, a in shapes.planes().items()})
+    return cfg, params, cache, s
+
+
+def _no_whole_copy(text, planes):
+    import re
+    for plane in planes:
+        assert plane in text, plane
+        assert not re.search(r"= " + re.escape(plane) + r"\S* copy\(", text), plane
+
+
+@pytest.mark.parametrize("t", [1, 16], ids=["pure-decode", "mixed"])
+def test_lfm2_slot_steps_compile_with_the_state_beside_the_pool(one_chip,
+                                                                monkeypatch, t):
+    """The two step programs of ``lfm2-24b-a2b.decode-heavy`` for the
+    described chip, two periods of layers: six conv operators over the slots'
+    state ring (one ``conv/ring`` site each a body), the two attention layers'
+    paged read in the gather form (heads of 64: ``_fused_choice`` wants 128
+    lanes), 64 experts in three launches a layer, no Q40 site on the XLA path,
+    and neither the pool nor the state copied whole."""
+    from dllama_tpu.obs import dispatch as obs_dispatch
+    from dllama_tpu.ops import conv
+    from dllama_tpu.runtime.decode_loop import slot_chunk
+
+    cfg, params, cache, s = _lfm2_programs(one_chip, monkeypatch, paged=True)
+    assert cfg.prefill_chunk() == 1024
+    # the attention layers' pool, heads of 64 two to a row of 128 lanes: as
+    # (2, 2056, 16, 8, 64) the chip's compact layout puts the pages minor-most
+    # and both planes are copied whole, twice a step
+    assert cache.k.shape == (2, 2056, 16, 4, 128)
+    assert cache.cz.shape == (6, 16, 1, conv.RING, 2048)      # the slots' state
+    assert att._fused_choice(t, 32, 8, 64, ps=16, maxp=128) == (False, False)
+    b = 16
+    obs_dispatch.reset()
+    try:
+        text = jax.jit(
+            lambda p, c, tok, pr, nv, k, tm, tp, tk, ptab: slot_chunk(
+                p, cfg, c, tok, pr, nv, k, tm, tp, tk, steps=1, greedy=True,
+                page_table=ptab), donate_argnums=(1,)).lower(
+            params, cache, s((b, t), jnp.int32), s((b,), jnp.int32),
+            s((b,), jnp.int32), s((2,), jnp.uint32), s((b,), jnp.float32),
+            s((b,), jnp.float32), s((b,), jnp.int32),
+            s((b, 128), jnp.int32)).compile().as_text()
+        sites = obs_dispatch.dispatches()
+    finally:
+        obs_dispatch.reset()
+    # the first period is unrolled (its first two layers are dense), the second
+    # scanned: 3 + 3 conv sites, 1 + 1 attention sites, 2 + 4 expert layers'
+    bodies = len(packing.buckets(b * t)) if t > 1 else 1
+    assert sites.get("conv/ring") == 6, sites
+    assert sites.get("kv_dense/paged-gather") == 2, sites
+    assert sites.get("moe/all-experts") == (2 + 4) * bodies, sites
+    assert "q40/xla-dequant" not in sites and "kv_dense/paged-fused" not in sites, sites
+    assert "q40_mm_experts" in text and "q40_mm_stacked" in text
+    for part in ("qkv/conv", "kv_write/conv", "attn/conv", "wo/conv"):
+        assert part in text, part
+    _no_whole_copy(text, ("bf16[2,2056,16,4,128]", f"bf16[6,16,1,{conv.RING},2048]"))
+
+
+def test_lfm2_one_stream_programs_compile_at_published_widths(one_chip, monkeypatch):
+    """The programs of ``lfm2-24b-a2b.single-stream`` for the described chip,
+    two periods of layers: the 256-row bucket of a prompt (wider than the
+    state ring: the rows that end at the prompt's last token are written) and
+    the 16-step decode chunk (``select-chosen``: three ``q40_mm_chosen``
+    launches an expert layer over the row's 4 experts)."""
+    import re
+
+    from dllama_tpu.models import transformer as tf
+    from dllama_tpu.obs import dispatch as obs_dispatch
+    from dllama_tpu.ops import conv
+    from dllama_tpu.runtime.decode_loop import decode_chunk
+
+    cfg, params, cache, s = _lfm2_programs(one_chip, monkeypatch, paged=False)
+    assert cache.k.shape == (2, 1, 8, 32768, 64)
+    assert cache.cz.shape == (6, 1, 1, conv.RING, 2048)
+    obs_dispatch.reset()
+    try:
+        prefill = jax.jit(
+            lambda p, c, tok, pos, last: tf.forward_last(p, cfg, tok, c, pos, last),
+            donate_argnums=(1,)).lower(
+            params, cache, s((1, 256), jnp.int32), s((), jnp.int32),
+            s((), jnp.int32)).compile().as_text()
+        sites_prefill = obs_dispatch.dispatches()
+        obs_dispatch.reset()
+        decode = jax.jit(
+            lambda p, c, tok, pos, k: decode_chunk(
+                p, cfg, c, tok, pos, k, steps=16, temperature=0.0, topp=0.9),
+            donate_argnums=(1,)).lower(
+            params, cache, s((1,), jnp.int32), s((), jnp.int32),
+            s((2,), jnp.uint32)).compile().as_text()
+        sites_decode = obs_dispatch.dispatches()
+    finally:
+        obs_dispatch.reset()
+    assert sites_prefill.get("moe/all-experts") == 6 and "moe/scan" not in sites_prefill
+    assert sites_decode.get("moe/select-chosen") == 6, sites_decode
+    for sites in (sites_prefill, sites_decode):
+        assert sites.get("conv/ring") == 6, sites
+        assert "q40/xla-dequant" not in sites, sites
+    ops = re.findall(r"^\s*(?:ROOT )?%?[\w.\-]+ = \S+ ([\w\-]+)\(.*?"
+                     r"op_name=\"([^\"]+)\"", decode, re.M)
+    calls = [path for op, path in ops if op == "custom-call"
+             and "pallas_call" in path and "/moe/experts/" in path]
+    assert len(calls) == 6 * 3 and all("q40_mm_chosen" in c for c in calls), calls
+    conv_calls = [path for op, path in ops if op == "custom-call"
+                  and "pallas_call" in path and "/conv/" in path]
+    assert len(conv_calls) == 6 * 2, conv_calls     # W_in and W_out a layer
+    for text in (prefill, decode):
+        _no_whole_copy(text, ("bf16[2,1,8,32768,64]",
+                              f"bf16[6,1,1,{conv.RING},2048]"))
