@@ -592,31 +592,38 @@ def child_kernels(rehearse: bool) -> None:
               "rel_err": rel_err(got, ref), "tol": ATTN_TOL,
               "seconds": round(time.perf_counter() - t0, 2)})
 
-    # the same kernel at a mixed step's 16 tokens a slot, at the two served
-    # cells' head geometry: a row whose block ends on the table's last
-    # position, one that crosses a chunk of the walk, one inside it, one at 0
-    t = 16
-    for name, hq_, hkv_ in (("mistral-7b", 32, 8), ("olmoe-1b-7b", 16, 16)):
+    # the same kernel at a mixed step's 16 tokens a slot, at the served cells'
+    # head geometry: a row whose block ends on the table's last position, one
+    # that crosses a chunk of the walk, one inside it, one at 0.  LFM2's heads
+    # of 64 lie two to a row of the pool (att.pool_rows), and the walk reads
+    # such a row as it lies: checked at the pure-decode step's one token too
+    for name, hq_, hkv_, dh_, ts in (("mistral-7b", 32, 8, dh, (16,)),
+                                     ("olmoe-1b-7b", 16, 16, dh, (16,)),
+                                     ("lfm2-24b-a2b", 32, 8, 64, (1, 16))):
         if rehearse:
-            hq_, hkv_ = hq_ // 8, max(1, hkv_ // 8)
+            hq_, hkv_ = hq_ // 4, max(1, hkv_ // 4)
         key, kq, kk, kv = jax.random.split(key, 4)
-        q = (jax.random.normal(kq, (b, hq_, t, dh)) * 0.5).astype(cfg.dtype)
         k_, v_ = (
-            (jax.random.normal(kx, (2, n_pages, ps, hkv_, dh)) * 0.5).astype(
-                cfg.dtype) for kx in (kk, kv))
-        pos_t = jnp.asarray(
-            [maxp * ps - t, att._WALK_PAGES * ps - 3, 2 * ps + 5, 0], jnp.int32)
-        t0 = time.perf_counter()
-        got = att.fused_paged_attention(q, k_, v_, layer, table, pos_t,
-                                        interpret=rehearse)
-        ref = att._rows_ceiling_attention(
-            q, att.paged_gather_layer(k_, layer, table),
-            att.paged_gather_layer(v_, layer, table), pos_t)
-        _say({"kernel": "fused_paged_attention", "kv": "dense", "t": t,
-              "geometry": {"heads": name, "hq": hq_, "hkv": hkv_, "dh": dh,
-                           "page": ps, "rows": b, "max_pages": maxp},
-              "rel_err": rel_err(got, ref), "tol": ATTN_TOL,
-              "seconds": round(time.perf_counter() - t0, 2)})
+            (jax.random.normal(kx, (2, n_pages, ps) + att.pool_rows(hkv_, dh_))
+             * 0.5).astype(cfg.dtype) for kx in (kk, kv))
+        for t in ts:
+            q = (jax.random.normal(jax.random.fold_in(kq, t), (b, hq_, t, dh_))
+                 * 0.5).astype(cfg.dtype)
+            pos_t = jnp.asarray(
+                [maxp * ps - t, att._WALK_PAGES * ps - 3, 2 * ps + 5, 0],
+                jnp.int32)
+            t0 = time.perf_counter()
+            got = att.fused_paged_attention(q, k_, v_, layer, table, pos_t,
+                                            interpret=rehearse)
+            ref = att._rows_ceiling_attention(
+                q, att.paged_gather_layer(k_, layer, table, dh=dh_),
+                att.paged_gather_layer(v_, layer, table, dh=dh_), pos_t)
+            _say({"kernel": "fused_paged_attention", "kv": "dense", "t": t,
+                  "geometry": {"heads": name, "hq": hq_, "hkv": hkv_,
+                               "dh": dh_, "pool_row": list(k_.shape[3:]),
+                               "page": ps, "rows": b, "max_pages": maxp},
+                  "rel_err": rel_err(got, ref), "tol": ATTN_TOL,
+                  "seconds": round(time.perf_counter() - t0, 2)})
 
     # the live walk at prefill rows against one-shot attention over the whole
     # contiguous cache, at the one-stream cells' 32k context: a prompt at

@@ -500,7 +500,7 @@ def model_from_engine(engine) -> CostModel | None:
                     fused, _ = _attn._fused_choice(
                         1, cfg.n_heads, cfg.n_kv_heads, cfg.head_size,
                         bool(engine.cache.quantized), engine.kv_page_size,
-                        engine.max_pages_per_slot)
+                        engine.max_pages_per_slot, cache.k.shape[-1])
             except Exception:
                 fused = False
         return CostModel(

@@ -184,7 +184,9 @@ def slot_gqa_attention_at(q: jax.Array, ck: jax.Array, cv: jax.Array,
 # PAGES minor-most (``{1,4,3,2,0}``), so every step that scatters or gathers
 # by page copies the whole pool to a page-major layout and back
 # (tests/test_tpu_compile.py, LFM2's heads of 64).  ``pool_rows`` keeps a row
-# lane-dense instead, and the pool functions below take a pool of either form.
+# lane-dense instead, and the pool functions below take a pool of either form:
+# the scatter and the gather forms by reshaping a token's slab, the fused page
+# walk by reading a row of several heads as it lies.
 _LANES = 128
 
 
@@ -192,7 +194,10 @@ def pool_rows(hkv: int, dh: int) -> tuple[int, int]:
     """The two minor axes of a dense paged pool ``(L, P, ps, *pool_rows)``:
     ``(Hkv, Dh)``, but heads narrower than 128 lanes are stored ``128 // Dh``
     to a row, ``(Hkv * Dh // 128, 128)``, where the heads divide so (the same
-    bytes in the same order: a token's ``(Hkv, Dh)`` slab reshaped)."""
+    bytes in the same order: a token's ``(Hkv, Dh)`` slab reshaped).  A pool
+    so folded is one the fused page walk takes (``_fused_choice``: its rows
+    fill whole lanes, so a page can be copied as it lies); one that keeps a
+    narrow head a row reads through the gather form."""
     f = _LANES // dh if dh < _LANES and _LANES % dh == 0 else 1
     return (hkv // f, f * dh) if f > 1 and hkv % f == 0 else (hkv, dh)
 
@@ -341,8 +346,13 @@ def paged_decode_attention(q: jax.Array, pool_k: jax.Array, pool_v: jax.Array,
 # behind the fold of this one — no materialized (B, Hkv, maxp·ps, Dh)
 # gather, and the online-softmax state is the loop's carry.  Dead pages
 # cost nothing: the loop's trip count is the slot's own.  Dense pools
-# only: an int8 pool's scale plane (L, P, ps, Hkv, 1) has one lane of 128
-# a row, no page of it can be sliced for a copy, and such a pool reads
+# whose rows fill whole lanes only: heads of 128, or narrower heads stored
+# ``f = 128 // Dh`` to a row (``pool_rows``), which the fold reads without
+# unpacking (the query is widened to the row, zero in the other heads'
+# lanes; a query row keeps the key rows its kv head lies in and takes its
+# own lanes of the result).  An int8 pool's scale plane (L, P, ps, Hkv, 1)
+# has one lane of 128 a row, and a pool of one narrow head a row half of
+# them: no page of either can be sliced for a copy, and such a pool reads
 # through the XLA forms below.  The copies are the kernel's own and not a
 # BlockSpec's: a BlockSpec keeps two buffers, so one page's copy in
 # flight, and takes a grid step for every page of the table, live or not
@@ -355,13 +365,18 @@ def paged_decode_attention(q: jax.Array, pool_k: jax.Array, pool_v: jax.Array,
 
 _FUSED_ENV = "DLLAMA_FUSED_ATTN"
 # pages of one chunk of the walk: 8 pages of 16 tokens at 16 kv heads are
-# 16 copies of 64 KB in flight and 2 MB of VMEM for the two buffers
+# 16 copies of 64 KB in flight and 2 MB of VMEM for the two buffers.  The
+# same 8 over a pool of several heads a row (LFM2's 8 heads of 64: 16 copies
+# of 16 KB): a chunk of 16 such pages moved the token gap by 0.1% and cost
+# 5 s of every start, the kernel's copies being unrolled where it is traced
+# (PERF.md §6, PR 48)
 _WALK_PAGES = 8
 # the most score elements one fold of the walk may hold: ``Hq * T`` query
 # rows by a chunk's ``tokens * Hkv`` keys, in f32 beside its mask and its
 # exponentials.  1 Mi of them compile into a v5e's 16 MiB of scoped VMEM
 # (Mistral's 32/8 heads up to T = 32, Llama-2-7B's 32/32 up to T = 8, at
-# page 16), 2 Mi do not (tests/test_tpu_compile.py); a wider block of rows
+# page 16; LFM2's 32/8 of 64, whose token has 4 key rows in the pool, up to
+# T = 64), 2 Mi do not (tests/test_tpu_compile.py); a wider block of rows
 # keeps the gather form
 _SCORE_TILE_MAX = 1 << 20
 
@@ -376,19 +391,23 @@ def fused_mode() -> str:
 
 
 def _make_fused_kernel(hq: int, hkv: int, dh: int, ps: int, cp: int,
-                       t: int, maxp: int, out_dtype):
+                       t: int, maxp: int, out_dtype, f: int = 1):
     """Build the fused page-walk kernel body for one (head/page/row)
     geometry; ``cp`` is the walk's chunk in pages, ``t`` the query tokens a
-    slot, ``maxp`` the table's width.
+    slot, ``maxp`` the table's width, ``f`` the kv heads a row of the pool
+    holds (:func:`pool_rows`; 1 for a pool of one head a row).
 
     Ref order: 3 scalar-prefetch refs (layer (1,), page table (B, maxp),
     per-row positions (B,)), then the q block, the K and V pools left in
     HBM, the output block, and the scratch: two chunk buffers per pool and
     one DMA semaphore per buffer."""
-    g = hq // hkv
     rows = hq * t
     inv_sqrt = np.float32(1.0 / math.sqrt(dh))
-    n_keys = cp * ps * hkv
+    # a token's keys are the ``kvr`` rows of ``width`` lanes it has in the
+    # pool, and ``gr`` query heads read each: the kv heads and their groups
+    # for a pool of one head a row, ``f`` kv heads' groups a row otherwise
+    kvr, gr, width = hkv // f, hq // hkv * f, f * dh
+    n_keys = cp * ps * kvr
 
     def kernel(layer_ref, ptab_ref, pos_ref, q_ref, *rest):
         from jax.experimental import pallas as plx
@@ -420,15 +439,18 @@ def _make_fused_kernel(hq: int, hkv: int, dh: int, ps: int, cp: int,
                     pltpu.make_async_copy(pool.at[0, 0], buf.at[slot, i],
                                           sem.at[slot]).wait()
 
-        # (Hq*T, Dh): head-major rows, a head's T tokens together.  Key
-        # column ``k`` of a chunk is token ``k // Hkv`` of kv head ``k %
-        # Hkv``; a row keeps its own kv head's keys up to its ceiling
+        # (Hq*T, width): head-major rows, a head's T tokens together.  Key
+        # column ``k`` of a chunk is token ``k // kvr`` of pool row ``k %
+        # kvr``; a query row keeps the keys of the pool row its kv head lies
+        # in, up to its ceiling.  Where that row holds ``f`` heads the query
+        # came widened to it, zero in the other heads' lanes, so the 128-lane
+        # contraction adds exactly 0 for them and the score is the own head's
         qb = q_ref[0]
         if t == 1:
             row = jax.lax.broadcasted_iota(jnp.int32, (hq, n_keys), 0)
             col = jax.lax.broadcasted_iota(jnp.int32, (hq, n_keys), 1)
-            own = jax.lax.rem(col, hkv) == jax.lax.div(row, g)
-            tok = jax.lax.div(col, hkv)  # a key's token, within its chunk
+            own = jax.lax.rem(col, kvr) == jax.lax.div(row, gr)
+            tok = jax.lax.div(col, kvr)  # a key's token, within its chunk
 
             def live(c):
                 return own & (c * (cp * ps) + tok <= pos)
@@ -440,9 +462,9 @@ def _make_fused_kernel(hq: int, hkv: int, dh: int, ps: int, cp: int,
             # pos + j - its token``, another head's key never
             row = jax.lax.broadcasted_iota(jnp.int32, (rows, 1), 0)
             col = jax.lax.broadcasted_iota(jnp.int32, (1, n_keys), 1)
-            own = jax.lax.rem(col, hkv) == jax.lax.div(jax.lax.div(row, t), g)
+            own = jax.lax.rem(col, kvr) == jax.lax.div(jax.lax.div(row, t), gr)
             thr = jnp.where(own, pos + jax.lax.rem(row, t)
-                            - jax.lax.div(col, hkv), -1)
+                            - jax.lax.div(col, kvr), -1)
 
             def live(c):
                 return thr >= c * (cp * ps)
@@ -456,7 +478,7 @@ def _make_fused_kernel(hq: int, hkv: int, dh: int, ps: int, cp: int,
                 start(c + 1, 1 - slot)
 
             wait(slot)
-            k = bufs[0][slot]    # (cp, ps, Hkv, Dh): token-major pages
+            k = bufs[0][slot]    # (cp, ps, kvr, width) or (cp, ps * kvr, width)
             v = bufs[1][slot]
             # the pages stay token-major: a chunk's (cp, ps, Hkv) rows are
             # one operand of n_keys keys, every query row is scored
@@ -468,8 +490,8 @@ def _make_fused_kernel(hq: int, hkv: int, dh: int, ps: int, cp: int,
             # head-major and Hkv dots of one row each, and at a chunk's
             # rows is still the faster form at every width but one
             # (tools/sweep_attn.py --paged; PERF.md §6, PR 37)
-            kf = k.reshape(n_keys, dh)
-            vf = v.reshape(n_keys, dh)
+            kf = k.reshape(n_keys, width)
+            vf = v.reshape(n_keys, width)
             sc = jax.lax.dot_general(
                 qb.astype(kf.dtype), kf, (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32) * inv_sqrt
@@ -481,7 +503,7 @@ def _make_fused_kernel(hq: int, hkv: int, dh: int, ps: int, cp: int,
             l_new = alpha * l_prev + jnp.sum(pexp, axis=1, keepdims=True)
             pv = jax.lax.dot_general(
                 pexp.astype(vf.dtype), vf, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)     # (Hq*T, Dh)
+                preferred_element_type=jnp.float32)     # (Hq*T, width)
             return m_new, l_new, alpha * acc + pv
 
         start(0, 0)
@@ -489,8 +511,20 @@ def _make_fused_kernel(hq: int, hkv: int, dh: int, ps: int, cp: int,
             0, n_chunks, fold,
             (jnp.full((rows, 1), _NEG, jnp.float32),
              jnp.zeros((rows, 1), jnp.float32),
-             jnp.zeros((rows, dh), jnp.float32)))
-        o_ref[0] = (acc / jnp.maximum(l, 1e-38)).astype(out_dtype)
+             jnp.zeros((rows, width), jnp.float32)))
+        out = acc / jnp.maximum(l, 1e-38)
+        if f > 1:
+            # the second dot gave every head of the row; a query row takes
+            # the lanes of its own
+            head = jax.lax.div(
+                jax.lax.broadcasted_iota(jnp.int32, (rows, 1), 0),
+                t * (hq // hkv))
+            mine = out[:, :dh]
+            for i in range(1, f):
+                mine = jnp.where(jax.lax.rem(head, f) == i,
+                                 out[:, i * dh:(i + 1) * dh], mine)
+            out = mine
+        o_ref[0] = out.astype(out_dtype)
 
     return kernel
 
@@ -499,7 +533,9 @@ def fused_paged_attention(q: jax.Array, pool_k: jax.Array, pool_v: jax.Array,
                           layer: jax.Array, page_table: jax.Array,
                           pos_rows: jax.Array,
                           *, interpret: bool = False) -> jax.Array:
-    """Paged GQA of ``T >= 1`` query tokens a slot over a dense pool as ONE
+    """Paged GQA of ``T >= 1`` query tokens a slot over a dense pool
+    ``(L, P, ps, G, W)`` whose rows fill whole lanes (``W`` = the head size,
+    or 128 with ``128 // Dh`` heads a row, :func:`pool_rows`) as ONE
     kernel: page-table walk and online-softmax fold in a single
     pallas_call, under :func:`_rows_ceiling_attention`'s per-row causal
     ceiling (token ``j`` of row ``r`` sees key positions ``<= pos_rows[r]
@@ -514,22 +550,44 @@ def fused_paged_attention(q: jax.Array, pool_k: jax.Array, pool_v: jax.Array,
     from jax.experimental.pallas import tpu as pltpu
 
     b, hq, t, dh = q.shape
-    ps, hkv = pool_k.shape[2], pool_k.shape[3]
+    ps, kvr, width = pool_k.shape[2:]
+    f = width // dh          # kv heads a row of the pool (pool_rows)
+    hkv = kvr * f
     maxp = page_table.shape[1]
     cp = min(_WALK_PAGES, maxp)
     pools = [pool_k, pool_v]
+    if f > 1:
+        # a folded row is a token's slab reshaped; the page goes the rest of
+        # the way, ``(ps * kvr, 128)``: the same bytes in the same order (XLA
+        # makes the reshape a bitcast of the resident pool), and a page whose
+        # second-minor axis fills the chip's sublane tile where ``kvr`` = 4
+        # rows would be padded to it in VMEM (0.62 against 0.83 us a chunk of
+        # 8 pages, PERF.md §6, PR 48)
+        pools = [pool.reshape(*pool.shape[:2], ps * kvr, width)
+                 for pool in pools]
+
+    def query_rows():  # built among the call's operands: at f = 1 the order
+        # of the program's equations is the parent's, and so is its cache key
+        qr = q.reshape(b, hq * t, dh)
+        if f == 1:
+            return qr
+        # each query row widened to the pool's row: its head's lanes where
+        # its kv head lies in the row, zero in the others
+        lane = np.arange(hq)[:, None] // (hq // hkv) % f == np.arange(f)
+        return jnp.where(np.repeat(lane, t, axis=0)[None, :, :, None],
+                         qr[:, :, None, :], 0).reshape(b, hq * t, width)
 
     def row_map(bi, *_):
         return (bi, 0, 0)
 
     hbm = plx.BlockSpec(memory_space=plx.ANY)
-    kernel = _make_fused_kernel(hq, hkv, dh, ps, cp, t, maxp, q.dtype)
+    kernel = _make_fused_kernel(hq, hkv, dh, ps, cp, t, maxp, q.dtype, f)
     out = plx.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
             grid=(b,),
-            in_specs=[plx.BlockSpec((1, hq * t, dh), row_map)]
+            in_specs=[plx.BlockSpec((1, hq * t, width), row_map)]
             + [hbm] * len(pools),
             out_specs=plx.BlockSpec((1, hq * t, dh), row_map),
             scratch_shapes=[pltpu.VMEM((2, cp, *pool.shape[2:]), pool.dtype)
@@ -542,32 +600,48 @@ def fused_paged_attention(q: jax.Array, pool_k: jax.Array, pool_v: jax.Array,
         name="paged_attn_fused",
     )(jnp.atleast_1d(layer).astype(jnp.int32),
       page_table.astype(jnp.int32), pos_rows.astype(jnp.int32),
-      q.reshape(b, hq * t, dh), *pools)
+      query_rows(), *pools)
     return out.reshape(b, hq, t, dh)
+
+
+# JAX traces a Pallas kernel anew at every call site, and a start of a served
+# LFM2 has fourteen (two attention sites a step program): under a jit the
+# sites of one step width share one trace, 4 s of that cell's ``setup_s`` in
+# the server's process (PERF.md §6, PR 48).  The walk over a pool of one head
+# a row is called bare, as it was: a program's cache key is its jaxpr (PR 46),
+# and wrapping it would give every other served cell's programs a new one.
+_folded_walk = jax.jit(fused_paged_attention, static_argnames=("interpret",))
 
 
 def _fused_choice(t: int, hq: int, hkv: int, dh: int = 128,
                   quantized: bool = False, ps: int = 16,
-                  maxp: int = _WALK_PAGES) -> tuple[bool, bool]:
+                  maxp: int = _WALK_PAGES,
+                  row: int | None = None) -> tuple[bool, bool]:
     """Resolve the fused-vs-fallback decision for one call site from
     static facts only (mode, platform, mesh, the block's query tokens
-    ``t``, head counts and size, the pool's codec, the page size ``ps``
-    and the table's width ``maxp``, which give the walk's chunk), so it is
-    the same inside and outside a jit trace.  Returns ``(use_fused,
+    ``t``, head counts and size, the pool's codec, the page size ``ps``,
+    the table's width ``maxp``, which give the walk's chunk, and the width
+    ``row`` of the pool's rows: its minor axis, ``dh`` where not given), so
+    it is the same inside and outside a jit trace.  Returns ``(use_fused,
     interpret)``.  On a single TPU device ``auto``/``on`` mean the fused
     kernel at every ``t`` (the pure-decode step's one token, a mixed
     step's chunk, a verify step's ``spec_k + 1``) for a dense pool whose
-    heads fill whole lanes (a page is copied as it lies, and a copy of
-    part of a 128-lane row is refused) and whose score tile fits
-    (``_SCORE_TILE_MAX``); nothing is executed to decide, so a Mosaic
-    lowering or runtime error propagates and fails the run (values are
-    checked on the chip by chip_smoke.py).  A ``pallas_call`` is not
+    ROWS fill whole lanes: heads of 128, or narrower heads that
+    :func:`pool_rows` folded ``row // dh`` to a row (a page is copied as it
+    lies, and a copy of part of a 128-lane row is refused, so a pool that
+    keeps a narrow head a row stays on the gather form), and whose score
+    tile fits ``_SCORE_TILE_MAX``, reckoned with the keys a token really
+    has in the pool, ``hkv * dh // row`` rows; nothing is executed to
+    decide, so a Mosaic lowering or runtime error propagates and fails the
+    run (values are checked on the chip by chip_smoke.py, at heads of 128
+    and at heads of 64 on a folded pool).  A ``pallas_call`` is not
     partitioned by GSPMD, so on a multi-device mesh the TPU path stays
     the gather form.  ``auto`` off-TPU falls
     back silently (the clean-run ledger contract); ``on`` where the
     kernel cannot run degrades loudly (warn-once)."""
     mode = fused_mode()
-    tile = hq * t * min(_WALK_PAGES, maxp) * ps * hkv
+    row = dh if row is None else row
+    tile = hq * t * min(_WALK_PAGES, maxp) * ps * (hkv * dh // row)
     if mode == "off" or hq % hkv != 0 or quantized or tile > _SCORE_TILE_MAX:
         return False, False
     if mode == "interp":
@@ -576,7 +650,7 @@ def _fused_choice(t: int, hq: int, hkv: int, dh: int = 128,
     mesh = get_active_mesh()
     n_dev = mesh.size if mesh is not None else 1
     if backend == "tpu" and n_dev == 1:
-        return dh % 128 == 0, False
+        return row % _LANES == 0, False
     if mode == "on":
         from ..obs import dispatch as obs_dispatch
         obs_dispatch.record_degrade(
@@ -616,12 +690,13 @@ def paged_gqa_attention_at(q: jax.Array, pool_k: jax.Array, pool_v: jax.Array,
     codec = "kv_int8" if scales is not None else "kv_dense"
     use_fused, interp = _fused_choice(t, q.shape[1], hkv, q.shape[3],
                                       scales is not None, ps,
-                                      page_table.shape[1])
+                                      page_table.shape[1], pool_k.shape[4])
     if use_fused:
         obs_dispatch.record_dispatch(codec, "paged-fused", t=t, s=s,
                                      page_size=ps, interpret=interp)
-        return fused_paged_attention(q, pool_k, pool_v, layer, page_table,
-                                     pos_rows, interpret=interp)
+        walk = fused_paged_attention if pool_k.shape[4] == dh else _folded_walk
+        return walk(q, pool_k, pool_v, layer, page_table, pos_rows,
+                    interpret=interp)
     if t == 1 and _use_live_walk(q.shape[1] // hkv, t, s):
         obs_dispatch.record_dispatch(codec, "paged-decode", t=t, s=s,
                                      page_size=ps)

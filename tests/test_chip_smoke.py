@@ -78,12 +78,16 @@ def test_rehearse_kernels_phase(rehearsal_env, capfd):
     errs = [r for r in rows if "rel_err" in r]
     # five shapes × rows {1, 8, 256} + a row's chosen experts in one launch +
     # dense/int8 fused attention + the fused walk at a chunk's 16 tokens a
-    # slot, two head geometries + the live walk at prefill rows, dense/int8 ×
-    # two positions
-    assert len(errs) == 24
+    # slot, three head geometries, and at one token over the pool that holds
+    # two heads of 64 a row + the live walk at prefill rows, dense/int8 × two
+    # positions
+    assert len(errs) == 26
     assert [r["chosen"] for r in errs if r["kernel"] == "q40.chosen_experts"] == [6]
     assert [r["geometry"]["heads"] for r in errs if r.get("t") == 16] == \
-        ["mistral-7b", "olmoe-1b-7b"]
+        ["mistral-7b", "olmoe-1b-7b", "lfm2-24b-a2b"]
+    assert [(r["t"], r["geometry"]["dh"], r["geometry"]["pool_row"])
+            for r in errs if r.get("geometry", {}).get("heads") == "lfm2-24b-a2b"] \
+        == [(1, 64, [1, 128]), (16, 64, [1, 128])]
     assert all(r["rel_err"] <= r["tol"] for r in errs)
 
 

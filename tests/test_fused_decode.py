@@ -65,27 +65,35 @@ def make_paged_engine(batch=4, page=PAGE, **kw):
 
 # -- kernel vs gather reference --------------------------------------------
 
-def _pool_fixture(quantized, hkv=2, ps=8, t=1, g=2, dh=16, nlayers=2):
+def _pool_fixture(quantized, hkv=2, ps=8, t=1, g=2, dh=16, nlayers=2,
+                  fold=False):
     """A ragged paged read of ``t`` query tokens a row over a table wider
     than one chunk of the walk: the pool is laid out from logical head-major
     KV by :func:`fixtures.pool_from_logical`, and ``logical`` is that KV
     (dequantized for an int8 pool) for a reference that never touches the
     pool.  Rows, by what their block of ``t`` tokens does: starts at
-    position 0; crosses a page boundary; crosses a boundary of the walk's
+    position 0; crosses a page boundary (its chunk reads its last live page
+    again for the pages past it); crosses a boundary of the walk's
     chunks (``_WALK_PAGES`` pages); ends on the table's last position; and
     one whose table past its first token's page is scratch page 0, as a
     decode row's is in a mixed step (its tokens past the first see page 0's
-    keys in both forms)."""
-    from dllama_tpu.ops.attention import _WALK_PAGES
-    b, maxp = 5, _WALK_PAGES + 2
+    keys in both forms).  ``fold``: the pool's rows hold ``128 // dh`` heads
+    each (``pool_rows``), the same bytes in the same order."""
+    from dllama_tpu.ops.attention import _WALK_PAGES as cp, pool_rows
+    rows = pool_rows(hkv, dh) if fold else (hkv, dh)
+    b, maxp = 5, cp + 2
     npages = 1 + b * maxp
     rng = np.random.RandomState(3)
     table = rng.permutation(np.arange(1, npages)).reshape(b, maxp)
-    pos_rows = jnp.asarray([0, ps - 1, _WALK_PAGES * ps - max(1, t // 2),
+    pos_rows = jnp.asarray([0, ps - 1, cp * ps - max(1, t // 2),
                             maxp * ps - t, ps + 1], jnp.int32)
     q = jnp.asarray(rng.randn(b, hkv * g, t, dh) * 0.3, jnp.float32)
     shape = (nlayers, b, hkv, maxp * ps, dh)
-    place = lambda a: jnp.asarray(pool_from_logical(a, table, npages, ps))  # noqa: E731
+
+    def place(a):
+        pool = pool_from_logical(a, table, npages, ps)
+        return jnp.asarray(pool.reshape(*pool.shape[:3], *rows)
+                           if pool.shape[-1] == dh else pool)
     if quantized:
         (qk, sk), (qv, sv) = (quantize_kv(jnp.asarray(rng.randn(*shape),
                                                       jnp.float32))
@@ -97,7 +105,7 @@ def _pool_fixture(quantized, hkv=2, ps=8, t=1, g=2, dh=16, nlayers=2):
                 for _ in range(2))
         pk, pv, scales, logical = place(k), place(v), None, (k, v)
         # scratch page 0 holds what the last invalid write left there
-        page0 = jnp.asarray(rng.randn(nlayers, ps, hkv, dh) * 0.3, pk.dtype)
+        page0 = jnp.asarray(rng.randn(nlayers, ps, *rows) * 0.3, pk.dtype)
         pk, pv = pk.at[:, 0].set(page0), pv.at[:, 0].set(-page0)
     table[-1, int(pos_rows[-1]) // ps + 1:] = 0
     return q, pk, pv, jnp.asarray(table, jnp.int32), pos_rows, scales, logical
@@ -126,19 +134,87 @@ def test_fused_kernel_matches_gather_reference(quantized, t, hkv, ps):
                                  scales=scales) if quantized else \
         fused_paged_attention(q, pk, pv, layer, table, pos_rows,
                               interpret=True)
+    _assert_is_the_gather_read(out, q, pk, pv, layer, table, pos_rows,
+                               (k_log, v_log), scales)
+
+
+def _assert_is_the_gather_read(out, q, pk, pv, layer, table, pos_rows, logical,
+                               scales=None):
+    """``out`` against the materialized-gather read of the same pool, whose
+    view must be the logical KV the pool was laid out from, exactly."""
     ks, vs = scales if scales is not None else (None, None)
-    k_l = paged_gather_layer(pk, layer, table, scale_pool=ks)
-    v_l = paged_gather_layer(pv, layer, table, scale_pool=vs)
+    dh = q.shape[-1]
+    k_l = paged_gather_layer(pk, layer, table, scale_pool=ks, dh=dh)
+    v_l = paged_gather_layer(pv, layer, table, scale_pool=vs, dh=dh)
     # every row but the last has its whole table
-    np.testing.assert_array_equal(np.asarray(k_l[:-1], np.float32),
-                                  np.asarray(k_log[1][:-1], np.float32))
-    np.testing.assert_array_equal(np.asarray(v_l[:-1], np.float32),
-                                  np.asarray(v_log[1][:-1], np.float32))
+    for view, log in zip((k_l, v_l), logical):
+        np.testing.assert_array_equal(np.asarray(view[:-1], np.float32),
+                                      np.asarray(log[1][:-1], np.float32))
     ref = _rows_ceiling_attention(q, k_l, v_l, pos_rows)
     assert out.shape == ref.shape == q.shape
     tol = 1e-2 * max(float(np.abs(np.asarray(ref, np.float32)).max()), 1e-3)
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(ref, np.float32), atol=tol)
+
+
+# heads narrower than a lane row, stored 128 // dh to a row of the pool: LFM2's
+# 32 / 8 heads of 64 (two a row) and 16 / 16 of 32 (four a row); a page of 4
+# tokens keeps the interpreter quick
+@pytest.mark.parametrize("t", [1, 5, 16])
+@pytest.mark.parametrize("hq,hkv,dh", [(32, 8, 64), (16, 16, 32)],
+                         ids=["32x8x64-two-a-row", "16x16x32-four-a-row"])
+def test_fused_kernel_walks_a_folded_pool(hq, hkv, dh, t):
+    """The page walk reads a pool row that holds several heads as it lies:
+    the query is widened to the row, the 128-lane contraction adds exactly 0
+    in the other heads' lanes, a query row keeps the key rows its kv head
+    lies in and takes its own lanes of the second dot.  Against the gather
+    form (which unfolds the rows, and IS the logical KV) on the ragged
+    fixture, at the tolerance of the whole-lane heads' cases."""
+    from dllama_tpu.ops.attention import pool_rows
+    ps = 4
+    q, pk, pv, table, pos_rows, _, (k_log, v_log) = _pool_fixture(
+        False, hkv, ps, t, hq // hkv, dh, fold=True)
+    assert pk.shape[2:] == (ps,) + pool_rows(hkv, dh) and pk.shape[4] == 128
+    layer = jnp.int32(1)
+    out = fused_paged_attention(q, pk, pv, layer, table, pos_rows,
+                                interpret=True)
+    _assert_is_the_gather_read(out, q, pk, pv, layer, table, pos_rows,
+                               (k_log, v_log))
+
+
+# (t, hq, hkv, dh, int8, page, table pages, the pool's row width) -> fused?
+# on one TPU device.  The rule reads the pool's rows: whole lanes take the
+# walk, part of a lane row stays on the gather form whatever the head size
+@pytest.mark.parametrize("args,fused", [
+    ((1, 32, 8, 64, False, 16, 128, 128), True),     # LFM2's folded pool
+    ((16, 32, 8, 64, False, 16, 128, 128), True),
+    ((1, 32, 8, 64, False, 16, 128, 64), False),     # the same heads, one a row
+    ((1, 32, 8, 64, False, 16, 128), False),         # no width given: dh
+    ((1, 32, 8, 64, True, 16, 128, 128), False),     # int8: no folded form
+    ((1, 16, 16, 32, False, 16, 128, 128), True),    # four heads a row
+    ((1, 32, 8, 96, False, 16, 128, 96), False),     # 96 does not divide 128
+    ((1, 32, 8, 256, False, 16, 128, 256), True),    # two lane rows a head
+    # the tile is counted in the rows a token has in the pool (4, not 8):
+    # 32 * t * 8 * 16 * 4 <= 1 Mi up to t = 64, where 8 rows a token stop at 32
+    ((64, 32, 8, 64, False, 16, 128, 128), True),
+    ((65, 32, 8, 64, False, 16, 128, 128), False),
+    ((33, 32, 8, 128, False, 16, 128, 128), False),
+    # a table shorter than the chunk bounds it: 4 pages, t up to 128
+    ((128, 32, 8, 64, False, 16, 4, 128), True),
+    ((129, 32, 8, 64, False, 16, 4, 128), False),
+], ids=["folded-t1", "folded-t16", "one-head-a-row", "width-defaults-to-dh",
+        "int8", "four-a-row", "dh96", "dh256", "tile-t64", "tile-t65",
+        "tile-8-rows-t33", "short-table-t128", "short-table-t129"])
+def test_fused_choice_reads_the_pools_row_width(monkeypatch, args, fused):
+    from dllama_tpu.ops import attention as att
+    from dllama_tpu.parallel.mesh import active_mesh
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setenv("DLLAMA_FUSED_ATTN", "auto")
+    assert att._fused_choice(*args) == (fused, False)
+    with active_mesh(make_mesh(tp=2, devices=jax.devices()[:2])):
+        assert att._fused_choice(*args) == (False, False)   # a mesh: gather
+    monkeypatch.setattr(jax, "default_backend", lambda: "cpu")
+    assert att._fused_choice(*args) == (False, False)       # auto off-TPU
 
 
 @pytest.mark.parametrize("mode", ["auto", "on"])
@@ -155,6 +231,7 @@ def test_fused_choice_is_static_and_raises_on_tpu(monkeypatch, mode):
     assert att._fused_choice(1, 4, 2) == (True, False)
     assert att._fused_choice(1, 3, 2) == (False, False)  # hq % hkv
     assert att._fused_choice(1, 4, 2, 64) == (False, False)  # half a lane row
+    assert att._fused_choice(1, 4, 2, 64, row=128) == (True, False)  # two heads fill it
     assert att._fused_choice(1, 4, 2, 128, True) == (False, False)  # int8 pool
 
     def read(qv):

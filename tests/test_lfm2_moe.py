@@ -618,9 +618,15 @@ def test_a_pool_of_narrow_heads_is_lane_dense_and_reads_the_same():
             assert np.abs(np.asarray(walk) - np.asarray(want)).max() < 1e-5
 
 
-def test_the_slot_path_at_heads_of_64_is_the_reference():
+@pytest.mark.parametrize("read", ["off", "interp"], ids=["gather", "fused-walk"])
+def test_the_slot_path_at_heads_of_64_is_the_reference(monkeypatch, read):
     """The served geometry's head size at toy widths: the pool's rows hold two
-    heads each; chunks of 16 with a ragged last one, then decoding."""
+    heads each; chunks of 16 with a ragged last one, then decoding, through
+    the gather form and through the fused page walk over the folded rows (the
+    kernel interpreted: what the chip runs since PR 48)."""
+    from dllama_tpu.obs import dispatch as obs_dispatch
+    monkeypatch.setenv("DLLAMA_FUSED_ATTN", read)
+    obs_dispatch.reset()
     cfg = tiny_lfm2_moe(head_dim=64)
     p = _init(cfg, seed=7)
     wanted = ref.np_forward_lfm2_moe({k: np.asarray(v) for k, v in p.items()},
@@ -637,6 +643,28 @@ def test_the_slot_path_at_heads_of_64_is_the_reference():
                                   jnp.asarray([n], jnp.int32), table)
         pos += n
         assert np.abs(np.asarray(lg)[0] - wanted[pos - 1]).max() < TOL, pos
+    sites = obs_dispatch.dispatches()
+    obs_dispatch.reset()
+    assert ("kv_dense/paged-fused" in sites) == (read == "interp"), sites
+    assert ("kv_dense/paged-gather" in sites) == (read == "off"), sites
+
+
+@pytest.mark.parametrize("head_dim,fused", [(64, True), (8, False)],
+                         ids=["rows-of-128-lanes", "rows-of-8-lanes"])
+def test_the_cost_model_asks_what_the_trace_asks(monkeypatch, head_dim, fused):
+    """``obs/cost.py`` files a paged step's attention under the family the
+    trace records: it hands ``_fused_choice`` the width of the pool's rows,
+    as ``paged_gqa_attention_at`` does, not the head size (heads of 64 two to
+    a row take the walk on a TPU; the toy's heads of 8, which ``pool_rows``
+    leaves a head a row, do not)."""
+    from dllama_tpu.obs import cost as obs_cost
+    cfg = tiny_lfm2_moe(head_dim=head_dim)
+    eng = Engine(cfg, _init(cfg, seed=7), mesh=_mesh(), batch=2, kv_pages=60,
+                 kv_page_size=4)
+    assert eng.cache.k.shape[-1] == (128 if fused else 8)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setenv("DLLAMA_FUSED_ATTN", "auto")
+    assert obs_cost.model_from_engine(eng).fused is fused
 
 
 def test_a_step_wider_than_the_state_ring_is_refused_by_name(params):

@@ -13,6 +13,8 @@ reason, and run in the test's own process.
 
 from __future__ import annotations
 
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -109,35 +111,45 @@ def test_q40_experts_matmul_compiles_at_olmoe_shapes(one_chip, name, n, d,
     assert f"f32[{E},{rows},{d}]" in text
 
 
-# (rows, query heads, kv heads, pages a slot): Llama-2-7B, and the two
-# served cells (Mistral-7B's GQA at 64 pages, OLMoE's MHA at 128).  Tokens a
-# slot: the pure-decode step's one, a verify block of spec_k + 1 = 5, the
-# mixed step's chunk of 16, and the widest block the rule takes (32 for the
-# served cells; 8 for Llama-2-7B, whose 32 kv heads make a chunk 4096 keys)
+# (rows, query heads, kv heads, pages a slot, head size): Llama-2-7B, and the
+# three served geometries (Mistral-7B's GQA at 64 pages, OLMoE's MHA at 128,
+# LFM2's heads of 64 two to a row of the pool).  Tokens a slot: the
+# pure-decode step's one, a verify block of spec_k + 1 = 5, the mixed step's
+# chunk of 16, and the widest block the rule takes (32 for Mistral and OLMoE;
+# 8 for Llama-2-7B, whose 32 kv heads make a chunk 4096 keys; 64 for LFM2,
+# whose token has 4 rows of keys in the pool)
 @pytest.mark.parametrize("t", [1, 5, 16, "widest"])
-@pytest.mark.parametrize("b,hq,hkv,maxp", [(4, 32, 32, 64), (16, 32, 8, 64),
-                                           (16, 16, 16, 128)],
-                         ids=["7b", "mistral-7b", "olmoe-1b-7b"])
+@pytest.mark.parametrize("b,hq,hkv,maxp,dh", [
+    (4, 32, 32, 64, 128), (16, 32, 8, 64, 128), (16, 16, 16, 128, 128),
+    (16, 32, 8, 128, 64)],
+    ids=["7b", "mistral-7b", "olmoe-1b-7b", "lfm2-24b-a2b"])
 def test_fused_paged_attention_compiles_at_served_geometry(one_chip, monkeypatch,
-                                                           b, hq, hkv, maxp, t):
-    dh, ps = 128, 16
+                                                           b, hq, hkv, maxp, dh,
+                                                           t):
+    ps = 16
     n_pages = 1 + b * maxp
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    widest = att._SCORE_TILE_MAX // (hq * att._WALK_PAGES * ps * hkv)
+    kvr, row = att.pool_rows(hkv, dh)
+    choice = lambda t: att._fused_choice(t, hq, hkv, dh, False, ps, maxp, row)[0]  # noqa: E731
+    widest = att._SCORE_TILE_MAX // (hq * att._WALK_PAGES * ps * kvr)
     if t == "widest":
         t = widest
-        assert att._fused_choice(t, hq, hkv, dh, False, ps, maxp)[0]
-        assert not att._fused_choice(t + 1, hq, hkv, dh, False, ps, maxp)[0]
+        assert choice(t) and not choice(t + 1)
     elif t > widest:
         # the rule keeps this width on the gather form here
-        assert not att._fused_choice(t, hq, hkv, dh, False, ps, maxp)[0]
+        assert not choice(t)
         return
     s = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)  # noqa: E731
-    pool = s((2, n_pages, ps, hkv, dh), jnp.bfloat16)
+    pool = s((2, n_pages, ps, kvr, row), jnp.bfloat16)
     text = jax.jit(att.fused_paged_attention).lower(
         s((b, hq, t, dh), jnp.bfloat16), pool, pool, s((), jnp.int32),
         s((b, maxp), jnp.int32), s((b,), jnp.int32)).compile().as_text()
     assert "tpu_custom_call" in text and "paged_attn_fused" in text
+    # the pool goes to the kernel as it lies: nothing of its extent is made
+    # (a folded pool's page is reshaped to (ps * rows, 128): a bitcast)
+    assert f"bf16[2,{n_pages},{ps},{kvr},{row}]" in text
+    assert not re.search(rf"= bf16\[2,{n_pages},[\d,]+\]\S* "
+                         r"(?!parameter|bitcast)[\w\-]+\(", text)
 
 
 def scope_of(path):
@@ -182,7 +194,6 @@ def _assert_pool_is_only_scattered(text, cfg, n_pages, ps):
     """Of every instruction of the compiled text whose result has the paged
     pool's full shape, the only ones that make a pool are the KV write's
     in-place scatter and the fusion that wraps it: no ``copy``."""
-    import re
 
     shape = f"[{cfg.n_layers},{n_pages},{ps},{cfg.n_kv_heads},{cfg.head_size}]"
     ops = re.findall(r"^\s*(?:ROOT )?%?([\w.\-]+) = (\S+) ([\w\-]+)\((.*)$",
@@ -218,7 +229,6 @@ def test_paged_slot_step_has_no_pool_copy(one_chip, monkeypatch, hkv):
     scatter's layout is the pool's resident one; with the offset between the
     head axes XLA copied the whole pool per layer here, and in and out of
     the program (PERF.md §6, PR 27)."""
-    import re
     import time
 
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
@@ -245,7 +255,6 @@ def test_one_stream_prefill_walks_live_blocks_without_a_slab(one_chip):
     extent (``[…,32768,128]``) — no chunk-major ``transpose``/``copy`` of
     the layer's K and V, which with the scan over all 32 chunks was 47.8 of
     the 74.8 ms program (PERF.md §6, PR 29)."""
-    import re
 
     from dllama_tpu.models import transformer as tf
 
@@ -299,7 +308,6 @@ def test_mixed_slot_step_keeps_q40_on_the_fused_kernel(one_chip, monkeypatch):
     every Q40 site is the fused kernel (row-blocked above 128 rows), none the
     XLA path, so no weight is written to HBM as bf16 (a ``convert``-rooted
     fusion under ``w13``/``w2``, 2.67 of 4.62 device seconds before PR 25)."""
-    import re
 
     from dllama_tpu.models.params import param_shapes
     from dllama_tpu.obs import dispatch as obs_dispatch
@@ -346,7 +354,6 @@ def test_olmoe_decode_slot_step_runs_the_experts_in_three_launches_a_layer(
     call sites in the layer loop's body, the strategy recorded is
     ``all-experts``, and no ``while`` (the scan over experts) or
     ``q40_mm_stacked`` launch is left under ``moe``."""
-    import re
 
     from dllama_tpu.io import mfile
     from dllama_tpu.models.config import tiny_config
@@ -484,7 +491,6 @@ def test_deepseek_v2_slot_steps_compile_over_a_latent_pool(one_chip, monkeypatch
     ``q40_mm_experts`` launches in the expert segment's loop body, no Q40 site
     takes the XLA path, and the only pool in the program is the two latent
     planes ``(L, P, 16, 512)`` and ``(L, P, 16, 64)``: nothing per head."""
-    import re
 
     from dllama_tpu.models import transformer as tf
     from dllama_tpu.models.config import tiny_deepseek2
@@ -651,7 +657,6 @@ def test_smallthinker_cell_programs_compile_at_published_widths(one_chip, monkey
     site takes the XLA path, the rings
     are 4608 positions beside full planes of 16384, and neither kind of plane
     is copied whole."""
-    import re
 
     from dllama_tpu.models import transformer as tf
     from dllama_tpu.obs import dispatch as obs_dispatch
@@ -694,7 +699,7 @@ def test_smallthinker_cell_programs_compile_at_published_widths(one_chip, monkey
             r"(\w+)\(.*?op_name=\"([^\"]+)\"", text))
         for plane in ("bf16[1,1,4,16384,128]", "bf16[3,1,4,4608,128]"):
             assert plane in text
-            assert not re.search(r"= " + re.escape(plane) + r"\S* copy\(", text), plane
+            assert not re.search(r"= \(?" + re.escape(plane) + r"\S* copy(-start)?\(", text), plane
 
 
 # K-EXAONE's share (PR 40): the attention projections at a query width of 8192
@@ -787,7 +792,6 @@ def test_k_exaone_slot_steps_compile_over_the_pool_per_kind(one_chip, monkeypatc
     ``_SCORE_TILE_MAX``), the window layers read a ring of ten pages a slot,
     the 16 held experts are three launches a layer, no Q40 site takes the XLA
     path, and neither the pool nor the window planes are copied whole."""
-    import re
 
     from dllama_tpu.obs import dispatch as obs_dispatch
     from dllama_tpu.runtime.decode_loop import slot_chunk
@@ -821,7 +825,7 @@ def test_k_exaone_slot_steps_compile_over_the_pool_per_kind(one_chip, monkeypatc
     assert "paged_attn_fused" in text and "q40_mm_experts" in text
     for plane in ("bf16[2,1025,16,8,128]", "bf16[6,160,16,8,128]"):
         assert plane in text
-        assert not re.search(r"= " + re.escape(plane) + r"\S* copy\(", text), plane
+        assert not re.search(r"= \(?" + re.escape(plane) + r"\S* copy(-start)?\(", text), plane
 
 
 # ---------------------------------------------------------------------------
@@ -1044,10 +1048,9 @@ def _lfm2_programs(one_chip, monkeypatch, paged: bool, n_layers=8, slots=16,
 
 
 def _no_whole_copy(text, planes):
-    import re
     for plane in planes:
         assert plane in text, plane
-        assert not re.search(r"= " + re.escape(plane) + r"\S* copy\(", text), plane
+        assert not re.search(r"= \(?" + re.escape(plane) + r"\S* copy(-start)?\(", text), plane
 
 
 @pytest.mark.parametrize("t", [1, 16], ids=["pure-decode", "mixed"])
@@ -1056,20 +1059,26 @@ def test_lfm2_slot_steps_compile_with_the_state_beside_the_pool(one_chip,
     """The two step programs of ``lfm2-24b-a2b.decode-heavy`` for the
     described chip, two periods of layers: six conv operators over the slots'
     state ring (one ``conv/ring`` site each a body), the two attention layers'
-    paged read in the gather form (heads of 64: ``_fused_choice`` wants 128
-    lanes), 64 experts in three launches a layer, no Q40 site on the XLA path,
-    and neither the pool nor the state copied whole."""
+    paged read the fused walk over the folded pool (heads of 64 two to a row
+    of 128 lanes: ``_fused_choice`` takes a pool whose rows fill whole lanes,
+    and not one that keeps such a head a row), 64 experts in three launches a
+    layer, no Q40 site on the XLA path, and neither the pool nor the state
+    copied whole, nor a slot's table gathered."""
     from dllama_tpu.obs import dispatch as obs_dispatch
     from dllama_tpu.ops import conv
     from dllama_tpu.runtime.decode_loop import slot_chunk
 
-    cfg, params, cache, s = _lfm2_programs(one_chip, monkeypatch, paged=True)
+    # a pool over VMEM's 128 MiB, as the cell's is at its eight attention
+    # layers (one that fits is prefetched there whole, which is not the subject)
+    cfg, params, cache, s = _lfm2_programs(one_chip, monkeypatch, paged=True,
+                                           pages=POOL_PAGES)
     assert cfg.prefill_chunk() == 1024
     # the attention layers' pool, heads of 64 two to a row of 128 lanes: as
-    # (2, 2056, 16, 8, 64) the chip's compact layout puts the pages minor-most
+    # (2, 4097, 16, 8, 64) the chip's compact layout puts the pages minor-most
     # and both planes are copied whole, twice a step
-    assert cache.k.shape == (2, 2056, 16, 4, 128)
+    assert cache.k.shape == (2, POOL_PAGES, 16, 4, 128)
     assert cache.cz.shape == (6, 16, 1, conv.RING, 2048)      # the slots' state
+    assert att._fused_choice(t, 32, 8, 64, ps=16, maxp=128, row=128) == (True, False)
     assert att._fused_choice(t, 32, 8, 64, ps=16, maxp=128) == (False, False)
     b = 16
     obs_dispatch.reset()
@@ -1089,13 +1098,20 @@ def test_lfm2_slot_steps_compile_with_the_state_beside_the_pool(one_chip,
     # scanned: 3 + 3 conv sites, 1 + 1 attention sites, 2 + 4 expert layers'
     bodies = len(packing.buckets(b * t)) if t > 1 else 1
     assert sites.get("conv/ring") == 6, sites
-    assert sites.get("kv_dense/paged-gather") == 2, sites
+    assert sites.get("kv_dense/paged-fused") == 2, sites
     assert sites.get("moe/all-experts") == (2 + 4) * bodies, sites
-    assert "q40/xla-dequant" not in sites and "kv_dense/paged-fused" not in sites, sites
+    assert "q40/xla-dequant" not in sites and "kv_dense/paged-gather" not in sites, sites
     assert "q40_mm_experts" in text and "q40_mm_stacked" in text
+    # one launch at each attention layer's site (the unrolled period's and the
+    # scanned body's), and no (B, Hkv, maxp * ps, Dh) view of a slot's table
+    assert len(re.findall(r"custom-call\(.*paged_attn_fused", text)) == 2
+    assert "[16,8,2048,64]" not in text
     for part in ("qkv/conv", "kv_write/conv", "attn/conv", "wo/conv"):
         assert part in text, part
-    _no_whole_copy(text, ("bf16[2,2056,16,4,128]", f"bf16[6,16,1,{conv.RING},2048]"))
+    _no_whole_copy(text, (f"bf16[2,{POOL_PAGES},16,4,128]",
+                          f"bf16[6,16,1,{conv.RING},2048]"))
+    # the walk's view of a page, (ps * rows, 128), is a bitcast of the pool
+    assert not re.search(rf"= bf16\[2,{POOL_PAGES},64,128\]\S* (?!bitcast)", text)
 
 
 def test_lfm2_one_stream_programs_compile_at_published_widths(one_chip, monkeypatch):
@@ -1104,7 +1120,6 @@ def test_lfm2_one_stream_programs_compile_at_published_widths(one_chip, monkeypa
     state ring: the rows that end at the prompt's last token are written) and
     the 16-step decode chunk (``select-chosen``: three ``q40_mm_chosen``
     launches an expert layer over the row's 4 experts)."""
-    import re
 
     from dllama_tpu.models import transformer as tf
     from dllama_tpu.obs import dispatch as obs_dispatch

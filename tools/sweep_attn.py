@@ -14,19 +14,23 @@ without the walk: ``--repo <parent checkout>``); the widths go through
 this tool gave); judge a candidate in the cell, not here.
 
 ``--paged`` times the served steps' read, ``paged_gqa_attention_at``'s two
-dense-pool arms, at the two served geometries (Mistral-7B: 32 query and 8 KV
-heads, 32 layers; OLMoE-1B-7B: 16 and 16, 16 layers; heads of 128, 16 slots,
-a 64-page table of 16-token pages out of the cell's pool): ``fused``
-(``fused_paged_attention``, kernel ``paged_attn_fused``) and ``gather``
-(``paged_gather_layer`` + ``_rows_ceiling_attention``) at ``t`` 1 and 16 and
-a live context of 256 and 1024 tokens a slot (``--ts`` for other widths),
-inside one jitted loop over the layers, each checked against the gather form
-(``rel_err``).  ``auto`` is the program's own choice, with the ledger path it
-recorded.  PERF.md §6, PR 37 has the table, and the two per-kv-head reads of
-the chunk buffer that were timed against the kept body and not kept.
+dense-pool arms, at the served geometries (Mistral-7B: 32 query and 8 KV
+heads of 128, 32 layers; OLMoE-1B-7B: 16 and 16 of 128, 16 layers, both over
+a 64-page table; LFM2-24B-A2B: 32 and 8 heads of 64, 8 attention layers, a
+128-page table, the pool stored two heads to a row as ``pool_rows`` says; 16
+slots, 16-token pages out of the cell's pool; ``--geo`` picks some):
+``fused`` (``fused_paged_attention``, kernel ``paged_attn_fused``) and
+``gather`` (``paged_gather_layer`` + ``_rows_ceiling_attention``) at ``t`` 1
+and 16 and a live context of 256 and 1024 tokens a slot (``--ts``, ``--lives``
+for others), inside one jitted loop over the layers, each checked against the
+gather form (``rel_err``).  ``auto`` is the program's own choice, with the
+ledger path it recorded.  PERF.md §6, PR 37 has the table, and the two
+per-kv-head reads of the chunk buffer that were timed against the kept body
+and not kept; PR 48 has the folded pool's.
 
 Usage: python tools/sweep_attn.py [--repo DIR] [--blocks 256,512,1024]
-       python tools/sweep_attn.py --paged [--repo DIR]
+       python tools/sweep_attn.py --paged [--repo DIR] [--geo lfm2-24b-a2b]
+                                  [--ts 1,16] [--lives 416,1024,2048]
 """
 
 from __future__ import annotations
@@ -43,10 +47,12 @@ HQ, HKV, DH, LAYERS, S = 32, 8, 128, 32, 32768
 POINTS = [(256, 0), (128, 0), (64, 0), (256, 16384), (16, 300), (1, 300)]
 
 
-# (name, query heads, kv heads, layers, pool pages): the two served cells
-PAGED_GEOMETRIES = [("mistral-7b", 32, 8, 32, 1032),
-                    ("olmoe-1b-7b", 16, 16, 16, 2056)]
-PAGED_SLOTS, PAGED_TABLE, PAGE = 16, 64, 16
+# (name, query heads, kv heads, head size, layers, pool pages, table pages):
+# the served cells' reads
+PAGED_GEOMETRIES = [("mistral-7b", 32, 8, 128, 32, 1032, 64),
+                    ("olmoe-1b-7b", 16, 16, 128, 16, 2056, 64),
+                    ("lfm2-24b-a2b", 32, 8, 64, 8, 2056, 128)]
+PAGED_SLOTS, PAGE = 16, 16
 
 
 def _median_ms(run, args, reps):
@@ -69,26 +75,32 @@ def sweep_paged(a, att) -> list[dict]:
     from dllama_tpu.obs import dispatch as obs_dispatch
 
     def gather(q, pk, pv, layer, table, pos):
+        dh = q.shape[-1]
         return att._rows_ceiling_attention(
-            q, att.paged_gather_layer(pk, layer, table),
-            att.paged_gather_layer(pv, layer, table), pos)
+            q, att.paged_gather_layer(pk, layer, table, dh=dh),
+            att.paged_gather_layer(pv, layer, table, dh=dh), pos)
 
     forms = {"auto": att.paged_gqa_attention_at, "gather": gather,
              "fused": att.fused_paged_attention}
-    b, maxp, ps = (2, 8, PAGE) if a.rehearse else (PAGED_SLOTS, PAGED_TABLE, PAGE)
+    b, ps = (2, PAGE) if a.rehearse else (PAGED_SLOTS, PAGE)
+    lives = [int(x) for x in a.lives.split(",")] if a.lives else \
+        (32, 64) if a.rehearse else (256, 1024)
     results = []
-    for geo, hq, hkv, layers, n_pages in PAGED_GEOMETRIES:
+    for geo, hq, hkv, dh, layers, n_pages, maxp in PAGED_GEOMETRIES:
+        if a.geo and geo not in a.geo.split(","):
+            continue
         if a.rehearse:
-            layers, n_pages = 2, 1 + b * maxp
+            layers, maxp = 2, 8
+            n_pages = 1 + b * maxp
         kk, kv, kq = jax.random.split(jax.random.PRNGKey(0), 3)
-        shape = (layers, n_pages, ps, hkv, DH)
+        shape = (layers, n_pages, ps) + att.pool_rows(hkv, dh)
         pk = jax.random.normal(kk, shape, jnp.bfloat16)
         pv = jax.random.normal(kv, shape, jnp.bfloat16)
         table = jnp.asarray(np.random.RandomState(0).permutation(
             np.arange(1, n_pages))[:b * maxp].reshape(b, maxp), jnp.int32)
         for t in (int(x) for x in a.ts.split(",")):
-            q = jax.random.normal(kq, (b, hq, t, DH), jnp.bfloat16)
-            for ctx in ((32, 64) if a.rehearse else (256, 1024)):
+            q = jax.random.normal(kq, (b, hq, t, dh), jnp.bfloat16)
+            for ctx in lives:
                 # every slot's last query token is the context's last
                 pos = jnp.full((b,), ctx - t, jnp.int32)
                 ref = None
@@ -135,6 +147,9 @@ def main() -> None:
     ap.add_argument("--paged", action="store_true",
                     help="the paged pool's reads: fused page walk vs gather")
     ap.add_argument("--ts", default="1,16", help="--paged: query tokens a slot")
+    ap.add_argument("--lives", default="",
+                    help="--paged: live contexts a slot, in tokens")
+    ap.add_argument("--geo", default="", help="--paged: geometries, by name")
     ap.add_argument("--blocks", default="256,512,1024")
     ap.add_argument("--reps", type=int, default=5)
     ap.add_argument("--rehearse", action="store_true",
