@@ -56,6 +56,7 @@ MOE_MODEL, MOE_LAYERS = "olmoe-1b-7b", 2
 MOE_TOL = 2e-2             # max |pallas - xla| / max |xla| of a layer's moe_ffn
 BUDGET_S = 1150            # the driver allows 1200 s, compilation included
 Q40_TOL = 1e-2             # max |pallas - xla| / max |xla|
+Q40_F32_TOL = 5e-6         # the one-row (grouped) body against x @ dequantize(float32)
 ATTN_TOL = 2e-2            # max |fused - gather| / max |gather| (bf16 out)
 TP_LOGIT_TOL = 5e-2        # max |tp4 - tp1| / max |tp1| on first-step logits
 CHILD_MARK = "CHIP_SMOKE "  # prefix of the result lines a child prints
@@ -488,9 +489,12 @@ def child_kernels(rehearse: bool) -> None:
     D, H, V = cfg.dim, cfg.hidden_dim, cfg.vocab_size
     qkv = (cfg.n_heads + 2 * cfg.n_kv_heads) * cfg.head_size
     # (name, n, d, stacked) — as the single-chip loader lays the model out
+    # moe_w2: DeepSeek-V2's expert width, one whole-axis tile of 44
+    # quantization blocks: the one-row body folds its partials at every step
+    # there (q40._partial_rows), on eight sublanes at the other shapes
     shapes = [("wqkv", D, qkv, True), ("wo", D, D, True),
               ("w13", D, 2 * H, True), ("w2", H, D, True),
-              ("wcls", D, V, False)]
+              ("wcls", D, V, False), ("moe_w2", 1408, D, True)]
     key = jax.random.PRNGKey(0)
 
     def rel_err(a_, b_):
@@ -522,6 +526,16 @@ def child_kernels(rehearse: bool) -> None:
                   "stacked": stacked, "rel_err": rel_err(got, ref),
                   "tol": Q40_TOL,
                   "seconds": round(time.perf_counter() - t0, 2)})
+            if q40._body(rows) == "grouped":
+                # one row is contracted a quantization block at a time with no
+                # weight rounded to bf16 (PR 50): it is held to the float32
+                # dequantization
+                dense = q40.dequantize(w.sliced() if stacked else w)
+                ref32 = jnp.dot(x.astype(jnp.float32), dense,
+                                precision=jax.lax.Precision.HIGHEST)
+                _say({"kernel": f"q40.{name}.f32", "shape": [n, d], "rows": rows,
+                      "stacked": stacked, "rel_err": rel_err(got, ref32),
+                      "tol": Q40_F32_TOL})
 
     # a decoded row's chosen experts in one launch (q40_mm_chosen), at
     # SmallThinker's gate (6 of a layer's 64 experts of 2560 x 768, one row;

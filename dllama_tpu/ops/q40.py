@@ -30,10 +30,19 @@ Two matmul implementations:
   to ``PALLAS_MAX_ROWS`` rows ride in one block (decode), more rows (the
   served mixed step, prefill buckets) in row blocks of up to
   ``ROW_BLOCK_MAX`` (:func:`_row_block`), each weight tile unpacked once
-  per block, to logical row order, and contracted in one dot against the
-  activation block ``(rows, tile_n)`` in the model's own column order (no
-  op stands between the caller's ``x`` and the launch but a cast and, for a
-  padded ``n``, the zero columns; PERF.md §6, PR 41).  A mixture-of-experts
+  per block and contracted against the activation block ``(rows, tile_n)``
+  in the model's own column order (no op stands between the caller's ``x``
+  and the launch but a cast and, for a padded ``n``, the zero columns;
+  PERF.md §6, PR 41).  How the tile is contracted follows the block's row
+  count, the one fact the kernel observes (:func:`_body`, PR 50): two rows
+  and more are one dot against the tile dequantized to bf16 in logical row
+  order; ONE row (every decoded token of a one-stream
+  program, a row's chosen experts) sends the raw nibbles to the dot a
+  quantization block at a time and scales the block partials, so no weight
+  is biased, scaled or rounded one by one (19% faster a launch at Mistral's
+  ``w13``, 13-15% at a row's chosen experts).  What bounds the
+  dot body at few rows is the VPU's work a weight on the way to the dot,
+  not the MXU's tile loads and not the DMA.  A mixture-of-experts
   layer's E experts, or the k a decoded row chose, are one launch a matmul
   (:func:`matmul_experts`, ``q40_mm_experts`` / ``q40_mm_chosen``): the
   expert index is a grid axis of the same kernel.  A `pallas_call` is not auto-partitioned by GSPMD, so
@@ -441,33 +450,123 @@ def _shard_nd(np_: int, d: int, kind: str | None, tp: int) -> tuple[int, int]:
     return np_, d
 
 
-def _site(np_: int, d: int, kind: str | None, tp: int) -> dict:
-    """What a kernel call site adds to its dispatch record: the tp slicing,
-    the tile pair its shard got and the stored input dim."""
-    return dict(kind=kind, tp=tp, stored_n=np_,
-                tiles=_tiles(*_shard_nd(np_, d, kind, tp)))
+def _record_site(rows: int, np_: int, d: int, kind: str | None, tp: int,
+                 **ctx) -> None:
+    """A kernel call site's two dispatch records: ``q40/pallas-fused`` with
+    the tp slicing, the tile pair its shard got, the stored input dim and the
+    ``body`` that contracts the tile, and ``q40_body/grouped|dot``, the
+    counter that says which body a compiled site took (:func:`_body`)."""
+    tiles = _tiles(*_shard_nd(np_, d, kind, tp))
+    body = _body(rows)
+    obs_dispatch.record_dispatch("q40", "pallas-fused", rows=rows, kind=kind,
+                                 tp=tp, stored_n=np_, tiles=tiles, body=body,
+                                 **ctx)
+    obs_dispatch.record_dispatch("q40_body", body, rows=rows)
 
 
 # ---------------------------------------------------------------------------
 # Pallas fused kernel
 # ---------------------------------------------------------------------------
 
+def _body(rows: int) -> str:
+    """How a weight tile is contracted against a block of ``rows`` activation
+    rows, from the block's shape alone: ``"grouped"`` at one row, else
+    ``"dot"``.  The grouped form's left operand has ``tile_n / 32`` rows an
+    activation row, so it is a one-row form: against the dot body −19% at one
+    row of Mistral's ``w13``, −12% at two, +13% at four, and at a row's six
+    chosen experts −14%, −5%, +22%; no cell runs two to four rows
+    (tools/sweep_q40.py --body ... 1,2,4 dot,grouped; PERF.md §6, PR 50)."""
+    return "grouped" if rows == 1 else "dot"
+
+
+def _partial_rows(tile_n: int) -> int:
+    """The sublanes on which the one-row body keeps a column's block partials
+    across the reduction steps: eight (whole vregs, added as they are and
+    folded once, at the last step) where the tile's quantization blocks are
+    whole groups of eight, which every tile the rule gives a benchmark's model
+    is; else one, folded at every step (a whole-axis tile of 44 blocks,
+    DeepSeek-V2's expert width, or a toy shard's 4: 2-6% a launch slower where
+    the sweep folded a short tile's every step, PERF.md §6, PR 50).  Both are
+    the grouped body: which one is the accumulator's business, not the
+    result's."""
+    return 8 if tile_n % 256 == 0 else 1
+
+
+def _contract_grouped(x_ref, vi, s32) -> jax.Array:
+    """The tile against the block's one row, a quantization block at a time:
+    ``sum_b s[b, d] * (sum_{i in b} x[i] * v[i, d] - 8 * sum_{i in b} x[i])``
+    over the tile's blocks ``b``, ``v`` the raw nibbles.  Scale and bias are
+    paid once a block partial (1/32 of a weight) and no weight is rounded: a
+    nibble and a bf16 activation are exact operands of the dot and their
+    products exact in its f32 sums, so the result is ``x @ dequantize(qt,
+    float32)`` up to summation order.
+
+    The inner sums of all ``nb`` blocks come out of ONE dot: its left operand
+    holds, in row ``b``, the activation row at block ``b``'s 32 columns and
+    zero elsewhere (block-diagonal), so row ``b`` of the product is block
+    ``b``'s partial sum.  Returns the block partials summed onto
+    :func:`_partial_rows` sublanes, which the caller accumulates over the
+    reduction steps and folds at the last."""
+    nb, td = s32.shape
+    tn = 32 * nb
+    lo = (vi & 0xF).astype(jnp.bfloat16).reshape(nb, 16, td)   # 0..15: exact
+    hi = (vi >> 4).astype(jnp.bfloat16).reshape(nb, 16, td)
+    w = jnp.concatenate([lo, hi], axis=1).reshape(tn, td)  # logical row order
+    own = (jax.lax.broadcasted_iota(jnp.int32, (nb, tn), 1) >> 5
+           == jax.lax.broadcasted_iota(jnp.int32, (nb, tn), 0))
+    xd = jnp.where(own, jnp.broadcast_to(x_ref[:].astype(jnp.float32),
+                                         (nb, tn)), 0.0)
+    p = jnp.dot(xd.astype(jnp.bfloat16), w,
+                preferred_element_type=jnp.float32)     # (nb, td)
+    p = (p - 8.0 * xd.sum(axis=1, keepdims=True)) * s32
+    if _partial_rows(tn) == 1:
+        return p.sum(axis=0, keepdims=True)
+    return p.reshape(nb // 8, 8, td).sum(axis=0)
+
+
+def _dequant_bf16(vi, s32) -> tuple[jax.Array, jax.Array]:
+    """The tile's lo and hi nibble planes dequantized, ``(nb, 16, td)`` bf16
+    each: a weight is ``bf16(f32(v−8)·s)``."""
+    nb, td = s32.shape
+    lo = ((vi & 0xF).astype(jnp.float32) - 8.0).reshape(nb, 16, td)
+    hi = ((vi >> 4).astype(jnp.float32) - 8.0).reshape(nb, 16, td)
+    lo = (lo * s32[:, None, :]).astype(jnp.bfloat16)
+    hi = (hi * s32[:, None, :]).astype(jnp.bfloat16)
+    return lo, hi
+
+
+def _contract_dot(x_ref, vi, s32) -> jax.Array:
+    """The dequantized tile against every row of the block in one dot: the
+    two planes of each 32-row quantization block are set one above the other
+    (logical row order) after the cast, where a piece of 16 rows × 128 lanes
+    is exactly one vreg tile: Mosaic emits no op for the placement (the
+    lowered kernel has the op counts of a body with a dot a plane)."""
+    nb, td = s32.shape
+    lo, hi = _dequant_bf16(vi, s32)
+    w = jnp.concatenate([lo, hi], axis=1).reshape(32 * nb, td)
+    return jnp.dot(x_ref[:], w, preferred_element_type=jnp.float32)
+
+
 def _q40_kernel(x_ref, qp_ref, s_ref, o_ref, acc_ref, *, nsteps, n_axis=1):
     """One (tile_n × tile_d) fused dequant-matmul step: the weight tile is
-    unpacked once, to logical row order, and contracted in one dot against
-    every activation row of the block (all rows, or one row block of the
-    row-blocked form, whose reduction axis is grid axis ``n_axis`` = 2).
+    unpacked once and contracted against every activation row of the block
+    (all rows, or one row block of the row-blocked form, whose reduction axis
+    is grid axis ``n_axis`` = 2) by the body :func:`_body` names.  The
+    activation block is ``(rows, tile_n)`` in the model's own column order.
 
-    Dequantization is ``bf16(f32(v−8)·s)`` per weight: the reference's
-    rounding (one bf16 round of the exact product, funcs.cpp:330-335
-    semantics), the same on every tp shard and in the XLA path.  The
-    activation block is ``(rows, tile_n)`` in the model's own column order:
-    the lo and hi nibble planes of each 32-row quantization block are set
-    one above the other after the bf16 cast, where a piece of 16 rows × 128
-    lanes is exactly one vreg tile: Mosaic emits no op for the placement
-    (the lowered kernel has the op counts of a body with a dot a plane).
-    VPU unpack work (~5.5 ops/weight) is the decode bottleneck after DMA.
-    """
+    At two rows and more (:func:`_contract_dot`) dequantization
+    is ``bf16(f32(v−8)·s)`` per weight: the reference's rounding (one bf16
+    round of the exact product, funcs.cpp:330-335 semantics), the same on
+    every tp shard and in the XLA path at more than one row.  The VPU's work
+    on the way to the dot (~5.5 ops a weight: mask or shift, convert, bias,
+    scale, cast) bounds that body at few rows, not the MXU's tile loads (the
+    dot issued twice on the same tile costs a fifth more, the dot over half
+    of it saves 1%) and not the DMA (PERF.md §6, PR 50).
+
+    At one row (:func:`_contract_grouped`) the raw nibbles go to the dot and
+    bias and scale are applied to the block partials it returns.  This is no
+    lower precision: no weight is rounded to bf16, and the result is the f32
+    dequantization's up to summation order."""
     i = pl.program_id(n_axis)
     qp = qp_ref[...]                                      # (tn/2, td) uint8
     tn2, td = qp.shape[-2:]
@@ -476,12 +575,8 @@ def _q40_kernel(x_ref, qp_ref, s_ref, o_ref, acc_ref, *, nsteps, n_axis=1):
     sbits = s_ref[...].reshape(nb, td)                    # uint16 f16 bits
     s32 = _f16_bits_to_f32(sbits)                         # (nb, td) f32, exact
     vi = qp.astype(jnp.int32)
-    lo = ((vi & 0xF).astype(jnp.float32) - 8.0).reshape(nb, 16, td)
-    hi = ((vi >> 4).astype(jnp.float32) - 8.0).reshape(nb, 16, td)
-    lo = (lo * s32[:, None, :]).astype(jnp.bfloat16)
-    hi = (hi * s32[:, None, :]).astype(jnp.bfloat16)
-    w = jnp.concatenate([lo, hi], axis=1).reshape(2 * tn2, td)
-    part = jnp.dot(x_ref[:], w, preferred_element_type=jnp.float32)
+    grouped = _body(x_ref.shape[0]) == "grouped"
+    part = (_contract_grouped if grouped else _contract_dot)(x_ref, vi, s32)
 
     @pl.when(i == 0)
     def _():
@@ -493,7 +588,10 @@ def _q40_kernel(x_ref, qp_ref, s_ref, o_ref, acc_ref, *, nsteps, n_axis=1):
 
     @pl.when(i == nsteps - 1)
     def _():
-        o_ref[:] = acc_ref[:]
+        if grouped:  # a row's partials lie on _partial_rows sublanes: one reduce
+            o_ref[:] = acc_ref[:].reshape(x_ref.shape[0], -1, td).sum(axis=1)
+        else:
+            o_ref[:] = acc_ref[:]
 
 
 def _stacked_q40_kernel(lidx_ref, x_ref, qp_ref, s_ref, o_ref, acc_ref, **kw):
@@ -575,7 +673,9 @@ def _mm_call(t: int, n: int, d: int, tile_n: int, tile_d: int,
         out_specs=pl.BlockSpec(
             ex(experts, None) + (tb, tile_d),
             at(lambda r, e, j, i, *l: ex(experts, e) + (r, j)), **ms),
-        scratch_shapes=[pltpu.VMEM((tb, tile_d), jnp.float32)])
+        scratch_shapes=[pltpu.VMEM(
+            (tb * (_partial_rows(tile_n) if _body(tb) == "grouped" else 1), tile_d),
+            jnp.float32)])
     return grid_kw, params, dict(nsteps=nn, n_axis=len(grid) - 1)
 
 
@@ -1080,10 +1180,8 @@ def matmul_experts(x: jax.Array, qt: QLayerView, experts: int, impl: str,
     read.  The view's ``layer`` indexes the lead dims in front of the expert
     axis."""
     x = _pad_x(x, qt.logical_nd[0], qt.qt.qpacked.shape[-2] * 2)
-    obs_dispatch.record_dispatch(
-        "q40", "pallas-fused", rows=x.shape[-2],
-        experts=experts if chosen is None else len(chosen),
-        **_site(x.shape[-1], qt.logical_nd[1], None, 1))
+    _record_site(x.shape[-2], x.shape[-1], qt.logical_nd[1], None, 1,
+                 experts=experts if chosen is None else len(chosen))
     out = _pallas_matmul_experts(x, *qt.flat_planes(), qt.layer,
                                  experts=experts, chosen=chosen,
                                  interpret=impl == "pallas_interpret")
@@ -1127,8 +1225,7 @@ def matmul(x: jax.Array, qt: QTensor | QLayerView, impl: str = "auto",
                 shape=(np_, d), kind=kind, tp=tp)
         else:
             x2 = _pad_x(x.reshape(rows, n), n, np_)
-            obs_dispatch.record_dispatch("q40", "pallas-fused", rows=rows,
-                                         **_site(np_, d, kind, tp))
+            _record_site(rows, np_, d, kind, tp)
             if view:
                 (qp, s), layer = qt.flat_planes(), qt.layer
             else:

@@ -1843,10 +1843,13 @@ class Engine:
         position — speculation only changes how many positions one
         dispatch verifies.  Hardware caveat: the ``T=k+1`` forward may
         reduce bf16 matmuls in a different order than the ``T=1`` decode
-        forward, so an argmax near-tie can resolve differently on a real
+        forward, and on packed Q40 weights the fused kernel contracts a
+        row that is alone in its block against weights no one rounded
+        to bf16 (ops/q40.py ``_body``: logits 1e-3 of the largest apart),
+        so an argmax near-tie can resolve differently on a real
         chip; both streams are valid greedy decodes of the model, but
         bit-identity across the two is only guaranteed where reduction
-        order matches.
+        order and weight rounding match.
         """
         return list(self.generate_pld_stream(prompt_tokens, steps,
                                              ngram=ngram, k=k,

@@ -55,6 +55,28 @@ def write_tiny_model(path, *, arch=mfile.ARCH_LLAMA, ftype=quants.Q80,
     return spec
 
 
+def bf16_exact_scales(tree):
+    """``tree`` with the f16 scales of its Q40 tensors cut to four significant
+    bits, so that every weight ``(v − 8) · s`` (four bits times four) is exact
+    in bf16.  The dot body and the XLA path round each weight to bf16, the
+    one-row body rounds none (PR 50): on such weights the rounding changes
+    nothing and all three compute the same function up to the order of their
+    sums, so a test may hold a decoded row on the fused kernel against the XLA
+    path, or one shard against several, as tightly as it holds many rows."""
+    import dataclasses
+
+    import jax
+
+    from dllama_tpu.ops import q40
+
+    def is_q40(t):
+        return isinstance(t, q40.QTensor)
+
+    return jax.tree.map(
+        lambda t: dataclasses.replace(t, scales=t.scales & np.uint16(0xFF80))
+        if is_q40(t) else t, tree, is_leaf=is_q40)
+
+
 def kernel_bodies(lowered_text: str) -> list[bytes]:
     """The serialized Mosaic module of every Pallas kernel in a TPU
     lowering's StableHLO text (``custom_call_config.body`` of each

@@ -154,6 +154,30 @@ def test_dispatch_paths_recorded():
     assert "q40/xla-dequant" in obs_dispatch.summary_line()
 
 
+@pytest.mark.parametrize("rows,body", [(1, "grouped"), (16, "dot")])
+def test_q40_site_records_the_body_and_the_path_share_ignores_it(rows, body):
+    """A fused Q40 call site says which body contracts its tile (PR 50):
+    ``q40_body/grouped`` at one row, ``q40_body/dot`` at 16, beside its
+    ``q40/pallas-fused`` record.  The codec is not ``q40``, so the benchmark's
+    ``pallas_path_pct`` reader counts the site once, not twice."""
+    import importlib.util
+    import jax.numpy as jnp
+    qt = _q40_fixture(256, 128)
+    obs_dispatch.reset()
+    q40.matmul(jnp.ones((rows, 256), jnp.bfloat16), qt, impl="pallas_interpret")
+    sites = obs_dispatch.dispatches()
+    assert sites == {"q40/pallas-fused": 1, f"q40_body/{body}": 1}
+    assert obs_metrics.MATMUL_DISPATCH.get("q40_body", body) >= 1
+    assert f"q40_body/{body}" in obs_dispatch.summary_line()
+    spec = importlib.util.spec_from_file_location("pallas_path_pct", os.path.join(
+        REPO, "benchmarks", "layer_metrics", "pallas_path_pct.py"))
+    reader = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(reader)
+    after = {"matmul_dispatch": {**sites, "q40/xla-dequant": 1}}
+    assert reader.read({"after": after}) == 50.0
+    obs_dispatch.reset()
+
+
 def test_engine_init_degrade_takes_the_ledger_path():
     """The engine-construction degrade — off-TPU tp collectives falling
     back to plain psum (``tp_psum``) — takes the ledger path: labeled

@@ -4,6 +4,8 @@ Mirrors the reference's kernel test strategy (funcs-test.cpp:18-60:
 quantized matmul vs F32 matmul within tolerance on random data) plus the
 N-shard ≡ 1-shard invariance pattern (commands-test.cpp:30-69)."""
 
+import os
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -15,6 +17,28 @@ from dllama_tpu.ops import q40
 
 def _rand(shape, seed=0, scale=0.1):
     return (np.random.RandomState(seed).randn(*shape) * scale).astype(np.float32)
+
+
+# How close the kernel comes to its reference, as a share of max |ref|.  At
+# two rows a block and more (the dot body) a weight is rounded to bf16 as in
+# the XLA path, which it equals up to summation order.  At one row (the grouped
+# body, PR 50) no weight is rounded: the reference is the float32
+# dequantization, and the bound is the f32 sums', 20 times tighter.
+DOT_TOL, GROUPED_TOL = 1e-4, 5e-6
+
+
+def _f32_reference(x, w) -> np.ndarray:
+    """``x @ dequantize(w, float32)``, summed in float64."""
+    dense = q40.dequantize(w.sliced() if isinstance(w, q40.QLayerView) else w)
+    return np.asarray(x, np.float64) @ np.asarray(dense, np.float64)
+
+
+def _reference(x, w):
+    """The reference of the body that ``x``'s rows take and its bound:
+    ``(ref, tol)``."""
+    if q40._body(x.shape[-2]) == "grouped":
+        return _f32_reference(x, w), GROUPED_TOL
+    return np.asarray(q40.matmul(x, w, impl="xla", out_dtype=jnp.float32)), DOT_TOL
 
 
 class TestFormat:
@@ -392,8 +416,11 @@ def test_kernel_matches_xla_at_the_rules_new_tiles(form, n, d):
     np.testing.assert_allclose(got, ref, rtol=0, atol=1e-4 * np.abs(ref).max())
 
 
-def test_dispatch_record_carries_the_tile_pair_and_the_stored_n(caplog):
+def test_dispatch_record_carries_the_tile_pair_and_the_stored_n(caplog, monkeypatch):
     import logging
+    # an earlier test of this worker may have configured the program's logger
+    # (obs.log.configure stops its propagation, which caplog listens through)
+    monkeypatch.setattr(logging.getLogger("dllama"), "propagate", True)
     qt = q40.quantize(_rand((1536, 256), seed=5))
     x = jnp.asarray(_rand((2, 1536), seed=6, scale=1.0), jnp.bfloat16)
     with caplog.at_level(logging.DEBUG, logger="dllama"):
@@ -447,9 +474,8 @@ class TestRowBlocks:
         x, _, w = self._case(form, n, 384, rows)
         got = np.asarray(q40.matmul(x, w, impl="pallas_interpret",
                                     out_dtype=jnp.float32))
-        ref = np.asarray(q40.matmul(x, w, impl="xla", out_dtype=jnp.float32))
-        np.testing.assert_allclose(got, ref, rtol=0,
-                                   atol=1e-4 * np.abs(ref).max())
+        ref, tol = _reference(x, w)
+        np.testing.assert_allclose(got, ref, rtol=0, atol=tol * np.abs(ref).max())
 
     @pytest.mark.parametrize("form", ["plain", "stacked"])
     def test_ragged_last_block_is_masked(self, form):
@@ -548,7 +574,10 @@ class TestAutoChoice:
             try:
                 with pytest.raises(Exception):  # noqa: B017 — any lowering error
                     jax.block_until_ready(call())
-                assert obs_dispatch.dispatches() == {f"{codec}/pallas-fused": 1}
+                # a Q40 site also says which body its block's rows took
+                body = {"q40_body/grouped": 1} if codec == "q40" else {}
+                assert obs_dispatch.dispatches() == {f"{codec}/pallas-fused": 1,
+                                                     **body}
                 assert obs_dispatch.degraded() is False
             finally:
                 obs_dispatch.reset()
@@ -689,15 +718,20 @@ def test_f16_bits_to_f32_exhaustive():
     np.testing.assert_array_equal(got, exp)
 
 
-def test_extreme_scales_roundtrip_through_kernel():
+@pytest.mark.parametrize("n", [64, 256])
+def test_extreme_scales_roundtrip_through_kernel(n):
     """Scales at the f16 extremes — subnormal deltas (tiny weights) and
     near-max deltas (|w| up to ~524k pre-clamp) — must dequantize exactly
     through the uint16 bit path in both the XLA and interpret-kernel
-    implementations."""
+    implementations.  One row takes the grouped body (a tile of two
+    quantization blocks or of eight), whose bias term ``8 * sum(x)`` cancels in
+    f32: its error against the float32 reference is under GROUPED_TOL over the
+    tiny blocks alone, over the huge ones alone and over both, and no larger
+    than the dot body's on the same input."""
     rng = np.random.RandomState(0)
-    w = rng.randn(64, 128).astype(np.float32)
-    w[:32] *= 1e-7          # subnormal f16 deltas (amax/8 < 6.1e-5)
-    w[32:] *= 5e4           # deltas near the f16 normal range top
+    w = rng.randn(n, 128).astype(np.float32)
+    w[:n // 2] *= 1e-7      # subnormal f16 deltas (amax/8 < 6.1e-5)
+    w[n // 2:] *= 5e4       # deltas near the f16 normal range top
     qt = q40.quantize(w)
     assert qt.scales.dtype == jnp.uint16
     dq = np.asarray(q40.dequantize(qt))
@@ -707,15 +741,129 @@ def test_extreme_scales_roundtrip_through_kernel():
     lo = (v & 0xF) - 8
     hi = (v >> 4) - 8
     dense = np.concatenate(
-        [lo.reshape(2, 16, 128), hi.reshape(2, 16, 128)], axis=1
-    ).reshape(64, 128) * np.repeat(sc, 32, axis=0)
+        [lo.reshape(n // 32, 16, 128), hi.reshape(n // 32, 16, 128)], axis=1
+    ).reshape(n, 128) * np.repeat(sc, 32, axis=0)
     np.testing.assert_array_equal(dq, dense.astype(np.float32))
 
-    x = _rand((1, 64), seed=1, scale=1.0)
+    x = _rand((1, n), seed=1, scale=1.0)
     ref = x @ dq
     out = np.asarray(q40.matmul(jnp.asarray(x), qt, impl="pallas_interpret"))
     np.testing.assert_allclose(out, ref, rtol=0,
                                atol=2e-2 * np.abs(ref).max() + 1e-12)
+    for keep in (slice(None), slice(0, n // 2), slice(n // 2, n)):
+        xk = np.zeros_like(x)
+        xk[:, keep] = x[:, keep]
+        xk = jnp.asarray(xk, jnp.bfloat16)
+        ref = _f32_reference(xk, qt)
+        one = np.asarray(q40._pallas_matmul(xk, qt.qpacked, qt.scales, interpret=True))
+        # the same row beside a second one takes the dot (bf16 weights)
+        two = np.asarray(q40._pallas_matmul(jnp.concatenate([xk, xk]), qt.qpacked,
+                                            qt.scales, interpret=True))[:1]
+        err = np.abs(one - ref).max()
+        assert err <= GROUPED_TOL * np.abs(ref).max(), (keep, err)
+        assert err <= np.abs(two - ref).max(), keep
+
+
+# ---- one row is contracted a quantization block at a time (PR 50) ----------
+
+# (n, d, tiles): an input dim stored padded (2752 -> 3072, three steps), a
+# ragged last d tile (320 in tiles of 256), several n steps at a forced tile,
+# and whole-axis tiles of 44 quantization blocks (DeepSeek-V2's expert width)
+# and of 3: no multiple of the eight sublanes the block partials lie on
+ONE_ROW_SHAPES = [(2752, 384, None), (512, 320, (256, 256)), (1024, 256, (256, 256)),
+                  (1408, 256, None), (96, 128, None)]
+
+
+@pytest.mark.parametrize("n,d,tiles", ONE_ROW_SHAPES, ids=lambda v: str(v))
+@pytest.mark.parametrize("form", ["flat", "stacked", "chosen", "chosen-x-an-expert"])
+def test_one_row_is_contracted_by_blocks_and_equals_the_f32_reference(form, n, d,
+                                                                      tiles):
+    """One row through each launch: the result is ``x @ dequantize(qt,
+    float32)`` within GROUPED_TOL, a bound the same row cannot meet on the dot
+    body (which rounds each weight to bf16: the row beside a second one)."""
+    experts, layer, picks = 3, 1, (2, 0, 2)
+    rng = np.random.default_rng(n + d)
+    lead = {"flat": (), "stacked": (2,)}.get(form, (2, experts))
+    qt = q40.quantize(rng.standard_normal((*lead, n, d)).astype(np.float32) * 0.1)
+    np_ = qt.qpacked.shape[-2] * 2
+    per_expert = form == "chosen-x-an-expert"
+    x = jnp.asarray(rng.standard_normal(
+        ((len(picks),) if per_expert else ()) + (1, n)), jnp.bfloat16)
+    xp = q40._pad_x(x, n, np_)
+
+    def launch(xp):
+        if form == "flat":
+            return q40._pallas_matmul(xp, qt.qpacked, qt.scales, interpret=True,
+                                      tiles=tiles)[None]
+        view = q40.QLayerView(qt, jnp.int32(layer))
+        if form == "stacked":
+            return q40._pallas_matmul_stacked(xp, *view.flat_planes(), view.layer,
+                                              interpret=True, tiles=tiles)[None]
+        return q40._pallas_matmul_experts(
+            xp, *view.flat_planes(), view.layer, experts=experts, interpret=True,
+            tiles=tiles, chosen=jnp.asarray(picks))
+
+    out = np.asarray(launch(xp))
+    two = np.asarray(launch(jnp.concatenate([xp, xp], axis=-2)))[..., :1, :]
+    planes = {"flat": [qt], "stacked": [q40.QLayerView(qt, jnp.int32(layer))]}.get(
+        form) or [q40.QLayerView(qt, jnp.int32(layer)).select(jnp.int32(e), experts)
+                  for e in picks]
+    assert out.shape == (len(planes), 1, d)
+    for j, w in enumerate(planes):
+        ref = _f32_reference(x[j] if per_expert else x, w)
+        err = np.abs(out[j] - ref).max() / np.abs(ref).max()
+        assert err <= GROUPED_TOL, (j, err)
+        assert np.abs(two[j] - ref).max() / np.abs(ref).max() > 10 * GROUPED_TOL
+
+
+@pytest.mark.parametrize("rows,body", [(1, "grouped"), (2, "dot"), (16, "dot"),
+                                       (256, "dot")])
+def test_the_blocks_rows_choose_the_body_and_the_ledger_says_which(rows, body, caplog,
+                                                                   monkeypatch):
+    """Nothing but the block's row count chooses: the ``q40_body`` counter and
+    the ``body=`` of the ``q40/pallas-fused`` record name the body, and the
+    kernel's jaxpr builds a block-diagonal left operand (from an iota) exactly
+    where they say ``grouped``."""
+    import logging
+    from dllama_tpu.obs import dispatch as obs_dispatch
+    monkeypatch.setattr(logging.getLogger("dllama"), "propagate", True)
+    assert q40._body(rows) == body
+    qt = q40.quantize(_rand((2, 512, 256), seed=2))
+    w = q40.QLayerView(qt, jnp.int32(1))
+    x = jax.ShapeDtypeStruct((rows, 512), jnp.bfloat16)
+    before = obs_dispatch.dispatches()
+    with caplog.at_level(logging.DEBUG, logger="dllama"):
+        jaxpr = jax.make_jaxpr(lambda x: q40.matmul(x, w, impl="pallas_interpret"))(x)
+    after = obs_dispatch.dispatches()
+    other = {"grouped": "dot", "dot": "grouped"}[body]
+    assert after.get(f"q40_body/{body}", 0) == before.get(f"q40_body/{body}", 0) + 1
+    assert after.get(f"q40_body/{other}", 0) == before.get(f"q40_body/{other}", 0)
+    rec, = [r for r in caplog.records if getattr(r, "path", None) == "pallas-fused"]
+    assert rec.body == body and rec.rows == rows
+    assert (" iota[" in str(jaxpr)) == (body == "grouped")
+
+
+@pytest.mark.parametrize("rows", [1, 2])
+@pytest.mark.parametrize("body", ["grouped", "vpu"])
+def test_the_sweeps_few_row_bodies_compute_the_matmul(body, rows):
+    """``tools/sweep_q40.py --body``'s forms the program does not run (the
+    grouped algebra at a few rows a block, and with its inner sums on the VPU)
+    are patched into the loaded module for a run: what the sweep times is the
+    matmul, within the grouped body's bound of the f32 reference, and the
+    module is the program's again afterwards."""
+    import importlib.util
+    spec = importlib.util.spec_from_file_location("sweep_q40", os.path.join(
+        os.path.dirname(os.path.dirname(__file__)), "tools", "sweep_q40.py"))
+    sweep = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(sweep)
+    qt = q40.quantize(_rand((768, 128), seed=9))
+    x = jnp.asarray(_rand((rows, 768), seed=10, scale=1.0), jnp.bfloat16)
+    with sweep._body_as(body):
+        assert q40._body(rows) == "grouped"
+        out = np.asarray(q40._pallas_matmul(x, qt.qpacked, qt.scales, interpret=True))
+    assert q40._body(2) == "dot" and q40._contract_grouped.__module__ == q40.__name__
+    ref = _f32_reference(x, qt)
+    assert np.abs(out - ref).max() <= GROUPED_TOL * np.abs(ref).max()
 
 
 def test_the_q40_knobs_stay_gone():
@@ -766,11 +914,10 @@ def test_experts_and_chosen_launches_take_whole_x_and_match_xla(form, per_expert
         chosen=jnp.asarray(picks) if form == "chosen" else None)
     assert out.shape == (len(picks), rows, d)
     for j, e in enumerate(picks):
-        ref = np.asarray(q40.matmul(x[j] if per_expert else x,
-                                    view.select(jnp.int32(e), experts),
-                                    impl="xla", out_dtype=jnp.float32))
+        ref, tol = _reference(x[j] if per_expert else x,
+                              view.select(jnp.int32(e), experts))
         np.testing.assert_allclose(np.asarray(out[j]), ref, rtol=0,
-                                   atol=1e-4 * np.abs(ref).max(), err_msg=str(j))
+                                   atol=tol * np.abs(ref).max(), err_msg=str(j))
 
 
 def _eqns_outside_kernels(jaxpr):

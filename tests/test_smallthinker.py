@@ -32,6 +32,7 @@ from dllama_tpu.ops import window
 from dllama_tpu.parallel.mesh import make_mesh
 from dllama_tpu.runtime.engine import Engine
 from dllama_tpu.runtime.scheduler import SlotScheduler
+from fixtures import bf16_exact_scales
 
 CFG = tiny_smallthinker()
 TOKS = np.random.RandomState(0).randint(3, 128, (60,)).astype(np.int32)
@@ -345,9 +346,11 @@ def test_a_decoded_block_on_the_chosen_launch_equals_the_loop(q40_file, t):
     matrix (``select-chosen``, the router's logits handed in from the layer's
     input), and the logits are those of the loop of one launch an expert on
     the XLA path (``select``): the same roundings, another order of float32
-    sums."""
+    sums (the scales are exact in bf16 times a nibble: a row alone on the
+    kernel rounds no weight, the XLA path each, and here that is the same)."""
     cfg, packed = load_params(mfile.MFile(q40_file), dtype=jnp.float32,
                               keep_quantized=True)
+    packed = bf16_exact_scales(packed)
     toks = jnp.asarray(TOKS)[None]
     _, cache = forward(packed, cfg.with_(quant_impl="xla"), toks[:, :20],
                        init_kv_cache(cfg, 1), jnp.int32(0))

@@ -1163,3 +1163,123 @@ def test_lfm2_one_stream_programs_compile_at_published_widths(one_chip, monkeypa
     for text in (prefill, decode):
         _no_whole_copy(text, ("bf16[2,1,8,32768,64]",
                               f"bf16[6,1,1,{conv.RING},2048]"))
+
+
+# ---------------------------------------------------------------------------
+# The Q40 body by rows (PR 50): one row contracts the raw nibbles a quantization
+# block at a time, more rows the dequantized tile.  The lowered kernel (the
+# Mosaic module in the custom call) says which.
+# ---------------------------------------------------------------------------
+def _kernel_ops(fn, shapes):
+    """Op counts of the one kernel ``fn`` launches, and its compiled text."""
+    import collections
+
+    from fixtures import kernel_bodies
+    from jax._src.lib.mlir import ir
+
+    lowered = jax.jit(fn).lower(*shapes)
+    (body,) = kernel_bodies(lowered.as_text())
+    ops = collections.Counter()
+
+    def walk(op):
+        ops[op.operation.name.removeprefix("stable_mosaic.")] += 1
+        for region in op.operation.regions:
+            for block in region.blocks:
+                for inner in block.operations:
+                    walk(inner)
+
+    with ir.Context() as ctx:
+        ctx.allow_unregistered_dialects = True
+        walk(ir.Module.parse(body).operation)
+    return ops, lowered.compile().as_text()
+
+
+def _launch(form, n, d, rows, sharding, experts=0, layers=2, k=0):
+    """(fn, shapes) of one launch: ``flat``, ``stacked``, ``chosen`` (k of
+    ``experts``, one shared activation) or ``chosen-x`` (one an expert)."""
+    s = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=sharding)  # noqa: E731
+    if form == "flat":
+        return q40._pallas_matmul, (
+            s((rows, n), jnp.bfloat16), s((n // 2, d), jnp.uint8),
+            s((n // 32, d), jnp.uint16))
+    if form == "stacked":
+        return q40._pallas_matmul_stacked, (
+            s((rows, n), jnp.bfloat16), s((layers, n // 2, d), jnp.uint8),
+            s((layers, n // 32, d), jnp.uint16), s((), jnp.int32))
+    return (lambda x, qp, sc, layer, chosen: q40._pallas_matmul_experts(
+        x, qp, sc, layer, experts=experts, chosen=chosen)), (
+        s(((k,) if form == "chosen-x" else ()) + (rows, n), jnp.bfloat16),
+        s((layers * experts, n // 2, d), jnp.uint8),
+        s((layers * experts, n // 32, d), jnp.uint16), s((), jnp.int32),
+        s((k,), jnp.int32))
+
+
+# Mistral-7B's five matmuls, a Yi-34B tp=4 shard's (w1 and w3, which a mesh
+# launches unfused: 7168 x 5120 each; wo: the whole 1792 rows a step; w2: 5120
+# in four steps of 1280), a row's chosen experts of SmallThinker (6 of 64) and
+# LFM2 (4 of 64), and DeepSeek-V2's expert width (1408: one tile of 44
+# quantization blocks, no multiple of eight)
+ONE_ROW_LAUNCHES = [
+    ("mistral-qkv", "stacked", 4096, 6144, {}),
+    ("mistral-wo", "stacked", 4096, 4096, {}),
+    ("mistral-w13", "stacked", 4096, 28672, {}),
+    ("mistral-w2", "stacked", 14336, 4096, {}),
+    ("mistral-head", "flat", 4096, 32768, {}),
+    ("yi-shard-w13", "stacked", 7168, 5120, {}),
+    ("yi-shard-wo", "stacked", 1792, 7168, {}),
+    ("yi-shard-w2", "stacked", 5120, 7168, {}),
+    ("smallthinker-gate", "chosen", 2560, 768, dict(experts=64, layers=4, k=6)),
+    ("smallthinker-down", "chosen-x", 768, 2560, dict(experts=64, layers=4, k=6)),
+    ("lfm2-gate", "chosen", 2048, 1536, dict(experts=64, layers=4, k=4)),
+    ("lfm2-down", "chosen-x", 1536, 2048, dict(experts=64, layers=4, k=4)),
+    ("deepseek-down", "chosen-x", 1408, 2048, dict(experts=64, layers=2, k=6)),
+]
+
+
+@pytest.mark.parametrize("name,form,n,d,kw", ONE_ROW_LAUNCHES,
+                         ids=[c[0] for c in ONE_ROW_LAUNCHES])
+def test_one_row_q40_body_lowers_for_the_v5e_with_no_op_a_weight_but_the_unpack(
+        one_chip, name, form, n, d, kw):
+    """At one row the raw nibbles go to one dot against a block-diagonal left
+    operand: the kernel compiles for the chip, and its Mosaic module holds one
+    matmul, the two nibble planes' conversions (and the scales'), ONE cast to
+    bf16 (the left operand: no weight is rounded), ONE subtraction (the bias
+    on the block partials, where the dot body has one a plane) and as many
+    multiplies as the dot body (the bias's 8 and the scale, on the block
+    partials, where that body scales each plane)."""
+    ops, text = _kernel_ops(*_launch(form, n, d, 1, one_chip, **kw))
+    assert "tpu_custom_call" in text
+    assert ops["tpu.matmul"] == 1 and ops["tpu.iota"] == 2
+    assert ops["arith.sitofp"] == SIXTEEN_ROW_BODY_OPS["arith.sitofp"]
+    assert ops["arith.truncf"] == 1 and ops["arith.subf"] == 1
+    assert ops["arith.mulf"] == SIXTEEN_ROW_BODY_OPS["arith.mulf"]
+    assert not ops["tpu.transpose"] and not ops["arith.divsi"]
+
+
+# the 16-row stacked launch at Mistral's w13 as PR 41 left it (and as the parent
+# of PR 50 lowers it): every op of the body with its count
+SIXTEEN_ROW_BODY_OPS = {
+    "builtin.module": 1, "func.func": 5, "func.return": 5, "arith.constant": 50,
+    "vector.load": 8, "vector.shape_cast": 7, "arith.extui": 5,
+    "vector.broadcast": 19, "arith.shrsi": 3, "arith.shli": 3, "arith.andi": 3,
+    "arith.addi": 1, "arith.ori": 2, "tpu.bitcast": 1, "arith.cmpi": 8,
+    "arith.select": 2, "arith.sitofp": 3, "arith.mulf": 4, "arith.subf": 2,
+    "arith.truncf": 2, "tpu.concatenate": 1, "tpu.matmul": 1, "scf.if": 3,
+    "tpu.vector_store": 3, "scf.yield": 6, "arith.addf": 1, "memref.load": 2,
+}
+
+
+@pytest.mark.parametrize("form,kw", [
+    ("stacked", {}), ("chosen", dict(experts=4, layers=2, k=2))])
+def test_a_sixteen_row_q40_launch_keeps_its_one_matmul_and_no_other_op(one_chip,
+                                                                      form, kw):
+    """At two rows and more the body is the parent's, op for op: one
+    matmul, two casts to bf16, two subtractions (a bias a plane)."""
+    ops, text = _kernel_ops(*_launch(form, 4096, 28672, 16, one_chip, **kw))
+    assert "tpu_custom_call" in text
+    # the chosen launch squeezes an expert axis off its output block and reads
+    # its planes from a vector: index plumbing, no work of the body
+    plumbing = {"arith.constant", "vector.shape_cast", "arith.index_cast"} \
+        if form == "chosen" else set()
+    assert {k: v for k, v in ops.items() if k not in plumbing} == \
+        {k: v for k, v in SIXTEEN_ROW_BODY_OPS.items() if k not in plumbing}

@@ -20,9 +20,30 @@ caller holds it, for two against its nibble halves): at 64 repetitions a
 one-row figure repeated to 1-2%, at the 128 it takes to 0.5%; an op that XLA
 keeps inside the scan is timed with the launch (PERF.md §6, PR 41).
 
-``--chosen`` times a decoded row's routed experts at SmallThinker's shapes:
-one launch over the six chosen planes (``q40_mm_chosen``) against six
-launches of ``q40_mm_stacked``, which is what ``moe_ffn`` ran at one row
+``--body`` takes a fourth argument, the bodies to time (default ``rule``).
+``rule`` is the kernel as the program runs it (``q40._body``: one row
+contracts the raw nibbles a quantization block at a time, more rows the
+dequantized tile in one dot).  ``dot`` is the dot at every row count, which at
+one row is the body every program ran up to PR 48.  ``half-dot`` is that body
+with the dot over HALF of the tile (the lo nibble planes; the hi planes are
+unpacked as ever and kept alive by a float32 sum, which adds ≈0.75 VPU op a
+weight to the body's ≈5.5) and ``dot-twice`` is it with the same VPU work and
+the dot issued TWICE: they answer what ROADMAP S2 left open for twenty PRs,
+*do the MXU's 128 x 128 tile loads bound the body at few rows?*  If they did,
+``half-dot`` would fall toward half and ``dot-twice`` rise toward double;
+``dot-twice`` is the cleaner of the two.  ``grouped`` is the one-row algebra
+at every row count up to 4, a block-diagonal left operand a row (is one row
+still where it stops winning?); ``vpu`` is the form NOT shipped: the same
+grouped algebra with the inner sums on the VPU (one multiply and one add a
+weight on float32, the byte left unmasked, no dot), which PR 49 would have
+shipped.  What each read is in PERF.md §6, PR 50.  All of them are patched
+into the loaded module for the run (``q40._body``, ``q40._contract_dot``,
+``q40._contract_grouped``); the program has no switch for them.
+
+``--chosen`` times a decoded row's routed experts at SmallThinker's shapes
+(6 of 64) and LFM2's (4 of 64): one launch over the chosen planes
+(``q40_mm_chosen``) against one launch each of ``q40_mm_stacked``, which is
+what ``moe_ffn`` ran at one row
 before and still runs on a mesh.  An iteration of the scan carries ops of
 its own (the index vector, the slice and sum that keep the result alive; for
 the loop also six index slices and a stack): 27 us read here where the
@@ -32,12 +53,13 @@ launch's time the trace's.
 
 Usage: python tools/sweep_q40.py --tiles [ds_gate,yi_wo]  # tile pairs at 1, 16, 256 rows
        python tools/sweep_q40.py --rows [head,w13]        # rows x row block
-       python tools/sweep_q40.py --body [w2,ds_down [16,32,64]]  # the body at the rule's tiles, 1 to 512 rows or the rows given
+       python tools/sweep_q40.py --body [w2,ds_down [16,32,64 [rule,dot,dot-twice,vpu]]]  # the body at the rule's tiles, 1 to 512 rows or the rows given
        python tools/sweep_q40.py --chosen [st_gate]       # one launch a row's experts, or one each
 """
 
 from __future__ import annotations
 
+import contextlib
 import json
 import os
 import sys
@@ -54,6 +76,7 @@ class Shape(NamedTuple):
     layers: int             # 0: a flat 2-D weight (the head)
     experts: int = 0        # > 0: the experts form, this many a layer
     x_per_expert: bool = False   # one activation block an expert (down)
+    chosen: int = 0         # the experts a decoded row reads (--chosen, --body)
     tiles: tuple = ()       # (tile_n, tile_d) pairs to time beside the rule's
 
 
@@ -91,13 +114,16 @@ SHAPES = [s._replace(tiles=WIDE) for s in MISTRAL] + [
     Shape("yi_w2", 5120, 7168, 8),
     Shape("w2_tp4", 3584, 4096, 8, tiles=((512, 1024), (3584, 256))),
 ]
-# SmallThinker-21B-A3B: 64 experts of 768, hidden 2560, 6 a row (--chosen)
-CHOSEN_SHAPES = [Shape("st_gate", 2560, 768, 4, 64),
-                 Shape("st_down", 768, 2560, 4, 64, True)]
-CHOSEN = 6
+# A decoded row's chosen experts (--chosen, --body): SmallThinker-21B-A3B, 64
+# experts of 768, hidden 2560, 6 a row; LFM2-24B-A2B, 64 of 1536, hidden 2048, 4
+CHOSEN_SHAPES = [Shape("st_gate", 2560, 768, 4, 64, chosen=6),
+                 Shape("st_down", 768, 2560, 4, 64, True, chosen=6),
+                 Shape("lfm2_gate", 2048, 1536, 4, 64, chosen=4),
+                 Shape("lfm2_down", 1536, 2048, 4, 64, True, chosen=4)]
 TILE_ROWS = (1, 16, 256)
 # K-EXAONE-236B-A23B, one chip's share: 16 held experts of 2048, hidden 6144
-BODY_SHAPES = MISTRAL + [s for s in SHAPES if s.experts and "pad" not in s.name] + [
+BODY_SHAPES = MISTRAL + [s for s in SHAPES if s.name in ("yi_w13", "yi_w2")
+                         or s.experts and "pad" not in s.name] + [
     Shape("kx_gate", 6144, 2048, 2, 16), Shape("kx_down", 2048, 6144, 2, 16, True)]
 BODY_ROWS = (1, 16, 128, 256, 512)
 # (rows, row block): None is the code's own choice (one block of every row
@@ -118,8 +144,8 @@ def _sweep(shapes, configs_of, only: set | None, reps: int, out_name: str):
     scan over the layer index, as the model runs it.  A config is ``(tag,
     rows, kw)``: ``tag`` names it in the record, ``kw`` holds the kernel's
     keywords (None: the dequantize-then-dot XLA path).  A ``tag`` with a
-    ``form`` reads CHOSEN traced planes of the layer's experts: ``chosen`` in
-    one launch, ``stacked-loop`` in one launch each.
+    ``form`` reads the shape's ``chosen`` traced planes of the layer's
+    experts: ``chosen`` in one launch, ``stacked-loop`` in one launch each.
     One JSON line per measurement; all of them to ``chiprun_out/<out_name>``."""
     import jax
     import jax.numpy as jnp
@@ -146,19 +172,19 @@ def _sweep(shapes, configs_of, only: set | None, reps: int, out_name: str):
             jnp.uint16), (planes, 1, 1))
         for tag, rows, kw in configs_of(sh):
             form = tag.get("form")
-            read = CHOSEN if form else max(E, 1)  # planes a call reads
+            read = sh.chosen if form else max(E, 1)  # planes a call reads
             xshape = ((read,) if sh.x_per_expert else ()) + (rows, n)
             x = jax.random.normal(key, xshape, jnp.bfloat16)
 
             def one(x, qp, sc, i):
                 if form:
-                    picks = (i + 11 * jnp.arange(CHOSEN)) % E  # distinct, traced
+                    picks = (i + 11 * jnp.arange(sh.chosen)) % E  # distinct, traced
                     if form == "chosen":
                         return q40._pallas_matmul_experts(
                             x, qp, sc, i % L, experts=E, chosen=picks, **kw)
                     return jnp.stack([q40._pallas_matmul_stacked(
                         x[j] if sh.x_per_expert else x, qp, sc,
-                        (i % L) * E + picks[j], **kw) for j in range(CHOSEN)])
+                        (i % L) * E + picks[j], **kw) for j in range(sh.chosen)])
                 if not sh.layers:
                     # no layer index to vary: vary x, or XLA hoists the one
                     # call out of the scan
@@ -251,22 +277,150 @@ def measure_rows(only: set | None = None, reps: int = 16,
                   lambda sh: configs, only, reps, "sweep_rows.json")
 
 
+def _half_dot(x_ref, vi, s32):
+    """The dot body with the dot over the lo nibble planes alone."""
+    import jax.numpy as jnp
+
+    lo, hi = _q40()._dequant_bf16(vi, s32)  # both planes unpacked, as ever
+    nb, _, td = lo.shape
+    part = jnp.dot(x_ref[:, :16 * nb], lo.reshape(16 * nb, td),
+                   preferred_element_type=jnp.float32)
+    # the hi plane stays alive in a sum nothing can equal: adds 0
+    alive = hi.astype(jnp.float32).reshape(2 * nb, 8, td).sum(axis=0)
+    return part + jnp.where(alive[:1] == 1.2345e30, 1.0, 0.0)
+
+
+def _dot_twice(x_ref, vi, s32):
+    """The dot body with its dot issued twice on the same unpacked tile."""
+    import jax.numpy as jnp
+
+    nb, td = s32.shape
+    lo, hi = _q40()._dequant_bf16(vi, s32)
+    w = jnp.concatenate([lo, hi], axis=1).reshape(32 * nb, td)
+    x = x_ref[:]
+    again = (x.astype(jnp.float32) * 0.5).astype(jnp.bfloat16)
+    return (jnp.dot(x, w, preferred_element_type=jnp.float32)
+            + jnp.dot(again, w, preferred_element_type=jnp.float32))
+
+
+def _contract_vpu(x_ref, vi, s32):
+    """The grouped algebra with the inner sums on the VPU (the form not
+    shipped): ``x_lo * lo + x_hi * hi == x_lo * byte + (x_hi - 16 * x_lo) *
+    hi``, so a weight costs half an unpack, half a shift, one conversion, one
+    multiply and one add on float32, and the activation row comes onto the
+    sublanes by one transpose.  ``q40._partial_rows`` sublanes a row, like the
+    shipped body."""
+    import jax.numpy as jnp
+
+    nb, td = s32.shape
+    lanes = lambda v: jnp.concatenate([v] * (td // 128), axis=-1)  # noqa: E731
+    byte = vi.astype(jnp.float32).reshape(nb, 16, td)
+    hi = (vi >> 4).astype(jnp.float32).reshape(nb, 16, td)
+    parts = []
+    for r in range(x_ref.shape[0]):
+        row = x_ref[r:r + 1, :].astype(jnp.float32)
+        xb = jnp.broadcast_to(row, (128, row.shape[-1])).T.reshape(nb, 32, 128)
+        xlo, xhi = xb[:, :16], xb[:, 16:]
+        p = byte * lanes(xlo) + hi * lanes(xhi - 16.0 * xlo)
+        bias = 8.0 * xb.reshape(nb, 4, 8, 128).sum(axis=1)
+        part = ((p[:, :8] + p[:, 8:] - lanes(bias)) * s32[:, None, :]).sum(axis=0)
+        parts.append(part if _q40()._partial_rows(32 * nb) == 8
+                     else part.sum(axis=0, keepdims=True))
+    return jnp.concatenate(parts, axis=0)
+
+
+def _grouped_rows(x_ref, vi, s32):
+    """The shipped one-row body (``q40._contract_grouped``) for a block of a
+    few rows: one block-diagonal left operand of ``nb`` rows an activation
+    row, set one above the other for ONE dot of ``rows * nb`` rows."""
+    import jax
+    import jax.numpy as jnp
+
+    nb, td = s32.shape
+    rows, tn = x_ref.shape
+    lo = (vi & 0xF).astype(jnp.bfloat16).reshape(nb, 16, td)
+    hi = (vi >> 4).astype(jnp.bfloat16).reshape(nb, 16, td)
+    w = jnp.concatenate([lo, hi], axis=1).reshape(tn, td)
+    own = (jax.lax.broadcasted_iota(jnp.int32, (nb, tn), 1) >> 5
+           == jax.lax.broadcasted_iota(jnp.int32, (nb, tn), 0))
+    xd = jnp.concatenate([jnp.where(own, jnp.broadcast_to(
+        x_ref[r:r + 1, :].astype(jnp.float32), (nb, tn)), 0.0)
+        for r in range(rows)], axis=0)
+    p = jnp.dot(xd.astype(jnp.bfloat16), w, preferred_element_type=jnp.float32)
+    p = (p - 8.0 * xd.sum(axis=1, keepdims=True)).reshape(rows, nb, td) * s32
+    k = _q40()._partial_rows(tn)
+    return p.reshape(rows, nb // k, k, td).sum(axis=1).reshape(rows * k, td)
+
+
+def _few_rows(rows: int) -> str:
+    return "grouped" if rows <= 4 else "dot"
+
+
+# what each of --body's bodies patches into the loaded q40 module
+BODIES = {
+    "rule": {},
+    "dot": dict(_body=lambda rows: "dot"),
+    "half-dot": dict(_body=lambda rows: "dot", _contract_dot=_half_dot),
+    "dot-twice": dict(_body=lambda rows: "dot", _contract_dot=_dot_twice),
+    "grouped": dict(_body=_few_rows, _contract_grouped=_grouped_rows),
+    "vpu": dict(_body=_few_rows, _contract_grouped=_contract_vpu),
+}
+
+
+@contextlib.contextmanager
+def _body_as(name: str):
+    """While entered, the loaded kernel runs body ``name`` of BODIES (see the
+    module docstring); ``rule`` changes nothing."""
+    import jax
+
+    if name not in BODIES:
+        sys.exit(f"unknown body {name!r}: one of {', '.join(BODIES)}")
+    q40 = _q40()
+    saved = {k: getattr(q40, k) for k in BODIES[name]}
+    for k, v in BODIES[name].items():
+        setattr(q40, k, v)
+    jax.clear_caches()
+    try:
+        yield
+    finally:
+        for k, v in saved.items():
+            setattr(q40, k, v)
+        jax.clear_caches()
+
+
 def measure_body(only: set | None = None, reps: int = 128,
-                 rows: tuple = BODY_ROWS) -> list[dict]:
+                 rows: tuple = BODY_ROWS, bodies: tuple = ("rule",)) -> list[dict]:
     """The kernel at the rule's tiles and the code's own row block: Mistral's
     five matmuls and the experts form of the three expert models at 1, 16,
-    128, 256 and 512 rows (or at ``rows``: the packed mixed step runs 64, PR 42).  Run on two checkouts, it compares two bodies."""
-    return _sweep([s._replace(layers=min(s.layers, 4)) for s in BODY_SHAPES],
-                  lambda sh: [({}, r, {}) for r in rows],
-                  only, reps, "sweep_body.json")
+    128, 256 and 512 rows (or at ``rows``: the packed mixed step runs 64, PR
+    42), and a row's chosen experts at SmallThinker's and LFM2's shapes up to
+    4 rows, under each of ``bodies``.  Run on two checkouts, ``rule`` compares two
+    bodies."""
+    results = []
+    for name in bodies:
+        tag = {"body": name}
+        with _body_as(name):
+            results += _sweep(
+                [s._replace(layers=min(s.layers, 4)) for s in BODY_SHAPES],
+                lambda sh: [(tag, r, {}) for r in rows],
+                only, reps, f"sweep_body.{name}.json")
+            few = [r for r in rows if r <= 4]
+            if few and (only is None or only & {s.name for s in CHOSEN_SHAPES}):
+                results += _sweep(
+                    CHOSEN_SHAPES,
+                    lambda sh: [({**tag, "form": "chosen", "chosen": sh.chosen}, r, {})
+                                for r in few],
+                    only, reps, f"sweep_body.{name}.chosen.json")
+    return results
 
 
 def measure_chosen(only: set | None = None, reps: int = 256) -> list[dict]:
-    """One decoded row's CHOSEN routed experts: one launch over their planes
-    against one launch each, at the rule's tiles (``ms`` is all six)."""
-    configs = [({"form": form, "chosen": CHOSEN}, 1, {})
-               for form in ("stacked-loop", "chosen")]
-    return _sweep(CHOSEN_SHAPES, lambda sh: configs, only, reps, "sweep_chosen.json")
+    """One decoded row's chosen routed experts: one launch over their planes
+    against one launch each, at the rule's tiles (``ms`` is all of them)."""
+    return _sweep(CHOSEN_SHAPES,
+                  lambda sh: [({"form": form, "chosen": sh.chosen}, 1, {})
+                              for form in ("stacked-loop", "chosen")],
+                  only, reps, "sweep_chosen.json")
 
 
 def main():
@@ -277,6 +431,8 @@ def main():
     kw = {}
     if sys.argv[1] == "--body" and len(sys.argv) > 3:
         kw["rows"] = tuple(int(r) for r in sys.argv[3].split(","))
+    if sys.argv[1] == "--body" and len(sys.argv) > 4:
+        kw["bodies"] = tuple(sys.argv[4].split(","))
     modes[sys.argv[1]](set(sys.argv[2].split(",")) if len(sys.argv) > 2
                        else None, **kw)
 
