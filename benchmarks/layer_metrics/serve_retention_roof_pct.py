@@ -1,0 +1,36 @@
+"""kernels: the retention read's share of the chip's roof in a served cell.  The
+least one pure-decode step of `slots_busy_mean` rows needs for the state and the
+recent rows (the configuration's `models/<name>.py`: `retention_bytes` over the
+peak bandwidth, `retention_flops` over the peak bf16 rate, harness/peaks.py; the
+larger of the two, and which one binds goes to `out/retention-roof.json`), over
+the device time under the parts `state` and `recent` of scope `attn` per
+scheduler step.  The floor counts the least any exact implementation does: one
+read of the symmetric state a row a layer and no write of it, so a wider stored
+state or an eager fold reads under its roof and nothing reads over 100.  A mixed
+step's chunk rows do sixteen times a decode row's work and are charged as
+decode rows, so a window with more mixed steps reads lower."""
+
+import json
+import os
+
+import slots_busy_mean
+from _parts import part_ms_per_step
+from _scopes import OUT
+from harness import models
+
+
+def read(ctx):
+    model = models.for_config(ctx["config"])
+    need_b = getattr(model, "retention_bytes", None)
+    need_f = getattr(model, "retention_flops", None)
+    ms = part_ms_per_step(ctx, "attn", ["state", "recent"])
+    rows = slots_busy_mean.read(ctx)
+    if not ms or not rows or need_b is None or ctx["peaks"] is None:
+        return None
+    by_bytes = need_b(ctx["config"], rows, ctx["chips"]) / ctx["peaks"]["hbm_bytes_per_s"]
+    by_flops = need_f(ctx["config"], rows, ctx["chips"]) / ctx["peaks"]["bf16_flops_per_s"]
+    with open(os.path.join(OUT, "retention-roof.json"), "w") as f:
+        json.dump({"rows": rows, "ms_per_step": ms,
+                   "floor_ms_bytes": by_bytes * 1e3, "floor_ms_flops": by_flops * 1e3,
+                   "floor": "bytes" if by_bytes >= by_flops else "flops"}, f, indent=1)
+    return 100.0 * max(by_bytes, by_flops) / (ms / 1e3)

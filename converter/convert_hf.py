@@ -11,7 +11,10 @@ routed experts; ``mtp.*`` tensors are skipped) and lfm2_moe folders
 (``ARCH_LFM2_MOE``: LFM2's gated short-convolution and attention layers,
 header keys 19, 23, 24, 31, 32, 34, 37, 38; ``conv.conv.weight`` (D, 1, L)
 becomes the flat ``conv_taps``; a tied head is written from the embedding's
-rows).  Key semantics preserved:
+rows) and brumby folders (``ARCH_BRUMBY``: Qwen3's names with a gate
+``self_attn.g_proj``, header keys 31 and 39; the names are ASSUMED, one table,
+``_BRUMBY_LEAVES``, unverified until the published files are in the
+repository).  Key semantics preserved:
 
 * q/k head permutation (convert-hf.py:12-15): HF stores RoPE in rotate-half
   layout; the `.m` format expects the interleaved-pair layout, so q and k
@@ -49,6 +52,7 @@ ARCH_BY_MODEL_TYPE = {
     "smallthinker": mfile.ARCH_SMALLTHINKER,
     "exaone_moe": mfile.ARCH_EXAONE_MOE,
     "lfm2_moe": mfile.ARCH_LFM2_MOE,
+    "brumby": mfile.ARCH_BRUMBY,
 }
 HIDDEN_ACT = {"gelu": mfile.ACT_GELU, "silu": mfile.ACT_SILU,
               "relu": mfile.ACT_RELU}
@@ -282,6 +286,32 @@ def _lfm2_moe_fields(config: dict) -> dict:
                 conv_taps=int(config["conv_L_cache"]))
 
 
+def _brumby_fields(config: dict) -> dict:
+    """The header's keys past the fourteen from a ``brumby`` config.json.
+    ``ARCH_BRUMBY`` is one block: Qwen3's bias-free projections with a head
+    size of ``hidden_size / num_attention_heads``, unscaled RoPE, no sliding
+    window, an untied head, power retention of degree 2 (the release's; no key
+    of config.json states it).  What the file cannot carry is refused by name."""
+    def no(why):
+        raise SystemExit(f"brumby: {why}")
+
+    if config.get("attention_bias", False):
+        no("attention_bias is true; the .m file has no projection bias")
+    if config.get("use_sliding_window", False):
+        no("use_sliding_window is true; a retention layer has no window")
+    if config.get("rope_scaling"):
+        no(f"rope_scaling is {config['rope_scaling']!r}; the runtime's RoPE is unscaled")
+    if config.get("tie_word_embeddings", False):
+        no("tie_word_embeddings is true; the published head is its own tensor")
+    heads = config["num_attention_heads"]
+    if config.get("head_dim", config["hidden_size"] // heads) * heads \
+            != config["hidden_size"]:
+        no("head_dim * num_attention_heads is not hidden_size; a brumby .m "
+           "file states no head size")
+    return dict(norm_eps=float(config.get("rms_norm_eps", 1e-6)),
+                retention_degree=2)
+
+
 def load_spec(folder: str, weights_ftype: int, experts_held: int = 0,
               first_expert: int = 0) -> mfile.ModelSpec:
     with open(os.path.join(folder, "config.json")) as f:
@@ -298,6 +328,8 @@ def load_spec(folder: str, weights_ftype: int, experts_held: int = 0,
                       hidden_act="relu")
     if arch == mfile.ARCH_LFM2_MOE and not (experts_held or first_expert):
         ext = _lfm2_moe_fields(config)
+    if arch == mfile.ARCH_BRUMBY:
+        ext = _brumby_fields(config)
     if arch in (mfile.ARCH_EXAONE_MOE, mfile.ARCH_LFM2_MOE):
         config = dict(config, rope_theta=(config.get("rope_parameters") or {}).get(
             "rope_theta", config.get("rope_theta", 10000.0)))
@@ -371,6 +403,17 @@ _LFM2_LEAVES = {
     "rms_ffn": "ffn_norm", "w1": "feed_forward.w1", "w2": "feed_forward.w2",
     "w3": "feed_forward.w3", "moe_router": "feed_forward.gate",
 }
+# ASSUMED: a brumby layer's tensors under ``model.layers.N.``: Qwen3's names
+# (its per-head norms and MLP) with the gate beside the projections.  Unverified
+# until the published files are in the repository: a name that differs shows
+# as "Layer ... not found" on the first tensor it concerns
+_BRUMBY_LEAVES = {
+    "wq": "self_attn.q_proj", "wk": "self_attn.k_proj", "wv": "self_attn.v_proj",
+    "wo": "self_attn.o_proj", "wg": "self_attn.g_proj",
+    "q_norm": "self_attn.q_norm", "k_norm": "self_attn.k_norm",
+    "rms_att": "input_layernorm", "rms_ffn": "post_attention_layernorm",
+    "w1": "mlp.gate_proj", "w2": "mlp.down_proj", "w3": "mlp.up_proj",
+}
 _DEEPSEEK2_LEAVES = {
     "wq_a": "self_attn.q_a_proj", "q_a_norm": "self_attn.q_a_layernorm",
     "wq_b": "self_attn.q_b_proj", "wkv_a": "self_attn.kv_a_proj_with_mqa",
@@ -401,6 +444,8 @@ def hf_source_name(our_name: str, spec: mfile.ModelSpec) -> tuple[str, bool]:
             hf_leaf = {"up": "w3", "gate": "w1", "down": "w2"}[leaf]
             return f"{base}.feed_forward.experts.{parts[3]}.{hf_leaf}.weight", False
         return f"{base}.{_LFM2_LEAVES[leaf]}.weight", False
+    if spec.arch == mfile.ARCH_BRUMBY:  # rows as published: halves rotate
+        return f"{base}.{_BRUMBY_LEAVES[leaf]}.weight", False
     # rows as published: these runtimes rotate halves, as HF does
     olmoe = spec.arch in (mfile.ARCH_OLMOE, mfile.ARCH_SMALLTHINKER,
                           mfile.ARCH_EXAONE_MOE)

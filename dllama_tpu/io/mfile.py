@@ -31,7 +31,11 @@ the reference converters load directly:
 ``window_full_at`` is K-EXAONE's attention (``wq`` .. ``k_norm``) and every
 other layer a gated short convolution (``conv_in`` (3 dim, dim), ``conv_taps``
 (dim x taps values, f32, channel by channel), ``conv_out`` (dim, dim)); no
-shared expert.  Matmul weights are stored row-major ``(d_out, n_in)`` in the
+shared expert.  A Brumby file (``ARCH_BRUMBY``) has keys 31 and 39 (the
+retention's degree) and Llama's layers with, after ``wo``, the gate ``wg``
+(n_kv_heads, dim; f32: eight rows are no Q40 matrix) and a ``q_norm`` /
+``k_norm`` of one head's size.  Matmul weights are stored row-major
+``(d_out, n_in)`` in the
   model's weight float type; norm weights and the embedding are F32
   (transformer.cpp:213-218, 266-278).
 
@@ -85,10 +89,16 @@ ARCH_EXAONE_MOE = 0xABCD06
 # and values; leading dense layers, then a sigmoid router with a choice bias
 # and a ``+ 1e-6`` in the normalisation, no shared expert
 ARCH_LFM2_MOE = 0xABCD07
+# Brumby (``brumby``): Qwen3's dense block (per-head q/k RMSNorm, rotate-half
+# RoPE, SwiGLU) with every attention layer replaced by power retention of
+# degree ``retention_degree`` (key 39): a gate a kv head (``wg``) and, in place
+# of keys and values, a state matrix a kv head (``ops/retention.py``)
+ARCH_BRUMBY = 0xABCD08
 ARCH_NAMES = {ARCH_LLAMA: "llama", ARCH_GROK1: "grok1", ARCH_MIXTRAL: "mixtral",
               ARCH_OLMOE: "olmoe", ARCH_DEEPSEEK2: "deepseek2",
               ARCH_SMALLTHINKER: "smallthinker",
-              ARCH_EXAONE_MOE: "exaone_moe", ARCH_LFM2_MOE: "lfm2_moe"}
+              ARCH_EXAONE_MOE: "exaone_moe", ARCH_LFM2_MOE: "lfm2_moe",
+              ARCH_BRUMBY: "brumby"}
 
 # TransformerHiddenAct (transformer.hpp:45-48), and beyond it ReLU
 ACT_GELU = 0
@@ -112,8 +122,9 @@ KEY_ROPE_THETA = 12
 KEY_WEIGHTS_FLOAT_TYPE = 13
 # beyond the reference's fourteen: DeepSeek-V2's (``EXT_KEYS``, 14..31),
 # SmallThinker's own (``WINDOW_KEYS``, 32..34; its file also carries key 31),
-# K-EXAONE's (``SHARE_KEYS``, 35..37; its file carries some of each) and
-# LFM2's one (``CONV_KEYS``, 38; its file carries some of each).
+# K-EXAONE's (``SHARE_KEYS``, 35..37; its file carries some of each),
+# LFM2's one (``CONV_KEYS``, 38; its file carries some of each) and Brumby's
+# one (``RETENTION_KEYS``, 39; its file also carries key 31).
 # ``(key, field, is_float)``: a float travels as the bits of its IEEE-754 f32
 # in the i32
 EXT_KEYS = (
@@ -149,7 +160,10 @@ SHARE_KEYS = (
 CONV_KEYS = (
     (38, "conv_taps", False),           # taps of a short-convolution layer (conv_L_cache)
 )
-ALL_EXT_KEYS = EXT_KEYS + WINDOW_KEYS + SHARE_KEYS + CONV_KEYS
+RETENTION_KEYS = (
+    (39, "retention_degree", False),    # p of a power-retention layer's (q . k)^p
+)
+ALL_EXT_KEYS = EXT_KEYS + WINDOW_KEYS + SHARE_KEYS + CONV_KEYS + RETENTION_KEYS
 # the keys a file of an arch carries past the fourteen
 ARCH_EXT_KEYS = {ARCH_DEEPSEEK2: tuple(range(14, 32)),
                  ARCH_SMALLTHINKER: (31, 32, 33, 34),
@@ -157,9 +171,10 @@ ARCH_EXT_KEYS = {ARCH_DEEPSEEK2: tuple(range(14, 32)),
                  # which layer of a period is attention: keys 34 and 37 as
                  # K-EXAONE's (a period and a place in it; no second pair of
                  # keys for the same two numbers); no window, so no key 33
-                 ARCH_LFM2_MOE: (19, 23, 24, 31, 32, 34, 37, 38)}
+                 ARCH_LFM2_MOE: (19, 23, 24, 31, 32, 34, 37, 38),
+                 ARCH_BRUMBY: (31, 39)}
 _EXT_BY_KEY = {k: (name, is_f) for k, name, is_f in ALL_EXT_KEYS}
-KEY_MAX = CONV_KEYS[-1][0]
+KEY_MAX = RETENTION_KEYS[-1][0]
 
 
 def _f32_bits(x: float) -> int:
@@ -218,6 +233,8 @@ class ModelSpec:
     window_full_at: int = 0
     # ARCH_LFM2_MOE's; 0 where the arch has none
     conv_taps: int = 0
+    # ARCH_BRUMBY's; 0 where the arch has none
+    retention_degree: int = 0
 
     @property
     def head_size(self) -> int:
@@ -292,6 +309,10 @@ def tensor_plan(spec: ModelSpec) -> list[TensorInfo]:
         if spec.arch == ARCH_OLMOE:
             add(f"layers.{i}.q_norm", (spec.dim,), quants.F32)
             add(f"layers.{i}.k_norm", (spec.kv_dim,), quants.F32)
+        if spec.arch == ARCH_BRUMBY:
+            add(f"layers.{i}.wg", (spec.n_kv_heads, spec.dim), quants.F32)
+            add(f"layers.{i}.q_norm", (spec.head_size,), quants.F32)
+            add(f"layers.{i}.k_norm", (spec.head_size,), quants.F32)
         if spec.n_experts > 0:
             add(f"layers.{i}.moe_router", (spec.n_experts, spec.dim), w)
             for e in range(spec.n_experts):
@@ -482,6 +503,13 @@ def validate_spec(spec: ModelSpec, path) -> ModelSpec:
         raise ArtifactError(path, "header key",
                             "key 38 describes an lfm2_moe file",
                             expected=hex(ARCH_LFM2_MOE), got=hex(spec.arch))
+    if (spec.arch == ARCH_BRUMBY) != bool(spec.retention_degree):
+        raise ArtifactError(path, "header key",
+                            "key 39 (the retention's degree) describes a brumby "
+                            "file, and a brumby file states it",
+                            expected=hex(ARCH_BRUMBY), got=hex(spec.arch))
+    if spec.arch == ARCH_BRUMBY:
+        _validate_brumby(spec, path)
     if spec.arch == ARCH_EXAONE_MOE:
         _validate_exaone_moe(spec, path)
     elif spec.experts_held or spec.first_expert or (
@@ -562,6 +590,23 @@ def _validate_exaone_moe(spec: ModelSpec, path) -> None:
     if spec.n_active_experts > spec.n_experts:
         bad("n_active_experts", "more experts a token than the router has",
             f"<= {spec.n_experts}", spec.n_active_experts)
+
+
+def _validate_brumby(spec: ModelSpec, path) -> None:
+    """The cross-field rules of an ``ARCH_BRUMBY`` header."""
+    def bad(field, why, expected, got):
+        raise ArtifactError(path, f"header field {field}", why,
+                            expected=expected, got=got)
+
+    if spec.retention_degree != 2:
+        bad("retention_degree", "power retention is implemented for the "
+            "released degree (the symmetric square of a head)", 2,
+            spec.retention_degree)
+    if spec.head_size % 2:
+        bad("n_heads", "RoPE rotates halves of a head", "an even dim / n_heads",
+            spec.head_size)
+    if spec.n_experts:
+        bad("n_experts", "a brumby layer has a dense SwiGLU", 0, spec.n_experts)
 
 
 def _validate_lfm2_moe(spec: ModelSpec, path) -> None:

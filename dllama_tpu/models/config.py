@@ -85,6 +85,8 @@ class ModelConfig:
     window_full_at: int = 0         # the full layer's place in a period
     # ---- ARCH_LFM2_MOE (header key 38); 0 = the arch has none
     conv_taps: int = 0              # > 0: a period's other layers are short convolutions
+    # ---- ARCH_BRUMBY (header key 39); 0 = the arch has none
+    retention_degree: int = 0       # > 0: every layer is power retention of this degree
 
     @property
     def head_size(self) -> int:
@@ -131,6 +133,23 @@ class ModelConfig:
         return 1e-6 if self.arch == mfile.ARCH_LFM2_MOE else 0.0
 
     @property
+    def attention_free(self) -> bool:
+        """No layer has keys and values to keep: every layer is power
+        retention (``ops/retention.py``), whose state is a matrix a kv head and
+        a short ring of recent positions, a fixed size a sequence whatever the
+        context's depth.  Such a model has no paged layer: no pages, a cached
+        token costs no bytes, and a slot engine admits by slot alone."""
+        return self.retention_degree > 0
+
+    @property
+    def keeps_state(self) -> bool:
+        """Some layer leaves behind a state that is not a row of keys and
+        values a position (a convolution's ring of ``z``, a retention layer's
+        matrix): the engines keep account of how far the position clock may be
+        moved back over it (``runtime/engine.py``, the pos-rewind invariant)."""
+        return self.conv_taps > 0 or self.retention_degree > 0
+
+    @property
     def n_experts_held(self) -> int:
         """Routed experts a layer holds planes for: all ``n_experts`` unless
         the file is one chip's share of an expert-parallel deployment (the
@@ -144,7 +163,13 @@ class ModelConfig:
         ``PREFILL_PRODUCT_BYTES``.  512 at 64 experts of 2560, 1024 at 16 held
         of 6144; a prompt up to one chunk takes one call."""
         rows = PREFILL_PRODUCT_BYTES // (4 * max(self.n_experts_held, 1) * self.dim)
-        return max(16, 1 << (max(rows, 1).bit_length() - 1))
+        rows = max(16, 1 << (max(rows, 1).bit_length() - 1))
+        if self.retention_degree:
+            # a call's rows all enter the ring of recent positions, and what
+            # it folds lies wholly before them (ops/retention.py)
+            from ..ops import retention
+            rows = min(rows, retention.MAX_ROWS)
+        return rows
 
     def window_ring(self, seq_len: int) -> int:
         """Positions a window layer's contiguous cache holds a row: the window
@@ -230,8 +255,9 @@ class ModelConfig:
         """K-EXAONE RMS-normalises each head of q and of k over its own
         ``head_size`` values, one weight vector of that size each a layer
         (``q_norm`` / ``k_norm``), before RoPE; so does LFM2 in its attention
-        layers."""
-        return self.arch in (mfile.ARCH_EXAONE_MOE, mfile.ARCH_LFM2_MOE)
+        layers, and Brumby (Qwen3's) in every layer."""
+        return self.arch in (mfile.ARCH_EXAONE_MOE, mfile.ARCH_LFM2_MOE,
+                             mfile.ARCH_BRUMBY)
 
     @property
     def router_sigmoid(self) -> bool:
@@ -327,6 +353,17 @@ def tiny_lfm2_moe(**kw) -> ModelConfig:
                 vocab_size=128, seq_len=128, rope_theta=1e6, norm_eps=1e-5,
                 head_dim=8, window_period=4, window_full_at=2, conv_taps=3,
                 moe_hidden_dim=32, n_dense_layers=2, routed_scale=1.0)
+    base.update(kw)
+    return tiny_config(**base)
+
+
+def tiny_brumby(**kw) -> ModelConfig:
+    """Brumby at a toy size that keeps every ratio: 5 query heads a kv head, a
+    head of 16 (eight blocks of 2 in the symmetric square, as 128 has eight of
+    16), degree 2, four layers all alike, an untied head, eps 1e-6."""
+    base = dict(arch=mfile.ARCH_BRUMBY, dim=160, hidden_dim=224, n_layers=4,
+                n_heads=10, n_kv_heads=2, vocab_size=128, seq_len=512,
+                rope_theta=1e6, norm_eps=1e-6, retention_degree=2)
     base.update(kw)
     return tiny_config(**base)
 

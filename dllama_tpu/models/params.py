@@ -128,6 +128,9 @@ def param_shapes(cfg: ModelConfig) -> dict[str, tuple[int, ...]]:
     }
     if cfg.qk_norm:
         shapes.update({"q_norm": (L, Hq), "k_norm": (L, Hkv)})
+    if cfg.retention_degree:
+        shapes.update({"wg": (L, D, cfg.n_kv_heads), "q_norm": (L, cfg.head_size),
+                       "k_norm": (L, cfg.head_size)})
     if cfg.is_moe:
         shapes.update({
             "router": (L, D, E),
@@ -155,7 +158,7 @@ def init_params(cfg: ModelConfig, seed: int = 0, scale: float = 0.02) -> Params:
             x = (rng.standard_normal(shape) * scale).astype(np.float32)
         if name == "conv_taps":  # O(1) taps: the state matters to the logits
             x = (0.5 + 0.5 * rng.standard_normal(shape)).astype(np.float32)
-        f32 = norm or name in ("router_bias", "conv_taps")
+        f32 = norm or name in ("router_bias", "conv_taps", "wg")
         params[name] = jnp.asarray(x, dtype=jnp.float32 if f32 else cfg.dtype)
     return params
 
@@ -351,9 +354,11 @@ def _read_params(mf: mfile.MFile, cfg: ModelConfig, dtype,
             p[key] = _stack(mf, [f"layers.{i}.{key}" for i in range(L)], True, np_dtype)
     p["rms_att"] = _stack(mf, [f"layers.{i}.rms_att" for i in range(L)], False, np.float32)
     p["rms_ffn"] = _stack(mf, [f"layers.{i}.rms_ffn" for i in range(L)], False, np.float32)
-    if cfg.qk_norm:
+    if cfg.qk_norm or cfg.retention_degree:
         for key in ("q_norm", "k_norm"):
             p[key] = _stack(mf, [f"layers.{i}.{key}" for i in range(L)], False, np.float32)
+    if cfg.retention_degree:  # the gate stays float32, whatever the weights' type
+        p["wg"] = _stack(mf, [f"layers.{i}.wg" for i in range(L)], True, np.float32)
     if cfg.is_moe:
         p["router"] = _stack(mf, [f"layers.{i}.moe_router" for i in range(L)], True, np_dtype)
         if quant:

@@ -31,7 +31,7 @@ import jax
 import jax.numpy as jnp
 
 from ..obs import dispatch as obs_dispatch
-from ..ops import mla, q40, q8
+from ..ops import mla, q40, q8, retention
 from ..ops.attention import (gqa_attention_at, paged_gqa_attention_at,
                              paged_update_kv_rows, paged_write_indices,
                              quantize_kv, slot_gqa_attention_at,
@@ -71,6 +71,18 @@ class KVCache(NamedTuple):
     # last positions' ``z`` a row, (Lc, B, 1, R, D) on both engines (a slot
     # owns its row; ops/conv.py); k and v are then its attention layers' planes
     cz: jax.Array | None = None
+    # a model of retention layers only (ops/retention.py): a row's state matrix
+    # and sum a kv head, rs (L, B, Hkv, D, Dh) and rz (L, B, Hkv, 1, D) float32,
+    # which no position and no page addresses; beside them its ring of recent
+    # keys, values and log-gates, rk / rv (L, B, Hkv, R, Dh) and rg (L, B, 1,
+    # R, Hkv), and its watermark rw (1, B, 1, 1, 1) int32: the state holds the
+    # tokens before it, the ring the rest.  k and v then have no layer
+    rs: jax.Array | None = None
+    rz: jax.Array | None = None
+    rk: jax.Array | None = None
+    rv: jax.Array | None = None
+    rg: jax.Array | None = None
+    rw: jax.Array | None = None
 
     @property
     def quantized(self) -> bool:
@@ -87,10 +99,19 @@ class KVCache(NamedTuple):
 
     def pool_planes(self) -> dict[str, jax.Array]:
         """The planes of a paged pool that a page id addresses (what a spill
-        or a hand-off record carries page by page): all of them but a windowed
-        model's slot rings and a convolution state, which belong to slots."""
-        return {n: a for n, a in self.planes().items()
-                if n not in ("wk", "wv", "cz")}
+        or a hand-off record carries page by page): all of them but what
+        belongs to a slot (``SLOT_PLANES``)."""
+        return {n: a for n, a in self.planes().items() if n not in SLOT_PLANES}
+
+
+# the planes of a slot engine's cache that belong to a slot and that no page id
+# addresses, by what they are: the one list the engines' accounts read
+# (``KVCache.pool_planes``, ``runtime/engine.py _note_cache_bytes``,
+# ``Engine.slot_state``)
+SLOT_PLANE_KINDS = {"wk": "window", "wv": "window", "cz": "conv",
+                    **dict.fromkeys(("rs", "rz", "rk", "rv", "rg", "rw"),
+                                    "retention")}
+SLOT_PLANES = tuple(SLOT_PLANE_KINDS)
 
 
 def init_kv_cache(cfg: ModelConfig, batch: int, seq_len: int | None = None,
@@ -108,6 +129,8 @@ def init_kv_cache(cfg: ModelConfig, batch: int, seq_len: int | None = None,
     the HBM read stays int8-sized).
     """
     s = seq_len or cfg.seq_len
+    if cfg.attention_free:
+        return _init_retention(cfg, batch, dtype, quant)
     if cfg.periodic:
         return windowed.init_cache(cfg, batch, s, dtype, quant)
     if cfg.is_mla:
@@ -127,6 +150,21 @@ def init_kv_cache(cfg: ModelConfig, batch: int, seq_len: int | None = None,
 # order is refused even where the sizes coincide
 PAGE_AXES = "ps,Hkv,Dh"
 LATENT_PAGE_AXES = "ps,r|ps,rope"  # a latent (MLA) pool's page, plane by plane
+
+
+def _init_retention(cfg: ModelConfig, rows: int, dtype, quant: bool) -> KVCache:
+    """The cache of a model of retention layers, the contiguous engine's and a
+    slot engine's alike (``rows``: sequences, or slots): no layer has keys and
+    values, so ``k`` / ``v`` have no layer and no position, and what a row
+    leaves behind is its state and its ring of recent positions
+    (``ops/retention.py``), a fixed size whatever the context's depth."""
+    if quant:
+        raise ValueError("a retention state has no int8 form (--kv-quant int8 "
+                         "is refused for this architecture)")
+    dt = dtype or cfg.dtype
+    none = jnp.zeros((0, rows, cfg.n_kv_heads, 0, cfg.head_size), dt)
+    return KVCache(none, none, **retention.init_planes(
+        cfg.n_layers, rows, cfg.n_kv_heads, cfg.head_size, dt))
 
 
 def _init_latent(lead, cfg: ModelConfig, dtype, quant: bool) -> KVCache:
@@ -168,7 +206,11 @@ def init_kv_pool(cfg: ModelConfig, n_pages: int, page_size: int,
     owns a ring of pages bounded by the window (``models/windowed.py
     init_pool``; ``max_pages``: a slot's table width, which bounds the ring).
     A model with convolution layers (``cfg.conv_taps``) likewise: ``k`` / ``v``
-    its attention layers' pool, ``cz`` its slots' convolution state."""
+    its attention layers' pool, ``cz`` its slots' convolution state.  A model
+    with no paged layer at all (``cfg.attention_free``) has a pool of no pages:
+    its slots' states and rings alone, as its contiguous cache."""
+    if cfg.attention_free:
+        return _init_retention(cfg, slots, dtype, quant)
     if cfg.periodic:
         return windowed.init_pool(cfg, n_pages, page_size, dtype, quant, slots,
                                   max_pages or n_pages)
@@ -334,6 +376,61 @@ def _attention_block(x, lp, cfg: ModelConfig, cache: KVCache, cos, sin, pos,
                       sp_on, ring)
         att = att.transpose(0, 2, 1, 3).reshape(b, t, hq * dh)
     return _project_out(att, lp, cfg), cache
+
+
+def _retention_block(x, lp, cfg: ModelConfig, cache: KVCache, cos, sin, pos,
+                     layer, marks, offsets=None, pos_rows=None, packed=None):
+    """One power-retention sub-block (``ops/retention.py`` has the operator and
+    why its state lags the clock).  The projections, the head norms and the
+    gate are row-local and pack; the fold, the ring's write and the read are a
+    per-row sequence operation and keep ``(B, T)``, as ``rope`` and a KV write
+    do.  ``marks``: the call's watermarks ``(w, w_new)``, the same for every
+    layer (``retention.clock``, once a step)."""
+    b, t, _ = x.shape
+    hq, hkv, dh = cfg.n_heads, cfg.n_kv_heads, cfg.head_size
+
+    def project(x):  # row-local: any leading axes
+        with scope("norm"):
+            xb = rmsnorm(x, lp["rms_att"], cfg.norm_eps)
+        with scope("qkv"):
+            if "wqkv" in lp:
+                q, k, v = jnp.split(_mm(xb, lp["wqkv"], cfg),
+                                    [hq * dh, (hq + hkv) * dh], axis=-1)
+            else:
+                q, k, v = (_mm(xb, lp[w], cfg, kind="row") for w in ("wq", "wk", "wv"))
+            lead = x.shape[:-1]
+            q = q.reshape(*lead, hq, dh)
+            k = k.reshape(*lead, hkv, dh)
+            with part("qk_norm"):
+                q = rmsnorm(q, lp["q_norm"], cfg.norm_eps)
+                k = rmsnorm(k, lp["k_norm"], cfg.norm_eps)
+            with part("retention"):  # one gate a kv head, float32
+                lg = jax.nn.log_sigmoid(jnp.matmul(
+                    xb.astype(jnp.float32), lp["wg"].astype(jnp.float32),
+                    precision=jax.lax.Precision.HIGHEST))
+            return q, k, v.reshape(*lead, hkv, dh), lg
+
+    q, k, v, lg = packing.over(packed, "qkv", project, x)
+    with scope("rope"):
+        q = apply_rope(q, cos, sin, interleaved=False).transpose(0, 2, 1, 3)
+        k = apply_rope(k, cos, sin, interleaved=False).transpose(0, 2, 1, 3)
+        v = v.transpose(0, 2, 1, 3)                              # (B, Hkv, T, Dh)
+        lg = lg.transpose(0, 2, 1)                               # (B, Hkv, T)
+    rows = pos_rows if pos_rows is not None else jnp.broadcast_to(pos, (b,))
+    w, w_new = marks
+    c = cache
+    with scope("kv_write"):
+        with part("fold"):
+            rs, rz = retention.fold(c.rs, c.rz, c.rk, c.rv, c.rg, layer, w,
+                                    w_new, floor=offsets)
+        with part("recent"):
+            rk, rv, rg = retention.write(c.rk, c.rv, c.rg, k, v, lg, layer, rows)
+        cache = c._replace(rs=rs, rz=rz, rk=rk, rv=rv, rg=rg)
+    with scope("attn"):
+        att = retention.read(q, rs, rz, rk, rv, rg, layer, rows, w_new,
+                             floor=offsets)
+        att = att.transpose(0, 2, 1, 3).reshape(b, t, hq * dh)
+    return packing.over(packed, "wo", _project_out, att, lp=lp, cfg=cfg), cache
 
 
 def _attend(q, k, v, cache: KVCache, cfg: ModelConfig, pos, t, layer, offsets,
@@ -744,6 +841,13 @@ def run_blocks(params: Params, cfg: ModelConfig, tokens: jax.Array,
         return windowed.run_periods(params, cfg, x, cache, cos, sin, pos,
                                     offsets, pos_rows, paged, packed, n_real)
 
+    marks = None
+    if cfg.retention_degree:  # the watermarks of this call, once for all layers
+        with scope("page_idx"):
+            marks = retention.clock(
+                cache.rw, pos_rows if pos_rows is not None
+                else jnp.broadcast_to(pos, (b,)), t, n_real)
+
     layer_keys = [k for k in params if k not in ("embedding", "rms_final", "wcls")]
     # Packed-Q40 weights stay out of the scan's xs: the scan would slice a
     # per-layer copy of the stacked HBM buffer every step; instead the body
@@ -759,10 +863,15 @@ def run_blocks(params: Params, cfg: ModelConfig, tokens: jax.Array,
         lp = dict(lp)
         for k in qt_keys:
             lp[k] = q40.QLayerView(params[k], idx)
-        att_out, kvc = _attention_block(x, lp, cfg, kvc, cos, sin, pos,
-                                        idx, offsets=offsets,
-                                        pos_rows=pos_rows, paged=paged,
-                                        packed=packed)
+        if marks is not None:
+            att_out, kvc = _retention_block(x, lp, cfg, kvc, cos, sin, pos,
+                                            idx, marks, offsets=offsets,
+                                            pos_rows=pos_rows, packed=packed)
+        else:
+            att_out, kvc = _attention_block(x, lp, cfg, kvc, cos, sin, pos,
+                                            idx, offsets=offsets,
+                                            pos_rows=pos_rows, paged=paged,
+                                            packed=packed)
         if cfg.post_block_norms:
             with scope("norm"):
                 att_out = rmsnorm(att_out, lp["rms_ffn"])  # grokRmfFfnNorm
@@ -787,7 +896,7 @@ def run_blocks(params: Params, cfg: ModelConfig, tokens: jax.Array,
         else:
             def dense(x):
                 with scope("norm"):
-                    xb = rmsnorm(x, lp["rms_ffn"])
+                    xb = rmsnorm(x, lp["rms_ffn"], cfg.norm_eps)
                 return _dense_ffn(xb, lp, cfg)
 
             ff = packing.over(packed, "w2", dense, x)
@@ -802,6 +911,8 @@ def run_blocks(params: Params, cfg: ModelConfig, tokens: jax.Array,
     # measured ~8 ms/token at 7B/1k, comparable to all the matmuls.
     (x, cache), _ = jax.lax.scan(
         block, (x, cache), (jnp.arange(cfg.n_layers), stacked))
+    if marks is not None:
+        cache = cache._replace(rw=marks[1].reshape(cache.rw.shape))
     return x, cache
 
 
@@ -896,7 +1007,7 @@ def forward_last(params: Params, cfg: ModelConfig, tokens: jax.Array,
     the same final index, so the shared ``last_index`` needs no per-row
     variant."""
     x, cache = run_blocks(params, cfg, tokens, cache, pos, offsets=offsets,
-                          n_real=last_index + 1 if cfg.conv_taps else None)
+                          n_real=last_index + 1 if cfg.keeps_state else None)
     with scope("head"):
         x_last = jax.lax.dynamic_slice_in_dim(x, last_index, 1, axis=1)[:, 0]  # (B, D)
     return _head(params, cfg, x_last), cache

@@ -764,7 +764,8 @@ KV_BYTES_PER_TOKEN = REGISTRY.gauge(
 # state rings (ops/conv.py), a fixed size a sequence or a slot
 KV_CACHE_BYTES = REGISTRY.labeled_gauge(
     "kv_cache_bytes", "kind",
-    "Resident bytes of the KV cache by kind of plane (full | window | conv).")
+    "Resident bytes of the KV cache by kind of plane (full | window | conv | "
+    "retention).")
 # the one-stream engine's account of its convolution state ring (runtime/
 # engine.py Engine._state_enter): a call that starts below the highest position
 # written is a rewind; "in_ring": the rows before it were still held;
@@ -774,6 +775,21 @@ CONV_STATE_REWINDS = REGISTRY.labeled_counter(
     "conv_state_rewinds", "outcome",
     "Rewinds of the position clock over a convolution state, by outcome "
     "(in_ring | reprefill).")
+
+# a retention layer's state lags the position clock (ops/retention.py): blocks
+# of FOLD positions folded from a row's ring into its state matrix, counted a
+# layer (the host's mirror of the device's rule, runtime/engine.py), and the
+# one-stream engine's rewinds over such a state: "in_ring" where the clock went
+# back over positions the ring still held, "refused" where they were folded
+# (StateRewindTooDeep; the caller prefills again from position 0)
+RETENTION_FOLDS = REGISTRY.counter(
+    "retention_folds",
+    "Blocks folded from a retention layer's ring of recent positions into its "
+    "state matrix, a row a layer.")
+RETENTION_REWINDS = REGISTRY.labeled_counter(
+    "retention_rewinds", "outcome",
+    "Rewinds of the position clock over a retention state, by outcome "
+    "(in_ring | refused).")
 
 # scheduler goodput accounting (runtime/scheduler.py + obs/flight.py):
 # every millisecond between the scheduler's first and last dispatch lands

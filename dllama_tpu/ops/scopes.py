@@ -23,7 +23,11 @@ layer (LFM2, ``ops/conv.py``) is part ``conv`` of the four scopes it passes
 through, ``qkv`` (``W_in`` and the ``B * X`` gate), ``kv_write`` (the state's
 write), ``attn`` (the state's read, the taps, the ``C`` gate) and ``wo``
 (``W_out``), so that a reader of a scope still reads a whole layer and a
-reader of the part reads the operator alone
+reader of the part reads the operator alone; a retention layer (Brumby,
+``ops/retention.py``) is Llama's block with, inside ``qkv``, the gate's
+``retention``, inside ``kv_write`` the ring's ``recent`` and the state's ``fold``
+(not ``absorb``, which is MLA's), and inside ``attn`` the state's read ``state``
+and the ring's rows ``recent``
 (``PARTS``, by scope): plain sub-names, not scopes.  An
 op's path then ends ``.../moe/experts/...`` and a reader that knows only
 ``SCOPES`` still files it under ``moe``; ``by-scope.json``'s op table
@@ -64,9 +68,12 @@ PARTS = {
         "kv_lora",   # MLA: the down-projection(s) from x, the latent's norm
         "qk_norm",   # K-EXAONE: the RMSNorm of each head of q and of k
         "conv",      # a short-convolution layer's W_in and its B * X gate
+        "retention", # a retention layer's gate: W_g and its logsigmoid
     ),
     "kv_write": (
         "conv",      # a short-convolution layer's state write (the ring of z)
+        "recent",    # a retention layer's write of its ring of recent k, v, log-gate
+        "fold",      # a retention layer's fold of the ring's oldest block into the state
     ),
     "attn": (
         "absorb",    # MLA absorbed form: W_uk into the query, W_uv out of the result
@@ -75,6 +82,8 @@ PARTS = {
         "window",    # a sliding-window layer's read (a row's ring, or a slot's ring of pages)
         "full",      # a periodic model's full layer's read
         "conv",      # a short-convolution layer's state read, taps and C gate
+        "state",     # a retention layer's read of its state matrix and sum
+        "recent",    # a retention layer's attention form over its ring's rows
     ),
     "wo": (
         "conv",      # a short-convolution layer's W_out

@@ -70,10 +70,12 @@ def check_tp_constraint(cfg: ModelConfig, tp: int) -> None:
 
 def param_specs(cfg: ModelConfig) -> dict[str, P]:
     """PartitionSpec per parameter (layer-stacked layouts from params.py)."""
-    if cfg.is_mla or cfg.arch in (mfile.ARCH_EXAONE_MOE, mfile.ARCH_LFM2_MOE):
+    if cfg.is_mla or cfg.attention_free or cfg.arch in (
+            mfile.ARCH_EXAONE_MOE, mfile.ARCH_LFM2_MOE):
         # one device (the engine refuses a tp / sp / ep mesh for these archs):
         # every stack whole, whatever its fused or unfused name
-        return dict.fromkeys(("embedding", "rms_final", "wcls") + MLA_ATT_KEYS
+        return dict.fromkeys(("embedding", "rms_final", "wcls", "rms_att",
+                              "rms_ffn", "wg") + MLA_ATT_KEYS
                              + ATT_KIND_KEYS + CONV_KEYS
                              + DENSE_FFN_KEYS + MOE_FFN_KEYS, REPL)
     specs = {

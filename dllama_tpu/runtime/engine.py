@@ -35,12 +35,13 @@ from .. import hostenv
 from ..io import mfile
 from ..models.config import ModelConfig
 from ..models.params import Params
-from ..models.transformer import (LATENT_PAGE_AXES, PAGE_AXES, forward_last,
+from ..models.transformer import (LATENT_PAGE_AXES, PAGE_AXES,
+                                  SLOT_PLANE_KINDS, forward_last,
                                   init_kv_cache)
 from ..obs import dispatch as obs_dispatch, metrics as obs_metrics, \
     trace as obs_trace
 from ..obs.log import get_logger
-from ..ops import conv, q40, q8
+from ..ops import conv, q40, q8, retention
 from ..parallel import sharding
 from ..parallel.mesh import active_mesh, make_mesh
 from ..sampling import Sampler
@@ -297,11 +298,18 @@ def _refuse_mesh_and_int8(mesh, kv_dtype, what: str, why: str) -> None:
                          "its cache has no int8 form")
 
 
+# what a slot owns, by the kind of its planes (``Engine.slot_state``)
+_SLOT_STATE = {"window": "window layers' rings",
+               "conv": "convolution layers' state",
+               "retention": "retention layers' state"}
+
+
 class StateRewindTooDeep(ValueError):
-    """A call starts so far below the highest position a convolution state was
-    written at that the rows before it have left the state's ring
-    (``ops/conv.py``): the caller resets the engine and prefills the
-    conversation again from position 0."""
+    """A call starts so far below the highest position a recurrent state was
+    written at that the rows before it are no longer there to resume from: they
+    have left a convolution state's ring (``ops/conv.py``), or a retention
+    layer has folded them into its state (``ops/retention.py``).  The caller
+    resets the engine and prefills the conversation again from position 0."""
 
 
 def _note_cache_bytes(cache, tokens: int, batch: int, paged: bool) -> int:
@@ -312,13 +320,16 @@ def _note_cache_bytes(cache, tokens: int, batch: int, paged: bool) -> int:
     the rings saves against ``kind="full"``'s planes per layer.  On a paged
     engine a token ADDS its bytes in the pool alone (the full layers'): a
     slot's ring of pages is there whatever the context's depth.  A
-    convolution state (``kind="conv"``) is a fixed size a sequence: a token
-    adds nothing to it on either engine."""
-    per_token, by_kind = 0, {"full": 0, "window": 0, "conv": 0}
+    convolution state (``kind="conv"``) and a retention layer's state and ring
+    (``kind="retention"``) are a fixed size a sequence: a token adds nothing to
+    them on either engine."""
+    per_token, by_kind = 0, dict.fromkeys(
+        ("full", *SLOT_PLANE_KINDS.values()), 0)
     for name, a in cache.planes().items():
-        kind = {"wk": "window", "wv": "window", "cz": "conv"}.get(name, "full")
+        kind = SLOT_PLANE_KINDS.get(name, "full")
         by_kind[kind] += int(a.nbytes)
-        if kind == "conv" or (paged and kind == "window"):
+        if kind in ("conv", "retention") or (paged and kind == "window") \
+                or not a.size:
             continue
         positions = tokens if kind == "full" else batch * a.shape[3]
         per_token += int(a.nbytes) // positions
@@ -384,6 +395,16 @@ class Engine:
                 f"a {'convolution' if cfg.conv_taps else 'windowed'} "
                 f"({mfile.ARCH_NAMES[cfg.arch]}) model",
                 "its two cache kinds have one placement")
+        if cfg.attention_free:
+            _refuse_mesh_and_int8(
+                self.mesh, kv_dtype,
+                f"a retention ({mfile.ARCH_NAMES[cfg.arch]}) model",
+                "its state a kv head is replicated with its slot")
+            if kv_pages:
+                raise ValueError(
+                    f"a retention ({mfile.ARCH_NAMES[cfg.arch]}) model keeps "
+                    "no keys and values, so it has no pages to count: drop "
+                    "--kv-pages (its slots are admitted by --batch-slots alone)")
         if self.mesh.shape.get("tp", 1) > 1 \
                 and jax.default_backend() != "tpu" \
                 and os.environ.get("DLLAMA_TP_REDUCE", "") != "psum":
@@ -475,9 +496,13 @@ class Engine:
         self.kv_bytes_per_token = _note_cache_bytes(self.cache, tokens, batch,
                                                     self.paged)
         self.pos = 0
-        # the one-stream account of a convolution state's ring: positions
-        # [lo, hi) are held (_state_enter / _state_wrote)
+        # the one-stream account of a recurrent state: a call may start at a
+        # position whose rows before it lie in [lo, hi) (_state_enter /
+        # _state_wrote)
         self._state_lo = self._state_hi = 0
+        # a slot engine's mirror of its slots' retention watermarks, for the
+        # fold counter alone (_note_slot_folds)
+        self._slot_marks = np.zeros(batch, np.int64)
 
         def step(params, cache, tokens, pos, last_index, offsets=None):
             return forward_last(params, cfg, tokens, cache, pos, last_index,
@@ -535,59 +560,83 @@ class Engine:
     # (runtime/stream.py, server/api.py).  KEYS AND VALUES survive that
     # because they are addressed by position: rows above ``pos`` are masked
     # and the next call overwrites them.  A RECURRENT STATE survives it only
-    # if it is addressed by position too and still holds the rows before the
-    # new ``pos``: a convolution layer's is a ring of ``ops/conv.py RING``
-    # positions (the bound R lives there, with the rewinds it was sized
-    # for), and this engine keeps account of which positions the ring holds.
-    # An arch with another state brings its plane into ``KVCache`` and its
-    # account here; nothing else in the engines changes.
+    # if what lies above the new ``pos`` is addressed by position too and
+    # what lies below it is still there: a convolution layer's state is a
+    # ring of ``ops/conv.py RING`` positions, and a retention layer keeps its
+    # newest ``ops/retention.py REWIND`` positions in a ring and OUT of its
+    # state matrix, which absorbs them a block behind the clock (the bounds
+    # live there, with the rewinds they were sized for).  This engine keeps
+    # account of the positions a call may start at, ``[lo, hi]`` less what a
+    # call reads before its first row.  An arch with another state brings its
+    # planes into ``KVCache`` and its two rules here (``_state_reach``,
+    # ``_state_wrote``); nothing else in the engines changes.
+    def _state_reach(self) -> int:
+        """Rows before its first that a one-stream call reads from the state's
+        ring: a convolution's ``conv_taps - 1``; a retention layer reads its
+        ring from the watermark on, whatever it holds."""
+        return self.cfg.conv_taps - 1 if self.cfg.conv_taps else 0
+
     def state_holds(self, pos: int) -> bool:
         """Whether a one-stream call may start at ``pos``: always for keys and
-        values; with a convolution state, only while the ``conv_taps - 1``
-        positions before ``pos`` are in the ring."""
-        if not self.cfg.conv_taps or self.paged or pos == 0:
+        values; with a recurrent state, only while the rows it resumes from
+        are still addressed by position."""
+        if not self.cfg.keeps_state or self.paged or pos == 0:
             return True
-        need = max(pos - (self.cfg.conv_taps - 1), 0)
+        need = max(pos - self._state_reach(), 0)
         return self._state_lo <= need and pos <= self._state_hi
 
     def resume_at(self, pos: int) -> bool:
         """Set the position clock to ``pos``, a conversation's cached end
         (``server/api.py NaiveCache``), if everything the cache keeps still
-        holds it: always for keys and values; a convolution state only while
-        the rows before ``pos`` are in its ring.  Otherwise count a
-        ``reprefill``, reset, and return False: the caller prefills the
-        conversation again from position 0."""
+        holds it: always for keys and values; a recurrent state only while
+        the rows before ``pos`` are in its ring.  Otherwise count the refusal,
+        reset, and return False: the caller prefills the conversation again
+        from position 0."""
         if self.state_holds(pos):
             self.pos = pos
             return True
-        obs_metrics.CONV_STATE_REWINDS.inc("reprefill")
+        self._count_rewind(False)
         self.reset()
         return False
+
+    def _count_rewind(self, held: bool) -> None:
+        if self.cfg.attention_free:
+            obs_metrics.RETENTION_REWINDS.inc("in_ring" if held else "refused")
+        else:
+            obs_metrics.CONV_STATE_REWINDS.inc("in_ring" if held else "reprefill")
 
     def _state_enter(self, pos: int) -> None:
         """Before a one-stream call at ``pos``: count a rewind, refuse one that
         left the ring (module-level :class:`StateRewindTooDeep`)."""
-        if not self.cfg.conv_taps or pos == self._state_hi:
+        if not self.cfg.keeps_state or pos == self._state_hi:
             return
         if pos > self._state_hi:
             raise StateRewindTooDeep(
-                f"a call at position {pos} skips positions the convolution "
+                f"a call at position {pos} skips positions the recurrent "
                 f"state has not seen (written up to {self._state_hi}): "
                 "prefill them first")
-        if self.state_holds(pos):
-            obs_metrics.CONV_STATE_REWINDS.inc("in_ring")
-            return
-        obs_metrics.CONV_STATE_REWINDS.inc("reprefill")
-        raise StateRewindTooDeep(
-            f"position {pos} is more than the convolution state's ring "
-            f"behind the highest position written ({self._state_hi}; the "
-            f"ring holds {self._state_lo}..): reset() and prefill the "
-            "conversation again from position 0")
+        held = self.state_holds(pos)
+        self._count_rewind(held)
+        if not held:
+            raise StateRewindTooDeep(
+                f"position {pos} is behind what the recurrent state still "
+                f"addresses by position (it holds {self._state_lo}.."
+                f"{self._state_hi}: a convolution's ring, or what a retention "
+                "layer has not yet folded into its state): reset() and prefill "
+                "the conversation again from position 0")
 
     def _state_wrote(self, pos: int, n_real: int, rows: int) -> None:
         """After a one-stream call of ``rows`` rows at ``pos`` of which the
-        first ``n_real`` hold a token: the ring holds what it held, less what
-        the rows written (``ops/conv.py written``) displaced."""
+        first ``n_real`` hold a token: what is still addressed by position.
+        A convolution's ring holds what it held, less what the rows written
+        (``ops/conv.py written``) displaced; a retention layer's watermark is
+        where ``ops/retention.py watermark`` puts it."""
+        if self.cfg.attention_free:
+            was = self._state_lo if pos else 0  # position 0 starts a sequence
+            lo = retention.watermark(was, pos + n_real)
+            obs_metrics.RETENTION_FOLDS.inc(
+                (lo - was) // retention.FOLD * self.cfg.n_layers)
+            self._state_lo, self._state_hi = lo, pos + n_real
         if not self.cfg.conv_taps:
             return
         first, count = conv.written(int(n_real), rows, conv.RING,
@@ -597,10 +646,23 @@ class Engine:
                                            pos + count - conv.RING)
         self._state_lo, self._state_hi = max(lo, 0), pos + n_real
 
+    def _note_slot_folds(self, pos_rows_np, clock_np) -> None:
+        """A slot dispatch's folds, for ``retention_folds``: the host's mirror
+        of each slot's watermark, moved by the device's own rule to the clock
+        the dispatch leaves."""
+        if not self.cfg.attention_free:
+            return
+        was = np.where(pos_rows_np == 0, 0, self._slot_marks)
+        self._slot_marks = retention.watermark(was, clock_np)
+        obs_metrics.RETENTION_FOLDS.inc(int(np.sum(
+            (self._slot_marks - was) // retention.FOLD)) * self.cfg.n_layers)
+
     def _max_burst(self, chunk: int) -> int:
-        """A decode burst of a model with a convolution state is capped so
-        that the deepest rewind (two pipelined bursts less one position)
-        stays in the ring."""
+        """A decode burst of a model with a recurrent state is capped so that
+        the deepest rewind (two pipelined bursts less one position) stays
+        addressed by position."""
+        if self.cfg.attention_free:
+            return min(chunk, retention.max_burst())
         if not self.cfg.conv_taps:
             return chunk
         return min(chunk, conv.max_burst(conv.RING, self.cfg.conv_taps))
@@ -655,7 +717,7 @@ class Engine:
             arrays["rng_dev_key"] = np.asarray(self._dev_key)
         meta_extra = dict(extra or {})
         meta_extra.setdefault("sampling_path", self.sampling_path)
-        if self.cfg.conv_taps:
+        if self.cfg.keeps_state:
             meta_extra["conv_state"] = [self._state_lo, self._state_hi]
         if self._offsets is not None:
             arrays["offsets"] = np.asarray(self._offsets)
@@ -748,10 +810,10 @@ class Engine:
         pages), so a 4-slot and an 8-slot replica can exchange requests
         as long as their page geometry matches."""
         from . import snapshot as snapfmt
+        self._refuse_slot_state("per-request hand-off (DLREQ01)")
         if not self.paged:
             raise ValueError("per-request hand-off needs a paged KV cache "
                              "(kv_pages > 0)")
-        self._refuse_slot_state("per-request hand-off (DLREQ01)")
         c = self.cfg
         k = self.cache.k
         fields = {
@@ -802,27 +864,25 @@ class Engine:
 
     @property
     def slot_state(self) -> str:
-        """What a paged engine's slots own beside the pool, which no page id
-        addresses (empty: nothing): a windowed model's rings of pages, a
-        convolution model's state.  The scheduler keeps everything that moves
-        a request's cache page by page off while this is set."""
-        if not self.paged:
+        """What a slot engine's slots own that no page id addresses (empty:
+        nothing): a windowed model's rings of pages, a convolution model's
+        state, a retention model's state and ring (``KVCache``'s
+        ``SLOT_PLANE_KINDS``).  The scheduler keeps everything that moves a
+        request's cache page by page off while this is set."""
+        kinds = {SLOT_PLANE_KINDS[n] for n in self.cache.planes()
+                 if n in SLOT_PLANE_KINDS}
+        if not kinds or not (self.paged or "retention" in kinds):
             return ""
-        if self.cache.cz is not None:
-            return "convolution layers' state"
-        return "window layers' slot rings" if self.ring_pages else ""
+        return _SLOT_STATE[kinds.pop()]
 
     def _refuse_slot_state(self, what: str) -> None:
-        """A windowed model's window layers keep a slot's last ``window``
-        positions in the slot's own ring of pages, and a convolution layer
-        keeps its state in the slot's own row, which no page id addresses:
-        what moves a request's cache page by page is refused by name."""
+        """What moves a request's cache page by page is refused by name for a
+        model whose slots own a state that no page id addresses."""
         if self.slot_state:
-            kind = "convolution" if self.cache.cz is not None else "windowed"
             raise ValueError(
-                f"{what} is not supported for a {kind} "
-                f"({mfile.ARCH_NAMES[self.cfg.arch]}) model: its "
-                f"{self.slot_state} are not carried page by page")
+                f"{what} is not supported for a "
+                f"{mfile.ARCH_NAMES[self.cfg.arch]} model: a slot's "
+                f"{self.slot_state} cannot be carried page by page")
 
     def read_pool_pages(self, pages) -> dict[str, np.ndarray]:
         """Copy the given physical pages out of the paged pool to host
@@ -1554,6 +1614,8 @@ class Engine:
             raise ContextOverflow(
                 f"slot step would write position {hi - 1} past seq_len "
                 f"{self.seq_len}; retire rows at the context edge first")
+        self._note_slot_folds(pos_rows_np,
+                              pos_rows_np + n_valid_np + (steps - 1))
         greedy = bool(np.all(temps_np == 0.0))
         from ..ops.attention import fused_mode
         has_mask = vocab_mask_np is not None
@@ -1675,6 +1737,7 @@ class Engine:
             raise ContextOverflow(
                 f"slot verify would write position {hi - 1} past seq_len "
                 f"{self.seq_len}; retire rows at the context edge first")
+        self._note_slot_folds(pos_rows_np, pos_rows_np + n_valid_np)
         greedy = bool(np.all(temps_np == 0.0))
         from ..ops.attention import fused_mode
         has_mask = vocab_mask_np is not None

@@ -293,15 +293,17 @@ class SlotScheduler:
         # whole request (prompt + budget), so a dispatch can never fail on
         # allocation and exhaustion surfaces as queueing → 429.
         self.paged = bool(getattr(engine, "paged", False))
-        # a windowed model's window layers keep a slot's last ``window``
-        # positions in the slot's own ring of pages (ops/window.py), and a
-        # convolution layer keeps its state in the slot's own row
-        # (ops/conv.py): no page id addresses either.  So nothing may start a
-        # slot past position 0 without having written those positions: the
-        # radix tree stays off (a hit would bind the attention layers' prefix
-        # pages and leave the slot's own state empty; ROADMAP M(a)(3), M(b)),
-        # and so does preemption, whose park and resume move a request page by
-        # page
+        # some models' slots own a state that no page id addresses
+        # (``Engine.slot_state``): a windowed model's window layers keep a
+        # slot's last ``window`` positions in the slot's own ring of pages
+        # (ops/window.py), a convolution layer its state in the slot's own row
+        # (ops/conv.py), a retention layer its state matrix and ring of recent
+        # positions (ops/retention.py; such a model has no pages at all and is
+        # admitted by slot alone).  So nothing may start a slot past position 0
+        # without having written those positions: the radix tree stays off (a
+        # hit would bind the attention layers' prefix pages and leave the
+        # slot's own state empty; ROADMAP M(a)(3), M(b)), and so does
+        # preemption, whose park and resume move a request page by page
         self.ring_pages = int(getattr(engine, "ring_pages", 0))
         self.slot_state = str(getattr(engine, "slot_state", ""))
         if self.slot_state:
@@ -312,10 +314,8 @@ class SlotScheduler:
             if max(int(prefill_chunk), int(spec_k) + 1 if spec else 0) > SLOT_ROWS:
                 raise ValueError(
                     f"a step of more than {SLOT_ROWS} rows (--sched-prefill-chunk "
-                    f"{prefill_chunk}, --spec-k {spec_k}) does not fit "
-                    + ("the slot rings of a windowed model's window layers"
-                       if self.ring_pages else
-                       "the state rings of a convolution model's slots"))
+                    f"{prefill_chunk}, --spec-k {spec_k}) does not fit a "
+                    f"slot's {self.slot_state}")
         self.pool: PagePool | None = None
         self.prefix_cache: RadixTree | None = None
         # KV tiering (runtime/kvtier.py): under ``optimistic`` reservation

@@ -517,3 +517,62 @@ def np_forward_lfm2_moe(params, cfg, tokens, wrong=None):
             x = x + lfm2_moe_layer(m, lp, cfg, wrong)
     x = norm(x, np.asarray(params["rms_final"]))
     return (x @ np.asarray(params["wcls"], np.float32)).astype(np.float32)
+
+
+def np_forward_brumby(params, cfg, tokens, wrong=None):
+    """Brumby's full-sequence forward, (T, V) logits, in the ATTENTION form of
+    power retention: no state, no ring, no ``phi``.  Every layer: per-head q/k
+    RMSNorm, rotate-half RoPE, one gate a kv head ``log gamma = logsigmoid(W_g
+    u)``, scores ``exp(G_t - G_j) (q_t . k_j / sqrt(dh))^2`` for ``j <= t``
+    with ``G`` the gate's running sum (float64 here), the quotient by their sum
+    plus 1e-6; then a dense SwiGLU.
+
+    ``wrong`` names one deliberate fault: ``no_gate`` (gamma = 1), ``no_quotient``
+    (the sum of scores not divided by), ``degree_1`` (scores not squared),
+    ``no_rope``, ``no_head_norm``, ``gate_per_query_head`` (head ``h`` reads
+    gate ``h % n_kv_heads``)."""
+    t = len(tokens)
+    hq, hkv, dh = cfg.n_heads, cfg.n_kv_heads, cfg.head_size
+    pos = np.arange(t)
+
+    def norm(x, w):
+        ms = np.mean(x.astype(np.float64) ** 2, axis=-1, keepdims=True)
+        return (w * (x / np.sqrt(ms + cfg.norm_eps))).astype(np.float32)
+
+    def seg(key, i):
+        return np.asarray(params[key][i], np.float32)
+
+    x = np.asarray(params["embedding"], np.float32)[tokens]
+    mask = pos[None, :] <= pos[:, None]
+    for li in range(cfg.n_layers):
+        u = norm(x, seg("rms_att", li))
+        q = (u @ seg("wq", li)).reshape(t, hq, dh)
+        k = (u @ seg("wk", li)).reshape(t, hkv, dh)
+        v = (u @ seg("wv", li)).reshape(t, hkv, dh)
+        if wrong != "no_head_norm":
+            q, k = norm(q, seg("q_norm", li)), norm(k, seg("k_norm", li))
+        if wrong != "no_rope":
+            q = rope_rotate(q, pos, cfg.rope_theta, False)
+            k = rope_rotate(k, pos, cfg.rope_theta, False)
+        gate = u.astype(np.float64) @ seg("wg", li).astype(np.float64)  # (T, Hkv)
+        cum = np.cumsum(-np.logaddexp(0.0, -gate), axis=0)               # G_t
+        if wrong == "no_gate":
+            cum = np.zeros_like(cum)
+        att = np.zeros((t, hq, dh), np.float32)
+        for h in range(hq):
+            g = h // (hq // hkv)
+            gg = h % hkv if wrong == "gate_per_query_head" else g
+            s = (q[:, h].astype(np.float64) @ k[:, g].astype(np.float64).T
+                 ) / np.sqrt(dh)
+            s = s if wrong == "degree_1" else s * s
+            a = np.where(mask, s * np.exp(np.where(
+                mask, cum[:, None, gg] - cum[None, :, gg], 0.0)), 0.0)
+            num = a @ v[:, g].astype(np.float64)
+            den = 1.0 if wrong == "no_quotient" else \
+                a.sum(-1, keepdims=True) + 1e-6
+            att[:, h] = num / den
+        x = x + att.reshape(t, hq * dh) @ seg("wo", li)
+        n = norm(x, seg("rms_ffn", li))
+        x = x + (silu(n @ seg("w1", li)) * (n @ seg("w3", li))) @ seg("w2", li)
+    x = norm(x, np.asarray(params["rms_final"], np.float32))
+    return (x @ np.asarray(params["wcls"], np.float32)).astype(np.float32)

@@ -559,7 +559,7 @@ def test_mla_parts_are_named_under_the_scopes_the_yardstick_knows(
     from dllama_tpu.ops.scopes import PARTS, SCOPES
     monkeypatch.setattr(mla, "EXPAND_MIN_T", min_t)
     # the last two: K-EXAONE's and a short-convolution layer's (LFM2)
-    assert PARTS["qkv"] == ("q_lora", "kv_lora", "qk_norm", "conv")
+    assert PARTS["qkv"][:4] == ("q_lora", "kv_lora", "qk_norm", "conv")
     assert PARTS["attn"][:3] == ("absorb", "latent", "expand")
     assert PARTS["moe"] == ("router", "experts", "combine", "shared")
     text = jax.jit(lambda p, tk, c: forward(p, CFG, tk, c, jnp.int32(0))).lower(

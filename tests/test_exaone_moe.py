@@ -493,7 +493,8 @@ def test_engine_scheduler_contiguous_and_paged_serve_the_same_tokens(
     assert eng.kv_bytes_per_token == cfg.n_full_layers * tok and eng.ring_pages == 9
     assert eng.cache.k.shape == (2, 49, 4, 2, 8) and eng.cache.wk.shape == (6, 18, 4, 2, 8)
     assert obs_metrics.KV_CACHE_BYTES._values == {
-        ("full",): 2 * 49 * 4 * tok, ("window",): 6 * 18 * 4 * tok, ("conv",): 0}
+        ("full",): 2 * 49 * 4 * tok, ("window",): 6 * 18 * 4 * tok, ("conv",): 0,
+        ("retention",): 0}
     assert obs_metrics.KV_BYTES_PER_TOKEN.value == cfg.n_full_layers * tok
     assert sched.prefix_cache is None and not sched.preempt
     # 42 and 60 positions written (the last token out is not fed) on rings of
@@ -525,13 +526,13 @@ def test_engine_refuses_meshes_by_name(params, axis):
 
 def test_what_moves_a_request_page_by_page_is_refused_by_name(params):
     eng = Engine(CFG, params, mesh=_mesh(), batch=2, kv_pages=49, kv_page_size=4)
-    with pytest.raises(ValueError, match="hand-off .* not supported for a windowed"):
+    with pytest.raises(ValueError, match="hand-off .* not supported for a exaone_moe"):
         eng.handoff_fingerprint()
-    with pytest.raises(ValueError, match="slot rings are not carried page by page"):
+    with pytest.raises(ValueError, match="rings cannot be carried page by page"):
         eng.write_pool_pages([1], {})
     with pytest.raises(ValueError, match="kv-reserve optimistic"):
         SlotScheduler(eng, kv_reserve="optimistic")
-    with pytest.raises(ValueError, match="does not fit the slot rings"):
+    with pytest.raises(ValueError, match="does not fit a slot's window layers' rings"):
         SlotScheduler(eng, prefill_chunk=32)
     sched = SlotScheduler(eng)
     try:

@@ -146,7 +146,7 @@ def test_arch_id_header_keys_and_round_trip(tmp_path, want):
     assert mfile.ARCH_LFM2_MOE == 0xABCD07
     assert mfile.ARCH_NAMES[mfile.ARCH_LFM2_MOE] == "lfm2_moe"
     assert mfile.ARCH_EXT_KEYS[mfile.ARCH_LFM2_MOE] == (19, 23, 24, 31, 32, 34, 37, 38)
-    assert mfile.KEY_MAX == 38
+    assert mfile.KEY_MAX >= 38
     path = tmp_path / "toy.m"
     _write_model(path, want["np"])
     mf = mfile.MFile(str(path))
@@ -889,15 +889,15 @@ def test_engine_refuses_meshes_by_name(params, axis):
 
 def test_what_moves_a_request_page_by_page_is_refused_by_name(params):
     eng = Engine(CFG, params, mesh=_mesh(), batch=2, kv_pages=40, kv_page_size=4)
-    with pytest.raises(ValueError, match="hand-off .* not supported for a convolution"):
+    with pytest.raises(ValueError, match="hand-off .* not supported for a lfm2_moe"):
         eng.handoff_fingerprint()
-    with pytest.raises(ValueError, match="convolution layers' state are not "
-                                         "carried page by page"):
+    with pytest.raises(ValueError, match="convolution layers' state cannot "
+                                         "be carried page by page"):
         eng.write_pool_pages([1], {})
     with pytest.raises(ValueError, match="kv-reserve optimistic"):
         SlotScheduler(eng, kv_reserve="optimistic")
-    with pytest.raises(ValueError, match="does not fit the state rings of a "
-                                         "convolution model's slots"):
+    with pytest.raises(ValueError, match="does not fit a slot's convolution "
+                                         "layers' state"):
         SlotScheduler(eng, prefill_chunk=32)
     sched = SlotScheduler(eng, prefix_reuse=True, preempt=True)
     try:  # the radix tree and preemption are off whatever was asked

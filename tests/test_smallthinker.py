@@ -195,8 +195,9 @@ def test_each_wrong_computation_fails_the_tolerance(want, wrong):
 
 def test_engine_chunked_prefill_equals_one_pass(params, want, monkeypatch):
     eng = Engine(CFG, params, mesh=_mesh(), batch=1)
+    before = obs_metrics.ENGINE_PREFILL_CHUNKS._value  # other files' chunks
     one, _ = eng.prefill([int(t) for t in TOKS[:45]])
-    assert obs_metrics.ENGINE_PREFILL_CHUNKS._value == 0
+    assert obs_metrics.ENGINE_PREFILL_CHUNKS._value == before
     monkeypatch.setattr(config_mod, "PREFILL_PRODUCT_BYTES", SMALL_PRODUCT)
     eng2 = Engine(CFG, params, mesh=_mesh(), batch=1)
     assert eng2.cache.wk.shape[3] == 32 and eng2.cache.k.shape[3] == 96
@@ -382,7 +383,8 @@ def test_engine_scheduler_contiguous_and_paged_serve_the_same_tokens(
     assert solo.kv_bytes_per_token == per_token
     tok = 2 * cfg.kv_dim * 4
     assert obs_metrics.KV_CACHE_BYTES._values == {
-        ("full",): 2 * 96 * tok, ("window",): 6 * 32 * tok, ("conv",): 0}
+        ("full",): 2 * 96 * tok, ("window",): 6 * 32 * tok, ("conv",): 0,
+        ("retention",): 0}
     p1, p2 = [5, 9, 2], [int(t) for t in TOKS[:21]]
     wanted = []
     for p in (p1, p2):
@@ -532,7 +534,8 @@ _OP_NAME = re.compile(r"op_name=\"([^\"]+)\"")
 @pytest.mark.parametrize("t", [1, 6])
 def test_layer_kinds_are_named_under_attn_and_the_router_under_moe(params, t):
     from dllama_tpu.ops.scopes import PARTS, SCOPES
-    assert PARTS["attn"][-3:] == ("window", "full", "conv")  # the last: LFM2's
+    # "conv" is LFM2's; Brumby's "state" and "recent" follow it
+    assert PARTS["attn"][-5:-2] == ("window", "full", "conv")
     obs_dispatch.reset()
     hlo = jax.jit(lambda p, tk, c: forward(p, CFG, tk, c, jnp.int32(3))).lower(
         params, jnp.zeros((1, t), jnp.int32), init_kv_cache(CFG, 1)
