@@ -17,9 +17,10 @@ Q40 weights:
   moe      the mixture-of-experts path at OLMoE-1B-7B's widths and 2 layers:
            a seeded .m through the loader, ``moe_ffn``'s one launch over the
            row's chosen experts (``q40_mm_chosen``) at 1 row against the XLA
-           loop, and its all-experts launches (``q40_mm_experts``, 64 packed
-           experts) at 16 and 256 rows against the XLA-dequantized scan, then
-           the same paged server on that file
+           loop, its all-experts launches (``q40_mm_experts``, 64 packed
+           experts) at 16 rows and its grouped ones (``q40_mm_grouped``, the
+           rows sorted by expert) at 256 against the XLA-dequantized scan,
+           then the same paged server on that file
 
 ``--chips 4`` runs, instead, only the tensor-parallel path and what it is
 compared with: the same files decoded greedily at tp=4 and tp=1.
@@ -165,7 +166,8 @@ def phase_kernels(timeout: float, rehearse: bool = False) -> dict:
 def phase_moe(mpath: str, timeout: float, rehearse: bool = False) -> dict:
     """Child: ``moe_ffn`` on a loaded file, kernel path against the XLA
     path, at one row (select-chosen; the XLA path's form of it is select) and
-    at 16 and 256 (all-experts; the XLA path's form of it is the scan)."""
+    at 16 (all-experts) and 256 (grouped: the rows sorted by expert, PR 53;
+    the XLA path's form of both is the scan)."""
     rc, out = run_child("moe", ["--model", mpath], timeout, rehearse)
     rows, comp = _results(out, "moe")
     for r in rows:
@@ -173,12 +175,12 @@ def phase_moe(mpath: str, timeout: float, rehearse: bool = False) -> dict:
     require(rc == 0, f"moe: child exited {rc}")
     errs = {(r["strategy"], r["rows"]): r for r in rows if "rel_err" in r}
     require(set(errs) == {("select-chosen", 1), ("all-experts", 16),
-                          ("all-experts", 256)}, f"moe: compared {sorted(errs)}")
+                          ("grouped", 256)}, f"moe: compared {sorted(errs)}")
     bad = [r for r in errs.values() if not r["rel_err"] <= r["tol"]]
     require(not bad, f"moe: above tolerance: {bad}")
     ledger = next(r for r in rows if r.get("what") == "ledger")["ledger"]
     require(all(f"moe/{p}×" in ledger for p in
-                ("select-chosen", "select", "all-experts", "scan")),
+                ("select-chosen", "select", "all-experts", "grouped", "scan")),
             f"moe: strategies absent from the ledger: {ledger}")
     if not rehearse:
         require("q40/pallas-fused" in ledger and "DEGRADED" not in ledger,
@@ -698,7 +700,7 @@ def child_moe(argv: list[str], rehearse: bool) -> None:
           "dim": cfg.dim, "expert_width": cfg.hidden_dim})
     obs_dispatch.reset()
     for rows, strategy in ((1, "select-chosen"), (16, "all-experts"),
-                           (256, "all-experts")):
+                           (256, "grouped")):
         x = jax.random.normal(jax.random.PRNGKey(rows), (rows, cfg.dim), dtype)
         t0 = time.perf_counter()
         got = jax.jit(lambda v: moe_ffn(v, lp, cfg.with_(

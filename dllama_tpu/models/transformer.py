@@ -25,6 +25,7 @@ hand-rolls as gather+merge (llama2-tasks.cpp:115-131).
 
 from __future__ import annotations
 
+import functools
 from typing import NamedTuple
 
 import jax
@@ -40,7 +41,7 @@ from ..ops.kernels import ACTIVATIONS, apply_rope, rmsnorm, rope_angles, softmax
 from ..ops.scopes import part, scope
 from ..ops.sp_attention import ring_attention, sp_gqa_attention, sp_update_kv_cache_at
 from ..parallel.mesh import get_active_mesh
-from . import packing, windowed
+from . import grouping, packing, windowed
 from .config import ModelConfig
 from .params import DENSE_FFN_KEYS, MLA_ATT_KEYS, MOE_FFN_KEYS, Params
 
@@ -548,80 +549,51 @@ def moe_ffn(xb2d: jax.Array, lp, cfg: ModelConfig,
     return out
 
 
-def _routed_experts(xb2d: jax.Array, lp, cfg: ModelConfig,
-                    router_logits: jax.Array | None = None) -> jax.Array:
-    """Mixture-of-experts FFN (grok1-tasks.cpp:56-228 semantics).
+@functools.partial(jax.jit, static_argnames=("held", "tr", "act", "impl", "dtype"))
+def _grouped_experts(xb2d, sel_idx, sel_w, here, stacks, layers, *, held: int,
+                     tr: int, act, impl: str, dtype):
+    """:func:`_routed_experts`'s strategy ``grouped`` from the router's choice
+    on: the pairs sorted by expert into blocks of ``tr`` rows, a block one
+    expert's (``models/grouping.py``), gate, up and down one launch each of
+    ``q40_mm_grouped`` over the blocks that hold rows, and a row's k results
+    gathered and summed.  Returns the rows' outputs and the blocks used.
 
-    Routing: softmax over *all* expert logits, top-k, renormalize the
-    selected probabilities (grokMoeRouterSoftmax/Topk/NormWeights,
-    grok1-tasks.cpp:60-114); OLMoE (``not cfg.norm_topk_prob``) uses the
-    selected probabilities as they are.  DeepSeek-V2 (``cfg.n_groups > 1``)
-    chooses in two stages, ``topk_groups`` groups by their best expert and
-    then the top-k of the experts in them, and scales the chosen
-    probabilities by ``cfg.routed_scale``; every strategy below takes its
-    experts and weights from this one choice.  K-EXAONE
-    (``cfg.router_sigmoid``) scores with a sigmoid, adds ``lp["router_bias"]``
-    for the choice only, and normalises and scales the chosen scores; LFM2
-    does the same over the sum ``+ cfg.router_norm_eps``.  The
-    logits are this layer's
-    FFN input times ``lp["router"]`` unless the caller hands ``router_logits``
-    ``(N, E)`` made elsewhere (SmallThinker's router reads the layer's input
-    before attention, ``models/windowed.py``).
-
-    A layer may hold planes for a run of its experts only
-    (``cfg.n_experts_held`` of them from ``cfg.first_expert``: one chip's share
-    of an expert-parallel deployment).  The router then still chooses among all
-    ``E`` and weighs over all ``k`` chosen, and the result is the held experts'
-    part of the sum: a chosen expert that lives elsewhere contributes nothing
-    here (every strategy: weight 0; ``all-experts`` and the loops never visit
-    it, ``select-chosen`` / ``select`` point its grid step at a held plane,
-    which is read in its stead).  The ledger records ``held`` beside
-    ``experts``.
-
-    Sub-scopes inside ``moe``: ``router`` (logits, softmax, top-k),
-    ``experts`` (the expert matmuls and, on the scan, its bookkeeping),
-    ``combine`` (the weighted sum and the cast to the activation dtype).  Each
-    compiled call site records its strategy in the dispatch ledger as
-    ``{codec="moe", path="select"|"select-chosen"|"all-experts"|"scan"|"unrolled"|
-    "dense"}``.
-
-    Execution strategies, chosen statically (token count, packed or not,
-    mesh, kernel path; no flag):
-    * up to 4 tokens: compute only the k selected experts, so HBM reads are
-      bounded by the k active experts' *packed* bytes (the reference likewise
-      keeps MoE Q40 end-to-end, transformer.cpp:299-317).  Packed Q40 experts
-      on one device with the fused kernel chosen (``q40.all_experts_impl``;
-      every one-stream decode step on a TPU) take ``select-chosen``: a row's
-      k planes are a grid axis of ``q40_mm_chosen``, gate, up and down one
-      launch each, then one weighted sum over k.  With ``quant_impl="xla"``,
-      on any mesh, and with Q80 experts (``select``) each (token, slot) pair
-      runs the fused dequant-matmul on a ``QLayerView`` whose flat index
-      selects the expert; dense experts use a gather + einsum.
-    * more tokens: run every expert and mask — regular shapes on the MXU.
-      Packed Q40 experts on one device with the fused kernel chosen
-      (``q40.all_experts_impl``; every served step and prefill on a TPU) take
-      ``all-experts``: gate, up and down are one launch each of
-      ``q40_mm_experts`` over all E experts, then one weighted sum over E.
-      With ``quant_impl="xla"``, on any mesh, and with Q80 experts, the loop
-      over experts stays: a static unroll up to MOE_PREFILL_UNROLL_MAX
-      (``unrolled``), a ``lax.scan`` past it (``scan``), one expert's weights
-      dequantized at a time.  Dense experts: one einsum (``dense``).
-
-    Experts are TP-sliced like the reference (all experts on all shards,
-    hidden dim sharded — transformer.cpp:299-317).  Under an ``ep`` mesh
-    axis the expert stacks additionally shard over experts — dense via the
-    PartitionSpecs (GSPMD inserts the gather), packed Q40 via the fused
-    kernel's per-shard flat-index decode + psum (q40._sharded_matmul_ep) —
-    so MoE weight residency scales 1/ep in both layouts.
-    """
+    ``stacks`` / ``layers``: the three expert stacks (``QTensor``) and the
+    traced index of this layer's planes in each; ``here``: the pairs whose
+    expert this chip holds (None: all).  One jitted function, so that a
+    program's expert layers, all of one shape, are traced and lowered once
+    and not once a layer: a prompt's program started 0.4 to 0.6 s later a
+    program without it (PERF.md section 6, PR 53).  Its cache outlives a
+    program, so the dispatch ledger is the caller's to write: nothing in here
+    records a site."""
     n, d = xb2d.shape
-    e, k = cfg.n_experts, cfg.n_active_experts
-    # the stacks' planes a layer: all e, or this chip's share of them
-    held, first = cfg.n_experts_held, cfg.first_expert
-    share = held != e
-    site = dict(experts=e, held=held) if share else dict(experts=e)
-    act = ACTIVATIONS[cfg.hidden_act]
+    m = grouping.blocks(n, sel_idx.shape[1], held, tr)
+    gate, up, down = (q40.QLayerView(qt, i) for qt, i in zip(stacks, layers))
+    with part("router"):
+        gp = grouping.plan(sel_idx, held, tr, here)
+    with part("experts"):
+        xg = xb2d.at[gp.gather].get(mode="promise_in_bounds").reshape(m, tr, d)
+        launch = functools.partial(q40.matmul_experts, experts=held, impl=impl,
+                                   chosen=gp.planes, used=gp.used, record=False)
+        g, u = launch(xg, gate), launch(xg, up)
+        o = launch(ACTIVATIONS[act](g) * u, down, out_dtype=jnp.float32)  # (M, tr, D)
+    with part("combine"):
+        # a row's k pairs, where the plan put them; a pair of an expert held
+        # elsewhere has no slot and what stands at the one it reads may be
+        # anything
+        own = o.reshape(m * tr, d).at[gp.slot].get(mode="promise_in_bounds")
+        if here is not None:
+            own = jnp.where(here[..., None], own, 0.0)
+        return (sel_w[..., None] * own).sum(1).astype(dtype), gp.used
 
+
+def route(xb2d: jax.Array, lp, cfg: ModelConfig,
+          router_logits: jax.Array | None = None
+          ) -> tuple[jax.Array, jax.Array]:
+    """The router's choice for every row: ``(top_idx, weights)``, both
+    ``(N, k)``, the experts among all ``cfg.n_experts`` and what each weighs
+    (:func:`_routed_experts` says how, per architecture)."""
+    n, e, k = xb2d.shape[0], cfg.n_experts, cfg.n_active_experts
     with part("router"):
         if router_logits is None:
             router = lp["router"]
@@ -651,6 +623,96 @@ def _routed_experts(xb2d: jax.Array, lp, cfg: ModelConfig,
             weights = top_vals / total
         if cfg.routed_scale != 1.0:
             weights = weights * jnp.float32(cfg.routed_scale)
+    return top_idx, weights
+
+
+def _routed_experts(xb2d: jax.Array, lp, cfg: ModelConfig,
+                    router_logits: jax.Array | None = None) -> jax.Array:
+    """Mixture-of-experts FFN (grok1-tasks.cpp:56-228 semantics).
+
+    Routing: softmax over *all* expert logits, top-k, renormalize the
+    selected probabilities (grokMoeRouterSoftmax/Topk/NormWeights,
+    grok1-tasks.cpp:60-114); OLMoE (``not cfg.norm_topk_prob``) uses the
+    selected probabilities as they are.  DeepSeek-V2 (``cfg.n_groups > 1``)
+    chooses in two stages, ``topk_groups`` groups by their best expert and
+    then the top-k of the experts in them, and scales the chosen
+    probabilities by ``cfg.routed_scale``; every strategy below takes its
+    experts and weights from this one choice.  K-EXAONE
+    (``cfg.router_sigmoid``) scores with a sigmoid, adds ``lp["router_bias"]``
+    for the choice only, and normalises and scales the chosen scores; LFM2
+    does the same over the sum ``+ cfg.router_norm_eps``.  The
+    logits are this layer's
+    FFN input times ``lp["router"]`` unless the caller hands ``router_logits``
+    ``(N, E)`` made elsewhere (SmallThinker's router reads the layer's input
+    before attention, ``models/windowed.py``).
+
+    A layer may hold planes for a run of its experts only
+    (``cfg.n_experts_held`` of them from ``cfg.first_expert``: one chip's share
+    of an expert-parallel deployment).  The router then still chooses among all
+    ``E`` and weighs over all ``k`` chosen, and the result is the held experts'
+    part of the sum: a chosen expert that lives elsewhere contributes nothing
+    here (every strategy: weight 0; ``all-experts`` and the loops never visit
+    it, ``grouped`` gives the pair no slot, ``select-chosen`` / ``select``
+    point its grid step at a held plane, which is read in its stead).  The
+    ledger records ``held`` beside ``experts``.
+
+    Sub-scopes inside ``moe``: ``router`` (logits, softmax, top-k),
+    ``experts`` (the expert matmuls and, on the scan, its bookkeeping),
+    ``combine`` (the weighted sum and the cast to the activation dtype).  Each
+    compiled call site records its strategy in the dispatch ledger as
+    ``{codec="moe", path="select"|"select-chosen"|"all-experts"|"grouped"|"scan"|
+    "unrolled"|"dense"}``.
+
+    Execution strategies, chosen statically (token count, packed or not,
+    mesh, kernel path; no flag):
+    * up to 4 tokens: compute only the k selected experts, so HBM reads are
+      bounded by the k active experts' *packed* bytes (the reference likewise
+      keeps MoE Q40 end-to-end, transformer.cpp:299-317).  Packed Q40 experts
+      on one device with the fused kernel chosen (``q40.all_experts_impl``;
+      every one-stream decode step on a TPU) take ``select-chosen``: a row's
+      k planes are a grid axis of ``q40_mm_chosen``, gate, up and down one
+      launch each, then one weighted sum over k.  With ``quant_impl="xla"``,
+      on any mesh, and with Q80 experts (``select``) each (token, slot) pair
+      runs the fused dequant-matmul on a ``QLayerView`` whose flat index
+      selects the expert; dense experts use a gather + einsum.
+    * 5 to 16 tokens: run every expert and mask.  Packed Q40 experts on one
+      device with the fused kernel chosen (``q40.all_experts_impl``; every
+      served pure-decode step on a TPU) take ``all-experts``: gate, up and down
+      are one launch each of ``q40_mm_experts`` over all E experts, then one
+      weighted sum over E.
+    * more than 16 tokens, on the same condition (a prompt's bucket, a prefill
+      chunk, a packed mixed step): ``grouped``, a row goes to its own k
+      experts.  The ``rows x k`` pairs are sorted by expert into blocks of
+      ``tr`` rows, a block one expert's (``models/grouping.py``: ``tr`` from
+      the mean rows an expert gets, one choice a call site), and gate, up and
+      down are one launch each of ``q40_mm_grouped`` over the blocks that hold
+      rows, each block's plane in a prefetched vector; then a row's k results
+      are gathered and summed.  No ``(E, rows, .)`` array is built.  The
+      result is ``all-experts``'s but for the order of a float32 sum over k
+      terms, where that one summed E of which E - k were zeros.
+    * more tokens otherwise: run every expert and mask.
+      With ``quant_impl="xla"``, on any mesh, and with Q80 experts, the loop
+      over experts stays: a static unroll up to MOE_PREFILL_UNROLL_MAX
+      (``unrolled``), a ``lax.scan`` past it (``scan``), one expert's weights
+      dequantized at a time.  Dense experts: one einsum (``dense``).
+
+    Experts are TP-sliced like the reference (all experts on all shards,
+    hidden dim sharded — transformer.cpp:299-317).  Under an ``ep`` mesh
+    axis the expert stacks additionally shard over experts — dense via the
+    PartitionSpecs (GSPMD inserts the gather), packed Q40 via the fused
+    kernel's per-shard flat-index decode + psum (q40._sharded_matmul_ep) —
+    so MoE weight residency scales 1/ep in both layouts.
+    """
+    n, d = xb2d.shape
+    e, k = cfg.n_experts, cfg.n_active_experts
+    # the stacks' planes a layer: all e, or this chip's share of them
+    held, first = cfg.n_experts_held, cfg.first_expert
+    share = held != e
+    site = dict(experts=e, held=held) if share else dict(experts=e)
+    act = ACTIVATIONS[cfg.hidden_act]
+
+    top_idx, weights = route(xb2d, lp, cfg, router_logits)
+    with part("router"):
         if share:
             # the few-row strategies index planes: a chosen expert held
             # elsewhere gets weight 0 and a held plane to stand on
@@ -714,14 +776,32 @@ def _routed_experts(xb2d: jax.Array, lp, cfg: ModelConfig,
         with part("combine"):
             return jnp.einsum("nk,nkd->nd", sel_w.astype(out.dtype), out)
 
+    kernel = quant and q40.all_experts_impl(
+        (lp["gate"], lp["up"], lp["down"]), n, cfg.quant_impl)
+    tr = grouping.block_rows(n, k, e) if kernel else None
+    if tr:
+        # packed experts on the fused kernel, one device, more than 16 rows:
+        # a row goes to its own k experts
+        m = grouping.blocks(n, k, held, tr)
+        obs_dispatch.record_dispatch("moe", "grouped", rows=n, tr=tr, **site,
+                                     blocks=m)
+        views = [lp[w] for w in ("gate", "up", "down")]
+        for v in views:  # the three launches of the jitted block
+            q40.record_experts_site(tr, v, m)
+        out, used = _grouped_experts(
+            xb2d, sel_idx, sel_w, here if share else None,
+            [v.qt for v in views], [v.layer for v in views], held=held, tr=tr,
+            act=cfg.hidden_act, impl=kernel, dtype=cfg.dtype)
+        # the pairs that took a slot: under ``share`` those held here
+        grouping.note(here if share else n * k, tr, used)
+        return out
+
     with part("router"):
         dense_w = jnp.zeros((n, e), weights.dtype)
         dense_w = jnp.put_along_axis(dense_w, top_idx, weights, axis=-1, inplace=False)
         if share:  # the held experts' columns; from here on ``e`` planes = held
             dense_w = dense_w[:, first:first + held]
 
-    kernel = quant and q40.all_experts_impl(
-        (lp["gate"], lp["up"], lp["down"]), n, cfg.quant_impl)
     if kernel:
         # packed experts on the fused kernel, one device: the expert index is
         # a grid axis, three launches a layer whatever E; every expert is
@@ -909,7 +989,7 @@ def run_blocks(params: Params, cfg: ModelConfig, tokens: jax.Array,
     # xs/ys makes XLA slice out and restack a full layer slab per step and
     # defensively copy the whole cache in the enclosing decode loop —
     # measured ~8 ms/token at 7B/1k, comparable to all the matmuls.
-    (x, cache), _ = jax.lax.scan(
+    (x, cache), _ = grouping.scan(
         block, (x, cache), (jnp.arange(cfg.n_layers), stacked))
     if marks is not None:
         cache = cache._replace(rw=marks[1].reshape(cache.rw.shape))
@@ -941,7 +1021,7 @@ def _run_segments(params: Params, cfg: ModelConfig, x, cache: KVCache, cos,
                 x = x + att_out
             return (ffn(x, lp), kvc), None
 
-        return jax.lax.scan(block, carry, jnp.arange(count, dtype=jnp.int32))[0]
+        return grouping.scan(block, carry, jnp.arange(count, dtype=jnp.int32))[0]
 
     def normed(x, lp):
         with scope("norm"):

@@ -46,7 +46,7 @@ from ..ops.attention import (gqa_attention_at, live_gqa_attention,
                              update_kv_cache_at)
 from ..ops.kernels import apply_rope, rmsnorm
 from ..ops.scopes import part, scope
-from . import packing
+from . import grouping, packing
 from .config import ModelConfig
 from .params import ATT_KIND_KEYS, CONV_KEYS, DENSE_FFN_KEYS, MOE_FFN_KEYS
 
@@ -361,6 +361,6 @@ def run_periods(params, cfg: ModelConfig, x, cache, cos, sin, pos, offsets,
                               min(n_dense - p * period, period))
     n_periods = cfg.n_layers // period
     if n_periods > lead:
-        carry, _ = jax.lax.scan(one_period, carry,
-                                jnp.arange(lead, n_periods, dtype=jnp.int32))
+        carry, _ = grouping.scan(one_period, carry,
+                                 jnp.arange(lead, n_periods, dtype=jnp.int32))
     return carry

@@ -616,6 +616,13 @@ ENGINE_PREFILL_CHUNKS = REGISTRY.counter(
     "engine_prefill_chunks",
     "Calls of a chunked prefill (Engine.prefill of a prompt longer than one "
     "chunk): the whole chunks and the tail.")
+MOE_GROUPED_ROWS = REGISTRY.labeled_counter(
+    "moe_grouped_rows", ("what",),
+    "Rows the grouped expert launches of prefill calls worked on, summed over "
+    "layers (moe_ffn's strategy `grouped`, models/grouping.py): pairs (the "
+    "(row, expert) pairs the router made that this chip holds the expert of) "
+    "and slots (the rows of the blocks those pairs took: blocks used x rows a "
+    "block).  pairs / slots is how full the blocks were.")
 ENGINE_CACHE_HITS = REGISTRY.counter(
     "engine_executable_cache_hits",
     "Engine steps served by an already-compiled executable.")

@@ -547,7 +547,8 @@ def _contract_dot(x_ref, vi, s32) -> jax.Array:
     return jnp.dot(x_ref[:], w, preferred_element_type=jnp.float32)
 
 
-def _q40_kernel(x_ref, qp_ref, s_ref, o_ref, acc_ref, *, nsteps, n_axis=1):
+def _q40_kernel(x_ref, qp_ref, s_ref, o_ref, acc_ref, *, nsteps, n_axis=1,
+                live=None):
     """One (tile_n × tile_d) fused dequant-matmul step: the weight tile is
     unpacked once and contracted against every activation row of the block
     (all rows, or one row block of the row-blocked form, whose reduction axis
@@ -566,8 +567,20 @@ def _q40_kernel(x_ref, qp_ref, s_ref, o_ref, acc_ref, *, nsteps, n_axis=1):
     At one row (:func:`_contract_grouped`) the raw nibbles go to the dot and
     bias and scale are applied to the block partials it returns.  This is no
     lower precision: no weight is rounded to bf16, and the result is the f32
-    dequantization's up to summation order."""
+    dequantization's up to summation order.
+
+    ``live`` (a traced predicate, the grouped launch's): the whole step runs
+    under it, and where it is false nothing is unpacked, contracted or
+    stored."""
     i = pl.program_id(n_axis)
+    if live is not None:
+        return pl.when(live)(functools.partial(
+            _q40_step, i, x_ref, qp_ref, s_ref, o_ref, acc_ref, nsteps))
+    _q40_step(i, x_ref, qp_ref, s_ref, o_ref, acc_ref, nsteps)
+
+
+def _q40_step(i, x_ref, qp_ref, s_ref, o_ref, acc_ref, nsteps):
+    """Reduction step ``i`` of ``nsteps`` of :func:`_q40_kernel`."""
     qp = qp_ref[...]                                      # (tn/2, td) uint8
     tn2, td = qp.shape[-2:]
     qp = qp.reshape(tn2, td)
@@ -599,6 +612,19 @@ def _stacked_q40_kernel(lidx_ref, x_ref, qp_ref, s_ref, o_ref, acc_ref, **kw):
     _q40_kernel(x_ref, qp_ref, s_ref, o_ref, acc_ref, **kw)
 
 
+def _grouped_q40_kernel(planes_ref, used_ref, x_ref, qp_ref, s_ref, o_ref,
+                        acc_ref, **kw):
+    """A block of rows against its expert's plane (``q40_mm_grouped``): grid
+    axis 0 walks the blocks, of which the first ``used_ref[0]`` hold rows.  The
+    others do nothing: their input index maps stand on the last tile fetched,
+    so nothing is read for them; each still writes its output tile back, as
+    it found it (``tr x tile_d`` float32 that nobody reads: 6 MB a launch at
+    LFM2's 256 rows, microseconds)."""
+    del planes_ref  # consumed by the index_maps
+    _q40_kernel(x_ref, qp_ref, s_ref, o_ref, acc_ref, **kw,
+                live=pl.program_id(0) < used_ref[0])
+
+
 def _row_block(t: int, tile_n: int, tile_d: int) -> int | None:
     """Rows per block of the row-blocked form; None up to PALLAS_MAX_ROWS,
     where one block holds every row and the program is the one it always was.
@@ -621,7 +647,8 @@ def _row_block(t: int, tile_n: int, tile_d: int) -> int | None:
 
 def _mm_call(t: int, n: int, d: int, tile_n: int, tile_d: int,
              stacked: bool, row_block: int | None, experts: int = 0,
-             x_per_expert: bool = False, chosen: bool = False, **ms):
+             x_per_expert: bool = False, chosen: bool = False,
+             grouped: bool = False, **ms):
     """What the three kernels share of their ``pallas_call``: grid and specs
     (as keywords), compiler parameters, and the kernel's own keywords.  The
     grid is ``(d tiles, n steps)`` with every row in the block up to
@@ -632,9 +659,15 @@ def _mm_call(t: int, n: int, d: int, tile_n: int, tile_d: int,
     output is ``(experts, t, d)``, and the activation is one ``(t, n)`` array
     for every expert (its index map ignores ``e``) or, with ``x_per_expert``,
     ``(experts, t, n)``: one ``(rows, tile_n)`` block a grid step, as the
-    caller holds it."""
+    caller holds it.  ``grouped``: the ``experts`` axis walks blocks of rows,
+    each with its plane in the prefetched vector, and a second prefetched
+    scalar says how many of them hold rows; a block past it keeps every input
+    index where the last step of the last block that does left it (the output
+    index moves on: a tile two blocks share would be written by whichever
+    core ends last where the block axis is split over cores)."""
     tr = row_block or _row_block(t, tile_n, tile_d)
     nd, nn = pl.cdiv(d, tile_d), n // tile_n
+    assert not grouped or (tr is None and chosen and x_per_expert)
     tb = t if tr is None else tr
     grid = ((() if tr is None else (pl.cdiv(t, tr),))
             + ((experts,) if experts else ()) + (nd, nn))
@@ -654,19 +687,36 @@ def _mm_call(t: int, n: int, d: int, tile_n: int, tile_d: int,
             return (l[0][e],)
         return (l[0][0] * experts + e,) if experts else tuple(ref[0] for ref in l)
 
+    def held(e, j, i, l):
+        """Grid indices as the index maps use them: a grouped launch's blocks
+        with no rows stand still on the last block that has some."""
+        if not grouped:
+            return e, j, i
+        live = e < l[1][0]
+        return (jnp.where(live, e, jnp.maximum(l[1][0] - 1, 0)),
+                jnp.where(live, j, nd - 1), jnp.where(live, i, nn - 1))
+
     # an expert axis of a block is squeezed (None): the kernel sees 2-D refs
     ex = lambda on, e: (e,) if on else ()  # noqa: E731
     params = pltpu.CompilerParams(
         dimension_semantics=("parallel",) * (len(grid) - 1) + ("arbitrary",),
         vmem_limit_bytes=None if tr is None else ROW_VMEM_LIMIT)
     lead = (1,) if stacked else ()
-    w_at = at(lambda r, e, j, i, *l: plane(e, l) + (i, j))
+
+    @at
+    def w_at(r, e, j, i, *l):
+        e, j, i = held(e, j, i, l)
+        return plane(e, l) + (i, j)
+
+    @at
+    def x_at(r, e, j, i, *l):
+        e, j, i = held(e, j, i, l)
+        return ex(x_per_expert, e) + (r, i)
+
     grid_kw = dict(
         grid=grid,
         in_specs=[
-            pl.BlockSpec(
-                ex(x_per_expert, None) + (tb, tile_n),
-                at(lambda r, e, j, i, *l: ex(x_per_expert, e) + (r, i)), **ms),
+            pl.BlockSpec(ex(x_per_expert, None) + (tb, tile_n), x_at, **ms),
             pl.BlockSpec(lead + (tile_n // 2, tile_d), w_at, **ms),
             pl.BlockSpec(lead + (tile_n // 32, tile_d), w_at, **ms),
         ],
@@ -742,13 +792,17 @@ def _pallas_matmul_experts(x: jax.Array, qpacked: jax.Array, scales: jax.Array,
                            interpret: bool = False,
                            tiles: tuple[int, int] | None = None,
                            row_block: int | None = None,
-                           chosen: jax.Array | None = None) -> jax.Array:
+                           chosen: jax.Array | None = None,
+                           used: jax.Array | None = None) -> jax.Array:
     """Experts of one layer in one launch: ``x`` against planes
     ``layer * experts + e`` of the flat ``(L * experts, n/2, d)`` stack, for
     every ``e`` in ``range(experts)`` → ``(experts, t, d)`` f32
     (``q40_mm_experts``), or for the ``k`` traced indices ``chosen`` alone →
     ``(k, t, d)`` (``q40_mm_chosen``: one row's routed experts; an index may
-    repeat).
+    repeat).  With ``used`` (a traced scalar) the ``k`` entries are blocks of
+    ``t`` rows, each wholly one expert's, of which the first ``used`` hold
+    rows and the others cost nothing (``q40_mm_grouped``: a prompt's rows
+    sorted by expert, ``models/grouping.py``).
 
     ``x`` is ``(t, n)``, shared by all experts (gate, up), or
     ``(experts | k, t, n)``, one activation block an expert (down).  The
@@ -765,18 +819,24 @@ def _pallas_matmul_experts(x: jax.Array, qpacked: jax.Array, scales: jax.Array,
         k, planes, name = experts, layer.reshape(1), "q40_mm_experts"
     else:
         k, planes, name = len(chosen), layer * experts + chosen, "q40_mm_chosen"
+    prefetched = (planes.astype(jnp.int32),)
+    kernel = _stacked_q40_kernel
+    if used is not None:
+        name, kernel = "q40_mm_grouped", _grouped_q40_kernel
+        prefetched += (used.reshape(1).astype(jnp.int32),)
     grid_kw, params, kernel_kw = _mm_call(
         t, n, d, tile_n, tile_d, True, row_block, experts=k,
-        x_per_expert=x.ndim == 3, chosen=chosen is not None)
+        x_per_expert=x.ndim == 3, chosen=chosen is not None,
+        grouped=used is not None)
     return pl.pallas_call(
-        functools.partial(_stacked_q40_kernel, **kernel_kw),
-        grid_spec=pltpu.PrefetchScalarGridSpec(num_scalar_prefetch=1,
-                                               **grid_kw),
+        functools.partial(kernel, **kernel_kw),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=len(prefetched), **grid_kw),
         out_shape=jax.ShapeDtypeStruct((k, t, d), jnp.float32),
         compiler_params=params,
         interpret=interpret,
         name=name,
-    )(planes.astype(jnp.int32), x.astype(jnp.bfloat16), qpacked, scales)
+    )(*prefetched, x.astype(jnp.bfloat16), qpacked, scales)
 
 
 @dataclass(frozen=True)
@@ -1170,20 +1230,34 @@ def all_experts_impl(views, rows: int, impl: str) -> str | None:
     return None if "xla" in impls else impls.pop()
 
 
+def record_experts_site(rows: int, qt: QLayerView, planes: int) -> None:
+    """The dispatch records of one :func:`matmul_experts` launch of ``rows``
+    rows a plane over ``planes`` planes (every expert, a row's chosen, or a
+    grouped launch's blocks)."""
+    _record_site(rows, qt.qt.qpacked.shape[-2] * 2, qt.logical_nd[1], None, 1,
+                 experts=planes)
+
+
 def matmul_experts(x: jax.Array, qt: QLayerView, experts: int, impl: str,
-                   out_dtype=None, chosen: jax.Array | None = None) -> jax.Array:
+                   out_dtype=None, chosen: jax.Array | None = None,
+                   used: jax.Array | None = None, record: bool = True) -> jax.Array:
     """``x @ dequantize(expert e of the view's layer)`` in one launch of the
     fused kernel on one device (``impl`` from :func:`all_experts_impl`), for
     every ``e``: ``x`` ``(t, n)`` shared or ``(experts, t, n)`` →
     ``(experts, t, d)``; or, with ``chosen`` ``(k,)`` traced indices, for those
     alone: ``x`` shared or ``(k, t, n)`` → ``(k, t, d)``, only their planes
-    read.  The view's ``layer`` indexes the lead dims in front of the expert
-    axis."""
+    read; with ``used`` besides, ``chosen`` names the plane of each of ``k``
+    blocks of ``t`` rows, ``x`` ``(k, t, n)``, and the blocks from ``used`` on
+    are skipped.  The view's ``layer`` indexes the lead dims in front of the
+    expert axis.  ``record=False``: the caller records the site
+    (:func:`record_experts_site`), because it is traced under a ``jax.jit`` of
+    its own whose cache would swallow a later program's records."""
     x = _pad_x(x, qt.logical_nd[0], qt.qt.qpacked.shape[-2] * 2)
-    _record_site(x.shape[-2], x.shape[-1], qt.logical_nd[1], None, 1,
-                 experts=experts if chosen is None else len(chosen))
+    if record:
+        record_experts_site(x.shape[-2], qt,
+                            experts if chosen is None else len(chosen))
     out = _pallas_matmul_experts(x, *qt.flat_planes(), qt.layer,
-                                 experts=experts, chosen=chosen,
+                                 experts=experts, chosen=chosen, used=used,
                                  interpret=impl == "pallas_interpret")
     return out.astype(out_dtype or x.dtype)
 

@@ -7,8 +7,8 @@ whatever instruction numbers the compile gave (docs/OBSERVABILITY.md,
 "Device scopes").  A scope changes metadata only: no executable, cache key
 or number moves.  Which weight a Pallas matmul read is told by the scope;
 its family by the kernel's ``name=`` (``q40_mm``, ``q40_mm_stacked``,
-``q40_mm_experts``, ``q40_mm_chosen``, ``q40_ring``, ``q8_mm``,
-``q8_mm_stacked``, ``paged_attn_fused``).
+``q40_mm_experts``, ``q40_mm_chosen``, ``q40_mm_grouped``, ``q40_ring``,
+``q8_mm``, ``q8_mm_stacked``, ``paged_attn_fused``).
 
 The tuple is an interface: the benchmark's readers
 (``benchmarks/layer_metrics/_scopes.py``) hold a copy and a test compares

@@ -33,6 +33,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from .. import hostenv
 from ..io import mfile
+from ..models import grouping
 from ..models.config import ModelConfig
 from ..models.params import Params
 from ..models.transformer import (LATENT_PAGE_AXES, PAGE_AXES,
@@ -505,8 +506,14 @@ class Engine:
         self._slot_marks = np.zeros(batch, np.int64)
 
         def step(params, cache, tokens, pos, last_index, offsets=None):
-            return forward_last(params, cfg, tokens, cache, pos, last_index,
-                                offsets=offsets)
+            # a third output where some layer sorted its rows by expert (a
+            # prompt's call of an expert model on one device): how full the
+            # blocks were; a dense model is traced as it always was
+            with (grouping.collecting() if cfg.is_moe
+                  else contextlib.nullcontext([])) as notes:
+                logits, cache = forward_last(params, cfg, tokens, cache, pos,
+                                             last_index, offsets=offsets)
+            return logits, cache, grouping.total(notes)
 
         # Outputs that the host reads (logits, sampled tokens) are pinned
         # replicated while the cache keeps its mesh sharding: on a
@@ -517,7 +524,7 @@ class Engine:
         self._rep = NamedSharding(self.mesh, P())
         # one compiled program per (batch, T-bucket); decode is bucket T=1
         self._step = jax.jit(step, donate_argnums=(1,),
-                             out_shardings=(self._rep, self._cache_sh))
+                             out_shardings=(self._rep, self._cache_sh, self._rep))
         if self.sp > 1:
             cfg_ring = cfg.with_(ring_prefill=True)
 
@@ -1052,15 +1059,19 @@ class Engine:
             if use_ring:
                 toks = jax.device_put(
                     tokens_np, NamedSharding(self.mesh, P("dp", "sp")))
-                logits, self.cache = self._step_ring(
+                (logits, self.cache), fill = self._step_ring(
                     self.params, self.cache, toks,
-                    jnp.int32(self.pos), jnp.int32(last_index))
+                    jnp.int32(self.pos), jnp.int32(last_index)), None
             else:
-                logits, self.cache = self._step(
+                logits, self.cache, fill = self._step(
                     self.params, self.cache, jnp.asarray(tokens_np),
                     jnp.int32(self.pos), jnp.int32(last_index), offsets)
         self._state_wrote(self.pos, last_index + 1, int(tokens_np.shape[1]))
         fired = self._sync(logits, "prefill/decode step")
+        if fill is not None:  # blocks were filled, and the call has been waited for
+            pairs, slots = (int(v) for v in np.asarray(fill))
+            obs_metrics.MOE_GROUPED_ROWS.inc("pairs", n=pairs)
+            obs_metrics.MOE_GROUPED_ROWS.inc("slots", n=slots)
         t1 = time.perf_counter()
         if fresh_exec:
             self._compiled_steps.add(step_key)

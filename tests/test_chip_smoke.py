@@ -127,9 +127,9 @@ def test_rehearse_tp_phase_on_virtual_devices(rehearsal_env, capfd):
 
 def test_rehearse_moe_phases(rehearsal_env, capfd):
     """The mixture-of-experts pass: a seeded OLMoE-shaped file (64 experts, 8
-    a token, toy widths) through the loader, ``moe_ffn``'s select-chosen and
-    all-experts strategies against the XLA path (whose many-row form is the
-    scan), then the paged server on that file (on the CPU: the XLA path)."""
+    a token, toy widths) through the loader, ``moe_ffn``'s select-chosen,
+    all-experts and grouped strategies against the XLA path (whose many-row
+    form is the scan), then the paged server on that file (on the CPU: the XLA path)."""
     from dllama_tpu.io import mfile
     from dllama_tpu.synth import synth_model_files
 
@@ -142,7 +142,7 @@ def test_rehearse_moe_phases(rehearsal_env, capfd):
     rows = [json.loads(ln) for ln in capfd.readouterr().out.splitlines()]
     errs = {(r["strategy"], r["rows"]): r for r in rows if "rel_err" in r}
     assert set(errs) == {("select-chosen", 1), ("all-experts", 16),
-                         ("all-experts", 256)}
+                         ("grouped", 256)}
     assert all(r["rel_err"] <= r["tol"] for r in errs.values())
     res = chip_smoke.phase_server(m, t, 600, tmp, slots=2, ctx=64, page=4,
                                   max_tokens=8, rehearse=True)

@@ -541,14 +541,18 @@ def test_deepseek_v2_slot_steps_compile_over_a_latent_pool(one_chip, monkeypatch
     # the mixed step holds one body of the experts a row bucket (64
     # and every row: models/packing.py), the pure-decode step the one it had
     bodies = len(packing.buckets(b * t)) if t > 1 else 1
-    assert sites.get("moe/all-experts") == bodies and "moe/scan" not in sites, sites
+    # 16 rows: every expert over every row; a mixed step's 64 or 256: a row to
+    # its own experts (PR 53)
+    strategy = "moe/grouped" if t > 1 else "moe/all-experts"
+    assert sites.get(strategy) == bodies and "moe/scan" not in sites, sites
     assert sites.get("attn/mla-absorbed") == 2 and "attn/mla-expanded" not in sites, sites
     assert "q40/xla-dequant" not in sites, sites
     ops = re.findall(r"^\s*(?:ROOT )?%?[\w.\-]+ = \S+ ([\w\-]+)\(.*?"
                      r"op_name=\"([^\"]+)\"", text, re.M)
-    calls = [path for op, path in ops if op == "custom-call"
-             and "pallas_call" in path and "/moe/experts/" in path]
-    assert len(calls) == 3 * bodies and all("q40_mm_experts" in c for c in calls), calls
+    calls = [path for op, path in ops if op == "custom-call" and "pallas_call" in path
+             and "/moe/" in path and "/experts/" in path]
+    launch = "q40_mm_grouped" if t > 1 else "q40_mm_experts"
+    assert len(calls) == 3 * bodies and all(launch in c for c in calls), calls
     assert any("/attn/latent/" in path for _, path in ops)
     assert any("/attn/absorb/" in path for _, path in ops)
     assert any("/moe/shared/" in path for _, path in ops)
@@ -648,11 +652,27 @@ def _smallthinker_programs(one_chip, monkeypatch, n_layers=4):
     return cfg, params, cache, s
 
 
+def _prompt_rows_go_to_their_own_experts(prefill: str, moe_layers: int, product: str):
+    """A prompt's compiled call (PR 53): three ``q40_mm_grouped`` launches an
+    expert layer and no ``q40_mm_experts``, and no float32 array of every
+    expert's result for every row (``product``, the ``(E, rows, d)`` shape the
+    weighted sum over all experts read)."""
+    ops = re.findall(r"^\s*(?:ROOT )?%?[\w.\-]+ = \S+ ([\w\-]+)\(.*?"
+                     r"op_name=\"([^\"]+)\"", prefill, re.M)
+    # (the grouped block is one jitted function: its name stands in the path)
+    calls = [path for op, path in ops if op == "custom-call" and "pallas_call" in path
+             and "/moe/" in path and "/experts/" in path]
+    assert len(calls) == moe_layers * 3, calls
+    assert all("q40_mm_grouped" in c for c in calls), calls
+    assert "q40_mm_experts" not in prefill and product not in prefill
+
+
 def test_smallthinker_cell_programs_compile_at_published_widths(one_chip, monkeypatch):
     """The programs of ``smallthinker-21b-a3b.long-stream`` for the described
-    chip, one period of layers: the 512-row prefill chunk (``all-experts``:
-    three ``q40_mm_experts`` launches a layer; the window layers' ring walk,
-    the full layer's live walk) and the 16-step decode chunk (``select-chosen``:
+    chip, one period of layers: the 512-row prefill chunk (``grouped``, PR 53:
+    three ``q40_mm_grouped`` launches a layer over blocks of 64 rows that
+    share an expert, and no array over all 64 experts' rows; the window
+    layers' ring walk, the full layer's live walk) and the 16-step decode chunk (``select-chosen``:
     three ``q40_mm_chosen`` launches a layer over the row's 6 experts).  No Q40
     site takes the XLA path, the rings
     are 4608 positions beside full planes of 16384, and neither kind of plane
@@ -683,9 +703,10 @@ def test_smallthinker_cell_programs_compile_at_published_widths(one_chip, monkey
         sites_decode = obs_dispatch.dispatches()
     finally:
         obs_dispatch.reset()
-    assert sites_prefill.get("moe/all-experts") == 4 and "moe/scan" not in sites_prefill
+    assert sites_prefill.get("moe/grouped") == 4 and "moe/all-experts" not in sites_prefill
     assert sites_decode.get("moe/select-chosen") == 4, sites_decode
     assert "moe/select" not in sites_decode
+    _prompt_rows_go_to_their_own_experts(prefill, 4, "f32[64,512,2560]")
     for sites in (sites_prefill, sites_decode):
         assert sites.get("attn/window-walk") == 3 and sites.get("attn/live-walk") == 1
         assert "q40/xla-dequant" not in sites, sites
@@ -819,10 +840,12 @@ def test_k_exaone_slot_steps_compile_over_the_pool_per_kind(one_chip, monkeypatc
     # the first period is unrolled (its first layer is dense), the second
     # scanned; the mixed step holds one body of the experts a row bucket
     bodies = len(packing.buckets(b * t)) if t > 1 else 1
-    assert sites.get("moe/all-experts") == (3 + 4) * bodies, sites
+    strategy, launch = (("moe/grouped", "q40_mm_grouped") if t > 1
+                        else ("moe/all-experts", "q40_mm_experts"))   # PR 53
+    assert sites.get(strategy) == (3 + 4) * bodies, sites
     assert sites.get("kv_dense/paged-fused") == 2 and sites.get("kv_dense/window-ring") == 6
     assert "q40/xla-dequant" not in sites and "kv_dense/paged-gather" not in sites, sites
-    assert "paged_attn_fused" in text and "q40_mm_experts" in text
+    assert "paged_attn_fused" in text and launch in text
     for plane in ("bf16[2,1025,16,8,128]", "bf16[6,160,16,8,128]"):
         assert plane in text
         assert not re.search(r"= \(?" + re.escape(plane) + r"\S* copy(-start)?\(", text), plane
@@ -837,7 +860,7 @@ def test_k_exaone_slot_steps_compile_over_the_pool_per_kind(one_chip, monkeypatc
 # hostenv.kernels_without_frames() leaves them out, so the bytes below must
 # not move with a line shift, another checkout path or another call stack.
 KERNEL_FAMILIES = ["q40_mm", "q40_mm_stacked", "q40_mm_experts",
-                   "q40_mm_chosen", "q8_mm", "q8_mm_stacked",
+                   "q40_mm_chosen", "q40_mm_grouped", "q8_mm", "q8_mm_stacked",
                    "paged_attn_fused-t1", "paged_attn_fused-t16", "q40_ring"]
 KERNEL_FILES = ("q40", "q8", "attention")
 
@@ -886,6 +909,11 @@ def _family_launch(family, ops, topo):
         return (lambda x, qp, sc, l, c: mq._pallas_matmul_experts(
             x, qp, sc, l, experts=experts, chosen=c),
             (x1, *planes((layers * experts,)), layer, s((k,), jnp.int32)))
+    if family == "q40_mm_grouped":
+        return (lambda x, qp, sc, l, c, u: mq._pallas_matmul_experts(
+            x, qp, sc, l, experts=experts, chosen=c, used=u),
+            (s((3, 16, n), jnp.bfloat16), *planes((layers * experts,)), layer,
+             s((3,), jnp.int32), layer))
     if family == "q8_mm":
         return m8._pallas_matmul, (x1, s((n, d), jnp.int8),
                                    s((n // 32, d), jnp.uint16))
@@ -1053,12 +1081,16 @@ def _no_whole_copy(text, planes):
         assert not re.search(r"= \(?" + re.escape(plane) + r"\S* copy(-start)?\(", text), plane
 
 
-@pytest.mark.parametrize("t", [1, 16], ids=["pure-decode", "mixed"])
+@pytest.mark.parametrize("t,n_layers", [(1, 8), (16, 32)], ids=["pure-decode", "mixed"])
 def test_lfm2_slot_steps_compile_with_the_state_beside_the_pool(one_chip,
-                                                                monkeypatch, t):
+                                                                monkeypatch, t, n_layers):
     """The two step programs of ``lfm2-24b-a2b.decode-heavy`` for the
-    described chip, two periods of layers: six conv operators over the slots'
-    state ring (one ``conv/ring`` site each a body), the two attention layers'
+    described chip, the pure-decode step at two periods of layers and the
+    mixed step at the cell's eight (24 conv layers' state, 100 MB: a state of
+    two periods, 25 MB, the compiler keeps in VMEM for a mixed step since the
+    ``(E, rows, .)`` temporaries went, PR 53, one copy in and one back, which
+    is its placement and not the subject): the conv operators over the slots'
+    state ring (one ``conv/ring`` site each a body), the attention layers'
     paged read the fused walk over the folded pool (heads of 64 two to a row
     of 128 lanes: ``_fused_choice`` takes a pool whose rows fill whole lanes,
     and not one that keeps such a head a row), 64 experts in three launches a
@@ -1071,13 +1103,14 @@ def test_lfm2_slot_steps_compile_with_the_state_beside_the_pool(one_chip,
     # a pool over VMEM's 128 MiB, as the cell's is at its eight attention
     # layers (one that fits is prefetched there whole, which is not the subject)
     cfg, params, cache, s = _lfm2_programs(one_chip, monkeypatch, paged=True,
-                                           pages=POOL_PAGES)
+                                           n_layers=n_layers, pages=POOL_PAGES)
+    n_att, n_conv = n_layers // 4, n_layers - n_layers // 4
     assert cfg.prefill_chunk() == 1024
     # the attention layers' pool, heads of 64 two to a row of 128 lanes: as
     # (2, 4097, 16, 8, 64) the chip's compact layout puts the pages minor-most
     # and both planes are copied whole, twice a step
-    assert cache.k.shape == (2, POOL_PAGES, 16, 4, 128)
-    assert cache.cz.shape == (6, 16, 1, conv.RING, 2048)      # the slots' state
+    assert cache.k.shape == (n_att, POOL_PAGES, 16, 4, 128)
+    assert cache.cz.shape == (n_conv, 16, 1, conv.RING, 2048)  # the slots' state
     assert att._fused_choice(t, 32, 8, 64, ps=16, maxp=128, row=128) == (True, False)
     assert att._fused_choice(t, 32, 8, 64, ps=16, maxp=128) == (False, False)
     b = 16
@@ -1099,25 +1132,28 @@ def test_lfm2_slot_steps_compile_with_the_state_beside_the_pool(one_chip,
     bodies = len(packing.buckets(b * t)) if t > 1 else 1
     assert sites.get("conv/ring") == 6, sites
     assert sites.get("kv_dense/paged-fused") == 2, sites
-    assert sites.get("moe/all-experts") == (2 + 4) * bodies, sites
+    strategy, launch = (("moe/grouped", "q40_mm_grouped") if t > 1
+                        else ("moe/all-experts", "q40_mm_experts"))   # PR 53
+    assert sites.get(strategy) == (2 + 4) * bodies, sites
     assert "q40/xla-dequant" not in sites and "kv_dense/paged-gather" not in sites, sites
-    assert "q40_mm_experts" in text and "q40_mm_stacked" in text
+    assert launch in text and "q40_mm_stacked" in text
     # one launch at each attention layer's site (the unrolled period's and the
     # scanned body's), and no (B, Hkv, maxp * ps, Dh) view of a slot's table
     assert len(re.findall(r"custom-call\(.*paged_attn_fused", text)) == 2
     assert "[16,8,2048,64]" not in text
     for part in ("qkv/conv", "kv_write/conv", "attn/conv", "wo/conv"):
         assert part in text, part
-    _no_whole_copy(text, (f"bf16[2,{POOL_PAGES},16,4,128]",
-                          f"bf16[6,16,1,{conv.RING},2048]"))
+    _no_whole_copy(text, (f"bf16[{n_att},{POOL_PAGES},16,4,128]",
+                          f"bf16[{n_conv},16,1,{conv.RING},2048]"))
     # the walk's view of a page, (ps * rows, 128), is a bitcast of the pool
-    assert not re.search(rf"= bf16\[2,{POOL_PAGES},64,128\]\S* (?!bitcast)", text)
+    assert not re.search(rf"= bf16\[{n_att},{POOL_PAGES},64,128\]\S* (?!bitcast)", text)
 
 
 def test_lfm2_one_stream_programs_compile_at_published_widths(one_chip, monkeypatch):
     """The programs of ``lfm2-24b-a2b.single-stream`` for the described chip,
     two periods of layers: the 256-row bucket of a prompt (wider than the
-    state ring: the rows that end at the prompt's last token are written) and
+    state ring: the rows that end at the prompt's last token are written;
+    its experts ``grouped``, PR 53: blocks of 32 rows that share an expert) and
     the 16-step decode chunk (``select-chosen``: three ``q40_mm_chosen``
     launches an expert layer over the row's 4 experts)."""
 
@@ -1147,8 +1183,9 @@ def test_lfm2_one_stream_programs_compile_at_published_widths(one_chip, monkeypa
         sites_decode = obs_dispatch.dispatches()
     finally:
         obs_dispatch.reset()
-    assert sites_prefill.get("moe/all-experts") == 6 and "moe/scan" not in sites_prefill
+    assert sites_prefill.get("moe/grouped") == 6 and "moe/all-experts" not in sites_prefill
     assert sites_decode.get("moe/select-chosen") == 6, sites_decode
+    _prompt_rows_go_to_their_own_experts(prefill, 6, "f32[64,256,2048]")
     for sites in (sites_prefill, sites_decode):
         assert sites.get("conv/ring") == 6, sites
         assert "q40/xla-dequant" not in sites, sites

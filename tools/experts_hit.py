@@ -17,8 +17,12 @@ step's rows: per layer and position the distinct experts among their
 ``rows x k`` choices are counted.  The benchmark's files are used as they
 are (configuration, seeded ``.m`` file); nothing is timed.
 
+``--dump DIR`` also writes what every layer chose for the first stream,
+``DIR/<config>.npy`` ``(layers, positions, k)`` int32: the routing ``tools/
+sweep_q40.py --grouped --routing DIR`` times a prompt's experts under.
+
 Usage: python tools/experts_hit.py --config benchmarks/configs/olmoe-1b-7b.json
-       [--rows 16] [--positions 64] [--cpu]   (--cpu: toy widths, control flow)
+       [--rows 16] [--positions 64] [--dump DIR] [--cpu]   (--cpu: toy widths, control flow)
 """
 
 from __future__ import annotations
@@ -39,9 +43,10 @@ def main(argv=None) -> int:
     ap.add_argument("--rows", type=int, default=16)
     ap.add_argument("--positions", type=int, default=64)
     ap.add_argument("--cpu", action="store_true")
+    ap.add_argument("--dump")
     a = ap.parse_args(argv)
-    if a.positions > 256:
-        raise SystemExit("--positions over 256 would prefill in several passes")
+    if a.positions > 512:
+        raise SystemExit("--positions over 512 would prefill in several passes")
     if a.cpu:
         os.environ.setdefault("JAX_PLATFORMS", "cpu")
     for p in (ROOT, BENCH, os.path.join(BENCH, "tools")):
@@ -67,18 +72,20 @@ def main(argv=None) -> int:
     chosen: dict[int, np.ndarray] = {}   # layer -> (positions, k) of the stream in flight
     moe_ffn = tf.moe_ffn
 
-    def tapped(xb2d, lp, mcfg):
-        logits = xb2d.astype("float32") @ lp["router"].astype("float32")
-        _, idx = jax.lax.top_k(logits, mcfg.n_active_experts)  # softmax keeps the order
+    def tapped(xb2d, lp, mcfg, *logits):
+        idx, _ = tf.route(xb2d, lp, mcfg, *logits)  # the program's own choice
         jax.debug.callback(lambda layer, i: chosen.__setitem__(int(layer), np.asarray(i)),
                            lp["up"].layer, idx)
-        return moe_ffn(xb2d, lp, mcfg)
+        return moe_ffn(xb2d, lp, mcfg, *logits)
 
     tf.moe_ffn = tapped
     try:
         engine, _ = cli.load_stack(cli.build_parser().parse_args(
             ["inference", "--model", mpath, "--tokenizer", tpath, "--workers", "tpu:1",
              "--temperature", "0", "--max-seq-len", str(max(256, a.positions))]))
+        if a.positions > engine.cfg.prefill_chunk():
+            raise SystemExit(f"--positions over {engine.cfg.prefill_chunk()} would "
+                             "prefill in several passes")
         streams = []
         for toks in correct.check_prompts(SEED, a.rows, a.positions, shape["vocab_size"]):
             engine.reset()
@@ -91,6 +98,9 @@ def main(argv=None) -> int:
     finally:
         tf.moe_ffn = moe_ffn
     idx = np.stack(streams)                                  # (rows, L, T, k)
+    if a.dump:
+        os.makedirs(a.dump, exist_ok=True)
+        np.save(os.path.join(a.dump, name + ".npy"), idx[0].astype(np.int32))
     n_exp, k = shape["n_experts"], shape["n_active_experts"]
     hit = np.array([[len(np.unique(idx[:, l, t])) for t in range(idx.shape[2])]
                     for l in range(idx.shape[1])])           # (L, T)

@@ -51,7 +51,20 @@ cell's trace reads 19-21 a launch, 67 where it reads 6 x 4.3
 (PERF.md §6, PR 39).  The order of the two forms is the tool's to say, a
 launch's time the trace's.
 
+``--grouped`` times one layer's routed experts as ``moe_ffn`` runs them (router,
+the experts' three launches, the weighted sum; scope ``moe`` without a shared
+expert) at the prompt and mixed-step rows of the five expert configurations:
+``all-experts`` (``q40_mm_experts`` over every expert, then a masked sum) against
+``grouped`` (``models/grouping.py``: the pairs sorted by expert into blocks of
+``tr`` rows, ``q40_mm_grouped`` over the blocks that hold rows) at ``tr`` 16 to
+128 (``rule`` marks the one ``grouping.block_rows`` takes).  The router's logits are handed in, so the routing
+is the tool's: ``uniform`` (k experts a row drawn evenly) and, with ``--routing
+DIR``, ``seeded`` (the cell's own: ``tools/experts_hit.py --dump DIR`` wrote what
+each layer of the benchmark's seeded weights chose for a prompt).  ``launch``
+records time the gate matrix's launch alone.  PERF.md section 6, PR 53.
+
 Usage: python tools/sweep_q40.py --tiles [ds_gate,yi_wo]  # tile pairs at 1, 16, 256 rows
+       python tools/sweep_q40.py --grouped [lfm2,olmoe [--routing chiprun_out/routing]]
        python tools/sweep_q40.py --rows [head,w13]        # rows x row block
        python tools/sweep_q40.py --body [w2,ds_down [16,32,64 [rule,dot,dot-twice,vpu]]]  # the body at the rule's tiles, 1 to 512 rows or the rows given
        python tools/sweep_q40.py --chosen [st_gate]       # one launch a row's experts, or one each
@@ -414,6 +427,157 @@ def measure_body(only: set | None = None, reps: int = 128,
     return results
 
 
+class Geometry(NamedTuple):
+    name: str               # the configuration it is the expert layer of
+    dim: int
+    width: int              # one expert's
+    experts: int            # the router's outputs
+    k: int
+    rows: tuple             # a prompt's bucket or chunk, a packed mixed step
+    held: int = 0           # planes on this chip, where fewer than ``experts``
+
+
+GROUPED = [Geometry("lfm2-24b-a2b", 2048, 1536, 64, 4, (256, 128)),
+           Geometry("smallthinker-21b-a3b", 2560, 768, 64, 6, (512,)),
+           Geometry("olmoe-1b-7b", 2048, 1024, 64, 8, (64, 256)),
+           Geometry("deepseek-v2", 5120, 1536, 160, 6, (64, 256)),
+           Geometry("k-exaone-236b-a23b", 6144, 2048, 128, 8, (256,), held=16)]
+GROUPED_TR = (16, 32, 64, 128)
+_IMPL = "pallas"            # a rehearsal off the chip sets "pallas_interpret"
+
+
+def _routing_logits(idx, experts: int):
+    """Router logits under which a plain softmax top-k chooses ``idx`` ``(...,
+    rows, k)``, in its order."""
+    import numpy as np
+    k = idx.shape[-1]
+    logits = np.zeros(idx.shape[:-1] + (experts,), np.float32)
+    np.put_along_axis(logits, idx, 10.0 - np.arange(k, dtype=np.float32), axis=-1)
+    return logits
+
+
+def measure_grouped(only: set | None = None, reps: int = 32,
+                    routing: str | None = None) -> list[dict]:
+    """One layer's routed experts under both many-row strategies (module
+    docstring).  A record: ``form`` (``all-experts`` | ``grouped``), ``what``
+    (``layer``: router to weighted sum; ``launch``: the gate launch alone),
+    ``routing``, ``tr``, ``blocks`` (static), ``blocks_used`` and ``fill_pct``
+    (mean over the layers timed), ``ms``."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    q40 = _q40()
+    from dllama_tpu.io import mfile
+    from dllama_tpu.models import grouping
+    from dllama_tpu.models import transformer as tf
+    from dllama_tpu.models.config import tiny_config
+    if _IMPL == "pallas" and jax.default_backend() != "tpu":
+        print(json.dumps({"error": "no TPU"}))
+        sys.exit(1)
+    key = jax.random.key(0)
+    rule = grouping.block_rows
+    L = 2
+    results = []
+    for geo in GROUPED:
+        if only and geo.name not in only and geo.name.split("-")[0] not in only:
+            continue
+        E, held = geo.experts, geo.held or geo.experts
+        cfg = tiny_config(arch=mfile.ARCH_OLMOE, dim=geo.dim, hidden_dim=geo.width,
+                          n_experts=E, n_active_experts=geo.k, dtype=jnp.bfloat16,
+                          experts_held=geo.held, first_expert=geo.held,
+                          quant_impl=_IMPL)
+
+        def stack(n, d):
+            qp = jnp.tile(jax.random.bits(key, (1, 1, n // 2, d), jnp.uint8),
+                          (L, held, 1, 1))
+            sc = jnp.tile(jax.lax.bitcast_convert_type(
+                jax.random.uniform(key, (1, 1, n // 32, d), jnp.float16) * 0.01,
+                jnp.uint16), (L, held, 1, 1))
+            return q40.QTensor(qp, sc, (n, d))
+
+        stacks = {"gate": stack(geo.dim, geo.width), "up": stack(geo.dim, geo.width),
+                  "down": stack(geo.width, geo.dim)}
+        seeded = None
+        path = routing and os.path.join(routing, geo.name + ".npy")
+        if path and os.path.exists(path):
+            seeded = np.load(path)                      # (layers, positions, k)
+        for rows in geo.rows:
+            rng = np.random.default_rng(rows)
+            routings = {"uniform": np.stack([
+                np.stack([rng.permutation(E)[:geo.k] for _ in range(rows)])
+                for _ in range(L)])}
+            if seeded is not None and seeded.shape[1] >= rows:
+                routings["seeded"] = seeded[:, :rows]
+            x = jax.random.normal(key, (rows, geo.dim), jnp.bfloat16)
+            for want in (None, *GROUPED_TR):
+                for rname, idx in routings.items():
+                    if want is None and rname != "uniform":
+                        continue  # all-experts does not read the routing
+                    logits = jnp.asarray(_routing_logits(idx, E))
+                    own = (idx >= cfg.first_expert) & (idx < cfg.first_expert + held)
+                    loc = np.where(own, idx - cfg.first_expert, held)
+                    used = np.mean([sum(-(-c // want) for c in np.bincount(
+                        l.ravel(), minlength=held + 1)[:held]) for l in loc]) if want else 0
+                    for what in ("layer", "launch"):
+                        if what == "launch" and rname != "uniform":
+                            continue
+                        rec = {"config": geo.name, "rows": rows, "k": geo.k,
+                               "held": held, "what": what, "routing": rname,
+                               "form": "grouped" if want else "all-experts"}
+                        if want:
+                            m = grouping.blocks(rows, geo.k, held, want)
+                            rec.update(tr=want, rule=want == rule(rows, geo.k, E), blocks=m,
+                                       blocks_used=float(used),
+                                       fill_pct=round(100 * own.sum() / len(idx)
+                                                      / (used * want), 1))
+
+                        def one(x, logits, qts, i):
+                            lp = {n: q40.QLayerView(qt, i % L) for n, qt in qts.items()}
+                            lg = jax.lax.dynamic_index_in_dim(
+                                logits, i % logits.shape[0], keepdims=False)
+                            if what == "layer":
+                                return tf.moe_ffn(x, lp, cfg, lg)
+                            if not want:
+                                return q40.matmul_experts(x, lp["gate"], held, _IMPL)
+                            top = jax.lax.top_k(lg, geo.k)[1] - cfg.first_expert
+                            here = (top >= 0) & (top < held)
+                            gp = grouping.plan(jnp.where(here, top, 0), held, want, here)
+                            xg = x[gp.gather].reshape(m, want, geo.dim)
+                            return q40.matmul_experts(xg, lp["gate"], held, _IMPL,
+                                                      chosen=gp.planes, used=gp.used)
+
+                        @jax.jit
+                        def run(x, logits, qts):
+                            def body(acc, i):
+                                o = one(x + acc.astype(x.dtype) * 0, logits, qts, i)
+                                return acc + o.astype(jnp.float32).sum() * 1e-9, None
+                            return jax.lax.scan(body, jnp.float32(0), jnp.arange(reps))[0]
+
+                        grouping.block_rows = lambda *a, _w=want: _w
+                        try:
+                            float(run(x, logits, stacks))
+                            best = float("inf")
+                            for _ in range(3):
+                                t0 = time.perf_counter()
+                                float(run(x, logits, stacks))
+                                best = min(best, (time.perf_counter() - t0) * 1000 / reps)
+                            rec["ms"] = round(best, 4)
+                        except Exception as e:  # noqa: BLE001 — a refusal is a result
+                            rec["error"] = f"{type(e).__name__}: {str(e)[:300]}"
+                        finally:
+                            grouping.block_rows = rule
+                        print(json.dumps(rec), flush=True)
+                        results.append(rec)
+                        del run
+                        jax.clear_caches()
+        del stacks
+    os.makedirs(os.path.join(HERE, "chiprun_out"), exist_ok=True)
+    with open(os.path.join(HERE, "chiprun_out", "sweep_grouped.json"), "w") as f:
+        json.dump(results, f, indent=1)
+    return results
+
+
 def measure_chosen(only: set | None = None, reps: int = 256) -> list[dict]:
     """One decoded row's chosen routed experts: one launch over their planes
     against one launch each, at the rule's tiles (``ms`` is all of them)."""
@@ -425,10 +589,15 @@ def measure_chosen(only: set | None = None, reps: int = 256) -> list[dict]:
 
 def main():
     modes = {"--tiles": measure_tiles, "--rows": measure_rows,
-             "--body": measure_body, "--chosen": measure_chosen}
+             "--body": measure_body, "--chosen": measure_chosen,
+             "--grouped": measure_grouped}
     if len(sys.argv) < 2 or sys.argv[1] not in modes:
         sys.exit(__doc__)
     kw = {}
+    if "--routing" in sys.argv:
+        at = sys.argv.index("--routing")
+        kw["routing"] = sys.argv[at + 1]
+        del sys.argv[at:at + 2]
     if sys.argv[1] == "--body" and len(sys.argv) > 3:
         kw["rows"] = tuple(int(r) for r in sys.argv[3].split(","))
     if sys.argv[1] == "--body" and len(sys.argv) > 4:
