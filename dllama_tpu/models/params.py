@@ -18,7 +18,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..io import mfile
-from ..obs import metrics as obs_metrics, trace as obs_trace
+from ..obs import memory as obs_memory, metrics as obs_metrics, \
+    trace as obs_trace
 from ..ops import q40, q8
 from .config import ModelConfig
 
@@ -322,8 +323,12 @@ def load_params(mf: mfile.MFile, cfg: ModelConfig | None = None,
     if cfg is None:
         cfg = ModelConfig.from_spec(mf.spec)
     with obs_trace.span("engine.load_read", layers=cfg.n_layers,
-                        total=obs_metrics.load_seconds("read")):
-        return cfg, _read_params(mf, cfg, dtype, keep_quantized, fuse)
+                        total=obs_metrics.load_seconds("read")) as sp:
+        params = _read_params(mf, cfg, dtype, keep_quantized, fuse)
+        # the host's side of the memory account: the resident set with the
+        # host stacks built (host_rss_bytes{phase="read"})
+        sp.update(rss=obs_memory.ACCOUNT.rss("read"))
+        return cfg, params
 
 
 def _read_params(mf: mfile.MFile, cfg: ModelConfig, dtype,

@@ -1,6 +1,6 @@
 """Unified observability for the serving stack (docs/OBSERVABILITY.md).
 
-Three stdlib-only building blocks, threaded through every layer:
+Stdlib-only building blocks, threaded through every layer:
 
 * :mod:`.metrics` — THE process-global registry of counters, gauges and
   fixed-bucket histograms, with two exposition paths from the one
@@ -23,6 +23,9 @@ Three stdlib-only building blocks, threaded through every layer:
   (no device counters), the per-backend peak table behind the
   ``dllama_mfu`` / ``dllama_mbu`` gauges, and per-request chip-time
   attribution feeding the flight recorder's cost block.
+* :mod:`.memory` — the memory account: the chip's memory by owner, the
+  program that set its peak, the host's resident set by phase, read at
+  edges only, and the ``hbm_exhausted`` line of a failing allocation.
 * :mod:`.flight` — the request flight recorder (per-request lifecycle
   records keyed by ``X-Request-Id``, served at ``/debug/requests``) and
   the per-dispatch slot timeline behind ``/debug/timeline`` and the
@@ -43,5 +46,5 @@ metric bump on the decode hot path costs one small lock.
 
 from __future__ import annotations
 
-from . import cost, dispatch, events, flight, log, metrics, slo, \
+from . import cost, dispatch, events, flight, log, memory, metrics, slo, \
     trace  # noqa: F401

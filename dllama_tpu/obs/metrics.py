@@ -91,7 +91,9 @@ class Counter:
 
 class Gauge:
     """A value that goes up and down (or is computed at read time via
-    ``fn`` — e.g. uptime)."""
+    ``fn`` — e.g. uptime).  A ``fn`` that returns ``None`` has nothing to
+    read (no ``/proc`` on this host): the JSON holds ``null`` and the
+    Prometheus family no sample, never a zero."""
 
     kind = "gauge"
 
@@ -112,9 +114,10 @@ class Gauge:
             self._value += n
 
     @property
-    def value(self) -> float:
+    def value(self) -> float | None:
         if self.fn is not None:
-            return float(self.fn())
+            v = self.fn()
+            return None if v is None else float(v)
         with self._lock:
             return self._value
 
@@ -123,13 +126,16 @@ class Gauge:
             self._value = 0.0
 
     def json_value(self):
-        return round(self.value, 6)
+        v = self.value
+        return None if v is None else round(v, 6)
 
     def render(self, lines: list[str]) -> None:
         if self.help:
             lines.append(f"# HELP {self.name} {_escape_help(self.help)}")
         lines.append(f"# TYPE {self.name} gauge")
-        lines.append(f"{self.name} {_fmt(self.value)}")
+        v = self.value
+        if v is not None:
+            lines.append(f"{self.name} {_fmt(v)}")
 
 
 class Cell:
@@ -750,6 +756,56 @@ HBM_BYTES_IN_USE = REGISTRY.labeled_gauge(
 HBM_BYTES_PEAK = REGISTRY.labeled_gauge(
     "hbm_bytes_peak", "device",
     "Per-device peak HBM bytes allocated since process start.")
+# the memory account (obs/memory.py; the engine donates the device reader):
+# what the fullest local device holds, by owner, read at edges only (a load
+# phase, a fresh program's first call and its landing, an idle entry or a
+# request's close after something was compiled or built): never inside a step
+HBM_ACCOUNT_BYTES = REGISTRY.labeled_gauge(
+    "hbm_account_bytes", "owner",
+    "HBM bytes of the fullest local device by owner: found (in use when the "
+    "process first placed parameters: what it held before the engine), "
+    "params (param_bytes_resident), cache (every live engine's cache, pool, "
+    "rings and states), resident_idle (bytes_in_use when nothing was in "
+    "flight), programs (resident_idle - found - params - cache: loaded "
+    "executables, retained outputs, the rest), limit (the allocator's "
+    "bytes_limit).  hbm_bytes_peak - resident_idle is the temporaries' high "
+    "water.")
+HBM_PEAK_RAISED_BYTES = REGISTRY.labeled_gauge(
+    "hbm_peak_raised_bytes", "key",
+    "By how much peak_bytes_in_use (the fullest device's) rose over the "
+    "first execution of a program, by the key its compile log line prints, "
+    "and over the load phases load_place and cache_build; key found: the "
+    "peak the process already had when it first placed parameters (what ran "
+    "before the engine).  A key whose first run left the peak where it "
+    "stood has no sample.")
+HBM_PEAK_SET_BY_BYTES = REGISTRY.labeled_gauge(
+    "hbm_peak_set_by_bytes", "key",
+    "One sample: the key of hbm_peak_raised_bytes that raised the peak "
+    "last, and the peak it left; key found: nothing the engine did has "
+    "passed the peak the process had before it loaded.")
+# the host's side of the same account, from /proc/self/status: the phases
+# are set where the load spans close and where an engine is built; "now" is
+# read at each scrape.  No /proc: no sample
+HOST_RSS_BYTES = REGISTRY.labeled_gauge(
+    "host_rss_bytes", "phase",
+    "Resident set of the process (VmRSS) by phase: read (engine.load_read "
+    "closed: host stacks built), placed (engine.load_place closed), ready "
+    "(the last engine built), now (this scrape).")
+HOST_RSS_PEAK_BYTES = REGISTRY.gauge(
+    "host_rss_peak_bytes",
+    "High water of the process's resident set (VmHWM; getrusage's "
+    "ru_maxrss under a kernel whose status file has no such line): whatever "
+    "ran in the process before the engine is inside it.")
+# what the account costs: its reads of memory_stats() (one a local device a
+# read) and of /proc/self/status at the edges above; a scrape's own reads
+# (hbm_bytes_*, host_rss_bytes{now}) are the scraper's and are not counted
+MEMORY_ACCOUNT_READS = REGISTRY.labeled_counter(
+    "memory_account_reads", "source",
+    "Reads the memory account made at its edges, by source (device: one "
+    "memory_stats() a local device; host: /proc/self/status).")
+MEMORY_ACCOUNT_READ_SECONDS = REGISTRY.labeled_counter(
+    "memory_account_read_seconds", "source",
+    "Seconds inside the reads counted by memory_account_reads.")
 # set by runtime/engine.py once place_params has run: what each device holds
 # of the model itself, so a lopsided placement (a whole stack staged on
 # device 0) shows on every backend, the CPU mesh of the tests included

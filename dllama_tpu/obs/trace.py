@@ -170,9 +170,19 @@ class SpanArgs(dict):
     """A span's arguments as its block sees them.  What the block adds (a
     shape decided half-way) reaches the ring and the profiler; ``total``
     is the counter cell the duration goes to, which the block may also
-    name late (a step's kind is decided while it is built)."""
+    name late (a step's kind is decided while it is built).  ``late(**kw)``
+    adds arguments AFTER the block has closed, to the ring's record alone
+    (the profiler's annotation has ended): what a span started and only a
+    later edge can read, e.g. the allocator's peak once a launch has landed
+    (``engine.compile``'s ``hbm_peak_after``)."""
 
-    __slots__ = ("total",)
+    __slots__ = ("total", "_ring")
+
+    def late(self, **kw) -> None:
+        self.update(kw)
+        ring = getattr(self, "_ring", None)
+        if ring is not None:
+            ring.update(kw)
 
 
 class Span:
@@ -210,8 +220,8 @@ class Span:
             if late:
                 ann.set_metadata(**late)
             ann.__exit__(None, None, None)
-        dur = self._tracer.record(self._name, self._t0, t1, rid=self._rid,
-                                  **args)
+        rec = self._tracer.put(self._name, self._t0, t1, self._rid, dict(args))
+        args._ring, dur = rec["args"], rec["dur"]
         if args.total is not None:
             if self._less is not None:
                 dur = max(dur - (self._less.received - self._less0), 0.0)
@@ -235,6 +245,11 @@ class Tracer:
         work on behalf of another request (the scheduler loop) stamp the
         ticket's ID explicitly.  The span's fleet trace id resolves from
         the rid→trace map first, then the ambient contextvar."""
+        return self.put(name, t0, t1, rid, args)["dur"]
+
+    def put(self, name: str, t0: float, t1: float, rid, args: dict) -> dict:
+        """:meth:`record` with the arguments as a dict the ring keeps, and
+        the ring's record returned (``Span`` adds late arguments to it)."""
         th = threading.current_thread()
         rid = rid if rid is not None else request_id_var.get()
         trace = trace_of(rid) or trace_id_var.get()
@@ -246,7 +261,7 @@ class Tracer:
             self._seq += 1
             span["seq"] = self._seq
             self._spans.append(span)
-        return dur
+        return span
 
     def resize(self, capacity: int) -> None:
         """Re-bound the ring, keeping the most recent spans that fit."""

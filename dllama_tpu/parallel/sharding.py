@@ -31,7 +31,8 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..io import mfile
 from ..models.config import ModelConfig
-from ..obs import metrics as obs_metrics, trace as obs_trace
+from ..obs import memory as obs_memory, metrics as obs_metrics, \
+    trace as obs_trace
 from ..models.params import (ATT_KIND_KEYS, CONV_KEYS, DENSE_FFN_KEYS,
                              MLA_ATT_KEYS, MOE_FFN_KEYS)
 
@@ -163,11 +164,15 @@ def place_params(params: dict, cfg: ModelConfig, mesh: Mesh) -> dict:
     N/2 and N/32 — both divisible at block granularity).
 
     The span ``engine.load_place`` and ``engine_load_seconds{phase="place"}``
-    (the gauge outlives the span ring).
+    (the gauge outlives the span ring); the span's ``rss`` and
+    ``host_rss_bytes{phase="placed"}`` are the process's resident set where
+    it closes (obs/memory.py).
     """
     with obs_trace.span("engine.load_place", devices=mesh.size,
-                        total=obs_metrics.load_seconds("place")):
-        return _place_params(params, cfg, mesh)
+                        total=obs_metrics.load_seconds("place")) as sp:
+        placed = _place_params(params, cfg, mesh)
+        sp.update(rss=obs_memory.ACCOUNT.rss("placed"))
+        return placed
 
 
 def _place_params(params: dict, cfg: ModelConfig, mesh: Mesh) -> dict:

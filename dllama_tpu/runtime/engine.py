@@ -22,8 +22,10 @@ only remaining boundary: fetching logits for the host-side sampler.
 from __future__ import annotations
 
 import contextlib
+import functools
 import os
 import time
+import weakref
 from dataclasses import dataclass, field
 
 import jax
@@ -39,8 +41,8 @@ from ..models.params import Params
 from ..models.transformer import (LATENT_PAGE_AXES, PAGE_AXES,
                                   SLOT_PLANE_KINDS, forward_last,
                                   init_kv_cache)
-from ..obs import dispatch as obs_dispatch, metrics as obs_metrics, \
-    trace as obs_trace
+from ..obs import dispatch as obs_dispatch, memory as obs_memory, \
+    metrics as obs_metrics, trace as obs_trace
 from ..obs.log import get_logger
 from ..ops import conv, q40, q8, retention
 from ..parallel import sharding
@@ -50,33 +52,42 @@ from ..sampling import Sampler
 _log = get_logger("runtime.engine")
 
 
+def _device_stats() -> dict[str, dict]:
+    """``{device_id: memory_stats()}`` over the local devices; a backend with
+    no allocator stats (CPU, some emulators) is left out."""
+    out: dict[str, dict] = {}
+    for d in jax.local_devices():
+        try:
+            ms = d.memory_stats()
+        except Exception:
+            ms = None
+        if ms:
+            out[str(d.id)] = ms
+    return out
+
+
 def _hbm_reader(stat: str):
     """Bind a per-device memory_stats field to a labeled gauge: returns
     ``{device_id: bytes}`` at read time, or ``{}`` where the backend has
-    no allocator stats (CPU, some emulators) — absence reads as no
-    samples, never as zeros."""
+    no allocator stats — absence reads as no samples, never as zeros."""
     def read() -> dict:
-        out: dict[str, float] = {}
-        for d in jax.local_devices():
-            try:
-                ms = d.memory_stats()
-            except Exception:
-                ms = None
-            if ms and stat in ms:
-                out[str(d.id)] = float(ms[stat])
-        return out
+        return {d: float(ms[stat]) for d, ms in _device_stats().items()
+                if stat in ms}
     return read
 
 
 # The obs package stays jax-free; the engine (which already owns the
 # devices) donates the reader at import.  LabeledGauge calls it lazily at
-# each /metrics read, so the gauges track live allocator state.
+# each /metrics read, so the gauges track live allocator state; the memory
+# account (obs/memory.py) calls it at its edges only.
 obs_metrics.HBM_BYTES_IN_USE.fn = _hbm_reader("bytes_in_use")
 obs_metrics.HBM_BYTES_PEAK.fn = _hbm_reader("peak_bytes_in_use")
+obs_memory.ACCOUNT.stats = _device_stats
 
 
 def _resident_param_bytes(params: Params) -> dict[str, int]:
-    """``{device_id: bytes}`` of the placed parameters' addressable shards."""
+    """``{device_id: bytes}`` of the placed parameters' addressable shards
+    (or of any pytree of placed arrays: a cache's planes)."""
     out: dict[str, int] = {}
     for leaf in jax.tree.leaves(params):
         for shard in leaf.addressable_shards:
@@ -134,8 +145,11 @@ def _compile_totals() -> tuple[int, int, float, float]:
 def _compile_span(key: tuple):
     before = _compile_totals()
     with obs_trace.span("engine.compile", key=repr(key)) as sp:
+        acct = obs_memory.ACCOUNT
+        acct.launching(repr(key), sp)
         try:
-            yield
+            with acct.exhaustion(repr(key)):
+                yield
         finally:
             req, hits, secs, load = (a - b for a, b in zip(_compile_totals(),
                                                            before))
@@ -151,7 +165,12 @@ def _compiling(fresh: bool, *key):
     meanwhile (``obs_metrics.watch_compiles``): programs looked up in the
     persistent cache, programs found there, the seconds inside the backend's
     compile-or-load (``backend_s``) and those of them not spent loading a
-    hit (``compiled_s``)."""
+    hit (``compiled_s``).  Where the backend reports allocator statistics the
+    span also carries ``hbm_in_use`` and ``hbm_peak_before`` (read at its
+    entry) and, in the ring's record, ``hbm_peak_after`` (read where the call
+    is first waited for, :meth:`Engine._sync`): the memory account's two
+    reads a fresh program (``obs/memory.py``); an allocation that fails in
+    the call logs ``hbm_exhausted`` on its way out."""
     return _compile_span(key) if fresh else contextlib.nullcontext()
 
 
@@ -340,6 +359,23 @@ def _note_cache_bytes(cache, tokens: int, batch: int, paged: bool) -> int:
     return per_token
 
 
+def _closes_request(stream):
+    """A one-stream generator method whose end (exhausted, returned early, or
+    abandoned by its consumer) is the close of a request:
+    :meth:`Engine.settled` runs after the stream's own clean-up.  A stream
+    that raises is no idle instant (its cache may be gone with a donation):
+    the error goes on as it is and a later close reads."""
+    @functools.wraps(stream)
+    def closing(self, *args, **kwargs):
+        try:
+            yield from stream(self, *args, **kwargs)
+        except GeneratorExit:
+            self.settled()
+            raise
+        self.settled()
+    return closing
+
+
 class Engine:
     """Owns placed params, the KV cache, and the compiled step functions."""
 
@@ -424,9 +460,16 @@ class Engine:
                      "collectives run as plain psum all-reduce")
         obs_metrics.watch_compiles()  # before this engine's first program
         hostenv.kernels_without_frames()  # for a caller that is no entry point
-        self.params = sharding.place_params(params, cfg, self.mesh)
+        # the memory account (obs/memory.py): what the process held on the
+        # chip before its first upload, then one reading a load phase; an
+        # allocation that fails in the load says who held what
+        acct = obs_memory.ACCOUNT
+        acct.found()
+        with acct.exhaustion():
+            self.params = sharding.place_params(params, cfg, self.mesh)
         for dev, nbytes in _resident_param_bytes(self.params).items():
             obs_metrics.PARAM_BYTES_RESIDENT.set(dev, nbytes)
+        acct.phase("load_place")
         # kv_dtype "q8" (or int8) selects the quantized cache: int8 values
         # + per-position f32 scales — ~2× less cache HBM traffic and
         # residency than bf16, so max context per chip nearly doubles
@@ -475,20 +518,22 @@ class Engine:
             # pages); paged attention dequantizes after the int8-sized
             # page read, so cache HBM traffic and residency halve again
             # on top of paging
-            self.cache = _zeros_on_mesh(
-                lambda: init_kv_pool(cfg, self.kv_pages, self.kv_page_size,
-                                     dtype=None if kv_quant else kv_dtype,
-                                     quant=kv_quant, slots=batch,
-                                     max_pages=self.max_pages_per_slot),
-                self._cache_sh)
+            with acct.exhaustion():
+                self.cache = _zeros_on_mesh(
+                    lambda: init_kv_pool(cfg, self.kv_pages, self.kv_page_size,
+                                         dtype=None if kv_quant else kv_dtype,
+                                         quant=kv_quant, slots=batch,
+                                         max_pages=self.max_pages_per_slot),
+                    self._cache_sh)
             obs_metrics.KV_PAGE_CODEC.set(
                 "int8" if kv_quant else str(self.cache.k.dtype), 1)
         else:
-            self.cache = _zeros_on_mesh(
-                lambda: init_kv_cache(cfg, batch, self.seq_len,
-                                      dtype=None if kv_quant else kv_dtype,
-                                      quant=kv_quant),
-                self._cache_sh)
+            with acct.exhaustion():
+                self.cache = _zeros_on_mesh(
+                    lambda: init_kv_cache(cfg, batch, self.seq_len,
+                                          dtype=None if kv_quant else kv_dtype,
+                                          quant=kv_quant),
+                    self._cache_sh)
         # what one cached token occupies over all layers, learned from the
         # cache itself (a latent cache: layers x C x element size; a windowed
         # model's rings at their own positions)
@@ -496,6 +541,12 @@ class Engine:
                   else batch * self.seq_len)
         self.kv_bytes_per_token = _note_cache_bytes(self.cache, tokens, batch,
                                                     self.paged)
+        # the account's ``cache`` owner: this engine's planes on each device,
+        # beside every other live engine's (a server's chat engine keeps its
+        # contiguous cache beside the batch engine's pool), until it goes
+        held = _resident_param_bytes(self.cache.planes())
+        acct.cache_built(held)
+        weakref.finalize(self, acct.cache_dropped, held)
         self.pos = 0
         # the one-stream account of a recurrent state: a call may start at a
         # position whose rows before it lie in [lo, hi) (_state_enter /
@@ -552,6 +603,19 @@ class Engine:
         self.sampling_path = os.environ.get(
             "DLLAMA_SAMPLING_PATH", "device").strip().lower() or "device"
         self._offsets: jax.Array | None = None  # ragged-batch left padding
+        acct.rss("ready")
+
+    def settled(self) -> None:
+        """Nothing of this engine is in flight: the scheduler is about to
+        park, or a one-stream request has closed.  Where a program was
+        compiled or a cache built since the last reading, the account reads
+        what stays resident (a cut stream may have left a burst in flight:
+        the cache is the last launch's output, so it is waited for first); a
+        warm engine reads nothing and waits for nothing."""
+        acct = obs_memory.ACCOUNT
+        if acct.dirty and acct.active:
+            jax.block_until_ready(self.cache)
+            acct.idle()
 
     # ------------------------------------------------------------------
     def reset(self):
@@ -953,6 +1017,13 @@ class Engine:
         def wait() -> list[str]:
             actions = FAULTS.fire("engine.device_step")
             jax.block_until_ready(arrays)
+            if obs_memory.ACCOUNT.pending:
+                # the first wait after a fresh program's launch: the cache is
+                # the last launch's output, so the program itself has ended
+                # (a pipelined step waits for its predecessor's tokens here)
+                # before the account reads where it left the peak
+                jax.block_until_ready(self.cache)
+                obs_memory.ACCOUNT.landed()
             return actions
 
         if not self.step_timeout:
@@ -1237,6 +1308,7 @@ class Engine:
         self._note_executable(fresh, key=("chunk",) + key)
         return self._chunk_fns[key]
 
+    @_closes_request
     def generate_stream(self, prompt_tokens: list[int], steps: int, *,
                         temperature: float = 0.0, topp: float = 0.9,
                         seed: int | None = 0, eos_ids: tuple[int, ...] = (),
@@ -1434,6 +1506,7 @@ class Engine:
                 break
         return outs
 
+    @_closes_request
     def generate_batch_stream(self, prompts: list[list[int]], steps: int, *,
                               temperature: float = 0.0, topp: float = 0.9,
                               seed: int | None = 0, chunk: int = 16):
@@ -1929,6 +2002,7 @@ class Engine:
                                              ngram=ngram, k=k,
                                              eos_ids=eos_ids))
 
+    @_closes_request
     def generate_pld_stream(self, prompt_tokens: list[int], steps: int, *,
                             ngram: int = 2, k: int = 7,
                             eos_ids: tuple[int, ...] = ()):
@@ -2022,6 +2096,7 @@ class Engine:
             if cur in eos_ids:
                 break
 
+    @_closes_request
     def generate(self, prompt_tokens: list[int], steps: int, sampler: Sampler,
                  eos_ids: tuple[int, ...] = (), prefill_single_token: bool = False):
         """Yield ``(token_id, stats)`` for up to ``steps`` generated tokens.

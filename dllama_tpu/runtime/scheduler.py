@@ -1688,6 +1688,10 @@ class SlotScheduler:
                         if dls:
                             timeout = min(timeout,
                                           max(min(dls) - now, 0.0))
+                        # no step is in flight (_dispatch's invariant):
+                        # the memory account's idle edge, which reads only
+                        # after a compile or a build (obs/memory.py)
+                        self.engine.settled()
                         w0 = time.perf_counter()
                         with self._span("sched.idle", timeout=timeout):
                             self._cond.wait(timeout)
