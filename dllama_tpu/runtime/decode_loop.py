@@ -22,6 +22,7 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from ..models.config import ModelConfig
 from ..models.transformer import (KVCache, forward_last, forward_slots,
@@ -122,6 +123,67 @@ def device_sample_rows(logits: jax.Array, key: jax.Array, temps: jax.Array,
         if topks is None:
             topks = jnp.zeros((b,), jnp.int32)
         return sample_on_device(logits, coins, temps, topps, topks, mask=mask)
+
+
+# A slot program's host operands cross as ONE int32 vector, built on the host
+# and uploaded by the jitted call itself (PR 55: an upload an operand cost a
+# served step 3 ms of Python, an argument an operand still 1.3 ms of
+# transfers).  In order: the (B, t) tokens, then B each of positions,
+# ``n_valid``, top-k, temperatures and top-p (floats by their bits), one flag
+# (the tokens are ``fed``), then the (B, pages) page table.
+
+
+def pack_slot_operands(tokens, pos_rows, n_valid, temps, topps, topks=None,
+                       page_tables=None) -> np.ndarray:
+    """The host side: a private, read-only int32 vector whatever the caller's
+    dtypes and strides (so one executable a key, and the caller may overwrite
+    its buffers at once).  ``tokens`` None: a pipelined step, whose tokens are
+    on the device (the flag set, the token column zero).
+
+    Joined as bytes, not assigned into an array: numpy lets go of the GIL
+    around every copy of more than 500 elements and every zeroed allocation
+    of a KiB, and in a server's process the first such release after a
+    fan-out hands the interpreter to the sixteen writer threads it woke,
+    ≈0.5 ms of a serial step (chip probe, PERF.md section 6, PR 55);
+    ``tobytes`` keeps it."""
+    b = len(pos_rows)
+    i32 = lambda a: np.asarray(a, np.int32).tobytes()  # noqa: E731
+    f32 = lambda a: np.asarray(a, np.float32).tobytes()  # noqa: E731
+    toks = bytes(4 * b) if tokens is None else i32(tokens)
+    per_row = [i32(pos_rows), i32(n_valid),
+               bytes(4 * b) if topks is None else i32(topks),
+               f32(temps), f32(topps)]
+    table = b"" if page_tables is None else i32(page_tables)
+    # the device side finds an operand by where it starts: one of another
+    # length would move every later one, where separate arguments could not
+    if any(len(v) != 4 * b for v in per_row) \
+            or len(toks) % (4 * b) or len(table) % (4 * b):
+        raise ValueError("slot operands disagree on the number of rows")
+    return np.frombuffer(
+        b"".join((toks, *per_row, i32(tokens is None), table)), np.int32)
+
+
+def unpack_slot_operands(ops: jax.Array, fed: jax.Array, t: int,
+                         paged: bool) -> dict:
+    """The device side, static slices of ``pack_slot_operands``' vector: the
+    operands of :func:`slot_chunk` and :func:`slot_verify_chunk` by their
+    parameters' names.  At ``t`` = 1 a step whose flag is set takes its
+    tokens from ``fed`` (B,), a prior dispatch's ``last`` that never left
+    the device; a host-fed step hands in any (B,) array, so both kinds of
+    step run one executable."""
+    b = fed.shape[0]
+    rows = b * t
+    tokens = ops[:rows].reshape(b, t)
+    pos_rows, n_valid, topks, temps, topps = (
+        ops[rows + j * b:rows + (j + 1) * b] for j in range(5))
+    if t == 1:
+        tokens = jnp.where(ops[rows + 5 * b] != 0,
+                           fed.astype(jnp.int32)[:, None], tokens)
+    as_f32 = lambda x: jax.lax.bitcast_convert_type(x, jnp.float32)  # noqa: E731
+    return dict(tokens=tokens, pos_rows=pos_rows, n_valid=n_valid,
+                temps=as_f32(temps), topps=as_f32(topps), topks=topks,
+                page_table=ops[rows + 5 * b + 1:].reshape(b, -1)
+                if paged else None)
 
 
 def slot_chunk(params, cfg: ModelConfig, cache: KVCache, tokens: jax.Array,

@@ -177,8 +177,8 @@ def _compiling(fresh: bool, *key):
 def _compile_clock() -> float:
     """Seconds JAX has spent tracing and compiling in this process: it moves
     over a call that compiled, whether the engine knew the program or not
-    (``jax.jit`` traces a known program again for operands placed anew: the
-    first pipelined step, whose tokens are on the device)."""
+    (``jax.jit`` traces a known program again for operands placed anew: a
+    key's second call, whose cache is the one its first call returned)."""
     return obs_metrics.JAXPR_TRACE_SECONDS.value \
         + obs_metrics.BACKEND_COMPILE_SECONDS.value
 
@@ -597,6 +597,7 @@ class Engine:
         # compiled chunk returns — sampled pure decode never syncs the
         # host for randomness (one-dispatch decode, ISSUE 20)
         self._dev_key: jax.Array | None = None
+        self._no_feed: jax.Array | None = None  # see _slot_operands
         # which sampling implementation owns this engine's draws; rides
         # snapshots/hand-off records so a sampled slot never resumes on
         # a replica whose stream would diverge
@@ -1590,29 +1591,33 @@ class Engine:
     def _slot_operands(self, kind: str, tokens_np, feed_dev, pos_rows_np,
                        n_valid_np, sub, temps_np, topps_np, topks_np,
                        page_tables_np, vocab_mask_np) -> tuple:
-        """The operands of a slot program in its argument order, the host
-        arrays uploaded: the span ``engine.h2d`` (``arrays`` and ``bytes``
-        that crossed; ``feed_dev``: the tokens were on the device already)
-        and ``sched_host_ms{phase="h2d"}`` under the step's ``kind``."""
+        """The operands of a slot program in its argument order.  The host
+        operands are packed into one numpy vector
+        (decode_loop.pack_slot_operands), which the jitted call uploads in
+        its own argument path (inside ``engine.launch``): no Python-level
+        upload an operand in front of it.  The array is private, so the
+        caller may overwrite its buffers (the scheduler's page tables) the
+        moment the enqueue returns, whenever the runtime reads it.  The span
+        ``engine.h2d`` (``arrays`` and ``bytes`` handed over to cross: the
+        packed operands, and the vocabulary mask where there is one;
+        ``feed_dev``: the tokens were on the device already) and
+        ``sched_host_ms{phase="h2d"}`` under the step's ``kind``."""
+        from .decode_loop import pack_slot_operands
         with obs_trace.span("engine.h2d", feed_dev=feed_dev is not None,
                             total=obs_metrics.host_ms("h2d", kind)) as sp:
-            if topks_np is None:
-                topks_np = np.zeros(len(pos_rows_np), np.int32)
-            host = [] if feed_dev is not None else [(tokens_np, jnp.int32)]
-            host += [(pos_rows_np, jnp.int32), (n_valid_np, jnp.int32),
-                     (temps_np, jnp.float32), (topps_np, jnp.float32),
-                     (topks_np, jnp.int32)]
-            if self.paged:
-                host.append((page_tables_np, jnp.int32))
+            host = [pack_slot_operands(
+                tokens_np, pos_rows_np, n_valid_np, temps_np, topps_np,
+                topks_np, page_tables_np if self.paged else None)]
             if vocab_mask_np is not None:
-                host.append((vocab_mask_np, bool))
-            dev = [jnp.asarray(a, dt) for a, dt in host]
-            tok = dev.pop(0) if feed_dev is None \
-                else jnp.asarray(feed_dev, jnp.int32)[:, None]  # on device
-            pos, n_valid, *rest = dev
-            sp.update(arrays=len(host),
-                      bytes=sum(np.asarray(a).nbytes for a, _ in host))
-            return (self.params, self.cache, tok, pos, n_valid, sub, *rest)
+                host.append(np.array(vocab_mask_np, np.bool_, order="C"))
+            if self._no_feed is None:  # what a host-fed step hands in as
+                # ``fed``: placed like a dispatch's ``last_dev``, so that a
+                # key's one executable is traced once
+                self._no_feed = jax.device_put(
+                    np.zeros((self.batch,), np.int32), self._rep)
+            fed = self._no_feed if feed_dev is None else feed_dev
+            sp.update(arrays=len(host), bytes=sum(a.nbytes for a in host))
+            return (self.params, self.cache, host[0], fed, sub, *host[1:])
 
     def slot_step_async(self, tokens_np: np.ndarray | None,
                         pos_rows_np: np.ndarray, n_valid_np: np.ndarray, *,
@@ -1666,7 +1671,7 @@ class Engine:
         (decode_loop.slot_chunk).  Its shape is static per engine, so it
         rides the same compile buckets as one extra operand.
         """
-        from .decode_loop import slot_chunk
+        from .decode_loop import slot_chunk, unpack_slot_operands
         if self.sp > 1:
             raise ValueError("slot serving is not supported on sp meshes "
                              "(sequence-sharded cache); use sp=1")
@@ -1707,36 +1712,26 @@ class Engine:
                fused_mode() if self.paged else "", has_mask)
         fresh = key not in self._chunk_fns
         if fresh:
-            cfg = self.cfg
-            if self.paged:
-                self._chunk_fns[key] = jax.jit(
-                    lambda p, c, tok, pr, nv, k, tm, tp, tk, ptab, vm=None:
-                    slot_chunk(
-                        p, cfg, c, tok, pr, nv, k, tm, tp, tk,
-                        steps=steps, greedy=greedy, page_table=ptab,
-                        vocab_mask=vm),
-                    donate_argnums=(1,),
-                    out_shardings=(self._rep, self._cache_sh, self._rep,
-                                   self._rep))
-            else:
-                self._chunk_fns[key] = jax.jit(
-                    lambda p, c, tok, pr, nv, k, tm, tp, tk, vm=None:
-                    slot_chunk(
-                        p, cfg, c, tok, pr, nv, k, tm, tp, tk,
-                        steps=steps, greedy=greedy, vocab_mask=vm),
-                    donate_argnums=(1,),
-                    out_shardings=(self._rep, self._cache_sh, self._rep,
-                                   self._rep))
+            cfg, paged = self.cfg, self.paged
+            self._chunk_fns[key] = jax.jit(
+                lambda p, c, ops, fed, k, vm=None: slot_chunk(
+                    p, cfg, c, key=k, steps=steps, greedy=greedy,
+                    vocab_mask=vm,
+                    **unpack_slot_operands(ops, fed, t, paged)),
+                donate_argnums=(1,),
+                out_shardings=(self._rep, self._cache_sh, self._rep,
+                               self._rep))
         self._note_executable(fresh, key=key)
         fn = self._chunk_fns[key]
         sub = self._next_dev_key()
         kind = "mixed" if t > 1 else "decode"  # the step's, as given here
         t0 = time.perf_counter()
         # the host's part of the enqueue, in two phases that each feed a
-        # cell of ``sched_host_ms``: the operand uploads (``engine.h2d``)
-        # and the jitted call (``engine.launch``: executable look-up,
-        # argument handling, PJRT enqueue and any wait inside it; a call
-        # that compiled goes to the ``compile`` cell instead)
+        # cell of ``sched_host_ms``: the operands' preparation
+        # (``engine.h2d``) and the jitted call (``engine.launch``:
+        # executable look-up, argument handling with the host operands'
+        # transfers, PJRT enqueue and any wait inside it; a call that
+        # compiled goes to the ``compile`` cell instead)
         with obs_trace.span("engine.slot_enqueue", t=t, steps=steps):
             args = self._slot_operands(
                 kind, tokens_np, feed_dev, pos_rows_np, n_valid_np, sub,
@@ -1795,7 +1790,7 @@ class Engine:
         Same engine-state discipline as ``slot_step_async``: slot clocks
         stay host-side with the scheduler; ``self.pos`` is untouched.
         """
-        from .decode_loop import slot_verify_chunk
+        from .decode_loop import slot_verify_chunk, unpack_slot_operands
         if self.sp > 1:
             raise ValueError("slot serving is not supported on sp meshes "
                              "(sequence-sharded cache); use sp=1")
@@ -1829,24 +1824,14 @@ class Engine:
                t, greedy, fused_mode() if self.paged else "", has_mask)
         fresh = key not in self._chunk_fns
         if fresh:
-            cfg = self.cfg
-            if self.paged:
-                self._chunk_fns[key] = jax.jit(
-                    lambda p, c, tok, pr, nv, k, tm, tp, tk, ptab, vm=None:
-                    slot_verify_chunk(p, cfg, c, tok, pr, nv, k, tm, tp, tk,
-                                      greedy=greedy, page_table=ptab,
-                                      vocab_mask=vm),
-                    donate_argnums=(1,),
-                    out_shardings=(self._rep, self._cache_sh,
-                                   self._rep, self._rep, self._rep))
-            else:
-                self._chunk_fns[key] = jax.jit(
-                    lambda p, c, tok, pr, nv, k, tm, tp, tk, vm=None:
-                    slot_verify_chunk(p, cfg, c, tok, pr, nv, k, tm, tp, tk,
-                                      greedy=greedy, vocab_mask=vm),
-                    donate_argnums=(1,),
-                    out_shardings=(self._rep, self._cache_sh,
-                                   self._rep, self._rep, self._rep))
+            cfg, paged = self.cfg, self.paged
+            self._chunk_fns[key] = jax.jit(
+                lambda p, c, ops, fed, k, vm=None: slot_verify_chunk(
+                    p, cfg, c, key=k, greedy=greedy, vocab_mask=vm,
+                    **unpack_slot_operands(ops, fed, t, paged)),
+                donate_argnums=(1,),
+                out_shardings=(self._rep, self._cache_sh,
+                               self._rep, self._rep, self._rep))
         self._note_executable(fresh, key=key)
         fn = self._chunk_fns[key]
         sub = self._next_dev_key()
