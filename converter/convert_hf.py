@@ -14,7 +14,10 @@ becomes the flat ``conv_taps``; a tied head is written from the embedding's
 rows) and brumby folders (``ARCH_BRUMBY``: Qwen3's names with a gate
 ``self_attn.g_proj``, header keys 31 and 39; the names are ASSUMED, one table,
 ``_BRUMBY_LEAVES``, unverified until the published files are in the
-repository).  Key semantics preserved:
+repository) and ouro folders (``ARCH_OURO``: a looped model, Llama's names with
+the two closing norms ``input_layernorm_2`` / ``post_attention_layernorm_2``,
+header keys 31 and 40; ASSUMED likewise, ``_OURO_LEAVES``;
+``early_exit_gate.*`` is skipped by name).  Key semantics preserved:
 
 * q/k head permutation (convert-hf.py:12-15): HF stores RoPE in rotate-half
   layout; the `.m` format expects the interleaved-pair layout, so q and k
@@ -53,6 +56,7 @@ ARCH_BY_MODEL_TYPE = {
     "exaone_moe": mfile.ARCH_EXAONE_MOE,
     "lfm2_moe": mfile.ARCH_LFM2_MOE,
     "brumby": mfile.ARCH_BRUMBY,
+    "ouro": mfile.ARCH_OURO,
 }
 HIDDEN_ACT = {"gelu": mfile.ACT_GELU, "silu": mfile.ACT_SILU,
               "relu": mfile.ACT_RELU}
@@ -312,6 +316,33 @@ def _brumby_fields(config: dict) -> dict:
                 retention_degree=2)
 
 
+def _ouro_fields(config: dict) -> dict:
+    """The header's keys past the fourteen from an ``ouro`` config.json: the
+    norm's eps and the passes (``total_ut_steps``).  The exit gate is not in the
+    file: at ``early_exit_threshold`` 1 every token runs every pass, and another
+    threshold is another function (a scheduler's and a sampler's), refused by
+    name, as is what the block does not have."""
+    def no(why):
+        raise SystemExit(f"ouro: {why}")
+
+    if config.get("early_exit_threshold", 1) != 1:
+        no(f"early_exit_threshold is {config['early_exit_threshold']!r}; the "
+           "exit gate is not computed, so only the published threshold of 1 "
+           "(every token runs every pass) is the same function")
+    if config.get("use_sliding_window", False) or config.get("rope_scaling") \
+            or config.get("tie_word_embeddings", False) \
+            or config.get("attention_bias", False):
+        no("a sliding window, rope_scaling, a tied head and a projection bias "
+           "are not part of this block")
+    heads = config["num_attention_heads"]
+    if config.get("head_dim", config["hidden_size"] // heads) * heads \
+            != config["hidden_size"]:
+        no("head_dim * num_attention_heads is not hidden_size; an ouro .m "
+           "file states no head size")
+    return dict(norm_eps=float(config.get("rms_norm_eps", 1e-6)),
+                loops=int(config["total_ut_steps"]))
+
+
 def load_spec(folder: str, weights_ftype: int, experts_held: int = 0,
               first_expert: int = 0) -> mfile.ModelSpec:
     with open(os.path.join(folder, "config.json")) as f:
@@ -330,6 +361,8 @@ def load_spec(folder: str, weights_ftype: int, experts_held: int = 0,
         ext = _lfm2_moe_fields(config)
     if arch == mfile.ARCH_BRUMBY:
         ext = _brumby_fields(config)
+    if arch == mfile.ARCH_OURO:
+        ext = _ouro_fields(config)
     if arch in (mfile.ARCH_EXAONE_MOE, mfile.ARCH_LFM2_MOE):
         config = dict(config, rope_theta=(config.get("rope_parameters") or {}).get(
             "rope_theta", config.get("rope_theta", 10000.0)))
@@ -414,6 +447,17 @@ _BRUMBY_LEAVES = {
     "rms_att": "input_layernorm", "rms_ffn": "post_attention_layernorm",
     "w1": "mlp.gate_proj", "w2": "mlp.down_proj", "w3": "mlp.up_proj",
 }
+# ASSUMED: an ouro layer's tensors under ``model.layers.N.``: Llama's names and
+# the two norms that close a branch.  Unverified until the published files are
+# in the repository: a name that differs shows as "Layer ... not found" on the
+# first tensor it concerns.  The four norms land in a Grok-1 file's slots
+_OURO_LEAVES = {
+    "wq": "self_attn.q_proj", "wk": "self_attn.k_proj", "wv": "self_attn.v_proj",
+    "wo": "self_attn.o_proj", "w1": "mlp.gate_proj", "w2": "mlp.down_proj",
+    "w3": "mlp.up_proj", "rms_att": "input_layernorm",
+    "rms_ffn": "input_layernorm_2", "rms_moe": "post_attention_layernorm",
+    "rms_ffn2": "post_attention_layernorm_2",
+}
 _DEEPSEEK2_LEAVES = {
     "wq_a": "self_attn.q_a_proj", "q_a_norm": "self_attn.q_a_layernorm",
     "wq_b": "self_attn.q_b_proj", "wkv_a": "self_attn.kv_a_proj_with_mqa",
@@ -446,6 +490,8 @@ def hf_source_name(our_name: str, spec: mfile.ModelSpec) -> tuple[str, bool]:
         return f"{base}.{_LFM2_LEAVES[leaf]}.weight", False
     if spec.arch == mfile.ARCH_BRUMBY:  # rows as published: halves rotate
         return f"{base}.{_BRUMBY_LEAVES[leaf]}.weight", False
+    if spec.arch == mfile.ARCH_OURO:    # rows as published: halves rotate
+        return f"{base}.{_OURO_LEAVES[leaf]}.weight", False
     # rows as published: these runtimes rotate halves, as HF does
     olmoe = spec.arch in (mfile.ARCH_OLMOE, mfile.ARCH_SMALLTHINKER,
                           mfile.ARCH_EXAONE_MOE)
@@ -504,6 +550,12 @@ def convert(folder: str, weights_ftype: int, out_path: str,
             print(f"⏭️  skipping {len(mtp)} mtp.* tensors (the multi-token-"
                   "prediction block is not computed; next-token logits do not "
                   "depend on it)")
+    if spec.arch == mfile.ARCH_OURO:
+        gate = sorted(k for k in store._index if "early_exit_gate" in k)
+        if gate:
+            print(f"⏭️  skipping {len(gate)} early_exit_gate.* tensors (at "
+                  "early_exit_threshold 1 every token runs every pass; the "
+                  "gate changes no logit)")
     if spec.arch == mfile.ARCH_DEEPSEEK2 and store.has("e_score_correction_bias"):
         raise SystemExit("deepseek_v2: the checkpoint has e_score_correction_bias "
                          "(V3's router); the runtime has no correction bias")

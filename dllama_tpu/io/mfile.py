@@ -94,11 +94,18 @@ ARCH_LFM2_MOE = 0xABCD07
 # degree ``retention_degree`` (key 39): a gate a kv head (``wg``) and, in place
 # of keys and values, a state matrix a kv head (``ops/retention.py``)
 ARCH_BRUMBY = 0xABCD08
+# Ouro (``ouro``): a looped model.  Llama's dense block (rotate-half RoPE, no
+# bias) with both branches normed again before the residual add (the four norm
+# vectors of a Grok-1 layer, in its slots), and the whole stack of ``n_layers``
+# weight sets applied ``n_loops`` times (key 40) over its own output, the final
+# norm closing every pass; pass ``u`` of layer ``l`` keeps keys and values of
+# its own (cache plane ``u * n_layers + l``)
+ARCH_OURO = 0xABCD09
 ARCH_NAMES = {ARCH_LLAMA: "llama", ARCH_GROK1: "grok1", ARCH_MIXTRAL: "mixtral",
               ARCH_OLMOE: "olmoe", ARCH_DEEPSEEK2: "deepseek2",
               ARCH_SMALLTHINKER: "smallthinker",
               ARCH_EXAONE_MOE: "exaone_moe", ARCH_LFM2_MOE: "lfm2_moe",
-              ARCH_BRUMBY: "brumby"}
+              ARCH_BRUMBY: "brumby", ARCH_OURO: "ouro"}
 
 # TransformerHiddenAct (transformer.hpp:45-48), and beyond it ReLU
 ACT_GELU = 0
@@ -124,7 +131,8 @@ KEY_WEIGHTS_FLOAT_TYPE = 13
 # SmallThinker's own (``WINDOW_KEYS``, 32..34; its file also carries key 31),
 # K-EXAONE's (``SHARE_KEYS``, 35..37; its file carries some of each),
 # LFM2's one (``CONV_KEYS``, 38; its file carries some of each) and Brumby's
-# one (``RETENTION_KEYS``, 39; its file also carries key 31).
+# one (``RETENTION_KEYS``, 39; its file also carries key 31) and Ouro's one
+# (``LOOP_KEYS``, 40; its file also carries key 31).
 # ``(key, field, is_float)``: a float travels as the bits of its IEEE-754 f32
 # in the i32
 EXT_KEYS = (
@@ -163,7 +171,11 @@ CONV_KEYS = (
 RETENTION_KEYS = (
     (39, "retention_degree", False),    # p of a power-retention layer's (q . k)^p
 )
-ALL_EXT_KEYS = EXT_KEYS + WINDOW_KEYS + SHARE_KEYS + CONV_KEYS + RETENTION_KEYS
+LOOP_KEYS = (
+    (40, "loops", False),               # passes of the whole stack over its own output (n_loops)
+)
+ALL_EXT_KEYS = (EXT_KEYS + WINDOW_KEYS + SHARE_KEYS + CONV_KEYS + RETENTION_KEYS
+                + LOOP_KEYS)
 # the keys a file of an arch carries past the fourteen
 ARCH_EXT_KEYS = {ARCH_DEEPSEEK2: tuple(range(14, 32)),
                  ARCH_SMALLTHINKER: (31, 32, 33, 34),
@@ -172,9 +184,10 @@ ARCH_EXT_KEYS = {ARCH_DEEPSEEK2: tuple(range(14, 32)),
                  # K-EXAONE's (a period and a place in it; no second pair of
                  # keys for the same two numbers); no window, so no key 33
                  ARCH_LFM2_MOE: (19, 23, 24, 31, 32, 34, 37, 38),
-                 ARCH_BRUMBY: (31, 39)}
+                 ARCH_BRUMBY: (31, 39),
+                 ARCH_OURO: (31, 40)}
 _EXT_BY_KEY = {k: (name, is_f) for k, name, is_f in ALL_EXT_KEYS}
-KEY_MAX = RETENTION_KEYS[-1][0]
+KEY_MAX = LOOP_KEYS[-1][0]
 
 
 def _f32_bits(x: float) -> int:
@@ -235,6 +248,8 @@ class ModelSpec:
     conv_taps: int = 0
     # ARCH_BRUMBY's; 0 where the arch has none
     retention_degree: int = 0
+    # ARCH_OURO's; 0 where the arch has none (the stack runs once)
+    loops: int = 0
 
     @property
     def head_size(self) -> int:
@@ -325,7 +340,9 @@ def tensor_plan(spec: ModelSpec) -> list[TensorInfo]:
             add(f"layers.{i}.w3", (spec.hidden_dim, spec.dim), w)
         add(f"layers.{i}.rms_att", (spec.dim,), quants.F32)
         add(f"layers.{i}.rms_ffn", (spec.dim,), quants.F32)
-        if spec.arch == ARCH_GROK1:
+        if spec.arch in (ARCH_GROK1, ARCH_OURO):
+            # the norm before the FFN and the one that closes it: ``rms_ffn``
+            # closes the attention branch in a file of these two archs
             add(f"layers.{i}.rms_moe", (spec.dim,), quants.F32)
             add(f"layers.{i}.rms_ffn2", (spec.dim,), quants.F32)
     add("rms_final", (spec.dim,), quants.F32)
@@ -510,6 +527,13 @@ def validate_spec(spec: ModelSpec, path) -> ModelSpec:
                             expected=hex(ARCH_BRUMBY), got=hex(spec.arch))
     if spec.arch == ARCH_BRUMBY:
         _validate_brumby(spec, path)
+    if (spec.arch == ARCH_OURO) != bool(spec.loops):
+        raise ArtifactError(path, "header key",
+                            "key 40 (the passes of a looped stack) describes an "
+                            "ouro file, and an ouro file states it",
+                            expected=hex(ARCH_OURO), got=hex(spec.arch))
+    if spec.arch == ARCH_OURO:
+        _validate_ouro(spec, path)
     if spec.arch == ARCH_EXAONE_MOE:
         _validate_exaone_moe(spec, path)
     elif spec.experts_held or spec.first_expert or (
@@ -646,6 +670,22 @@ def _validate_lfm2_moe(spec: ModelSpec, path) -> None:
         bad("n_shared_experts", "an lfm2_moe layer has no shared expert and "
             "one group of experts (keys 20..22 are not its own)", 0,
             (spec.n_shared_experts, spec.n_groups, spec.topk_groups))
+
+
+def _validate_ouro(spec: ModelSpec, path) -> None:
+    """The cross-field rules of an ``ARCH_OURO`` header."""
+    def bad(field, why, expected, got):
+        raise ArtifactError(path, f"header field {field}", why,
+                            expected=expected, got=got)
+
+    if not 1 <= spec.loops <= 64:
+        bad("loops", "an ouro file states how many times its stack runs",
+            "1..64", spec.loops)
+    if spec.n_experts:
+        bad("n_experts", "an ouro layer has a dense FFN", 0, spec.n_experts)
+    if spec.dim // spec.n_heads % 2:
+        bad("n_heads", "RoPE rotates halves of a head", "an even head size",
+            spec.dim // spec.n_heads)
 
 
 def _validate_deepseek2(spec: ModelSpec, path) -> None:

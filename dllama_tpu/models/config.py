@@ -87,6 +87,23 @@ class ModelConfig:
     conv_taps: int = 0              # > 0: a period's other layers are short convolutions
     # ---- ARCH_BRUMBY (header key 39); 0 = the arch has none
     retention_degree: int = 0       # > 0: every layer is power retention of this degree
+    # ---- ARCH_OURO (header key 40); 0 = the arch has none
+    loops: int = 0                  # > 0: the whole stack runs this many times (n_loops)
+
+    @property
+    def n_loops(self) -> int:
+        """Passes of the whole stack of ``n_layers`` weight sets over its own
+        output, the final norm closing each (a looped model, Ouro); 1 for every
+        other arch.  A token therefore runs ``n_layers * n_loops`` blocks."""
+        return self.loops or 1
+
+    @property
+    def n_cache_planes(self) -> int:
+        """Leading axis of the cache of keys and values: a plane a (pass,
+        layer), plane ``u * n_layers + l`` for pass ``u`` of layer ``l``, since a
+        pass attends over what the same pass of the same layer wrote at the
+        earlier positions.  ``n_layers`` wherever the stack runs once."""
+        return self.n_layers * self.n_loops
 
     @property
     def head_size(self) -> int:
@@ -247,8 +264,10 @@ class ModelConfig:
     def post_block_norms(self) -> bool:
         """Grok-1 normalizes each sub-block's *output* before the residual
         add (grokRmfFfnNorm / grokMoeRmsNormFinal, grok1-tasks.cpp:16-41,
-        :245-263); Llama/Mixtral add raw outputs to the residual."""
-        return self.arch == mfile.ARCH_GROK1
+        :245-263); Llama/Mixtral add raw outputs to the residual.  Ouro's
+        "sandwich" norms are the same four vectors a layer, around a dense
+        FFN."""
+        return self.arch in (mfile.ARCH_GROK1, mfile.ARCH_OURO)
 
     @property
     def qk_head_norm(self) -> bool:
@@ -364,6 +383,17 @@ def tiny_brumby(**kw) -> ModelConfig:
     base = dict(arch=mfile.ARCH_BRUMBY, dim=160, hidden_dim=224, n_layers=4,
                 n_heads=10, n_kv_heads=2, vocab_size=128, seq_len=512,
                 rope_theta=1e6, norm_eps=1e-6, retention_degree=2)
+    base.update(kw)
+    return tiny_config(**base)
+
+
+def tiny_ouro(**kw) -> ModelConfig:
+    """Ouro at a toy size: three layers run three times (so that the pass
+    count, the layer count and 1 are three numbers), as many kv heads as query
+    heads, sandwich norms, an untied head, eps 1e-6."""
+    base = dict(arch=mfile.ARCH_OURO, dim=64, hidden_dim=96, n_layers=3,
+                n_heads=4, n_kv_heads=4, vocab_size=128, seq_len=64,
+                rope_theta=1e6, norm_eps=1e-6, loops=3)
     base.update(kw)
     return tiny_config(**base)
 

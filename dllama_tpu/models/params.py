@@ -139,10 +139,10 @@ def param_shapes(cfg: ModelConfig) -> dict[str, tuple[int, ...]]:
             "gate": (L, E, D, F),
             "down": (L, E, F, D),
         })
-        if cfg.post_block_norms:  # Grok-1 extra norms
-            shapes.update({"rms_moe": (L, D), "rms_ffn2": (L, D)})
     else:
         shapes.update({"w1": (L, D, F), "w2": (L, F, D), "w3": (L, D, F)})
+    if cfg.post_block_norms:  # Grok-1's and Ouro's extra norms
+        shapes.update({"rms_moe": (L, D), "rms_ffn2": (L, D)})
     return shapes
 
 
@@ -377,9 +377,6 @@ def _read_params(mf: mfile.MFile, cfg: ModelConfig, dtype,
                             for e in range(cfg.n_experts)]
                     per_layer.append(np.stack(mats))
                 p[key] = np.stack(per_layer).astype(np_dtype)
-        if cfg.post_block_norms:
-            p["rms_moe"] = _stack(mf, [f"layers.{i}.rms_moe" for i in range(L)], False, np.float32)
-            p["rms_ffn2"] = _stack(mf, [f"layers.{i}.rms_ffn2" for i in range(L)], False, np.float32)
     elif quant and fuse:
         p["w13"] = _stack_q(
             mf, [[f"layers.{i}.w1", f"layers.{i}.w3"] for i in range(L)], codec)
@@ -390,6 +387,9 @@ def _read_params(mf: mfile.MFile, cfg: ModelConfig, dtype,
     else:
         for key in ("w1", "w2", "w3"):
             p[key] = _stack(mf, [f"layers.{i}.{key}" for i in range(L)], True, np_dtype)
+    if cfg.post_block_norms:
+        for key in ("rms_moe", "rms_ffn2"):
+            p[key] = _stack(mf, [f"layers.{i}.{key}" for i in range(L)], False, np.float32)
     return _read_tail(mf, p, np_dtype, codec if quant else None)
 
 

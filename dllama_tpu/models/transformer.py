@@ -17,6 +17,9 @@ program:
 * Mixtral — same attention, MoE FFN, rotate-half RoPE
 * Grok-1  — embedding ×78.38…, post-sub-block rmsnorms before each residual
             add, MoE with GELU, logits ×0.577…
+* Ouro    — Llama's dense block with the same post-sub-block rmsnorms, and
+            the whole layer scan entered ``cfg.n_loops`` times over its own
+            output (a looped model): one cache plane a (pass, layer)
 
 Tensor-parallel execution needs no code here: weights arrive sharded
 (parallel/sharding.py) and XLA inserts the all-reduces the reference
@@ -54,7 +57,9 @@ MOE_PREFILL_UNROLL_MAX = 8
 
 class KVCache(NamedTuple):
     # (L, B, Hkv, S, Dh) — cfg dtype, or int8 when quantized; a paged pool
-    # (init_kv_pool) is (L, P, ps, Hkv, Dh), its scale planes (L, P, ps, Hkv, 1)
+    # (init_kv_pool) is (L, P, ps, Hkv, Dh), its scale planes (L, P, ps, Hkv, 1).
+    # L is ``cfg.n_cache_planes``: a plane a layer, and in a looped model a
+    # plane a (pass, layer)
     k: jax.Array
     v: jax.Array
     # per-(layer, row, head, position) dequant scales, (L, B, Hkv, S, 1)
@@ -136,7 +141,7 @@ def init_kv_cache(cfg: ModelConfig, batch: int, seq_len: int | None = None,
         return windowed.init_cache(cfg, batch, s, dtype, quant)
     if cfg.is_mla:
         return _init_latent((cfg.n_layers, batch, s), cfg, dtype, quant)
-    shape = (cfg.n_layers, batch, cfg.n_kv_heads, s, cfg.head_size)
+    shape = (cfg.n_cache_planes, batch, cfg.n_kv_heads, s, cfg.head_size)
     if quant:
         sshape = shape[:-1] + (1,)
         return KVCache(jnp.zeros(shape, jnp.int8), jnp.zeros(shape, jnp.int8),
@@ -224,7 +229,7 @@ def init_kv_pool(cfg: ModelConfig, n_pages: int, page_size: int,
     # ``--kv-quant int8`` gives a scale a head, both of which a periodic model
     # refuses by name.  An arch here with heads under 128 lanes (none of the
     # supported ones on one chip) pays the layout copies ``pool_rows`` names.
-    shape = (cfg.n_layers, n_pages, page_size, cfg.n_kv_heads, cfg.head_size)
+    shape = (cfg.n_cache_planes, n_pages, page_size, cfg.n_kv_heads, cfg.head_size)
     if quant:
         sshape = shape[:-1] + (1,)
         return KVCache(jnp.zeros(shape, jnp.int8), jnp.zeros(shape, jnp.int8),
@@ -277,7 +282,10 @@ def _attention_block(x, lp, cfg: ModelConfig, cache: KVCache, cos, sin, pos,
     (L, B, Hkv, S, Dh) buffers carried through the layer scan; this layer
     writes its (B, Hkv, T, Dh) step window in place at ``(layer, pos)`` and
     reads back only its own layer slice for attention (see
-    ops.attention.update_kv_cache_at for the cost model).  With ``packed``
+    ops.attention.update_kv_cache_at for the cost model).  Two indices: the
+    weight set is ``lp`` (the caller's view of layer ``l``) and ``layer`` is
+    the CACHE PLANE, ``l`` itself unless the stack runs several times
+    (``cfg.n_cache_planes``).  With ``packed``
     (a slot step at ``t > 1``, models/packing.py) the two projections run over
     the rows that hold a token; everything between them keeps (B, T)."""
     b, t, d = x.shape
@@ -285,7 +293,7 @@ def _attention_block(x, lp, cfg: ModelConfig, cache: KVCache, cos, sin, pos,
 
     def project(x):  # row-local: any leading axes
         with scope("norm"):
-            xb = rmsnorm(x, lp["rms_att"])
+            xb = rmsnorm(x, lp["rms_att"], cfg.norm_eps)
         with scope("qkv"):
             if "wqkv" in lp:  # fused projection (quantized load): one kernel launch
                 qkv = _mm(xb, lp["wqkv"], cfg)
@@ -937,47 +945,57 @@ def run_blocks(params: Params, cfg: ModelConfig, tokens: jax.Array,
                if isinstance(params[k], (q40.QTensor, q8.Q8Tensor))]
     stacked = {k: params[k] for k in layer_keys if k not in qt_keys}
 
-    def block(carry, layer):
+    L, loops = cfg.n_layers, cfg.n_loops
+    eps = cfg.norm_eps
+
+    def closed(branch, g):
+        """A branch as the residual takes it: normed again first where the arch
+        has post-block norms (part ``post`` of scope ``norm``)."""
+        if not cfg.post_block_norms:
+            return branch
+        with scope("norm"), part("post"):
+            return rmsnorm(branch, g, eps)
+
+    def block(carry, layer, first_plane=0):
         x, kvc = carry
         idx, lp = layer
         lp = dict(lp)
         for k in qt_keys:
             lp[k] = q40.QLayerView(params[k], idx)
+        # the weight set is ``idx``; the cache plane is the pass's own
+        plane = idx + first_plane if loops > 1 else idx
         if marks is not None:
             att_out, kvc = _retention_block(x, lp, cfg, kvc, cos, sin, pos,
                                             idx, marks, offsets=offsets,
                                             pos_rows=pos_rows, packed=packed)
         else:
             att_out, kvc = _attention_block(x, lp, cfg, kvc, cos, sin, pos,
-                                            idx, offsets=offsets,
+                                            plane, offsets=offsets,
                                             pos_rows=pos_rows, paged=paged,
                                             packed=packed)
-        if cfg.post_block_norms:
-            with scope("norm"):
-                att_out = rmsnorm(att_out, lp["rms_ffn"])  # grokRmfFfnNorm
+        att_out = closed(att_out, lp.get("rms_ffn"))  # grokRmfFfnNorm
         with scope("wo"):
             x = x + att_out
 
+        # the norm before the FFN: ``rms_moe`` where ``rms_ffn`` closed attention
+        pre = "rms_moe" if cfg.post_block_norms else "rms_ffn"
         if cfg.is_moe:
             def experts(x):  # row-local: any leading axes
                 with scope("norm"):
-                    xb = rmsnorm(x, lp["rms_moe"] if cfg.post_block_norms
-                                 else lp["rms_ffn"])
+                    xb = rmsnorm(x, lp[pre])
                 with scope("moe"):
                     return moe_ffn(xb.reshape(-1, cfg.dim), lp,
                                    cfg).reshape(x.shape)
 
             ff = packing.over(packed, "moe", experts, x)
-            if cfg.post_block_norms:
-                with scope("norm"):
-                    ff = rmsnorm(ff, lp["rms_ffn2"])  # grokMoeRmsNormFinal
+            ff = closed(ff, lp.get("rms_ffn2"))  # grokMoeRmsNormFinal
             with scope("moe"):
                 x = x + ff
         else:
             def dense(x):
                 with scope("norm"):
-                    xb = rmsnorm(x, lp["rms_ffn"], cfg.norm_eps)
-                return _dense_ffn(xb, lp, cfg)
+                    xb = rmsnorm(x, lp[pre], eps)
+                return closed(_dense_ffn(xb, lp, cfg), lp.get("rms_ffn2"))
 
             ff = packing.over(packed, "w2", dense, x)
             with scope("w2"):
@@ -989,8 +1007,30 @@ def run_blocks(params: Params, cfg: ModelConfig, tokens: jax.Array,
     # xs/ys makes XLA slice out and restack a full layer slab per step and
     # defensively copy the whole cache in the enclosing decode loop —
     # measured ~8 ms/token at 7B/1k, comparable to all the matmuls.
-    (x, cache), _ = grouping.scan(
-        block, (x, cache), (jnp.arange(cfg.n_layers), stacked))
+    layers = (jnp.arange(L), stacked)
+    if loops == 1:
+        (x, cache), _ = grouping.scan(block, (x, cache), layers)
+    else:
+        # a looped model: the same scan over the same L weight sets, entered
+        # once a pass by an outer scan that carries (x, cache), so the program
+        # holds ONE layer body whatever the pass count; pass u writes and reads
+        # planes u * L .. u * L + L - 1, and the final norm closes every pass
+        # (``_head`` norms the last one's)
+        obs_dispatch.record_dispatch("loop", "scan", passes=loops, layers=L,
+                                     planes=cfg.n_cache_planes)
+
+        def one_pass(carry, u):
+            x, kvc = carry
+            with scope("norm"):
+                x = jax.lax.cond(
+                    u > 0, lambda x: rmsnorm(x, params["rms_final"], eps),
+                    lambda x: x, x)
+            return jax.lax.scan(
+                functools.partial(block, first_plane=u * L), (x, kvc),
+                layers)[0], None
+
+        (x, cache), _ = jax.lax.scan(one_pass, (x, cache),
+                                     jnp.arange(loops, dtype=jnp.int32))
     if marks is not None:
         cache = cache._replace(rw=marks[1].reshape(cache.rw.shape))
     return x, cache

@@ -242,9 +242,16 @@ class CostModel:
                  fused: bool = False, n_dense_layers: int = 0,
                  moe_hidden_dim: int = 0, n_shared_experts: int = 0,
                  mla: dict | None = None, head_dim: int = 0,
-                 window: int = 0, window_period: int = 0):
+                 window: int = 0, window_period: int = 0, n_loops: int = 1):
         self.dim = dim
         self.hidden_dim = hidden_dim
+        #: a looped model runs its ``n_layers`` weight sets ``n_loops`` times a
+        #: token: every weight is streamed, every block computed and a position
+        #: cached (a plane a (pass, layer)) that many times.  ``n_layers`` is
+        #: from here on the blocks a token runs; the weight sets are
+        #: ``n_layers // n_loops``
+        self.n_loops = max(1, int(n_loops))
+        n_layers = n_layers * self.n_loops
         self.n_layers = n_layers
         self.n_heads = n_heads
         self.n_kv_heads = n_kv_heads
@@ -525,7 +532,7 @@ def model_from_engine(engine) -> CostModel | None:
                      qk_rope_head_dim=cfg.qk_rope_head_dim,
                      v_head_dim=cfg.v_head_dim) if cfg.is_mla else None,
             head_dim=cfg.head_dim, window=cfg.window,
-            window_period=cfg.window_period)
+            window_period=cfg.window_period, n_loops=cfg.n_loops)
     except Exception:
         return None
 

@@ -820,6 +820,13 @@ KV_BYTES_PER_TOKEN = REGISTRY.gauge(
     "kv_bytes_per_token",
     "Bytes one cached token occupies over all layers, in the contiguous "
     "cache or the paged pool.")
+# beside it: how many times the model's stack of layers runs a token (a looped
+# model, ``ModelConfig.n_loops``); 1 for every arch that runs its layers once.
+# A cached token holds that many planes a layer, which kv_bytes_per_token counts
+MODEL_LOOP_PASSES = REGISTRY.gauge(
+    "model_loop_passes",
+    "Passes of the whole layer stack a token runs (1 unless the model is "
+    "looped); the cache holds a plane a (pass, layer).")
 # beside it, from the same arrays: what the cache holds by kind of plane.
 # "full": planes of every position (all of a model without window layers, the
 # paged pool); "window": a windowed model's rings, bounded by the window plus

@@ -27,7 +27,9 @@ reader of the part reads the operator alone; a retention layer (Brumby,
 ``ops/retention.py``) is Llama's block with, inside ``qkv``, the gate's
 ``retention``, inside ``kv_write`` the ring's ``recent`` and the state's ``fold``
 (not ``absorb``, which is MLA's), and inside ``attn`` the state's read ``state``
-and the ring's rows ``recent``
+and the ring's rows ``recent``;
+inside ``norm`` the norms that close a branch before the residual add (Grok-1's
+and Ouro's sandwich norms) are ``post``, the others keep the bare scope
 (``PARTS``, by scope): plain sub-names, not scopes.  An
 op's path then ends ``.../moe/experts/...`` and a reader that knows only
 ``SCOPES`` still files it under ``moe``; ``by-scope.json``'s op table
@@ -63,6 +65,9 @@ SCOPES = (
 # sub-names by the scope they split; an arch that lacks the work lacks the name
 # (no dense-attention program has ``q_lora``, OLMoE's ``moe`` has no ``shared``)
 PARTS = {
+    "norm": (
+        "post",      # a norm that CLOSES a branch before the residual add (Grok-1, Ouro)
+    ),
     "qkv": (
         "q_lora",    # MLA: q's latent norm and the up-projection to the heads
         "kv_lora",   # MLA: the down-projection(s) from x, the latent's norm

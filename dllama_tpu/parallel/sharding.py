@@ -110,14 +110,14 @@ def param_specs(cfg: ModelConfig) -> dict[str, P]:
             "gate": P(None, "ep", None, "tp"),
             "down": P(None, "ep", "tp", None),
         })
-        if cfg.post_block_norms:
-            specs.update({"rms_moe": REPL, "rms_ffn2": REPL})
     else:
         specs.update({
             "w1": P(None, None, "tp"),
             "w2": P(None, "tp", None),
             "w3": P(None, None, "tp"),
         })
+    if cfg.post_block_norms:
+        specs.update({"rms_moe": REPL, "rms_ffn2": REPL})
     return specs
 
 

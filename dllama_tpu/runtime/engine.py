@@ -318,6 +318,14 @@ def _refuse_mesh_and_int8(mesh, kv_dtype, what: str, why: str) -> None:
                          "its cache has no int8 form")
 
 
+def _loop_fields(cfg: ModelConfig) -> dict:
+    """What a looped model adds to a snapshot's and a hand-off record's
+    fingerprint: its planes are (pass, layer), so the pass count is part of what
+    a record's leading axis means.  Empty where the stack runs once, so every
+    other arch's digest is what it was."""
+    return {"n_loops": cfg.n_loops} if cfg.n_loops > 1 else {}
+
+
 # what a slot owns, by the kind of its planes (``Engine.slot_state``)
 _SLOT_STATE = {"window": "window layers' rings",
                "conv": "convolution layers' state",
@@ -442,6 +450,12 @@ class Engine:
                     f"a retention ({mfile.ARCH_NAMES[cfg.arch]}) model keeps "
                     "no keys and values, so it has no pages to count: drop "
                     "--kv-pages (its slots are admitted by --batch-slots alone)")
+        if cfg.n_loops > 1:
+            _refuse_mesh_and_int8(
+                self.mesh, kv_dtype,
+                f"a looped ({mfile.ARCH_NAMES[cfg.arch]}) model",
+                "its cache is a plane a (pass, layer), which no placement "
+                "or scale plane has been tried on")
         if self.mesh.shape.get("tp", 1) > 1 \
                 and jax.default_backend() != "tpu" \
                 and os.environ.get("DLLAMA_TP_REDUCE", "") != "psum":
@@ -541,6 +555,7 @@ class Engine:
                   else batch * self.seq_len)
         self.kv_bytes_per_token = _note_cache_bytes(self.cache, tokens, batch,
                                                     self.paged)
+        obs_metrics.MODEL_LOOP_PASSES.set(cfg.n_loops)
         # the account's ``cache`` owner: this engine's planes on each device,
         # beside every other live engine's (a server's chat engine keeps its
         # contiguous cache beside the batch engine's pool), until it goes
@@ -755,6 +770,7 @@ class Engine:
             "n_kv_heads": c.n_kv_heads, "n_experts": c.n_experts,
             "n_active_experts": c.n_active_experts,
             "vocab_size": c.vocab_size, "hidden_act": c.hidden_act,
+            **_loop_fields(c),
             "rope_theta": c.rope_theta,
             "batch": self.batch, "seq_len": self.seq_len,
             "cache": [[n, str(a.dtype), list(a.shape)]
@@ -894,6 +910,7 @@ class Engine:
             "n_kv_heads": c.n_kv_heads, "n_experts": c.n_experts,
             "n_active_experts": c.n_active_experts,
             "vocab_size": c.vocab_size, "hidden_act": c.hidden_act,
+            **_loop_fields(c),
             "rope_theta": c.rope_theta, "seq_len": self.seq_len,
             # page shape (ps, Hkv, Dh | ps, C) + dtype, not pool page count, with
             # the axis order by name: a record written head-major (before

@@ -576,3 +576,67 @@ def np_forward_brumby(params, cfg, tokens, wrong=None):
         x = x + (silu(n @ seg("w1", li)) * (n @ seg("w3", li))) @ seg("w2", li)
     x = norm(x, np.asarray(params["rms_final"], np.float32))
     return (x @ np.asarray(params["wcls"], np.float32)).astype(np.float32)
+
+
+# ---- Ouro (ARCH_OURO) -------------------------------------------------------
+# A looped model, written from the equations (Zhu et al., "Scaling Latent
+# Reasoning via Looped Language Models", arXiv:2510.25741; the published
+# modeling code's names in brackets): the whole sequence every pass, no cache,
+# so "pass u of layer l attends over what pass u of layer l wrote" is simply
+# causal attention over this pass's own keys and values.  Shares nothing with
+# dllama_tpu.models.transformer.  The keyword switches compute something else:
+# the tests use them to show that each wrong computation is seen.
+
+def np_forward_ouro(params, cfg, tokens, *, passes=None, wrong=""):
+    """Full-sequence forward of ``cfg.n_loops`` passes over ``cfg.n_layers``
+    weight sets.  tokens (T,); returns (T, V) float32 logits of the last pass.
+
+    ``wrong``: ``"read_pass0"`` (every pass attends over pass 0's keys and
+    values of the layer), ``"write_next"`` (a pass's keys and values are the
+    next pass's: what a pass reads is what the one before it wrote),
+    ``"no_loop_norm"`` (the final norm left out between passes),
+    ``"no_post_norm"`` (the norms that close a branch left out),
+    ``"rope_by_pass"`` (the position advanced by the pass in RoPE)."""
+    t = len(tokens)
+    h, hkv, dh = cfg.n_heads, cfg.n_kv_heads, cfg.head_size
+    eps, n_pass = cfg.norm_eps, passes or cfg.n_loops
+    mask = np.tril(np.ones((t, t), bool))
+
+    def norm(x, w):
+        return rmsnorm_eps(x, w, eps)
+
+    def post(x, w):
+        return x if wrong == "no_post_norm" else norm(x, w)
+
+    x = params["embedding"][tokens].astype(np.float32)
+    kept = {}  # (pass, layer) -> the keys and values that pass wrote
+    for u in range(n_pass):
+        pos = np.arange(t) + (u if wrong == "rope_by_pass" else 0)
+        for li in range(cfg.n_layers):
+            lp = {k: np.asarray(v[li]) for k, v in params.items()
+                  if k not in ("embedding", "rms_final", "wcls")}
+            xb = norm(x, lp["rms_att"])                      # [input_layernorm]
+            q = rope_rotate((xb @ lp["wq"]).reshape(t, h, dh), pos,
+                            cfg.rope_theta, False)
+            k = rope_rotate((xb @ lp["wk"]).reshape(t, hkv, dh), pos,
+                            cfg.rope_theta, False)
+            v = (xb @ lp["wv"]).reshape(t, hkv, dh)
+            kept[u, li] = (k, v)
+            if wrong == "read_pass0":
+                k, v = kept[0, li]
+            elif wrong == "write_next" and u:
+                k, v = kept[u - 1, li]
+            att = np.zeros((t, h, dh), np.float32)
+            for i in range(h):
+                g = i // (h // hkv)
+                s = np.where(mask, q[:, i] @ k[:, g].T / np.sqrt(dh), -np.inf)
+                att[:, i] = softmax(s) @ v[:, g]
+            a = att.reshape(t, h * dh) @ lp["wo"]
+            x = x + post(a, lp["rms_ffn"])                   # [input_layernorm_2]
+            y = norm(x, lp["rms_moe"])                       # [post_attention_layernorm]
+            f = (silu(y @ lp["w1"]) * (y @ lp["w3"])) @ lp["w2"]
+            x = x + post(f, lp["rms_ffn2"])                  # [post_attention_layernorm_2]
+        if u < n_pass - 1 and wrong != "no_loop_norm":
+            x = norm(x, np.asarray(params["rms_final"]))     # closes EVERY pass
+    x = norm(x, np.asarray(params["rms_final"]))
+    return (x @ params["wcls"]).astype(np.float32)

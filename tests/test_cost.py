@@ -295,6 +295,49 @@ def test_scheduler_attribution_e2e(clean_obs):
     assert perf["chip_ms_by_class"]
 
 
+@pytest.mark.parametrize("shape", ["toy", "published"])
+def test_a_looped_model_streams_and_caches_once_a_pass(shape, clean_obs):
+    """A looped model (Ouro) runs its weight sets ``n_loops`` times a token:
+    ``n_loops`` x the parameters a token, and a cached position holds a plane a
+    (pass, layer): 1,572,864 B for Ouro-2.6B in bfloat16."""
+    if shape == "published":
+        kw = dict(dim=2048, hidden_dim=5632, n_layers=48, n_heads=16,
+                  n_kv_heads=16, vocab_size=49152, kv_codec="kv_bfloat16",
+                  kv_el_bytes=2)
+        loops, per_layer, kv_pos = 4, 4 * 2048 * 2048 + 3 * 2048 * 5632, 2 * 2048 * 2
+    else:
+        kw, loops, per_layer, kv_pos = {}, 3, PARAMS_PER_TOKEN // 2, KV_POS_F32
+    once, looped = tiny_cost_model(**kw), tiny_cost_model(n_loops=loops, **kw)
+    layers = once.n_layers
+    assert looped.params_per_token == loops * layers * per_layer \
+        == loops * once.params_per_token
+    assert looped.kv_write_bytes(1) == loops * layers * kv_pos
+    assert looped.weight_read_bytes() - once.weight_read_bytes() \
+        == (loops - 1) * once.codec_bytes(once.params_per_token)
+    assert looped.kv_read_bytes(9, 1, True) == loops * once.kv_read_bytes(9, 1, True)
+    assert looped.attn_flops(9, 1) == loops * once.attn_flops(9, 1)
+    assert looped.logit_flops(1) == once.logit_flops(1)      # the head runs once
+    if shape == "published":
+        assert looped.kv_write_bytes(1) == 1_572_864
+        assert looped.params_per_token == 4 * 2_466_250_752
+        return
+    # the engine's own model and gauge agree with it
+    import jax
+
+    from dllama_tpu.models.config import tiny_ouro
+    from dllama_tpu.models.params import init_params
+    from dllama_tpu.parallel.mesh import make_mesh
+    from dllama_tpu.runtime.engine import Engine
+
+    cfg = tiny_ouro()
+    eng = Engine(cfg, init_params(cfg, seed=4),
+                 mesh=make_mesh(tp=1, devices=jax.devices()[:1]), batch=1)
+    cm = obs_cost.model_from_engine(eng)
+    assert (cm.n_loops, cm.n_layers) == (3, 9)
+    assert cm.kv_write_bytes(1) == eng.kv_bytes_per_token \
+        == obs_metrics.KV_BYTES_PER_TOKEN.json_value() == 9 * 2 * cfg.kv_dim * 4
+
+
 def test_model_from_engine_sniffs_codecs(clean_obs):
     import jax
 

@@ -154,6 +154,38 @@ def test_dispatch_paths_recorded():
     assert "q40/xla-dequant" in obs_dispatch.summary_line()
 
 
+@pytest.mark.parametrize("loops", [3, 0])
+def test_a_looped_program_records_its_loop_once(loops, caplog):
+    """``{codec="loop",path="scan"}``: one a compiled program of a looped model,
+    with its passes, layers and cache planes on the debug record; a model that
+    runs its layers once records nothing of the kind, and the gauge
+    ``model_loop_passes`` says 1 there."""
+    import jax
+    import jax.numpy as jnp
+    from dllama_tpu.models.config import tiny_config, tiny_ouro
+    from dllama_tpu.models.params import init_params
+    from dllama_tpu.parallel.mesh import make_mesh
+    from dllama_tpu.runtime.engine import Engine
+    cfg = tiny_ouro(loops=loops) if loops else tiny_config()
+    obs_dispatch.reset()
+    with caplog.at_level(logging.DEBUG, logger="dllama.obs.dispatch"):
+        eng = Engine(cfg, init_params(cfg, seed=2),
+                     mesh=make_mesh(tp=1, devices=jax.devices()[:1]), batch=1)
+        eng.prefill([5, 6, 7])
+        eng.decode_one(8)
+    sites = obs_dispatch.dispatches()
+    assert sites.get("loop/scan", 0) == (2 if loops else 0)  # the prompt's, the token's
+    assert obs_metrics.MODEL_LOOP_PASSES.json_value() == (loops or 1)
+    recs = [r for r in caplog.records if getattr(r, "codec", "") == "loop"]
+    if loops:
+        assert {(r.passes, r.layers, r.planes) for r in recs} == {(3, 3, 9)}
+        assert obs_metrics.MATMUL_DISPATCH.get("loop", "scan") >= 2
+        assert "loop/scan" in obs_dispatch.summary_line()
+    else:
+        assert not recs
+    obs_dispatch.reset()
+
+
 @pytest.mark.parametrize("rows,body", [(1, "grouped"), (16, "dot")])
 def test_q40_site_records_the_body_and_the_path_share_ignores_it(rows, body):
     """A fused Q40 call site says which body contracts its tile (PR 50):

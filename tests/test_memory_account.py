@@ -32,19 +32,30 @@ FAMILIES = (obs_metrics.HBM_ACCOUNT_BYTES, obs_metrics.HBM_PEAK_RAISED_BYTES,
 
 
 class FakeChip:
-    """``{device: memory_stats()}`` of one device "0": what the process's live
-    arrays hold there above ``base``; a test raises ``peak`` by hand where a
-    program's temporaries would (they raise the peak and are gone)."""
+    """``{device: memory_stats()}`` of one device "0": what the arrays born
+    since this fake was made hold there above ``base``; a test raises ``peak``
+    by hand where a program's temporaries would (they raise the peak and are
+    gone).
+
+    The arrays the process already had stand for ``base`` and are held until
+    the fake goes: another test file's leftovers (an engine in a reference
+    cycle, a scheduler thread on its way out) are otherwise collected whenever
+    the collector or that thread gets to it, between two reads of one test, and
+    ``found`` then differs from the reading before it or ``programs`` comes out
+    negative (seen once in the driver's run of PR 55's tree, on the first test
+    of this file, which runs after whatever file its worker had before)."""
 
     LIMIT = 1 << 34
 
     def __init__(self, base=1 << 20):
         self.base, self.peak, self.reads = base, 0, 0
+        self._held = list(jax.live_arrays())
+        self._old = {id(a) for a in self._held}   # held, so no id is reused
 
     def live(self) -> int:
         dev = jax.devices()[0]
         return self.base + sum(a.nbytes for a in jax.live_arrays()
-                               if dev in a.devices())
+                               if id(a) not in self._old and dev in a.devices())
 
     def __call__(self) -> dict:
         self.reads += 1

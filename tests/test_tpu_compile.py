@@ -195,7 +195,8 @@ def _assert_pool_is_only_scattered(text, cfg, n_pages, ps):
     pool's full shape, the only ones that make a pool are the KV write's
     in-place scatter and the fusion that wraps it: no ``copy``."""
 
-    shape = f"[{cfg.n_layers},{n_pages},{ps},{cfg.n_kv_heads},{cfg.head_size}]"
+    shape = (f"[{cfg.n_cache_planes},{n_pages},{ps},{cfg.n_kv_heads},"
+             f"{cfg.head_size}]")
     ops = re.findall(r"^\s*(?:ROOT )?%?([\w.\-]+) = (\S+) ([\w\-]+)\((.*)$",
                      text, re.M)
     made = []
@@ -299,6 +300,32 @@ def test_mixed_paged_slot_step_has_no_pool_copy(one_chip, monkeypatch):
     assert "paged_attn_fused" in text
     # the gather form's (B, Hkv, maxp * ps, Dh) view of K or V is gone
     assert f"[16,{cfg.n_kv_heads},{8 * ps},{cfg.head_size}]" not in text
+    _assert_pool_is_only_scattered(text, cfg, n_pages, ps)
+
+
+@pytest.mark.parametrize("t", [1, 16], ids=["pure-decode", "mixed"])
+def test_looped_paged_slot_step_has_no_pool_copy(one_chip, monkeypatch, t):
+    """A looped model's slot step (2 weight sets x 2 passes = 4 planes, 8 slots
+    as its cell has): the cache is the carry of TWO nested loops, the pass
+    around the layer, and still nothing of the pool's full shape is made but
+    the KV write's in-place scatter: a copy a pass would be the whole 9.87 GB
+    pool of ``ouro-2.6b.short-reason`` four times a step.  The fused walk reads
+    the plane it is handed."""
+    from dllama_tpu.models.config import tiny_ouro
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(jax, "device_count", lambda: 1)
+    cfg = tiny_ouro(dim=512, hidden_dim=1024, n_layers=2, n_heads=4,
+                    n_kv_heads=4, vocab_size=1024, seq_len=256,
+                    dtype=jnp.bfloat16, loops=2)
+    n_pages, ps = POOL_PAGES, 16
+    text = _slot_step_text(one_chip, cfg, _dense_toy_params(cfg, one_chip), 8,
+                           t, n_pages, 16)
+    assert "paged_attn_fused" in text
+    paths = re.findall(r"op_name=\"([^\"]+)\"", text)
+    assert any(p.count("/while/body/") == 2 and scope_of(p) == "attn"
+               for p in paths)               # the layer's loop inside the pass's
+    assert any("/norm/post/" in p for p in paths)
     _assert_pool_is_only_scattered(text, cfg, n_pages, ps)
 
 

@@ -113,6 +113,31 @@ def test_compiled_step_carries_every_scope_of_its_path(path, extra):
             scatters
 
 
+@pytest.mark.parametrize("toy", ["ouro", "grok1", "llama"])
+def test_the_norms_that_close_a_branch_are_part_post(toy):
+    """Inside scope ``norm`` the norms that close a branch before the residual
+    add carry the part ``post`` (Ouro's sandwich norms, Grok-1's two): a looped
+    model's step tells its 768 norms into pre and post in ``by-scope.json``.  A
+    reader of scope ``norm`` still reads them all, and an arch without such
+    norms has no op under the name."""
+    from dllama_tpu.io import mfile
+    from dllama_tpu.models.config import tiny_ouro
+    cfg = {"ouro": tiny_ouro(),
+           "grok1": tiny_config(arch=mfile.ARCH_GROK1, n_experts=4,
+                                n_active_experts=2, hidden_act=mfile.ACT_GELU),
+           "llama": CFG}[toy]
+    p = init_params(cfg, seed=4)
+    ops = compiled_ops(lambda p, c, tok: tf.forward(p, cfg, tok, c, jnp.int32(3)),
+                       p, tf.init_kv_cache(cfg, 1, 64), jnp.zeros((1, 1), jnp.int32))
+    post = [name for _, name in ops if "/norm/post/" in name]
+    assert bool(post) == (toy != "llama")
+    assert all(scope_of(name) == "norm" for name in post)
+    assert any(scope_of(n) == "norm" and "/post/" not in n for _, n in ops)
+    if toy == "ouro":  # the pass's own norm lies in the outer loop alone
+        assert any(n.count("/while/body/") == 1 and scope_of(n) == "norm"
+                   for _, n in ops)
+
+
 def test_scope_set_is_defined_once():
     hits = subprocess.run(
         ["grep", "-rln", "named_scope", os.path.join(REPO, "dllama_tpu"),
