@@ -530,8 +530,10 @@ def child_kernels(rehearse: bool) -> None:
                   "seconds": round(time.perf_counter() - t0, 2)})
             if q40._body(rows) == "grouped":
                 # one row is contracted a quantization block at a time with no
-                # weight rounded to bf16 (PR 50): it is held to the float32
-                # dequantization
+                # weight rounded to bf16 (PR 50), the tile's bytes made bf16
+                # as 32-bit words (PR 58: the chip's half of the proof that
+                # pltpu.bitcast sets a word's bytes on rows as the interpreter
+                # does): it is held to the float32 dequantization
                 dense = q40.dequantize(w.sliced() if stacked else w)
                 ref32 = jnp.dot(x.astype(jnp.float32), dense,
                                 precision=jax.lax.Precision.HIGHEST)
@@ -557,6 +559,12 @@ def child_kernels(rehearse: bool) -> None:
           "experts": experts, "chosen": len(chosen),
           "rel_err": rel_err(got, ref), "tol": Q40_TOL,
           "seconds": round(time.perf_counter() - t0, 2)})
+    ref32 = jnp.stack([jnp.dot(
+        x1.astype(jnp.float32), q40.dequantize(view.select(e, experts).sliced()),
+        precision=jax.lax.Precision.HIGHEST) for e in chosen])
+    _say({"kernel": "q40.chosen_experts.f32", "shape": [n, d], "rows": 1,
+          "experts": experts, "chosen": len(chosen),
+          "rel_err": rel_err(got, ref32), "tol": Q40_F32_TOL})
 
     # the auto choice inside a jit trace must be the Pallas kernel on a TPU
     # (w, x: the last pair of the loop above — wcls, 8 rows)

@@ -39,10 +39,16 @@ Two matmul implementations:
   order; ONE row (every decoded token of a one-stream
   program, a row's chosen experts) sends the raw nibbles to the dot a
   quantization block at a time and scales the block partials, so no weight
-  is biased, scaled or rounded one by one (19% faster a launch at Mistral's
-  ``w13``, 13-15% at a row's chosen experts).  What bounds the
-  dot body at few rows is the VPU's work a weight on the way to the dot,
-  not the MXU's tile loads and not the DMA.  A mixture-of-experts
+  is biased, scaled or rounded one by one (PR 50: 19% faster a launch at
+  Mistral's ``w13``, 13-15% at a row's chosen experts), and makes them the
+  dot's bf16 operand without extending or converting one: the packed tile is
+  bitcast to 32-bit words and ``(W << 3) & 0x00780078 | 0x41804180`` is one
+  word of two bf16 ``16 + v`` (PR 58: 1.5 integer ops a weight on the tile
+  where there were 3.5-4; the kernel's static schedule a 1024 x 1024 tile
+  1846 -> 1196 bundles, a launch 16% faster at ``w13``, 9-13% at a row's
+  chosen experts; what is left of a launch is the pipeline's DMA and steps).
+  What bounds the dot body at few rows is the VPU's work a weight on the way
+  to the dot, not the MXU's tile loads and not the DMA.  A mixture-of-experts
   layer's E experts, or the k a decoded row chose, are one launch a matmul
   (:func:`matmul_experts`, ``q40_mm_experts`` / ``q40_mm_chosen``): the
   expert index is a grid axis of the same kernel.  A `pallas_call` is not auto-partitioned by GSPMD, so
@@ -454,10 +460,14 @@ def _record_site(rows: int, np_: int, d: int, kind: str | None, tp: int,
                  **ctx) -> None:
     """A kernel call site's two dispatch records: ``q40/pallas-fused`` with
     the tp slicing, the tile pair its shard got, the stored input dim and the
-    ``body`` that contracts the tile, and ``q40_body/grouped|dot``, the
-    counter that says which body a compiled site took (:func:`_body`)."""
+    ``body`` that contracts the tile, and ``q40_body/grouped-words|
+    grouped-nibbles|dot``, the counter that says which body a compiled site
+    took (:func:`_body`) and, at one row, how it made the dot's right operand
+    (:func:`_nibbles_as`)."""
     tiles = _tiles(*_shard_nd(np_, d, kind, tp))
     body = _body(rows)
+    if body == "grouped":
+        body += "-" + _nibbles_as(tiles[0])
     obs_dispatch.record_dispatch("q40", "pallas-fused", rows=rows, kind=kind,
                                  tp=tp, stored_n=np_, tiles=tiles, body=body,
                                  **ctx)
@@ -475,7 +485,9 @@ def _body(rows: int) -> str:
     activation row, so it is a one-row form: against the dot body −19% at one
     row of Mistral's ``w13``, −12% at two, +13% at four, and at a row's six
     chosen experts −14%, −5%, +22%; no cell runs two to four rows
-    (tools/sweep_q40.py --body ... 1,2,4 dot,grouped; PERF.md §6, PR 50)."""
+    (tools/sweep_q40.py --body ... 1,2,4 dot,grouped; PERF.md §6, PR 50).
+    How the grouped body makes the dot's right operand follows the tile's
+    rows (:func:`_nibbles_as`, PR 58)."""
     return "grouped" if rows == 1 else "dot"
 
 
@@ -492,36 +504,115 @@ def _partial_rows(tile_n: int) -> int:
     return 8 if tile_n % 256 == 0 else 1
 
 
-def _contract_grouped(x_ref, vi, s32) -> jax.Array:
-    """The tile against the block's one row, a quantization block at a time:
-    ``sum_b s[b, d] * (sum_{i in b} x[i] * v[i, d] - 8 * sum_{i in b} x[i])``
-    over the tile's blocks ``b``, ``v`` the raw nibbles.  Scale and bias are
-    paid once a block partial (1/32 of a weight) and no weight is rounded: a
-    nibble and a bf16 activation are exact operands of the dot and their
-    products exact in its f32 sums, so the result is ``x @ dequantize(qt,
-    float32)`` up to summation order.
+def _nibbles_as(tile_n: int) -> str:
+    """How the one-row body turns the packed tile into the dot's right operand,
+    from the tile's shape alone: ``"words"`` (:func:`_words_bf16`) wherever the
+    activation row beside it is whole vregs of 128 lanes, which also makes the
+    tile's packed rows whole vregs of 32-bit words (every tile the rule gives a
+    model: all are multiples of 256), else ``"nibbles"``, each nibble extended
+    and converted (:func:`_nibbles_bf16`: a toy's whole-axis tile of 32, 64 or
+    96 rows).  Both are the grouped body, with the same sums."""
+    return "words" if tile_n % 128 == 0 else "nibbles"
 
-    The inner sums of all ``nb`` blocks come out of ONE dot: its left operand
-    holds, in row ``b``, the activation row at block ``b``'s 32 columns and
-    zero elsewhere (block-diagonal), so row ``b`` of the product is block
-    ``b``'s partial sum.  Returns the block partials summed onto
-    :func:`_partial_rows` sublanes, which the caller accumulates over the
-    reduction steps and folds at the last."""
-    nb, td = s32.shape
+
+def _words_bf16(qp) -> tuple[jax.Array, float]:
+    """The packed tile as bf16 ``16 + v`` without leaving integer registers,
+    and that bias.  The uint8 tile is bitcast to 32-bit words (no extend): a
+    word holds packed rows ``4r .. 4r + 3`` in its bytes, ``0x00780078`` is the
+    place of ``v << 3`` in both 16-bit halves, and ``0x4180 | v << 3`` IS the
+    bf16 ``16 + v``.  So ``(W << 3) & 0x00780078 | 0x41804180`` is one word of
+    two bf16 (the low nibbles of bytes 0 and 2), and ``W >> 1``, ``W >> 5``,
+    ``W >> 9`` give the other three pairs: 3 ops for 2 weights, no conversion.
+    A bitcast to bf16 sets a word's halves on rows ``2r``, ``2r + 1``; the
+    four results are set one above the other a vreg (16 rows) at a time, so
+    row ``64 G + 16 q + 8 b + i`` of the operand (``q`` the result, ``b`` 0 or
+    1, ``i`` under 8) is logical row ``64 G + 32 b + 16 (q & 1) + 2 i + (q >>
+    1)`` of the tile: :func:`_words_row`, :func:`_words_block`."""
+    tn, td = 2 * qp.shape[0], qp.shape[1]
+    w = pltpu.bitcast(qp, jnp.uint32)                      # (tn/8, td)
+    pieces = [pltpu.bitcast(
+        ((w << 3 if at < 3 else w >> (at - 3)) & jnp.uint32(0x00780078))
+        | jnp.uint32(0x41804180), jnp.bfloat16).reshape(tn // 64, 16, td)
+        for at in (0, 4, 8, 12)]   # bytes 0 | 2: lo, hi; bytes 1 | 3: lo, hi
+    return jnp.concatenate(pieces, axis=1).reshape(tn, td), 24.0
+
+
+def _words_row(j):
+    """The logical row of the tile that row ``j`` (an int32 iota) of
+    :func:`_words_bf16`'s operand holds: a move inside ``j``'s 64."""
+    return (j & ~63) | ((j & 8) << 2) | (j & 16) | ((j & 7) << 1) | ((j >> 5) & 1)
+
+
+def _words_block(j):
+    """The quantization block of that row."""
+    return ((j >> 6) << 1) | ((j >> 3) & 1)
+
+
+def _nibbles_bf16(qp) -> tuple[jax.Array, float]:
+    """The packed tile as bf16 ``0 + v`` in logical row order, and its bias:
+    extended to int32, each nibble plane masked or shifted and converted
+    (0..15: exact)."""
+    tn, td = 2 * qp.shape[0], qp.shape[1]
+    vi = qp.astype(jnp.int32)
+    lo = (vi & 0xF).astype(jnp.bfloat16).reshape(tn // 32, 16, td)
+    hi = (vi >> 4).astype(jnp.bfloat16).reshape(tn // 32, 16, td)
+    return jnp.concatenate([lo, hi], axis=1).reshape(tn, td), 8.0
+
+
+def _block_diagonal(x_ref, nb: int, order=None) -> jax.Array:
+    """The one-row body's left operand ``(nb, tile_n)`` float32: in row ``b``
+    the activation row at block ``b``'s 32 columns and zero elsewhere, its
+    columns in the order of the right operand's rows.  ``order`` is the pair
+    of maps from a row of that operand to its logical row and to its block
+    (None: logical order).  The first moves a column inside its 128 lanes, so
+    the activation row is permuted by one lane gather over its vregs, inside
+    the kernel: no op stands in front of the launch."""
     tn = 32 * nb
-    lo = (vi & 0xF).astype(jnp.bfloat16).reshape(nb, 16, td)   # 0..15: exact
-    hi = (vi >> 4).astype(jnp.bfloat16).reshape(nb, 16, td)
-    w = jnp.concatenate([lo, hi], axis=1).reshape(tn, td)  # logical row order
-    own = (jax.lax.broadcasted_iota(jnp.int32, (nb, tn), 1) >> 5
-           == jax.lax.broadcasted_iota(jnp.int32, (nb, tn), 0))
-    xd = jnp.where(own, jnp.broadcast_to(x_ref[:].astype(jnp.float32),
-                                         (nb, tn)), 0.0)
+    at = jax.lax.broadcasted_iota(jnp.int32, (1, tn), 1)
+    x = x_ref[:].astype(jnp.float32)
+    if order is None:
+        blk = at >> 5
+    else:
+        row_of, block_of = order
+        blk = block_of(at)
+        x = x.reshape(tn // 128, 128)
+        pick = row_of(jax.lax.broadcasted_iota(jnp.int32, x.shape, 1))  # in 0..127
+        x = jnp.take_along_axis(x, pick, axis=1,
+                                mode="promise_in_bounds").reshape(1, tn)
+    own = blk == jax.lax.broadcasted_iota(jnp.int32, (nb, tn), 0)
+    return jnp.where(own, jnp.broadcast_to(x, (nb, tn)), 0.0)
+
+
+def _block_sums(x_ref, w, bias: float, order, s32) -> jax.Array:
+    """``sum_b s[b, d] * (sum_{i in b} x[i] * w[i, d] - bias * sum_{i in b}
+    x[i])`` over the tile's blocks ``b``: ONE dot against the block-diagonal
+    left operand (:func:`_block_diagonal`, its columns in ``order``), so row
+    ``b`` of the product is block ``b``'s partial sum, then bias and scale on
+    the ``nb`` partials.  Returns them summed onto :func:`_partial_rows`
+    sublanes, which the caller accumulates over the reduction steps and folds
+    at the last."""
+    nb, td = s32.shape
+    xd = _block_diagonal(x_ref, nb, order)
     p = jnp.dot(xd.astype(jnp.bfloat16), w,
                 preferred_element_type=jnp.float32)     # (nb, td)
-    p = (p - 8.0 * xd.sum(axis=1, keepdims=True)) * s32
-    if _partial_rows(tn) == 1:
+    p = (p - bias * xd.sum(axis=1, keepdims=True)) * s32
+    if _partial_rows(32 * nb) == 1:
         return p.sum(axis=0, keepdims=True)
     return p.reshape(nb // 8, 8, td).sum(axis=0)
+
+
+def _contract_grouped(x_ref, qp, s32) -> jax.Array:
+    """The packed tile against the block's one row, a quantization block at a
+    time (:func:`_block_sums`): the dot's right operand is ``c + v``, ``v`` the
+    raw nibbles and ``c`` what the operand's form adds to them
+    (:func:`_nibbles_as`: 16 as words, 0 a nibble at a time), and the bias ``c
+    + 8``.  Scale and bias are paid once a block partial (1/32 of a weight)
+    and no weight is rounded: ``c + v`` and a bf16 activation are exact
+    operands of the dot and their products exact in its f32 sums, so the
+    result is ``x @ dequantize(qt, float32)`` up to summation order."""
+    if _nibbles_as(2 * qp.shape[0]) == "words":
+        return _block_sums(x_ref, *_words_bf16(qp), (_words_row, _words_block), s32)
+    return _block_sums(x_ref, *_nibbles_bf16(qp), None, s32)
 
 
 def _dequant_bf16(vi, s32) -> tuple[jax.Array, jax.Array]:
@@ -567,7 +658,12 @@ def _q40_kernel(x_ref, qp_ref, s_ref, o_ref, acc_ref, *, nsteps, n_axis=1,
     At one row (:func:`_contract_grouped`) the raw nibbles go to the dot and
     bias and scale are applied to the block partials it returns.  This is no
     lower precision: no weight is rounded to bf16, and the result is the f32
-    dequantization's up to summation order.
+    dequantization's up to summation order.  The tile is not extended to
+    int32 there: its bytes become the bf16 operand as 32-bit words
+    (:func:`_words_bf16`: ~1.5 integer ops a weight, none a conversion; PR
+    50's mask or shift and conversion a nibble, ~3.5-4 with the extend, stay
+    for a toy's tile of under 128 rows), and the left operand follows the
+    row order they come out in (:func:`_block_diagonal`).
 
     ``live`` (a traced predicate, the grouped launch's): the whole step runs
     under it, and where it is false nothing is unpacked, contracted or
@@ -587,9 +683,11 @@ def _q40_step(i, x_ref, qp_ref, s_ref, o_ref, acc_ref, nsteps):
     nb = tn2 // 16
     sbits = s_ref[...].reshape(nb, td)                    # uint16 f16 bits
     s32 = _f16_bits_to_f32(sbits)                         # (nb, td) f32, exact
-    vi = qp.astype(jnp.int32)
     grouped = _body(x_ref.shape[0]) == "grouped"
-    part = (_contract_grouped if grouped else _contract_dot)(x_ref, vi, s32)
+    if grouped:  # the tile as it lies: its bytes become bf16 words there
+        part = _contract_grouped(x_ref, qp, s32)
+    else:
+        part = _contract_dot(x_ref, qp.astype(jnp.int32), s32)
 
     @pl.when(i == 0)
     def _():

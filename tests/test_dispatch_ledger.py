@@ -186,17 +186,20 @@ def test_a_looped_program_records_its_loop_once(loops, caplog):
     obs_dispatch.reset()
 
 
-@pytest.mark.parametrize("rows,body", [(1, "grouped"), (16, "dot")])
-def test_q40_site_records_the_body_and_the_path_share_ignores_it(rows, body):
-    """A fused Q40 call site says which body contracts its tile (PR 50):
-    ``q40_body/grouped`` at one row, ``q40_body/dot`` at 16, beside its
+@pytest.mark.parametrize("rows,n,body", [(1, 256, "grouped-words"),
+                                         (1, 96, "grouped-nibbles"), (16, 256, "dot")])
+def test_q40_site_records_the_body_and_the_path_share_ignores_it(rows, n, body):
+    """A fused Q40 call site says which body contracts its tile (PR 50) and,
+    at one row, how the tile's nibbles became the dot's operand (PR 58):
+    ``q40_body/grouped-words`` at one row (``grouped-nibbles`` for a toy's tile
+    of 96 rows), ``q40_body/dot`` at 16, beside its
     ``q40/pallas-fused`` record.  The codec is not ``q40``, so the benchmark's
     ``pallas_path_pct`` reader counts the site once, not twice."""
     import importlib.util
     import jax.numpy as jnp
-    qt = _q40_fixture(256, 128)
+    qt = _q40_fixture(n, 128)
     obs_dispatch.reset()
-    q40.matmul(jnp.ones((rows, 256), jnp.bfloat16), qt, impl="pallas_interpret")
+    q40.matmul(jnp.ones((rows, n), jnp.bfloat16), qt, impl="pallas_interpret")
     sites = obs_dispatch.dispatches()
     assert sites == {"q40/pallas-fused": 1, f"q40_body/{body}": 1}
     assert obs_metrics.MATMUL_DISPATCH.get("q40_body", body) >= 1

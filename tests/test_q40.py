@@ -575,7 +575,7 @@ class TestAutoChoice:
                 with pytest.raises(Exception):  # noqa: B017 — any lowering error
                     jax.block_until_ready(call())
                 # a Q40 site also says which body its block's rows took
-                body = {"q40_body/grouped": 1} if codec == "q40" else {}
+                body = {"q40_body/grouped-words": 1} if codec == "q40" else {}
                 assert obs_dispatch.dispatches() == {f"{codec}/pallas-fused": 1,
                                                      **body}
                 assert obs_dispatch.degraded() is False
@@ -816,38 +816,133 @@ def test_one_row_is_contracted_by_blocks_and_equals_the_f32_reference(form, n, d
         assert np.abs(two[j] - ref).max() / np.abs(ref).max() > 10 * GROUPED_TOL
 
 
-@pytest.mark.parametrize("rows,body", [(1, "grouped"), (2, "dot"), (16, "dot"),
-                                       (256, "dot")])
-def test_the_blocks_rows_choose_the_body_and_the_ledger_says_which(rows, body, caplog,
+@pytest.mark.parametrize("rows,n,body", [
+    (1, 512, "grouped-words"), (1, 1408, "grouped-words"), (1, 128, "grouped-words"),
+    (1, 96, "grouped-nibbles"), (1, 64, "grouped-nibbles"),
+    (2, 512, "dot"), (2, 96, "dot"), (16, 512, "dot"), (256, 512, "dot")])
+def test_the_blocks_rows_choose_the_body_and_the_ledger_says_which(rows, n, body, caplog,
                                                                    monkeypatch):
-    """Nothing but the block's row count chooses: the ``q40_body`` counter and
-    the ``body=`` of the ``q40/pallas-fused`` record name the body, and the
-    kernel's jaxpr builds a block-diagonal left operand (from an iota) exactly
-    where they say ``grouped``."""
+    """Nothing but the block's shape chooses: its row count the body, and at
+    one row the tile's rows how the nibbles become the dot's operand (words
+    wherever ``tile_n`` is a multiple of 128, the activation row beside it
+    whole vregs; a toy's whole-axis tile of 64 or 96 rows keeps a conversion a
+    nibble).  The ``q40_body`` counter and the ``body=`` of the
+    ``q40/pallas-fused`` record name both, and the kernel's jaxpr builds a
+    block-diagonal left operand (from an iota) exactly where they say
+    ``grouped``."""
     import logging
     from dllama_tpu.obs import dispatch as obs_dispatch
     monkeypatch.setattr(logging.getLogger("dllama"), "propagate", True)
-    assert q40._body(rows) == body
-    qt = q40.quantize(_rand((2, 512, 256), seed=2))
+    assert q40._body(rows) == body.split("-")[0]
+    if rows == 1:
+        assert "grouped-" + q40._nibbles_as(q40._tiles(n, 256)[0]) == body
+    qt = q40.quantize(_rand((2, n, 256), seed=2))
     w = q40.QLayerView(qt, jnp.int32(1))
-    x = jax.ShapeDtypeStruct((rows, 512), jnp.bfloat16)
+    x = jax.ShapeDtypeStruct((rows, n), jnp.bfloat16)
     before = obs_dispatch.dispatches()
     with caplog.at_level(logging.DEBUG, logger="dllama"):
         jaxpr = jax.make_jaxpr(lambda x: q40.matmul(x, w, impl="pallas_interpret"))(x)
     after = obs_dispatch.dispatches()
-    other = {"grouped": "dot", "dot": "grouped"}[body]
-    assert after.get(f"q40_body/{body}", 0) == before.get(f"q40_body/{body}", 0) + 1
-    assert after.get(f"q40_body/{other}", 0) == before.get(f"q40_body/{other}", 0)
+    for name in ("grouped-words", "grouped-nibbles", "dot", "grouped"):
+        assert after.get(f"q40_body/{name}", 0) == \
+            before.get(f"q40_body/{name}", 0) + (name == body), name
     rec, = [r for r in caplog.records if getattr(r, "path", None) == "pallas-fused"]
     assert rec.body == body and rec.rows == rows
-    assert (" iota[" in str(jaxpr)) == (body == "grouped")
+    assert (" iota[" in str(jaxpr)) == body.startswith("grouped")
 
 
-@pytest.mark.parametrize("rows", [1, 2])
-@pytest.mark.parametrize("body", ["grouped", "vpu"])
+def _kernel_eqns(jaxpr):
+    """Every equation inside the ``pallas_call`` kernels of ``jaxpr``, nested
+    jaxprs (a ``pl.when``'s branches, an inner ``jit``) included."""
+    def walk(j, inside):
+        for eqn in j.eqns:
+            if inside:
+                yield eqn
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                yield from walk(sub, inside or eqn.primitive.name == "pallas_call")
+    return walk(jaxpr, False)
+
+
+@pytest.mark.parametrize("form", ["flat", "stacked", "chosen"])
+@pytest.mark.parametrize("n,d,tiles,converts", [
+    (1024, 256, None, False), (1024, 256, (256, 128), False), (1408, 128, None, False),
+    (96, 128, None, True)], ids=lambda v: str(v))
+def test_no_nibble_of_a_words_tile_is_converted_from_an_integer(form, n, d, tiles,
+                                                               converts):
+    """The mechanism (PR 58): at one row a tile the word form takes never
+    leaves integer registers until it IS the bf16 operand: the kernel's jaxpr
+    holds no ``convert_element_type`` from an integer to a float on anything
+    as large as the tile (the scales' ``(tile_n / 32, tile_d)`` mantissas still
+    are, once a block), and no extension of the bytes either.  A tile it
+    cannot take (96 rows) converts each nibble plane, as PR 50's body did."""
+    experts = 2
+    lead = {"flat": (), "stacked": (2,)}.get(form, (2 * experts,))
+    x = jax.ShapeDtypeStruct((1, n), jnp.bfloat16)
+    qp = jax.ShapeDtypeStruct((*lead, n // 2, d), jnp.uint8)
+    sc = jax.ShapeDtypeStruct((*lead, n // 32, d), jnp.uint16)
+    layer = jax.ShapeDtypeStruct((), jnp.int32)
+    if form == "flat":
+        jaxpr = jax.make_jaxpr(lambda *a: q40._pallas_matmul(
+            *a, interpret=True, tiles=tiles))(x, qp, sc)
+    elif form == "stacked":
+        jaxpr = jax.make_jaxpr(lambda *a: q40._pallas_matmul_stacked(
+            *a, interpret=True, tiles=tiles))(x, qp, sc, layer)
+    else:
+        jaxpr = jax.make_jaxpr(lambda *a: q40._pallas_matmul_experts(
+            *a, experts=experts, interpret=True, tiles=tiles,
+            chosen=jnp.asarray([1, 0])))(x, qp, sc, layer)
+    tn, td = tiles or q40._tiles(n, d)
+    eqns = list(_kernel_eqns(jaxpr.jaxpr))
+    assert any(e.primitive.name == "dot_general" for e in eqns)
+    widened = [e for e in eqns if e.primitive.name == "convert_element_type"
+               and jnp.issubdtype(e.invars[0].aval.dtype, jnp.integer)
+               and e.invars[0].aval.size >= tn // 8 * td]
+    to_float = [e for e in widened if jnp.issubdtype(e.outvars[0].aval.dtype, jnp.floating)]
+    assert bool(to_float) == converts and bool(widened) == converts, widened
+    assert any(e.primitive.name == "bitcast" for e in eqns) != converts
+
+
+# (n, tiles): whole-axis tiles of 8, 4 and 12 blocks (two, one and three vregs
+# of ``x``), 44 blocks (DeepSeek-V2's expert width), two reduction steps, and
+# tiles the word form does not take (2 and 3 blocks: a conversion a nibble)
+ONE_HOT_TILES = [(256, None), (128, None), (384, None), (1408, None),
+                 (512, (256, 128)), (64, None), (96, None)]
+
+
+@pytest.mark.parametrize("n,tiles", ONE_HOT_TILES, ids=lambda v: str(v))
+def test_every_nibble_of_a_word_lands_on_its_own_logical_row(n, tiles):
+    """A one-hot ``x`` at each of the ``n`` positions against weights whose
+    nibble is ``(row + column) % 16`` and whose scale is a power of two that
+    differs from block to block: over the 128 columns every nibble value 0-15
+    stands in every position of every word, and each one-hot row must read its
+    OWN logical row of the tile at its own block's scale, exactly (one product,
+    ``(16 + v) - 24``, times a power of two).  This is the CPU half of the
+    proof that the order in which ``pltpu.bitcast`` sets a word's bytes and
+    halves on rows is the one the left operand follows; ``chip_smoke.py``'s
+    ``q40.*.f32`` lines are the chip's."""
+    d = 128
+    rows, cols = np.arange(n)[:, None], np.arange(d)[None, :]
+    qvals = ((rows + cols) % 16 - 8).astype(np.int8)
+    scales = (2.0 ** ((np.arange(n // 32)[:, None] * 5 + cols) % 7 - 3)).astype(np.float16)
+    qt = q40.pack_planes(qvals[None], scales[None])
+    # one launch: position j is "expert" j's own activation row, every one of
+    # them reading plane 0
+    x = jnp.asarray(np.eye(n, dtype=np.float32)[:, None, :], jnp.bfloat16)
+    out = np.asarray(q40._pallas_matmul_experts(
+        x, qt.qpacked, qt.scales, jnp.int32(0), experts=1, interpret=True,
+        tiles=tiles, chosen=jnp.zeros((n,), jnp.int32)))
+    assert out.shape == (n, 1, d)
+    want = qvals.astype(np.float32) * np.repeat(scales.astype(np.float32), 32, axis=0)
+    np.testing.assert_array_equal(out[:, 0, :], want)
+
+
+@pytest.mark.parametrize("body,rows", [
+    ("grouped", 1), ("grouped", 2), ("vpu", 1), ("vpu", 2), ("nibbles", 1),
+    ("bytes", 1), ("words128", 1)])
 def test_the_sweeps_few_row_bodies_compute_the_matmul(body, rows):
     """``tools/sweep_q40.py --body``'s forms the program does not run (the
-    grouped algebra at a few rows a block, and with its inner sums on the VPU)
+    grouped algebra at a few rows a block, with its inner sums on the VPU, and
+    the one-row operand made a nibble or a byte at a time or as ``128 + v``)
     are patched into the loaded module for a run: what the sweep times is the
     matmul, within the grouped body's bound of the f32 reference, and the
     module is the program's again afterwards."""

@@ -462,11 +462,12 @@ def test_all_experts_kernel_programs_are_the_parents(case):
 # cells 1.3-1.8%; PR 41 took one away).  A PR that moves a hash on purpose
 # re-pins it and says what every cell's programs paid.  PR 50 moved the two
 # one-row programs on purpose (the raw nibbles contracted a quantization block
-# at a time: every one-stream decode program compiles anew once); at 16 and 256
-# rows the hashes are PR 41's.
+# at a time: every one-stream decode program compiles anew once), and PR 58
+# again (the packed tile becomes the dot's operand as 32-bit words: 770a7b64 ->
+# 347b3509, 8fd90d1e -> 7d725ac7); at 16 and 256 rows the hashes are PR 41's.
 PARENT_KERNEL_JAXPRS = {
-    (False, 1): "770a7b645e7c06b5", (False, 16): "05cb10f14fd92041",
-    (False, 256): "3967bc32ae344097", (True, 1): "8fd90d1e2378d43f",
+    (False, 1): "347b35095f1be2aa", (False, 16): "05cb10f14fd92041",
+    (False, 256): "3967bc32ae344097", (True, 1): "7d725ac7dbb00725",
     (True, 16): "0b6ec8b329a75164", (True, 256): "0dce88ed8621a5ef",
 }
 

@@ -1304,17 +1304,25 @@ ONE_ROW_LAUNCHES = [
                          ids=[c[0] for c in ONE_ROW_LAUNCHES])
 def test_one_row_q40_body_lowers_for_the_v5e_with_no_op_a_weight_but_the_unpack(
         one_chip, name, form, n, d, kw):
-    """At one row the raw nibbles go to one dot against a block-diagonal left
-    operand: the kernel compiles for the chip, and its Mosaic module holds one
-    matmul, the two nibble planes' conversions (and the scales'), ONE cast to
-    bf16 (the left operand: no weight is rounded), ONE subtraction (the bias
-    on the block partials, where the dot body has one a plane) and as many
-    multiplies as the dot body (the bias's 8 and the scale, on the block
-    partials, where that body scales each plane)."""
+    """At one row the packed tile goes to one dot against a block-diagonal left
+    operand as 32-bit words of two bf16 ``16 + v`` (PR 58): the kernel compiles
+    for the chip, lane gather included, and its Mosaic module holds one matmul,
+    NO extension and NO integer-to-float conversion of the tile (the scales'
+    mantissas alone are converted, where the dot body converts two planes
+    too), five bitcasts beside the scales' (the tile to words, four results to
+    bf16), one lane gather over the vregs of ``x``, ONE cast to bf16 (the left operand:
+    no weight is rounded), ONE subtraction (the bias on the block partials) and
+    as many multiplies as the dot body (the bias's 24 and the scale, on the
+    block partials, where that body scales each plane)."""
     ops, text = _kernel_ops(*_launch(form, n, d, 1, one_chip, **kw))
+    tile_n = q40._tiles(q40.padded_n(n), d)[0]
+    assert q40._nibbles_as(tile_n) == "words"
     assert "tpu_custom_call" in text
-    assert ops["tpu.matmul"] == 1 and ops["tpu.iota"] == 2
-    assert ops["arith.sitofp"] == SIXTEEN_ROW_BODY_OPS["arith.sitofp"]
+    assert ops["tpu.matmul"] == 1
+    assert ops["arith.sitofp"] == SIXTEEN_ROW_BODY_OPS["arith.sitofp"] - 2
+    assert ops["arith.extui"] == SIXTEEN_ROW_BODY_OPS["arith.extui"] - 1
+    assert ops["tpu.bitcast"] == SIXTEEN_ROW_BODY_OPS["tpu.bitcast"] + 5
+    assert ops["tpu.dynamic_gather"] == 1
     assert ops["arith.truncf"] == 1 and ops["arith.subf"] == 1
     assert ops["arith.mulf"] == SIXTEEN_ROW_BODY_OPS["arith.mulf"]
     assert not ops["tpu.transpose"] and not ops["arith.divsi"]

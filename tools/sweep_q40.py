@@ -23,22 +23,30 @@ keeps inside the scan is timed with the launch (PERF.md §6, PR 41).
 ``--body`` takes a fourth argument, the bodies to time (default ``rule``).
 ``rule`` is the kernel as the program runs it (``q40._body``: one row
 contracts the raw nibbles a quantization block at a time, more rows the
-dequantized tile in one dot).  ``dot`` is the dot at every row count, which at
-one row is the body every program ran up to PR 48.  ``half-dot`` is that body
-with the dot over HALF of the tile (the lo nibble planes; the hi planes are
-unpacked as ever and kept alive by a float32 sum, which adds ≈0.75 VPU op a
-weight to the body's ≈5.5) and ``dot-twice`` is it with the same VPU work and
-the dot issued TWICE: they answer what ROADMAP S2 left open for twenty PRs,
-*do the MXU's 128 x 128 tile loads bound the body at few rows?*  If they did,
-``half-dot`` would fall toward half and ``dot-twice`` rise toward double;
-``dot-twice`` is the cleaner of the two.  ``grouped`` is the one-row algebra
-at every row count up to 4, a block-diagonal left operand a row (is one row
-still where it stops winning?); ``vpu`` is the form NOT shipped: the same
-grouped algebra with the inner sums on the VPU (one multiply and one add a
-weight on float32, the byte left unmasked, no dot), which PR 49 would have
-shipped.  What each read is in PERF.md §6, PR 50.  All of them are patched
-into the loaded module for the run (``q40._body``, ``q40._contract_dot``,
-``q40._contract_grouped``); the program has no switch for them.
+dequantized tile in one dot; since PR 58 the one-row operand is made of the
+packed tile's 32-bit words, ``q40._nibbles_as``).  ``dot`` is the dot at every
+row count, which at one row is the body every program ran up to PR 48 (≈5.5 VPU
+ops a weight).  ``half-dot`` is that body with the dot over HALF of the tile
+(the lo nibble planes; the hi planes are unpacked as ever and kept alive by a
+float32 sum, ≈0.75 op a weight more) and ``dot-twice`` is it with the same VPU
+work and the dot issued TWICE: they answered *do the MXU's 128 x 128 tile loads
+bound the body at few rows?* (no: PERF.md §6, PR 50).  ``grouped`` is PR 50's
+one-row algebra at every row count up to 4, a block-diagonal left operand a row;
+``vpu`` the same algebra with the inner sums on the VPU (no dot), which PR 49
+would have shipped.  The one-row operand's other forms (PERF.md §6, PR 58's
+Step 0; ops a weight on the tile, and the kernel's final VLIW bundles a 1024 x
+1024 tile compiled for the v5e): ``nibbles`` is PR 50's (extend, mask or shift,
+int -> f32 -> bf16: ≈3.5-4, 1846 bundles); ``bytes`` the ROADMAP's first form
+(extend, ``0x41804180 | (b & 0xF) << 3 | (b & 0xF0) << 15``: ≈3, 1583);
+``rule`` the shipped words (bitcast to uint32, four times shift / and / or:
+1.5, plus the relayout Mosaic puts in front of the bitcast, 1196);
+``words128`` the words with ``0x4300 | v`` = 128 + v (1.375, 1151; its sums 17
+times the nibbles' read 7e-7 of the reference where 16 + v reads 9e-8);
+``touch`` no body at all (the scales decoded, eight rows looked at, 301): what
+a launch costs when the pipeline's DMA and its grid steps are all there is.
+All of them are patched into the loaded module for the run (``q40._body``,
+``q40._contract_dot``, ``q40._contract_grouped``, ``q40._nibbles_as``,
+``q40._words_bf16``); the program has no switch for them.
 
 ``--chosen`` times a decoded row's routed experts at SmallThinker's shapes
 (6 of 64) and LFM2's (4 of 64): one launch over the chosen planes
@@ -67,6 +75,7 @@ Usage: python tools/sweep_q40.py --tiles [ds_gate,yi_wo]  # tile pairs at 1, 16,
        python tools/sweep_q40.py --grouped [lfm2,olmoe [--routing chiprun_out/routing]]
        python tools/sweep_q40.py --rows [head,w13]        # rows x row block
        python tools/sweep_q40.py --body [w2,ds_down [16,32,64 [rule,dot,dot-twice,vpu]]]  # the body at the rule's tiles, 1 to 512 rows or the rows given
+       python tools/sweep_q40.py --body w13,w2,yi_kv,st_down 1 rule,nibbles,bytes,words128,touch  # the one-row operand's forms
        python tools/sweep_q40.py --chosen [st_gate]       # one launch a row's experts, or one each
 """
 
@@ -135,9 +144,11 @@ CHOSEN_SHAPES = [Shape("st_gate", 2560, 768, 4, 64, chosen=6),
                  Shape("lfm2_down", 1536, 2048, 4, 64, True, chosen=4)]
 TILE_ROWS = (1, 16, 256)
 # K-EXAONE-236B-A23B, one chip's share: 16 held experts of 2048, hidden 6144
-BODY_SHAPES = MISTRAL + [s for s in SHAPES if s.name in ("yi_w13", "yi_w2")
+BODY_SHAPES = MISTRAL + [s for s in SHAPES if s.name.startswith("yi_")
                          or s.experts and "pad" not in s.name] + [
-    Shape("kx_gate", 6144, 2048, 2, 16), Shape("kx_down", 2048, 6144, 2, 16, True)]
+    Shape("kx_gate", 6144, 2048, 2, 16), Shape("kx_down", 2048, 6144, 2, 16, True),
+    # LFM2-24B-A2B's short-convolution projections (hidden 2048; in: B, C, x)
+    Shape("lfm2_conv_in", 2048, 6144, 4), Shape("lfm2_conv_out", 2048, 2048, 4)]
 BODY_ROWS = (1, 16, 128, 256, 512)
 # (rows, row block): None is the code's own choice (one block of every row
 # up to 128, q40._row_block above), "xla" the dequantize-then-dot path
@@ -316,7 +327,7 @@ def _dot_twice(x_ref, vi, s32):
             + jnp.dot(again, w, preferred_element_type=jnp.float32))
 
 
-def _contract_vpu(x_ref, vi, s32):
+def _contract_vpu(x_ref, qp, s32):
     """The grouped algebra with the inner sums on the VPU (the form not
     shipped): ``x_lo * lo + x_hi * hi == x_lo * byte + (x_hi - 16 * x_lo) *
     hi``, so a weight costs half an unpack, half a shift, one conversion, one
@@ -326,6 +337,7 @@ def _contract_vpu(x_ref, vi, s32):
     import jax.numpy as jnp
 
     nb, td = s32.shape
+    vi = qp.astype(jnp.int32)
     lanes = lambda v: jnp.concatenate([v] * (td // 128), axis=-1)  # noqa: E731
     byte = vi.astype(jnp.float32).reshape(nb, 16, td)
     hi = (vi >> 4).astype(jnp.float32).reshape(nb, 16, td)
@@ -342,15 +354,17 @@ def _contract_vpu(x_ref, vi, s32):
     return jnp.concatenate(parts, axis=0)
 
 
-def _grouped_rows(x_ref, vi, s32):
-    """The shipped one-row body (``q40._contract_grouped``) for a block of a
-    few rows: one block-diagonal left operand of ``nb`` rows an activation
-    row, set one above the other for ONE dot of ``rows * nb`` rows."""
+def _grouped_rows(x_ref, qp, s32):
+    """PR 50's one-row body (a conversion a nibble, logical row order) for a
+    block of a few rows: one block-diagonal left operand of ``nb`` rows an
+    activation row, set one above the other for ONE dot of ``rows * nb``
+    rows."""
     import jax
     import jax.numpy as jnp
 
     nb, td = s32.shape
     rows, tn = x_ref.shape
+    vi = qp.astype(jnp.int32)
     lo = (vi & 0xF).astype(jnp.bfloat16).reshape(nb, 16, td)
     hi = (vi >> 4).astype(jnp.bfloat16).reshape(nb, 16, td)
     w = jnp.concatenate([lo, hi], axis=1).reshape(tn, td)
@@ -365,6 +379,46 @@ def _grouped_rows(x_ref, vi, s32):
     return p.reshape(rows, nb // k, k, td).sum(axis=1).reshape(rows * k, td)
 
 
+def _words128_bf16(qp):
+    """``q40._words_bf16`` with ``0x4300 | v`` = 128 + v in each half: no shift
+    for the first pair, 11 ops for 8 weights, sums 17 times the nibbles'."""
+    import jax.numpy as jnp
+    from jax.experimental.pallas import tpu as pltpu
+
+    tn, td = 2 * qp.shape[0], qp.shape[1]
+    w = pltpu.bitcast(qp, jnp.uint32)
+    pieces = [pltpu.bitcast(
+        ((w >> at if at else w) & jnp.uint32(0x000F000F)) | jnp.uint32(0x43004300),
+        jnp.bfloat16).reshape(tn // 64, 16, td) for at in (0, 4, 8, 12)]
+    return jnp.concatenate(pieces, axis=1).reshape(tn, td), 136.0
+
+
+def _bytes(x_ref, qp, s32):
+    """Form B (ROADMAP S2(3)(iii) as first written): the tile extended to
+    int32 and ``0x41804180 | (b & 0xF) << 3 | (b & 0xF0) << 15``, one word of
+    two bf16 ``16 + v`` a byte in five integer ops and no conversion; row ``2
+    p + h`` of the operand holds nibble ``h`` of packed row ``p``."""
+    import jax.numpy as jnp
+    from jax.experimental.pallas import tpu as pltpu
+
+    b = qp.astype(jnp.int32)
+    w = pltpu.bitcast(((b & 0xF) << 3) | ((b & 0xF0) << 15) | 0x41804180,
+                      jnp.bfloat16)
+    return _q40()._block_sums(
+        x_ref, w, 24.0,
+        (lambda j: (j & ~31) | ((j & 1) << 4) | ((j >> 1) & 15), lambda j: j >> 5), s32)
+
+
+def _touch(x_ref, qp, s32):
+    """No body at all: the scales decoded, eight rows of the tile looked at.
+    What a launch costs when the pipeline's DMA and its steps are all there
+    is: the floor of every body at the rule's tiles."""
+    import jax.numpy as jnp
+
+    k = _q40()._partial_rows(32 * s32.shape[0])
+    return s32[:k] + qp[:32].astype(jnp.int32).astype(jnp.float32).sum(axis=0, keepdims=True)
+
+
 def _few_rows(rows: int) -> str:
     return "grouped" if rows <= 4 else "dot"
 
@@ -377,6 +431,10 @@ BODIES = {
     "dot-twice": dict(_body=lambda rows: "dot", _contract_dot=_dot_twice),
     "grouped": dict(_body=_few_rows, _contract_grouped=_grouped_rows),
     "vpu": dict(_body=_few_rows, _contract_grouped=_contract_vpu),
+    "nibbles": dict(_nibbles_as=lambda tile_n: "nibbles"),
+    "words128": dict(_words_bf16=_words128_bf16),
+    "bytes": dict(_contract_grouped=_bytes),
+    "touch": dict(_contract_grouped=_touch),
 }
 
 
