@@ -17,7 +17,12 @@ rows) and brumby folders (``ARCH_BRUMBY``: Qwen3's names with a gate
 repository) and ouro folders (``ARCH_OURO``: a looped model, Llama's names with
 the two closing norms ``input_layernorm_2`` / ``post_attention_layernorm_2``,
 header keys 31 and 40; ASSUMED likewise, ``_OURO_LEAVES``;
-``early_exit_gate.*`` is skipped by name).  Key semantics preserved:
+``early_exit_gate.*`` is skipped by name) and falcon_h1 folders
+(``ARCH_FALCON_H1``: attention and a Mamba-2 mixer in every block, header keys
+31, 32, 41..60; ``mamba.in_proj.weight`` becomes ``ssm_in`` (its ``z | x | B |
+C`` rows) and the float32 ``ssm_dt`` (its ``dt`` rows), ``conv1d.weight`` (C, 1,
+K) the flat ``ssm_conv_w``; ASSUMED likewise, ``_FALCON_H1_LEAVES``).  Key
+semantics preserved:
 
 * q/k head permutation (convert-hf.py:12-15): HF stores RoPE in rotate-half
   layout; the `.m` format expects the interleaved-pair layout, so q and k
@@ -57,6 +62,7 @@ ARCH_BY_MODEL_TYPE = {
     "lfm2_moe": mfile.ARCH_LFM2_MOE,
     "brumby": mfile.ARCH_BRUMBY,
     "ouro": mfile.ARCH_OURO,
+    "falcon_h1": mfile.ARCH_FALCON_H1,
 }
 HIDDEN_ACT = {"gelu": mfile.ACT_GELU, "silu": mfile.ACT_SILU,
               "relu": mfile.ACT_RELU}
@@ -343,6 +349,41 @@ def _ouro_fields(config: dict) -> dict:
                 loops=int(config["total_ut_steps"]))
 
 
+def _falcon_h1_fields(config: dict) -> dict:
+    """The header's keys past the fourteen from a ``falcon_h1`` config.json:
+    the attention head size, the mixer's sizes, every multiplier, and
+    ``rope_theta`` as a float.  What the file cannot carry is refused by name."""
+    def no(why):
+        raise SystemExit(f"falcon_h1: {why}")
+
+    for key in ("attention_bias", "mamba_proj_bias", "mlp_bias",
+                "projectors_bias", "rope_scaling", "tie_word_embeddings",
+                "mamba_norm_before_gate", "attn_layer_indices"):
+        if config.get(key):
+            no(f"{key} is {config[key]!r}; this block has no such thing")
+    for key in ("mamba_conv_bias", "mamba_rms_norm", "mamba_use_mlp"):
+        if not config.get(key, True):
+            no(f"{key} is false; the .m file always has the tensors")
+    heads, dh = config["mamba_n_heads"], config["mamba_d_head"]
+    if heads * dh != config["mamba_d_ssm"]:
+        no("mamba_n_heads * mamba_d_head is not mamba_d_ssm")
+    mup = dict(zip(mfile.SSM_MUP, config["ssm_multipliers"]))
+    gate, down = config["mlp_multipliers"]
+    return dict(
+        norm_eps=float(config.get("rms_norm_eps", 1e-5)),
+        head_dim=int(config["head_dim"]), ssm_heads=heads, ssm_head_dim=dh,
+        ssm_state=int(config["mamba_d_state"]),
+        ssm_groups=int(config["mamba_n_groups"]),
+        ssm_conv=int(config["mamba_d_conv"]),
+        mup_embedding=config["embedding_multiplier"],
+        mup_head=config["lm_head_multiplier"],
+        mup_attn_in=config["attention_in_multiplier"],
+        mup_attn_out=config["attention_out_multiplier"],
+        mup_ssm_in=config["ssm_in_multiplier"],
+        mup_ssm_out=config["ssm_out_multiplier"],
+        mup_key=config["key_multiplier"], mup_gate=gate, mup_down=down, **mup)
+
+
 def load_spec(folder: str, weights_ftype: int, experts_held: int = 0,
               first_expert: int = 0) -> mfile.ModelSpec:
     with open(os.path.join(folder, "config.json")) as f:
@@ -363,6 +404,8 @@ def load_spec(folder: str, weights_ftype: int, experts_held: int = 0,
         ext = _brumby_fields(config)
     if arch == mfile.ARCH_OURO:
         ext = _ouro_fields(config)
+    if arch == mfile.ARCH_FALCON_H1:
+        ext = _falcon_h1_fields(config)
     if arch in (mfile.ARCH_EXAONE_MOE, mfile.ARCH_LFM2_MOE):
         config = dict(config, rope_theta=(config.get("rope_parameters") or {}).get(
             "rope_theta", config.get("rope_theta", 10000.0)))
@@ -468,13 +511,40 @@ _DEEPSEEK2_LEAVES = {
 }
 
 
+# ASSUMED: a falcon_h1 layer's tensors under ``model.layers.N.`` (the published
+# ``modeling_falcon_h1.py``'s module names as the builder knows them; the
+# mixer's vectors carry no ``.weight``).  ``ssm_in`` and ``ssm_dt`` are both
+# rows of ``mamba.in_proj.weight`` (:func:`_falcon_h1_rows`).  Unverified until
+# the published files are in the repository
+_FALCON_H1_LEAVES = {
+    "wq": "self_attn.q_proj.weight", "wk": "self_attn.k_proj.weight",
+    "wv": "self_attn.v_proj.weight", "wo": "self_attn.o_proj.weight",
+    "ssm_in": "mamba.in_proj.weight", "ssm_dt": "mamba.in_proj.weight",
+    "ssm_conv_w": "mamba.conv1d.weight", "ssm_conv_b": "mamba.conv1d.bias",
+    "ssm_a_log": "mamba.A_log", "ssm_dt_bias": "mamba.dt_bias",
+    "ssm_d": "mamba.D", "ssm_norm": "mamba.norm.weight",
+    "ssm_out": "mamba.out_proj.weight",
+    "w1": "feed_forward.gate_proj.weight", "w2": "feed_forward.down_proj.weight",
+    "w3": "feed_forward.up_proj.weight",
+    "rms_att": "input_layernorm.weight", "rms_ffn": "pre_ff_layernorm.weight",
+}
+
+
+def _falcon_h1_rows(leaf: str, t, spec: mfile.ModelSpec):
+    """``in_proj``'s rows ``z | x | B | C`` for ``ssm_in``, its last
+    ``ssm_heads`` rows (``dt``) for ``ssm_dt``; every other tensor whole."""
+    cut = spec.ssm_inner + spec.ssm_channels
+    return {"ssm_in": t[:cut], "ssm_dt": t[cut:]}.get(leaf, t)
+
+
 def hf_source_name(our_name: str, spec: mfile.ModelSpec) -> tuple[str, bool]:
     """Map a `.m` plan tensor name to its HF key; returns (key, permute?)."""
     if our_name == "token_embedding":
         return "model.embed_tokens.weight", False
     if our_name == "rms_final":
-        return ("model.embedding_norm.weight" if spec.arch == mfile.ARCH_LFM2_MOE
-                else "model.norm.weight"), False
+        return {mfile.ARCH_LFM2_MOE: "model.embedding_norm.weight",
+                mfile.ARCH_FALCON_H1: "model.final_layernorm.weight"}.get(
+                    spec.arch, "model.norm.weight"), False
     if our_name == "wcls":
         return "lm_head.weight", False
     parts = our_name.split(".")
@@ -492,6 +562,8 @@ def hf_source_name(our_name: str, spec: mfile.ModelSpec) -> tuple[str, bool]:
         return f"{base}.{_BRUMBY_LEAVES[leaf]}.weight", False
     if spec.arch == mfile.ARCH_OURO:    # rows as published: halves rotate
         return f"{base}.{_OURO_LEAVES[leaf]}.weight", False
+    if spec.arch == mfile.ARCH_FALCON_H1:  # rows as published: halves rotate
+        return f"{base}.{_FALCON_H1_LEAVES[leaf]}", False
     # rows as published: these runtimes rotate halves, as HF does
     olmoe = spec.arch in (mfile.ARCH_OLMOE, mfile.ARCH_SMALLTHINKER,
                           mfile.ARCH_EXAONE_MOE)
@@ -567,6 +639,8 @@ def convert(folder: str, weights_ftype: int, out_path: str,
                 # the published model ties its head to the embedding
                 key = "model.embed_tokens.weight"
             t = store.get(key)
+            if spec.arch == mfile.ARCH_FALCON_H1:
+                t = _falcon_h1_rows(item.name.split(".")[-1], t, spec)
             if do_permute:
                 heads = spec.n_heads if item.name.endswith("wq") else spec.n_kv_heads
                 t = permute(t, spec.n_heads, heads)

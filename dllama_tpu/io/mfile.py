@@ -34,7 +34,11 @@ other layer a gated short convolution (``conv_in`` (3 dim, dim), ``conv_taps``
 shared expert.  A Brumby file (``ARCH_BRUMBY``) has keys 31 and 39 (the
 retention's degree) and Llama's layers with, after ``wo``, the gate ``wg``
 (n_kv_heads, dim; f32: eight rows are no Q40 matrix) and a ``q_norm`` /
-``k_norm`` of one head's size.  Matmul weights are stored row-major
+``k_norm`` of one head's size.  A Falcon-H1 file (``ARCH_FALCON_H1``) has keys
+31, 32 and 41..60 (the state-space mixer's sizes, the muP multipliers as f32
+bits, and ``rope_theta`` as a float: 1e11 passes an i32) and Llama's layers
+with, after ``wo``, the mixer's tensors (:func:`_ssm_tensors`).  Matmul weights
+are stored row-major
 ``(d_out, n_in)`` in the
   model's weight float type; norm weights and the embedding are F32
   (transformer.cpp:213-218, 266-278).
@@ -101,11 +105,18 @@ ARCH_BRUMBY = 0xABCD08
 # norm closing every pass; pass ``u`` of layer ``l`` keeps keys and values of
 # its own (cache plane ``u * n_layers + l``)
 ARCH_OURO = 0xABCD09
+# Falcon-H1 (``falcon_h1``): a hybrid-head model.  EVERY block runs grouped-query
+# attention (rotate-half RoPE, a head size of its own, key 32) and a Mamba-2
+# state-space mixer (keys 41..45; ``ops/ssm.py``) side by side on one normed
+# input and adds both to the residual, then a SwiGLU; every branch carries muP
+# multipliers, scalars of the published config (keys 46..59)
+ARCH_FALCON_H1 = 0xABCD0A
 ARCH_NAMES = {ARCH_LLAMA: "llama", ARCH_GROK1: "grok1", ARCH_MIXTRAL: "mixtral",
               ARCH_OLMOE: "olmoe", ARCH_DEEPSEEK2: "deepseek2",
               ARCH_SMALLTHINKER: "smallthinker",
               ARCH_EXAONE_MOE: "exaone_moe", ARCH_LFM2_MOE: "lfm2_moe",
-              ARCH_BRUMBY: "brumby", ARCH_OURO: "ouro"}
+              ARCH_BRUMBY: "brumby", ARCH_OURO: "ouro",
+              ARCH_FALCON_H1: "falcon_h1"}
 
 # TransformerHiddenAct (transformer.hpp:45-48), and beyond it ReLU
 ACT_GELU = 0
@@ -132,7 +143,8 @@ KEY_WEIGHTS_FLOAT_TYPE = 13
 # K-EXAONE's (``SHARE_KEYS``, 35..37; its file carries some of each),
 # LFM2's one (``CONV_KEYS``, 38; its file carries some of each) and Brumby's
 # one (``RETENTION_KEYS``, 39; its file also carries key 31) and Ouro's one
-# (``LOOP_KEYS``, 40; its file also carries key 31).
+# (``LOOP_KEYS``, 40; its file also carries key 31) and Falcon-H1's
+# (``SSM_KEYS``, 41..60; its file also carries keys 31 and 32).
 # ``(key, field, is_float)``: a float travels as the bits of its IEEE-754 f32
 # in the i32
 EXT_KEYS = (
@@ -174,8 +186,28 @@ RETENTION_KEYS = (
 LOOP_KEYS = (
     (40, "loops", False),               # passes of the whole stack over its own output (n_loops)
 )
+# the five multipliers of ``ssm_multipliers``, in the order of ``W_in``'s split
+SSM_MUP = ("mup_z", "mup_x", "mup_b", "mup_c", "mup_dt")
+SSM_KEYS = (
+    (41, "ssm_heads", False),           # mamba_n_heads
+    (42, "ssm_head_dim", False),        # mamba_d_head
+    (43, "ssm_state", False),           # mamba_d_state: rows of a head's state matrix
+    (44, "ssm_groups", False),          # mamba_n_groups: heads / groups share one B and one C
+    (45, "ssm_conv", False),            # mamba_d_conv: taps of the causal depthwise convolution
+    (46, "mup_embedding", True),        # embedding_multiplier
+    (47, "mup_head", True),             # lm_head_multiplier
+    (48, "mup_attn_in", True),          # attention_in_multiplier
+    (49, "mup_attn_out", True),         # attention_out_multiplier
+    (50, "mup_ssm_in", True),           # ssm_in_multiplier
+    (51, "mup_ssm_out", True),          # ssm_out_multiplier
+    (52, "mup_key", True),              # key_multiplier
+    (53, "mup_gate", True),             # mlp_multipliers[0]: on the gate's projection
+    (54, "mup_down", True),             # mlp_multipliers[1]: on the down projection
+) + tuple((55 + i, name, True) for i, name in enumerate(SSM_MUP)) + (
+    (60, "rope_theta_f32", True),       # rope_theta where key 12's i32 cannot hold it
+)
 ALL_EXT_KEYS = (EXT_KEYS + WINDOW_KEYS + SHARE_KEYS + CONV_KEYS + RETENTION_KEYS
-                + LOOP_KEYS)
+                + LOOP_KEYS + SSM_KEYS)
 # the keys a file of an arch carries past the fourteen
 ARCH_EXT_KEYS = {ARCH_DEEPSEEK2: tuple(range(14, 32)),
                  ARCH_SMALLTHINKER: (31, 32, 33, 34),
@@ -185,9 +217,10 @@ ARCH_EXT_KEYS = {ARCH_DEEPSEEK2: tuple(range(14, 32)),
                  # keys for the same two numbers); no window, so no key 33
                  ARCH_LFM2_MOE: (19, 23, 24, 31, 32, 34, 37, 38),
                  ARCH_BRUMBY: (31, 39),
-                 ARCH_OURO: (31, 40)}
+                 ARCH_OURO: (31, 40),
+                 ARCH_FALCON_H1: (31, 32) + tuple(range(41, 61))}
 _EXT_BY_KEY = {k: (name, is_f) for k, name, is_f in ALL_EXT_KEYS}
-KEY_MAX = LOOP_KEYS[-1][0]
+KEY_MAX = SSM_KEYS[-1][0]
 
 
 def _f32_bits(x: float) -> int:
@@ -250,6 +283,37 @@ class ModelSpec:
     retention_degree: int = 0
     # ARCH_OURO's; 0 where the arch has none (the stack runs once)
     loops: int = 0
+    # ARCH_FALCON_H1's; 0 / 1.0 where the arch has none
+    ssm_heads: int = 0
+    ssm_head_dim: int = 0
+    ssm_state: int = 0
+    ssm_groups: int = 0
+    ssm_conv: int = 0
+    mup_embedding: float = 1.0
+    mup_head: float = 1.0
+    mup_attn_in: float = 1.0
+    mup_attn_out: float = 1.0
+    mup_ssm_in: float = 1.0
+    mup_ssm_out: float = 1.0
+    mup_key: float = 1.0
+    mup_gate: float = 1.0
+    mup_down: float = 1.0
+    mup_z: float = 1.0
+    mup_x: float = 1.0
+    mup_b: float = 1.0
+    mup_c: float = 1.0
+    mup_dt: float = 1.0
+    rope_theta_f32: float = 0.0
+
+    @property
+    def ssm_inner(self) -> int:
+        """``mamba_d_ssm``: the mixer's heads times their size."""
+        return self.ssm_heads * self.ssm_head_dim
+
+    @property
+    def ssm_channels(self) -> int:
+        """Channels of the mixer's convolution: ``x | B | C``."""
+        return self.ssm_inner + 2 * self.ssm_groups * self.ssm_state
 
     @property
     def head_size(self) -> int:
@@ -328,6 +392,8 @@ def tensor_plan(spec: ModelSpec) -> list[TensorInfo]:
             add(f"layers.{i}.wg", (spec.n_kv_heads, spec.dim), quants.F32)
             add(f"layers.{i}.q_norm", (spec.head_size,), quants.F32)
             add(f"layers.{i}.k_norm", (spec.head_size,), quants.F32)
+        if spec.arch == ARCH_FALCON_H1:
+            _ssm_tensors(spec, f"layers.{i}.", add)
         if spec.n_experts > 0:
             add(f"layers.{i}.moe_router", (spec.n_experts, spec.dim), w)
             for e in range(spec.n_experts):
@@ -348,6 +414,26 @@ def tensor_plan(spec: ModelSpec) -> list[TensorInfo]:
     add("rms_final", (spec.dim,), quants.F32)
     add("wcls", (spec.vocab_size, spec.dim), w)
     return plan
+
+
+def _ssm_tensors(spec: ModelSpec, p: str, add) -> None:
+    """A Falcon-H1 layer's state-space mixer.  The published ``in_proj`` (rows
+    ``z | x | B | C | dt``) is two tensors here: ``ssm_in``, its ``z | xBC``
+    rows (Q40), and ``ssm_dt``, its last ``ssm_heads`` rows, float32 as
+    Brumby's ``wg``: 32 rows are no Q40 matrix (9248 = 289 x 32 is no multiple
+    of the 128 lanes) and ``dt`` sets the state's decay.  ``ssm_conv_w`` flat,
+    channel by channel (value ``c * taps + j`` weighs position ``t - (taps - 1)
+    + j`` in channel ``c``)."""
+    w, d, h = spec.weights_ftype, spec.dim, spec.ssm_heads
+    add(p + "ssm_in", (spec.ssm_inner + spec.ssm_channels, d), w)
+    add(p + "ssm_dt", (h, d), quants.F32)
+    add(p + "ssm_conv_w", (spec.ssm_channels * spec.ssm_conv,), quants.F32)
+    add(p + "ssm_conv_b", (spec.ssm_channels,), quants.F32)
+    add(p + "ssm_a_log", (h,), quants.F32)
+    add(p + "ssm_dt_bias", (h,), quants.F32)
+    add(p + "ssm_d", (h,), quants.F32)
+    add(p + "ssm_norm", (spec.ssm_inner,), quants.F32)
+    add(p + "ssm_out", (d, spec.ssm_inner), w)
 
 
 def _deepseek2_layers(spec: ModelSpec, add) -> None:
@@ -511,6 +597,8 @@ def validate_spec(spec: ModelSpec, path) -> ModelSpec:
         _validate_smallthinker(spec, path)
     elif spec.arch == ARCH_LFM2_MOE:
         _validate_lfm2_moe(spec, path)
+    elif spec.arch == ARCH_FALCON_H1:
+        _validate_falcon_h1(spec, path)
     elif spec.head_dim or spec.window or spec.window_period:
         raise ArtifactError(path, "header key",
                             "keys 32..34 describe a smallthinker file (or an "
@@ -534,6 +622,12 @@ def validate_spec(spec: ModelSpec, path) -> ModelSpec:
                             expected=hex(ARCH_OURO), got=hex(spec.arch))
     if spec.arch == ARCH_OURO:
         _validate_ouro(spec, path)
+    if spec.arch != ARCH_FALCON_H1 and any(
+            getattr(spec, name) != getattr(ModelSpec, name)
+            for _, name, _ in SSM_KEYS):
+        raise ArtifactError(path, "header key",
+                            "keys 41..60 describe a falcon_h1 file",
+                            expected=hex(ARCH_FALCON_H1), got=hex(spec.arch))
     if spec.arch == ARCH_EXAONE_MOE:
         _validate_exaone_moe(spec, path)
     elif spec.experts_held or spec.first_expert or (
@@ -631,6 +725,39 @@ def _validate_brumby(spec: ModelSpec, path) -> None:
             spec.head_size)
     if spec.n_experts:
         bad("n_experts", "a brumby layer has a dense SwiGLU", 0, spec.n_experts)
+
+
+def _validate_falcon_h1(spec: ModelSpec, path) -> None:
+    """The cross-field rules of an ``ARCH_FALCON_H1`` header."""
+    def bad(field, why, expected, got):
+        raise ArtifactError(path, f"header field {field}", why,
+                            expected=expected, got=got)
+
+    if not 2 <= spec.head_dim <= 4096 or spec.head_dim % 2:
+        bad("head_dim", "a falcon_h1 file states its attention head size, and "
+            "RoPE rotates halves of it", "even, 2..4096", spec.head_dim)
+    if spec.window or spec.window_period:
+        bad("window", "a falcon_h1 file has no sliding window and no periods: "
+            "every block is alike", 0, (spec.window, spec.window_period))
+    for field, hi in (("ssm_heads", 4096), ("ssm_head_dim", 4096),
+                      ("ssm_state", 4096), ("ssm_groups", 4096)):
+        if not 1 <= getattr(spec, field) <= hi:
+            bad(field, "a falcon_h1 file states its state-space mixer's sizes",
+                f"1..{hi}", getattr(spec, field))
+    if spec.ssm_heads % spec.ssm_groups:
+        bad("ssm_groups", "the mixer's heads share B and C in whole groups",
+            f"a divisor of ssm_heads={spec.ssm_heads}", spec.ssm_groups)
+    if not 2 <= spec.ssm_conv <= 64:
+        bad("ssm_conv", "a falcon_h1 file states its convolution's taps "
+            "(mamba_d_conv)", "2..64", spec.ssm_conv)
+    if spec.n_experts:
+        bad("n_experts", "a falcon_h1 block has a dense SwiGLU", 0,
+            spec.n_experts)
+    for _, name, is_f in SSM_KEYS[5:]:
+        v = getattr(spec, name)
+        if not (v > 0 and np.isfinite(v)):
+            bad(name, "a multiplier (and rope_theta) is a positive float",
+                "> 0", v)
 
 
 def _validate_lfm2_moe(spec: ModelSpec, path) -> None:
@@ -836,6 +963,8 @@ def read_spec(path: str | os.PathLike, weights_ftype: int | None = None) -> Mode
                 "model file does not specify weights float type; pass weights_ftype "
                 "(reference: 'Not specified weights float type', transformer.cpp:80-81)")
         spec.weights_ftype = weights_ftype
+    if spec.rope_theta_f32:  # the float form wins: key 12 then holds a clipped i32
+        spec.rope_theta = spec.rope_theta_f32
     return validate_spec(spec, path)
 
 
@@ -985,13 +1114,16 @@ def write_header(f, spec: ModelSpec) -> int:
         (KEY_VOCAB_SIZE, spec.vocab_size),
         (KEY_SEQ_LEN, spec.seq_len),
         (KEY_HIDDEN_ACT, spec.hidden_act),
-        (KEY_ROPE_THETA, int(spec.rope_theta)),
+        (KEY_ROPE_THETA, int(min(spec.rope_theta, 2 ** 31 - 1))),
         (KEY_WEIGHTS_FLOAT_TYPE, spec.weights_ftype),
     ]
     # the older archs keep the reference's fourteen keys, byte for byte
     own = ARCH_EXT_KEYS.get(spec.arch, ())
-    pairs += [(k, _f32_bits(getattr(spec, name)) if is_f
-               else getattr(spec, name))
+
+    def value(name):  # key 60 states rope_theta again, as a float
+        return getattr(spec, "rope_theta" if name == "rope_theta_f32" else name)
+
+    pairs += [(k, _f32_bits(value(name)) if is_f else value(name))
               for k, name, is_f in ALL_EXT_KEYS if k in own]
     data = b"".join(struct.pack("<ii", k, v) for k, v in pairs)
     f.write(struct.pack("<ii", MAGIC_V2, 8 + len(data)))

@@ -89,6 +89,52 @@ class ModelConfig:
     retention_degree: int = 0       # > 0: every layer is power retention of this degree
     # ---- ARCH_OURO (header key 40); 0 = the arch has none
     loops: int = 0                  # > 0: the whole stack runs this many times (n_loops)
+    # ---- ARCH_FALCON_H1 (header keys 41..60); 0 / 1.0 = the arch has none
+    ssm_heads: int = 0              # > 0: every block has a Mamba-2 mixer beside its attention
+    ssm_head_dim: int = 0
+    ssm_state: int = 0              # rows of a head's state matrix (mamba_d_state)
+    ssm_groups: int = 0             # heads / groups share one B and one C
+    ssm_conv: int = 0               # taps of the mixer's causal depthwise convolution
+    # the muP multipliers, scalars of the published config, each applied where
+    # ``_hybrid_mixers`` / ``run_blocks`` / ``_head`` say
+    mup_embedding: float = 1.0
+    mup_head: float = 1.0
+    mup_attn_in: float = 1.0
+    mup_attn_out: float = 1.0
+    mup_ssm_in: float = 1.0
+    mup_ssm_out: float = 1.0
+    mup_key: float = 1.0
+    mup_gate: float = 1.0
+    mup_down: float = 1.0
+    mup_z: float = 1.0              # ssm_multipliers, in the order of W_in's split
+    mup_x: float = 1.0
+    mup_b: float = 1.0
+    mup_c: float = 1.0
+    mup_dt: float = 1.0
+
+    @property
+    def has_ssm(self) -> bool:
+        """Every block runs a Mamba-2 state-space mixer (``ops/ssm.py``) beside
+        its attention: one layer of one row owns keys and values (pages, on a
+        paged engine) AND a state matrix a head with its rings."""
+        return self.ssm_heads > 0
+
+    @property
+    def ssm_inner(self) -> int:
+        """``mamba_d_ssm``: the mixer's heads times their size."""
+        return self.ssm_heads * self.ssm_head_dim
+
+    @property
+    def ssm_channels(self) -> int:
+        """Channels of the mixer's convolution: ``x | B | C``."""
+        return self.ssm_inner + 2 * self.ssm_groups * self.ssm_state
+
+    @property
+    def folds_state(self) -> bool:
+        """Some layer keeps a state matrix that lags the position clock behind
+        a watermark (``ops/retention.py watermark``): retention's, or a
+        state-space mixer's."""
+        return self.retention_degree > 0 or self.has_ssm
 
     @property
     def n_loops(self) -> int:
@@ -164,7 +210,7 @@ class ModelConfig:
         values a position (a convolution's ring of ``z``, a retention layer's
         matrix): the engines keep account of how far the position clock may be
         moved back over it (``runtime/engine.py``, the pos-rewind invariant)."""
-        return self.conv_taps > 0 or self.retention_degree > 0
+        return self.conv_taps > 0 or self.folds_state
 
     @property
     def n_experts_held(self) -> int:
@@ -181,7 +227,7 @@ class ModelConfig:
         of 6144; a prompt up to one chunk takes one call."""
         rows = PREFILL_PRODUCT_BYTES // (4 * max(self.n_experts_held, 1) * self.dim)
         rows = max(16, 1 << (max(rows, 1).bit_length() - 1))
-        if self.retention_degree:
+        if self.folds_state:
             # a call's rows all enter the ring of recent positions, and what
             # it folds lies wholly before them (ops/retention.py)
             from ..ops import retention
@@ -254,11 +300,13 @@ class ModelConfig:
 
     @property
     def embedding_scale(self) -> float:
-        return GROK_EMBEDDING_SCALE if self.arch == mfile.ARCH_GROK1 else 1.0
+        if self.arch == mfile.ARCH_GROK1:
+            return GROK_EMBEDDING_SCALE
+        return self.mup_embedding
 
     @property
     def logit_scale(self) -> float:
-        return GROK_LOGIT_SCALE if self.arch == mfile.ARCH_GROK1 else 1.0
+        return GROK_LOGIT_SCALE if self.arch == mfile.ARCH_GROK1 else self.mup_head
 
     @property
     def post_block_norms(self) -> bool:
@@ -310,8 +358,9 @@ class ModelConfig:
             n_active_experts=spec.n_active_experts, vocab_size=spec.vocab_size,
             seq_len=spec.seq_len, hidden_act=spec.hidden_act,
             rope_theta=spec.rope_theta, dtype=dtype,
+            # key 60 is rope_theta again as a float: read_spec folded it in
             **{name: getattr(spec, name)
-               for _, name, _ in mfile.ALL_EXT_KEYS})
+               for _, name, _ in mfile.ALL_EXT_KEYS if name != "rope_theta_f32"})
 
     def with_(self, **kw) -> "ModelConfig":
         return replace(self, **kw)
@@ -383,6 +432,24 @@ def tiny_brumby(**kw) -> ModelConfig:
     base = dict(arch=mfile.ARCH_BRUMBY, dim=160, hidden_dim=224, n_layers=4,
                 n_heads=10, n_kv_heads=2, vocab_size=128, seq_len=512,
                 rope_theta=1e6, norm_eps=1e-6, retention_degree=2)
+    base.update(kw)
+    return tiny_config(**base)
+
+
+def tiny_falcon_h1(**kw) -> ModelConfig:
+    """Falcon-H1 at a toy size that keeps every ratio: five query heads a kv
+    head at a head size that is not dim / n_heads, a mixer of 4 heads of 16 in
+    two groups with a state of 24 rows (not the head size), 4 taps, an odd
+    ``W_in`` width (64 + 160 + 4 = 228), every multiplier off 1, an untied
+    head, three blocks all alike."""
+    base = dict(arch=mfile.ARCH_FALCON_H1, dim=64, hidden_dim=96, n_layers=3,
+                n_heads=10, n_kv_heads=2, vocab_size=128, seq_len=512,
+                rope_theta=1e11, norm_eps=1e-5, head_dim=16, ssm_heads=4,
+                ssm_head_dim=16, ssm_state=24, ssm_groups=2, ssm_conv=4,
+                mup_embedding=5.66, mup_head=0.25, mup_attn_in=0.9,
+                mup_attn_out=0.6, mup_ssm_in=0.5, mup_ssm_out=0.8,
+                mup_key=0.7, mup_gate=0.6, mup_down=0.45, mup_z=0.7,
+                mup_x=1.5, mup_b=1.4, mup_c=1.3, mup_dt=0.7)
     base.update(kw)
     return tiny_config(**base)
 

@@ -41,6 +41,10 @@ MOE_FFN_KEYS = ("router", "router_bias", "up", "gate", "down", "shared_w1",
 # all L
 CONV_KEYS = ("conv_in", "conv_taps", "conv_out")
 ATT_KIND_KEYS = ("wq", "wk", "wv", "wqkv", "wo", "q_norm", "k_norm")
+# a Falcon-H1 mixer's tensors that stay float32 whatever the weights' type (the
+# ``dt`` projection sets the state's decay; the rest are vectors)
+SSM_F32 = ("ssm_dt", "ssm_conv_w", "ssm_conv_b", "ssm_a_log", "ssm_dt_bias",
+           "ssm_d", "ssm_norm")
 
 
 def _mla_param_shapes(cfg: ModelConfig) -> dict[str, tuple[int, ...]]:
@@ -132,6 +136,13 @@ def param_shapes(cfg: ModelConfig) -> dict[str, tuple[int, ...]]:
     if cfg.retention_degree:
         shapes.update({"wg": (L, D, cfg.n_kv_heads), "q_norm": (L, cfg.head_size),
                        "k_norm": (L, cfg.head_size)})
+    if cfg.has_ssm:  # the mixer beside attention (io/mfile.py _ssm_tensors)
+        H, C = cfg.ssm_heads, cfg.ssm_channels
+        shapes.update({"ssm_in": (L, D, cfg.ssm_inner + C), "ssm_dt": (L, D, H),
+                       "ssm_conv_w": (L, C, cfg.ssm_conv), "ssm_conv_b": (L, C),
+                       "ssm_a_log": (L, H), "ssm_dt_bias": (L, H),
+                       "ssm_d": (L, H), "ssm_norm": (L, cfg.ssm_inner),
+                       "ssm_out": (L, cfg.ssm_inner, D)})
     if cfg.is_moe:
         shapes.update({
             "router": (L, D, E),
@@ -159,7 +170,13 @@ def init_params(cfg: ModelConfig, seed: int = 0, scale: float = 0.02) -> Params:
             x = (rng.standard_normal(shape) * scale).astype(np.float32)
         if name == "conv_taps":  # O(1) taps: the state matters to the logits
             x = (0.5 + 0.5 * rng.standard_normal(shape)).astype(np.float32)
-        f32 = norm or name in ("router_bias", "conv_taps", "wg")
+        if name in ("ssm_conv_w", "ssm_d"):
+            x = (0.5 + 0.5 * rng.standard_normal(shape)).astype(np.float32)
+        if name == "ssm_a_log":  # A = -exp(.) in 0.5..2, dt in 0.01..0.1: a
+            x = np.log(rng.uniform(0.5, 2.0, shape)).astype(np.float32)
+        if name == "ssm_dt_bias":  # state hundreds of positions deep matters
+            x = np.log(np.expm1(rng.uniform(0.01, 0.1, shape))).astype(np.float32)
+        f32 = norm or name in SSM_F32 + ("router_bias", "conv_taps", "wg")
         params[name] = jnp.asarray(x, dtype=jnp.float32 if f32 else cfg.dtype)
     return params
 
@@ -227,6 +244,8 @@ def quantize_matmuls(params: Params, cfg: ModelConfig,
             [np.asarray(params[k], np.float32) for k in ("wq", "wk", "wv")], axis=-1))
         del out["wq"], out["wk"], out["wv"]
         keys = ["wo", "wcls"]
+        if cfg.has_ssm:
+            keys += ["ssm_in", "ssm_out"]
         if not cfg.is_moe:
             out["w13"] = q40.quantize(np.concatenate(
                 [np.asarray(params[k], np.float32) for k in ("w1", "w3")], axis=-1))
@@ -234,6 +253,8 @@ def quantize_matmuls(params: Params, cfg: ModelConfig,
             keys.append("w2")
     else:
         keys = ["wq", "wk", "wv", "wo", "wcls"]
+        if cfg.has_ssm:
+            keys += ["ssm_in", "ssm_out"]
         if not cfg.is_moe:
             keys += ["w1", "w2", "w3"]
     if cfg.is_moe:
@@ -364,6 +385,13 @@ def _read_params(mf: mfile.MFile, cfg: ModelConfig, dtype,
             p[key] = _stack(mf, [f"layers.{i}.{key}" for i in range(L)], False, np.float32)
     if cfg.retention_degree:  # the gate stays float32, whatever the weights' type
         p["wg"] = _stack(mf, [f"layers.{i}.wg" for i in range(L)], True, np.float32)
+    if cfg.has_ssm:
+        st = _Stacks(mf, p, np_dtype, codec if quant else None)
+        st.mats(("ssm_in", "ssm_out"), range(L))
+        st.vecs(SSM_F32[1:], range(L))
+        p["ssm_dt"] = _stack(mf, [f"layers.{i}.ssm_dt" for i in range(L)], True,
+                             np.float32)
+        p["ssm_conv_w"] = p["ssm_conv_w"].reshape(L, cfg.ssm_channels, cfg.ssm_conv)
     if cfg.is_moe:
         p["router"] = _stack(mf, [f"layers.{i}.moe_router" for i in range(L)], True, np_dtype)
         if quant:

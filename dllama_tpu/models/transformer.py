@@ -20,6 +20,9 @@ program:
 * Ouro    — Llama's dense block with the same post-sub-block rmsnorms, and
             the whole layer scan entered ``cfg.n_loops`` times over its own
             output (a looped model): one cache plane a (pass, layer)
+* Falcon-H1 — attention and a Mamba-2 state-space mixer (``ops/ssm.py``) side
+            by side in every block, both on one normed input, both added to
+            the residual; muP multipliers (``cfg.mup_*``) on every branch
 
 Tensor-parallel execution needs no code here: weights arrive sharded
 (parallel/sharding.py) and XLA inserts the all-reduces the reference
@@ -35,7 +38,7 @@ import jax
 import jax.numpy as jnp
 
 from ..obs import dispatch as obs_dispatch
-from ..ops import mla, q40, q8, retention
+from ..ops import conv, mla, q40, q8, retention, ssm
 from ..ops.attention import (gqa_attention_at, paged_gqa_attention_at,
                              paged_update_kv_rows, paged_write_indices,
                              quantize_kv, slot_gqa_attention_at,
@@ -89,6 +92,11 @@ class KVCache(NamedTuple):
     rv: jax.Array | None = None
     rg: jax.Array | None = None
     rw: jax.Array | None = None
+    # a model with a state-space mixer beside attention in every block
+    # (ops/ssm.py) has BOTH: k and v (the contiguous planes, or the pool's
+    # pages) and, under retention's field names at the mixer's sizes, a row's
+    # state rs (L, B, H, N, P), its rings rk (B), rv (x), rg (dt), its watermark
+    # rw, and the convolution's ring cz (L, B, 1, R, C); no rz
 
     @property
     def quantized(self) -> bool:
@@ -120,6 +128,31 @@ SLOT_PLANE_KINDS = {"wk": "window", "wv": "window", "cz": "conv",
 SLOT_PLANES = tuple(SLOT_PLANE_KINDS)
 
 
+def plane_kind(cfg: ModelConfig, name: str) -> str:
+    """The owner of a cache plane by its field name: ``full`` for what a
+    position (or a page id) addresses, else ``SLOT_PLANE_KINDS``'s; a
+    state-space mixer's planes, whatever field they stand in, are ``ssm``."""
+    kind = SLOT_PLANE_KINDS.get(name, "full")
+    return "ssm" if cfg.has_ssm and kind != "full" else kind
+
+
+def _refuse_int8_beside_a_mixer(quant: bool) -> None:
+    if quant:
+        raise ValueError("a state-space mixer's state has no int8 form "
+                         "(--kv-quant int8 is refused for this architecture)")
+
+
+def _with_ssm(cache: KVCache, cfg: ModelConfig, rows: int, dt) -> KVCache:
+    """``cache`` (keys and values) with a state-space mixer's planes for ``rows``
+    rows beside it, where the model has one."""
+    if not cfg.has_ssm:
+        return cache
+    if rows < 1:
+        raise ValueError("a pool beside a state-space mixer needs the number "
+                         "of slots: each owns a state and its rings")
+    return cache._replace(**ssm.init_planes(cfg, rows, dt))
+
+
 def init_kv_cache(cfg: ModelConfig, batch: int, seq_len: int | None = None,
                   dtype=None, quant: bool = False) -> KVCache:
     """Preallocated full-length cache (reference: transformer.cpp:280-282).
@@ -142,13 +175,16 @@ def init_kv_cache(cfg: ModelConfig, batch: int, seq_len: int | None = None,
     if cfg.is_mla:
         return _init_latent((cfg.n_layers, batch, s), cfg, dtype, quant)
     shape = (cfg.n_cache_planes, batch, cfg.n_kv_heads, s, cfg.head_size)
+    if cfg.has_ssm:
+        _refuse_int8_beside_a_mixer(quant)
     if quant:
         sshape = shape[:-1] + (1,)
         return KVCache(jnp.zeros(shape, jnp.int8), jnp.zeros(shape, jnp.int8),
                        jnp.zeros(sshape, jnp.float32),
                        jnp.zeros(sshape, jnp.float32))
     dt = dtype or cfg.dtype
-    return KVCache(jnp.zeros(shape, dt), jnp.zeros(shape, dt))
+    return _with_ssm(KVCache(jnp.zeros(shape, dt), jnp.zeros(shape, dt)), cfg,
+                     batch, dt)
 
 
 # axis order inside one pool page, by name: part of the snapshot and
@@ -214,7 +250,10 @@ def init_kv_pool(cfg: ModelConfig, n_pages: int, page_size: int,
     A model with convolution layers (``cfg.conv_taps``) likewise: ``k`` / ``v``
     its attention layers' pool, ``cz`` its slots' convolution state.  A model
     with no paged layer at all (``cfg.attention_free``) has a pool of no pages:
-    its slots' states and rings alone, as its contiguous cache."""
+    its slots' states and rings alone, as its contiguous cache.  A model with a
+    state-space mixer beside attention in every block (``cfg.has_ssm``) has a
+    pool for all its layers AND, for each of ``slots`` slots, the mixer's state
+    and rings in every layer (``ops/ssm.py``)."""
     if cfg.attention_free:
         return _init_retention(cfg, slots, dtype, quant)
     if cfg.periodic:
@@ -230,13 +269,16 @@ def init_kv_pool(cfg: ModelConfig, n_pages: int, page_size: int,
     # refuses by name.  An arch here with heads under 128 lanes (none of the
     # supported ones on one chip) pays the layout copies ``pool_rows`` names.
     shape = (cfg.n_cache_planes, n_pages, page_size, cfg.n_kv_heads, cfg.head_size)
+    if cfg.has_ssm:
+        _refuse_int8_beside_a_mixer(quant)
     if quant:
         sshape = shape[:-1] + (1,)
         return KVCache(jnp.zeros(shape, jnp.int8), jnp.zeros(shape, jnp.int8),
                        jnp.zeros(sshape, jnp.float32),
                        jnp.zeros(sshape, jnp.float32))
     dt = dtype or cfg.dtype
-    return KVCache(jnp.zeros(shape, dt), jnp.zeros(shape, dt))
+    return _with_ssm(KVCache(jnp.zeros(shape, dt), jnp.zeros(shape, dt)), cfg,
+                     slots, dt)
 
 
 def _mm(x, w, cfg: ModelConfig, kind: str | None = None):
@@ -256,7 +298,7 @@ def update_cache_at(cache: KVCache, k_new, v_new, layer, pos) -> KVCache:
         if not cache.quantized:
             ck, cv = update_kv_cache_at(cache.k, cache.v, k_new, v_new,
                                         layer, pos)
-            return KVCache(ck, cv)
+            return cache._replace(k=ck, v=cv)
         qk, sk = quantize_kv(k_new)
         qv, sv = quantize_kv(v_new)
         zero = jnp.zeros((), layer.dtype)
@@ -268,11 +310,19 @@ def update_cache_at(cache: KVCache, k_new, v_new, layer, pos) -> KVCache:
             jax.lax.dynamic_update_slice(cache.v_scale, sv[None], idx))
 
 
+def _mup(cfg: ModelConfig, name: str):
+    """A muP multiplier of the published config (Falcon-H1) in the activation
+    dtype; every use is guarded by ``!= 1.0``, so no other arch's program has
+    the multiply."""
+    return jnp.asarray(getattr(cfg, "mup_" + name), cfg.dtype)
+
+
 def _project_out(att, lp, cfg: ModelConfig):
     """An attention sub-block's output projection (row-local; col-sharded on
     a tp mesh: partial sums all-reduced here)."""
     with scope("wo"):
-        return _mm(att, lp["wo"], cfg, kind="col")
+        out = _mm(att, lp["wo"], cfg, kind="col")
+        return out if cfg.mup_attn_out == 1.0 else out * _mup(cfg, "attn_out")
 
 
 def _attention_block(x, lp, cfg: ModelConfig, cache: KVCache, cos, sin, pos,
@@ -295,6 +345,8 @@ def _attention_block(x, lp, cfg: ModelConfig, cache: KVCache, cos, sin, pos,
         with scope("norm"):
             xb = rmsnorm(x, lp["rms_att"], cfg.norm_eps)
         with scope("qkv"):
+            if cfg.mup_attn_in != 1.0:
+                xb = xb * _mup(cfg, "attn_in")
             if "wqkv" in lp:  # fused projection (quantized load): one kernel launch
                 qkv = _mm(xb, lp["wqkv"], cfg)
                 q, k, v = jnp.split(qkv, [hq * dh, (hq + hkv) * dh], axis=-1)
@@ -302,6 +354,8 @@ def _attention_block(x, lp, cfg: ModelConfig, cache: KVCache, cos, sin, pos,
                 q = _mm(xb, lp["wq"], cfg, kind="row")
                 k = _mm(xb, lp["wk"], cfg, kind="row")
                 v = _mm(xb, lp["wv"], cfg, kind="row")
+            if cfg.mup_key != 1.0:  # before the rotation and the write
+                k = k * _mup(cfg, "key")
             if cfg.qk_norm:
                 # over the whole projection, before the head split and RoPE; on
                 # a tp mesh q and k are sharded on this axis and the mean is
@@ -354,7 +408,7 @@ def _attention_block(x, lp, cfg: ModelConfig, cache: KVCache, cos, sin, pos,
                 with scope("kv_write"):
                     ck, cv = paged_update_kv_rows(cache.k, cache.v, k, v,
                                                   layer, pidx, oidx)
-                    cache = KVCache(ck, cv)
+                    cache = cache._replace(k=ck, v=cv)
                 with scope("attn"):
                     att = paged_gqa_attention_at(q, cache.k, cache.v, layer,
                                                  page_table, pos_rows)
@@ -362,7 +416,7 @@ def _attention_block(x, lp, cfg: ModelConfig, cache: KVCache, cos, sin, pos,
             with scope("kv_write"):
                 ck, cv = update_kv_cache_rows(cache.k, cache.v, k, v, layer,
                                               pos_rows)
-                cache = KVCache(ck, cv)
+                cache = cache._replace(k=ck, v=cv)
             with scope("attn"):
                 att = slot_gqa_attention_at(q, cache.k, cache.v, layer,
                                             pos_rows)
@@ -377,7 +431,7 @@ def _attention_block(x, lp, cfg: ModelConfig, cache: KVCache, cos, sin, pos,
         with scope("kv_write"):
             ck, cv = sp_update_kv_cache_at(cache.k, cache.v, k, v, layer, pos,
                                            mesh)
-            cache = KVCache(ck, cv)
+            cache = cache._replace(k=ck, v=cv)
     else:
         cache = update_cache_at(cache, k, v, layer, pos)
     with scope("attn"):
@@ -440,6 +494,81 @@ def _retention_block(x, lp, cfg: ModelConfig, cache: KVCache, cos, sin, pos,
                              floor=offsets)
         att = att.transpose(0, 2, 1, 3).reshape(b, t, hq * dh)
     return packing.over(packed, "wo", _project_out, att, lp=lp, cfg=cfg), cache
+
+
+def _ssm_block(x, lp, cfg: ModelConfig, cache: KVCache, pos, layer, marks,
+               offsets=None, pos_rows=None, packed=None, n_real=None):
+    """Falcon-H1's state-space mixer (``ops/ssm.py`` has the operator and why
+    its state lags the clock), the second branch of a block whose first is
+    :func:`_attention_block` over the same cache: it norms ``x`` with the same
+    vector.  The projections, ``dt``, the gate and the grouped norm are
+    row-local and pack; the convolution, the fold, the rings' write and the
+    read are a per-row sequence operation and keep ``(B, T)``.  ``marks``: the
+    call's watermarks, as :func:`_retention_block`'s."""
+    b, t, _ = x.shape
+    h, p, g, n = cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_groups, cfg.ssm_state
+    inner, f32 = cfg.ssm_inner, jnp.float32
+    hi = jax.lax.Precision.HIGHEST
+
+    def project(x):  # row-local: any leading axes
+        with scope("norm"):
+            u = rmsnorm(x, lp["rms_att"], cfg.norm_eps)
+        with scope("qkv"), part("ssm"):
+            u = u * _mup(cfg, "ssm_in")
+            z, xbc = jnp.split(_mm(u, lp["ssm_in"], cfg), [inner], axis=-1)
+            mup = jnp.repeat(jnp.asarray([cfg.mup_x, cfg.mup_b, cfg.mup_c], cfg.dtype),
+                             jnp.asarray([inner, g * n, g * n]),
+                             total_repeat_length=cfg.ssm_channels)
+            dt = jnp.matmul(u.astype(f32), lp["ssm_dt"].astype(f32), precision=hi)
+            dt = jax.nn.softplus(dt * cfg.mup_dt + lp["ssm_dt_bias"])
+            return z * _mup(cfg, "z"), xbc * mup, dt
+
+    def project_out(y, z, lp, cfg):
+        with scope("wo"), part("ssm"):
+            # gate first, then RMSNorm over each group (mamba_norm_before_gate
+            # false), in float32
+            y = (y * jax.nn.silu(z.astype(f32))).reshape(*y.shape[:-1], g, -1)
+            y = y * jax.lax.rsqrt(jnp.mean(y * y, -1, keepdims=True) + cfg.norm_eps)
+            y = y.reshape(*z.shape) * lp["ssm_norm"]
+            return _mm(y.astype(cfg.dtype), lp["ssm_out"], cfg) * _mup(cfg, "ssm_out")
+
+    z, xbc, dt = packing.over(packed, "qkv", project, x)
+    rows = pos_rows if pos_rows is not None else jnp.broadcast_to(pos, (b,))
+    w, w_new = marks
+    a = -jnp.exp(lp["ssm_a_log"])
+    c, taps = cache, cfg.ssm_conv
+    # the convolution's ring is written FIRST and read after: a call's rows do
+    # not reach the taps - 1 positions before it (ring >= T + taps - 1), and a
+    # read that precedes the writes makes XLA copy the whole plane a layer
+    # (twice 377 MB at the published widths; tests/test_tpu_compile.py)
+    with scope("kv_write"), part("conv"):
+        cz = conv.state_write(c.cz, xbc, layer, rows, taps, n_real)
+    with scope("attn"), part("conv"):
+        conv.record(t, cz.shape[3], taps)
+        carried = conv.state_read(cz, layer, rows, taps, floor=offsets)
+        xbc_c = jax.nn.silu(conv.taps(xbc, carried, lp["ssm_conv_w"], rows,
+                                      floor=offsets) + lp["ssm_conv_b"]
+                            ).astype(cfg.dtype)
+        xs, bm, cm = (v.reshape(b, t, k, -1).transpose(0, 2, 1, 3) for v, k in zip(
+            jnp.split(xbc_c, [inner, inner + g * n], axis=-1), (h, g, g)))
+    with scope("kv_write"):
+        with part("fold"):
+            # the fold reads the rings as the last call left them; nothing else
+            # orders it before this call's writes, and without the barrier XLA
+            # kept the old x ring for it: the whole plane copied twice a layer
+            # in the mixed step (tests/test_tpu_compile.py)
+            rs, rk, rv, rg = jax.lax.optimization_barrier((ssm.fold(
+                c.rs, c.rk, c.rv, c.rg, a, layer, w, w_new), c.rk, c.rv, c.rg))
+        with part("recent"):
+            rk, rv, rg = ssm.write(rk, rv, rg, bm, xs, ssm.live_dt(
+                dt, rows, offsets, n_real), layer, rows)
+        cache = c._replace(rs=rs, rk=rk, rv=rv, rg=rg, cz=cz)
+    with scope("attn"):
+        y = ssm.read(cm, rs, rk, rv, rg, a, layer, rows, w_new)   # (B, H, T, P)
+        with part("recent"):
+            y = y + lp["ssm_d"][None, :, None, None] * xs.astype(f32)
+            y = y.transpose(0, 2, 1, 3).reshape(b, t, inner)
+    return packing.over(packed, "wo", project_out, y, z, lp=lp, cfg=cfg), cache
 
 
 def _attend(q, k, v, cache: KVCache, cfg: ModelConfig, pos, t, layer, offsets,
@@ -535,14 +664,20 @@ def _dense_ffn(xb, lp, cfg: ModelConfig):
         with scope("w13"):
             h13 = _mm(xb, lp["w13"], cfg)
             h1, h3 = jnp.split(h13, 2, axis=-1)
+            if cfg.mup_gate != 1.0:
+                h1 = h1 * _mup(cfg, "gate")
             h = act(h1) * h3
     else:
         with scope("w1"):
-            h1 = act(_mm(xb, lp["w1"], cfg, kind="row"))
+            h1 = _mm(xb, lp["w1"], cfg, kind="row")
+            if cfg.mup_gate != 1.0:
+                h1 = h1 * _mup(cfg, "gate")
+            h1 = act(h1)
         with scope("w3"):
             h = h1 * _mm(xb, lp["w3"], cfg, kind="row")
     with scope("w2"):
-        return _mm(h, lp["w2"], cfg, kind="col")
+        out = _mm(h, lp["w2"], cfg, kind="col")
+        return out if cfg.mup_down == 1.0 else out * _mup(cfg, "down")
 
 
 def moe_ffn(xb2d: jax.Array, lp, cfg: ModelConfig,
@@ -930,7 +1065,7 @@ def run_blocks(params: Params, cfg: ModelConfig, tokens: jax.Array,
                                     offsets, pos_rows, paged, packed, n_real)
 
     marks = None
-    if cfg.retention_degree:  # the watermarks of this call, once for all layers
+    if cfg.folds_state:  # the watermarks of this call, once for all layers
         with scope("page_idx"):
             marks = retention.clock(
                 cache.rw, pos_rows if pos_rows is not None
@@ -964,7 +1099,7 @@ def run_blocks(params: Params, cfg: ModelConfig, tokens: jax.Array,
             lp[k] = q40.QLayerView(params[k], idx)
         # the weight set is ``idx``; the cache plane is the pass's own
         plane = idx + first_plane if loops > 1 else idx
-        if marks is not None:
+        if cfg.retention_degree:
             att_out, kvc = _retention_block(x, lp, cfg, kvc, cos, sin, pos,
                                             idx, marks, offsets=offsets,
                                             pos_rows=pos_rows, packed=packed)
@@ -973,6 +1108,11 @@ def run_blocks(params: Params, cfg: ModelConfig, tokens: jax.Array,
                                             plane, offsets=offsets,
                                             pos_rows=pos_rows, paged=paged,
                                             packed=packed)
+        if cfg.has_ssm:  # the second mixer reads the same normed input
+            ssm_out, kvc = _ssm_block(x, lp, cfg, kvc, pos, idx, marks,
+                                      offsets=offsets, pos_rows=pos_rows,
+                                      packed=packed, n_real=n_real)
+            att_out = att_out + ssm_out
         att_out = closed(att_out, lp.get("rms_ffn"))  # grokRmfFfnNorm
         with scope("wo"):
             x = x + att_out

@@ -242,7 +242,8 @@ class CostModel:
                  fused: bool = False, n_dense_layers: int = 0,
                  moe_hidden_dim: int = 0, n_shared_experts: int = 0,
                  mla: dict | None = None, head_dim: int = 0,
-                 window: int = 0, window_period: int = 0, n_loops: int = 1):
+                 window: int = 0, window_period: int = 0, n_loops: int = 1,
+                 ssm: dict | None = None):
         self.dim = dim
         self.hidden_dim = hidden_dim
         #: a looped model runs its ``n_layers`` weight sets ``n_loops`` times a
@@ -301,6 +302,19 @@ class CostModel:
                     + r * n_heads * (dn + dv) + n_heads * dv * dim)
             self.kv_values = r + dr
             self.pair_flops = 2 * n_heads * (2 * r + dr)
+        #: a state-space mixer beside attention in every block (Falcon-H1):
+        #: ``ssm`` holds heads, head_dim, state, groups and ring (the recent
+        #: positions a row keeps out of its state).  Its two projections join
+        #: the matmuls; a row's forward pass reads its state matrix once a
+        #: layer, ``heads * state * head_dim`` float32, and its rings, whatever
+        #: the context's depth (``state_read_bytes``), and computes ``C^T S``
+        #: and the ring's attention form (``state_flops``): depth-free, where
+        #: the same block's keys and values are not
+        self.ssm = ssm
+        if ssm:
+            inner = ssm["heads"] * ssm["head_dim"]
+            bc = 2 * ssm["groups"] * ssm["state"]
+            attn += dim * (2 * inner + bc + ssm["heads"]) + inner * dim
         #: matmul weights touched per token (logits head separate)
         self.params_per_token = n_layers * (attn + ffn)
         if n_dense_layers and self.moe:
@@ -382,6 +396,28 @@ class CostModel:
                 pos, n_new, burst, self.window)
         return positions * self.kv_pos_bytes()
 
+    def state_read_bytes(self, passes: int) -> int:
+        """Bytes ``passes`` forward passes of one row read of a state-space
+        mixer's state and rings, all layers (0: the model has none)."""
+        z = self.ssm
+        if not z:
+            return 0
+        state = z["heads"] * z["state"] * z["head_dim"] * 4
+        ring = z["ring"] * ((z["heads"] * z["head_dim"] + z["groups"]
+                             * z["state"]) * self.kv_el_bytes + z["heads"] * 4)
+        return passes * self.n_layers * (state + ring)
+
+    def state_flops(self, n_new: int) -> int:
+        """Multiply-adds x 2 of the same for ``n_new`` query tokens: each
+        head's ``C`` against its state, and its scores and values over the
+        ring."""
+        z = self.ssm
+        if not z:
+            return 0
+        per_head = z["state"] * z["head_dim"] + z["ring"] * (
+            z["state"] + z["head_dim"])
+        return 2 * n_new * self.n_layers * z["heads"] * per_head
+
     def ring_bytes(self, tokens: int) -> int:
         """Aggregate TP ring all-reduce hop bytes: two f32 reduces of
         ``dim`` per layer per token, ``2*(tp-1)`` hop copies per
@@ -397,12 +433,13 @@ class CostModel:
         per pass and split at dispatch level)."""
         burst = phase == "decode"
         n_logits = 1 if phase == "prefill" else n_new
-        flops = (self.matmul_flops(n_new) + self.logit_flops(n_logits)
-                 + self.attn_flops(pos, n_new))
+        attn = self.attn_flops(pos, n_new) + self.state_flops(n_new)
+        flops = self.matmul_flops(n_new) + self.logit_flops(n_logits) + attn
         kv = (self.kv_write_bytes(n_new)
-              + self.kv_read_bytes(pos, n_new, burst))
+              + self.kv_read_bytes(pos, n_new, burst)
+              + self.state_read_bytes(n_new if burst else 1))
         return {"phase": phase, "flops": flops, "kv_bytes": kv,
-                "attn_flops": self.attn_flops(pos, n_new),
+                "attn_flops": attn,
                 "ring_bytes": self.ring_bytes(n_new)}
 
     def attn_path(self, phase: str) -> str:
@@ -532,7 +569,10 @@ def model_from_engine(engine) -> CostModel | None:
                      qk_rope_head_dim=cfg.qk_rope_head_dim,
                      v_head_dim=cfg.v_head_dim) if cfg.is_mla else None,
             head_dim=cfg.head_dim, window=cfg.window,
-            window_period=cfg.window_period, n_loops=cfg.n_loops)
+            window_period=cfg.window_period, n_loops=cfg.n_loops,
+            ssm=dict(heads=cfg.ssm_heads, head_dim=cfg.ssm_head_dim,
+                     state=cfg.ssm_state, groups=cfg.ssm_groups,
+                     ring=engine.cache.rk.shape[3]) if cfg.has_ssm else None)
     except Exception:
         return None
 

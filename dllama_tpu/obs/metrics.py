@@ -835,7 +835,7 @@ MODEL_LOOP_PASSES = REGISTRY.gauge(
 KV_CACHE_BYTES = REGISTRY.labeled_gauge(
     "kv_cache_bytes", "kind",
     "Resident bytes of the KV cache by kind of plane (full | window | conv | "
-    "retention).")
+    "retention | ssm).")
 # the one-stream engine's account of its convolution state ring (runtime/
 # engine.py Engine._state_enter): a call that starts below the highest position
 # written is a rewind; "in_ring": the rows before it were still held;
@@ -860,6 +860,17 @@ RETENTION_REWINDS = REGISTRY.labeled_counter(
     "retention_rewinds", "outcome",
     "Rewinds of the position clock over a retention state, by outcome "
     "(in_ring | refused).")
+
+# a state-space mixer's state lags the clock by the same rule (ops/ssm.py), with
+# counters of its own: a model that has one has pages too, and no retention
+SSM_FOLDS = REGISTRY.counter(
+    "ssm_folds",
+    "Blocks folded from a state-space mixer's rings of recent positions into "
+    "its state matrix, a row a layer.")
+SSM_STATE_REWINDS = REGISTRY.labeled_counter(
+    "ssm_state_rewinds", "outcome",
+    "Rewinds of the position clock over a state-space mixer's state, by "
+    "outcome (in_ring | refused).")
 
 # scheduler goodput accounting (runtime/scheduler.py + obs/flight.py):
 # every millisecond between the scheduler's first and last dispatch lands

@@ -120,11 +120,11 @@ def state_write(cz: jax.Array, z: jax.Array, layer: jax.Array, pos: jax.Array,
     return window.ring_write_plane(cz, z[:, None], layer, pos)
 
 
-def taps_and_gate(z: jax.Array, carried: jax.Array, c: jax.Array,
-                  w: jax.Array, pos: jax.Array,
-                  floor: jax.Array | None = None) -> jax.Array:
-    """``y (B, T, D)`` from the call's ``z``, the ``carried (B, K - 1, D)`` rows
-    before it, the gate ``c`` and the taps ``w (D, K)``, summed in float32."""
+def taps(z: jax.Array, carried: jax.Array, w: jax.Array, pos: jax.Array,
+         floor: jax.Array | None = None) -> jax.Array:
+    """The depthwise causal convolution ``(B, T, D)`` float32 of the call's
+    ``z`` after the ``carried (B, K - 1, D)`` rows before it under the taps ``w
+    (D, K)``."""
     t, k = z.shape[1], w.shape[-1]
     if floor is not None:  # a ragged row's padding is before its sequence
         at = pos[:, None] + jnp.arange(t)[None, :]
@@ -136,7 +136,16 @@ def taps_and_gate(z: jax.Array, carried: jax.Array, c: jax.Array,
     acc = ext[:, 0:t] * wf[:, 0]
     for j in range(1, k):
         acc = acc + ext[:, j:j + t] * wf[:, j]
-    return (c.astype(jnp.float32) * acc).astype(z.dtype)
+    return acc
+
+
+def taps_and_gate(z: jax.Array, carried: jax.Array, c: jax.Array,
+                  w: jax.Array, pos: jax.Array,
+                  floor: jax.Array | None = None) -> jax.Array:
+    """``y (B, T, D)`` from the call's ``z``, the ``carried (B, K - 1, D)`` rows
+    before it, the gate ``c`` and the taps ``w (D, K)``, summed in float32."""
+    return (c.astype(jnp.float32) * taps(z, carried, w, pos, floor)
+            ).astype(z.dtype)
 
 
 def record(t: int, ring: int, taps: int) -> None:

@@ -27,7 +27,12 @@ reader of the part reads the operator alone; a retention layer (Brumby,
 ``ops/retention.py``) is Llama's block with, inside ``qkv``, the gate's
 ``retention``, inside ``kv_write`` the ring's ``recent`` and the state's ``fold``
 (not ``absorb``, which is MLA's), and inside ``attn`` the state's read ``state``
-and the ring's rows ``recent``;
+and the ring's rows ``recent``; a state-space mixer (Falcon-H1, ``ops/ssm.py``)
+stands BESIDE attention in one block, so attention's own ops keep the bare
+scopes and the mixer's carry ``ssm`` inside ``qkv`` (``W_in``, the ``dt``
+projection, softplus) and ``wo`` (the gate, the grouped norm, ``W_out``), and
+inside ``attn`` / ``kv_write`` the names above where the meaning is the same
+(``conv``, ``state``, ``recent``, ``fold``);
 inside ``norm`` the norms that close a branch before the residual add (Grok-1's
 and Ouro's sandwich norms) are ``post``, the others keep the bare scope
 (``PARTS``, by scope): plain sub-names, not scopes.  An
@@ -74,6 +79,7 @@ PARTS = {
         "qk_norm",   # K-EXAONE: the RMSNorm of each head of q and of k
         "conv",      # a short-convolution layer's W_in and its B * X gate
         "retention", # a retention layer's gate: W_g and its logsigmoid
+        "ssm",       # a state-space mixer's W_in, dt projection and softplus
     ),
     "kv_write": (
         "conv",      # a short-convolution layer's state write (the ring of z)
@@ -92,6 +98,7 @@ PARTS = {
     ),
     "wo": (
         "conv",      # a short-convolution layer's W_out
+        "ssm",       # a state-space mixer's gate, grouped norm and W_out
     ),
     "moe": (
         "router",    # router logits, softmax, (groups,) top-k, the dense weight table

@@ -1,0 +1,202 @@
+"""Mamba-2's selective state-space mixer ("SSD"; Falcon-H1's second sequence
+mixer, beside grouped-query attention in every block) and its state: a matrix a
+head that no position addresses, kept a block behind the position clock by
+``ops/retention.py``'s watermark, beside a position-addressed ring of the
+convolution's input as ``ops/conv.py``'s.
+
+The operator, for the block's normed input ``u`` (``H`` heads of ``P`` values,
+``G`` groups of ``N`` state rows, ``K`` taps; head ``h`` reads group ``g(h) = h
+// (H / G)``; ``models/transformer.py _ssm_block`` makes ``z``, ``xBC`` and
+``dt`` with their multipliers)::
+
+    xBC_t[c] = silu(sum_{j<K} w[c, j] * xBC_{t-(K-1)+j}[c] + b[c])    zero before the start
+    [x | B | C] = xBC        dt_t^h = softplus(dt_t^h + dt_bias^h)     A^h = -exp(A_log^h)
+    S_t^h = exp(dt_t^h A^h) S_{t-1}^h + dt_t^h B_t^g (x_t^h)^T         (N x P, float32)
+    y_t^h = (C_t^g)^T S_t^h + D^h x_t^h
+
+In the attention form: ``y_t^h = sum_{j<=t} exp(sum_{i=j+1..t} dt_i^h A^h) (C_t^g
+. B_j^g) dt_j^h x_j^h + D^h x_t^h``: linear attention with a scalar decay a
+head, queries ``C``, keys ``B``, values ``dt * x``.  That is retention's
+operator with ``phi`` the identity, no quotient and the gate ``dt * A``, so the
+state is kept rewindable the same way and by the same rule, which this module
+imports and does not restate: the state ``rs (L, rows, H, N, P)`` float32 holds
+the tokens ``[0, w)``; rings of ``retention.RING`` recent positions hold ``B``
+(``rk (L, rows, G, RING, N)``), ``x`` (``rv (L, rows, H, RING, P)``) and ``dt``
+(``rg (L, rows, 1, RING, H)`` float32) of ``[w, pos]``; ``rw`` is the watermark
+``w``; ``retention.clock`` / ``watermark`` say when ``FOLD`` positions leave the
+ring for the state, ``REWIND`` behind the clock, so a rewind within ``REWIND``
+finds everything addressed by position and a deeper one is refused by name
+(``Engine._state_enter``).  ``dt`` is stored and not ``dt * x`` and ``dt * A``:
+the ring's bytes are the same, the products are made in float32 on the read,
+and **a row whose ``dt`` is 0 neither decays nor feeds the state**, so a row
+that holds no token (a ragged batch's left padding, a call's rows past its
+``n_real``) is exact by masking its ``dt`` at the write and nothing downstream
+knows of padding.  The convolution's input ``xBC`` (after the multipliers,
+before the taps) is in ``cz (L, rows, 1, conv.RING, C)``, written and read with
+``ops/conv.py``'s ring rule.
+
+A call of ``t <= retention.MAX_ROWS`` rows (1) folds, (2) writes its rows into
+the rings, (3) reads: ``C^T S`` decayed from ``w`` to each query plus the
+attention form over the ring's rows in ``[w, query]``.  The state is read once
+a call and written once a fold.  **Three things the compile for the chip
+taught** (``tests/test_tpu_compile.py`` holds them at the published widths): the
+read takes a layer's slice of ``rs`` and of ``rv`` AS IT LIES, heads flat and
+``x`` in slot order (the small ``C`` is repeated to the heads and the small
+weights are rolled to the slots), because a view by groups or a roll of ``x``
+between the slice and the product made XLA copy the slice out first (134 MB a
+layer, more than twice the product's own time on the chip); the caller orders
+the fold before the ring writes with a barrier and writes the convolution's
+ring before it reads it, because XLA otherwise kept the old plane alive beside
+the new one (the whole ``rv`` or ``cz`` plane copied twice a layer in the mixed
+step).
+
+Ledger: ``{codec="ssm", path="state-read"|"block"|"fold"}`` one a compiled call
+site.  Device time: part ``ssm`` of scopes ``qkv`` (``W_in``, the ``dt``
+projection, softplus) and ``wo`` (the gate, the grouped norm, ``W_out``), parts
+``conv`` / ``state`` / ``recent`` of ``attn`` and ``conv`` / ``recent`` /
+``fold`` of ``kv_write`` (``ops/scopes.py``): attention's own ops in the same
+block keep the bare scopes.
+"""
+
+from __future__ import annotations
+
+import jax
+import jax.numpy as jnp
+
+from ..obs import dispatch as obs_dispatch
+from . import conv, window
+from .retention import FOLD, RING, _in_order
+from .scopes import part
+
+_HI = jax.lax.Precision.HIGHEST
+
+
+def init_planes(cfg, rows: int, dt) -> dict:
+    """The mixer's planes of ``rows`` rows (module docstring), by field of
+    ``KVCache``."""
+    L, h, g = cfg.n_layers, cfg.ssm_heads, cfg.ssm_groups
+    return {
+        "rs": jnp.zeros((L, rows, h, cfg.ssm_state, cfg.ssm_head_dim), jnp.float32),
+        "rk": jnp.zeros((L, rows, g, RING, cfg.ssm_state), dt),
+        "rv": jnp.zeros((L, rows, h, RING, cfg.ssm_head_dim), dt),
+        "rg": jnp.zeros((L, rows, 1, RING, h), jnp.float32),
+        "rw": jnp.zeros((1, rows, 1, 1, 1), jnp.int32),
+        "cz": jnp.zeros((L, rows, 1, conv.RING, cfg.ssm_channels), dt),
+    }
+
+
+def live_dt(dt, pos, floor=None, n_real=None):
+    """``dt (B, T, H)`` with 0 in the rows that hold no token: past the call's
+    ``n_real`` rows, before a ragged row's ``floor``."""
+    t = dt.shape[1]
+    j = jnp.arange(t)[None, :]
+    live = jnp.ones((1, t), bool)
+    if n_real is not None:
+        live = live & (j < jnp.reshape(n_real, (-1, 1)))
+    if floor is not None:
+        live = live & (pos[:, None] + j >= floor[:, None])
+    return jnp.where(live[..., None], dt, 0.0)
+
+
+def fold(rs, rk, rv, rg, a, layer, w, w_new):
+    """Fold positions ``[w, w + FOLD)`` of every row whose watermark moves from
+    the rings into the state at ``layer``: ``S <- Gamma S + sum_j d_j dt_j B_j
+    x_j^T`` with ``d_j`` the decay from ``j`` to the block's end and ``Gamma``
+    the block's whole decay; ``a (H,)`` is the layer's ``A``.  A loop over the
+    rows that fold (none in most steps), each touching its own ``(H, N, P)`` of
+    the plane in place."""
+    h, n, p = rs.shape[2:]
+    g = rk.shape[2]
+    obs_dispatch.record_dispatch("ssm", "fold", t=FOLD, ring=RING, N=n)
+    need = w_new > w
+    order = jnp.argsort(jnp.logical_not(need), stable=True).astype(jnp.int32)
+    count = jnp.sum(need.astype(jnp.int32))
+    zero = jnp.zeros((), jnp.int32)
+    li = layer.astype(jnp.int32)
+
+    def one(carry):
+        i, rs = carry
+        row = order[i]
+        at = w[row]
+        s0 = at % RING
+
+        def held(ring):
+            return jax.lax.dynamic_slice(
+                ring, (li, row, zero, s0, zero),
+                (1, 1, ring.shape[2], FOLD, ring.shape[4]))[0, 0]
+
+        bf = held(rk).astype(jnp.float32)                       # (G, FOLD, N)
+        xf = held(rv).astype(jnp.float32)                       # (H, FOLD, P)
+        dt = held(rg)[0].T                                      # (H, FOLD)
+        la = dt * a[:, None]
+        total = jnp.sum(la, axis=-1)                            # (H,)
+        coef = jnp.exp(total[:, None] - jnp.cumsum(la, axis=-1)) * dt
+        s_add = jnp.einsum("gmjn,gmjp->gmnp", bf[:, None] * coef.reshape(
+            g, h // g, FOLD, 1), xf.reshape(g, h // g, FOLD, p), precision=_HI)
+        keep = jnp.where(at > 0, jnp.exp(total), 0.0)           # (H,)
+        s_old = jax.lax.dynamic_slice(rs, (li, row, zero, zero, zero),
+                                      (1, 1, h, n, p))
+        return i + 1, jax.lax.dynamic_update_slice(
+            rs, s_old * keep[None, None, :, None, None]
+            + s_add.reshape(1, 1, h, n, p), (li, row, zero, zero, zero))
+
+    return jax.lax.while_loop(lambda c: c[0] < count, one, (zero, rs))[1]
+
+
+def write(rk, rv, rg, b, x, dt, layer, pos):
+    """A call's ``b (B, G, T, N)``, ``x (B, H, T, P)`` and ``dt (B, T, H)`` into
+    the rings at ``layer``, row ``r`` at positions ``pos[r] .. pos[r] + T - 1``."""
+    rk = window.ring_write_plane(rk, b, layer, pos)
+    rv = window.ring_write_plane(rv, x, layer, pos)
+    rg = window.ring_write_plane(rg, dt[:, None], layer, pos)
+    return rk, rv, rg
+
+
+def read(c, rs, rk, rv, rg, a, layer, pos, base):
+    """``y (B, H, T, P)`` float32 (without the ``D x`` term) for the queries ``c
+    (B, G, T, N)`` at positions ``pos[b] + t`` over a row's state (tokens ``[0,
+    base)``) and its rings (``[base, query]``; the call's own rows are already
+    written)."""
+    b, g, t, n = c.shape
+    h, p = rs.shape[2], rs.shape[4]
+    m = h // g
+    obs_dispatch.record_dispatch(
+        "ssm", "state-read" if t == 1 else "block", t=t, ring=RING, N=n)
+    li = layer.astype(jnp.int32)
+    cf = c.astype(jnp.float32)
+    swap = base % RING != 0
+    at = pos[:, None] + jnp.arange(t)[None, :] - base[:, None]     # (B, T) in ring order
+    idx = jnp.arange(RING)
+    with part("recent"):
+        # B and dt in position order from the watermark (small planes: rolled)
+        br = _in_order(jax.lax.dynamic_index_in_dim(rk, li, 0, False), swap)
+        dt = _in_order(jax.lax.dynamic_index_in_dim(rg, li, 0, False), swap
+                       )[:, 0].transpose(0, 2, 1)                  # (B, H, RING)
+        live = idx[None, :] <= at[:, -1:]                          # (B, RING)
+        seen = idx[None, None, :] <= at[:, :, None]                # (B, T, RING)
+        dt = jnp.where(live[:, None, :], dt, 0.0)
+        cs = jnp.cumsum(dt * a[None, :, None], axis=-1)
+        gq = jnp.take_along_axis(cs, jnp.broadcast_to(
+            at[:, None, :], (b, h, t)), axis=-1)                   # (B, H, T)
+        cb = jnp.einsum("bgtn,bgcn->bgtc", cf, br.astype(jnp.float32),
+                        precision=_HI)                             # (B, G, T, RING)
+        decay = jnp.exp(jnp.where(seen[:, None], gq[..., None]
+                                  - cs[:, :, None, :], -jnp.inf))  # (B, H, T, RING)
+        w = decay * dt[:, :, None, :] * jnp.repeat(cb, m, axis=1)
+        xr = jax.lax.dynamic_index_in_dim(rv, li, 0, False)        # (B, H, RING, P)
+        # x stays in slot order as it lies and the weights are rolled to meet it
+        # (RING is two FOLDs and the watermark a multiple of FOLD, so the same
+        # swap of halves goes either way): a ring of x is 128 times a row of
+        # weights, and the layer's slice feeds the product uncopied
+        w = _in_order(w[..., None], swap)[..., 0]
+        y = jnp.einsum("bhtc,bhcp->bhtp", w, xr.astype(jnp.float32), precision=_HI)
+    with part("state"):
+        s = jax.lax.dynamic_index_in_dim(rs, li, 0, False)         # (B, H, N, P)
+        since = jnp.where((base > 0)[:, None, None], jnp.exp(gq), 0.0)
+        # a head at a time against its group's C, the state taken as it lies: a
+        # view of it by groups stood between the layer's slice and the product,
+        # and XLA then copied the slice out first (134 MB a layer at the
+        # published widths, twice the product's own time)
+        y = y + since[..., None] * jnp.einsum(
+            "bhtn,bhnp->bhtp", jnp.repeat(cf, m, axis=1), s, precision=_HI)
+    return y

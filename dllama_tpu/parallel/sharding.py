@@ -33,7 +33,7 @@ from ..io import mfile
 from ..models.config import ModelConfig
 from ..obs import memory as obs_memory, metrics as obs_metrics, \
     trace as obs_trace
-from ..models.params import (ATT_KIND_KEYS, CONV_KEYS, DENSE_FFN_KEYS,
+from ..models.params import (ATT_KIND_KEYS, CONV_KEYS, DENSE_FFN_KEYS, SSM_F32,
                              MLA_ATT_KEYS, MOE_FFN_KEYS)
 
 REPL = P()
@@ -71,13 +71,14 @@ def check_tp_constraint(cfg: ModelConfig, tp: int) -> None:
 
 def param_specs(cfg: ModelConfig) -> dict[str, P]:
     """PartitionSpec per parameter (layer-stacked layouts from params.py)."""
-    if cfg.is_mla or cfg.attention_free or cfg.arch in (
+    if cfg.is_mla or cfg.attention_free or cfg.has_ssm or cfg.arch in (
             mfile.ARCH_EXAONE_MOE, mfile.ARCH_LFM2_MOE):
         # one device (the engine refuses a tp / sp / ep mesh for these archs):
         # every stack whole, whatever its fused or unfused name
         return dict.fromkeys(("embedding", "rms_final", "wcls", "rms_att",
                               "rms_ffn", "wg") + MLA_ATT_KEYS
-                             + ATT_KIND_KEYS + CONV_KEYS
+                             + ATT_KIND_KEYS + CONV_KEYS + SSM_F32
+                             + ("ssm_in", "ssm_out")
                              + DENSE_FFN_KEYS + MOE_FFN_KEYS, REPL)
     specs = {
         "embedding": REPL,                   # root-owned in the reference; replicated here

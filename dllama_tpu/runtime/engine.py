@@ -40,7 +40,7 @@ from ..models.config import ModelConfig
 from ..models.params import Params
 from ..models.transformer import (LATENT_PAGE_AXES, PAGE_AXES,
                                   SLOT_PLANE_KINDS, forward_last,
-                                  init_kv_cache)
+                                  init_kv_cache, plane_kind)
 from ..obs import dispatch as obs_dispatch, memory as obs_memory, \
     metrics as obs_metrics, trace as obs_trace
 from ..obs.log import get_logger
@@ -329,18 +329,21 @@ def _loop_fields(cfg: ModelConfig) -> dict:
 # what a slot owns, by the kind of its planes (``Engine.slot_state``)
 _SLOT_STATE = {"window": "window layers' rings",
                "conv": "convolution layers' state",
-               "retention": "retention layers' state"}
+               "retention": "retention layers' state",
+               "ssm": "state-space mixers' state"}
 
 
 class StateRewindTooDeep(ValueError):
     """A call starts so far below the highest position a recurrent state was
     written at that the rows before it are no longer there to resume from: they
     have left a convolution state's ring (``ops/conv.py``), or a retention
-    layer has folded them into its state (``ops/retention.py``).  The caller
+    layer (or a state-space mixer, ``ops/ssm.py``) has folded them into its
+    state (``ops/retention.py``).  The caller
     resets the engine and prefills the conversation again from position 0."""
 
 
-def _note_cache_bytes(cache, tokens: int, batch: int, paged: bool) -> int:
+def _note_cache_bytes(cfg: ModelConfig, cache, tokens: int, batch: int,
+                      paged: bool) -> int:
     """Set the cache's gauges from its own arrays and return what one cached
     token occupies over all layers.  A windowed model's rings hold fewer
     positions than its full planes, so each plane is counted at its own
@@ -350,13 +353,15 @@ def _note_cache_bytes(cache, tokens: int, batch: int, paged: bool) -> int:
     slot's ring of pages is there whatever the context's depth.  A
     convolution state (``kind="conv"``) and a retention layer's state and ring
     (``kind="retention"``) are a fixed size a sequence: a token adds nothing to
-    them on either engine."""
+    them on either engine; so are a state-space mixer's state and rings
+    (``kind="ssm"``), which stand BESIDE ``kind="full"``'s keys and values in
+    the same layers."""
     per_token, by_kind = 0, dict.fromkeys(
-        ("full", *SLOT_PLANE_KINDS.values()), 0)
+        ("full", *SLOT_PLANE_KINDS.values(), "ssm"), 0)
     for name, a in cache.planes().items():
-        kind = SLOT_PLANE_KINDS.get(name, "full")
+        kind = plane_kind(cfg, name)
         by_kind[kind] += int(a.nbytes)
-        if kind in ("conv", "retention") or (paged and kind == "window") \
+        if kind in ("conv", "retention", "ssm") or (paged and kind == "window") \
                 or not a.size:
             continue
         positions = tokens if kind == "full" else batch * a.shape[3]
@@ -450,6 +455,11 @@ class Engine:
                     f"a retention ({mfile.ARCH_NAMES[cfg.arch]}) model keeps "
                     "no keys and values, so it has no pages to count: drop "
                     "--kv-pages (its slots are admitted by --batch-slots alone)")
+        if cfg.has_ssm:
+            _refuse_mesh_and_int8(
+                self.mesh, kv_dtype,
+                f"a state-space ({mfile.ARCH_NAMES[cfg.arch]}) model",
+                "its state a head is replicated with its slot")
         if cfg.n_loops > 1:
             _refuse_mesh_and_int8(
                 self.mesh, kv_dtype,
@@ -553,8 +563,8 @@ class Engine:
         # model's rings at their own positions)
         tokens = (self.kv_pages * self.kv_page_size if self.paged
                   else batch * self.seq_len)
-        self.kv_bytes_per_token = _note_cache_bytes(self.cache, tokens, batch,
-                                                    self.paged)
+        self.kv_bytes_per_token = _note_cache_bytes(cfg, self.cache, tokens,
+                                                    batch, self.paged)
         obs_metrics.MODEL_LOOP_PASSES.set(cfg.n_loops)
         # the account's ``cache`` owner: this engine's planes on each device,
         # beside every other live engine's (a server's chat engine keeps its
@@ -565,10 +575,12 @@ class Engine:
         self.pos = 0
         # the one-stream account of a recurrent state: a call may start at a
         # position whose rows before it lie in [lo, hi) (_state_enter /
-        # _state_wrote)
-        self._state_lo = self._state_hi = 0
-        # a slot engine's mirror of its slots' retention watermarks, for the
-        # fold counter alone (_note_slot_folds)
+        # _state_wrote): a convolution's ring, or where a state lags the clock
+        # (cfg.folds_state) from its watermark on; a state-space mixer has
+        # both, its convolution ring's low in ``_state_ring_lo``
+        self._state_lo = self._state_hi = self._state_ring_lo = 0
+        # a slot engine's mirror of its slots' watermarks, for the fold
+        # counter alone (_note_slot_folds)
         self._slot_marks = np.zeros(batch, np.int64)
 
         def step(params, cache, tokens, pos, last_index, offsets=None):
@@ -638,7 +650,7 @@ class Engine:
         """Restart the sequence (new conversation); cache memory is reused."""
         self.pos = 0
         self._offsets = None
-        self._state_lo = self._state_hi = 0
+        self._state_lo = self._state_hi = self._state_ring_lo = 0
 
     # -- the pos-rewind invariant ---------------------------------------
     # Callers set ``pos`` back and go on: a decode burst that ran past an
@@ -652,7 +664,9 @@ class Engine:
     # ring of ``ops/conv.py RING`` positions, and a retention layer keeps its
     # newest ``ops/retention.py REWIND`` positions in a ring and OUT of its
     # state matrix, which absorbs them a block behind the clock (the bounds
-    # live there, with the rewinds they were sized for).  This engine keeps
+    # live there, with the rewinds they were sized for); a state-space mixer
+    # (``ops/ssm.py``) keeps both, a convolution's ring and a state behind
+    # retention's watermark, and both rules hold for it.  This engine keeps
     # account of the positions a call may start at, ``[lo, hi]`` less what a
     # call reads before its first row.  An arch with another state brings its
     # planes into ``KVCache`` and its two rules here (``_state_reach``,
@@ -670,7 +684,10 @@ class Engine:
         if not self.cfg.keeps_state or self.paged or pos == 0:
             return True
         need = max(pos - self._state_reach(), 0)
-        return self._state_lo <= need and pos <= self._state_hi
+        held = self._state_lo <= need and pos <= self._state_hi
+        if self.cfg.has_ssm:  # and the taps - 1 rows before it in the mixer's ring
+            held = held and self._state_ring_lo <= max(pos - self.cfg.ssm_conv + 1, 0)
+        return held
 
     def resume_at(self, pos: int) -> bool:
         """Set the position clock to ``pos``, a conversation's cached end
@@ -687,7 +704,9 @@ class Engine:
         return False
 
     def _count_rewind(self, held: bool) -> None:
-        if self.cfg.attention_free:
+        if self.cfg.has_ssm:
+            obs_metrics.SSM_STATE_REWINDS.inc("in_ring" if held else "refused")
+        elif self.cfg.attention_free:
             obs_metrics.RETENTION_REWINDS.inc("in_ring" if held else "refused")
         else:
             obs_metrics.CONV_STATE_REWINDS.inc("in_ring" if held else "reprefill")
@@ -709,50 +728,63 @@ class Engine:
                 f"position {pos} is behind what the recurrent state still "
                 f"addresses by position (it holds {self._state_lo}.."
                 f"{self._state_hi}: a convolution's ring, or what a retention "
-                "layer has not yet folded into its state): reset() and prefill "
+                "layer or a state-space mixer has not yet folded into its state"
+                + (f", and its convolution's ring from {self._state_ring_lo}"
+                   if self.cfg.has_ssm else "") + "): reset() and prefill "
                 "the conversation again from position 0")
 
     def _state_wrote(self, pos: int, n_real: int, rows: int) -> None:
         """After a one-stream call of ``rows`` rows at ``pos`` of which the
         first ``n_real`` hold a token: what is still addressed by position.
         A convolution's ring holds what it held, less what the rows written
-        (``ops/conv.py written``) displaced; a retention layer's watermark is
+        (``ops/conv.py written``) displaced; a lagging state's watermark is
         where ``ops/retention.py watermark`` puts it."""
-        if self.cfg.attention_free:
+        if self.cfg.folds_state:
             was = self._state_lo if pos else 0  # position 0 starts a sequence
             lo = retention.watermark(was, pos + n_real)
-            obs_metrics.RETENTION_FOLDS.inc(
-                (lo - was) // retention.FOLD * self.cfg.n_layers)
+            self._count_folds((lo - was) // retention.FOLD)
             self._state_lo, self._state_hi = lo, pos + n_real
-        if not self.cfg.conv_taps:
-            return
-        first, count = conv.written(int(n_real), rows, conv.RING,
-                                    self.cfg.conv_taps)
+        if self.cfg.has_ssm:
+            self._state_ring_lo = self._ring_low(
+                self._state_ring_lo, pos, n_real, rows, self.cfg.ssm_conv)
+        if self.cfg.conv_taps:
+            self._state_lo, self._state_hi = self._ring_low(
+                self._state_lo, pos, n_real, rows, self.cfg.conv_taps), pos + n_real
+
+    @staticmethod
+    def _ring_low(lo: int, pos: int, n_real: int, rows: int, taps: int) -> int:
+        """The lowest position a convolution's ring still holds after a call of
+        ``rows`` rows at ``pos`` (``ops/conv.py written``: which of them it
+        took)."""
+        first, count = conv.written(int(n_real), rows, conv.RING, taps)
         # rows that start inside the call are not joined to what came before
-        lo = pos + first if first else max(min(self._state_lo, pos),
-                                           pos + count - conv.RING)
-        self._state_lo, self._state_hi = max(lo, 0), pos + n_real
+        return max(pos + first if first else max(min(lo, pos),
+                                                 pos + count - conv.RING), 0)
+
+    def _count_folds(self, blocks: int) -> None:
+        """``blocks`` blocks folded into a state in every layer."""
+        folds = (obs_metrics.SSM_FOLDS if self.cfg.has_ssm
+                 else obs_metrics.RETENTION_FOLDS)
+        folds.inc(int(blocks) * self.cfg.n_layers)
 
     def _note_slot_folds(self, pos_rows_np, clock_np) -> None:
-        """A slot dispatch's folds, for ``retention_folds``: the host's mirror
-        of each slot's watermark, moved by the device's own rule to the clock
-        the dispatch leaves."""
-        if not self.cfg.attention_free:
+        """A slot dispatch's folds, for ``retention_folds`` / ``ssm_folds``: the
+        host's mirror of each slot's watermark, moved by the device's own rule
+        to the clock the dispatch leaves."""
+        if not self.cfg.folds_state:
             return
         was = np.where(pos_rows_np == 0, 0, self._slot_marks)
         self._slot_marks = retention.watermark(was, clock_np)
-        obs_metrics.RETENTION_FOLDS.inc(int(np.sum(
-            (self._slot_marks - was) // retention.FOLD)) * self.cfg.n_layers)
+        self._count_folds(np.sum((self._slot_marks - was) // retention.FOLD))
 
     def _max_burst(self, chunk: int) -> int:
         """A decode burst of a model with a recurrent state is capped so that
         the deepest rewind (two pipelined bursts less one position) stays
         addressed by position."""
-        if self.cfg.attention_free:
-            return min(chunk, retention.max_burst())
-        if not self.cfg.conv_taps:
-            return chunk
-        return min(chunk, conv.max_burst(conv.RING, self.cfg.conv_taps))
+        if self.cfg.folds_state:
+            chunk = min(chunk, retention.max_burst())
+        taps = self.cfg.conv_taps or self.cfg.ssm_conv
+        return min(chunk, conv.max_burst(conv.RING, taps)) if taps else chunk
 
     # -- state snapshot/restore (runtime/snapshot.py format) -----------
     def config_fingerprint(self) -> str:
@@ -806,7 +838,8 @@ class Engine:
         meta_extra = dict(extra or {})
         meta_extra.setdefault("sampling_path", self.sampling_path)
         if self.cfg.keeps_state:
-            meta_extra["conv_state"] = [self._state_lo, self._state_hi]
+            meta_extra["conv_state"] = [self._state_lo, self._state_hi,
+                                        self._state_ring_lo]
         if self._offsets is not None:
             arrays["offsets"] = np.asarray(self._offsets)
             meta_extra["has_offsets"] = True
@@ -869,8 +902,8 @@ class Engine:
             **{n: cache_np[f"cache.{n}"] for n in self.cache.planes()})
         self.cache = jax.device_put(cache, self._cache_sh)
         self.pos = pos
-        self._state_lo, self._state_hi = meta.get("extra", {}).get(
-            "conv_state", (0, pos))
+        self._state_lo, self._state_hi, self._state_ring_lo = (
+            list(meta.get("extra", {}).get("conv_state", (0, pos))) + [0])[:3]
         self._chunk_counter = int(meta["chunk_counter"])
         self._key = jnp.asarray(arrays["rng_key"]) if "rng_key" in arrays \
             else jax.random.PRNGKey(0)
@@ -955,12 +988,13 @@ class Engine:
     def slot_state(self) -> str:
         """What a slot engine's slots own that no page id addresses (empty:
         nothing): a windowed model's rings of pages, a convolution model's
-        state, a retention model's state and ring (``KVCache``'s
-        ``SLOT_PLANE_KINDS``).  The scheduler keeps everything that moves a
-        request's cache page by page off while this is set."""
-        kinds = {SLOT_PLANE_KINDS[n] for n in self.cache.planes()
+        state, a retention model's state and ring, a state-space mixer's state
+        and rings beside its attention's pages (``KVCache``'s
+        ``SLOT_PLANE_KINDS``, ``plane_kind``).  The scheduler keeps everything
+        that moves a request's cache page by page off while this is set."""
+        kinds = {plane_kind(self.cfg, n) for n in self.cache.planes()
                  if n in SLOT_PLANE_KINDS}
-        if not kinds or not (self.paged or "retention" in kinds):
+        if not kinds or not (self.paged or kinds & {"retention", "ssm"}):
             return ""
         return _SLOT_STATE[kinds.pop()]
 

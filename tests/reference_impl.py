@@ -640,3 +640,107 @@ def np_forward_ouro(params, cfg, tokens, *, passes=None, wrong=""):
             x = norm(x, np.asarray(params["rms_final"]))     # closes EVERY pass
     x = norm(x, np.asarray(params["rms_final"]))
     return (x @ params["wcls"]).astype(np.float32)
+
+
+# ---- Falcon-H1 (ARCH_FALCON_H1) ---------------------------------------------
+# A hybrid-head model, written from the equations (Zuo et al., "Falcon-H1: A
+# Family of Hybrid-Head Language Models", TII 2025; the published modeling
+# code's names in brackets): the whole sequence, the state-space mixer in its
+# ATTENTION form (a double sum), no state, no ring, no convolution cache, no
+# pages.  Shares nothing with dllama_tpu.models.transformer or ops/ssm.py.
+
+def np_forward_falcon_h1(params, cfg, tokens, wrong=""):
+    """Full-sequence forward, (T, V) float32 logits.  Every block: ONE norm
+    feeds grouped-query attention (rotate-half RoPE, ``key_multiplier`` on k)
+    and the Mamba-2 mixer (``in_proj`` rows ``z | x | B | C | dt`` times
+    ``ssm_multipliers``, a causal depthwise convolution with bias and silu over
+    ``x | B | C``, ``dt = softplus(dt + dt_bias)``, ``A = -exp(A_log)``, ``y_t =
+    sum_{j<=t} exp(sum_{i=j+1..t} dt_i A) (C_t . B_j) dt_j x_j + D x_t``, gated
+    by ``silu(z)`` and THEN RMS-normed group by group), both added to the
+    residual; then a SwiGLU with its two multipliers.
+
+    ``wrong`` names one deliberate fault: ``no_<name>`` for a multiplier of
+    ``cfg.mup_<name>`` set to 1, ``no_decay`` (A = 0), ``no_conv`` (the taps
+    replaced by the identity), ``norm_before_gate``, ``one_group`` (every head
+    reads group 0's B and C), ``no_skip`` (D = 0), ``no_ssm`` / ``no_attn`` (a
+    branch dropped)."""
+    t = len(tokens)
+    hq, hkv, dh = cfg.n_heads, cfg.n_kv_heads, cfg.head_size
+    h, p, g, n, taps = (cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_groups,
+                        cfg.ssm_state, cfg.ssm_conv)
+    inner, f64 = cfg.ssm_inner, np.float64
+    pos = np.arange(t)
+    causal = pos[None, :] <= pos[:, None]
+
+    def mup(name):
+        return 1.0 if wrong == "no_" + name else getattr(cfg, "mup_" + name)
+
+    def norm(x, w):
+        return rmsnorm_eps(x, w, cfg.norm_eps)
+
+    def seg(key, i):
+        return np.asarray(params[key][i], np.float32)
+
+    x = np.asarray(params["embedding"], np.float32)[tokens] * mup("embedding")
+    for li in range(cfg.n_layers):
+        u = norm(x, seg("rms_att", li))                   # [input_layernorm]
+        # -- attention [self_attn]
+        ua = u * mup("attn_in")
+        q = rope_rotate((ua @ seg("wq", li)).reshape(t, hq, dh), pos,
+                        cfg.rope_theta, False)
+        k = rope_rotate((ua @ seg("wk", li) * mup("key")).reshape(t, hkv, dh),
+                        pos, cfg.rope_theta, False)
+        v = (ua @ seg("wv", li)).reshape(t, hkv, dh)
+        att = np.zeros((t, hq, dh), np.float32)
+        for i in range(hq):
+            j = i // (hq // hkv)
+            s = np.where(causal, q[:, i] @ k[:, j].T / np.sqrt(dh), -np.inf)
+            att[:, i] = softmax(s) @ v[:, j]
+        a = att.reshape(t, hq * dh) @ seg("wo", li) * mup("attn_out")
+        # -- the mixer [mamba]
+        us = u * mup("ssm_in")
+        z, xbc = np.split(us @ seg("ssm_in", li), [inner], axis=-1)
+        z = z * mup("z")
+        xbc = xbc * np.repeat([mup("x"), mup("b"), mup("c")],
+                              [inner, g * n, g * n]).astype(np.float32)
+        dt = (us.astype(f64) @ seg("ssm_dt", li).astype(f64)) * mup("dt")
+        dt = np.logaddexp(0.0, dt + seg("ssm_dt_bias", li))           # (T, H)
+        if wrong != "no_conv":
+            w = seg("ssm_conv_w", li)                                  # (C, K)
+            ext = np.concatenate([np.zeros((taps - 1, xbc.shape[1]),
+                                           np.float32), xbc])
+            xbc = sum(ext[j:j + t] * w[:, j] for j in range(taps)) \
+                + seg("ssm_conv_b", li)
+        xbc = silu(xbc)
+        xs, bm, cm = np.split(xbc, [inner, inner + g * n], axis=-1)
+        xs = xs.reshape(t, h, p).astype(f64)
+        bm, cm = bm.reshape(t, g, n).astype(f64), cm.reshape(t, g, n).astype(f64)
+        a_h = -np.exp(seg("ssm_a_log", li).astype(f64))
+        if wrong == "no_decay":
+            a_h = a_h * 0.0
+        cum = np.cumsum(dt * a_h, axis=0)                              # (T, H)
+        d_h = seg("ssm_d", li) * (0.0 if wrong == "no_skip" else 1.0)
+        y = np.zeros((t, h, p), f64)
+        for i in range(h):
+            j = 0 if wrong == "one_group" else i // (h // g)
+            w = np.where(causal, (cm[:, j] @ bm[:, j].T) * np.exp(np.where(
+                causal, cum[:, None, i] - cum[None, :, i], 0.0)), 0.0)
+            y[:, i] = (w * dt[None, :, i]) @ xs[:, i] + d_h[i] * xs[:, i]
+        y = y.reshape(t, inner)
+
+        def grouped(y):  # RMSNorm over each of the g groups [FalconH1RMSNormGated]
+            y = y.reshape(t, g, -1)
+            y = y / np.sqrt(np.mean(y * y, -1, keepdims=True) + cfg.norm_eps)
+            return y.reshape(t, inner)
+
+        gate = silu(z.astype(f64))
+        y = grouped(y) * seg("ssm_norm", li) * gate \
+            if wrong == "norm_before_gate" \
+            else grouped(y * gate) * seg("ssm_norm", li)
+        s = y.astype(np.float32) @ seg("ssm_out", li) * mup("ssm_out")
+        x = x + (0.0 if wrong == "no_attn" else a) + (0.0 if wrong == "no_ssm" else s)
+        f = norm(x, seg("rms_ffn", li))                   # [pre_ff_layernorm]
+        x = x + (silu(f @ seg("w1", li) * mup("gate")) * (f @ seg("w3", li))
+                 ) @ seg("w2", li) * mup("down")
+    x = norm(x, np.asarray(params["rms_final"], np.float32))
+    return (x @ np.asarray(params["wcls"], np.float32) * mup("head")).astype(np.float32)

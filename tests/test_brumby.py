@@ -120,7 +120,7 @@ def _write_model(path, p, cfg=CFG, ftype=quants.F32, **kw):
 def test_arch_id_header_keys_and_round_trip(tmp_path, want):
     assert mfile.ARCH_BRUMBY == 0xABCD08 and mfile.ARCH_NAMES[0xABCD08] == "brumby"
     assert mfile.ARCH_EXT_KEYS[mfile.ARCH_BRUMBY] == (31, 39)
-    assert mfile.KEY_MAX == 40   # since PR 57: key 40, a looped model's `loops`, is the last
+    assert mfile.KEY_MAX == 60   # since PR 60: key 60, Falcon-H1's float rope_theta, is the last
     path = str(tmp_path / "b.m")
     _write_model(path, want["np"])
     spec = mfile.read_spec(path)

@@ -355,3 +355,58 @@ def test_model_from_engine_sniffs_codecs(clean_obs):
     assert cm.tp == 1 and not cm.paged
     # an unmodelable engine degrades to None, never raises
     assert obs_cost.model_from_engine(object()) is None
+
+
+@pytest.mark.parametrize("shape", ["toy", "published"])
+def test_a_state_space_mixer_adds_depth_free_bytes_beside_the_kv(shape, clean_obs):
+    """A mixer beside attention (Falcon-H1): its two projections join the
+    parameters a token, a row's pass reads its state and rings once a layer
+    whatever the context's depth, and the same block's keys and values still grow
+    with it: 36,864 B a position and 75.5 MB of state a slot at the cell's 18
+    blocks."""
+    if shape == "published":
+        kw = dict(dim=5120, hidden_dim=21504, n_layers=18, n_heads=20,
+                  n_kv_heads=4, head_dim=128, vocab_size=261120,
+                  kv_codec="kv_bfloat16", kv_el_bytes=2)
+        ssm = dict(heads=32, head_dim=128, state=256, groups=2, ring=128)
+    else:
+        kw, ssm = {}, dict(heads=4, head_dim=16, state=24, groups=2, ring=128)
+    plain, mixed = tiny_cost_model(**kw), tiny_cost_model(ssm=ssm, **kw)
+    layers, dim = plain.n_layers, plain.dim
+    inner, bc = ssm["heads"] * ssm["head_dim"], 2 * ssm["groups"] * ssm["state"]
+    assert mixed.params_per_token - plain.params_per_token \
+        == layers * (dim * (2 * inner + bc + ssm["heads"]) + inner * dim)
+    state = ssm["heads"] * ssm["state"] * ssm["head_dim"] * 4
+    ring = 128 * ((inner + bc // 2) * mixed.kv_el_bytes + 4 * ssm["heads"])
+    assert mixed.state_read_bytes(1) == layers * (state + ring)
+    assert plain.state_read_bytes(1) == plain.state_flops(5) == 0
+    # depth-free: the same at every position; the KV is not
+    assert mixed.row_cost("decode", 900, 1)["kv_bytes"] \
+        - mixed.row_cost("decode", 9, 1)["kv_bytes"] \
+        == plain.kv_read_bytes(900, 1, True) - plain.kv_read_bytes(9, 1, True) > 0
+    assert mixed.row_cost("decode", 9, 3)["kv_bytes"] \
+        - plain.row_cost("decode", 9, 3)["kv_bytes"] == 3 * layers * (state + ring)
+    assert mixed.row_cost("prefill", 0, 16)["kv_bytes"] \
+        - plain.row_cost("prefill", 0, 16)["kv_bytes"] == layers * (state + ring)
+    assert mixed.kv_write_bytes(1) == plain.kv_write_bytes(1)
+    if shape == "published":
+        assert mixed.kv_write_bytes(1) == 36_864 and layers * state == 75_497_472
+        assert mixed.params_per_token == 18 * (429_916_160 + 32 * 5120)
+        return
+    import jax
+
+    from dllama_tpu.models.config import tiny_falcon_h1
+    from dllama_tpu.models.params import init_params
+    from dllama_tpu.parallel.mesh import make_mesh
+    from dllama_tpu.runtime.engine import Engine
+
+    cfg = tiny_falcon_h1()
+    eng = Engine(cfg, init_params(cfg, seed=4),
+                 mesh=make_mesh(tp=1, devices=jax.devices()[:1]), batch=1)
+    cm = obs_cost.model_from_engine(eng)
+    assert cm.ssm == dict(heads=4, head_dim=16, state=24, groups=2, ring=128)
+    assert cm.kv_write_bytes(1) == eng.kv_bytes_per_token \
+        == obs_metrics.KV_BYTES_PER_TOKEN.json_value() == 3 * 2 * cfg.kv_dim * 4
+    assert cm.state_read_bytes(1) == sum(
+        int(a.nbytes) for n, a in eng.cache.planes().items()
+        if n in ("rs", "rk", "rv", "rg"))

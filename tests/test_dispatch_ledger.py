@@ -582,3 +582,31 @@ def test_fast_tier_collects_core_suites():
     for f in targets:
         n = len(re.findall(re.escape(f) + r"::", r.stdout))
         assert n >= 3, f"fast tier collects only {n} tests from {f}"
+
+
+@pytest.mark.parametrize("toy", ["falcon_h1", "llama"])
+def test_a_state_space_mixer_records_its_three_sites(toy):
+    """``{codec="ssm", path="state-read"|"block"|"fold"}`` one a compiled call
+    site of a model with a mixer beside attention, and its convolution's
+    ``conv/ring``; a model without one records none, and ``ssm_folds`` counts
+    the blocks a prompt folded, a layer."""
+    import jax
+    from dllama_tpu.models.config import tiny_config, tiny_falcon_h1
+    from dllama_tpu.models.params import init_params
+    from dllama_tpu.parallel.mesh import make_mesh
+    from dllama_tpu.runtime.engine import Engine
+    cfg = tiny_falcon_h1() if toy == "falcon_h1" else tiny_config(seq_len=256)
+    obs_dispatch.reset()
+    before = obs_metrics.SSM_FOLDS.json_value()
+    eng = Engine(cfg, init_params(cfg, seed=2),
+                 mesh=make_mesh(tp=1, devices=jax.devices()[:1]), batch=1)
+    eng.prefill(list(range(3, 103)))           # 100 tokens: one block folded
+    eng.decode_one(8)
+    sites = set(obs_dispatch.dispatches())
+    mixer = {"ssm/state-read", "ssm/block", "ssm/fold", "conv/ring"}
+    assert (mixer <= sites) if toy == "falcon_h1" else not (mixer & sites)
+    assert obs_metrics.SSM_FOLDS.json_value() - before == (
+        cfg.n_layers if toy == "falcon_h1" else 0)
+    if toy == "falcon_h1":
+        assert "ssm/state-read" in obs_dispatch.summary_line()
+    obs_dispatch.reset()

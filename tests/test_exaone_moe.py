@@ -494,7 +494,7 @@ def test_engine_scheduler_contiguous_and_paged_serve_the_same_tokens(
     assert eng.cache.k.shape == (2, 49, 4, 2, 8) and eng.cache.wk.shape == (6, 18, 4, 2, 8)
     assert obs_metrics.KV_CACHE_BYTES._values == {
         ("full",): 2 * 49 * 4 * tok, ("window",): 6 * 18 * 4 * tok, ("conv",): 0,
-        ("retention",): 0}
+        ("retention",): 0, ("ssm",): 0}
     assert obs_metrics.KV_BYTES_PER_TOKEN.value == cfg.n_full_layers * tok
     assert sched.prefix_cache is None and not sched.preempt
     # 42 and 60 positions written (the last token out is not fed) on rings of

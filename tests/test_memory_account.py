@@ -388,3 +388,23 @@ def test_the_tool_prints_the_account_from_a_metrics_snapshot(fakes):
     assert "device " in text and " ms" in text
     # a program without the account (or the CPU): dashes, never zeros
     assert "found                 -" in tool.render({})
+
+
+@pytest.mark.parametrize("paged", [False, True], ids=["contiguous", "paged"])
+def test_the_cache_owner_holds_a_mixers_state_beside_keys_and_values(fakes, paged):
+    """A model with a state-space mixer beside attention (Falcon-H1): the
+    account's ``cache`` owner is every plane of the engine, the keys and values
+    AND the slots' states and rings, and ``kv_cache_bytes{kind}`` names the two:
+    ``full`` what a position (or a page) addresses, ``ssm`` what a slot owns."""
+    from dllama_tpu.models.config import tiny_falcon_h1
+    cfg = tiny_falcon_h1(seq_len=128)
+    kw = dict(batch=2, kv_pages=2 * 32 + 1, kv_page_size=PAGE) if paged else {}
+    eng = Engine(cfg, jax.tree.map(np.asarray, init_params(cfg, seed=4)),
+                 mesh=make_mesh(tp=1, devices=jax.devices()[:1]), **kw)
+    acc = account()
+    assert acc["cache"] == plane_bytes(eng)
+    by_kind = obs_metrics.KV_CACHE_BYTES.json_value()
+    planes = eng.cache.planes()
+    assert by_kind["full"] == int(planes["k"].nbytes + planes["v"].nbytes)
+    assert by_kind["ssm"] == plane_bytes(eng) - by_kind["full"] > 0
+    assert by_kind["full"] + by_kind["ssm"] == acc["cache"]

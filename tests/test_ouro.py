@@ -123,7 +123,7 @@ def _write_model(path, p, cfg=CFG, ftype=quants.F32):
 def test_arch_id_header_key_and_round_trip(tmp_path, want):
     assert mfile.ARCH_OURO == 0xABCD09 and mfile.ARCH_NAMES[mfile.ARCH_OURO] == "ouro"
     assert mfile.ARCH_EXT_KEYS[mfile.ARCH_OURO] == (31, 40)
-    assert mfile.KEY_MAX == 40
+    assert mfile.KEY_MAX >= 40
     names = [t.name for t in mfile.tensor_plan(_spec())]
     assert names[-6:-2] == ["layers.2.rms_att", "layers.2.rms_ffn",
                             "layers.2.rms_moe", "layers.2.rms_ffn2"]

@@ -384,7 +384,7 @@ def test_engine_scheduler_contiguous_and_paged_serve_the_same_tokens(
     tok = 2 * cfg.kv_dim * 4
     assert obs_metrics.KV_CACHE_BYTES._values == {
         ("full",): 2 * 96 * tok, ("window",): 6 * 32 * tok, ("conv",): 0,
-        ("retention",): 0}
+        ("retention",): 0, ("ssm",): 0}
     p1, p2 = [5, 9, 2], [int(t) for t in TOKS[:21]]
     wanted = []
     for p in (p1, p2):

@@ -1355,3 +1355,101 @@ def test_a_sixteen_row_q40_launch_keeps_its_one_matmul_and_no_other_op(one_chip,
         if form == "chosen" else set()
     assert {k: v for k, v in ops.items() if k not in plumbing} == \
         {k: v for k, v in SIXTEEN_ROW_BODY_OPS.items() if k not in plumbing}
+
+
+@pytest.mark.parametrize("t", [1, 16], ids=["pure-decode", "mixed"])
+def test_falcon_h1_cell_programs_compile_with_a_state_beside_the_pool(
+        one_chip, monkeypatch, t):
+    """The slot programs of ``falcon-h1-34b.chat-wide`` for the described chip at
+    the published widths, the cell's 18 blocks, 32 slots and 2080 pages: ONE
+    layer of one slot owns pages and a state matrix and rings.  The fused page
+    walk takes five query heads a kv head; the mixer's two projections are
+    launches of their own under the part ``ssm``; the state is made by the
+    fold's in-place update alone, and NO plane of the cache is copied whole (a
+    convolution ring read before it was written was, twice a layer: 27 GB a
+    mixed step; so was the ``x`` ring while nothing ordered the fold before the
+    ring writes: 43 GB) and no layer's slice of the state or of the ``x`` ring
+    is copied out in front of its product (134 MB and 34 MB a layer: the
+    temporaries stay under 0.2 GB); arguments and temporaries stay under the
+    13.5 GB at which two checks ran out of memory."""
+    from dllama_tpu.io import mfile
+    from dllama_tpu.models import transformer as tf
+    from dllama_tpu.models.config import ModelConfig
+    from dllama_tpu.models.params import SSM_F32, param_shapes
+    from dllama_tpu.runtime.decode_loop import slot_chunk
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(jax, "device_count", lambda: 1)
+    layers, b = 18, 32
+    cfg = ModelConfig(
+        arch=mfile.ARCH_FALCON_H1, dim=5120, hidden_dim=21504, n_layers=layers,
+        n_heads=20, n_kv_heads=4, n_experts=0, n_active_experts=0,
+        vocab_size=261120, seq_len=1024, hidden_act=mfile.ACT_SILU,
+        rope_theta=1e11, norm_eps=1e-5, head_dim=128, ssm_heads=32,
+        ssm_head_dim=128, ssm_state=256, ssm_groups=2, ssm_conv=4,
+        mup_embedding=5.657, mup_head=0.0078125, mup_attn_out=0.0375,
+        mup_ssm_in=0.25, mup_ssm_out=0.0884, mup_key=0.011, mup_gate=0.1768,
+        mup_down=0.01116, mup_z=0.3536, mup_x=0.25, mup_b=0.1768, mup_c=0.5,
+        mup_dt=0.3536, dtype=jnp.bfloat16)
+    s = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)  # noqa: E731
+
+    def packed(*shapes):
+        *lead, n, _ = shapes[0]
+        d, np_ = sum(sh[-1] for sh in shapes), q40.padded_n(n)
+        return q40.QTensor(s((*lead, np_ // 2, d), jnp.uint8),
+                           s((*lead, np_ // 32, d), jnp.uint16), (n, d))
+
+    sh = param_shapes(cfg)
+    params = {k: s(sh[k], jnp.float32) for k in sh
+              if k.startswith("rms") or k in SSM_F32}
+    params["embedding"] = s(sh["embedding"], jnp.bfloat16)
+    params.update(wqkv=packed(sh["wq"], sh["wk"], sh["wv"]),
+                  w13=packed(sh["w1"], sh["w3"]),
+                  **{k: packed(sh[k]) for k in ("wo", "w2", "ssm_in", "ssm_out",
+                                                "wcls")})
+    planes = jax.eval_shape(lambda: tf.init_kv_pool(
+        cfg, 2080, 16, slots=b, max_pages=64)).planes()
+    cache = tf.KVCache(**{n: s(a.shape, a.dtype) for n, a in planes.items()})
+    vec = lambda dt: s((b,), dt)  # noqa: E731
+    compiled = jax.jit(
+        lambda p, c, tok, pr, nv, k, tm, tp, tk, pt: slot_chunk(
+            p, cfg, c, tok, pr, nv, k, tm, tp, tk, steps=1, greedy=True,
+            page_table=pt), donate_argnums=(1,)).lower(
+        params, cache, s((b, t), jnp.int32), vec(jnp.int32), vec(jnp.int32),
+        s((2,), jnp.uint32), vec(jnp.float32), vec(jnp.float32),
+        vec(jnp.int32), s((b, 64), jnp.int32)).compile()
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 13.5e9
+    assert mem.temp_size_in_bytes < (0.01e9 if t == 1 else 0.2e9)
+    text = compiled.as_text()
+    kernels = re.findall(r'op_name="[^"]*closed_call/([^"]*)/pallas_call"', text)
+    assert "attn/paged_attn_fused" in kernels
+    launches = {k.replace("cond/branch_0_fun/", "").replace("cond/branch_1_fun/", "")
+                for k in kernels}
+    assert {k for k in launches if "ssm" in k} == {
+        f"{sc}/ssm/jit(_pallas_matmul_stacked)/q40_mm_stacked" if t == 1 else
+        f"{sc}/{sc}/ssm/jit(_pallas_matmul_stacked)/q40_mm_stacked"
+        for sc in ("qkv", "wo")}
+    ops = re.findall(r"^\s*(?:ROOT )?%?([\w.\-]+) = (\S+) ([\w\-]+)\((.*)$",
+                     text, re.M)
+    type_of = {jnp.dtype(jnp.bfloat16): "bf16", jnp.dtype(jnp.float32): "f32",
+               jnp.dtype(jnp.int32): "s32"}
+    # the planes over the chip's 128 MiB of VMEM (a plane that fits, ``rk`` and
+    # ``rg`` here, may be prefetched there whole: POOL_PAGES' comment)
+    whole = {f"{type_of[jnp.dtype(a.dtype)]}[{','.join(map(str, a.shape))}]": n
+             for n, a in planes.items()
+             if a.size * jnp.dtype(a.dtype).itemsize > 128 << 20}
+    assert set(whole.values()) == {"v", "rs", "rv", "cz"}   # k has v's shape
+    made = {}
+    for name, result, op, rest in ops:
+        plane = whole.get(result.split("{")[0])
+        if plane and op not in ("parameter", "get-tuple-element", "bitcast",
+                                "while", "call", "conditional"):
+            path = re.search(r'op_name="([^"]+)"', rest)
+            made.setdefault(plane, []).append((op, path.group(1) if path else ""))
+    assert all(op in ("dynamic-update-slice", "scatter", "fusion")
+               for ops_ in made.values() for op, _ in ops_), made
+    assert {p for _, p in made["rs"]} == {
+        "jit(<lambda>)/while/body/closed_call/kv_write/fold/while/body/"
+        "dynamic_update_slice"}
+    assert all("/kv_write/" in p for ops_ in made.values() for _, p in ops_), made

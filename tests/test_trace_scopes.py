@@ -332,3 +332,31 @@ def test_an_idle_scheduler_goes_quiet():
                     if s["name"] in ("sched.idle", "sched.admit")]
     finally:
         sched.close()
+
+
+@pytest.mark.parametrize("toy", ["falcon_h1", "brumby", "llama"])
+def test_a_mixer_beside_attention_keeps_attentions_scopes_bare(toy):
+    """A state-space mixer stands BESIDE attention in one block (Falcon-H1): its
+    ops carry the part ``ssm`` inside ``qkv`` and ``wo`` and, inside ``attn`` and
+    ``kv_write``, the parts a retention layer and a convolution layer have
+    (``state``, ``recent``, ``fold``, ``conv``); attention's own ops of the same
+    layer keep the bare scopes, so a reader can tell the two mixers apart.  A
+    retention model has the shared parts and no ``ssm``; Llama has none."""
+    from dllama_tpu.models.config import tiny_brumby, tiny_falcon_h1
+    cfg = {"falcon_h1": tiny_falcon_h1(), "brumby": tiny_brumby(),
+           "llama": CFG}[toy]
+    p = init_params(cfg, seed=4)
+    ops = compiled_ops(lambda p, c, tok: tf.forward(p, cfg, tok, c, jnp.int32(3)),
+                       p, tf.init_kv_cache(cfg, 1, 64), jnp.zeros((1, 4), jnp.int32))
+    names = [name for _, name in ops]
+    has = lambda part: any(part in n for n in names)  # noqa: E731
+    assert has("/qkv/ssm/") == has("/wo/ssm/") == has("/attn/conv/") \
+        == (toy == "falcon_h1")
+    assert has("/attn/state/") == has("/kv_write/fold/") == (toy != "llama")
+    assert has("/qkv/retention/") == (toy == "brumby")
+    if toy == "falcon_h1":
+        for scope in ("qkv", "attn", "kv_write", "wo"):
+            own = [n for n in names if scope_of(n) == scope
+                   and not any(f"/{scope}/{part}/" in n for part in (
+                       "ssm", "conv", "state", "recent", "fold"))]
+            assert own, scope                 # attention's own, under the bare scope
