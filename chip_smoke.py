@@ -57,7 +57,7 @@ MOE_MODEL, MOE_LAYERS = "olmoe-1b-7b", 2
 MOE_TOL = 2e-2             # max |pallas - xla| / max |xla| of a layer's moe_ffn
 BUDGET_S = 1150            # the driver allows 1200 s, compilation included
 Q40_TOL = 1e-2             # max |pallas - xla| / max |xla|
-Q40_F32_TOL = 5e-6         # the one-row (grouped) body against x @ dequantize(float32)
+Q40_F32_TOL = 5e-6         # the one-row (grouped) and the sliced body against x @ dequantize(float32)
 ATTN_TOL = 2e-2            # max |fused - gather| / max |gather| (bf16 out)
 TP_LOGIT_TOL = 5e-2        # max |tp4 - tp1| / max |tp1| on first-step logits
 CHILD_MARK = "CHIP_SMOKE "  # prefix of the result lines a child prints
@@ -518,7 +518,7 @@ def child_kernels(rehearse: bool) -> None:
         key, k1, k2, k3 = jax.random.split(key, 4)
         qt = random_q40(k1, k2, (2,) if stacked else (), n, d)
         w = q40.QLayerView(qt, jnp.int32(1)) if stacked else qt
-        for rows in (1, 8, 256):  # 256: the row-blocked form
+        for rows in (1, 8, 16, 256):  # 256: the row-blocked form
             x = jax.random.normal(jax.random.fold_in(k3, rows), (rows, n),
                                   jnp.bfloat16)
             t0 = time.perf_counter()
@@ -528,12 +528,14 @@ def child_kernels(rehearse: bool) -> None:
                   "stacked": stacked, "rel_err": rel_err(got, ref),
                   "tol": Q40_TOL,
                   "seconds": round(time.perf_counter() - t0, 2)})
-            if q40._body(rows) == "grouped":
+            if q40._body(rows, q40._tiles(q40.padded_n(n), d)[0]) != "dot":
                 # one row is contracted a quantization block at a time with no
                 # weight rounded to bf16 (PR 50), the tile's bytes made bf16
                 # as 32-bit words (PR 58: the chip's half of the proof that
                 # pltpu.bitcast sets a word's bytes on rows as the interpreter
-                # does): it is held to the float32 dequantization
+                # does), and 2 to SLICED_MAX_ROWS rows a 128-row slice at a
+                # time by the same algebra (PR 62): both are held to the
+                # float32 dequantization
                 dense = q40.dequantize(w.sliced() if stacked else w)
                 ref32 = jnp.dot(x.astype(jnp.float32), dense,
                                 precision=jax.lax.Precision.HIGHEST)

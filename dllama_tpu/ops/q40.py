@@ -34,19 +34,28 @@ Two matmul implementations:
   in the model's own column order (no op stands between the caller's ``x``
   and the launch but a cast and, for a padded ``n``, the zero columns;
   PERF.md §6, PR 41).  How the tile is contracted follows the block's row
-  count, the one fact the kernel observes (:func:`_body`, PR 50): two rows
-  and more are one dot against the tile dequantized to bf16 in logical row
-  order; ONE row (every decoded token of a one-stream
-  program, a row's chosen experts) sends the raw nibbles to the dot a
-  quantization block at a time and scales the block partials, so no weight
-  is biased, scaled or rounded one by one (PR 50: 19% faster a launch at
-  Mistral's ``w13``, 13-15% at a row's chosen experts), and makes them the
-  dot's bf16 operand without extending or converting one: the packed tile is
-  bitcast to 32-bit words and ``(W << 3) & 0x00780078 | 0x41804180`` is one
-  word of two bf16 ``16 + v`` (PR 58: 1.5 integer ops a weight on the tile
-  where there were 3.5-4; the kernel's static schedule a 1024 x 1024 tile
-  1846 -> 1196 bundles, a launch 16% faster at ``w13``, 9-13% at a row's
-  chosen experts; what is left of a launch is the pipeline's DMA and steps).
+  count, the one fact the kernel observes (:func:`_body`, PR 50): ONE row
+  (every decoded token of a one-stream program, a row's chosen experts)
+  sends the raw nibbles to the dot a quantization block at a time and scales
+  the block partials, so no weight is biased, scaled or rounded one by one
+  (PR 50: 19% faster a launch at Mistral's ``w13``, 13-15% at a row's chosen
+  experts), and makes them the dot's bf16 operand without extending or
+  converting one: the packed tile is bitcast to 32-bit words and ``(W << 3) &
+  0x00780078 | 0x41804180`` is one word of two bf16 ``16 + v`` (PR 58: 1.5
+  integer ops a weight on the tile where there were 3.5-4; the kernel's
+  static schedule a 1024 x 1024 tile 1846 -> 1196 bundles, a launch 16%
+  faster at ``w13``, 9-13% at a row's chosen experts; what is left of a
+  launch is the pipeline's DMA and steps).  A block of 2 to
+  ``SLICED_MAX_ROWS`` rows (a served pure-decode step of up to 16 slots, a
+  verify window, a grouped launch's blocks of 16 rows) takes the same words
+  and the same algebra a 128-row slice of the tile at a time (PR 62,
+  :func:`_contract_sliced`: the words' row order keeps four whole
+  quantization blocks in a slice, so a row costs the MXU four rows of left
+  operand a slice where the one-row form over the whole tile would cost it
+  ``tile_n / 32``; bias and scale on ``4 rows x tile_d`` partials a slice).
+  More rows than that are one dot against the tile dequantized to bf16 in
+  logical row order (~5.5 VPU ops a weight: a prompt's chunk, a packed mixed
+  step), the only body that still rounds a weight (ROADMAP D17).
   What bounds the dot body at few rows is the VPU's work a weight on the way
   to the dot, not the MXU's tile loads and not the DMA.  A mixture-of-experts
   layer's E experts, or the k a decoded row chose, are one launch a matmul
@@ -465,9 +474,11 @@ def _record_site(rows: int, np_: int, d: int, kind: str | None, tp: int,
     took (:func:`_body`) and, at one row, how it made the dot's right operand
     (:func:`_nibbles_as`)."""
     tiles = _tiles(*_shard_nd(np_, d, kind, tp))
-    body = _body(rows)
+    body = _body(rows, tiles[0])
     if body == "grouped":
         body += "-" + _nibbles_as(tiles[0])
+    elif body == "sliced":
+        body += "-words"
     obs_dispatch.record_dispatch("q40", "pallas-fused", rows=rows, kind=kind,
                                  tp=tp, stored_n=np_, tiles=tiles, body=body,
                                  **ctx)
@@ -478,17 +489,52 @@ def _record_site(rows: int, np_: int, d: int, kind: str | None, tp: int,
 # Pallas fused kernel
 # ---------------------------------------------------------------------------
 
-def _body(rows: int) -> str:
+# The most rows a block may hold and still be contracted a 128-row slice at a
+# time (:func:`_contract_sliced`).  A row costs the MXU four rows of left
+# operand a slice, so 32 rows are its 128, and there the chip read the form
+# level with the dot body or behind it (PERF.md §6, PR 62: tools/sweep_q40.py
+# --body <35 shapes of the served cells> 8,16,32 sliced,dot; ms a launch, dot →
+# sliced):
+#   rows   K-EXAONE's held experts (gate | down)   Mistral w13      Falcon-H1 w2
+#      2   0.3181 → 0.2339 | 0.3181 → 0.2269       0.1893 → 0.1397
+#      8   0.3181 → 0.2301 | 0.3192 → 0.2244       0.1908 → 0.1367  0.1786 → 0.1257
+#     16   0.3260 → 0.2549 | 0.3268 → 0.2542       0.1941 → 0.1528  0.1818 → 0.1411
+#     32   0.3351 → 0.3386 | 0.3360 → 0.3424       0.1981 → 0.2014  0.1860 → 0.1893
+# −24 to −32% at 2 to 8 rows and −14 to −24% at 16 at every shape of 2 MB and
+# more (−10% at Ouro's 2048 x 2048), +0.2 to +3.6% at 32 at 29 of 33 shapes:
+# the kernel's static schedule says why (2289 bundles a 1024 x 1024 tile
+# against the dot body's 2284: the MXU slots hold the 128 left rows a slice).
+SLICED_MAX_ROWS = 16
+
+
+def _body(rows: int, tile_n: int) -> str:
     """How a weight tile is contracted against a block of ``rows`` activation
-    rows, from the block's shape alone: ``"grouped"`` at one row, else
-    ``"dot"``.  The grouped form's left operand has ``tile_n / 32`` rows an
-    activation row, so it is a one-row form: against the dot body −19% at one
-    row of Mistral's ``w13``, −12% at two, +13% at four, and at a row's six
-    chosen experts −14%, −5%, +22%; no cell runs two to four rows
-    (tools/sweep_q40.py --body ... 1,2,4 dot,grouped; PERF.md §6, PR 50).
-    How the grouped body makes the dot's right operand follows the tile's
-    rows (:func:`_nibbles_as`, PR 58)."""
-    return "grouped" if rows == 1 else "dot"
+    rows, from the block's shape alone: ``"grouped"`` at one row, ``"sliced"``
+    at 2 to SLICED_MAX_ROWS rows of a tile whose rows are whole vregs of 128
+    lanes (every tile the rule gives a model; a toy's shorter tile keeps the
+    dot), else ``"dot"``.  The grouped form's left operand has ``tile_n / 32``
+    rows an activation row over the whole tile, so it is a one-row form
+    (against the dot body -19% at one row of Mistral's ``w13``, -12% at two,
+    +13% at four: PERF.md §6, PR 50); the sliced form's has four a 128-row
+    slice, and its edge is the sweep's (SLICED_MAX_ROWS).  Both make the dot's
+    right operand of the packed tile's words (:func:`_words_bf16`) and pay
+    bias and scale a block partial; the dot body dequantizes every weight."""
+    if rows == 1:
+        return "grouped"
+    if rows <= SLICED_MAX_ROWS and tile_n % 128 == 0:
+        return "sliced"
+    return "dot"
+
+
+def _block_rows(rows: int, tile_n: int) -> int:
+    """The rows of the activation and output blocks and of the accumulator
+    for a launch of ``rows`` rows in one block: the sliced body's are whole
+    sublane groups of eight (the rows past the array's are the block's
+    padding: read as they lie, each alone in its own row of every sum, and
+    never written back)."""
+    if _body(rows, tile_n) == "sliced":
+        return -(-rows // 8) * 8
+    return rows
 
 
 def _partial_rows(tile_n: int) -> int:
@@ -615,6 +661,72 @@ def _contract_grouped(x_ref, qp, s32) -> jax.Array:
     return _block_sums(x_ref, *_nibbles_bf16(qp), None, s32)
 
 
+def _sliced_left(x: jax.Array) -> jax.Array:
+    """The sliced body's left operand ``(tile_n / 128, 4 * rows, 128)``
+    float32 of the block's rows ``x``: for each 128-row slice of
+    :func:`_words_bf16`'s operand, row ``(b, r)`` (blocks major, rows minor)
+    holds activation row ``r`` at the 32 columns of the slice's quantization
+    block ``b`` and zero elsewhere, its columns in the order of that operand's
+    rows (:func:`_words_row` moves a row inside its 64, so a slice holds four
+    whole blocks: what :func:`_block_diagonal` does for one row over the whole
+    tile)."""
+    rows, tn = x.shape
+    x = x.astype(jnp.float32)
+    # the slices one above the other: whole vregs, one lane gather over them
+    xs = jnp.concatenate([jax.lax.slice_in_dim(x, at, at + 128, axis=1)
+                          for at in range(0, tn, 128)], axis=0)
+    lane = jax.lax.broadcasted_iota(jnp.int32, xs.shape, 1)
+    xs = jnp.take_along_axis(xs, _words_row(lane), axis=1,
+                             mode="promise_in_bounds")
+    own = (_words_block(jax.lax.broadcasted_iota(jnp.int32, (1, 4, 1, 128), 3))
+           == jax.lax.broadcasted_iota(jnp.int32, (1, 4, 1, 128), 1))
+    xd = jnp.where(own, xs.reshape(tn // 128, 1, rows, 128), 0.0)
+    return xd.reshape(tn // 128, 4 * rows, 128)
+
+
+def _pairwise_sum(p: jax.Array) -> jax.Array:
+    """``p.sum(axis=0)`` with the adds in a tree while the count halves (32
+    partials of a 1024-row tile: depth 5, then the accumulator's one add a
+    step), so that a long reduction's rounding grows with its depth and not
+    with its length (Falcon-H1's 21504 rows are 672 block partials)."""
+    while p.shape[0] % 2 == 0:
+        p = p.reshape(p.shape[0] // 2, 2, *p.shape[1:]).sum(axis=1)
+    return p.sum(axis=0)
+
+
+@jax.jit
+def _sliced_sums(x: jax.Array, qp: jax.Array, s32: jax.Array) -> jax.Array:
+    """:func:`_contract_sliced` on values.  Its own ``jit``: every site whose
+    block and tile have these shapes shares ONE trace of the body (a model's
+    tiles are mostly one pair), so a start pays the body's equations once a
+    shape and not once a site (ROADMAP S10); inside a kernel it is inlined."""
+    rows, tn = x.shape
+    td = qp.shape[1]
+    g = tn // 128
+    w, bias = _words_bf16(qp)
+    xd = _sliced_left(x)
+    p = jax.lax.dot_general(
+        xd.astype(jnp.bfloat16), w.reshape(g, 128, td),
+        (((2,), (1,)), ((0,), (0,))), preferred_element_type=jnp.float32)
+    p = p - bias * xd.sum(axis=2, keepdims=True)            # (g, 4 rows, td)
+    p = p.reshape(g, 4, rows, td) * s32.reshape(g, 4, 1, td)
+    return _pairwise_sum(p.reshape(g * 4, rows, td))
+
+
+def _contract_sliced(x_ref, qp, s32) -> jax.Array:
+    """The packed tile against a block of 2 to SLICED_MAX_ROWS rows, a 128-row
+    slice at a time: the right operand is :func:`_words_bf16`'s (exact bf16
+    ``16 + v``, no nibble extended, converted or scaled), each slice of it is
+    contracted against the four quantization blocks that lie in it (one
+    batched dot of ``(4 rows, 128) @ (128, tile_d)`` a slice, float32 sums),
+    and bias (``24`` times the block's sum of ``x``) and scale are paid on the
+    ``(4 rows, tile_d)`` partials, whose rows of one block are whole sublane
+    groups that one row of ``s32`` scales.  No weight is biased, scaled or
+    rounded one by one, so the result is ``x @ dequantize(qt, float32)`` up to
+    summation order, as :func:`_contract_grouped`'s."""
+    return _sliced_sums(x_ref[:], qp, s32)
+
+
 def _dequant_bf16(vi, s32) -> tuple[jax.Array, jax.Array]:
     """The tile's lo and hi nibble planes dequantized, ``(nb, 16, td)`` bf16
     each: a weight is ``bf16(f32(v−8)·s)``."""
@@ -646,7 +758,7 @@ def _q40_kernel(x_ref, qp_ref, s_ref, o_ref, acc_ref, *, nsteps, n_axis=1,
     is grid axis ``n_axis`` = 2) by the body :func:`_body` names.  The
     activation block is ``(rows, tile_n)`` in the model's own column order.
 
-    At two rows and more (:func:`_contract_dot`) dequantization
+    Above SLICED_MAX_ROWS rows (:func:`_contract_dot`) dequantization
     is ``bf16(f32(v−8)·s)`` per weight: the reference's rounding (one bf16
     round of the exact product, funcs.cpp:330-335 semantics), the same on
     every tp shard and in the XLA path at more than one row.  The VPU's work
@@ -664,6 +776,16 @@ def _q40_kernel(x_ref, qp_ref, s_ref, o_ref, acc_ref, *, nsteps, n_axis=1,
     50's mask or shift and conversion a nibble, ~3.5-4 with the extend, stay
     for a toy's tile of under 128 rows), and the left operand follows the
     row order they come out in (:func:`_block_diagonal`).
+
+    At 2 to SLICED_MAX_ROWS rows (:func:`_contract_sliced`, PR 62) the same
+    words are contracted a 128-row slice at a time against the four
+    quantization blocks that lie in it, and bias and scale are paid on ``4
+    rows x tile_d`` partials a slice: the same exact operands and f32 sums as
+    at one row.  So every block of 1 to SLICED_MAX_ROWS rows (every decode
+    and verify step) computes ``x @ dequantize(qt, float32)`` up to summation
+    order, and only a block of more rows (a prompt's chunk, a packed mixed
+    step) and the XLA path keep the bf16 round of a weight: the edge ROADMAP
+    D17 names, which a toy's tile of under 128 rows has at two rows already.
 
     ``live`` (a traced predicate, the grouped launch's): the whole step runs
     under it, and where it is false nothing is unpacked, contracted or
@@ -683,9 +805,12 @@ def _q40_step(i, x_ref, qp_ref, s_ref, o_ref, acc_ref, nsteps):
     nb = tn2 // 16
     sbits = s_ref[...].reshape(nb, td)                    # uint16 f16 bits
     s32 = _f16_bits_to_f32(sbits)                         # (nb, td) f32, exact
-    grouped = _body(x_ref.shape[0]) == "grouped"
+    body = _body(x_ref.shape[0], 2 * tn2)
+    grouped = body == "grouped"
     if grouped:  # the tile as it lies: its bytes become bf16 words there
         part = _contract_grouped(x_ref, qp, s32)
+    elif body == "sliced":
+        part = _contract_sliced(x_ref, qp, s32)
     else:
         part = _contract_dot(x_ref, qp.astype(jnp.int32), s32)
 
@@ -766,7 +891,7 @@ def _mm_call(t: int, n: int, d: int, tile_n: int, tile_d: int,
     tr = row_block or _row_block(t, tile_n, tile_d)
     nd, nn = pl.cdiv(d, tile_d), n // tile_n
     assert not grouped or (tr is None and chosen and x_per_expert)
-    tb = t if tr is None else tr
+    tb = _block_rows(t, tile_n) if tr is None else tr
     grid = ((() if tr is None else (pl.cdiv(t, tr),))
             + ((experts,) if experts else ()) + (nd, nn))
 
@@ -822,7 +947,8 @@ def _mm_call(t: int, n: int, d: int, tile_n: int, tile_d: int,
             ex(experts, None) + (tb, tile_d),
             at(lambda r, e, j, i, *l: ex(experts, e) + (r, j)), **ms),
         scratch_shapes=[pltpu.VMEM(
-            (tb * (_partial_rows(tile_n) if _body(tb) == "grouped" else 1), tile_d),
+            (tb * (_partial_rows(tile_n) if _body(tb, tile_n) == "grouped" else 1),
+             tile_d),
             jnp.float32)])
     return grid_kw, params, dict(nsteps=nn, n_axis=len(grid) - 1)
 

@@ -76,18 +76,20 @@ def test_rehearse_kernels_phase(rehearsal_env, capfd):
     assert dev["platform"] == "cpu"
     rows = [json.loads(ln) for ln in capfd.readouterr().out.splitlines()]
     errs = [r for r in rows if "rel_err" in r]
-    # six shapes × rows {1, 8, 256}, and at one row (the grouped body, PR 50) also
-    # against the float32 dequantization + a row's chosen experts in one launch,
+    # six shapes × rows {1, 8, 16, 256}, and at one row (the grouped body, PR 50)
+    # and at 8 and 16 (the sliced body, PR 62; the toy's ``moe_w2`` and ``wcls``
+    # tiles of 1408 and 128 rows too) also against the float32 dequantization,
+    # where the tile is whole vregs + a row's chosen experts in one launch,
     # against XLA and against the float32 dequantization (PR 58) +
     # dense/int8 fused attention + the fused walk at a chunk's 16 tokens a
     # slot, three head geometries, and at one token over the pool that holds
     # two heads of 64 a row + the live walk at prefill rows, dense/int8 × two
     # positions
-    assert len(errs) == 36
+    assert len(errs) == 54
     f32 = [r for r in errs if r["kernel"].endswith(".f32")]
     assert "q40.chosen_experts.f32" in [r["kernel"] for r in f32]
-    assert len(f32) == 7 and all(r["rows"] == 1 and r["tol"] == chip_smoke.Q40_F32_TOL
-                                 for r in f32)
+    assert len(f32) == 19 and all(r["tol"] == chip_smoke.Q40_F32_TOL for r in f32)
+    assert sorted({r["rows"] for r in f32}) == [1, 8, 16]
     assert [r["chosen"] for r in errs if r["kernel"] == "q40.chosen_experts"] == [6]
     assert [r["geometry"]["heads"] for r in errs if r.get("t") == 16] == \
         ["mistral-7b", "olmoe-1b-7b", "lfm2-24b-a2b"]

@@ -187,12 +187,15 @@ def test_a_looped_program_records_its_loop_once(loops, caplog):
 
 
 @pytest.mark.parametrize("rows,n,body", [(1, 256, "grouped-words"),
-                                         (1, 96, "grouped-nibbles"), (16, 256, "dot")])
+                                         (1, 96, "grouped-nibbles"),
+                                         (16, 256, "sliced-words"), (16, 96, "dot"),
+                                         (64, 256, "dot")])
 def test_q40_site_records_the_body_and_the_path_share_ignores_it(rows, n, body):
     """A fused Q40 call site says which body contracts its tile (PR 50) and,
     at one row, how the tile's nibbles became the dot's operand (PR 58):
     ``q40_body/grouped-words`` at one row (``grouped-nibbles`` for a toy's tile
-    of 96 rows), ``q40_body/dot`` at 16, beside its
+    of 96 rows), ``q40_body/sliced-words`` at 16 (PR 62; ``dot`` for the toy's
+    tile), ``q40_body/dot`` at 64, beside its
     ``q40/pallas-fused`` record.  The codec is not ``q40``, so the benchmark's
     ``pallas_path_pct`` reader counts the site once, not twice."""
     import importlib.util

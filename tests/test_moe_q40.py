@@ -256,7 +256,11 @@ def test_tp8_quantized_moe_matches_tp1():
                       dim=256, hidden_dim=256, n_layers=2, n_heads=8,
                       n_kv_heads=8, vocab_size=128, seq_len=32,
                       ).with_(quant_impl="pallas_interpret")
-    qparams = quantize_matmuls(init_params(cfg, seed=3), cfg)
+    # a toy shard's reduction tile of 32 rows keeps the dot body (a weight
+    # rounded to bf16) where the whole matrix's takes the sliced body at the
+    # prompt's three rows (none rounded, PR 62): weights exact in bf16 compute
+    # one function on both
+    qparams = bf16_exact_scales(quantize_matmuls(init_params(cfg, seed=3), cfg))
     prompt = [1, 2, 3]
     e1 = Engine(cfg, qparams, mesh=make_mesh(tp=1, devices=jax.devices()[:1]))
     e8 = Engine(cfg, qparams, mesh=make_mesh(tp=8))

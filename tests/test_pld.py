@@ -81,7 +81,8 @@ def test_pld_rejects_batch_and_sp():
             sp_engine.generate_pld([1, 2], 8)
 
 
-# ---- on the fused Q40 kernel a row's logits follow the block's rows (PR 50) --
+# ---- on the fused Q40 kernel a row's logits follow the block's rows only above
+# ---- SLICED_MAX_ROWS rows (PRs 50, 62) --------------------------------------
 
 def _q40_engine(exact_scales=False):
     from dllama_tpu.models.params import quantize_matmuls
@@ -97,11 +98,10 @@ def _q40_engine(exact_scales=False):
 @pytest.mark.parametrize("k", [1, 5])
 def test_pld_on_the_fused_q40_kernel_matches_vanilla_greedy(k):
     """A decode step's one row is contracted against weights no one rounded
-    (``q40_body/grouped-*``), a verify window's ``k + 1`` rows against weights
-    rounded to bf16 (``q40_body/dot``): the verify's logits are 1e-3 of the
-    largest away from the step's.  The contract stands as its docstring words
-    it: every emitted token is an argmax of the model at its position, and
-    away from a near-tie the two streams are one, accepted drafts included."""
+    (``q40_body/grouped-*``), and since PR 62 so are a verify window's ``k + 1``
+    rows (``q40_body/sliced-words``, no ``dot`` site): the verify's logits are
+    the step's to the order of their float32 sums, so the two streams are one,
+    accepted drafts included."""
     from dllama_tpu.obs import dispatch as obs_dispatch
     prompt = PROMPTS[2]
     obs_dispatch.reset()
@@ -109,35 +109,43 @@ def test_pld_on_the_fused_q40_kernel_matches_vanilla_greedy(k):
         prompt, 40, temperature=0.0, chunk=8)]
     assert sum(v for k, v in obs_dispatch.dispatches().items()
                if k.startswith("q40_body/grouped-")) > 0
-    before = obs_dispatch.dispatches().get("q40_body/dot", 0)
+    before = obs_dispatch.dispatches().get("q40_body/sliced-words", 0)
     eng = _q40_engine()
     assert eng.generate_pld(prompt, 40, ngram=2, k=k) == ref
-    assert obs_dispatch.dispatches()["q40_body/dot"] > before
+    assert obs_dispatch.dispatches()["q40_body/sliced-words"] > before
+    assert "q40_body/dot" not in obs_dispatch.dispatches()
     obs_dispatch.reset()
 
 
+@pytest.mark.parametrize("rows", [2, 17], ids=["two-rows", "one-past-the-sliced-body"])
 @pytest.mark.parametrize("exact_scales", [False, True],
                          ids=["f16-scales", "bf16-exact-scales"])
 def test_a_rows_logits_depend_on_the_blocks_rows_by_the_weights_rounding_alone(
-        exact_scales):
+        exact_scales, rows):
     """The same token at the same position, decoded alone and as the first of a
-    two-row block: the logits differ at the 1e-3 level, never by more than 1e-2
-    of the largest, with the same argmax; and the whole of the difference is
-    the bf16 rounding of a weight, which the two-row body makes and the one-row
-    body does not: on weights that are exact in bf16 they agree to the order
-    of their float32 sums."""
+    block.  Beside one other row (any block of 2 to SLICED_MAX_ROWS: every
+    verify window, every served decode step) no weight is rounded either, and
+    the logits agree to the order of their float32 sums (PR 62: ROADMAP D17's
+    first way out).  In a block of one row more than that the dot body rounds
+    each weight to bf16: the logits differ at the 1e-3 level, never by more
+    than 1e-2 of the largest, with the same argmax; and the whole of the
+    difference is that rounding: on weights that are exact in bf16 they agree
+    to the order of their sums again."""
     from dllama_tpu.models.transformer import forward, init_kv_cache
+    from dllama_tpu.ops import q40
+    assert rows == 2 or rows == q40.SLICED_MAX_ROWS + 1
     eng = _q40_engine(exact_scales)
     cfg, params = eng.cfg, eng.params
     prompt = jax.numpy.asarray([PROMPTS[1]], jax.numpy.int32)
     _, cache = forward(params, cfg, prompt, init_kv_cache(cfg, 1), jax.numpy.int32(0))
     pos = jax.numpy.int32(prompt.shape[1])
     alone, _ = forward(params, cfg, jax.numpy.asarray([[9]]), cache, pos)
-    beside, _ = forward(params, cfg, jax.numpy.asarray([[9, 3]]), cache, pos)
+    block = [9] + [3 + i % 5 for i in range(rows - 1)]
+    beside, _ = forward(params, cfg, jax.numpy.asarray([block]), cache, pos)
     alone, beside = np.asarray(alone)[0, 0], np.asarray(beside)[0, 0]
     diff = np.abs(alone - beside).max() / np.abs(alone).max()
     assert alone.argmax() == beside.argmax()
-    if exact_scales:
+    if exact_scales or rows == 2:
         assert diff < 2e-5, diff
     else:
         assert 1e-4 < diff < 1e-2, diff

@@ -19,12 +19,14 @@ def _rand(shape, seed=0, scale=0.1):
     return (np.random.RandomState(seed).randn(*shape) * scale).astype(np.float32)
 
 
-# How close the kernel comes to its reference, as a share of max |ref|.  At
-# two rows a block and more (the dot body) a weight is rounded to bf16 as in
+# How close the kernel comes to its reference, as a share of max |ref|.  Above
+# SLICED_MAX_ROWS rows a block (the dot body) a weight is rounded to bf16 as in
 # the XLA path, which it equals up to summation order.  At one row (the grouped
-# body, PR 50) no weight is rounded: the reference is the float32
+# body, PR 50) and at 2 to SLICED_MAX_ROWS rows of a tile of whole vregs (the
+# sliced body, PR 62) no weight is rounded: the reference is the float32
 # dequantization, and the bound is the f32 sums', 20 times tighter.
 DOT_TOL, GROUPED_TOL = 1e-4, 5e-6
+R = q40.SLICED_MAX_ROWS
 
 
 def _f32_reference(x, w) -> np.ndarray:
@@ -33,10 +35,12 @@ def _f32_reference(x, w) -> np.ndarray:
     return np.asarray(x, np.float64) @ np.asarray(dense, np.float64)
 
 
-def _reference(x, w):
-    """The reference of the body that ``x``'s rows take and its bound:
-    ``(ref, tol)``."""
-    if q40._body(x.shape[-2]) == "grouped":
+def _reference(x, w, tiles=None):
+    """The reference of the body that ``x``'s rows take (at the rule's tiles,
+    or at ``tiles``) and its bound: ``(ref, tol)``."""
+    qt = w.qt if isinstance(w, q40.QLayerView) else w
+    tile_n = (tiles or q40._tiles(qt.qpacked.shape[-2] * 2, qt.logical_nd[1]))[0]
+    if q40._body(x.shape[-2], tile_n) != "dot":
         return _f32_reference(x, w), GROUPED_TOL
     return np.asarray(q40.matmul(x, w, impl="xla", out_dtype=jnp.float32)), DOT_TOL
 
@@ -241,7 +245,12 @@ class TestShardMap:
         cfg = tiny_config(dim=256, hidden_dim=256, n_layers=2, n_heads=8,
                           n_kv_heads=8, vocab_size=128, seq_len=64,
                           ).with_(quant_impl="pallas_interpret")
-        params = quantize_matmuls(init_params(cfg, seed=4), cfg)
+        # a toy shard's reduction tile (256 / 8 = 32 rows) keeps the dot body,
+        # which rounds a weight to bf16, where the whole matrix's tile takes the
+        # sliced body at the prompt's four rows, which rounds none (PR 62):
+        # weights whose rounding is exact compute the same function on both
+        from fixtures import bf16_exact_scales
+        params = bf16_exact_scales(quantize_matmuls(init_params(cfg, seed=4), cfg))
         prompt = [3, 17, 29, 5]
 
         e1 = Engine(cfg, params, mesh=make_mesh(tp=1, devices=jax.devices()[:1]))
@@ -249,8 +258,8 @@ class TestShardMap:
         assert "wq" in e8.params and "wqkv" not in e8.params  # unfused for tp
         l1, _ = e1.prefill(prompt)
         l8, _ = e8.prefill(prompt)
-        # the per-weight rounding is identical across tp configs, so the
-        # bound stays tight
+        # no weight's rounding differs across tp configs, so the bound stays
+        # tight
         np.testing.assert_allclose(l1, l8, atol=1e-3 + 1e-3 * np.abs(l1).max(), rtol=0)
 
         def greedy(engine):
@@ -403,8 +412,9 @@ NEW_TILE_SHAPES = [(5120, 1536), (1536, 5120), (5120, 2112), (1792, 7168),
 @pytest.mark.parametrize("n,d", NEW_TILE_SHAPES, ids=lambda v: str(v))
 def test_kernel_matches_xla_at_the_rules_new_tiles(form, n, d):
     """Interpret-mode numerics at the tile pairs this rule brought, chosen by
-    the rule itself, against the XLA reference: another tile_n only moves
-    where the f32 accumulator's partial sums are cut."""
+    the rule itself, against the reference of the body five rows take (the
+    sliced body's float32 dequantization): another tile_n only moves where
+    the f32 accumulator's partial sums are cut."""
     assert q40._tiles(n, d) != _parent_tiles(_parent_padded_n(n), d)
     lead = (2,) if form == "stacked" else ()
     qt = q40.quantize(_rand((*lead, n, d), seed=n % 97))
@@ -412,8 +422,8 @@ def test_kernel_matches_xla_at_the_rules_new_tiles(form, n, d):
     x = jnp.asarray(_rand((5, n), seed=d % 89, scale=1.0), jnp.bfloat16)
     w = q40.QLayerView(qt, jnp.int32(1)) if form == "stacked" else qt
     got = np.asarray(q40.matmul(x, w, impl="pallas_interpret", out_dtype=jnp.float32))
-    ref = np.asarray(q40.matmul(x, w, impl="xla", out_dtype=jnp.float32))
-    np.testing.assert_allclose(got, ref, rtol=0, atol=1e-4 * np.abs(ref).max())
+    ref, tol = _reference(x, w)
+    np.testing.assert_allclose(got, ref, rtol=0, atol=tol * np.abs(ref).max())
 
 
 def test_dispatch_record_carries_the_tile_pair_and_the_stored_n(caplog, monkeypatch):
@@ -718,16 +728,17 @@ def test_f16_bits_to_f32_exhaustive():
     np.testing.assert_array_equal(got, exp)
 
 
-@pytest.mark.parametrize("n", [64, 256])
-def test_extreme_scales_roundtrip_through_kernel(n):
+@pytest.mark.parametrize("n,rows", [(64, 1), (256, 1), (256, 2), (256, 16), (256, R)])
+def test_extreme_scales_roundtrip_through_kernel(n, rows):
     """Scales at the f16 extremes — subnormal deltas (tiny weights) and
     near-max deltas (|w| up to ~524k pre-clamp) — must dequantize exactly
     through the uint16 bit path in both the XLA and interpret-kernel
     implementations.  One row takes the grouped body (a tile of two
-    quantization blocks or of eight), whose bias term ``8 * sum(x)`` cancels in
-    f32: its error against the float32 reference is under GROUPED_TOL over the
-    tiny blocks alone, over the huge ones alone and over both, and no larger
-    than the dot body's on the same input."""
+    quantization blocks or of eight) and 2 to SLICED_MAX_ROWS rows the sliced
+    body (two slices of four), whose bias term ``24 * sum(x)`` cancels in f32:
+    the error against the float32 reference is under GROUPED_TOL over the tiny
+    blocks alone, over the huge ones alone and over both, and no larger than
+    the dot body's on the same input."""
     rng = np.random.RandomState(0)
     w = rng.randn(n, 128).astype(np.float32)
     w[:n // 2] *= 1e-7      # subnormal f16 deltas (amax/8 < 6.1e-5)
@@ -745,26 +756,32 @@ def test_extreme_scales_roundtrip_through_kernel(n):
     ).reshape(n, 128) * np.repeat(sc, 32, axis=0)
     np.testing.assert_array_equal(dq, dense.astype(np.float32))
 
-    x = _rand((1, n), seed=1, scale=1.0)
+    x = _rand((rows, n), seed=1, scale=1.0)
     ref = x @ dq
     out = np.asarray(q40.matmul(jnp.asarray(x), qt, impl="pallas_interpret"))
     np.testing.assert_allclose(out, ref, rtol=0,
                                atol=2e-2 * np.abs(ref).max() + 1e-12)
+    assert q40._body(rows, n) == ("grouped" if rows == 1 else "sliced")
     for keep in (slice(None), slice(0, n // 2), slice(n // 2, n)):
         xk = np.zeros_like(x)
         xk[:, keep] = x[:, keep]
         xk = jnp.asarray(xk, jnp.bfloat16)
         ref = _f32_reference(xk, qt)
-        one = np.asarray(q40._pallas_matmul(xk, qt.qpacked, qt.scales, interpret=True))
-        # the same row beside a second one takes the dot (bf16 weights)
-        two = np.asarray(q40._pallas_matmul(jnp.concatenate([xk, xk]), qt.qpacked,
-                                            qt.scales, interpret=True))[:1]
-        err = np.abs(one - ref).max()
+        few = np.asarray(q40._pallas_matmul(xk, qt.qpacked, qt.scales, interpret=True))
+        # the same rows in a block of more than SLICED_MAX_ROWS take the dot
+        # (bf16 weights)
+        many = np.asarray(q40._pallas_matmul(
+            jnp.concatenate([xk] * (R // rows + 1)), qt.qpacked, qt.scales,
+            interpret=True))[:rows]
+        err = np.abs(few - ref).max()
         assert err <= GROUPED_TOL * np.abs(ref).max(), (keep, err)
-        assert err <= np.abs(two - ref).max(), keep
+        # (the tiny blocks alone are exact on the dot body too: a subnormal
+        # scale times a nibble is a bf16)
+        if rows == 1 or keep == slice(None):
+            assert err <= np.abs(many - ref).max(), keep
 
 
-# ---- one row is contracted a quantization block at a time (PR 50) ----------
+# ---- few rows are contracted a quantization block at a time (PRs 50, 62) ---
 
 # (n, d, tiles): an input dim stored padded (2752 -> 3072, three steps), a
 # ragged last d tile (320 in tiles of 256), several n steps at a forced tile,
@@ -772,15 +789,26 @@ def test_extreme_scales_roundtrip_through_kernel(n):
 # and of 3: no multiple of the eight sublanes the block partials lie on
 ONE_ROW_SHAPES = [(2752, 384, None), (512, 320, (256, 256)), (1024, 256, (256, 256)),
                   (1408, 256, None), (96, 128, None)]
+# (rows, n, d, tiles) of the sliced body (PR 62): rows short of a sublane
+# group, a padded n, a ragged d, whole-axis tiles of 1024, 1536 and 2048 rows
+# (8, 12 and 16 slices) and of 1408 (11); then what keeps the dot: one row
+# more than SLICED_MAX_ROWS, and a toy's tile of 96 rows (no whole vreg)
+FEW_ROW_SHAPES = [(2, 2752, 384, None), (3, 512, 320, (256, 256)), (8, 1024, 256, None),
+                  (16, 1536, 128, None), (R, 2048, 128, None), (16, 1408, 256, None),
+                  (R + 1, 1024, 256, (256, 256)), (16, 96, 128, None)]
 
 
-@pytest.mark.parametrize("n,d,tiles", ONE_ROW_SHAPES, ids=lambda v: str(v))
+@pytest.mark.parametrize("rows,n,d,tiles",
+                         [(1, *s) for s in ONE_ROW_SHAPES] + FEW_ROW_SHAPES,
+                         ids=lambda v: str(v))
 @pytest.mark.parametrize("form", ["flat", "stacked", "chosen", "chosen-x-an-expert"])
-def test_one_row_is_contracted_by_blocks_and_equals_the_f32_reference(form, n, d,
-                                                                      tiles):
-    """One row through each launch: the result is ``x @ dequantize(qt,
-    float32)`` within GROUPED_TOL, a bound the same row cannot meet on the dot
-    body (which rounds each weight to bf16: the row beside a second one)."""
+def test_few_rows_are_contracted_by_blocks_and_equal_the_f32_reference(form, rows, n,
+                                                                       d, tiles):
+    """A block of 1 to SLICED_MAX_ROWS rows through each launch: the result is
+    ``x @ dequantize(qt, float32)`` within GROUPED_TOL, a bound the same rows
+    cannot meet on the dot body (which rounds each weight to bf16: the rows in
+    a block of more than SLICED_MAX_ROWS, or against a toy's tile of 96 rows),
+    and a row's result is the same alone and in company, to summation order."""
     experts, layer, picks = 3, 1, (2, 0, 2)
     rng = np.random.default_rng(n + d)
     lead = {"flat": (), "stacked": (2,)}.get(form, (2, experts))
@@ -788,8 +816,10 @@ def test_one_row_is_contracted_by_blocks_and_equals_the_f32_reference(form, n, d
     np_ = qt.qpacked.shape[-2] * 2
     per_expert = form == "chosen-x-an-expert"
     x = jnp.asarray(rng.standard_normal(
-        ((len(picks),) if per_expert else ()) + (1, n)), jnp.bfloat16)
+        ((len(picks),) if per_expert else ()) + (rows, n)), jnp.bfloat16)
     xp = q40._pad_x(x, n, np_)
+    exact = q40._body(rows, (tiles or q40._tiles(np_, d))[0]) != "dot"
+    assert exact == (rows == 1 or rows <= R and n != 96)
 
     def launch(xp):
         if form == "flat":
@@ -804,38 +834,51 @@ def test_one_row_is_contracted_by_blocks_and_equals_the_f32_reference(form, n, d
             tiles=tiles, chosen=jnp.asarray(picks))
 
     out = np.asarray(launch(xp))
-    two = np.asarray(launch(jnp.concatenate([xp, xp], axis=-2)))[..., :1, :]
+    many = np.asarray(launch(jnp.concatenate([xp] * (R // rows + 1), axis=-2))
+                      )[..., :rows, :]
+    alone = np.asarray(launch(xp[..., :1, :]))
     planes = {"flat": [qt], "stacked": [q40.QLayerView(qt, jnp.int32(layer))]}.get(
         form) or [q40.QLayerView(qt, jnp.int32(layer)).select(jnp.int32(e), experts)
                   for e in picks]
-    assert out.shape == (len(planes), 1, d)
+    assert out.shape == (len(planes), rows, d)
     for j, w in enumerate(planes):
         ref = _f32_reference(x[j] if per_expert else x, w)
         err = np.abs(out[j] - ref).max() / np.abs(ref).max()
-        assert err <= GROUPED_TOL, (j, err)
-        assert np.abs(two[j] - ref).max() / np.abs(ref).max() > 10 * GROUPED_TOL
+        assert (err <= GROUPED_TOL) == exact, (j, err)
+        assert np.abs(many[j] - ref).max() / np.abs(ref).max() > 10 * GROUPED_TOL
+        # a row alone takes the grouped body: the same sums in another order,
+        # or (beside the dot body) the bf16 edge D17 names
+        gap = np.abs(alone[j] - out[j][:1]).max() / np.abs(ref).max()
+        assert (gap <= 2 * GROUPED_TOL) == exact, (j, gap)
 
 
 @pytest.mark.parametrize("rows,n,body", [
     (1, 512, "grouped-words"), (1, 1408, "grouped-words"), (1, 128, "grouped-words"),
     (1, 96, "grouped-nibbles"), (1, 64, "grouped-nibbles"),
-    (2, 512, "dot"), (2, 96, "dot"), (16, 512, "dot"), (256, 512, "dot")])
+    (2, 512, "sliced-words"), (3, 128, "sliced-words"), (8, 1408, "sliced-words"),
+    (16, 512, "sliced-words"), (R, 512, "sliced-words"),
+    (2, 96, "dot"), (16, 64, "dot"), (R + 1, 512, "dot"), (256, 512, "dot")])
 def test_the_blocks_rows_choose_the_body_and_the_ledger_says_which(rows, n, body, caplog,
                                                                    monkeypatch):
-    """Nothing but the block's shape chooses: its row count the body, and at
-    one row the tile's rows how the nibbles become the dot's operand (words
-    wherever ``tile_n`` is a multiple of 128, the activation row beside it
-    whole vregs; a toy's whole-axis tile of 64 or 96 rows keeps a conversion a
-    nibble).  The ``q40_body`` counter and the ``body=`` of the
-    ``q40/pallas-fused`` record name both, and the kernel's jaxpr builds a
-    block-diagonal left operand (from an iota) exactly where they say
-    ``grouped``."""
+    """Nothing but the block's shape chooses: its row count the body (one row
+    ``grouped``, 2 to SLICED_MAX_ROWS ``sliced``, more the ``dot``: one edge,
+    monotone), and the tile's rows whether the nibbles become the dot's
+    operand as words (wherever ``tile_n`` is a multiple of 128, the activation
+    row beside it whole vregs; a toy's whole-axis tile of 64 or 96 rows keeps
+    a conversion a nibble at one row and the dot above it).  The ``q40_body``
+    counter and the ``body=`` of the ``q40/pallas-fused`` record name both,
+    and the kernel's jaxpr builds a block-diagonal left operand (from an iota)
+    exactly where they say ``grouped`` or ``sliced``."""
     import logging
     from dllama_tpu.obs import dispatch as obs_dispatch
     monkeypatch.setattr(logging.getLogger("dllama"), "propagate", True)
-    assert q40._body(rows) == body.split("-")[0]
+    tile_n = q40._tiles(n, 256)[0]
+    assert q40._body(rows, tile_n) == body.split("-")[0]
     if rows == 1:
-        assert "grouped-" + q40._nibbles_as(q40._tiles(n, 256)[0]) == body
+        assert "grouped-" + q40._nibbles_as(tile_n) == body
+    assert [q40._body(r, tile_n) for r in range(1, 300)] == sorted(
+        (q40._body(r, tile_n) for r in range(1, 300)),
+        key=("grouped", "sliced", "dot").index)
     qt = q40.quantize(_rand((2, n, 256), seed=2))
     w = q40.QLayerView(qt, jnp.int32(1))
     x = jax.ShapeDtypeStruct((rows, n), jnp.bfloat16)
@@ -843,12 +886,13 @@ def test_the_blocks_rows_choose_the_body_and_the_ledger_says_which(rows, n, body
     with caplog.at_level(logging.DEBUG, logger="dllama"):
         jaxpr = jax.make_jaxpr(lambda x: q40.matmul(x, w, impl="pallas_interpret"))(x)
     after = obs_dispatch.dispatches()
-    for name in ("grouped-words", "grouped-nibbles", "dot", "grouped"):
+    for name in ("grouped-words", "grouped-nibbles", "sliced-words", "dot", "grouped",
+                 "sliced"):
         assert after.get(f"q40_body/{name}", 0) == \
             before.get(f"q40_body/{name}", 0) + (name == body), name
     rec, = [r for r in caplog.records if getattr(r, "path", None) == "pallas-fused"]
     assert rec.body == body and rec.rows == rows
-    assert (" iota[" in str(jaxpr)) == body.startswith("grouped")
+    assert (" iota[" in str(jaxpr)) == (body != "dot")
 
 
 def _kernel_eqns(jaxpr):
@@ -864,20 +908,24 @@ def _kernel_eqns(jaxpr):
 
 
 @pytest.mark.parametrize("form", ["flat", "stacked", "chosen"])
-@pytest.mark.parametrize("n,d,tiles,converts", [
-    (1024, 256, None, False), (1024, 256, (256, 128), False), (1408, 128, None, False),
-    (96, 128, None, True)], ids=lambda v: str(v))
-def test_no_nibble_of_a_words_tile_is_converted_from_an_integer(form, n, d, tiles,
-                                                               converts):
-    """The mechanism (PR 58): at one row a tile the word form takes never
-    leaves integer registers until it IS the bf16 operand: the kernel's jaxpr
-    holds no ``convert_element_type`` from an integer to a float on anything
-    as large as the tile (the scales' ``(tile_n / 32, tile_d)`` mantissas still
-    are, once a block), and no extension of the bytes either.  A tile it
-    cannot take (96 rows) converts each nibble plane, as PR 50's body did."""
+@pytest.mark.parametrize("rows,n,d,tiles,converts", [
+    (1, 1024, 256, None, False), (1, 1024, 256, (256, 128), False),
+    (1, 1408, 128, None, False), (1, 96, 128, None, True),
+    (16, 1024, 256, None, False), (16, 1024, 256, (256, 128), False),
+    (16, 1408, 128, None, False), (16, 96, 128, None, True)], ids=lambda v: str(v))
+def test_no_nibble_of_a_words_tile_is_converted_from_an_integer(form, rows, n, d,
+                                                               tiles, converts):
+    """The mechanism (PR 58; at 2 to SLICED_MAX_ROWS rows since PR 62): a tile
+    the word form takes never leaves integer registers until it IS the bf16
+    operand: the kernel's jaxpr holds no ``convert_element_type`` from an
+    integer to a float on anything as large as the tile (the scales'
+    ``(tile_n / 32, tile_d)`` mantissas still are, once a block), and no
+    extension of the bytes either.  A tile it cannot take (96 rows) converts
+    each nibble plane, as PR 50's body did at one row and the dot body does
+    above it."""
     experts = 2
     lead = {"flat": (), "stacked": (2,)}.get(form, (2 * experts,))
-    x = jax.ShapeDtypeStruct((1, n), jnp.bfloat16)
+    x = jax.ShapeDtypeStruct((rows, n), jnp.bfloat16)
     qp = jax.ShapeDtypeStruct((*lead, n // 2, d), jnp.uint8)
     sc = jax.ShapeDtypeStruct((*lead, n // 32, d), jnp.uint16)
     layer = jax.ShapeDtypeStruct((), jnp.int32)
@@ -937,7 +985,7 @@ def test_every_nibble_of_a_word_lands_on_its_own_logical_row(n, tiles):
 
 
 @pytest.mark.parametrize("body,rows", [
-    ("grouped", 1), ("grouped", 2), ("vpu", 1), ("vpu", 2), ("nibbles", 1),
+    ("sliced", 2), ("sliced", 32), ("vpu", 1), ("vpu", 2), ("nibbles", 1),
     ("bytes", 1), ("words128", 1)])
 def test_the_sweeps_few_row_bodies_compute_the_matmul(body, rows):
     """``tools/sweep_q40.py --body``'s forms the program does not run (the
@@ -953,10 +1001,11 @@ def test_the_sweeps_few_row_bodies_compute_the_matmul(body, rows):
     spec.loader.exec_module(sweep)
     qt = q40.quantize(_rand((768, 128), seed=9))
     x = jnp.asarray(_rand((rows, 768), seed=10, scale=1.0), jnp.bfloat16)
+    rule = q40._body
     with sweep._body_as(body):
-        assert q40._body(rows) == "grouped"
+        assert q40._body(rows, 768) == ("sliced" if body == "sliced" else "grouped")
         out = np.asarray(q40._pallas_matmul(x, qt.qpacked, qt.scales, interpret=True))
-    assert q40._body(2) == "dot" and q40._contract_grouped.__module__ == q40.__name__
+    assert q40._body is rule and q40._contract_grouped.__module__ == q40.__name__
     ref = _f32_reference(x, qt)
     assert np.abs(out - ref).max() <= GROUPED_TOL * np.abs(ref).max()
 
@@ -1010,7 +1059,7 @@ def test_experts_and_chosen_launches_take_whole_x_and_match_xla(form, per_expert
     assert out.shape == (len(picks), rows, d)
     for j, e in enumerate(picks):
         ref, tol = _reference(x[j] if per_expert else x,
-                              view.select(jnp.int32(e), experts))
+                              view.select(jnp.int32(e), experts), tiles=(256, 256))
         np.testing.assert_allclose(np.asarray(out[j]), ref, rtol=0,
                                    atol=tol * np.abs(ref).max(), err_msg=str(j))
 

@@ -1270,12 +1270,22 @@ def _launch(form, n, d, rows, sharding, experts=0, layers=2, k=0):
         return q40._pallas_matmul_stacked, (
             s((rows, n), jnp.bfloat16), s((layers, n // 2, d), jnp.uint8),
             s((layers, n // 32, d), jnp.uint16), s((), jnp.int32))
+    planes = (s((layers * experts, n // 2, d), jnp.uint8),
+              s((layers * experts, n // 32, d), jnp.uint16), s((), jnp.int32))
+    if form in ("experts", "experts-x"):  # every expert of the layer
+        return (lambda x, qp, sc, layer: q40._pallas_matmul_experts(
+            x, qp, sc, layer, experts=experts)), (
+            s(((experts,) if form == "experts-x" else ()) + (rows, n), jnp.bfloat16),
+            *planes)
+    if form == "grouped":  # k blocks of rows, each with its plane (PR 53)
+        return (lambda x, qp, sc, layer, chosen, used: q40._pallas_matmul_experts(
+            x, qp, sc, layer, experts=experts, chosen=chosen, used=used)), (
+            s((k, rows, n), jnp.bfloat16), *planes, s((k,), jnp.int32),
+            s((), jnp.int32))
     return (lambda x, qp, sc, layer, chosen: q40._pallas_matmul_experts(
         x, qp, sc, layer, experts=experts, chosen=chosen)), (
         s(((k,) if form == "chosen-x" else ()) + (rows, n), jnp.bfloat16),
-        s((layers * experts, n // 2, d), jnp.uint8),
-        s((layers * experts, n // 32, d), jnp.uint16), s((), jnp.int32),
-        s((k,), jnp.int32))
+        *planes, s((k,), jnp.int32))
 
 
 # Mistral-7B's five matmuls, a Yi-34B tp=4 shard's (w1 and w3, which a mesh
@@ -1319,18 +1329,72 @@ def test_one_row_q40_body_lowers_for_the_v5e_with_no_op_a_weight_but_the_unpack(
     assert q40._nibbles_as(tile_n) == "words"
     assert "tpu_custom_call" in text
     assert ops["tpu.matmul"] == 1
-    assert ops["arith.sitofp"] == SIXTEEN_ROW_BODY_OPS["arith.sitofp"] - 2
-    assert ops["arith.extui"] == SIXTEEN_ROW_BODY_OPS["arith.extui"] - 1
-    assert ops["tpu.bitcast"] == SIXTEEN_ROW_BODY_OPS["tpu.bitcast"] + 5
+    assert ops["arith.sitofp"] == DOT_BODY_OPS["arith.sitofp"] - 2
+    # (a grouped launch extends its one-bit ``live`` predicate)
+    assert ops["arith.extui"] == DOT_BODY_OPS["arith.extui"] - 1 + (form == "grouped")
+    assert ops["tpu.bitcast"] == DOT_BODY_OPS["tpu.bitcast"] + 5
     assert ops["tpu.dynamic_gather"] == 1
     assert ops["arith.truncf"] == 1 and ops["arith.subf"] == 1
-    assert ops["arith.mulf"] == SIXTEEN_ROW_BODY_OPS["arith.mulf"]
+    assert ops["arith.mulf"] == DOT_BODY_OPS["arith.mulf"]
     assert not ops["tpu.transpose"] and not ops["arith.divsi"]
 
 
-# the 16-row stacked launch at Mistral's w13 as PR 41 left it (and as the parent
-# of PR 50 lowers it): every op of the body with its count
-SIXTEEN_ROW_BODY_OPS = {
+# The served cells' launches of 2 to SLICED_MAX_ROWS rows (PR 62): K-EXAONE's
+# held experts, its widest dense matmuls and its head's share at 16 rows,
+# LFM2's and DeepSeek-V2's experts (a whole-axis tile of 1536 rows: 12
+# slices), Brumby's and Ouro's at 8 (6144 rows stored for 5632), Mistral's w13
+# at 2 and 3 rows (a verify window: the block's rows padded to a sublane
+# group), a grouped launch's blocks of 16 rows (LFM2's 128-row bucket, a packed
+# mixed step of OLMoE's)
+SLICED_LAUNCHES = [
+    ("k-exaone-gate", "experts", 6144, 2048, 16, dict(experts=16)),
+    ("k-exaone-down", "experts-x", 2048, 6144, 16, dict(experts=16)),
+    ("k-exaone-w13", "stacked", 6144, 36864, 16, {}),
+    ("lfm2-gate", "experts", 2048, 1536, 16, dict(experts=64)),
+    ("deepseek-down", "experts-x", 1536, 5120, 16, dict(experts=160)),
+    ("olmoe-down", "experts-x", 1024, 2048, 16, dict(experts=64)),
+    ("brumby-w2", "stacked", 17408, 5120, 8, {}),
+    ("ouro-w2", "stacked", 6144, 2048, 8, {}),
+    ("k-exaone-w2", "stacked", 18432, 6144, 16, {}),
+    ("k-exaone-head", "flat", 6144, 19200, 16, {}),
+    ("mistral-w13-2", "stacked", 4096, 28672, 2, {}),
+    ("mistral-w13-3", "stacked", 4096, 28672, 3, {}),
+    ("lfm2-grouped-16", "grouped", 2048, 1536, 16, dict(experts=64, k=80)),
+    ("olmoe-grouped-16", "grouped", 1024, 2048, 16, dict(experts=64, k=95)),
+]
+
+
+@pytest.mark.parametrize("name,form,n,d,rows,kw", SLICED_LAUNCHES,
+                         ids=[c[0] for c in SLICED_LAUNCHES])
+def test_sliced_q40_body_lowers_for_the_v5e_with_no_op_a_weight_but_the_unpack(
+        one_chip, name, form, n, d, rows, kw):
+    """At 2 to SLICED_MAX_ROWS rows the packed tile goes to the dot as the
+    one-row body's 32-bit words, a 128-row slice at a time (PR 62): the kernel
+    compiles for the chip at the served cells' shapes, and its Mosaic module
+    holds ONE matmul, batched over the slices of the tile (no copy of the body
+    a slice), NO extension and NO integer-to-float
+    conversion of the tile (the scales' mantissas alone are converted), the
+    one-row body's five bitcasts beside the scales', one lane gather over the
+    slices of ``x``, ONE cast to bf16 (the left operand: no weight is
+    rounded) and ONE subtraction (the bias, on the block partials)."""
+    ops, text = _kernel_ops(*_launch(form, n, d, rows, one_chip, **kw))
+    tile_n = q40._tiles(q40.padded_n(n), d)[0]
+    assert q40._body(rows, tile_n) == "sliced"
+    assert "tpu_custom_call" in text
+    assert ops["tpu.matmul"] == 1
+    assert ops["arith.sitofp"] == DOT_BODY_OPS["arith.sitofp"] - 2
+    # (a grouped launch extends its one-bit ``live`` predicate)
+    assert ops["arith.extui"] == DOT_BODY_OPS["arith.extui"] - 1 + (form == "grouped")
+    assert ops["tpu.bitcast"] == DOT_BODY_OPS["tpu.bitcast"] + 5
+    assert ops["tpu.dynamic_gather"] == 1
+    assert ops["arith.truncf"] == 1 and ops["arith.subf"] == 1
+    assert not ops["tpu.transpose"] and not ops["arith.divsi"]
+
+
+# the 64-row stacked launch at Mistral's w13 (a packed mixed step's rows) as PR
+# 41 left the 16-row one (and as the parent of PR 50 lowers it): every op of the
+# dot body with its count
+DOT_BODY_OPS = {
     "builtin.module": 1, "func.func": 5, "func.return": 5, "arith.constant": 50,
     "vector.load": 8, "vector.shape_cast": 7, "arith.extui": 5,
     "vector.broadcast": 19, "arith.shrsi": 3, "arith.shli": 3, "arith.andi": 3,
@@ -1343,18 +1407,19 @@ SIXTEEN_ROW_BODY_OPS = {
 
 @pytest.mark.parametrize("form,kw", [
     ("stacked", {}), ("chosen", dict(experts=4, layers=2, k=2))])
-def test_a_sixteen_row_q40_launch_keeps_its_one_matmul_and_no_other_op(one_chip,
-                                                                      form, kw):
-    """At two rows and more the body is the parent's, op for op: one
+def test_a_sixty_four_row_q40_launch_keeps_its_one_matmul_and_no_other_op(one_chip,
+                                                                         form, kw):
+    """Above SLICED_MAX_ROWS rows the body is PR 41's, op for op: one
     matmul, two casts to bf16, two subtractions (a bias a plane)."""
-    ops, text = _kernel_ops(*_launch(form, 4096, 28672, 16, one_chip, **kw))
+    ops, text = _kernel_ops(*_launch(form, 4096, 28672, 64, one_chip, **kw))
+    assert q40._body(64, 1024) == "dot"
     assert "tpu_custom_call" in text
     # the chosen launch squeezes an expert axis off its output block and reads
     # its planes from a vector: index plumbing, no work of the body
     plumbing = {"arith.constant", "vector.shape_cast", "arith.index_cast"} \
         if form == "chosen" else set()
     assert {k: v for k, v in ops.items() if k not in plumbing} == \
-        {k: v for k, v in SIXTEEN_ROW_BODY_OPS.items() if k not in plumbing}
+        {k: v for k, v in DOT_BODY_OPS.items() if k not in plumbing}
 
 
 @pytest.mark.parametrize("t", [1, 16], ids=["pure-decode", "mixed"])

@@ -2,7 +2,8 @@
 
 Times the kernel on one chip at the matmul shapes of the benchmark's
 configurations (Mistral-7B, OLMoE-1B-7B, DeepSeek-V2, a tp=4 shard of
-Yi-34B; K-EXAONE's held experts under ``--body``): the flat and stacked
+Yi-34B; under ``--body`` also K-EXAONE's, LFM2's, Brumby's, Ouro's and
+Falcon-H1's): the flat and stacked
 forms, and the experts form (``q40_mm_experts``) at each model's expert
 count.  Every measurement happens *inside one jitted ``lax.scan``* cycling
 the layer index, exactly like the decode loop runs the kernel: a host-side
@@ -22,18 +23,28 @@ keeps inside the scan is timed with the launch (PERF.md §6, PR 41).
 
 ``--body`` takes a fourth argument, the bodies to time (default ``rule``).
 ``rule`` is the kernel as the program runs it (``q40._body``: one row
-contracts the raw nibbles a quantization block at a time, more rows the
-dequantized tile in one dot; since PR 58 the one-row operand is made of the
-packed tile's 32-bit words, ``q40._nibbles_as``).  ``dot`` is the dot at every
-row count, which at one row is the body every program ran up to PR 48 (≈5.5 VPU
-ops a weight).  ``half-dot`` is that body with the dot over HALF of the tile
+contracts the raw nibbles a quantization block at a time, 2 to
+``q40.SLICED_MAX_ROWS`` rows a 128-row slice of the tile at a time by the same
+algebra (PR 62), more rows the dequantized tile in one dot; since PR 58 the
+few-row operand is made of the packed tile's 32-bit words,
+``q40._nibbles_as``).  ``dot`` is the dot at every row count, which at one row
+is the body every program ran up to PR 48 and at 2 to 16 rows up to PR 60
+(≈5.5 VPU ops a weight).  ``sliced`` is the sliced body at 2 to 32 rows
+whatever the constant says: ``--body <shapes> 2,4,8,16,32 sliced,dot`` is the
+table that set it (the shapes of the eight served cells are in BODY_SHAPES;
+PERF.md §6, PR 62: −24 to −32% a launch at 2 to 8 rows, −14 to −24% at 16,
++0.2 to +3.6% at 32), and ``sliced-from-1`` the same from ONE row (the block
+padded to eight: what the one-row launch would pay for sharing the body).
+``half-dot`` is the dot body with the dot over HALF of the tile
 (the lo nibble planes; the hi planes are unpacked as ever and kept alive by a
 float32 sum, ≈0.75 op a weight more) and ``dot-twice`` is it with the same VPU
 work and the dot issued TWICE: they answered *do the MXU's 128 x 128 tile loads
-bound the body at few rows?* (no: PERF.md §6, PR 50).  ``grouped`` is PR 50's
-one-row algebra at every row count up to 4, a block-diagonal left operand a row;
-``vpu`` the same algebra with the inner sums on the VPU (no dot), which PR 49
-would have shipped.  The one-row operand's other forms (PERF.md §6, PR 58's
+bound the body at few rows?* (no: PERF.md §6, PR 50).  ``vpu`` is PR 50's
+one-row algebra up to 4 rows with the inner sums on the VPU (no dot), which PR
+49 would have shipped.  (``grouped``, PR 50's body with a block-diagonal left
+operand of ``tile_n / 32`` rows a row at 2 to 4 rows, left the tool in PR 62:
+the sliced body is its few-row form, −26 to −29% where it read −12% to +22%.)
+The one-row operand's other forms (PERF.md §6, PR 58's
 Step 0; ops a weight on the tile, and the kernel's final VLIW bundles a 1024 x
 1024 tile compiled for the v5e): ``nibbles`` is PR 50's (extend, mask or shift,
 int -> f32 -> bf16: ≈3.5-4, 1846 bundles); ``bytes`` the ROADMAP's first form
@@ -43,10 +54,16 @@ int -> f32 -> bf16: ≈3.5-4, 1846 bundles); ``bytes`` the ROADMAP's first form
 ``words128`` the words with ``0x4300 | v`` = 128 + v (1.375, 1151; its sums 17
 times the nibbles' read 7e-7 of the reference where 16 + v reads 9e-8);
 ``touch`` no body at all (the scales decoded, eight rows looked at, 301): what
-a launch costs when the pipeline's DMA and its grid steps are all there is.
+a launch costs when the pipeline's DMA and its grid steps are all there is, at
+one row and at the sliced body's rows.
 All of them are patched into the loaded module for the run (``q40._body``,
-``q40._contract_dot``, ``q40._contract_grouped``, ``q40._nibbles_as``,
-``q40._words_bf16``); the program has no switch for them.
+``q40._contract_dot``, ``q40._contract_grouped``, ``q40._contract_sliced``,
+``q40._nibbles_as``, ``q40._words_bf16``); the program has no switch for them.
+
+``--check`` runs the rule's body at ``--body``'s shapes on the chip and prints
+its largest difference from ``x @ dequantize(qt, float32)`` as a share of the
+largest output: the few-row bodies read 1e-7, the dot body the bf16 round of a
+weight, 1.5e-3 (ROADMAP D17).
 
 ``--chosen`` times a decoded row's routed experts at SmallThinker's shapes
 (6 of 64) and LFM2's (4 of 64): one launch over the chosen planes
@@ -76,7 +93,9 @@ Usage: python tools/sweep_q40.py --tiles [ds_gate,yi_wo]  # tile pairs at 1, 16,
        python tools/sweep_q40.py --rows [head,w13]        # rows x row block
        python tools/sweep_q40.py --body [w2,ds_down [16,32,64 [rule,dot,dot-twice,vpu]]]  # the body at the rule's tiles, 1 to 512 rows or the rows given
        python tools/sweep_q40.py --body w13,w2,yi_kv,st_down 1 rule,nibbles,bytes,words128,touch  # the one-row operand's forms
+       python tools/sweep_q40.py --body kx_gate,kx_down,w13,fh_w2 2,8,16,32 sliced,dot  # the table behind q40.SLICED_MAX_ROWS
        python tools/sweep_q40.py --chosen [st_gate]       # one launch a row's experts, or one each
+       python tools/sweep_q40.py --check [kx_gate,w13 [2,16,33]]  # the rule's body against the float32 reference
 """
 
 from __future__ import annotations
@@ -148,7 +167,27 @@ BODY_SHAPES = MISTRAL + [s for s in SHAPES if s.name.startswith("yi_")
                          or s.experts and "pad" not in s.name] + [
     Shape("kx_gate", 6144, 2048, 2, 16), Shape("kx_down", 2048, 6144, 2, 16, True),
     # LFM2-24B-A2B's short-convolution projections (hidden 2048; in: B, C, x)
-    Shape("lfm2_conv_in", 2048, 6144, 4), Shape("lfm2_conv_out", 2048, 2048, 4)]
+    Shape("lfm2_conv_in", 2048, 6144, 4), Shape("lfm2_conv_out", 2048, 2048, 4),
+    # the served cells' other matmuls (PR 62: the table that set
+    # q40.SLICED_MAX_ROWS).  K-EXAONE's dense shapes (16 rows a step): q | k | v,
+    # wo, the shared expert, the leading dense layer, the head's share
+    Shape("kx_qkv", 6144, 10240, 4), Shape("kx_wo", 8192, 6144, 4),
+    Shape("kx_shared13", 6144, 4096, 4), Shape("kx_w13", 6144, 36864, 2),
+    Shape("kx_w2", 18432, 6144, 2), Shape("kx_head", 6144, 19200, 0),
+    # LFM2's 64 experts, all of them walked by a 16-row step
+    Shape("lfm2_gate_all", 2048, 1536, 2, 64),
+    Shape("lfm2_down_all", 1536, 2048, 2, 64, True),
+    # Brumby-14B (8 rows a step)
+    Shape("br_qkv", 5120, 7168, 4), Shape("br_wo", 5120, 5120, 4),
+    Shape("br_w13", 5120, 34816, 2), Shape("br_w2", 17408, 5120, 2),
+    Shape("br_head", 5120, 151936, 0),
+    # Ouro-2.6B (8 rows); 5632 is stored as 6144 (q40.padded_n)
+    Shape("ouro_qkv", 2048, 6144, 4), Shape("ouro_wo", 2048, 2048, 4),
+    Shape("ouro_w13", 2048, 11264, 4), Shape("ouro_w2", 6144, 2048, 4),
+    Shape("ouro_head", 2048, 49152, 0),
+    # Falcon-H1-34B (32 rows): the mixer's in-projection, the MLP, the head
+    Shape("fh_in", 5120, 9216, 4), Shape("fh_w13", 5120, 43008, 2),
+    Shape("fh_w2", 21504, 5120, 2), Shape("fh_head", 5120, 261120, 0)]
 BODY_ROWS = (1, 16, 128, 256, 512)
 # (rows, row block): None is the code's own choice (one block of every row
 # up to 128, q40._row_block above), "xla" the dequantize-then-dot path
@@ -354,31 +393,6 @@ def _contract_vpu(x_ref, qp, s32):
     return jnp.concatenate(parts, axis=0)
 
 
-def _grouped_rows(x_ref, qp, s32):
-    """PR 50's one-row body (a conversion a nibble, logical row order) for a
-    block of a few rows: one block-diagonal left operand of ``nb`` rows an
-    activation row, set one above the other for ONE dot of ``rows * nb``
-    rows."""
-    import jax
-    import jax.numpy as jnp
-
-    nb, td = s32.shape
-    rows, tn = x_ref.shape
-    vi = qp.astype(jnp.int32)
-    lo = (vi & 0xF).astype(jnp.bfloat16).reshape(nb, 16, td)
-    hi = (vi >> 4).astype(jnp.bfloat16).reshape(nb, 16, td)
-    w = jnp.concatenate([lo, hi], axis=1).reshape(tn, td)
-    own = (jax.lax.broadcasted_iota(jnp.int32, (nb, tn), 1) >> 5
-           == jax.lax.broadcasted_iota(jnp.int32, (nb, tn), 0))
-    xd = jnp.concatenate([jnp.where(own, jnp.broadcast_to(
-        x_ref[r:r + 1, :].astype(jnp.float32), (nb, tn)), 0.0)
-        for r in range(rows)], axis=0)
-    p = jnp.dot(xd.astype(jnp.bfloat16), w, preferred_element_type=jnp.float32)
-    p = (p - 8.0 * xd.sum(axis=1, keepdims=True)).reshape(rows, nb, td) * s32
-    k = _q40()._partial_rows(tn)
-    return p.reshape(rows, nb // k, k, td).sum(axis=1).reshape(rows * k, td)
-
-
 def _words128_bf16(qp):
     """``q40._words_bf16`` with ``0x4300 | v`` = 128 + v in each half: no shift
     for the first pair, 11 ops for 8 weights, sums 17 times the nibbles'."""
@@ -419,22 +433,47 @@ def _touch(x_ref, qp, s32):
     return s32[:k] + qp[:32].astype(jnp.int32).astype(jnp.float32).sum(axis=0, keepdims=True)
 
 
-def _few_rows(rows: int) -> str:
+def _touch_rows(x_ref, qp, s32):
+    """:func:`_touch` for the sliced body's block of rows."""
+    import jax.numpy as jnp
+
+    return jnp.broadcast_to(_touch(x_ref, qp, s32)[:1], (x_ref.shape[0], qp.shape[1]))
+
+
+def _few_rows(rows: int, tile_n: int) -> str:
     return "grouped" if rows <= 4 else "dot"
+
+
+def _sliced_from_1(rows: int, tile_n: int) -> str:
+    """The sliced body from ONE row (its block padded to eight: what the
+    one-row launch would pay if :func:`q40._contract_sliced` served it) to 32
+    whatever ``q40.SLICED_MAX_ROWS`` says."""
+    return "sliced" if rows <= 32 and tile_n % 128 == 0 else "dot"
+
+
+def _sliced_to_32(rows: int, tile_n: int) -> str:
+    """``q40._body`` with the sliced body's edge at 32 rows: the table that
+    set ``q40.SLICED_MAX_ROWS``, re-run."""
+    return "grouped" if rows == 1 else _sliced_from_1(rows, tile_n)
+
+
+def _always_dot(rows: int, tile_n: int) -> str:
+    return "dot"
 
 
 # what each of --body's bodies patches into the loaded q40 module
 BODIES = {
     "rule": {},
-    "dot": dict(_body=lambda rows: "dot"),
-    "half-dot": dict(_body=lambda rows: "dot", _contract_dot=_half_dot),
-    "dot-twice": dict(_body=lambda rows: "dot", _contract_dot=_dot_twice),
-    "grouped": dict(_body=_few_rows, _contract_grouped=_grouped_rows),
+    "dot": dict(_body=_always_dot),
+    "sliced": dict(_body=_sliced_to_32),
+    "sliced-from-1": dict(_body=_sliced_from_1),
+    "half-dot": dict(_body=_always_dot, _contract_dot=_half_dot),
+    "dot-twice": dict(_body=_always_dot, _contract_dot=_dot_twice),
     "vpu": dict(_body=_few_rows, _contract_grouped=_contract_vpu),
     "nibbles": dict(_nibbles_as=lambda tile_n: "nibbles"),
     "words128": dict(_words_bf16=_words128_bf16),
     "bytes": dict(_contract_grouped=_bytes),
-    "touch": dict(_contract_grouped=_touch),
+    "touch": dict(_contract_grouped=_touch, _contract_sliced=_touch_rows),
 }
 
 
@@ -636,6 +675,53 @@ def measure_grouped(only: set | None = None, reps: int = 32,
     return results
 
 
+def check_values(only: set | None = None, rows: tuple = (2, 8, 16, 32)) -> list[dict]:
+    """The kernel's values on the chip at ``--body``'s shapes, one layer of
+    each, as the rule runs it: the largest difference from ``x @
+    dequantize(qt, float32)`` (summed in float64 on the host: a float32
+    product on the chip, at the highest precision, is itself 1e-7 to 3e-7
+    away) as a share of the largest output.  The bodies that round no weight (one row,
+    and 2 to ``q40.SLICED_MAX_ROWS``) read 1e-7; the dot body 1.5e-3, the bf16
+    round of a weight (ROADMAP D17)."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    q40 = _q40()
+    if jax.default_backend() != "tpu":
+        print(json.dumps({"error": "no TPU"}))
+        sys.exit(1)
+    key = jax.random.key(1)
+    results = []
+    for sh in BODY_SHAPES:
+        if only and sh.name not in only:
+            continue
+        n, d, E = sh.n, sh.d, min(sh.experts, 4)
+        k1, k2, k3 = jax.random.split(jax.random.fold_in(key, n + d), 3)
+        qp = jax.random.bits(k1, (max(E, 1), n // 2, d), jnp.uint8)
+        sc = jax.lax.bitcast_convert_type(
+            (0.004 + 0.008 * jax.random.uniform(k2, (max(E, 1), n // 32, d))
+             ).astype(jnp.float16), jnp.uint16)
+        dense = np.asarray(q40.dequantize(q40.QTensor(qp, sc, (n, d))), np.float64)
+        for r in rows:
+            x = jax.random.normal(
+                k3, ((E,) if sh.x_per_expert else ()) + (r, n), jnp.bfloat16)
+            if E:
+                got = q40._pallas_matmul_experts(x, qp, sc, jnp.int32(0), experts=E)
+            else:
+                got = q40._pallas_matmul_stacked(x, qp, sc, jnp.int32(0))[None]
+            ref = np.einsum("...rn,end->erd" if not sh.x_per_expert else "ern,end->erd",
+                            np.asarray(x, np.float64), dense)
+            rec = {"shape": sh.name, "n": n, "d": d, "rows": r,
+                   "body": q40._body(r, q40._tiles(n, d)[0]),
+                   "rel_err": float(np.abs(np.asarray(got, np.float64) - ref).max()
+                                    / np.abs(ref).max())}
+            print(json.dumps(rec), flush=True)
+            results.append(rec)
+        del qp, sc, dense
+    return results
+
+
 def measure_chosen(only: set | None = None, reps: int = 256) -> list[dict]:
     """One decoded row's chosen routed experts: one launch over their planes
     against one launch each, at the rule's tiles (``ms`` is all of them)."""
@@ -648,7 +734,7 @@ def measure_chosen(only: set | None = None, reps: int = 256) -> list[dict]:
 def main():
     modes = {"--tiles": measure_tiles, "--rows": measure_rows,
              "--body": measure_body, "--chosen": measure_chosen,
-             "--grouped": measure_grouped}
+             "--grouped": measure_grouped, "--check": check_values}
     if len(sys.argv) < 2 or sys.argv[1] not in modes:
         sys.exit(__doc__)
     kw = {}
@@ -656,7 +742,7 @@ def main():
         at = sys.argv.index("--routing")
         kw["routing"] = sys.argv[at + 1]
         del sys.argv[at:at + 2]
-    if sys.argv[1] == "--body" and len(sys.argv) > 3:
+    if sys.argv[1] in ("--body", "--check") and len(sys.argv) > 3:
         kw["rows"] = tuple(int(r) for r in sys.argv[3].split(","))
     if sys.argv[1] == "--body" and len(sys.argv) > 4:
         kw["bodies"] = tuple(sys.argv[4].split(","))
