@@ -113,6 +113,19 @@ class ModelConfig:
     mup_dt: float = 1.0
 
     @property
+    def cache_kinds(self) -> tuple:
+        """The kinds of cache the model leaves behind, rows of
+        ``models/cache_kinds.py``, which say what each may do."""
+        from . import cache_kinds as kinds
+        if self.retention_degree:
+            return (kinds.RETENTION,)
+        first = (kinds.LATENT if self.is_mla
+                 else kinds.LOOPED if self.n_loops > 1 else kinds.FULL)
+        beside = (kinds.WINDOW if self.window else kinds.CONV if self.conv_taps
+                  else kinds.SSM if self.has_ssm else None)
+        return (first, beside) if beside else (first,)
+
+    @property
     def has_ssm(self) -> bool:
         """Every block runs a Mamba-2 state-space mixer (``ops/ssm.py``) beside
         its attention: one layer of one row owns keys and values (pages, on a
@@ -333,6 +346,18 @@ class ModelConfig:
         normalised to sum to 1 (LFM2: over their sum ``+ router_norm_eps``)
         and scaled by ``routed_scale``."""
         return self.arch in (mfile.ARCH_EXAONE_MOE, mfile.ARCH_LFM2_MOE)
+
+    @property
+    def ffn_by_segment(self) -> bool:
+        """K-EXAONE's and LFM2's files: a dense FFN in the leading layers and
+        experts after them, each stacked over its own segment."""
+        return self.arch in (mfile.ARCH_EXAONE_MOE, mfile.ARCH_LFM2_MOE)
+
+    @property
+    def router_reads_input(self) -> bool:
+        """SmallThinker's router reads the layer's input as it arrives, before
+        the attention norm, and not the FFN's normed input."""
+        return self.arch == mfile.ARCH_SMALLTHINKER
 
     @property
     def qk_norm(self) -> bool:

@@ -114,7 +114,7 @@ def _exaone_param_shapes(cfg: ModelConfig) -> dict[str, tuple[int, ...]]:
 def param_shapes(cfg: ModelConfig) -> dict[str, tuple[int, ...]]:
     if cfg.is_mla:
         return _mla_param_shapes(cfg)
-    if cfg.arch in (mfile.ARCH_EXAONE_MOE, mfile.ARCH_LFM2_MOE):
+    if cfg.ffn_by_segment:
         return _exaone_param_shapes(cfg)
     L, D, F, V = cfg.n_layers, cfg.dim, cfg.hidden_dim, cfg.vocab_size
     Hq = cfg.n_heads * cfg.head_size       # == D
@@ -228,7 +228,7 @@ def quantize_matmuls(params: Params, cfg: ModelConfig,
     out = dict(params)
     if cfg.is_mla:
         return _quantize_mla(out, fuse)
-    if cfg.arch in (mfile.ARCH_EXAONE_MOE, mfile.ARCH_LFM2_MOE):
+    if cfg.ffn_by_segment:
         # two FFN kinds beside Llama's attention: _quantize_mla's key list
         # covers them (absent keys are skipped), after the q/k/v join
         if fuse:
@@ -363,7 +363,7 @@ def _read_params(mf: mfile.MFile, cfg: ModelConfig, dtype,
     L = cfg.n_layers
     p: Params = {}
     p["embedding"] = mf.tensor("token_embedding").astype(np_dtype)
-    if cfg.is_mla or cfg.arch in (mfile.ARCH_EXAONE_MOE, mfile.ARCH_LFM2_MOE):
+    if cfg.is_mla or cfg.ffn_by_segment:
         read = _read_mla_layers if cfg.is_mla else _read_exaone_layers
         read(mf, cfg, p, np_dtype, codec if quant else None, fuse)
         return _read_tail(mf, p, np_dtype, codec if quant else None)

@@ -47,7 +47,7 @@ from ..ops.kernels import ACTIVATIONS, apply_rope, rmsnorm, rope_angles, softmax
 from ..ops.scopes import part, scope
 from ..ops.sp_attention import ring_attention, sp_gqa_attention, sp_update_kv_cache_at
 from ..parallel.mesh import get_active_mesh
-from . import grouping, packing, windowed
+from . import cache_kinds, grouping, packing, windowed
 from .config import ModelConfig
 from .params import DENSE_FFN_KEYS, MLA_ATT_KEYS, MOE_FFN_KEYS, Params
 
@@ -92,11 +92,9 @@ class KVCache(NamedTuple):
     rv: jax.Array | None = None
     rg: jax.Array | None = None
     rw: jax.Array | None = None
-    # a model with a state-space mixer beside attention in every block
-    # (ops/ssm.py) has BOTH: k and v (the contiguous planes, or the pool's
-    # pages) and, under retention's field names at the mixer's sizes, a row's
-    # state rs (L, B, H, N, P), its rings rk (B), rv (x), rg (dt), its watermark
-    # rw, and the convolution's ring cz (L, B, 1, R, C); no rz
+    # a model with a state-space mixer beside attention in every block has
+    # BOTH: k and v and, in retention's fields at the mixer's sizes (ops/ssm.py),
+    # rs (L, B, H, N, P), rk (B), rv (x), rg (dt), rw, and cz (L, B, 1, R, C)
 
     @property
     def quantized(self) -> bool:
@@ -114,37 +112,22 @@ class KVCache(NamedTuple):
     def pool_planes(self) -> dict[str, jax.Array]:
         """The planes of a paged pool that a page id addresses (what a spill
         or a hand-off record carries page by page): all of them but what
-        belongs to a slot (``SLOT_PLANES``)."""
-        return {n: a for n, a in self.planes().items() if n not in SLOT_PLANES}
+        belongs to a slot (``models/cache_kinds.py``: ``full``'s fields)."""
+        return {n: a for n, a in self.planes().items()
+                if n in cache_kinds.FULL.planes}
 
 
-# the planes of a slot engine's cache that belong to a slot and that no page id
-# addresses, by what they are: the one list the engines' accounts read
-# (``KVCache.pool_planes``, ``runtime/engine.py _note_cache_bytes``,
-# ``Engine.slot_state``)
-SLOT_PLANE_KINDS = {"wk": "window", "wv": "window", "cz": "conv",
-                    **dict.fromkeys(("rs", "rz", "rk", "rv", "rg", "rw"),
-                                    "retention")}
-SLOT_PLANES = tuple(SLOT_PLANE_KINDS)
-
-
-def plane_kind(cfg: ModelConfig, name: str) -> str:
-    """The owner of a cache plane by its field name: ``full`` for what a
-    position (or a page id) addresses, else ``SLOT_PLANE_KINDS``'s; a
-    state-space mixer's planes, whatever field they stand in, are ``ssm``."""
-    kind = SLOT_PLANE_KINDS.get(name, "full")
-    return "ssm" if cfg.has_ssm and kind != "full" else kind
-
-
-def _refuse_int8_beside_a_mixer(quant: bool) -> None:
+def _init_full(shape, cfg: ModelConfig, dtype, quant: bool, rows: int) -> KVCache:
+    """Keys and values of ``shape`` a head (int8 with their scale planes where
+    ``quant``), and a state-space mixer's planes for ``rows`` rows beside them
+    where the model has one."""
     if quant:
-        raise ValueError("a state-space mixer's state has no int8 form "
-                         "(--kv-quant int8 is refused for this architecture)")
-
-
-def _with_ssm(cache: KVCache, cfg: ModelConfig, rows: int, dt) -> KVCache:
-    """``cache`` (keys and values) with a state-space mixer's planes for ``rows``
-    rows beside it, where the model has one."""
+        sshape = shape[:-1] + (1,)
+        return KVCache(jnp.zeros(shape, jnp.int8), jnp.zeros(shape, jnp.int8),
+                       jnp.zeros(sshape, jnp.float32),
+                       jnp.zeros(sshape, jnp.float32))
+    dt = dtype or cfg.dtype
+    cache = KVCache(jnp.zeros(shape, dt), jnp.zeros(shape, dt))
     if not cfg.has_ssm:
         return cache
     if rows < 1:
@@ -168,23 +151,16 @@ def init_kv_cache(cfg: ModelConfig, batch: int, seq_len: int | None = None,
     the HBM read stays int8-sized).
     """
     s = seq_len or cfg.seq_len
-    if cfg.attention_free:
-        return _init_retention(cfg, batch, dtype, quant)
-    if cfg.periodic:
-        return windowed.init_cache(cfg, batch, s, dtype, quant)
-    if cfg.is_mla:
-        return _init_latent((cfg.n_layers, batch, s), cfg, dtype, quant)
-    shape = (cfg.n_cache_planes, batch, cfg.n_kv_heads, s, cfg.head_size)
-    if cfg.has_ssm:
-        _refuse_int8_beside_a_mixer(quant)
     if quant:
-        sshape = shape[:-1] + (1,)
-        return KVCache(jnp.zeros(shape, jnp.int8), jnp.zeros(shape, jnp.int8),
-                       jnp.zeros(sshape, jnp.float32),
-                       jnp.zeros(sshape, jnp.float32))
-    dt = dtype or cfg.dtype
-    return _with_ssm(KVCache(jnp.zeros(shape, dt), jnp.zeros(shape, dt)), cfg,
-                     batch, dt)
+        cache_kinds.refuse_int8(cfg)
+    if cfg.attention_free:
+        return _init_retention(cfg, batch, dtype)
+    if cfg.periodic:
+        return windowed.init_cache(cfg, batch, s, dtype)
+    if cfg.is_mla:
+        return _init_latent((cfg.n_layers, batch, s), cfg, dtype)
+    shape = (cfg.n_cache_planes, batch, cfg.n_kv_heads, s, cfg.head_size)
+    return _init_full(shape, cfg, dtype, quant, batch)
 
 
 # axis order inside one pool page, by name: part of the snapshot and
@@ -194,29 +170,22 @@ PAGE_AXES = "ps,Hkv,Dh"
 LATENT_PAGE_AXES = "ps,r|ps,rope"  # a latent (MLA) pool's page, plane by plane
 
 
-def _init_retention(cfg: ModelConfig, rows: int, dtype, quant: bool) -> KVCache:
+def _init_retention(cfg: ModelConfig, rows: int, dtype) -> KVCache:
     """The cache of a model of retention layers, the contiguous engine's and a
     slot engine's alike (``rows``: sequences, or slots): no layer has keys and
     values, so ``k`` / ``v`` have no layer and no position, and what a row
     leaves behind is its state and its ring of recent positions
     (``ops/retention.py``), a fixed size whatever the context's depth."""
-    if quant:
-        raise ValueError("a retention state has no int8 form (--kv-quant int8 "
-                         "is refused for this architecture)")
     dt = dtype or cfg.dtype
     none = jnp.zeros((0, rows, cfg.n_kv_heads, 0, cfg.head_size), dt)
     return KVCache(none, none, **retention.init_planes(
         cfg.n_layers, rows, cfg.n_kv_heads, cfg.head_size, dt))
 
 
-def _init_latent(lead, cfg: ModelConfig, dtype, quant: bool) -> KVCache:
+def _init_latent(lead, cfg: ModelConfig, dtype) -> KVCache:
     """MLA's cache in either form, ``lead`` = (L, B, S) or (L, P, ps):
     ``kv_lora_rank + qk_rope_head_dim`` values a token a layer in two planes
     (ops/mla.py has why two), nothing per head."""
-    if quant:
-        raise ValueError("a latent (MLA) cache has no int8 form yet: the "
-                         "latent and the rotated key want a scale each "
-                         "(--kv-quant int8 is refused for this architecture)")
     dt = dtype or cfg.dtype
     return KVCache(jnp.zeros(lead + (cfg.kv_lora_rank,), dt),
                    jnp.zeros(lead + (cfg.qk_rope_head_dim,), dt))
@@ -242,26 +211,21 @@ def init_kv_pool(cfg: ModelConfig, n_pages: int, page_size: int,
     is self-describing: values and scales always travel together through
     spills, snapshots and DLREQ01 hand-offs.
 
-    A windowed model (``cfg.window``) has a pool and a table per layer kind:
-    ``k`` / ``v`` are its FULL layers' pool alone, and its window layers get
-    planes of their own, ``wk`` / ``wv``, in which each of ``slots`` slots
-    owns a ring of pages bounded by the window (``models/windowed.py
-    init_pool``; ``max_pages``: a slot's table width, which bounds the ring).
-    A model with convolution layers (``cfg.conv_taps``) likewise: ``k`` / ``v``
-    its attention layers' pool, ``cz`` its slots' convolution state.  A model
-    with no paged layer at all (``cfg.attention_free``) has a pool of no pages:
-    its slots' states and rings alone, as its contiguous cache.  A model with a
-    state-space mixer beside attention in every block (``cfg.has_ssm``) has a
-    pool for all its layers AND, for each of ``slots`` slots, the mixer's state
-    and rings in every layer (``ops/ssm.py``)."""
+    ``k`` / ``v`` are the pool of the layers that keep keys and values alone
+    (none in a model of retention layers: a pool of no pages); every other kind
+    of plane (``KVCache``) is there once for each of ``slots`` slots, a window
+    layer's as a ring of pages bounded by the window (``models/windowed.py
+    init_pool``; ``max_pages``: a slot's table width, which bounds the ring)."""
+    if quant:
+        cache_kinds.refuse_int8(cfg)
     if cfg.attention_free:
-        return _init_retention(cfg, slots, dtype, quant)
+        return _init_retention(cfg, slots, dtype)
     if cfg.periodic:
-        return windowed.init_pool(cfg, n_pages, page_size, dtype, quant, slots,
+        return windowed.init_pool(cfg, n_pages, page_size, dtype, slots,
                                   max_pages or n_pages)
     if cfg.is_mla:
         # (L, P, ps, ·): the same token-major page, one row a token a plane
-        return _init_latent((cfg.n_layers, n_pages, page_size), cfg, dtype, quant)
+        return _init_latent((cfg.n_layers, n_pages, page_size), cfg, dtype)
     # heads stay one to a row here, whatever their size (``attention.pool_rows``
     # folds narrow heads for a periodic model's pool): this pool is the one a
     # mesh shards by kv head on axis 3 (``kv_pool_sharding``) and that
@@ -269,16 +233,7 @@ def init_kv_pool(cfg: ModelConfig, n_pages: int, page_size: int,
     # refuses by name.  An arch here with heads under 128 lanes (none of the
     # supported ones on one chip) pays the layout copies ``pool_rows`` names.
     shape = (cfg.n_cache_planes, n_pages, page_size, cfg.n_kv_heads, cfg.head_size)
-    if cfg.has_ssm:
-        _refuse_int8_beside_a_mixer(quant)
-    if quant:
-        sshape = shape[:-1] + (1,)
-        return KVCache(jnp.zeros(shape, jnp.int8), jnp.zeros(shape, jnp.int8),
-                       jnp.zeros(sshape, jnp.float32),
-                       jnp.zeros(sshape, jnp.float32))
-    dt = dtype or cfg.dtype
-    return _with_ssm(KVCache(jnp.zeros(shape, dt), jnp.zeros(shape, dt)), cfg,
-                     slots, dt)
+    return _init_full(shape, cfg, dtype, quant, slots)
 
 
 def _mm(x, w, cfg: ModelConfig, kind: str | None = None):

@@ -47,13 +47,9 @@ from ..ops.attention import (gqa_attention_at, live_gqa_attention,
 from ..ops.kernels import apply_rope, rmsnorm
 from ..ops.scopes import part, scope
 from . import grouping, packing
+from .cache_kinds import SLOT_ROWS
 from .config import ModelConfig
 from .params import ATT_KIND_KEYS, CONV_KEYS, DENSE_FFN_KEYS, MOE_FFN_KEYS
-
-# rows of the widest slot step a slot's ring of pages is sized for: the
-# scheduler's default prefill chunk (``--sched-prefill-chunk``), and at least a
-# verify step's ``spec_k + 1``; a wider step is refused by name where traced
-SLOT_ROWS = 16
 
 
 # keys a trip of a full layer's live walk reads for ONE decoded token.  A trip
@@ -64,13 +60,10 @@ SLOT_ROWS = 16
 DECODE_BLOCK = 2048
 
 
-def init_cache(cfg: ModelConfig, batch: int, seq_len: int, dtype, quant: bool):
+def init_cache(cfg: ModelConfig, batch: int, seq_len: int, dtype):
     """The contiguous cache of a windowed model: full planes of ``seq_len``
     positions, rings of ``cfg.window_ring(seq_len)``."""
     from .transformer import KVCache
-    if quant:
-        raise ValueError("a cache with window layers has no int8 form yet "
-                         "(--kv-quant int8 is refused for this architecture)")
     dt = dtype or cfg.dtype
     tail = (cfg.n_kv_heads, seq_len, cfg.head_size)
     full = (cfg.n_full_layers, batch) + tail
@@ -93,14 +86,11 @@ def _conv_state(cfg: ModelConfig, rows: int, dt):
 
 
 def init_pool(cfg: ModelConfig, n_pages: int, page_size: int, dtype,
-              quant: bool, slots: int, max_pages: int):
+              slots: int, max_pages: int):
     """The paged engine's cache of a windowed model: the full layers' pool of
     ``n_pages`` and, for ``slots`` slots, the window layers' rings of
     ``window_pages(window, SLOT_ROWS, page_size, max_pages)`` pages each."""
     from .transformer import KVCache
-    if quant:
-        raise ValueError("a cache with window layers has no int8 form yet "
-                         "(--kv-quant int8 is refused for this architecture)")
     if slots < 1:
         raise ValueError("a windowed model's pool needs the number of slots: "
                          "each owns a ring of pages in the window layers' planes")
@@ -273,11 +263,10 @@ def run_periods(params, cfg: ModelConfig, x, cache, cos, sin, pos, offsets,
     ``n_real``: how many of the ``T`` rows hold a token (a scalar, or ``(B,)``
     on a slot step; ``None``: all), which a convolution layer's state write
     needs of a call wider than its ring."""
-    from ..io import mfile
     from .transformer import _dense_ffn, moe_ffn
     b, t, d = x.shape
     period, n_dense = cfg.window_period, cfg.n_dense_layers
-    router_first = cfg.arch == mfile.ARCH_SMALLTHINKER
+    router_first = cfg.router_reads_input
     keys = [k for k in params if k not in ("embedding", "rms_final", "wcls")]
     # a stack covers all layers, the dense prefix or the expert layers
     dense_keys = [k for k in keys if n_dense and k in DENSE_FFN_KEYS]

@@ -29,12 +29,10 @@ from __future__ import annotations
 import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ..io import mfile
 from ..models.config import ModelConfig
 from ..obs import memory as obs_memory, metrics as obs_metrics, \
     trace as obs_trace
-from ..models.params import (ATT_KIND_KEYS, CONV_KEYS, DENSE_FFN_KEYS, SSM_F32,
-                             MLA_ATT_KEYS, MOE_FFN_KEYS)
+from ..models.params import param_shapes
 
 REPL = P()
 
@@ -71,15 +69,6 @@ def check_tp_constraint(cfg: ModelConfig, tp: int) -> None:
 
 def param_specs(cfg: ModelConfig) -> dict[str, P]:
     """PartitionSpec per parameter (layer-stacked layouts from params.py)."""
-    if cfg.is_mla or cfg.attention_free or cfg.has_ssm or cfg.arch in (
-            mfile.ARCH_EXAONE_MOE, mfile.ARCH_LFM2_MOE):
-        # one device (the engine refuses a tp / sp / ep mesh for these archs):
-        # every stack whole, whatever its fused or unfused name
-        return dict.fromkeys(("embedding", "rms_final", "wcls", "rms_att",
-                              "rms_ffn", "wg") + MLA_ATT_KEYS
-                             + ATT_KIND_KEYS + CONV_KEYS + SSM_F32
-                             + ("ssm_in", "ssm_out")
-                             + DENSE_FFN_KEYS + MOE_FFN_KEYS, REPL)
     specs = {
         "embedding": REPL,                   # root-owned in the reference; replicated here
         "wq": P(None, None, "tp"),           # RowMatmulSlice: out dim = heads
@@ -119,11 +108,15 @@ def param_specs(cfg: ModelConfig) -> dict[str, P]:
         })
     if cfg.post_block_norms:
         specs.update({"rms_moe": REPL, "rms_ffn2": REPL})
+    names = param_shapes(cfg)
+    if any(k.one_device for k in cfg.cache_kinds) and not set(names) <= set(specs):
+        # one device (the engine refuses a tp / sp / ep mesh for such a cache)
+        # and a stack the slicing above has no line for: every stack whole,
+        # under its fused name too.  SmallThinker and Ouro, whose stacks all
+        # have a line, keep it: an argument's spec is part of a program's text
+        # and of its key in the compile cache even where one device makes it moot
+        return dict.fromkeys((*names, "wqkv", "wqkv_a", "w13", "shared_w13"), REPL)
     return specs
-
-
-def param_shardings(cfg: ModelConfig, mesh: Mesh) -> dict[str, NamedSharding]:
-    return {k: NamedSharding(mesh, spec) for k, spec in param_specs(cfg).items()}
 
 
 def kv_cache_spec(seq_axis: str | None = None) -> P:

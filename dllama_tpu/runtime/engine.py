@@ -35,12 +35,11 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from .. import hostenv
 from ..io import mfile
-from ..models import grouping
+from ..models import cache_kinds, grouping
 from ..models.config import ModelConfig
 from ..models.params import Params
-from ..models.transformer import (LATENT_PAGE_AXES, PAGE_AXES,
-                                  SLOT_PLANE_KINDS, forward_last,
-                                  init_kv_cache, plane_kind)
+from ..models.transformer import (LATENT_PAGE_AXES, PAGE_AXES, forward_last,
+                                  init_kv_cache)
 from ..obs import dispatch as obs_dispatch, memory as obs_memory, \
     metrics as obs_metrics, trace as obs_trace
 from ..obs.log import get_logger
@@ -303,34 +302,32 @@ def page_axes(cache) -> str:
     return LATENT_PAGE_AXES if cache.latent else PAGE_AXES
 
 
-def _refuse_mesh_and_int8(mesh, kv_dtype, what: str, why: str) -> None:
+def _refuse_mesh_and_int8(mesh, kv_quant: bool, what: str, why: str) -> None:
     """What a model whose cache is not one per-head plane a layer cannot do
-    yet (``what``: latent attention, DeepSeek-V2; window layers, SmallThinker),
+    yet (``what`` and ``why``: the kind's row in ``models/cache_kinds.py``),
     refused here by name and not discovered in a trace."""
     for ax in ("tp", "sp", "ep"):
         if mesh.shape.get(ax, 1) > 1:
             raise ValueError(
                 f"{what} runs on one device: a {ax}={mesh.shape[ax]} mesh is "
                 f"not supported for this architecture ({why}; not wired)")
-    if kv_dtype == "q8" or (kv_dtype is not None
-                            and jnp.dtype(kv_dtype) == jnp.int8):
+    if kv_quant:
         raise ValueError(f"--kv-quant int8 is not supported with {what}: "
                          "its cache has no int8 form")
 
 
-def _loop_fields(cfg: ModelConfig) -> dict:
-    """What a looped model adds to a snapshot's and a hand-off record's
-    fingerprint: its planes are (pass, layer), so the pass count is part of what
-    a record's leading axis means.  Empty where the stack runs once, so every
-    other arch's digest is what it was."""
-    return {"n_loops": cfg.n_loops} if cfg.n_loops > 1 else {}
-
-
-# what a slot owns, by the kind of its planes (``Engine.slot_state``)
-_SLOT_STATE = {"window": "window layers' rings",
-               "conv": "convolution layers' state",
-               "retention": "retention layers' state",
-               "ssm": "state-space mixers' state"}
+def _model_fields(c: ModelConfig) -> dict:
+    """The model's part of a snapshot's and a hand-off record's fingerprint.  A
+    looped model's planes are (pass, layer), so its pass count is part of what a
+    record's leading axis means; where the stack runs once the key is absent
+    and the digest is what it was."""
+    return {"arch": c.arch, "dim": c.dim, "hidden_dim": c.hidden_dim,
+            "n_layers": c.n_layers, "n_heads": c.n_heads,
+            "n_kv_heads": c.n_kv_heads, "n_experts": c.n_experts,
+            "n_active_experts": c.n_active_experts,
+            "vocab_size": c.vocab_size, "hidden_act": c.hidden_act,
+            "rope_theta": c.rope_theta,
+            **({"n_loops": c.n_loops} if c.n_loops > 1 else {})}
 
 
 class StateRewindTooDeep(ValueError):
@@ -345,26 +342,18 @@ class StateRewindTooDeep(ValueError):
 def _note_cache_bytes(cfg: ModelConfig, cache, tokens: int, batch: int,
                       paged: bool) -> int:
     """Set the cache's gauges from its own arrays and return what one cached
-    token occupies over all layers.  A windowed model's rings hold fewer
-    positions than its full planes, so each plane is counted at its own
-    positions a row: ``kv_cache_bytes{kind="window"}`` is what the bound on
-    the rings saves against ``kind="full"``'s planes per layer.  On a paged
-    engine a token ADDS its bytes in the pool alone (the full layers'): a
-    slot's ring of pages is there whatever the context's depth.  A
-    convolution state (``kind="conv"``) and a retention layer's state and ring
-    (``kind="retention"``) are a fixed size a sequence: a token adds nothing to
-    them on either engine; so are a state-space mixer's state and rings
-    (``kind="ssm"``), which stand BESIDE ``kind="full"``'s keys and values in
-    the same layers."""
-    per_token, by_kind = 0, dict.fromkeys(
-        ("full", *SLOT_PLANE_KINDS.values(), "ssm"), 0)
+    token occupies over all layers, each plane under its owner's label and
+    counted as its owner grows (``models/cache_kinds.py``: ``gauge``,
+    ``grows_by``).  ``kv_cache_bytes{kind="window"}`` is what the bound on the
+    rings saves against ``kind="full"``'s planes per layer; a state-space
+    mixer's planes (``kind="ssm"``) stand BESIDE ``kind="full"``'s."""
+    per_token, by_kind = 0, dict.fromkeys((k.gauge for k in cache_kinds.KINDS), 0)
     for name, a in cache.planes().items():
-        kind = plane_kind(cfg, name)
-        by_kind[kind] += int(a.nbytes)
-        if kind in ("conv", "retention", "ssm") or (paged and kind == "window") \
-                or not a.size:
+        kind = cache_kinds.owner(cfg, name)
+        by_kind[kind.gauge] += int(a.nbytes)
+        if not a.size or not kind.grows_by or (paged and kind.grows_by == "ring"):
             continue
-        positions = tokens if kind == "full" else batch * a.shape[3]
+        positions = tokens if kind.grows_by == "tokens" else batch * a.shape[3]
         per_token += int(a.nbytes) // positions
     for kind, nbytes in by_kind.items():
         obs_metrics.KV_CACHE_BYTES.set(kind, nbytes)
@@ -435,37 +424,16 @@ class Engine:
                 raise ValueError(
                     f"n_experts {cfg.n_experts} not divisible by ep={ep}")
         self.cfg = cfg
-        if cfg.is_mla:
-            _refuse_mesh_and_int8(
-                self.mesh, kv_dtype, "latent attention (MLA)",
-                "the latent cache would be replicated and the heads sharded")
-        if cfg.periodic:
-            _refuse_mesh_and_int8(
-                self.mesh, kv_dtype,
-                f"a {'convolution' if cfg.conv_taps else 'windowed'} "
-                f"({mfile.ARCH_NAMES[cfg.arch]}) model",
-                "its two cache kinds have one placement")
-        if cfg.attention_free:
-            _refuse_mesh_and_int8(
-                self.mesh, kv_dtype,
-                f"a retention ({mfile.ARCH_NAMES[cfg.arch]}) model",
-                "its state a kv head is replicated with its slot")
-            if kv_pages:
-                raise ValueError(
-                    f"a retention ({mfile.ARCH_NAMES[cfg.arch]}) model keeps "
-                    "no keys and values, so it has no pages to count: drop "
-                    "--kv-pages (its slots are admitted by --batch-slots alone)")
-        if cfg.has_ssm:
-            _refuse_mesh_and_int8(
-                self.mesh, kv_dtype,
-                f"a state-space ({mfile.ARCH_NAMES[cfg.arch]}) model",
-                "its state a head is replicated with its slot")
-        if cfg.n_loops > 1:
-            _refuse_mesh_and_int8(
-                self.mesh, kv_dtype,
-                f"a looped ({mfile.ARCH_NAMES[cfg.arch]}) model",
-                "its cache is a plane a (pass, layer), which no placement "
-                "or scale plane has been tried on")
+        # kv_dtype "q8" (or int8) selects the quantized cache: int8 values +
+        # per-position f32 scales (models.transformer.init_kv_cache has why)
+        kv_quant = kv_dtype == "q8" or (
+            kv_dtype is not None and jnp.dtype(kv_dtype) == jnp.int8)
+        for kind in cfg.cache_kinds:
+            what = kind.what.format(arch=mfile.ARCH_NAMES[cfg.arch])
+            if kind.one_device:
+                _refuse_mesh_and_int8(self.mesh, kv_quant, what, kind.one_device)
+            if kv_pages and kind.no_pages:
+                raise ValueError(f"{what} {kind.no_pages}")
         if self.mesh.shape.get("tp", 1) > 1 \
                 and jax.default_backend() != "tpu" \
                 and os.environ.get("DLLAMA_TP_REDUCE", "") != "psum":
@@ -494,12 +462,6 @@ class Engine:
         for dev, nbytes in _resident_param_bytes(self.params).items():
             obs_metrics.PARAM_BYTES_RESIDENT.set(dev, nbytes)
         acct.phase("load_place")
-        # kv_dtype "q8" (or int8) selects the quantized cache: int8 values
-        # + per-position f32 scales — ~2× less cache HBM traffic and
-        # residency than bf16, so max context per chip nearly doubles
-        # (beyond reference; see models.transformer.init_kv_cache)
-        kv_quant = kv_dtype == "q8" or (
-            kv_dtype is not None and jnp.dtype(kv_dtype) == jnp.int8)
         if kv_quant and self.sp > 1:
             raise ValueError("quantized KV cache is not supported on sp "
                              "meshes (shard-local sp cache writes are "
@@ -508,8 +470,9 @@ class Engine:
         # sp × per-chip HBM (capability the reference lacks, SURVEY §5);
         # the same sharding is pinned as jit out_shardings below so cache
         # placement and step outputs can never silently diverge
+        latent = cache_kinds.LATENT in cfg.cache_kinds
         self._cache_sh = sharding.kv_cache_sharding(
-            self.mesh, "sp" if self.sp > 1 else None, latent=cfg.is_mla)
+            self.mesh, "sp" if self.sp > 1 else None, latent=latent)
         # kv_pages > 0 replaces the per-slot contiguous cache with a paged
         # pool + per-slot page tables (ops/attention.py paged section):
         # memory is bounded by live tokens, not batch × seq_len, and the
@@ -535,8 +498,7 @@ class Engine:
             # pool layout (L, P, ps, Hkv, Dh): pages ride the batch ("dp")
             # axis, a page is token-major, so the kv-head axis that tp
             # shards is axis 3 — a spec of its own
-            self._cache_sh = sharding.kv_pool_sharding(self.mesh,
-                                                       latent=cfg.is_mla)
+            self._cache_sh = sharding.kv_pool_sharding(self.mesh, latent=latent)
             # --kv-quant int8: pool pages hold int8 values + per-position
             # f32 scale planes (the Q80 weight codec's trick applied to
             # pages); paged attention dequantizes after the int8-sized
@@ -573,11 +535,13 @@ class Engine:
         acct.cache_built(held)
         weakref.finalize(self, acct.cache_dropped, held)
         self.pos = 0
-        # the one-stream account of a recurrent state: a call may start at a
-        # position whose rows before it lie in [lo, hi) (_state_enter /
-        # _state_wrote): a convolution's ring, or where a state lags the clock
-        # (cfg.folds_state) from its watermark on; a state-space mixer has
-        # both, its convolution ring's low in ``_state_ring_lo``
+        # the one-stream account of a recurrent state (``_state``: its row of
+        # ``models/cache_kinds.py``): a call may start at a position whose rows
+        # before it lie in [lo, hi) (_state_enter / _state_wrote): a
+        # convolution's ring, or where a state lags the clock (the row has
+        # ``folds``) from its watermark on; a state-space mixer has both, its
+        # convolution ring's low in ``_state_ring_lo``
+        self._state = next((k for k in cfg.cache_kinds if k.rewinds), None)
         self._state_lo = self._state_hi = self._state_ring_lo = 0
         # a slot engine's mirror of its slots' watermarks, for the fold
         # counter alone (_note_slot_folds)
@@ -669,25 +633,25 @@ class Engine:
     # retention's watermark, and both rules hold for it.  This engine keeps
     # account of the positions a call may start at, ``[lo, hi]`` less what a
     # call reads before its first row.  An arch with another state brings its
-    # planes into ``KVCache`` and its two rules here (``_state_reach``,
-    # ``_state_wrote``); nothing else in the engines changes.
-    def _state_reach(self) -> int:
-        """Rows before its first that a one-stream call reads from the state's
-        ring: a convolution's ``conv_taps - 1``; a retention layer reads its
-        ring from the watermark on, whatever it holds."""
-        return self.cfg.conv_taps - 1 if self.cfg.conv_taps else 0
-
+    # planes into ``KVCache``, its row into ``models/cache_kinds.py`` and its
+    # two rules here (``state_holds``, ``_state_wrote``); nothing else in the
+    # engines changes.
     def state_holds(self, pos: int) -> bool:
         """Whether a one-stream call may start at ``pos``: always for keys and
         values; with a recurrent state, only while the rows it resumes from
         are still addressed by position."""
-        if not self.cfg.keeps_state or self.paged or pos == 0:
+        st = self._state
+        if st is None or self.paged or pos == 0:
             return True
-        need = max(pos - self._state_reach(), 0)
-        held = self._state_lo <= need and pos <= self._state_hi
-        if self.cfg.has_ssm:  # and the taps - 1 rows before it in the mixer's ring
-            held = held and self._state_ring_lo <= max(pos - self.cfg.ssm_conv + 1, 0)
-        return held
+        # a call reads the ``taps - 1`` rows before its first from a
+        # convolution's ring, and a state that folds its own ring from the
+        # watermark on, whatever it holds
+        taps = st.taps(self.cfg)
+        before = max(pos - taps + 1, 0) if taps else pos
+        if not st.folds:
+            return self._state_lo <= before and pos <= self._state_hi
+        return (self._state_lo <= pos <= self._state_hi
+                and self._state_ring_lo <= before)
 
     def resume_at(self, pos: int) -> bool:
         """Set the position clock to ``pos``, a conversation's cached end
@@ -699,22 +663,14 @@ class Engine:
         if self.state_holds(pos):
             self.pos = pos
             return True
-        self._count_rewind(False)
+        self._state.rewinds.inc(self._state.too_deep)
         self.reset()
         return False
-
-    def _count_rewind(self, held: bool) -> None:
-        if self.cfg.has_ssm:
-            obs_metrics.SSM_STATE_REWINDS.inc("in_ring" if held else "refused")
-        elif self.cfg.attention_free:
-            obs_metrics.RETENTION_REWINDS.inc("in_ring" if held else "refused")
-        else:
-            obs_metrics.CONV_STATE_REWINDS.inc("in_ring" if held else "reprefill")
 
     def _state_enter(self, pos: int) -> None:
         """Before a one-stream call at ``pos``: count a rewind, refuse one that
         left the ring (module-level :class:`StateRewindTooDeep`)."""
-        if not self.cfg.keeps_state or pos == self._state_hi:
+        if self._state is None or pos == self._state_hi:
             return
         if pos > self._state_hi:
             raise StateRewindTooDeep(
@@ -722,7 +678,7 @@ class Engine:
                 f"state has not seen (written up to {self._state_hi}): "
                 "prefill them first")
         held = self.state_holds(pos)
-        self._count_rewind(held)
+        self._state.rewinds.inc("in_ring" if held else self._state.too_deep)
         if not held:
             raise StateRewindTooDeep(
                 f"position {pos} is behind what the recurrent state still "
@@ -730,7 +686,8 @@ class Engine:
                 f"{self._state_hi}: a convolution's ring, or what a retention "
                 "layer or a state-space mixer has not yet folded into its state"
                 + (f", and its convolution's ring from {self._state_ring_lo}"
-                   if self.cfg.has_ssm else "") + "): reset() and prefill "
+                   if self._state.folds and self._state.taps_field else "")
+                + "): reset() and prefill "
                 "the conversation again from position 0")
 
     def _state_wrote(self, pos: int, n_real: int, rows: int) -> None:
@@ -739,17 +696,21 @@ class Engine:
         A convolution's ring holds what it held, less what the rows written
         (``ops/conv.py written``) displaced; a lagging state's watermark is
         where ``ops/retention.py watermark`` puts it."""
-        if self.cfg.folds_state:
+        st = self._state
+        if st is None:
+            return
+        taps = st.taps(self.cfg)
+        if st.folds:
             was = self._state_lo if pos else 0  # position 0 starts a sequence
             lo = retention.watermark(was, pos + n_real)
-            self._count_folds((lo - was) // retention.FOLD)
+            st.folds.inc(int((lo - was) // retention.FOLD) * self.cfg.n_layers)
             self._state_lo, self._state_hi = lo, pos + n_real
-        if self.cfg.has_ssm:
-            self._state_ring_lo = self._ring_low(
-                self._state_ring_lo, pos, n_real, rows, self.cfg.ssm_conv)
-        if self.cfg.conv_taps:
+            if taps:
+                self._state_ring_lo = self._ring_low(
+                    self._state_ring_lo, pos, n_real, rows, taps)
+        else:
             self._state_lo, self._state_hi = self._ring_low(
-                self._state_lo, pos, n_real, rows, self.cfg.conv_taps), pos + n_real
+                self._state_lo, pos, n_real, rows, taps), pos + n_real
 
     @staticmethod
     def _ring_low(lo: int, pos: int, n_real: int, rows: int, taps: int) -> int:
@@ -761,30 +722,20 @@ class Engine:
         return max(pos + first if first else max(min(lo, pos),
                                                  pos + count - conv.RING), 0)
 
-    def _count_folds(self, blocks: int) -> None:
-        """``blocks`` blocks folded into a state in every layer."""
-        folds = (obs_metrics.SSM_FOLDS if self.cfg.has_ssm
-                 else obs_metrics.RETENTION_FOLDS)
-        folds.inc(int(blocks) * self.cfg.n_layers)
-
     def _note_slot_folds(self, pos_rows_np, clock_np) -> None:
         """A slot dispatch's folds, for ``retention_folds`` / ``ssm_folds``: the
         host's mirror of each slot's watermark, moved by the device's own rule
         to the clock the dispatch leaves."""
-        if not self.cfg.folds_state:
+        if not (self._state and self._state.folds):
             return
         was = np.where(pos_rows_np == 0, 0, self._slot_marks)
         self._slot_marks = retention.watermark(was, clock_np)
-        self._count_folds(np.sum((self._slot_marks - was) // retention.FOLD))
+        blocks = int(np.sum((self._slot_marks - was) // retention.FOLD))
+        self._state.folds.inc(blocks * self.cfg.n_layers)  # a block in every layer
 
     def _max_burst(self, chunk: int) -> int:
-        """A decode burst of a model with a recurrent state is capped so that
-        the deepest rewind (two pipelined bursts less one position) stays
-        addressed by position."""
-        if self.cfg.folds_state:
-            chunk = min(chunk, retention.max_burst())
-        taps = self.cfg.conv_taps or self.cfg.ssm_conv
-        return min(chunk, conv.max_burst(conv.RING, taps)) if taps else chunk
+        """``chunk`` within what a recurrent state's rewinds allow."""
+        return self._state.max_burst(self.cfg, chunk) if self._state else chunk
 
     # -- state snapshot/restore (runtime/snapshot.py format) -----------
     def config_fingerprint(self) -> str:
@@ -795,15 +746,8 @@ class Engine:
         independent, so a snapshot taken on one mesh restores onto
         another (device_put reshards)."""
         from . import snapshot as snapfmt
-        c = self.cfg
         fields = {
-            "arch": c.arch, "dim": c.dim, "hidden_dim": c.hidden_dim,
-            "n_layers": c.n_layers, "n_heads": c.n_heads,
-            "n_kv_heads": c.n_kv_heads, "n_experts": c.n_experts,
-            "n_active_experts": c.n_active_experts,
-            "vocab_size": c.vocab_size, "hidden_act": c.hidden_act,
-            **_loop_fields(c),
-            "rope_theta": c.rope_theta,
+            **_model_fields(self.cfg),
             "batch": self.batch, "seq_len": self.seq_len,
             "cache": [[n, str(a.dtype), list(a.shape)]
                       for n, a in self._cache_arrays().items()],
@@ -837,7 +781,7 @@ class Engine:
             arrays["rng_dev_key"] = np.asarray(self._dev_key)
         meta_extra = dict(extra or {})
         meta_extra.setdefault("sampling_path", self.sampling_path)
-        if self.cfg.keeps_state:
+        if self._state is not None:
             meta_extra["conv_state"] = [self._state_lo, self._state_hi,
                                         self._state_ring_lo]
         if self._offsets is not None:
@@ -935,16 +879,9 @@ class Engine:
         if not self.paged:
             raise ValueError("per-request hand-off needs a paged KV cache "
                              "(kv_pages > 0)")
-        c = self.cfg
         k = self.cache.k
         fields = {
-            "arch": c.arch, "dim": c.dim, "hidden_dim": c.hidden_dim,
-            "n_layers": c.n_layers, "n_heads": c.n_heads,
-            "n_kv_heads": c.n_kv_heads, "n_experts": c.n_experts,
-            "n_active_experts": c.n_active_experts,
-            "vocab_size": c.vocab_size, "hidden_act": c.hidden_act,
-            **_loop_fields(c),
-            "rope_theta": c.rope_theta, "seq_len": self.seq_len,
+            **_model_fields(self.cfg), "seq_len": self.seq_len,
             # page shape (ps, Hkv, Dh | ps, C) + dtype, not pool page count, with
             # the axis order by name: a record written head-major (before
             # PR 27) is refused even where Hkv == ps; the codec is
@@ -987,16 +924,13 @@ class Engine:
     @property
     def slot_state(self) -> str:
         """What a slot engine's slots own that no page id addresses (empty:
-        nothing): a windowed model's rings of pages, a convolution model's
-        state, a retention model's state and ring, a state-space mixer's state
-        and rings beside its attention's pages (``KVCache``'s
-        ``SLOT_PLANE_KINDS``, ``plane_kind``).  The scheduler keeps everything
-        that moves a request's cache page by page off while this is set."""
-        kinds = {plane_kind(self.cfg, n) for n in self.cache.planes()
-                 if n in SLOT_PLANE_KINDS}
-        if not kinds or not (self.paged or kinds & {"retention", "ssm"}):
-            return ""
-        return _SLOT_STATE[kinds.pop()]
+        nothing): ``slot_owns`` of the kind's row in ``models/cache_kinds.py``.
+        The scheduler keeps everything that moves a request's cache page by page
+        off while this is set, and its steps within ``slot_rows``."""
+        return next((k.slot_owns for k in self.cfg.cache_kinds if k.slot_owns
+                     and (self.paged or k.slot_unpaged)), "")
+
+    slot_rows = cache_kinds.SLOT_ROWS  # the widest step of a slot that owns one
 
     def _refuse_slot_state(self, what: str) -> None:
         """What moves a request's cache page by page is refused by name for a

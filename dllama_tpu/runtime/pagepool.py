@@ -261,22 +261,6 @@ class RadixTree:
             freed += 1
         return freed, visited
 
-    def drop_all(self) -> int:
-        """Release every retained page (scheduler close/reset)."""
-        freed = 0
-
-        def walk(children):
-            nonlocal freed
-            for nd in children.values():
-                walk(nd.children)
-                self.pool.decref([nd.page])
-                freed += 1
-
-        walk(self._children)
-        self._children = {}
-        self._n_nodes = 0
-        return freed
-
     # -- snapshot plumbing (runtime/snapshot.py DLSNAP02) -------------------
 
     def export(self) -> list:

@@ -294,28 +294,24 @@ class SlotScheduler:
         # allocation and exhaustion surfaces as queueing → 429.
         self.paged = bool(getattr(engine, "paged", False))
         # some models' slots own a state that no page id addresses
-        # (``Engine.slot_state``): a windowed model's window layers keep a
-        # slot's last ``window`` positions in the slot's own ring of pages
-        # (ops/window.py), a convolution layer its state in the slot's own row
-        # (ops/conv.py), a retention layer its state matrix and ring of recent
-        # positions (ops/retention.py; such a model has no pages at all and is
-        # admitted by slot alone).  So nothing may start a slot past position 0
-        # without having written those positions: the radix tree stays off (a
+        # (``Engine.slot_state``, from the kind's row in
+        # ``models/cache_kinds.py``).  So nothing may start a slot past position
+        # 0 without having written those positions: the radix tree stays off (a
         # hit would bind the attention layers' prefix pages and leave the
         # slot's own state empty; ROADMAP M(a)(3), M(b)), and so does
-        # preemption, whose park and resume move a request page by page
+        # preemption, whose park and resume move a request page by page; and a
+        # step fits what the slot's planes were sized for
         self.ring_pages = int(getattr(engine, "ring_pages", 0))
         self.slot_state = str(getattr(engine, "slot_state", ""))
         if self.slot_state:
             prefix_reuse = preempt = False
             if kv_reserve == "optimistic":
                 engine._refuse_slot_state("--kv-reserve optimistic (the spill tier)")
-            from ..models.windowed import SLOT_ROWS
-            if max(int(prefill_chunk), int(spec_k) + 1 if spec else 0) > SLOT_ROWS:
+            if max(int(prefill_chunk), int(spec_k) + 1 if spec else 0) > engine.slot_rows:
                 raise ValueError(
-                    f"a step of more than {SLOT_ROWS} rows (--sched-prefill-chunk "
-                    f"{prefill_chunk}, --spec-k {spec_k}) does not fit a "
-                    f"slot's {self.slot_state}")
+                    f"a step of more than {engine.slot_rows} rows "
+                    f"(--sched-prefill-chunk {prefill_chunk}, --spec-k {spec_k}) "
+                    f"does not fit a slot's {self.slot_state}")
         self.pool: PagePool | None = None
         self.prefix_cache: RadixTree | None = None
         # KV tiering (runtime/kvtier.py): under ``optimistic`` reservation

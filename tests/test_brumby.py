@@ -642,44 +642,6 @@ def test_the_gauges_and_the_ledger_name_the_state(params):
     obs_dispatch.reset()
 
 
-@pytest.mark.parametrize("kw,says", [
-    (dict(kv_dtype="q8"), "kv-quant int8 is not supported with a retention"),
-    (dict(kv_pages=40, kv_page_size=4), "no pages to count: drop --kv-pages"),
-])
-def test_engine_refuses_int8_and_pages_by_name(params, kw, says):
-    with pytest.raises(ValueError, match=says):
-        Engine(CFG, params, mesh=_mesh(), batch=1, **kw)
-
-
-@pytest.mark.parametrize("axis", ["tp", "sp"])
-def test_engine_refuses_a_mesh_by_name(params, axis):
-    mesh = make_mesh(**{axis: 2}, devices=jax.devices()[:2])
-    with pytest.raises(ValueError, match=f"a retention .brumby. model runs on one "
-                                         f"device: a {axis}=2 mesh"):
-        Engine(CFG, params, mesh=mesh, batch=1)
-
-
-def test_what_moves_a_request_page_by_page_is_refused_by_name(params):
-    eng = Engine(CFG, params, mesh=_mesh(), batch=2)
-    with pytest.raises(ValueError, match="hand-off .* not supported for a brumby "
-                                         "model: a slot's retention layers' state"):
-        eng.handoff_fingerprint()
-    with pytest.raises(ValueError, match="retention layers' state cannot be "
-                                         "carried page by page"):
-        eng.write_pool_pages([1], {})
-    with pytest.raises(ValueError, match="kv-reserve optimistic"):
-        SlotScheduler(eng, kv_reserve="optimistic")
-    with pytest.raises(ValueError, match="does not fit a slot's retention "
-                                         "layers' state"):
-        SlotScheduler(eng, prefill_chunk=32)
-    sched = SlotScheduler(eng, prefix_reuse=True, preempt=True)
-    try:  # the radix tree and preemption are off whatever was asked
-        assert sched.prefix_cache is None and not sched.preempt
-        assert sched.handoff_export_all() == {} and sched.checkpoint_export("x") is None
-    finally:
-        sched.close()
-
-
 def test_scopes_name_the_operator_inside_the_stages_it_passes(params):
     text = jax.jit(lambda c: forward_slots(
         params, CFG, jnp.zeros((1, 4), jnp.int32), c, jnp.zeros((1,), jnp.int32),

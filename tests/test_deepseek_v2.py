@@ -530,24 +530,6 @@ def test_snapshot_round_trip_of_a_latent_cache(params, tmp_path):
     assert again == rest  # the restored latent cache continues the stream
 
 
-@pytest.mark.parametrize("kw,says", [
-    (dict(kv_dtype="q8"), "--kv-quant int8 is not supported with latent attention"),
-    (dict(kv_dtype=jnp.int8), "--kv-quant int8 is not supported with latent attention"),
-    (dict(mesh=("tp", 2)), "a tp=2 mesh is not supported for this architecture"),
-    (dict(mesh=("sp", 2)), "a sp=2 mesh is not supported for this architecture"),
-    (dict(mesh=("ep", 2)), "a ep=2 mesh is not supported for this architecture"),
-])
-def test_what_mla_cannot_do_yet_is_refused_by_name(params, kw, says):
-    kw = dict(kw)
-    if "mesh" in kw:
-        ax, n = kw["mesh"]
-        kw["mesh"] = make_mesh(**{ax: n}, devices=jax.devices()[:n])
-    with pytest.raises(ValueError, match=says):
-        Engine(CFG, params, batch=1, **kw)
-    with pytest.raises(ValueError, match="no int8 form"):
-        init_kv_pool(CFG, 4, 4, quant=True)
-
-
 # ---- tracing -------------------------------------------------------------------
 
 _OP_NAME = re.compile(r"op_name=\"([^\"]+)\"")

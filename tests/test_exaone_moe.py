@@ -316,16 +316,6 @@ def test_a_slot_at_forty_windows_keeps_a_ring_of_window_pages(want):
     assert sum(ring_pages_recycled(p, p + 1, 4, ring) for p in range(640)) == 151
 
 
-def test_a_step_wider_than_the_ring_is_refused_by_name(params):
-    cache = init_kv_pool(CFG, 40, 4, slots=1, max_pages=24)
-    table = jnp.asarray(np.arange(1, 25, dtype=np.int32)[None])
-    with pytest.raises(ValueError, match="does not fit a window layer's ring"):
-        forward_slots(params, CFG, jnp.zeros((1, 32), jnp.int32), cache,
-                      jnp.zeros((1,), jnp.int32), jnp.full((1,), 32, jnp.int32), table)
-    with pytest.raises(ValueError, match="needs the number of slots"):
-        init_kv_pool(CFG, 40, 4)
-
-
 # ---- moe_ffn: every strategy, and the share ---------------------------------
 
 def _layer_params(cfg, seed=11):
@@ -503,42 +493,6 @@ def test_engine_scheduler_contiguous_and_paged_serve_the_same_tokens(
     assert recycled == (-(-42 // 4) - 9) + (-(-60 // 4) - 9) == 8, recycled
     sites = obs_dispatch.dispatches()
     assert "kv_dense/window-ring" in sites and "kv_dense/window-gather" not in sites
-
-
-@pytest.mark.parametrize("kw,says", [
-    (dict(kv_dtype="q8"), "--kv-quant int8 is not supported with a windowed"),
-    (dict(kv_dtype="q8", kv_pages=20, kv_page_size=4),
-     "--kv-quant int8 is not supported with a windowed"),
-])
-def test_engine_refuses_int8_by_name(params, kw, says):
-    with pytest.raises(ValueError, match=says):
-        Engine(CFG, params, mesh=_mesh(), batch=1, **kw)
-
-
-@pytest.mark.parametrize("axis", ["tp", "sp", "ep"])
-def test_engine_refuses_meshes_by_name(params, axis):
-    if len(jax.devices()) < 2:
-        pytest.skip("needs two devices")
-    mesh = make_mesh(**{axis: 2}, devices=jax.devices()[:2])
-    with pytest.raises(ValueError, match=r"windowed \(exaone_moe\) model runs on one device"):
-        Engine(CFG, params, mesh=mesh, batch=1)
-
-
-def test_what_moves_a_request_page_by_page_is_refused_by_name(params):
-    eng = Engine(CFG, params, mesh=_mesh(), batch=2, kv_pages=49, kv_page_size=4)
-    with pytest.raises(ValueError, match="hand-off .* not supported for a exaone_moe"):
-        eng.handoff_fingerprint()
-    with pytest.raises(ValueError, match="rings cannot be carried page by page"):
-        eng.write_pool_pages([1], {})
-    with pytest.raises(ValueError, match="kv-reserve optimistic"):
-        SlotScheduler(eng, kv_reserve="optimistic")
-    with pytest.raises(ValueError, match="does not fit a slot's window layers' rings"):
-        SlotScheduler(eng, prefill_chunk=32)
-    sched = SlotScheduler(eng)
-    try:
-        assert sched.handoff_export_all() == {} and sched.checkpoint_export("x") is None
-    finally:
-        sched.close()
 
 
 def test_snapshot_carries_both_kinds_of_plane(params, tmp_path, small_chunk):

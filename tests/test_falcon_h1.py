@@ -621,42 +621,6 @@ def test_the_gauges_and_the_ledger_name_the_three_owners(params):
     obs_dispatch.reset()
 
 
-def test_engine_refuses_int8_by_name(params):
-    with pytest.raises(ValueError, match="kv-quant int8 is not supported with a "
-                                         "state-space .falcon_h1. model"):
-        Engine(CFG, params, mesh=_mesh(), batch=1, kv_dtype="q8")
-
-
-@pytest.mark.parametrize("axis", ["tp", "sp"])
-def test_engine_refuses_a_mesh_by_name(params, axis):
-    mesh = make_mesh(**{axis: 2}, devices=jax.devices()[:2])
-    with pytest.raises(ValueError, match=f"a state-space .falcon_h1. model runs on "
-                                         f"one device: a {axis}=2 mesh"):
-        Engine(CFG, params, mesh=mesh, batch=1)
-
-
-def test_what_moves_a_request_page_by_page_is_refused_by_name(params):
-    eng = Engine(CFG, params, mesh=_mesh(), batch=2, seq_len=64, kv_pages=40,
-                 kv_page_size=4)
-    with pytest.raises(ValueError, match="hand-off .* not supported for a falcon_h1 "
-                                         "model: a slot's state-space mixers' state"):
-        eng.handoff_fingerprint()
-    with pytest.raises(ValueError, match="state-space mixers' state cannot be "
-                                         "carried page by page"):
-        eng.write_pool_pages([1], {})
-    with pytest.raises(ValueError, match="kv-reserve optimistic"):
-        SlotScheduler(eng, kv_reserve="optimistic")
-    with pytest.raises(ValueError, match="does not fit a slot's state-space "
-                                         "mixers' state"):
-        SlotScheduler(eng, prefill_chunk=32)
-    sched = SlotScheduler(eng, prefix_reuse=True, preempt=True)
-    try:  # the radix tree and preemption are off whatever was asked
-        assert sched.prefix_cache is None and not sched.preempt
-        assert sched.handoff_export_all() == {} and sched.checkpoint_export("x") is None
-    finally:
-        sched.close()
-
-
 def test_scopes_tell_the_two_mixers_of_one_layer_apart(params):
     cache = init_kv_pool(CFG, 12, 4, slots=1, max_pages=12)
     table = jnp.asarray(np.arange(12, dtype=np.int32)[None])

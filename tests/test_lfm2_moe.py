@@ -667,15 +667,6 @@ def test_the_cost_model_asks_what_the_trace_asks(monkeypatch, head_dim, fused):
     assert obs_cost.model_from_engine(eng).fused is fused
 
 
-def test_a_step_wider_than_the_state_ring_is_refused_by_name(params):
-    cache = init_kv_pool(CFG, 40, 4, slots=1, max_pages=40)
-    table = jnp.asarray(np.arange(40, dtype=np.int32)[None])
-    with pytest.raises(ValueError, match="does not fit a convolution layer's state ring"):
-        forward_slots(params, CFG, jnp.zeros((1, 64), jnp.int32), cache,
-                      jnp.zeros((1,), jnp.int32), jnp.full((1,), 64, jnp.int32),
-                      table)
-
-
 @pytest.mark.parametrize("paged,head_dim", [(True, 8), (False, 8), (True, 64)],
                          ids=["paged", "contiguous", "paged-heads-of-64"])
 def test_the_scheduler_serves_the_reference_token_for_token(params, want, paged,
@@ -866,45 +857,6 @@ def test_the_gauges_and_the_ledger_name_the_state(params):
     assert one.slot_state == "" and one.kv_bytes_per_token == 2 * 2 * 2 * 8 * 4
     one.prefill([int(t) for t in TOKS[:5]])
     assert "conv/ring" in obs_dispatch.dispatches()
-
-
-@pytest.mark.parametrize("kw,says", [
-    (dict(kv_dtype="q8"), "--kv-quant int8 is not supported with a convolution"),
-    (dict(kv_dtype="q8", kv_pages=16, kv_page_size=4),
-     "--kv-quant int8 is not supported with a convolution"),
-])
-def test_engine_refuses_int8_by_name(params, kw, says):
-    with pytest.raises(ValueError, match=says):
-        Engine(CFG, params, mesh=_mesh(), batch=1, **kw)
-
-
-@pytest.mark.parametrize("axis", ["tp", "sp", "ep"])
-def test_engine_refuses_meshes_by_name(params, axis):
-    mesh = make_mesh(**{axis: 2}, devices=jax.devices()[:2])
-    with pytest.raises(ValueError,
-                       match=f"a convolution \\(lfm2_moe\\) model runs on one "
-                             f"device: a {axis}=2 mesh"):
-        Engine(CFG, params, mesh=mesh, batch=1)
-
-
-def test_what_moves_a_request_page_by_page_is_refused_by_name(params):
-    eng = Engine(CFG, params, mesh=_mesh(), batch=2, kv_pages=40, kv_page_size=4)
-    with pytest.raises(ValueError, match="hand-off .* not supported for a lfm2_moe"):
-        eng.handoff_fingerprint()
-    with pytest.raises(ValueError, match="convolution layers' state cannot "
-                                         "be carried page by page"):
-        eng.write_pool_pages([1], {})
-    with pytest.raises(ValueError, match="kv-reserve optimistic"):
-        SlotScheduler(eng, kv_reserve="optimistic")
-    with pytest.raises(ValueError, match="does not fit a slot's convolution "
-                                         "layers' state"):
-        SlotScheduler(eng, prefill_chunk=32)
-    sched = SlotScheduler(eng, prefix_reuse=True, preempt=True)
-    try:  # the radix tree and preemption are off whatever was asked
-        assert sched.prefix_cache is None and not sched.preempt
-        assert sched.handoff_export_all() == {} and sched.checkpoint_export("x") is None
-    finally:
-        sched.close()
 
 
 def test_snapshot_carries_the_state_and_its_account(params, tmp_path):

@@ -479,20 +479,6 @@ def test_the_gauges_and_the_ledger_name_the_loop(params):
     assert obs_metrics.MODEL_LOOP_PASSES.json_value() == 1
 
 
-def test_engine_refuses_int8_by_name(params):
-    with pytest.raises(ValueError, match="kv-quant int8 is not supported with a "
-                                         "looped .ouro. model"):
-        Engine(CFG, params, mesh=_mesh(), batch=1, kv_dtype="q8")
-
-
-@pytest.mark.parametrize("axis", ["tp", "sp"])
-def test_engine_refuses_a_mesh_by_name(params, axis):
-    mesh = make_mesh(**{axis: 2}, devices=jax.devices()[:2])
-    with pytest.raises(ValueError, match=f"a looped .ouro. model runs on one "
-                                         f"device: a {axis}=2 mesh"):
-        Engine(CFG, params, mesh=mesh, batch=1)
-
-
 # ---- the converter -----------------------------------------------------------
 
 OURO_HF = dict(

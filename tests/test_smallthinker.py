@@ -457,24 +457,6 @@ def test_snapshot_round_trip_of_the_two_cache_kinds(params, tmp_path, small_chun
     assert other.config_fingerprint() != eng.config_fingerprint()
 
 
-@pytest.mark.parametrize("kw,says", [
-    (dict(kv_dtype="q8"), "--kv-quant int8 is not supported with a windowed"),
-    (dict(kv_dtype=jnp.int8), "--kv-quant int8 is not supported with a windowed"),
-    (dict(mesh=("tp", 2)), "a tp=2 mesh is not supported for this architecture"),
-    (dict(mesh=("sp", 2)), "a sp=2 mesh is not supported for this architecture"),
-    (dict(mesh=("ep", 2)), "a ep=2 mesh is not supported for this architecture"),
-])
-def test_what_a_windowed_model_cannot_do_yet_is_refused_by_name(params, kw, says):
-    kw = dict(kw)
-    if "mesh" in kw:
-        ax, n = kw["mesh"]
-        kw["mesh"] = make_mesh(**{ax: n}, devices=jax.devices()[:n])
-    with pytest.raises(ValueError, match=says):
-        Engine(CFG, params, batch=1, **kw)
-    with pytest.raises(ValueError, match="no int8 form"):
-        init_kv_cache(CFG, 1, quant=True)
-
-
 def test_prefill_chunk_is_a_rule_from_the_shapes():
     real = ModelConfig(arch=mfile.ARCH_SMALLTHINKER, dim=2560, hidden_dim=768,
                        n_layers=52, n_heads=28, n_kv_heads=4, n_experts=64,
