@@ -650,6 +650,15 @@ def child_kernels(rehearse: bool) -> None:
                                "page": ps, "rows": b, "max_pages": maxp},
                   "rel_err": rel_err(got, ref), "tol": ATTN_TOL,
                   "seconds": round(time.perf_counter() - t0, 2)})
+            # a slot's first chunk is copied behind the slot before it, into
+            # the buffer a word carried across the grid's steps names (PR 64):
+            # each slot read alone must give the same bits (tolerance 0)
+            alone = jnp.concatenate([att.fused_paged_attention(
+                q[i:i + 1], k_, v_, layer, table[i:i + 1], pos_t[i:i + 1],
+                interpret=rehearse) for i in range(b)])
+            _say({"kernel": "fused_paged_attention.slot-alone", "t": t,
+                  "geometry": {"heads": name, "rows": b},
+                  "rel_err": rel_err(got, alone), "tol": 0.0})
 
     # the live walk at prefill rows against one-shot attention over the whole
     # contiguous cache, at the one-stream cells' 32k context: a prompt at

@@ -343,7 +343,9 @@ def paged_decode_attention(q: jax.Array, pool_k: jax.Array, pool_v: jax.Array,
 # in chunks of ``_WALK_PAGES``.  The pool stays in HBM; a chunk's pages are
 # copied straight out of ``pool[layer, table[b, p]]`` into one of two VMEM
 # buffers, all of a chunk's copies in flight together and the next chunk's
-# behind the fold of this one — no materialized (B, Hkv, maxp·ps, Dh)
+# behind the fold of this one, the next SLOT's first chunk behind a slot's
+# last fold (PR 64: only the launch's first chunk is copied with nothing
+# beside it) — no materialized (B, Hkv, maxp·ps, Dh)
 # gather, and the online-softmax state is the loop's carry.  Dead pages
 # cost nothing: the loop's trip count is the slot's own.  Dense pools
 # whose rows fill whole lanes only: heads of 128, or narrower heads stored
@@ -399,8 +401,23 @@ def _make_fused_kernel(hq: int, hkv: int, dh: int, ps: int, cp: int,
 
     Ref order: 3 scalar-prefetch refs (layer (1,), page table (B, maxp),
     per-row positions (B,)), then the q block, the K and V pools left in
-    HBM, the output block, and the scratch: two chunk buffers per pool and
-    one DMA semaphore per buffer."""
+    HBM, the output block, and the scratch: two chunk buffers per pool, one
+    DMA semaphore per buffer, and one SMEM word.
+
+    The scratch lives across the grid's steps, which run in order on one
+    core (``"arbitrary"``), and carries two things from slot ``b`` to slot
+    ``b + 1``: the copy of slot ``b + 1``'s first chunk, in flight or landed
+    in the buffer slot ``b``'s last fold did not read, and in the SMEM word
+    which buffer that is (it follows the chunks the slots before walked).
+    Who starts which copy: grid step 0 primes its own chunk 0 into buffer
+    0; every fold starts ONE chunk into the other buffer before it waits for
+    its own, chunk ``c + 1`` of its slot or, at the slot's last chunk, chunk
+    0 of the next slot (table and positions of every slot are
+    scalar-prefetched); the last fold of the last slot starts nothing.  So a
+    slot's first chunk is copied behind the previous slot's last fold, every
+    ``wait`` answers exactly one ``start``, and no copy is in flight when
+    the kernel ends.  The fold is what it was: a slot's output does not
+    depend on the slots beside it, bit for bit."""
     rows = hq * t
     inv_sqrt = np.float32(1.0 / math.sqrt(dh))
     # a token's keys are the ``kvr`` rows of ``width`` lanes it has in the
@@ -412,22 +429,35 @@ def _make_fused_kernel(hq: int, hkv: int, dh: int, ps: int, cp: int,
     def kernel(layer_ref, ptab_ref, pos_ref, q_ref, *rest):
         from jax.experimental import pallas as plx
         from jax.experimental.pallas import tpu as pltpu
-        pools, o_ref, bufs, sem = rest[:2], rest[2], rest[3:5], rest[5]
+        pools, o_ref, bufs = rest[:2], rest[2], rest[3:5]
+        sem, first = rest[5], rest[6]
         b = plx.program_id(0)
+        nxt = jax.lax.min(b + 1, plx.num_programs(0) - 1)
+        has_next = b < nxt
         pos = pos_ref[b]
         layer = layer_ref[0]
-        # the row's last live page: its last query token's (the step's own
-        # keys are in the pool before the read), inside the table
-        last = pos // ps if t == 1 else \
-            jnp.minimum((pos + (t - 1)) // ps, maxp - 1)
-        n_chunks = last // cp + 1
 
-        def start(c, slot):
-            # the last chunk's pages past ``last`` read the last live page
+        # Scalar index arithmetic goes through ``jax.lax``: a ``jnp`` function
+        # (``//``, ``minimum``, ``where``) is a ``jit`` of its own, and every
+        # call of one in here is a nested trace, at every site of every
+        # program of every start (positions are never negative, so ``div``
+        # is the floor)
+        def last_page(p):
+            # a row's last live page: its last query token's (the step's own
+            # keys are in the pool before the read), inside the table
+            return jax.lax.div(p, ps) if t == 1 else \
+                jax.lax.min(jax.lax.div(p + (t - 1), ps), maxp - 1)
+
+        last, last_nxt = last_page(pos), last_page(pos_ref[nxt])
+        n_chunks = jax.lax.div(last, cp) + 1
+
+        def start(r, c, last, slot):
+            # chunk ``c`` of row ``r``, whose last live page is ``last``.  The
+            # last chunk's pages past ``last`` read the last live page
             # again: their keys are masked, and a buffer never holds bytes
             # that were not a page's
             for i in range(cp):
-                page = ptab_ref[b, jnp.minimum(c * cp + i, last)]
+                page = ptab_ref[r, jax.lax.min(c * cp + i, last)]
                 for pool, buf in zip(pools, bufs):
                     pltpu.make_async_copy(pool.at[layer, page],
                                           buf.at[slot, i],
@@ -471,11 +501,16 @@ def _make_fused_kernel(hq: int, hkv: int, dh: int, ps: int, cp: int,
 
         def fold(c, carry):
             m_prev, l_prev, acc = carry
-            slot = jax.lax.rem(c, 2)
+            slot = jax.lax.rem(begin + c, 2)
+            more = c + 1 < n_chunks
 
-            @plx.when(c + 1 < n_chunks)
-            def _next():
-                start(c + 1, 1 - slot)
+            # one copy site for both: the slot's next chunk, else the next
+            # slot's first (the copies are unrolled where this is traced)
+            @plx.when(more | has_next)
+            def _ahead():
+                start(jax.lax.select(more, b, nxt),
+                      jax.lax.select(more, c + 1, jnp.zeros_like(c)),
+                      jax.lax.select(more, last, last_nxt), 1 - slot)
 
             wait(slot)
             k = bufs[0][slot]    # (cp, ps, kvr, width) or (cp, ps * kvr, width)
@@ -506,12 +541,19 @@ def _make_fused_kernel(hq: int, hkv: int, dh: int, ps: int, cp: int,
                 preferred_element_type=jnp.float32)     # (Hq*T, width)
             return m_new, l_new, alpha * acc + pv
 
-        start(0, 0)
+        @plx.when(b == 0)
+        def _prime():
+            first[0] = 0
+            start(0, 0, last, 0)
+
+        begin = first[0]   # the buffer this slot's first chunk is in
         _, l, acc = jax.lax.fori_loop(
             0, n_chunks, fold,
             (jnp.full((rows, 1), _NEG, jnp.float32),
              jnp.zeros((rows, 1), jnp.float32),
              jnp.zeros((rows, width), jnp.float32)))
+        # the buffer the next slot's first chunk went to
+        first[0] = jax.lax.rem(begin + n_chunks, 2)
         out = acc / jnp.maximum(l, 1e-38)
         if f > 1:
             # the second dot gave every head of the row; a query row takes
@@ -545,6 +587,13 @@ def fused_paged_attention(q: jax.Array, pool_k: jax.Array, pool_v: jax.Array,
     walk's chunk.  Tokens past a row's ``n_valid`` read what the gather
     form reads for them (their pages past the row's reservation are
     scratch page 0) and the caller drops them.
+
+    The grid is one slot a step, in order on one core, and the chunk
+    buffers, their two DMA semaphores and one SMEM word are scratch that
+    lives across the steps: a slot's last fold starts the copy of the next
+    slot's first chunk into the buffer it is not reading, and the word says
+    which buffer that was (:func:`_make_fused_kernel`).  A slot's rows are
+    bit for bit what a call of that slot alone gives.
     """
     from jax.experimental import pallas as plx
     from jax.experimental.pallas import tpu as pltpu
@@ -566,16 +615,13 @@ def fused_paged_attention(q: jax.Array, pool_k: jax.Array, pool_v: jax.Array,
         pools = [pool.reshape(*pool.shape[:2], ps * kvr, width)
                  for pool in pools]
 
-    def query_rows():  # built among the call's operands: at f = 1 the order
-        # of the program's equations is the parent's, and so is its cache key
-        qr = q.reshape(b, hq * t, dh)
-        if f == 1:
-            return qr
+    qr = q.reshape(b, hq * t, dh)
+    if f > 1:
         # each query row widened to the pool's row: its head's lanes where
         # its kv head lies in the row, zero in the others
         lane = np.arange(hq)[:, None] // (hq // hkv) % f == np.arange(f)
-        return jnp.where(np.repeat(lane, t, axis=0)[None, :, :, None],
-                         qr[:, :, None, :], 0).reshape(b, hq * t, width)
+        qr = jnp.where(np.repeat(lane, t, axis=0)[None, :, :, None],
+                       qr[:, :, None, :], 0).reshape(b, hq * t, width)
 
     def row_map(bi, *_):
         return (bi, 0, 0)
@@ -592,7 +638,7 @@ def fused_paged_attention(q: jax.Array, pool_k: jax.Array, pool_v: jax.Array,
             out_specs=plx.BlockSpec((1, hq * t, dh), row_map),
             scratch_shapes=[pltpu.VMEM((2, cp, *pool.shape[2:]), pool.dtype)
                             for pool in pools]
-            + [pltpu.SemaphoreType.DMA((2,))]),
+            + [pltpu.SemaphoreType.DMA((2,)), pltpu.SMEM((1,), jnp.int32)]),
         out_shape=jax.ShapeDtypeStruct((b, hq * t, dh), q.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
@@ -600,7 +646,7 @@ def fused_paged_attention(q: jax.Array, pool_k: jax.Array, pool_v: jax.Array,
         name="paged_attn_fused",
     )(jnp.atleast_1d(layer).astype(jnp.int32),
       page_table.astype(jnp.int32), pos_rows.astype(jnp.int32),
-      query_rows(), *pools)
+      qr, *pools)
     return out.reshape(b, hq, t, dh)
 
 
@@ -608,8 +654,11 @@ def fused_paged_attention(q: jax.Array, pool_k: jax.Array, pool_v: jax.Array,
 # LFM2 has fourteen (two attention sites a step program): under a jit the
 # sites of one step width share one trace, 4 s of that cell's ``setup_s`` in
 # the server's process (PERF.md §6, PR 48).  The walk over a pool of one head
-# a row is called bare, as it was: a program's cache key is its jaxpr (PR 46),
-# and wrapping it would give every other served cell's programs a new one.
+# a row is called bare: its programs hold ONE site each (a scan over the
+# layers), so there is no trace to share, and the wrapper costs each of them
+# 0.02 s of trace and lowering and counts the nested trace once more in
+# ``jaxpr_trace_seconds`` (``setup_trace_s`` 20.8-21.6 s with it against
+# 18.5-19.6 without, in Mistral's served cell; PR 64 tried it, PERF.md §6).
 _folded_walk = jax.jit(fused_paged_attention, static_argnames=("interpret",))
 
 
@@ -692,8 +741,11 @@ def paged_gqa_attention_at(q: jax.Array, pool_k: jax.Array, pool_v: jax.Array,
                                       scales is not None, ps,
                                       page_table.shape[1], pool_k.shape[4])
     if use_fused:
+        # ``ahead``: what a slot's last fold copies behind it, the next
+        # slot's first chunk, by construction in b - 1 of b grid steps
         obs_dispatch.record_dispatch(codec, "paged-fused", t=t, s=s,
-                                     page_size=ps, interpret=interp)
+                                     page_size=ps, interpret=interp,
+                                     ahead="slot")
         walk = fused_paged_attention if pool_k.shape[4] == dh else _folded_walk
         return walk(q, pool_k, pool_v, layer, page_table, pos_rows,
                     interpret=interp)

@@ -85,6 +85,15 @@ def test_rehearse_kernels_phase(rehearsal_env, capfd):
     # slot, three head geometries, and at one token over the pool that holds
     # two heads of 64 a row + the live walk at prefill rows, dense/int8 × two
     # positions
+    # + each of the walk's four (geometry, t) against its slots read one a
+    # call, at tolerance 0 (PR 64: what crosses the grid's steps)
+    alone = [r for r in errs if r["kernel"].endswith(".slot-alone")]
+    assert [(r["geometry"]["heads"], r["t"], r["rel_err"], r["tol"])
+            for r in alone] == [("mistral-7b", 16, 0.0, 0.0),
+                                ("olmoe-1b-7b", 16, 0.0, 0.0),
+                                ("lfm2-24b-a2b", 1, 0.0, 0.0),
+                                ("lfm2-24b-a2b", 16, 0.0, 0.0)]
+    errs = [r for r in errs if r not in alone]
     assert len(errs) == 54
     f32 = [r for r in errs if r["kernel"].endswith(".f32")]
     assert "q40.chosen_experts.f32" in [r["kernel"] for r in f32]

@@ -338,6 +338,91 @@ def test_fused_kernel_under_jit():
                                np.asarray(ref, np.float32), atol=tol)
 
 
+# -- a slot's first chunk is copied behind the previous slot's last fold -----
+# (PR 64) depths of a slot, in chunks of the walk: 1, 2, 3; "x" exactly
+# ``_WALK_PAGES`` live pages (one chunk, none of its pages past ``last``);
+# "e" an empty slot (position 0: one page); "z" the table's last position.
+# Which buffer a slot begins in is the parity of the chunks before it.
+_UNITS = "1121322331"   # every ordered pair of 1, 2, 3 chunks
+SLOT_DEPTHS = (
+    ["1", "3", "x", "12", "21", "e3", "3e", "x2", "zz"]
+    + ["".join(p) + tail for p, tail in zip(
+        ("123", "132", "213", "231", "312", "321"),
+        ("xe", "ex", "ez", "ze", "ee", "xx"))]
+    + ["e" + p + "e" for p in ("123", "321")] + ["1e3e2", "2e1e3"]
+    + ["e" + _UNITS + "exz2e", "3x" + _UNITS[::-1] + "e2ze"])
+
+
+def _depth_fixture(depths, t, f, ps=4):
+    """A pool and one slot a letter of ``depths``, whose last query token
+    lies on the last position of the page the letter names."""
+    from dllama_tpu.ops.attention import _WALK_PAGES as cp, pool_rows
+    hkv, g, dh = (4, 2, 64) if f == 2 else (2, 2, 16)
+    b, maxp = len(depths), 3 * cp
+    last = {"1": cp - 3, "2": 2 * cp - 2, "3": 2 * cp, "x": cp - 1,
+            "z": maxp - 1}
+    pos = [0 if d == "e" else (last[d] + 1) * ps - t for d in depths]
+    assert min(pos) >= 0
+    npages = 1 + b * maxp
+    rng = np.random.RandomState(len(depths) * 31 + t)
+    rows = pool_rows(hkv, dh) if f == 2 else (hkv, dh)
+    pk, pv = (jnp.asarray(rng.randn(2, npages, ps, *rows) * 0.3, jnp.bfloat16)
+              for _ in range(2))
+    table = rng.permutation(np.arange(1, npages)).reshape(b, maxp)
+    q = jnp.asarray(rng.randn(b, hkv * g, t, dh) * 0.3, jnp.bfloat16)
+    return (q, pk, pv, jnp.asarray(table, jnp.int32),
+            jnp.asarray(pos, jnp.int32))
+
+
+_walk = jax.jit(fused_paged_attention, static_argnames=("interpret",))
+
+
+@pytest.mark.parametrize("f", [1, 2], ids=["one-head-a-row", "two-a-row"])
+@pytest.mark.parametrize("t", [1, 5, 16])
+@pytest.mark.parametrize("depths", SLOT_DEPTHS)
+def test_a_slot_reads_what_it_reads_alone(depths, t, f):
+    """A call over ``B`` slots gives, slot for slot, exactly (bit for bit)
+    what ``B`` calls of one slot give: the copy a slot's last fold starts
+    for the next slot, and the buffer parity carried across the grid's steps,
+    change who copies a chunk and where to, never what is folded."""
+    assert len(depths) in (1, 2, 5, 16)
+    q, pk, pv, table, pos = _depth_fixture(depths, t, f)
+    layer = jnp.int32(1)
+    got = np.asarray(_walk(q, pk, pv, layer, table, pos, interpret=True),
+                     np.float32)
+    assert np.isfinite(got).all()
+    for i in range(len(depths)):
+        alone = _walk(q[i:i + 1], pk, pv, layer, table[i:i + 1],
+                      pos[i:i + 1], interpret=True)
+        np.testing.assert_array_equal(got[i], np.asarray(alone[0], np.float32),
+                                      err_msg=f"slot {i} of {depths!r}")
+
+
+@pytest.mark.parametrize("dma", ["on_wait", "eager"])
+@pytest.mark.parametrize("t,f", [(1, 1), (16, 1), (5, 2)])
+def test_every_wait_answers_one_start_and_none_is_left(capfd, dma, t, f):
+    """The same read in the TPU interpreter, which keeps the semaphores'
+    counts and leaves scratch memory NaN until something writes it:
+    ``on_wait`` runs a copy only when its semaphore is waited for, so a fold
+    that read a buffer nobody waited on would read NaN; ``eager`` signals at
+    the start, so a copy started and never waited for is a count left at the
+    kernel's exit, which the interpreter prints."""
+    from jax.experimental.pallas import tpu as pltpu
+    q, pk, pv, table, pos = _depth_fixture("e2x31", t, f)
+    want = fused_paged_attention(q, pk, pv, jnp.int32(1), table, pos,
+                                 interpret=True)
+    got = fused_paged_attention(
+        q, pk, pv, jnp.int32(1), table, pos,
+        interpret=pltpu.InterpretParams(dma_execution_mode=dma,
+                                        uninitialized_memory="nan",
+                                        detect_races=True))
+    np.testing.assert_array_equal(np.asarray(got, np.float32),
+                                  np.asarray(want, np.float32))
+    from jax._src.pallas.mosaic.interpret import interpret_pallas_call as ipc
+    assert not ipc.races.races_found
+    assert "non-zero count" not in capfd.readouterr().out
+
+
 # -- e2e greedy byte parity: fused vs fallback -----------------------------
 
 def _sched_streams(overlap, kv_dtype, max_new=20):

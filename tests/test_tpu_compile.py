@@ -112,8 +112,9 @@ def test_q40_experts_matmul_compiles_at_olmoe_shapes(one_chip, name, n, d,
 
 
 # (rows, query heads, kv heads, pages a slot, head size): Llama-2-7B, and the
-# three served geometries (Mistral-7B's GQA at 64 pages, OLMoE's MHA at 128,
-# LFM2's heads of 64 two to a row of the pool).  Tokens a slot: the
+# four served geometries (Mistral-7B's GQA at 64 pages, OLMoE's MHA at 128,
+# LFM2's heads of 64 two to a row of the pool, Ouro's 8 slots of 48 pages at
+# OLMoE's heads).  Tokens a slot: the
 # pure-decode step's one, a verify block of spec_k + 1 = 5, the mixed step's
 # chunk of 16, and the widest block the rule takes (32 for Mistral and OLMoE;
 # 8 for Llama-2-7B, whose 32 kv heads make a chunk 4096 keys; 64 for LFM2,
@@ -121,11 +122,18 @@ def test_q40_experts_matmul_compiles_at_olmoe_shapes(one_chip, name, n, d,
 @pytest.mark.parametrize("t", [1, 5, 16, "widest"])
 @pytest.mark.parametrize("b,hq,hkv,maxp,dh", [
     (4, 32, 32, 64, 128), (16, 32, 8, 64, 128), (16, 16, 16, 128, 128),
-    (16, 32, 8, 128, 64)],
-    ids=["7b", "mistral-7b", "olmoe-1b-7b", "lfm2-24b-a2b"])
+    (16, 32, 8, 128, 64), (8, 16, 16, 48, 128)],
+    ids=["7b", "mistral-7b", "olmoe-1b-7b", "lfm2-24b-a2b", "ouro-2.6b"])
 def test_fused_paged_attention_compiles_at_served_geometry(one_chip, monkeypatch,
                                                            b, hq, hkv, maxp, dh,
                                                            t):
+    """The walk compiles for the v5e with what it carries across the grid's
+    steps (two chunk buffers a pool, two DMA semaphores, one SMEM word: the
+    buffer the next slot's first chunk went to), at the widest score tile
+    too, and copying ahead for the next slot added no copy site: the kernel
+    holds the two a kernel that starts cold at every slot holds, a chunk's
+    pages of both pools each (the copies are unrolled where the kernel is
+    traced: a third site is seconds of every start, PERF.md §6, PR 48)."""
     ps = 16
     n_pages = 1 + b * maxp
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
@@ -141,10 +149,13 @@ def test_fused_paged_attention_compiles_at_served_geometry(one_chip, monkeypatch
         return
     s = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)  # noqa: E731
     pool = s((2, n_pages, ps, kvr, row), jnp.bfloat16)
-    text = jax.jit(att.fused_paged_attention).lower(
+    ops, text = _kernel_ops(att.fused_paged_attention, (
         s((b, hq, t, dh), jnp.bfloat16), pool, pool, s((), jnp.int32),
-        s((b, maxp), jnp.int32), s((b,), jnp.int32)).compile().as_text()
+        s((b, maxp), jnp.int32), s((b,), jnp.int32)))
     assert "tpu_custom_call" in text and "paged_attn_fused" in text
+    copies = 2 * att._WALK_PAGES     # a chunk's pages, of two pools
+    assert ops["tpu.enqueue_dma"] == 2 * copies, ops  # the primer; the fold's
+    assert ops["tpu.wait_dma2"] == copies, ops
     # the pool goes to the kernel as it lies: nothing of its extent is made
     # (a folded pool's page is reshaped to (ps * rows, 128): a bitcast)
     assert f"bf16[2,{n_pages},{ps},{kvr},{row}]" in text

@@ -28,9 +28,18 @@ ledger path it recorded.  PERF.md §6, PR 37 has the table, and the two
 per-kv-head reads of the chunk buffer that were timed against the kept body
 and not kept; PR 48 has the folded pool's.
 
+``--paged --slots 1,8,16,32`` times the fused walk alone over that many
+slots at every live context, and prints for each geometry and ``t`` the
+least-squares fit of a launch's time, ``us_launch + us_slot * slots +
+us_chunk * slots * chunks`` (a chunk is 8 pages): what a slot costs before
+its chunks, which is where a walk that starts cold at every slot pays, apart
+from what a chunk costs (PERF.md §6, PR 64 has both tables; Ouro-2.6B's
+geometry, 16 and 16 heads of 128 over a 48-page table, is its default there).
+
 Usage: python tools/sweep_attn.py [--repo DIR] [--blocks 256,512,1024]
        python tools/sweep_attn.py --paged [--repo DIR] [--geo lfm2-24b-a2b]
                                   [--ts 1,16] [--lives 416,1024,2048]
+                                  [--slots 1,8,16,32]
 """
 
 from __future__ import annotations
@@ -51,7 +60,8 @@ POINTS = [(256, 0), (128, 0), (64, 0), (256, 16384), (16, 300), (1, 300)]
 # the served cells' reads
 PAGED_GEOMETRIES = [("mistral-7b", 32, 8, 128, 32, 1032, 64),
                     ("olmoe-1b-7b", 16, 16, 128, 16, 2056, 64),
-                    ("lfm2-24b-a2b", 32, 8, 64, 8, 2056, 128)]
+                    ("lfm2-24b-a2b", 32, 8, 64, 8, 2056, 128),
+                    ("ouro-2.6b", 16, 16, 128, 16, 1544, 48)]
 PAGED_SLOTS, PAGE = 16, 16
 
 
@@ -82,23 +92,30 @@ def sweep_paged(a, att) -> list[dict]:
 
     forms = {"auto": att.paged_gqa_attention_at, "gather": gather,
              "fused": att.fused_paged_attention}
-    b, ps = (2, PAGE) if a.rehearse else (PAGED_SLOTS, PAGE)
+    ps = PAGE
+    slots = [int(x) for x in a.slots.split(",")] if a.slots else \
+        [2 if a.rehearse else PAGED_SLOTS]
+    if a.slots:   # the fit is the walk's; the gather form is its check
+        del forms["auto"]
     lives = [int(x) for x in a.lives.split(",")] if a.lives else \
         (32, 64) if a.rehearse else (256, 1024)
     results = []
+    geos = a.geo.split(",") if a.geo else ["ouro-2.6b"] if a.slots else None
     for geo, hq, hkv, dh, layers, n_pages, maxp in PAGED_GEOMETRIES:
-        if a.geo and geo not in a.geo.split(","):
+        if geos and geo not in geos:
             continue
         if a.rehearse:
-            layers, maxp = 2, 8
-            n_pages = 1 + b * maxp
+            layers, maxp = 2, 24 if a.slots else 8
+            n_pages = 1 + max(slots) * maxp
+        if n_pages <= max(slots) * maxp:
+            raise SystemExit(f"{geo}'s pool is under {max(slots)} tables")
         kk, kv, kq = jax.random.split(jax.random.PRNGKey(0), 3)
         shape = (layers, n_pages, ps) + att.pool_rows(hkv, dh)
         pk = jax.random.normal(kk, shape, jnp.bfloat16)
         pv = jax.random.normal(kv, shape, jnp.bfloat16)
-        table = jnp.asarray(np.random.RandomState(0).permutation(
-            np.arange(1, n_pages))[:b * maxp].reshape(b, maxp), jnp.int32)
-        for t in (int(x) for x in a.ts.split(",")):
+        pages = np.random.RandomState(0).permutation(np.arange(1, n_pages))
+        for t, b in ((int(x), b) for x in a.ts.split(",") for b in slots):
+            table = jnp.asarray(pages[:b * maxp].reshape(b, maxp), jnp.int32)
             q = jax.random.normal(kq, (b, hq, t, dh), jnp.bfloat16)
             for ctx in lives:
                 # every slot's last query token is the context's last
@@ -128,8 +145,8 @@ def sweep_paged(a, att) -> list[dict]:
                                       pos).astype(jnp.float32)
                     if name == "gather":
                         ref = one
-                    rec = {"geometry": geo, "t": t, "live": ctx, "form": name,
-                           "ms_all_layers": med, "min_ms": low,
+                    rec = {"geometry": geo, "t": t, "slots": b, "live": ctx,
+                           "form": name, "ms_all_layers": med, "min_ms": low,
                            "layers": layers, "repo": a.repo}
                     if name == "auto":
                         rec["ledger"] = path
@@ -138,7 +155,37 @@ def sweep_paged(a, att) -> list[dict]:
                                                / jnp.abs(ref).max())
                     results.append(rec)
                     print(json.dumps(rec), flush=True)
+    if a.slots:
+        results += _slot_fits(results, att._WALK_PAGES * ps, a.repo)
     return results
+
+
+def _slot_fits(results: list[dict], chunk: int, repo: str) -> list[dict]:
+    """A launch of the fused walk as ``us_launch + us_slot * slots + us_chunk
+    * slots * chunks``, least squares over a geometry's and a ``t``'s points
+    (the fastest repetition of each: the walk's own time, a program's
+    dispatch in the constant)."""
+    import numpy as np
+
+    fits = []
+    walks = [r for r in results if r["form"] == "fused"]
+    for geo, t in sorted({(r["geometry"], r["t"]) for r in walks}):
+        pts = [r for r in walks if (r["geometry"], r["t"]) == (geo, t)]
+        chunks = [-(-r["live"] // chunk) for r in pts]
+        x = np.array([[1, r["slots"], r["slots"] * c]
+                      for r, c in zip(pts, chunks)], float)
+        y = np.array([1e3 * r["min_ms"] / r["layers"] for r in pts])
+        if np.linalg.matrix_rank(x) < 3:
+            continue   # one slot count or one depth: nothing to tell apart
+        coef = np.linalg.lstsq(x, y, rcond=None)[0]
+        fit = {"geometry": geo, "t": t, "fit": "launch+slot+chunk",
+               "points": len(pts), "us_launch": round(coef[0], 2),
+               "us_slot": round(coef[1], 3), "us_chunk": round(coef[2], 3),
+               "worst_residual_us": round(float(np.abs(x @ coef - y).max()), 2),
+               "repo": repo}
+        fits.append(fit)
+        print(json.dumps(fit), flush=True)
+    return fits
 
 
 def main() -> None:
@@ -150,6 +197,9 @@ def main() -> None:
     ap.add_argument("--lives", default="",
                     help="--paged: live contexts a slot, in tokens")
     ap.add_argument("--geo", default="", help="--paged: geometries, by name")
+    ap.add_argument("--slots", default="",
+                    help="--paged: slot counts; times the fused walk and fits "
+                         "a launch as launch + slot + chunk")
     ap.add_argument("--blocks", default="256,512,1024")
     ap.add_argument("--reps", type=int, default=5)
     ap.add_argument("--rehearse", action="store_true",
