@@ -21,7 +21,13 @@ header keys 31 and 40; ASSUMED likewise, ``_OURO_LEAVES``;
 (``ARCH_FALCON_H1``: attention and a Mamba-2 mixer in every block, header keys
 31, 32, 41..60; ``mamba.in_proj.weight`` becomes ``ssm_in`` (its ``z | x | B |
 C`` rows) and the float32 ``ssm_dt`` (its ``dt`` rows), ``conv1d.weight`` (C, 1,
-K) the flat ``ssm_conv_w``; ASSUMED likewise, ``_FALCON_H1_LEAVES``).  Key
+K) the flat ``ssm_conv_w``; ASSUMED likewise, ``_FALCON_H1_LEAVES``) and
+granitemoehybrid folders (``ARCH_GRANITE_HYBRID``: Granite-4.0-H's mixer layers
+and position-free attention layers, header keys 19, 20, 31, 32, 34, 37, 41..47,
+49, 51, 52, 54; the experts' stacked ``input_linear`` (E, 2 F, D) is cut into each
+expert's ``gate`` and ``up`` halves and ``output_linear`` (E, D, F) into its
+``down``; the tied head is written from the embedding's rows; ASSUMED likewise,
+``_GRANITE_LEAVES``).  Key
 semantics preserved:
 
 * q/k head permutation (convert-hf.py:12-15): HF stores RoPE in rotate-half
@@ -58,6 +64,7 @@ ARCH_BY_MODEL_TYPE = {
     "olmoe": mfile.ARCH_OLMOE,
     "deepseek_v2": mfile.ARCH_DEEPSEEK2,
     "smallthinker": mfile.ARCH_SMALLTHINKER,
+    "granitemoehybrid": mfile.ARCH_GRANITE_HYBRID,
     "exaone_moe": mfile.ARCH_EXAONE_MOE,
     "lfm2_moe": mfile.ARCH_LFM2_MOE,
     "brumby": mfile.ARCH_BRUMBY,
@@ -384,6 +391,66 @@ def _falcon_h1_fields(config: dict) -> dict:
         mup_key=config["key_multiplier"], mup_gate=gate, mup_down=down, **mup)
 
 
+def _granite_hybrid_fields(config: dict) -> dict:
+    """The header's keys past the fourteen from a ``granitemoehybrid``
+    config.json: the expert's and the shared MLP's widths, the period and the
+    attention layer's place in it, the mixer's sizes and the four scalars
+    (``attention_multiplier`` as the key's multiplier under the usual
+    ``head^-1/2``; ``logits_scaling`` as its inverse).  What the file cannot
+    carry is refused by name."""
+    def no(why):
+        raise SystemExit(f"granitemoehybrid: {why}")
+
+    if config.get("position_embedding_type", "nope") != "nope":
+        no(f"position_embedding_type is {config['position_embedding_type']!r}; "
+           "the runtime rotates nothing in this architecture")
+    for key in ("attention_bias", "mamba_proj_bias", "rope_scaling"):
+        if config.get(key):
+            no(f"{key} is {config[key]!r}; the .m file has no projection bias "
+               "and the runtime no scaled positions")
+    if not config.get("mamba_conv_bias", True):
+        no("mamba_conv_bias is false; the .m file always has the tensor")
+    if int(config.get("mamba_n_groups", 1)) != 1:
+        no(f"mamba_n_groups is {config['mamba_n_groups']}; more than one mixer "
+           "group has a norm a group, and this file's gated norm is ONE over "
+           "all of the mixer's channels")
+    if config.get("normalization_function", "rmsnorm") != "rmsnorm" \
+            or config.get("hidden_act", "silu") != "silu":
+        no("the layer is RMSNorm and silu")
+    layers = config["num_hidden_layers"]
+    types = list(config["layer_types"])
+    kinds = [t == "attention" for t in types]
+    at = kinds.index(True) if True in kinds else -1
+    period = kinds[at + 1:].index(True) + 1 if True in kinds[at + 1:] else layers
+    if set(types) - {"mamba", "attention"} or len(kinds) != layers or at < 0 \
+            or period < 2 or layers % period or kinds != [
+            j == at for j in range(period)] * (layers // period):
+        no(f"layer_types {types} is not whole periods of mamba layers with one "
+           "attention layer")
+    heads, dh = int(config["mamba_n_heads"]), int(config["mamba_d_head"])
+    if heads * dh != int(config.get("mamba_expand", 2)) * config["hidden_size"]:
+        no("mamba_n_heads * mamba_d_head is not mamba_expand * hidden_size")
+    f, fs = int(config["intermediate_size"]), int(config["shared_intermediate_size"])
+    if fs % f:
+        no(f"shared_intermediate_size {fs} is not a whole number of experts' "
+           f"widths ({f})")
+    if config["hidden_size"] % config["num_attention_heads"]:
+        no("hidden_size is not a multiple of num_attention_heads")
+    head = config["hidden_size"] // config["num_attention_heads"]
+    return dict(
+        moe_hidden_dim=f, n_shared_experts=fs // f,
+        norm_eps=float(config.get("rms_norm_eps", 1e-5)), head_dim=head,
+        window_period=period, window_full_at=at, ssm_heads=heads,
+        ssm_head_dim=dh, ssm_state=int(config["mamba_d_state"]), ssm_groups=1,
+        ssm_conv=int(config["mamba_d_conv"]),
+        mup_embedding=float(config["embedding_multiplier"]),
+        mup_head=1.0 / float(config["logits_scaling"]),
+        mup_key=float(config["attention_multiplier"]) * float(np.sqrt(head)),
+        # residual_multiplier stands on each branch's output
+        **dict.fromkeys(("mup_attn_out", "mup_ssm_out", "mup_down"),
+                        float(config["residual_multiplier"])))
+
+
 def load_spec(folder: str, weights_ftype: int, experts_held: int = 0,
               first_expert: int = 0) -> mfile.ModelSpec:
     with open(os.path.join(folder, "config.json")) as f:
@@ -406,6 +473,10 @@ def load_spec(folder: str, weights_ftype: int, experts_held: int = 0,
         ext = _ouro_fields(config)
     if arch == mfile.ARCH_FALCON_H1:
         ext = _falcon_h1_fields(config)
+    if arch == mfile.ARCH_GRANITE_HYBRID:
+        ext = _granite_hybrid_fields(config)
+        # the header's hidden_dim is the shared MLP's width
+        config = dict(config, intermediate_size=config["shared_intermediate_size"])
     if arch in (mfile.ARCH_EXAONE_MOE, mfile.ARCH_LFM2_MOE):
         config = dict(config, rope_theta=(config.get("rope_parameters") or {}).get(
             "rope_theta", config.get("rope_theta", 10000.0)))
@@ -530,6 +601,41 @@ _FALCON_H1_LEAVES = {
 }
 
 
+# ASSUMED: a granitemoehybrid layer's tensors under ``model.layers.N.``
+# (transformers' GraniteMoeHybrid module names as the builder knows them; the
+# mixer's as Falcon-H1's).  The experts are ONE stacked tensor a projection,
+# ``input_linear`` (E, 2 F, D) with each expert's rows ``gate | up`` and
+# ``output_linear`` (E, D, F); the shared MLP's ``input_linear`` (2 Fs, D) is
+# ``gate | up`` likewise (:func:`_granite_rows`).  Unverified until the
+# published files are in the repository
+_GRANITE_LEAVES = dict(
+    {k: v for k, v in _FALCON_H1_LEAVES.items() if k.startswith(("w", "ssm_"))
+     and k not in ("w1", "w2", "w3")},
+    moe_router="block_sparse_moe.router.layer.weight",
+    gate="block_sparse_moe.input_linear.weight",
+    up="block_sparse_moe.input_linear.weight",
+    down="block_sparse_moe.output_linear.weight",
+    shared_w1="shared_mlp.input_linear.weight",
+    shared_w3="shared_mlp.input_linear.weight",
+    shared_w2="shared_mlp.output_linear.weight",
+    rms_att="input_layernorm.weight", rms_ffn="post_attention_layernorm.weight")
+
+
+def _granite_rows(name: str, t, spec: mfile.ModelSpec):
+    """The rows of a published tensor that the plan's tensor ``name`` holds:
+    ``in_proj``'s as Falcon-H1's, an expert's plane of the stacked experts and
+    the ``gate | up`` halves of an ``input_linear``."""
+    parts = name.split(".")
+    leaf = parts[-1]
+    if len(parts) > 3 and parts[2] == "experts":
+        t = t[int(parts[3])]
+        f = spec.moe_hidden_dim
+        return {"gate": t[:f], "up": t[f:]}.get(leaf, t)
+    fs = spec.hidden_dim
+    return {"shared_w1": t[:fs], "shared_w3": t[fs:]}.get(
+        leaf, _falcon_h1_rows(leaf, t, spec))
+
+
 def _falcon_h1_rows(leaf: str, t, spec: mfile.ModelSpec):
     """``in_proj``'s rows ``z | x | B | C`` for ``ssm_in``, its last
     ``ssm_heads`` rows (``dt``) for ``ssm_dt``; every other tensor whole."""
@@ -564,6 +670,8 @@ def hf_source_name(our_name: str, spec: mfile.ModelSpec) -> tuple[str, bool]:
         return f"{base}.{_OURO_LEAVES[leaf]}.weight", False
     if spec.arch == mfile.ARCH_FALCON_H1:  # rows as published: halves rotate
         return f"{base}.{_FALCON_H1_LEAVES[leaf]}", False
+    if spec.arch == mfile.ARCH_GRANITE_HYBRID:  # rows as published: no rotation
+        return f"{base}.{_GRANITE_LEAVES[leaf]}", False
     # rows as published: these runtimes rotate halves, as HF does
     olmoe = spec.arch in (mfile.ARCH_OLMOE, mfile.ARCH_SMALLTHINKER,
                           mfile.ARCH_EXAONE_MOE)
@@ -631,16 +739,21 @@ def convert(folder: str, weights_ftype: int, out_path: str,
     if spec.arch == mfile.ARCH_DEEPSEEK2 and store.has("e_score_correction_bias"):
         raise SystemExit("deepseek_v2: the checkpoint has e_score_correction_bias "
                          "(V3's router); the runtime has no correction bias")
+    tied = (mfile.ARCH_LFM2_MOE, mfile.ARCH_GRANITE_HYBRID)
+    held = (None, None)  # the last published tensor read: a stacked one feeds many
     with mfile.MFileWriter(out_path, spec) as w:
         for item in w.plan:
             key, do_permute = hf_source_name(item.name, spec)
-            if item.name == "wcls" and spec.arch == mfile.ARCH_LFM2_MOE \
-                    and not store.has(key):
+            if item.name == "wcls" and spec.arch in tied and not store.has(key):
                 # the published model ties its head to the embedding
                 key = "model.embed_tokens.weight"
-            t = store.get(key)
+            if held[0] != key:
+                held = (key, store.get(key))
+            t = held[1]
             if spec.arch == mfile.ARCH_FALCON_H1:
                 t = _falcon_h1_rows(item.name.split(".")[-1], t, spec)
+            if spec.arch == mfile.ARCH_GRANITE_HYBRID:
+                t = _granite_rows(item.name, t, spec)
             if do_permute:
                 heads = spec.n_heads if item.name.endswith("wq") else spec.n_kv_heads
                 t = permute(t, spec.n_heads, heads)

@@ -37,7 +37,10 @@ retention's degree) and Llama's layers with, after ``wo``, the gate ``wg``
 ``k_norm`` of one head's size.  A Falcon-H1 file (``ARCH_FALCON_H1``) has keys
 31, 32 and 41..60 (the state-space mixer's sizes, the muP multipliers as f32
 bits, and ``rope_theta`` as a float: 1e11 passes an i32) and Llama's layers
-with, after ``wo``, the mixer's tensors (:func:`_ssm_tensors`).  Matmul weights
+with, after ``wo``, the mixer's tensors (:func:`_ssm_tensors`).  A Granite-4.0-H
+file (``ARCH_GRANITE_HYBRID``) has keys 19, 20, 31, 32, 34, 37, 41..47, 49, 51,
+52 and 54: LFM2's walk with the mixer's tensors where that has a convolution's, no head
+norms, no router bias, and a shared MLP in every layer.  Matmul weights
 are stored row-major
 ``(d_out, n_in)`` in the
   model's weight float type; norm weights and the embedding are F32
@@ -111,12 +114,25 @@ ARCH_OURO = 0xABCD09
 # input and adds both to the residual, then a SwiGLU; every branch carries muP
 # multipliers, scalars of the published config (keys 46..59)
 ARCH_FALCON_H1 = 0xABCD0A
+# Granite-4.0-H (``granitemoehybrid``): periods (keys 34, 37) in which one layer
+# is grouped-query attention WITHOUT positions (no RoPE anywhere) and the others
+# are Mamba-2 mixers (keys 41..45): a layer keeps a state OR keys and values.
+# Every layer has a softmax router renormalised over the chosen experts
+# (Mixtral's), experts of ``moe_hidden_dim`` (key 19) and a shared gated MLP
+# ``n_shared_experts`` experts wide (key 20; ``hidden_dim`` is its width).  Four
+# scalars of the published config, each under the key that already means it: on
+# the embedding (46), on the logits (47), ``residual_multiplier`` on each
+# branch's output (49 attention's, 51 the mixer's, 54 the experts' and the
+# shared MLP's sum) and, in place of the scores' ``head^-1/2``,
+# ``attention_multiplier`` as the key's multiplier (52) ``* head^1/2``
+ARCH_GRANITE_HYBRID = 0xABCD0B
 ARCH_NAMES = {ARCH_LLAMA: "llama", ARCH_GROK1: "grok1", ARCH_MIXTRAL: "mixtral",
               ARCH_OLMOE: "olmoe", ARCH_DEEPSEEK2: "deepseek2",
               ARCH_SMALLTHINKER: "smallthinker",
               ARCH_EXAONE_MOE: "exaone_moe", ARCH_LFM2_MOE: "lfm2_moe",
               ARCH_BRUMBY: "brumby", ARCH_OURO: "ouro",
-              ARCH_FALCON_H1: "falcon_h1"}
+              ARCH_FALCON_H1: "falcon_h1",
+              ARCH_GRANITE_HYBRID: "granitemoehybrid"}
 
 # TransformerHiddenAct (transformer.hpp:45-48), and beyond it ReLU
 ACT_GELU = 0
@@ -144,7 +160,8 @@ KEY_WEIGHTS_FLOAT_TYPE = 13
 # LFM2's one (``CONV_KEYS``, 38; its file carries some of each) and Brumby's
 # one (``RETENTION_KEYS``, 39; its file also carries key 31) and Ouro's one
 # (``LOOP_KEYS``, 40; its file also carries key 31) and Falcon-H1's
-# (``SSM_KEYS``, 41..60; its file also carries keys 31 and 32).
+# (``SSM_KEYS``, 41..60; its file also carries keys 31 and 32; Granite's file
+# carries some of each and no key of its own).
 # ``(key, field, is_float)``: a float travels as the bits of its IEEE-754 f32
 # in the i32
 EXT_KEYS = (
@@ -218,7 +235,12 @@ ARCH_EXT_KEYS = {ARCH_DEEPSEEK2: tuple(range(14, 32)),
                  ARCH_LFM2_MOE: (19, 23, 24, 31, 32, 34, 37, 38),
                  ARCH_BRUMBY: (31, 39),
                  ARCH_OURO: (31, 40),
-                 ARCH_FALCON_H1: (31, 32) + tuple(range(41, 61))}
+                 ARCH_FALCON_H1: (31, 32) + tuple(range(41, 61)),
+                 # the period as LFM2's (34, 37), the mixer's sizes as
+                 # Falcon-H1's (41..45) and the multipliers that mean what its
+                 # four scalars mean (46, 47, 52; 49, 51, 54 on a branch's output)
+                 ARCH_GRANITE_HYBRID: (19, 20, 31, 32, 34, 37, 41, 42, 43, 44,
+                                       45, 46, 47, 49, 51, 52, 54)}
 _EXT_BY_KEY = {k: (name, is_f) for k, name, is_f in ALL_EXT_KEYS}
 KEY_MAX = SSM_KEYS[-1][0]
 
@@ -377,9 +399,10 @@ def tensor_plan(spec: ModelSpec) -> list[TensorInfo]:
     add("token_embedding", (spec.vocab_size, spec.dim), quants.F32)
     if spec.arch == ARCH_DEEPSEEK2:
         _deepseek2_layers(spec, add)
-    if spec.arch in (ARCH_EXAONE_MOE, ARCH_LFM2_MOE):
+    if spec.arch in (ARCH_EXAONE_MOE, ARCH_LFM2_MOE, ARCH_GRANITE_HYBRID):
         _exaone_moe_layers(spec, add)
-    own_layers = spec.arch in (ARCH_DEEPSEEK2, ARCH_EXAONE_MOE, ARCH_LFM2_MOE)
+    own_layers = spec.arch in (ARCH_DEEPSEEK2, ARCH_EXAONE_MOE, ARCH_LFM2_MOE,
+                               ARCH_GRANITE_HYBRID)
     for i in range(0 if own_layers else spec.n_layers):
         add(f"layers.{i}.wq", (spec.q_dim, spec.dim), w)
         add(f"layers.{i}.wk", (spec.kv_dim, spec.dim), w)
@@ -482,28 +505,38 @@ def _exaone_moe_layers(spec: ModelSpec, add) -> None:
     period's attention layer, the convolution's three tensors in the attention
     tensors' place (``conv_taps`` flat, channel by channel: value ``c * taps +
     j`` weighs ``z[t - (taps - 1) + j]`` in channel ``c``), and no shared
-    expert."""
+    expert.  A Granite file is that walk with a mixer's tensors
+    (:func:`_ssm_tensors`) where LFM2 has a convolution's, neither head norm
+    nor router bias (its attention and its router have none), and the shared
+    MLP in every layer."""
     w, d, f = spec.weights_ftype, spec.dim, spec.moe_hidden_dim
+    granite = spec.arch == ARCH_GRANITE_HYBRID
     for i in range(spec.n_layers):
         p = f"layers.{i}."
-        if spec.conv_taps and i % spec.window_period != spec.window_full_at:
+        other = (spec.window_period
+                 and i % spec.window_period != spec.window_full_at)
+        if spec.conv_taps and other:
             add(p + "conv_in", (3 * d, d), w)
             add(p + "conv_taps", (d * spec.conv_taps,), quants.F32)
             add(p + "conv_out", (d, d), w)
+        elif spec.ssm_heads and other:
+            _ssm_tensors(spec, p, add)
         else:
             add(p + "wq", (spec.q_dim, d), w)
             add(p + "wk", (spec.kv_dim, d), w)
             add(p + "wv", (spec.kv_dim, d), w)
             add(p + "wo", (d, spec.q_dim), w)
-            add(p + "q_norm", (spec.head_size,), quants.F32)
-            add(p + "k_norm", (spec.head_size,), quants.F32)
+            if not granite:
+                add(p + "q_norm", (spec.head_size,), quants.F32)
+                add(p + "k_norm", (spec.head_size,), quants.F32)
         if i < spec.n_dense_layers:
             add(p + "w1", (spec.hidden_dim, d), w)
             add(p + "w2", (d, spec.hidden_dim), w)
             add(p + "w3", (spec.hidden_dim, d), w)
         else:
             add(p + "moe_router", (spec.n_experts, d), w)
-            add(p + "moe_router_bias", (spec.n_experts,), quants.F32)
+            if not granite:
+                add(p + "moe_router_bias", (spec.n_experts,), quants.F32)
             for e in range(spec.n_experts_held):
                 add(f"{p}experts.{e}.up", (f, d), w)
                 add(f"{p}experts.{e}.gate", (f, d), w)
@@ -599,6 +632,8 @@ def validate_spec(spec: ModelSpec, path) -> ModelSpec:
         _validate_lfm2_moe(spec, path)
     elif spec.arch == ARCH_FALCON_H1:
         _validate_falcon_h1(spec, path)
+    elif spec.arch == ARCH_GRANITE_HYBRID:
+        _validate_granite_hybrid(spec, path)
     elif spec.head_dim or spec.window or spec.window_period:
         raise ArtifactError(path, "header key",
                             "keys 32..34 describe a smallthinker file (or an "
@@ -622,22 +657,25 @@ def validate_spec(spec: ModelSpec, path) -> ModelSpec:
                             expected=hex(ARCH_OURO), got=hex(spec.arch))
     if spec.arch == ARCH_OURO:
         _validate_ouro(spec, path)
-    if spec.arch != ARCH_FALCON_H1 and any(
+    if spec.arch not in (ARCH_FALCON_H1, ARCH_GRANITE_HYBRID) and any(
             getattr(spec, name) != getattr(ModelSpec, name)
             for _, name, _ in SSM_KEYS):
         raise ArtifactError(path, "header key",
-                            "keys 41..60 describe a falcon_h1 file",
+                            "keys 41..60 describe a falcon_h1 file (or a "
+                            "granitemoehybrid one)",
                             expected=hex(ARCH_FALCON_H1), got=hex(spec.arch))
     if spec.arch == ARCH_EXAONE_MOE:
         _validate_exaone_moe(spec, path)
     elif spec.experts_held or spec.first_expert or (
-            spec.window_full_at and spec.arch != ARCH_LFM2_MOE):
+            spec.window_full_at
+            and spec.arch not in (ARCH_LFM2_MOE, ARCH_GRANITE_HYBRID)):
         raise ArtifactError(path, "header key",
                             "keys 35..37 describe an exaone_moe file",
                             expected=hex(ARCH_EXAONE_MOE), got=hex(spec.arch))
     if spec.arch == ARCH_DEEPSEEK2:
         _validate_deepseek2(spec, path)
-    elif spec.arch not in (ARCH_EXAONE_MOE, ARCH_LFM2_MOE) and (  # which carry some of those keys
+    elif spec.arch not in (ARCH_EXAONE_MOE, ARCH_LFM2_MOE,
+                           ARCH_GRANITE_HYBRID) and (  # which carry some of those keys
             spec.is_mla or spec.n_dense_layers or spec.n_shared_experts
             or spec.n_groups or spec.moe_hidden_dim):
         raise ArtifactError(path, "header key",
@@ -758,6 +796,62 @@ def _validate_falcon_h1(spec: ModelSpec, path) -> None:
         if not (v > 0 and np.isfinite(v)):
             bad(name, "a multiplier (and rope_theta) is a positive float",
                 "> 0", v)
+
+
+def _validate_granite_hybrid(spec: ModelSpec, path) -> None:
+    """The cross-field rules of an ``ARCH_GRANITE_HYBRID`` header."""
+    def bad(field, why, expected, got):
+        raise ArtifactError(path, f"header field {field}", why,
+                            expected=expected, got=got)
+
+    if not 2 <= spec.head_dim <= 4096:
+        bad("head_dim", "a granitemoehybrid file states its attention head "
+            "size", "2..4096", spec.head_dim)
+    if spec.window or spec.conv_taps:
+        bad("window", "a granitemoehybrid file has no sliding window and no "
+            "short convolution: the layers beside a period's attention layer "
+            "are state-space mixers", 0, (spec.window, spec.conv_taps))
+    if spec.window_period < 2 or spec.n_layers % spec.window_period:
+        bad("window_period", "the layers are whole periods of one attention "
+            "layer and window_period - 1 mixer layers",
+            f">= 2, a divisor of n_layers={spec.n_layers}", spec.window_period)
+    if not 0 <= spec.window_full_at < spec.window_period:
+        bad("window_full_at", "the attention layer's place in a period",
+            f"0..{spec.window_period - 1}", spec.window_full_at)
+    for field, hi in (("ssm_heads", 4096), ("ssm_head_dim", 4096),
+                      ("ssm_state", 4096), ("ssm_groups", 4096)):
+        if not 1 <= getattr(spec, field) <= hi:
+            bad(field, "a granitemoehybrid file states its state-space mixer's "
+                "sizes", f"1..{hi}", getattr(spec, field))
+    if spec.ssm_groups != 1:
+        bad("ssm_groups", "the gated norm before W_out is one RMSNorm over all "
+            "of the mixer's channels only where the heads share one B and one "
+            "C (a per-group norm is Falcon-H1's)", 1, spec.ssm_groups)
+    if not 2 <= spec.ssm_conv <= 64:
+        bad("ssm_conv", "a granitemoehybrid file states its convolution's taps "
+            "(mamba_d_conv)", "2..64", spec.ssm_conv)
+    if not spec.n_experts or not spec.n_active_experts:
+        bad("n_experts", "every granitemoehybrid layer has experts and a top-k",
+            ">= 1", spec.n_experts)
+    if not 1 <= spec.moe_hidden_dim <= 1 << 24:
+        bad("moe_hidden_dim", "a granitemoehybrid file states its experts' "
+            "width", "1..2^24", spec.moe_hidden_dim)
+    if not 0 <= spec.n_shared_experts <= 64 or (
+            spec.hidden_dim != spec.moe_hidden_dim * max(spec.n_shared_experts, 1)):
+        bad("n_shared_experts", "the shared MLP is a whole number of experts "
+            "wide and hidden_dim is its width", "hidden_dim / moe_hidden_dim",
+            (spec.n_shared_experts, spec.hidden_dim, spec.moe_hidden_dim))
+    own = ("mup_embedding", "mup_head", "mup_key", "mup_attn_out",
+           "mup_ssm_out", "mup_down")
+    for name in own:
+        v = getattr(spec, name)
+        if not (v > 0 and np.isfinite(v)):
+            bad(name, "a multiplier is a positive float", "> 0", v)
+    for _, name, is_f in SSM_KEYS[5:]:
+        if name not in own and getattr(spec, name) != getattr(ModelSpec, name):
+            bad(name, "a granitemoehybrid file carries the embedding's, the "
+                "head's, the key's and the three branch outputs' multipliers "
+                "alone", getattr(ModelSpec, name), getattr(spec, name))
 
 
 def _validate_lfm2_moe(spec: ModelSpec, path) -> None:

@@ -52,6 +52,12 @@ class Kind:
     too_deep: str = "refused"
     folds: obs_metrics.Counter | None = None
     taps_field: str = ""
+    # the config's field with how many layers keep this kind (a fold is a block
+    # in each of them)
+    depth_field: str = "n_layers"
+
+    def depth(self, cfg) -> int:
+        return getattr(cfg, self.depth_field)
 
     def taps(self, cfg) -> int:
         return getattr(cfg, self.taps_field) if self.taps_field else 0
@@ -98,7 +104,10 @@ RETENTION = Kind(
     slot_owns="retention layers' state", slot_unpaged=True,
     rewinds=obs_metrics.RETENTION_REWINDS, folds=obs_metrics.RETENTION_FOLDS)
 # the mixer stands in retention's fields at its own sizes (rk holds B, rv x, rg
-# dt; no rz) and in ``cz`` for its convolution's ring
+# dt; no rz) and in ``cz`` for its convolution's ring.  One row for both of its
+# models: a layer of a slot owns a state AND pages (Falcon-H1, every block) or a
+# state OR pages (Granite, by the layer's place in its period); either way the
+# planes are ``cfg.n_ssm_layers`` deep and ``full``'s ``cfg.n_full_layers``
 SSM = Kind(
     "ssm", ("rs", "rk", "rv", "rg", "rw", "cz"), "ssm",
     what="a state-space ({arch}) model",
@@ -106,7 +115,7 @@ SSM = Kind(
     no_int8_form="a state-space mixer's state has no int8 form",
     slot_owns="state-space mixers' state", slot_unpaged=True,
     rewinds=obs_metrics.SSM_STATE_REWINDS, folds=obs_metrics.SSM_FOLDS,
-    taps_field="ssm_conv")
+    taps_field="ssm_conv", depth_field="n_ssm_layers")
 KINDS = (FULL, LOOPED, LATENT, WINDOW, CONV, RETENTION, SSM)
 
 

@@ -90,7 +90,9 @@ class ModelConfig:
     # ---- ARCH_OURO (header key 40); 0 = the arch has none
     loops: int = 0                  # > 0: the whole stack runs this many times (n_loops)
     # ---- ARCH_FALCON_H1 (header keys 41..60); 0 / 1.0 = the arch has none
-    ssm_heads: int = 0              # > 0: every block has a Mamba-2 mixer beside its attention
+    # > 0: Mamba-2 mixers, beside attention in every block, or (with a
+    # ``window_period``) as the other layers of a period (Granite)
+    ssm_heads: int = 0
     ssm_head_dim: int = 0
     ssm_state: int = 0              # rows of a head's state matrix (mamba_d_state)
     ssm_groups: int = 0             # heads / groups share one B and one C
@@ -127,10 +129,19 @@ class ModelConfig:
 
     @property
     def has_ssm(self) -> bool:
-        """Every block runs a Mamba-2 state-space mixer (``ops/ssm.py``) beside
-        its attention: one layer of one row owns keys and values (pages, on a
-        paged engine) AND a state matrix a head with its rings."""
+        """Some layer runs a Mamba-2 state-space mixer (``ops/ssm.py``): beside
+        its attention in every block (Falcon-H1: one layer of one row owns keys
+        and values, pages on a paged engine, AND a state matrix a head with its
+        rings), or as the other layers of a period (Granite: a layer owns a
+        state OR keys and values)."""
         return self.ssm_heads > 0
+
+    @property
+    def n_ssm_layers(self) -> int:
+        """Layers that keep a mixer's state: how deep its planes are."""
+        if not self.has_ssm:
+            return 0
+        return self.n_layers - self.n_full_layers if self.periodic else self.n_layers
 
     @property
     def ssm_inner(self) -> int:
@@ -176,9 +187,18 @@ class ModelConfig:
     def periodic(self) -> bool:
         """The layers come in periods of ``window_period`` of which one, at
         ``window_full_at``, is full attention (``models/windowed.py``): the
-        others are sliding-window layers (``window``) or gated short
-        convolutions (``conv_taps``)."""
-        return self.window > 0 or self.conv_taps > 0
+        others are sliding-window layers (``window``), gated short
+        convolutions (``conv_taps``) or state-space mixers (``ssm_heads``)."""
+        return self.window > 0 or self.conv_taps > 0 or (
+            self.ssm_heads > 0 and self.window_period > 0)
+
+    @property
+    def kind_stacked(self) -> bool:
+        """A period's other layers are another operator with weights of its own
+        (convolutions, mixers): each operator's weights are stacked over the
+        layers of its kind (``params.ATT_KIND_KEYS`` / ``CONV_KEYS`` /
+        ``MIXER_KEYS``, indexed by ``windowed.kind_index``)."""
+        return self.periodic and not self.window
 
     @property
     def n_full_layers(self) -> int:
@@ -197,9 +217,12 @@ class ModelConfig:
 
     @property
     def full_rotates(self) -> bool:
-        """A period's full-attention layer carries rotate-half RoPE (LFM2); in
-        a windowed model it is unrotated (NoPE) and the window layers rotate."""
-        return self.conv_taps > 0
+        """A period's full-attention layer carries rotate-half RoPE (LFM2).  In
+        a windowed model it is unrotated (NoPE) and the window layers rotate;
+        Granite rotates nothing anywhere (``position_embedding_type`` "nope"):
+        its other layers keep a state, as LFM2's, and its full layer is
+        position-free, as a windowed model's."""
+        return self.arch == mfile.ARCH_LFM2_MOE
 
     @property
     def router_norm_eps(self) -> float:
@@ -351,7 +374,8 @@ class ModelConfig:
     def ffn_by_segment(self) -> bool:
         """K-EXAONE's and LFM2's files: a dense FFN in the leading layers and
         experts after them, each stacked over its own segment."""
-        return self.arch in (mfile.ARCH_EXAONE_MOE, mfile.ARCH_LFM2_MOE)
+        return self.arch in (mfile.ARCH_EXAONE_MOE, mfile.ARCH_LFM2_MOE,
+                             mfile.ARCH_GRANITE_HYBRID)
 
     @property
     def router_reads_input(self) -> bool:
@@ -475,6 +499,28 @@ def tiny_falcon_h1(**kw) -> ModelConfig:
                 mup_attn_out=0.6, mup_ssm_in=0.5, mup_ssm_out=0.8,
                 mup_key=0.7, mup_gate=0.6, mup_down=0.45, mup_z=0.7,
                 mup_x=1.5, mup_b=1.4, mup_c=1.3, mup_dt=0.7)
+    base.update(kw)
+    return tiny_config(**base)
+
+
+def tiny_granite_hybrid(**kw) -> ModelConfig:
+    """Granite-4.0-H at a toy size that keeps every ratio: periods of four
+    mixer layers and one attention layer (at 2) without positions, two periods,
+    4 query heads a kv head, a mixer of 8 heads of 8 in ONE group with a state
+    of 12 rows (not the head size), 4 taps, 12 experts of which 3 a token
+    beside a shared MLP twice an expert's width in every layer, every
+    multiplier it has off 1 (the key's is ``attention_multiplier * sqrt(head)``;
+    ``residual_multiplier`` stands on each branch's output: ``mup_attn_out``,
+    ``mup_ssm_out`` and, on the experts' and the shared MLP's sum,
+    ``mup_down``)."""
+    base = dict(arch=mfile.ARCH_GRANITE_HYBRID, dim=64, hidden_dim=64,
+                n_layers=10, n_heads=8, n_kv_heads=2, n_experts=12,
+                n_active_experts=3, vocab_size=128, seq_len=512,
+                norm_eps=1e-5, head_dim=8, window_period=5, window_full_at=2,
+                moe_hidden_dim=32, n_shared_experts=2, ssm_heads=8,
+                ssm_head_dim=8, ssm_state=12, ssm_groups=1, ssm_conv=4,
+                mup_embedding=3.0, mup_head=0.25, mup_key=0.3 * 8 ** 0.5,
+                mup_attn_out=0.5, mup_ssm_out=0.5, mup_down=0.5)
     base.update(kw)
     return tiny_config(**base)
 

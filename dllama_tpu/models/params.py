@@ -45,6 +45,9 @@ ATT_KIND_KEYS = ("wq", "wk", "wv", "wqkv", "wo", "q_norm", "k_norm")
 # ``dt`` projection sets the state's decay; the rest are vectors)
 SSM_F32 = ("ssm_dt", "ssm_conv_w", "ssm_conv_b", "ssm_a_log", "ssm_dt_bias",
            "ssm_d", "ssm_norm")
+# Granite's stacks by layer kind: a mixer layer's tensors over the mixer layers
+# (Falcon-H1's, where every block has one, are over all L)
+MIXER_KEYS = ("ssm_in", "ssm_out") + SSM_F32
 
 
 def _mla_param_shapes(cfg: ModelConfig) -> dict[str, tuple[int, ...]]:
@@ -83,12 +86,15 @@ def _exaone_param_shapes(cfg: ModelConfig) -> dict[str, tuple[int, ...]]:
     segment, as DeepSeek-V2's).  The router has ``n_experts`` columns; the
     expert stacks have ``n_experts_held`` planes a layer.  LFM2's are the same
     with the attention stacks over its attention layers alone and the
-    convolution's (``CONV_KEYS``) over the others."""
+    convolution's (``CONV_KEYS``) over the others; Granite's likewise with the
+    mixer's (``MIXER_KEYS``), no head norms and no router bias."""
     L, D, V = cfg.n_layers, cfg.dim, cfg.vocab_size
     Ld, Le, Dh = cfg.n_dense_layers, cfg.n_moe_layers, cfg.head_size
     E, H, F = cfg.n_experts, cfg.n_experts_held, cfg.expert_dim
     Fs = F * cfg.n_shared_experts
-    La, Lc = (cfg.n_full_layers, cfg.n_conv_layers) if cfg.conv_taps else (L, 0)
+    La = cfg.n_full_layers if cfg.kind_stacked else L
+    Lc = cfg.n_conv_layers
+    # in the order the seeded draws of ``init_params`` have always taken them
     shapes = {
         "embedding": (V, D),
         "wq": (La, D, cfg.q_dim), "wk": (La, D, cfg.kv_dim),
@@ -99,9 +105,15 @@ def _exaone_param_shapes(cfg: ModelConfig) -> dict[str, tuple[int, ...]]:
         "router": (Le, D, E), "router_bias": (Le, E),
         "up": (Le, H, D, F), "gate": (Le, H, D, F), "down": (Le, H, F, D),
     }
+    if not cfg.qk_head_norm:
+        del shapes["q_norm"], shapes["k_norm"]
+    if not cfg.router_sigmoid:
+        del shapes["router_bias"]
     if Lc:
         shapes.update({"conv_in": (Lc, D, 3 * D), "conv_taps": (Lc, D, cfg.conv_taps),
                        "conv_out": (Lc, D, D)})
+    if cfg.has_ssm:
+        shapes.update(_mixer_shapes(cfg))
     if Ld:
         Fd = cfg.hidden_dim
         shapes.update({"w1": (Ld, D, Fd), "w2": (Ld, Fd, D), "w3": (Ld, D, Fd)})
@@ -109,6 +121,17 @@ def _exaone_param_shapes(cfg: ModelConfig) -> dict[str, tuple[int, ...]]:
         shapes.update({"shared_w1": (Le, D, Fs), "shared_w2": (Le, Fs, D),
                        "shared_w3": (Le, D, Fs)})
     return shapes
+
+
+def _mixer_shapes(cfg: ModelConfig) -> dict[str, tuple[int, ...]]:
+    """A state-space mixer's stacks, over the layers that have one
+    (io/mfile.py _ssm_tensors)."""
+    L, D, H, C = cfg.n_ssm_layers, cfg.dim, cfg.ssm_heads, cfg.ssm_channels
+    return {"ssm_in": (L, D, cfg.ssm_inner + C), "ssm_dt": (L, D, H),
+            "ssm_conv_w": (L, C, cfg.ssm_conv), "ssm_conv_b": (L, C),
+            "ssm_a_log": (L, H), "ssm_dt_bias": (L, H),
+            "ssm_d": (L, H), "ssm_norm": (L, cfg.ssm_inner),
+            "ssm_out": (L, cfg.ssm_inner, D)}
 
 
 def param_shapes(cfg: ModelConfig) -> dict[str, tuple[int, ...]]:
@@ -136,13 +159,8 @@ def param_shapes(cfg: ModelConfig) -> dict[str, tuple[int, ...]]:
     if cfg.retention_degree:
         shapes.update({"wg": (L, D, cfg.n_kv_heads), "q_norm": (L, cfg.head_size),
                        "k_norm": (L, cfg.head_size)})
-    if cfg.has_ssm:  # the mixer beside attention (io/mfile.py _ssm_tensors)
-        H, C = cfg.ssm_heads, cfg.ssm_channels
-        shapes.update({"ssm_in": (L, D, cfg.ssm_inner + C), "ssm_dt": (L, D, H),
-                       "ssm_conv_w": (L, C, cfg.ssm_conv), "ssm_conv_b": (L, C),
-                       "ssm_a_log": (L, H), "ssm_dt_bias": (L, H),
-                       "ssm_d": (L, H), "ssm_norm": (L, cfg.ssm_inner),
-                       "ssm_out": (L, cfg.ssm_inner, D)})
+    if cfg.has_ssm:  # the mixer beside attention
+        shapes.update(_mixer_shapes(cfg))
     if cfg.is_moe:
         shapes.update({
             "router": (L, D, E),
@@ -281,7 +299,7 @@ def _quantize_mla(out: Params, fuse: bool) -> Params:
                     np.concatenate([f32(pre + "1"), f32(pre + "3")], -1))
     for k in ("wq_a", "wkv_a", "wq_b", "wo", "wcls", "w1", "w2", "w3", "up",
               "gate", "down", "shared_w1", "shared_w2", "shared_w3", "conv_in",
-              "conv_out"):
+              "conv_out", "ssm_in", "ssm_out"):
         if k in out:
             out[k] = q40.quantize(np.asarray(out[k], np.float32))
     return out
@@ -386,12 +404,7 @@ def _read_params(mf: mfile.MFile, cfg: ModelConfig, dtype,
     if cfg.retention_degree:  # the gate stays float32, whatever the weights' type
         p["wg"] = _stack(mf, [f"layers.{i}.wg" for i in range(L)], True, np.float32)
     if cfg.has_ssm:
-        st = _Stacks(mf, p, np_dtype, codec if quant else None)
-        st.mats(("ssm_in", "ssm_out"), range(L))
-        st.vecs(SSM_F32[1:], range(L))
-        p["ssm_dt"] = _stack(mf, [f"layers.{i}.ssm_dt" for i in range(L)], True,
-                             np.float32)
-        p["ssm_conv_w"] = p["ssm_conv_w"].reshape(L, cfg.ssm_channels, cfg.ssm_conv)
+        _Stacks(mf, p, np_dtype, codec if quant else None).mixers(cfg, range(L))
     if cfg.is_moe:
         p["router"] = _stack(mf, [f"layers.{i}.moe_router" for i in range(L)], True, np_dtype)
         if quant:
@@ -445,6 +458,15 @@ class _Stacks:
                 self.mf, [f"layers.{i}.{src or key}" for i in layers], False,
                 np.float32)
 
+    def mixers(self, cfg, layers):
+        """The state-space mixers of ``layers`` (``MIXER_KEYS``)."""
+        self.mats(("ssm_in", "ssm_out"), layers)
+        self.vecs(SSM_F32[1:], layers)
+        self.p["ssm_dt"] = _stack(self.mf, [f"layers.{i}.ssm_dt" for i in layers],
+                                  True, np.float32)
+        self.p["ssm_conv_w"] = self.p["ssm_conv_w"].reshape(
+            len(layers), cfg.ssm_channels, cfg.ssm_conv)
+
 
 def _read_ffn_segments(mf: mfile.MFile, cfg: ModelConfig, p: Params, st: _Stacks,
                        join: bool) -> None:
@@ -483,11 +505,11 @@ def _read_exaone_layers(mf: mfile.MFile, cfg: ModelConfig, p: Params, np_dtype,
     attention of every layer with its two head norms, then the FFN segments;
     the router's choice bias stays float32.  An LFM2 file's: the attention of
     its attention layers, the convolution of the others (the taps float32,
-    ``(Lc, dim, taps)``)."""
+    ``(Lc, dim, taps)``).  A Granite file's: the mixers of the others."""
     every = range(cfg.n_layers)
-    att = [i for i in every if not cfg.conv_taps
+    att = [i for i in every if not cfg.kind_stacked
            or i % cfg.window_period == cfg.window_full_at]
-    conv = [i for i in every if i not in att]
+    other = [i for i in every if i not in att]
     st = _Stacks(mf, p, np_dtype, codec)
     join = codec is not None and fuse
     if join:
@@ -495,15 +517,19 @@ def _read_exaone_layers(mf: mfile.MFile, cfg: ModelConfig, p: Params, np_dtype,
         st.mats(("wo",), att)
     else:
         st.mats(("wq", "wk", "wv", "wo"), att)
-    st.vecs(("q_norm", "k_norm"), att)
+    if cfg.qk_head_norm:
+        st.vecs(("q_norm", "k_norm"), att)
     st.vecs(("rms_att", "rms_ffn"), every)
-    if conv:
-        st.mats(("conv_in", "conv_out"), conv)
-        st.vecs(("conv_taps",), conv)
-        p["conv_taps"] = p["conv_taps"].reshape(len(conv), cfg.dim, cfg.conv_taps)
+    if other and cfg.conv_taps:
+        st.mats(("conv_in", "conv_out"), other)
+        st.vecs(("conv_taps",), other)
+        p["conv_taps"] = p["conv_taps"].reshape(len(other), cfg.dim, cfg.conv_taps)
+    elif other:
+        st.mixers(cfg, other)
     _read_ffn_segments(mf, cfg, p, st, join)
-    st.vecs(("router_bias",), range(cfg.n_dense_layers, cfg.n_layers),
-            src="moe_router_bias")
+    if cfg.router_sigmoid:
+        st.vecs(("router_bias",), range(cfg.n_dense_layers, cfg.n_layers),
+                src="moe_router_bias")
 
 
 def _read_mla_layers(mf: mfile.MFile, cfg: ModelConfig, p: Params, np_dtype,

@@ -133,7 +133,7 @@ def _init_full(shape, cfg: ModelConfig, dtype, quant: bool, rows: int) -> KVCach
     if rows < 1:
         raise ValueError("a pool beside a state-space mixer needs the number "
                          "of slots: each owns a state and its rings")
-    return cache._replace(**ssm.init_planes(cfg, rows, dt))
+    return cache._replace(**ssm.init_planes(cfg, rows, dt, cfg.n_ssm_layers))
 
 
 def init_kv_cache(cfg: ModelConfig, batch: int, seq_len: int | None = None,
@@ -453,12 +453,16 @@ def _retention_block(x, lp, cfg: ModelConfig, cache: KVCache, cos, sin, pos,
 
 def _ssm_block(x, lp, cfg: ModelConfig, cache: KVCache, pos, layer, marks,
                offsets=None, pos_rows=None, packed=None, n_real=None):
-    """Falcon-H1's state-space mixer (``ops/ssm.py`` has the operator and why
-    its state lags the clock), the second branch of a block whose first is
+    """A state-space mixer (``ops/ssm.py`` has the operator and why its state
+    lags the clock).  In Falcon-H1 the second branch of a block whose first is
     :func:`_attention_block` over the same cache: it norms ``x`` with the same
-    vector.  The projections, ``dt``, the gate and the grouped norm are
-    row-local and pack; the convolution, the fold, the rings' write and the
-    read are a per-row sequence operation and keep ``(B, T)``.  ``marks``: the
+    vector.  In Granite the whole of a mixer layer's first sub-block
+    (``models/windowed.py``).  ``layer`` is the layer's place among the layers
+    that have a mixer, which indexes the planes (``cfg.n_ssm_layers`` deep) and
+    is the index ``lp``'s mixer weights were taken at.  The projections,
+    ``dt``, the gate and the grouped norm are row-local and pack; the
+    convolution, the fold, the rings' write and the read are a per-row
+    sequence operation and keep ``(B, T)``.  ``marks``: the
     call's watermarks, as :func:`_retention_block`'s."""
     b, t, _ = x.shape
     h, p, g, n = cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_groups, cfg.ssm_state
@@ -469,14 +473,21 @@ def _ssm_block(x, lp, cfg: ModelConfig, cache: KVCache, pos, layer, marks,
         with scope("norm"):
             u = rmsnorm(x, lp["rms_att"], cfg.norm_eps)
         with scope("qkv"), part("ssm"):
-            u = u * _mup(cfg, "ssm_in")
+            if cfg.mup_ssm_in != 1.0:
+                u = u * _mup(cfg, "ssm_in")
             z, xbc = jnp.split(_mm(u, lp["ssm_in"], cfg), [inner], axis=-1)
-            mup = jnp.repeat(jnp.asarray([cfg.mup_x, cfg.mup_b, cfg.mup_c], cfg.dtype),
-                             jnp.asarray([inner, g * n, g * n]),
-                             total_repeat_length=cfg.ssm_channels)
+            # ``ssm_multipliers`` (Falcon-H1), in the order of ``W_in``'s split
+            scaled = (cfg.mup_z, cfg.mup_x, cfg.mup_b, cfg.mup_c, cfg.mup_dt) != (1.0,) * 5
+            if scaled:
+                mup = jnp.repeat(
+                    jnp.asarray([cfg.mup_x, cfg.mup_b, cfg.mup_c], cfg.dtype),
+                    jnp.asarray([inner, g * n, g * n]),
+                    total_repeat_length=cfg.ssm_channels)
             dt = jnp.matmul(u.astype(f32), lp["ssm_dt"].astype(f32), precision=hi)
-            dt = jax.nn.softplus(dt * cfg.mup_dt + lp["ssm_dt_bias"])
-            return z * _mup(cfg, "z"), xbc * mup, dt
+            if scaled:
+                dt = dt * cfg.mup_dt
+            dt = jax.nn.softplus(dt + lp["ssm_dt_bias"])
+            return (z * _mup(cfg, "z"), xbc * mup, dt) if scaled else (z, xbc, dt)
 
     def project_out(y, z, lp, cfg):
         with scope("wo"), part("ssm"):
@@ -485,7 +496,8 @@ def _ssm_block(x, lp, cfg: ModelConfig, cache: KVCache, pos, layer, marks,
             y = (y * jax.nn.silu(z.astype(f32))).reshape(*y.shape[:-1], g, -1)
             y = y * jax.lax.rsqrt(jnp.mean(y * y, -1, keepdims=True) + cfg.norm_eps)
             y = y.reshape(*z.shape) * lp["ssm_norm"]
-            return _mm(y.astype(cfg.dtype), lp["ssm_out"], cfg) * _mup(cfg, "ssm_out")
+            out = _mm(y.astype(cfg.dtype), lp["ssm_out"], cfg)
+            return out if cfg.mup_ssm_out == 1.0 else out * _mup(cfg, "ssm_out")
 
     z, xbc, dt = packing.over(packed, "qkv", project, x)
     rows = pos_rows if pos_rows is not None else jnp.broadcast_to(pos, (b,))
@@ -1015,16 +1027,17 @@ def run_blocks(params: Params, cfg: ModelConfig, tokens: jax.Array,
     if cfg.is_mla:
         return _run_segments(params, cfg, x, cache, cos, sin, pos, offsets,
                              pos_rows, paged, packed)
-    if cfg.periodic:
-        return windowed.run_periods(params, cfg, x, cache, cos, sin, pos,
-                                    offsets, pos_rows, paged, packed, n_real)
-
     marks = None
     if cfg.folds_state:  # the watermarks of this call, once for all layers
         with scope("page_idx"):
             marks = retention.clock(
                 cache.rw, pos_rows if pos_rows is not None
                 else jnp.broadcast_to(pos, (b,)), t, n_real)
+    if cfg.periodic:
+        x, cache = windowed.run_periods(params, cfg, x, cache, cos, sin, pos,
+                                        offsets, pos_rows, paged, packed,
+                                        n_real, marks)
+        return x, _marked(cache, marks)
 
     layer_keys = [k for k in params if k not in ("embedding", "rms_final", "wcls")]
     # Packed-Q40 weights stay out of the scan's xs: the scan would slice a
@@ -1126,9 +1139,15 @@ def run_blocks(params: Params, cfg: ModelConfig, tokens: jax.Array,
 
         (x, cache), _ = jax.lax.scan(one_pass, (x, cache),
                                      jnp.arange(loops, dtype=jnp.int32))
-    if marks is not None:
-        cache = cache._replace(rw=marks[1].reshape(cache.rw.shape))
-    return x, cache
+    return x, _marked(cache, marks)
+
+
+def _marked(cache: KVCache, marks) -> KVCache:
+    """``cache`` with the watermarks the call moved to (``marks``: None where no
+    layer's state lags the clock)."""
+    if marks is None:
+        return cache
+    return cache._replace(rw=marks[1].reshape(cache.rw.shape))
 
 
 def _run_segments(params: Params, cfg: ModelConfig, x, cache: KVCache, cos,

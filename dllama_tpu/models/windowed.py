@@ -1,11 +1,15 @@
 """Layers of a model whose layers come in periods (SmallThinker, K-EXAONE,
-LFM2): ``window_period`` layers of which one, at ``window_full_at``, is full
-attention, over a cache with a kind of plane per layer kind.  The others are
-sliding-window attention (``cfg.window``) or gated short convolutions
-(``cfg.conv_taps``, ``ops/conv.py``: no keys or values, a ring of the last
-positions' ``z`` in the plane ``cz``); a convolution model's full layers carry
-RoPE (``cfg.full_rotates``) and its weights are stacked by layer kind
-(``params.ATT_KIND_KEYS`` / ``CONV_KEYS``, indexed by ``kind_index``).
+LFM2, Granite-4.0-H): ``window_period`` layers of which one, at
+``window_full_at``, is full attention, over a cache with a kind of plane per
+layer kind.  The others are sliding-window attention (``cfg.window``), gated
+short convolutions (``cfg.conv_taps``, ``ops/conv.py``: no keys or values, a
+ring of the last positions' ``z`` in the plane ``cz``) or Mamba-2 state-space
+mixers (``cfg.ssm_heads``, ``ops/ssm.py``: a state matrix a head behind rings
+of recent positions, ``transformer._ssm_block``, the function Falcon-H1's
+blocks call).  A convolution model's full layers carry RoPE
+(``cfg.full_rotates``), a mixer model's rotate nothing; the weights of both are
+stacked by layer kind (``cfg.kind_stacked``: ``params.ATT_KIND_KEYS`` /
+``CONV_KEYS`` / ``MIXER_KEYS``, indexed by ``kind_index``).
 
 A layer ``l`` with ``l % window_period == window_full_at`` is *full*: no
 rotation at all (NoPE) and a causal mask over every position.  The others are
@@ -28,9 +32,11 @@ scheduler's page tables address, and ``wk`` / ``wv`` the window layers' planes
 ``kind_index``.
 
 The layer loop is a ``lax.scan`` over periods whose body unrolls the period's
-layers, so a layer's kind is static where it is traced; the periods that hold
-a dense layer are unrolled in front of the scan.  Weights stay stacked by
-layer (by segment for the two FFN kinds) and are indexed where used, as in
+layers, so a layer's kind is static where it is traced (a period's runs of
+mixer layers, nine of Granite's ten, are a scan of their own inside it: one
+mixer body a run, half of the program to trace and compile); the periods that
+hold a dense layer are unrolled in front of the scan.  Weights stay stacked
+by layer (by segment for the two FFN kinds) and are indexed where used, as in
 ``transformer.run_blocks``.
 """
 
@@ -39,7 +45,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from ..ops import conv, q40, q8, window
+from ..ops import conv, q40, q8, ssm, window
 from ..ops.attention import (gqa_attention_at, live_gqa_attention,
                              paged_gqa_attention_at, paged_update_kv_rows,
                              pool_rows,
@@ -49,7 +55,8 @@ from ..ops.scopes import part, scope
 from . import grouping, packing
 from .cache_kinds import SLOT_ROWS
 from .config import ModelConfig
-from .params import ATT_KIND_KEYS, CONV_KEYS, DENSE_FFN_KEYS, MOE_FFN_KEYS
+from .params import (ATT_KIND_KEYS, CONV_KEYS, DENSE_FFN_KEYS, MIXER_KEYS,
+                     MOE_FFN_KEYS)
 
 
 # keys a trip of a full layer's live walk reads for ONE decoded token.  A trip
@@ -67,22 +74,25 @@ def init_cache(cfg: ModelConfig, batch: int, seq_len: int, dtype):
     dt = dtype or cfg.dtype
     tail = (cfg.n_kv_heads, seq_len, cfg.head_size)
     full = (cfg.n_full_layers, batch) + tail
-    if cfg.conv_taps:
+    if cfg.kind_stacked:
         return KVCache(jnp.zeros(full, dt), jnp.zeros(full, dt),
-                       cz=_conv_state(cfg, batch, dt))
+                       **_slot_state(cfg, batch, dt))
     ring = (cfg.n_kv_heads, cfg.window_ring(seq_len), cfg.head_size)
     win = (cfg.n_window_layers, batch) + ring
     return KVCache(jnp.zeros(full, dt), jnp.zeros(full, dt),
                    wk=jnp.zeros(win, dt), wv=jnp.zeros(win, dt))
 
 
-def _conv_state(cfg: ModelConfig, rows: int, dt):
-    """The convolution layers' state ``(Lc, rows, 1, R, D)``: a ring of
-    ``conv.RING`` positions of ``z`` a row (a sequence of the contiguous
-    cache, a slot of a slot engine), laid out as a ring of one head of ``D``
-    so that the window writes of ``ops/window.py`` and the cache's one
-    placement apply."""
-    return jnp.zeros((cfg.n_conv_layers, rows, 1, conv.RING, cfg.dim), dt)
+def _slot_state(cfg: ModelConfig, rows: int, dt) -> dict:
+    """What a period's other layers keep of ``rows`` rows (a sequence of the
+    contiguous cache, a slot of a slot engine), by field of ``KVCache``.  The
+    convolution layers' state ``cz (Lc, rows, 1, R, D)``: a ring of
+    ``conv.RING`` positions of ``z`` a row, laid out as a ring of one head of
+    ``D`` so that the window writes of ``ops/window.py`` and the cache's one
+    placement apply; or the mixer layers' planes (``ops/ssm.py``)."""
+    if cfg.has_ssm:
+        return ssm.init_planes(cfg, rows, dt, cfg.n_ssm_layers)
+    return {"cz": jnp.zeros((cfg.n_conv_layers, rows, 1, conv.RING, cfg.dim), dt)}
 
 
 def init_pool(cfg: ModelConfig, n_pages: int, page_size: int, dtype,
@@ -92,17 +102,20 @@ def init_pool(cfg: ModelConfig, n_pages: int, page_size: int, dtype,
     ``window_pages(window, SLOT_ROWS, page_size, max_pages)`` pages each."""
     from .transformer import KVCache
     if slots < 1:
-        raise ValueError("a windowed model's pool needs the number of slots: "
-                         "each owns a ring of pages in the window layers' planes")
+        raise ValueError(
+            "a pool beside a state-space mixer needs the number of slots: each "
+            "owns a state and its rings" if cfg.has_ssm else
+            "a windowed model's pool needs the number of slots: each owns a "
+            "ring of pages in the window layers' planes")
     dt = dtype or cfg.dtype
     page = (page_size, cfg.n_kv_heads, cfg.head_size)
     # the pool's heads under 128 lanes are stored lane-dense (ops/attention.py
     # pool_rows); the window layers' rings are read as slices, never by page
     full = (cfg.n_full_layers, n_pages, page_size) + pool_rows(
         cfg.n_kv_heads, cfg.head_size)
-    if cfg.conv_taps:  # the state is the slots' own, whatever the pages hold
+    if cfg.kind_stacked:  # the state is the slots' own, whatever the pages hold
         return KVCache(jnp.zeros(full, dt), jnp.zeros(full, dt),
-                       cz=_conv_state(cfg, slots, dt))
+                       **_slot_state(cfg, slots, dt))
     ring = window.window_pages(cfg.window, SLOT_ROWS, page_size, max_pages)
     win = (cfg.n_window_layers, slots * ring) + page
     return KVCache(jnp.zeros(full, dt), jnp.zeros(full, dt),
@@ -163,7 +176,7 @@ def _attention(x, lp, cfg: ModelConfig, cache, cos, sin, pos, plane,
     """One attention sub-block; ``plane`` indexes the cache's stack of this
     layer's kind (the contiguous planes or rings, the pool or the slots'
     rings of pages).  ``packed``: as ``transformer._attention_block``."""
-    from .transformer import _mm, _project_out
+    from .transformer import _mm, _mup, _project_out
     b, t, _ = x.shape
     hq, hkv, dh = cfg.n_heads, cfg.n_kv_heads, cfg.head_size
 
@@ -176,6 +189,8 @@ def _attention(x, lp, cfg: ModelConfig, cache, cos, sin, pos, plane,
                                     [hq * dh, (hq + hkv) * dh], axis=-1)
             else:
                 q, k, v = (_mm(xb, lp[w], cfg, kind="row") for w in ("wq", "wk", "wv"))
+            if cfg.mup_key != 1.0:  # before the write (Granite: the scores' scale)
+                k = k * _mup(cfg, "key")
             lead = x.shape[:-1]
             q = q.reshape(*lead, hq, dh)
             k = k.reshape(*lead, hkv, dh)
@@ -256,14 +271,15 @@ def _attention(x, lp, cfg: ModelConfig, cache, cos, sin, pos, plane,
 
 
 def run_periods(params, cfg: ModelConfig, x, cache, cos, sin, pos, offsets,
-                pos_rows, paged, packed=None, n_real=None):
+                pos_rows, paged, packed=None, n_real=None, marks=None):
     """All layers of a periodic model over the residual stream ``x (B, T,
     D)``; returns it and the updated cache (``transformer.run_blocks`` has
     embedded the tokens and made the angles, and planned ``packed``).
     ``n_real``: how many of the ``T`` rows hold a token (a scalar, or ``(B,)``
     on a slot step; ``None``: all), which a convolution layer's state write
-    needs of a call wider than its ring."""
-    from .transformer import _dense_ffn, moe_ffn
+    needs of a call wider than its ring.  ``marks``: the call's watermarks
+    where some layer's state lags the clock (``transformer._ssm_block``)."""
+    from .transformer import _dense_ffn, _mup, _ssm_block, moe_ffn
     b, t, d = x.shape
     period, n_dense = cfg.window_period, cfg.n_dense_layers
     router_first = cfg.router_reads_input
@@ -272,10 +288,11 @@ def run_periods(params, cfg: ModelConfig, x, cache, cos, sin, pos, offsets,
     dense_keys = [k for k in keys if n_dense and k in DENSE_FFN_KEYS]
     moe_keys = [k for k in keys if n_dense and k in MOE_FFN_KEYS]
     att_keys = [k for k in keys if k not in dense_keys and k not in moe_keys]
-    if cfg.conv_taps:  # its operators' weights are stacked by layer kind
-        kind_keys = {False: [k for k in att_keys if k in CONV_KEYS],
+    if cfg.kind_stacked:  # its operators' weights are stacked by layer kind
+        other_keys = CONV_KEYS if cfg.conv_taps else MIXER_KEYS
+        kind_keys = {False: [k for k in att_keys if k in other_keys],
                      True: [k for k in att_keys if k in ATT_KIND_KEYS]}
-        att_keys = [k for k in att_keys if k not in CONV_KEYS + ATT_KIND_KEYS]
+        att_keys = [k for k in att_keys if k not in other_keys + ATT_KIND_KEYS]
     if paged is not None and cache.wk is not None:
         with scope("page_idx"):  # the window planes' write places, once
             paged = paged + (window.paged_ring_indices(
@@ -289,9 +306,9 @@ def run_periods(params, cfg: ModelConfig, x, cache, cos, sin, pos, offsets,
     def one_layer(x, kvc, layer, j: int, plane, dense: bool):
         """Layer ``layer`` (traced or static), the ``j``-th of its period."""
         full = j == cfg.window_full_at
-        windowed = not full and not cfg.conv_taps
+        windowed = not full and not cfg.kind_stacked
         lp = {k: at(params[k], layer) for k in att_keys}
-        if cfg.conv_taps:
+        if cfg.kind_stacked:
             lp.update({k: at(params[k], plane) for k in kind_keys[full]})
         lp.update({k: at(params[k], layer - (0 if dense else n_dense))
                    for k in (dense_keys if dense else moe_keys)})
@@ -304,9 +321,13 @@ def run_periods(params, cfg: ModelConfig, x, cache, cos, sin, pos, offsets,
         if full or windowed:
             att_out, kvc = _attention(x, lp, cfg, kvc, cos, sin, pos, plane,
                                       windowed, offsets, pos_rows, paged, packed)
-        else:
+        elif cfg.conv_taps:
             att_out, kvc = _short_conv(x, lp, cfg, kvc, pos, plane, offsets,
                                        pos_rows, n_real, packed)
+        else:
+            att_out, kvc = _ssm_block(x, lp, cfg, kvc, pos, plane, marks,
+                                      offsets=offsets, pos_rows=pos_rows,
+                                      packed=packed, n_real=n_real)
         with scope("wo"):
             x = x + att_out
 
@@ -332,15 +353,35 @@ def run_periods(params, cfg: ModelConfig, x, cache, cos, sin, pos, offsets,
         ff = packing.over(packed, "moe", experts, x,
                        *(() if router_logits is None else (router_logits,)))
         with scope("moe"):
+            if cfg.mup_down != 1.0:
+                # the experts' and the shared MLP's sum times one scalar
+                # (Granite's residual multiplier: what stands on a dense FFN's
+                # down projection, where ``_dense_ffn`` applies it itself)
+                ff = ff * _mup(cfg, "down")
             return x + ff, kvc
+
+    # a period's layers in runs of one kind: a run of mixer layers is ONE body
+    # under a scan of its own (stacked by kind, its weights and planes are a
+    # run of their stacks); attention layers, window layers and convolutions
+    # stay unrolled, as their cells were measured
+    runs = [(j, 1) for j in range(period)]
+    if cfg.has_ssm and not n_dense:
+        mid = cfg.window_full_at
+        runs = [r for r in ((0, mid), (mid, 1), (mid + 1, period - mid - 1)) if r[1]]
 
     def one_period(carry, p, first_dense: int = 0):
         """Period ``p`` (traced in the scan, static in front of it); its first
         ``first_dense`` layers have the dense FFN."""
         x, kvc = carry
-        for j in range(period):
-            x, kvc = one_layer(x, kvc, p * period + j, j, kind_index(cfg, p, j),
-                               dense=j < first_dense)
+        for j, n in runs:
+            first, plane = p * period + j, kind_index(cfg, p, j)
+            if n == 1:
+                x, kvc = one_layer(x, kvc, first, j, plane, dense=j < first_dense)
+                continue
+            (x, kvc), _ = grouping.scan(
+                lambda c, i, j=j, first=first, plane=plane: (one_layer(
+                    *c, first + i, j, plane + i, dense=False), None),
+                (x, kvc), jnp.arange(n, dtype=jnp.int32))
         return (x, kvc), None
 
     carry = (x, cache)

@@ -243,7 +243,7 @@ class CostModel:
                  moe_hidden_dim: int = 0, n_shared_experts: int = 0,
                  mla: dict | None = None, head_dim: int = 0,
                  window: int = 0, window_period: int = 0, n_loops: int = 1,
-                 ssm: dict | None = None):
+                 ssm: dict | None = None, n_ssm_layers: int | None = None):
         self.dim = dim
         self.hidden_dim = hidden_dim
         #: a looped model runs its ``n_layers`` weight sets ``n_loops`` times a
@@ -311,20 +311,30 @@ class CostModel:
         #: and the ring's attention form (``state_flops``): depth-free, where
         #: the same block's keys and values are not
         self.ssm = ssm
+        #: ``n_ssm_layers`` (Granite): the mixer is a layer KIND, so that many
+        #: layers have its projections and state and the OTHERS attention's
+        #: projections, keys and values; ``None``: every layer has both
+        self.n_ssm_layers = (n_layers if n_ssm_layers is None else n_ssm_layers
+                             ) if ssm else 0
+        self.n_kv_layers = n_layers if n_ssm_layers is None else (
+            n_layers - n_ssm_layers)
+        mixer = 0
         if ssm:
             inner = ssm["heads"] * ssm["head_dim"]
             bc = 2 * ssm["groups"] * ssm["state"]
-            attn += dim * (2 * inner + bc + ssm["heads"]) + inner * dim
-        #: matmul weights touched per token (logits head separate)
-        self.params_per_token = n_layers * (attn + ffn)
-        if n_dense_layers and self.moe:
+            mixer = dim * (2 * inner + bc + ssm["heads"]) + inner * dim
+        if (n_dense_layers or moe_hidden_dim) and self.moe:
             # two layer kinds: a dense FFN in the leading layers, and in the
             # rest the token's routed experts plus the shared expert
             fe = moe_hidden_dim or hidden_dim
             moe_ffn = 3 * dim * fe * (n_active_experts + n_shared_experts)
-            self.params_per_token = (
-                n_layers * attn + n_dense_layers * 3 * dim * hidden_dim
-                + (n_layers - n_dense_layers) * moe_ffn)
+            ffns = (n_dense_layers * 3 * dim * hidden_dim
+                    + (n_layers - n_dense_layers) * moe_ffn)
+        else:
+            ffns = n_layers * ffn
+        #: matmul weights touched per token (logits head separate)
+        self.params_per_token = (self.n_kv_layers * attn
+                                 + self.n_ssm_layers * mixer + ffns)
 
     # --- building blocks (all return ints) -------------------------------
 
@@ -357,7 +367,7 @@ class CostModel:
     def attn_flops(self, pos: int, n_new: int) -> int:
         """QK^T + weighted V sum: 4 * dim MACs -> FLOPs per (query,
         context) pair, per layer."""
-        full = self.n_layers - self.n_window_layers
+        full = self.n_kv_layers - self.n_window_layers
         seen = full * self._ctx_sum(pos, n_new) + self.n_window_layers * sum(
             min(pos + j + 1, self.window) for j in range(
                 n_new if self.n_window_layers else 0))
@@ -371,7 +381,7 @@ class CostModel:
         return self.kv_values * self.kv_el_bytes
 
     def kv_write_bytes(self, n_new: int) -> int:
-        return n_new * self.n_layers * self.kv_pos_bytes()
+        return n_new * self.n_kv_layers * self.kv_pos_bytes()
 
     def _read_positions(self, pos: int, n_new: int, burst: bool,
                         window: int = 0) -> int:
@@ -389,7 +399,7 @@ class CostModel:
         return paged_up(pos + n_new)
 
     def kv_read_bytes(self, pos: int, n_new: int, burst: bool) -> int:
-        full = self.n_layers - self.n_window_layers
+        full = self.n_kv_layers - self.n_window_layers
         positions = full * self._read_positions(pos, n_new, burst)
         if self.n_window_layers:
             positions += self.n_window_layers * self._read_positions(
@@ -405,7 +415,7 @@ class CostModel:
         state = z["heads"] * z["state"] * z["head_dim"] * 4
         ring = z["ring"] * ((z["heads"] * z["head_dim"] + z["groups"]
                              * z["state"]) * self.kv_el_bytes + z["heads"] * 4)
-        return passes * self.n_layers * (state + ring)
+        return passes * self.n_ssm_layers * (state + ring)
 
     def state_flops(self, n_new: int) -> int:
         """Multiply-adds x 2 of the same for ``n_new`` query tokens: each
@@ -416,7 +426,7 @@ class CostModel:
             return 0
         per_head = z["state"] * z["head_dim"] + z["ring"] * (
             z["state"] + z["head_dim"])
-        return 2 * n_new * self.n_layers * z["heads"] * per_head
+        return 2 * n_new * self.n_ssm_layers * z["heads"] * per_head
 
     def ring_bytes(self, tokens: int) -> int:
         """Aggregate TP ring all-reduce hop bytes: two f32 reduces of
@@ -572,7 +582,9 @@ def model_from_engine(engine) -> CostModel | None:
             window_period=cfg.window_period, n_loops=cfg.n_loops,
             ssm=dict(heads=cfg.ssm_heads, head_dim=cfg.ssm_head_dim,
                      state=cfg.ssm_state, groups=cfg.ssm_groups,
-                     ring=engine.cache.rk.shape[3]) if cfg.has_ssm else None)
+                     ring=engine.cache.rk.shape[3]) if cfg.has_ssm else None,
+            n_ssm_layers=cfg.n_ssm_layers if cfg.has_ssm and cfg.periodic
+            else None)
     except Exception:
         return None
 

@@ -1,5 +1,6 @@
 """Mamba-2's selective state-space mixer ("SSD"; Falcon-H1's second sequence
-mixer, beside grouped-query attention in every block) and its state: a matrix a
+mixer, beside grouped-query attention in every block, and the other layers of a
+Granite-4.0-H period, ``models/windowed.py``) and its state: a matrix a
 head that no position addresses, kept a block behind the position clock by
 ``ops/retention.py``'s watermark, beside a position-addressed ring of the
 convolution's input as ``ops/conv.py``'s.
@@ -21,7 +22,8 @@ operator with ``phi`` the identity, no quotient and the gate ``dt * A``, so the
 state is kept rewindable the same way and by the same rule, which this module
 imports and does not restate: the state ``rs (L, rows, H, N, P)`` float32 holds
 the tokens ``[0, w)``; rings of ``retention.RING`` recent positions hold ``B``
-(``rk (L, rows, G, RING, N)``), ``x`` (``rv (L, rows, H, RING, P)``) and ``dt``
+(``rk (L, rows, G, RING, N)``), ``x`` (``rv (L, rows, H, RING, P)``; heads of
+64 two to a row, ``heads_a_row``) and ``dt``
 (``rg (L, rows, 1, RING, H)`` float32) of ``[w, pos]``; ``rw`` is the watermark
 ``w``; ``retention.clock`` / ``watermark`` say when ``FOLD`` positions leave the
 ring for the state, ``REWIND`` behind the clock, so a rewind within ``REWIND``
@@ -69,16 +71,44 @@ from .retention import FOLD, RING, _in_order
 from .scopes import part
 
 _HI = jax.lax.Precision.HIGHEST
+_LANES = 128
 
 
-def init_planes(cfg, rows: int, dt) -> dict:
+def heads_a_row(h: int, p: int) -> int:
+    """Heads of ``x`` a row of the ring ``rv`` holds: 2 where a head is half a
+    lane row (``P`` = 64: Granite) and the heads pair up, else 1.  A plane
+    whose minor axis is 64 wide is laid out by the TPU compiler with the axis
+    before it, the ring's 128 positions, along the lanes: one position's write
+    is then a store a value (8192 a slot a layer), and the pure-decode step
+    spent 0.7 ms a layer in them (PERF.md section 6, PR 65).  Two heads side by
+    side fill the lanes, and a position is a row again.  Heads narrower still
+    keep a head a row: no served model has them."""
+    return 2 if 2 * p == _LANES and h % 2 == 0 else 1
+
+
+def _paired(x, rows: int):
+    """``x (B, H, T, P)`` as the ring holds it, ``(B, rows, T, H / rows * P)``:
+    the heads that share a row side by side."""
+    b, h, t, p = x.shape
+    if rows == h:
+        return x
+    return x.reshape(b, rows, h // rows, t, p).transpose(0, 1, 3, 2, 4).reshape(
+        b, rows, t, -1)
+
+
+def init_planes(cfg, rows: int, dt, layers: int | None = None) -> dict:
     """The mixer's planes of ``rows`` rows (module docstring), by field of
-    ``KVCache``."""
-    L, h, g = cfg.n_layers, cfg.ssm_heads, cfg.ssm_groups
+    ``KVCache``, ``layers`` deep: as many as the model has mixer layers
+    (``cfg.n_ssm_layers``: every block of Falcon-H1; a period's other layers of
+    Granite, whose attention layers keep keys and values in planes of their
+    own depth).  ``None``: every layer of ``cfg``."""
+    L = cfg.n_layers if layers is None else layers
+    h, g = cfg.ssm_heads, cfg.ssm_groups
+    f = heads_a_row(h, cfg.ssm_head_dim)
     return {
         "rs": jnp.zeros((L, rows, h, cfg.ssm_state, cfg.ssm_head_dim), jnp.float32),
         "rk": jnp.zeros((L, rows, g, RING, cfg.ssm_state), dt),
-        "rv": jnp.zeros((L, rows, h, RING, cfg.ssm_head_dim), dt),
+        "rv": jnp.zeros((L, rows, h // f, RING, f * cfg.ssm_head_dim), dt),
         "rg": jnp.zeros((L, rows, 1, RING, h), jnp.float32),
         "rw": jnp.zeros((1, rows, 1, 1, 1), jnp.int32),
         "cz": jnp.zeros((L, rows, 1, conv.RING, cfg.ssm_channels), dt),
@@ -131,8 +161,21 @@ def fold(rs, rk, rv, rg, a, layer, w, w_new):
         la = dt * a[:, None]
         total = jnp.sum(la, axis=-1)                            # (H,)
         coef = jnp.exp(total[:, None] - jnp.cumsum(la, axis=-1)) * dt
-        s_add = jnp.einsum("gmjn,gmjp->gmnp", bf[:, None] * coef.reshape(
-            g, h // g, FOLD, 1), xf.reshape(g, h // g, FOLD, p), precision=_HI)
+        f = h // xf.shape[0]
+        if f == 1:
+            s_add = jnp.einsum("gmjn,gmjp->gmnp", bf[:, None] * coef.reshape(
+                g, h // g, FOLD, 1), xf.reshape(g, h // g, FOLD, p), precision=_HI)
+        else:
+            # the ring's rows hold f heads side by side (``heads_a_row``): each
+            # head's weighted B against the whole row, its own P columns kept;
+            # the slice of the ring is taken as it lies (splitting its rows by
+            # head made XLA lay the whole plane the other way round and copy it
+            # in and out of every step)
+            bw = (jnp.repeat(bf, h // g, axis=0) * coef[..., None]).reshape(
+                h // f, f, FOLD, n)
+            s_add = jnp.stack([jnp.einsum(
+                "rjn,rjq->rnq", bw[:, k], xf, precision=_HI)[
+                    ..., k * p:(k + 1) * p] for k in range(f)], axis=1)
         keep = jnp.where(at > 0, jnp.exp(total), 0.0)           # (H,)
         s_old = jax.lax.dynamic_slice(rs, (li, row, zero, zero, zero),
                                       (1, 1, h, n, p))
@@ -147,7 +190,7 @@ def write(rk, rv, rg, b, x, dt, layer, pos):
     """A call's ``b (B, G, T, N)``, ``x (B, H, T, P)`` and ``dt (B, T, H)`` into
     the rings at ``layer``, row ``r`` at positions ``pos[r] .. pos[r] + T - 1``."""
     rk = window.ring_write_plane(rk, b, layer, pos)
-    rv = window.ring_write_plane(rv, x, layer, pos)
+    rv = window.ring_write_plane(rv, _paired(x, rv.shape[2]), layer, pos)
     rg = window.ring_write_plane(rg, dt[:, None], layer, pos)
     return rk, rv, rg
 
@@ -159,7 +202,7 @@ def read(c, rs, rk, rv, rg, a, layer, pos, base):
     written)."""
     b, g, t, n = c.shape
     h, p = rs.shape[2], rs.shape[4]
-    m = h // g
+    m, f = h // g, h // rv.shape[2]
     obs_dispatch.record_dispatch(
         "ssm", "state-read" if t == 1 else "block", t=t, ring=RING, N=n)
     li = layer.astype(jnp.int32)
@@ -189,7 +232,20 @@ def read(c, rs, rk, rv, rg, a, layer, pos, base):
         # swap of halves goes either way): a ring of x is 128 times a row of
         # weights, and the layer's slice feeds the product uncopied
         w = _in_order(w[..., None], swap)[..., 0]
-        y = jnp.einsum("bhtc,bhcp->bhtp", w, xr.astype(jnp.float32), precision=_HI)
+        if f == 1:
+            y = jnp.einsum("bhtc,bhcp->bhtp", w, xr.astype(jnp.float32),
+                           precision=_HI)
+        else:
+            # a row of the ring holds f heads side by side (``heads_a_row``):
+            # each of them against the whole row, as the one-head form above
+            # (ONE product over both, two rows of weights a row of the ring,
+            # made XLA lay the ring along the lanes again and copy the plane
+            # to do it: 0.6 GB of temporaries), its own P columns kept
+            wf, xf = w.reshape(b, h // f, f, t, RING), xr.astype(jnp.float32)
+            y = jnp.stack([jnp.einsum(
+                "bgtc,bgcq->bgtq", wf[:, :, k], xf, precision=_HI)[
+                    ..., k * p:(k + 1) * p] for k in range(f)],
+                axis=2).reshape(b, h, t, p)
     with part("state"):
         s = jax.lax.dynamic_index_in_dim(rs, li, 0, False)         # (B, H, N, P)
         since = jnp.where((base > 0)[:, None, None], jnp.exp(gq), 0.0)

@@ -703,7 +703,7 @@ class Engine:
         if st.folds:
             was = self._state_lo if pos else 0  # position 0 starts a sequence
             lo = retention.watermark(was, pos + n_real)
-            st.folds.inc(int((lo - was) // retention.FOLD) * self.cfg.n_layers)
+            st.folds.inc(int((lo - was) // retention.FOLD) * st.depth(self.cfg))
             self._state_lo, self._state_hi = lo, pos + n_real
             if taps:
                 self._state_ring_lo = self._ring_low(
@@ -731,7 +731,7 @@ class Engine:
         was = np.where(pos_rows_np == 0, 0, self._slot_marks)
         self._slot_marks = retention.watermark(was, clock_np)
         blocks = int(np.sum((self._slot_marks - was) // retention.FOLD))
-        self._state.folds.inc(blocks * self.cfg.n_layers)  # a block in every layer
+        self._state.folds.inc(blocks * self._state.depth(self.cfg))  # a block a layer of the kind
 
     def _max_burst(self, chunk: int) -> int:
         """``chunk`` within what a recurrent state's rewinds allow."""

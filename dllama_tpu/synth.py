@@ -63,6 +63,26 @@ MODEL_SHAPES = {
                            n_heads=8, n_kv_heads=8, n_experts=64,
                            n_active_experts=8, vocab_size=4096, seq_len=256,
                            dtype="float32"),
+    # Granite-4.0-H-Small (ibm-granite/granite-4.0-h-small): mixer layers 9 : 1
+    # with position-free attention layers, 72 experts top-10 and a shared MLP;
+    # the header keys past the fourteen ride in the shape (``_ext``)
+    "granite-4.0-h-small": dict(
+        arch="granitemoehybrid", dim=4096, hidden_dim=1536, n_layers=40,
+        n_heads=32, n_kv_heads=8, n_experts=72, n_active_experts=10,
+        vocab_size=100352, seq_len=2048, dtype="bfloat16", norm_eps=1e-5,
+        head_dim=128, window_period=10, window_full_at=5, moe_hidden_dim=768,
+        n_shared_experts=2, ssm_heads=128, ssm_head_dim=64, ssm_state=128,
+        ssm_groups=1, ssm_conv=4, mup_embedding=12.0, mup_head=0.0625,
+        mup_key=0.0078125 * 128 ** 0.5, mup_attn_out=0.22, mup_ssm_out=0.22,
+        mup_down=0.22),
+    "cpu-tiny-granite": dict(
+        arch="granitemoehybrid", dim=128, hidden_dim=64, n_layers=5,
+        n_heads=8, n_kv_heads=2, n_experts=12, n_active_experts=3,
+        vocab_size=300, seq_len=256, dtype="float32", norm_eps=1e-5,
+        head_dim=16, window_period=5, window_full_at=2, moe_hidden_dim=32,
+        n_shared_experts=2, ssm_heads=4, ssm_head_dim=64, ssm_state=32,
+        ssm_groups=1, ssm_conv=4, mup_embedding=12.0, mup_head=0.0625,
+        mup_key=0.3, mup_attn_out=0.22, mup_ssm_out=0.22, mup_down=0.22),
     "cpu-tiny": dict(dim=512, hidden_dim=1408, n_layers=4, n_heads=8,
                      n_kv_heads=8, vocab_size=4096, seq_len=256,
                      dtype="float32"),
@@ -77,6 +97,12 @@ def model_shape(name: str) -> dict:
 
 def _arch_id(shape: dict) -> int:
     return {v: k for k, v in mfile.ARCH_NAMES.items()}[shape.get("arch", "llama")]
+
+
+def _ext(shape: dict) -> dict:
+    """The header keys past the fourteen that a shape states (none for the
+    archs that have none)."""
+    return {name: shape[name] for _, name, _ in mfile.ALL_EXT_KEYS if name in shape}
 
 
 def model_cfg(name: str):
@@ -130,7 +156,7 @@ def synth_model_files(name: str, dirpath: str, n_layers: int | None = None,
         vocab_size=shape["vocab_size"], seq_len=shape["seq_len"],
         hidden_act=mfile.ACT_SILU,
         rope_theta=shape.get("rope_theta", 10000.0),
-        weights_ftype=quants.Q40)
+        weights_ftype=quants.Q40, **_ext(shape))
     stem = f"{name}-L{spec.n_layers}-s{seed}-synth"
     mpath = os.path.join(dirpath, stem + ".m")
     tpath = os.path.join(dirpath, stem + ".t")

@@ -744,3 +744,121 @@ def np_forward_falcon_h1(params, cfg, tokens, wrong=""):
                  ) @ seg("w2", li) * mup("down")
     x = norm(x, np.asarray(params["rms_final"], np.float32))
     return (x @ np.asarray(params["wcls"], np.float32) * mup("head")).astype(np.float32)
+
+
+def np_forward_granite_hybrid(params, cfg, tokens, wrong=""):
+    """Full-sequence forward, (T, V) float32 logits, of Granite-4.0-H: the
+    embedding times ``mup_embedding``; layer ``l`` is grouped-query attention
+    WITHOUT positions where ``l % window_period == window_full_at`` (scores
+    ``q . k * mup_key / sqrt(head)``: the key's multiplier carries
+    ``attention_multiplier``) and else a Mamba-2 mixer (``np_forward_falcon_h1``'s,
+    with no multiplier inside, ONE group, the gate first and one RMSNorm over
+    all of its channels), its weights at the layer's place among its kind;
+    then top-k of a softmax router renormalised over the CHOSEN experts plus a
+    shared gated MLP, in every layer; each branch's output times its
+    multiplier (``mup_attn_out``, ``mup_ssm_out``, ``mup_down``: the published
+    ``residual_multiplier`` three times); logits times ``mup_head``.
+
+    ``wrong`` names one deliberate fault: ``no_<name>`` for ``cfg.mup_<name>``
+    set to 1 (``no_residual``: all three of the branches'), ``rope`` (q and k rotated), ``softmax_all`` (the chosen weights
+    as the softmax over ALL experts gave them), ``no_shared``, ``no_decay``,
+    ``no_conv``, ``norm_before_gate``, ``no_skip``."""
+    t = len(tokens)
+    hq, hkv, dh = cfg.n_heads, cfg.n_kv_heads, cfg.head_size
+    h, p, n, taps = cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state, cfg.ssm_conv
+    inner, f64 = cfg.ssm_inner, np.float64
+    pos = np.arange(t)
+    causal = pos[None, :] <= pos[:, None]
+    assert cfg.ssm_groups == 1
+
+    def mup(name):
+        if wrong == "no_residual" and name in ("attn_out", "ssm_out", "down"):
+            return 1.0
+        return 1.0 if wrong == "no_" + name else getattr(cfg, "mup_" + name)
+
+    def norm(x, w):
+        return rmsnorm_eps(x, w, cfg.norm_eps)
+
+    def attention(u, i):
+        def seg(key):
+            return np.asarray(params[key][i], np.float32)
+
+        q = (u @ seg("wq")).reshape(t, hq, dh)
+        k = (u @ seg("wk") * mup("key")).reshape(t, hkv, dh)
+        if wrong == "rope":
+            q = rope_rotate(q, pos, cfg.rope_theta, False)
+            k = rope_rotate(k, pos, cfg.rope_theta, False)
+        v = (u @ seg("wv")).reshape(t, hkv, dh)
+        att = np.zeros((t, hq, dh), np.float32)
+        for a in range(hq):
+            j = a // (hq // hkv)
+            s = np.where(causal, q[:, a] @ k[:, j].T / np.sqrt(dh), -np.inf)
+            att[:, a] = softmax(s) @ v[:, j]
+        return att.reshape(t, hq * dh) @ seg("wo")
+
+    def mixer(u, i):
+        def seg(key):
+            return np.asarray(params[key][i], np.float32)
+
+        z, xbc = np.split(u @ seg("ssm_in"), [inner], axis=-1)
+        dt = np.logaddexp(0.0, u.astype(f64) @ seg("ssm_dt").astype(f64)
+                          + seg("ssm_dt_bias"))                        # (T, H)
+        if wrong != "no_conv":
+            w = seg("ssm_conv_w")                                      # (C, K)
+            ext = np.concatenate([np.zeros((taps - 1, xbc.shape[1]),
+                                           np.float32), xbc])
+            xbc = sum(ext[j:j + t] * w[:, j] for j in range(taps)) \
+                + seg("ssm_conv_b")
+        xs, bm, cm = np.split(silu(xbc).astype(f64), [inner, inner + n], axis=-1)
+        xs = xs.reshape(t, h, p)
+        a_h = -np.exp(seg("ssm_a_log").astype(f64)) * (
+            0.0 if wrong == "no_decay" else 1.0)
+        cum = np.cumsum(dt * a_h, axis=0)                              # (T, H)
+        d_h = seg("ssm_d") * (0.0 if wrong == "no_skip" else 1.0)
+        cb = cm @ bm.T                                                 # one group
+        y = np.zeros((t, h, p), f64)
+        for a in range(h):
+            w = np.where(causal, cb * np.exp(np.where(
+                causal, cum[:, None, a] - cum[None, :, a], 0.0)), 0.0)
+            y[:, a] = (w * dt[None, :, a]) @ xs[:, a] + d_h[a] * xs[:, a]
+        y = y.reshape(t, inner)
+
+        def normed(y):  # ONE RMSNorm over all of the mixer's channels
+            return y / np.sqrt(np.mean(y * y, -1, keepdims=True) + cfg.norm_eps)
+
+        gate = silu(z.astype(f64))
+        y = normed(y) * seg("ssm_norm") * gate if wrong == "norm_before_gate" \
+            else normed(y * gate) * seg("ssm_norm")
+        return y.astype(np.float32) @ seg("ssm_out")
+
+    def experts(f, li):
+        def seg(key):
+            return np.asarray(params[key][li], np.float32)
+
+        logits = f @ seg("router")
+        out = np.zeros_like(f)
+        for r in range(t):
+            top = np.argsort(-logits[r], kind="stable")[:cfg.n_active_experts]
+            w = softmax(logits[r])[top] if wrong == "softmax_all" \
+                else softmax(logits[r][top])
+            for e, we in zip(top, w):
+                out[r] += we * ((silu(f[r] @ seg("gate")[e]) * (f[r] @ seg("up")[e]))
+                                @ seg("down")[e])
+        if wrong != "no_shared":
+            out = out + (silu(f @ seg("shared_w1")) * (f @ seg("shared_w3"))
+                         ) @ seg("shared_w2")
+        return out
+
+    x = np.asarray(params["embedding"], np.float32)[tokens] * mup("embedding")
+    n_att = n_mix = 0
+    for li in range(cfg.n_layers):
+        u = norm(x, np.asarray(params["rms_att"][li], np.float32))
+        if li % cfg.window_period == cfg.window_full_at:
+            a, n_att = mup("attn_out") * attention(u, n_att), n_att + 1
+        else:
+            a, n_mix = mup("ssm_out") * mixer(u, n_mix), n_mix + 1
+        x = x + a
+        f = norm(x, np.asarray(params["rms_ffn"][li], np.float32))
+        x = x + mup("down") * experts(f, li)
+    x = norm(x, np.asarray(params["rms_final"], np.float32))
+    return (x @ np.asarray(params["wcls"], np.float32) * mup("head")).astype(np.float32)

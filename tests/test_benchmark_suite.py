@@ -26,7 +26,7 @@ _BENCH_TESTS = os.path.join(os.path.dirname(os.path.dirname(
 MODULES = ("test_exaone_moe", "test_host_readers", "test_loadgen", "test_manifest",
            "test_memory_readers", "test_models",
            "test_models_brumby", "test_models_deepseek_v2", "test_models_falcon_h1",
-           "test_models_lfm2_moe",
+           "test_models_granitemoehybrid", "test_models_lfm2_moe",
            "test_models_olmoe", "test_models_ouro",
            "test_models_program", "test_models_smallthinker", "test_prefill_readers",
            "test_rows_reader",

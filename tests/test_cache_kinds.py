@@ -38,6 +38,7 @@ CONFIGS = {
     "brumby": config_mod.tiny_brumby,
     "ouro": config_mod.tiny_ouro,
     "falcon_h1": config_mod.tiny_falcon_h1,
+    "granitemoehybrid": config_mod.tiny_granite_hybrid,
 }
 
 
@@ -246,6 +247,17 @@ MATRIX = {name: {**_RUNS, **said} for name, said in {
         **_DENSE,
         **_no_int8_form("a state-space mixer's state has no int8 form"),
         **_a_slot_owns("falcon_h1", "state-space mixers' state", contiguous=True),
+        "pool-of-no-slots": "ValueError: a pool beside a state-space mixer needs "
+                            "the number of slots: each owns a state and its rings",
+        "traced-step-64": _RECENT_RING},
+    # a layer of a slot owns a state OR pages (Falcon-H1: both): the same row of
+    # the table, so the same sentences; its experts do not lift the ep refusal
+    "granitemoehybrid": {
+        **_on_one_device("a state-space (granitemoehybrid) model", "its state a "
+                         "head is replicated with its slot"),
+        **_no_int8_form("a state-space mixer's state has no int8 form"),
+        **_a_slot_owns("granitemoehybrid", "state-space mixers' state",
+                       contiguous=True),
         "pool-of-no-slots": "ValueError: a pool beside a state-space mixer needs "
                             "the number of slots: each owns a state and its rings",
         "traced-step-64": _RECENT_RING},

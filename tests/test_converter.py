@@ -395,3 +395,120 @@ def test_convert_hf_refuses_smallthinker_variants_by_name(tmp_path, key, value, 
     (tmp_path / "config.json").write_text(json.dumps(config))
     with pytest.raises(SystemExit, match=says):
         convert_hf.load_spec(str(tmp_path), quants.F32)
+
+
+# ---- granitemoehybrid (Granite-4.0-H) ------------------------------------------
+
+GRANITE_HF = dict(
+    model_type="granitemoehybrid", hidden_size=64, intermediate_size=32,
+    shared_intermediate_size=64, num_hidden_layers=10, num_attention_heads=8,
+    num_key_value_heads=2, num_local_experts=12, num_experts_per_tok=3,
+    vocab_size=128, max_position_embeddings=512, hidden_act="silu",
+    normalization_function="rmsnorm", rms_norm_eps=1e-5, rope_theta=10000,
+    rope_scaling=None, position_embedding_type="nope", attention_bias=False,
+    mamba_proj_bias=False, mamba_conv_bias=True, mamba_n_heads=8, mamba_d_head=8,
+    mamba_d_state=12, mamba_n_groups=1, mamba_d_conv=4, mamba_expand=1,
+    mamba_chunk_size=256, tie_word_embeddings=True,
+    layer_types=(["mamba"] * 2 + ["attention"] + ["mamba"] * 2) * 2,
+    embedding_multiplier=3.0, logits_scaling=4.0, residual_multiplier=0.5,
+    attention_multiplier=0.3)
+
+
+def test_convert_granitemoehybrid_names_stacked_experts_and_logits(tmp_path):
+    """A toy checkpoint under the names the converter ASSUMES (nobody fetched
+    the published files): the mixer's as Falcon-H1's, the experts as ONE stacked
+    ``input_linear`` (E, 2 F, D) of ``gate | up`` rows and ``output_linear`` (E,
+    D, F), the shared MLP's ``input_linear`` likewise, a tied head (no
+    ``lm_head``), through the converter, the loader and ``forward`` against the
+    plain numpy reference on the same weights.  The header carries the period,
+    the four scalars (``attention_multiplier`` as the key's multiplier,
+    ``residual_multiplier`` on each of the three branch outputs) and the shared
+    MLP's width in experts."""
+    import jax
+    import jax.numpy as jnp
+    from safetensors.numpy import save_file
+
+    import convert_hf
+    import reference_impl as ref
+    from dllama_tpu.models.config import tiny_granite_hybrid
+    from dllama_tpu.models.params import init_params
+    from dllama_tpu.models.transformer import forward, init_kv_cache
+
+    cfg = tiny_granite_hybrid()
+    p = {k: np.asarray(v, np.float32)
+         for k, v in init_params(cfg, seed=9, scale=0.08).items()}
+    p["wcls"] = p["embedding"].T.copy()                      # the head is tied
+    hf = {"model.embed_tokens.weight": p["embedding"],
+          "model.norm.weight": p["rms_final"]}
+    n_att = n_mix = 0
+    for i in range(cfg.n_layers):
+        base = f"model.layers.{i}."
+        if i % cfg.window_period == cfg.window_full_at:
+            for ours, theirs in (("wq", "q_proj"), ("wk", "k_proj"),
+                                 ("wv", "v_proj"), ("wo", "o_proj")):
+                hf[f"{base}self_attn.{theirs}.weight"] = p[ours][n_att].T
+            n_att += 1
+        else:
+            hf[base + "mamba.in_proj.weight"] = np.concatenate(
+                [p["ssm_in"][n_mix].T, p["ssm_dt"][n_mix].T])      # z | xBC | dt
+            hf[base + "mamba.conv1d.weight"] = p["ssm_conv_w"][n_mix][:, None, :]
+            hf[base + "mamba.out_proj.weight"] = p["ssm_out"][n_mix].T
+            for ours, theirs in (("ssm_conv_b", "conv1d.bias"), ("ssm_a_log", "A_log"),
+                                 ("ssm_dt_bias", "dt_bias"), ("ssm_d", "D"),
+                                 ("ssm_norm", "norm.weight")):
+                hf[f"{base}mamba.{theirs}"] = p[ours][n_mix]
+            n_mix += 1
+        hf[base + "input_layernorm.weight"] = p["rms_att"][i]
+        hf[base + "post_attention_layernorm.weight"] = p["rms_ffn"][i]
+        moe = base + "block_sparse_moe."
+        hf[moe + "router.layer.weight"] = p["router"][i].T
+        hf[moe + "input_linear.weight"] = np.concatenate(
+            [p["gate"][i].transpose(0, 2, 1), p["up"][i].transpose(0, 2, 1)], axis=1)
+        hf[moe + "output_linear.weight"] = p["down"][i].transpose(0, 2, 1)
+        hf[base + "shared_mlp.input_linear.weight"] = np.concatenate(
+            [p["shared_w1"][i].T, p["shared_w3"][i].T])
+        hf[base + "shared_mlp.output_linear.weight"] = p["shared_w2"][i].T
+    (tmp_path / "config.json").write_text(json.dumps(GRANITE_HF))
+    save_file({k: np.ascontiguousarray(v, np.float32) for k, v in hf.items()},
+              str(tmp_path / "model.safetensors"))
+    out = str(tmp_path / "granite.m")
+    convert_hf.convert(str(tmp_path), quants.F32, out)
+    mf = mfile.MFile(out)
+    assert mf.spec.arch == mfile.ARCH_GRANITE_HYBRID
+    assert (mf.spec.window_period, mf.spec.window_full_at, mf.spec.hidden_dim,
+            mf.spec.moe_hidden_dim, mf.spec.n_shared_experts) == (5, 2, 64, 32, 2)
+    assert (mf.spec.mup_embedding, mf.spec.mup_head, mf.spec.mup_attn_out,
+            mf.spec.mup_ssm_out, mf.spec.mup_down) == (3.0, 0.25, 0.5, 0.5, 0.5)
+    assert abs(mf.spec.mup_key - 0.3 * 8 ** 0.5) < 1e-6
+    got_cfg, params = load_params(mf)
+    for k, v in params.items():
+        np.testing.assert_array_equal(np.asarray(v, np.float32), p[k], err_msg=k)
+    got_cfg = got_cfg.with_(dtype=jnp.float32)
+    toks = np.random.RandomState(4).randint(3, 128, (32,)).astype(np.int32)
+    want = ref.np_forward_granite_hybrid(p, cfg, toks)
+    with jax.default_matmul_precision("highest"):
+        logits, _ = forward(params, got_cfg, jnp.asarray(toks)[None],
+                            init_kv_cache(got_cfg, 1, 64), jnp.int32(0))
+    # the header carries the multipliers as float32
+    np.testing.assert_allclose(np.asarray(logits)[0], want, atol=2e-5, rtol=1e-4)
+
+
+@pytest.mark.parametrize("key,value,says", [
+    ("position_embedding_type", "rope", "position_embedding_type is 'rope'"),
+    ("mamba_n_groups", 2, "a norm a group"),
+    ("attention_bias", True, "attention_bias is True"),
+    ("mamba_proj_bias", True, "mamba_proj_bias is True"),
+    ("shared_intermediate_size", 48, "not a whole number of experts"),
+    ("layer_types", ["mamba"] * 9 + ["attention"], "x"),
+])
+def test_convert_hf_refuses_granite_variants_by_name(tmp_path, key, value, says):
+    import convert_hf
+
+    config = dict(GRANITE_HF, **{key: value})
+    (tmp_path / "config.json").write_text(json.dumps(config))
+    if says == "x":  # ten layers, the attention layer last: one whole period
+        spec = convert_hf.load_spec(str(tmp_path), quants.F32)
+        assert (spec.window_period, spec.window_full_at) == (10, 9)
+        return
+    with pytest.raises(SystemExit, match=says):
+        convert_hf.load_spec(str(tmp_path), quants.F32)

@@ -410,3 +410,44 @@ def test_a_state_space_mixer_adds_depth_free_bytes_beside_the_kv(shape, clean_ob
     assert cm.state_read_bytes(1) == sum(
         int(a.nbytes) for n, a in eng.cache.planes().items()
         if n in ("rs", "rk", "rv", "rg"))
+
+
+def test_a_mixer_as_a_layer_kind_counts_each_kind_by_its_own_depth(clean_obs):
+    """Granite-4.0-H: a layer has a mixer OR attention.  The mixer layers bring
+    their projections and a state read a pass, the attention layers their
+    projections and the keys and values, every layer its token's experts and
+    the shared MLP: 8,192 B a cached position over the cell's 2 attention
+    layers and 75.5 MB of state matrices a slot over its 18 mixer layers."""
+    import jax
+
+    from dllama_tpu.models.config import tiny_granite_hybrid
+    from dllama_tpu.models.params import init_params
+    from dllama_tpu.parallel.mesh import make_mesh
+    from dllama_tpu.runtime.engine import Engine
+
+    ssm = dict(heads=128, head_dim=64, state=128, groups=1, ring=128)
+    cm = tiny_cost_model(
+        dim=4096, hidden_dim=1536, n_layers=20, n_heads=32, n_kv_heads=8,
+        head_dim=128, vocab_size=100352, kv_codec="kv_bfloat16", kv_el_bytes=2,
+        n_experts=72, n_active_experts=10, moe_hidden_dim=768,
+        n_shared_experts=2, ssm=ssm, n_ssm_layers=18)
+    att = 2 * 4096 * 4096 + 2 * 4096 * 1024
+    mixer = 4096 * (2 * 8192 + 256 + 128) + 8192 * 4096
+    assert (cm.n_kv_layers, cm.n_ssm_layers) == (2, 18)
+    assert cm.params_per_token == 2 * att + 18 * mixer + 20 * 3 * 4096 * 768 * 12
+    assert cm.kv_write_bytes(1) == 8_192
+    state = 128 * 128 * 64 * 4
+    ring = 128 * ((8192 + 128) * 2 + 4 * 128)
+    assert cm.state_read_bytes(1) == 18 * (state + ring) and 18 * state == 75_497_472
+    assert cm.row_cost("decode", 900, 1)["kv_bytes"] \
+        - cm.row_cost("decode", 9, 1)["kv_bytes"] == 2 * (900 - 9) * 4_096
+    cfg = tiny_granite_hybrid()
+    eng = Engine(cfg, init_params(cfg, seed=4),
+                 mesh=make_mesh(tp=1, devices=jax.devices()[:1]), batch=1)
+    cm = obs_cost.model_from_engine(eng)
+    assert (cm.n_kv_layers, cm.n_ssm_layers) == (2, 8)
+    assert cm.kv_write_bytes(1) == eng.kv_bytes_per_token \
+        == obs_metrics.KV_BYTES_PER_TOKEN.json_value() == 2 * 2 * cfg.kv_dim * 4
+    assert cm.state_read_bytes(1) == sum(
+        int(a.nbytes) for n, a in eng.cache.planes().items()
+        if n in ("rs", "rk", "rv", "rg"))

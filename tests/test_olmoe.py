@@ -60,7 +60,8 @@ def test_arch_id_and_the_flags_derived_from_it():
                      "lfm2_moe": (False, True, False),
                      "brumby": (False, True, False),
                      "ouro": (False, True, False),
-                     "falcon_h1": (False, True, False)}
+                     "falcon_h1": (False, True, False),
+                     "granitemoehybrid": (False, True, False)}
     shapes = param_shapes(tiny_config(arch=mfile.ARCH_OLMOE, n_experts=4,
                                       n_active_experts=2))
     assert (shapes["q_norm"], shapes["k_norm"]) == ((2, 64), (2, 32))

@@ -1529,3 +1529,130 @@ def test_falcon_h1_cell_programs_compile_with_a_state_beside_the_pool(
         "jit(<lambda>)/while/body/closed_call/kv_write/fold/while/body/"
         "dynamic_update_slice"}
     assert all("/kv_write/" in p for ops_ in made.values() for _, p in ops_), made
+
+
+# ---- Granite-4.0-H-Small: a state OR pages a layer, 72 experts of 768 -----------
+
+@pytest.mark.parametrize("rows", [16, 256])
+@pytest.mark.parametrize("name,n,d,per_expert", [
+    ("gate", 4096, 768, False), ("down", 768, 4096, True)], ids=["gate", "down"])
+def test_q40_experts_matmul_compiles_at_granites_72_experts_of_768(
+        one_chip, name, n, d, per_expert, rows):
+    """The narrowest expert in the benchmark (K-EXAONE 2048, OLMoE 1024), 72 = 9
+    x 8 of them a layer: the tile rule finds whole tiles at 768 and the launch
+    over every expert compiles at a pure-decode step's rows and at a chunk's."""
+    L, E = 20, 72
+    assert q40.padded_n(n) == n
+    tn, td = q40._tiles(n, d)
+    assert n % tn == 0 and d % td == 0 and td % 128 == 0
+    s = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)  # noqa: E731
+    x = s(((E,) if per_expert else ()) + (rows, n), jnp.bfloat16)
+    text = jax.jit(
+        lambda x, qp, sc, layer: q40._pallas_matmul_experts(
+            x, qp, sc, layer, experts=E)).lower(
+        x, s((L * E, n // 2, d), jnp.uint8), s((L * E, n // 32, d), jnp.uint16),
+        s((), jnp.int32)).compile().as_text()
+    assert "q40_mm_experts" in text and f"f32[{E},{rows},{d}]" in text
+
+
+@pytest.mark.parametrize("t", [1, 16], ids=["pure-decode", "mixed"])
+def test_granite_cell_programs_compile_with_a_state_or_pages_a_layer(
+        one_chip, monkeypatch, t):
+    """The slot programs of ``granite-4.0-h-small.decode-heavy`` for the
+    described chip at the published widths, the cell's 20 layers, 16 slots and
+    2056 pages: 18 layers of a slot own a state matrix (128 heads of 64, ONE
+    group, 128 rows) with its rings, 2 own pages, none both.  The mixer's read,
+    fold and write compile at that geometry through the function Falcon-H1's
+    blocks call; its two projections are launches of their own under the part
+    ``ssm``, the experts at 72 of width 768 take ``all-experts`` at 16 rows
+    and ``grouped`` past them with the shared MLP beside them under
+    ``moe/shared``; the fused page walk takes the two attention layers; NO
+    plane of the cache is copied whole and the state is made by the fold's
+    in-place update alone; arguments and temporaries stay under the 13.5 GB at
+    which two checks ran out of memory."""
+    from dllama_tpu.io import mfile
+    from dllama_tpu.models import transformer as tf
+    from dllama_tpu.models.config import ModelConfig
+    from dllama_tpu.models.params import SSM_F32, param_shapes
+    from dllama_tpu.runtime.decode_loop import slot_chunk
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(jax, "device_count", lambda: 1)
+    layers, b = 20, 16
+    cfg = ModelConfig(
+        arch=mfile.ARCH_GRANITE_HYBRID, dim=4096, hidden_dim=1536,
+        n_layers=layers, n_heads=32, n_kv_heads=8, n_experts=72,
+        n_active_experts=10, vocab_size=100352, seq_len=2048,
+        hidden_act=mfile.ACT_SILU, rope_theta=10000.0, norm_eps=1e-5,
+        head_dim=128, window_period=10, window_full_at=5, moe_hidden_dim=768,
+        n_shared_experts=2, ssm_heads=128, ssm_head_dim=64, ssm_state=128,
+        ssm_groups=1, ssm_conv=4, mup_embedding=12.0, mup_head=0.0625,
+        mup_key=0.0884, mup_attn_out=0.22, mup_ssm_out=0.22, mup_down=0.22,
+        dtype=jnp.bfloat16)
+    assert (cfg.n_ssm_layers, cfg.n_full_layers) == (18, 2)
+    s = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)  # noqa: E731
+
+    def packed(*shapes):
+        *lead, n, _ = shapes[0]
+        d, np_ = sum(sh[-1] for sh in shapes), q40.padded_n(n)
+        return q40.QTensor(s((*lead, np_ // 2, d), jnp.uint8),
+                           s((*lead, np_ // 32, d), jnp.uint16), (n, d))
+
+    sh = param_shapes(cfg)
+    params = {k: s(sh[k], jnp.float32) for k in sh
+              if k.startswith("rms") or k in SSM_F32}
+    params["embedding"] = s(sh["embedding"], jnp.bfloat16)
+    params["router"] = s(sh["router"], jnp.bfloat16)
+    params.update(wqkv=packed(sh["wq"], sh["wk"], sh["wv"]),
+                  shared_w13=packed(sh["shared_w1"], sh["shared_w3"]),
+                  **{k: packed(sh[k]) for k in (
+                      "wo", "ssm_in", "ssm_out", "up", "gate", "down",
+                      "shared_w2", "wcls")})
+    planes = jax.eval_shape(lambda: tf.init_kv_pool(
+        cfg, 2056, 16, slots=b, max_pages=128)).planes()
+    assert planes["rs"].shape[0] == 18 and planes["k"].shape[0] == 2
+    cache = tf.KVCache(**{n: s(a.shape, a.dtype) for n, a in planes.items()})
+    vec = lambda dt: s((b,), dt)  # noqa: E731
+    compiled = jax.jit(
+        lambda p, c, tok, pr, nv, k, tm, tp, tk, pt: slot_chunk(
+            p, cfg, c, tok, pr, nv, k, tm, tp, tk, steps=1, greedy=True,
+            page_table=pt), donate_argnums=(1,)).lower(
+        params, cache, s((b, t), jnp.int32), vec(jnp.int32), vec(jnp.int32),
+        s((2,), jnp.uint32), vec(jnp.float32), vec(jnp.float32),
+        vec(jnp.int32), s((b, 128), jnp.int32)).compile()
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 13.5e9
+    assert mem.temp_size_in_bytes < (0.05e9 if t == 1 else 0.6e9)
+    text = compiled.as_text()
+    kernels = re.findall(r'op_name="[^"]*closed_call/([^"]*)/pallas_call"', text)
+    launches = {k.replace("cond/branch_0_fun/", "").replace("cond/branch_1_fun/", "")
+                for k in kernels}
+    assert "attn/full/paged_attn_fused" in launches
+    assert {k for k in launches if "/ssm/" in k} == {
+        f"{sc}/ssm/jit(_pallas_matmul_stacked)/q40_mm_stacked" if t == 1 else
+        f"{sc}/{sc}/ssm/jit(_pallas_matmul_stacked)/q40_mm_stacked"
+        for sc in ("qkv", "wo")}
+    assert any("moe/shared/" in k for k in launches)
+    assert any(("q40_mm_experts" if t == 1 else "q40_mm_grouped") in k
+               for k in launches if "moe/" in k)
+    ops = re.findall(r"^\s*(?:ROOT )?%?([\w.\-]+) = (\S+) ([\w\-]+)\((.*)$",
+                     text, re.M)
+    type_of = {jnp.dtype(jnp.bfloat16): "bf16", jnp.dtype(jnp.float32): "f32",
+               jnp.dtype(jnp.int32): "s32"}
+    whole = {f"{type_of[jnp.dtype(a.dtype)]}[{','.join(map(str, a.shape))}]": n
+             for n, a in planes.items()
+             if a.size * jnp.dtype(a.dtype).itemsize > 128 << 20}
+    assert set(whole.values()) == {"v", "rs", "rv", "cz"}   # k has v's shape
+    made = {}
+    for name, result, op, rest in ops:
+        plane = whole.get(result.split("{")[0])
+        if plane and op not in ("parameter", "get-tuple-element", "bitcast",
+                                "while", "call", "conditional"):
+            path = re.search(r'op_name="([^"]+)"', rest)
+            made.setdefault(plane, []).append((op, path.group(1) if path else ""))
+    assert all(op in ("dynamic-update-slice", "scatter", "fusion")
+               for ops_ in made.values() for op, _ in ops_), made
+    assert all("/kv_write/" in p for ops_ in made.values() for _, p in ops_), made
+    # the state's plane: the fold's update in place, alone or fused with the
+    # block's product (a copy of the 1.2 GB plane would show in the temporaries)
+    assert all("/kv_write/fold/while/body/" in p for _, p in made["rs"]), made["rs"]

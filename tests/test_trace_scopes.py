@@ -334,6 +334,31 @@ def test_an_idle_scheduler_goes_quiet():
         sched.close()
 
 
+def test_a_periods_mixer_layers_carry_falcons_parts_and_the_shared_mlp_its_own():
+    """Granite-4.0-H: the mixer is a layer KIND of a period and runs through the
+    function Falcon-H1's blocks call, so its ops carry the same parts (``ssm`` of
+    ``qkv`` / ``wo``, ``conv`` / ``state`` / ``recent`` of ``attn``, ``conv`` /
+    ``recent`` / ``fold`` of ``kv_write``) and the readers that sum them
+    (``serve_ssm_ms_per_step``) find them; the period's attention layer keeps the
+    bare ``qkv`` / ``wo`` and the part ``full`` of ``attn``; the shared MLP
+    behind every layer's experts is ``moe/shared`` beside ``moe/experts``."""
+    from dllama_tpu.models.config import tiny_granite_hybrid
+    cfg = tiny_granite_hybrid()
+    p = init_params(cfg, seed=4)
+    ops = compiled_ops(lambda p, c, tok: tf.forward(p, cfg, tok, c, jnp.int32(3)),
+                       p, tf.init_kv_cache(cfg, 1, 64), jnp.zeros((1, 4), jnp.int32))
+    names = [name for _, name in ops]
+    for part in ("/qkv/ssm/", "/wo/ssm/", "/attn/conv/", "/attn/state/",
+                 "/attn/recent/", "/kv_write/conv/", "/kv_write/recent/",
+                 "/kv_write/fold/", "/attn/full/", "/moe/shared/", "/moe/experts/",
+                 "/moe/router/"):
+        assert any(part in n for n in names), part
+    for scope in ("qkv", "wo"):  # the attention layer's own, under the bare scope
+        assert [n for n in names if scope_of(n) == scope
+                and f"/{scope}/ssm/" not in n], scope
+    assert not any("/attn/window/" in n or "/qkv/retention/" in n for n in names)
+
+
 @pytest.mark.parametrize("toy", ["falcon_h1", "brumby", "llama"])
 def test_a_mixer_beside_attention_keeps_attentions_scopes_bare(toy):
     """A state-space mixer stands BESIDE attention in one block (Falcon-H1): its
