@@ -6,7 +6,9 @@ full width (and by default the full depth) of Llama-2-7B with seeded random
 Q40 weights:
 
   kernels  each main-path Pallas kernel against its plain reference on the
-           chip, at the real shapes (Q40 matmul, fused paged attention)
+           chip, at the real shapes (Q40 matmul, fused paged attention, a
+           pure-decode step's ring writes at Falcon-H1's and Granite's widths:
+           one launch a plane against the windows, bit for bit)
   cli      ``python -m dllama_tpu inference`` on a synthesized .m/.t pair
   server   ``python -m dllama_tpu.server.api`` with a paged slot scheduler:
            concurrent completions, a streamed chat, /metrics, SIGTERM drain
@@ -689,6 +691,87 @@ def child_kernels(rehearse: bool) -> None:
                                "block": att._kv_chunk(s_len)},
                   "rel_err": rel_err(got, ref), "tol": ATTN_TOL,
                   "seconds": round(time.perf_counter() - t0, 2)})
+
+    _ring_writes(rehearse)
+
+
+def _ring_writes(rehearse: bool) -> None:
+    """A pure-decode step's ring writes (``ops/window.py``, PR 66) at
+    Falcon-H1's and Granite's widths, depth and slots: the same tokens through
+    the mixer's ``ssm.write`` and ``conv.state_write`` as the rule puts them
+    (one launch a plane; one slab for Falcon-H1's ``dt`` ring) and as windows a
+    row, four steps from slots that cross an aligned window's edge (15 -> 16) and the ring's end
+    (127 -> 0, 63 -> 0 in the convolution's), some rows' ``dt`` masked: the
+    four planes bit for bit."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from dllama_tpu.obs import dispatch as obs_dispatch
+    from dllama_tpu.ops import conv, ssm, window
+
+    # (name, mixer layers, slots, heads, head size, groups, state rows, channels)
+    for name, layers, b, h, p, g, n, ch in (
+            ("falcon-h1-34b", 18, 32, 32, 128, 2, 256, 5120),
+            ("granite-4.0-h-small", 18, 16, 128, 64, 1, 128, 8448)):
+        if rehearse:
+            layers, b, h, n, ch = 2, 4, h // 8, n // 8, ch // 8
+        f = ssm.heads_a_row(h, p)
+        shapes = {"rk": ((layers, b, g, ssm.RING, n), jnp.bfloat16),
+                  "rv": ((layers, b, h // f, ssm.RING, f * p), jnp.bfloat16),
+                  "rg": ((layers, b, 1, ssm.RING, h), jnp.float32),
+                  "cz": ((layers, b, 1, conv.RING, ch), jnp.bfloat16)}
+        edge = np.array([14, 126, 62, 0, 15, 127, 63, 1])
+        pos0 = jnp.asarray(edge[np.arange(b) % 8] + 128 * (np.arange(b) // 8),
+                           jnp.int32)
+
+        def steps(planes, seed):
+            def one(planes, i):
+                k = jax.random.fold_in(jax.random.PRNGKey(seed), i)
+                kb, kx, kd, kz = jax.random.split(k, 4)
+                layer = (i * (layers - 1)) % layers
+                dt = jax.random.uniform(kd, (b, 1, h)) * (
+                    jnp.arange(b) % 3 != 2)[:, None, None]
+                rk, rv, rg = ssm.write(
+                    planes["rk"], planes["rv"], planes["rg"],
+                    jax.random.normal(kb, (b, g, 1, n), jnp.bfloat16),
+                    jax.random.normal(kx, (b, h, 1, p), jnp.bfloat16), dt,
+                    layer, pos0 + i)
+                cz = conv.state_write(
+                    planes["cz"], jax.random.normal(kz, (b, 1, ch), jnp.bfloat16),
+                    layer, pos0 + i, 4)
+                return dict(rk=rk, rv=rv, rg=rg, cz=cz), None
+            return jax.lax.scan(one, planes, jnp.arange(4))[0]
+
+        got = {}
+        for form, min_rows in (("rule", window.PUT_MIN_ROWS), ("windows", 1 << 30)):
+            keep, window.PUT_MIN_ROWS = window.PUT_MIN_ROWS, min_rows
+            obs_dispatch.reset()
+            t0 = time.perf_counter()
+            try:
+                planes = {k: jax.random.normal(
+                    jax.random.PRNGKey(7), sh, dt) for k, (sh, dt) in shapes.items()}
+                got[form] = jax.block_until_ready(jax.jit(
+                    lambda pl_: steps(pl_, 3), donate_argnums=0)(planes))
+            finally:
+                window.PUT_MIN_ROWS = keep
+            paths = {k: v for k, v in obs_dispatch.dispatches().items()
+                     if k.startswith("ring/")}
+            if form == "rule" and not rehearse:   # the dt ring of 32 heads: the slab
+                want = {"ring/put-kernel": 3, "ring/put-slab": 1} if h % 128 \
+                    else {"ring/put-kernel": 4}
+                require(paths == want,
+                        f"ring writes of {name}: the rule chose {paths}")
+            if form == "windows":
+                require(set(paths) == {"ring/windows"}, f"forced windows: {paths}")
+        for k in shapes:
+            same = bool(jnp.array_equal(got["rule"][k], got["windows"][k]))
+            _say({"kernel": "ring_put", "plane": f"{name}.{k}",
+                  "geometry": {"shape": list(shapes[k][0]), "rows": b,
+                               "slots": "15->16, 127->0 (63->0 in cz)"},
+                  "rel_err": 0.0 if same else 1.0, "tol": 0.0,
+                  "seconds": round(time.perf_counter() - t0, 2)})
+        del got
 
 
 def child_moe(argv: list[str], rehearse: bool) -> None:

@@ -40,7 +40,7 @@ before the taps) is in ``cz (L, rows, 1, conv.RING, C)``, written and read with
 A call of ``t <= retention.MAX_ROWS`` rows (1) folds, (2) writes its rows into
 the rings, (3) reads: ``C^T S`` decayed from ``w`` to each query plus the
 attention form over the ring's rows in ``[w, query]``.  The state is read once
-a call and written once a fold.  **Three things the compile for the chip
+a call and written once a fold.  **Four things the compile for the chip
 taught** (``tests/test_tpu_compile.py`` holds them at the published widths): the
 read takes a layer's slice of ``rs`` and of ``rv`` AS IT LIES, heads flat and
 ``x`` in slot order (the small ``C`` is repeated to the heads and the small
@@ -50,7 +50,12 @@ layer, more than twice the product's own time on the chip); the caller orders
 the fold before the ring writes with a barrier and writes the convolution's
 ring before it reads it, because XLA otherwise kept the old plane alive beside
 the new one (the whole ``rv`` or ``cz`` plane copied twice a layer in the mixed
-step).
+step); and a launch that updates a plane in place (``window.ring_put``, a
+pure-decode step's write of ``rk``, ``rv`` and ``cz``) asks for nearly all of
+VMEM as its scope, because XLA moved a plane that fits there (``rk``, 75 MB)
+into VMEM and back around the launch, a layer at a time, whatever memory space
+the launch named (pinning the launch's result to HBM aborted the compiler),
+and cannot give the launch both that scope and a plane there.
 
 Ledger: ``{codec="ssm", path="state-read"|"block"|"fold"}`` one a compiled call
 site.  Device time: part ``ssm`` of scopes ``qkv`` (``W_in``, the ``dt``

@@ -94,6 +94,13 @@ def test_rehearse_kernels_phase(rehearsal_env, capfd):
                                 ("lfm2-24b-a2b", 1, 0.0, 0.0),
                                 ("lfm2-24b-a2b", 16, 0.0, 0.0)]
     errs = [r for r in errs if r not in alone]
+    # + a pure-decode step's ring writes, the rule's form against the windows,
+    # the four planes of Falcon-H1's mixer and of Granite's, bit for bit (PR 66)
+    rings = [r for r in errs if r["kernel"] == "ring_put"]
+    assert [(r["plane"], r["rel_err"], r["tol"]) for r in rings] == [
+        (f"{m}.{k}", 0.0, 0.0) for m in ("falcon-h1-34b", "granite-4.0-h-small")
+        for k in ("rk", "rv", "rg", "cz")]
+    errs = [r for r in errs if r not in rings]
     assert len(errs) == 54
     f32 = [r for r in errs if r["kernel"].endswith(".f32")]
     assert "q40.chosen_experts.f32" in [r["kernel"] for r in f32]

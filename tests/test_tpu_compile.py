@@ -1433,6 +1433,32 @@ def test_a_sixty_four_row_q40_launch_keeps_its_one_matmul_and_no_other_op(one_ch
         {k: v for k, v in DOT_BODY_OPS.items() if k not in plumbing}
 
 
+def _only_writes_make_a_plane(made, planes, text, launches, t):
+    """``made``: the ops of a compiled slot program whose result has the shape
+    of a plane too large for VMEM.  Nothing makes one but the cache's writes:
+    in-place windows, the scatter into pages, their fusions, and at one token
+    a row the launch that puts every row's token into a ring
+    (``window.ring_put``, PR 66: no window of the ``x`` ring or of the
+    convolution's is left), all under the scope ``kv_write``, the launches
+    under its parts ``recent`` and ``conv`` and in no program of more tokens a
+    row; and no plane of the mixer, small or large, is moved into VMEM whole
+    (XLA did that to a 75 MB ring around a launch that aliased it, in and out
+    a layer, until the launch asked for VMEM's scope itself)."""
+    assert all(op in ("dynamic-update-slice", "scatter", "fusion") or (
+        op == "custom-call" and t == 1 and path.endswith("/ring_put/pallas_call"))
+        for ops_ in made.values() for op, path in ops_), made
+    assert all("/kv_write/" in p for ops_ in made.values() for _, p in ops_), made
+    assert {k for k in launches if k.endswith("/ring_put")} == (
+        {"kv_write/recent/ring_put", "kv_write/conv/ring_put"} if t == 1 else set())
+    if t == 1:
+        assert {op for n in ("rv", "cz") for op, _ in made[n]} == {"custom-call"}
+    else:
+        assert "ring_put/pallas_call" not in text
+    for n in ("rs", "rk", "rv", "rg", "cz"):
+        shape = ",".join(map(str, planes[n].shape))
+        assert not re.search(rf"\[{shape}\]\{{[^}}]*S\(1\)\}}", text), n
+
+
 @pytest.mark.parametrize("t", [1, 16], ids=["pure-decode", "mixed"])
 def test_falcon_h1_cell_programs_compile_with_a_state_beside_the_pool(
         one_chip, monkeypatch, t):
@@ -1441,7 +1467,9 @@ def test_falcon_h1_cell_programs_compile_with_a_state_beside_the_pool(
     layer of one slot owns pages and a state matrix and rings.  The fused page
     walk takes five query heads a kv head; the mixer's two projections are
     launches of their own under the part ``ssm``; the state is made by the
-    fold's in-place update alone, and NO plane of the cache is copied whole (a
+    fold's in-place update alone, the pure-decode step puts its tokens into
+    a ring in one launch (PR 66; the ``dt`` ring of 32 heads in one fused
+    update), and NO plane of the cache is copied whole (a
     convolution ring read before it was written was, twice a layer: 27 GB a
     mixed step; so was the ``x`` ring while nothing ordered the fold before the
     ring writes: 43 GB) and no layer's slice of the state or of the ``x`` ring
@@ -1523,12 +1551,10 @@ def test_falcon_h1_cell_programs_compile_with_a_state_beside_the_pool(
                                 "while", "call", "conditional"):
             path = re.search(r'op_name="([^"]+)"', rest)
             made.setdefault(plane, []).append((op, path.group(1) if path else ""))
-    assert all(op in ("dynamic-update-slice", "scatter", "fusion")
-               for ops_ in made.values() for op, _ in ops_), made
+    _only_writes_make_a_plane(made, planes, text, launches, t)
     assert {p for _, p in made["rs"]} == {
         "jit(<lambda>)/while/body/closed_call/kv_write/fold/while/body/"
         "dynamic_update_slice"}
-    assert all("/kv_write/" in p for ops_ in made.values() for _, p in ops_), made
 
 
 # ---- Granite-4.0-H-Small: a state OR pages a layer, 72 experts of 768 -----------
@@ -1650,9 +1676,7 @@ def test_granite_cell_programs_compile_with_a_state_or_pages_a_layer(
                                 "while", "call", "conditional"):
             path = re.search(r'op_name="([^"]+)"', rest)
             made.setdefault(plane, []).append((op, path.group(1) if path else ""))
-    assert all(op in ("dynamic-update-slice", "scatter", "fusion")
-               for ops_ in made.values() for op, _ in ops_), made
-    assert all("/kv_write/" in p for ops_ in made.values() for _, p in ops_), made
+    _only_writes_make_a_plane(made, planes, text, launches, t)
     # the state's plane: the fold's update in place, alone or fused with the
     # block's product (a copy of the 1.2 GB plane would show in the temporaries)
     assert all("/kv_write/fold/while/body/" in p for _, p in made["rs"]), made["rs"]

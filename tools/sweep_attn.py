@@ -36,7 +36,23 @@ its chunks, which is where a walk that starts cold at every slot pays, apart
 from what a chunk costs (PERF.md §6, PR 64 has both tables; Ouro-2.6B's
 geometry, 16 and 16 heads of 128 over a 48-page table, is its default there).
 
+``--ring-write`` times a pure-decode step's ring writes (``ops/window.py``):
+one token a row into a stacked plane ``(L, B, H, R, Dh)`` at every layer, as
+``windows`` (``_write_row`` a row: every call before PR 66, and every call of
+more than one token a row), as ``put-kernel`` (``ring_put``: the rows' aligned
+windows in one launch) and as ``put-slab`` (``_put_slab``: the layer's slab in
+one fused update), at 1 to 32 rows, for the planes of the served cells that
+have rings (Falcon-H1's and Granite's ``rk`` / ``rv`` / ``rg`` / ``cz``,
+Brumby's three, LFM2's ``cz``), inside one jitted loop over the layers with the
+plane donated; ``rule`` is ``_put_form``'s own choice.  Each form's plane is
+compared with the windows' bit for bit (``equal``), and ``vmem`` says whether
+XLA moved the whole plane into VMEM for the loop (in a served step it did so
+around a launch that aliased a plane that fits, which is why the launch asks
+for VMEM's scope itself: PERF.md section 6, PR 66 has the table).
+
 Usage: python tools/sweep_attn.py [--repo DIR] [--blocks 256,512,1024]
+       python tools/sweep_attn.py --ring-write [--rows 1,2,4,8,16,32]
+                                  [--geo falcon-h1-34b.rv,...]
        python tools/sweep_attn.py --paged [--repo DIR] [--geo lfm2-24b-a2b]
                                   [--ts 1,16] [--lives 416,1024,2048]
                                   [--slots 1,8,16,32]
@@ -75,6 +91,101 @@ def _median_ms(run, args, reps):
         times.append(time.perf_counter() - t0)
     return (round(1e3 * sorted(times)[len(times) // 2], 3),
             round(1e3 * min(times), 3))
+
+
+# (name, layers, H, ring, Dh, dtype): the planes a served pure-decode step
+# writes one token a row into (32 rows in Falcon-H1's cell, 16 in Granite's and
+# LFM2's, 8 in Brumby's)
+RING_PLANES = [("falcon-h1-34b.rk", 18, 2, 128, 256, "bfloat16"),
+               ("falcon-h1-34b.rv", 18, 32, 128, 128, "bfloat16"),
+               ("falcon-h1-34b.rg", 18, 1, 128, 32, "float32"),
+               ("falcon-h1-34b.cz", 18, 1, 64, 5120, "bfloat16"),
+               ("granite-4.0-h-small.rk", 18, 1, 128, 128, "bfloat16"),
+               ("granite-4.0-h-small.rv", 18, 64, 128, 128, "bfloat16"),
+               ("granite-4.0-h-small.rg", 18, 1, 128, 128, "float32"),
+               ("granite-4.0-h-small.cz", 18, 1, 64, 8448, "bfloat16"),
+               ("brumby-14b-base.rk", 40, 8, 128, 128, "bfloat16"),
+               ("brumby-14b-base.rg", 40, 1, 128, 8, "float32"),
+               ("lfm2-24b-a2b.cz", 30, 1, 64, 2048, "bfloat16")]
+
+
+def sweep_ring_write(a) -> list[dict]:
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from dllama_tpu.obs import dispatch as obs_dispatch
+    from dllama_tpu.ops import window
+
+    def windows(ring, new, layer, pos):
+        for row in range(new.shape[0]):
+            ring = window._write_row(ring, new[row], layer, row, pos[row],
+                                     ring.shape[3])
+        return ring
+
+    forms = {"windows": windows, "rule": window.ring_write_plane}
+    if hasattr(window, "ring_put"):   # a tree without the launch: --repo
+        forms.update({
+            "put-kernel": functools.partial(window.ring_put, interpret=a.rehearse),
+            "put-slab": window._put_slab})
+    results = []
+    geos = a.geo.split(",") if a.geo else None
+    for name, layers, h, r, dh, dt in RING_PLANES:
+        if geos and name not in geos:
+            continue
+        if a.rehearse:
+            layers, dh = 2, min(dh, 256)
+        passes = max(1, 400 // layers)
+        for b in (int(x) for x in a.rows.split(",")):
+            shape = (layers, b, h, r, dh)
+            new = jax.random.normal(jax.random.PRNGKey(1), (b, h, 1, dh),
+                                    jnp.bfloat16)
+            # rows out of step, as a served step's: every slot class
+            pos = jnp.asarray(np.random.RandomState(b).randint(0, 4096, b),
+                              jnp.int32)
+            ref = None
+            for form, fn in forms.items():
+                if form == "put-kernel" and dh % 128:
+                    continue   # Mosaic copies no part of a 128-lane row
+
+                def run(ring, new_, pos_, fn=fn):
+                    # some hundreds of writes a call: a program's dispatch
+                    # (0.4 ms) is then a microsecond of a layer's figure
+                    return jax.lax.fori_loop(
+                        0, passes * layers, lambda i, ring_: fn(
+                            ring_, new_, i % layers, pos_ + i), ring)
+
+                obs_dispatch.reset()
+                ring = jnp.zeros(shape, jnp.dtype(dt))
+                comp = jax.jit(run, donate_argnums=0).lower(
+                    ring, new, pos).compile()
+                path = sorted(k for k in obs_dispatch.dispatches()
+                              if k.startswith("ring/"))
+                plane = "[" + ",".join(map(str, shape)) + "]"
+                vmem = any(plane in line and "S(1)" in line.split(plane)[1][:40]
+                           for line in comp.as_text().splitlines())
+                times = []
+                for _ in range(a.reps + 1):
+                    t0 = time.perf_counter()
+                    ring = jax.block_until_ready(comp(ring, new, pos))
+                    times.append(time.perf_counter() - t0)
+                times = sorted(times[1:])
+                rec = {"plane": name, "rows": b, "form": form,
+                       "us_a_layer": round(
+                           1e6 * times[len(times) // 2] / (passes * layers), 2),
+                       "min_us_a_layer": round(
+                           1e6 * times[0] / (passes * layers), 2),
+                       "layers": layers, "vmem": vmem, "repo": a.repo}
+                if form == "rule":
+                    rec["ledger"] = path
+                if form == "windows":
+                    ref = ring
+                else:
+                    rec["equal"] = bool(jnp.array_equal(ring, ref))
+                results.append(rec)
+                print(json.dumps(rec), flush=True)
+            del ref, ring
+    return results
 
 
 def sweep_paged(a, att) -> list[dict]:
@@ -200,6 +311,11 @@ def main() -> None:
     ap.add_argument("--slots", default="",
                     help="--paged: slot counts; times the fused walk and fits "
                          "a launch as launch + slot + chunk")
+    ap.add_argument("--ring-write", action="store_true",
+                    help="a pure-decode step's ring writes: windows vs the "
+                         "launch vs the slab")
+    ap.add_argument("--rows", default="1,2,4,8,16,32",
+                    help="--ring-write: rows a call")
     ap.add_argument("--blocks", default="256,512,1024")
     ap.add_argument("--reps", type=int, default=5)
     ap.add_argument("--rehearse", action="store_true",
@@ -216,6 +332,9 @@ def main() -> None:
         sys.exit(1)
     if a.paged:
         _write(sweep_paged(a, att), "sweep_attn_paged.jsonl")
+        return
+    if a.ring_write:
+        _write(sweep_ring_write(a), "sweep_ring_write.jsonl")
         return
     layers = 2 if a.rehearse else LAYERS
     widths = [None] + ([int(b) for b in a.blocks.split(",") if b]
