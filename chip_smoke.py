@@ -42,6 +42,7 @@ without a line containing ``"ok": true``.
 from __future__ import annotations
 
 import argparse
+import functools
 import json
 import os
 import shutil
@@ -693,6 +694,81 @@ def child_kernels(rehearse: bool) -> None:
                   "seconds": round(time.perf_counter() - t0, 2)})
 
     _ring_writes(rehearse)
+    _ring_reads(rehearse)
+
+
+def _ring_reads(rehearse: bool) -> None:
+    """A decoded token's recent rows (``ops/ssm.py``, PR 67) at Falcon-H1's and
+    Granite's widths, depth and slots: the launch over the live positions
+    (``recent_walk``) against the XLA form over the whole ring (``_recent``),
+    at 1, 33, 64, 65 and 96 live positions a slot, watermarks in both halves of
+    the ring, some rows' ``dt`` 0, and through ``ssm.read`` as the rule has it.
+    The tolerance is twice the unit test's 1e-6 of the output's largest value:
+    on the chip each form rounds the decay's exponent its own way, and the
+    sweep's largest reading over 24 points was 1.04e-6 (96 live rows)."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from dllama_tpu.obs import dispatch as obs_dispatch
+    from dllama_tpu.ops import ssm
+
+    lives = np.array([1, 33, 64, 65, 96])
+    for name, layers, b, h, p, g, n in (
+            ("falcon-h1-34b", 18, 32, 32, 128, 2, 256),
+            ("granite-4.0-h-small", 18, 16, 128, 64, 1, 128)):
+        if rehearse:
+            layers, b, h, n = 2, 5, h // 8, n // 8
+        f = ssm.heads_a_row(h, p)
+        rng = np.random.RandomState(11)
+
+        def noise(shape, dtype, lo=None, hi=None):
+            # a drawn vector of prime length repeated over the plane: one small
+            # program a shape where a generator's is a second of every rehearsal
+            v = rng.standard_normal(8191) if lo is None else rng.uniform(lo, hi, 8191)
+            return jnp.resize(jnp.asarray(v, dtype), shape)
+
+        rs = noise((layers, b, h, n, p), jnp.float32)
+        rk = noise((layers, b, g, ssm.RING, n), jnp.bfloat16)
+        rv = noise((layers, b, h // f, ssm.RING, f * p), jnp.bfloat16)
+        rg = noise((layers, b, 1, ssm.RING, h), jnp.float32, 0.01, 0.1) * (
+            jnp.arange(ssm.RING) % 7 != 3)[:, None]
+        c = noise((b, g, 1, n), jnp.bfloat16)
+        a = -noise((h,), jnp.float32, 0.5, 2.0)
+        base = jnp.asarray(ssm.FOLD * (np.arange(b) % 5 > 1) * (1 + np.arange(b) % 4),
+                           jnp.int32)
+        pos = base + jnp.asarray(lives[np.arange(b) % 5] - 1, jnp.int32)
+        layer = jnp.int32(layers - 1)
+        t0 = time.perf_counter()
+        want = jax.jit(ssm._recent)(c.astype(jnp.float32), rk, rv, rg, a, layer,
+                                    pos, base)
+        got = jax.jit(functools.partial(ssm.recent_walk, interpret=rehearse))(
+            c, rk, rv, rg, a, layer, pos, base)
+        geo = {"slots": b, "heads": h, "p": p, "groups": g, "n": n,
+               "live": sorted(set(lives[np.arange(b) % 5].tolist()))}
+        for what, x, y in (("y", got[0], want[0]), ("gq", got[1], want[1])):
+            _say({"kernel": "ssm_recent_walk", "mixer": name, "what": what,
+                  "geometry": geo, "tol": 2e-6,
+                  "rel_err": float(jnp.max(jnp.abs(x - y))
+                                   / jnp.maximum(jnp.max(jnp.abs(y)), 1e-30)),
+                  "seconds": round(time.perf_counter() - t0, 2)})
+        # the whole read as the rule has it on this backend: the launch beside
+        # the state's product, against the XLA form beside the same product
+        obs_dispatch.reset()
+        whole = jax.jit(ssm.read)(c, rs, rk, rv, rg, a, layer, pos, base)
+        paths = {k for k in obs_dispatch.dispatches() if k.startswith("ssm/")}
+        if not rehearse:
+            require(paths == {"ssm/state-read", "ssm/recent-walk"},
+                    f"the read of {name}: the rule chose {paths}")
+        keep, ssm.WALK_MIN_ROWS = ssm.WALK_MIN_ROWS, 1 << 30
+        try:
+            ref = jax.jit(ssm.read)(c, rs, rk, rv, rg, a, layer, pos, base)
+        finally:
+            ssm.WALK_MIN_ROWS = keep
+        _say({"kernel": "ssm_recent_walk", "mixer": name, "what": "read",
+              "geometry": geo, "tol": 2e-6,
+              "rel_err": float(jnp.max(jnp.abs(whole - ref)) / jnp.max(jnp.abs(ref))),
+              "seconds": round(time.perf_counter() - t0, 2)})
 
 
 def _ring_writes(rehearse: bool) -> None:

@@ -101,6 +101,14 @@ def test_rehearse_kernels_phase(rehearsal_env, capfd):
         (f"{m}.{k}", 0.0, 0.0) for m in ("falcon-h1-34b", "granite-4.0-h-small")
         for k in ("rk", "rv", "rg", "cz")]
     errs = [r for r in errs if r not in rings]
+    # + a decoded token's recent rows, the launch over the live positions against
+    # the XLA form over the ring: ``y``, ``gq`` and the whole read, both mixers (PR 67)
+    walks = [r for r in errs if r["kernel"] == "ssm_recent_walk"]
+    assert [(r["mixer"], r["what"], r["tol"]) for r in walks] == [
+        (m, w, 2e-6) for m in ("falcon-h1-34b", "granite-4.0-h-small")
+        for w in ("y", "gq", "read")]
+    assert all(r["geometry"]["live"] == [1, 33, 64, 65, 96] for r in walks)
+    errs = [r for r in errs if r not in walks]
     assert len(errs) == 54
     f32 = [r for r in errs if r["kernel"].endswith(".f32")]
     assert "q40.chosen_experts.f32" in [r["kernel"] for r in f32]

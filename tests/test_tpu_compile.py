@@ -1433,7 +1433,7 @@ def test_a_sixty_four_row_q40_launch_keeps_its_one_matmul_and_no_other_op(one_ch
         {k: v for k, v in DOT_BODY_OPS.items() if k not in plumbing}
 
 
-def _only_writes_make_a_plane(made, planes, text, launches, t):
+def _only_writes_make_a_plane(made, planes, text, launches, t, parked=()):
     """``made``: the ops of a compiled slot program whose result has the shape
     of a plane too large for VMEM.  Nothing makes one but the cache's writes:
     in-place windows, the scatter into pages, their fusions, and at one token
@@ -1443,20 +1443,42 @@ def _only_writes_make_a_plane(made, planes, text, launches, t):
     under its parts ``recent`` and ``conv`` and in no program of more tokens a
     row; and no plane of the mixer, small or large, is moved into VMEM whole
     (XLA did that to a 75 MB ring around a launch that aliased it, in and out
-    a layer, until the launch asked for VMEM's scope itself)."""
+    a layer, until the launch asked for VMEM's scope itself).  The rings' rows
+    are READ by one launch a mixer layer at one token a row and by none in a
+    program of more (``ssm.recent_walk``, PR 67), which takes the ``B`` and ``x``
+    planes as the ring puts leave them: nothing parks an array whole in the
+    layer body (no ``ConcatBitcast``, no ``slice-start``) but ONE of what
+    ``parked`` names (Granite's parent parked two stacked scale planes a layer,
+    23.6 MB; one is left, whichever XLA chooses for this body, whatever scope
+    the launch asks for) and nothing copies ``rk``, ``rv`` or ``rg`` (the fold's
+    loop wants ``rg`` with the positions minor and the launch took it with the
+    heads minor: a copy of the whole 9.4 MB plane a layer, until the launch
+    took the layer's ``dt`` as a slice)."""
     assert all(op in ("dynamic-update-slice", "scatter", "fusion") or (
         op == "custom-call" and t == 1 and path.endswith("/ring_put/pallas_call"))
         for ops_ in made.values() for op, path in ops_), made
     assert all("/kv_write/" in p for ops_ in made.values() for _, p in ops_), made
     assert {k for k in launches if k.endswith("/ring_put")} == (
         {"kv_write/recent/ring_put", "kv_write/conv/ring_put"} if t == 1 else set())
+    walks = {k for k in launches if k.endswith("/ssm_recent_walk")}
     if t == 1:
         assert {op for n in ("rv", "cz") for op, _ in made[n]} == {"custom-call"}
+        assert len(walks) == 1 and all(
+            k.endswith("attn/recent/jit(recent_walk)/ssm_recent_walk")
+            for k in walks), walks
+        found = set(re.findall(
+            r"= (\w+\[[\d,]+\])\S* custom-call\([^\n]*ConcatBitcast", text))
+        assert found <= set(parked) and len(found) <= 1, found
+        assert ("slice-start" in text) == bool(found)
     else:
         assert "ring_put/pallas_call" not in text
+        assert "ssm_recent_walk" not in text
     for n in ("rs", "rk", "rv", "rg", "cz"):
         shape = ",".join(map(str, planes[n].shape))
         assert not re.search(rf"\[{shape}\]\{{[^}}]*S\(1\)\}}", text), n
+    for n in ("rk", "rv", "rg"):
+        shape = ",".join(map(str, planes[n].shape))
+        assert not re.search(rf"\[{shape}\]\S* copy\(", text), n
 
 
 @pytest.mark.parametrize("t", [1, 16], ids=["pure-decode", "mixed"])
@@ -1530,7 +1552,7 @@ def test_falcon_h1_cell_programs_compile_with_a_state_beside_the_pool(
     assert "attn/paged_attn_fused" in kernels
     launches = {k.replace("cond/branch_0_fun/", "").replace("cond/branch_1_fun/", "")
                 for k in kernels}
-    assert {k for k in launches if "ssm" in k} == {
+    assert {k for k in launches if "/ssm/" in k} == {
         f"{sc}/ssm/jit(_pallas_matmul_stacked)/q40_mm_stacked" if t == 1 else
         f"{sc}/{sc}/ssm/jit(_pallas_matmul_stacked)/q40_mm_stacked"
         for sc in ("qkv", "wo")}
@@ -1676,7 +1698,8 @@ def test_granite_cell_programs_compile_with_a_state_or_pages_a_layer(
                                 "while", "call", "conditional"):
             path = re.search(r'op_name="([^"]+)"', rest)
             made.setdefault(plane, []).append((op, path.group(1) if path else ""))
-    _only_writes_make_a_plane(made, planes, text, launches, t)
+    _only_writes_make_a_plane(made, planes, text, launches, t,
+                              parked=("u16[20,48,4096]", "u16[20,128,3072]"))
     # the state's plane: the fold's update in place, alone or fused with the
     # block's product (a copy of the 1.2 GB plane would show in the temporaries)
     assert all("/kv_write/fold/while/body/" in p for _, p in made["rs"]), made["rs"]

@@ -50,9 +50,18 @@ XLA moved the whole plane into VMEM for the loop (in a served step it did so
 around a launch that aliased a plane that fits, which is why the launch asks
 for VMEM's scope itself: PERF.md section 6, PR 66 has the table).
 
+``--ring-read`` times the part ``recent`` of a decoded token's state-space read
+(``ops/ssm.py``): the XLA form over the whole ring (``_recent``) against the
+launch over the live positions (``recent_walk``), us a layer at 1 to 32 slots
+and 33 / 64 / 96 live positions a slot, at Falcon-H1's and Granite's widths,
+each checked against the XLA form (``rel_err``); ``rule`` is ``_read_form``'s
+choice at that many slots (PERF.md section 6, PR 67 has the table).
+
 Usage: python tools/sweep_attn.py [--repo DIR] [--blocks 256,512,1024]
        python tools/sweep_attn.py --ring-write [--rows 1,2,4,8,16,32]
                                   [--geo falcon-h1-34b.rv,...]
+       python tools/sweep_attn.py --ring-read [--rows 1,8,16,32]
+                                  [--lives 33,64,96] [--geo falcon-h1-34b]
        python tools/sweep_attn.py --paged [--repo DIR] [--geo lfm2-24b-a2b]
                                   [--ts 1,16] [--lives 416,1024,2048]
                                   [--slots 1,8,16,32]
@@ -136,7 +145,7 @@ def sweep_ring_write(a) -> list[dict]:
         if a.rehearse:
             layers, dh = 2, min(dh, 256)
         passes = max(1, 400 // layers)
-        for b in (int(x) for x in a.rows.split(",")):
+        for b in (int(x) for x in (a.rows or "1,2,4,8,16,32").split(",")):
             shape = (layers, b, h, r, dh)
             new = jax.random.normal(jax.random.PRNGKey(1), (b, h, 1, dh),
                                     jnp.bfloat16)
@@ -185,6 +194,77 @@ def sweep_ring_write(a) -> list[dict]:
                 results.append(rec)
                 print(json.dumps(rec), flush=True)
             del ref, ring
+    return results
+
+
+# (name, layers, H, P, G, N): the mixers whose rings a served pure-decode step
+# reads (32 slots in Falcon-H1's cell, 16 in Granite's)
+MIXERS = [("falcon-h1-34b", 18, 32, 128, 2, 256),
+          ("granite-4.0-h-small", 18, 128, 64, 1, 128)]
+
+
+def sweep_ring_read(a) -> list[dict]:
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from dllama_tpu.ops import ssm
+    from dllama_tpu.ops.retention import FOLD, RING
+
+    forms = {"xla": lambda c, *rest: ssm._recent(c.astype(jnp.float32), *rest),
+             "walk": functools.partial(ssm.recent_walk, interpret=a.rehearse)}
+    results = []
+    geos = a.geo.split(",") if a.geo else None
+    for name, layers, h, p, g, n in MIXERS:
+        if geos and name not in geos:
+            continue
+        if a.rehearse:
+            layers = 2
+        f = ssm.heads_a_row(h, p)
+        passes = max(1, 200 // layers)
+        for b in (int(x) for x in (a.rows or "1,8,16,32").split(",")):
+            keys = jax.random.split(jax.random.PRNGKey(b), 5)
+            rk = jax.random.normal(keys[0], (layers, b, g, RING, n), jnp.bfloat16)
+            rv = jax.random.normal(keys[1], (layers, b, h // f, RING, f * p),
+                                   jnp.bfloat16)
+            rg = jax.random.uniform(keys[2], (layers, b, 1, RING, h), jnp.float32,
+                                    0.01, 0.1)
+            c = jax.random.normal(keys[3], (b, g, 1, n), jnp.bfloat16)
+            av = -jax.random.uniform(keys[4], (h,), jnp.float32, 0.5, 2.0)
+            for live in (int(x) for x in (a.lives or "33,64,96").split(",")):
+                # watermarks out of step, as a served step's: both halves
+                base = jnp.asarray(FOLD * np.random.RandomState(b).randint(
+                    0, 64, b), jnp.int32)
+                pos = base + live - 1
+                ref = None
+                for form, fn in forms.items():
+                    def run(c_, rk_, rv_, rg_, pos_, base_, fn=fn):
+                        # each layer's queries depend on the last layer's
+                        # output, as in the model: nothing runs side by side
+                        def one(i, y):
+                            return fn(c_ + (y[:, :g, :, :1] * 0).astype(c_.dtype),
+                                      rk_, rv_, rg_, av, i % layers, pos_,
+                                      base_)[0]
+                        return jax.lax.fori_loop(
+                            0, passes * layers, one,
+                            jnp.zeros((b, h, 1, p), jnp.float32))
+
+                    med, low = _median_ms(jax.jit(run), (c, rk, rv, rg, pos, base),
+                                          a.reps)
+                    y = jax.jit(fn)(c, rk, rv, rg, av, jnp.int32(1), pos, base)[0]
+                    rec = {"mixer": name, "slots": b, "live": live, "form": form,
+                           "us_a_layer": round(1e3 * med / (passes * layers), 2),
+                           "min_us_a_layer": round(1e3 * low / (passes * layers), 2),
+                           "layers": layers, "repo": a.repo}
+                    if form == "xla":
+                        ref = y
+                        rec["rule"] = ssm._read_form(rv.shape, b, 1)
+                    else:
+                        rec["rel_err"] = float(jnp.max(jnp.abs(y - ref))
+                                               / jnp.max(jnp.abs(ref)))
+                    results.append(rec)
+                    print(json.dumps(rec), flush=True)
+            del rk, rv, rg
     return results
 
 
@@ -306,7 +386,8 @@ def main() -> None:
                     help="the paged pool's reads: fused page walk vs gather")
     ap.add_argument("--ts", default="1,16", help="--paged: query tokens a slot")
     ap.add_argument("--lives", default="",
-                    help="--paged: live contexts a slot, in tokens")
+                    help="--paged: live contexts a slot, in tokens; "
+                         "--ring-read: live positions a slot (33,64,96)")
     ap.add_argument("--geo", default="", help="--paged: geometries, by name")
     ap.add_argument("--slots", default="",
                     help="--paged: slot counts; times the fused walk and fits "
@@ -314,8 +395,12 @@ def main() -> None:
     ap.add_argument("--ring-write", action="store_true",
                     help="a pure-decode step's ring writes: windows vs the "
                          "launch vs the slab")
-    ap.add_argument("--rows", default="1,2,4,8,16,32",
-                    help="--ring-write: rows a call")
+    ap.add_argument("--ring-read", action="store_true",
+                    help="a decoded token's recent rows: the XLA form over the "
+                         "ring vs the launch over the live positions")
+    ap.add_argument("--rows", default="",
+                    help="--ring-write: rows a call (1,2,4,8,16,32); "
+                         "--ring-read: slots (1,8,16,32)")
     ap.add_argument("--blocks", default="256,512,1024")
     ap.add_argument("--reps", type=int, default=5)
     ap.add_argument("--rehearse", action="store_true",
@@ -335,6 +420,9 @@ def main() -> None:
         return
     if a.ring_write:
         _write(sweep_ring_write(a), "sweep_ring_write.jsonl")
+        return
+    if a.ring_read:
+        _write(sweep_ring_read(a), "sweep_ring_read.jsonl")
         return
     layers = 2 if a.rehearse else LAYERS
     widths = [None] + ([int(b) for b in a.blocks.split(",") if b]
